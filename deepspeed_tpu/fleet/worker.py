@@ -260,6 +260,11 @@ class FleetFrontEnd:
         """Wire one replica worker (spool dir, inbox, supervisor) without
         starting it — the constructor batch-starts; ``add_worker`` starts
         its own."""
+        from deepspeed_tpu.utils.platform import refuse_chip_children
+
+        refuse_chip_children(len(self.spools) + 1,
+                             {**os.environ, **self._env},
+                             "FleetFrontEnd worker mode")
         spool = os.path.join(self.run_dir, name)
         os.makedirs(os.path.join(spool, INBOX_DIR), exist_ok=True)
         self.spools[name] = spool

@@ -29,6 +29,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deepspeed_tpu.utils.platform import on_tpu
+
 NEG_INF = -1e30
 
 
@@ -115,21 +117,67 @@ def paged_attention_usable(q, k_pool, block_size: int) -> bool:
 # O(S * table-width) (grid version), and the loop issues no work at all
 # for pad slots.
 # ===================================================================== #
+# --------------------------------------------------------------------- #
+# int8 mode of the decode and verify kernels.  Mosaic takes neither an
+# int8 [bs, Hkv, D] tile whose sublane dim Hkv is under the packed int8
+# tile, nor a [bs, Hkv] fp32 scale block whose lane dim is Hkv ("Slice
+# shape along dimension 2 must be aligned to tiling").  So the payload is
+# walked in the flattened-lane view [bs, Hkv*D] (bs rows in sublanes,
+# head i in lanes [i*D, (i+1)*D), the prefill kernel's layout) and the
+# scales of each sequence's table blocks arrive as [B, Hkv, bs] — rows
+# in LANES, which is also the orientation the arithmetic wants: the
+# per-row scale multiplies the [g, bs] score tile after the QK dot and
+# the probability tile before the PV dot (a lane-wise product on g rows
+# instead of a dequantized [bs, D] tile).  Cost to know: at 8 KV heads
+# the flattened view is not a free reshape of the [rows, Hkv, D] pool in
+# the TPU tiled layout — XLA inserts a relayout copy of the pool in front
+# of the call (PERF.md) — so this form is the one that compiles, not yet
+# the one that is fast.
+# --------------------------------------------------------------------- #
+def _block_scales(scale_pool, block_tables, slots, nb, block_size, hkv):
+    """[rows, Hkv] scale pool -> [S, B, Hkv, bs] scales of each
+    sequence's table blocks (an XLA gather bounded by the table extent,
+    1/32 of the payload bytes it describes at D=128)."""
+    return scale_pool.reshape(nb, block_size, hkv)[
+        block_tables[slots]].transpose(0, 1, 3, 2)
+
+
+def _head_tiles(block, hkv, d):
+    """[bs, Hkv*D] int8 block -> Hkv fp32 [bs, D] tiles (static,
+    128-aligned lane slices)."""
+    return [block[:, i * d:(i + 1) * d].astype(jnp.float32)
+            for i in range(hkv)]
+
+
+def _scores_int8(qg, k_tiles, ks, scale):
+    """qg [Hkv, g, D], int8-valued k tiles, ks [Hkv, bs] -> [Hkv, g, bs]
+    scaled scores."""
+    return jnp.stack([
+        jax.lax.dot_general(qg[i], kt, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+        * (ks[i:i + 1, :] * scale) for i, kt in enumerate(k_tiles)])
+
+
+def _pv_int8(pg, v_tiles, vs):
+    """pg [Hkv, g, bs], int8-valued v tiles, vs [Hkv, bs] -> [Hkv, g, D]."""
+    return jnp.stack([
+        jax.lax.dot_general(pg[i] * vs[i:i + 1, :], vt,
+                            (((1,), (0,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+        for i, vt in enumerate(v_tiles)])
+
+
 def _decode_kernel(token_slot, token_pos, tables, q_ref, k_hbm, v_hbm,
                    *refs, block_size, scale, window, quantized=False):
-    # quantized mode threads two extra HBM scale pools + their VMEM
-    # double buffers through the SAME kernel body: dequant happens here
-    # on the block walk (int8 payload * per-row/per-head scale), fused
-    # into the online-softmax update — never as a separate materialized
-    # pass, and the HBM read is int8 bytes + the tiny scale stream.
+    # quantized mode walks the SAME block schedule over the int8 payload
+    # and applies the scales inside the online-softmax update (see the
+    # int8 note above) — never a separate dequantized pass, and the HBM
+    # read is int8 bytes plus the small pre-gathered scale block.
     if quantized:
-        (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf,
-         sems) = refs
-        streams = ((k_buf, k_hbm, 0), (v_buf, v_hbm, 1),
-                   (ks_buf, ks_hbm, 2), (vs_buf, vs_hbm, 3))
+        ks_ref, vs_ref, o_ref, k_buf, v_buf, sems = refs
     else:
         o_ref, k_buf, v_buf, sems = refs
-        streams = ((k_buf, k_hbm, 0), (v_buf, v_hbm, 1))
+    streams = ((k_buf, k_hbm, 0), (v_buf, v_hbm, 1))
     t = pl.program_id(0)
     pos = token_pos[t]
     slot = token_slot[t]
@@ -141,7 +189,7 @@ def _decode_kernel(token_slot, token_pos, tables, q_ref, k_hbm, v_hbm,
 
     q = q_ref[0].astype(jnp.float32)      # [H, D]
     h, d = q.shape
-    hkv = k_buf.shape[2]
+    hkv = k_buf.shape[2] // d if quantized else k_buf.shape[2]
     g = h // hkv
     qg = q.reshape(hkv, g, d)
 
@@ -153,14 +201,6 @@ def _decode_kernel(token_slot, token_pos, tables, q_ref, k_hbm, v_hbm,
     def _():
         for buf, hbm, which in streams:
             dma(buf, hbm, 0, lo, which).start()
-
-    def load_kv(sl):
-        k = k_buf[sl].astype(jnp.float32)             # [bs, Hkv, D]
-        v = v_buf[sl].astype(jnp.float32)
-        if quantized:                                 # fused dequant
-            k = k * ks_buf[sl].astype(jnp.float32)[..., None]
-            v = v * vs_buf[sl].astype(jnp.float32)[..., None]
-        return k, v
 
     def body(i, carry):
         m_prev, l_prev, acc = carry
@@ -175,10 +215,14 @@ def _decode_kernel(token_slot, token_pos, tables, q_ref, k_hbm, v_hbm,
 
         for buf, hbm, which in streams:
             dma(buf, hbm, sl, j, which).wait()
-        k, v = load_kv(sl)
-        s = jax.lax.dot_general(
-            qg, k.transpose(1, 2, 0), (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale   # [Hkv, g, bs]
+        if quantized:
+            s = _scores_int8(qg, _head_tiles(k_buf[sl], hkv, d),
+                             ks_ref[0, j], scale)         # [Hkv, g, bs]
+        else:
+            k = k_buf[sl].astype(jnp.float32)             # [bs, Hkv, D]
+            s = jax.lax.dot_general(
+                qg, k.transpose(1, 2, 0), (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32) * scale
         key_pos = j * block_size + jax.lax.broadcasted_iota(
             jnp.int32, (hkv, g, block_size), 2)
         keep = key_pos <= pos
@@ -192,25 +236,21 @@ def _decode_kernel(token_slot, token_pos, tables, q_ref, k_hbm, v_hbm,
         corr = jnp.exp(m_prev - m_new)
         l_new = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
         pg = p.reshape(hkv, g, block_size)
-        out = jax.lax.dot_general(
-            pg, v.transpose(1, 0, 2), (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)       # [Hkv, g, D]
+        if quantized:
+            out = _pv_int8(pg, _head_tiles(v_buf[sl], hkv, d),
+                           vs_ref[0, j])              # [Hkv, g, D]
+        else:
+            v = v_buf[sl].astype(jnp.float32)
+            out = jax.lax.dot_general(
+                pg, v.transpose(1, 0, 2), (((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=jnp.float32)
         acc = acc * corr + out.reshape(h, d)
         return m_new, l_new, acc
 
     m0 = jnp.full((h, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((h, 1), jnp.float32)
     acc0 = jnp.zeros((h, d), jnp.float32)
-    if quantized:
-        # no unroll kwarg: jax 0.4.37 rejects `unroll` with a traced
-        # trip count (the verify kernel's long-standing form); the
-        # unquantized call below keeps its historical spelling — its
-        # interpret-mode behavior on old jax is part of the frozen
-        # tier-1 seed set and must not change
-        _m, l, acc = jax.lax.fori_loop(0, n, body, (m0, l0, acc0))
-    else:
-        _m, l, acc = jax.lax.fori_loop(0, n, body, (m0, l0, acc0),
-                                       unroll=False)
+    _m, l, acc = jax.lax.fori_loop(0, n, body, (m0, l0, acc0))
     safe_l = jnp.where(l == 0.0, 1.0, l)
     o_ref[0] = (acc / safe_l).astype(o_ref.dtype)
 
@@ -231,42 +271,19 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     blocks.  Returns [S, H, D] (pad slots, pos<0, give zeros).
 
     ``k_scale``/``v_scale`` (``[rows, Hkv]`` fp32, int8 pools) switch on
-    the fused-dequant mode: the scale pools ride in HBM next to the
-    payload, each walked block DMAs payload + scales together, and the
-    dequant happens in VMEM inside the online-softmax update."""
+    the fused-dequant mode: the int8 payload is walked block by block
+    from HBM, the scales of each sequence's table blocks are gathered
+    once (:func:`_block_scales`), and they are applied in VMEM inside the
+    online-softmax update."""
     s_count, h, d = q.shape
-    hkv = k_pool.shape[1]
-    nb = k_pool.shape[0] // block_size
     quantized = k_scale is not None
     if interpret is None:
-        try:
-            interpret = jax.devices()[0].platform != "tpu"
-        except Exception:  # noqa: BLE001
-            interpret = True
-
-    kp = k_pool.reshape(nb, block_size, hkv, d)
-    vp = v_pool.reshape(nb, block_size, hkv, d)
+        interpret = not on_tpu()
     scale = 1.0 / (d ** 0.5)
-
-    n_streams = 4 if quantized else 2
-    in_specs = [
-        pl.BlockSpec((1, h, d), lambda t, slot, pos, tab: (t, 0, 0)),
-        pl.BlockSpec(memory_space=pl.ANY),
-        pl.BlockSpec(memory_space=pl.ANY),
-    ]
-    scratch = [
-        pltpu.VMEM((2, block_size, hkv, d), k_pool.dtype),
-        pltpu.VMEM((2, block_size, hkv, d), v_pool.dtype),
-    ]
-    operands = [q, kp, vp]
-    if quantized:
-        in_specs += [pl.BlockSpec(memory_space=pl.ANY),
-                     pl.BlockSpec(memory_space=pl.ANY)]
-        scratch += [pltpu.VMEM((2, block_size, hkv), jnp.float32),
-                    pltpu.VMEM((2, block_size, hkv), jnp.float32)]
-        operands += [k_scale.reshape(nb, block_size, hkv),
-                     v_scale.reshape(nb, block_size, hkv)]
-    scratch.append(pltpu.SemaphoreType.DMA((2, n_streams)))
+    tables = block_tables.astype(jnp.int32)
+    slots = token_slot.astype(jnp.int32)
+    operands, in_specs, scratch = _walk_operands(
+        q, k_pool, v_pool, k_scale, v_scale, tables, slots, block_size)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -283,8 +300,36 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_count, h, d), q.dtype),
         interpret=bool(interpret),
-    )(token_slot.astype(jnp.int32), token_pos.astype(jnp.int32),
-      block_tables.astype(jnp.int32), *operands)
+    )(slots, token_pos.astype(jnp.int32), tables, *operands)
+
+
+def _walk_operands(q, k_pool, v_pool, k_scale, v_scale, tables, slots,
+                   block_size):
+    """(operands, in_specs, scratch) shared by the decode and verify
+    wrappers: the q block per sequence, the KV pools left in HBM for the
+    manual block walk, the double-buffered block scratch and its DMA
+    semaphores.  bf16 pools walk [bs, Hkv, D] blocks; int8 pools the
+    flattened-lane [bs, Hkv*D] view plus each sequence's gathered scale
+    blocks (see the int8 note)."""
+    rows, hkv, d = k_pool.shape
+    nb = rows // block_size
+    quantized = k_scale is not None
+    block = (block_size, hkv * d) if quantized else (block_size, hkv, d)
+    operands = [q, k_pool.reshape(nb, *block), v_pool.reshape(nb, *block)]
+    in_specs = [
+        pl.BlockSpec((1,) + q.shape[1:], lambda t, slot, pos, tab: (t, 0, 0)),
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
+    ]
+    if quantized:
+        in_specs += [pl.BlockSpec((1, tables.shape[1], hkv, block_size),
+                                  lambda t, slot, pos, tab: (t, 0, 0, 0))] * 2
+        operands += [_block_scales(sc, tables, slots, nb, block_size, hkv)
+                     for sc in (k_scale, v_scale)]
+    scratch = [pltpu.VMEM((2,) + block, k_pool.dtype),
+               pltpu.VMEM((2,) + block, v_pool.dtype),
+               pltpu.SemaphoreType.DMA((2, 2))]
+    return operands, in_specs, scratch
 
 
 # ===================================================================== #
@@ -301,18 +346,14 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
 def _verify_kernel(token_slot, token_pos, tables, q_ref, k_hbm, v_hbm,
                    *refs, block_size, scale, window, k_tokens,
                    quantized=False):
-    # same fused-dequant contract as _decode_kernel: quantized mode adds
-    # HBM scale pools + VMEM scale buffers, and the K query rows share
-    # ONE dequantized block per walk step (the whole point — the int8
-    # read amortises across all K candidate positions)
+    # same int8 contract as _decode_kernel, and the K query rows share
+    # ONE converted block per walk step (the whole point — the int8 read
+    # amortises across all K candidate positions)
     if quantized:
-        (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf,
-         sems) = refs
-        streams = ((k_buf, k_hbm, 0), (v_buf, v_hbm, 1),
-                   (ks_buf, ks_hbm, 2), (vs_buf, vs_hbm, 3))
+        ks_ref, vs_ref, o_ref, k_buf, v_buf, sems = refs
     else:
         o_ref, k_buf, v_buf, sems = refs
-        streams = ((k_buf, k_hbm, 0), (v_buf, v_hbm, 1))
+    streams = ((k_buf, k_hbm, 0), (v_buf, v_hbm, 1))
     t = pl.program_id(0)
     pos0 = token_pos[t]                   # first fed position (0 on pads)
     slot = token_slot[t]
@@ -326,7 +367,7 @@ def _verify_kernel(token_slot, token_pos, tables, q_ref, k_hbm, v_hbm,
     qf = q_ref[0].astype(jnp.float32)     # [K*H, D], row k*H+h
     h = qf.shape[0] // k_tokens
     d = qf.shape[1]
-    hkv = k_buf.shape[2]
+    hkv = k_buf.shape[2] // d if quantized else k_buf.shape[2]
     g = h // hkv
 
     def dma(buf, hbm, sl, j, which):
@@ -351,18 +392,24 @@ def _verify_kernel(token_slot, token_pos, tables, q_ref, k_hbm, v_hbm,
 
         for buf, hbm, which in streams:
             dma(buf, hbm, sl, j, which).wait()
-        k = k_buf[sl].astype(jnp.float32)             # [bs, Hkv, D]
-        v = v_buf[sl].astype(jnp.float32)
-        if quantized:                                 # fused dequant
-            k = k * ks_buf[sl].astype(jnp.float32)[..., None]
-            v = v * vs_buf[sl].astype(jnp.float32)[..., None]
+        if quantized:
+            k_tiles = _head_tiles(k_buf[sl], hkv, d)
+            v_tiles = _head_tiles(v_buf[sl], hkv, d)
+            ks, vs = ks_ref[0, j], vs_ref[0, j]       # [Hkv, bs]
+        else:
+            k = k_buf[sl].astype(jnp.float32)         # [bs, Hkv, D]
+            v = v_buf[sl].astype(jnp.float32)
         ms, ls, accs = [], [], []
         for kq in range(k_tokens):        # static unroll: K is small
             q = qf[kq * h:(kq + 1) * h]               # [H, D]
             qg = q.reshape(hkv, g, d)
-            s = jax.lax.dot_general(
-                qg, k.transpose(1, 2, 0), (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32) * scale   # [Hkv,g,bs]
+            if quantized:
+                s = _scores_int8(qg, k_tiles, ks, scale)      # [Hkv,g,bs]
+            else:
+                s = jax.lax.dot_general(
+                    qg, k.transpose(1, 2, 0),
+                    (((2,), (1,)), ((0,), (0,))),
+                    preferred_element_type=jnp.float32) * scale
             key_pos = j * block_size + jax.lax.broadcasted_iota(
                 jnp.int32, (hkv, g, block_size), 2)
             keep = key_pos <= pos0 + kq   # row k's own causal frontier
@@ -378,9 +425,12 @@ def _verify_kernel(token_slot, token_pos, tables, q_ref, k_hbm, v_hbm,
             ls.append(l_prev[kq * h:(kq + 1) * h] * corr
                       + jnp.sum(p, axis=1, keepdims=True))
             pg = p.reshape(hkv, g, block_size)
-            out = jax.lax.dot_general(
-                pg, v.transpose(1, 0, 2), (((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.float32)   # [Hkv, g, D]
+            if quantized:
+                out = _pv_int8(pg, v_tiles, vs)       # [Hkv, g, D]
+            else:
+                out = jax.lax.dot_general(
+                    pg, v.transpose(1, 0, 2), (((2,), (1,)), ((0,), (0,))),
+                    preferred_element_type=jnp.float32)
             accs.append(acc[kq * h:(kq + 1) * h] * corr
                         + out.reshape(h, d))
             ms.append(m_new)
@@ -420,43 +470,18 @@ def paged_verify_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     """
     t_count, h, d = q.shape
     s_count = t_count // k_tokens
-    hkv = k_pool.shape[1]
-    nb = k_pool.shape[0] // block_size
     quantized = k_scale is not None
     if interpret is None:
-        try:
-            interpret = jax.devices()[0].platform != "tpu"
-        except Exception:  # noqa: BLE001
-            interpret = True
+        interpret = not on_tpu()
 
-    kp = k_pool.reshape(nb, block_size, hkv, d)
-    vp = v_pool.reshape(nb, block_size, hkv, d)
     scale = 1.0 / (d ** 0.5)
+    tables = block_tables.astype(jnp.int32)
     # per-slot metadata: the first row of each K-group drives the walk
     slot0 = token_slot.reshape(s_count, k_tokens)[:, 0].astype(jnp.int32)
     pos0 = token_pos.reshape(s_count, k_tokens)[:, 0].astype(jnp.int32)
-    qf = q.reshape(s_count, k_tokens * h, d)
-
-    n_streams = 4 if quantized else 2
-    in_specs = [
-        pl.BlockSpec((1, k_tokens * h, d),
-                     lambda t, slot, pos, tab: (t, 0, 0)),
-        pl.BlockSpec(memory_space=pl.ANY),
-        pl.BlockSpec(memory_space=pl.ANY),
-    ]
-    scratch = [
-        pltpu.VMEM((2, block_size, hkv, d), k_pool.dtype),
-        pltpu.VMEM((2, block_size, hkv, d), v_pool.dtype),
-    ]
-    operands = [qf, kp, vp]
-    if quantized:
-        in_specs += [pl.BlockSpec(memory_space=pl.ANY),
-                     pl.BlockSpec(memory_space=pl.ANY)]
-        scratch += [pltpu.VMEM((2, block_size, hkv), jnp.float32),
-                    pltpu.VMEM((2, block_size, hkv), jnp.float32)]
-        operands += [k_scale.reshape(nb, block_size, hkv),
-                     v_scale.reshape(nb, block_size, hkv)]
-    scratch.append(pltpu.SemaphoreType.DMA((2, n_streams)))
+    operands, in_specs, scratch = _walk_operands(
+        q.reshape(s_count, k_tokens * h, d), k_pool, v_pool, k_scale,
+        v_scale, tables, slot0, block_size)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -474,7 +499,7 @@ def paged_verify_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((s_count, k_tokens * h, d),
                                        q.dtype),
         interpret=bool(interpret),
-    )(slot0, pos0, block_tables.astype(jnp.int32), *operands)
+    )(slot0, pos0, tables, *operands)
     return out.reshape(t_count, h, d)
 
 
@@ -574,9 +599,7 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     s_count, b_per_seq = block_tables.shape
     nt = t_count // tile_q
     if interpret is None:
-        from deepspeed_tpu.ops.flash_attention import _on_tpu
-
-        interpret = not _on_tpu()
+        interpret = not on_tpu()
 
     # flattened-lane layouts (see _prefill_kernel): q/o [T, H*D], pools
     # [nb, bs, Hkv*D]
@@ -653,10 +676,7 @@ def paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     nb = k_pool.shape[0] // block_size
     s_count, b_per_seq = block_tables.shape
     if interpret is None:
-        try:
-            interpret = jax.devices()[0].platform != "tpu"
-        except Exception:  # noqa: BLE001
-            interpret = True
+        interpret = not on_tpu()
 
     kp = k_pool.reshape(nb, block_size, hkv, d)
     vp = v_pool.reshape(nb, block_size, hkv, d)
@@ -779,10 +799,11 @@ def _dslint_paged_int8_setup():
 
 @pallas_kernel_case(
     "paged_decode_dma_int8",
-    note="int8 block-quantized decode: payload + per-row/per-head "
-         "scale pools both walk in HBM (memory_space=ANY); dequant is "
-         "fused into the double-buffered block walk — the VMEM cost is "
-         "the int8 block scratch plus two [bs, Hkv] scale buffers")
+    note="int8 block-quantized decode: the payload walks in HBM "
+         "(memory_space=ANY) as flattened-lane [bs, Hkv*D] blocks and "
+         "the scales are applied around the dots inside the walk — the "
+         "VMEM cost is the int8 block scratch plus each sequence's "
+         "pre-gathered [B, Hkv, bs] scale blocks")
 def _dslint_paged_decode_int8_case():
     import numpy as np
 
@@ -798,8 +819,8 @@ def _dslint_paged_decode_int8_case():
 @pallas_kernel_case(
     "paged_verify_multiquery_int8",
     note="int8 speculative verify: K=4 query rows share one "
-         "fused-dequant block walk (int8 payload + scale DMAs amortise "
-         "across every candidate position)")
+         "fused-dequant block walk (the int8 payload DMA and its "
+         "conversion amortise across every candidate position)")
 def _dslint_paged_verify_int8_case():
     import numpy as np
 

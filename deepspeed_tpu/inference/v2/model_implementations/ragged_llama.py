@@ -53,6 +53,7 @@ from deepspeed_tpu.models.llama import LlamaConfig, apply_rotary
 # training rules so a sharding change propagates to both
 from deepspeed_tpu.models.llama import LLAMA_PARTITION_RULES as _TP_RULES
 from deepspeed_tpu.ops.quantized_matmul import qmm
+from deepspeed_tpu.utils.platform import on_tpu
 
 
 def ragged_param_specs(params) -> Any:
@@ -132,10 +133,7 @@ def _paged_attention(q, k_pool, v_pool, batch, block_size,
     """
     quantized = k_scale is not None
     if use_kernel is None:
-        try:
-            use_kernel = jax.devices()[0].platform == "tpu"
-        except Exception:  # noqa: BLE001
-            use_kernel = False
+        use_kernel = on_tpu()
     if use_kernel and force_dense is None:
         from deepspeed_tpu.inference.v2.kernels import (
             paged_attention, paged_attention_usable,
@@ -485,7 +483,8 @@ class RaggedLlama:
             out, new_cache[f"layer_{i}"] = ragged_attention_block(
                 lp["self_attn"], xa, kv_cache[f"layer_{i}"], batch,
                 self.block_size, cfg, h, hkv, d, cos, sin, ax=ax,
-                decode_mode=decode, verify_k=verify_k)
+                prefill_tile=prefill_tile, decode_mode=decode,
+                verify_k=verify_k)
             x = x + out
             xm = _rms_norm(x, lp["post_attention_layernorm"]["scale"],
                            cfg.rms_norm_eps)

@@ -9,7 +9,8 @@ layer consumes (``comm/comm.py init_distributed``):
 * ``WORLD_SIZE`` / ``RANK`` / ``LOCAL_RANK`` — global/local process ids
 
 On a real TPU pod each host runs ONE process (slots=1) that owns all local
-chips; slots>1 is the CPU-simulation / subdevice path. A child failure
+chips; slots>1 is the CPU-simulation path and is refused on a TPU host,
+where the children would fail or hang on chips another process holds. A child failure
 tears down the whole local group (reference terminate_process_tree).
 """
 
@@ -80,6 +81,9 @@ def main(args=None) -> int:
     # dropped connection can never orphan the worker group
     signal.signal(signal.SIGHUP, _terminate)
 
+    from deepspeed_tpu.utils.platform import refuse_chip_children
+
+    refuse_chip_children(len(local_slots), os.environ, "launcher")
     cmd = _child_cmd(args)
     for i, slot in enumerate(local_slots):
         env = dict(os.environ)

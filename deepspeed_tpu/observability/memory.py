@@ -31,8 +31,8 @@ from __future__ import annotations
 
 from typing import Any, Callable, Dict, List, Optional
 
-#: CompiledMemoryStats fields worth keeping (jax 0.4.x names); absent
-#: attributes are simply skipped, so newer/older jaxlibs degrade softly
+#: CompiledMemoryStats fields worth keeping; an attribute a backend
+#: does not report is skipped
 MEMORY_FIELDS = (
     "argument_size_in_bytes",
     "output_size_in_bytes",
@@ -295,8 +295,8 @@ def virtual_mesh_probe(name: str,
                        ledger: Optional[MemoryLedger] = None
                        ) -> Dict[str, Any]:
     """Run one named probe in-process and ledger it under
-    ``virtual_mesh/<name>``.  Any failure (old-jax mesh APIs, OOM-sized
-    HLO, missing model) becomes an explicit ``unavailable`` record."""
+    ``virtual_mesh/<name>``.  Any failure (OOM-sized HLO, missing
+    model) becomes an explicit ``unavailable`` record."""
     ledger = ledger if ledger is not None else MemoryLedger()
     key = f"virtual_mesh/{name}"
     builder = VIRTUAL_MESH_PROBES.get(name)
@@ -304,12 +304,15 @@ def virtual_mesh_probe(name: str,
         return ledger.record_unavailable(
             key, f"unknown probe {name!r} "
                  f"(have {sorted(VIRTUAL_MESH_PROBES)})")
+    import jax
+
+    # outside the try: a backend that fails to start is an error, not an
+    # "unavailable" record
+    where = {"devices": jax.device_count(),
+             "platform": jax.devices()[0].platform}
     try:
         model, batch, seq, meta = builder()
-        import jax
-
-        meta = {**meta, "devices": jax.device_count(),
-                "platform": jax.devices()[0].platform}
+        meta = {**meta, **where}
         lowered = zero3_train_lowering(model, batch, seq)
         compiled = lowered.compile()
     except Exception as e:  # noqa: BLE001 — absence is a record

@@ -78,17 +78,13 @@ def dot_product_attention(q, k, v, *, causal: bool = True,
         return _xla_attention(q, k, v, causal=causal, mask=mask,
                               scale=scale, window=window, bias=bias)
     if implementation in ("auto", "pallas"):
-        try:
-            from deepspeed_tpu.ops.flash_attention import (
-                flash_attention_usable, flash_attention)
-        except ImportError:
-            if implementation == "pallas":
-                raise  # an explicit kernel request must not silently degrade
-        else:
-            if implementation == "pallas" or flash_attention_usable(q, k, v, causal,
-                                                                    mask):
-                return flash_attention(q, k, v, causal=causal, mask=mask,
-                                       scale=scale, window=window)
+        from deepspeed_tpu.ops.flash_attention import (
+            flash_attention_usable, flash_attention)
+
+        if implementation == "pallas" or flash_attention_usable(
+                q, k, v, causal, mask):
+            return flash_attention(q, k, v, causal=causal, mask=mask,
+                                   scale=scale, window=window)
     return _xla_attention(q, k, v, causal=causal, mask=mask, scale=scale,
                           window=window)
 
@@ -110,18 +106,14 @@ def folded_attention(q, k, v, *, num_heads: int,
     working and only eligible ones take the kernel."""
     hkv = num_kv_heads if num_kv_heads is not None else num_heads
     if implementation in ("auto", "pallas"):
-        try:
-            from deepspeed_tpu.ops.flash_attention import (
-                flash_attention_folded, flash_attention_folded_usable)
-        except ImportError:
-            if implementation == "pallas":
-                raise  # an explicit kernel request must not silently degrade
-        else:
-            if implementation == "pallas" or flash_attention_folded_usable(
-                    q, k, v, num_heads, hkv, causal, None):
-                return flash_attention_folded(
-                    q, k, v, num_heads=num_heads, num_kv_heads=hkv,
-                    causal=causal, scale=scale, window=window)
+        from deepspeed_tpu.ops.flash_attention import (
+            flash_attention_folded, flash_attention_folded_usable)
+
+        if implementation == "pallas" or flash_attention_folded_usable(
+                q, k, v, num_heads, hkv, causal, None):
+            return flash_attention_folded(
+                q, k, v, num_heads=num_heads, num_kv_heads=hkv,
+                causal=causal, scale=scale, window=window)
     b, sq, hd = q.shape
     sk = k.shape[1]
     d = hd // num_heads
@@ -150,24 +142,20 @@ def paired_attention(q, k, v, *, num_heads: int,
     to the bshd path, so routing never fails."""
     hkv = num_kv_heads if num_kv_heads is not None else num_heads
     if implementation in ("auto", "pallas"):
-        try:
-            from deepspeed_tpu.ops.flash_attention import (
-                flash_attention_paired, flash_attention_paired_usable,
-                paired_heads_per_block)
-        except ImportError:
-            if implementation == "pallas":
-                raise  # an explicit kernel request must not silently degrade
-        else:
-            d = q.shape[-1] // num_heads if q.ndim == 3 and \
-                q.shape[-1] % num_heads == 0 else 0
-            pairable = d and paired_heads_per_block(num_heads, hkv,
-                                                    d) is not None
-            if pairable and (implementation == "pallas" or
-                             flash_attention_paired_usable(
-                                 q, k, v, num_heads, hkv, causal, None)):
-                return flash_attention_paired(
-                    q, k, v, num_heads=num_heads, num_kv_heads=hkv,
-                    causal=causal, scale=scale, window=window)
+        from deepspeed_tpu.ops.flash_attention import (
+            flash_attention_paired, flash_attention_paired_usable,
+            paired_heads_per_block)
+
+        d = q.shape[-1] // num_heads if q.ndim == 3 and \
+            q.shape[-1] % num_heads == 0 else 0
+        pairable = d and paired_heads_per_block(num_heads, hkv,
+                                                d) is not None
+        if pairable and (implementation == "pallas" or
+                         flash_attention_paired_usable(
+                             q, k, v, num_heads, hkv, causal, None)):
+            return flash_attention_paired(
+                q, k, v, num_heads=num_heads, num_kv_heads=hkv,
+                causal=causal, scale=scale, window=window)
     return folded_attention(q, k, v, num_heads=num_heads, num_kv_heads=hkv,
                             causal=causal, scale=scale, window=window,
                             implementation=implementation)

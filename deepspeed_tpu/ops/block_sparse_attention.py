@@ -31,7 +31,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from deepspeed_tpu.ops.flash_attention import NEG_INF, _on_tpu
+from deepspeed_tpu.ops.flash_attention import NEG_INF
+from deepspeed_tpu.utils.platform import on_tpu
 
 
 def _pick_tile(s: int, block: int, target: int = 256) -> int:
@@ -389,7 +390,7 @@ def block_sparse_attention(q, k, v, bs_layout: BlockSparseLayout,
     b, h, s, d = q.shape
     scale = scale if scale is not None else 1.0 / float(np.sqrt(d))
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not on_tpu()
     return _bs_attn(q, k, v, bs_layout.cells, bs_layout.tile_any,
                     bs_layout.block, bs_layout.tile_q, bs_layout.tile_k,
                     float(scale), bool(interpret))

@@ -35,7 +35,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from deepspeed_tpu.ops.flash_attention import NEG_INF, _on_tpu
+from deepspeed_tpu.ops.flash_attention import NEG_INF
+from deepspeed_tpu.utils.platform import on_tpu
 
 __all__ = ["DS4Sci_EvoformerAttention", "EvoformerAttnBuilder",
            "evoformer_attention_dense"]
@@ -288,7 +289,7 @@ def _bwd_chunked(res, dout):
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
 def _evoformer(Q, K, V, biases: Tuple, interpret):
-    if interpret is None and not _on_tpu():
+    if interpret is None and not on_tpu():
         return evoformer_attention_dense(Q, K, V, list(biases))
     return _flash_path(Q, K, V, biases, interpret or False)
 
@@ -322,7 +323,7 @@ def DS4Sci_EvoformerAttention(Q: jnp.ndarray, K: jnp.ndarray,
     """
     bs = tuple(biases or [])
     sq, sk = Q.shape[-3], K.shape[-3]
-    use_kernel = ((interpret is not None or _on_tpu())
+    use_kernel = ((interpret is not None or on_tpu())
                   and Q.shape[-1] % 8 == 0
                   and _pick_block(sq, 256) is not None
                   and _pick_block(sk, 256) is not None)

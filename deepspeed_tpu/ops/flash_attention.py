@@ -41,6 +41,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deepspeed_tpu.parallel.topology import GROUP_ALIASES
+from deepspeed_tpu.utils.platform import on_tpu
+
 NEG_INF = -1e30
 # Measured on v5e (125M-class shapes): 512/1024 blocks beat both 128/128
 # tiles (grid overhead) and XLA's fused attention by ~1.5x; the [bq, bk]
@@ -64,11 +67,62 @@ def _pick_block(n: int, target: int) -> int:
     return best
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:
-        return False
+# --------------------------------------------------------------------- #
+# Mesh partitioning.  GSPMD cannot partition a compiled Mosaic kernel
+# ("Mosaic kernels cannot be automatically partitioned. Please wrap the
+# call in a shard_map") — so on a multi-device mesh the public entries
+# run the kernel once per shard: attention is independent per batch row
+# and per head, nothing crosses shards.  The interpreter lowers to plain
+# XLA ops that GSPMD splits itself and needs none of this.
+# --------------------------------------------------------------------- #
+_BATCH_AXES = GROUP_ALIASES["dp"]
+_HEAD_AXES = ("seq", "model")   # Ulysses scatters heads over 'seq',
+#                                 tensor parallelism over 'model'
+
+
+def mesh_partition(batch: int, num_heads: int, num_kv_heads: int,
+                   heads_ok=lambda n: True):
+    """``(mesh, batch_axes, head_axes, head_shards)`` for splitting one
+    attention call over the engine's mesh, or None when there is nothing
+    to split: no topology, one device, or a caller that is already
+    inside a manual (``shard_map``) region and holds local shards.
+
+    Batch goes over the data-parallel axes and heads over 'seq' x
+    'model', each only when it divides (``heads_ok(n)`` lets a layout
+    veto a head split its tiling cannot take); a dimension that does not
+    divide stays whole on every shard, which is redundant but correct."""
+    import math
+
+    from deepspeed_tpu.parallel import groups
+
+    topo = groups.get_topology(optional=True)
+    if topo is None or topo.world_size == 1 or \
+            jax.sharding.get_abstract_mesh().manual_axes:
+        return None
+    mesh = topo.mesh
+    size = lambda axes: math.prod(mesh.shape[a] for a in axes)
+    batch_axes = _BATCH_AXES if batch % size(_BATCH_AXES) == 0 else None
+    head_axes = tuple(a for a in _HEAD_AXES if mesh.shape[a] > 1)
+    n = size(head_axes)
+    if n == 1 or num_heads % n or num_kv_heads % n or not heads_ok(n):
+        head_axes, n = None, 1
+    return mesh, batch_axes, head_axes, n
+
+
+def run_partitioned(kernel, q, k, v, part):
+    """``kernel(q, k, v, head_shards)`` per shard of the partition from
+    :func:`mesh_partition` (one whole call when it is None); heads are
+    dim 2 of [B,S,H,D] and of the folded [B,S,H*D] alike."""
+    from jax.sharding import PartitionSpec as P
+
+    if part is None:
+        return kernel(q, k, v, 1)
+    mesh, batch_axes, head_axes, n = part
+    spec = P(batch_axes, None, head_axes, *([None] * (q.ndim - 3)))
+    return jax.shard_map(
+        lambda a, b, c: kernel(a, b, c, n), mesh=mesh,
+        in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False)(q, k, v)
 
 
 def flash_attention_usable(q, k, v, causal, mask) -> bool:
@@ -84,7 +138,7 @@ def flash_attention_usable(q, k, v, causal, mask) -> bool:
         return False
     if sq * sk < 128 * 128:  # tiny: XLA fusion wins
         return False
-    return _on_tpu()
+    return on_tpu()
 
 
 def _causal_keep(iq, ik, block_q, block_k, causal_offset, window):
@@ -493,15 +547,17 @@ def flash_attention(q, k, v, *, causal: bool = True,
         raise ValueError(
             f"seq lengths ({sq},{sk}) must divide blocks ({block_q},{block_k})")
     if interpret is None:
-        interpret = not _on_tpu()
+        interpret = not on_tpu()
 
-    qt = q.transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
-    vt = v.transpose(0, 2, 1, 3)
-    o = _flash(qt, kt, vt, float(scale), bool(causal), int(block_q),
-               int(block_k), bool(interpret),
-               int(window) if window is not None else None)
-    return o.transpose(0, 2, 1, 3)
+    def kernel(q, k, v, _head_shards):
+        o = _flash(q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
+                   v.transpose(0, 2, 1, 3), float(scale), bool(causal),
+                   int(block_q), int(block_k), bool(interpret),
+                   int(window) if window is not None else None)
+        return o.transpose(0, 2, 1, 3)
+
+    return run_partitioned(
+        kernel, q, k, v, None if interpret else mesh_partition(b, h, hkv))
 
 
 # ===================================================================== #
@@ -562,7 +618,7 @@ def flash_attention_folded_usable(q, k, v, num_heads, num_kv_heads,
         return False
     if sq * sk < 128 * 128:
         return False
-    return _on_tpu()
+    return on_tpu()
 
 
 def _fwd_kernel_folded_onepass(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
@@ -990,11 +1046,18 @@ def flash_attention_folded(q, k, v, *, num_heads: int,
         raise ValueError(
             f"seq lengths ({sq},{sk}) must divide blocks ({block_q},{block_k})")
     if interpret is None:
-        interpret = not _on_tpu()
-    return _flash_folded(q, k, v, int(num_heads), int(hkv), float(scale),
-                         bool(causal), int(block_q), int(block_k),
-                         bool(interpret),
-                         int(window) if window is not None else None)
+        interpret = not on_tpu()
+
+    def kernel(q, k, v, head_shards):
+        return _flash_folded(
+            q, k, v, int(num_heads) // head_shards, int(hkv) // head_shards,
+            float(scale), bool(causal), int(block_q), int(block_k),
+            bool(interpret), int(window) if window is not None else None)
+
+    return run_partitioned(
+        kernel, q, k, v, None if interpret else mesh_partition(
+            b, num_heads, hkv, lambda n: folded_heads_per_block(
+                num_heads // n, hkv // n, d) is not None))
 
 
 # ===================================================================== #
@@ -1068,7 +1131,7 @@ def flash_attention_paired_usable(q, k, v, num_heads, num_kv_heads,
         return False
     if sq * sk < 128 * 128:
         return False
-    return _on_tpu()
+    return on_tpu()
 
 
 def _lane_iota(rows: int):
@@ -1572,11 +1635,18 @@ def flash_attention_paired(q, k, v, *, num_heads: int,
         raise ValueError(
             f"seq lengths ({sq},{sk}) must divide blocks ({block_q},{block_k})")
     if interpret is None:
-        interpret = not _on_tpu()
-    return _flash_paired(q, k, v, int(num_heads), int(hkv), float(scale),
-                         bool(causal), int(block_q), int(block_k),
-                         bool(interpret),
-                         int(window) if window is not None else None)
+        interpret = not on_tpu()
+
+    def kernel(q, k, v, head_shards):
+        return _flash_paired(
+            q, k, v, int(num_heads) // head_shards, int(hkv) // head_shards,
+            float(scale), bool(causal), int(block_q), int(block_k),
+            bool(interpret), int(window) if window is not None else None)
+
+    return run_partitioned(
+        kernel, q, k, v, None if interpret else mesh_partition(
+            b, num_heads, hkv, lambda n: paired_heads_per_block(
+                num_heads // n, hkv // n, d) is not None))
 
 
 # ===================================================================== #

@@ -41,12 +41,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # noqa: BLE001
-        return False
+from deepspeed_tpu.utils.platform import on_tpu
 
 
 # --------------------------------------------------------------------- #
@@ -326,7 +321,7 @@ def _use_kernel(interpret, m, n, tile_m, tile_n) -> Tuple[bool, bool]:
     if m % tile_m != 0 or n % tile_n != 0:
         return False, False
     if interpret is None:
-        return (True, False) if _on_tpu() else (False, False)
+        return (True, False) if on_tpu() else (False, False)
     return True, bool(interpret)
 
 

@@ -4,15 +4,21 @@ cached artifact; here g++ → shared object consumed over ctypes instead of a
 torch extension).
 
 Builds ``csrc/host_ops.cpp`` (vectorized host optimizers + AIO threadpool)
-into ``build/libds_host_ops.so`` on first use. ``available()`` gates the
-callers; everything has a numpy fallback so the framework works without a
-toolchain.
+into ``build/libds_host_ops.<key>.so`` on first use, where ``<key>`` hashes
+the source text, the compiler flags and this host's CPU identification —
+``-march=native`` bakes the build machine's instruction set into the
+object, and ``build/`` can travel with a copied tree, so an artefact made
+from other source, other flags or on another CPU is never loaded.
+``available()`` gates the callers; everything has a numpy fallback so the
+framework works without a toolchain.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
+import platform
 import subprocess
 import threading
 from typing import Optional
@@ -24,7 +30,7 @@ _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
 _SRC = os.path.join(_REPO_ROOT, "csrc", "host_ops.cpp")
 _BUILD_DIR = os.environ.get(
     "DS_BUILD_DIR", os.path.join(_REPO_ROOT, "build"))
-_LIB_PATH = os.path.join(_BUILD_DIR, "libds_host_ops.so")
+_BASE_FLAGS = ["-O3", "-std=c++17", "-shared", "-fPIC", "-fopenmp"]
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -34,33 +40,64 @@ _i64 = ctypes.c_int64
 _f32p = ctypes.POINTER(ctypes.c_float)
 
 
+def _host_cpu_id() -> str:
+    """What ``-march=native`` depends on: the CPU model and its feature
+    flags (first processor entry of /proc/cpuinfo), else the platform's
+    own description."""
+    try:
+        with open("/proc/cpuinfo") as f:
+            seen = {}
+            for line in f:
+                key = line.split(":", 1)[0].strip()
+                if key in ("model name", "flags", "Features") \
+                        and key not in seen:
+                    seen[key] = line.strip()
+            if seen:
+                return platform.machine() + "|" + "|".join(
+                    seen[k] for k in sorted(seen))
+    except OSError:
+        pass
+    return platform.machine() + "|" + platform.processor()
+
+
+def _lib_path(flags) -> str:
+    h = hashlib.sha256()
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    h.update(" ".join(flags).encode())
+    h.update(_host_cpu_id().encode())
+    return os.path.join(_BUILD_DIR,
+                        f"libds_host_ops.{h.hexdigest()[:16]}.so")
+
+
 def _compile() -> Optional[str]:
     os.makedirs(_BUILD_DIR, exist_ok=True)
-    if os.path.exists(_LIB_PATH) and \
-            os.path.getmtime(_LIB_PATH) >= os.path.getmtime(_SRC):
-        return _LIB_PATH
-    # Build to a per-process temp path and rename atomically: N local ranks
-    # may race here (the threading lock is per-process only), and a
-    # concurrent truncate of a dlopen'd .so is a SIGBUS.
-    tmp = f"{_LIB_PATH}.tmp.{os.getpid()}"
-    cmd = ["g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-fopenmp",
-           "-march=native", _SRC, "-o", tmp, "-lpthread"]
-    try:
-        r = subprocess.run(cmd, capture_output=True, text=True, timeout=300)
-    except (OSError, subprocess.TimeoutExpired) as e:  # no toolchain
-        logger.warning(f"native host ops unavailable (g++ failed: {e})")
-        return None
-    if r.returncode != 0:
-        # retry without -march=native (portability)
-        cmd.remove("-march=native")
-        r = subprocess.run(cmd, capture_output=True, text=True, timeout=300)
-        if r.returncode != 0:
-            logger.warning(
-                f"native host ops build failed:\n{r.stderr[-1000:]}")
+    # -march=native first; without it as the portable retry.  Each flag
+    # set has its own artefact name, so a hit is always this source built
+    # with these flags for this CPU.
+    err = ""
+    for flags in (_BASE_FLAGS + ["-march=native"], _BASE_FLAGS):
+        lib = _lib_path(flags)
+        if os.path.exists(lib):
+            return lib
+        # Build to a per-process temp path and rename atomically: N local
+        # ranks may race here (the threading lock is per-process only),
+        # and a concurrent truncate of a dlopen'd .so is a SIGBUS.
+        tmp = f"{lib}.tmp.{os.getpid()}"
+        cmd = ["g++", *flags, _SRC, "-o", tmp, "-lpthread"]
+        try:
+            r = subprocess.run(cmd, capture_output=True, text=True,
+                               timeout=300)
+        except (OSError, subprocess.TimeoutExpired) as e:  # no toolchain
+            logger.warning(f"native host ops unavailable (g++ failed: {e})")
             return None
-    os.replace(tmp, _LIB_PATH)
-    logger.info(f"built native host ops -> {_LIB_PATH}")
-    return _LIB_PATH
+        if r.returncode == 0:
+            os.replace(tmp, lib)
+            logger.info(f"built native host ops -> {lib}")
+            return lib
+        err = r.stderr
+    logger.warning(f"native host ops build failed:\n{err[-1000:]}")
+    return None
 
 
 def _bind(lib: ctypes.CDLL) -> ctypes.CDLL:

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from deepspeed_tpu.ops.flash_attention import _on_tpu
+from deepspeed_tpu.utils.platform import on_tpu
 
 
 def is_quant_record(leaf) -> bool:
@@ -139,7 +139,7 @@ def quantized_matmul(x: jnp.ndarray, record, tile_n: int = 256,
     # size heuristic regardless of how interpret was spelled
     tiles_ok = rpg_tile is not None and n % tile_n == 0
     run_kernel = tiles_ok and (
-        interpret is True or (m >= 64 and _on_tpu()))
+        interpret is True or (m >= 64 and on_tpu()))
     if not run_kernel:
         return x @ dequant_reference(record, x.dtype)
     # pad M to the bf16 sublane multiple

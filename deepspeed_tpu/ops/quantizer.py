@@ -33,6 +33,8 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from deepspeed_tpu.utils.platform import on_tpu
+
 __all__ = [
     "quantize", "dequantize", "fake_quantize", "stochastic_quantize",
     "quantized_reduce", "swizzle_quant", "pack_int4", "unpack_int4",
@@ -237,11 +239,7 @@ def quantize_pallas(x: jnp.ndarray, num_groups: int):
     Falls back to :func:`quantize` off-TPU (the jnp form is one XLA fusion
     there anyway).
     """
-    try:
-        platform = jax.devices()[0].platform
-    except Exception:  # noqa: BLE001
-        platform = "cpu"
-    if platform != "tpu":
+    if not on_tpu():
         q, s, _ = quantize(x, num_groups, 8, True)
         return q, s
     return _quantize_kernel_call(_group(x, num_groups))

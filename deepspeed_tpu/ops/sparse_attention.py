@@ -332,9 +332,9 @@ class SparseSelfAttention:
             return False
         if self.implementation == "pallas":
             return True
-        from deepspeed_tpu.ops.block_sparse_attention import _on_tpu
+        from deepspeed_tpu.utils.platform import on_tpu
 
-        return _on_tpu()
+        return on_tpu()
 
     def __call__(self, query, key, value, key_padding_mask=None):
         s = query.shape[2]

@@ -141,7 +141,6 @@ class MeshTopology:
 
     def __init__(self, dims: ParallelDims, devices: Optional[Sequence[Any]] = None):
         import jax
-        from jax.sharding import Mesh
 
         if devices is None:
             devices = jax.devices()
@@ -152,13 +151,9 @@ class MeshTopology:
         # propagating/resolving shardings between the annotated state specs
         # (the Explicit default would demand manual resolution at every dot).
         axis_types = (jax.sharding.AxisType.Auto,) * len(MESH_AXES)
-        try:
-            # make_mesh picks an ICI-friendly device assignment on TPU.
-            self.mesh = jax.make_mesh(shape, MESH_AXES, devices=devices,
-                                      axis_types=axis_types)
-        except TypeError:
-            device_array = np.asarray(devices).reshape(shape)
-            self.mesh = Mesh(device_array, MESH_AXES, axis_types=axis_types)
+        # make_mesh picks an ICI-friendly device assignment on TPU.
+        self.mesh = jax.make_mesh(shape, MESH_AXES, devices=devices,
+                                  axis_types=axis_types)
 
     # ------------------------------------------------------------------ #
     # Axis algebra

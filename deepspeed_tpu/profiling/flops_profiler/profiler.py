@@ -29,10 +29,7 @@ from deepspeed_tpu.utils.logging import log_dist, logger
 
 def _cost_analysis(compiled) -> Dict[str, float]:
     try:
-        ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):  # older jax returns [dict]
-            ca = ca[0] if ca else {}
-        return dict(ca or {})
+        return dict(compiled.cost_analysis() or {})
     except Exception as e:  # pragma: no cover
         logger.warning(f"cost_analysis unavailable: {e}")
         return {}

@@ -73,6 +73,15 @@ def _shapes_match(args, shapes) -> bool:
         return False
 
 
+def _abstract(x) -> jax.ShapeDtypeStruct:
+    """Shape/dtype of a program input, with its sharding where the array
+    is committed to one (an uncommitted scalar such as ``lr`` follows the
+    other arguments; pinning it to its current single device would make
+    the re-lowering on a multi-device mesh inconsistent)."""
+    sharding = x.sharding if getattr(x, "committed", False) else None
+    return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
+
+
 def _as_model_fns(model, loss_fn) -> Tuple[Callable, Callable]:
     """Normalise a model into (init_fn, apply_fn).
 
@@ -1055,9 +1064,8 @@ class DeepSpeedEngine:
         every micro step IS a boundary (gas=1) and no phase/placement
         machinery needs a host hop between gradient and update (offload
         transfers, 1-bit phase switch, ZeRO++ manual micro, flops-profiler
-        AOT bookkeeping). Halves the per-step dispatch count — significant
-        over remote-tunnel backends — and lets XLA overlap the optimizer
-        with the backward tail."""
+        AOT bookkeeping). Halves the per-step dispatch count and lets XLA
+        overlap the optimizer with the backward tail."""
         zc = self.config.zero_config
         return (self.config.fuse_optimizer_step
                 and self.config.gradient_accumulation_steps == 1
@@ -1244,10 +1252,7 @@ class DeepSpeedEngine:
                 # re-lower this exact program later without holding (or
                 # donating) live state
                 self._fused_in_shapes = jax.tree.map(
-                    lambda x: jax.ShapeDtypeStruct(
-                        x.shape, x.dtype,
-                        sharding=getattr(x, "sharding", None)),
-                    (self.state, lr, rng) + args)
+                    _abstract, (self.state, lr, rng) + args)
             self.timers(FORWARD_MICRO_TIMER).start()
             self.state, loss, gnorm, overflow = self._jit_fused(
                 self.state, lr, rng, *args)
@@ -1261,10 +1266,7 @@ class DeepSpeedEngine:
         inputs = (self.state["params"], self.state["acc_grads"],
                   self.state["loss_scale"], rng) + args
         if self._micro_in_shapes is None:
-            self._micro_in_shapes = jax.tree.map(
-                lambda x: jax.ShapeDtypeStruct(
-                    x.shape, x.dtype, sharding=getattr(x, "sharding", None)),
-                inputs)
+            self._micro_in_shapes = jax.tree.map(_abstract, inputs)
         micro_fn = self._jit_micro
         if self.config.flops_profiler.enabled:
             # AOT-compile once and reuse the executable for both execution
@@ -1440,6 +1442,19 @@ class DeepSpeedEngine:
                 ranks=[0])
         self._skipped_steps_logged = skipped
 
+    def lower_train_step(self):
+        """The program ``forward`` dispatches — the fused
+        loss+grads+optimizer step, or the micro (loss+grads) program
+        when the step cannot fuse — lowered from the input shapes its
+        first call recorded.  Abstract: no live state is touched or
+        donated."""
+        if self._fused_in_shapes is not None:
+            return self._jit_fused.lower(*self._fused_in_shapes)
+        if self._micro_in_shapes is not None:
+            return self._jit_micro.lower(*self._micro_in_shapes)
+        raise RuntimeError(
+            "lower_train_step: no train program yet — run a step first")
+
     def capture_memory_ledger(self, ledger=None):
         """HLO memory ledger of this engine's compiled train programs
         (``memory_analysis`` + ``cost_analysis`` per program).
@@ -1464,19 +1479,17 @@ class DeepSpeedEngine:
             if self._micro_compiled is not None:
                 led.record("train_micro", self._micro_compiled, meta=meta)
                 recorded = True
-            elif self._jit_micro is not None \
-                    and self._micro_in_shapes is not None:
-                led.record("train_micro", self._jit_micro.lower(
-                    *self._micro_in_shapes).compile(), meta=meta)
+            elif self._micro_in_shapes is not None:
+                led.record("train_micro",
+                           self.lower_train_step().compile(), meta=meta)
                 recorded = True
             if self._apply_compiled is not None:
                 led.record("optimizer_apply", self._apply_compiled,
                            meta=meta)
                 recorded = True
-            if self._jit_fused is not None \
-                    and self._fused_in_shapes is not None:
-                led.record("train_fused_step", self._jit_fused.lower(
-                    *self._fused_in_shapes).compile(), meta=meta)
+            if self._fused_in_shapes is not None:
+                led.record("train_fused_step",
+                           self.lower_train_step().compile(), meta=meta)
                 recorded = True
         except Exception as e:  # noqa: BLE001 — absence is a record
             led.record_unavailable("train_step",

@@ -1,0 +1,175 @@
+"""chip_smoke.py's CPU dry run, and the bring-up rules it rests on: no
+fallback that hides the device, one process per chip, a compile cache that
+can be placed from outside.
+
+The chip run itself (Mistral-7B widths, Mosaic-compiled kernels) only
+happens through the chip tool; here the same phases run at ``mistral_tiny``
+size on the 8-device CPU mesh with the chip check waived explicitly — which
+the command line cannot do.
+"""
+
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+_REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+sys.path.insert(0, _REPO)
+
+import chip_smoke  # noqa: E402
+
+
+def _tiny_sizes():
+    from deepspeed_tpu.models.mistral import mistral_tiny
+
+    return chip_smoke.SmokeSizes(
+        model_config=mistral_tiny(dtype=jnp.bfloat16,
+                                  max_position_embeddings=256),
+        train_layers=2, train_seq=64, train_steps=5,
+        serve_layers=2, block_size=8, token_budget=32, max_seqs=8,
+        prompt_lens=(5, 9, 17, 30, 44, 61, 75, 90),
+        new_tokens=(8, 6, 4, 8, 6, 4, 8, 6),
+        check_prompt_len=24)
+
+
+def test_smoke_phases_tiny_on_cpu_mesh(monkeypatch):
+    """Train and serve phases end to end on the virtual mesh: ZeRO-3 x TP
+    training with falling loss and no recompilation, tensor-parallel
+    serving of mixed-length requests, shards on every device."""
+    placed = []
+    monkeypatch.setattr("deepspeed_tpu.utils.compile_cache."
+                        "enable_compile_cache",
+                        lambda: placed.append(1) or "(not placed in tests)")
+    out = chip_smoke.run(_tiny_sizes(), require_chip=False,
+                         phases=("train", "serve"))
+    assert out["ok"] and placed == [1]
+    assert out["device"] == {"platform": "cpu", "kind": "cpu", "count": 8}
+
+    train = out["train"]
+    assert train["mesh"] == {"data": 4, "model": 2}
+    assert train["zero_stage"] == 3 and train["steps"] == 6
+    assert train["losses"][-1] < train["losses"][0]
+    assert train["loss_route_diff"] <= chip_smoke.TRAIN_LOSS_TOL
+    held = train["state_shards"]["per_device_bytes"]
+    assert len(held) == 8 and max(held) <= 2 * min(held) and min(held) > 0
+
+    serve = out["serve"]
+    assert serve["tensor_parallel"] == 2          # 2 KV heads divide by 2
+    assert serve["requests"] == 16
+    assert serve["logit_err_vs_xla"] <= chip_smoke.SERVE_LOGIT_TOL
+    assert "decode_step" in serve["attention_route"]
+    assert any(k.startswith("prefill_T") for k in serve["attention_route"])
+    assert len(serve["ttft_s"]) == 8 and len(serve["tpot_s"]) == 8
+    for tree in serve["state_shards"].values():
+        assert len(tree["per_device_bytes"]) == 2
+        assert min(tree["per_device_bytes"]) > 0
+
+
+def test_smoke_gates_fail_loudly():
+    """The checks that tell a chip run from a CPU or interpreter run."""
+    with pytest.raises(chip_smoke.SmokeFailure, match="XLA composition"):
+        chip_smoke.attention_route("func.func @main() { stablehlo.dot }",
+                                   True, "step")
+    text = ('%1 = stablehlo.custom_call @tpu_custom_call(%0) '
+            '{backend_config = "...", kernel_name = "_fwd_kernel"}')
+    assert chip_smoke.attention_route(text, True, "step") == \
+        {"_fwd_kernel": 1}
+    # everything on the first device, or replicated everywhere, fails
+    devs = jax.devices()[:2]
+    one = {"w": jax.device_put(jnp.ones((64, 64)), devs[0])}
+    with pytest.raises(chip_smoke.SmokeFailure, match="no shard"):
+        chip_smoke.shard_report(one, devs, "state")
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(devs, ("model",))
+    rep = {"w": jax.device_put(jnp.ones((64, 64)), NamedSharding(mesh, P()))}
+    with pytest.raises(chip_smoke.SmokeFailure, match="replicated"):
+        chip_smoke.shard_report(rep, devs, "state")
+    split = {"w": jax.device_put(jnp.ones((64, 64)),
+                                 NamedSharding(mesh, P("model")))}
+    assert chip_smoke.shard_report(split, devs, "state")[
+        "per_device_bytes"] == [8192, 8192]
+
+
+def test_cli_refuses_to_run_without_a_chip():
+    """``python chip_smoke.py`` on the CPU: non-zero within seconds, naming
+    the platform it found, and no result line."""
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    r = subprocess.run([sys.executable, os.path.join(_REPO, "chip_smoke.py")],
+                       env=env, capture_output=True, text=True, timeout=120)
+    assert r.returncode != 0
+    assert "platform=cpu" in r.stdout
+    assert "no TPU" in r.stderr and "'cpu'" in r.stderr
+    for line in r.stdout.splitlines():
+        assert not line.startswith("{"), line     # no JSON result
+
+
+def test_on_tpu_lets_backend_errors_propagate(monkeypatch):
+    """A backend that fails to start is an error, not 'not a TPU'."""
+    from deepspeed_tpu.utils.platform import on_tpu
+
+    assert on_tpu() is False                      # the CPU mesh
+
+    def broken(*_a, **_k):
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+
+    monkeypatch.setattr(jax, "devices", broken)
+    with pytest.raises(RuntimeError, match="Unable to initialize"):
+        on_tpu()
+    from deepspeed_tpu.accelerator import real_accelerator
+
+    monkeypatch.delenv("DS_ACCELERATOR", raising=False)
+    with pytest.raises(RuntimeError, match="Unable to initialize"):
+        real_accelerator._detect()
+
+
+def test_compile_cache_is_placeable_from_outside(monkeypatch):
+    from deepspeed_tpu.utils.compile_cache import enable_compile_cache
+
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda k, v: updates.append((k, v)))
+    # placed from outside: JAX reads the variable itself, code sets nothing
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/somewhere/else")
+    assert enable_compile_cache() == "/somewhere/else"
+    assert updates == []
+    # not placed: a fixed path under the checkout, never a temporary one
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    want = os.path.join(_REPO, ".jax_cache")
+    assert enable_compile_cache() == want
+    assert updates == [("jax_compilation_cache_dir", want)]
+
+
+def test_one_process_per_chip_refusals(monkeypatch, tmp_path):
+    """On a TPU host nothing starts several chip-needing children: the
+    launcher and the fleet's worker mode raise instead of hanging on a held
+    chip, and the autotuner refuses ``isolate``."""
+    from deepspeed_tpu.utils import platform
+
+    monkeypatch.setattr(platform, "tpu_host", lambda: True)
+    with pytest.raises(RuntimeError, match="One process drives all local"):
+        platform.refuse_chip_children(2, {}, "launcher")
+    platform.refuse_chip_children(1, {}, "launcher")
+    platform.refuse_chip_children(2, {"JAX_PLATFORMS": "cpu"}, "launcher")
+
+    from deepspeed_tpu.fleet.worker import FleetFrontEnd
+
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    with pytest.raises(RuntimeError, match="FleetFrontEnd worker mode"):
+        FleetFrontEnd(lambda name, spool: [sys.executable, "-c", "pass"],
+                      n_replicas=2, run_dir=str(tmp_path))
+
+    from deepspeed_tpu.autotuning.autotuner import Autotuner
+
+    monkeypatch.setattr(platform, "on_tpu", lambda: True)
+    tuner = Autotuner(model=None, base_config={},
+                      sample_batch_fn=lambda n: (), isolate=True,
+                      results_dir=str(tmp_path / "results"))
+    with pytest.raises(RuntimeError, match="isolate=True"):
+        tuner.tune()
+    assert not (tmp_path / "results").exists()

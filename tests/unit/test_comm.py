@@ -119,8 +119,7 @@ def test_host_api():
 
 
 # ------------------------------------------------------------------ #
-# barrier(timeout=) + the uninitialized-collective guard (no shard_map
-# dependence: these run on the jax-0.4.37 host too)
+# barrier(timeout=) + the uninitialized-collective guard
 # ------------------------------------------------------------------ #
 def test_barrier_timeout_raises_instead_of_deadlocking(monkeypatch):
     import time

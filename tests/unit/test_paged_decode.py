@@ -86,3 +86,52 @@ def test_paged_decode_gqa_grouping():
                             use_kernel=False)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                atol=5e-3, rtol=1e-2)
+
+
+@pytest.mark.parametrize("hkv", [2, 8])
+@pytest.mark.parametrize("window", [None, 100])
+def test_paged_decode_and_verify_int8_match_dequantized_reference(hkv,
+                                                                   window):
+    """int8 pools: the kernels walk the flattened-lane [bs, Hkv*D] payload
+    and apply the pre-gathered [Hkv, bs] scales around the dots (the
+    layout Mosaic compiles; the chip's selftest runs the same cases
+    compiled).  Same pools, same scales as the XLA path over explicitly
+    dequantized pools, so any difference is the kernel's arithmetic."""
+    from deepspeed_tpu.inference.v2.kernels.blocked_flash import (
+        paged_verify_attention)
+    from deepspeed_tpu.inference.v2.ragged.kv_cache import (dequantize_kv,
+                                                            quantize_kv)
+
+    q, k_pool, v_pool, tables = _setup(3, hkv=hkv)
+    kq, ks = quantize_kv(k_pool)
+    vq, vs = quantize_kv(v_pool)
+    kd = dequantize_kv(kq, ks, jnp.float32)
+    vd = dequantize_kv(vq, vs, jnp.float32)
+    token_pos = jnp.asarray([200, 317, -1, 450], jnp.int32)
+    token_slot = jnp.arange(4, dtype=jnp.int32)
+    batch = {"block_tables": tables, "token_slot": token_slot,
+             "token_pos": token_pos}
+    got = paged_decode_attention(q, kq, vq, tables, token_slot, token_pos,
+                                 block_size=BS, window=window,
+                                 k_scale=ks, v_scale=vs, interpret=True)
+    want = _paged_attention(q, kd, vd, batch, BS, use_kernel=False,
+                            window=window)
+    assert float(jnp.max(jnp.abs(got[2]))) == 0.0       # pad row
+    for i in (0, 1, 3):
+        np.testing.assert_allclose(np.asarray(got[i]), np.asarray(want[i]),
+                                   atol=5e-3, rtol=1e-2)
+
+    K = 4
+    qv = jax.random.normal(jax.random.key(9), (4 * K, 8, 128), jnp.float32)
+    vslot = jnp.repeat(token_slot, K)
+    vpos = (jnp.asarray([200, 317, 64, 450], jnp.int32)[:, None]
+            + jnp.arange(K, dtype=jnp.int32)[None, :]).reshape(-1)
+    vbatch = {"block_tables": tables, "token_slot": vslot,
+              "token_pos": vpos}
+    gotv = paged_verify_attention(qv, kq, vq, tables, vslot, vpos,
+                                  block_size=BS, k_tokens=K, window=window,
+                                  k_scale=ks, v_scale=vs, interpret=True)
+    wantv = _paged_attention(qv, kd, vd, vbatch, BS, use_kernel=False,
+                             window=window)
+    np.testing.assert_allclose(np.asarray(gotv), np.asarray(wantv),
+                               atol=5e-3, rtol=1e-2)

@@ -6,8 +6,7 @@ auto-resume smoke tool.
 
 Everything runs single-device CPU: the corruption matrix drives the REAL
 ``save_engine_state`` / ``load_engine_state`` paths through the smoke
-tool's ``MiniEngine`` (no ``jax.shard_map`` dependence — the jax-0.4.37
-host constraint from CHANGES.md).
+tool's ``MiniEngine``.
 """
 
 import csv

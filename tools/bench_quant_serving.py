@@ -6,6 +6,9 @@ import numpy as np
 
 
 def main():
+    from deepspeed_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     import jax
     import jax.numpy as jnp
 

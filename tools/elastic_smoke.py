@@ -5,7 +5,7 @@ when capacity CANNOT arrive the brownout ladder degrades quality instead
 of letting the fleet fall over.
 
 Four variants over the same tiny-Llama serving workload (single-device
-engines per the jax-0.4.37 host constraint — no mesh APIs):
+engines):
 
 **soak** — a diurnal open-loop trace (two day/night swings) replayed
 against a 1-replica in-process :class:`ServingFleet` wearing the full

@@ -4,7 +4,7 @@ and that its defense-in-depth layer contains hostile inputs and sick
 replicas instead of cascading.
 
 Five variants over the same tiny-Llama serving workload (single-device
-engines per the jax-0.4.37 host constraint — no mesh APIs):
+engines):
 
 **kill** — a 2-replica fleet of REAL subprocess workers
 (:func:`deepspeed_tpu.fleet.worker.run_replica_worker`, each under its

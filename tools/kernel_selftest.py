@@ -4,7 +4,8 @@ interpret divergence would go unseen).
 
 Runs each compiled kernel on the REAL device against its jnp reference at
 small-but-representative shapes and reports max abs error per kernel.
-``bench.py`` embeds the result in the driver-captured JSON; standalone:
+``chip_smoke.py`` runs it as a gate (any case not ``ok`` fails the smoke);
+``bench.py`` embeds the result in its JSON; standalone:
 
     python tools/kernel_selftest.py
 
@@ -263,6 +264,25 @@ def run_selftest(tol: float = 3e-2) -> dict:
                                k_scale=ks8, v_scale=vs8,
                                interpret=False), want8))
 
+    # the same decode at Mistral's head counts (32 q / 8 kv): the int8
+    # tile and scale shapes Mosaic sees depend on the kv-head count
+    ks3 = jax.random.split(jax.random.fold_in(key, 12), 3)
+    kq8h, ks8h = quantize_kv(jax.random.normal(
+        ks3[0], (pool_rows, 8, 128), jnp.bfloat16))
+    vq8h, vs8h = quantize_kv(jax.random.normal(
+        ks3[1], (pool_rows, 8, 128), jnp.bfloat16))
+    q3 = jax.random.normal(ks3[2], (S, 32, 128), jnp.bfloat16)
+    want8h = _paged_attention(
+        q3, dequantize_kv(kq8h, ks8h, jnp.float32),
+        dequantize_kv(vq8h, vs8h, jnp.float32), batch, bs,
+        use_kernel=False)
+    guarded("paged_decode_dma_int8_hkv8", lambda: record(
+        "paged_decode_dma_int8_hkv8",
+        paged_decode_attention(q3, kq8h, vq8h, tables, token_slot,
+                               token_pos, block_size=bs,
+                               k_scale=ks8h, v_scale=vs8h,
+                               interpret=False), want8h))
+
     wantv8 = _paged_attention(qv, kd8, vd8, vbatch, bs, use_kernel=False)
     guarded("paged_verify_multiquery_int8", lambda: record(
         "paged_verify_multiquery_int8",
@@ -381,6 +401,9 @@ if __name__ == "__main__":
 
     sys.path.insert(0, os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
+    from deepspeed_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     out = run_selftest()
     print(json.dumps(out, indent=2))
     sys.exit(0 if out.get("ok") else 1)

@@ -11,8 +11,8 @@ mechanical sweep, not a hand-assembled sequence of bench commands:
     python tools/perf_matrix.py --run --only offload_pipelined_ab
 
 ``--run`` executes each selected row's bench in a subprocess, parses
-the LAST JSON line it prints (every bench driver in this repo emits
-exactly one record, with error fallbacks), gates it against any
+the LAST JSON line it prints (a bench prints exactly one record, or none
+and a non-zero exit when it fails or finds no chip), gates it against any
 matching-metric history records found in the repo's ``BENCH_*``/
 ``MULTICHIP_*`` files via :mod:`tools.perf_gate`, and prints one
 verdict line per row plus a final JSON summary.  Rows whose capability
@@ -55,6 +55,8 @@ class MatrixRow:
     timeout_s: float = 600.0
 
 
+_NEEDS_CHIP = "the benches refuse to run without a TPU"
+
 #: The matrix.  Geometry knobs live in the argv — a new milestone is a
 #: new row, not a new driver.
 ROWS: List[MatrixRow] = [
@@ -80,7 +82,8 @@ ROWS: List[MatrixRow] = [
                   "whole-tree offload boundary",
         metric="train_offload_pipelined_ab",
         argv=["bench.py", "--offload-ab"],
-        cpu_ok=True),
+        cpu_ok=False,
+        cpu_note=_NEEDS_CHIP),
     MatrixRow(
         name="train_7b_zero3_virtual_mesh",
         milestone="BASELINE: Llama-2 7B, ZeRO-3 + fused_adam, v5p-16",
@@ -96,7 +99,8 @@ ROWS: List[MatrixRow] = [
                   "geometry)",
         metric="fastgen_decode_tokens_per_sec_125m",
         argv=["bench_serving.py"],
-        cpu_ok=True,
+        cpu_ok=False,
+        cpu_note=_NEEDS_CHIP,
         timeout_s=900.0),
     MatrixRow(
         name="fastgen_7b_int8",
@@ -111,7 +115,8 @@ ROWS: List[MatrixRow] = [
                   "(decode A/B)",
         metric="serving_scheduler_goodput_tokens_per_sec",
         argv=["bench_serving.py", "--scheduler"],
-        cpu_ok=True,
+        cpu_ok=False,
+        cpu_note=_NEEDS_CHIP,
         timeout_s=900.0),
     MatrixRow(
         name="serving_session_mix",
@@ -119,14 +124,16 @@ ROWS: List[MatrixRow] = [
                   "tier)",
         metric="serving_session_mix_resident_sessions",
         argv=["bench_serving.py", "--session-mix"],
-        cpu_ok=True,
+        cpu_ok=False,
+        cpu_note=_NEEDS_CHIP,
         timeout_s=900.0),
     MatrixRow(
         name="serving_speculative",
         milestone="ROADMAP: speculative decode (draft-k acceptance)",
         metric="serving_speculative_decode_tokens_per_sec",
         argv=["bench_serving.py", "--speculative"],
-        cpu_ok=True,
+        cpu_ok=False,
+        cpu_note=_NEEDS_CHIP,
         timeout_s=900.0),
     MatrixRow(
         name="serving_fleet_disagg",
@@ -135,7 +142,8 @@ ROWS: List[MatrixRow] = [
         metric="serving_fleet_goodput_tokens_per_sec",
         argv=["bench_serving.py", "--fleet", "2",
               "--disaggregate", "1:1"],
-        cpu_ok=True,
+        cpu_ok=False,
+        cpu_note=_NEEDS_CHIP,
         timeout_s=900.0),
     MatrixRow(
         name="serving_gateway_replayed_burst",

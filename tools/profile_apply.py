@@ -13,8 +13,7 @@ from deepspeed_tpu.models import LlamaConfig, LlamaForCausalLM
 
 
 def sync(x):
-    leaf = jax.tree_util.tree_leaves(x)[0]
-    return jax.device_get(jnp.ravel(leaf)[0])
+    jax.block_until_ready(x)
 
 
 def timeit(fn, *args, iters=10):
@@ -28,6 +27,9 @@ def timeit(fn, *args, iters=10):
 
 
 def main():
+    from deepspeed_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     cfg_m = LlamaConfig(vocab_size=32000, hidden_size=768,
                         intermediate_size=2048, num_hidden_layers=12,
                         num_attention_heads=12, num_key_value_heads=12,

@@ -14,8 +14,7 @@ from deepspeed_tpu.ops.attention import dot_product_attention
 
 
 def sync(x):
-    leaf = jax.tree_util.tree_leaves(x)[0]
-    return jax.device_get(jnp.ravel(leaf)[0])
+    jax.block_until_ready(x)
 
 
 def timeit(fn, *args, iters=10):
@@ -29,6 +28,9 @@ def timeit(fn, *args, iters=10):
 
 
 def main():
+    from deepspeed_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     mb, seq = 8, 1024
     key = jax.random.key(0)
     q = jax.random.normal(key, (mb, seq, 12, 64), jnp.bfloat16)

@@ -13,8 +13,7 @@ from deepspeed_tpu.ops.attention import _xla_attention
 
 
 def sync(x):
-    leaf = jax.tree_util.tree_leaves(x)[0]
-    return jax.device_get(jnp.ravel(leaf)[0])
+    jax.block_until_ready(x)
 
 
 def timeit(fn, *args, iters=10):
@@ -50,6 +49,9 @@ def bench(name, attn):
 
 
 def main():
+    from deepspeed_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     for bq, bk in ((512, 1024), (512, 512), (256, 512), (256, 256),
                    (128, 256), (128, 128)):
         bench(f"ours bq={bq} bk={bk}",

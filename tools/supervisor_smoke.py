@@ -2,8 +2,7 @@
 loop end-to-end with 2 subprocess workers.
 
 Two variants over the same worker program (a single-device ``MiniEngine``
-training loop under :class:`ResilientTrainLoop` — the full engine needs
-mesh APIs this jax-0.4.37 host lacks, per CHANGES.md PR-1):
+training loop under :class:`ResilientTrainLoop`):
 
 **crash** — the parent SIGKILLs worker 0 mid-step (after at least one
 checkpoint has committed).  The supervisor sees the nonzero exit, tears
