@@ -32,7 +32,8 @@ from deepspeed_tpu.inference.v2.model_implementations.ragged_llama import (
 )
 from deepspeed_tpu.inference.v2.ragged import (DSStateManager,
                                                RaggedBatchWrapper)
-from deepspeed_tpu.observability.tracer import annotate
+from deepspeed_tpu.observability.tracer import SpanHandle, open_span
+from deepspeed_tpu.utils.compile_cache import key_cache_on_names
 from deepspeed_tpu.utils.logging import log_dist
 
 
@@ -97,12 +98,26 @@ def _pack_tables_positions(seqs, max_seqs: int, max_blocks: int):
     return tables, pos
 
 
+def _named(fn, name: str):
+    """``fn`` under the name its jitted program is to carry: the XLA
+    module (``jit_decode_step``), the profiler's module line and the head
+    of every ``op_name`` (``jit(decode_step)/layers_0/mlp/...``); all
+    step programs were ``jit(run)``."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
 class InferenceEngineV2:
     """reference engine_v2.py:30."""
 
     def __init__(self, model: RaggedLlama, params: Any,
                  config: Optional[RaggedInferenceEngineConfig] = None):
         self.config = config or RaggedInferenceEngineConfig()
+        # the step programs' scopes are read by name from a profile
+        key_cache_on_names()
+        #: the scheduler's Tracer (``attach_tracer``): the engine's spans
+        #: nest under whatever span of it is open (the tick's phase)
+        self.tracer = None
         sm_cfg = self.config.state_manager
         kv_cfg = self.config.kv_cache
         max_pos = getattr(model, "max_positions", None)
@@ -176,6 +191,10 @@ class InferenceEngineV2:
             f"max_seqs={sm_cfg.max_ragged_sequence_count} "
             f"kv_blocks={self.state_manager.allocator.num_blocks} "
             f"block_size={kv_cfg.block_size}", ranks=[0])
+
+    def attach_tracer(self, tracer) -> None:
+        """Record this engine's spans on ``tracer`` (None detaches)."""
+        self.tracer = tracer
 
     # ------------------------------------------------------------------ #
     # Scheduling predicates (reference can_schedule:181 / query:153)
@@ -292,7 +311,8 @@ class InferenceEngineV2:
                 return self.model(params, cache, batch,
                                   prefill_tile=prefill_tile)
 
-            step = jax.jit(run, donate_argnums=(1,))
+            step = jax.jit(_named(run, f"ragged_step_T{bucket}" + (
+                "_tiled" if prefill_tile else "")), donate_argnums=(1,))
             self._steps[key] = step
         return step
 
@@ -307,10 +327,11 @@ class InferenceEngineV2:
     #: 50% of the scheduled tokens
     PREFILL_TILE = 128
 
-    def _run_one_batch(self, uids, sync: bool = True) -> Dict[int, np.ndarray]:
-        """Build one ragged batch under the token budget (SplitFuse
-        chunking), run the jitted step, and return logits for slots whose
-        pending queue drained."""
+    def _build_batch(self, uids):
+        """One ragged batch under the token budget: SplitFuse chunking, KV
+        allocation, the metadata and its ONE upload.  Returns ``(scheduled
+        uids, drained flags, token bucket, prefill tile or None, packed
+        device metadata)``, or None when nothing is pending."""
         sm = self.state_manager
         self._batch.clear()
         # tiled-prefill mode: every live chunk long enough that aligning
@@ -341,7 +362,7 @@ class InferenceEngineV2:
             scheduled.append(uid)
             drained.append(len(chunk) == len(seq.pending))
         if not scheduled:
-            return {}
+            return None
 
         from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import (
             pack_metadata)
@@ -356,12 +377,28 @@ class InferenceEngineV2:
                          if b >= self._batch.current_tokens)
         meta = self._batch.finalize(bucket)
         packed = jnp.asarray(pack_metadata(meta))  # ONE upload
+        return scheduled, drained, bucket, tile if use_tiles else None, \
+            packed
+
+    def _run_one_batch(self, uids, sync: bool = True) -> Dict[int, np.ndarray]:
+        """Build one ragged batch under the token budget (SplitFuse
+        chunking), run the jitted step, and return logits for slots whose
+        pending queue drained."""
+        sm = self.state_manager
+        with open_span(self.tracer, "engine/build_batch") as span:
+            built = self._build_batch(uids)
+            if built is None:
+                return {}
+            scheduled, drained, bucket, tile, packed = built
+            if type(span) is SpanHandle:
+                # useful tokens of the bucket they are padded to
+                span.attrs = {"tokens": self._batch.current_tokens,
+                              "bucket": bucket}
         # host↔device alignment: a jax.profiler capture shows this named
         # bracket on the host track lined up with the XLA execution it
-        # dispatched (annotate() is a shared no-op unless enabled)
-        with annotate("engine/ragged_step"):
-            logits, new_cache = self._get_step(
-                bucket, tile if use_tiles else None)(
+        # dispatched
+        with open_span(self.tracer, "engine/ragged_step"):
+            logits, new_cache = self._get_step(bucket, tile)(
                 self.params, sm.kv_cache.cache, packed)
         sm.kv_cache.update(new_cache)
 
@@ -379,8 +416,9 @@ class InferenceEngineV2:
                     out[uid] = logits[slot]        # lazy device row
                     continue
                 if logits_host is None:
-                    logits_host = np.asarray(
-                        jax.device_get(logits), np.float32)
+                    with open_span(self.tracer, "engine/fetch_logits"):
+                        logits_host = np.asarray(
+                            jax.device_get(logits), np.float32)
                 out[uid] = logits_host[slot]
         return out
 
@@ -426,6 +464,40 @@ class InferenceEngineV2:
         n = len(uids)
         if n > S:
             raise ValueError(f"decode_step: {n} sequences exceed max_seqs {S}")
+        with open_span(self.tracer, "engine/decode_prep"):
+            seqs, state = self._prepare_decode(uids)
+            tok = self._as_token_array(tokens, n, S)
+        try:
+            with open_span(self.tracer, "engine/decode_step"):
+                logits, nxt, new_cache, new_pos = self._get_decode_step()(
+                    self.params, sm.kv_cache.cache, state["tables"],
+                    state["pos"], tok)
+        except Exception:
+            self._recover_donated_cache()
+            raise
+        sm.kv_cache.update(new_cache)
+        host_toks = (None if isinstance(tokens, jax.Array)
+                     else [int(t) for t in tokens])
+        for i, seq in enumerate(seqs):
+            if host_toks is not None:
+                sm.record_fed_tokens(seq, host_toks[i:i + 1])
+            seq.seen_tokens += 1
+            sm.register_prefix(seq)
+        # device positions advanced in lockstep with seen_tokens
+        self._dev_decode_state = {
+            "tables": state["tables"], "pos": new_pos,
+            "key": (tuple(uids), tuple(s.seen_tokens for s in seqs))}
+        if greedy:
+            return logits, nxt
+        return logits
+
+    def _prepare_decode(self, uids):
+        """The host's share of a decode step before its dispatch: one KV
+        slot per sequence (a new block when the last one is full) and,
+        only when an allocation changed a table or the batch is not the
+        one the device state describes, the upload of tables and
+        positions.  Returns ``(sequences, device state)``."""
+        sm = self.state_manager
         max_context = self.config.state_manager.max_context
         seqs = []
         tables_changed = False
@@ -446,34 +518,13 @@ class InferenceEngineV2:
             RAGGED_DEBUG, validate_ragged_metadata)
 
         if RAGGED_DEBUG:
-            validate_ragged_metadata(seqs, [np.empty(1)] * n, sm.block_size)
+            validate_ragged_metadata(seqs, [np.empty(1)] * len(seqs),
+                                     sm.block_size)
         state = self._dev_decode_state
         key = (tuple(uids), tuple(s.seen_tokens for s in seqs))
         if state is None or tables_changed or state["key"] != key:
             state = self._upload_decode_state(seqs, key)
-        try:
-            with annotate("engine/decode_step"):
-                logits, nxt, new_cache, new_pos = self._get_decode_step()(
-                    self.params, sm.kv_cache.cache, state["tables"],
-                    state["pos"], self._as_token_array(tokens, n, S))
-        except Exception:
-            self._recover_donated_cache()
-            raise
-        sm.kv_cache.update(new_cache)
-        host_toks = (None if isinstance(tokens, jax.Array)
-                     else [int(t) for t in tokens])
-        for i, seq in enumerate(seqs):
-            if host_toks is not None:
-                sm.record_fed_tokens(seq, host_toks[i:i + 1])
-            seq.seen_tokens += 1
-            sm.register_prefix(seq)
-        # device positions advanced in lockstep with seen_tokens
-        self._dev_decode_state = {
-            "tables": state["tables"], "pos": new_pos,
-            "key": (tuple(uids), tuple(s.seen_tokens for s in seqs))}
-        if greedy:
-            return logits, nxt
-        return logits
+        return seqs, state
 
     def _recover_donated_cache(self) -> None:
         """A jitted step that donates the KV cache raised after donation
@@ -521,10 +572,11 @@ class InferenceEngineV2:
         def run(params, cache, tables, pos, tok):
             batch = _device_decode_batch(tables, pos, tok, bs, B)
             logits, new_cache = self.model(params, cache, batch, decode=True)
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            with jax.named_scope("sample_argmax"):
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return logits, nxt, new_cache, pos + 1
 
-        runner = jax.jit(run, donate_argnums=(1, 3))
+        runner = jax.jit(_named(run, "decode_step"), donate_argnums=(1, 3))
         self._steps[key] = runner
         return runner
 
@@ -599,7 +651,7 @@ class InferenceEngineV2:
         packed = jnp.asarray(np.concatenate(
             [tables.ravel(), pos, tok.ravel()]))       # ONE upload
         try:
-            with annotate("engine/verify_step"):
+            with open_span(self.tracer, "engine/verify_step"):
                 logits, nxt, new_cache = self._get_verify_step(K)(
                     self.params, sm.kv_cache.cache, packed)
         except Exception:
@@ -669,10 +721,12 @@ class InferenceEngineV2:
             batch = _device_verify_batch(tables, pos, tok, bs, B, k_tokens)
             logits, new_cache = self.model(params, cache, batch, **kwargs)
             logits = logits.reshape(S, k_tokens, -1)
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            with jax.named_scope("sample_argmax"):
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return logits, nxt, new_cache
 
-        runner = jax.jit(run, donate_argnums=(1,))
+        runner = jax.jit(_named(run, f"verify_step_K{k_tokens}"),
+                         donate_argnums=(1,))
         self._steps[key] = runner
         return runner
 

@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from deepspeed_tpu.utils.platform import on_tpu
+from deepspeed_tpu.utils.platform import kernel_names, on_tpu
 
 NEG_INF = -1e30
 
@@ -300,6 +300,7 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_count, h, d), q.dtype),
         interpret=bool(interpret),
+        **kernel_names(kernel, op_name=False),
     )(slots, token_pos.astype(jnp.int32), tables, *operands)
 
 
@@ -499,6 +500,7 @@ def paged_verify_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((s_count, k_tokens * h, d),
                                        q.dtype),
         interpret=bool(interpret),
+        **kernel_names(kernel, op_name=False),
     )(slot0, pos0, tables, *operands)
     return out.reshape(t_count, h, d)
 
@@ -650,6 +652,7 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t_count, h * d), q.dtype),
         interpret=bool(interpret),
+        **kernel_names(kernel, op_name=False),
     )(tile_slot, tile_maxpos, block_tables.astype(jnp.int32), qf, pos8,
       kp, vp)
     return out.reshape(t_count, h, d)
@@ -716,6 +719,7 @@ def paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t_count, h, d), q.dtype),
         interpret=bool(interpret),
+        **kernel_names(kernel, op_name=False),
     )(token_slot.astype(jnp.int32), token_pos.astype(jnp.int32),
       block_tables.astype(jnp.int32), q, kp, vp)
 

@@ -190,73 +190,96 @@ def _paged_attention(q, k_pool, v_pool, batch, block_size,
                     q, k_pool, v_pool, batch["block_tables"],
                     batch["token_slot"], batch["token_pos"],
                     block_size=block_size, window=w)
+    S, B = batch["block_tables"].shape
+    if decode_mode and (force_dense if force_dense is not None
+                        else k_pool.shape[0] <= 2 * S * B * block_size):
+        with jax.named_scope("attn/dense_read"):
+            return _dense_pool_read(q, k_pool, v_pool, k_scale, v_scale,
+                                    batch, block_size, window)
+    with jax.named_scope("attn/gather_read"):
+        return _gather_read(q, k_pool, v_pool, k_scale, v_scale, batch,
+                            block_size, window, decode_mode)
+
+
+def _dense_pool_read(q, k_pool, v_pool, k_scale, v_scale, batch, block_size,
+                     window):
+    """The decode read on a tight pool (device scope ``attn/dense_read``)."""
+    from deepspeed_tpu.inference.v2.ragged.kv_cache import dequantize_kv
+
+    quantized = k_scale is not None
+    block_tables = batch["block_tables"]          # [S, B]
+    token_slot = batch["token_slot"]              # [T]
+    token_pos = batch["token_pos"]                # [T]
+    hkv = k_pool.shape[1]
+    group = q.shape[1] // hkv
+    # Masked DENSE attention over the whole pool: when the engine
+    # sizes the pool close to max_seqs * max_context (the serving-
+    # dense case), the live contexts cover most of it, so reading
+    # every pool row ONCE — no [T, C, Hkv, D] gather copy, no Pallas
+    # grid overhead — is the bandwidth-minimal program (measured
+    # 0.46 vs 1.7 ms/step for 12 layers of a 125M-GQA model on
+    # v5e).  Visibility is derived PER TOKEN against that token's
+    # own block table — NOT via a row->owner scatter, which breaks
+    # under the prefix cache where one warm block legitimately sits
+    # in several sequences' tables (last-write-wins ownership would
+    # mask a shared block out of every table but one).  The [T, B,
+    # rows] compare is decode-sized (T == S) and XLA CSE dedupes it
+    # across layers.  Pools much larger than the live contexts
+    # (rows > 2*S*C) take the gather path below instead, which is
+    # bounded by the block-table extent.
+    from deepspeed_tpu.inference.v2.ragged.blocked_allocator import (
+        BlockedAllocator)
+
+    trash = BlockedAllocator.TRASH_BLOCK
     if quantized:
-        # reference/CPU path (and quantized TPU prefill / non-128 head
-        # dims): dequantize at the READ site of each branch below, never
-        # the whole pool up front — the dense branch reads every pool
-        # row by design (pool ~ live), but the gather branch serves the
-        # pool >> live capacity regime where an O(pool) f32
-        # materialization would cost 4x the memory int8 just saved
-        from deepspeed_tpu.inference.v2.ragged.kv_cache import dequantize_kv
+        # pool-wide dequant matches this branch's pool-wide read
+        # (it only runs when rows <= 2*S*C, i.e. pool ~ live)
+        k_pool = dequantize_kv(k_pool, k_scale, jnp.float32)
+        v_pool = dequantize_kv(v_pool, v_scale, jnp.float32)
+    rows = k_pool.shape[0]
+    rowblk = jnp.arange(rows, dtype=jnp.int32) // block_size
+    rowoff = jnp.arange(rows, dtype=jnp.int32) % block_size
+    tbl = block_tables[token_slot]                         # [T, B]
+    match = tbl[:, :, None] == rowblk[None, None, :]       # [T, B, rows]
+    # absolute position of each visible row in ITS table slot
+    j_idx = jnp.argmax(match, axis=1).astype(jnp.int32)    # [T, rows]
+    row_pos = j_idx * block_size + rowoff[None, :]
+    qg = q.reshape(q.shape[0], hkv, group, q.shape[2])
+    scores = jnp.einsum("tkgd,rkd->tkgr", qg, k_pool,
+                        preferred_element_type=jnp.float32) / jnp.sqrt(
+        jnp.float32(q.shape[-1]))
+    keep = (jnp.any(match, axis=1)
+            & (row_pos <= token_pos[:, None])
+            & (rowblk != trash)[None, :])                  # [T, rows]
+    if window is not None:
+        keep &= row_pos > token_pos[:, None] - window
+    # FINITE mask value: a pad slot owns no rows, so -inf would
+    # softmax to NaN and poison the residual stream
+    scores = jnp.where(keep[:, None, None, :], scores, -1e30)
+    probs = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("tkgr,rkd->tkgd", probs.astype(v_pool.dtype),
+                     v_pool, preferred_element_type=jnp.float32)
+    return out.reshape(q.shape).astype(q.dtype)
+
+
+def _gather_read(q, k_pool, v_pool, k_scale, v_scale, batch, block_size,
+                 window, decode_mode):
+    """The XLA gather composition (device scope ``attn/gather_read``): the
+    reference/CPU path, large-pool decode off the kernels, quantized
+    prefill.  Quantized pools dequantize at the READ site, never the whole
+    pool up front: this branch serves the pool >> live capacity regime,
+    where an O(pool) f32 materialization would cost 4x the memory int8
+    just saved."""
+    from deepspeed_tpu.inference.v2.ragged.kv_cache import dequantize_kv
+
+    quantized = k_scale is not None
     block_tables = batch["block_tables"]          # [S, B]
     token_slot = batch["token_slot"]              # [T]
     token_pos = batch["token_pos"]                # [T]
     S, B = block_tables.shape
     C = B * block_size
-    h = q.shape[1]
     hkv = k_pool.shape[1]
-    group = h // hkv
-
-    if decode_mode and (force_dense if force_dense is not None
-                        else k_pool.shape[0] <= 2 * S * C):
-        # Masked DENSE attention over the whole pool: when the engine
-        # sizes the pool close to max_seqs * max_context (the serving-
-        # dense case), the live contexts cover most of it, so reading
-        # every pool row ONCE — no [T, C, Hkv, D] gather copy, no Pallas
-        # grid overhead — is the bandwidth-minimal program (measured
-        # 0.46 vs 1.7 ms/step for 12 layers of a 125M-GQA model on
-        # v5e).  Visibility is derived PER TOKEN against that token's
-        # own block table — NOT via a row->owner scatter, which breaks
-        # under the prefix cache where one warm block legitimately sits
-        # in several sequences' tables (last-write-wins ownership would
-        # mask a shared block out of every table but one).  The [T, B,
-        # rows] compare is decode-sized (T == S) and XLA CSE dedupes it
-        # across layers.  Pools much larger than the live contexts
-        # (rows > 2*S*C) take the gather path below instead, which is
-        # bounded by the block-table extent.
-        from deepspeed_tpu.inference.v2.ragged.blocked_allocator import (
-            BlockedAllocator)
-
-        trash = BlockedAllocator.TRASH_BLOCK
-        if quantized:
-            # pool-wide dequant matches this branch's pool-wide read
-            # (it only runs when rows <= 2*S*C, i.e. pool ~ live)
-            k_pool = dequantize_kv(k_pool, k_scale, jnp.float32)
-            v_pool = dequantize_kv(v_pool, v_scale, jnp.float32)
-        rows = k_pool.shape[0]
-        rowblk = jnp.arange(rows, dtype=jnp.int32) // block_size
-        rowoff = jnp.arange(rows, dtype=jnp.int32) % block_size
-        tbl = block_tables[token_slot]                         # [T, B]
-        match = tbl[:, :, None] == rowblk[None, None, :]       # [T, B, rows]
-        # absolute position of each visible row in ITS table slot
-        j_idx = jnp.argmax(match, axis=1).astype(jnp.int32)    # [T, rows]
-        row_pos = j_idx * block_size + rowoff[None, :]
-        qg = q.reshape(q.shape[0], hkv, group, q.shape[2])
-        scores = jnp.einsum("tkgd,rkd->tkgr", qg, k_pool,
-                            preferred_element_type=jnp.float32) / jnp.sqrt(
-            jnp.float32(q.shape[-1]))
-        keep = (jnp.any(match, axis=1)
-                & (row_pos <= token_pos[:, None])
-                & (rowblk != trash)[None, :])                  # [T, rows]
-        if window is not None:
-            keep &= row_pos > token_pos[:, None] - window
-        # FINITE mask value: a pad slot owns no rows, so -inf would
-        # softmax to NaN and poison the residual stream
-        scores = jnp.where(keep[:, None, None, :], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum("tkgr,rkd->tkgd", probs.astype(v_pool.dtype),
-                         v_pool, preferred_element_type=jnp.float32)
-        return out.reshape(q.shape).astype(q.dtype)
+    group = q.shape[1] // hkv
 
     # Gather each slot's context: [S, C, Hkv, D].  Context index == absolute
     # position because block tables are append-ordered.
@@ -323,9 +346,30 @@ def ragged_attention_block(lp_attn, xa, layer_cache, batch, block_size, cfg,
     Returns ``(attn_out [T, H_model], new_layer_cache)``."""
     dt = cfg.dtype
     kv_dest = batch["kv_dest"]
-    q = qmm(xa, lp_attn["q_proj"]["kernel"], dt).reshape(-1, h, d)
-    k = qmm(xa, lp_attn["k_proj"]["kernel"], dt).reshape(-1, hkv, d)
-    v = qmm(xa, lp_attn["v_proj"]["kernel"], dt).reshape(-1, hkv, d)
+    with jax.named_scope("attn/qkv"):
+        q = qmm(xa, lp_attn["q_proj"]["kernel"], dt).reshape(-1, h, d)
+        k = qmm(xa, lp_attn["k_proj"]["kernel"], dt).reshape(-1, hkv, d)
+        v = qmm(xa, lp_attn["v_proj"]["kernel"], dt).reshape(-1, hkv, d)
+    with jax.named_scope("attn/rope_insert"):
+        q, k_pool, v_pool, k_scale, v_scale, new_cache = _rope_insert(
+            q, k, v, cos, sin, layer_cache, kv_dest)
+    out = _paged_attention(q, k_pool, v_pool, batch, block_size,
+                           window=cfg.sliding_window,
+                           prefill_tile=prefill_tile,
+                           decode_mode=decode_mode, verify_k=verify_k,
+                           k_scale=k_scale, v_scale=v_scale)
+    with jax.named_scope("attn/out_proj"):
+        out = qmm(out.reshape(-1, h * d), lp_attn["o_proj"]["kernel"], dt)
+        if ax is not None:
+            out = jax.lax.psum(out, ax)               # row-parallel attn-out
+    return out, new_cache
+
+
+def _rope_insert(q, k, v, cos, sin, layer_cache, kv_dest):
+    """Rotary on q and k, then the paged-KV scatter of this step's k and v
+    (device scope ``attn/rope_insert``).  Returns ``(q, k_pool, v_pool,
+    k_scale, v_scale, new_layer_cache)``; the scales are None on a float
+    pool."""
     # apply_rotary broadcasts over [T, H, D] with cos/sin [T, 1, D/2]
     q = apply_rotary(q, cos, sin)
     k = apply_rotary(k, cos, sin)
@@ -334,8 +378,7 @@ def ragged_attention_block(lp_attn, xa, layer_cache, batch, block_size, cfg,
     # per-head scale scatter in the same step, so the cache is always
     # self-describing and every downstream reader (kernels, COW copy,
     # host spool, disaggregated handoff) sees one consistent record.
-    quantized = layer_cache["k"].dtype == jnp.int8
-    if quantized:
+    if layer_cache["k"].dtype == jnp.int8:
         from deepspeed_tpu.inference.v2.ragged.kv_cache import quantize_kv
 
         kq, ks = quantize_kv(k)
@@ -344,24 +387,13 @@ def ragged_attention_block(lp_attn, xa, layer_cache, batch, block_size, cfg,
         v_pool = layer_cache["v"].at[kv_dest].set(vq)
         k_scale = layer_cache["k_scale"].at[kv_dest].set(ks)
         v_scale = layer_cache["v_scale"].at[kv_dest].set(vs)
-        new_cache = {"k": k_pool, "v": v_pool,
-                     "k_scale": k_scale, "v_scale": v_scale}
-    else:
-        k_scale = v_scale = None
-        k_pool = layer_cache["k"].at[kv_dest].set(
-            k.astype(layer_cache["k"].dtype))
-        v_pool = layer_cache["v"].at[kv_dest].set(
-            v.astype(layer_cache["v"].dtype))
-        new_cache = {"k": k_pool, "v": v_pool}
-    out = _paged_attention(q, k_pool, v_pool, batch, block_size,
-                           window=cfg.sliding_window,
-                           prefill_tile=prefill_tile,
-                           decode_mode=decode_mode, verify_k=verify_k,
-                           k_scale=k_scale, v_scale=v_scale)
-    out = qmm(out.reshape(-1, h * d), lp_attn["o_proj"]["kernel"], dt)
-    if ax is not None:
-        out = jax.lax.psum(out, ax)                   # row-parallel attn-out
-    return out, new_cache
+        return q, k_pool, v_pool, k_scale, v_scale, {
+            "k": k_pool, "v": v_pool, "k_scale": k_scale, "v_scale": v_scale}
+    k_pool = layer_cache["k"].at[kv_dest].set(
+        k.astype(layer_cache["k"].dtype))
+    v_pool = layer_cache["v"].at[kv_dest].set(
+        v.astype(layer_cache["v"].dtype))
+    return q, k_pool, v_pool, None, None, {"k": k_pool, "v": v_pool}
 
 
 class RaggedLlama:
@@ -469,47 +501,60 @@ class RaggedLlama:
         token_ids = batch["token_ids"]            # [T]
         token_pos = batch["token_pos"]            # [T]
 
-        x = self._embed(m["embed_tokens"]["embedding"].astype(dt), token_ids,
-                        ax)                                        # [T, H]
+        with jax.named_scope("embed"):
+            x = self._embed(m["embed_tokens"]["embedding"].astype(dt),
+                            token_ids, ax)                         # [T, H]
         h, hkv, d = (cfg.num_attention_heads // tp,
                      cfg.num_key_value_heads // tp, cfg.head_dim)
         cos, sin = _rotary(token_pos, d, cfg.rope_theta)
         new_cache = {}
+        # device scopes (op_name of every operation): layers_<i>/attn/qkv
+        # (with its norm), attn/rope_insert, attn/dense_read or
+        # attn/gather_read or a paged_* kernel, attn/out_proj, mlp (with
+        # its norm), then lm_head
         for i in range(cfg.num_hidden_layers):
             lp = m[f"layers_{i}"]
-            mlp = lp["mlp"]
-            xa = _rms_norm(x, lp["input_layernorm"]["scale"],
-                           cfg.rms_norm_eps)
-            out, new_cache[f"layer_{i}"] = ragged_attention_block(
-                lp["self_attn"], xa, kv_cache[f"layer_{i}"], batch,
-                self.block_size, cfg, h, hkv, d, cos, sin, ax=ax,
-                prefill_tile=prefill_tile, decode_mode=decode,
-                verify_k=verify_k)
-            x = x + out
-            xm = _rms_norm(x, lp["post_attention_layernorm"]["scale"],
-                           cfg.rms_norm_eps)
-            gate = qmm(xm, mlp["gate_proj"]["kernel"], dt)
-            up = qmm(xm, mlp["up_proj"]["kernel"], dt)
-            mo = qmm(jax.nn.silu(gate) * up, mlp["down_proj"]["kernel"],
-                     dt)
+            with jax.named_scope(f"layers_{i}"):
+                with jax.named_scope("attn/qkv"):
+                    xa = _rms_norm(x, lp["input_layernorm"]["scale"],
+                                   cfg.rms_norm_eps)
+                out, new_cache[f"layer_{i}"] = ragged_attention_block(
+                    lp["self_attn"], xa, kv_cache[f"layer_{i}"], batch,
+                    self.block_size, cfg, h, hkv, d, cos, sin, ax=ax,
+                    prefill_tile=prefill_tile, decode_mode=decode,
+                    verify_k=verify_k)
+                x = x + out
+                with jax.named_scope("mlp"):
+                    x = x + self._mlp(lp, x, ax)
+        with jax.named_scope("lm_head"):
+            x = _rms_norm(x, m["norm"]["scale"], cfg.rms_norm_eps)
+            # ★logits_gather analog: slice each slot's last token BEFORE
+            # the unembed matmul — [S, H] @ [H, V] instead of [T, V] over
+            # every packed token row (a SplitFuse prefill bucket is T >> S,
+            # so the full-width unembed wastes T/S of the vocab matmul and
+            # its [T, V] HBM writes); (TP) all-gathers only the [S, V/tp]
+            # slice (reference sharding/unembed.py gathers the sliced
+            # logits too)
+            x = x[batch["logits_idx"]]
+            if cfg.tie_word_embeddings:
+                logits = x @ m["embed_tokens"]["embedding"].astype(dt).T
+            else:
+                logits = qmm(x, params["lm_head"]["kernel"], dt)
             if ax is not None:
-                mo = jax.lax.psum(mo, ax)         # row-parallel mlp-down
-            x = x + mo
-        x = _rms_norm(x, m["norm"]["scale"], cfg.rms_norm_eps)
-        # ★logits_gather analog: slice each slot's last token BEFORE the
-        # unembed matmul — [S, H] @ [H, V] instead of [T, V] over every
-        # packed token row (a SplitFuse prefill bucket is T >> S, so the
-        # full-width unembed wastes T/S of the vocab matmul and its [T, V]
-        # HBM writes); (TP) all-gathers only the [S, V/tp] slice
-        # (reference sharding/unembed.py gathers the sliced logits too)
-        x = x[batch["logits_idx"]]
-        if cfg.tie_word_embeddings:
-            logits = x @ m["embed_tokens"]["embedding"].astype(dt).T
-        else:
-            logits = qmm(x, params["lm_head"]["kernel"], dt)
-        if ax is not None:
-            logits = jax.lax.all_gather(logits, ax, axis=1, tiled=True)
+                logits = jax.lax.all_gather(logits, ax, axis=1, tiled=True)
         return logits, new_cache
+
+    def _mlp(self, lp, x, ax):
+        """Post-attention norm and the SwiGLU MLP of one layer."""
+        cfg, mlp, dt = self.config, lp["mlp"], self.config.dtype
+        xm = _rms_norm(x, lp["post_attention_layernorm"]["scale"],
+                       cfg.rms_norm_eps)
+        gate = qmm(xm, mlp["gate_proj"]["kernel"], dt)
+        up = qmm(xm, mlp["up_proj"]["kernel"], dt)
+        mo = qmm(jax.nn.silu(gate) * up, mlp["down_proj"]["kernel"], dt)
+        if ax is not None:
+            mo = jax.lax.psum(mo, ax)             # row-parallel mlp-down
+        return mo
 
 
 def _rotary(positions, head_dim, theta):

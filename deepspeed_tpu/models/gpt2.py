@@ -91,32 +91,38 @@ class GPT2Block(nn.Module):
                                        param_dtype=jnp.float32, name=name)
         dense = lambda feats, name: nn.Dense(feats, dtype=cfg.dtype,
                                              param_dtype=jnp.float32, name=name)
-        y = ln("ln_1")(x)
-        qkv = dense(3 * cfg.hidden_size, "c_attn")(y)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        layout = resolve_attention_layout(cfg.attention_layout)
-        if layout in ("folded", "paired"):
-            # consume the c_attn GEMM output directly ([B,S,H*D] end to
-            # end); ineligible geometries fall back inside
-            attn_fn = paired_attention if layout == "paired" \
-                else folded_attention
-            out = attn_fn(q, k, v, num_heads=h, causal=True)
-        else:
-            reshape = lambda t: t.reshape(*t.shape[:2], h, d)
-            out = dot_product_attention(reshape(q), reshape(k), reshape(v),
-                                        causal=True)
-            out = out.reshape(*x.shape[:2], cfg.hidden_size)
-        out = dense(cfg.hidden_size, "attn_out")(out)
-        if cfg.resid_pdrop > 0:
-            out = nn.Dropout(cfg.resid_pdrop)(out, deterministic=deterministic)
-        x = x + out
-        y = ln("ln_2")(x)
-        y = dense(cfg.mlp_dim, "c_fc")(y)
-        y = nn.gelu(y, approximate=True)
-        y = dense(cfg.hidden_size, "c_proj")(y)
-        if cfg.resid_pdrop > 0:
-            y = nn.Dropout(cfg.resid_pdrop)(y, deterministic=deterministic)
-        return x + y
+        # device scopes h_<i>/attn and h_<i>/mlp (the sublayers are not
+        # modules of their own here, so flax names only their Dense layers)
+        with jax.named_scope("attn"):
+            y = ln("ln_1")(x)
+            qkv = dense(3 * cfg.hidden_size, "c_attn")(y)
+            q, k, v = jnp.split(qkv, 3, axis=-1)
+            layout = resolve_attention_layout(cfg.attention_layout)
+            if layout in ("folded", "paired"):
+                # consume the c_attn GEMM output directly ([B,S,H*D] end to
+                # end); ineligible geometries fall back inside
+                attn_fn = paired_attention if layout == "paired" \
+                    else folded_attention
+                out = attn_fn(q, k, v, num_heads=h, causal=True)
+            else:
+                reshape = lambda t: t.reshape(*t.shape[:2], h, d)
+                out = dot_product_attention(reshape(q), reshape(k),
+                                            reshape(v), causal=True)
+                out = out.reshape(*x.shape[:2], cfg.hidden_size)
+            out = dense(cfg.hidden_size, "attn_out")(out)
+            if cfg.resid_pdrop > 0:
+                out = nn.Dropout(cfg.resid_pdrop)(
+                    out, deterministic=deterministic)
+            x = x + out
+        with jax.named_scope("mlp"):
+            y = ln("ln_2")(x)
+            y = dense(cfg.mlp_dim, "c_fc")(y)
+            y = nn.gelu(y, approximate=True)
+            y = dense(cfg.hidden_size, "c_proj")(y)
+            if cfg.resid_pdrop > 0:
+                y = nn.Dropout(cfg.resid_pdrop)(
+                    y, deterministic=deterministic)
+            return x + y
 
 
 class GPT2LMHeadModel(nn.Module):
@@ -146,7 +152,10 @@ class GPT2LMHeadModel(nn.Module):
             x = block(cfg, name=f"h_{i}")(x, deterministic)
         x = nn.LayerNorm(epsilon=cfg.layer_norm_epsilon, dtype=cfg.dtype,
                          param_dtype=jnp.float32, name="ln_f")(x)
-        logits = wte.attend(x.astype(cfg.dtype))
         if labels is None:
-            return logits
-        return cross_entropy_loss(logits, labels)
+            return wte.attend(x.astype(cfg.dtype))
+        # one device scope over the tied head and the loss, forward and
+        # backward: the training step's vocabulary-sized work
+        with jax.named_scope("lm_head_loss"):
+            return cross_entropy_loss(wte.attend(x.astype(cfg.dtype)),
+                                      labels)

@@ -324,13 +324,19 @@ class LlamaForCausalLM(nn.Module):
             input_ids, tie_logits=cfg.tie_word_embeddings,
             positions=positions, cache=cache, cache_index=cache_index)
         x, new_cache = out if cache is not None else (out, None)
-        if cfg.tie_word_embeddings:
-            logits = x
-        else:
-            logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
-                              param_dtype=jnp.float32, name="lm_head")(x)
+
+        def head(x):
+            if cfg.tie_word_embeddings:
+                return x
+            return nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
+                            param_dtype=jnp.float32, name="lm_head")(x)
+
         if labels is not None:
-            return cross_entropy_loss(logits, labels)
+            # one device scope over the head and the loss, forward and
+            # backward: the training step's vocabulary-sized work
+            with jax.named_scope("lm_head_loss"):
+                return cross_entropy_loss(head(x), labels)
+        logits = head(x)
         return (logits, new_cache) if cache is not None else logits
 
 

@@ -15,6 +15,20 @@ Typical use::
     write_chrome_trace("trace.json", tracer.export_events())
     # -> load in https://ui.perfetto.dev
 
+Inside a tick the scheduler and the engine it drives record one span
+tree on that tracer (the scheduler hands it over): ``tick`` → ``pack`` /
+``prefill`` / ``decode`` / ``verify`` / ``sample`` → ``engine/build_batch``,
+``engine/ragged_step``, ``engine/fetch_logits``, ``engine/decode_prep``,
+``engine/decode_step``, ``engine/verify_step``, ``fetch``, ``advance``.  A
+counter is recorded once, on the span that owns it: the ``tick`` span
+closes with ``kind`` and ``emitted``, ``engine/build_batch`` with the
+``tokens`` it fed and the ``bucket`` they were padded to.  The catalogue (name, site, parent, attrs), the
+one rule for when a span is also a ``jax.profiler.TraceAnnotation``
+(opened with ``Tracer.span`` while ``enable_device_annotations`` is on)
+and the off-path cost (one attribute test, the shared null context) are
+in :mod:`~deepspeed_tpu.observability.tracer`.  ``annotate()`` /
+``step_annotation()`` remain for callers that have no tracer.
+
 Every request carries a ``trace_id`` minted at submit; spans from every
 replica incarnation it touches (kill→replay, rolling restarts,
 disaggregated prefill→decode handoff) share that id, so the exported
@@ -49,7 +63,7 @@ from deepspeed_tpu.observability.tracer import (Tracer, annotate,
                                                 enable_device_annotations,
                                                 load_chrome_trace,
                                                 merge_events, mint_trace_id,
-                                                step_annotation,
+                                                open_span, step_annotation,
                                                 write_chrome_trace)
 
 __all__ = ["FlightRecorder", "MemoryLedger", "MetricSpec",
@@ -60,5 +74,5 @@ __all__ = ["FlightRecorder", "MemoryLedger", "MetricSpec",
            "format_waterfall", "kv_occupancy", "list_postmortems",
            "load_chrome_trace", "load_postmortem",
            "make_occupancy_provider", "merge_events", "mint_trace_id",
-           "phase_durations", "step_annotation", "tenant_occupancy",
+           "open_span", "phase_durations", "step_annotation", "tenant_occupancy",
            "virtual_mesh_probe", "write_chrome_trace", "write_postmortem"]

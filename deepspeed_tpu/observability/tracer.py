@@ -23,11 +23,63 @@ Design constraints (the decode fast tick must stay <2% slower traced):
   random prefix, so two incarnations of a replica (fresh Tracer each)
   can contribute spans to the SAME ``trace_id`` without id collisions.
 
-Host↔device alignment: :func:`annotate` wraps engine dispatch sites in
-``jax.profiler.TraceAnnotation`` so a ``jax.profiler`` capture lines the
-XLA timeline up against these host spans.  It returns a shared no-op
-context unless :func:`enable_device_annotations` (or ``DS_DEVICE_TRACE``)
-turned annotations on — the steady-state tick pays nothing by default.
+One span mechanism, down to where the work happens.  The scheduler hands
+its tracer to the engine (``ContinuousBatchScheduler(engine, tracer=)`` /
+``attach_tracer``); both open every span of a tick with :func:`open_span`
+(:meth:`Tracer.span` on a live tracer), which uses the innermost span
+still open as the default parent, so a span records the span that caused
+it without handles being threaded through calls.  A counter is recorded
+once, on the span that owns it, and only where something reads it.  Span
+catalogue of one scheduler tick:
+
+=======================  ==========================  ========  =================
+span                     site                        parent    attrs (counters)
+=======================  ==========================  ========  =================
+``tick``                 ``scheduler.step``          —         ``tick`` (number)
+                                                               and, closing,
+                                                               ``kind`` (decode
+                                                               / mixed / prefill
+                                                               / verify) and
+                                                               ``emitted``
+``pack``                 ``_step_traced``            tick      —
+``prefill``              ``_step_traced``: ``put``   tick      —
+``sample``               after ``put``               tick      —
+``decode``               ``_fast_decode_tick``       tick      —
+``verify``               ``_speculative_decode_``    tick      —
+                         ``tick``
+``engine/build_batch``   ``_run_one_batch``: the     prefill   ``tokens`` fed of
+                         chunks, their KV slots,               the ``bucket``
+                         the metadata, its upload              padded to
+``engine/ragged_step``   the step's dispatch         prefill   —
+``engine/fetch_logits``  ``device_get(logits)``      prefill   —
+``engine/decode_prep``   ``decode_step``: KV slots,  decode    —
+                         table upload, token array
+``engine/decode_step``   the step's dispatch         decode    —
+``engine/verify_step``   the step's dispatch         verify    —
+``fetch``                ``scheduler._fetch``: the   decode /  —
+                         blocking ``device_get``     verify
+``advance``              ``_advance_emitted``, the   decode /  —
+                         verify acceptance loop      verify /
+                                                     sample
+=======================  ==========================  ========  =================
+
+(``request/*`` spans and instants carry each request's own ``trace_id``
+and are opened with :meth:`Tracer.start` / :meth:`Tracer.instant`.)
+
+Host↔device alignment, one rule: a span opened with :meth:`Tracer.span`
+is ALSO entered as a ``jax.profiler.TraceAnnotation`` of the same name
+while :func:`enable_device_annotations` (or ``DS_DEVICE_TRACE``) is on,
+nested Tracer start → annotation enter → work → annotation exit → Tracer
+finish, so during a ``jax.profiler`` capture every such span is natively
+on the device trace's clock (``tick`` besides keeps its ``ds_tick`` step
+annotation around the dispatching part).  Spans opened with ``start`` /
+``finish`` (the overlapping ``request/*`` phases) are never annotations.
+A scheduler or engine nobody handed a tracer still annotates its sites.
+
+Off-path cost: with no tracer, or a disabled one, a site is one
+attribute test and the shared null context — no ``SpanHandle``, no clock
+read, no annotation (:func:`annotate` is the same null context unless
+annotations are on).
 """
 
 from __future__ import annotations
@@ -65,17 +117,31 @@ def device_annotations_enabled() -> bool:
     return _DEVICE_ANNOTATIONS
 
 
+_PROFILER_CLS: Dict[str, Any] = {}
+
+
+def _profiler_cls(attr: str):
+    """``jax.profiler.<attr>``, looked up once (None without jax)."""
+    if attr not in _PROFILER_CLS:
+        try:
+            import jax.profiler as jp
+            _PROFILER_CLS[attr] = getattr(jp, attr)
+        except Exception:  # pragma: no cover — jax-less analysis contexts
+            _PROFILER_CLS[attr] = None
+    return _PROFILER_CLS[attr]
+
+
 def annotate(name: str):
-    """Context manager bracketing a device dispatch for the profiler.
+    """Context manager bracketing a piece of host work for the profiler.
     A shared no-op unless annotations were enabled — the decode fast
-    tick must not pay a TraceAnnotation allocation per step by default."""
+    tick must not pay a TraceAnnotation allocation per step by default.
+    Inside the engine and the scheduler, spans go through
+    :meth:`Tracer.span`, which calls this; it stays public for callers
+    that have no tracer."""
     if not _DEVICE_ANNOTATIONS:
         return _NULL_CM
-    try:
-        from jax.profiler import TraceAnnotation
-    except Exception:  # pragma: no cover — jax-less analysis contexts
-        return _NULL_CM
-    return TraceAnnotation(name)
+    cls = _profiler_cls("TraceAnnotation")
+    return _NULL_CM if cls is None else cls(name)
 
 
 def step_annotation(step: int):
@@ -84,11 +150,8 @@ def step_annotation(step: int):
     timeline.  Same no-op contract as :func:`annotate`."""
     if not _DEVICE_ANNOTATIONS:
         return _NULL_CM
-    try:
-        from jax.profiler import StepTraceAnnotation
-    except Exception:  # pragma: no cover
-        return _NULL_CM
-    return StepTraceAnnotation("ds_tick", step_num=step)
+    cls = _profiler_cls("StepTraceAnnotation")
+    return _NULL_CM if cls is None else cls("ds_tick", step_num=step)
 
 
 # --------------------------------------------------------------------- #
@@ -111,12 +174,60 @@ class SpanHandle:
         self.attrs = attrs
 
 
+class _Span:
+    """What :meth:`Tracer.span` returns on an enabled tracer."""
+
+    __slots__ = ("tr", "args", "h", "outer", "ann")
+
+    def __init__(self, tr, name, trace_id, parent, tid, attrs):
+        self.tr = tr
+        self.args = (name, trace_id, parent, tid, attrs)
+
+    def __enter__(self) -> SpanHandle:
+        tr = self.tr
+        name, trace_id, parent, tid, attrs = self.args
+        outer = self.outer = tr._current
+        if outer is not None:
+            if parent is None:
+                parent = outer.span_id
+            if trace_id is None:
+                trace_id = outer.trace_id
+            if tid is None:
+                tid = outer.tid
+        h = self.h = tr.start(name, trace_id=trace_id, parent=parent,
+                              tid=tid, attrs=attrs)
+        tr._current = h
+        self.ann = ann = annotate(name)
+        if ann is not _NULL_CM:
+            ann.__enter__()
+        return h
+
+    def __exit__(self, *exc) -> None:
+        if self.ann is not _NULL_CM:
+            self.ann.__exit__(*exc)
+        self.tr._current = self.outer
+        self.tr.finish(self.h)
+
+
+def open_span(tracer: Optional["Tracer"], name: str, **kw):
+    """``tracer.span(name, **kw)`` on a live tracer.  Without one (None,
+    or disabled): the bare profiler annotation while those are on, so a
+    capture of an engine nobody handed a tracer still shows its
+    brackets; else the shared null context.  ``with ... as h``: a site
+    that records counters sets ``h.attrs`` when ``h`` is a
+    :class:`SpanHandle`."""
+    if tracer is not None and tracer.enabled:
+        return tracer.span(name, **kw)
+    return annotate(name)
+
+
 class Tracer:
     """Bounded-ring span recorder; see module doc.
 
-    ``enabled=False`` makes every record call a cheap early return — the
-    handles still mint ids so trace continuity survives a disable/enable
-    window (e.g. a bench's untraced A arm).
+    ``enabled=False`` makes every record call a cheap early return —
+    ``start`` handles still mint ids so trace continuity survives a
+    disable/enable window (e.g. a bench's untraced A arm); ``span`` is
+    the shared null context.
     """
 
     def __init__(self, capacity: int = 4096, enabled: bool = True,
@@ -135,6 +246,8 @@ class Tracer:
         self._n = 0                         # total records ever written
         #: open spans by span_id (closed ones move to the ring)
         self._open: Dict[str, SpanHandle] = {}
+        #: innermost span open through :meth:`span`: the default parent
+        self._current: Optional[SpanHandle] = None
         #: wall-clock anchor: wall seconds at monotonic t0 — lets a
         #: merged multi-process trace share one absolute axis
         self._mono0_ns = time.monotonic_ns()
@@ -172,16 +285,24 @@ class Tracer:
             "t1_ns": time.monotonic_ns(),
             **({"attrs": a} if a else {})})
 
-    @contextlib.contextmanager
     def span(self, name: str, *, trace_id: Optional[str] = None,
              parent: Optional[str] = None, tid: Optional[str] = None,
              attrs: Optional[dict] = None):
-        h = self.start(name, trace_id=trace_id, parent=parent, tid=tid,
-                       attrs=attrs)
-        try:
-            yield h
-        finally:
-            self.finish(h)
+        """Context manager around one nested piece of work; ``with ... as
+        h`` gives the open handle (set ``h.attrs`` inside the block to
+        record counters on it).  ``parent`` / ``trace_id`` / ``tid``
+        default to those of the innermost span still open through this
+        method, so a callee's span records the span that caused it
+        without the caller threading handles (one thread drives a
+        tracer's context-manager spans: the scheduler loop).  While
+        device annotations are on it is also a
+        ``jax.profiler.TraceAnnotation`` of the same name, entered after
+        the span starts and left before it finishes.  On a disabled
+        tracer it is the shared null context: ``h`` is None, nothing is
+        built and no clock is read."""
+        if not self.enabled:
+            return _NULL_CM
+        return _Span(self, name, trace_id, parent, tid, attrs)
 
     def instant(self, name: str, *, trace_id: Optional[str] = None,
                 parent: Optional[str] = None, tid: Optional[str] = None,
@@ -237,6 +358,7 @@ class Tracer:
         self._ring = [None] * self.capacity
         self._n = 0
         self._open.clear()
+        self._current = None
         self.dropped = 0
 
     # -- export --------------------------------------------------------- #

@@ -32,7 +32,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.ops.flash_attention import NEG_INF
-from deepspeed_tpu.utils.platform import on_tpu
+from deepspeed_tpu.utils.platform import kernel_names, on_tpu
 
 
 def _pick_tile(s: int, block: int, target: int = 256) -> int:
@@ -266,6 +266,7 @@ def _fwd(q, k, v, cells, tile_any, *, block, block_q, block_k, interpret):
         out_shape=[jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
                    jax.ShapeDtypeStruct((b, h, s, 8), jnp.float32)],
         interpret=interpret,
+        **kernel_names(kernel),
     )(tile_any, cells, q, k, v)
 
 
@@ -292,6 +293,7 @@ def _bwd(res, g, *, block, block_q, block_k, scale, interpret):
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
+        **kernel_names(_bwd_dq_kernel),
     )(tile_any, cells, q, k, v, do, lse, delta)
 
     cells_spec, qs, ks, ls = _specs(block, block_q, block_k, d, cq, ck,
@@ -311,6 +313,7 @@ def _bwd(res, g, *, block, block_q, block_k, scale, interpret):
         out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         interpret=interpret,
+        **kernel_names(_bwd_dkv_kernel),
     )(tile_any, cells, q, k, v, do, lse, delta)
     return dq, dk.astype(k.dtype), dv.astype(v.dtype)
 

@@ -36,7 +36,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.ops.flash_attention import NEG_INF
-from deepspeed_tpu.utils.platform import on_tpu
+from deepspeed_tpu.utils.platform import kernel_names, on_tpu
 
 __all__ = ["DS4Sci_EvoformerAttention", "EvoformerAttnBuilder",
            "evoformer_attention_dense"]
@@ -199,6 +199,7 @@ def _evo_kernel_call(q, k, v, biases, lead: Tuple[int, ...],
             pltpu.VMEM((block_q, 128), jnp.float32),
         ],
         interpret=interpret,
+        **kernel_names(kernel),
     )(*ops)
 
 

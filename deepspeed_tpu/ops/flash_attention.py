@@ -42,7 +42,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.parallel.topology import GROUP_ALIASES
-from deepspeed_tpu.utils.platform import on_tpu
+from deepspeed_tpu.utils.platform import kernel_names, on_tpu
 
 NEG_INF = -1e30
 # Measured on v5e (125M-class shapes): 512/1024 blocks beat both 128/128
@@ -305,6 +305,7 @@ def _fwd(q, k, v, *, causal, block_q, block_k, interpret, window=None):
         ],
         scratch_shapes=scratch,
         interpret=interpret,
+        **kernel_names(kernel),
     )(q, k, v)
 
 
@@ -439,6 +440,7 @@ def _bwd(res, grads, *, scale, causal, block_q, block_k, interpret,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        **kernel_names(_bwd_dq_kernel),
     )(q, k, v, do, lse, delta)
 
     # dK/dV per q-head, then sum each GQA group
@@ -474,6 +476,7 @@ def _bwd(res, grads, *, scale, causal, block_q, block_k, interpret,
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
         interpret=interpret,
+        **kernel_names(_bwd_dkv_kernel),
     )(q, k, v, do, lse, delta)
 
     if g > 1:
@@ -770,6 +773,7 @@ def _fwd_folded(q, k, v, *, h, hkv, causal, block_q, block_k, interpret,
         ],
         scratch_shapes=scratch,
         interpret=interpret,
+        **kernel_names(kernel),
     )(q, k, v)
 
 
@@ -921,6 +925,7 @@ def _bwd_folded(res, grads, *, h, hkv, scale, causal, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((hb, block_q, d), jnp.float32)],
         interpret=interpret,
+        **kernel_names(_bwd_dq_kernel_folded),
     )(q, k, v, do, lse, delta)
 
     # dK/dV per q-head (folded [B, Sk, H*D]), then sum each GQA group
@@ -957,6 +962,7 @@ def _bwd_folded(res, grads, *, h, hkv, scale, causal, block_q, block_k,
         scratch_shapes=[pltpu.VMEM((hb, block_k, d), jnp.float32),
                         pltpu.VMEM((hb, block_k, d), jnp.float32)],
         interpret=interpret,
+        **kernel_names(_bwd_dkv_kernel_folded),
     )(q, k, v, do, lse, delta)
 
     if g > 1:
@@ -1329,6 +1335,7 @@ def _fwd_paired(q, k, v, *, h, hkv, causal, block_q, block_k, interpret,
         ],
         scratch_shapes=scratch,
         interpret=interpret,
+        **kernel_names(kernel),
     )(q, k, v)
 
 
@@ -1508,6 +1515,7 @@ def _bwd_paired(res, grads, *, h, hkv, scale, causal, block_q, block_k,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((n_pairs, block_q, 128), jnp.float32)],
         interpret=interpret,
+        **kernel_names(_bwd_dq_kernel_paired),
     )(q, k, v, do, lse, delta)
 
     # dK/dV per q-head (folded [B, Sk, H*D]), then sum each GQA group
@@ -1544,6 +1552,7 @@ def _bwd_paired(res, grads, *, h, hkv, scale, causal, block_q, block_k,
         scratch_shapes=[pltpu.VMEM((n_pairs, block_k, 128), jnp.float32),
                         pltpu.VMEM((n_pairs, block_k, 128), jnp.float32)],
         interpret=interpret,
+        **kernel_names(_bwd_dkv_kernel_paired),
     )(q, k, v, do, lse, delta)
 
     if g > 1:

@@ -41,7 +41,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from deepspeed_tpu.utils.platform import on_tpu
+from deepspeed_tpu.utils.platform import kernel_names, on_tpu
 
 
 # --------------------------------------------------------------------- #
@@ -136,6 +136,7 @@ def _gmm_fwd_kernel_call(lhs, rhs, group_sizes, tile_m: int, tile_n: int,
         ),
         out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
         interpret=interpret,
+        **kernel_names(kernel),
     )(gids, mtids, rs, re_, lhs, rhs)
     # m-tiles past the last group are never visited (uninitialised) —
     # the contract is zeros there
@@ -205,6 +206,7 @@ def _gmm_dlhs_kernel_call(dout, rhs, group_sizes, tile_m: int, tile_n: int,
         ),
         out_shape=jax.ShapeDtypeStruct((m, k), dout.dtype),
         interpret=interpret,
+        **kernel_names(kernel),
     )(gids, mtids, rs, re_, dout, rhs)
     # gradient rows past the last group: never visited -> zeros by contract
     total = jnp.sum(group_sizes)
@@ -276,6 +278,7 @@ def _gmm_drhs_kernel_call(lhs, dout, group_sizes, tile_m: int, tile_n: int,
         ),
         out_shape=jax.ShapeDtypeStruct((e, k, n), lhs.dtype),
         interpret=interpret,
+        **kernel_names(kernel),
     )(gids, mtids, rs, re_, lhs, dout)
     # empty groups' output blocks are never visited (uninitialised, can
     # hold NaN) — an expert that received no tokens has zero gradient;

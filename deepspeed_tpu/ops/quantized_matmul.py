@@ -29,7 +29,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from deepspeed_tpu.utils.platform import on_tpu
+from deepspeed_tpu.utils.platform import kernel_names, on_tpu
 
 
 def is_quant_record(leaf) -> bool:
@@ -101,6 +101,7 @@ def _qmm_call(x, q, scale, tile_k: int, tile_n: int, interpret: bool):
         out_shape=jax.ShapeDtypeStruct((m, n), x.dtype),
         scratch_shapes=[pltpu.VMEM((m, tile_n), jnp.float32)],
         interpret=interpret,
+        **kernel_names(kernel),
     )(x, q, scale_rows)
 
 

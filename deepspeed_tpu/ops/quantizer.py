@@ -33,7 +33,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.utils.platform import on_tpu
+from deepspeed_tpu.utils.platform import kernel_names, on_tpu
 
 __all__ = [
     "quantize", "dequantize", "fake_quantize", "stochastic_quantize",
@@ -228,6 +228,7 @@ def _quantize_kernel_call(g: jnp.ndarray):
                    pl.BlockSpec((block_g,), lambda i: (i,))],
         out_shape=[jax.ShapeDtypeStruct((ng, gs), jnp.int8),
                    jax.ShapeDtypeStruct((ng,), jnp.float32)],
+        **kernel_names(_quantize_kernel),
     )(g)
     return out[0], out[1]
 

@@ -52,6 +52,7 @@ from deepspeed_tpu.runtime.config import DeepSpeedConfig
 from deepspeed_tpu.runtime.lr_schedules import LRScheduler, get_lr_schedule_fn
 from deepspeed_tpu.runtime.zero import ZeroShardings
 from deepspeed_tpu.ops.optimizers import OptimizerDef, get_optimizer
+from deepspeed_tpu.utils.compile_cache import key_cache_on_names
 from deepspeed_tpu.utils.logging import log_dist, logger
 from deepspeed_tpu.utils.timer import (FORWARD_MICRO_TIMER, STEP_MICRO_TIMER,
                                        NoopTimer, SynchronizedWallClockTimer,
@@ -152,6 +153,8 @@ class DeepSpeedEngine:
                  lr_scheduler: Any = None,
                  dont_change_device: bool = False):
         self.accelerator = get_accelerator()
+        # the fused step's scopes are read by name from a profile
+        key_cache_on_names()
         cfg = config if config is not None else config_params
         self.config = (cfg if isinstance(cfg, DeepSpeedConfig)
                        else DeepSpeedConfig(cfg or {}))
@@ -633,8 +636,9 @@ class DeepSpeedEngine:
             if self.zero_stage >= 3:
                 # order the stage-3 param all-gathers into
                 # allgather_bucket_size groups (overlap_comm)
-                params = self._comm_bucket_chain(
-                    params, self._allgather_bucket_bytes)
+                with jax.named_scope("zero/gather"):
+                    params = self._comm_bucket_chain(
+                        params, self._allgather_bucket_bytes)
 
             def scaled_loss_fn(p):
                 out = self._apply_fn(p, *args, rng=rng, train=True)
@@ -647,8 +651,9 @@ class DeepSpeedEngine:
                 # per-bucket gradient reduce-scatter (overlap_comm): the
                 # barrier chain keeps XLA from combining every leaf's
                 # collective into one program-tail reduce
-                grads = self._comm_bucket_chain(
-                    grads, self._reduce_bucket_bytes)
+                with jax.named_scope("zero/reduce"):
+                    grads = self._comm_bucket_chain(
+                        grads, self._reduce_bucket_bytes)
             return grads, loss
 
         return micro_grads
@@ -788,26 +793,34 @@ class DeepSpeedEngine:
                 # warmup phase: average the per-device accumulators in full
                 # precision (XLA reduces the dp-sharded leading dim)
                 grads = jax.tree.map(lambda g: g.mean(axis=0), grads)
-            # global grad norm (sharded leaves -> XLA inserts the reduction;
-            # fp32 accumulation regardless of grad dtype)
-            sumsq = sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
-                        for g in jax.tree.leaves(grads))
-            gnorm = jnp.sqrt(sumsq)
-            overflow = ~jnp.isfinite(gnorm) if fp16 else jnp.asarray(False)
-            if clip > 0.0:
-                coef = jnp.minimum(1.0, clip / (gnorm + 1e-6))
-                # f32 coef promotes bf16 grads to f32 inside the (fused)
-                # update kernel — no extra materialised tree
-                grads = jax.tree.map(lambda g: g * coef, grads)
+            # device scopes: optimizer/clip (the global norm and the
+            # scaling), optimizer/<name> (the update, the overflow guard
+            # and the cast back to the compute dtype)
+            with jax.named_scope("optimizer/clip"):
+                # global grad norm (sharded leaves -> XLA inserts the
+                # reduction; fp32 accumulation regardless of grad dtype)
+                sumsq = sum(jnp.sum(jnp.square(g.astype(jnp.float32)))
+                            for g in jax.tree.leaves(grads))
+                gnorm = jnp.sqrt(sumsq)
+                overflow = ~jnp.isfinite(gnorm) if fp16 \
+                    else jnp.asarray(False)
+                if clip > 0.0:
+                    coef = jnp.minimum(1.0, clip / (gnorm + 1e-6))
+                    # f32 coef promotes bf16 grads to f32 inside the
+                    # (fused) update kernel — no extra materialised tree
+                    grads = jax.tree.map(lambda g: g * coef, grads)
 
             opt_step_next = state["opt_step"] + 1
-            new_master, new_opt = self.optimizer_def.update(
-                grads, state["opt"], state["master"], lr, opt_step_next)
+            with jax.named_scope(f"optimizer/{self.optimizer_def.name}"):
+                new_master, new_opt = self.optimizer_def.update(
+                    grads, state["opt"], state["master"], lr, opt_step_next)
 
-            keep = lambda new, old: jax.tree.map(
-                lambda n, o: jnp.where(overflow, o, n), new, old)
-            new_master = keep(new_master, state["master"])
-            new_opt = keep(new_opt, state["opt"])
+                keep = lambda new, old: jax.tree.map(
+                    lambda n, o: jnp.where(overflow, o, n), new, old)
+                new_master = keep(new_master, state["master"])
+                new_opt = keep(new_opt, state["opt"])
+                new_params = jax.tree.map(
+                    lambda m: m.astype(self.compute_dtype), new_master)
 
             new_scale, new_good, new_hyst = self._loss_scale_next(
                 state["loss_scale"], state["good_steps"],
@@ -818,8 +831,7 @@ class DeepSpeedEngine:
             new_state.update({
                 "step": state["step"] + 1,
                 "opt_step": jnp.where(overflow, state["opt_step"], opt_step_next),
-                "params": jax.tree.map(
-                    lambda m: m.astype(self.compute_dtype), new_master),
+                "params": new_params,
                 "master": new_master,
                 "opt": new_opt,
                 # direct-grad path: acc_grads were never touched (still

@@ -27,7 +27,6 @@ jitted ragged step — the same split the reference keeps.
 
 from __future__ import annotations
 
-import contextlib
 import itertools
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -37,7 +36,8 @@ import numpy as np
 from deepspeed_tpu.inference.v2.speculative import (SpeculativeConfig,
                                                     SpeculativeStats,
                                                     accept_drafts)
-from deepspeed_tpu.observability.tracer import (Tracer, mint_trace_id,
+from deepspeed_tpu.observability.tracer import (SpanHandle, Tracer,
+                                                mint_trace_id, open_span,
                                                 step_annotation)
 from deepspeed_tpu.resilience import chaos
 from deepspeed_tpu.resilience.heartbeat import Heartbeat
@@ -46,9 +46,6 @@ from deepspeed_tpu.serving.request import (Request, RequestState,
                                            SamplingParams)
 from deepspeed_tpu.serving.sampler import sample_batch
 from deepspeed_tpu.utils.logging import logger
-
-_NULL_CM = contextlib.nullcontext()
-
 
 class QueueFullError(RuntimeError):
     """``submit()`` rejected: the admission queue is at ``max_queue``.
@@ -89,14 +86,18 @@ class ContinuousBatchScheduler:
                  registry=None, registry_key: str = "serving"):
         self.engine = engine
         #: request-scoped tracing (None = zero-overhead off).  Tick
-        #: phases (pack, prefill, decode/verify, sample, emit) record as
-        #: child spans under a per-tick span on the scheduler's own
-        #: trace; request lifecycle spans carry each request's trace_id.
+        #: phases (pack, prefill, decode/verify, sample) record as child
+        #: spans under a per-tick span on the scheduler's own trace, the
+        #: engine's spans and ``fetch`` / ``advance`` under the phase that
+        #: caused them (catalogue: observability/tracer.py); request
+        #: lifecycle spans carry each request's trace_id.
         #: The fleet re-points tracer/trace_tid at respawn so spans are
         #: tagged ``replica#incarnation``.
         self.tracer = tracer
         self.trace_tid = tracer.default_tid if tracer is not None \
             else "scheduler"
+        if tracer is not None and hasattr(engine, "attach_tracer"):
+            engine.attach_tracer(tracer)
         #: the tick timeline's own trace (request traces are per-request)
         self.sched_trace_id = mint_trace_id()
         #: uid -> open request-phase SpanHandle
@@ -357,6 +358,8 @@ class ContinuousBatchScheduler:
         (default: the tracer's own tid).  The tracer/trace_tid pair must
         move together — this is the one place that knows that."""
         self.tracer = tracer
+        if hasattr(self.engine, "attach_tracer"):
+            self.engine.attach_tracer(tracer)
         if self._registry is not None:
             # a respawn's fresh tracer supersedes the dead one's ring
             # gauges under the same stable provider key; detaching
@@ -444,33 +447,20 @@ class ContinuousBatchScheduler:
         ``(request, token)`` pairs emitted this tick."""
         if self._heartbeat is not None:
             self._heartbeat.beat(self._tick)
-        tr = self.tracer
-        tracing = tr is not None and tr.enabled
-        tick_h = tr.start("tick", trace_id=self.sched_trace_id,
-                          tid=self.trace_tid,
-                          attrs={"tick": self._tick}) if tracing else None
-        try:
-            return self._step_traced(tr, tick_h)
-        finally:
-            if tick_h is not None:
-                tr.finish(tick_h)
+        with open_span(self.tracer, "tick", trace_id=self.sched_trace_id,
+                       tid=self.trace_tid,
+                       attrs={"tick": self._tick}) as tick_h:
+            return self._step_traced(
+                tick_h if type(tick_h) is SpanHandle else None)
 
-    def _phase(self, name: str, tick_h):
-        """Child span for one tick phase (no-op context without a
-        tracer/tick span)."""
-        if tick_h is None:
-            return _NULL_CM
-        return self.tracer.span(name, trace_id=self.sched_trace_id,
-                                parent=tick_h.span_id, tid=self.trace_tid)
-
-    def _step_traced(self, tr, tick_h) -> List[Tuple[Request, int]]:
+    def _step_traced(self, tick_h) -> List[Tuple[Request, int]]:
         self._expire_deadlines()
         self._reap_unservable()
         uids: List[int] = []
         chunks: List[List[int]] = []
         packed: List[Request] = []
 
-        with self._phase("pack", tick_h):
+        with open_span(self.tracer, "pack"):
             self._pack_decodes(uids, chunks, packed)
             self._pack_prefills(uids, chunks, packed)
 
@@ -493,32 +483,35 @@ class ContinuousBatchScheduler:
         # that stalls on anything (engine, allocator, GIL) should trip
         t0 = time.monotonic()
         chaos.fire("tick_stall")
-        decode_tick = all(r.state is RequestState.DECODE for r in packed)
+        n_decode = sum(r.state is RequestState.DECODE for r in packed)
+        decode_tick = n_decode == len(packed)
+        kind = "decode" if decode_tick else "mixed" if n_decode \
+            else "prefill"
         with step_annotation(self._tick):
             if self.fast_decode and decode_tick:
                 emitted = None
                 if self._spec_active is not None:
-                    with self._phase("verify", tick_h):
+                    with open_span(self.tracer, "verify"):
                         emitted = self._speculative_decode_tick(
                             uids, chunks, packed)
+                    if emitted is not None:
+                        kind = "verify"
                 if emitted is None:
                     if self._spec_active is not None:
                         self.spec_stats.fallback_ticks += 1
-                    with self._phase("decode", tick_h):
+                    with open_span(self.tracer, "decode"):
                         emitted = self._fast_decode_tick(uids, chunks,
                                                          packed)
             else:
-                with self._phase("prefill", tick_h):
+                with open_span(self.tracer, "prefill"):
                     logits = self.engine.put(uids, chunks, sync=True)
                     for req, chunk in zip(packed, chunks):
                         req.fed += len(chunk)
-                with self._phase("sample", tick_h):
+                with open_span(self.tracer, "sample"):
                     emitted = self._sample_and_advance(packed, logits)
-        if tick_h is not None and emitted:
-            tr.instant("emit", trace_id=self.sched_trace_id,
-                       parent=tick_h.span_id, tid=self.trace_tid,
-                       attrs={"tokens": len(emitted),
-                              "requests": len(packed)})
+        if tick_h is not None:
+            # the tick span closes with what ran and what came out
+            tick_h.attrs.update(kind=kind, emitted=len(emitted))
         if decode_tick:
             # per-tick TPOT accounting divides by tokens DELIVERED (a
             # speculative tick can emit several per request)
@@ -543,26 +536,31 @@ class ContinuousBatchScheduler:
         the argmax'd token vector (a few bytes/request); any stochastic
         request still needs its logits row on the host for the
         (seed, uid, position)-keyed sampler."""
-        import jax
-
         tokens = [c[0] for c in chunks]
         n = len(uids)
         self.fast_ticks += 1
         if all(r.sampling.greedy for r in packed):
             _, nxt = self.engine.decode_step(uids, tokens, greedy=True)
-            toks = [int(t) for t in
-                    np.asarray(jax.device_get(nxt))[:n]]
+            toks = [int(t) for t in self._fetch(nxt)[:n]]
             for req in packed:
                 req.fed += 1
             return self._advance_emitted(packed, toks)
         logits = self.engine.decode_step(uids, tokens)
-        rows = np.asarray(jax.device_get(logits), np.float32)[:n]
+        rows = np.asarray(self._fetch(logits), np.float32)[:n]
         for req in packed:
             req.fed += 1
         tokens_out = sample_batch(rows, [r.sampling for r in packed],
                                   [len(r.generated) for r in packed],
                                   [r.uid for r in packed])
         return self._advance_emitted(packed, tokens_out.tolist())
+
+    def _fetch(self, device_array) -> np.ndarray:
+        """The tick's one blocking transfer: the host waits here for the
+        step program to finish and its tokens (or logits) to arrive."""
+        import jax
+
+        with open_span(self.tracer, "fetch"):
+            return np.asarray(jax.device_get(device_array))
 
     # -- speculative decode -------------------------------------------- #
     def _speculative_decode_tick(self, uids, chunks, packed
@@ -607,8 +605,6 @@ class ContinuousBatchScheduler:
         K = gamma + 1
         if not self.engine.can_schedule(uids, [K] * len(uids)):
             return None                  # lookahead KV/context won't fit
-        import jax
-
         feed = [[r.history[-1]] + d + [0] * (gamma - len(d))
                 for r, d in zip(packed, drafts)]
         spans = [len(d) + 1 for d in drafts]
@@ -618,7 +614,7 @@ class ContinuousBatchScheduler:
             # vocab] logits (the same asymmetry the plain greedy fast
             # tick exploits via decode_step(greedy=True))
             _, nxt = self.engine.verify_step(uids, feed, greedy=True)
-            toks = np.asarray(jax.device_get(nxt))[:len(uids)]
+            toks = self._fetch(nxt)[:len(uids)]
             cand = np.concatenate(
                 [toks[i, :m] for i, m in enumerate(spans)])
         else:
@@ -628,7 +624,7 @@ class ContinuousBatchScheduler:
             # every candidate slot: slot k of request i draws at
             # generation position len(generated)+k — the exact key
             # sequential decode would use
-            rows = np.asarray(jax.device_get(
+            rows = np.asarray(self._fetch(
                 self.engine.verify_step(uids, feed)),
                 np.float32)[:len(uids)]
             flat_rows, flat_params, flat_pos, flat_uids = [], [], [], []
@@ -640,39 +636,40 @@ class ContinuousBatchScheduler:
                 flat_uids.extend([r.uid] * m)
             cand = sample_batch(np.concatenate(flat_rows, axis=0),
                                 flat_params, flat_pos, flat_uids)
-        emitted: List[Tuple[Request, int]] = []
-        now = time.monotonic()
-        self.spec_stats.ticks += 1
-        off = 0
-        for i, (req, d) in enumerate(zip(packed, drafts)):
-            out, acc = accept_drafts(cand[off:off + spans[i]], d)
-            off += spans[i]
-            self.spec_stats.drafted += len(d)
-            self.spec_stats.accepted += acc
-            self.spec_stats.k_sum += k_targets[i]
-            self.spec_stats.k_requests += 1
-            if spec.autotune_k and d:
-                a = spec.accept_ewma_alpha
-                rate = acc / len(d)
-                prev = self._spec_accept_ewma.get(req.uid)
-                ew = rate if prev is None else (1.0 - a) * prev + a * rate
-                self._spec_accept_ewma[req.uid] = ew
-                k_cur = k_targets[i]
-                if ew < spec.shrink_threshold and k_cur > spec.min_draft_k:
-                    k_cur -= 1
-                elif ew > spec.grow_threshold and k_cur < spec.draft_k:
-                    k_cur += 1
-                self._spec_k[req.uid] = k_cur
-            # commit the accepted feed prefix (input + accepted drafts);
-            # the engine trims rejected lookahead blocks back
-            self.engine.commit_verified(req.uid, feed[i][:1 + acc])
-            req.fed += 1 + acc
-            got = self._emit_many(req, out, now)
-            # count what was DELIVERED, not what was accepted — a stop
-            # token mid-burst truncates delivery exactly where
-            # sequential decode would have stopped
-            self.spec_stats.emitted += len(got)
-            emitted.extend(got)
+        with open_span(self.tracer, "advance"):
+            emitted: List[Tuple[Request, int]] = []
+            now = time.monotonic()
+            self.spec_stats.ticks += 1
+            off = 0
+            for i, (req, d) in enumerate(zip(packed, drafts)):
+                out, acc = accept_drafts(cand[off:off + spans[i]], d)
+                off += spans[i]
+                self.spec_stats.drafted += len(d)
+                self.spec_stats.accepted += acc
+                self.spec_stats.k_sum += k_targets[i]
+                self.spec_stats.k_requests += 1
+                if spec.autotune_k and d:
+                    a = spec.accept_ewma_alpha
+                    rate = acc / len(d)
+                    prev = self._spec_accept_ewma.get(req.uid)
+                    ew = rate if prev is None else (1.0 - a) * prev + a * rate
+                    self._spec_accept_ewma[req.uid] = ew
+                    k_cur = k_targets[i]
+                    if ew < spec.shrink_threshold and k_cur > spec.min_draft_k:
+                        k_cur -= 1
+                    elif ew > spec.grow_threshold and k_cur < spec.draft_k:
+                        k_cur += 1
+                    self._spec_k[req.uid] = k_cur
+                # commit the accepted feed prefix (input + accepted drafts);
+                # the engine trims rejected lookahead blocks back
+                self.engine.commit_verified(req.uid, feed[i][:1 + acc])
+                req.fed += 1 + acc
+                got = self._emit_many(req, out, now)
+                # count what was DELIVERED, not what was accepted — a stop
+                # token mid-burst truncates delivery exactly where
+                # sequential decode would have stopped
+                self.spec_stats.emitted += len(got)
+                emitted.extend(got)
         return emitted
 
     def _emit_many(self, req: Request, tokens: Sequence[int],
@@ -917,20 +914,24 @@ class ContinuousBatchScheduler:
 
     def _advance_emitted(self, ready,
                          tokens: List[int]) -> List[Tuple[Request, int]]:
+        """Hand each request its token (``on_token`` runs here), check its
+        stops, move its lifecycle on."""
         now = time.monotonic()
         emitted: List[Tuple[Request, int]] = []
-        for req, tok in zip(ready, tokens):
-            req.emit(tok, now)
-            emitted.append((req, tok))
-            reason = req.should_stop()
-            if reason is None and len(req.history) >= self.max_context:
-                reason = "length"
-            if reason is not None:
-                self._finish(req, reason)
-            elif req.state is RequestState.PREFILL:
-                req.transition(RequestState.DECODE)
-                # prefill phase over: the span chain continues as decode
-                self._open_req_span(req, "decode")
+        with open_span(self.tracer, "advance"):
+            for req, tok in zip(ready, tokens):
+                req.emit(tok, now)
+                emitted.append((req, tok))
+                reason = req.should_stop()
+                if reason is None and len(req.history) >= self.max_context:
+                    reason = "length"
+                if reason is not None:
+                    self._finish(req, reason)
+                elif req.state is RequestState.PREFILL:
+                    req.transition(RequestState.DECODE)
+                    # prefill phase over: the span chain continues as
+                    # decode
+                    self._open_req_span(req, "decode")
         return emitted
 
     def _drop_request_state(self, uid: int) -> None:

@@ -7,6 +7,16 @@ where ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it and nothing
 is set in code; otherwise the cache lives at ``<checkout>/.jax_cache`` —
 never a temporary name, a pid or a time.  The library itself never calls
 this: a script's ``main`` does, before its first compilation.
+
+What the cache keys on is the library's business, though.  By default the
+key is computed from the module with its debug information stripped, so it
+ignores ``jax.named_scope``, the ``op_name`` of every operation and source
+locations: a program that differs from a cached one only in its scopes
+loads the cached executable and shows the OLD names to a profiler (seen on
+the chip, PERF.md section 6, PR 23).  The span and scope names are what the
+engines publish to an operator and to the benchmark's readers, so both
+engines call :func:`key_cache_on_names` when they are built: a change of
+names then compiles once more instead of reporting another program's.
 """
 
 from __future__ import annotations
@@ -15,6 +25,16 @@ import os
 
 _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
+
+
+def key_cache_on_names() -> None:
+    """Keep scopes, ``op_name`` and source locations in the persistent
+    cache's key (``jax_compilation_cache_include_metadata_in_key``).
+    Costs one cold compile whenever a traced source line moves; changes
+    nothing where no cache directory is set."""
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
 
 
 def enable_compile_cache() -> str:

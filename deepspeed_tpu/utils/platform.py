@@ -17,6 +17,23 @@ def on_tpu() -> bool:
     return jax.devices()[0].platform == "tpu"
 
 
+def kernel_names(kernel, op_name: bool = True) -> dict:
+    """Keyword arguments that name a ``pl.pallas_call`` on the device:
+    ``metadata={"kernel": <the kernel function's name>}`` reaches the
+    compiled custom call as ``frontend_attributes={kernel_metadata=...}``,
+    which is part of the text a device trace prints for the call.  With
+    ``op_name`` also ``name=``, which puts the name into the call's
+    ``op_name`` and makes it the HLO instruction's name; a caller whose
+    instruction name others match (the paged-attention wrappers) leaves
+    that off."""
+    while hasattr(kernel, "func"):          # functools.partial
+        kernel = kernel.func
+    names = {"metadata": {"kernel": kernel.__name__}}
+    if op_name:
+        names["name"] = kernel.__name__
+    return names
+
+
 def require_tpu(who: str):
     """For entry-point scripts that only mean something on the chip:
     ``jax.devices()``, or exit non-zero naming the platform found — a

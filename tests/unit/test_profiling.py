@@ -129,13 +129,16 @@ class TestFlopsProfiler:
                     + 2 * 2 * B * S * H * I)     # c_fc + c_proj
         per_mod = per_module_flops(fn, params, x)
         assert sum(per_mod.values()) == pytest.approx(analytic, rel=1e-9)
-        # the tree names the issuing modules (what the waterfall reads)
-        rolled = module_tree(per_mod, depth=2)
-        for mod, want in (("GPT2Block/c_attn", 2 * B * S * 3 * H * H),
-                          ("GPT2Block/attn_out", 2 * B * S * H * H),
-                          ("GPT2Block/c_fc", 2 * B * S * H * I),
-                          ("GPT2Block/c_proj", 2 * B * S * H * I)):
+        # the tree names the issuing modules (what the waterfall reads),
+        # under the block's attn / mlp device scopes (PR 23)
+        rolled = module_tree(per_mod, depth=3)
+        for mod, want in (("GPT2Block/attn/c_attn", 2 * B * S * 3 * H * H),
+                          ("GPT2Block/attn/attn_out", 2 * B * S * H * H),
+                          ("GPT2Block/mlp/c_fc", 2 * B * S * H * I),
+                          ("GPT2Block/mlp/c_proj", 2 * B * S * H * I)):
             assert rolled[mod] == pytest.approx(want, rel=1e-9), mod
+        assert module_tree(per_mod, depth=2)["GPT2Block/mlp"] == \
+            pytest.approx(2 * 2 * B * S * H * I, rel=1e-9)
         # compiler-exact total: matmuls dominate, tail is single-digit %
         flops, macs, _params = get_model_profile(
             fn, args=(params, x), print_profile=False, as_string=False)

@@ -1,0 +1,512 @@
+"""PR 23: spans inside the scheduler tick, counters on the tick span, and
+kernel names on every Mosaic call.
+
+* the span tree of a mixed and of a pure-decode tick: every ``engine/*``,
+  ``fetch`` and ``advance`` span has a chain of parents up to its ``tick``,
+  with the names and counters of the catalogue in
+  ``observability/tracer.py``; the export passes ``obs_dump.validate_trace``;
+* the tick span's closing counters;
+* with no tracer, or a disabled one, a tick records nothing and builds no
+  ``SpanHandle``;
+* every ``pl.pallas_call`` of the package lowers for the TPU with
+  ``kernel_metadata`` naming its kernel function.
+"""
+
+import contextlib
+import functools
+import importlib
+import importlib.util
+import pathlib
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from deepspeed_tpu.analysis import registry
+from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
+                                        RaggedInferenceEngineConfig)
+from deepspeed_tpu.inference.v2.model_implementations import RaggedLlama
+from deepspeed_tpu.models import LlamaConfig, LlamaForCausalLM
+from deepspeed_tpu.observability import Tracer, tracer as tracer_mod
+from deepspeed_tpu.serving import (ContinuousBatchScheduler, SamplingParams,
+                                   SpeculativeConfig)
+
+CFG = LlamaConfig.tiny(dtype=jnp.float32)
+_TOOLS = pathlib.Path(__file__).resolve().parents[2] / "tools"
+
+
+@pytest.fixture(scope="module")
+def params():
+    return LlamaForCausalLM(CFG).init(
+        jax.random.key(0), np.zeros((1, 4), np.int32))["params"]
+
+
+def _sched(params, tracer=None, **kw):
+    cfg = RaggedInferenceEngineConfig.from_dict({
+        "state_manager": {"max_ragged_batch_size": 32,
+                          "max_ragged_sequence_count": 4,
+                          "max_context": 48},
+        "kv_cache": {"block_size": 8, "num_blocks": 17}})
+    return ContinuousBatchScheduler(
+        InferenceEngineV2(RaggedLlama(CFG, 8), params, cfg), tracer=tracer,
+        **kw)
+
+
+def _prompt(n, seed=0):
+    return np.random.default_rng(seed).integers(
+        0, CFG.vocab_size, size=(n,)).tolist()
+
+
+def _drive(sched):
+    """A prefill-only tick, a pure-decode tick, a mixed tick (the second
+    prompt arrives while the first decodes), then decode to the end: the
+    last ticks decode one sequence alone, inside one KV block."""
+    sched.submit(_prompt(13), SamplingParams(greedy=True, max_new_tokens=8))
+    sched.step()
+    sched.step()
+    sched.submit(_prompt(11, 1), SamplingParams(greedy=True,
+                                                max_new_tokens=3))
+    sched.run_until_idle()
+
+
+@pytest.fixture(scope="module")
+def traced(params):
+    tr = Tracer()
+    sched = _sched(params, tracer=tr)
+    _drive(sched)
+    return tr, sched
+
+
+def _ticks(tr):
+    """[(tick record, {name: [descendant records]})], oldest first."""
+    recs = [r for r in tr.records() if r["ph"] == "X"]
+    by_id = {r["span_id"]: r for r in recs}
+    out = {r["span_id"]: (r, {}) for r in recs if r["name"] == "tick"}
+    for r in recs:
+        up = r
+        while up.get("parent") is not None:
+            up = by_id[up["parent"]]
+        if up is not r and up["span_id"] in out:
+            out[up["span_id"]][1].setdefault(r["name"], []).append(r)
+    return list(out.values())
+
+
+def _of_kind(tr, kind):
+    return [(t, kids) for t, kids in _ticks(tr)
+            if t["attrs"].get("kind") == kind]
+
+
+# --------------------------------------------------------------------- #
+# the span tree
+# --------------------------------------------------------------------- #
+def test_every_inner_span_chains_up_to_a_tick(traced):
+    tr, _ = traced
+    recs = [r for r in tr.records() if r["ph"] == "X"]
+    by_id = {r["span_id"]: r for r in recs}
+    inner = [r for r in recs if r["name"].startswith("engine/")
+             or r["name"] in ("fetch", "advance")]
+    assert inner
+    for r in inner:
+        chain = [r]
+        while chain[-1].get("parent") is not None:
+            chain.append(by_id[chain[-1]["parent"]])
+        names = [c["name"] for c in chain]
+        assert names[-1] == "tick", names
+        # under the tick's phase, never directly under the tick
+        assert names[-2] in ("prefill", "decode", "verify", "sample"), names
+        assert {c["trace_id"] for c in chain} == {r["trace_id"]}
+        assert all(a["t0_ns"] >= b["t0_ns"] and a["t1_ns"] <= b["t1_ns"]
+                   for a, b in zip(chain, chain[1:]))
+
+
+def test_pure_decode_tick_tree(traced):
+    tr, _ = traced
+    ticks = _of_kind(tr, "decode")
+    assert ticks
+    for tick, kids in ticks:
+        assert sorted(kids) == ["advance", "decode", "engine/decode_prep",
+                                "engine/decode_step", "fetch", "pack"]
+        assert all(len(v) == 1 for v in kids.values())
+        # a counter is recorded once, where something reads it: none of
+        # a decode tick's inner spans owns one
+        assert not any("attrs" in v[0] for v in kids.values())
+        order = [kids[k][0] for k in ("engine/decode_prep",
+                                      "engine/decode_step", "fetch",
+                                      "advance")]
+        assert all(a["t1_ns"] <= b["t0_ns"] for a, b in zip(order, order[1:]))
+
+
+def test_mixed_tick_tree(traced):
+    tr, _ = traced
+    (tick, kids), = _of_kind(tr, "mixed")
+    assert sorted(kids) == ["advance", "engine/build_batch",
+                            "engine/fetch_logits", "engine/ragged_step",
+                            "pack", "prefill", "sample"]
+    # one decoding token and an 11-token prompt, padded to the 16 bucket
+    assert kids["engine/build_batch"][0]["attrs"] == {"tokens": 1 + 11,
+                                                      "bucket": 16}
+    assert not any("attrs" in v[0] for k, v in kids.items()
+                   if k != "engine/build_batch")
+    by_id = {r["span_id"]: r for r in tr.records()}
+    assert by_id[kids["advance"][0]["parent"]]["name"] == "sample"
+    assert by_id[kids["engine/build_batch"][0]["parent"]]["name"] == \
+        "prefill"
+
+
+def test_the_emit_instant_is_gone(traced):
+    tr, _ = traced
+    assert not [r for r in tr.records() if r["name"] == "emit"]
+    assert {r["name"] for r in tr.records() if r["ph"] == "i"} == \
+        {"request/submit"}
+
+
+def test_export_validates(traced):
+    tr, _ = traced
+    spec = importlib.util.spec_from_file_location("obs_dump",
+                                                  _TOOLS / "obs_dump.py")
+    obs_dump = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(obs_dump)
+    events = tr.export_events()
+    assert obs_dump.validate_trace(events) == []
+    tick = next(e for e in events if e["name"] == "tick")
+    assert {"tick", "kind", "emitted"} <= set(tick["args"])
+
+
+# --------------------------------------------------------------------- #
+# the tick span's closing counters
+# --------------------------------------------------------------------- #
+def test_tick_closing_counters(traced):
+    tr, sched = traced
+    ticks = _ticks(tr)
+    assert [t["attrs"]["kind"] for t, _ in ticks[:3]] == \
+        ["prefill", "decode", "mixed"]
+    for t, kids in ticks:
+        assert set(t["attrs"]) == {"tick", "kind", "emitted"}
+        builds = kids.get("engine/build_batch", [])
+        if t["attrs"]["kind"] == "decode":
+            # one token a running sequence, and no ragged batch
+            assert 1 <= t["attrs"]["emitted"] <= sched.max_seqs
+            assert not builds
+        else:
+            assert builds
+            for b in builds:
+                assert 0 < b["attrs"]["tokens"] <= b["attrs"]["bucket"]
+    assert [t["attrs"]["tick"] for t, _ in ticks] == list(range(len(ticks)))
+    assert [t["attrs"]["emitted"] for t, _ in ticks[:3]] == [1, 1, 2]
+    assert ticks[0][1]["engine/build_batch"][0]["attrs"] == \
+        {"tokens": 13, "bucket": 16}
+    assert sum(t["attrs"]["emitted"] for t, _ in ticks) == 8 + 3
+
+
+def test_verify_tick_counters(params):
+    tr = Tracer()
+    sched = _sched(params, tracer=tr, speculative=SpeculativeConfig(draft_k=3))
+    # a repeating prompt, so the n-gram drafter has something to propose
+    sched.submit([5, 6, 7, 8] * 4, SamplingParams(greedy=True,
+                                                  max_new_tokens=8))
+    sched.run_until_idle()
+    verify = _of_kind(tr, "verify")
+    if not verify:
+        pytest.skip("the drafter proposed nothing on this model")
+    for tick, kids in verify:
+        # a verify tick emits the accepted drafts and one token more
+        assert 1 <= tick["attrs"]["emitted"] <= 3 + 1
+        assert {"verify", "engine/verify_step", "fetch", "advance"} <= \
+            set(kids)
+    assert sum(t["attrs"]["emitted"] for t, _ in _ticks(tr)) == 8
+
+
+# --------------------------------------------------------------------- #
+# off: nothing recorded, nothing built
+# --------------------------------------------------------------------- #
+@pytest.mark.parametrize("make", [lambda: None,
+                                  lambda: Tracer(enabled=False)],
+                         ids=["no_tracer", "disabled_tracer"])
+def test_untraced_tick_builds_no_span(params, monkeypatch, make):
+    built = []
+    real_init = tracer_mod.SpanHandle.__init__
+
+    def counting(self, *a, **kw):
+        built.append(a[0])
+        real_init(self, *a, **kw)
+
+    monkeypatch.setattr(tracer_mod.SpanHandle, "__init__", counting)
+    tr = make()
+    sched = _sched(params, tracer=tr)
+    assert sched.engine.tracer is tr
+    _drive(sched)
+    assert built == []
+    if tr is not None:
+        assert len(tr) == 0 and not tr.open_spans()
+        assert tr.span("x") is tracer_mod._NULL_CM
+
+
+def test_attach_tracer_reaches_the_engine(params):
+    sched = _sched(params)
+    assert sched.engine.tracer is None
+    tr = Tracer()
+    sched.attach_tracer(tr)
+    assert sched.engine.tracer is tr
+    _drive(sched)
+    assert _of_kind(tr, "decode")
+    sched.attach_tracer(None)
+    assert sched.engine.tracer is None
+
+
+def test_span_defaults_to_the_innermost_open_span():
+    tr = Tracer(tid="t0")
+    with tr.span("outer", trace_id="abc") as outer:
+        with tr.span("inner") as inner:
+            assert (inner.parent, inner.trace_id, inner.tid) == \
+                (outer.span_id, "abc", "t0")
+            with tr.span("other", trace_id="xyz", parent="p") as other:
+                assert (other.parent, other.trace_id) == ("p", "xyz")
+        with tr.span("second") as second:
+            assert second.parent == outer.span_id
+    with tr.span("alone") as alone:
+        assert alone.parent is None
+    # a span opened with start()/finish() is nobody's default parent
+    h = tr.start("loose")
+    with tr.span("after") as after:
+        assert after.parent is None
+    tr.finish(h)
+
+
+def test_span_is_a_profiler_annotation_only_while_those_are_on(monkeypatch):
+    entered = []
+
+    class Ann:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            entered.append(("in", self.name))
+
+        def __exit__(self, *exc):
+            entered.append(("out", self.name))
+
+    monkeypatch.setitem(tracer_mod._PROFILER_CLS, "TraceAnnotation", Ann)
+    tr = Tracer()
+    with tr.span("quiet"):
+        pass
+    assert entered == []
+    monkeypatch.setattr(tracer_mod, "_DEVICE_ANNOTATIONS", True)
+    with tr.span("a"):
+        with tr.span("b"):
+            pass
+    assert entered == [("in", "a"), ("in", "b"), ("out", "b"), ("out", "a")]
+    # the Tracer span encloses its annotation: started before, ended after
+    assert [r["name"] for r in tr.records()] == ["quiet", "b", "a"]
+    # no tracer: the sites still annotate, and yield no SpanHandle
+    with tracer_mod.open_span(None, "bare") as h:
+        assert not isinstance(h, tracer_mod.SpanHandle)
+    assert entered[-2:] == [("in", "bare"), ("out", "bare")]
+    monkeypatch.setattr(tracer_mod, "_DEVICE_ANNOTATIONS", False)
+    assert tracer_mod.open_span(None, "x") is tracer_mod._NULL_CM
+
+
+def test_untraced_scheduler_still_annotates_while_those_are_on(
+        params, monkeypatch):
+    """With no tracer every site of the tick is the bare profiler
+    annotation (an operator's ``jax.profiler`` capture shows the tick's
+    brackets whoever holds a tracer), and the tick runs as untraced."""
+    names = []
+
+    class Ann:
+        def __init__(self, name, **kw):
+            names.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            pass
+
+    monkeypatch.setitem(tracer_mod._PROFILER_CLS, "TraceAnnotation", Ann)
+    monkeypatch.setitem(tracer_mod._PROFILER_CLS, "StepTraceAnnotation", Ann)
+    monkeypatch.setattr(tracer_mod, "_DEVICE_ANNOTATIONS", True)
+    sched = _sched(params)
+    _drive(sched)
+    assert len(sched.finished_requests) == 2
+    assert {"tick", "ds_tick", "pack", "prefill", "sample", "decode",
+            "engine/build_batch", "engine/ragged_step",
+            "engine/fetch_logits", "engine/decode_prep",
+            "engine/decode_step", "fetch", "advance"} == set(names)
+
+
+# --------------------------------------------------------------------- #
+# kernel names on the device
+# --------------------------------------------------------------------- #
+@functools.lru_cache(maxsize=None)
+def _lowered_calls(case_name):
+    """[(kernel function's name, text lowered for the TPU)] of every
+    ``pl.pallas_call`` a registered kernel case makes.  The call is
+    lowered as the library configured it but for the interpreter flag;
+    nothing runs (the case goes on with zeros)."""
+    from jax.experimental import pallas as pl
+
+    for mod in registry.KERNEL_MODULES:
+        importlib.import_module(mod)
+    real = pl.pallas_call
+    calls = []
+
+    def lowering_pallas_call(kernel, out_shape, **kw):
+        kw["interpret"] = False
+        inner = real(kernel, out_shape, **kw)
+        fn = kernel
+        while hasattr(fn, "func"):
+            fn = fn.func
+
+        def runner(*ops):
+            sds = [jax.ShapeDtypeStruct(o.shape, o.dtype) for o in ops]
+            with jax.disable_jit(False):
+                text = jax.jit(inner).trace(*sds).lower(
+                    lowering_platforms=("tpu",)).as_text(debug_info=True)
+            calls.append((fn.__name__, text))
+            return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
+                                out_shape)
+
+        return runner
+
+    pl.pallas_call = lowering_pallas_call
+    try:
+        with jax.disable_jit():
+            _CASES.get(case_name, registry.KERNEL_CASES[case_name].fn)()
+    finally:
+        pl.pallas_call = real
+    return calls
+
+
+def _quantizer_case():
+    """32 groups: the registered case's 64 make a rank-1 scale block of 32
+    of 64, which this jax's Mosaic lowering refuses (a block of rank 1
+    has to cover its array or be a multiple of 128)."""
+    from deepspeed_tpu.ops import quantizer
+
+    x = jnp.asarray(np.linspace(-1.0, 1.0, 32 * 512, dtype=np.float32))
+    quantizer._quantize_kernel_call(quantizer._group(x, 32))
+
+
+_CASES = {"quantizer_int8": _quantizer_case}
+
+
+_KERNEL_ENTRIES = [
+    ("flash_attention", "_fwd_kernel"),
+    ("flash_attention", "_bwd_dq_kernel"),
+    ("flash_attention", "_bwd_dkv_kernel"),
+    ("flash_attention_folded", "_fwd_kernel_folded"),
+    ("flash_attention_folded", "_bwd_dq_kernel_folded"),
+    ("flash_attention_folded", "_bwd_dkv_kernel_folded"),
+    ("flash_attention_paired", "_fwd_kernel_paired"),
+    ("flash_attention_paired", "_bwd_dq_kernel_paired"),
+    ("flash_attention_paired", "_bwd_dkv_kernel_paired"),
+    ("paged_attention_grid", "_kernel"),
+    ("paged_prefill", "_prefill_kernel"),
+    ("paged_decode_dma", "_decode_kernel"),
+    ("paged_verify_multiquery", "_verify_kernel"),
+    ("gmm_fwd", "_gmm_kernel"),
+    ("gmm_dlhs", "_gmm_dlhs_kernel"),
+    ("gmm_drhs", "_gmm_drhs_kernel"),
+    ("quantized_matmul", "_qmm_kernel"),
+    ("quantizer_int8", "_quantize_kernel"),
+    ("block_sparse_attention", "_fwd_kernel"),
+    ("block_sparse_attention", "_bwd_dq_kernel"),
+    ("block_sparse_attention", "_bwd_dkv_kernel"),
+    ("evoformer_attn", "_evo_kernel"),
+]
+
+
+@pytest.mark.parametrize("case, kernel", _KERNEL_ENTRIES,
+                         ids=[f"{c}-{k}" for c, k in _KERNEL_ENTRIES])
+def test_pallas_call_lowers_with_its_kernels_name(case, kernel):
+    calls = _lowered_calls(case)
+    mine = [(n, t) for n, t in calls if n == kernel
+            or (kernel.startswith("_fwd_kernel") and n == kernel + "_onepass")]
+    assert mine, (kernel, sorted({n for n, _ in calls}))
+    for name, text in mine:
+        assert "tpu_custom_call" in text
+        m = re.search(r'kernel_metadata = "(.*?)"\}', text, re.S)
+        assert m, "no kernel_metadata on the custom call"
+        # MLIR escapes a byte of a string attribute as a backslash and
+        # two hex digits
+        meta = re.sub(r"\\([0-9A-F]{2})",
+                      lambda h: chr(int(h.group(1), 16)), m.group(1))
+        assert re.sub(r"\s", "", meta) == '{"kernel":"%s"}' % name
+        # the Mosaic kernel keeps the function's name too
+        assert f'kernel_name = "{name}"' in text
+
+
+def test_every_pallas_call_site_is_covered():
+    """No ``pallas_call`` without the names: each site passes
+    ``**kernel_names(...)``."""
+    root = pathlib.Path(__file__).resolve().parents[2] / "deepspeed_tpu"
+    sites = named = 0
+    for mod in registry.KERNEL_MODULES:
+        src = (root.parent / (mod.replace(".", "/") + ".py")).read_text()
+        sites += len(re.findall(r"pl\.pallas_call\(", src))
+        named += len(re.findall(r"\*\*kernel_names\(", src))
+    assert sites == named == 22
+
+
+def test_paged_wrappers_keep_their_instruction_names():
+    """The accepted readers match ``paged_*attention`` at the start of the
+    Mosaic call's HLO instruction name, which XLA takes from the jitted
+    wrapper while no ``name=`` is passed: the four paged kernels pass
+    metadata only."""
+    for case in ("paged_attention_grid", "paged_prefill", "paged_decode_dma",
+                 "paged_verify_multiquery"):
+        for name, text in _lowered_calls(case):
+            loc = re.search(r'loc\("([^"]*pallas_call)"', text)
+            assert loc and f"{name}/pallas_call" not in loc.group(1), loc
+    # ... and the others pass name= too, which reaches the op_name
+    (name, text), = _lowered_calls("quantized_matmul")
+    assert re.search(r'loc\("[^"]*_qmm_kernel/pallas_call"', text)
+
+
+def test_step_programs_carry_their_names(traced):
+    """Each jitted step program is named for what it is: the name heads
+    every ``op_name`` of a trace."""
+    _, sched = traced
+    eng = sched.engine
+    got = {key: re.search(r"module @(\w+)", eng.lower_step(key).as_text())
+           .group(1) for key in eng.step_keys}
+    assert got[("decode_step",)] == "jit_decode_step"
+    assert got[(16, None)] == "jit_ragged_step_T16"
+    assert set(got.values()) <= {"jit_decode_step", "jit_ragged_step_T16",
+                                 "jit_ragged_step_T32"}
+    text = eng.lower_step(("decode_step",)).as_text(debug_info=True)
+    for scope in ("layers_0/attn/qkv", "layers_0/attn/rope_insert",
+                  "layers_1/mlp", "lm_head", "sample_argmax", "embed"):
+        assert f'"jit(decode_step)/{scope}' in text, scope
+    # off the TPU the decode read is the XLA gather composition
+    assert "layers_0/attn/gather_read" in text or \
+        "layers_0/attn/dense_read" in text
+
+
+def test_engines_key_the_compile_cache_on_names(params):
+    """The persistent compile cache's default key strips debug information,
+    so a program that differs from a cached one only in its scopes would
+    load the old executable and show the old names: building either engine
+    puts names and locations into the key."""
+    import sys
+
+    import deepspeed_tpu
+    from deepspeed_tpu.parallel import groups
+
+    sys.path.insert(0, str(pathlib.Path(__file__).parent))
+    from simple_model import SimpleModel
+
+    flag = "jax_compilation_cache_include_metadata_in_key"
+    model = SimpleModel(hidden_dim=8)
+    for build in (lambda: _sched(params),
+                  lambda: deepspeed_tpu.initialize(
+                      model=(model.init, model.apply),
+                      config={"train_micro_batch_size_per_gpu": 2,
+                              "optimizer": {"type": "Adam",
+                                            "params": {"lr": 1e-2}}})):
+        jax.config.update(flag, False)
+        build()
+        assert getattr(jax.config, flag) is True
+    groups.reset()
