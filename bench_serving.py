@@ -450,10 +450,11 @@ def measure_scheduler(n_requests: int = 32, rate_rps: float = 16.0,
 
     # warmup: replay a small burst of the SAME workload (same prompt
     # length / generation length / concurrency) through a throwaway
-    # scheduler, so every bucket/tile program the measured loop packs —
-    # lone tiled prefills, mixed decode+chunk untiled batches, the small
-    # decode buckets — is compiled before the clock starts (programs are
-    # cached on the shared engine)
+    # scheduler, so every program the measured loop packs — the
+    # two-segment put programs (single-token rows + whole tiles) of each
+    # row count, or the token buckets of an engine whose budget is no
+    # whole number of tiles — is compiled before the clock starts
+    # (programs are cached on the shared engine)
     warm = ContinuousBatchScheduler(engine)
     n_warm = min(clients, n_requests)
     warm.run_with_arrivals(prompts[:n_warm], [0.0] * n_warm,

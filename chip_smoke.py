@@ -257,6 +257,32 @@ def attention_route(lowered_text: str, require_chip: bool,
     return route
 
 
+def check_put_routes(routes: Dict[str, Dict[str, int]], max_seqs: int,
+                     require_chip: bool) -> None:
+    """The ``put`` programs of a serving run whose token budget is whole
+    tiles: each is a two-segment program (``prefill_T<rows>_tiled``, rows =
+    ``max_seqs`` single-token rows + whole tiles), mixed ticks included,
+    and on the chip its tiles go through ``_prefill_kernel`` and nothing
+    of it through the token-grid ``_kernel`` (PR 21 recorded
+    ``prefill_T1024 -> _kernel`` for every tick with a decode in it)."""
+    puts = {n: r for n, r in routes.items() if n.startswith("prefill_T")}
+    tiled = {n: r for n, r in puts.items() if n.endswith("_tiled")}
+    if not tiled:
+        return                  # a budget that is no whole number of tiles
+    if len(tiled) != len(puts):
+        raise SmokeFailure(f"serve: a tiled engine ran untiled programs: "
+                           f"{sorted(set(puts) - set(tiled))}")
+    if not require_chip:
+        return
+    for name, route in tiled.items():
+        rows = int(name[len("prefill_T"):-len("_tiled")])
+        if "_kernel" in route or \
+                (rows > max_seqs) != ("_prefill_kernel" in route):
+            raise SmokeFailure(
+                f"serve: {name} took the route {route}; its tiles belong "
+                f"to _prefill_kernel and no row to the token-grid _kernel")
+
+
 # --------------------------------------------------------------------- #
 # Phase: train
 # --------------------------------------------------------------------- #
@@ -514,6 +540,7 @@ def serve_phase(sizes: SmokeSizes, devices, require_chip: bool,
                                        require_chip, name)
     if "decode_step" not in routes:
         raise SmokeFailure("serve: no pure-decode tick ran")
+    check_put_routes(routes, sizes.max_seqs, require_chip)
 
     shards = {
         "params": shard_report(params, devices, "serving weights"),

@@ -171,11 +171,14 @@ class InferenceEngineV2:
             self.state_manager.kv_cache.cache = jax.tree.map(
                 lambda x: jax.device_put(x, kv_sh),
                 self.state_manager.kv_cache.cache)
-        # Token-dim buckets: a decode step (a handful of tokens) compiles
-        # to a SMALL program instead of the prefill-sized one — the paged
-        # kernel's grid is proportional to the token capacity, so running
-        # every decode at the full SplitFuse budget costs a prefill's grid
-        # per generated token. Powers-of-4 keeps compile count low.
+        # Token-dim buckets of an engine that packs chunks back to back
+        # (its budget is no whole number of PREFILL_TILEs; a tiled engine
+        # sizes its programs in ``_build_batch``): a decode step (a handful
+        # of tokens) compiles to a SMALL program instead of the
+        # prefill-sized one — the paged kernel's grid is proportional to
+        # the token capacity, so running every decode at the full SplitFuse
+        # budget costs a prefill's grid per generated token. Powers-of-4
+        # keeps compile count low.
         budget = sm_cfg.max_ragged_batch_size
         self._buckets = sorted({b for b in (16, 64, 256, 1024)
                                 if b < budget} | {budget})
@@ -217,12 +220,26 @@ class InferenceEngineV2:
 
     def can_schedule(self, uids: Sequence[int],
                      lengths: Sequence[int]) -> bool:
-        """Would scheduling `lengths[i]` new tokens for `uids[i]` fit the
-        token budget, sequence slots, and free KV blocks right now?"""
+        """Would scheduling `lengths[i]` new tokens for `uids[i]` fit ONE
+        ``put`` forward right now: the token budget, the sequence slots,
+        the rows of the two-segment layout (a chunk longer than one token
+        takes whole tiles, see ``_build_batch``) and the free KV blocks?"""
         if len(uids) > self._batch.max_seqs:
             return False
         if sum(lengths) > self._batch.token_budget:
             return False
+        tile = self._prefill_tile()
+        if tile and sum(-(-n // tile) * tile for n in lengths
+                        if n > 1) > self._batch.token_budget:
+            return False
+        return self.can_allocate(uids, lengths)
+
+    def can_allocate(self, uids: Sequence[int],
+                     lengths: Sequence[int]) -> bool:
+        """Do `lengths[i]` more tokens for `uids[i]` fit the free KV blocks
+        and ``max_context``?  The part of :meth:`can_schedule` that holds
+        for any step program (``verify_step`` feeds K tokens a sequence
+        without going through ``put``'s row layout)."""
         max_context = self.config.state_manager.max_context
         blocks = 0
         for uid, n in zip(uids, lengths):
@@ -297,7 +314,10 @@ class InferenceEngineV2:
 
     def _get_step(self, bucket: int, prefill_tile: Optional[int] = None):
         """One jitted (model fwd ∘ metadata unpack) program per
-        (token bucket, tile mode); the KV pool is donated."""
+        (rows of the token buffer, tile mode); the KV pool is donated.
+        With ``prefill_tile`` the rows are the two-segment layout of
+        ``_build_batch``: ``max_seqs`` single-token rows, then whole
+        tiles."""
         key = (bucket, prefill_tile)
         step = self._steps.get(key)
         if step is None:
@@ -321,29 +341,32 @@ class InferenceEngineV2:
                    and self.state_manager.get_sequence(u).pending
                    for u in uids)
 
-    #: q-tile for the tiled prefill kernel (the reference atom_builder's
-    #: work-unit height); chunks pack tile-aligned when every scheduled
-    #: chunk is at least this long, so the alignment padding never exceeds
-    #: 50% of the scheduled tokens
+    #: q-tile of the tiled prefill kernel (the reference atom_builder's
+    #: work-unit height).  An engine whose token budget is a whole number
+    #: of tiles packs EVERY ragged batch in the two-segment layout: the
+    #: chunks of one token in ``max_seqs`` rows at the front, every longer
+    #: chunk tile-aligned behind them.  Which segment a chunk goes to is
+    #: read from its length, so a tick that mixes decodes with prefill
+    #: chunks still runs its prefill through the tiled kernel.
     PREFILL_TILE = 128
+
+    def _prefill_tile(self) -> Optional[int]:
+        """The tile of this engine's ragged batches, or None when the
+        token budget does not divide into tiles (such an engine packs
+        chunks back to back and attends through the token-grid kernel)."""
+        tile, budget = self.PREFILL_TILE, self._batch.token_budget
+        return tile if budget >= tile and budget % tile == 0 else None
 
     def _build_batch(self, uids):
         """One ragged batch under the token budget: SplitFuse chunking, KV
         allocation, the metadata and its ONE upload.  Returns ``(scheduled
-        uids, drained flags, token bucket, prefill tile or None, packed
-        device metadata)``, or None when nothing is pending."""
+        uids, drained flags, rows of the step program, prefill tile or
+        None, packed device metadata)``, or None when nothing is
+        pending."""
         sm = self.state_manager
         self._batch.clear()
-        # tiled-prefill mode: every live chunk long enough that aligning
-        # each to a PREFILL_TILE boundary wastes < half the budget
-        tile = self.PREFILL_TILE
-        pend = [len(sm.get_sequence(u).pending) for u in uids
-                if sm.get_sequence(u) is not None
-                and sm.get_sequence(u).pending]
-        use_tiles = (bool(pend) and min(pend) >= tile
-                     and self._batch.token_budget >= tile
-                     and self._batch.token_budget % tile == 0)
-        if use_tiles:
+        tile = self._prefill_tile()
+        if tile:
             self._batch.set_alignment(tile)
         scheduled: List[int] = []
         drained: List[bool] = []
@@ -351,34 +374,34 @@ class InferenceEngineV2:
             seq = sm.get_sequence(uid)
             if seq is None or not seq.pending:
                 continue
-            # room from the (tile-aligned, in tiled mode) next chunk start
-            room = self._batch.token_budget - self._batch._next_start()
-            if room <= 0 or self._batch.current_sequences >= \
-                    self._batch.max_seqs:
-                break
-            chunk = seq.pending[:room]               # Dynamic SplitFuse
-            sm.maybe_allocate_kv(seq, len(chunk))
+            n = self._batch.fit(len(seq.pending))    # Dynamic SplitFuse
+            if n == 0:
+                continue     # a later one-token chunk may still have a row
+            chunk = seq.pending[:n]
+            sm.maybe_allocate_kv(seq, n)
             self._batch.insert_sequence(seq, np.asarray(chunk, np.int32))
             scheduled.append(uid)
-            drained.append(len(chunk) == len(seq.pending))
+            drained.append(n == len(seq.pending))
         if not scheduled:
             return None
 
         from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import (
             pack_metadata)
 
-        if use_tiles:
-            # the tiled kernel needs a tile-divisible token capacity
-            cands = [b for b in self._buckets if b % tile == 0] + [tile]
-            bucket = min(b for b in cands
-                         if b >= self._batch.current_tokens)
+        if tile:
+            # tiled segment: tile x 2^k rows up to the budget, or none
+            need = self._batch.tiled_rows
+            tiled = tile if need else 0
+            while tiled < need:
+                tiled *= 2
+            bucket = self._batch.max_seqs + min(
+                tiled, self._batch.token_budget)
         else:
             bucket = min(b for b in self._buckets
                          if b >= self._batch.current_tokens)
         meta = self._batch.finalize(bucket)
         packed = jnp.asarray(pack_metadata(meta))  # ONE upload
-        return scheduled, drained, bucket, tile if use_tiles else None, \
-            packed
+        return scheduled, drained, bucket, tile, packed
 
     def _run_one_batch(self, uids, sync: bool = True) -> Dict[int, np.ndarray]:
         """Build one ragged batch under the token budget (SplitFuse
@@ -391,7 +414,7 @@ class InferenceEngineV2:
                 return {}
             scheduled, drained, bucket, tile, packed = built
             if type(span) is SpanHandle:
-                # useful tokens of the bucket they are padded to
+                # useful tokens of the rows they are padded to
                 span.attrs = {"tokens": self._batch.current_tokens,
                               "bucket": bucket}
         # host↔device alignment: a jax.profiler capture shows this named
@@ -867,7 +890,7 @@ class InferenceEngineV2:
     def lower_step(self, key: tuple):
         """One already-built step program lowered over abstract shapes
         (nothing runs, the live cache is not donated).  ``key`` is the
-        program's key in ``step_keys``: ``(token_bucket, prefill_tile)``
+        program's key in ``step_keys``: ``(buffer_rows, prefill_tile)``
         for the ragged ``put`` step, ``("decode_step",)``,
         ``("verify_step", K)`` or ``("decode_loop", steps)``."""
         S, B = self._batch.max_seqs, self._max_blocks

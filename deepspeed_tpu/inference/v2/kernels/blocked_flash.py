@@ -11,7 +11,24 @@ is ever materialised (the XLA reference path builds a [T, C, Hkv, D]
 gather; this kernel's live set is one [block_size, Hkv, D] block plus the
 accumulators).
 
-Grid: (tokens, blocks_per_sequence); the block axis is innermost and
+Four kernels, by the shape of the rows they serve (the route is
+``ragged_llama._paged_attention``'s):
+
+* ``_prefill_kernel`` — every ``put`` forward of an engine whose token
+  budget is a whole number of tiles, mixed ticks included: the engine packs
+  each chunk longer than one token tile-aligned (``RaggedBatchWrapper.
+  set_alignment``), so the grid is (tiles, blocks) with bf16 MXU dots on
+  [tile, D] x [block, D];
+* ``_decode_kernel`` — one token a row (a decode step, or the single-token
+  rows of such a forward) on a pool larger than the live contexts: one grid
+  step per row, a manual double-buffered DMA walk over the live blocks;
+* ``_verify_kernel`` — K rows per sequence sharing that walk (speculation);
+* ``_kernel`` — the generic grid (tokens, blocks_per_sequence), one token's
+  [H, D] query against one block per step: engines whose budget is no whole
+  number of tiles, verify at head sizes the DMA walk cannot copy, and the
+  single-token rows on a big pool at such head sizes.
+
+In all of them the block axis is innermost and
 sequential on TPU, so fp32 online-softmax accumulators live in VMEM
 scratch across it (same structure as ops/flash_attention.py). Invalid
 table slots (past a sequence's length) are masked by position — their DMA
@@ -508,11 +525,13 @@ def paged_verify_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
 # ===================================================================== #
 # Tiled prefill (reference ragged_ops/atom_builder + blocked_flash: work
 # units are "atoms" = a q-tile of consecutive same-sequence tokens x a KV
-# block range). The engine packs prefill chunks TILE-ALIGNED in the token
-# buffer, so every [tile_q]-row stripe belongs to one sequence (pad rows
-# carry position -1 and mask to zero) — the grid is (tiles, blocks), not
-# (tokens, blocks): a 512-token prefill at tile 128 runs 4xB steps
-# instead of 512xB.
+# block range). The engine packs every chunk longer than one token
+# TILE-ALIGNED in the tiled segment of the token buffer (single-token
+# chunks — the decodes of a mixed tick — sit in their own rows in front of
+# it and never reach this kernel), so every [tile_q]-row stripe belongs to
+# one sequence (pad rows carry position -1 and mask to zero) — the grid is
+# (tiles, blocks), not (tokens, blocks): a 512-token prefill at tile 128
+# runs 4xB steps instead of 512xB.
 # ===================================================================== #
 def _prefill_kernel(tile_slot, tile_maxpos, tables, q_ref, pos_ref, k_ref,
                     v_ref, o_ref, acc_ref, m_ref, l_ref, *, block_size,
@@ -689,10 +708,12 @@ def paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         # clamp out-of-band block indices into the token's live band:
         # skipped iterations then revisit an already-resident pool block,
         # which the Pallas pipeline elides instead of DMAing garbage
-        jj = jnp.minimum(j, pos[t] // block_size)
+        # (pad rows of a two-segment batch carry position -1: block 0)
+        last = jnp.maximum(pos[t], 0) // block_size
+        jj = jnp.minimum(j, last)
         if window is not None:
             lo = jnp.maximum((pos[t] - window + 1) // block_size, 0)
-            jj = jnp.maximum(jj, jnp.minimum(lo, pos[t] // block_size))
+            jj = jnp.maximum(jj, jnp.minimum(lo, last))
         return (tab[slot[t], jj], 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -856,3 +877,49 @@ def _dslint_paged_prefill_case():
                             jnp.zeros((T,), jnp.int32),
                             jnp.arange(T, dtype=jnp.int32),
                             block_size=bs, tile_q=128, interpret=True)
+
+
+@pallas_kernel_case(
+    "paged_two_segment",
+    note="a mixed tick's batch at Mistral's head counts (32q/8kv, d=128) "
+         "through ragged_llama._paged_attention: 4 single-token rows "
+         "(slots in no order, two pads at position -1) take the decode "
+         "walk of a big pool, the tile-aligned chunks behind them (one "
+         "with a sub-tile tail) the tiled prefill kernel")
+def _dslint_paged_two_segment_case():
+    two_segment_case()
+
+
+def two_segment_case(tight_pool: bool = False):
+    """One two-segment batch through the kernel route (compiled on the
+    chip, interpreted off it) and through the XLA composition: ``(got,
+    want, mask of the real rows)``.  Shared with tools/kernel_selftest.py.
+    ``tight_pool`` sizes the pool under twice the table extent, where the
+    single-token rows take the dense read instead of the walk."""
+    import numpy as np
+
+    from deepspeed_tpu.inference.v2.model_implementations.ragged_llama \
+        import _paged_attention
+
+    bs, S, B, tile, h, hkv, d = 128, 4, 4, 128, 32, 8, 128
+    nb = S * B + 1 if tight_pool else 2 * S * B + 2
+    rng = np.random.default_rng(21)
+    pool = lambda: jnp.asarray(
+        rng.standard_normal((nb * bs, hkv, d)).astype(np.float32),
+        jnp.bfloat16)
+    kp, vp = pool(), pool()
+    tables = jnp.arange(1, S * B + 1, dtype=jnp.int32).reshape(S, B)
+    T = S + 3 * tile
+    slot = np.zeros((T,), np.int32)
+    pos = np.full((T,), -1, np.int32)
+    slot[0:2], pos[0:2] = (2, 0), (317, 200)       # two decodes, two pads
+    slot[S:S + 150], pos[S:S + 150] = 1, np.arange(100, 250)  # tile + tail
+    slot[S + 256:S + 384], pos[S + 256:S + 384] = 3, np.arange(0, 128)
+    q = jnp.asarray(rng.standard_normal((T, h, d)).astype(np.float32),
+                    jnp.bfloat16)
+    batch = {"block_tables": tables, "token_slot": jnp.asarray(slot),
+             "token_pos": jnp.asarray(pos)}
+    got = _paged_attention(q, kp, vp, batch, bs, use_kernel=True,
+                           prefill_tile=tile)
+    want = _paged_attention(q, kp, vp, batch, bs, use_kernel=False)
+    return got, want, pos >= 0

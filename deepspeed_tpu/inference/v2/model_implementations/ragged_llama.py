@@ -105,12 +105,18 @@ def _paged_attention(q, k_pool, v_pool, batch, block_size,
     Returns [T, H, D]. Under TP the caller passes LOCAL heads — the kernel
     is oblivious to the mesh. ``window`` = Mistral sliding-window width.
 
-    On TPU a PREFILL routes to the Pallas blocked-flash kernels
+    On TPU a ``put`` forward routes to the Pallas blocked-flash kernels
     (inference/v2/kernels/blocked_flash.py): block tables drive the
     kernel's DMA schedule, so no [T, C, Hkv, D] context gather is ever
-    materialised. ``prefill_tile`` (engine-set when the batch was packed
-    tile-aligned) selects the TILED kernel — grid (tiles, blocks) instead
-    of (tokens, blocks), the reference's atom_builder work-unit shape.
+    materialised.  ``prefill_tile`` (static; set by an engine that packs
+    the two-segment layout, ``RaggedBatchWrapper.set_alignment``) says the
+    first S rows (S = the block table's height) are single-token rows and
+    the rest whole tiles: the tiles go to the TILED kernel — grid (tiles,
+    blocks), bf16 MXU dots, the reference's atom_builder work-unit shape
+    — and the single-token rows to the read a decode step of this pool
+    takes (:func:`_single_row_read`).  Without it (a token budget that is
+    no whole number of tiles) the whole buffer goes to the token-grid
+    kernel, grid (tokens, blocks).
 
     ``decode_mode`` (static; engine decode programs set it) asserts
     T == S with ``token_slot == arange(S)``.  On TPU it routes to the
@@ -134,6 +140,13 @@ def _paged_attention(q, k_pool, v_pool, batch, block_size,
     quantized = k_scale is not None
     if use_kernel is None:
         use_kernel = on_tpu()
+    S, B = batch["block_tables"].shape
+    # the manual-DMA walk wins when the pool is LARGER than the live
+    # contexts (its read is O(live); the dense path's is O(pool) —
+    # crossover table in tools/profile_decode_attn.py: 4.28 vs 5.77 ms at
+    # pool 512 blk / ctx 2k).  Tight pools (pool ~ live, the serving-dense
+    # case) keep the dense read, which measured ~10% faster there.
+    big_pool = k_pool.shape[0] > 2 * S * B * block_size
     if use_kernel and force_dense is None:
         from deepspeed_tpu.inference.v2.kernels import (
             paged_attention, paged_attention_usable,
@@ -142,6 +155,8 @@ def _paged_attention(q, k_pool, v_pool, batch, block_size,
 
         if paged_attention_usable(q, k_pool, block_size):
             w = int(window) if window is not None else None
+            meta = (batch["block_tables"], batch["token_slot"],
+                    batch["token_pos"])
             if verify_k and q.shape[-1] % 128 == 0:
                 # speculative multi-token verify: K query rows per slot
                 # share one O(live-context) block walk (the fused
@@ -150,49 +165,41 @@ def _paged_attention(q, k_pool, v_pool, batch, block_size,
                 # head dims fall through to the generic grid kernel,
                 # which handles verify-shaped metadata unchanged.
                 return paged_verify_attention(
-                    q, k_pool, v_pool, batch["block_tables"],
-                    batch["token_slot"], batch["token_pos"],
-                    block_size=block_size, k_tokens=int(verify_k),
-                    window=w, k_scale=k_scale, v_scale=v_scale)
+                    q, k_pool, v_pool, *meta, block_size=block_size,
+                    k_tokens=int(verify_k), window=w, k_scale=k_scale,
+                    v_scale=v_scale)
             if decode_mode:
                 # the manual-DMA kernel copies [bs, Hkv, D] pool blocks,
-                # whose lane dim D must be 128-aligned, and it wins when
-                # the pool is LARGER than the live contexts (its read is
-                # O(live); the dense path's is O(pool) — crossover table
-                # in tools/profile_decode_attn.py: 4.28 vs 5.77 ms at
-                # pool 512 blk / ctx 2k).  Tight pools (pool ~ live, the
-                # serving-dense case) keep the dense read below, which
-                # measured ~10% faster there.  Quantized pools ALWAYS
-                # take the DMA kernel: the dense path would dequantize
-                # the whole pool, and the capacity regime int8 exists
-                # for (many spooled/idle sessions) is precisely
-                # pool >> live.
-                S_, B_ = batch["block_tables"].shape
-                big_pool = k_pool.shape[0] > 2 * S_ * B_ * block_size
+                # whose lane dim D must be 128-aligned.  Quantized pools
+                # ALWAYS take it: the dense path would dequantize the
+                # whole pool, and the capacity regime int8 exists for
+                # (many spooled/idle sessions) is precisely pool >> live.
                 if q.shape[-1] % 128 == 0 and (big_pool or quantized):
                     return paged_decode_attention(
-                        q, k_pool, v_pool, batch["block_tables"],
-                        batch["token_slot"], batch["token_pos"],
-                        block_size=block_size, window=w,
-                        k_scale=k_scale, v_scale=v_scale)
-            elif not quantized and prefill_tile \
-                    and q.shape[0] % prefill_tile == 0:
+                        q, k_pool, v_pool, *meta, block_size=block_size,
+                        window=w, k_scale=k_scale, v_scale=v_scale)
+            elif quantized:
                 # prefill kernels are not scale-aware (prefill is
                 # compute-bound — the int8 win is decode bandwidth);
                 # quantized prefill takes the XLA gather+dequant below
-                return paged_prefill_attention(
-                    q, k_pool, v_pool, batch["block_tables"],
-                    batch["token_slot"], batch["token_pos"],
+                pass
+            elif prefill_tile:
+                tables, slot, pos = meta
+                single = _single_row_read(
+                    q[:S], k_pool, v_pool, tables, slot[:S], pos[:S],
+                    block_size, w, big_pool)
+                if q.shape[0] == S:          # no chunk longer than a token
+                    return single
+                return jnp.concatenate([single, paged_prefill_attention(
+                    q[S:], k_pool, v_pool, tables, slot[S:], pos[S:],
                     block_size=block_size, tile_q=int(prefill_tile),
-                    window=w)
-            elif not quantized:
+                    window=w)])
+            else:
                 return paged_attention(
-                    q, k_pool, v_pool, batch["block_tables"],
-                    batch["token_slot"], batch["token_pos"],
-                    block_size=block_size, window=w)
-    S, B = batch["block_tables"].shape
+                    q, k_pool, v_pool, *meta, block_size=block_size,
+                    window=w)
     if decode_mode and (force_dense if force_dense is not None
-                        else k_pool.shape[0] <= 2 * S * B * block_size):
+                        else not big_pool):
         with jax.named_scope("attn/dense_read"):
             return _dense_pool_read(q, k_pool, v_pool, k_scale, v_scale,
                                     batch, block_size, window)
@@ -201,9 +208,35 @@ def _paged_attention(q, k_pool, v_pool, batch, block_size,
                             block_size, window, decode_mode)
 
 
+def _single_row_read(q, k_pool, v_pool, tables, slot, pos, block_size,
+                     window, big_pool):
+    """The single-token segment of a two-segment batch on TPU (float
+    pool): the read a decode step of the same pool takes — the manual-DMA
+    walk over live blocks on a big pool, the dense read on a tight one —
+    except that the rows' slots are in no order, which both take, and
+    that a big pool at a head size the DMA walk cannot copy (D % 128)
+    sends these few rows through the token-grid kernel."""
+    from deepspeed_tpu.inference.v2.kernels import (paged_attention,
+                                                    paged_decode_attention)
+
+    if big_pool:
+        walk = (paged_decode_attention if q.shape[-1] % 128 == 0
+                else paged_attention)
+        return walk(q, k_pool, v_pool, tables, slot, pos,
+                    block_size=block_size, window=window)
+    with jax.named_scope("attn/dense_read"):
+        return _dense_pool_read(
+            q, k_pool, v_pool, None, None,
+            {"block_tables": tables, "token_slot": slot, "token_pos": pos},
+            block_size, window)
+
+
 def _dense_pool_read(q, k_pool, v_pool, k_scale, v_scale, batch, block_size,
                      window):
-    """The decode read on a tight pool (device scope ``attn/dense_read``)."""
+    """The one-token-a-row read on a tight pool (device scope
+    ``attn/dense_read``): a decode step's rows, or the single-token rows
+    of a two-segment batch, whose slots are in no order and whose pad rows
+    carry position -1 (they attend nothing and come out finite)."""
     from deepspeed_tpu.inference.v2.ragged.kv_cache import dequantize_kv
 
     quantized = k_scale is not None
@@ -453,7 +486,8 @@ class RaggedLlama:
 
         Returns ``(logits [S, vocab], new_kv_cache)`` where row ``s`` holds
         the logits of slot ``s``'s LAST scheduled token. ``prefill_tile``
-        (static) marks a tile-aligned batch -> tiled prefill kernel;
+        (static) marks a two-segment batch (single-token rows, then whole
+        tiles) -> dense/decode read + tiled prefill kernel;
         ``decode`` (static) marks a one-token-per-slot batch with
         ``token_slot == arange`` -> decode-optimised attention path;
         ``verify_k`` (static) marks a speculative verify batch — K

@@ -14,6 +14,11 @@ budget) packing tokens from up to ``max_seqs`` sequences::
     context_lens [max_seqs] int32  tokens valid after this forward
     logits_idx   [max_seqs] int32  index in [T] of each slot's last token
     kv_dest      [T] int32  flat pool index for each token's KV write
+
+Chunks sit back to back in the buffer, or — after ``set_alignment``, which
+the engine calls whenever its token budget is a whole number of prefill
+tiles — in two segments: ``max_seqs`` rows for the chunks of one token,
+then every longer chunk on a tile boundary (pad rows at position -1).
 """
 
 from __future__ import annotations
@@ -114,44 +119,75 @@ class RaggedBatchWrapper:
         self._starts: List[int] = []
         self._tokens_used = 0
         self._align = 0
+        self._singles = 0
+        self._tiled_used = 0
 
     def set_alignment(self, align: int) -> None:
-        """Tile-align chunk starts (the prefill kernel's contract: every
-        [align]-row stripe of the token buffer is single-sequence; pad
-        rows carry position -1). Call right after clear(); alignment
-        padding counts against the token budget."""
+        """Lay the batch out in two segments (the tiled prefill kernel's
+        contract).  Rows ``[0, max_seqs)`` are the single-token segment:
+        every chunk of exactly one token (the decodes of a mixed tick, a
+        prompt's one-token tail) takes one row there.  Behind it, in the
+        tiled segment, every longer chunk starts on an ``align`` boundary,
+        so each [align]-row stripe is single-sequence — a prompt's last
+        chunk may be shorter than a tile.  Pad rows of both segments carry
+        position -1.  Call right after clear().  Alignment padding is rows,
+        not tokens: the token budget still counts real tokens, and the
+        tiled segment holds at most ``token_budget`` rows."""
         if self._seqs:
             raise RuntimeError("set_alignment before inserting sequences")
         self._align = int(align)
 
     @property
     def current_tokens(self) -> int:
+        """Real (unpadded) tokens scheduled so far."""
         return self._tokens_used
+
+    @property
+    def tiled_rows(self) -> int:
+        """Rows the tiled segment needs, whole tiles (0 when unaligned or
+        when every chunk is a single token)."""
+        return self._tile_up(self._tiled_used)
 
     @property
     def current_sequences(self) -> int:
         return len(self._seqs)
 
-    def _next_start(self) -> int:
-        if self._align <= 1:
-            return self._tokens_used
-        a = self._align
-        return ((self._tokens_used + a - 1) // a) * a
+    def _tile_up(self, rows: int) -> int:
+        a = max(self._align, 1)
+        return -(-rows // a) * a
+
+    def fit(self, n_tokens: int) -> int:
+        """How many of a sequence's ``n_tokens`` pending tokens its chunk
+        may take now (Dynamic SplitFuse); 0 when the batch has no room."""
+        if len(self._seqs) >= self.max_seqs:
+            return 0
+        n = min(n_tokens, self.token_budget - self._tokens_used)
+        if self._align > 1 and n > 1:
+            # a longer chunk starts on the next tile boundary
+            n = min(n, self.token_budget - self.tiled_rows)
+        return n
 
     def can_fit(self, n_tokens: int) -> bool:
-        return (self._next_start() + n_tokens <= self.token_budget
-                and len(self._seqs) < self.max_seqs)
+        return self.fit(n_tokens) == n_tokens
 
     def insert_sequence(self, seq: DSSequenceDescriptor,
                         tokens: np.ndarray) -> None:
         """reference ``insert_sequence``: add one sequence's chunk."""
-        if not self.can_fit(len(tokens)):
+        n = len(tokens)
+        if not self.can_fit(n):
             raise RuntimeError("ragged batch full")
-        start = self._next_start()
+        if self._align <= 1:
+            start = self._tokens_used
+        elif n == 1:
+            start = self._singles
+            self._singles += 1
+        else:
+            start = self.max_seqs + self.tiled_rows
+            self._tiled_used = start - self.max_seqs + n
         self._seqs.append(seq)
         self._chunks.append(np.asarray(tokens, np.int32))
         self._starts.append(start)
-        self._tokens_used = start + len(tokens)
+        self._tokens_used += n
 
     def finalize(self, token_capacity: int = None):
         """Build the device metadata (reference ``finalize``: host->device
@@ -162,10 +198,11 @@ class RaggedBatchWrapper:
         compiles to a small program instead of the prefill-sized one.
         """
         T = token_capacity if token_capacity is not None else self.token_budget
-        if self._tokens_used > T:
+        rows = (self.max_seqs + self.tiled_rows if self._align > 1
+                else self._tokens_used)
+        if rows > T:
             raise ValueError(
-                f"finalize: {self._tokens_used} scheduled tokens exceed "
-                f"token capacity {T}")
+                f"finalize: {rows} scheduled rows exceed token capacity {T}")
         if RAGGED_DEBUG:
             validate_ragged_metadata(self._seqs, self._chunks,
                                      self.block_size)
@@ -173,8 +210,8 @@ class RaggedBatchWrapper:
         bs = self.block_size
         token_ids = np.zeros((T,), np.int32)
         token_slot = np.zeros((T,), np.int32)
-        # aligned mode: pads carry position -1 so both kernels and the XLA
-        # path mask them to zero rows
+        # two-segment mode: pads carry position -1 so the kernels and the
+        # XLA path mask them out
         token_pos = np.full((T,), -1 if self._align > 1 else 0, np.int32)
         kv_dest = np.full((T,), TRASH * bs, np.int32)  # pads -> trash block
         block_tables = np.full((S, B), TRASH, np.int32)

@@ -11,7 +11,8 @@ under the fixed token budget:
 2. then SplitFuse prefill chunks — mid-prefill continuations, preempted
    requests being resumed (recompute), and new admissions — each sized by
    binary search against ``engine.can_schedule()`` to fill the remaining
-   budget without overcommitting KV blocks or sequence slots.
+   budget without overcommitting KV blocks, sequence slots or the rows
+   of the engine's tile-aligned layout, so the tick is ONE forward.
 
 KV pressure: when the decode set itself no longer fits (every decode token
 may need a fresh block), the scheduler preempts the lowest-priority /
@@ -603,7 +604,7 @@ class ContinuousBatchScheduler:
                  if spec.autotune_k or self.spec_k_cap is not None
                  else spec.draft_k)
         K = gamma + 1
-        if not self.engine.can_schedule(uids, [K] * len(uids)):
+        if not self.engine.can_allocate(uids, [K] * len(uids)):
             return None                  # lookahead KV/context won't fit
         feed = [[r.history[-1]] + d + [0] * (gamma - len(d))
                 for r, d in zip(packed, drafts)]

@@ -31,7 +31,7 @@ def _tiny_sizes():
         model_config=mistral_tiny(dtype=jnp.bfloat16,
                                   max_position_embeddings=256),
         train_layers=2, train_seq=64, train_steps=5,
-        serve_layers=2, block_size=8, token_budget=32, max_seqs=8,
+        serve_layers=2, block_size=8, token_budget=128, max_seqs=8,
         prompt_lens=(5, 9, 17, 30, 44, 61, 75, 90),
         new_tokens=(8, 6, 4, 8, 6, 4, 8, 6),
         check_prompt_len=24)
@@ -63,7 +63,10 @@ def test_smoke_phases_tiny_on_cpu_mesh(monkeypatch):
     assert serve["requests"] == 16
     assert serve["logit_err_vs_xla"] <= chip_smoke.SERVE_LOGIT_TOL
     assert "decode_step" in serve["attention_route"]
-    assert any(k.startswith("prefill_T") for k in serve["attention_route"])
+    # a budget of one whole tile: every put program, mixed ticks included,
+    # is the two-segment one of 8 single-token rows and that tile
+    assert {k for k in serve["attention_route"] if k != "decode_step"} == \
+        {"prefill_T136_tiled"}
     assert len(serve["ttft_s"]) == 8 and len(serve["tpot_s"]) == 8
     for tree in serve["state_shards"].values():
         assert len(tree["per_device_bytes"]) == 2
@@ -79,6 +82,22 @@ def test_smoke_gates_fail_loudly():
             '{backend_config = "...", kernel_name = "_fwd_kernel"}')
     assert chip_smoke.attention_route(text, True, "step") == \
         {"_fwd_kernel": 1}
+    # a mixed tick's program: tiles through _prefill_kernel, single-token
+    # rows through the decode walk (or the dense XLA read), never _kernel
+    good = {"decode_step": {"_decode_kernel": 1},
+            "prefill_T8_tiled": {"_decode_kernel": 1},
+            "prefill_T1032_tiled": {"_decode_kernel": 1,
+                                    "_prefill_kernel": 1}}
+    chip_smoke.check_put_routes(good, 8, True)
+    chip_smoke.check_put_routes({"prefill_T64": {"_kernel": 1}}, 8, True)
+    for bad in ({"prefill_T1032_tiled": {"_kernel": 1}},
+                {"prefill_T1032_tiled": {"_decode_kernel": 1}},
+                {"prefill_T1032_tiled": {"_prefill_kernel": 1, "_kernel": 1}},
+                {"prefill_T8_tiled": {"_kernel": 1}}):
+        with pytest.raises(chip_smoke.SmokeFailure, match="token-grid"):
+            chip_smoke.check_put_routes({**good, **bad}, 8, True)
+    with pytest.raises(chip_smoke.SmokeFailure, match="untiled"):
+        chip_smoke.check_put_routes({**good, "prefill_T16": {}}, 8, False)
     # everything on the first device, or replicated everywhere, fails
     devs = jax.devices()[:2]
     one = {"w": jax.device_put(jnp.ones((64, 64)), devs[0])}

@@ -751,21 +751,246 @@ def test_tiled_prefill_kernel_window_matches_xla():
                                rtol=2e-5, atol=2e-5)
 
 
-def test_engine_tiled_prefill_matches_sequential():
-    """Long prompts trigger tile-aligned packing + the tiled kernel path;
-    tokens must equal the v1 reference exactly."""
+@pytest.mark.parametrize("d,nb,single", [
+    (128, 40, "paged_decode_attention"),      # big pool, D % 128 == 0
+    (16, 40, "paged_attention"),              # big pool, small heads
+    (16, 12, "_dense_pool_read"),             # tight pool
+])
+def test_two_segment_attention_routes_match_xla(monkeypatch, d, nb, single):
+    """``_paged_attention`` on a two-segment buffer: the S single-token rows
+    (slots in no order, pad rows at position -1) take the read a decode
+    step of that pool takes, the tiles the tiled kernel; every real row
+    equals the XLA gather composition."""
+    from deepspeed_tpu.inference.v2 import kernels
+    from deepspeed_tpu.inference.v2.model_implementations import ragged_llama
+
+    rng = np.random.default_rng(19)
+    bs, hkv, h, tile, S = 8, 2, 4, 16, 4
+    k_pool = jnp.asarray(rng.normal(size=(nb * bs, hkv, d)).astype(np.float32))
+    v_pool = jnp.asarray(rng.normal(size=(nb * bs, hkv, d)).astype(np.float32))
+    tables = jnp.asarray([[1, 2, 3, 4], [5, 6, 0, 0], [7, 8, 9, 0],
+                          [10, 11, 0, 0]], jnp.int32)
+    T = S + 3 * tile
+    token_slot = np.zeros((T,), np.int32)
+    token_pos = np.full((T,), -1, np.int32)
+    # single-token rows: slot 2 at position 20, slot 0 at 30; two pad rows
+    token_slot[0:2], token_pos[0:2] = (2, 0), (20, 30)
+    # tiles: slot 1 positions 0..8 (one tile), slot 3 positions 3..15+5
+    token_slot[S:S + 9], token_pos[S:S + 9] = 1, np.arange(0, 9)
+    token_slot[S + 16:S + 34], token_pos[S + 16:S + 34] = 3, np.arange(1, 19)
+    q = jnp.asarray(rng.normal(size=(T, h, d)).astype(np.float32))
+    batch = {"block_tables": tables, "token_slot": jnp.asarray(token_slot),
+             "token_pos": jnp.asarray(token_pos)}
+    ran = []
+    for mod, name in ((kernels, "paged_decode_attention"),
+                      (kernels, "paged_attention"),
+                      (kernels, "paged_prefill_attention"),
+                      (ragged_llama, "_dense_pool_read")):
+        def spy(*a, _f=getattr(mod, name), _n=name, **kw):
+            ran.append(_n)
+            return _f(*a, **kw)
+        monkeypatch.setattr(mod, name, spy)
+    for window in (None, 12):
+        ref = ragged_llama._paged_attention(q, k_pool, v_pool, batch, bs,
+                                            use_kernel=False, window=window)
+        del ran[:]
+        got = ragged_llama._paged_attention(q, k_pool, v_pool, batch, bs,
+                                            use_kernel=True, window=window,
+                                            prefill_tile=tile)
+        assert ran == [single, "paged_prefill_attention"]
+        real = token_pos >= 0
+        assert got.shape == ref.shape and np.isfinite(np.asarray(got)).all()
+        np.testing.assert_allclose(np.asarray(got)[real],
+                                   np.asarray(ref)[real],
+                                   rtol=2e-5, atol=2e-5)
+    # a batch of single-token chunks only: no tiled segment at all
+    head = {k: v[:S] if k != "block_tables" else v for k, v in batch.items()}
+    del ran[:]
+    got = ragged_llama._paged_attention(q[:S], k_pool, v_pool, head, bs,
+                                        use_kernel=True, prefill_tile=tile)
+    assert ran == [single]
+    np.testing.assert_allclose(
+        np.asarray(got)[:2],
+        np.asarray(ragged_llama._paged_attention(
+            q[:S], k_pool, v_pool, head, bs, use_kernel=False))[:2],
+        rtol=2e-5, atol=2e-5)
+
+
+def _tiled_scheduler(monkeypatch, params, cfg=CFG, token_budget=64,
+                     num_blocks=None, prefix_cache=False):
+    """A scheduler over an engine that packs the two-segment layout at
+    tile 16 and attends through the Pallas kernels in interpret mode (the
+    route ``_paged_attention`` takes on a TPU), with the calls of the two
+    ``put`` kernels counted as they are traced."""
+    from deepspeed_tpu.inference.v2 import kernels
+    from deepspeed_tpu.inference.v2.model_implementations import ragged_llama
+    from deepspeed_tpu.observability import Tracer
+    from deepspeed_tpu.serving import ContinuousBatchScheduler
+
+    monkeypatch.setattr(ragged_llama, "on_tpu", lambda: True)
+    calls = {"paged_prefill_attention": 0, "paged_attention": 0}
+    for name in calls:
+        def counted(*a, _f=getattr(kernels, name), _n=name, **kw):
+            calls[_n] += 1
+            return _f(*a, **kw)
+        monkeypatch.setattr(kernels, name, counted)
+    eng_cfg = RaggedInferenceEngineConfig.from_dict({
+        "state_manager": {"max_ragged_batch_size": token_budget,
+                          "max_ragged_sequence_count": 4,
+                          "max_context": 64},
+        "kv_cache": {"block_size": 8, "enable_prefix_cache": prefix_cache,
+                     **({"num_blocks": num_blocks} if num_blocks else {})}})
+    eng = InferenceEngineV2(RaggedLlama(cfg, 8), params, eng_cfg)
+    eng.PREFILL_TILE = 16
+    tr = Tracer()
+    return ContinuousBatchScheduler(eng, tracer=tr), eng, tr, calls
+
+
+def _tick_spans(tr):
+    """[(kind of the tick, {span name: [records under it]})], oldest
+    first."""
+    recs = [r for r in tr.records() if r["ph"] == "X"]
+    by_id = {r["span_id"]: r for r in recs}
+    ticks = {r["span_id"]: (r["attrs"].get("kind"), {}) for r in recs
+             if r["name"] == "tick"}
+    for r in recs:
+        up = r
+        while up.get("parent") is not None:
+            up = by_id[up["parent"]]
+        if up is not r and up["span_id"] in ticks:
+            ticks[up["span_id"]][1].setdefault(r["name"], []).append(r)
+    return list(ticks.values())
+
+
+def _sequential_reference(params, prompts, n_new, cfg=CFG):
+    """Greedy tokens of each prompt served alone, on the XLA route of an
+    engine that packs chunks back to back (budget 24: no whole tiles)."""
+    eng = InferenceEngineV2(RaggedLlama(cfg, 8), params,
+                            RaggedInferenceEngineConfig.from_dict({
+                                "state_manager": {
+                                    "max_ragged_batch_size": 24,
+                                    "max_ragged_sequence_count": 4,
+                                    "max_context": 64},
+                                "kv_cache": {"block_size": 8}}))
+    outs = []
+    for i, p in enumerate(prompts):
+        toks = [int(np.argmax(eng.put([i], [list(p)])[i]))]
+        while len(toks) < n_new:
+            toks.append(int(np.argmax(eng.put([i], [toks[-1:]])[i])))
+        eng.flush([i])
+        outs.append(toks)
+    assert all(k[1] is None for k in eng.step_keys)
+    return outs
+
+
+#: name -> prompt lengths, the tick each arrives at, and what is special.
+#: ``kinds``: tick kinds that must occur with a ``put`` forward.
+_TWO_SEGMENT_CASES = {
+    # (a) two sequences decode, then one long prompt arrives
+    "decodes_beside_one_chunk": dict(lens=(5, 7, 40), arrive=(0, 0, 3),
+                                     kinds={"mixed"}),
+    # (b) a decode beside several chunks; 37 = two tiles and a 5-token
+    # tail, and the 64-row tiled segment splits the 30-token prompt
+    "decodes_beside_chunks_with_tail": dict(lens=(6, 37, 30),
+                                            arrive=(0, 2, 2),
+                                            kinds={"mixed"}),
+    # (c) no decode, one chunk shorter than a tile
+    "subtile_chunk_alone": dict(lens=(5,), arrive=(0,), kinds={"prefill"}),
+    # the case the old rule (every chunk >= a tile) admitted
+    "long_prompts_no_decode": dict(lens=(17, 20), arrive=(0, 0),
+                                   kinds={"prefill"}),
+    # (d) a sliding window shorter than the contexts
+    "sliding_window": dict(lens=(6, 40, 23), arrive=(0, 3, 3),
+                           kinds={"mixed"}, cfg=dict(sliding_window=12)),
+    # (e) the second prompt shares two warm blocks with the first and
+    # arrives while it decodes
+    "shared_prefix_blocks": dict(lens=(20, 33), arrive=(0, 3), share=16,
+                                 kinds={"mixed"}, prefix_cache=True),
+    # (f) a pool of 8 blocks under four requests: one is preempted and
+    # resumes (recompute) beside the decodes of the others
+    "preempted_resumes_into_mixed_tick": dict(
+        lens=(14, 15, 13, 12), arrive=(0, 0, 1, 1), num_blocks=9,
+        kinds={"mixed"}, preempt=True, n_new=10),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_TWO_SEGMENT_CASES))
+def test_two_segment_batches_match_sequential(monkeypatch, case):
+    """Every ``put`` forward of a tiled engine packs single-token rows in
+    front of tile-aligned chunks: the generated tokens equal those of each
+    prompt served alone on the XLA route, every program that ran is a
+    tiled one, its prefill went through the tiled kernel and nothing
+    through the token-grid kernel, and a tick is one forward."""
+    spec = _TWO_SEGMENT_CASES[case]
+    cfg = LlamaConfig.tiny(dtype=jnp.float32, **spec.get("cfg", {}))
+    rng = np.random.default_rng(17)
+    prompts = [rng.integers(0, cfg.vocab_size, size=(n,)).tolist()
+               for n in spec["lens"]]
+    for p in prompts[1:]:
+        p[:spec.get("share", 0)] = prompts[0][:spec.get("share", 0)]
+    n_new = spec.get("n_new", 6)
+    params = _params()
+    want = _sequential_reference(params, prompts, n_new, cfg)
+
+    from deepspeed_tpu.serving import SamplingParams
+
+    sched, eng, tr, calls = _tiled_scheduler(
+        monkeypatch, params, cfg, num_blocks=spec.get("num_blocks"),
+        prefix_cache=spec.get("prefix_cache", False))
+    reqs, tick, preempted_at = [], 0, None
+    while len(reqs) < len(prompts) or sched.num_pending:
+        while len(reqs) < len(prompts) and spec["arrive"][len(reqs)] <= tick:
+            reqs.append(sched.submit(prompts[len(reqs)], SamplingParams(
+                greedy=True, max_new_tokens=n_new)))
+        sched.step()
+        if preempted_at is None and sched.metrics.preemptions:
+            preempted_at = tick
+        tick += 1
+        assert tick < 500
+    for r, w in zip(reqs, want):
+        assert r.generated == w, (case, r.uid)
+
+    puts = [k for k in eng.step_keys if not isinstance(k[0], str)]
+    assert puts and all(k[1] == 16 and (k[0] - 4) % 16 == 0 for k in puts), \
+        eng.step_keys
+    assert calls["paged_prefill_attention"] and not calls["paged_attention"]
+    forwards = [(kind, kids) for kind, kids in _tick_spans(tr)
+                if "engine/ragged_step" in kids]
+    assert spec["kinds"] <= {kind for kind, _ in forwards}
+    for kind, kids in forwards:
+        assert len(kids["engine/ragged_step"]) == 1, (case, kind)
+        attrs = kids["engine/build_batch"][0]["attrs"]
+        assert (attrs["bucket"], 16) in puts
+        assert 0 < attrs["tokens"] <= min(attrs["bucket"], 64)
+    if spec.get("preempt"):
+        # the victim's recompute chunk ran beside the others' decodes
+        assert preempted_at is not None and any(r.preemptions for r in reqs)
+        mixed_after = [t for t in tr.records() if t["name"] == "tick"
+                       and t["attrs"].get("kind") == "mixed"
+                       and t["attrs"]["tick"] > preempted_at]
+        assert mixed_after
+    if spec.get("prefix_cache"):
+        assert eng.prefix_cache_stats.hit_tokens == spec["share"]
+
+
+def test_engine_tiled_generate_matches_v1():
+    """``generate`` (``put`` + the scanned decode loop) on a tiled engine
+    equals the v1 reference exactly.  The three chunks need 32 + 32 + 16
+    rows of a 64-row tiled segment, so ``put`` loops a second forward for
+    the last one (the scheduler never does: ``can_schedule`` counts the
+    rows)."""
     rng = np.random.default_rng(17)
     prompts = [rng.integers(0, CFG.vocab_size, size=(n,)).tolist()
-               for n in (17, 20)]
+               for n in (17, 20, 3)]
     params = _params()
     ref = _v1_reference_tokens(params, prompts, n_new=5)
 
     eng = _v2_engine(params, token_budget=64, block_size=8, max_context=64)
-    eng.PREFILL_TILE = 16   # prompts (17, 20) >= tile -> tiled path
-    # monkeypatch-free check that the tiled program was built
+    eng.PREFILL_TILE = 16
     out = eng.generate(prompts, max_new_tokens=5)
-    assert any(k[1] == 16 for k in eng._steps if isinstance(k, tuple)
-               and len(k) == 2 and not isinstance(k[0], str)), \
-        list(eng._steps)
+    assert not eng.can_schedule([1, 2, 3], [17, 20, 3])
+    assert eng.can_schedule([1, 2, 3], [17, 20, 1])
+    assert [k for k in eng.step_keys if not isinstance(k[0], str)] == \
+        [(4 + 64, 16), (4 + 16, 16)]
     for got, want in zip(out, ref):
         np.testing.assert_array_equal(got, np.asarray(want))

@@ -200,6 +200,49 @@ def test_tick_closing_counters(traced):
     assert sum(t["attrs"]["emitted"] for t, _ in ticks) == 8 + 3
 
 
+def test_tiled_tick_is_one_forward_and_bucket_is_its_rows(params):
+    """An engine whose budget is whole tiles (here 64 = 4 x 16): the
+    scheduler sizes chunks by the ROWS ``can_schedule`` counts, so a tick
+    whose chunks fill the tiled segment is one forward (the old packing
+    pushed what alignment padding displaced into a second forward of the
+    same ``put``), and ``bucket`` is the row count of the program that
+    ran: ``max_seqs`` single-token rows + whole tiles."""
+    tr = Tracer()
+    cfg = RaggedInferenceEngineConfig.from_dict({
+        "state_manager": {"max_ragged_batch_size": 64,
+                          "max_ragged_sequence_count": 4,
+                          "max_context": 64},
+        "kv_cache": {"block_size": 8}})
+    eng = InferenceEngineV2(RaggedLlama(CFG, 8), params, cfg)
+    eng.PREFILL_TILE = 16
+    sched = ContinuousBatchScheduler(eng, tracer=tr)
+    sched.submit(_prompt(6), SamplingParams(greedy=True, max_new_tokens=12))
+    sched.step()
+    # 33 + 31 = 64 tokens are within the budget, but 33 takes three tiles
+    # and leaves one: the second chunk is cut to 16 tokens
+    for n, seed in ((33, 1), (31, 2)):
+        sched.submit(_prompt(n, seed), SamplingParams(greedy=True,
+                                                      max_new_tokens=2))
+    sched.run_until_idle()
+    forwards = [(t, kids) for t, kids in _ticks(tr)
+                if "engine/ragged_step" in kids]
+    assert [t["attrs"]["kind"] for t, _ in forwards[:3]] == \
+        ["prefill", "mixed", "mixed"]
+    for t, kids in forwards:
+        assert len(kids["engine/ragged_step"]) == 1
+        assert len(kids["engine/build_batch"]) == 1
+    builds = [kids["engine/build_batch"][0]["attrs"] for _, kids in forwards]
+    assert builds[0] == {"tokens": 6, "bucket": 4 + 16}
+    assert builds[1] == {"tokens": 1 + 33 + 16, "bucket": 4 + 64}
+    assert builds[2] == {"tokens": 1 + 1 + 15, "bucket": 4 + 16}
+    assert {(b["bucket"], 16) for b in builds} == \
+        {k for k in eng.step_keys if not isinstance(k[0], str)}
+    # real tokens never pass the budget, rows never the largest program
+    assert not eng.can_schedule([7, 8], [33, 31])
+    assert eng.can_schedule([7, 8, 9, 10], [1, 1, 33, 16])
+    assert not eng.can_schedule([7, 8], [64, 1])
+
+
 def test_verify_tick_counters(params):
     tr = Tracer()
     sched = _sched(params, tracer=tr, speculative=SpeculativeConfig(draft_k=3))
@@ -406,6 +449,8 @@ _KERNEL_ENTRIES = [
     ("paged_prefill", "_prefill_kernel"),
     ("paged_decode_dma", "_decode_kernel"),
     ("paged_verify_multiquery", "_verify_kernel"),
+    ("paged_two_segment", "_decode_kernel"),
+    ("paged_two_segment", "_prefill_kernel"),
     ("gmm_fwd", "_gmm_kernel"),
     ("gmm_dlhs", "_gmm_dlhs_kernel"),
     ("gmm_drhs", "_gmm_drhs_kernel"),
@@ -456,7 +501,7 @@ def test_paged_wrappers_keep_their_instruction_names():
     wrapper while no ``name=`` is passed: the four paged kernels pass
     metadata only."""
     for case in ("paged_attention_grid", "paged_prefill", "paged_decode_dma",
-                 "paged_verify_multiquery"):
+                 "paged_verify_multiquery", "paged_two_segment"):
         for name, text in _lowered_calls(case):
             loc = re.search(r'loc\("([^"]*pallas_call)"', text)
             assert loc and f"{name}/pallas_call" not in loc.group(1), loc

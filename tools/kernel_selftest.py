@@ -309,6 +309,23 @@ def run_selftest(tol: float = 3e-2) -> dict:
                                 block_size=bs, tile_q=128,
                                 interpret=False), wantp))
 
+    # a mixed tick's two-segment batch at Mistral's head counts (32q/8kv,
+    # d128) through the route the engine takes: single-token rows (slots
+    # in no order, pads at position -1) by the decode walk on a big pool
+    # and by the dense read on a tight one, tile-aligned chunks by the
+    # tiled kernel
+    from deepspeed_tpu.inference.v2.kernels.blocked_flash import (
+        two_segment_case)
+
+    def two_segment(name, tight_pool):
+        got, want, real = two_segment_case(tight_pool)
+        record(name, got[real], want[real])
+
+    guarded("paged_two_segment_walk",
+            lambda: two_segment("paged_two_segment_walk", False))
+    guarded("paged_two_segment_dense",
+            lambda: two_segment("paged_two_segment_dense", True))
+
     # ---- grouped GEMM fwd + both grads (MoE dropless path) ---- #
     from deepspeed_tpu.ops.grouped_gemm import gmm, gmm_reference
 
