@@ -11,6 +11,12 @@ seeded random weights:
 * **serve** — ``RaggedLlama`` -> ``InferenceEngineV2`` ->
   ``ContinuousBatchScheduler.submit()`` / ``run_until_idle()``: requests of
   mixed length, SplitFuse chunking, greedy decode.
+* **moe** (one device) — the routed-expert path at the published
+  OLMoE-1B-7B widths (64 experts of width 1024 at top-8, hidden 2048):
+  the grouped GEMM at a decode tick's and at a mixed tick's row count
+  against ``gmm_reference``, and a depth-2 ``RaggedMixtral`` engine
+  (``put`` then ``decode_step``) on the grouped path against the dense
+  all-experts composition.
 * **kernels** — ``tools/kernel_selftest.run_selftest()`` as a gate.
 
 With more than one device visible the same phases run across all of them
@@ -60,6 +66,38 @@ TRAIN_LOSS_TOL = 5e-3
 # logits by their full scale.
 SERVE_LOGIT_TOL = 0.05
 
+# Grouped GEMM against ``gmm_reference`` (XLA: one-hot mask and one einsum),
+# bf16 in and out, K = 2048: both accumulate in float32 and round the result
+# once, so where the order of the sums differs they differ by at most one
+# bf16 spacing of a value, 2^-7 of the LARGEST value.  On the v5e the kernel
+# reads 0.0 at 256 and at 4352 rows; ``gmm_reference`` with bf16 partial
+# sums (16 slices of K) reads 0.0099 and 0.0122 against itself, over the
+# limit (PR 25, chip call 4).  A tile visited by the wrong group, a row
+# written twice or an unvisited row is off by the value itself.
+GMM_TOL = 2.0 ** -7
+
+# Logits of a depth-2 OLMoE-width engine, grouped path against the dense
+# all-experts composition on the same bf16 weights, over the largest logit.
+# The two sum the same bf16 products in float32 in another order and round
+# the expert outputs to bf16 at different points (per routed row against
+# per expert and token), so activations differ by bf16 roundings, and where
+# a token's 8th and 9th router probabilities lie within such a rounding the
+# second layer routes it differently in the two runs.  About twice what the
+# v5e reads (0.0062-0.0071 in four calls); top-8 weights renormalised on one
+# side, the smallest fault of the router there is, read 0.687 (PR 25, chip
+# call 4), and a wrong sort, offset or combine weight is no smaller.
+MOE_LOGIT_TOL = 0.015
+
+# Share of rows for which ``moe_router`` on FLOAT32 activations and router
+# weights (values that are no bf16 values) picks the same top-k set as
+# float64 on the host.  On the v5e 1.00000 of 4352 rows; the same GEMM at
+# the TPU's default precision (operands rounded to bf16) 0.98047 (PR 25,
+# chip call 4).  The benchmark's ``correct`` cannot see the router's
+# precision: a bf16 engine's activations and weights are bf16 values, so
+# both precisions give the same logits there, bit for bit.  A float32
+# engine on the chip is what this floor guards.
+ROUTER_AGREE_FLOOR = 0.999
+
 _ATTENTION_KERNELS = ("_fwd_kernel", "_bwd_dq_kernel", "_bwd_dkv_kernel",
                       "_kernel", "_prefill_kernel", "_decode_kernel",
                       "_verify_kernel")
@@ -87,6 +125,12 @@ class SmokeSizes:
     prompt_lens: Sequence[int]
     new_tokens: Sequence[int]
     check_prompt_len: int
+    # the moe phase: a MixtralConfig at the published OLMoE widths (depth as
+    # given), the grouped GEMM's row counts, the engine's prompt
+    moe_config: Any = None
+    moe_gmm_rows: Sequence[int] = (256, 4352)
+    moe_prompt_len: int = 300
+    moe_new_tokens: int = 4
 
 
 def chip_sizes(n_devices: int) -> SmokeSizes:
@@ -105,8 +149,11 @@ def chip_sizes(n_devices: int) -> SmokeSizes:
     import jax.numpy as jnp
 
     from deepspeed_tpu.models.mistral import MistralConfig
+    from deepspeed_tpu.models.mixtral import MixtralConfig
 
     return SmokeSizes(
+        moe_config=MixtralConfig.olmoe_1b_7b(num_hidden_layers=2,
+                                             dtype=jnp.bfloat16),
         model_config=MistralConfig(dtype=jnp.bfloat16),
         train_layers=1 if n_devices == 1 else 6,
         train_seq=4096, train_steps=6,
@@ -235,6 +282,13 @@ def _balanced_bytes_in_use(devices, what: str) -> None:
                            f"across devices: {used}")
 
 
+def mosaic_kernel_names(lowered_text: str) -> List[str]:
+    """The kernel function's name at every Mosaic call site of a lowered
+    program, in text order."""
+    return re.findall(r'@tpu_custom_call\(.*?kernel_name = "([^"]+)"',
+                      lowered_text)
+
+
 def attention_route(lowered_text: str, require_chip: bool,
                     what: str) -> Dict[str, int]:
     """Which attention kernels a lowered program calls through Mosaic,
@@ -243,10 +297,8 @@ def attention_route(lowered_text: str, require_chip: bool,
     Every layer has the same shapes and so takes the same route; on the
     chip that route must be a compiled Pallas kernel — the XLA composition
     and the interpreter leave no ``tpu_custom_call``."""
-    names = re.findall(r'@tpu_custom_call\(.*?kernel_name = "([^"]+)"',
-                       lowered_text)
     route: Dict[str, int] = {}
-    for n in names:
+    for n in mosaic_kernel_names(lowered_text):
         if n in _ATTENTION_KERNELS:
             route[n] = route.get(n, 0) + 1
     if require_chip and not route:
@@ -385,12 +437,14 @@ def train_phase(sizes: SmokeSizes, devices, require_chip: bool,
 # --------------------------------------------------------------------- #
 # Phase: serve
 # --------------------------------------------------------------------- #
-def _seeded_bf16_params(cfg, mesh=None):
-    """The LlamaForCausalLM parameter tree built leaf by leaf in bf16 on
-    the device, each leaf born with its tensor-parallel sharding when
-    there is a ``mesh`` (``model.init`` materialises float32 first, which
-    at these widths overflows the chip by itself).  Kernels ~ N(0,
-    1/fan_in), embedding ~ N(0, 0.02^2), norm scales 1."""
+def _seeded_bf16_params(cfg, mesh=None, model_cls=None):
+    """The parameter tree of ``model_cls`` (LlamaForCausalLM unless given)
+    built leaf by leaf in bf16 on the device, each leaf born with its
+    tensor-parallel sharding when there is a ``mesh`` (``model.init``
+    materialises float32 first, which at these widths overflows the chip
+    by itself).  Kernels ~ N(0, 1/fan_in) (stacked expert matrices
+    [E, in, out] by their own fan-in), embedding ~ N(0, 0.02^2), norm
+    scales 1."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding
@@ -400,7 +454,7 @@ def _seeded_bf16_params(cfg, mesh=None):
     from deepspeed_tpu.models.llama import LlamaForCausalLM
 
     shapes = jax.eval_shape(
-        LlamaForCausalLM(cfg).init, jax.random.key(0),
+        (model_cls or LlamaForCausalLM)(cfg).init, jax.random.key(0),
         jax.ShapeDtypeStruct((1, 8), jnp.int32))["params"]
     flat, treedef = jax.tree_util.tree_flatten_with_path(shapes)
     flat_sh = [None] * len(flat) if mesh is None else [
@@ -420,7 +474,7 @@ def _seeded_bf16_params(cfg, mesh=None):
     for i, ((path, leaf), sh) in enumerate(zip(flat, flat_sh)):
         name = str(getattr(path[-1], "key", path[-1]))
         std = None if name == "scale" else \
-            0.02 if name == "embedding" else leaf.shape[0] ** -0.5
+            0.02 if name == "embedding" else leaf.shape[-2] ** -0.5
         leaves.append(maker(leaf.shape, std, sh)(
             jax.random.fold_in(jax.random.key(1), i)))
     return jax.tree_util.tree_unflatten(treedef, leaves)
@@ -574,6 +628,131 @@ def serve_phase(sizes: SmokeSizes, devices, require_chip: bool,
 
 
 # --------------------------------------------------------------------- #
+# Phase: routed experts (one device: RaggedMixtral serves TP = 1)
+# --------------------------------------------------------------------- #
+def moe_phase(sizes: SmokeSizes, devices, require_chip: bool,
+              clock: CompileClock) -> Dict[str, Any]:
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
+                                            RaggedInferenceEngineConfig)
+    from deepspeed_tpu.inference.v2.model_implementations.ragged_mixtral \
+        import RaggedMixtral, moe_router
+    from deepspeed_tpu.models.mixtral import MixtralForCausalLM
+    from deepspeed_tpu.ops.grouped_gemm import (_pick_tiles, gmm,
+                                                gmm_reference)
+
+    cfg = sizes.moe_config
+    if cfg is None or len(devices) > 1:
+        # RaggedMixtral serves TP = 1: nothing here exists across chips
+        return {"skipped": "no moe_config in these sizes" if cfg is None
+                else "one-device phase", **clock.take()}
+    e, k = cfg.num_local_experts, cfg.num_experts_per_tok
+    h, f = cfg.hidden_size, cfg.intermediate_size
+    rng = np.random.default_rng(4)
+    cases: Dict[str, Any] = {}
+    # the kernel alone: a decode tick's rows (4 an expert, several groups
+    # empty, every work unit a boundary unit) and a mixed tick's
+    for name, m in zip(("gmm_fwd_e64_decode", "gmm_fwd_e64_prefill"),
+                       sizes.moe_gmm_rows):
+        group_sizes = rng.multinomial(m, np.full(e, 1.0 / e))
+        tm, tn = _pick_tiles(m, h, f)
+        lhs = jnp.asarray(rng.standard_normal((m, h)), jnp.bfloat16)
+        rhs = jnp.asarray(rng.standard_normal((e, h, f)) * h ** -0.5,
+                          jnp.bfloat16)
+        gs = jnp.asarray(group_sizes, jnp.int32)
+        # on the chip the compiled kernel; in the dry run the interpreter
+        got = np.asarray(gmm(lhs, rhs, gs, tm, tn,
+                             False if require_chip else True), np.float32)
+        want = np.asarray(gmm_reference(lhs, rhs, gs), np.float32)
+        err = float(np.max(np.abs(got - want)) / np.max(np.abs(want)))
+        cases[name] = {"rows": int(m), "tiles": [tm, tn],
+                       "group_rows": [int(group_sizes.min()),
+                                      int(group_sizes.max())],
+                       "empty_groups": int((group_sizes == 0).sum()),
+                       "max_err": round(err, 6)}
+        if not np.isfinite(got).all() or err > GMM_TOL:
+            raise SmokeFailure(f"moe: {name} differs from gmm_reference by "
+                               f"{err:.4f} of its largest value "
+                               f"(> {GMM_TOL}): {cases[name]}")
+        del lhs, rhs, got, want
+
+    # the router alone, float32 in: the top-k set against float64 on the host
+    x = rng.standard_normal((sizes.moe_gmm_rows[-1], h)).astype(np.float32)
+    wg = (rng.standard_normal((h, e)) * h ** -0.5).astype(np.float32)
+    topi = np.sort(np.asarray(jax.jit(
+        lambda a, b: moe_router(a, b, k, cfg.norm_topk_prob)[0])(x, wg)), -1)
+    want = np.sort(np.argsort(
+        -(x.astype(np.float64) @ wg.astype(np.float64)), -1)[:, :k], -1)
+    agree = float(np.mean(np.all(topi == want, axis=-1)))
+    cases["router_f32_agreement"] = {"rows": int(x.shape[0]),
+                                     "same_top_k": round(agree, 5)}
+    if agree < ROUTER_AGREE_FLOOR:
+        raise SmokeFailure(
+            f"moe: the router picks the float64 top-{k} set for only "
+            f"{agree:.4f} of {x.shape[0]} float32 rows "
+            f"(< {ROUTER_AGREE_FLOOR}): it is not computed in float32")
+
+    # the engine: put then decode_step, grouped against the dense oracle
+    class DenseOracle(RaggedMixtral):
+        grouped = False
+
+    params = _seeded_bf16_params(cfg, model_cls=MixtralForCausalLM)
+    bs = sizes.block_size
+    n_prompt, n_new = sizes.moe_prompt_len, sizes.moe_new_tokens
+    max_context = -(-(n_prompt + n_new + 1) // bs) * bs
+    eng_cfg = RaggedInferenceEngineConfig.from_dict({
+        "state_manager": {"max_ragged_batch_size": sizes.token_budget,
+                          "max_ragged_sequence_count": sizes.max_seqs,
+                          "max_context": max_context},
+        "kv_cache": {"block_size": bs,
+                     "num_blocks": 2 * (max_context // bs) + 2}})
+    ids = rng.integers(0, cfg.vocab_size, size=(n_prompt + n_new,))
+    logits, routes = {}, {}
+    for path, model_cls in (("grouped", RaggedMixtral),
+                            ("dense", DenseOracle)):
+        engine = InferenceEngineV2(model_cls(cfg, bs), params, eng_cfg)
+        rows = [np.asarray(engine.put([1], [ids[:n_prompt].tolist()])[1],
+                           np.float32)]
+        for t in ids[n_prompt:]:
+            rows.append(np.asarray(jax.device_get(
+                engine.decode_step([1], [int(t)])), np.float32)[0])
+        logits[path] = np.stack(rows)
+        for key in engine.step_keys:
+            name = "decode_step" if key == ("decode_step",) else \
+                f"prefill_T{key[0]}" + ("_tiled" if key[1] else "")
+            text = engine.lower_step(key).as_text()
+            kernels = mosaic_kernel_names(text)
+            routes[f"{path}/{name}"] = {
+                "kernels": {n: kernels.count(n) for n in sorted(set(kernels))},
+                # the dense composition's [E, T, F] intermediate
+                # (the weights are [E, H, F]: the token rows are not H)
+                "all_experts_einsum": any(
+                    int(t) != h for t in re.findall(
+                        rf"tensor<{e}x(\d+)x{f}x", text))}
+        del engine
+    for name, r in routes.items():
+        grouped = name.startswith("grouped/")
+        if r["all_experts_einsum"] == grouped or (
+                require_chip and grouped != ("_gmm_kernel" in r["kernels"])):
+            raise SmokeFailure(f"moe: {name} took the wrong expert path: "
+                               f"{r}")
+    err = float(np.max(np.abs(logits["grouped"] - logits["dense"]))
+                / np.max(np.abs(logits["dense"])))
+    if not np.isfinite(logits["grouped"]).all() or err > MOE_LOGIT_TOL:
+        raise SmokeFailure(
+            f"moe: grouped-path logits differ from the dense all-experts "
+            f"composition by {err:.4f} of the largest logit "
+            f"(> {MOE_LOGIT_TOL})")
+    cases["ragged_moe_serve"] = {
+        "layers": cfg.num_hidden_layers, "experts": e, "top_k": k,
+        "prompt": n_prompt, "decoded": n_new,
+        "logit_err_vs_dense": round(err, 5), "routes": routes}
+    return {**cases, **clock.take(), "memory": memory_report(devices)}
+
+
+# --------------------------------------------------------------------- #
 # Phase: every other kernel
 # --------------------------------------------------------------------- #
 def kernels_phase(_sizes, _devices, _require_chip,
@@ -592,7 +771,7 @@ def kernels_phase(_sizes, _devices, _require_chip,
             **clock.take()}
 
 
-PHASES = {"train": train_phase, "serve": serve_phase,
+PHASES = {"train": train_phase, "serve": serve_phase, "moe": moe_phase,
           "kernels": kernels_phase}
 
 
@@ -636,7 +815,7 @@ def main() -> int:
         json.dump(summary, f, indent=1)
     print("chip_smoke: compile seconds per phase "
           + json.dumps({p: summary[p]["compile_s"]
-                        for p in ("train", "serve", "kernels")})
+                        for p in ("train", "serve", "moe", "kernels")})
           + f", wall {summary['wall_s']} s", flush=True)
     print(json.dumps({"ok": True, "device": summary["device"]}))
     return 0

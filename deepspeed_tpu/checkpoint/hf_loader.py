@@ -120,29 +120,58 @@ def _llama_rules() -> List[Rule]:
     ]
 
 
-def _mixtral_rules() -> List[Rule]:
-    # our Mixtral tree is flat (no "model" wrapper) and the MoE block is
-    # moe/layer.py MoE -> deepspeed_moe -> {gate/wg, experts/w_*}
-    hf2us = {"w1": "w_gate", "w3": "w_up", "w2": "w_down"}
+def _flat_moe_backbone_rules() -> List[Rule]:
+    # what Mixtral and OLMoE name alike; our tree for both is flat (no
+    # "model" wrapper)
     return [
         (r"^model\.embed_tokens\.weight$",
          lambda m: (("embed_tokens", "embedding"), None)),
         (r"^model\.layers\.(\d+)\.self_attn\.(q|k|v|o)_proj\.weight$",
          lambda m: ((f"layers_{m.group(1)}", "self_attn",
                      f"{m.group(2)}_proj", "kernel"), "t")),
-        (r"^model\.layers\.(\d+)\.block_sparse_moe\.gate\.weight$",
-         lambda m: ((f"layers_{m.group(1)}", "block_sparse_moe",
-                     "deepspeed_moe", "gate", "wg", "kernel"), "t")),
-        (r"^model\.layers\.(\d+)\.block_sparse_moe\.experts\.(\d+)\."
-         r"(w1|w2|w3)\.weight$",
-         lambda m: ((f"layers_{m.group(1)}", "block_sparse_moe",
-                     "deepspeed_moe", "experts", hf2us[m.group(3)]),
-                    ("stack", int(m.group(2))))),
         (r"^model\.layers\.(\d+)\.(input_layernorm|post_attention_layernorm)"
          r"\.weight$",
          lambda m: ((f"layers_{m.group(1)}", m.group(2), "scale"), None)),
         (r"^model\.norm\.weight$", lambda m: (("norm", "scale"), None)),
         (r"^lm_head\.weight$", lambda m: (("lm_head", "kernel"), "t")),
+    ]
+
+
+def _moe_path(m, *leaf):
+    # the MoE block is moe/layer.py MoE -> deepspeed_moe -> {gate/wg,
+    # experts/w_*}
+    return (f"layers_{m.group(1)}", "block_sparse_moe", "deepspeed_moe",
+            *leaf)
+
+
+def _mixtral_rules() -> List[Rule]:
+    hf2us = {"w1": "w_gate", "w3": "w_up", "w2": "w_down"}
+    return _flat_moe_backbone_rules() + [
+        (r"^model\.layers\.(\d+)\.block_sparse_moe\.gate\.weight$",
+         lambda m: (_moe_path(m, "gate", "wg", "kernel"), "t")),
+        (r"^model\.layers\.(\d+)\.block_sparse_moe\.experts\.(\d+)\."
+         r"(w1|w2|w3)\.weight$",
+         lambda m: (_moe_path(m, "experts", hf2us[m.group(3)]),
+                    ("stack", int(m.group(2))))),
+    ]
+
+
+def _olmoe_rules() -> List[Rule]:
+    # OLMoE (``model_type: olmoe``) is served and trained by the Mixtral
+    # classes, so its tensors land in the Mixtral tree; it differs in its
+    # names (``mlp.gate`` / ``mlp.experts.<e>.{gate,up,down}_proj``) and
+    # in the q/k RMSNorm scales
+    return _flat_moe_backbone_rules() + [
+        (r"^model\.layers\.(\d+)\.self_attn\.(q|k)_norm\.weight$",
+         lambda m: ((f"layers_{m.group(1)}", "self_attn",
+                     f"{m.group(2)}_norm", "scale"), None)),
+        (r"^model\.layers\.(\d+)\.mlp\.gate\.weight$",
+         lambda m: (_moe_path(m, "gate", "wg", "kernel"), "t")),
+        (r"^model\.layers\.(\d+)\.mlp\.experts\.(\d+)\."
+         r"(gate|up|down)_proj\.weight$",
+         lambda m: (_moe_path(m, "experts", f"w_{m.group(3)}"),
+                    ("stack", int(m.group(2))))),
+        (r".*rotary_emb\.inv_freq$", lambda m: (None, None)),  # recomputed
     ]
 
 
@@ -366,6 +395,7 @@ _ARCH_RULES: Dict[str, Callable[[], List[Rule]]] = {
     "mistral": _llama_rules,     # same architecture/serialization
     "internlm": _llama_rules,
     "mixtral": _mixtral_rules,
+    "olmoe": _olmoe_rules,
     "gpt2": _gpt2_rules,
     "opt": _opt_rules,
     "falcon": _falcon_rules,
@@ -426,6 +456,30 @@ def config_from_hf(model_path: str, dtype: Any = None):
             rope_theta=cfg.get("rope_theta", 10000.0),
             num_local_experts=cfg.get("num_local_experts", 8),
             num_experts_per_tok=cfg.get("num_experts_per_tok", 2),
+            dtype=dt)
+    if arch == "olmoe":
+        from deepspeed_tpu.models.mixtral import MixtralConfig
+
+        if cfg.get("clip_qkv") is not None or cfg.get("attention_bias") \
+                or cfg.get("rope_scaling") is not None \
+                or cfg.get("tie_word_embeddings"):
+            raise HFLoadError(
+                "olmoe: clip_qkv, attention_bias, rope_scaling and a tied "
+                "head are not implemented (OLMoE-1B-7B sets none of them)")
+        return arch, MixtralConfig.olmoe_1b_7b(
+            vocab_size=cfg["vocab_size"],
+            hidden_size=cfg["hidden_size"],
+            intermediate_size=cfg["intermediate_size"],
+            num_hidden_layers=cfg["num_hidden_layers"],
+            num_attention_heads=cfg["num_attention_heads"],
+            num_key_value_heads=cfg.get(
+                "num_key_value_heads", cfg["num_attention_heads"]),
+            max_position_embeddings=cfg["max_position_embeddings"],
+            rms_norm_eps=cfg.get("rms_norm_eps", 1e-5),
+            rope_theta=float(cfg.get("rope_theta", 10000.0)),
+            num_local_experts=cfg["num_experts"],
+            num_experts_per_tok=cfg["num_experts_per_tok"],
+            norm_topk_prob=cfg.get("norm_topk_prob", False),
             dtype=dt)
     if arch == "gpt2":
         from deepspeed_tpu.models.gpt2 import GPT2Config
@@ -674,7 +728,7 @@ def model_from_hf(model_path: str, dtype: Any = None):
         from deepspeed_tpu.models.llama import LlamaForCausalLM
 
         return arch, cfg, LlamaForCausalLM(cfg)
-    if arch == "mixtral":
+    if arch in ("mixtral", "olmoe"):
         from deepspeed_tpu.models.mixtral import MixtralForCausalLM
 
         return arch, cfg, MixtralForCausalLM(cfg)

@@ -487,9 +487,11 @@ class InferenceEngineV2:
         n = len(uids)
         if n > S:
             raise ValueError(f"decode_step: {n} sequences exceed max_seqs {S}")
-        with open_span(self.tracer, "engine/decode_prep"):
+        with open_span(self.tracer, "engine/decode_prep") as span:
             seqs, state = self._prepare_decode(uids)
             tok = self._as_token_array(tokens, n, S)
+            if type(span) is SpanHandle:
+                span.attrs = {"seqs": n}    # live rows of the S it runs
         try:
             with open_span(self.tracer, "engine/decode_step"):
                 logits, nxt, new_cache, new_pos = self._get_decode_step()(
@@ -1108,7 +1110,7 @@ class InferenceEngineV2:
                 quantize_groups: int = 64):
         """Serve a real HuggingFace checkpoint directory (reference: the
         MII/engine_factory path that builds a FastGen engine from a HF
-        snapshot).  Llama/Mistral/Mixtral-family checkpoints supported;
+        snapshot).  Llama/Mistral/Mixtral/OLMoE checkpoints supported;
         with ``mesh`` (a non-trivial 'model' axis) weights land
         PRE-SHARDED by the Megatron split rules via
         :func:`shard_ragged_params`'s specs — no full host/device copy.
@@ -1142,7 +1144,7 @@ class InferenceEngineV2:
                     f"parallelism yet — pass mesh=None")
             cls_ = RaggedOPT if arch == "opt" else RaggedFalcon
             model = cls_(mcfg, block_size)
-        elif arch == "mixtral":
+        elif arch in ("mixtral", "olmoe"):
             from deepspeed_tpu.inference.v2.model_implementations. \
                 ragged_mixtral import RaggedMixtral
 

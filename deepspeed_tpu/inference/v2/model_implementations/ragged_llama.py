@@ -374,14 +374,29 @@ def ragged_attention_block(lp_attn, xa, layer_cache, batch, block_size, cfg,
                            prefill_tile=None, decode_mode=False,
                            verify_k=None):
     """Shared per-layer attention body (RaggedLlama + RaggedMixtral):
-    qkv proj → rotary → paged-KV scatter → blocked-flash → o_proj
+    qkv proj (→ q/k RMSNorm where the layer has ``q_norm``/``k_norm``)
+    → rotary → paged-KV scatter → blocked-flash → o_proj
     (+ row-parallel psum under TP). ``h``/``hkv`` are LOCAL head counts.
     Returns ``(attn_out [T, H_model], new_layer_cache)``."""
     dt = cfg.dtype
     kv_dest = batch["kv_dest"]
+    # OLMoE / OLMo-2 (static: the layer's own parameters say so): RMSNorm
+    # over the WHOLE q and k projections, all heads at once, before the
+    # head split and the rotary embedding
+    qk_norm = "q_norm" in lp_attn
+    if qk_norm and ax is not None:
+        raise NotImplementedError(
+            "q/k normalisation spans every head: it does not compose with "
+            "head-split tensor parallelism yet")
     with jax.named_scope("attn/qkv"):
-        q = qmm(xa, lp_attn["q_proj"]["kernel"], dt).reshape(-1, h, d)
-        k = qmm(xa, lp_attn["k_proj"]["kernel"], dt).reshape(-1, hkv, d)
+        q = qmm(xa, lp_attn["q_proj"]["kernel"], dt)
+        if qk_norm:
+            q = _rms_norm(q, lp_attn["q_norm"]["scale"], cfg.rms_norm_eps)
+        q = q.reshape(-1, h, d)
+        k = qmm(xa, lp_attn["k_proj"]["kernel"], dt)
+        if qk_norm:
+            k = _rms_norm(k, lp_attn["k_norm"]["scale"], cfg.rms_norm_eps)
+        k = k.reshape(-1, hkv, d)
         v = qmm(xa, lp_attn["v_proj"]["kernel"], dt).reshape(-1, hkv, d)
     with jax.named_scope("attn/rope_insert"):
         q, k_pool, v_pool, k_scale, v_scale, new_cache = _rope_insert(

@@ -1,19 +1,33 @@
-"""Ragged (paged-KV) Mixtral forward for the FastGen engine.
+"""Ragged (paged-KV) Mixtral / OLMoE forward for the FastGen engine.
 
 Reference analog: ``inference/v2/model_implementations/mixtral/`` served by
 the MoE ragged kernels (``kernels/ragged_ops/{top_k_gating,moe_scatter,
 moe_gather}/``, ``kernels/cutlass_ops/moe_gemm/``).
 
 TPU-native design: the attention/paged-KV machinery is shared with
-:class:`RaggedLlama` (same flat token buffer, same blocked-flash kernel);
-the FFN is a **dropless** top-k routed MoE over the flat ``[T, H]`` buffer:
+:class:`RaggedLlama` (same flat token buffer, same blocked-flash kernels,
+same two-segment batches; q/k RMSNorm where the layer's parameters carry
+``q_norm``/``k_norm``, as OLMoE's do); the FFN is a **dropless** top-k
+routed MoE over the flat ``[T, H]`` buffer:
 
-* router logits + top-k + renormalised weights per token (the reference's
-  ★top_k_gating kernel; HF Mixtral inference semantics),
-* dense einsum dispatch: every expert processes the full token buffer and
-  the combine mask zeroes unselected rows (the reference's moe_scatter/
-  moe_gemm/moe_gather pipeline; a sorted grouped-matmul Pallas kernel can
-  replace the einsum without changing this layout).
+* ``moe/router``: post-attention norm, router logits in float32, softmax
+  over ALL experts, top-k; the weights renormalised (HF Mixtral) or kept
+  as the softmax gave them (``config.norm_topk_prob`` false: OLMoE) — the
+  reference's ★top_k_gating kernel;
+* ``moe/dispatch``: counting sort of the ``T x k`` routed rows by expert
+  and the gather of their activations (★moe_scatter);
+* ``moe/experts``: three calls of the grouped GEMM Mosaic kernel
+  (``ops/grouped_gemm.py::_gmm_kernel``, ★moe_gemm) around the SwiGLU
+  product: each expert multiplies only the rows routed to it, so FLOPs
+  scale with ``k x T``, not ``E x T``.  This is the DEFAULT path, on the
+  TPU and (as the XLA composition ``gmm_reference``) off it;
+* ``moe/combine``: unsort and the weighted sum of each token's ``k`` rows
+  (★moe_gather).
+
+``dropless_moe(..., grouped=False)`` computes every expert over every
+token by dense einsums and masks: ``E / k`` times the FLOPs (8x for OLMoE).
+It is the parity oracle of the tests and of ``chip_smoke.py`` and is never
+served.
 
 Dropless gating is what makes MoE *ragged-safe*: with no capacity buckets
 there is no cross-token interaction, so the pad lanes of the token budget
@@ -39,7 +53,23 @@ from deepspeed_tpu.inference.v2.model_implementations.ragged_llama import (
 from deepspeed_tpu.models.mixtral import MixtralConfig
 
 
-def dropless_moe(x, moe_params, k: int, dtype, grouped=None):
+def moe_router(x, wg, k: int, renormalize: bool = True):
+    """Router of the dropless MoE: ``x`` [T, H] (normed) x ``wg`` [H, E] in
+    float32 -> (topi [T, k] int32, weights [T, k] float32).  Float32
+    products as well as sums: on a TPU a float32 matmul at the default
+    precision rounds its operands to bf16, which changes nothing for a bf16
+    engine (its activations and weights are bf16 values already) and flips
+    routings on near ties for a float32 one."""
+    from deepspeed_tpu.ops.grouped_gemm import exact_topk_routing
+
+    with jax.named_scope("moe/router"):
+        logits = jnp.matmul(x.astype(jnp.float32), wg.astype(jnp.float32),
+                            precision=jax.lax.Precision.HIGHEST)  # [T, E]
+        return exact_topk_routing(logits, k, renormalize)
+
+
+def dropless_moe(x, moe_params, k: int, dtype, grouped=None,
+                 renormalize: bool = True):
     """Dropless top-k MoE over a flat token buffer.
 
     x: [T, H]; returns [T, H]. Router math in fp32 (reference TopKGate is
@@ -49,16 +79,15 @@ def dropless_moe(x, moe_params, k: int, dtype, grouped=None):
     (ops/grouped_gemm.py — the reference's ★moe_gemm/★moe_scatter/
     ★moe_gather pipeline): tokens are sorted by expert and each expert
     multiplies only its own row block, so FLOPs scale with k·T instead
-    of E·T (4× fewer for Mixtral's 8-expert top-2).  ``grouped=False``
-    forces the dense all-experts einsum (the parity oracle).
+    of E·T (4× fewer for Mixtral's 8-expert top-2, 8x for OLMoE's 64 at
+    top-8).  ``grouped=False`` forces the dense all-experts einsum (the
+    parity oracle).  ``renormalize`` (static) is HF ``norm_topk_prob``.
     """
-    from deepspeed_tpu.ops.grouped_gemm import (exact_topk_routing,
-                                                grouped_moe_ffn)
+    from deepspeed_tpu.ops.grouped_gemm import grouped_moe_ffn
 
     wg = moe_params["gate"]["wg"]["kernel"]            # [H, E]
     experts = moe_params["experts"]
-    logits = x.astype(jnp.float32) @ wg.astype(jnp.float32)   # [T, E]
-    topi, w = exact_topk_routing(logits, k)            # [T, k]
+    topi, w = moe_router(x, wg, k, renormalize)        # [T, k]
     e_count = wg.shape[1]
     w_gate = experts["w_gate"].astype(dtype)           # [E, H, F]
     w_up = experts["w_up"].astype(dtype)
@@ -77,11 +106,16 @@ def dropless_moe(x, moe_params, k: int, dtype, grouped=None):
 
 
 class RaggedMixtral:
-    """Callable ragged MoE forward bound to a :class:`MixtralConfig`."""
+    """Callable ragged MoE forward bound to a :class:`MixtralConfig`
+    (Mixtral, and OLMoE through ``qk_norm`` / ``norm_topk_prob``)."""
 
     #: attention goes through the shared ragged_attention_block, whose
     #: write path quantizes on insert — int8 KV works here too
     supports_quantized_kv = True
+
+    #: the grouped GEMM path.  chip_smoke.py's parity oracle is a subclass
+    #: that sets this False (dense all-experts einsum); never served
+    grouped = None
 
     def __init__(self, config: MixtralConfig, block_size: int):
         self.config = config
@@ -109,27 +143,38 @@ class RaggedMixtral:
         token_ids = batch["token_ids"]
         token_pos = batch["token_pos"]
 
-        x = params["embed_tokens"]["embedding"].astype(dt)[token_ids]
+        with jax.named_scope("embed"):
+            x = params["embed_tokens"]["embedding"].astype(dt)[token_ids]
         h, hkv, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
                      cfg.head_dim)
         cos, sin = _rotary(token_pos, d, cfg.rope_theta)
         new_cache = {}
+        # device scopes as RaggedLlama's (layers_<i>/attn/qkv with its
+        # norm, attn/rope_insert, the attention read, attn/out_proj), then
+        # moe/router (with its norm), moe/dispatch, moe/experts,
+        # moe/combine in place of mlp, then lm_head
         for i in range(cfg.num_hidden_layers):
             lp = params[f"layers_{i}"]
-            xa = _rms_norm(x, lp["input_layernorm"]["scale"],
-                           cfg.rms_norm_eps)
-            out, new_cache[f"layer_{i}"] = ragged_attention_block(
-                lp["self_attn"], xa, kv_cache[f"layer_{i}"], batch,
-                self.block_size, cfg, h, hkv, d, cos, sin,
-                prefill_tile=prefill_tile, decode_mode=decode)
-            x = x + out
-            xm = _rms_norm(x, lp["post_attention_layernorm"]["scale"],
-                           cfg.rms_norm_eps)
-            x = x + dropless_moe(
-                xm, lp["block_sparse_moe"]["deepspeed_moe"],
-                cfg.num_experts_per_tok, dt)
-        x = _rms_norm(x, params["norm"]["scale"], cfg.rms_norm_eps)
-        # slot rows gathered BEFORE the vocab matmul (prefill buckets
-        # would otherwise unembed every packed token row)
-        x = x[batch["logits_idx"]]
-        return x @ params["lm_head"]["kernel"].astype(dt), new_cache
+            with jax.named_scope(f"layers_{i}"):
+                with jax.named_scope("attn/qkv"):
+                    xa = _rms_norm(x, lp["input_layernorm"]["scale"],
+                                   cfg.rms_norm_eps)
+                out, new_cache[f"layer_{i}"] = ragged_attention_block(
+                    lp["self_attn"], xa, kv_cache[f"layer_{i}"], batch,
+                    self.block_size, cfg, h, hkv, d, cos, sin,
+                    prefill_tile=prefill_tile, decode_mode=decode)
+                x = x + out
+                with jax.named_scope("moe/router"):
+                    xm = _rms_norm(x, lp["post_attention_layernorm"]["scale"],
+                                   cfg.rms_norm_eps)
+                x = x + dropless_moe(
+                    xm, lp["block_sparse_moe"]["deepspeed_moe"],
+                    cfg.num_experts_per_tok, dt, grouped=self.grouped,
+                    renormalize=cfg.norm_topk_prob)
+        with jax.named_scope("lm_head"):
+            x = _rms_norm(x, params["norm"]["scale"], cfg.rms_norm_eps)
+            # slot rows gathered BEFORE the vocab matmul (prefill buckets
+            # would otherwise unembed every packed token row)
+            x = x[batch["logits_idx"]]
+            logits = x @ params["lm_head"]["kernel"].astype(dt)
+        return logits, new_cache

@@ -64,6 +64,9 @@ class LlamaConfig:
     # run full-lane MXU dots (ineligible geometries fall back to
     # folded/bshd per call).
     attention_layout: Any = None
+    # OLMo-2 / OLMoE: RMSNorm over the WHOLE q and k projections (all heads
+    # at once), before the head split and the rotary embedding.
+    qk_norm: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -160,13 +163,20 @@ class LlamaAttention(nn.Module):
             # one wide matmul (fused qkv_gemm) then split
             qkv = dense((h + 2 * hkv) * d, "qkv_proj")(x)
             q, k, v = jnp.split(qkv, [h * d, (h + hkv) * d], axis=-1)
-            q = q.reshape(*x.shape[:2], h, d)
-            k = k.reshape(*x.shape[:2], hkv, d)
-            v = v.reshape(*x.shape[:2], hkv, d)
         else:
-            q = dense(h * d, "q_proj")(x).reshape(*x.shape[:2], h, d)
-            k = dense(hkv * d, "k_proj")(x).reshape(*x.shape[:2], hkv, d)
-            v = dense(hkv * d, "v_proj")(x).reshape(*x.shape[:2], hkv, d)
+            q = k = v = None
+
+        def heads(t, name, n):
+            """One projection (unless the fused one made it), its RMSNorm
+            over all heads at once where the config has ``qk_norm``, the
+            head split."""
+            if t is None:
+                t = dense(n * d, f"{name}_proj")(x)
+            if cfg.qk_norm and name != "v":
+                t = RMSNorm(cfg.rms_norm_eps, name=f"{name}_norm")(t)
+            return t.reshape(*x.shape[:2], n, d)
+
+        q, k, v = heads(q, "q", h), heads(k, "k", hkv), heads(v, "v", hkv)
         cos, sin = rotary_embedding(positions, d, cfg.rope_theta)
         q = apply_rotary(q, cos, sin)
         k = apply_rotary(k, cos, sin)

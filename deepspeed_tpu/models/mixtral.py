@@ -31,6 +31,12 @@ class MixtralConfig(LlamaConfig):
     num_experts_per_tok: int = 2
     router_aux_loss_coef: float = 0.02
     moe_capacity_factor: float = 1.25
+    # HF ``norm_topk_prob``: the top-k router weights renormalised to sum
+    # to one (Mixtral) or used as the softmax gave them (OLMoE: False).
+    # Serving honours it always; the training gate on its dropless path,
+    # which ``MixtralBlock`` takes for more than two experts a token (the
+    # capacity gate knows k <= 2 and always renormalises).
+    norm_topk_prob: bool = True
 
     @staticmethod
     def tiny(**kw) -> "MixtralConfig":
@@ -48,6 +54,21 @@ class MixtralConfig(LlamaConfig):
                     num_attention_heads=32, num_key_value_heads=8,
                     num_local_experts=8, num_experts_per_tok=2,
                     rope_theta=1e6)
+        base.update(kw)
+        return MixtralConfig(**base)
+
+    @staticmethod
+    def olmoe_1b_7b(**kw) -> "MixtralConfig":
+        """OLMoE-1B-7B (allenai, ``model_type: olmoe``): 64 experts of
+        width 1024 at top-8 with unnormalised router weights, 16 KV heads,
+        RMSNorm on the whole q and k projections."""
+        base = dict(vocab_size=50304, hidden_size=2048,
+                    intermediate_size=1024, num_hidden_layers=16,
+                    num_attention_heads=16, num_key_value_heads=16,
+                    max_position_embeddings=4096, rms_norm_eps=1e-5,
+                    rope_theta=10000.0, num_local_experts=64,
+                    num_experts_per_tok=8, qk_norm=True,
+                    norm_topk_prob=False)
         base.update(kw)
         return MixtralConfig(**base)
 
@@ -82,7 +103,8 @@ class MixtralBlock(nn.Module):
             k=cfg.num_experts_per_tok,
             capacity_factor=cfg.moe_capacity_factor,
             eval_capacity_factor=cfg.moe_capacity_factor,
-            dtype=cfg.dtype, name="block_sparse_moe")(
+            dtype=cfg.dtype, dropless=cfg.num_experts_per_tok > 2,
+            norm_topk_prob=cfg.norm_topk_prob, name="block_sparse_moe")(
                 RMSNorm(cfg.rms_norm_eps, name="post_attention_layernorm")(x),
                 train=train, rng=rng)
         return x + moe_out, l_aux

@@ -34,6 +34,9 @@ class MoE(nn.Module):
     #: Megablocks-style dropless routing via the grouped GEMM kernel
     #: (ops/grouped_gemm.py); see MOELayer.dropless
     dropless: bool = False
+    #: dropless routing only: renormalise the top-k weights (HF Mixtral)
+    #: or keep the softmax's own (OLMoE ``norm_topk_prob: false``)
+    norm_topk_prob: bool = True
 
     def _validate(self):
         if self.num_experts % max(1, self.ep_size) != 0:
@@ -52,7 +55,7 @@ class MoE(nn.Module):
             min_capacity=self.min_capacity,
             noisy_gate_policy=self.noisy_gate_policy,
             drop_tokens=self.drop_tokens, dtype=self.dtype, mesh=self.mesh,
-            dropless=self.dropless,
+            dropless=self.dropless, norm_topk_prob=self.norm_topk_prob,
             name="deepspeed_moe")(hidden_states, train=train, rng=rng)
         if self.use_residual:
             # reference residual MoE (PR-MoE): dense FFN + learned mix

@@ -121,6 +121,7 @@ class TopKGate(nn.Module):
     min_capacity: int = 4
     noisy_gate_policy: Optional[str] = None
     drop_tokens: bool = True
+    norm_topk_prob: bool = True       # dropless path only
 
     @nn.compact
     def __call__(self, x, train: bool = True, rng=None,
@@ -129,13 +130,14 @@ class TopKGate(nn.Module):
                           dtype=jnp.float32, param_dtype=jnp.float32,
                           name="wg")(x.astype(jnp.float32))
         if dropless:
-            # Megablocks-style routing: exact top-k with renormalised
-            # weights, NO capacity buckets (grouped GEMM handles the
+            # Megablocks-style routing: exact top-k (weights renormalised
+            # unless norm_topk_prob is off), NO capacity buckets (grouped GEMM handles the
             # ragged per-expert token counts).  Returns
             # (l_aux, topi [S,k], topw [S,k]).
             from deepspeed_tpu.ops.grouped_gemm import exact_topk_routing
 
-            topi, topw = exact_topk_routing(logits, self.k)
+            topi, topw = exact_topk_routing(logits, self.k,
+                                            self.norm_topk_prob)
             probs = jax.nn.softmax(logits, axis=-1)
             me = jnp.mean(probs, axis=0)
             ce = jnp.mean(
@@ -214,6 +216,7 @@ class MOELayer(nn.Module):
     #: ep_size == 1 (expert weights replicated or TP-sharded) — the
     #: capacity path remains the expert-parallel all-to-all form.
     dropless: bool = False
+    norm_topk_prob: bool = True       # dropless path only
 
     @nn.compact
     def __call__(self, x, train: bool = True, rng=None):
@@ -236,7 +239,8 @@ class MOELayer(nn.Module):
                     "dropless MoE uses exact top-k routing; "
                     "noisy_gate_policy is not supported with dropless=True")
             l_aux, topi, topw = TopKGate(
-                self.num_experts, self.k, name="gate")(
+                self.num_experts, self.k,
+                norm_topk_prob=self.norm_topk_prob, name="gate")(
                     tokens, train=train, dropless=True)
             out = ExpertsFFN(self.num_experts, self.hidden,
                              self.intermediate, self.dtype,

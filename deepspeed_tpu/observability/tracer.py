@@ -52,8 +52,8 @@ span                     site                        parent    attrs (counters)
                          the metadata, its upload              padded to
 ``engine/ragged_step``   the step's dispatch         prefill   —
 ``engine/fetch_logits``  ``device_get(logits)``      prefill   —
-``engine/decode_prep``   ``decode_step``: KV slots,  decode    —
-                         table upload, token array
+``engine/decode_prep``   ``decode_step``: KV slots,  decode    ``seqs``: live
+                         table upload, token array             rows of the step
 ``engine/decode_step``   the step's dispatch         decode    —
 ``engine/verify_step``   the step's dispatch         verify    —
 ``fetch``                ``scheduler._fetch``: the   decode /  —

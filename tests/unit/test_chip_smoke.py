@@ -8,6 +8,7 @@ size on the 8-device CPU mesh with the chip check waived explicitly — which
 the command line cannot do.
 """
 
+import dataclasses
 import json
 import os
 import subprocess
@@ -71,6 +72,40 @@ def test_smoke_phases_tiny_on_cpu_mesh(monkeypatch):
     for tree in serve["state_shards"].values():
         assert len(tree["per_device_bytes"]) == 2
         assert min(tree["per_device_bytes"]) > 0
+
+
+def test_smoke_moe_phase_tiny_on_one_cpu_device():
+    """The routed-expert phase's control flow at a tiny OLMoE-shaped size:
+    the grouped GEMM (interpreted) against ``gmm_reference`` at two row
+    counts, and the engine's grouped path against the dense composition,
+    each program on the expert path it was built for."""
+    from deepspeed_tpu.models.mixtral import MixtralConfig
+
+    sizes = dataclasses.replace(
+        _tiny_sizes(), token_budget=64, max_seqs=4,
+        moe_config=MixtralConfig.olmoe_1b_7b(
+            vocab_size=256, hidden_size=128, intermediate_size=128,
+            num_hidden_layers=2, num_attention_heads=4,
+            num_key_value_heads=4, max_position_embeddings=256,
+            num_local_experts=8, num_experts_per_tok=2, dtype=jnp.bfloat16),
+        moe_gmm_rows=(128, 256), moe_prompt_len=40, moe_new_tokens=3)
+    out = chip_smoke.moe_phase(sizes, jax.devices()[:1], False,
+                               chip_smoke.CompileClock())
+    for name, rows in (("gmm_fwd_e64_decode", 128),
+                       ("gmm_fwd_e64_prefill", 256)):
+        assert out[name]["rows"] == rows
+        assert out[name]["max_err"] <= chip_smoke.GMM_TOL
+    assert out["router_f32_agreement"]["same_top_k"] >= \
+        chip_smoke.ROUTER_AGREE_FLOOR
+    serve = out["ragged_moe_serve"]
+    assert serve["logit_err_vs_dense"] <= chip_smoke.MOE_LOGIT_TOL
+    assert {n.split("/")[0] for n in serve["routes"]} == {"grouped", "dense"}
+    assert any(n.endswith("decode_step") for n in serve["routes"])
+    for name, r in serve["routes"].items():
+        assert r["all_experts_einsum"] == name.startswith("dense/")
+    # without a moe_config (a caller's own SmokeSizes) the phase says so
+    assert "skipped" in chip_smoke.moe_phase(
+        _tiny_sizes(), jax.devices()[:1], False, chip_smoke.CompileClock())
 
 
 def test_smoke_gates_fail_loudly():

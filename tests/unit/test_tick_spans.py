@@ -129,9 +129,14 @@ def test_pure_decode_tick_tree(traced):
         assert sorted(kids) == ["advance", "decode", "engine/decode_prep",
                                 "engine/decode_step", "fetch", "pack"]
         assert all(len(v) == 1 for v in kids.values())
-        # a counter is recorded once, where something reads it: none of
-        # a decode tick's inner spans owns one
-        assert not any("attrs" in v[0] for v in kids.values())
+        # a counter is recorded once, where something reads it: of a
+        # decode tick's inner spans only engine/decode_prep owns one, the
+        # live rows of the step (``gmm_roofline_pct`` of the benchmark
+        # reads it)
+        assert {k for k, v in kids.items() if "attrs" in v[0]} == \
+            {"engine/decode_prep"}
+        seqs = kids["engine/decode_prep"][0]["attrs"]
+        assert set(seqs) == {"seqs"} and 1 <= seqs["seqs"] <= 4
         order = [kids[k][0] for k in ("engine/decode_prep",
                                       "engine/decode_step", "fetch",
                                       "advance")]
