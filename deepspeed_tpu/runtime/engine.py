@@ -373,6 +373,7 @@ class DeepSpeedEngine:
         self._apply_in_shapes = None
         self._fused_in_shapes = None  # fused-step shapes (memory ledger)
         self._shardings: Optional[Dict[str, Any]] = None
+        self._param_use_shardings = None  # stage 3: params as gathered
         self._rng = jax.random.key(self.config.seed)
 
         from deepspeed_tpu.monitor.monitor import MonitorMaster
@@ -480,6 +481,10 @@ class DeepSpeedEngine:
         param_s = named(self.zero.param_specs(params_shapes, base))
         master_s = named(self.zero.master_specs(params_shapes, base))
         grad_s = named(self.zero.grad_specs(params_shapes, base))
+        # stage 3 only: what each leaf is gathered to where it is used
+        self._param_use_shardings = named(
+            self.zero.param_use_specs(params_shapes, base)) \
+            if self.zero_stage >= 3 else None
         scalar = NamedSharding(mesh, P())
         opt_shapes = jax.eval_shape(self.optimizer_def.init, params_shapes)
         # moments mirror the master sharding of their parameter
@@ -634,11 +639,28 @@ class DeepSpeedEngine:
 
         def micro_grads(params, scale, rng, args):
             if self.zero_stage >= 3:
-                # order the stage-3 param all-gathers into
-                # allgather_bucket_size groups (overlap_comm)
                 with jax.named_scope("zero/gather"):
+                    # order the stage-3 param all-gathers into
+                    # allgather_bucket_size groups (overlap_comm)
                     params = self._comm_bucket_chain(
                         params, self._allgather_bucket_bytes)
+                    # Gather on use: each leaf is constrained to the spec it
+                    # has while it is used (its TP base spec), so the model
+                    # multiplies by a weight that is whole over the ZeRO
+                    # axes and activations keep the Megatron placement
+                    # (batch over 'data', heads / intermediate over
+                    # 'model').  The stored spec alone does not say that:
+                    # on a data x model mesh GSPMD reads P('data','model')
+                    # as 2-D tensor parallelism and reshards the
+                    # ACTIVATIONS to the weights (zero/partition.py).  The
+                    # constraint sits outside the differentiated function,
+                    # so it binds the primal only: no cotangent is pinned
+                    # to the gathered spec, and each weight gradient is
+                    # reduce-scattered straight to its grad spec.  XLA keeps
+                    # a gathered weight for the backward pass while memory
+                    # allows and gathers it again when it does not.
+                    params = jax.lax.with_sharding_constraint(
+                        params, self._param_use_shardings)
 
             def scaled_loss_fn(p):
                 out = self._apply_fn(p, *args, rng=rng, train=True)

@@ -15,11 +15,28 @@ stage  optimizer state      gradients            parameters
 3      sharded              reduce-scattered     sharded (gathered on use)
 =====  ===================  ===================  =====================
 
-Handing these specs to ``jit`` as in/out shardings makes XLA emit exactly the
-reference's communication pattern — reduce-scatter of grads, all-gather of
-stage-3 params ahead of use — with the latency-hiding scheduler playing the
-role of the reference's prefetch coordinator
+Handing the optimizer-state and gradient specs to ``jit`` as in/out
+shardings makes XLA emit the reference's pattern for them (reduce-scatter of
+grads into sharded state), with the latency-hiding scheduler playing the role
+of the reference's prefetch coordinator
 (zero/partitioned_param_coordinator.py:58) and bucketer (stage_1_and_2.py:888).
+
+**The stage-3 parameter spec alone does not.**  A spec says where an array is
+stored, not that it is gathered before use.  ``param_specs`` adds the ZeRO
+axes to a dimension of each kernel (``q_proj`` ``P(None, 'model')`` becomes
+``P('data', 'model')``: the contraction dimension over ``data``), and GSPMD
+reads that as two-dimensional tensor parallelism: it leaves the weight where
+it is and moves the *activations* to it.  The Mistral-7B ZeRO-3 x TP step on
+a 2 x 2 mesh compiled to 72 ``all-to-all``s (the float32 residual stream
+turned from batch-over-``data`` to hidden-over-``data`` inside every RMSNorm
+and back, 54 ms of a 298 ms step on a v5e) and gathers of ``[batch, seq, ...]``
+activations; the same happens on a pure ``data`` mesh.  So the engine says
+"gathered on use" itself: ``param_use_specs`` is the spec a parameter has
+while it is used (its TP base spec), and ``engine._make_micro_grads``
+constrains every stage-3 leaf to it inside the ``zero/gather`` scope.  The
+compiled step then all-gathers weights only, activations keep the Megatron
+placement (batch over ``data``, heads / intermediate over ``model``) through
+every layer, and each weight gradient is reduce-scattered to ``grad_specs``.
 
 ``param_persistence_threshold`` keeps small params replicated even at stage 3,
 mirroring the reference's persistence heuristic
@@ -143,9 +160,17 @@ class ZeroShardings:
 
     # ------------------------------------------------------------------ #
     def param_specs(self, shapes, base_specs=None):
-        """Compute-precision parameters (the model's working copy)."""
+        """Compute-precision parameters (the model's working copy) as they
+        are STORED between uses."""
         if self.stage >= 3:
             return self._sharded(shapes, base_specs, axes=self.param_axes)
+        return self._base(shapes, base_specs)
+
+    def param_use_specs(self, shapes, base_specs=None):
+        """Compute-precision parameters while they are USED: gathered over
+        the ZeRO axes, so only the TP base spec is left (what
+        ``param_specs`` returns below stage 3).  The engine constrains each
+        stage-3 leaf to this inside its ``zero/gather`` scope."""
         return self._base(shapes, base_specs)
 
     def master_specs(self, shapes, base_specs=None):

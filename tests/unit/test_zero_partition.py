@@ -2,8 +2,10 @@
 
 import jax
 import numpy as np
+import pytest
 from jax.sharding import PartitionSpec as P
 
+from deepspeed_tpu.analysis.hlo_collectives import collectives, summary
 from deepspeed_tpu.parallel import groups
 from deepspeed_tpu.parallel.topology import MeshTopology, ParallelDims
 from deepspeed_tpu.runtime.zero import ZeroShardings, shard_leaf_spec
@@ -74,3 +76,166 @@ def test_stage3_persistence_threshold():
     # master always shards regardless of persistence floor
     m = zs.master_specs(shapes)
     assert m["small"] != P()
+
+
+# What the TPU compiler writes and the CPU tests below never produce: a
+# reduce-scatter as an ``all-reduce-scatter`` fusion, one asynchronous
+# collective cloned under one channel_id, a ``-start`` that lists operand and
+# result.  Lines cut from the compiled Mistral-7B ZeRO-3 x TP step.
+_TPU_HLO = """\
+%all-reduce-scatter.3 (input.3: bf16[2,4096,7168]) -> bf16[2080,7168] {
+  %all-reduce.240 = bf16[4160,7168]{1,0:T(8,128)(2,1)} all-reduce(%pad.57), channel_id=491, replica_groups={{0,2},{1,3}}, to_apply=%add.10.clone
+}
+
+%fused_computation.7 (param_0.1: bf16[2048,7168]) -> bf16[4096,7168] {
+  %all-gather.309 = bf16[4096,7168]{1,0:T(8,128)(2,1)} all-gather(%param_0.1), channel_id=13, dimensions={0}, metadata={op_name="jit(fused)/zero/gather/sharding_constraint" stack_frame_id=17}
+}
+
+ENTRY %main.1 (p: bf16[2048,7168]) -> bf16[4096,7168] {
+  %all-gather.311 = bf16[4096,7168]{1,0:T(8,128)(2,1)} all-gather(%param_0.2), channel_id=13, dimensions={0}, metadata={op_name="jit(fused)/zero/gather/sharding_constraint" stack_frame_id=17}
+  %all-to-all.6 = f32[2,1,4096,2048]{3,2,1,0:T(8,128)} all-to-all(%broadcast.354), channel_id=136, dimensions={0}, metadata={op_name="jit(fused)/transpose(jvp(M))/model/norm/mul" stack_frame_id=158}
+  %collective-permute-start = (s32[1,4096,1]{1,2,0:T(1,128)}, s32[1,4096,1]{1,2,0:T(1,128)}, u32[]{:S(2)}, u32[]{:S(2)}) collective-permute-start(%fusion.736), channel_id=14, source_target_pairs={{0,0},{1,2}}
+  %collective-permute-done = s32[1,4096,1]{1,2,0:T(1,128)} collective-permute-done(%collective-permute-start)
+  %all-reduce.7 = (f32[], f32[]) all-reduce(%a, %b), channel_id=15, to_apply=%add
+}
+"""
+
+
+def test_hlo_collectives_reads_the_tpu_compilers_forms():
+    found = collectives(_TPU_HLO)
+    assert [(c.kind, c.results) for c in found] == [
+        ("reduce-scatter", (("bf16", (2080, 7168)),)),
+        ("all-gather", (("bf16", (4096, 7168)),)),      # channel 13, once
+        ("all-to-all", (("f32", (2, 1, 4096, 2048)),)),
+        ("collective-permute", (("s32", (1, 4096, 1)),)),
+        ("all-reduce", (("f32", ()), ("f32", ()))),
+    ]
+    assert found[1].scope == "jit(fused)/zero/gather/sharding_constraint"
+    assert found[2].max_rank == 4
+    by_kind = summary(found)
+    assert by_kind["all-gather"] == {"count": 1, "bytes": 4096 * 7168 * 2}
+    assert by_kind["all-reduce"] == {"count": 1, "bytes": 8}
+
+
+# --------------------------------------------------------------------- #
+# Stage 3 gathers its weights on use (engine._make_micro_grads): read off
+# the compiled step, and checked against a single-device run.
+# --------------------------------------------------------------------- #
+_ADAMW = {"type": "AdamW", "params": {"lr": 3e-3, "weight_decay": 0.0}}
+
+
+def _llama_engine(dp, tp, stage, gas=1, threshold=0, micro=1, dtype="bf16",
+                  optimizer=_ADAMW):
+    import jax.numpy as jnp
+
+    import deepspeed_tpu
+    from deepspeed_tpu.models import LlamaConfig, LlamaForCausalLM
+
+    groups.reset()
+    topo = groups.initialize_mesh(
+        model_parallel_size=tp, data_parallel_size=dp,
+        devices=jax.devices()[:dp * tp])
+    cfg = {
+        "train_micro_batch_size_per_gpu": micro,
+        "gradient_accumulation_steps": gas,
+        "optimizer": optimizer,
+        "zero_optimization": {"stage": stage,
+                              "stage3_param_persistence_threshold": threshold},
+        "gradient_clipping": 1.0,
+    }
+    if dtype == "bf16":
+        cfg["bf16"] = {"enabled": True}
+    model_cfg = LlamaConfig.tiny(
+        dtype=jnp.bfloat16 if dtype == "bf16" else jnp.float32)
+    engine, _, _, _ = deepspeed_tpu.initialize(
+        model=LlamaForCausalLM(model_cfg), config=cfg, topology=topo)
+    return engine, model_cfg
+
+
+def _token_batches(n, batch, vocab, seq=32, seed=1):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, vocab, size=(batch, seq)).astype(np.int32)
+            for _ in range(n)]
+
+
+def _step(engine, ids):
+    loss = engine(ids, ids)
+    engine.backward(loss)
+    engine.step()
+    return float(jax.device_get(loss))
+
+
+def _compiled_step(dp, tp, stage):
+    engine, model_cfg = _llama_engine(dp, tp, stage)
+    _step(engine, _token_batches(1, dp, model_cfg.vocab_size)[0])
+    return engine, engine.lower_train_step().compile().as_text()
+
+
+def _is_float(c):
+    return all(dtype in ("bf16", "f16", "f32") for dtype, _ in c.results)
+
+
+@pytest.mark.parametrize("dp,tp", [(2, 2), (4, 2), (4, 1)])
+def test_stage3_gathers_weights_not_activations(dp, tp):
+    """On a data x model mesh (and on a pure data mesh) the compiled fused
+    step moves WEIGHTS over the ZeRO axes, under ``zero/gather``; no
+    activation is resharded between 'data' and 'model'.  At the parent of
+    PR 26 this program held 16 all-to-alls and gathers of [batch, seq, ...]
+    activations: the stored specs alone read as 2-D tensor parallelism."""
+    engine, hlo = _compiled_step(dp, tp, stage=3)
+    found = collectives(hlo)
+    # the embedding's backward scatter-add moves its [batch, seq, hidden]
+    # cotangent (and the token ids) once, at every stage: not a resharding
+    # the ungathered weight caused
+    in_layers = [c for c in found if "embed_tokens" not in c.scope]
+    assert [c for c in in_layers if c.kind == "all-to-all"] == []
+    gathers = [c for c in in_layers if c.kind == "all-gather"]
+    assert gathers, "stage 3 on a mesh gathers something"
+    for c in gathers:
+        assert _is_float(c) and c.max_rank <= 2, c   # parameter-shaped
+        assert "zero/gather" in c.scope, c
+    # every sharded leaf is gathered once: forward and backward share it
+    assert len(gathers) <= len(jax.tree.leaves(engine.state["params"]))
+
+
+@pytest.mark.parametrize("dp,tp,stage", [(2, 2, 1), (1, 1, 3), (1, 1, 1)])
+def test_gather_on_use_adds_nothing_where_nothing_is_sharded(dp, tp, stage):
+    """Below stage 3, and on one device, the step holds no instruction of
+    the gather: its scope is empty in the compiled program."""
+    _, hlo = _compiled_step(dp, tp, stage)
+    assert "zero/gather" not in hlo
+    if dp * tp == 1:
+        assert collectives(hlo) == []
+
+
+@pytest.mark.parametrize("dp,tp,gas,threshold", [
+    (2, 2, 1, 0), (4, 2, 1, 0), (4, 1, 1, 0),
+    (2, 2, 2, 0),        # gas 2: the micro + apply programs, not the fused
+    (2, 2, 1, 100),      # the norms (64 elements) stay unsharded
+])
+def test_stage3_on_mesh_matches_single_device(dp, tp, gas, threshold):
+    """Three optimizer steps from one seed: losses and master weights of
+    stage 3 on the mesh agree with stage 0 on one device, to the tolerance
+    of test_engine.py::test_zero_stages_agree.  SGD, because its update is
+    linear in the gradient: the partitioning sums in another order, and
+    Adam's g / sqrt(v) turns that into 3e-5 on the few elements whose
+    gradient is near zero."""
+    batch, steps = 4, 3
+    sgd = {"type": "SGD", "params": {"lr": 0.1, "momentum": 0.9}}
+    runs = []
+    for (d, t, stage) in ((1, 1, 0), (dp, tp, 3)):
+        engine, model_cfg = _llama_engine(
+            d, t, stage, gas=gas, threshold=threshold if stage else 0,
+            micro=batch // d, dtype="fp32", optimizer=sgd)
+        losses = [_step(engine, ids) for ids in
+                  _token_batches(steps * gas, batch, model_cfg.vocab_size)]
+        assert engine.global_steps == steps
+        runs.append((losses, jax.device_get(engine.state["master"])))
+    if threshold:
+        specs = jax.tree.leaves(
+            jax.tree.map(lambda x: x.sharding.spec, engine.state["params"]))
+        assert any(s == P() or s == P(None) for s in specs)
+    (ref_losses, ref_master), (losses, master) = runs
+    np.testing.assert_allclose(losses, ref_losses, rtol=1e-5, atol=1e-6)
+    for a, b in zip(jax.tree.leaves(ref_master), jax.tree.leaves(master)):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
