@@ -97,7 +97,7 @@ def _rms_norm(x, scale, eps):
 
 def _paged_attention(q, k_pool, v_pool, batch, block_size,
                      use_kernel=None, window=None, prefill_tile=None,
-                     decode_mode=False, force_dense=None, verify_k=None,
+                     decode_mode=False, verify_k=None,
                      k_scale=None, v_scale=None):
     """Paged attention over the blocked KV pool.
 
@@ -126,8 +126,6 @@ def _paged_attention(q, k_pool, v_pool, batch, block_size,
     double-buffered HBM block DMAs, so the read volume is Σ live-context
     bytes rather than O(pool) (the round-4 dense default, which becomes
     the dominant cost at 7B-scale pools) or O(S x table-width).
-    ``force_dense`` (tools/profile_decode_attn.py) pins the XLA
-    dense/gather fallbacks for comparison.
 
     The plain XLA gather composition below is the reference/CPU path.
 
@@ -142,12 +140,12 @@ def _paged_attention(q, k_pool, v_pool, batch, block_size,
         use_kernel = on_tpu()
     S, B = batch["block_tables"].shape
     # the manual-DMA walk wins when the pool is LARGER than the live
-    # contexts (its read is O(live); the dense path's is O(pool) —
-    # crossover table in tools/profile_decode_attn.py: 4.28 vs 5.77 ms at
-    # pool 512 blk / ctx 2k).  Tight pools (pool ~ live, the serving-dense
-    # case) keep the dense read, which measured ~10% faster there.
+    # contexts (its read is O(live); the dense path's is O(pool):
+    # 4.28 vs 5.77 ms at pool 512 blk / ctx 2k).  Tight pools (pool ~
+    # live, the serving-dense case) keep the dense read, which measured
+    # ~10% faster there.
     big_pool = k_pool.shape[0] > 2 * S * B * block_size
-    if use_kernel and force_dense is None:
+    if use_kernel:
         from deepspeed_tpu.inference.v2.kernels import (
             paged_attention, paged_attention_usable,
             paged_decode_attention, paged_prefill_attention,
@@ -198,8 +196,7 @@ def _paged_attention(q, k_pool, v_pool, batch, block_size,
                 return paged_attention(
                     q, k_pool, v_pool, *meta, block_size=block_size,
                     window=w)
-    if decode_mode and (force_dense if force_dense is not None
-                        else not big_pool):
+    if decode_mode and not big_pool:
         with jax.named_scope("attn/dense_read"):
             return _dense_pool_read(q, k_pool, v_pool, k_scale, v_scale,
                                     batch, block_size, window)

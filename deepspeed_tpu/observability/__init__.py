@@ -1,8 +1,6 @@
 """Observability layer: request-scoped distributed tracing, the unified
 metrics registry, the crash flight recorder (the capture surface, PR 12)
-— and the ANALYSIS layer over those captures: roofline/MFU attribution
-(:mod:`~deepspeed_tpu.observability.roofline`) and the HLO memory
-ledger + live occupancy gauges
+— and the HLO memory ledger + live occupancy gauges over those captures
 (:mod:`~deepspeed_tpu.observability.memory`).
 
 Typical use::
@@ -33,9 +31,7 @@ Every request carries a ``trace_id`` minted at submit; spans from every
 replica incarnation it touches (kill→replay, rolling restarts,
 disaggregated prefill→decode handoff) share that id, so the exported
 timeline shows ONE request's whole life.  ``tools/obs_dump.py`` renders
-and schema-validates the export; ``tools/perf_report.py`` renders the
-MFU waterfall + memory ledger from a bench record; ``tools/
-perf_gate.py`` gates fresh numbers against the BENCH history.
+and schema-validates the export.
 """
 
 from deepspeed_tpu.observability import metrics as _metrics  # noqa: F401
@@ -48,16 +44,10 @@ from deepspeed_tpu.observability.memory import (MemoryLedger,
                                                 capture_memory_analysis,
                                                 kv_occupancy,
                                                 make_occupancy_provider,
-                                                tenant_occupancy,
-                                                virtual_mesh_probe)
+                                                tenant_occupancy)
 from deepspeed_tpu.observability.registry import (MetricSpec,
                                                   MetricsRegistry,
                                                   default_registry)
-from deepspeed_tpu.observability.roofline import (OpCost, Waterfall,
-                                                  build_waterfall,
-                                                  chip_specs,
-                                                  format_waterfall,
-                                                  phase_durations)
 from deepspeed_tpu.observability.tracer import (Tracer, annotate,
                                                 device_annotations_enabled,
                                                 enable_device_annotations,
@@ -66,13 +56,11 @@ from deepspeed_tpu.observability.tracer import (Tracer, annotate,
                                                 open_span, step_annotation,
                                                 write_chrome_trace)
 
-__all__ = ["FlightRecorder", "MemoryLedger", "MetricSpec",
-           "MetricsRegistry", "OpCost", "Tracer", "Waterfall", "annotate",
-           "build_waterfall", "capture_cost_analysis",
-           "capture_memory_analysis", "chip_specs", "default_registry",
+__all__ = ["FlightRecorder", "MemoryLedger", "MetricSpec", "MetricsRegistry",
+           "Tracer", "annotate", "capture_cost_analysis",
+           "capture_memory_analysis", "default_registry",
            "device_annotations_enabled", "enable_device_annotations",
-           "format_waterfall", "kv_occupancy", "list_postmortems",
-           "load_chrome_trace", "load_postmortem",
-           "make_occupancy_provider", "merge_events", "mint_trace_id",
-           "open_span", "phase_durations", "step_annotation", "tenant_occupancy",
-           "virtual_mesh_probe", "write_chrome_trace", "write_postmortem"]
+           "kv_occupancy", "list_postmortems", "load_chrome_trace",
+           "load_postmortem", "make_occupancy_provider", "merge_events",
+           "mint_trace_id", "open_span", "step_annotation",
+           "tenant_occupancy", "write_chrome_trace", "write_postmortem"]

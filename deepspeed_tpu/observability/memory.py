@@ -7,13 +7,7 @@ Two kinds of memory evidence, one API:
   ``cost_analysis()`` (flops, bytes accessed) per named program, with an
   explicit ``{"available": False, "reason": ...}`` record on backends
   that omit the analysis or lowerings that fail — a claim of absence is
-  still a record, never a silent skip.  :func:`virtual_mesh_probe` is
-  the reusable form of ROADMAP item 3's "HLO memory evidence on virtual
-  meshes": it abstract-lowers (``jax.eval_shape`` — **no weights are
-  ever materialised**) a ZeRO-3-style sharded train step for a named
-  geometry on the host's virtual device mesh and ledgers the result, so
-  the 7B ZeRO-3 / MoE / long-seq compile claims are a config entry, not
-  a bespoke script.
+  still a record, never a silent skip.
 
 * **live** — :func:`kv_occupancy` / :func:`tenant_occupancy` /
   :func:`hbm_footprint` read HOST-SIDE bookkeeping only (allocator free
@@ -83,9 +77,8 @@ def capture_cost_analysis(compiled) -> Dict[str, float]:
 
 def unavailable_entry(reason: str,
                       meta: Optional[dict] = None) -> Dict[str, Any]:
-    """One ledger entry claiming absence — the SINGLE definition of the
-    unavailable-record shape every BENCH JSON consumer parses (bench.py,
-    bench_serving.py and the subprocess probe build theirs here too)."""
+    """One ledger entry claiming absence — the single definition of the
+    unavailable-record shape."""
     return {"memory": {"available": False, "reason": str(reason)},
             "cost": {"flops": 0.0, "bytes_accessed": 0.0},
             **({"meta": dict(meta)} if meta else {})}
@@ -174,193 +167,6 @@ class MemoryLedger:
                     out[f"observability/hbm_{name}_{short}_bytes"] = \
                         float(mem[f])
         return out
-
-
-# --------------------------------------------------------------------- #
-# Virtual-mesh compile probes (ROADMAP item 3's evidence, as one API)
-# --------------------------------------------------------------------- #
-def _zero3_shard_spec(shape, mesh_size: int):
-    """ZeRO-3-style placement: shard the first divisible dim across the
-    data axis, replicate otherwise (what partition padding buys on the
-    real engine)."""
-    from jax.sharding import PartitionSpec as P
-
-    for i, d in enumerate(shape):
-        if d >= mesh_size and d % mesh_size == 0:
-            return P(*([None] * i + ["data"]))
-    return P()
-
-
-def zero3_train_lowering(model, batch: int, seq: int,
-                         optimizer_dtype="float32"):
-    """Abstract-lower a ZeRO-3-style fwd+bwd+Adam train step for
-    ``model`` on a virtual ``('data',)`` mesh over ALL visible devices.
-
-    Params, grads, and optimizer moments are sharded per
-    :func:`_zero3_shard_spec` (per-device shards; GSPMD materialises the
-    gathers), the batch is dp-sharded.  Everything is
-    ``ShapeDtypeStruct`` — a 7B lowering runs on a laptop because no
-    array is ever allocated.  Returns the lowered object (call
-    ``.compile()`` for ``memory_analysis``)."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-    from jax.sharding import Mesh, NamedSharding
-    from jax.sharding import PartitionSpec as P
-
-    mesh = Mesh(np.array(jax.devices()), ("data",))
-
-    def sds(s, dtype=None, spec=None):
-        return jax.ShapeDtypeStruct(
-            s.shape, dtype or s.dtype,
-            sharding=NamedSharding(
-                mesh, spec if spec is not None
-                else _zero3_shard_spec(s.shape, mesh.size)))
-
-    pshapes = jax.eval_shape(
-        lambda: model.init(jax.random.key(0),
-                           jnp.zeros((1, 4), jnp.int32))["params"])
-    params = jax.tree.map(sds, pshapes)
-    moment = jax.tree.map(lambda s: sds(s, jnp.dtype(optimizer_dtype)),
-                          pshapes)
-    ids = jax.ShapeDtypeStruct((batch, seq), jnp.int32,
-                               sharding=NamedSharding(mesh, P("data")))
-
-    def train_step(params, m, v, ids):
-        loss, g = jax.value_and_grad(
-            lambda p: model.apply({"params": p}, ids, ids))(params)
-        new_m = jax.tree.map(
-            lambda a, b: 0.9 * a + 0.1 * b.astype(a.dtype), m, g)
-        new_v = jax.tree.map(
-            lambda a, b: 0.999 * a + 0.001 * (b.astype(a.dtype) ** 2),
-            v, g)
-        new_p = jax.tree.map(
-            lambda p, mm, vv: (p.astype(mm.dtype)
-                               - 1e-4 * mm / (jnp.sqrt(vv) + 1e-8)
-                               ).astype(p.dtype),
-            params, new_m, new_v)
-        return new_p, new_m, new_v, loss
-
-    return jax.jit(train_step).lower(params, moment, moment, ids)
-
-
-def _probe_7b_zero3():
-    import jax.numpy as jnp
-
-    from deepspeed_tpu.models import LlamaConfig, LlamaForCausalLM
-
-    cfg = LlamaConfig.llama2_7b(dtype=jnp.bfloat16)
-    return (LlamaForCausalLM(cfg), 8, 1024,
-            {"geometry": "llama2-7b 4096h/11008i/32L/32H bf16",
-             "zero_stage": 3, "batch": 8, "seq": 1024})
-
-
-def _probe_125m_zero3():
-    import jax.numpy as jnp
-
-    from deepspeed_tpu.models import LlamaConfig, LlamaForCausalLM
-
-    cfg = LlamaConfig(vocab_size=32000, hidden_size=768,
-                      intermediate_size=2048, num_hidden_layers=12,
-                      num_attention_heads=12, num_key_value_heads=12,
-                      max_position_embeddings=2048, dtype=jnp.bfloat16)
-    return (LlamaForCausalLM(cfg), 8, 1024,
-            {"geometry": "gpt2-125m-class llama 768h/12L bf16",
-             "zero_stage": 3, "batch": 8, "seq": 1024})
-
-
-def _probe_tiny_zero3():
-    import jax.numpy as jnp
-
-    from deepspeed_tpu.models import LlamaConfig, LlamaForCausalLM
-
-    cfg = LlamaConfig.tiny(dtype=jnp.bfloat16)
-    return (LlamaForCausalLM(cfg), 8, 32,
-            {"geometry": "tiny llama (test probe)", "zero_stage": 3,
-             "batch": 8, "seq": 32})
-
-
-#: named probes: name -> () -> (model, batch, seq, meta).  Extend here
-#: for the remaining ROADMAP item 3 configs (Mixtral EP, 64k Ulysses)
-#: once their virtual-mesh lowerings exist — the ledger/bench plumbing
-#: is already generic.
-VIRTUAL_MESH_PROBES: Dict[str, Callable] = {
-    "7b_zero3": _probe_7b_zero3,
-    "125m_zero3": _probe_125m_zero3,
-    "tiny_zero3": _probe_tiny_zero3,
-}
-
-
-def virtual_mesh_probe(name: str,
-                       ledger: Optional[MemoryLedger] = None
-                       ) -> Dict[str, Any]:
-    """Run one named probe in-process and ledger it under
-    ``virtual_mesh/<name>``.  Any failure (OOM-sized HLO, missing
-    model) becomes an explicit ``unavailable`` record."""
-    ledger = ledger if ledger is not None else MemoryLedger()
-    key = f"virtual_mesh/{name}"
-    builder = VIRTUAL_MESH_PROBES.get(name)
-    if builder is None:
-        return ledger.record_unavailable(
-            key, f"unknown probe {name!r} "
-                 f"(have {sorted(VIRTUAL_MESH_PROBES)})")
-    import jax
-
-    # outside the try: a backend that fails to start is an error, not an
-    # "unavailable" record
-    where = {"devices": jax.device_count(),
-             "platform": jax.devices()[0].platform}
-    try:
-        model, batch, seq, meta = builder()
-        meta = {**meta, **where}
-        lowered = zero3_train_lowering(model, batch, seq)
-        compiled = lowered.compile()
-    except Exception as e:  # noqa: BLE001 — absence is a record
-        return ledger.record_unavailable(
-            key, f"{type(e).__name__}: {e}")
-    return ledger.record(key, compiled, meta=meta)
-
-
-def virtual_mesh_probe_subprocess(name: str, timeout_s: float = 300.0,
-                                  devices: int = 8) -> Dict[str, Any]:
-    """Run :func:`virtual_mesh_probe` in a CLEAN subprocess pinned to
-    ``devices`` virtual CPU devices (the bench path: the parent may hold
-    a TPU backend, and a 7B CPU compile should never wedge the bench —
-    on timeout the record says so)."""
-    import json
-    import os
-    import subprocess
-    import sys
-
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = "cpu"
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                        f" --xla_force_host_platform_device_count={devices}"
-                        ).strip()
-    code = (
-        "import json\n"
-        "from deepspeed_tpu.observability.memory import ("
-        "MemoryLedger, virtual_mesh_probe)\n"
-        f"led = MemoryLedger()\n"
-        f"virtual_mesh_probe({name!r}, led)\n"
-        "print(json.dumps(led.to_json()))\n")
-    try:
-        r = subprocess.run([sys.executable, "-c", code], env=env,
-                           capture_output=True, text=True,
-                           timeout=timeout_s,
-                           cwd=os.path.dirname(os.path.dirname(
-                               os.path.dirname(os.path.abspath(__file__)))))
-    except subprocess.TimeoutExpired:
-        return unavailable_entry(f"probe timed out after {timeout_s}s")
-    if r.returncode != 0:
-        return unavailable_entry(f"probe rc={r.returncode}: "
-                                 f"{r.stderr.strip()[-300:]}")
-    try:
-        payload = json.loads(r.stdout.strip().splitlines()[-1])
-        return MemoryLedger.from_json(payload).entries[
-            f"virtual_mesh/{name}"]
-    except Exception as e:  # noqa: BLE001
-        return unavailable_entry(f"unparseable probe output: {e}")
 
 
 # --------------------------------------------------------------------- #

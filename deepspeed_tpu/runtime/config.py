@@ -118,14 +118,14 @@ class ZeroConfig(DeepSpeedConfigModel):
       1: optimizer state (incl. fp32 master) sharded;
       2: + gradients reduce-scattered and kept sharded;
       3: + parameters sharded (gathered on use by XLA).
-    ``overlap_comm`` (default on) buckets the fused train step's gradient
-    reduce-scatter / stage-3 param all-gather into ``reduce_bucket_size``/
-    ``allgather_bucket_size``-byte chunks chained with optimization
-    barriers, so XLA's latency-hiding scheduler interleaves per-bucket
-    collectives with backward compute instead of one combined collective
-    at the program tail (engine._comm_bucket_chain). The remaining
-    prefetch knobs (prefetch_bucket_size, ...) are accepted for config
-    parity: XLA's gather-prefetch performs the equivalent automatically.
+    Accepted for config parity and read by nothing (XLA's scheduler places
+    and overlaps the collectives): ``overlap_comm``, ``reduce_bucket_size``,
+    ``allgather_bucket_size``, ``prefetch_bucket_size``,
+    ``max_live_parameters``, ``max_reuse_distance``,
+    ``contiguous_gradients``, ``reduce_scatter``, ``allgather_partitions``,
+    ``round_robin_gradients``, ``ignore_unused_parameters``,
+    ``sub_group_size``, ``model_persistence_threshold``,
+    ``memory_efficient_linear``.
     """
 
     stage: int = 0

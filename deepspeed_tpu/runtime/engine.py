@@ -241,17 +241,6 @@ class DeepSpeedEngine:
             param_axes=param_axes, master_axes=master_axes,
             grad_axes=grad_axes)
 
-        # async collective overlap (reference stage_1_and_2.py
-        # overlap_comm / reduce_bucket_size): chunk the grad tree into
-        # bucket-size-byte groups chained by optimization barriers so the
-        # collective combiner emits one reduce-scatter per bucket and the
-        # latency-hiding scheduler interleaves them with backward compute
-        # (default ON, the reference's default for stage >= 1)
-        self._overlap_comm = (True if zc0.overlap_comm is None
-                              else bool(zc0.overlap_comm))
-        self._reduce_bucket_bytes = int(zc0.reduce_bucket_size)
-        self._allgather_bucket_bytes = int(zc0.allgather_bucket_size)
-
         # offload (reference zero/parameter_offload.py; OffloadPP ratio) ----
         from deepspeed_tpu.runtime.zero.offload import validate_offload_config
 
@@ -640,10 +629,6 @@ class DeepSpeedEngine:
         def micro_grads(params, scale, rng, args):
             if self.zero_stage >= 3:
                 with jax.named_scope("zero/gather"):
-                    # order the stage-3 param all-gathers into
-                    # allgather_bucket_size groups (overlap_comm)
-                    params = self._comm_bucket_chain(
-                        params, self._allgather_bucket_bytes)
                     # Gather on use: each leaf is constrained to the spec it
                     # has while it is used (its TP base spec), so the model
                     # multiplies by a weight that is whole over the ZeRO
@@ -669,13 +654,6 @@ class DeepSpeedEngine:
 
             (_, loss), grads = jax.value_and_grad(
                 scaled_loss_fn, has_aux=True)(params)
-            if self.zero_stage >= 1:
-                # per-bucket gradient reduce-scatter (overlap_comm): the
-                # barrier chain keeps XLA from combining every leaf's
-                # collective into one program-tail reduce
-                with jax.named_scope("zero/reduce"):
-                    grads = self._comm_bucket_chain(
-                        grads, self._reduce_bucket_bytes)
             return grads, loss
 
         return micro_grads
@@ -752,44 +730,6 @@ class DeepSpeedEngine:
             new_hyst = jnp.where(overflow, jnp.maximum(hyst - 1, 1),
                                  jnp.where(grow, full, hyst))
         return new_scale, new_good, new_hyst
-
-    def _comm_bucket_chain(self, tree, bucket_bytes: int):
-        """Collective-overlap bucketing (reference stage_1_and_2.py
-        ``overlap_comm``): split ``tree``'s leaves into bucket-size-byte
-        groups and chain the groups with ``lax.optimization_barrier`` —
-        value-identity, but the barrier chain stops XLA's collective
-        combiner from merging every leaf's reduce-scatter/all-gather into
-        ONE tail collective, so the latency-hiding scheduler can overlap
-        bucket k's collective with the compute still producing bucket
-        k+1.  No-op when overlap is off or the mesh has one device."""
-        if not self._overlap_comm or self.dp_world_size <= 1:
-            return tree
-        leaves, treedef = jax.tree_util.tree_flatten(tree)
-        if len(leaves) <= 1:
-            return tree
-        from deepspeed_tpu.runtime.zero.offload import (
-            partition_transfer_buckets)
-
-        sizes = [int(np.prod(l.shape)) * jnp.dtype(l.dtype).itemsize
-                 for l in leaves]
-        n = max(1, min(len(leaves),
-                       -(-sum(sizes) // max(int(bucket_bytes), 1))))
-        buckets = partition_transfer_buckets(sizes, n)
-        if len(buckets) <= 1:
-            return tree
-        out = list(leaves)
-        carry = None
-        for bucket in buckets:
-            vals = tuple(out[i] for i in bucket)
-            if carry is None:
-                vals = jax.lax.optimization_barrier(vals)
-            else:
-                *vals, carry = jax.lax.optimization_barrier(
-                    vals + (carry,))
-            carry = vals[0]
-            for j, i in enumerate(bucket):
-                out[i] = vals[j]
-        return jax.tree_util.tree_unflatten(treedef, out)
 
     def _make_apply_step(self):
         """The pure optimizer-step closure, shared by the standalone apply

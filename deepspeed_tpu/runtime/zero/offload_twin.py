@@ -16,8 +16,7 @@ Host tier emulation, best fidelity first:
 1. ``pinned_host`` memory-kind shardings when the default device
    advertises that memory space (TPU; the engine's real tier);
 2. a second CPU device when ``--xla_force_host_platform_device_count>=2``
-   is set (real async inter-device copies — how ``bench.py --offload-ab``
-   measures transfer/compute overlap on a CPU host);
+   is set (real async inter-device copies);
 3. same-device shardings otherwise (placement no-ops: bit-exactness and
    trace-cleanliness remain meaningful, transfer timings do not).
 """

@@ -1005,8 +1005,7 @@ class ContinuousBatchScheduler:
         """Open-loop arrival driver: submit ``prompts[i]`` once
         ``arrivals[i]`` seconds of wall clock have elapsed, stepping the
         scheduler between arrivals until everything terminates.  Used by
-        the Poisson benches (``bench_serving.py --scheduler``) and the
-        tier-1 smoke.  ``sampling`` is one :class:`SamplingParams` shared
+        the tier-1 smoke.  ``sampling`` is one :class:`SamplingParams` shared
         by all requests, or a per-request sequence."""
         n = len(prompts)
         per_req = isinstance(sampling, (list, tuple))

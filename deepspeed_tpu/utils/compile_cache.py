@@ -1,6 +1,6 @@
 """Persistent XLA compilation cache placement for the repo's entry-point
-scripts (``chip_smoke.py``, ``bench.py``, ``bench_serving.py``, the
-``tools/`` scripts that run on a chip).
+scripts (``chip_smoke.py``, ``benchmark/run.py``, the ``tools/`` scripts
+that run on a chip).
 
 The directory is part of the cache key, so it must not move between runs:
 where ``JAX_COMPILATION_CACHE_DIR`` is set JAX already reads it and nothing

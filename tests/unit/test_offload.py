@@ -458,32 +458,6 @@ def test_transfer_stats_structural_overlap():
     assert snap["observability/offload_buckets"] == 2
 
 
-def test_comm_bucket_chain_value_identity():
-    """The overlap_comm barrier chain reorders scheduling, never values:
-    every leaf comes back numerically identical, in any bucket count."""
-    from types import SimpleNamespace
-
-    from deepspeed_tpu.runtime.engine import DeepSpeedEngine
-
-    rng = np.random.default_rng(3)
-    tree = {f"g{i}": jnp.asarray(
-        rng.standard_normal((2 ** (i + 2),)).astype(np.float32))
-        for i in range(6)}
-    stub = SimpleNamespace(_overlap_comm=True, dp_world_size=2)
-    for bucket_bytes in (1, 64, 10 ** 9):
-        out = DeepSpeedEngine._comm_bucket_chain(stub, tree, bucket_bytes)
-        assert jax.tree_util.tree_structure(out) == \
-            jax.tree_util.tree_structure(tree)
-        for k in tree:
-            np.testing.assert_array_equal(np.asarray(out[k]),
-                                          np.asarray(tree[k]))
-    # disabled / single-device meshes are strict no-ops
-    off = SimpleNamespace(_overlap_comm=False, dp_world_size=2)
-    assert DeepSpeedEngine._comm_bucket_chain(off, tree, 64) is tree
-    one = SimpleNamespace(_overlap_comm=True, dp_world_size=1)
-    assert DeepSpeedEngine._comm_bucket_chain(one, tree, 64) is tree
-
-
 def test_engine_pipelined_offload_parity():
     """Full-engine pipelined-vs-sync parity (needs the multi-axis mesh
     engine; skipped on hosts where it cannot construct — the twin tests

@@ -297,40 +297,3 @@ def test_paired_steady_state_recompile_and_sync_free(trace_guard):
         for _ in range(3):
             out = step(qf, kf, vf)
     jax.block_until_ready(out)
-
-
-# ===================================================================== #
-# Roofline: the paired layout moves the lane ceiling
-# ===================================================================== #
-def test_roofline_paired_layout_full_peak_scale():
-    """train_step_costs at the honest d64 geometry: bshd/folded report
-    the half-lane ceiling (0.5), the paired layout reports FULL peak
-    (1.0) and names the row — the MFU waterfall shows the ceiling
-    moving (ISSUE 15 acceptance)."""
-    from deepspeed_tpu.observability.roofline import (build_waterfall,
-                                                      train_step_costs)
-
-    kw = dict(hidden=768, layers=12, heads=12, intermediate=2048,
-              vocab=32000, batch=8, seq=1024)
-    att = {layout: next(o for o in train_step_costs(
-        attention_layout=layout, **kw) if "flash_attention" in o.name)
-        for layout in ("bshd", "folded", "paired")}
-    assert att["bshd"].peak_scale == pytest.approx(0.5)
-    assert att["folded"].peak_scale == pytest.approx(0.5)
-    assert att["paired"].peak_scale == pytest.approx(1.0)
-    assert "paired" in att["paired"].name
-    # full lanes halve the attention row's compute-attainable time
-    wf = build_waterfall(train_step_costs(attention_layout="paired", **kw),
-                         measured_s=0.1, peak_flops=197e12, hbm_bw=819e9)
-    row = next(r for r in wf.rows if "flash_attention" in r.name)
-    wf0 = build_waterfall(train_step_costs(attention_layout="bshd", **kw),
-                          measured_s=0.1, peak_flops=197e12, hbm_bw=819e9)
-    row0 = next(r for r in wf0.rows if "flash_attention" in r.name)
-    assert row.attainable_s < row0.attainable_s
-    # d >= 128 geometries never pretend to pair
-    att128 = next(o for o in train_step_costs(
-        hidden=768, layers=6, heads=6, intermediate=2048, vocab=32000,
-        batch=16, seq=1024, attention_layout="paired")
-        if "flash_attention" in o.name)
-    assert att128.peak_scale == pytest.approx(1.0)
-    assert "paired" not in att128.name

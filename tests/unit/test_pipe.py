@@ -581,6 +581,10 @@ def test_pipeline_1f1b_loss_depth_invariant():
         return _train(eng, 3, batches), p0
 
     l2, params0 = losses_at(2)
+    # the body is stacked [stages, layers per stage, ...]: the same four
+    # layers are [2, 2, ...] at depth 2 and [4, 1, ...] at depth 4
+    params0 = {**params0, "body": jax.tree.map(
+        lambda a: a.reshape((4, 1) + a.shape[2:]), params0["body"])}
     l4, _ = losses_at(4, params0)
     np.testing.assert_allclose(l4, l2, rtol=2e-5)
 

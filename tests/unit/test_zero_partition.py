@@ -1,5 +1,7 @@
 """ZeRO sharding-policy tests (reference: tests/unit/runtime/zero/)."""
 
+import functools
+
 import jax
 import numpy as np
 import pytest
@@ -125,7 +127,7 @@ _ADAMW = {"type": "AdamW", "params": {"lr": 3e-3, "weight_decay": 0.0}}
 
 
 def _llama_engine(dp, tp, stage, gas=1, threshold=0, micro=1, dtype="bf16",
-                  optimizer=_ADAMW):
+                  optimizer=_ADAMW, zero_keys=None):
     import jax.numpy as jnp
 
     import deepspeed_tpu
@@ -140,7 +142,8 @@ def _llama_engine(dp, tp, stage, gas=1, threshold=0, micro=1, dtype="bf16",
         "gradient_accumulation_steps": gas,
         "optimizer": optimizer,
         "zero_optimization": {"stage": stage,
-                              "stage3_param_persistence_threshold": threshold},
+                              "stage3_param_persistence_threshold": threshold,
+                              **(zero_keys or {})},
         "gradient_clipping": 1.0,
     }
     if dtype == "bf16":
@@ -165,10 +168,15 @@ def _step(engine, ids):
     return float(jax.device_get(loss))
 
 
-def _compiled_step(dp, tp, stage):
-    engine, model_cfg = _llama_engine(dp, tp, stage)
+def _lowered_step(dp, tp, stage, zero_keys=None):
+    engine, model_cfg = _llama_engine(dp, tp, stage, zero_keys=zero_keys)
     _step(engine, _token_batches(1, dp, model_cfg.vocab_size)[0])
-    return engine, engine.lower_train_step().compile().as_text()
+    return engine, engine.lower_train_step()
+
+
+def _compiled_step(dp, tp, stage):
+    engine, lowered = _lowered_step(dp, tp, stage)
+    return engine, lowered.compile().as_text()
 
 
 def _is_float(c):
@@ -239,3 +247,47 @@ def test_stage3_on_mesh_matches_single_device(dp, tp, gas, threshold):
     np.testing.assert_allclose(losses, ref_losses, rtol=1e-5, atol=1e-6)
     for a, b in zip(jax.tree.leaves(ref_master), jax.tree.leaves(master)):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+
+
+# --------------------------------------------------------------------- #
+# One gradient path: XLA's scheduler places the collectives; the keys
+# DeepSpeed's bucket machinery reads are accepted and change nothing.
+# --------------------------------------------------------------------- #
+@pytest.mark.parametrize("stage", [1, 2, 3],
+                         ids=["stage1", "stage2", "stage3"])
+def test_zero_step_has_no_optimization_barrier(stage):
+    """The fused step on the 8-device mesh chains nothing: neither the
+    lowered nor the compiled program holds an optimization barrier, even
+    with buckets of a few leaves asked for (the bucket chain the chip
+    measured as a loss put one barrier per bucket there)."""
+    _, lowered = _lowered_step(4, 2, stage, zero_keys={
+        "overlap_comm": True, "reduce_bucket_size": 4096,
+        "allgather_bucket_size": 4096})
+    assert "optimization_barrier" not in lowered.as_text()
+    assert "opt-barrier" not in lowered.compile().as_text()
+
+
+@functools.lru_cache(maxsize=None)
+def _default_stage3_text():
+    return _lowered_step(2, 2, 3)[1].as_text()
+
+
+@pytest.mark.parametrize("key,value", [
+    ("overlap_comm", True), ("overlap_comm", False),
+    ("reduce_bucket_size", 4096), ("allgather_bucket_size", 4096),
+    ("stage3_prefetch_bucket_size", 1024),
+    ("stage3_max_live_parameters", 1024),
+    ("stage3_max_reuse_distance", 1024),
+    ("contiguous_gradients", False), ("reduce_scatter", False),
+    ("allgather_partitions", False), ("round_robin_gradients", True),
+    ("ignore_unused_parameters", False), ("sub_group_size", 1024),
+    ("stage3_model_persistence_threshold", 1024),
+    ("memory_efficient_linear", False),
+], ids=lambda v: v if isinstance(v, str) else str(v).lower())
+def test_zero_parity_keys_are_inert(key, value):
+    """Every key ``ZeroConfig``'s doc calls accepted for parity loads at a
+    non-default value and leaves the lowered stage-3 step byte-identical."""
+    engine, lowered = _lowered_step(2, 2, 3, zero_keys={key: value})
+    field = key.removeprefix("stage3_")
+    assert getattr(engine.config.zero_config, field) == value
+    assert lowered.as_text() == _default_stage3_text()

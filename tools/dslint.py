@@ -61,13 +61,11 @@ def run(argv=None) -> int:
     if not args.skip_metrics:
         from deepspeed_tpu.analysis.metrics_lint import run_metrics_lint
 
-        # default scope widens beyond the package: the tools/benches
-        # also name metrics, and a typo there misreads a real series
+        # default scope widens beyond the package: the tools also name
+        # metrics, and a typo there misreads a real series
         mpaths = args.paths or [
             os.path.join(repo_root(), "deepspeed_tpu"),
             os.path.join(repo_root(), "tools"),
-            os.path.join(repo_root(), "bench_serving.py"),
-            os.path.join(repo_root(), "bench.py"),
         ]
         findings.extend(run_metrics_lint(mpaths))
     if not args.skip_pallas:

@@ -5,7 +5,7 @@ interpret divergence would go unseen).
 Runs each compiled kernel on the REAL device against its jnp reference at
 small-but-representative shapes and reports max abs error per kernel.
 ``chip_smoke.py`` runs it as a gate (any case not ``ok`` fails the smoke);
-``bench.py`` embeds the result in its JSON; standalone:
+standalone:
 
     python tools/kernel_selftest.py
 
@@ -292,8 +292,7 @@ def run_selftest(tol: float = 3e-2) -> dict:
                                interpret=False), wantv8))
 
     # prefill: tile-aligned tokens for slot 0, at the ENGINE's shipped
-    # 125M serving geometry (6 q heads / 2 kv heads — the exact kernel
-    # instantiation bench_serving.py runs)
+    # 125M serving geometry (6 q heads / 2 kv heads)
     T = 256
     qp = jax.random.normal(jax.random.fold_in(key, 9), (T, 6, 64),
                            jnp.bfloat16)
