@@ -123,6 +123,7 @@ def run_replica_worker(spool_dir: str, scheduler,
             ev.flush()
 
         while True:
+            taken = 0
             for name in sorted(os.listdir(inbox)):
                 path = os.path.join(inbox, name)
                 try:
@@ -131,6 +132,7 @@ def run_replica_worker(spool_dir: str, scheduler,
                     os.remove(path)
                 except (OSError, ValueError):
                     continue      # torn write: the front-end will rewrite
+                taken += 1
                 try:
                     scheduler.resubmit(snap)
                 except (ValueError, RuntimeError) as e:
@@ -143,6 +145,10 @@ def run_replica_worker(spool_dir: str, scheduler,
                     ev.write(json.dumps({"uid": snap.uid,
                                          "done": "rejected",
                                          "state": "failed", "n": 0}) + "\n")
+            if taken:
+                # a request that kills its host in the first tick it is
+                # packed into still leaves its submit instant behind
+                recorder.flush()
             if os.path.exists(stop_path):
                 scheduler.shutdown(drain_deadline_s)
                 flush_finished()

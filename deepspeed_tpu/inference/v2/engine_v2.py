@@ -465,8 +465,13 @@ class InferenceEngineV2:
         ``tokens`` is each sequence's next input token: a host list of ints
         OR a ``jax.Array`` of shape ``[len(uids)]`` (int32) — typically
         the greedy tokens the previous call returned, which never leave
-        the device.  Every ``uids[i]`` must be live with no pending prompt
-        tokens (run :meth:`put` first).
+        the device.  The caller keeps that array: the serving scheduler
+        holds the ``next_tokens`` of the step in flight, feeds it to the
+        next step over the same rows before it has fetched it, and reports
+        the values through :meth:`record_device_tokens` once it has, which
+        is what the prefix cache registers decoded blocks from.  Every
+        ``uids[i]`` must be live with no pending prompt tokens (run
+        :meth:`put` first).
 
         Returns logits ``[max_seqs, vocab]`` as a device array WITHOUT
         host synchronisation; rows ``>= len(uids)`` are padding.  With
@@ -504,10 +509,13 @@ class InferenceEngineV2:
         host_toks = (None if isinstance(tokens, jax.Array)
                      else [int(t) for t in tokens])
         for i, seq in enumerate(seqs):
+            seq.seen_tokens += 1
             if host_toks is not None:
                 sm.record_fed_tokens(seq, host_toks[i:i + 1])
-            seq.seen_tokens += 1
-            sm.register_prefix(seq)
+                sm.register_prefix(seq)
+            # else the values are still on the device: the caller reports
+            # them (record_device_tokens), or registration stops at the
+            # next full block, whose tokens the host then lacks
         # device positions advanced in lockstep with seen_tokens
         self._dev_decode_state = {
             "tables": state["tables"], "pos": new_pos,
@@ -515,6 +523,20 @@ class InferenceEngineV2:
         if greedy:
             return logits, nxt
         return logits
+
+    def record_device_tokens(self, uids: Sequence[int],
+                             tokens: Sequence[int]) -> None:
+        """The values of the tokens the last :meth:`decode_step` fed
+        ``uids`` as a device array, now that the host has fetched them: the
+        sequences' token records catch up with ``seen_tokens`` and their
+        full blocks enter the prefix cache, as after a step fed from the
+        host.  A uid flushed in between is skipped."""
+        sm = self.state_manager
+        for uid, tok in zip(uids, tokens):
+            seq = sm.get_sequence(uid)
+            if seq is not None:
+                sm.record_fed_tokens(seq, (tok,))
+                sm.register_prefix(seq)
 
     def _prepare_decode(self, uids):
         """The host's share of a decode step before its dispatch: one KV

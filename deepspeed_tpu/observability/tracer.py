@@ -44,7 +44,13 @@ span                     site                        parent    attrs (counters)
 ``pack``                 ``_step_traced``            tick      —
 ``prefill``              ``_step_traced``: ``put``   tick      —
 ``sample``               after ``put``               tick      —
-``decode``               ``_fast_decode_tick``       tick      —
+``decode``               ``_fast_decode_tick``: a    tick      closing: ``steps``
+                         pure-decode tick's work:              (1) and ``ahead``
+                         the dispatches it makes,              (1 when the step
+                         the fetch of the step it              whose tokens the
+                         returns, the advance                  tick returns was
+                                                               dispatched during
+                                                               the tick before)
 ``verify``               ``_speculative_decode_``    tick      —
                          ``tick``
 ``engine/build_batch``   ``_run_one_batch``: the     prefill   ``tokens`` fed of
@@ -54,10 +60,23 @@ span                     site                        parent    attrs (counters)
 ``engine/fetch_logits``  ``device_get(logits)``      prefill   —
 ``engine/decode_prep``   ``decode_step``: KV slots,  decode    ``seqs``: live
                          table upload, token array             rows of the step
-``engine/decode_step``   the step's dispatch         decode    —
+``engine/decode_step``   the step's dispatch: of     decode    —
+                         the step the tick returns
+                         unless that is in flight,
+                         and of the step after it
+                         when the next tick decodes
+                         the same rows (so 0, 1 or
+                         2 a tick, one a tick over
+                         a run of decode ticks)
 ``engine/verify_step``   the step's dispatch         verify    —
 ``fetch``                ``scheduler._fetch``: the   decode /  —
-                         blocking ``device_get``     verify
+                         blocking ``device_get`` of  verify
+                         the step the tick returns:
+                         when that step is ahead,
+                         the wait for a program
+                         dispatched a tick earlier,
+                         what the host's own work
+                         since did not cover
 ``advance``              ``_advance_emitted``, the   decode /  —
                          verify acceptance loop      verify /
                                                      sample

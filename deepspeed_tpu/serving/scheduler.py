@@ -24,13 +24,21 @@ unpreempted continuation.
 
 Everything here is host-side python; device work is the engine's single
 jitted ragged step — the same split the reference keeps.
+
+A greedy pure-decode tick does not wait for its own tokens before it hands
+the device the next step: while the host can tell that the next tick will
+decode exactly the same rows, it dispatches that step on the device-resident
+tokens of this one, then fetches this one's (:class:`_DecodeStep`,
+``_fast_decode_tick``).  The device runs one program behind the other while
+the host advances, packs and prepares.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import itertools
 import time
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -70,6 +78,20 @@ class TickDeadlineError(RuntimeError):
         self.uids = list(uids)
         self.elapsed_s = elapsed_s
         self.deadline_s = deadline_s
+
+
+@dataclasses.dataclass
+class _DecodeStep:
+    """A greedy ``decode_step`` whose tokens are still on the device: the
+    scheduler's one piece of state about a program in flight."""
+
+    #: the requests it was packed from, in row order
+    packed: List[Request]
+    #: ``int32[max_seqs]`` on the device, row ``i`` = ``packed[i]``'s token
+    nxt: Any
+    #: 1 when it was dispatched during the tick before the one that returns
+    #: its tokens
+    ahead: int
 
 
 class ContinuousBatchScheduler:
@@ -165,6 +187,18 @@ class ContinuousBatchScheduler:
         #: measures)
         self.fast_decode = fast_decode and hasattr(engine, "decode_step")
         self.fast_ticks = 0
+        #: the decode step dispatched ahead of the tick that will return
+        #: its tokens (None: the host and the device are level).  While it
+        #: is set the engine's positions are one ahead of ``Request.fed``
+        #: for its rows, and every path that frees or moves a sequence
+        #: outside that tick calls ``_settle`` first.
+        self._inflight: Optional[_DecodeStep] = None
+        #: tokens a ``_settle`` outside the owning tick's decode phase
+        #: handed out; the next ``step`` returns them before its own
+        self._early: List[Tuple[Request, int]] = []
+        #: how long the last tick took, for "is a deadline due before the
+        #: next one"
+        self._tick_s = 0.0
         sm_cfg = engine.config.state_manager
         self.token_budget = sm_cfg.max_ragged_batch_size
         #: the configured budget, for set_token_budget(None) to restore
@@ -220,6 +254,7 @@ class ContinuousBatchScheduler:
     def set_speculative_enabled(self, enabled: bool) -> None:
         """Disable/re-enable speculative decoding at runtime.  A no-op
         on schedulers built without a speculative config."""
+        self._settle()      # the verify path packs its own decode ticks
         self._speculative_enabled = bool(enabled)
 
     def set_spec_k_cap(self, cap: Optional[int]) -> None:
@@ -406,7 +441,9 @@ class ContinuousBatchScheduler:
     # ------------------------------------------------------------------ #
     @property
     def num_pending(self) -> int:
-        """Requests not yet in a terminal state."""
+        """Requests not yet in a terminal state.  Non-zero while a decode
+        step is in flight: its rows are running requests (one whose rows
+        have all stopped is discarded when the last of them stops)."""
         return len(self._queued) + len(self._running) + len(self._preempted)
 
     @staticmethod
@@ -436,7 +473,10 @@ class ContinuousBatchScheduler:
     def running_decode_uids(self) -> List[int]:
         """Running requests whose prefill completed (state DECODE) — the
         disaggregated fleet migrates exactly these off a prefill replica,
-        KV in hand, the tick they finish prefilling."""
+        KV in hand, the tick they finish prefilling.  Settles a decode
+        step in flight first: its token may end a request, and the caller
+        extracts every uid listed here."""
+        self._settle()
         return [r.uid for r in self._running.values()
                 if r.state is RequestState.DECODE]
 
@@ -451,8 +491,11 @@ class ContinuousBatchScheduler:
         with open_span(self.tracer, "tick", trace_id=self.sched_trace_id,
                        tid=self.trace_tid,
                        attrs={"tick": self._tick}) as tick_h:
-            return self._step_traced(
+            emitted = self._step_traced(
                 tick_h if type(tick_h) is SpanHandle else None)
+        if self._early:
+            emitted, self._early = self._early + emitted, []
+        return emitted
 
     def _step_traced(self, tick_h) -> List[Tuple[Request, int]]:
         self._expire_deadlines()
@@ -462,8 +505,16 @@ class ContinuousBatchScheduler:
         packed: List[Request] = []
 
         with open_span(self.tracer, "pack"):
-            self._pack_decodes(uids, chunks, packed)
-            self._pack_prefills(uids, chunks, packed)
+            if self._inflight is None:
+                self._pack_decodes(uids, chunks, packed)
+                self._pack_prefills(uids, chunks, packed)
+            else:
+                # this tick returns the tokens of the step in flight: its
+                # rows were packed a tick ago, and whatever arrived since
+                # joins in the next tick
+                packed = [r for r in self._inflight.packed
+                          if r.finish_reason is None]
+                uids = [r.uid for r in packed]
 
         if not uids:
             self._handle_stall()
@@ -500,9 +551,7 @@ class ContinuousBatchScheduler:
                 if emitted is None:
                     if self._spec_active is not None:
                         self.spec_stats.fallback_ticks += 1
-                    with open_span(self.tracer, "decode"):
-                        emitted = self._fast_decode_tick(uids, chunks,
-                                                         packed)
+                    emitted = self._fast_decode_tick(uids, chunks, packed)
             else:
                 with open_span(self.tracer, "prefill"):
                     logits = self.engine.put(uids, chunks, sync=True)
@@ -518,8 +567,8 @@ class ContinuousBatchScheduler:
             # speculative tick can emit several per request)
             self.metrics.record_decode_tick(len(emitted), len(packed),
                                             time.monotonic() - t0)
+        self._tick_s = elapsed = time.monotonic() - t0
         if self.tick_deadline_s is not None:
-            elapsed = time.monotonic() - t0
             if elapsed > self.tick_deadline_s:
                 self.tick_deadline_trips += 1
                 self._tick += 1
@@ -536,24 +585,129 @@ class ContinuousBatchScheduler:
         the device-resident block tables.  All-greedy batches fetch only
         the argmax'd token vector (a few bytes/request); any stochastic
         request still needs its logits row on the host for the
-        (seed, uid, position)-keyed sampler."""
-        tokens = [c[0] for c in chunks]
-        n = len(uids)
+        (seed, uid, position)-keyed sampler.
+
+        The greedy tick keeps its tokens on the device until it has given
+        the device its next program: the step whose tokens this tick
+        returns is the one in flight (dispatched during the tick before)
+        or is dispatched now; then, if the next tick will decode the same
+        rows (``_same_rows_next_tick``), the step after it is dispatched
+        on this step's device-resident ``nxt``; only then are this step's
+        tokens fetched and handed out (``_consume``).  The ``decode`` span
+        closes with ``steps`` (1) and ``ahead`` (was the returned step
+        dispatched a tick ago)."""
         self.fast_ticks += 1
-        if all(r.sampling.greedy for r in packed):
-            _, nxt = self.engine.decode_step(uids, tokens, greedy=True)
-            toks = [int(t) for t in self._fetch(nxt)[:n]]
-            for req in packed:
-                req.fed += 1
-            return self._advance_emitted(packed, toks)
-        logits = self.engine.decode_step(uids, tokens)
-        rows = np.asarray(self._fetch(logits), np.float32)[:n]
-        for req in packed:
+        with open_span(self.tracer, "decode") as span:
+            if not all(r.sampling.greedy for r in packed):
+                logits = self.engine.decode_step(uids, [c[0] for c in chunks])
+                rows = np.asarray(self._fetch(logits),
+                                  np.float32)[:len(uids)]
+                for req in packed:
+                    req.fed += 1
+                tokens_out = sample_batch(
+                    rows, [r.sampling for r in packed],
+                    [len(r.generated) for r in packed],
+                    [r.uid for r in packed])
+                emitted = self._advance_emitted(packed, tokens_out.tolist())
+                ahead = 0
+            else:
+                step, self._inflight = self._inflight, None
+                try:
+                    if step is None:
+                        step = self._dispatch_decode(
+                            uids, packed, [c[0] for c in chunks], ahead=0)
+                    if self._same_rows_next_tick(step, uids, packed):
+                        self._inflight = self._dispatch_decode(
+                            uids, packed, step.nxt, ahead=1)
+                except Exception:
+                    self._abandon(packed)
+                    raise
+                emitted = self._consume(step)
+                ahead = step.ahead
+            if type(span) is SpanHandle:
+                span.attrs = {"ahead": ahead, "steps": 1}
+        return emitted
+
+    def _dispatch_decode(self, uids, packed, tokens,
+                         ahead: int) -> _DecodeStep:
+        """One greedy ``decode_step`` over ``packed``'s rows, fed
+        ``tokens`` (host ints, or the ``nxt`` of the step before it)."""
+        _, nxt = self.engine.decode_step(uids, tokens, greedy=True)
+        return _DecodeStep(packed, nxt, ahead)
+
+    def _same_rows_next_tick(self, step: _DecodeStep, uids, packed) -> bool:
+        """Can the host tell, before ``step``'s tokens arrive, that the
+        next tick will be a greedy pure-decode tick over exactly its rows
+        in the same order?  Read from the host's own state: no speculation
+        (its verify pass packs the tick), nothing waiting to join (queue,
+        preempted, a running request mid-prefill), no row that stopped
+        while ``step`` was in flight, none that reaches ``max_new_tokens``
+        or ``max_context`` with ``step``'s token or whose deadline falls
+        due within a tick, and KV room for every row's next position
+        without a preemption.  A stop token cannot be told in advance: its
+        row of the step dispatched ahead is dropped (``_consume``)."""
+        if (self._spec_active is not None or self._queued
+                or self._preempted or len(packed) != len(step.packed)
+                or len(self._running) != len(packed)):
+            return False
+        due = time.monotonic() + self._tick_s
+        for r in packed:
+            n = len(r.generated) + 1
+            if n >= r.sampling.max_new_tokens \
+                    or len(r.prompt) + n >= self.max_context:
+                return False
+            if r.deadline_s is not None \
+                    and due - r.arrival_time > r.deadline_s:
+                return False
+        return self.engine.can_schedule(uids, [1] * len(uids))
+
+    def _consume(self, step: _DecodeStep) -> List[Tuple[Request, int]]:
+        """Fetch ``step``'s tokens and hand each to its request, as the
+        tick that owns it does.  A row whose request ended while the step
+        was in flight (a stop token a tick ago, a failure) is dropped:
+        nothing past the end is emitted or recorded.  The rows that go on
+        tell the engine the values the step dispatched behind this one was
+        fed from the device, for the prefix cache."""
+        try:
+            toks = self._fetch(step.nxt)
+        except Exception:
+            self._abandon(step.packed)
+            raise
+        rows = [(r, int(t)) for r, t in zip(step.packed, toks)
+                if r.finish_reason is None]
+        for req, _ in rows:
             req.fed += 1
-        tokens_out = sample_batch(rows, [r.sampling for r in packed],
-                                  [len(r.generated) for r in packed],
-                                  [r.uid for r in packed])
-        return self._advance_emitted(packed, tokens_out.tolist())
+        emitted = self._advance_emitted([r for r, _ in rows],
+                                        [t for _, t in rows])
+        if self._inflight is not None:
+            fed = [(r.uid, t) for r, t in rows if r.finish_reason is None]
+            if fed:
+                self.engine.record_device_tokens(*zip(*fed))
+            else:
+                self._inflight = None   # every row stopped: none is wanted
+        return emitted
+
+    def _settle(self) -> None:
+        """Bring the host level with the device: fetch and hand out the
+        tokens of the decode step in flight, if there is one.  Every path
+        that frees or moves a sequence outside the decode tick calls this
+        first, so it never meets an engine position the requests have not
+        reached; the next ``step`` returns the tokens."""
+        step, self._inflight = self._inflight, None
+        if step is not None:
+            self._early.extend(self._consume(step))
+
+    def _abandon(self, packed) -> None:
+        """A decode program failed at its dispatch or at its fetch: what it
+        and the step dispatched behind it left in the pool and in the
+        engine's positions is not what the requests were handed.  Drop the
+        step in flight, let the engine recover its donated cache, and send
+        the rows back through recompute."""
+        self._inflight = None
+        self.engine._recover_donated_cache()
+        for req in packed:
+            if self._running.get(req.uid) is req:
+                self._preempt(req)
 
     def _fetch(self, device_array) -> np.ndarray:
         """The tick's one blocking transfer: the host waits here for the
@@ -805,7 +959,9 @@ class ContinuousBatchScheduler:
                    key=lambda r: (r.priority, -r.admitted_at))
 
     def _preempt(self, req: Request) -> None:
-        self.engine.flush_to_host([req.uid])
+        self._settle()
+        if self.engine.state_manager.get_sequence(req.uid) is not None:
+            self.engine.flush_to_host([req.uid])
         del self._running[req.uid]
         req.fed = 0
         req.preemptions += 1
@@ -818,6 +974,9 @@ class ContinuousBatchScheduler:
                      f"({len(req.generated)} tokens generated)")
 
     def _fail(self, req: Request, reason: str) -> None:
+        # its row of a step in flight is dropped, not handed out
+        req.finish_reason = reason
+        self._settle()
         # a QUEUED request can hold engine state too: resubmit() with a KV
         # payload injects the sequence before admission packs it
         if self.engine.state_manager.get_sequence(req.uid) is not None:
@@ -830,7 +989,6 @@ class ContinuousBatchScheduler:
         if req in self._preempted:
             self._preempted.remove(req)
             self._parked_backlog -= self._work(req)
-        req.finish_reason = reason
         self._close_req_span(req.uid, outcome="failed", reason=reason)
         req.transition(RequestState.FAILED)
         self._drop_request_state(req.uid)
@@ -843,10 +1001,12 @@ class ContinuousBatchScheduler:
         "deadline") — queued, running, or preempted alike.  Tokens already
         generated stay on the request, but a blown SLO is a failure: the
         client stopped waiting, so finishing the work is wasted compute."""
-        for req in [*self._queued, *self._running.values(),
-                    *self._preempted]:
-            if req.past_deadline:
-                self._fail(req, "deadline")
+        late = [req for req in [*self._queued, *self._running.values(),
+                                *self._preempted] if req.past_deadline]
+        for req in late:        # before the first _fail settles
+            req.finish_reason = "deadline"
+        for req in late:
+            self._fail(req, "deadline")
 
     def _reap_unservable(self) -> None:
         """Terminate requests whose token history has outgrown the ENTIRE
@@ -860,6 +1020,7 @@ class ContinuousBatchScheduler:
         for req in [*self._running.values(), *self._preempted]:
             if -(-len(req.history) // sm.block_size) <= usable:
                 continue
+            self._settle()
             if req.uid in self._running:
                 self.engine.flush([req.uid])
                 del self._running[req.uid]
@@ -945,6 +1106,11 @@ class ContinuousBatchScheduler:
         self._spec_k.pop(uid, None)
 
     def _finish(self, req: Request, reason: str) -> None:
+        """Only ever called while tokens are handed out.  A request that
+        stops on a stop token may hold a row of the step dispatched ahead:
+        that row is dropped when the step is consumed, and its write into
+        the blocks freed here lands before any later program's (each
+        depends on the donated cache)."""
         self.engine.flush([req.uid])
         del self._running[req.uid]
         req.finish_reason = reason
@@ -996,6 +1162,7 @@ class ContinuousBatchScheduler:
                 break
             self.step()
             ticks += 1
+        self._settle()      # max_ticks can stop the loop a step ahead
         self._export_metrics()
         return self.finished_requests
 
@@ -1092,7 +1259,9 @@ class ContinuousBatchScheduler:
         NEW object; this one transitions to the terminal ``HANDED_OFF``
         (so tenant-quota views prune it, and a holder sees it is gone).
         ``include_kv=True`` (running requests only) carries the device KV
-        along so the target replica skips the recompute re-prefill."""
+        along so the target replica skips the recompute re-prefill.  Both
+        callers have settled the step in flight (``drain``'s exit,
+        ``extract_for_handoff``)."""
         kv_state = None
         fed = 0
         if req.uid in self._running:
@@ -1134,6 +1303,7 @@ class ContinuousBatchScheduler:
         ``flush_to_host(include_kv=True)`` payload when requested and the
         request was running (None otherwise).  The disaggregated
         prefill→decode pump calls this the tick a prefill completes."""
+        self._settle()      # before the lookup: its token may end ``uid``
         for req in [*self._running.values(), *self._queued,
                     *self._preempted]:
             if req.uid == uid:
@@ -1180,6 +1350,7 @@ class ContinuousBatchScheduler:
         end = time.monotonic() + deadline
         while self.num_pending and time.monotonic() < end:
             self.step()
+        self._settle()      # the deadline can stop the loop a step ahead
         if not self.num_pending:
             self._export_metrics()
         return self.num_pending == 0

@@ -125,7 +125,7 @@ def test_drain_handoff_roundtrip_parity(params, gold):
     a, b = _sched(params), _sched(params)
     samp = SamplingParams(greedy=True, max_new_tokens=GEN)
     ra = [a.submit(p, sampling=samp) for p in _prompts()]
-    for _ in range(4):
+    for _ in range(3):      # mid-stream once the step in flight settles
         a.step()
     drained, snaps = a.shutdown(0.0, handoff=True)
     assert not drained and len(snaps) == 3

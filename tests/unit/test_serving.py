@@ -811,6 +811,315 @@ def test_fast_decode_survives_preemption_and_mixed_ticks(params):
 
 
 # --------------------------------------------------------------------- #
+# Dispatch-ahead on greedy pure-decode ticks: the next step goes to the
+# device before this one's tokens are fetched.  The bar: the streams of a
+# scheduler that runs ahead equal, token for token and finish reason for
+# finish reason, those of one that feeds every decode through ``put``.
+# --------------------------------------------------------------------- #
+def _ahead_prompts(n, seed=30, lo=5, hi=12):
+    rng = np.random.default_rng(seed)
+    return [rng.integers(0, CFG.vocab_size, size=(int(k),)).tolist()
+            for k in rng.integers(lo, hi, size=n)]
+
+
+def _greedy(n, **kw):
+    return SamplingParams(greedy=True, max_new_tokens=n, **kw)
+
+
+def _steps(sched, n, in_flight=True):
+    """``n`` ticks; then, on a scheduler that runs ahead, a decode step is
+    in flight (or the scenario is not testing what it says it is)."""
+    for _ in range(n):
+        sched.step()
+    if sched.fast_decode and in_flight:
+        assert sched._inflight is not None
+
+
+def _sc_length_staggered(sched, mk):
+    reqs = [sched.submit(p, _greedy(n))
+            for p, n in zip(_ahead_prompts(3), (3, 6, 9))]
+    sched.run_until_idle()
+    return reqs
+
+
+def _sc_stop_token_in_flight(sched, mk):
+    """A stop token that arrives while the row's next step is in flight:
+    nothing is emitted past it, the sequence and its blocks go the tick it
+    arrives, and the other row decodes on."""
+    a, b = _ahead_prompts(2, seed=31)
+    ref = _greedy_reference(sched.engine.params, [a], n_new=8)[0]
+    stop = ref[4] if ref.index(ref[4]) >= 2 else ref[2]
+    ra = sched.submit(a, _greedy(12, stop_token_ids=(stop,)))
+    rb = sched.submit(b, _greedy(10))
+    sm = sched.engine.state_manager
+    while ra.finish_reason is None:
+        sched.step()
+    assert ra.generated[-1] == stop and ra.generated.count(stop) == 1
+    assert sm.get_sequence(ra.uid) is None
+    if sched.fast_decode:       # the step ahead was fed the stop token
+        assert ra in sched._inflight.packed
+    assert sched.running_decode_uids == [rb.uid]    # settles that step
+    seq = sm.get_sequence(rb.uid)
+    assert seq.seen_tokens == rb.fed == len(rb.history) - 1
+    assert len(seq.blocks) == -(-seq.seen_tokens // 8) == \
+        sm.allocator.num_blocks - 1 - sm.free_blocks
+    sched.run_until_idle()
+    return [ra, rb]
+
+
+def _sc_max_context(sched, mk):
+    # max_context 32: a 20-token prompt ends by context, not by budget
+    p = _ahead_prompts(1, seed=32, lo=20, hi=21)[0]
+    reqs = [sched.submit(p, _greedy(64)),
+            sched.submit(_ahead_prompts(1, seed=33)[0], _greedy(20))]
+    sched.run_until_idle()
+    assert reqs[0].finish_reason == "length"
+    assert len(reqs[0].history) == 32
+    return reqs
+
+
+def _sc_arrival_mid_run(sched, mk):
+    a, b = _ahead_prompts(2, seed=34)
+    reqs = [sched.submit(a, _greedy(10))]
+    _steps(sched, 3)
+    reqs.append(sched.submit(b, _greedy(6)))
+    sched.run_until_idle()
+    return reqs
+
+
+def _sc_stochastic_joins(sched, mk):
+    a, b = _ahead_prompts(2, seed=35)
+    reqs = [sched.submit(a, _greedy(10), uid=71)]
+    _steps(sched, 3)
+    reqs.append(sched.submit(b, SamplingParams(
+        greedy=False, temperature=0.8, top_k=8, seed=3, max_new_tokens=6),
+        uid=72))
+    sched.run_until_idle()
+    return reqs
+
+
+def _sc_preemption_under_kv_pressure(sched, mk):
+    prompts = _ahead_prompts(6, seed=19, lo=6, hi=16)
+    reqs, tick = [], 0
+    while len(reqs) < len(prompts) or sched.num_pending:
+        if len(reqs) < len(prompts) and tick % 2 == 0:
+            reqs.append(sched.submit(prompts[len(reqs)], _greedy(8)))
+        sched.step()
+        tick += 1
+        assert tick < 2000
+    assert sched.metrics.preemptions >= 1
+    return reqs
+
+
+def _sc_handoff_with_kv(sched, mk, include_kv=True):
+    a, b = _ahead_prompts(2, seed=36)
+    ra, rb = sched.submit(a, _greedy(12)), sched.submit(b, _greedy(9))
+    _steps(sched, 4)
+    snap, kv = sched.extract_for_handoff(ra.uid, include_kv=include_kv)
+    assert sched._inflight is None and (kv is not None) == include_kv
+    # the engine is where the requests are: nothing of a step in flight
+    sm = sched.engine.state_manager
+    assert sm.get_sequence(rb.uid).seen_tokens == rb.fed == \
+        len(rb.history) - 1
+    if kv is not None:
+        assert kv["seen_tokens"] == snap.fed_tokens == \
+            len(snap.prompt) + len(snap.generated) - 1
+    ra2 = sched.resubmit(snap, kv_state=kv)
+    sched.run_until_idle()
+    assert ra.finish_reason == "handoff"
+    return [ra2, rb]
+
+
+def _sc_flush_to_host(sched, mk):
+    return _sc_handoff_with_kv(sched, mk, include_kv=False)
+
+
+def _sc_shutdown_drains(sched, mk):
+    reqs = [sched.submit(p, _greedy(9)) for p in _ahead_prompts(2, seed=37)]
+    _steps(sched, 4)
+    assert sched.shutdown(30.0) is True
+    assert sched.num_pending == 0 and sched._inflight is None
+    sm = sched.engine.state_manager
+    assert sm.n_tracked_sequences == 0
+    return reqs
+
+
+def _sc_shutdown_hands_off(sched, mk):
+    reqs = [sched.submit(p, _greedy(9)) for p in _ahead_prompts(2, seed=38)]
+    _steps(sched, 4)
+    drained, snaps = sched.shutdown(0.0, handoff=True)
+    assert not drained and len(snaps) == 2 and sched._inflight is None
+    assert sched.engine.state_manager.n_tracked_sequences == 0
+    other = mk()
+    out = [other.resubmit(s) for s in snaps]
+    other.run_until_idle()
+    assert [r.uid for r in out] == [r.uid for r in reqs]
+    return out
+
+
+def _sc_deadline_expires(sched, mk):
+    """The deadline falls due with the row's next step in flight: that
+    row of it is dropped, so the request keeps the tokens it had."""
+    a, b = _ahead_prompts(2, seed=39)
+    ra = sched.submit(a, _greedy(12), deadline_s=500.0)
+    rb = sched.submit(b, _greedy(9))
+    _steps(sched, 4)
+    ra.arrival_time -= 1000.0
+    sched.step()
+    assert ra.finish_reason == "deadline" and \
+        ra.state is RequestState.FAILED
+    assert sched.engine.state_manager.get_sequence(ra.uid) is None
+    sched.run_until_idle()
+    return [ra, rb]
+
+
+_AHEAD_SCENARIOS = [
+    _sc_length_staggered, _sc_stop_token_in_flight, _sc_max_context,
+    _sc_arrival_mid_run, _sc_stochastic_joins,
+    _sc_preemption_under_kv_pressure, _sc_handoff_with_kv,
+    _sc_flush_to_host, _sc_shutdown_drains, _sc_shutdown_hands_off,
+    _sc_deadline_expires]
+
+
+@pytest.mark.parametrize("scenario", _AHEAD_SCENARIOS,
+                         ids=[f.__name__[4:] for f in _AHEAD_SCENARIOS])
+def test_running_ahead_matches_sequential_decode(params, scenario):
+    tight = scenario is _sc_preemption_under_kv_pressure
+
+    def run(fast):
+        def mk():
+            return ContinuousBatchScheduler(_engine(
+                params, max_context=48 if tight else 32,
+                num_blocks=7 if tight else None), fast_decode=fast)
+
+        sched = mk()
+        ahead = _spy_device_steps(sched.engine)
+        reqs = scenario(sched, mk)
+        sm = sched.engine.state_manager
+        assert sched.num_pending == 0 and sched._inflight is None
+        assert sm.n_tracked_sequences == 0
+        assert sm.free_blocks == sm.allocator.num_blocks - 1
+        return [(r.uid, r.generated, r.finish_reason, r.state)
+                for r in reqs], len(ahead)
+
+    got, n_ahead = run(True)
+    want, none = run(False)
+    assert got == want
+    assert n_ahead >= 1 and none == 0     # it did run ahead
+
+
+def _spy_device_steps(engine):
+    """The ``decode_step`` calls fed a device array: the steps dispatched
+    ahead."""
+    calls, orig = [], engine.decode_step
+
+    def ds(uids, tokens, greedy=False):
+        if isinstance(tokens, jax.Array):
+            calls.append(list(uids))
+        return orig(uids, tokens, greedy=greedy)
+
+    engine.decode_step = ds
+    return calls
+
+
+def test_failed_fetch_of_a_step_ahead_recovers(params, monkeypatch):
+    """The fetch of a step that was dispatched ahead fails: the error
+    comes out of the tick that owns the step, nothing of it or of the step
+    behind it stays in the engine, and the rows recompute to the streams
+    they would have had."""
+    prompts = _ahead_prompts(2, seed=40)
+    want = _greedy_reference(params, prompts, n_new=9)
+    eng = _engine(params)
+    sched = ContinuousBatchScheduler(eng)
+    reqs = [sched.submit(p, _greedy(9)) for p in prompts]
+    _steps(sched, 3)
+    before = [list(r.generated) for r in reqs]
+    real_fetch, recovered = sched._fetch, []
+    real_recover = eng._recover_donated_cache
+
+    def failing(arr):
+        monkeypatch.setattr(sched, "_fetch", real_fetch)
+        raise RuntimeError("device lost")
+
+    monkeypatch.setattr(sched, "_fetch", failing)
+    monkeypatch.setattr(eng, "_recover_donated_cache",
+                        lambda: (recovered.append(1), real_recover()))
+    assert sched._inflight.ahead == 1
+    with pytest.raises(RuntimeError, match="device lost"):
+        sched.step()
+    assert recovered == [1] and sched._inflight is None
+    sm = eng.state_manager
+    assert sm.n_tracked_sequences == 0 and eng._dev_decode_state is None
+    assert sm.free_blocks == sm.allocator.num_blocks - 1
+    assert [r.generated for r in reqs] == before      # nothing handed out
+    assert all(r.state is RequestState.PREEMPTED for r in reqs)
+    sched.run_until_idle()
+    assert [r.generated for r in reqs] == want
+    assert sm.n_tracked_sequences == 0
+
+
+def test_prefix_cache_registers_the_same_blocks_running_ahead(params):
+    """A sequence decoded ahead is fed device tokens whose values reach
+    the host a tick later: its decoded blocks enter the radix tree all
+    the same, and a stop token's row registers nothing past the stop."""
+    prompt = _ahead_prompts(1, seed=41, lo=9, hi=10)[0]
+    ref = _greedy_reference(params, [prompt], n_new=30)[0]
+    # stop where the fed history ends one short of a full block: a row
+    # decoded one step too far would fill it
+    cut = next(i for i in range(14, 30)
+               if (len(prompt) + i) % 8 == 7 and ref.index(ref[i]) == i)
+
+    def run(fast):
+        cfg = RaggedInferenceEngineConfig.from_dict({
+            "state_manager": {"max_ragged_batch_size": 32,
+                              "max_ragged_sequence_count": 4,
+                              "max_context": 64},
+            "kv_cache": {"block_size": 8, "enable_prefix_cache": True}})
+        eng = InferenceEngineV2(RaggedLlama(CFG, 8), params, cfg)
+        sched = ContinuousBatchScheduler(eng, fast_decode=fast)
+        r = sched.submit(prompt, _greedy(30, stop_token_ids=(ref[cut],)))
+        stopped = []
+        while sched.num_pending:
+            sched.step()
+            seq = eng.state_manager.get_sequence(r.uid)
+            if seq is not None:
+                stopped.append(seq.register_stopped)
+        assert r.finish_reason == "stop" and r.generated == ref[:cut + 1]
+        pc = eng.state_manager.prefix_cache
+        return (pc.cached_blocks, pc.match_len(r.history), any(stopped),
+                sorted(pc.node_tokens(n) for n in pc._iter_nodes()
+                       if n.block is not None))
+
+    ahead, seq = run(True), run(False)
+    assert ahead == seq
+    blocks, matched, stopped, _ = ahead
+    fed = len(prompt) + cut             # the stop token itself is never fed
+    assert not stopped and blocks == fed // 8 and matched == 8 * blocks
+
+
+def test_running_ahead_builds_no_new_program(params):
+    """Arrivals, finishes, a preemption: the programs the engine built are
+    the ones it built before any step ran ahead — one ``decode_step``, the
+    ragged buckets — and the decode program's text does not depend on who
+    fed it."""
+    def run(fast):
+        eng = _engine(params, token_budget=32, max_context=48, num_blocks=7)
+        sched = ContinuousBatchScheduler(eng, fast_decode=fast)
+        _sc_preemption_under_kv_pressure(sched, None)
+        return eng
+
+    ahead, seq = run(True), run(False)
+    assert set(ahead.step_keys) == set(seq.step_keys) | {("decode_step",)}
+    assert set(ahead.step_keys) <= {("decode_step",), (16, None), (32, None)}
+    fed_by_host = _engine(params, token_budget=32, max_context=48,
+                          num_blocks=7)
+    fed_by_host.put([1], [[3, 4, 5]])
+    fed_by_host.decode_step([1], [6], greedy=True)
+    assert ahead.lower_step(("decode_step",)).as_text() == \
+        fed_by_host.lower_step(("decode_step",)).as_text()
+
+
+# --------------------------------------------------------------------- #
 # The tier-1 smoke (tools/serving_smoke.py)
 # --------------------------------------------------------------------- #
 def _load_smoke():

@@ -126,21 +126,56 @@ def test_pure_decode_tick_tree(traced):
     ticks = _of_kind(tr, "decode")
     assert ticks
     for tick, kids in ticks:
-        assert sorted(kids) == ["advance", "decode", "engine/decode_prep",
-                                "engine/decode_step", "fetch", "pack"]
-        assert all(len(v) == 1 for v in kids.values())
-        # a counter is recorded once, where something reads it: of a
-        # decode tick's inner spans only engine/decode_prep owns one, the
-        # live rows of the step (``gmm_roofline_pct`` of the benchmark
-        # reads it)
+        # every pure-decode tick: one pack, one decode phase, one fetch and
+        # one advance; a dispatch (prep + step) for each program it hands
+        # the device: the step it returns unless that was in flight, and
+        # the step after it when it runs ahead
+        assert {"advance", "decode", "fetch", "pack"} <= set(kids) <= \
+            {"advance", "decode", "engine/decode_prep", "engine/decode_step",
+             "fetch", "pack"}
+        assert all(len(kids[k]) == 1
+                   for k in ("advance", "decode", "fetch", "pack"))
+        preps = kids.get("engine/decode_prep", [])
+        steps = kids.get("engine/decode_step", [])
+        assert len(preps) == len(steps) <= 2
+        counters = kids["decode"][0]["attrs"]
+        assert set(counters) == {"ahead", "steps"} and counters["steps"] == 1
+        # a step in flight is not dispatched again; one that is not, is
+        assert (len(steps) >= 1) == (counters["ahead"] == 0)
+        # a counter is recorded once, where something reads it: the live
+        # rows of each step on its engine/decode_prep (``gmm_roofline_pct``
+        # of the benchmark reads it), the mechanism's on the decode span
         assert {k for k, v in kids.items() if "attrs" in v[0]} == \
-            {"engine/decode_prep"}
-        seqs = kids["engine/decode_prep"][0]["attrs"]
-        assert set(seqs) == {"seqs"} and 1 <= seqs["seqs"] <= 4
-        order = [kids[k][0] for k in ("engine/decode_prep",
-                                      "engine/decode_step", "fetch",
-                                      "advance")]
+            {"decode"} | ({"engine/decode_prep"} if preps else set())
+        for prep in preps:
+            assert set(prep["attrs"]) == {"seqs"} and \
+                1 <= prep["attrs"]["seqs"] <= 4
+        order = [r for pair in zip(preps, steps) for r in pair] + \
+            [kids["fetch"][0], kids["advance"][0]]
         assert all(a["t1_ns"] <= b["t0_ns"] for a, b in zip(order, order[1:]))
+        by_id = {r["span_id"]: r for r in tr.records()}
+        assert all(by_id[r["parent"]]["name"] == "decode" for r in order)
+
+
+def test_decode_span_counts_the_steps_ahead(traced):
+    """``_drive``: r1 decodes alone (a step, and the next dispatched ahead),
+    r2 arrives while that one is in flight (the tick returns it and
+    dispatches nothing: r2 joins a tick later than it would have), the
+    mixed tick, two rows decode until r2's third token (ahead of its last
+    token: the row still runs; not past it), then r1 alone to its eighth.
+    Every run of decode ticks hands the device one program a tick."""
+    tr, _ = traced
+    kinds = [t["attrs"]["kind"] for t, _ in _ticks(tr)]
+    assert kinds == ["prefill", "decode", "decode", "mixed",
+                     "decode", "decode", "decode", "decode"]
+    ticks = _of_kind(tr, "decode")
+    ahead = [kids["decode"][0]["attrs"]["ahead"] for _, kids in ticks]
+    assert ahead == [0, 1, 0, 1, 0, 1]
+    dispatched = [len(kids.get("engine/decode_step", []))
+                  for _, kids in ticks]
+    assert dispatched == [2, 0, 2, 0, 2, 0]
+    assert sum(dispatched) == len(ticks) == \
+        sum(kids["decode"][0]["attrs"]["steps"] for _, kids in ticks)
 
 
 def test_mixed_tick_tree(traced):
@@ -185,8 +220,10 @@ def test_export_validates(traced):
 def test_tick_closing_counters(traced):
     tr, sched = traced
     ticks = _ticks(tr)
-    assert [t["attrs"]["kind"] for t, _ in ticks[:3]] == \
-        ["prefill", "decode", "mixed"]
+    # the second prompt arrives while the step after tick 1 is in flight:
+    # it joins once that step's tick has returned
+    assert [t["attrs"]["kind"] for t, _ in ticks[:4]] == \
+        ["prefill", "decode", "decode", "mixed"]
     for t, kids in ticks:
         assert set(t["attrs"]) == {"tick", "kind", "emitted"}
         builds = kids.get("engine/build_batch", [])
@@ -199,7 +236,7 @@ def test_tick_closing_counters(traced):
             for b in builds:
                 assert 0 < b["attrs"]["tokens"] <= b["attrs"]["bucket"]
     assert [t["attrs"]["tick"] for t, _ in ticks] == list(range(len(ticks)))
-    assert [t["attrs"]["emitted"] for t, _ in ticks[:3]] == [1, 1, 2]
+    assert [t["attrs"]["emitted"] for t, _ in ticks[:4]] == [1, 1, 1, 2]
     assert ticks[0][1]["engine/build_batch"][0]["attrs"] == \
         {"tokens": 13, "bucket": 16}
     assert sum(t["attrs"]["emitted"] for t, _ in ticks) == 8 + 3
