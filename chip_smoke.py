@@ -17,6 +17,12 @@ seeded random weights:
   against ``gmm_reference``, and a depth-2 ``RaggedMixtral`` engine
   (``put`` then ``decode_step``) on the grouped path against the dense
   all-experts composition.
+* **gdn** (one device) — linear-attention layers with per-sequence state at
+  the published Qwen3-Next-80B-A3B widths, one period deep (3 Gated DeltaNet
+  + 1 gated attention layer, a share of the experts): two requests of
+  different lengths served TOGETHER through the scheduler (the longer one's
+  chunks beside the other's decodes), each one's logits against the plain
+  float32 reference's own forward (``benchmark/reference/qwen3_next.py``).
 * **kernels** — ``tools/kernel_selftest.run_selftest()`` as a gate.
 
 With more than one device visible the same phases run across all of them
@@ -98,6 +104,14 @@ MOE_LOGIT_TOL = 0.015
 # engine on the chip is what this floor guards.
 ROUTER_AGREE_FLOOR = 0.999
 
+# Logits of the one-period Qwen3-Next engine (bf16) against the float32
+# token-by-token reference on the same weights, over the largest reference
+# logit: the benchmark's own limit for a bf16 engine (``LOGIT_TOL`` of
+# ``benchmark/runners/serve_ragged.py``).  A state slot handed to the wrong
+# sequence, a convolution tail dropped at a chunk boundary or a slot reused
+# without its reset moves the logits by their full scale.
+GDN_LOGIT_TOL = 0.03
+
 _ATTENTION_KERNELS = ("_fwd_kernel", "_bwd_dq_kernel", "_bwd_dkv_kernel",
                       "_kernel", "_prefill_kernel", "_decode_kernel",
                       "_verify_kernel")
@@ -131,6 +145,11 @@ class SmokeSizes:
     moe_gmm_rows: Sequence[int] = (256, 4352)
     moe_prompt_len: int = 300
     moe_new_tokens: int = 4
+    # the gdn phase: the published Qwen3-Next keys (depth, experts held and
+    # vocabulary as given), its two prompts and what each generates
+    gdn_hf: Any = None
+    gdn_prompt_lens: Sequence[int] = (1500, 300)
+    gdn_new_tokens: Sequence[int] = (6, 12)
 
 
 def chip_sizes(n_devices: int) -> SmokeSizes:
@@ -151,7 +170,14 @@ def chip_sizes(n_devices: int) -> SmokeSizes:
     from deepspeed_tpu.models.mistral import MistralConfig
     from deepspeed_tpu.models.mixtral import MixtralConfig
 
+    with open(os.path.join(_HERE, "benchmark", "configs",
+                           "qwen3-next-80b-a3b-serve-1chip.json")) as f:
+        # every width as published; one period, 16 of the 512 experts held,
+        # an eighth of the vocabulary slice
+        gdn_hf = dict(json.load(f), num_hidden_layers=4, num_experts=16,
+                      vocab_size=4748)
     return SmokeSizes(
+        gdn_hf=gdn_hf,
         moe_config=MixtralConfig.olmoe_1b_7b(num_hidden_layers=2,
                                              dtype=jnp.bfloat16),
         model_config=MistralConfig(dtype=jnp.bfloat16),
@@ -753,6 +779,67 @@ def moe_phase(sizes: SmokeSizes, devices, require_chip: bool,
 
 
 # --------------------------------------------------------------------- #
+# Phase: linear-attention layers and their state (one device)
+# --------------------------------------------------------------------- #
+def gdn_phase(sizes: SmokeSizes, devices, require_chip: bool,
+              clock: CompileClock) -> Dict[str, Any]:
+    hf = sizes.gdn_hf
+    if hf is None or len(devices) > 1:
+        return {"skipped": "no gdn_hf in these sizes" if hf is None
+                else "one-device phase", **clock.take()}
+    if _HERE not in sys.path:
+        sys.path.insert(0, _HERE)
+    from benchmark.families import qwen3_next as family
+    from benchmark.reference import qwen3_next as reference
+    from benchmark.runners.serve_ragged import make_params
+    from benchmark.tools.interleaved_check import serve_and_compare
+    from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
+                                            RaggedInferenceEngineConfig)
+
+    bs = sizes.block_size
+    max_context = -(-(max(p + g for p, g in zip(
+        sizes.gdn_prompt_lens, sizes.gdn_new_tokens)) + 1) // bs) * bs
+    engine = InferenceEngineV2(
+        family.serve_model(hf, bs), make_params(family, hf, 5),
+        RaggedInferenceEngineConfig.from_dict({
+            "state_manager": {"max_ragged_batch_size": sizes.token_budget,
+                              "max_ragged_sequence_count": sizes.max_seqs,
+                              "max_context": max_context},
+            "kv_cache": {"block_size": bs,
+                         "num_blocks": 3 * (max_context // bs) + 2}}))
+    rng = np.random.default_rng(6)
+    prompts = [rng.integers(0, hf["vocab_size"], size=(n,)).tolist()
+               for n in sizes.gdn_prompt_lens]
+    out = serve_and_compare(engine, reference,
+                            family.reference_params(engine.params), hf,
+                            prompts, sizes.gdn_new_tokens)
+    kernels = {}
+    for key in engine.step_keys:
+        name = "decode_step" if key == ("decode_step",) else f"T{key[0]}"
+        names = mosaic_kernel_names(engine.lower_step(key).as_text())
+        kernels[name] = sorted(set(names))
+        if require_chip and "_gdn_step_kernel" not in names:
+            raise SmokeFailure(f"gdn: {name} has no Mosaic delta-rule "
+                               f"call: {kernels[name]}")
+    if max(out["gaps"]) > GDN_LOGIT_TOL:
+        raise SmokeFailure(
+            f"gdn: logits of interleaved requests differ from their own "
+            f"reference forwards by {out['gaps']} of the largest logit "
+            f"(> {GDN_LOGIT_TOL})")
+    pool = engine.state_manager.state_pool
+    if pool.held:
+        raise SmokeFailure(f"gdn: {pool.held} state slots still held "
+                           f"after every request finished")
+    return {"layers": hf["num_hidden_layers"],
+            "experts_held": hf["num_experts"],
+            "prompt_lens": list(sizes.gdn_prompt_lens),
+            "logit_gaps": [round(g, 5) for g in out["gaps"]],
+            "rows_compared": out["rows"], "ticks": out["ticks"],
+            "kernels": kernels, **clock.take(),
+            "memory": memory_report(devices)}
+
+
+# --------------------------------------------------------------------- #
 # Phase: every other kernel
 # --------------------------------------------------------------------- #
 def kernels_phase(_sizes, _devices, _require_chip,
@@ -772,7 +859,7 @@ def kernels_phase(_sizes, _devices, _require_chip,
 
 
 PHASES = {"train": train_phase, "serve": serve_phase, "moe": moe_phase,
-          "kernels": kernels_phase}
+          "gdn": gdn_phase, "kernels": kernels_phase}
 
 
 def run(sizes: Optional[SmokeSizes] = None, require_chip: bool = True,
@@ -815,7 +902,7 @@ def main() -> int:
         json.dump(summary, f, indent=1)
     print("chip_smoke: compile seconds per phase "
           + json.dumps({p: summary[p]["compile_s"]
-                        for p in ("train", "serve", "moe", "kernels")})
+                        for p in PHASES})
           + f", wall {summary['wall_s']} s", flush=True)
     print(json.dumps({"ok": True, "device": summary["device"]}))
     return 0

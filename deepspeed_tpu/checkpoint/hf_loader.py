@@ -24,6 +24,7 @@ Supported layouts: single ``model.safetensors``, sharded
 
 from __future__ import annotations
 
+import dataclasses
 import json
 import os
 import re
@@ -172,6 +173,74 @@ def _olmoe_rules() -> List[Rule]:
          lambda m: (_moe_path(m, "experts", f"w_{m.group(3)}"),
                     ("stack", int(m.group(2))))),
         (r".*rotary_emb\.inv_freq$", lambda m: (None, None)),  # recomputed
+    ]
+
+
+def _qwen3_next_regroup(widths):
+    """The published Gated DeltaNet projections interleave their outputs
+    PER KEY HEAD (``in_proj_qkvz``: each key head's q, k, then the v and z
+    of the value heads it serves; ``in_proj_ba``: its b, then its a);
+    ``RaggedQwen3Next`` reads ``q | k | v | z`` and ``b | a`` with all
+    heads of one before the next.  ``widths(cfg)`` gives one key head's
+    parts.  HF weight [out, in] -> kernel [in, out], regrouped."""
+    def tf(w, cfg):
+        w = np.asarray(w)
+        hk = int(cfg["linear_num_key_heads"])
+        parts = np.split(w.reshape(hk, -1, w.shape[-1]),
+                         np.cumsum(widths(cfg))[:-1], axis=1)
+        return np.concatenate(
+            [p.reshape(-1, w.shape[-1]) for p in parts], axis=0).T
+    return tf
+
+
+def _qwen3_next_rules() -> List[Rule]:
+    # Qwen3-Next (``model_type: qwen3_next``) -> RaggedQwen3Next's tree
+    def layer(m, *leaf):
+        return (f"layers_{m.group(1)}", *leaf)
+
+    def ratio(c):
+        return int(c["linear_num_value_heads"]) \
+            // int(c["linear_num_key_heads"])
+
+    qkvz = _qwen3_next_regroup(lambda c: [
+        int(c["linear_key_head_dim"]), int(c["linear_key_head_dim"]),
+        ratio(c) * int(c["linear_value_head_dim"]),
+        ratio(c) * int(c["linear_value_head_dim"])])
+    ba = _qwen3_next_regroup(lambda c: [ratio(c), ratio(c)])
+    return _flat_moe_backbone_rules() + [
+        (r"^model\.layers\.(\d+)\.self_attn\.(q|k)_norm\.weight$",
+         lambda m: (layer(m, "self_attn", f"{m.group(2)}_norm", "scale"),
+                    None)),
+        (r"^model\.layers\.(\d+)\.linear_attn\.in_proj_qkvz\.weight$",
+         lambda m: (layer(m, "linear_attn", "in_proj_qkvz", "kernel"), qkvz)),
+        (r"^model\.layers\.(\d+)\.linear_attn\.in_proj_ba\.weight$",
+         lambda m: (layer(m, "linear_attn", "in_proj_ba", "kernel"), ba)),
+        # [channels, 1, taps] -> [taps, channels], the last tap on the
+        # current token either way
+        (r"^model\.layers\.(\d+)\.linear_attn\.conv1d\.weight$",
+         lambda m: (layer(m, "linear_attn", "conv1d", "kernel"),
+                    lambda w, _c: np.asarray(w)[:, 0, :].T)),
+        (r"^model\.layers\.(\d+)\.linear_attn\.(A_log|dt_bias)$",
+         lambda m: (layer(m, "linear_attn", m.group(2)), None)),
+        (r"^model\.layers\.(\d+)\.linear_attn\.norm\.weight$",
+         lambda m: (layer(m, "linear_attn", "norm", "scale"), None)),
+        (r"^model\.layers\.(\d+)\.linear_attn\.out_proj\.weight$",
+         lambda m: (layer(m, "linear_attn", "out_proj", "kernel"), "t")),
+        (r"^model\.layers\.(\d+)\.mlp\.gate\.weight$",
+         lambda m: (layer(m, "mlp", "gate", "wg", "kernel"), "t")),
+        (r"^model\.layers\.(\d+)\.mlp\.experts\.(\d+)\."
+         r"(gate|up|down)_proj\.weight$",
+         lambda m: (layer(m, "mlp", "experts", f"w_{m.group(3)}"),
+                    ("stack", int(m.group(2))))),
+        (r"^model\.layers\.(\d+)\.mlp\.shared_expert\."
+         r"(gate|up|down)_proj\.weight$",
+         lambda m: (layer(m, "mlp", "shared_expert", f"{m.group(2)}_proj",
+                          "kernel"), "t")),
+        (r"^model\.layers\.(\d+)\.mlp\.shared_expert_gate\.weight$",
+         lambda m: (layer(m, "mlp", "shared_expert_gate", "kernel"), "t")),
+        # the multi-token-prediction module is not served
+        (r"^mtp\..*$", lambda m: (None, None)),
+        (r".*rotary_emb\.inv_freq$", lambda m: (None, None)),
     ]
 
 
@@ -396,6 +465,7 @@ _ARCH_RULES: Dict[str, Callable[[], List[Rule]]] = {
     "internlm": _llama_rules,
     "mixtral": _mixtral_rules,
     "olmoe": _olmoe_rules,
+    "qwen3_next": _qwen3_next_rules,
     "gpt2": _gpt2_rules,
     "opt": _opt_rules,
     "falcon": _falcon_rules,
@@ -481,6 +551,22 @@ def config_from_hf(model_path: str, dtype: Any = None):
             num_experts_per_tok=cfg["num_experts_per_tok"],
             norm_topk_prob=cfg.get("norm_topk_prob", False),
             dtype=dt)
+    if arch == "qwen3_next":
+        from deepspeed_tpu.inference.v2.model_implementations. \
+            ragged_qwen3_next import Qwen3NextConfig
+
+        if cfg.get("rope_scaling") is not None or cfg.get("attention_bias") \
+                or cfg.get("use_sliding_window") or cfg.get("mlp_only_layers") \
+                or cfg.get("decoder_sparse_step", 1) != 1 \
+                or cfg.get("tie_word_embeddings"):
+            raise HFLoadError(
+                "qwen3_next: rope_scaling, attention_bias, a sliding "
+                "window, dense-only layers and a tied head are not "
+                "implemented (Qwen3-Next-80B-A3B sets none of them)")
+        fields = {f.name for f in dataclasses.fields(Qwen3NextConfig)} \
+            - {"dtype"}
+        return arch, Qwen3NextConfig(
+            **{k: v for k, v in cfg.items() if k in fields}, dtype=dt)
     if arch == "gpt2":
         from deepspeed_tpu.models.gpt2 import GPT2Config
 
@@ -705,7 +791,8 @@ def load_hf_checkpoint(model_path: str, architecture: Optional[str] = None,
                 if n_experts and len(stacks[path]) == n_experts:
                     flush_stack(path)
             else:
-                arr = tensor.T if tf == "t" else tensor
+                arr = tensor.T if tf == "t" else \
+                    tf(tensor, file_cfg) if callable(tf) else tensor
                 place(path, arr)
             break
         else:

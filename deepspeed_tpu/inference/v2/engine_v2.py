@@ -31,19 +31,27 @@ from deepspeed_tpu.inference.v2.model_implementations.ragged_llama import (
     shard_ragged_params,
 )
 from deepspeed_tpu.inference.v2.ragged import (DSStateManager,
-                                               RaggedBatchWrapper)
+                                               RaggedBatchWrapper,
+                                               RecurrentStateError)
 from deepspeed_tpu.observability.tracer import SpanHandle, open_span
 from deepspeed_tpu.utils.compile_cache import key_cache_on_names
 from deepspeed_tpu.utils.logging import log_dist
 
 
 def _device_decode_batch(tables, pos, tok, block_size: int,
-                         max_blocks: int):
+                         max_blocks: int, state_slot=None):
     """Ragged batch dict for a one-token-per-slot decode round, with the
     KV write target derived ON DEVICE from the block tables — the single
     source of the per-step decode metadata contract (shared by the
-    scanned ``decode_loop`` body and the per-call ``decode_step``)."""
+    scanned ``decode_loop`` body and the per-call ``decode_step``).
+    ``state_slot`` ([S], a model with recurrent state): each row's slot of
+    the state pool; every row is its own chunk of one token."""
     S = tables.shape[0]
+    if state_slot is not None:
+        return {**_device_decode_batch(tables, pos, tok, block_size,
+                                       max_blocks),
+                "state_slot": state_slot,
+                "chunk_start": jnp.arange(S, dtype=jnp.int32)}
     slot = jnp.arange(S, dtype=jnp.int32)
     blk = jnp.take_along_axis(
         tables, jnp.clip(pos // block_size, 0, max_blocks - 1)[:, None],
@@ -128,10 +136,23 @@ class InferenceEngineV2:
                 f"positions past it would silently alias the last row")
         self.model = model
         self.params = params
+        # a model with per-sequence recurrent state (linear-attention
+        # layers) says so: the state manager then keeps a slot pool beside
+        # the KV pool, and the paths that skip or rewind positions refuse
+        state_spec = getattr(model, "state_spec", None)
+        self._stateful = state_spec is not None
+        if self._stateful and getattr(kv_cfg, "enable_prefix_cache", False):
+            raise RecurrentStateError(
+                f"kv_cache.enable_prefix_cache (attach_prefix, its "
+                f"copy-on-write fork, the host tier) skips the prefill of "
+                f"cached positions: {type(model).__name__} keeps recurrent "
+                f"state a skipped position would never reach, and state "
+                f"snapshots at block boundaries are not implemented")
         self.state_manager = DSStateManager(
             sm_cfg, kv_cfg, num_layers=model.num_layers,
             num_kv_heads=model.num_kv_heads, head_dim=model.head_dim,
-            dtype=getattr(model.config, "dtype", None))
+            dtype=getattr(model.config, "dtype", None),
+            state_spec=state_spec)
         if self.state_manager.kv_cache.quantized:
             if not getattr(model, "supports_quantized_kv", False):
                 raise ValueError(
@@ -158,7 +179,9 @@ class InferenceEngineV2:
             token_budget=sm_cfg.max_ragged_batch_size,
             max_seqs=sm_cfg.max_ragged_sequence_count,
             max_blocks=self._max_blocks,
-            block_size=kv_cfg.block_size)
+            block_size=kv_cfg.block_size,
+            state_scratch=(self.state_manager.state_pool.scratch
+                           if self._stateful else None))
         # Tensor parallelism (reference inference/v2/model_implementations/
         # sharding/): the model is mesh-bound -> place params by the
         # Megatron split rules and the KV pool kv-head-split, so the
@@ -251,6 +274,10 @@ class InferenceEngineV2:
                 blocks += -(-n // self.state_manager.block_size)
             else:
                 blocks += self.state_manager.blocks_needed(seq, n)
+        if self._stateful and sum(
+                self.state_manager.get_sequence(u) is None
+                for u in uids) > self.state_manager.state_pool.free:
+            return False            # a new sequence needs a state slot
         return blocks <= self.state_manager.free_blocks
 
     def attach_prefix(self, uid: int, tokens: Sequence[int]) -> int:
@@ -325,9 +352,10 @@ class InferenceEngineV2:
                 unpack_metadata)
 
             S, B = self._batch.max_seqs, self._max_blocks
+            extra = (True,) if self._stateful else ()
 
             def run(params, cache, packed):
-                batch = unpack_metadata(packed, bucket, S, B)
+                batch = unpack_metadata(packed, bucket, S, B, *extra)
                 return self.model(params, cache, batch,
                                   prefill_tile=prefill_tile)
 
@@ -368,6 +396,12 @@ class InferenceEngineV2:
         tile = self._prefill_tile()
         if tile:
             self._batch.set_alignment(tile)
+        elif self._stateful:
+            raise RecurrentStateError(
+                f"a model with recurrent state runs its prompt chunks "
+                f"through whole tiles: max_ragged_batch_size "
+                f"{self._batch.token_budget} is no multiple of "
+                f"{self.PREFILL_TILE}")
         scheduled: List[int] = []
         drained: List[bool] = []
         for uid in uids:
@@ -417,6 +451,13 @@ class InferenceEngineV2:
                 # useful tokens of the rows they are padded to
                 span.attrs = {"tokens": self._batch.current_tokens,
                               "bucket": bucket}
+                if self._stateful:
+                    # slots held; sequences with a chunk in the tile
+                    # segment here, and those chunks' tokens
+                    tiled = [n for n in self._batch.chunk_sizes if n > 1]
+                    span.attrs.update(state_slots=sm.state_pool.held,
+                                      chunk_seqs=len(tiled),
+                                      chunk_tokens=sum(tiled))
         # host↔device alignment: a jax.profiler capture shows this named
         # bracket on the host track lined up with the XLA execution it
         # dispatched
@@ -497,11 +538,13 @@ class InferenceEngineV2:
             tok = self._as_token_array(tokens, n, S)
             if type(span) is SpanHandle:
                 span.attrs = {"seqs": n}    # live rows of the S it runs
+                if self._stateful:
+                    span.attrs["state_slots"] = sm.state_pool.held
         try:
             with open_span(self.tracer, "engine/decode_step"):
                 logits, nxt, new_cache, new_pos = self._get_decode_step()(
                     self.params, sm.kv_cache.cache, state["tables"],
-                    state["pos"], tok)
+                    state["pos"], tok, *state["slots"])
         except Exception:
             self._recover_donated_cache()
             raise
@@ -519,6 +562,7 @@ class InferenceEngineV2:
         # device positions advanced in lockstep with seen_tokens
         self._dev_decode_state = {
             "tables": state["tables"], "pos": new_pos,
+            "slots": state["slots"],
             "key": (tuple(uids), tuple(s.seen_tokens for s in seqs))}
         if greedy:
             return logits, nxt
@@ -573,6 +617,12 @@ class InferenceEngineV2:
             state = self._upload_decode_state(seqs, key)
         return seqs, state
 
+    def _refuse_stateful(self, path: str, why: str) -> None:
+        if self._stateful:
+            raise RecurrentStateError(
+                f"{path}: {type(self.model).__name__} keeps per-sequence "
+                f"recurrent state; {why}")
+
     def _recover_donated_cache(self) -> None:
         """A jitted step that donates the KV cache raised after donation
         — the cache may reference consumed arrays and its content is
@@ -603,8 +653,14 @@ class InferenceEngineV2:
     def _upload_decode_state(self, seqs, key):
         tables, pos = _pack_tables_positions(seqs, self._batch.max_seqs,
                                              self._max_blocks)
+        slots = ()
+        if self._stateful:      # each row's state slot; pad rows: scratch
+            slots = np.full((self._batch.max_seqs,),
+                            self.state_manager.state_pool.scratch, np.int32)
+            slots[:len(seqs)] = [s.state_slot for s in seqs]
+            slots = (jnp.asarray(slots),)
         state = {"tables": jnp.asarray(tables), "pos": jnp.asarray(pos),
-                 "key": key}
+                 "slots": slots, "key": key}
         self._dev_decode_state = state
         return state
 
@@ -616,8 +672,8 @@ class InferenceEngineV2:
         B = self._max_blocks
         bs = self.state_manager.block_size
 
-        def run(params, cache, tables, pos, tok):
-            batch = _device_decode_batch(tables, pos, tok, bs, B)
+        def run(params, cache, tables, pos, tok, *slots):
+            batch = _device_decode_batch(tables, pos, tok, bs, B, *slots)
             logits, new_cache = self.model(params, cache, batch, decode=True)
             with jax.named_scope("sample_argmax"):
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -663,6 +719,9 @@ class InferenceEngineV2:
         caller fetches K ints per sequence instead of K vocab rows
         (the same asymmetry :meth:`decode_step`'s greedy mode exploits).
         """
+        self._refuse_stateful(
+            "verify_step", "rejected lookahead tokens would have advanced "
+            "the state and cannot be rolled back")
         sm = self.state_manager
         S, B = self._batch.max_seqs, self._max_blocks
         n = len(uids)
@@ -803,6 +862,9 @@ class InferenceEngineV2:
         Internally runs scan chunks drawn from :data:`DECODE_CHUNKS` so the
         set of compiled programs is bounded regardless of ``steps``.
         """
+        self._refuse_stateful(
+            "decode_loop", "the scanned program does not carry state slots; "
+            "decode_step does")
         if len(tokens) != len(uids):
             raise ValueError(
                 f"decode_loop: {len(uids)} uids but {len(tokens)} tokens")
@@ -924,14 +986,18 @@ class InferenceEngineV2:
                 a.shape, a.dtype, sharding=getattr(a, "sharding", None))
 
         ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+        state = (ints(S),) if self._stateful else ()
         if key == ("decode_step",):
-            args = (ints(S, B), ints(S), ints(S))
+            args = (ints(S, B), ints(S), ints(S)) + state
         elif key[0] == "verify_step":
             args = (ints(S * B + S + S * key[1]),)
         elif key[0] == "decode_loop":
             args = (ints(S * B + 2 * S),)
         else:                   # (bucket, tile): the packed metadata row
-            args = (ints(4 * key[0] + S * B + 2 * S),)
+            from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import (
+                packed_length)
+
+            args = (ints(packed_length(key[0], S, B, self._stateful)),)
         return self._steps[key].lower(
             jax.tree_util.tree_map(sds, self.params),
             jax.tree_util.tree_map(sds, self.state_manager.kv_cache.cache),
@@ -995,6 +1061,11 @@ class InferenceEngineV2:
         ``[blocks * block_size, Hkv, D]`` arrays in block-table order) so
         another engine over the same model can :meth:`resume` WITHOUT the
         recompute re-prefill — the disaggregated prefill→decode handoff."""
+        if include_kv:
+            self._refuse_stateful(
+                "flush_to_host(include_kv=True)", "the handoff payload "
+                "carries KV rows only; without it the sequence is "
+                "recomputed from a zeroed slot")
         out: Dict[int, Dict[str, Any]] = {}
         for uid in uids:
             seq = self.state_manager.get_sequence(uid)
@@ -1035,6 +1106,9 @@ class InferenceEngineV2:
                 f"flushed, or the uid was reused")
         if kv_state is None or "kv" not in kv_state:
             return self.put([uid], [tokens], sync=sync)
+        self._refuse_stateful(
+            "resume(kv_state=...)", "a KV payload skips the positions the "
+            "state has to be recomputed over; resume without it")
         seen = int(kv_state["seen_tokens"])
         if not 0 < seen <= len(tokens):
             raise ValueError(
@@ -1132,7 +1206,7 @@ class InferenceEngineV2:
                 quantize_groups: int = 64):
         """Serve a real HuggingFace checkpoint directory (reference: the
         MII/engine_factory path that builds a FastGen engine from a HF
-        snapshot).  Llama/Mistral/Mixtral/OLMoE checkpoints supported;
+        snapshot).  Llama/Mistral/Mixtral/OLMoE/Qwen3-Next checkpoints supported;
         with ``mesh`` (a non-trivial 'model' axis) weights land
         PRE-SHARDED by the Megatron split rules via
         :func:`shard_ragged_params`'s specs — no full host/device copy.
@@ -1176,6 +1250,15 @@ class InferenceEngineV2:
                     "yet — pass mesh=None (weights would silently land "
                     "unsharded otherwise)")
             model = RaggedMixtral(mcfg, block_size)
+        elif arch == "qwen3_next":
+            from deepspeed_tpu.inference.v2.model_implementations. \
+                ragged_qwen3_next import RaggedQwen3Next
+
+            if mesh is not None and mesh.shape.get("model", 1) > 1:
+                raise ValueError(
+                    "RaggedQwen3Next does not support tensor parallelism "
+                    "yet — pass mesh=None")
+            model = RaggedQwen3Next(mcfg, block_size)
         else:
             raise ValueError(
                 f"FastGen has no ragged model for architecture {arch!r}")
@@ -1231,7 +1314,8 @@ class InferenceEngineV2:
         outs: Dict[int, List[int]] = {u: [] for u in uids}
         live = list(uids)
         logits = self.put(uids, prompts)
-        if eos_token_id is None and max_new_tokens > 1:
+        if eos_token_id is None and max_new_tokens > 1 \
+                and not self._stateful:
             # no early-exit needed -> device-resident decode: one dispatch
             # per decode chunk instead of one per token (grouped by
             # max_seqs — decode_loop batches at most one slot per sequence)

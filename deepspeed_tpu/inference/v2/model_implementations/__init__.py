@@ -1,6 +1,6 @@
 """Inference v2 model implementations (reference:
 inference/v2/model_implementations/ — llama_v2, opt, mistral, mixtral,
-falcon families)."""
+falcon families; qwen3_next has no reference counterpart)."""
 
 from deepspeed_tpu.inference.v2.model_implementations.ragged_llama import (
     RaggedLlama,
@@ -16,11 +16,16 @@ from deepspeed_tpu.inference.v2.model_implementations.ragged_mixtral import (
 from deepspeed_tpu.inference.v2.model_implementations.ragged_opt import (
     RaggedOPT,
 )
+from deepspeed_tpu.inference.v2.model_implementations.ragged_qwen3_next import (
+    Qwen3NextConfig,
+    RaggedQwen3Next,
+)
 
 # Mistral is the Llama architecture + sliding window: serve it with
 # RaggedLlama over a config whose ``sliding_window`` is set (reference
 # mistral/ container reuses the llama modules the same way)
 RaggedMistral = RaggedLlama
 
-__all__ = ["RaggedLlama", "RaggedMistral", "RaggedMixtral", "RaggedOPT",
-           "RaggedFalcon", "ragged_param_specs", "shard_ragged_params"]
+__all__ = ["Qwen3NextConfig", "RaggedLlama", "RaggedMistral", "RaggedMixtral",
+           "RaggedOPT", "RaggedFalcon", "RaggedQwen3Next",
+           "ragged_param_specs", "shard_ragged_params"]

@@ -95,6 +95,11 @@ def _rms_norm(x, scale, eps):
             * scale.astype(jnp.float32)).astype(x.dtype)
 
 
+def _rms_norm_1p(x, scale, eps):
+    """RMSNorm with a zero-centred weight: ``x / rms(x) * (1 + w)``."""
+    return _rms_norm(x, 1.0 + scale.astype(jnp.float32), eps)
+
+
 def _paged_attention(q, k_pool, v_pool, batch, block_size,
                      use_kernel=None, window=None, prefill_tile=None,
                      decode_mode=False, verify_k=None,
@@ -374,26 +379,44 @@ def ragged_attention_block(lp_attn, xa, layer_cache, batch, block_size, cfg,
     qkv proj (→ q/k RMSNorm where the layer has ``q_norm``/``k_norm``)
     → rotary → paged-KV scatter → blocked-flash → o_proj
     (+ row-parallel psum under TP). ``h``/``hkv`` are LOCAL head counts.
-    Returns ``(attn_out [T, H_model], new_layer_cache)``."""
+    Returns ``(attn_out [T, H_model], new_layer_cache)``.
+
+    Static branches, each read from the layer's own parameters or the
+    config: a ``q_norm`` scale as long as ONE head normalises every head on
+    its own (after the head split), a longer one the whole projection;
+    ``cfg.zero_centered_norm`` makes those norms ``1 + w``; ``cos``/``sin``
+    narrower than half a head rotate only the head's first dims (partial
+    rotary); ``cfg.attn_output_gate``: ``q_proj`` emits, per head, the
+    query and a gate, and the attention output is multiplied by the gate's
+    sigmoid before ``o_proj``."""
     dt = cfg.dtype
     kv_dest = batch["kv_dest"]
     # OLMoE / OLMo-2 (static: the layer's own parameters say so): RMSNorm
     # over the WHOLE q and k projections, all heads at once, before the
     # head split and the rotary embedding
     qk_norm = "q_norm" in lp_attn
-    if qk_norm and ax is not None:
+    headwise = qk_norm and lp_attn["q_norm"]["scale"].shape[-1] == d != h * d
+    if qk_norm and not headwise and ax is not None:
         raise NotImplementedError(
             "q/k normalisation spans every head: it does not compose with "
             "head-split tensor parallelism yet")
+    norm = _rms_norm_1p if getattr(cfg, "zero_centered_norm", False) \
+        else _rms_norm
+    gate = None
     with jax.named_scope("attn/qkv"):
         q = qmm(xa, lp_attn["q_proj"]["kernel"], dt)
-        if qk_norm:
+        if getattr(cfg, "attn_output_gate", False):
+            q, gate = jnp.split(q.reshape(-1, h, 2 * d), 2, axis=-1)
+        if qk_norm and not headwise:
             q = _rms_norm(q, lp_attn["q_norm"]["scale"], cfg.rms_norm_eps)
         q = q.reshape(-1, h, d)
         k = qmm(xa, lp_attn["k_proj"]["kernel"], dt)
-        if qk_norm:
+        if qk_norm and not headwise:
             k = _rms_norm(k, lp_attn["k_norm"]["scale"], cfg.rms_norm_eps)
         k = k.reshape(-1, hkv, d)
+        if headwise:
+            q = norm(q, lp_attn["q_norm"]["scale"], cfg.rms_norm_eps)
+            k = norm(k, lp_attn["k_norm"]["scale"], cfg.rms_norm_eps)
         v = qmm(xa, lp_attn["v_proj"]["kernel"], dt).reshape(-1, hkv, d)
     with jax.named_scope("attn/rope_insert"):
         q, k_pool, v_pool, k_scale, v_scale, new_cache = _rope_insert(
@@ -404,6 +427,9 @@ def ragged_attention_block(lp_attn, xa, layer_cache, batch, block_size, cfg,
                            decode_mode=decode_mode, verify_k=verify_k,
                            k_scale=k_scale, v_scale=v_scale)
     with jax.named_scope("attn/out_proj"):
+        if gate is not None:
+            out = (out.astype(jnp.float32) * jax.nn.sigmoid(
+                gate.astype(jnp.float32))).astype(out.dtype)
         out = qmm(out.reshape(-1, h * d), lp_attn["o_proj"]["kernel"], dt)
         if ax is not None:
             out = jax.lax.psum(out, ax)               # row-parallel attn-out
@@ -416,8 +442,15 @@ def _rope_insert(q, k, v, cos, sin, layer_cache, kv_dest):
     k_scale, v_scale, new_layer_cache)``; the scales are None on a float
     pool."""
     # apply_rotary broadcasts over [T, H, D] with cos/sin [T, 1, D/2]
-    q = apply_rotary(q, cos, sin)
-    k = apply_rotary(k, cos, sin)
+    rot = 2 * cos.shape[-1]
+    if rot < q.shape[-1]:           # partial rotary: the first dims only
+        q = jnp.concatenate([apply_rotary(q[..., :rot], cos, sin),
+                             q[..., rot:]], axis=-1)
+        k = jnp.concatenate([apply_rotary(k[..., :rot], cos, sin),
+                             k[..., rot:]], axis=-1)
+    else:
+        q = apply_rotary(q, cos, sin)
+        k = apply_rotary(k, cos, sin)
     # dtype-polymorphic pool (static branch: the leaf dtype is known at
     # trace time).  int8 mode quantizes ON INSERT — payload + per-row/
     # per-head scale scatter in the same step, so the cache is always

@@ -69,7 +69,7 @@ def moe_router(x, wg, k: int, renormalize: bool = True):
 
 
 def dropless_moe(x, moe_params, k: int, dtype, grouped=None,
-                 renormalize: bool = True):
+                 renormalize: bool = True, expert_start: int = 0):
     """Dropless top-k MoE over a flat token buffer.
 
     x: [T, H]; returns [T, H]. Router math in fp32 (reference TopKGate is
@@ -82,6 +82,15 @@ def dropless_moe(x, moe_params, k: int, dtype, grouped=None,
     of E·T (4× fewer for Mixtral's 8-expert top-2, 8x for OLMoE's 64 at
     top-8).  ``grouped=False`` forces the dense all-experts einsum (the
     parity oracle).  ``renormalize`` (static) is HF ``norm_topk_prob``.
+
+    A share of the experts (static, read from the shapes: the expert
+    matrices hold fewer experts than the router has outputs): the router
+    still scores every expert and takes the top-k of all of them, and the
+    result is the part of the sum that the held experts
+    ``[expert_start, expert_start + held)`` give; a token routed wholly
+    elsewhere gets zeros.  With every expert held this is the path above,
+    unchanged.  A gated shared expert (``shared_expert`` and
+    ``shared_expert_gate`` in ``moe_params``) is added for every token.
     """
     from deepspeed_tpu.ops.grouped_gemm import grouped_moe_ffn
 
@@ -92,9 +101,17 @@ def dropless_moe(x, moe_params, k: int, dtype, grouped=None,
     w_gate = experts["w_gate"].astype(dtype)           # [E, H, F]
     w_up = experts["w_up"].astype(dtype)
     w_down = experts["w_down"].astype(dtype)
-    if grouped is None or grouped:
-        return grouped_moe_ffn(x.astype(dtype), topi, w.astype(dtype),
-                               w_gate, w_up, w_down)
+    # a share and a shared expert exist on the grouped path only (the
+    # dense composition below is the all-experts parity oracle)
+    share = w_gate.shape[0] != e_count
+    shared = "shared_expert" in moe_params
+    if share or shared or grouped is None or grouped:
+        kwargs = {"expert_start": int(expert_start)} if share else {}
+        out = grouped_moe_ffn(x.astype(dtype), topi, w.astype(dtype),
+                              w_gate, w_up, w_down, **kwargs)
+        if shared:
+            out = out + _shared_expert(x.astype(dtype), moe_params, dtype)
+        return out
     # dense all-experts composition (reference/oracle path)
     comb = jnp.sum(jax.nn.one_hot(topi, e_count, dtype=jnp.float32)
                    * w[..., None], axis=1)             # [T, E]
@@ -103,6 +120,20 @@ def dropless_moe(x, moe_params, k: int, dtype, grouped=None,
         jnp.einsum("tm,emf->etf", xe, w_up)            # [E, T, F]
     out = jnp.einsum("etf,efm->etm", h, w_down)        # [E, T, H]
     return jnp.einsum("te,etm->tm", comb.astype(dtype), out)
+
+
+def _shared_expert(x, moe_params, dtype):
+    """The expert every token takes (device scope ``moe/shared``):
+    ``sigmoid(x . w_sg) * down(silu(gate x) * up x)``."""
+    with jax.named_scope("moe/shared"):
+        se = moe_params["shared_expert"]
+        hmid = jax.nn.silu(x @ se["gate_proj"]["kernel"].astype(dtype)) \
+            * (x @ se["up_proj"]["kernel"].astype(dtype))
+        y = hmid @ se["down_proj"]["kernel"].astype(dtype)
+        sg = jax.nn.sigmoid(
+            x.astype(jnp.float32)
+            @ moe_params["shared_expert_gate"]["kernel"].astype(jnp.float32))
+        return (sg * y.astype(jnp.float32)).astype(dtype)
 
 
 class RaggedMixtral:

@@ -13,8 +13,11 @@ from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import RaggedBatchWrapper
 from deepspeed_tpu.inference.v2.ragged.sequence_descriptor import (
     DSSequenceDescriptor,
 )
+from deepspeed_tpu.inference.v2.ragged.state_pool import (RecurrentStateError,
+                                                          StateSlotPool)
 
 __all__ = ["BlockedAllocator", "BlockedKVCache", "DSStateManager",
            "HostKVTier", "HostTierStats", "PrefixCacheStats",
            "RadixPrefixCache", "RaggedBatchWrapper",
+           "RecurrentStateError", "StateSlotPool",
            "DSSequenceDescriptor", "quantize_kv", "dequantize_kv"]

@@ -75,7 +75,13 @@ def dequantize_kv(q: jnp.ndarray, scale: jnp.ndarray,
 
 class BlockedKVCache:
     def __init__(self, num_layers: int, num_blocks: int, block_size: int,
-                 num_kv_heads: int, head_dim: int, dtype: Any = jnp.bfloat16):
+                 num_kv_heads: int, head_dim: int, dtype: Any = jnp.bfloat16,
+                 kv_layers=None):
+        #: the layers that hold keys and values (all of them, unless the
+        #: model says which: its other layers keep state in slots, see
+        #: ``state_pool.py``, and their leaves join ``cache`` beside these)
+        self.kv_layers = tuple(range(num_layers) if kv_layers is None
+                               else kv_layers)
         self.num_layers = num_layers
         self.num_blocks = num_blocks
         self.block_size = block_size
@@ -102,7 +108,7 @@ class BlockedKVCache:
             return leaves
 
         self.cache: Dict[str, Dict[str, jax.Array]] = {
-            f"layer_{i}": layer() for i in range(num_layers)
+            f"layer_{i}": layer() for i in self.kv_layers
         }
 
     # The engine threads self.cache through the jitted forward and stores the
@@ -115,8 +121,17 @@ class BlockedKVCache:
         prefix cache's copy-on-write fork).  One jitted program per cache
         geometry — src/dst are traced scalars, so forking different blocks
         never recompiles; the old cache is donated (in-place on device)."""
-        self.cache = _copy_block(self.cache, jnp.int32(src), jnp.int32(dst),
-                                 self.block_size)
+        self._update_pools(_copy_block(
+            self._pools(), jnp.int32(src), jnp.int32(dst), self.block_size))
+
+    def _pools(self) -> Dict[str, Dict[str, jax.Array]]:
+        """The KV layers of ``cache``: block operations move pool rows and
+        leave any state slots beside them alone."""
+        return {f"layer_{i}": self.cache[f"layer_{i}"]
+                for i in self.kv_layers}
+
+    def _update_pools(self, pools) -> None:
+        self.cache = {**self.cache, **pools}
 
     def _block_rows(self, blocks) -> "jax.Array":
         """Flat pool row indices covering ``blocks`` in table order."""
@@ -135,7 +150,7 @@ class BlockedKVCache:
         of which physical blocks held it."""
         rows = self._block_rows(blocks)
         return jax.device_get(
-            jax.tree_util.tree_map(lambda a: a[rows], self.cache))
+            jax.tree_util.tree_map(lambda a: a[rows], self._pools()))
 
     def scatter_blocks(self, blocks, host_tree) -> None:
         """Write a :meth:`gather_blocks` payload into ``blocks`` of THIS
@@ -153,7 +168,8 @@ class BlockedKVCache:
                     f"{(n,) + a.shape[1:]} (cache geometry differs)")
             return a.at[rows].set(h)
 
-        self.cache = jax.tree_util.tree_map(one, self.cache, host_tree)
+        self._update_pools(
+            jax.tree_util.tree_map(one, self._pools(), host_tree))
 
     @property
     def per_token_bytes(self) -> int:
@@ -165,7 +181,7 @@ class BlockedKVCache:
         per_head = self.head_dim * itemsize
         if self.quantized:
             per_head += 4                       # fp32 scale per (row, head)
-        return 2 * self.num_layers * self.num_kv_heads * per_head
+        return 2 * len(self.kv_layers) * self.num_kv_heads * per_head
 
 
 @partial(jax.jit, static_argnums=(3,), donate_argnums=(0,))

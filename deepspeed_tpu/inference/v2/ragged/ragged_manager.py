@@ -30,6 +30,7 @@ from deepspeed_tpu.inference.v2.ragged.prefix_cache import RadixPrefixCache
 from deepspeed_tpu.inference.v2.ragged.sequence_descriptor import (
     DSSequenceDescriptor,
 )
+from deepspeed_tpu.inference.v2.ragged.state_pool import StateSlotPool
 
 
 class DSStateManager:
@@ -38,7 +39,7 @@ class DSStateManager:
     def __init__(self, config: DSStateManagerConfig,
                  kv_config: KVCacheConfig,
                  num_layers: int, num_kv_heads: int, head_dim: int,
-                 dtype=None):
+                 dtype=None, state_spec=None):
         self.config = config
         self.kv_config = kv_config
         self.block_size = kv_config.block_size
@@ -56,8 +57,21 @@ class DSStateManager:
             kwargs["dtype"] = kv_config.dtype
         elif dtype is not None or kv_config.cache_dtype is not None:
             kwargs["dtype"] = kv_config.cache_dtype or dtype
+        #: slots of per-sequence recurrent state, for a model whose
+        #: ``state_spec`` names layers that keep such state instead of keys
+        #: and values (``{"layers": [...], "leaves": {name: (shape,
+        #: dtype)}}``): as many as sequences one forward can hold
+        self.state_pool: Optional[StateSlotPool] = None
+        if state_spec is not None:
+            self.state_pool = StateSlotPool(
+                config.max_ragged_sequence_count, state_spec["layers"],
+                state_spec["leaves"])
+            kwargs["kv_layers"] = [i for i in range(num_layers)
+                                   if i not in set(state_spec["layers"])]
         self.kv_cache = BlockedKVCache(num_layers, num_blocks, self.block_size,
                                        num_kv_heads, head_dim, **kwargs)
+        if self.state_pool is not None:
+            self.kv_cache.cache.update(self.state_pool.new_arrays())
         self.prefix_cache: Optional[RadixPrefixCache] = (
             RadixPrefixCache(self.allocator, self.block_size)
             if getattr(kv_config, "enable_prefix_cache", False) else None)
@@ -111,6 +125,8 @@ class DSStateManager:
                     f"too many tracked sequences "
                     f"({self.config.max_tracked_sequences})")
             seq = DSSequenceDescriptor(uid=uid)
+            if self.state_pool is not None:
+                seq.state_slot = self.state_pool.take()
             self._seqs[uid] = seq
         return seq
 
@@ -141,6 +157,8 @@ class DSStateManager:
             raise ValueError(f"unknown sequence uid {uid}")
         if seq.blocks:
             self.allocator.free(seq.blocks)
+        if self.state_pool is not None:
+            self.state_pool.release(seq.state_slot)
 
     def flush(self, uids: Iterable[int]) -> None:
         for uid in uids:

@@ -15,6 +15,12 @@ budget) packing tokens from up to ``max_seqs`` sequences::
     logits_idx   [max_seqs] int32  index in [T] of each slot's last token
     kv_dest      [T] int32  flat pool index for each token's KV write
 
+and, for a model with per-sequence recurrent state (``state_pool.py``)::
+
+    state_slot  [max_seqs] int32  state slot of each batch slot's sequence
+                                  (pad -> the scratch slot)
+    chunk_start [max_seqs] int32  buffer row of the chunk's first token
+
 Chunks sit back to back in the buffer, or — after ``set_alignment``, which
 the engine calls whenever its token budget is a whole number of prefill
 tiles — in two segments: ``max_seqs`` rows for the chunks of one token,
@@ -106,7 +112,10 @@ def validate_ragged_metadata(seqs: List[DSSequenceDescriptor],
 
 class RaggedBatchWrapper:
     def __init__(self, token_budget: int, max_seqs: int, max_blocks: int,
-                 block_size: int):
+                 block_size: int, state_scratch: int = None):
+        #: the scratch slot of the model's recurrent-state pool, or None
+        #: for a model without one (no state fields in the metadata then)
+        self.state_scratch = state_scratch
         self.token_budget = token_budget
         self.max_seqs = max_seqs
         self.max_blocks = max_blocks
@@ -235,12 +244,18 @@ class RaggedBatchWrapper:
             context_lens[slot] = seq.seen_tokens + n
             logits_idx[slot] = cursor + n - 1
 
-        return {
+        meta = {
             "token_ids": token_ids, "token_slot": token_slot,
             "token_pos": token_pos, "kv_dest": kv_dest,
             "block_tables": block_tables, "context_lens": context_lens,
             "logits_idx": logits_idx, "n_valid": np.int32(n_valid),
         }
+        if self.state_scratch is not None:
+            meta["state_slot"] = np.full((S,), self.state_scratch, np.int32)
+            meta["chunk_start"] = np.zeros((S,), np.int32)
+            meta["state_slot"][:n_valid] = [s.state_slot for s in self._seqs]
+            meta["chunk_start"][:n_valid] = self._starts
+        return meta
 
     @property
     def sequences(self) -> List[DSSequenceDescriptor]:
@@ -258,25 +273,40 @@ class RaggedBatchWrapper:
 # --------------------------------------------------------------------- #
 _META_FIELDS = ("token_ids", "token_slot", "token_pos", "kv_dest",
                 "block_tables", "context_lens", "logits_idx")
+#: appended for a model with recurrent state
+_STATE_FIELDS = ("state_slot", "chunk_start")
+
+
+def _fields(meta_or_flag):
+    return _META_FIELDS + (_STATE_FIELDS if meta_or_flag else ())
 
 
 def pack_metadata(meta) -> np.ndarray:
     """Flatten the finalize() dict into one int32 vector (host side)."""
     return np.concatenate(
-        [np.asarray(meta[k], np.int32).ravel() for k in _META_FIELDS])
+        [np.asarray(meta[k], np.int32).ravel()
+         for k in _fields("state_slot" in meta)])
+
+
+def packed_length(token_capacity: int, max_seqs: int, max_blocks: int,
+                  state: bool = False) -> int:
+    """Length of the packed vector of one batch."""
+    return 4 * token_capacity + max_seqs * max_blocks \
+        + (4 if state else 2) * max_seqs
 
 
 def unpack_metadata(packed, token_capacity: int, max_seqs: int,
-                    max_blocks: int):
+                    max_blocks: int, state: bool = False):
     """Rebuild the batch dict from the packed vector (inside jit)."""
     T, S, B = token_capacity, max_seqs, max_blocks
     sizes = {"token_ids": (T, (T,)), "token_slot": (T, (T,)),
              "token_pos": (T, (T,)), "kv_dest": (T, (T,)),
              "block_tables": (S * B, (S, B)),
-             "context_lens": (S, (S,)), "logits_idx": (S, (S,))}
+             "context_lens": (S, (S,)), "logits_idx": (S, (S,)),
+             "state_slot": (S, (S,)), "chunk_start": (S, (S,))}
     out = {}
     o = 0
-    for k in _META_FIELDS:
+    for k in _fields(state):
         n, shape = sizes[k]
         out[k] = packed[o:o + n].reshape(shape)
         o += n

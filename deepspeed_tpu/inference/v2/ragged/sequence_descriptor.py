@@ -15,6 +15,8 @@ class DSSequenceDescriptor:
     pending: List[int] = dataclasses.field(default_factory=list)
     # tokens awaiting scheduling (prompt remainder under SplitFuse)
     done: bool = False
+    #: slot of the recurrent-state pool (-1: the model keeps none)
+    state_slot: int = -1
 
     # -- prefix-cache bookkeeping (all zero when caching is off) ------- #
     #: token VALUES whose KV this sequence holds, positions [0, len);

@@ -1,0 +1,77 @@
+"""Per-sequence recurrent state beside the paged KV pool.
+
+A linear-attention (Gated DeltaNet) layer keeps no keys and values: it keeps,
+for every live sequence, a fixed-size state (a recurrent matrix per head and
+the tail of its causal convolution).  The state manager holds those in SLOTS:
+``num_slots`` of them, one taken when a sequence is created and released when
+it is flushed, plus one scratch slot (index ``num_slots``) that pad rows of a
+step program read and write so that they touch no live sequence.
+
+The device arrays (``{layer_<i>: {leaf: [num_slots + 1, ...]}}``) live in the
+same cache tree as the KV pools, so one donation and one ``update`` cover
+both; this class owns the geometry and the host's free list.  A slot is not
+cleared when it is released: the step program zeroes the state of a sequence
+whose chunk starts at position 0, on the device, so a recompute (preemption,
+a failed step) restarts from zero whatever the slot held.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict, List, Sequence, Tuple
+
+import jax.numpy as jnp
+import numpy as np
+
+
+class RecurrentStateError(NotImplementedError):
+    """A path that skips or rewinds positions (prefix-cache attach and its
+    copy-on-write fork, the host tier, a KV handoff, speculative verify, the
+    scanned decode loop) was asked of a model with per-sequence recurrent
+    state, which cannot follow without a snapshot of that state."""
+
+
+class StateSlotPool:
+    def __init__(self, num_slots: int, layers: Sequence[int],
+                 leaves: Dict[str, Tuple[Tuple[int, ...], Any]]):
+        self.num_slots = int(num_slots)
+        self.layers = tuple(layers)
+        self.leaves = dict(leaves)
+        self._free: List[int] = list(range(self.num_slots - 1, -1, -1))
+
+    @property
+    def scratch(self) -> int:
+        """The slot pad rows use."""
+        return self.num_slots
+
+    @property
+    def free(self) -> int:
+        return len(self._free)
+
+    @property
+    def held(self) -> int:
+        return self.num_slots - len(self._free)
+
+    def take(self) -> int:
+        if not self._free:
+            raise RuntimeError(
+                f"no free state slot ({self.num_slots} held): a model with "
+                f"recurrent state tracks at most max_ragged_sequence_count "
+                f"sequences")
+        return self._free.pop()
+
+    def release(self, slot: int) -> None:
+        self._free.append(slot)
+
+    def new_arrays(self) -> Dict[str, Dict[str, Any]]:
+        return {f"layer_{i}": {
+            name: jnp.zeros((self.num_slots + 1,) + tuple(shape), dtype)
+            for name, (shape, dtype) in self.leaves.items()}
+            for i in self.layers}
+
+    @property
+    def per_sequence_bytes(self) -> int:
+        """HBM bytes one live sequence holds across every stateful layer,
+        whatever its length (the KV pool's ``per_token_bytes`` is apart)."""
+        return len(self.layers) * sum(
+            int(np.prod(shape)) * jnp.dtype(dtype).itemsize
+            for shape, dtype in self.leaves.values())

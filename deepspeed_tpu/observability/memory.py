@@ -204,6 +204,18 @@ def kv_occupancy(state_manager) -> Dict[str, float]:
         "observability/kv_sequences_live": float(
             state_manager.n_tracked_sequences),
     }
+    pool = getattr(state_manager, "state_pool", None)
+    if pool is not None:
+        # recurrent state: bytes a SEQUENCE holds whatever its length,
+        # told apart from the pool's bytes a token
+        out.update({
+            "observability/state_slots_total": float(pool.num_slots),
+            "observability/state_slots_held": float(pool.held),
+            "observability/state_pool_bytes": float(
+                (pool.num_slots + 1) * pool.per_sequence_bytes),
+            "observability/state_live_bytes": float(
+                pool.held * pool.per_sequence_bytes),
+        })
     tier = getattr(state_manager, "host_tier", None)
     if tier is not None:
         st = tier.stats

@@ -29,6 +29,11 @@ def _declare(reg: MetricsRegistry) -> None:
     reg.gauge("observability/kv_*",
               help="KV pool occupancy: blocks live/warm/evictable, "
                    "token + byte gauges")
+    # recurrent-state slots beside the KV pool (a model with
+    # linear-attention layers): bytes a SEQUENCE holds, whatever its length
+    reg.gauge("observability/state_*",
+              help="recurrent-state slot pool: slots total/held, pool and "
+                   "live bytes")
     # host cold-tier gauges (kv_cache.host_tier): spooled/restored block
     # counters, tier residency, and the spool/restore latency
     # percentiles the session-mix bench reports — declared exactly (on

@@ -55,11 +55,23 @@ span                     site                        parent    attrs (counters)
                          ``tick``
 ``engine/build_batch``   ``_run_one_batch``: the     prefill   ``tokens`` fed of
                          chunks, their KV slots,               the ``bucket``
-                         the metadata, its upload              padded to
+                         the metadata, its upload              padded to; with
+                                                               recurrent state:
+                                                               ``state_slots``
+                                                               held,
+                                                               ``chunk_seqs``
+                                                               with a chunk in
+                                                               the tile segment
+                                                               and their
+                                                               ``chunk_tokens``
 ``engine/ragged_step``   the step's dispatch         prefill   —
 ``engine/fetch_logits``  ``device_get(logits)``      prefill   —
 ``engine/decode_prep``   ``decode_step``: KV slots,  decode    ``seqs``: live
-                         table upload, token array             rows of the step
+                         table upload, token array             rows of the step;
+                                                               with recurrent
+                                                               state:
+                                                               ``state_slots``
+                                                               held
 ``engine/decode_step``   the step's dispatch: of     decode    —
                          the step the tick returns
                          unless that is in flight,

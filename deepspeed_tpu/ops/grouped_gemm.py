@@ -420,11 +420,19 @@ def exact_topk_routing(logits: jnp.ndarray, k: int,
 def grouped_moe_ffn(x: jnp.ndarray, topi: jnp.ndarray, topw: jnp.ndarray,
                     w_gate: jnp.ndarray, w_up: jnp.ndarray,
                     w_down: jnp.ndarray,
-                    interpret: Optional[bool] = None) -> jnp.ndarray:
+                    interpret: Optional[bool] = None,
+                    expert_start: Optional[int] = None) -> jnp.ndarray:
     """x: [T, H]; topi/topw: [T, k] routing; w_gate/w_up: [E, H, F],
     w_down: [E, F, H].  Returns [T, H].  FLOPs scale with k·T (not E·T):
     tokens are sorted by expert and each expert multiplies only its own
-    contiguous row block."""
+    contiguous row block.
+
+    ``expert_start`` (static): the matrices are ONE SHARE of a wider
+    router's experts, ``[expert_start, expert_start + E)`` of the ids in
+    ``topi``.  Rows routed elsewhere leave before the counting sort (the
+    groups then sum to fewer than ``T x k`` rows, which the kernel's
+    work-unit list allows) and weigh nothing in the combine: the result is
+    the part of the expert sum this share gives."""
     t, h = x.shape
     e = w_gate.shape[0]
     k = topi.shape[1]
@@ -433,6 +441,12 @@ def grouped_moe_ffn(x: jnp.ndarray, topi: jnp.ndarray, topw: jnp.ndarray,
     # grouped GEMMs and the SwiGLU product), moe/combine (unsort + weight)
     with jax.named_scope("moe/dispatch"):
         flat_e = topi.reshape(-1).astype(jnp.int32)          # [T*k]
+        if expert_start is not None:
+            flat_e = flat_e - expert_start
+            held = (flat_e >= 0) & (flat_e < e)
+            # -1 has no one-hot column: counted in no group, ranked nowhere
+            flat_e = jnp.where(held, flat_e, -1)
+            topw = jnp.where(held.reshape(topw.shape), topw, 0)
         # counting sort by expert (stable): XLA's general sort is far
         # slower than a one-hot cumsum at these sizes (measured ~0.7 ms
         # for an argsort-based sort/gather stage at M=4096 on v5e)
@@ -443,6 +457,10 @@ def grouped_moe_ffn(x: jnp.ndarray, topi: jnp.ndarray, topw: jnp.ndarray,
         offsets = jnp.cumsum(group_sizes) - group_sizes
         dest = offsets[flat_e] + rank                    # [M] sorted slot
         m_rows = flat_e.shape[0]
+        if expert_start is not None:
+            # past the end: dropped by the scatter below, and the combine's
+            # gather of it (clamped) is weighed by zero
+            dest = jnp.where(held, dest, m_rows)
         order = jnp.zeros((m_rows,), jnp.int32).at[dest].set(
             jnp.arange(m_rows, dtype=jnp.int32))
         xs = x[order // k]                               # [T*k, H] sorted

@@ -160,6 +160,15 @@ class ContinuousBatchScheduler:
                 raise ValueError(
                     "speculative decoding needs an engine with "
                     "verify_step/commit_verified (InferenceEngineV2)")
+            if getattr(engine, "_stateful", False):
+                from deepspeed_tpu.inference.v2.ragged import (
+                    RecurrentStateError)
+
+                raise RecurrentStateError(
+                    "speculative decoding verifies drafts through "
+                    "verify_step, which a model with per-sequence "
+                    "recurrent state refuses: rejected lookahead tokens "
+                    "cannot be rolled back out of the state")
             if not fast_decode:
                 raise ValueError(
                     "speculative decoding runs on the fast decode tick — "

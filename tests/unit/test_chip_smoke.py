@@ -108,6 +108,40 @@ def test_smoke_moe_phase_tiny_on_one_cpu_device():
         _tiny_sizes(), jax.devices()[:1], False, chip_smoke.CompileClock())
 
 
+def test_smoke_gdn_phase_tiny_on_one_cpu_device(monkeypatch):
+    """The linear-attention phase's control flow at a tiny Qwen3-Next-shaped
+    size: two requests of different lengths interleaved through the
+    scheduler (the longer one in two chunks), each against its own
+    reference forward; every state slot given back.  The phase serves bf16
+    against a float32 reference: at hidden 64 with top-3 of 8 experts a
+    bf16 rounding flips routings and moves logits by tenths (measured 0.05
+    and 0.19), which the published widths do not do; the float32 parity of
+    the same interleaving is ``test_ragged_qwen3_next.py``'s (5e-7)."""
+    monkeypatch.setattr(chip_smoke, "GDN_LOGIT_TOL", 0.5)
+    hf = {"model_type": "qwen3_next", "vocab_size": 256, "hidden_size": 64,
+          "num_hidden_layers": 4, "num_attention_heads": 4,
+          "num_key_value_heads": 2, "head_dim": 16,
+          "partial_rotary_factor": 0.25, "rope_theta": 10000,
+          "rms_norm_eps": 1e-6, "max_position_embeddings": 512,
+          "full_attention_interval": 4, "linear_num_key_heads": 2,
+          "linear_num_value_heads": 4, "linear_key_head_dim": 16,
+          "linear_value_head_dim": 16, "linear_conv_kernel_dim": 4,
+          "num_experts": 4, "router_experts": 8, "expert_start": 2,
+          "num_experts_per_tok": 3, "moe_intermediate_size": 32,
+          "shared_expert_intermediate_size": 32, "norm_topk_prob": True,
+          "rope_scaling": None}
+    sizes = dataclasses.replace(
+        _tiny_sizes(), token_budget=128, max_seqs=4, block_size=16,
+        gdn_hf=hf, gdn_prompt_lens=(150, 40), gdn_new_tokens=(4, 7))
+    out = chip_smoke.gdn_phase(sizes, jax.devices()[:1], False,
+                               chip_smoke.CompileClock())
+    assert max(out["logit_gaps"]) <= chip_smoke.GDN_LOGIT_TOL
+    assert min(out["rows_compared"]) >= 4
+    assert "decode_step" in out["kernels"]
+    assert "skipped" in chip_smoke.gdn_phase(
+        _tiny_sizes(), jax.devices()[:1], False, chip_smoke.CompileClock())
+
+
 def test_smoke_gates_fail_loudly():
     """The checks that tell a chip run from a CPU or interpreter run."""
     with pytest.raises(chip_smoke.SmokeFailure, match="XLA composition"):

@@ -351,6 +351,57 @@ def run_selftest(tol: float = 3e-2) -> dict:
 
     guarded("gmm_grads", gmm_grads_case)
 
+    # ---- gated delta rule (linear attention): the decode update and the
+    # chunked rule at the Qwen3-Next cell's shapes, against their XLA
+    # compositions.  float32 throughout: the kernels' MXU passes are
+    # contract_precision<fp32>, so 1e-3 of the largest value is generous ---- #
+    from deepspeed_tpu.ops import gated_delta_rule as gdr
+
+    def gdn_inputs(rows, seed):
+        ks = jax.random.split(jax.random.fold_in(key, seed), 6)
+        unit = lambda y: y / jnp.linalg.norm(y, axis=-1, keepdims=True)
+        q = unit(jax.random.normal(ks[0], (rows, 32, 128))) * 128 ** -0.5
+        k = unit(jax.random.normal(ks[1], (rows, 32, 128)) + 0.3)
+        v = jax.random.normal(ks[2], (rows, 32, 128))
+        g = -0.05 * jnp.abs(jax.random.normal(ks[3], (rows, 32)))
+        beta = jax.nn.sigmoid(jax.random.normal(ks[4], (rows, 32)))
+        pool = jax.random.normal(ks[5], (9, 32, 128, 128))
+        return pool, q, k, v, g, beta
+
+    def gdn_case(name, got, want):
+        scale = max(float(jnp.max(jnp.abs(w))) for w in want)
+        err = max(float(jnp.max(jnp.abs(a - b))) for a, b in zip(got, want))
+        results[name] = {"max_err": round(err / scale, 7),
+                         "ok": bool(err / scale < 1e-3)}
+
+    def gdn_step_case():
+        pool, *rows = gdn_inputs(8, 21)
+        slots = jnp.asarray([3, 0, 8, 5, 8, 1, 8, 8], jnp.int32)
+        reset = jnp.asarray([0, 1, 1, 0, 1, 0, 1, 1], bool)
+        got = gdr.gdn_step(pool, *rows, slots, reset, interpret=False)
+        want = gdr.gdn_step_reference(pool, *rows, slots, reset)
+        live = jnp.asarray([0, 1, 3, 5])
+        gdn_case("gdn_step", (got[0][live], got[1][:8]),
+                 (want[0][live], want[1][:8]))
+
+    def gdn_chunk_case():
+        # three sequences over five tiles (2 + 2 + 1, the last 40 rows
+        # short), a pad tile behind them
+        pool, q, k, v, g, beta = gdn_inputs(768, 22)
+        real = (jnp.arange(768) < 600)[:, None]
+        g, beta = jnp.where(real, g, 0.0), jnp.where(real, beta, 0.0)
+        slots = jnp.asarray([2, 2, 6, 6, 0, 8], jnp.int32)
+        reset = jnp.asarray([1, 0, 0, 0, 1, 0], bool)
+        got = gdr.gdn_chunk(pool, q, k, v, g, beta, slots, reset, 128,
+                            interpret=False)
+        want = gdr.gdn_chunk_reference(pool, q, k, v, g, beta, slots, reset,
+                                       128)
+        gdn_case("gdn_chunk", (got[0][:600], got[1][:8]),
+                 (want[0][:600], want[1][:8]))
+
+    guarded("gdn_step", gdn_step_case)
+    guarded("gdn_chunk", gdn_chunk_case)
+
     # ---- int8-resident quantized matmul ---- #
     from deepspeed_tpu.ops.quantized_matmul import (
         dequant_reference, quantized_matmul)
