@@ -340,9 +340,11 @@ def check_put_routes(routes: Dict[str, Dict[str, int]], max_seqs: int,
     """The ``put`` programs of a serving run whose token budget is whole
     tiles: each is a two-segment program (``prefill_T<rows>_tiled``, rows =
     ``max_seqs`` single-token rows + whole tiles), mixed ticks included,
-    and on the chip its tiles go through ``_prefill_kernel`` and nothing
-    of it through the token-grid ``_kernel`` (PR 21 recorded
-    ``prefill_T1024 -> _kernel`` for every tick with a decode in it)."""
+    and on the chip its tiles go through ``_prefill_kernel``, its
+    single-token rows through the decode walk (``_decode_kernel``: every
+    model here has a head size the walk can copy) and nothing of it through
+    the token-grid ``_kernel`` (PR 21 recorded ``prefill_T1024 -> _kernel``
+    for every tick with a decode in it)."""
     puts = {n: r for n, r in routes.items() if n.startswith("prefill_T")}
     tiled = {n: r for n, r in puts.items() if n.endswith("_tiled")}
     if not tiled:
@@ -359,6 +361,10 @@ def check_put_routes(routes: Dict[str, Dict[str, int]], max_seqs: int,
             raise SmokeFailure(
                 f"serve: {name} took the route {route}; its tiles belong "
                 f"to _prefill_kernel and no row to the token-grid _kernel")
+        if "_decode_kernel" not in route:
+            raise SmokeFailure(
+                f"serve: {name} took the route {route}; its single-token "
+                f"rows belong to the decode walk, _decode_kernel")
 
 
 # --------------------------------------------------------------------- #
@@ -535,10 +541,9 @@ def serve_phase(sizes: SmokeSizes, devices, require_chip: bool,
                                                  sizes.new_tokens)) + 1)
                     // bs) * bs
     per_seq = max_context // bs
-    # more than twice the block-table extent, so that decode walks live
-    # blocks through the manual-DMA kernel (a pool this tight against the
-    # tables would take the dense XLA read instead; ragged_llama.py)
-    num_blocks = 2 * sizes.max_seqs * per_seq + per_seq + 1
+    # room for every sequence at its full length: one-token rows walk the
+    # blocks they hold through the manual-DMA kernel at any pool size
+    num_blocks = sizes.max_seqs * per_seq + 1
     eng_cfg = RaggedInferenceEngineConfig.from_dict({
         "state_manager": {"max_ragged_batch_size": sizes.token_budget,
                           "max_ragged_sequence_count": sizes.max_seqs,
@@ -855,6 +860,9 @@ def kernels_phase(_sizes, _devices, _require_chip,
                            f"cases not ok: {bad or results}")
     return {"cases": len(cases),
             "max_err": {k: v["max_err"] for k, v in cases.items()},
+            # share held -> [blocks, decode walk us, dense XLA read us]
+            "decode_read_us": {k: v["us"] for k, v in cases.items()
+                               if "us" in v},
             **clock.take()}
 
 

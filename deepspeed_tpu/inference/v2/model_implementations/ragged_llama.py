@@ -118,21 +118,28 @@ def _paged_attention(q, k_pool, v_pool, batch, block_size,
     first S rows (S = the block table's height) are single-token rows and
     the rest whole tiles: the tiles go to the TILED kernel — grid (tiles,
     blocks), bf16 MXU dots, the reference's atom_builder work-unit shape
-    — and the single-token rows to the read a decode step of this pool
-    takes (:func:`_single_row_read`).  Without it (a token budget that is
+    — and the single-token rows to the read a decode step takes
+    (:func:`_single_row_read`).  Without it (a token budget that is
     no whole number of tiles) the whole buffer goes to the token-grid
     kernel, grid (tokens, blocks).
 
     ``decode_mode`` (static; engine decode programs set it) asserts
-    T == S with ``token_slot == arange(S)``.  On TPU it routes to the
-    O(live-context) manual-DMA decode kernel
-    (:func:`deepspeed_tpu.inference.v2.kernels.paged_decode_attention`)
-    — per-sequence dynamic walk over live block-table entries with
-    double-buffered HBM block DMAs, so the read volume is Σ live-context
-    bytes rather than O(pool) (the round-4 dense default, which becomes
-    the dominant cost at 7B-scale pools) or O(S x table-width).
+    T == S with ``token_slot == arange(S)``.  On TPU, at a head size the
+    DMA walk can copy (``D % 128 == 0``), it routes to the manual-DMA
+    decode kernel
+    (:func:`deepspeed_tpu.inference.v2.kernels.paged_decode_attention`):
+    each row reads exactly the blocks its table holds up to its position,
+    so what the read costs follows what the rows hold (their
+    ``token_pos``), whatever the pool's size.  The device scope of that
+    read is ``attn/dense_read``, the name the XLA dense read below had
+    when it was the cells' route (PERF.md section 7: the benchmark's
+    readers match it).
 
-    The plain XLA gather composition below is the reference/CPU path.
+    Off the kernels (CPU; head sizes the walk cannot copy, the d64
+    families) a decode step takes one of two XLA compositions, which are
+    also the references the kernels are tested against: the masked dense
+    read of the whole pool while the pool is no larger than twice what the
+    tables could hold, the gather bounded by the table extent beyond.
 
     ``k_scale``/``v_scale`` (int8 pools; ``[rows, Hkv]`` fp32) select
     the block-quantized mode: the hot decode/verify Pallas kernels fuse
@@ -143,13 +150,7 @@ def _paged_attention(q, k_pool, v_pool, batch, block_size,
     quantized = k_scale is not None
     if use_kernel is None:
         use_kernel = on_tpu()
-    S, B = batch["block_tables"].shape
-    # the manual-DMA walk wins when the pool is LARGER than the live
-    # contexts (its read is O(live); the dense path's is O(pool):
-    # 4.28 vs 5.77 ms at pool 512 blk / ctx 2k).  Tight pools (pool ~
-    # live, the serving-dense case) keep the dense read, which measured
-    # ~10% faster there.
-    big_pool = k_pool.shape[0] > 2 * S * B * block_size
+    S = batch["block_tables"].shape[0]
     if use_kernel:
         from deepspeed_tpu.inference.v2.kernels import (
             paged_attention, paged_attention_usable,
@@ -160,27 +161,25 @@ def _paged_attention(q, k_pool, v_pool, batch, block_size,
             w = int(window) if window is not None else None
             meta = (batch["block_tables"], batch["token_slot"],
                     batch["token_pos"])
-            if verify_k and q.shape[-1] % 128 == 0:
+            # the manual-DMA walks copy [bs, Hkv, D] pool blocks, whose
+            # lane dim D must be 128-aligned
+            walk = q.shape[-1] % 128 == 0
+            if verify_k and walk:
                 # speculative multi-token verify: K query rows per slot
-                # share one O(live-context) block walk (the fused
-                # multi-query variant of the decode kernel); lane-dim
-                # constraint matches the decode DMA kernel's.  Smaller
-                # head dims fall through to the generic grid kernel,
-                # which handles verify-shaped metadata unchanged.
+                # share one block walk (the fused multi-query variant of
+                # the decode kernel).  Smaller head dims fall through to
+                # the generic grid kernel, which handles verify-shaped
+                # metadata unchanged.
                 return paged_verify_attention(
                     q, k_pool, v_pool, *meta, block_size=block_size,
                     k_tokens=int(verify_k), window=w, k_scale=k_scale,
                     v_scale=v_scale)
             if decode_mode:
-                # the manual-DMA kernel copies [bs, Hkv, D] pool blocks,
-                # whose lane dim D must be 128-aligned.  Quantized pools
-                # ALWAYS take it: the dense path would dequantize the
-                # whole pool, and the capacity regime int8 exists for
-                # (many spooled/idle sessions) is precisely pool >> live.
-                if q.shape[-1] % 128 == 0 and (big_pool or quantized):
-                    return paged_decode_attention(
-                        q, k_pool, v_pool, *meta, block_size=block_size,
-                        window=w, k_scale=k_scale, v_scale=v_scale)
+                if walk:
+                    with jax.named_scope("attn/dense_read"):
+                        return paged_decode_attention(
+                            q, k_pool, v_pool, *meta, block_size=block_size,
+                            window=w, k_scale=k_scale, v_scale=v_scale)
             elif quantized:
                 # prefill kernels are not scale-aware (prefill is
                 # compute-bound — the int8 win is decode bandwidth);
@@ -190,7 +189,7 @@ def _paged_attention(q, k_pool, v_pool, batch, block_size,
                 tables, slot, pos = meta
                 single = _single_row_read(
                     q[:S], k_pool, v_pool, tables, slot[:S], pos[:S],
-                    block_size, w, big_pool)
+                    block_size, w)
                 if q.shape[0] == S:          # no chunk longer than a token
                     return single
                 return jnp.concatenate([single, paged_prefill_attention(
@@ -201,7 +200,7 @@ def _paged_attention(q, k_pool, v_pool, batch, block_size,
                 return paged_attention(
                     q, k_pool, v_pool, *meta, block_size=block_size,
                     window=w)
-    if decode_mode and not big_pool:
+    if decode_mode and not _big_pool(k_pool, batch, block_size):
         with jax.named_scope("attn/dense_read"):
             return _dense_pool_read(q, k_pool, v_pool, k_scale, v_scale,
                                     batch, block_size, window)
@@ -210,35 +209,48 @@ def _paged_attention(q, k_pool, v_pool, batch, block_size,
                             block_size, window, decode_mode)
 
 
+def _big_pool(k_pool, batch, block_size) -> bool:
+    """Off the decode walk only: is the pool larger than twice what the
+    block tables could hold?  Then a read bounded by the table extent
+    (the gather; the token-grid kernel) beats one of every pool row."""
+    S, B = batch["block_tables"].shape
+    return k_pool.shape[0] > 2 * S * B * block_size
+
+
 def _single_row_read(q, k_pool, v_pool, tables, slot, pos, block_size,
-                     window, big_pool):
+                     window):
     """The single-token segment of a two-segment batch on TPU (float
-    pool): the read a decode step of the same pool takes — the manual-DMA
-    walk over live blocks on a big pool, the dense read on a tight one —
-    except that the rows' slots are in no order, which both take, and
-    that a big pool at a head size the DMA walk cannot copy (D % 128)
-    sends these few rows through the token-grid kernel."""
+    pool): the read a decode step takes — the manual-DMA walk over the
+    blocks each row holds, in the device scope ``attn/dense_read`` —
+    except that the rows' slots are in no order and pad rows (position
+    -1) sit between them, which the walk takes.  At a head size the walk
+    cannot copy (D % 128) these few rows go through the token-grid kernel
+    on a big pool and the dense XLA read on a tight one."""
     from deepspeed_tpu.inference.v2.kernels import (paged_attention,
                                                     paged_decode_attention)
 
-    if big_pool:
-        walk = (paged_decode_attention if q.shape[-1] % 128 == 0
-                else paged_attention)
-        return walk(q, k_pool, v_pool, tables, slot, pos,
-                    block_size=block_size, window=window)
+    batch = {"block_tables": tables, "token_slot": slot, "token_pos": pos}
+    walk = q.shape[-1] % 128 == 0
+    if not walk and _big_pool(k_pool, batch, block_size):
+        return paged_attention(q, k_pool, v_pool, tables, slot, pos,
+                               block_size=block_size, window=window)
     with jax.named_scope("attn/dense_read"):
-        return _dense_pool_read(
-            q, k_pool, v_pool, None, None,
-            {"block_tables": tables, "token_slot": slot, "token_pos": pos},
-            block_size, window)
+        if walk:
+            return paged_decode_attention(
+                q, k_pool, v_pool, tables, slot, pos,
+                block_size=block_size, window=window)
+        return _dense_pool_read(q, k_pool, v_pool, None, None, batch,
+                                block_size, window)
 
 
 def _dense_pool_read(q, k_pool, v_pool, k_scale, v_scale, batch, block_size,
                      window):
-    """The one-token-a-row read on a tight pool (device scope
-    ``attn/dense_read``): a decode step's rows, or the single-token rows
-    of a two-segment batch, whose slots are in no order and whose pad rows
-    carry position -1 (they attend nothing and come out finite)."""
+    """The one-token-a-row read off the decode walk, on a tight pool
+    (device scope ``attn/dense_read``): a decode step's rows, or the
+    single-token rows of a two-segment batch, whose slots are in no order
+    and whose pad rows carry position -1 (they attend nothing and come out
+    finite).  The CPU's route, the d64 families' on the chip, and the
+    reference the walk is tested against."""
     from deepspeed_tpu.inference.v2.ragged.kv_cache import dequantize_kv
 
     quantized = k_scale is not None
@@ -247,21 +259,21 @@ def _dense_pool_read(q, k_pool, v_pool, k_scale, v_scale, batch, block_size,
     token_pos = batch["token_pos"]                # [T]
     hkv = k_pool.shape[1]
     group = q.shape[1] // hkv
-    # Masked DENSE attention over the whole pool: when the engine
-    # sizes the pool close to max_seqs * max_context (the serving-
-    # dense case), the live contexts cover most of it, so reading
-    # every pool row ONCE — no [T, C, Hkv, D] gather copy, no Pallas
-    # grid overhead — is the bandwidth-minimal program (measured
-    # 0.46 vs 1.7 ms/step for 12 layers of a 125M-GQA model on
-    # v5e).  Visibility is derived PER TOKEN against that token's
-    # own block table — NOT via a row->owner scatter, which breaks
-    # under the prefix cache where one warm block legitimately sits
-    # in several sequences' tables (last-write-wins ownership would
-    # mask a shared block out of every table but one).  The [T, B,
-    # rows] compare is decode-sized (T == S) and XLA CSE dedupes it
-    # across layers.  Pools much larger than the live contexts
-    # (rows > 2*S*C) take the gather path below instead, which is
-    # bounded by the block-table extent.
+    # Masked DENSE attention over the whole pool: every pool row is read
+    # ONCE, held or not — no [T, C, Hkv, D] gather copy, no Pallas grid
+    # overhead.  At a head size the decode walk cannot copy it is the
+    # cheaper of the two XLA reads while the pool is within twice the
+    # table extent (0.46 vs 1.7 ms/step for 12 layers of a 125M-GQA d64
+    # model on v5e); where the walk runs, the walk is faster at every
+    # share of the pool held (PERF.md section 5, PR 30).  Visibility is
+    # derived PER TOKEN against that token's own block table — NOT via a
+    # row->owner scatter, which breaks under the prefix cache where one
+    # warm block legitimately sits in several sequences' tables
+    # (last-write-wins ownership would mask a shared block out of every
+    # table but one).  The [T, B, rows] compare is decode-sized (T == S)
+    # and XLA CSE dedupes it across layers.  Pools much larger than the
+    # table extent (rows > 2*S*C) take the gather path below instead,
+    # which is bounded by the block-table extent.
     from deepspeed_tpu.inference.v2.ragged.blocked_allocator import (
         BlockedAllocator)
 
@@ -588,9 +600,10 @@ class RaggedLlama:
         cos, sin = _rotary(token_pos, d, cfg.rope_theta)
         new_cache = {}
         # device scopes (op_name of every operation): layers_<i>/attn/qkv
-        # (with its norm), attn/rope_insert, attn/dense_read or
-        # attn/gather_read or a paged_* kernel, attn/out_proj, mlp (with
-        # its norm), then lm_head
+        # (with its norm), attn/rope_insert, attn/dense_read (the read of
+        # one-token rows: the decode walk, or the dense XLA read) or
+        # attn/gather_read or another paged_* kernel, attn/out_proj, mlp
+        # (with its norm), then lm_head
         for i in range(cfg.num_hidden_layers):
             lp = m[f"layers_{i}"]
             with jax.named_scope(f"layers_{i}"):

@@ -45,12 +45,17 @@ span                     site                        parent    attrs (counters)
 ``prefill``              ``_step_traced``: ``put``   tick      —
 ``sample``               after ``put``               tick      —
 ``decode``               ``_fast_decode_tick``: a    tick      closing: ``steps``
-                         pure-decode tick's work:              (1) and ``ahead``
+                         pure-decode tick's work:              (1), ``ahead``
                          the dispatches it makes,              (1 when the step
                          the fetch of the step it              whose tokens the
                          returns, the advance                  tick returns was
                                                                dispatched during
-                                                               the tick before)
+                                                               the tick before),
+                                                               ``read_blocks``
+                                                               (table blocks
+                                                               that step's rows
+                                                               hold: what its
+                                                               attention read)
 ``verify``               ``_speculative_decode_``    tick      —
                          ``tick``
 ``engine/build_batch``   ``_run_one_batch``: the     prefill   ``tokens`` fed of

@@ -603,14 +603,18 @@ class ContinuousBatchScheduler:
         rows (``_same_rows_next_tick``), the step after it is dispatched
         on this step's device-resident ``nxt``; only then are this step's
         tokens fetched and handed out (``_consume``).  The ``decode`` span
-        closes with ``steps`` (1) and ``ahead`` (was the returned step
-        dispatched a tick ago)."""
+        closes with ``steps`` (1), ``ahead`` (was the returned step
+        dispatched a tick ago) and ``read_blocks`` (the table blocks that
+        step's rows hold up to the positions it fed: what its attention
+        read, from the host's own lengths)."""
         self.fast_ticks += 1
         with open_span(self.tracer, "decode") as span:
+            traced = type(span) is SpanHandle
             if not all(r.sampling.greedy for r in packed):
                 logits = self.engine.decode_step(uids, [c[0] for c in chunks])
                 rows = np.asarray(self._fetch(logits),
                                   np.float32)[:len(uids)]
+                held = self._held_blocks(packed) if traced else 0
                 for req in packed:
                     req.fed += 1
                 tokens_out = sample_batch(
@@ -631,11 +635,19 @@ class ContinuousBatchScheduler:
                 except Exception:
                     self._abandon(packed)
                     raise
+                held = self._held_blocks(step.packed) if traced else 0
                 emitted = self._consume(step)
                 ahead = step.ahead
-            if type(span) is SpanHandle:
-                span.attrs = {"ahead": ahead, "steps": 1}
+            if traced:
+                span.attrs = {"ahead": ahead, "steps": 1,
+                              "read_blocks": held}
         return emitted
+
+    def _held_blocks(self, packed) -> int:
+        """Table blocks the rows of a decode step about to be consumed hold
+        up to the position it fed (``fed``, before the step is counted)."""
+        bs = self.engine.state_manager.block_size
+        return sum(r.fed // bs + 1 for r in packed)
 
     def _dispatch_decode(self, uids, packed, tokens,
                          ahead: int) -> _DecodeStep:

@@ -152,7 +152,7 @@ def test_smoke_gates_fail_loudly():
     assert chip_smoke.attention_route(text, True, "step") == \
         {"_fwd_kernel": 1}
     # a mixed tick's program: tiles through _prefill_kernel, single-token
-    # rows through the decode walk (or the dense XLA read), never _kernel
+    # rows through the decode walk, never _kernel or the XLA read
     good = {"decode_step": {"_decode_kernel": 1},
             "prefill_T8_tiled": {"_decode_kernel": 1},
             "prefill_T1032_tiled": {"_decode_kernel": 1,
@@ -164,6 +164,10 @@ def test_smoke_gates_fail_loudly():
                 {"prefill_T1032_tiled": {"_prefill_kernel": 1, "_kernel": 1}},
                 {"prefill_T8_tiled": {"_kernel": 1}}):
         with pytest.raises(chip_smoke.SmokeFailure, match="token-grid"):
+            chip_smoke.check_put_routes({**good, **bad}, 8, True)
+    for bad in ({"prefill_T8_tiled": {}},
+                {"prefill_T1032_tiled": {"_prefill_kernel": 1}}):
+        with pytest.raises(chip_smoke.SmokeFailure, match="decode walk"):
             chip_smoke.check_put_routes({**good, **bad}, 8, True)
     with pytest.raises(chip_smoke.SmokeFailure, match="untiled"):
         chip_smoke.check_put_routes({**good, "prefill_T16": {}}, 8, False)

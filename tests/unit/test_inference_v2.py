@@ -752,9 +752,10 @@ def test_tiled_prefill_kernel_window_matches_xla():
 
 
 @pytest.mark.parametrize("d,nb,single", [
-    (128, 40, "paged_decode_attention"),      # big pool, D % 128 == 0
+    (128, 40, "paged_decode_attention"),      # D % 128 == 0: the walk,
+    (128, 12, "paged_decode_attention"),      # whatever the pool's size
     (16, 40, "paged_attention"),              # big pool, small heads
-    (16, 12, "_dense_pool_read"),             # tight pool
+    (16, 12, "_dense_pool_read"),             # tight pool, small heads
 ])
 def test_two_segment_attention_routes_match_xla(monkeypatch, d, nb, single):
     """``_paged_attention`` on a two-segment buffer: the S single-token rows

@@ -139,7 +139,8 @@ def test_pure_decode_tick_tree(traced):
         steps = kids.get("engine/decode_step", [])
         assert len(preps) == len(steps) <= 2
         counters = kids["decode"][0]["attrs"]
-        assert set(counters) == {"ahead", "steps"} and counters["steps"] == 1
+        assert set(counters) == {"ahead", "steps", "read_blocks"} \
+            and counters["steps"] == 1
         # a step in flight is not dispatched again; one that is not, is
         assert (len(steps) >= 1) == (counters["ahead"] == 0)
         # a counter is recorded once, where something reads it: the live
@@ -176,6 +177,11 @@ def test_decode_span_counts_the_steps_ahead(traced):
     assert dispatched == [2, 0, 2, 0, 2, 0]
     assert sum(dispatched) == len(ticks) == \
         sum(kids["decode"][0]["attrs"]["steps"] for _, kids in ticks)
+    # the table blocks (of 8) the returned step's rows hold at the
+    # positions it fed: r1 at 13, 14 | r1 at 16, 17 beside r2 at 11, 12 |
+    # r1 at 18, 19
+    assert [kids["decode"][0]["attrs"]["read_blocks"] for _, kids in ticks] \
+        == [2, 2, 3 + 2, 3 + 2, 3, 3]
 
 
 def test_mixed_tick_tree(traced):

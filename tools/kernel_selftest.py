@@ -20,6 +20,91 @@ import json
 import sys
 
 
+#: pool blocks, KV heads, group size, head size of the serving cells'
+#: attention pools (Mistral-7B, OLMoE-1B-7B, Qwen3-Next; block size 128)
+DECODE_READ_CELLS = {"mistral7b": (160, 8, 4, 128),
+                     "olmoe": (192, 16, 1, 128),
+                     "qwen3next": (512, 2, 8, 256)}
+
+
+def decode_read_case(cell: str, tol: float, layers: int = 16,
+                     repeats: int = 10) -> dict:
+    """The decode walk (``paged_decode_attention``, compiled) against the
+    XLA dense read (``_dense_pool_read``) on one cell's pool: 32 rows that
+    hold 15%, 50% and 75% of its blocks between them (about three blocks a
+    row at 15%, the rest pads).  ``max_err`` over the three; ``us`` = for
+    each share ``[blocks held, walk, dense read]``, microseconds a call:
+    ``layers`` calls a program, ``repeats`` programs dispatched back to
+    back, host clock around them."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.inference.v2.kernels import paged_decode_attention
+    from deepspeed_tpu.inference.v2.model_implementations.ragged_llama \
+        import _dense_pool_read
+
+    bs, rows_n, width = 128, 32, 36
+    nb, hkv, g, d = DECODE_READ_CELLS[cell]
+    ks = jax.random.split(jax.random.key(30), 3)
+    k_pool = jax.random.normal(ks[0], (nb * bs, hkv, d), jnp.bfloat16)
+    v_pool = jax.random.normal(ks[1], (nb * bs, hkv, d), jnp.bfloat16)
+    q = jax.random.normal(ks[2], (rows_n, hkv * g, d), jnp.bfloat16)
+    slot = jnp.arange(rows_n, dtype=jnp.int32)
+
+    def walk(q, k_pool, v_pool, tables, pos):
+        return paged_decode_attention(q, k_pool, v_pool, tables, slot, pos,
+                                      block_size=bs, interpret=False)
+
+    def dense(q, k_pool, v_pool, tables, pos):
+        batch = {"block_tables": tables, "token_slot": slot,
+                 "token_pos": pos}
+        return _dense_pool_read(q, k_pool, v_pool, None, None, batch, bs,
+                                None)
+
+    def stacked(read):
+        """``layers`` reads with a query of their own each, summed (the
+        pools are arguments: a closed-over pool would be compiled in as a
+        constant)."""
+        def run(q, *rest):
+            return sum(read(q + jnp.asarray(0.01 * i, q.dtype),
+                            *rest).astype(jnp.float32)
+                       for i in range(layers))
+        return jax.jit(run)
+
+    def timed(run, *args):
+        run(*args).block_until_ready()
+        t0 = time.perf_counter()
+        for _ in range(repeats):
+            out = run(*args)
+        out.block_until_ready()
+        return (time.perf_counter() - t0) / repeats / layers * 1e6, out
+
+    walks, denses = stacked(walk), stacked(dense)
+    rng = np.random.default_rng(30)
+    err, us = 0.0, {}
+    for share in (0.15, 0.5, 0.75):
+        held = int(round(share * (nb - 1)))
+        live = min(rows_n, max(1, held // 3))
+        per = np.full(live, held // live)
+        per[:held - per.sum()] += 1
+        tables = np.zeros((rows_n, width), np.int32)
+        pos = np.full(rows_n, -1, np.int32)
+        free = iter(rng.permutation(nb - 1) + 1)
+        for r, n in zip(rng.permutation(rows_n)[:live], per):
+            tables[r, :n] = [next(free) for _ in range(n)]
+            pos[r] = (n - 1) * bs + rng.integers(0, bs)
+        args = (q, k_pool, v_pool, jnp.asarray(tables), jnp.asarray(pos))
+        t_walk, got = timed(walks, *args)
+        t_dense, want = timed(denses, *args)
+        err = max(err, float(jnp.max(jnp.abs(got - want)[pos >= 0]))
+                  / layers)
+        us[str(share)] = [held, round(t_walk, 1), round(t_dense, 1)]
+    return {"max_err": round(err, 6), "ok": bool(err < tol), "us": us}
+
+
 def run_selftest(tol: float = 3e-2) -> dict:
     """Returns {kernel_name: {"max_err": float, "ok": bool}} plus an
     overall "ok". Skips (with a note) off-TPU."""
@@ -208,8 +293,9 @@ def run_selftest(tol: float = 3e-2) -> dict:
         paged_attention(q1, k_pool, v_pool, tables, token_slot, token_pos,
                         block_size=bs, interpret=False), want))
 
-    # O(live-context) manual-DMA decode kernel (the engine decode default
-    # for 128-aligned head dims — its pool-block DMAs need D % 128 == 0)
+    # manual-DMA decode walk over the blocks each row holds (the read of
+    # every one-token row at 128-aligned head dims — its pool-block DMAs
+    # need D % 128 == 0)
     from deepspeed_tpu.inference.v2.kernels import paged_decode_attention
 
     ks2 = jax.random.split(jax.random.fold_in(key, 8), 3)
@@ -310,9 +396,9 @@ def run_selftest(tol: float = 3e-2) -> dict:
 
     # a mixed tick's two-segment batch at Mistral's head counts (32q/8kv,
     # d128) through the route the engine takes: single-token rows (slots
-    # in no order, pads at position -1) by the decode walk on a big pool
-    # and by the dense read on a tight one, tile-aligned chunks by the
-    # tiled kernel
+    # in no order, pads at position -1) by the decode walk, on a pool
+    # larger than the tables could hold and on one smaller, tile-aligned
+    # chunks by the tiled kernel
     from deepspeed_tpu.inference.v2.kernels.blocked_flash import (
         two_segment_case)
 
@@ -322,8 +408,16 @@ def run_selftest(tol: float = 3e-2) -> dict:
 
     guarded("paged_two_segment_walk",
             lambda: two_segment("paged_two_segment_walk", False))
-    guarded("paged_two_segment_dense",
-            lambda: two_segment("paged_two_segment_dense", True))
+    guarded("paged_two_segment_tight",
+            lambda: two_segment("paged_two_segment_tight", True))
+
+    # the decode walk against the XLA dense read at the three serving
+    # cells' pools and head layouts, 32 rows, with the time of each at
+    # 15%, 50% and 75% of the pool held
+    for cell in DECODE_READ_CELLS:
+        guarded("paged_decode_walk_" + cell,
+                lambda c=cell: results.update(
+                    {"paged_decode_walk_" + c: decode_read_case(c, tol)}))
 
     # ---- grouped GEMM fwd + both grads (MoE dropless path) ---- #
     from deepspeed_tpu.ops.grouped_gemm import gmm, gmm_reference
