@@ -23,6 +23,12 @@ seeded random weights:
   different lengths served TOGETHER through the scheduler (the longer one's
   chunks beside the other's decodes), each one's logits against the plain
   float32 reference's own forward (``benchmark/reference/qwen3_next.py``).
+* **mla** (one device) — latent attention at the published Moonlight-16B-A3B
+  widths, three layers deep (the dense layer and two routed ones, 16 of the
+  64 experts held): two requests of different lengths served TOGETHER, the
+  longer one's chunks through the expanded path beside the other's decodes
+  through the absorbed one, each against the float32 expanded-form reference
+  (``benchmark/reference/moonlight.py``).
 * **kernels** — ``tools/kernel_selftest.run_selftest()`` as a gate.
 
 With more than one device visible the same phases run across all of them
@@ -150,6 +156,9 @@ class SmokeSizes:
     gdn_hf: Any = None
     gdn_prompt_lens: Sequence[int] = (1500, 300)
     gdn_new_tokens: Sequence[int] = (6, 12)
+    # the mla phase: the published Moonlight keys (depth and vocabulary as
+    # given); prompts and new tokens as the gdn phase's
+    mla_hf: Any = None
 
 
 def chip_sizes(n_devices: int) -> SmokeSizes:
@@ -176,8 +185,13 @@ def chip_sizes(n_devices: int) -> SmokeSizes:
         # an eighth of the vocabulary slice
         gdn_hf = dict(json.load(f), num_hidden_layers=4, num_experts=16,
                       vocab_size=4748)
+    with open(os.path.join(_HERE, "benchmark", "configs",
+                           "moonlight-16b-a3b-serve-1chip.json")) as f:
+        # every width as published; the dense layer and two routed ones, an
+        # eighth of the vocabulary slice
+        mla_hf = dict(json.load(f), num_hidden_layers=3, vocab_size=5120)
     return SmokeSizes(
-        gdn_hf=gdn_hf,
+        gdn_hf=gdn_hf, mla_hf=mla_hf,
         moe_config=MixtralConfig.olmoe_1b_7b(num_hidden_layers=2,
                                              dtype=jnp.bfloat16),
         model_config=MistralConfig(dtype=jnp.bfloat16),
@@ -786,21 +800,21 @@ def moe_phase(sizes: SmokeSizes, devices, require_chip: bool,
 # --------------------------------------------------------------------- #
 # Phase: linear-attention layers and their state (one device)
 # --------------------------------------------------------------------- #
-def gdn_phase(sizes: SmokeSizes, devices, require_chip: bool,
-              clock: CompileClock) -> Dict[str, Any]:
-    hf = sizes.gdn_hf
-    if hf is None or len(devices) > 1:
-        return {"skipped": "no gdn_hf in these sizes" if hf is None
-                else "one-device phase", **clock.take()}
+def _interleaved_against_reference(sizes: SmokeSizes, hf, family: str):
+    """Two requests of different lengths served TOGETHER through the
+    scheduler on a ``benchmark/families/<family>`` engine at ``hf``'s
+    widths, each one's logits against its own reference forward.  Returns
+    ``(engine, serve_and_compare's result, {program: Mosaic kernels})``."""
     if _HERE not in sys.path:
         sys.path.insert(0, _HERE)
-    from benchmark.families import qwen3_next as family
-    from benchmark.reference import qwen3_next as reference
+    from benchmark.lib import spec
     from benchmark.runners.serve_ragged import make_params
     from benchmark.tools.interleaved_check import serve_and_compare
     from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
                                             RaggedInferenceEngineConfig)
 
+    family = spec.module("families", family)
+    reference = spec.module("reference", family.REFERENCE)
     bs = sizes.block_size
     max_context = -(-(max(p + g for p, g in zip(
         sizes.gdn_prompt_lens, sizes.gdn_new_tokens)) + 1) // bs) * bs
@@ -818,30 +832,72 @@ def gdn_phase(sizes: SmokeSizes, devices, require_chip: bool,
     out = serve_and_compare(engine, reference,
                             family.reference_params(engine.params), hf,
                             prompts, sizes.gdn_new_tokens)
-    kernels = {}
-    for key in engine.step_keys:
-        name = "decode_step" if key == ("decode_step",) else f"T{key[0]}"
-        names = mosaic_kernel_names(engine.lower_step(key).as_text())
-        kernels[name] = sorted(set(names))
-        if require_chip and "_gdn_step_kernel" not in names:
-            raise SmokeFailure(f"gdn: {name} has no Mosaic delta-rule "
-                               f"call: {kernels[name]}")
+    kernels = {
+        "decode_step" if key == ("decode_step",) else f"T{key[0]}":
+        sorted(set(mosaic_kernel_names(engine.lower_step(key).as_text())))
+        for key in engine.step_keys}
+    return engine, out, kernels
+
+
+def _interleaved_summary(phase: str, sizes, out, kernels, devices, clock,
+                         **facts) -> Dict[str, Any]:
     if max(out["gaps"]) > GDN_LOGIT_TOL:
         raise SmokeFailure(
-            f"gdn: logits of interleaved requests differ from their own "
+            f"{phase}: logits of interleaved requests differ from their own "
             f"reference forwards by {out['gaps']} of the largest logit "
             f"(> {GDN_LOGIT_TOL})")
-    pool = engine.state_manager.state_pool
-    if pool.held:
-        raise SmokeFailure(f"gdn: {pool.held} state slots still held "
-                           f"after every request finished")
-    return {"layers": hf["num_hidden_layers"],
-            "experts_held": hf["num_experts"],
-            "prompt_lens": list(sizes.gdn_prompt_lens),
+    return {**facts, "prompt_lens": list(sizes.gdn_prompt_lens),
             "logit_gaps": [round(g, 5) for g in out["gaps"]],
             "rows_compared": out["rows"], "ticks": out["ticks"],
             "kernels": kernels, **clock.take(),
             "memory": memory_report(devices)}
+
+
+def gdn_phase(sizes: SmokeSizes, devices, require_chip: bool,
+              clock: CompileClock) -> Dict[str, Any]:
+    hf = sizes.gdn_hf
+    if hf is None or len(devices) > 1:
+        return {"skipped": "no gdn_hf in these sizes" if hf is None
+                else "one-device phase", **clock.take()}
+    engine, out, kernels = _interleaved_against_reference(
+        sizes, hf, "qwen3_next")
+    for name, names in kernels.items():
+        if require_chip and "_gdn_step_kernel" not in names:
+            raise SmokeFailure(f"gdn: {name} has no Mosaic delta-rule "
+                               f"call: {names}")
+    pool = engine.state_manager.state_pool
+    if pool.held:
+        raise SmokeFailure(f"gdn: {pool.held} state slots still held "
+                           f"after every request finished")
+    return _interleaved_summary(
+        "gdn", sizes, out, kernels, devices, clock,
+        layers=hf["num_hidden_layers"], experts_held=hf["num_experts"])
+
+
+# --------------------------------------------------------------------- #
+# Phase: latent attention and its two paths (one device)
+# --------------------------------------------------------------------- #
+def mla_phase(sizes: SmokeSizes, devices, require_chip: bool,
+              clock: CompileClock) -> Dict[str, Any]:
+    hf = sizes.mla_hf
+    if hf is None or len(devices) > 1:
+        return {"skipped": "no mla_hf in these sizes" if hf is None
+                else "one-device phase", **clock.take()}
+    _engine, out, kernels = _interleaved_against_reference(
+        sizes, hf, "moonlight")
+    for name, names in kernels.items():
+        # a program with a tile segment expands and reads it too
+        want = {"_latent_decode_kernel"} | (
+            {"_latent_expand_kernel", "_latent_prefill_kernel"}
+            if name != "decode_step" and int(name[1:]) > sizes.max_seqs
+            else set())
+        if require_chip and not want <= set(names):
+            raise SmokeFailure(f"mla: {name} lacks Mosaic calls "
+                               f"{sorted(want - set(names))}: {names}")
+    return _interleaved_summary(
+        "mla", sizes, out, kernels, devices, clock,
+        layers=hf["num_hidden_layers"],
+        experts_held=hf["n_routed_experts"])
 
 
 # --------------------------------------------------------------------- #
@@ -867,7 +923,7 @@ def kernels_phase(_sizes, _devices, _require_chip,
 
 
 PHASES = {"train": train_phase, "serve": serve_phase, "moe": moe_phase,
-          "gdn": gdn_phase, "kernels": kernels_phase}
+          "gdn": gdn_phase, "mla": mla_phase, "kernels": kernels_phase}
 
 
 def run(sizes: Optional[SmokeSizes] = None, require_chip: bool = True,

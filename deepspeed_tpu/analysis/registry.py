@@ -34,6 +34,7 @@ KERNEL_MODULES = (
     "deepspeed_tpu.ops.block_sparse_attention",
     "deepspeed_tpu.ops.evoformer_attn",
     "deepspeed_tpu.inference.v2.kernels.blocked_flash",
+    "deepspeed_tpu.inference.v2.kernels.latent_flash",
 )
 
 #: default per-call VMEM budget estimate ceiling — v5e VMEM is 16 MiB;

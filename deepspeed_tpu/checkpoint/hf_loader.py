@@ -244,6 +244,69 @@ def _qwen3_next_rules() -> List[Rule]:
     ]
 
 
+def _deepseek_v3_rope_rows(head_width):
+    """The published DeepSeek-V3 family stores the rotary dims of ``q_proj``
+    (each head's last ``qk_rope_head_dim`` outputs) and of
+    ``kv_a_proj_with_mqa`` (its last ``qk_rope_head_dim`` outputs)
+    INTERLEAVED (x0, y0, x1, y1, ...), and its code regroups them to the
+    rotate-half layout (x0, x1, ..., y0, y1, ...) before every rotation;
+    ``RaggedDeepseekV3`` rotates in that layout, so the regrouping is done
+    once, here.  ``head_width(cfg)`` is the width of one block of outputs
+    whose LAST rope dims are the rotary ones.  HF weight [out, in] ->
+    kernel [in, out]."""
+    def tf(w, cfg):
+        w = np.asarray(w)
+        rope, width = int(cfg["qk_rope_head_dim"]), head_width(cfg)
+        order = np.arange(width)
+        order[width - rope:] = width - rope + np.concatenate(
+            [np.arange(0, rope, 2), np.arange(1, rope, 2)])
+        blocks = w.reshape(-1, width, w.shape[-1])[:, order]
+        return blocks.reshape(w.shape).T
+    return tf
+
+
+def _deepseek_v3_rules() -> List[Rule]:
+    # DeepSeek-V3 family (``model_type: deepseek_v3``; Moonlight) ->
+    # RaggedDeepseekV3's tree
+    def layer(m, *leaf):
+        return (f"layers_{m.group(1)}", *leaf)
+
+    q_rows = _deepseek_v3_rope_rows(
+        lambda c: int(c["qk_nope_head_dim"]) + int(c["qk_rope_head_dim"]))
+    kva_rows = _deepseek_v3_rope_rows(
+        lambda c: int(c["kv_lora_rank"]) + int(c["qk_rope_head_dim"]))
+    backbone = [r for r in _flat_moe_backbone_rules()
+                if "self_attn" not in r[0]]
+    return backbone + [
+        (r"^model\.layers\.(\d+)\.self_attn\.q_proj\.weight$",
+         lambda m: (layer(m, "self_attn", "q_proj", "kernel"), q_rows)),
+        (r"^model\.layers\.(\d+)\.self_attn\.kv_a_proj_with_mqa\.weight$",
+         lambda m: (layer(m, "self_attn", "kv_a_proj_with_mqa", "kernel"),
+                    kva_rows)),
+        (r"^model\.layers\.(\d+)\.self_attn\.kv_a_layernorm\.weight$",
+         lambda m: (layer(m, "self_attn", "kv_a_layernorm", "scale"), None)),
+        (r"^model\.layers\.(\d+)\.self_attn\.(kv_b|o)_proj\.weight$",
+         lambda m: (layer(m, "self_attn", f"{m.group(2)}_proj", "kernel"),
+                    "t")),
+        (r"^model\.layers\.(\d+)\.mlp\.(gate|up|down)_proj\.weight$",
+         lambda m: (layer(m, "mlp", f"{m.group(2)}_proj", "kernel"), "t")),
+        (r"^model\.layers\.(\d+)\.mlp\.gate\.weight$",
+         lambda m: (layer(m, "mlp", "gate", "wg", "kernel"), "t")),
+        (r"^model\.layers\.(\d+)\.mlp\.gate\.e_score_correction_bias$",
+         lambda m: (layer(m, "mlp", "gate", "e_score_correction_bias"),
+                    None)),
+        (r"^model\.layers\.(\d+)\.mlp\.experts\.(\d+)\."
+         r"(gate|up|down)_proj\.weight$",
+         lambda m: (layer(m, "mlp", "experts", f"w_{m.group(3)}"),
+                    ("stack", int(m.group(2))))),
+        (r"^model\.layers\.(\d+)\.mlp\.shared_experts\."
+         r"(gate|up|down)_proj\.weight$",
+         lambda m: (layer(m, "mlp", "shared_expert", f"{m.group(2)}_proj",
+                          "kernel"), "t")),
+        (r".*rotary_emb\.inv_freq$", lambda m: (None, None)),
+    ]
+
+
 def _gpt2_rules() -> List[Rule]:
     # GPT-2 Conv1D weights are already [in, out] — no transpose
     return [
@@ -466,6 +529,7 @@ _ARCH_RULES: Dict[str, Callable[[], List[Rule]]] = {
     "mixtral": _mixtral_rules,
     "olmoe": _olmoe_rules,
     "qwen3_next": _qwen3_next_rules,
+    "deepseek_v3": _deepseek_v3_rules,
     "gpt2": _gpt2_rules,
     "opt": _opt_rules,
     "falcon": _falcon_rules,
@@ -566,6 +630,24 @@ def config_from_hf(model_path: str, dtype: Any = None):
         fields = {f.name for f in dataclasses.fields(Qwen3NextConfig)} \
             - {"dtype"}
         return arch, Qwen3NextConfig(
+            **{k: v for k, v in cfg.items() if k in fields}, dtype=dt)
+    if arch == "deepseek_v3":
+        from deepspeed_tpu.inference.v2.model_implementations. \
+            ragged_deepseek_v3 import DeepseekV3Config
+
+        if cfg.get("rope_scaling") is not None or cfg.get("attention_bias") \
+                or cfg.get("tie_word_embeddings") \
+                or cfg.get("num_nextn_predict_layers"):
+            raise HFLoadError(
+                "deepseek_v3: rope_scaling (and its scaled softmax factor), "
+                "attention_bias, a tied head and a multi-token-prediction "
+                "module are not implemented (Moonlight-16B-A3B sets none "
+                "of them)")
+        fields = {f.name for f in dataclasses.fields(DeepseekV3Config)} \
+            - {"dtype"}
+        # (n_group > 1, q_lora_rank, another scoring_func: the config
+        # refuses each by name)
+        return arch, DeepseekV3Config(
             **{k: v for k, v in cfg.items() if k in fields}, dtype=dt)
     if arch == "gpt2":
         from deepspeed_tpu.models.gpt2 import GPT2Config
@@ -746,7 +828,7 @@ def load_hf_checkpoint(model_path: str, architecture: Optional[str] = None,
     # are ~95% of parameters; buffering them all would hold the whole model
     # on the host, defeating the streaming design).
     n_experts = file_cfg.get("num_local_experts") or \
-        file_cfg.get("num_experts")
+        file_cfg.get("num_experts") or file_cfg.get("n_routed_experts")
 
     def flush_stack(path):
         parts = stacks.pop(path)

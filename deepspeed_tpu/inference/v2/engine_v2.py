@@ -152,7 +152,7 @@ class InferenceEngineV2:
             sm_cfg, kv_cfg, num_layers=model.num_layers,
             num_kv_heads=model.num_kv_heads, head_dim=model.head_dim,
             dtype=getattr(model.config, "dtype", None),
-            state_spec=state_spec)
+            state_spec=state_spec, kv_row=getattr(model, "kv_row", None))
         if self.state_manager.kv_cache.quantized:
             if not getattr(model, "supports_quantized_kv", False):
                 raise ValueError(
@@ -451,13 +451,32 @@ class InferenceEngineV2:
                 # useful tokens of the rows they are padded to
                 span.attrs = {"tokens": self._batch.current_tokens,
                               "bucket": bucket}
+                latent = bool(sm.kv_cache.kv_row)
+                if self._stateful or latent:
+                    # sequences with a chunk in the tile segment here, and
+                    # those chunks' (start, tokens)
+                    tiled = [(s.seen_tokens, n) for s, n in zip(
+                        self._batch.sequences, self._batch.chunk_sizes)
+                        if n > 1]
+                    span.attrs.update(chunk_seqs=len(tiled),
+                                      chunk_tokens=sum(n for _, n in tiled))
                 if self._stateful:
-                    # slots held; sequences with a chunk in the tile
-                    # segment here, and those chunks' tokens
-                    tiled = [n for n in self._batch.chunk_sizes if n > 1]
-                    span.attrs.update(state_slots=sm.state_pool.held,
-                                      chunk_seqs=len(tiled),
-                                      chunk_tokens=sum(tiled))
+                    span.attrs["state_slots"] = sm.state_pool.held
+                if latent:
+                    # what the expanded read must do: the causal (query,
+                    # key) pairs of the chunks, and the context rows to
+                    # expand (each chunk's end position); what the
+                    # absorbed read must: the table blocks the batch's
+                    # one-token rows hold up to the position they feed
+                    bs = sm.block_size
+                    span.attrs.update(
+                        attn_pairs=sum(n * (2 * a + n + 1) // 2
+                                       for a, n in tiled),
+                        ctx_rows=sum(a + n for a, n in tiled),
+                        row_blocks=sum(
+                            s.seen_tokens // bs + 1 for s, n in zip(
+                                self._batch.sequences,
+                                self._batch.chunk_sizes) if n == 1))
         # host↔device alignment: a jax.profiler capture shows this named
         # bracket on the host track lined up with the XLA execution it
         # dispatched
@@ -722,6 +741,12 @@ class InferenceEngineV2:
         self._refuse_stateful(
             "verify_step", "rejected lookahead tokens would have advanced "
             "the state and cannot be rolled back")
+        if self.state_manager.kv_cache.kv_row:
+            raise NotImplementedError(
+                f"verify_step: {type(self.model).__name__} keeps a latent "
+                f"row {self.state_manager.kv_cache.kv_row}; the K-rows-a-"
+                f"sequence verify read exists for per-head keys and values "
+                f"only")
         sm = self.state_manager
         S, B = self._batch.max_seqs, self._max_blocks
         n = len(uids)
@@ -1206,8 +1231,8 @@ class InferenceEngineV2:
                 quantize_groups: int = 64):
         """Serve a real HuggingFace checkpoint directory (reference: the
         MII/engine_factory path that builds a FastGen engine from a HF
-        snapshot).  Llama/Mistral/Mixtral/OLMoE/Qwen3-Next checkpoints supported;
-        with ``mesh`` (a non-trivial 'model' axis) weights land
+        snapshot).  Llama/Mistral/Mixtral/OLMoE/Qwen3-Next and DeepSeek-V3
+        family (Moonlight) checkpoints supported; with ``mesh`` (a non-trivial 'model' axis) weights land
         PRE-SHARDED by the Megatron split rules via
         :func:`shard_ragged_params`'s specs — no full host/device copy.
 
@@ -1259,6 +1284,14 @@ class InferenceEngineV2:
                     "RaggedQwen3Next does not support tensor parallelism "
                     "yet — pass mesh=None")
             model = RaggedQwen3Next(mcfg, block_size)
+        elif arch == "deepseek_v3":
+            from deepspeed_tpu.inference.v2.model_implementations. \
+                ragged_deepseek_v3 import RaggedDeepseekV3
+
+            model = RaggedDeepseekV3(
+                mcfg, block_size,
+                mesh=mesh if mesh is not None
+                and mesh.shape.get("model", 1) > 1 else None)
         else:
             raise ValueError(
                 f"FastGen has no ragged model for architecture {arch!r}")

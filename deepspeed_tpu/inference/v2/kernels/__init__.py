@@ -8,6 +8,13 @@ from deepspeed_tpu.inference.v2.kernels.blocked_flash import (
     paged_verify_attention,
 )
 
-__all__ = ["paged_attention", "paged_attention_usable",
+from deepspeed_tpu.inference.v2.kernels.latent_flash import (
+    latent_decode_attention,
+    latent_expand,
+    latent_prefill_attention,
+)
+
+__all__ = ["latent_decode_attention", "latent_expand",
+           "latent_prefill_attention", "paged_attention", "paged_attention_usable",
            "paged_decode_attention", "paged_prefill_attention",
            "paged_verify_attention"]

@@ -16,6 +16,10 @@ from deepspeed_tpu.inference.v2.model_implementations.ragged_mixtral import (
 from deepspeed_tpu.inference.v2.model_implementations.ragged_opt import (
     RaggedOPT,
 )
+from deepspeed_tpu.inference.v2.model_implementations.ragged_deepseek_v3 import (
+    DeepseekV3Config,
+    RaggedDeepseekV3,
+)
 from deepspeed_tpu.inference.v2.model_implementations.ragged_qwen3_next import (
     Qwen3NextConfig,
     RaggedQwen3Next,
@@ -26,6 +30,7 @@ from deepspeed_tpu.inference.v2.model_implementations.ragged_qwen3_next import (
 # mistral/ container reuses the llama modules the same way)
 RaggedMistral = RaggedLlama
 
-__all__ = ["Qwen3NextConfig", "RaggedLlama", "RaggedMistral", "RaggedMixtral",
+__all__ = ["DeepseekV3Config", "RaggedDeepseekV3", "Qwen3NextConfig",
+           "RaggedLlama", "RaggedMistral", "RaggedMixtral",
            "RaggedOPT", "RaggedFalcon", "RaggedQwen3Next",
            "ragged_param_specs", "shard_ragged_params"]

@@ -53,23 +53,32 @@ from deepspeed_tpu.inference.v2.model_implementations.ragged_llama import (
 from deepspeed_tpu.models.mixtral import MixtralConfig
 
 
-def moe_router(x, wg, k: int, renormalize: bool = True):
+def moe_router(x, wg, k: int, renormalize: bool = True, bias=None,
+               routed_scale: float = 1.0):
     """Router of the dropless MoE: ``x`` [T, H] (normed) x ``wg`` [H, E] in
     float32 -> (topi [T, k] int32, weights [T, k] float32).  Float32
     products as well as sums: on a TPU a float32 matmul at the default
     precision rounds its operands to bf16, which changes nothing for a bf16
     engine (its activations and weights are bf16 values already) and flips
-    routings on near ties for a float32 one."""
-    from deepspeed_tpu.ops.grouped_gemm import exact_topk_routing
+    routings on near ties for a float32 one.  Softmax then top-k; with a
+    selection ``bias`` [E] (static: the router's parameters carry one) the
+    sigmoid router of the DeepSeek-V3 family, its weights times
+    ``routed_scale``."""
+    from deepspeed_tpu.ops.grouped_gemm import (exact_topk_routing,
+                                                sigmoid_bias_topk_routing)
 
     with jax.named_scope("moe/router"):
         logits = jnp.matmul(x.astype(jnp.float32), wg.astype(jnp.float32),
                             precision=jax.lax.Precision.HIGHEST)  # [T, E]
+        if bias is not None:
+            return sigmoid_bias_topk_routing(logits, bias, k, renormalize,
+                                             routed_scale)
         return exact_topk_routing(logits, k, renormalize)
 
 
 def dropless_moe(x, moe_params, k: int, dtype, grouped=None,
-                 renormalize: bool = True, expert_start: int = 0):
+                 renormalize: bool = True, expert_start: int = 0,
+                 routed_scale: float = 1.0):
     """Dropless top-k MoE over a flat token buffer.
 
     x: [T, H]; returns [T, H]. Router math in fp32 (reference TopKGate is
@@ -89,14 +98,20 @@ def dropless_moe(x, moe_params, k: int, dtype, grouped=None,
     result is the part of the sum that the held experts
     ``[expert_start, expert_start + held)`` give; a token routed wholly
     elsewhere gets zeros.  With every expert held this is the path above,
-    unchanged.  A gated shared expert (``shared_expert`` and
-    ``shared_expert_gate`` in ``moe_params``) is added for every token.
+    unchanged.  A shared expert (``shared_expert`` in ``moe_params``; gated
+    where ``shared_expert_gate`` is there too) is added for every token.
+    A router whose parameters carry ``e_score_correction_bias`` is the
+    sigmoid router with a selection bias, its weights times
+    ``routed_scale`` (static).
     """
     from deepspeed_tpu.ops.grouped_gemm import grouped_moe_ffn
 
     wg = moe_params["gate"]["wg"]["kernel"]            # [H, E]
     experts = moe_params["experts"]
-    topi, w = moe_router(x, wg, k, renormalize)        # [T, k]
+    topi, w = moe_router(
+        x, wg, k, renormalize,
+        bias=moe_params["gate"].get("e_score_correction_bias"),
+        routed_scale=routed_scale)                     # [T, k]
     e_count = wg.shape[1]
     w_gate = experts["w_gate"].astype(dtype)           # [E, H, F]
     w_up = experts["w_up"].astype(dtype)
@@ -124,12 +139,15 @@ def dropless_moe(x, moe_params, k: int, dtype, grouped=None,
 
 def _shared_expert(x, moe_params, dtype):
     """The expert every token takes (device scope ``moe/shared``):
-    ``sigmoid(x . w_sg) * down(silu(gate x) * up x)``."""
+    ``down(silu(gate x) * up x)``, times ``sigmoid(x . w_sg)`` where the
+    parameters carry that gate (static)."""
     with jax.named_scope("moe/shared"):
         se = moe_params["shared_expert"]
         hmid = jax.nn.silu(x @ se["gate_proj"]["kernel"].astype(dtype)) \
             * (x @ se["up_proj"]["kernel"].astype(dtype))
         y = hmid @ se["down_proj"]["kernel"].astype(dtype)
+        if "shared_expert_gate" not in moe_params:
+            return y
         sg = jax.nn.sigmoid(
             x.astype(jnp.float32)
             @ moe_params["shared_expert_gate"]["kernel"].astype(jnp.float32))

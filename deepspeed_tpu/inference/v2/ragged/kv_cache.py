@@ -20,6 +20,12 @@ scales together with zero special cases, and a restored block is
 bit-exact.  Prefill/decode writes quantize on cache insert
 (:func:`quantize_kv`); dequant happens in-kernel on the block walk
 (``kernels/blocked_flash.py``), never as a separate materialized pass.
+
+**A model-stated row** (``kv_row``, e.g. ``{"ckv": 640}`` for latent
+attention): instead of ``k``/``v`` per KV head a layer holds the named
+leaves ``[num_blocks * block_size, lanes]`` behind the same allocator and
+block tables.  Every block operation is a ``tree_map`` over pool rows and
+carries such a row unchanged; int8 mode (a scale per KV head) refuses it.
 """
 
 from __future__ import annotations
@@ -76,7 +82,7 @@ def dequantize_kv(q: jnp.ndarray, scale: jnp.ndarray,
 class BlockedKVCache:
     def __init__(self, num_layers: int, num_blocks: int, block_size: int,
                  num_kv_heads: int, head_dim: int, dtype: Any = jnp.bfloat16,
-                 kv_layers=None):
+                 kv_layers=None, kv_row=None):
         #: the layers that hold keys and values (all of them, unless the
         #: model says which: its other layers keep state in slots, see
         #: ``state_pool.py``, and their leaves join ``cache`` beside these)
@@ -91,9 +97,20 @@ class BlockedKVCache:
         self.dtype = dtype
         #: int8 pools carry per-row/per-head fp32 scale records in-tree
         self.quantized = jnp.dtype(dtype) == jnp.dtype(jnp.int8)
+        #: leaf name -> lanes of the row a layer keeps per token, for a
+        #: model that states one (see the module doc); None: k and v
+        self.kv_row = dict(kv_row) if kv_row else None
+        if self.kv_row and self.quantized:
+            raise NotImplementedError(
+                f"kv_cache.dtype=int8 on a model-stated row {self.kv_row}: "
+                f"quantize_kv keeps one scale per KV head, and a latent "
+                f"row has no head to scale by")
         flat = num_blocks * block_size
 
         def layer():
+            if self.kv_row:
+                return {name: jnp.zeros((flat, lanes), dtype)
+                        for name, lanes in self.kv_row.items()}
             leaves = {
                 "k": jnp.zeros((flat, num_kv_heads, head_dim), dtype),
                 "v": jnp.zeros((flat, num_kv_heads, head_dim), dtype),
@@ -178,6 +195,8 @@ class BlockedKVCache:
         per (row, head), so occupancy gauges and the roofline decode
         bytes model never over-report bf16 bytes under quantization."""
         itemsize = jnp.dtype(self.dtype).itemsize
+        if self.kv_row:
+            return len(self.kv_layers) * sum(self.kv_row.values()) * itemsize
         per_head = self.head_dim * itemsize
         if self.quantized:
             per_head += 4                       # fp32 scale per (row, head)

@@ -39,7 +39,7 @@ class DSStateManager:
     def __init__(self, config: DSStateManagerConfig,
                  kv_config: KVCacheConfig,
                  num_layers: int, num_kv_heads: int, head_dim: int,
-                 dtype=None, state_spec=None):
+                 dtype=None, state_spec=None, kv_row=None):
         self.config = config
         self.kv_config = kv_config
         self.block_size = kv_config.block_size
@@ -62,6 +62,8 @@ class DSStateManager:
         #: and values (``{"layers": [...], "leaves": {name: (shape,
         #: dtype)}}``): as many as sequences one forward can hold
         self.state_pool: Optional[StateSlotPool] = None
+        if kv_row:          # the model states its pool row (latent attention)
+            kwargs["kv_row"] = kv_row
         if state_spec is not None:
             self.state_pool = StateSlotPool(
                 config.max_ragged_sequence_count, state_spec["layers"],

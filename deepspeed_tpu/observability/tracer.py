@@ -68,7 +68,21 @@ span                     site                        parent    attrs (counters)
                                                                with a chunk in
                                                                the tile segment
                                                                and their
-                                                               ``chunk_tokens``
+                                                               ``chunk_tokens``;
+                                                               with a latent
+                                                               row: those two,
+                                                               ``attn_pairs``
+                                                               (causal query-key
+                                                               pairs of those
+                                                               chunks) and
+                                                               ``ctx_rows``
+                                                               (their end
+                                                               positions: rows
+                                                               to expand),
+                                                               ``row_blocks``
+                                                               (table blocks its
+                                                               one-token rows
+                                                               hold)
 ``engine/ragged_step``   the step's dispatch         prefill   —
 ``engine/fetch_logits``  ``device_get(logits)``      prefill   —
 ``engine/decode_prep``   ``decode_step``: KV slots,  decode    ``seqs``: live

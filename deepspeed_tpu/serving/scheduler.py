@@ -169,6 +169,11 @@ class ContinuousBatchScheduler:
                     "verify_step, which a model with per-sequence "
                     "recurrent state refuses: rejected lookahead tokens "
                     "cannot be rolled back out of the state")
+            if getattr(engine.state_manager.kv_cache, "kv_row", None):
+                raise NotImplementedError(
+                    "speculative decoding verifies drafts through "
+                    "verify_step, which reads per-head keys and values: "
+                    "a model that keeps a latent row refuses it")
             if not fast_decode:
                 raise ValueError(
                     "speculative decoding runs on the fast decode tick — "

@@ -142,6 +142,38 @@ def test_smoke_gdn_phase_tiny_on_one_cpu_device(monkeypatch):
         _tiny_sizes(), jax.devices()[:1], False, chip_smoke.CompileClock())
 
 
+def test_smoke_mla_phase_tiny_on_one_cpu_device(monkeypatch):
+    """The latent-attention phase's control flow at a tiny Moonlight-shaped
+    size: two requests interleaved through the scheduler (the longer one in
+    two chunks through the expanded composition, decodes through the
+    absorbed one), each against its own reference forward.  bf16 against
+    float32 at hidden 64 flips routings (as the gdn phase's test says); the
+    float32 parity is ``test_ragged_deepseek_v3.py``'s."""
+    monkeypatch.setattr(chip_smoke, "GDN_LOGIT_TOL", 0.5)
+    hf = {"model_type": "deepseek_v3", "vocab_size": 256, "hidden_size": 64,
+          "intermediate_size": 96, "moe_intermediate_size": 32,
+          "num_hidden_layers": 3, "num_attention_heads": 4,
+          "kv_lora_rank": 32, "q_lora_rank": None, "qk_nope_head_dim": 16,
+          "qk_rope_head_dim": 8, "v_head_dim": 16, "n_routed_experts": 4,
+          "router_experts": 8, "expert_start": 2, "n_shared_experts": 2,
+          "num_experts_per_tok": 3, "first_k_dense_replace": 1,
+          "moe_layer_freq": 1, "n_group": 1, "topk_group": 1,
+          "norm_topk_prob": True, "routed_scaling_factor": 2.446,
+          "scoring_func": "sigmoid", "topk_method": "noaux_tc",
+          "rope_theta": 50000, "rms_norm_eps": 1e-5,
+          "max_position_embeddings": 512}
+    sizes = dataclasses.replace(
+        _tiny_sizes(), token_budget=128, max_seqs=4, block_size=16,
+        mla_hf=hf, gdn_prompt_lens=(150, 40), gdn_new_tokens=(4, 7))
+    out = chip_smoke.mla_phase(sizes, jax.devices()[:1], False,
+                               chip_smoke.CompileClock())
+    assert max(out["logit_gaps"]) <= chip_smoke.GDN_LOGIT_TOL
+    assert min(out["rows_compared"]) >= 4
+    assert "decode_step" in out["kernels"]
+    assert "skipped" in chip_smoke.mla_phase(
+        _tiny_sizes(), jax.devices()[:1], False, chip_smoke.CompileClock())
+
+
 def test_smoke_gates_fail_loudly():
     """The checks that tell a chip run from a CPU or interpreter run."""
     with pytest.raises(chip_smoke.SmokeFailure, match="XLA composition"):

@@ -499,6 +499,9 @@ _KERNEL_ENTRIES = [
     ("paged_verify_multiquery", "_verify_kernel"),
     ("paged_two_segment", "_decode_kernel"),
     ("paged_two_segment", "_prefill_kernel"),
+    ("latent_decode_dma", "_latent_decode_kernel"),
+    ("latent_expand_prefill", "_latent_expand_kernel"),
+    ("latent_expand_prefill", "_latent_prefill_kernel"),
     ("gmm_fwd", "_gmm_kernel"),
     ("gmm_dlhs", "_gmm_dlhs_kernel"),
     ("gmm_drhs", "_gmm_drhs_kernel"),
@@ -542,7 +545,7 @@ def test_every_pallas_call_site_is_covered():
         src = (root.parent / (mod.replace(".", "/") + ".py")).read_text()
         sites += len(re.findall(r"pl\.pallas_call\(", src))
         named += len(re.findall(r"\*\*kernel_names\(", src))
-    assert sites == named == 24
+    assert sites == named == 27
 
 
 def test_paged_wrappers_keep_their_instruction_names():

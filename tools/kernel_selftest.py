@@ -27,6 +27,32 @@ DECODE_READ_CELLS = {"mistral7b": (160, 8, 4, 128),
                      "qwen3next": (512, 2, 8, 256)}
 
 
+def _stacked(read, layers: int):
+    """``layers`` reads with a query of their own each, summed (the pools
+    are arguments: a closed-over pool would be compiled in as a constant)."""
+    import jax
+    import jax.numpy as jnp
+
+    def run(q, *rest):
+        return sum(read(q + jnp.asarray(0.01 * i, q.dtype),
+                        *rest).astype(jnp.float32)
+                   for i in range(layers))
+    return jax.jit(run)
+
+
+def _timed(run, layers: int, repeats: int, *args):
+    """(microseconds a read, the last result): ``repeats`` programs of
+    ``layers`` reads dispatched back to back, host clock around them."""
+    import time
+
+    run(*args).block_until_ready()
+    t0 = time.perf_counter()
+    for _ in range(repeats):
+        out = run(*args)
+    out.block_until_ready()
+    return (time.perf_counter() - t0) / repeats / layers * 1e6, out
+
+
 def decode_read_case(cell: str, tol: float, layers: int = 16,
                      repeats: int = 10) -> dict:
     """The decode walk (``paged_decode_attention``, compiled) against the
@@ -36,8 +62,6 @@ def decode_read_case(cell: str, tol: float, layers: int = 16,
     each share ``[blocks held, walk, dense read]``, microseconds a call:
     ``layers`` calls a program, ``repeats`` programs dispatched back to
     back, host clock around them."""
-    import time
-
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -64,25 +88,7 @@ def decode_read_case(cell: str, tol: float, layers: int = 16,
         return _dense_pool_read(q, k_pool, v_pool, None, None, batch, bs,
                                 None)
 
-    def stacked(read):
-        """``layers`` reads with a query of their own each, summed (the
-        pools are arguments: a closed-over pool would be compiled in as a
-        constant)."""
-        def run(q, *rest):
-            return sum(read(q + jnp.asarray(0.01 * i, q.dtype),
-                            *rest).astype(jnp.float32)
-                       for i in range(layers))
-        return jax.jit(run)
-
-    def timed(run, *args):
-        run(*args).block_until_ready()
-        t0 = time.perf_counter()
-        for _ in range(repeats):
-            out = run(*args)
-        out.block_until_ready()
-        return (time.perf_counter() - t0) / repeats / layers * 1e6, out
-
-    walks, denses = stacked(walk), stacked(dense)
+    walks, denses = _stacked(walk, layers), _stacked(dense, layers)
     rng = np.random.default_rng(30)
     err, us = 0.0, {}
     for share in (0.15, 0.5, 0.75):
@@ -97,12 +103,119 @@ def decode_read_case(cell: str, tol: float, layers: int = 16,
             tables[r, :n] = [next(free) for _ in range(n)]
             pos[r] = (n - 1) * bs + rng.integers(0, bs)
         args = (q, k_pool, v_pool, jnp.asarray(tables), jnp.asarray(pos))
-        t_walk, got = timed(walks, *args)
-        t_dense, want = timed(denses, *args)
+        t_walk, got = _timed(walks, layers, repeats, *args)
+        t_dense, want = _timed(denses, layers, repeats, *args)
         err = max(err, float(jnp.max(jnp.abs(got - want)[pos >= 0]))
                   / layers)
         us[str(share)] = [held, round(t_walk, 1), round(t_dense, 1)]
     return {"max_err": round(err, 6), "ok": bool(err < tol), "us": us}
+
+
+def latent_read_case(tol: float, layers: int = 13, repeats: int = 10) -> dict:
+    """The latent decode walk (``latent_decode_attention``, compiled)
+    against the absorbed XLA composition on the Moonlight cell's pool
+    (2,560 blocks of 128 rows of 640 lanes, 16 heads, tables 60 wide): 64
+    rows (eight of them pads) that hold 15%, 50% and 75% of its blocks
+    between them.  ``us`` as
+    :func:`decode_read_case`: ``[blocks held, walk, XLA gather read]``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.inference.v2.kernels.latent_flash import (
+        latent_decode_attention)
+    from deepspeed_tpu.inference.v2.model_implementations. \
+        ragged_deepseek_v3 import absorbed_read_xla
+
+    bs, rows_n, width, nb, h, w, rank = 128, 64, 60, 2560, 16, 640, 512
+    scale = 192 ** -0.5
+    ks = jax.random.split(jax.random.key(31), 2)
+    pool = jax.random.normal(ks[0], (nb * bs, w), jnp.bfloat16)
+    q = jax.random.normal(ks[1], (rows_n, h, w), jnp.bfloat16) * 0.2
+    slot = jnp.arange(rows_n, dtype=jnp.int32)
+
+    def walk(q, pool, tables, pos):
+        return latent_decode_attention(q, pool, tables, slot, pos,
+                                       block_size=bs, value_dim=rank,
+                                       scale=scale, interpret=False)
+
+    def gather(q, pool, tables, pos):
+        return absorbed_read_xla(q, pool, tables, slot, pos, bs, rank, scale)
+
+    walks, gathers = _stacked(walk, layers), _stacked(gather, layers)
+    rng = np.random.default_rng(31)
+    err, us = 0.0, {}
+    for share in (0.15, 0.5, 0.75):
+        held = int(round(share * (nb - 1)))
+        live = rows_n - 8                   # eight pad rows among them
+        per = np.full(live, held // live)
+        per[:held - per.sum()] += 1
+        tables = np.zeros((rows_n, width), np.int32)
+        pos = np.full(rows_n, -1, np.int32)
+        free = iter(rng.permutation(nb - 1) + 1)
+        for r, n in zip(rng.permutation(rows_n)[:live], per):
+            tables[r, :n] = [next(free) for _ in range(n)]
+            pos[r] = (n - 1) * bs + rng.integers(0, bs)
+        args = (q, pool, jnp.asarray(tables), jnp.asarray(pos))
+        t_walk, got = _timed(walks, layers, repeats, *args)
+        t_gather, want = _timed(gathers, layers, repeats, *args)
+        err = max(err, float(jnp.max(jnp.abs(got - want)[pos >= 0]))
+                  / layers)
+        us[str(share)] = [held, round(t_walk, 1), round(t_gather, 1)]
+    return {"max_err": round(err, 6), "ok": bool(err < tol), "us": us}
+
+
+def latent_prefill_case():
+    """One tile segment (three chunks: a 300-token chunk from position 200,
+    a 128-token one from 0, a 70-token tail from 400; a pad tile) through
+    the expand and prefill kernels (compiled) and through the expanded XLA
+    composition: ``(got, want)`` over the real rows."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.inference.v2.kernels.latent_flash import (
+        latent_expand, latent_prefill_attention)
+    from deepspeed_tpu.inference.v2.model_implementations. \
+        ragged_deepseek_v3 import expanded_read_xla
+
+    bs, tile, s_count, b = 128, 128, 8, 4
+    h, rank, nope, rope, vd, w = 16, 512, 128, 64, 128, 640
+    scale = (nope + rope) ** -0.5
+    ks = jax.random.split(jax.random.key(32), 4)
+    pool = jax.random.normal(ks[0], ((s_count * b + 1) * bs, w),
+                             jnp.bfloat16)
+    pool = pool.at[:, rank + rope:].set(0)
+    w_kvb = (jax.random.normal(ks[1], (rank, h * (nope + vd)), jnp.float32)
+             * rank ** -0.5).astype(jnp.bfloat16)
+    rng = np.random.default_rng(32)
+    tables = jnp.asarray(
+        (rng.permutation(s_count * b) + 1).reshape(s_count, b), jnp.int32)
+    t_rows = 6 * tile
+    slot = np.zeros((t_rows,), np.int32)
+    pos = np.full((t_rows,), -1, np.int32)
+    for start, seq, first, n in ((0, 5, 200, 300), (384, 2, 0, 128),
+                                 (512, 7, 400, 70)):
+        slot[start:start + n], pos[start:start + n] = seq, np.arange(
+            first, first + n)
+    q_nope = jax.random.normal(ks[2], (t_rows, h, nope), jnp.bfloat16)
+    q_pe = jax.random.normal(ks[3], (t_rows, h, rope), jnp.bfloat16)
+    slot, pos = jnp.asarray(slot), jnp.asarray(pos)
+    kv, plan = latent_expand(pool, w_kvb, tables, slot, pos, block_size=bs,
+                             tile_q=tile, rank=rank, interpret=False)
+    q_cat = jnp.concatenate(
+        [q_nope, q_pe, jnp.zeros((t_rows, h, 128 - rope), jnp.bfloat16)], -1)
+    got = latent_prefill_attention(q_cat, kv, plan, pos, block_size=bs,
+                                   tile_q=tile, nope=nope, v_dim=vd,
+                                   scale=scale, interpret=False)
+    # the composition expands every ROW's context: a tile of rows a call
+    ref = jax.jit(expanded_read_xla, static_argnums=(7, 8, 9))
+    want = jnp.concatenate([
+        ref(q_nope[i:i + tile], q_pe[i:i + tile], pool, w_kvb, tables,
+            slot[i:i + tile], pos[i:i + tile], bs, rank, scale)
+        for i in range(0, t_rows, tile)])
+    real = np.asarray(pos) >= 0
+    return got[real], want[real]
 
 
 def run_selftest(tol: float = 3e-2) -> dict:
@@ -418,6 +531,14 @@ def run_selftest(tol: float = 3e-2) -> dict:
         guarded("paged_decode_walk_" + cell,
                 lambda c=cell: results.update(
                     {"paged_decode_walk_" + c: decode_read_case(c, tol)}))
+
+    # the latent decode walk (absorbed form, one stream) against its XLA
+    # composition at the Moonlight cell's pool, timed at the same shares,
+    # and the expand + prefill kernels against the expanded composition
+    guarded("latent_decode_walk", lambda: results.update(
+        {"latent_decode_walk": latent_read_case(tol)}))
+    guarded("latent_prefill", lambda: record(
+        "latent_prefill", *latent_prefill_case()))
 
     # ---- grouped GEMM fwd + both grads (MoE dropless path) ---- #
     from deepspeed_tpu.ops.grouped_gemm import gmm, gmm_reference
