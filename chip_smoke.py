@@ -919,6 +919,8 @@ def kernels_phase(_sizes, _devices, _require_chip,
             # share held -> [blocks, decode walk us, dense XLA read us]
             "decode_read_us": {k: v["us"] for k, v in cases.items()
                                if "us" in v},
+            # cell.call -> shape, tiles, live units of units, us, least us
+            "gmm_share": cases.get("gmm_share", {}).get("calls"),
             **clock.take()}
 
 

@@ -23,10 +23,16 @@ the kernel enumerates a fixed worst-case list of work units — one per
 BLOCK-SPEC INDEX MAPS: each work unit DMAs exactly the lhs m-tile and the
 rhs slice of ITS group.  Rows of a shared boundary tile are masked by the
 group's row range, so every output row is written by exactly one work
-unit.  The same metadata drives the two backward kernels (dlhs
-accumulates over n-tiles; drhs is the "tgmm" — per-group lhsᵀ@dout
-accumulated over the group's work units), wired as a ``custom_vjp`` so
-dropless MoE TRAINING differentiates through the kernel.
+unit.  The list is as long as the worst case; the units past the ones a
+call needs (``num_work``: most of the list when the matrices are a share
+of a wider router's experts and the groups sum to a fraction of ``M``)
+name the last live unit's blocks with an empty row range and are SKIPPED:
+the kernels test ``row_end > row_start`` and run no MXU pass and no store
+for them, so such a unit costs a grid step and nothing else (no DMA
+either: its block indices did not change).  The same metadata drives the
+two backward kernels (dlhs accumulates over n-tiles; drhs is the "tgmm" —
+per-group lhsᵀ@dout accumulated over the group's work units), wired as a
+``custom_vjp`` so dropless MoE TRAINING differentiates through the kernel.
 
 All accumulation is fp32 in VMEM scratch regardless of input dtype.
 """
@@ -67,8 +73,9 @@ def make_group_metadata(group_sizes: jnp.ndarray, m: int, tile_m: int):
     num_work = work_end[-1]
     # invalid (>= num_work) units DUPLICATE the last valid unit (same
     # group, same m-tile — so they never trigger an init/flush boundary
-    # in any kernel) but get an EMPTY row range, so their contribution is
-    # masked to zero everywhere
+    # in any kernel, and the pipeline moves no block for them) but get an
+    # EMPTY row range, which every kernel reads as "skip": no dot, no
+    # store
     idx_c = jnp.minimum(idx, jnp.maximum(num_work - 1, 0))
     group_ids = jnp.searchsorted(work_end, idx_c, side="right").astype(
         jnp.int32)
@@ -88,19 +95,24 @@ def _gmm_kernel(group_ids, m_tile_ids, row_start, row_end, lhs_ref,
                 rhs_ref, out_ref, *, tile_m: int):
     w = pl.program_id(1)
     mt = m_tile_ids[w]
-    rows = mt * tile_m + jax.lax.broadcasted_iota(
-        jnp.int32, (tile_m, 1), 0)
-    keep = (rows >= row_start[w]) & (rows < row_end[w])
 
     # first work unit visiting this m-tile initialises the output block
     @pl.when(jnp.logical_or(w == 0, m_tile_ids[w - 1] != mt))
     def _():
         out_ref[:] = jnp.zeros_like(out_ref)
 
-    partial = jax.lax.dot_general(
-        lhs_ref[:], rhs_ref[0], (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
-    out_ref[:] = jnp.where(keep, partial.astype(out_ref.dtype), out_ref[:])
+    # a unit past ``num_work`` holds no rows: no MXU pass, no store (its
+    # blocks are the last live unit's, so nothing was moved for it either)
+    @pl.when(row_end[w] > row_start[w])
+    def _():
+        rows = mt * tile_m + jax.lax.broadcasted_iota(
+            jnp.int32, (tile_m, 1), 0)
+        keep = (rows >= row_start[w]) & (rows < row_end[w])
+        partial = jax.lax.dot_general(
+            lhs_ref[:], rhs_ref[0], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        out_ref[:] = jnp.where(keep, partial.astype(out_ref.dtype),
+                               out_ref[:])
 
 
 @functools.partial(jax.jit, static_argnames=("tile_m", "tile_n",
@@ -155,27 +167,32 @@ def _gmm_dlhs_kernel(group_ids, m_tile_ids, row_start, row_end, dout_ref,
     j = pl.program_id(1)
     mt = m_tile_ids[w]
 
+    live = row_end[w] > row_start[w]     # a unit past num_work is skipped
+
     @pl.when(j == 0)
     def _():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    # [tm, tn] @ [K, tn]^T -> [tm, K]
-    acc_ref[:] += jax.lax.dot_general(
-        dout_ref[:], rhs_ref[0], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)
+    @pl.when(live)
+    def _():
+        # [tm, tn] @ [K, tn]^T -> [tm, K]
+        acc_ref[:] += jax.lax.dot_general(
+            dout_ref[:], rhs_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
     @pl.when(j == n_tiles - 1)
     def _():
-        rows = mt * tile_m + jax.lax.broadcasted_iota(
-            jnp.int32, (tile_m, 1), 0)
-        keep = (rows >= row_start[w]) & (rows < row_end[w])
-
         @pl.when(jnp.logical_or(w == 0, m_tile_ids[w - 1] != mt))
         def _():
             out_ref[:] = jnp.zeros_like(out_ref)
 
-        out_ref[:] = jnp.where(keep, acc_ref[:].astype(out_ref.dtype),
-                               out_ref[:])
+        @pl.when(live)
+        def _():
+            rows = mt * tile_m + jax.lax.broadcasted_iota(
+                jnp.int32, (tile_m, 1), 0)
+            keep = (rows >= row_start[w]) & (rows < row_end[w])
+            out_ref[:] = jnp.where(keep, acc_ref[:].astype(out_ref.dtype),
+                                   out_ref[:])
 
 
 @functools.partial(jax.jit, static_argnames=("tile_m", "tile_n",
@@ -232,14 +249,19 @@ def _gmm_drhs_kernel(group_ids, m_tile_ids, row_start, row_end, lhs_ref,
     def _():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    rows = mt * tile_m + jax.lax.broadcasted_iota(
-        jnp.int32, (tile_m, 1), 0)
-    keep = (rows >= row_start[w]) & (rows < row_end[w])
-    lhs_masked = jnp.where(keep, lhs_ref[:].astype(jnp.float32), 0.0)
-    # [tm, K]^T @ [tm, tn] -> [K, tn]
-    acc_ref[:] += jax.lax.dot_general(
-        lhs_masked, dout_ref[:].astype(jnp.float32),
-        (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+    # a unit past num_work accumulates nothing; the flush below stays
+    # outside this guard: it fires at w == W - 1, which IS such a unit
+    # whenever there is one
+    @pl.when(row_end[w] > row_start[w])
+    def _():
+        rows = mt * tile_m + jax.lax.broadcasted_iota(
+            jnp.int32, (tile_m, 1), 0)
+        keep = (rows >= row_start[w]) & (rows < row_end[w])
+        lhs_masked = jnp.where(keep, lhs_ref[:].astype(jnp.float32), 0.0)
+        # [tm, K]^T @ [tm, tn] -> [K, tn]
+        acc_ref[:] += jax.lax.dot_general(
+            lhs_masked, dout_ref[:].astype(jnp.float32),
+            (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32)
 
     last_of_group = jnp.logical_or(
         w == num_work_static - 1,

@@ -14,6 +14,9 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from jax.experimental import pallas as pl
+
+from deepspeed_tpu.ops import grouped_gemm
 from deepspeed_tpu.ops.grouped_gemm import (
     gmm,
     gmm_reference,
@@ -113,6 +116,119 @@ def test_gmm_grad_rows_past_last_group_are_zero():
                                atol=1e-4, rtol=1e-4)
     np.testing.assert_allclose(np.asarray(drhs), np.asarray(gr[1]),
                                atol=1e-4, rtol=1e-4)
+
+
+# ------------------------------------------------------------------ #
+# Group sizes a SHARE of a wider router's experts produces (PR 32): the
+# groups sum to a fraction of m, so most of the static work-unit list is
+# units with an empty row range, which the kernels skip.
+# ------------------------------------------------------------------ #
+def _share_sizes(name):
+    """(m, group sizes) by case name."""
+    rng = np.random.default_rng(32)
+    e = 128
+    uniform = np.full(e, 1.0 / e)
+    if name == "quarter":        # the Qwen3-Next decode tick: 384 rows,
+        return 384, rng.multinomial(81, uniform)   # 128 groups, ~80 live
+    if name == "all_empty":      # a tick that routes nothing here
+        return 384, np.zeros(e, np.int64)
+    if name == "one_live":
+        sizes = np.zeros(e, np.int64)
+        sizes[77] = 5
+        return 384, sizes
+    if name == "tile_edge":      # the live rows end exactly on a tile edge
+        return 384, rng.multinomial(256, uniform)
+    assert name == "all_full"    # every boundary inside a tile: no unit
+    return 512, np.asarray([100, 130, 150, 132])   # of the list is padding
+
+
+SHARE_CASES = ["quarter", "all_empty", "one_live", "tile_edge", "all_full"]
+
+
+def _parent_gmm_kernel(group_ids, m_tile_ids, row_start, row_end, lhs_ref,
+                       rhs_ref, out_ref, *, tile_m: int):
+    """``_gmm_kernel`` as it was before PR 32, kept as the oracle: every
+    unit multiplies, a unit with an empty row range stores back what was
+    there."""
+    w = pl.program_id(1)
+    mt = m_tile_ids[w]
+    rows = mt * tile_m + jax.lax.broadcasted_iota(
+        jnp.int32, (tile_m, 1), 0)
+    keep = (rows >= row_start[w]) & (rows < row_end[w])
+
+    @pl.when(jnp.logical_or(w == 0, m_tile_ids[w - 1] != mt))
+    def _():
+        out_ref[:] = jnp.zeros_like(out_ref)
+
+    partial = jax.lax.dot_general(
+        lhs_ref[:], rhs_ref[0], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    out_ref[:] = jnp.where(keep, partial.astype(out_ref.dtype), out_ref[:])
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("name", SHARE_CASES)
+def test_gmm_on_a_share_is_the_parents_kernel_bit_for_bit(
+        name, dtype, monkeypatch):
+    m, sizes = _share_sizes(name)
+    lhs, rhs, gs = _case(m, 64, 256, len(sizes), sizes, seed=32,
+                         dtype=dtype)
+    got = np.asarray(gmm(lhs, rhs, gs, TM, TN, True).astype(jnp.float32))
+    want = np.asarray(gmm_reference(lhs, rhs, gs).astype(jnp.float32))
+    tol = 1e-5 if dtype == jnp.float32 else 2e-2
+    np.testing.assert_allclose(got, want, atol=tol, rtol=tol)
+    assert np.all(got[int(sizes.sum()):] == 0)
+    # the unjitted call, so that the patched body is the one traced
+    monkeypatch.setattr(grouped_gemm, "_gmm_kernel", _parent_gmm_kernel)
+    parent = grouped_gemm._gmm_fwd_kernel_call.__wrapped__(
+        lhs, rhs, gs, TM, TN, True)
+    np.testing.assert_array_equal(
+        got, np.asarray(parent.astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("name", ["quarter", "one_live"])
+def test_gmm_grads_on_a_share(name):
+    """dlhs / drhs where most units are skipped: drhs flushes its last
+    group at the LAST unit of the list, which is a skipped one here."""
+    m, sizes = _share_sizes(name)
+    lhs, rhs, gs = _case(m, 64, 128, len(sizes), sizes, seed=33)
+
+    def loss(fn):
+        return lambda a, b: jnp.sum(fn(a, b) ** 2)
+
+    gk = jax.grad(loss(lambda a, b: gmm(a, b, gs, TM, TN, True)),
+                  argnums=(0, 1))(lhs, rhs)
+    gr = jax.grad(loss(lambda a, b: gmm_reference(a, b, gs)),
+                  argnums=(0, 1))(lhs, rhs)
+    for got, want in zip(gk, gr):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-4, rtol=1e-4)
+    assert np.all(np.asarray(gk[0])[int(sizes.sum()):] == 0)
+    assert np.all(np.asarray(gk[1])[sizes == 0] == 0)
+    last = int(np.flatnonzero(sizes)[-1])
+    assert np.any(np.asarray(gk[1])[last] != 0)
+
+
+@pytest.mark.parametrize("name", SHARE_CASES)
+def test_num_work_counts_the_units_that_hold_rows(name):
+    m, sizes = _share_sizes(name)
+    gids, mtids, rs, re_, nw = make_group_metadata(
+        jnp.asarray(sizes, jnp.int32), m, TM)
+    rs, re_ = np.asarray(rs), np.asarray(re_)
+    assert rs.shape[0] == m // TM + len(sizes) - 1
+    live = re_ > rs
+    assert int(nw) == live.sum()
+    assert live[:int(nw)].all()          # and they come first
+    # a group of n rows starting at s touches these tiles, one unit each
+    ends = np.cumsum(sizes)
+    starts = ends - sizes
+    assert int(nw) == sum((e - 1) // TM - s // TM + 1
+                          for s, e in zip(starts, ends) if e > s)
+    if name == "all_full":
+        assert live.all()
+    if name == "all_empty":
+        assert not live.any()
 
 
 def test_gmm_nondivisible_falls_back():

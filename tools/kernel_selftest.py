@@ -27,6 +27,15 @@ DECODE_READ_CELLS = {"mistral7b": (160, 8, 4, 128),
                      "qwen3next": (512, 2, 8, 256)}
 
 
+#: tokens a tick, top-k, the router's experts, the experts this share
+#: holds, hidden and expert widths: the grouped GEMM's calls in the two
+#: cells that serve a share of the experts (a Qwen3-Next decode tick and
+#: full mixed tick, a Moonlight full mixed tick)
+GMM_SHARE_CELLS = {"qwen3next_decode": (32, 10, 512, 128, 2048, 512),
+                   "qwen3next_T1056": (1056, 10, 512, 128, 2048, 512),
+                   "moonlight_T1088": (1088, 6, 64, 16, 2048, 1408)}
+
+
 def _stacked(read, layers: int):
     """``layers`` reads with a query of their own each, summed (the pools
     are arguments: a closed-over pool would be compiled in as a constant)."""
@@ -109,6 +118,69 @@ def decode_read_case(cell: str, tol: float, layers: int = 16,
                   / layers)
         us[str(share)] = [held, round(t_walk, 1), round(t_dense, 1)]
     return {"max_err": round(err, 6), "ok": bool(err < tol), "us": us}
+
+
+def gmm_share_case(tol: float, layers: int = 8, repeats: int = 10) -> dict:
+    """The grouped GEMM (``gmm``, compiled) where the matrices are a share
+    of the router's experts: for each entry of ``GMM_SHARE_CELLS``, seeded
+    uniform top-k routing over all experts, the rows routed to the held
+    ones sorted first (what ``grouped_moe_ffn`` hands the kernel), the
+    gate / up and the down call at the tiles ``_pick_tiles`` gives them,
+    against ``gmm_reference`` on the row tiles that hold rows (the others
+    must be zero).  ``calls`` = for each cell and call ``{shape [m, k, n],
+    tiles, live_units of units, us a call, least_us}``: ``layers`` calls a
+    program with a layer's weights each (an argument each), ``repeats``
+    programs back to back, host clock around them; least = the touched
+    experts' weight bytes at 819 GB/s or the routed rows' FLOPs at 197
+    TFLOP/s, the larger."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.ops.grouped_gemm import (
+        _pick_tiles, gmm, gmm_reference, make_group_metadata)
+
+    rng = np.random.default_rng(32)
+    err, calls = 0.0, {}
+    for cell, (t, k_top, routed, held, h, f) in GMM_SHARE_CELLS.items():
+        topi = np.argsort(rng.random((t, routed)), axis=1)[:, :k_top]
+        sizes = np.bincount(topi[topi < held], minlength=held)
+        total, m = int(sizes.sum()), -(-t * k_top // 128) * 128
+        gs = jnp.asarray(sizes, jnp.int32)
+        for call, (k, n) in (("gate_up", (h, f)), ("down", (f, h))):
+            tm, tn = _pick_tiles(m, k, n)
+            keys = jax.random.split(jax.random.key(len(calls)), layers + 1)
+            lhs = jax.random.normal(keys[0], (m, k), jnp.bfloat16)
+            # a layer's weights an argument each: a slice of one stacked
+            # argument is copied in front of every call (0.65 ms for the
+            # 268 MB of a Qwen3-Next layer)
+            rhs = tuple(jax.random.normal(key, (held, k, n), jnp.bfloat16)
+                        * k ** -0.5 for key in keys[1:])
+
+            def run(lhs, rhs, gs):
+                # a corner of each result: the whole call runs, and no
+                # pass over its output is timed beside it
+                return sum(gmm(lhs, w, gs, tm, tn, False)[:8, :128]
+                           .astype(jnp.float32) for w in rhs)
+
+            us, _ = _timed(jax.jit(run), layers, repeats, lhs, rhs, gs)
+            got = gmm(lhs, rhs[0], gs, tm, tn, False)
+            top = -(-total // 128) * 128
+            want = gmm_reference(lhs[:top], rhs[0], gs)
+            err = max(err,
+                      float(jnp.max(jnp.abs(got[:top].astype(jnp.float32)
+                                            - want.astype(jnp.float32)))),
+                      float(jnp.max(jnp.abs(got[top:].astype(jnp.float32)),
+                                    initial=0.0)))
+            nw = make_group_metadata(gs, m, tm)[4]
+            least = max(int((sizes > 0).sum()) * k * n * 2 / 819e9,
+                        2 * total * k * n / 197e12)
+            calls[f"{cell}.{call}"] = {
+                "shape": [m, k, n], "tiles": [tm, tn],
+                "live_units": int(nw), "units": m // tm + held - 1,
+                "us": round(us, 1), "least_us": round(least * 1e6, 1)}
+    return {"max_err": round(err, 6), "ok": bool(err < tol),
+            "calls": calls}
 
 
 def latent_read_case(tol: float, layers: int = 13, repeats: int = 10) -> dict:
@@ -550,6 +622,12 @@ def run_selftest(tol: float = 3e-2) -> dict:
     guarded("gmm_fwd", lambda: record(
         "gmm_fwd", gmm(lhs, rhs, sizes, interpret=False),
         gmm_reference(lhs, rhs, sizes)))
+
+    # the same kernel where most of its work units hold no rows: the two
+    # serving cells whose matrices are a share of the router's experts,
+    # with live units of all units and microseconds a call beside the least
+    guarded("gmm_share", lambda: results.update(
+        {"gmm_share": gmm_share_case(tol)}))
 
     def gmm_grads_case():
         g_got = jax.grad(lambda a, b: jnp.sum(
