@@ -126,6 +126,11 @@ class InferenceEngineV2:
         #: the scheduler's Tracer (``attach_tracer``): the engine's spans
         #: nest under whatever span of it is open (the tick's phase)
         self.tracer = None
+        #: number of the last step program dispatched (every ``decode_step``
+        #: / ``ragged_step_*`` / ``verify_step_*`` launch, engine-wide,
+        #: from 1): what its dispatch span and the wait that retires it
+        #: are named by
+        self.last_launch = 0
         sm_cfg = self.config.state_manager
         kv_cfg = self.config.kv_cache
         max_pos = getattr(model, "max_positions", None)
@@ -221,6 +226,17 @@ class InferenceEngineV2:
     def attach_tracer(self, tracer) -> None:
         """Record this engine's spans on ``tracer`` (None detaches)."""
         self.tracer = tracer
+
+    def _launched(self, span, step) -> int:
+        """Count the step program just dispatched; a live dispatch span
+        closes with the launch record: that number and the jitted
+        program's name (the ``jit(<program>)`` that heads the ``op_name``
+        of its device operations)."""
+        self.last_launch += 1
+        if type(span) is SpanHandle:
+            span.attrs = {"launch": self.last_launch,
+                          "program": step.__name__}
+        return self.last_launch
 
     # ------------------------------------------------------------------ #
     # Scheduling predicates (reference can_schedule:181 / query:153)
@@ -480,9 +496,10 @@ class InferenceEngineV2:
         # host↔device alignment: a jax.profiler capture shows this named
         # bracket on the host track lined up with the XLA execution it
         # dispatched
-        with open_span(self.tracer, "engine/ragged_step"):
-            logits, new_cache = self._get_step(bucket, tile)(
-                self.params, sm.kv_cache.cache, packed)
+        with open_span(self.tracer, "engine/ragged_step") as span:
+            step = self._get_step(bucket, tile)
+            logits, new_cache = step(self.params, sm.kv_cache.cache, packed)
+            launch = self._launched(span, step)
         sm.kv_cache.update(new_cache)
 
         out: Dict[int, np.ndarray] = {}
@@ -499,7 +516,10 @@ class InferenceEngineV2:
                     out[uid] = logits[slot]        # lazy device row
                     continue
                 if logits_host is None:
-                    with open_span(self.tracer, "engine/fetch_logits"):
+                    with open_span(self.tracer,
+                                   "engine/fetch_logits") as span:
+                        if type(span) is SpanHandle:
+                            span.attrs = {"launch": launch}
                         logits_host = np.asarray(
                             jax.device_get(logits), np.float32)
                 out[uid] = logits_host[slot]
@@ -560,10 +580,12 @@ class InferenceEngineV2:
                 if self._stateful:
                     span.attrs["state_slots"] = sm.state_pool.held
         try:
-            with open_span(self.tracer, "engine/decode_step"):
-                logits, nxt, new_cache, new_pos = self._get_decode_step()(
+            with open_span(self.tracer, "engine/decode_step") as span:
+                step = self._get_decode_step()
+                logits, nxt, new_cache, new_pos = step(
                     self.params, sm.kv_cache.cache, state["tables"],
                     state["pos"], tok, *state["slots"])
+                self._launched(span, step)
         except Exception:
             self._recover_donated_cache()
             raise
@@ -782,9 +804,11 @@ class InferenceEngineV2:
         packed = jnp.asarray(np.concatenate(
             [tables.ravel(), pos, tok.ravel()]))       # ONE upload
         try:
-            with open_span(self.tracer, "engine/verify_step"):
-                logits, nxt, new_cache = self._get_verify_step(K)(
+            with open_span(self.tracer, "engine/verify_step") as span:
+                step = self._get_verify_step(K)
+                logits, nxt, new_cache = step(
                     self.params, sm.kv_cache.cache, packed)
+                self._launched(span, step)
         except Exception:
             # same donated-cache hazard as decode_step: with speculation
             # on, THIS is the steady-state tick, so it needs the same

@@ -20,7 +20,9 @@ tree on that tracer (the scheduler hands it over): ``tick`` → ``pack`` /
 ``engine/decode_step``, ``engine/verify_step``, ``fetch``, ``advance``.  A
 counter is recorded once, on the span that owns it: the ``tick`` span
 closes with ``kind`` and ``emitted``, ``engine/build_batch`` with the
-``tokens`` it fed and the ``bucket`` they were padded to.  The catalogue (name, site, parent, attrs), the
+``tokens`` it fed and the ``bucket`` they were padded to, a dispatch span
+with the ``launch`` number and the ``program`` name, and the ``fetch`` /
+``engine/fetch_logits`` that waits for that launch with its ``launch``.  The catalogue (name, site, parent, attrs), the
 one rule for when a span is also a ``jax.profiler.TraceAnnotation``
 (opened with ``Tracer.span`` while ``enable_device_annotations`` is on)
 and the off-path cost (one attribute test, the shared null context) are
@@ -49,7 +51,6 @@ from deepspeed_tpu.observability.registry import (MetricSpec,
                                                   MetricsRegistry,
                                                   default_registry)
 from deepspeed_tpu.observability.tracer import (Tracer, annotate,
-                                                device_annotations_enabled,
                                                 enable_device_annotations,
                                                 load_chrome_trace,
                                                 merge_events, mint_trace_id,
@@ -59,8 +60,7 @@ from deepspeed_tpu.observability.tracer import (Tracer, annotate,
 __all__ = ["FlightRecorder", "MemoryLedger", "MetricSpec", "MetricsRegistry",
            "Tracer", "annotate", "capture_cost_analysis",
            "capture_memory_analysis", "default_registry",
-           "device_annotations_enabled", "enable_device_annotations",
-           "kv_occupancy", "list_postmortems", "load_chrome_trace",
-           "load_postmortem", "make_occupancy_provider", "merge_events",
-           "mint_trace_id", "open_span", "step_annotation",
+           "enable_device_annotations", "kv_occupancy", "list_postmortems",
+           "load_chrome_trace", "load_postmortem", "make_occupancy_provider",
+           "merge_events", "mint_trace_id", "open_span", "step_annotation",
            "tenant_occupancy", "write_chrome_trace", "write_postmortem"]

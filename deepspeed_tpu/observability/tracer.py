@@ -83,15 +83,21 @@ span                     site                        parent    attrs (counters)
                                                                (table blocks its
                                                                one-token rows
                                                                hold)
-``engine/ragged_step``   the step's dispatch         prefill   —
-``engine/fetch_logits``  ``device_get(logits)``      prefill   —
+``engine/ragged_step``   the step's dispatch         prefill   the launch record
+                                                               (below):
+                                                               ``launch``,
+                                                               ``program``
+``engine/fetch_logits``  ``device_get(logits)``: the prefill   ``launch``: the
+                         wait that retires the                 launch whose
+                         batch's launch                        result it
+                                                               blocked on
 ``engine/decode_prep``   ``decode_step``: KV slots,  decode    ``seqs``: live
                          table upload, token array             rows of the step;
                                                                with recurrent
                                                                state:
                                                                ``state_slots``
                                                                held
-``engine/decode_step``   the step's dispatch: of     decode    —
+``engine/decode_step``   the step's dispatch: of     decode    the launch record
                          the step the tick returns
                          unless that is in flight,
                          and of the step after it
@@ -99,9 +105,9 @@ span                     site                        parent    attrs (counters)
                          the same rows (so 0, 1 or
                          2 a tick, one a tick over
                          a run of decode ticks)
-``engine/verify_step``   the step's dispatch         verify    —
-``fetch``                ``scheduler._fetch``: the   decode /  —
-                         blocking ``device_get`` of  verify
+``engine/verify_step``   the step's dispatch         verify    the launch record
+``fetch``                ``scheduler._fetch``: the   decode /  ``launch``: the
+                         blocking ``device_get`` of  verify    launch it retires
                          the step the tick returns:
                          when that step is ahead,
                          the wait for a program
@@ -112,6 +118,20 @@ span                     site                        parent    attrs (counters)
                          verify acceptance loop      verify /
                                                      sample
 =======================  ==========================  ========  =================
+
+The launch record.  The engine numbers every step program it dispatches
+(``engine.last_launch``: one integer, engine-wide, from 1).  Each of the
+three dispatch spans closes with ``launch`` (that number) and ``program``
+(the ``__name__`` of the jitted function: ``decode_step``,
+``ragged_step_T1088_tiled``, ``verify_step_K4``, letter for letter the
+``jit(<program>)`` that heads the ``op_name`` of its device operations and,
+as ``jit_<program>``, names its executions on a profile's "XLA Modules"
+line).  The wait that retires a launch, ``fetch`` or
+``engine/fetch_logits``, closes with the ``launch`` it blocked on; the
+device runs launches in order, so that wait retires every earlier one too
+(a prefill chunk that drains no sequence is fetched by nobody).  A
+dispatch on the host is joined to its execution on the device by these
+two, not by order or by a clock.
 
 (``request/*`` spans and instants carry each request's own ``trace_id``
 and are opened with :meth:`Tracer.start` / :meth:`Tracer.instant`.)
@@ -161,10 +181,6 @@ def enable_device_annotations(on: bool = True) -> None:
     the XLA device timeline when a ``jax.profiler`` capture is active)."""
     global _DEVICE_ANNOTATIONS
     _DEVICE_ANNOTATIONS = bool(on)
-
-
-def device_annotations_enabled() -> bool:
-    return _DEVICE_ANNOTATIONS
 
 
 _PROFILER_CLS: Dict[str, Any] = {}

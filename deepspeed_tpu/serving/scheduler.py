@@ -92,6 +92,9 @@ class _DecodeStep:
     #: 1 when it was dispatched during the tick before the one that returns
     #: its tokens
     ahead: int
+    #: the engine's number for this launch (``engine.last_launch`` right
+    #: after the dispatch): the ``fetch`` that retires it closes with it
+    launch: int
 
 
 class ContinuousBatchScheduler:
@@ -617,8 +620,9 @@ class ContinuousBatchScheduler:
             traced = type(span) is SpanHandle
             if not all(r.sampling.greedy for r in packed):
                 logits = self.engine.decode_step(uids, [c[0] for c in chunks])
-                rows = np.asarray(self._fetch(logits),
-                                  np.float32)[:len(uids)]
+                rows = np.asarray(
+                    self._fetch(logits, self.engine.last_launch),
+                    np.float32)[:len(uids)]
                 held = self._held_blocks(packed) if traced else 0
                 for req in packed:
                     req.fed += 1
@@ -659,7 +663,7 @@ class ContinuousBatchScheduler:
         """One greedy ``decode_step`` over ``packed``'s rows, fed
         ``tokens`` (host ints, or the ``nxt`` of the step before it)."""
         _, nxt = self.engine.decode_step(uids, tokens, greedy=True)
-        return _DecodeStep(packed, nxt, ahead)
+        return _DecodeStep(packed, nxt, ahead, self.engine.last_launch)
 
     def _same_rows_next_tick(self, step: _DecodeStep, uids, packed) -> bool:
         """Can the host tell, before ``step``'s tokens arrive, that the
@@ -695,7 +699,7 @@ class ContinuousBatchScheduler:
         tell the engine the values the step dispatched behind this one was
         fed from the device, for the prefix cache."""
         try:
-            toks = self._fetch(step.nxt)
+            toks = self._fetch(step.nxt, step.launch)
         except Exception:
             self._abandon(step.packed)
             raise
@@ -735,12 +739,16 @@ class ContinuousBatchScheduler:
             if self._running.get(req.uid) is req:
                 self._preempt(req)
 
-    def _fetch(self, device_array) -> np.ndarray:
+    def _fetch(self, device_array, launch: int) -> np.ndarray:
         """The tick's one blocking transfer: the host waits here for the
-        step program to finish and its tokens (or logits) to arrive."""
+        step program to finish and its tokens (or logits) to arrive.  The
+        span closes with ``launch``, the engine's number of the launch
+        whose result this is."""
         import jax
 
-        with open_span(self.tracer, "fetch"):
+        with open_span(self.tracer, "fetch") as span:
+            if type(span) is SpanHandle:
+                span.attrs = {"launch": launch}
             return np.asarray(jax.device_get(device_array))
 
     # -- speculative decode -------------------------------------------- #
@@ -795,7 +803,7 @@ class ContinuousBatchScheduler:
             # vocab] logits (the same asymmetry the plain greedy fast
             # tick exploits via decode_step(greedy=True))
             _, nxt = self.engine.verify_step(uids, feed, greedy=True)
-            toks = self._fetch(nxt)[:len(uids)]
+            toks = self._fetch(nxt, self.engine.last_launch)[:len(uids)]
             cand = np.concatenate(
                 [toks[i, :m] for i, m in enumerate(spans)])
         else:
@@ -805,9 +813,9 @@ class ContinuousBatchScheduler:
             # every candidate slot: slot k of request i draws at
             # generation position len(generated)+k — the exact key
             # sequential decode would use
-            rows = np.asarray(self._fetch(
-                self.engine.verify_step(uids, feed)),
-                np.float32)[:len(uids)]
+            logits = self.engine.verify_step(uids, feed)
+            rows = np.asarray(self._fetch(logits, self.engine.last_launch),
+                              np.float32)[:len(uids)]
             flat_rows, flat_params, flat_pos, flat_uids = [], [], [], []
             for i, (r, d) in enumerate(zip(packed, drafts)):
                 m = spans[i]

@@ -313,7 +313,7 @@ def test_abandoned_step_recomputes_from_a_zeroed_slot(solo_runs,
         sched.step()
     real_fetch = sched._fetch
 
-    def failing(arr):
+    def failing(arr, launch):
         monkeypatch.setattr(sched, "_fetch", real_fetch)
         raise RuntimeError("device lost")
 

@@ -1037,7 +1037,7 @@ def test_failed_fetch_of_a_step_ahead_recovers(params, monkeypatch):
     real_fetch, recovered = sched._fetch, []
     real_recover = eng._recover_donated_cache
 
-    def failing(arr):
+    def failing(arr, launch):
         monkeypatch.setattr(sched, "_fetch", real_fetch)
         raise RuntimeError("device lost")
 

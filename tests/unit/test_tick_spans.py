@@ -147,7 +147,8 @@ def test_pure_decode_tick_tree(traced):
         # rows of each step on its engine/decode_prep (``gmm_roofline_pct``
         # of the benchmark reads it), the mechanism's on the decode span
         assert {k for k, v in kids.items() if "attrs" in v[0]} == \
-            {"decode"} | ({"engine/decode_prep"} if preps else set())
+            {"decode", "fetch"} | ({"engine/decode_prep",
+                                   "engine/decode_step"} if preps else set())
         for prep in preps:
             assert set(prep["attrs"]) == {"seqs"} and \
                 1 <= prep["attrs"]["seqs"] <= 4
@@ -193,8 +194,9 @@ def test_mixed_tick_tree(traced):
     # one decoding token and an 11-token prompt, padded to the 16 bucket
     assert kids["engine/build_batch"][0]["attrs"] == {"tokens": 1 + 11,
                                                       "bucket": 16}
-    assert not any("attrs" in v[0] for k, v in kids.items()
-                   if k != "engine/build_batch")
+    # ... the launch record on the dispatch, the launch on its wait
+    assert {k for k, v in kids.items() if "attrs" in v[0]} == \
+        {"engine/build_batch", "engine/ragged_step", "engine/fetch_logits"}
     by_id = {r["span_id"]: r for r in tr.records()}
     assert by_id[kids["advance"][0]["parent"]]["name"] == "sample"
     assert by_id[kids["engine/build_batch"][0]["parent"]]["name"] == \
@@ -310,6 +312,168 @@ def test_verify_tick_counters(params):
 
 
 # --------------------------------------------------------------------- #
+# the launch record
+# --------------------------------------------------------------------- #
+_DISPATCH = ("engine/decode_step", "engine/ragged_step", "engine/verify_step")
+_WAIT = ("fetch", "engine/fetch_logits")
+
+
+def _launch_spans(tr):
+    """(dispatch records, wait records), each oldest first by its end."""
+    recs = sorted((r for r in tr.records() if r["ph"] == "X"),
+                  key=lambda r: r["t1_ns"])
+    return ([r for r in recs if r["name"] in _DISPATCH],
+            [r for r in recs if r["name"] in _WAIT])
+
+
+def test_every_dispatch_carries_its_launch_and_program(traced):
+    tr, sched = traced
+    eng = sched.engine
+    dispatches, _ = _launch_spans(tr)
+    for r in dispatches:
+        assert set(r["attrs"]) == {"launch", "program"}
+    # numbered from 1, engine-wide, no number twice, in dispatch order
+    assert [r["attrs"]["launch"] for r in dispatches] == \
+        list(range(1, len(dispatches) + 1))
+    assert eng.last_launch == len(dispatches)
+    # the name the jitted function was given, letter for letter the
+    # ``jit(...)`` of the program's operations (and ``jit_...`` of its
+    # module: ``test_step_programs_carry_their_names``)
+    names = {eng._steps[key].__name__ for key in eng.step_keys}
+    assert {r["attrs"]["program"] for r in dispatches} == names == \
+        {"decode_step", "ragged_step_T16"}
+    for r in dispatches:
+        want = "decode_step" if r["name"] == "engine/decode_step" \
+            else "ragged_step_T16"
+        assert r["attrs"]["program"] == want
+        assert f'"jit({want})/' in eng.lower_step(
+            ("decode_step",) if want == "decode_step" else (16, None)
+        ).as_text(debug_info=True)
+
+
+def test_every_launch_is_retired_by_the_wait_that_names_it(traced):
+    """Each launch is waited for once, by a ``fetch`` (a decode step) or an
+    ``engine/fetch_logits`` (a ragged batch) that closes with its number;
+    ``_drive`` ends idle, so no launch is left in flight.  A step sent
+    ahead is retired in the NEXT tick, after that tick has (or has not)
+    dispatched its successor; every other launch inside its own tick."""
+    tr, _ = traced
+    dispatches, waits = _launch_spans(tr)
+    assert all(set(w["attrs"]) == {"launch"} for w in waits)
+    retired = [w["attrs"]["launch"] for w in waits]
+    assert sorted(retired) == [d["attrs"]["launch"] for d in dispatches]
+    assert retired == sorted(retired)       # the device runs them in order
+    wait_of = {w["attrs"]["launch"]: w for w in waits}
+    tick_of = {}
+    for tick, kids in _ticks(tr):
+        for name in _DISPATCH + _WAIT:
+            for r in kids.get(name, []):
+                tick_of[r["span_id"]] = tick["attrs"]["tick"]
+    by_launch = {d["attrs"]["launch"]: d for d in dispatches}
+    ahead = 0
+    for n, d in by_launch.items():
+        w = wait_of[n]
+        assert d["t1_ns"] <= w["t0_ns"]
+        assert (w["name"] == "fetch") == (d["name"] == "engine/decode_step")
+        gap = tick_of[w["span_id"]] - tick_of[d["span_id"]]
+        assert gap in (0, 1)
+        if gap:
+            ahead += 1
+            # dispatched as the second step of its tick, behind the step
+            # that tick returned ...
+            assert tick_of[by_launch[n - 1]["span_id"]] == \
+                tick_of[d["span_id"]]
+            # ... and retired after whatever the next tick dispatched
+            later = [x for x in dispatches
+                     if tick_of[x["span_id"]] == tick_of[w["span_id"]]]
+            assert all(x["t1_ns"] <= w["t0_ns"] for x in later)
+    # the decode ticks that found their step in flight
+    # (``test_decode_span_counts_the_steps_ahead``: [0, 1, 0, 1, 0, 1])
+    assert ahead == 3
+
+
+def test_a_run_of_decode_ticks_retires_behind_its_successor(params):
+    """Over a run of decode ticks of the same rows each tick dispatches
+    the step after the one it returns and THEN waits for the one it
+    returns: launch n is retired after launch n + 1 went out."""
+    tr = Tracer()
+    sched = _sched(params, tracer=tr)
+    sched.submit(_prompt(9), SamplingParams(greedy=True, max_new_tokens=8))
+    sched.run_until_idle()
+    dispatches, waits = _launch_spans(tr)
+    end_of = {d["attrs"]["launch"]: d["t1_ns"] for d in dispatches}
+    behind = [w["attrs"]["launch"] for w in waits
+              if end_of.get(w["attrs"]["launch"] + 1, 1 << 62) <= w["t0_ns"]]
+    # prefill 1 (the first token) | decode: 2 and 3 out, 2 back | 4 out, 3
+    # back | ... | 8 out, 7 back | 8 back: the eighth token's step is not
+    # followed (the row reaches max_new_tokens with it)
+    assert len(dispatches) == 8 and behind == [2, 3, 4, 5, 6, 7]
+
+
+def test_a_tracer_attached_later_continues_the_count(params):
+    """Launches are counted traced or not: a tracer attached to a running
+    engine records from the number the engine has reached, and one taken
+    off again leaves the count running."""
+    sched = _sched(params)
+    eng = sched.engine
+    sched.submit(_prompt(9), SamplingParams(greedy=True, max_new_tokens=6))
+    sched.step()
+    sched.step()
+    before = eng.last_launch
+    assert before >= 2
+    tr = Tracer()
+    sched.attach_tracer(tr)
+    sched.step()
+    dispatches, waits = _launch_spans(tr)
+    assert [d["attrs"]["launch"] for d in dispatches] == \
+        list(range(before + 1, eng.last_launch + 1))
+    # the step the untraced tick before left in flight is retired here,
+    # under its own number
+    assert [w["attrs"]["launch"] for w in waits] == [before]
+    sched.attach_tracer(None)
+    recorded, reached = len(tr), eng.last_launch
+    sched.run_until_idle()
+    assert len(tr) == recorded and eng.last_launch > reached
+
+
+def test_a_sampled_decode_tick_fetches_its_own_launch(params):
+    """A tick with a row that is not greedy dispatches one decode step and
+    waits for its logits in the same tick: the ``fetch`` names the launch
+    the tick's own ``engine/decode_step`` made."""
+    tr = Tracer()
+    sched = _sched(params, tracer=tr)
+    sched.submit(_prompt(9), SamplingParams(greedy=False, temperature=0.8,
+                                            max_new_tokens=4))
+    sched.run_until_idle()
+    decode = _of_kind(tr, "decode")
+    assert decode
+    for _tick, kids in decode:
+        d, = kids["engine/decode_step"]
+        w, = kids["fetch"]
+        assert set(d["attrs"]) == {"launch", "program"}
+        assert w["attrs"] == {"launch": d["attrs"]["launch"]}
+        assert d["t1_ns"] <= w["t0_ns"]
+
+
+def test_verify_dispatch_carries_the_launch_record(params):
+    tr = Tracer()
+    sched = _sched(params, tracer=tr, speculative=SpeculativeConfig(draft_k=3))
+    sched.submit([5, 6, 7, 8] * 4, SamplingParams(greedy=True,
+                                                  max_new_tokens=8))
+    sched.run_until_idle()
+    dispatches, waits = _launch_spans(tr)
+    verify = [d for d in dispatches if d["name"] == "engine/verify_step"]
+    if not verify:
+        pytest.skip("the drafter proposed nothing on this model")
+    for d in verify:
+        assert re.fullmatch(r"verify_step_K[2-4]", d["attrs"]["program"])
+        w, = [w for w in waits if w["attrs"]["launch"] == d["attrs"]["launch"]]
+        assert w["name"] == "fetch" and d["t1_ns"] <= w["t0_ns"]
+    assert [d["attrs"]["launch"] for d in dispatches] == \
+        list(range(1, len(dispatches) + 1))
+
+
+# --------------------------------------------------------------------- #
 # off: nothing recorded, nothing built
 # --------------------------------------------------------------------- #
 @pytest.mark.parametrize("make", [lambda: None,
@@ -329,6 +493,8 @@ def test_untraced_tick_builds_no_span(params, monkeypatch, make):
     assert sched.engine.tracer is tr
     _drive(sched)
     assert built == []
+    # launches are still counted: one integer
+    assert sched.engine.last_launch == 8
     if tr is not None:
         assert len(tr) == 0 and not tr.open_spans()
         assert tr.span("x") is tracer_mod._NULL_CM
