@@ -805,11 +805,12 @@ def _interleaved_against_reference(sizes: SmokeSizes, hf, family: str):
     scheduler on a ``benchmark/families/<family>`` engine at ``hf``'s
     widths, each one's logits against its own reference forward.  Returns
     ``(engine, serve_and_compare's result, {program: Mosaic kernels})``."""
-    if _HERE not in sys.path:
-        sys.path.insert(0, _HERE)
+    for path in (_HERE, os.path.join(_HERE, "tools")):
+        if path not in sys.path:
+            sys.path.insert(0, path)
     from benchmark.lib import spec
     from benchmark.runners.serve_ragged import make_params
-    from benchmark.tools.interleaved_check import serve_and_compare
+    from interleaved_logits import serve_and_compare
     from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
                                             RaggedInferenceEngineConfig)
 

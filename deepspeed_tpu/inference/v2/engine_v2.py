@@ -12,7 +12,10 @@ ragged step.
 ``put`` runs one forward over whatever chunks fit the budget and returns the
 next-token logits per *fully scheduled* sequence; prompts longer than the
 remaining budget are chunked (SplitFuse) and continue on the next ``put``
-round via the sequence's ``pending`` queue.
+round via the sequence's ``pending`` queue.  Every step program also takes
+the argmax of its logits rows, so a caller that samples greedily asks for
+the tokens (``put(..., greedy=True)``) and one int32 a row crosses to the
+host instead of a vocabulary row.
 """
 
 from __future__ import annotations
@@ -319,17 +322,24 @@ class InferenceEngineV2:
     # ------------------------------------------------------------------ #
     def put(self, uids: Sequence[int],
             tokens: Sequence[Sequence[int]],
-            sync: bool = True) -> Dict[int, np.ndarray]:
+            sync: bool = True, greedy: bool = False) -> Dict[int, Any]:
         """Schedule new tokens for the given sequences and run forwards until
         every scheduled chunk has been consumed.
 
         Returns ``{uid: logits[vocab]}`` for the sequences whose LAST token
         was processed this call (i.e. every uid — chunked prompts loop
         internally until drained, as the reference's MII loop does across
-        ``put`` calls).  With ``sync=False`` the values are device arrays
-        (no blocking download) so a caller can pipeline further device work
-        — e.g. sampling — before the first host sync; see also
-        :meth:`decode_step` for the fully device-resident decode round.
+        ``put`` calls).  With ``greedy=True`` returns ``{uid: token}``
+        instead: the argmax the step program itself took of those rows
+        (the first index of the maximum, as ``np.argmax`` of the fetched
+        row gives), so a forward costs ONE transfer of ``int32[max_seqs]``
+        and the logits never leave the device.  It is the same program
+        either way (as :meth:`decode_step` returns both): which of its
+        outputs is fetched is all that ``greedy`` chooses.  With
+        ``sync=False`` the values are device arrays (no blocking download)
+        so a caller can pipeline further device work — e.g. sampling —
+        before the first host sync; see also :meth:`decode_step` for the
+        fully device-resident decode round.
         """
         max_context = self.config.state_manager.max_context
         for uid, toks in zip(uids, tokens):
@@ -349,18 +359,19 @@ class InferenceEngineV2:
                     f"({seq.seen_tokens} seen + {len(seq.pending)} pending "
                     f"+ {len(toks)} new); check can_schedule()/query() first")
             seq.pending.extend(int(t) for t in toks)
-        results: Dict[int, np.ndarray] = {}
+        results: Dict[int, Any] = {}
         while self._has_pending(uids):
-            for uid, logits in self._run_one_batch(uids, sync=sync).items():
-                results[uid] = logits
+            results.update(self._run_one_batch(uids, sync=sync,
+                                               greedy=greedy))
         return results
 
     def _get_step(self, bucket: int, prefill_tile: Optional[int] = None):
-        """One jitted (model fwd ∘ metadata unpack) program per
-        (rows of the token buffer, tile mode); the KV pool is donated.
-        With ``prefill_tile`` the rows are the two-segment layout of
-        ``_build_batch``: ``max_seqs`` single-token rows, then whole
-        tiles."""
+        """One jitted (model fwd ∘ metadata unpack ∘ argmax) program per
+        (rows of the token buffer, tile mode), returning ``(logits
+        [max_seqs, vocab], next_tokens int32[max_seqs], new cache)``; the
+        KV pool is donated.  With ``prefill_tile`` the rows are the
+        two-segment layout of ``_build_batch``: ``max_seqs`` single-token
+        rows, then whole tiles."""
         key = (bucket, prefill_tile)
         step = self._steps.get(key)
         if step is None:
@@ -372,8 +383,11 @@ class InferenceEngineV2:
 
             def run(params, cache, packed):
                 batch = unpack_metadata(packed, bucket, S, B, *extra)
-                return self.model(params, cache, batch,
-                                  prefill_tile=prefill_tile)
+                logits, new_cache = self.model(params, cache, batch,
+                                               prefill_tile=prefill_tile)
+                with jax.named_scope("sample_argmax"):
+                    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                return logits, nxt, new_cache
 
             step = jax.jit(_named(run, f"ragged_step_T{bucket}" + (
                 "_tiled" if prefill_tile else "")), donate_argnums=(1,))
@@ -453,10 +467,15 @@ class InferenceEngineV2:
         packed = jnp.asarray(pack_metadata(meta))  # ONE upload
         return scheduled, drained, bucket, tile, packed
 
-    def _run_one_batch(self, uids, sync: bool = True) -> Dict[int, np.ndarray]:
+    def _run_one_batch(self, uids, sync: bool = True,
+                       greedy: bool = False) -> Dict[int, Any]:
         """Build one ragged batch under the token budget (SplitFuse
-        chunking), run the jitted step, and return logits for slots whose
-        pending queue drained."""
+        chunking), run the jitted step, and return the logits row (its
+        argmax with ``greedy``) of every slot whose pending queue drained.
+        With ``sync`` the host waits once, for the one output asked for:
+        the span of that wait is ``engine/fetch_logits`` for the logits
+        and ``fetch`` for the token vector, and closes with the step's
+        ``launch``."""
         sm = self.state_manager
         with open_span(self.tracer, "engine/build_batch") as span:
             built = self._build_batch(uids)
@@ -498,12 +517,14 @@ class InferenceEngineV2:
         # dispatched
         with open_span(self.tracer, "engine/ragged_step") as span:
             step = self._get_step(bucket, tile)
-            logits, new_cache = step(self.params, sm.kv_cache.cache, packed)
+            logits, nxt, new_cache = step(self.params, sm.kv_cache.cache,
+                                          packed)
             launch = self._launched(span, step)
         sm.kv_cache.update(new_cache)
 
-        out: Dict[int, np.ndarray] = {}
-        logits_host = None
+        rows = nxt if greedy else logits
+        out: Dict[int, Any] = {}
+        host = None
         for slot, (uid, done) in enumerate(zip(scheduled, drained)):
             seq = sm.get_sequence(uid)
             n = self._batch.chunk_sizes[slot]
@@ -513,16 +534,17 @@ class InferenceEngineV2:
             sm.register_prefix(seq)
             if done:
                 if not sync:
-                    out[uid] = logits[slot]        # lazy device row
+                    out[uid] = rows[slot]          # lazy device row
                     continue
-                if logits_host is None:
-                    with open_span(self.tracer,
+                if host is None:
+                    with open_span(self.tracer, "fetch" if greedy else
                                    "engine/fetch_logits") as span:
                         if type(span) is SpanHandle:
                             span.attrs = {"launch": launch}
-                        logits_host = np.asarray(
-                            jax.device_get(logits), np.float32)
-                out[uid] = logits_host[slot]
+                        host = jax.device_get(rows)
+                        host = host.tolist() if greedy else \
+                            np.asarray(host, np.float32)
+                out[uid] = host[slot]
         return out
 
     # ------------------------------------------------------------------ #
@@ -1370,13 +1392,13 @@ class InferenceEngineV2:
             uids = list(range(len(prompts)))
         outs: Dict[int, List[int]] = {u: [] for u in uids}
         live = list(uids)
-        logits = self.put(uids, prompts)
+        nxt = self.put(uids, prompts, greedy=True)
         if eos_token_id is None and max_new_tokens > 1 \
                 and not self._stateful:
             # no early-exit needed -> device-resident decode: one dispatch
             # per decode chunk instead of one per token (grouped by
             # max_seqs — decode_loop batches at most one slot per sequence)
-            first = {u: int(np.argmax(logits[u])) for u in uids}
+            first = nxt
             rest: Dict[int, np.ndarray] = {}
             S = self._batch.max_seqs
             for g in range(0, len(uids), S):
@@ -1389,7 +1411,6 @@ class InferenceEngineV2:
             return [np.asarray([first[u]] + rest[u].tolist(), np.int32)
                     for u in uids]
         for _ in range(max_new_tokens):
-            nxt = {u: int(np.argmax(logits[u])) for u in live}
             for u in live:
                 outs[u].append(nxt[u])
             live = [u for u in live
@@ -1397,6 +1418,6 @@ class InferenceEngineV2:
                             and nxt[u] == eos_token_id)]
             if not live:
                 break
-            logits = self.put(live, [[nxt[u]] for u in live])
+            nxt = self.put(live, [[nxt[u]] for u in live], greedy=True)
         self.flush(uids)
         return [np.asarray(outs[u], np.int32) for u in uids]

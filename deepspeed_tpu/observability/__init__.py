@@ -21,8 +21,10 @@ tree on that tracer (the scheduler hands it over): ``tick`` → ``pack`` /
 counter is recorded once, on the span that owns it: the ``tick`` span
 closes with ``kind`` and ``emitted``, ``engine/build_batch`` with the
 ``tokens`` it fed and the ``bucket`` they were padded to, a dispatch span
-with the ``launch`` number and the ``program`` name, and the ``fetch`` /
-``engine/fetch_logits`` that waits for that launch with its ``launch``.  The catalogue (name, site, parent, attrs), the
+with the ``launch`` number and the ``program`` name, the ``fetch`` /
+``engine/fetch_logits`` that waits for that launch with its ``launch``, and
+a ``put`` tick's ``sample`` with ``sampled`` / ``device_sampled`` (tokens
+emitted, and how many were the step program's argmax).  The catalogue (name, site, parent, attrs), the
 one rule for when a span is also a ``jax.profiler.TraceAnnotation``
 (opened with ``Tracer.span`` while ``enable_device_annotations`` is on)
 and the off-path cost (one attribute test, the shared null context) are

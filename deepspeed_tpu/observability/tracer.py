@@ -43,7 +43,18 @@ span                     site                        parent    attrs (counters)
                                                                ``emitted``
 ``pack``                 ``_step_traced``            tick      —
 ``prefill``              ``_step_traced``: ``put``   tick      —
-``sample``               after ``put``               tick      —
+``sample``               after ``put``: the host's   tick      closing:
+                         sampler over fetched                  ``sampled`` (rows
+                         logits, or nothing but                that emitted a
+                         ``advance`` when ``put``              token this tick),
+                         returned the program's                ``device_sampled``
+                         argmax                                (of those, tokens
+                                                               that were the
+                                                               step program's
+                                                               argmax: all when
+                                                               every packed row
+                                                               is greedy, else
+                                                               none)
 ``decode``               ``_fast_decode_tick``: a    tick      closing: ``steps``
                          pure-decode tick's work:              (1), ``ahead``
                          the dispatches it makes,              (1 when the step
@@ -89,8 +100,10 @@ span                     site                        parent    attrs (counters)
                                                                ``program``
 ``engine/fetch_logits``  ``device_get(logits)``: the prefill   ``launch``: the
                          wait that retires the                 launch whose
-                         batch's launch                        result it
-                                                               blocked on
+                         batch's launch, when a                result it
+                         packed row is stochastic              blocked on
+                         (or ``put`` was called for
+                         logits)
 ``engine/decode_prep``   ``decode_step``: KV slots,  decode    ``seqs``: live
                          table upload, token array             rows of the step;
                                                                with recurrent
@@ -113,7 +126,10 @@ span                     site                        parent    attrs (counters)
                          the wait for a program
                          dispatched a tick earlier,
                          what the host's own work
-                         since did not cover
+                         since did not cover.
+                         ``put(greedy=True)``: the   prefill
+                         wait for a ragged batch's
+                         ``int32[max_seqs]`` argmax
 ``advance``              ``_advance_emitted``, the   decode /  —
                          verify acceptance loop      verify /
                                                      sample

@@ -25,6 +25,12 @@ unpreempted continuation.
 Everything here is host-side python; device work is the engine's single
 jitted ragged step — the same split the reference keeps.
 
+What a tick fetches follows its rows' ``SamplingParams``: every step program
+takes the argmax of its logits, and a tick whose packed rows are all greedy
+(mixed, prefill or decode) fetches that token vector, one int32 a row; one
+stochastic row and the tick fetches the logits for the (seed, uid,
+position)-keyed host sampler, as every tick once did.
+
 A greedy pure-decode tick does not wait for its own tokens before it hands
 the device the next step: while the host can tell that the next tick will
 decode exactly the same rows, it dispatches that step on the device-resident
@@ -501,8 +507,10 @@ class ContinuousBatchScheduler:
     # One scheduling tick
     # ------------------------------------------------------------------ #
     def step(self) -> List[Tuple[Request, int]]:
-        """Pack one engine forward and sample its logits.  Returns the
-        ``(request, token)`` pairs emitted this tick."""
+        """Pack one engine forward and hand out its tokens: the program's
+        argmax when every packed row is greedy, a host sample of its
+        fetched logits otherwise.  Returns the ``(request, token)`` pairs
+        emitted this tick."""
         if self._heartbeat is not None:
             self._heartbeat.beat(self._tick)
         with open_span(self.tracer, "tick", trace_id=self.sched_trace_id,
@@ -570,12 +578,19 @@ class ContinuousBatchScheduler:
                         self.spec_stats.fallback_ticks += 1
                     emitted = self._fast_decode_tick(uids, chunks, packed)
             else:
+                # all-greedy rows: the program's own argmax is the sample
+                greedy = all(r.sampling.greedy for r in packed)
                 with open_span(self.tracer, "prefill"):
-                    logits = self.engine.put(uids, chunks, sync=True)
+                    out = self.engine.put(uids, chunks, sync=True,
+                                          greedy=greedy)
                     for req, chunk in zip(packed, chunks):
                         req.fed += len(chunk)
-                with open_span(self.tracer, "sample"):
-                    emitted = self._sample_and_advance(packed, logits)
+                with open_span(self.tracer, "sample") as span:
+                    emitted = self._sample_and_advance(packed, out, greedy)
+                    if type(span) is SpanHandle:
+                        span.attrs = {
+                            "sampled": len(emitted),
+                            "device_sampled": len(emitted) if greedy else 0}
         if tick_h is not None:
             # the tick span closes with what ran and what came out
             tick_h.attrs.update(kind=kind, emitted=len(emitted))
@@ -1097,11 +1112,18 @@ class ContinuousBatchScheduler:
             self._fail(self._queued[0], "kv_capacity")
 
     # -- sampling / lifecycle advance ---------------------------------- #
-    def _sample_and_advance(self, packed, logits) -> List[Tuple[Request, int]]:
+    def _sample_and_advance(self, packed, out,
+                            greedy: bool) -> List[Tuple[Request, int]]:
+        """Hand a token to every row of a ``put`` tick whose feed is
+        complete.  ``out`` is what the engine returned per uid: with
+        ``greedy`` the tokens themselves (the step program's argmax),
+        otherwise the logits rows, sampled here."""
         ready = [r for r in packed if r.remaining_feed == 0]
         if not ready:
             return []
-        rows = np.stack([np.asarray(logits[r.uid], np.float32)
+        if greedy:
+            return self._advance_emitted(ready, [out[r.uid] for r in ready])
+        rows = np.stack([np.asarray(out[r.uid], np.float32)
                          for r in ready])
         tokens = sample_batch(rows, [r.sampling for r in ready],
                               [len(r.generated) for r in ready],

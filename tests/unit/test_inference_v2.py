@@ -995,3 +995,81 @@ def test_engine_tiled_generate_matches_v1():
         [(4 + 64, 16), (4 + 16, 16)]
     for got, want in zip(out, ref):
         np.testing.assert_array_equal(got, np.asarray(want))
+
+
+# --------------------------------------------------------------------- #
+# put(greedy=True): the step program's own argmax, one int32 a row
+# --------------------------------------------------------------------- #
+def _tied_params(params):
+    """``params`` with every odd column of the head a copy of the even one
+    before it: each row's maximum is held by two indices, bit for bit."""
+    kernel = np.array(params["lm_head"]["kernel"])
+    kernel[:, 1::2] = kernel[:, 0::2]
+    return {**params, "lm_head": {"kernel": jnp.asarray(kernel)}}
+
+
+@pytest.mark.parametrize("tile", [None, 16], ids=["packed", "tiled"])
+@pytest.mark.parametrize("tied", [False, True], ids=["distinct", "tied"])
+def test_put_greedy_is_the_argmax_of_put_logits(tile, tied):
+    """Two engines fed the same ragged batches (a 20-token prompt in two
+    chunks beside a 5-token one, then a token each beside a third prompt):
+    the tokens one returns are ``np.argmax`` of the rows the other returns,
+    in both layouts; with tied maxima the first index wins on both sides."""
+    params = _tied_params(_params()) if tied else _params()
+    rng = np.random.default_rng(40)
+    a, b, c = (rng.integers(0, CFG.vocab_size, size=(n,)).tolist()
+               for n in (20, 5, 9))
+    engines = []
+    for _ in range(2):
+        eng = _v2_engine(params, token_budget=64 if tile else 16)
+        if tile:
+            eng.PREFILL_TILE = tile
+        engines.append(eng)
+    by_logits, by_tokens = engines
+    feeds = [([1, 2], [a, b])]
+    for _ in range(3):
+        rows = by_logits.put(*feeds[-1])
+        toks = by_tokens.put(*feeds[-1], greedy=True)
+        assert set(toks) == set(rows)
+        for uid, row in rows.items():
+            assert type(toks[uid]) is int
+            assert toks[uid] == int(np.argmax(row)), (uid, len(feeds))
+            if tied:
+                assert toks[uid] % 2 == 0 and \
+                    row[toks[uid]] == row[toks[uid] + 1]
+        feeds.append(([1, 2, 3][:len(feeds) + 2],
+                      ([[toks[1]], [toks[2]]] + [c])[:len(feeds) + 2]))
+    # the same programs, built once: asking for the other output of a
+    # bucket compiles nothing
+    assert by_logits.step_keys == by_tokens.step_keys
+    by_tokens.put([1], [[7]])
+    keys = by_tokens.step_keys
+    by_tokens.put([1], [[8]], greedy=True)
+    assert by_tokens.step_keys == keys
+    assert [by_tokens._steps[k]._cache_size() for k in keys] == \
+        [1] * len(keys)
+
+
+def test_put_greedy_without_sync_returns_device_tokens():
+    eng = _v2_engine(_params())
+    out = eng.put([1], [[3, 4, 5]], sync=False, greedy=True)
+    assert isinstance(out[1], jax.Array) and out[1].dtype == jnp.int32
+    want = _v2_engine(_params()).put([1], [[3, 4, 5]])
+    assert int(out[1]) == int(np.argmax(want[1]))
+
+
+def test_ragged_step_programs_keep_their_names_and_outputs():
+    """One program a ``(rows, tile)`` key under the name it had, returning
+    the logits, their argmax and the donated cache."""
+    eng = _v2_engine(_params(), token_budget=64)
+    eng.PREFILL_TILE = 16
+    eng.put([1], [list(range(1, 20))])
+    eng.put([1], [[5]], greedy=True)
+    assert eng.step_keys == [(4 + 32, 16), (4, 16)]
+    assert [eng._steps[k].__name__ for k in eng.step_keys] == \
+        ["ragged_step_T36_tiled", "ragged_step_T4_tiled"]
+    lowered = eng.lower_step((4, 16))
+    logits, nxt, _cache = lowered.out_info
+    assert logits.shape == (4, CFG.vocab_size)
+    assert nxt.shape == (4,) and nxt.dtype == jnp.int32
+    assert "sample_argmax" in lowered.as_text(debug_info=True)

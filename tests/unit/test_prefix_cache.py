@@ -450,9 +450,9 @@ def test_scheduler_admission_attaches_cached_prefix(params):
     calls = []
     orig = eng.put
 
-    def spy(uids, tokens, sync=True):
+    def spy(uids, tokens, **how):
         calls.append([len(t) for t in tokens])
-        return orig(uids, tokens, sync=sync)
+        return orig(uids, tokens, **how)
 
     eng.put = spy
     r2 = sched.submit(prompt, sampling=SamplingParams(max_new_tokens=2))

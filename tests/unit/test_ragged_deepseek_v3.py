@@ -22,8 +22,9 @@ import jax.numpy as jnp
 
 _REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-if _REPO not in sys.path:
-    sys.path.insert(0, _REPO)
+for _path in (_REPO, os.path.join(_REPO, "tools")):
+    if _path not in sys.path:
+        sys.path.insert(0, _path)
 
 from benchmark.families import moonlight as family          # noqa: E402
 from benchmark.reference import moonlight as reference      # noqa: E402
@@ -253,7 +254,7 @@ def runs():
 def test_interleaved_logits_match_each_reference(runs):
     """Four requests of different lengths join one after another at 40
     slots: the longer ones' chunks share batches with the others' decodes."""
-    from benchmark.tools.interleaved_check import serve_and_compare
+    from interleaved_logits import serve_and_compare
 
     params, prompts = runs
     eng = _engine(params)

@@ -23,8 +23,9 @@ import jax.numpy as jnp
 
 _REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
-if _REPO not in sys.path:
-    sys.path.insert(0, _REPO)
+for _path in (_REPO, os.path.join(_REPO, "tools")):
+    if _path not in sys.path:
+        sys.path.insert(0, _path)
 
 from benchmark.families import qwen3_next as family         # noqa: E402
 from benchmark.reference import qwen3_next as reference     # noqa: E402
@@ -246,7 +247,7 @@ def test_interleaved_sequences_equal_their_solo_runs(solo_runs):
 
 
 def test_interleaved_logits_match_each_reference(solo_runs):
-    from benchmark.tools.interleaved_check import serve_and_compare
+    from interleaved_logits import serve_and_compare
 
     params, prompts, _ = solo_runs
     out = serve_and_compare(_engine(params), reference, _ref_params(params),

@@ -235,9 +235,9 @@ def _spy_put(engine):
     calls = []
     orig = engine.put
 
-    def spy(uids, tokens, sync=True):
+    def spy(uids, tokens, **how):
         calls.append([len(t) for t in tokens])
-        return orig(uids, tokens, sync=sync)
+        return orig(uids, tokens, **how)
 
     engine.put = spy
     return calls
@@ -712,9 +712,9 @@ def _spy_paths(engine):
     paths = []
     orig_put, orig_ds = engine.put, engine.decode_step
 
-    def put(uids, tokens, sync=True):
+    def put(uids, tokens, **how):
         paths.append(("put", [len(t) for t in tokens]))
-        return orig_put(uids, tokens, sync=sync)
+        return orig_put(uids, tokens, **how)
 
     def ds(uids, tokens, greedy=False):
         paths.append(("decode_step", len(uids)))
@@ -1117,6 +1117,153 @@ def test_running_ahead_builds_no_new_program(params):
     fed_by_host.decode_step([1], [6], greedy=True)
     assert ahead.lower_step(("decode_step",)).as_text() == \
         fed_by_host.lower_step(("decode_step",)).as_text()
+
+
+# --------------------------------------------------------------------- #
+# What a ``put`` tick fetches follows its rows: the step program's argmax
+# (one int32 a row) when every packed row is greedy, the logits for the
+# host sampler when one is not.  The bar: request by request the tokens of
+# the logits path.
+# --------------------------------------------------------------------- #
+def _spy_greedy(engine, force_logits=False):
+    """Record what every ``put`` was asked for.  With ``force_logits`` a
+    greedy ask is answered as every tick once was: the logits of the same
+    program fetched, ``np.argmax`` on the host."""
+    asked = []
+    orig = engine.put
+
+    def put(uids, tokens, sync=True, greedy=False):
+        asked.append(greedy)
+        if greedy and force_logits:
+            rows = orig(uids, tokens, sync=sync)
+            return {u: int(np.argmax(r)) for u, r in rows.items()}
+        return orig(uids, tokens, sync=sync, greedy=greedy)
+
+    engine.put = put
+    return asked
+
+
+def _tied(params):
+    """Every odd column of the head a copy of the even one before it: each
+    row's maximum is held by two indices, bit for bit."""
+    kernel = np.array(params["lm_head"]["kernel"])
+    kernel[:, 1::2] = kernel[:, 0::2]
+    return {**params, "lm_head": {"kernel": jnp.asarray(kernel)}}
+
+
+_FETCH_CASES = {
+    # prompts join requests that are decoding, one every other tick
+    "joins": dict(lens=(13, 7, 21, 5, 11), new=6),
+    "tied_maxima": dict(lens=(13, 7, 21, 5, 11), new=6, tied=True),
+    # 6 usable blocks for requests of up to 3: recompute after preemption
+    "preempted": dict(lens=(9, 14, 7, 12, 10, 8), new=8, num_blocks=7,
+                      max_context=48),
+    # 16 tokens of every prompt are two cached blocks after the first
+    "prefix_hit": dict(lens=(20, 19, 23, 18), new=5, prefix=16),
+}
+
+
+def _serve_case(params, case, force_logits):
+    spec = _FETCH_CASES[case]
+    rng = np.random.default_rng(41)
+    head = rng.integers(0, CFG.vocab_size, size=(spec.get("prefix", 0),))
+    prompts = [head.tolist() + rng.integers(
+        0, CFG.vocab_size, size=(n - len(head),)).tolist()
+        for n in spec["lens"]]
+    cfg = RaggedInferenceEngineConfig.from_dict({
+        "state_manager": {"max_ragged_batch_size": 32,
+                          "max_ragged_sequence_count": 4,
+                          "max_context": spec.get("max_context", 64)},
+        "kv_cache": {"block_size": 8,
+                     "enable_prefix_cache": "prefix" in spec,
+                     **({"num_blocks": spec["num_blocks"]}
+                        if "num_blocks" in spec else {})}})
+    eng = InferenceEngineV2(
+        RaggedLlama(CFG, 8), _tied(params) if spec.get("tied") else params,
+        cfg)
+    asked = _spy_greedy(eng, force_logits)
+    sched = ContinuousBatchScheduler(eng)
+    reqs, tick = [], 0
+    while len(reqs) < len(prompts) or sched.num_pending:
+        if len(reqs) < len(prompts) and tick % 2 == 0:
+            reqs.append(sched.submit(prompts[len(reqs)],
+                                     sampling=_greedy(spec["new"])))
+        sched.step()
+        tick += 1
+        assert tick < 2000
+    return reqs, asked, sched, eng
+
+
+@pytest.mark.parametrize("case", sorted(_FETCH_CASES))
+def test_greedy_put_ticks_emit_the_tokens_of_the_logits_path(params, case):
+    reqs, asked, sched, eng = _serve_case(params, case, force_logits=False)
+    want, asked_w, _, _ = _serve_case(params, case, force_logits=True)
+    # the same ticks, every one of them asked for tokens
+    assert asked == asked_w and asked and all(asked)
+    for got, ref in zip(reqs, want):
+        assert got.state is RequestState.FINISHED
+        assert (got.generated, got.finish_reason, got.preemptions) == \
+            (ref.generated, ref.finish_reason, ref.preemptions), got.uid
+    spec = _FETCH_CASES[case]
+    if spec.get("tied"):
+        assert all(t % 2 == 0 for r in reqs for t in r.generated)
+    if "num_blocks" in spec:
+        assert sched.metrics.preemptions >= 1 and \
+            any(r.preemptions for r in reqs)
+    if "prefix" in spec:
+        assert eng.prefix_cache_stats.hit_tokens >= \
+            spec["prefix"] * (len(reqs) - 2)
+
+
+def _draws_alone(params, prompt, sp, uid):
+    """The (seed, uid, position)-keyed draws of one stochastic request by
+    the plain loop: ``put`` for logits, ``sample_batch`` on the host."""
+    eng = _engine(params, token_budget=64)
+    feed, toks = list(prompt), []
+    for pos in range(sp.max_new_tokens):
+        row = eng.put([uid], [feed])[uid]
+        feed = [int(sample_batch(row[None], [sp], [pos], [uid])[0])]
+        toks += feed
+    return toks
+
+
+def test_one_stochastic_row_sends_its_ticks_to_the_logits(params):
+    """A stochastic request beside greedy ones: every ``put`` tick that
+    packs it fetches logits and it draws what it draws alone; the ticks
+    without it fetch tokens; the greedy requests emit what they emit with
+    no stochastic row anywhere (``joins`` above)."""
+    spec = _FETCH_CASES["joins"]
+    greedy_alone, _, _, _ = _serve_case(params, "joins", force_logits=False)
+    prompts = [list(r.prompt) for r in greedy_alone]
+    sp = SamplingParams(greedy=False, temperature=0.8, top_k=8, seed=3,
+                        max_new_tokens=9)
+    rng = np.random.default_rng(42)
+    noisy_prompt = rng.integers(0, CFG.vocab_size, size=(10,)).tolist()
+
+    eng = _engine(params, max_seqs=4)
+    orig, asked = eng.put, []
+
+    def put(uids, tokens, sync=True, greedy=False):
+        asked.append((greedy, 77 in uids))
+        return orig(uids, tokens, sync=sync, greedy=greedy)
+
+    eng.put = put
+    sched = ContinuousBatchScheduler(eng)
+    reqs, noisy, tick = [], None, 0
+    while len(reqs) < len(prompts) or sched.num_pending:
+        if len(reqs) < len(prompts) and tick % 2 == 0:
+            reqs.append(sched.submit(prompts[len(reqs)],
+                                     sampling=_greedy(spec["new"])))
+        if tick == 3:
+            noisy = sched.submit(noisy_prompt, sampling=sp, uid=77)
+        sched.step()
+        tick += 1
+        assert tick < 2000
+    assert {True, False} == {g for g, _ in asked}
+    assert all(greedy != packed_noisy for greedy, packed_noisy in asked)
+    assert noisy.generated == _draws_alone(params, noisy_prompt, sp, 77)
+    for got, ref in zip(reqs, greedy_alone):
+        assert got.generated == ref.generated, got.uid
 
 
 # --------------------------------------------------------------------- #

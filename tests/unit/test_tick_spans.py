@@ -188,19 +188,97 @@ def test_decode_span_counts_the_steps_ahead(traced):
 def test_mixed_tick_tree(traced):
     tr, _ = traced
     (tick, kids), = _of_kind(tr, "mixed")
+    # every row is greedy: the wait is for the token vector (``fetch``)
     assert sorted(kids) == ["advance", "engine/build_batch",
-                            "engine/fetch_logits", "engine/ragged_step",
+                            "engine/ragged_step", "fetch",
                             "pack", "prefill", "sample"]
     # one decoding token and an 11-token prompt, padded to the 16 bucket
     assert kids["engine/build_batch"][0]["attrs"] == {"tokens": 1 + 11,
                                                       "bucket": 16}
-    # ... the launch record on the dispatch, the launch on its wait
+    # ... the launch record on the dispatch, the launch on its wait, and
+    # on ``sample`` the rows that emitted and how many by the program's
+    # argmax: the decoding row and the prompt that ended here
     assert {k for k, v in kids.items() if "attrs" in v[0]} == \
-        {"engine/build_batch", "engine/ragged_step", "engine/fetch_logits"}
+        {"engine/build_batch", "engine/ragged_step", "fetch", "sample"}
+    assert kids["sample"][0]["attrs"] == {"sampled": 2, "device_sampled": 2}
     by_id = {r["span_id"]: r for r in tr.records()}
     assert by_id[kids["advance"][0]["parent"]]["name"] == "sample"
+    assert by_id[kids["fetch"][0]["parent"]]["name"] == "prefill"
     assert by_id[kids["engine/build_batch"][0]["parent"]]["name"] == \
         "prefill"
+
+
+def _mixed_run(params, stochastic):
+    """``_drive`` with the second request stochastic or not: (tracer,
+    scheduler)."""
+    tr = Tracer()
+    sched = _sched(params, tracer=tr)
+    sched.submit(_prompt(13), SamplingParams(greedy=True, max_new_tokens=8))
+    sched.step()
+    sched.step()
+    sched.submit(_prompt(11, 1), SamplingParams(
+        greedy=not stochastic, temperature=0.7, seed=5, max_new_tokens=3))
+    sched.run_until_idle()
+    return tr, sched
+
+
+@pytest.mark.parametrize("stochastic", [False, True],
+                         ids=["all_greedy", "one_stochastic"])
+def test_put_tick_wait_and_sample_counters(params, stochastic):
+    """The wait of a ``put`` tick closes with the launch its dispatch
+    closed with, under the name of what it fetched (``fetch``: the token
+    vector; ``engine/fetch_logits``: the logits, one stochastic row is
+    enough), and ``sample`` closes with the rows that emitted and how many
+    of those tokens were the program's argmax."""
+    tr, _ = _mixed_run(params, stochastic)
+    put_ticks = [(t, kids) for t, kids in _ticks(tr)
+                 if t["attrs"]["kind"] in ("mixed", "prefill")]
+    assert [t["attrs"]["kind"] for t, _ in put_ticks] == ["prefill", "mixed"]
+    for tick, kids in put_ticks:
+        # the first tick packs the greedy request alone
+        logits = stochastic and tick["attrs"]["kind"] == "mixed"
+        wait, other = ("engine/fetch_logits", "fetch") if logits \
+            else ("fetch", "engine/fetch_logits")
+        assert other not in kids and len(kids[wait]) == 1
+        assert kids[wait][0]["attrs"] == {
+            "launch": kids["engine/ragged_step"][0]["attrs"]["launch"]}
+        emitted = tick["attrs"]["emitted"]
+        assert kids["sample"][0]["attrs"] == {
+            "sampled": emitted, "device_sampled": 0 if logits else emitted}
+    # a decode tick's ``fetch`` is the scheduler's own, no ``sample`` there
+    for tick, kids in _of_kind(tr, "decode"):
+        assert "sample" not in kids
+
+
+def test_a_greedy_mixed_tick_after_a_logits_put_builds_nothing(params):
+    """One program a ``(rows, tile)``: after a ladder of greedy requests
+    the keys and names are what they were before the argmax moved in, and
+    the first all-greedy mixed tick after a ``put`` for logits of the same
+    bucket (the harness's ``_check_logits`` before its window) runs the
+    program that ``put`` ran."""
+    sched = _sched(params)
+    eng = sched.engine
+    for n in (9, 20):                               # the ladder: T16, T32
+        sched.submit(_prompt(n), SamplingParams(greedy=True,
+                                                max_new_tokens=2))
+        sched.run_until_idle()
+    keys = eng.step_keys
+    assert keys == [(16, None), ("decode_step",), (32, None)]
+    assert [eng._steps[k].__name__ for k in keys] == \
+        ["ragged_step_T16", "decode_step", "ragged_step_T32"]
+    row = eng.put([900], [_prompt(12, 3)])[900]     # logits, bucket 16
+    assert row.shape == (CFG.vocab_size,)
+    eng.flush([900])
+    sizes = [eng._steps[k]._cache_size() for k in keys]
+    tr = Tracer()
+    sched.tracer = tr
+    eng.attach_tracer(tr)
+    _drive(sched)
+    (_, kids), = _of_kind(tr, "mixed")
+    assert kids["engine/build_batch"][0]["attrs"]["bucket"] == 16
+    assert kids["sample"][0]["attrs"] == {"sampled": 2, "device_sampled": 2}
+    assert eng.step_keys == keys
+    assert [eng._steps[k]._cache_size() for k in keys] == sizes == [1, 1, 1]
 
 
 def test_the_emit_instant_is_gone(traced):
@@ -352,8 +430,9 @@ def test_every_dispatch_carries_its_launch_and_program(traced):
 
 
 def test_every_launch_is_retired_by_the_wait_that_names_it(traced):
-    """Each launch is waited for once, by a ``fetch`` (a decode step) or an
-    ``engine/fetch_logits`` (a ragged batch) that closes with its number;
+    """Each launch is waited for once, by a ``fetch`` (a token vector: every
+    row here is greedy) or an ``engine/fetch_logits`` (a ragged batch with
+    a stochastic row: none here) that closes with its number;
     ``_drive`` ends idle, so no launch is left in flight.  A step sent
     ahead is retired in the NEXT tick, after that tick has (or has not)
     dispatched its successor; every other launch inside its own tick."""
@@ -374,7 +453,7 @@ def test_every_launch_is_retired_by_the_wait_that_names_it(traced):
     for n, d in by_launch.items():
         w = wait_of[n]
         assert d["t1_ns"] <= w["t0_ns"]
-        assert (w["name"] == "fetch") == (d["name"] == "engine/decode_step")
+        assert w["name"] == "fetch"
         gap = tick_of[w["span_id"]] - tick_of[d["span_id"]]
         assert gap in (0, 1)
         if gap:
@@ -589,8 +668,8 @@ def test_untraced_scheduler_still_annotates_while_those_are_on(
     assert len(sched.finished_requests) == 2
     assert {"tick", "ds_tick", "pack", "prefill", "sample", "decode",
             "engine/build_batch", "engine/ragged_step",
-            "engine/fetch_logits", "engine/decode_prep",
-            "engine/decode_step", "fetch", "advance"} == set(names)
+            "engine/decode_prep", "engine/decode_step", "fetch",
+            "advance"} == set(names)
 
 
 # --------------------------------------------------------------------- #
