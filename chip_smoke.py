@@ -29,6 +29,13 @@ seeded random weights:
   longer one's chunks through the expanded path beside the other's decodes
   through the absorbed one, each against the float32 expanded-form reference
   (``benchmark/reference/moonlight.py``).
+* **conv** (one device) — the gated short convolution and 64-wide heads at
+  the published LFM2-24B-A2B widths, four layers deep (the two dense layers
+  and one period's attention and convolution layer, every expert held): the
+  same two requests served TOGETHER, the longer one's chunks carrying their
+  convolution tails beside the other's decodes, the one-token rows through
+  the decode walk on the flat pool row, each against the float32 reference
+  (``benchmark/reference/lfm2_moe.py``).
 * **kernels** — ``tools/kernel_selftest.run_selftest()`` as a gate.
 
 With more than one device visible the same phases run across all of them
@@ -159,6 +166,9 @@ class SmokeSizes:
     # the mla phase: the published Moonlight keys (depth and vocabulary as
     # given); prompts and new tokens as the gdn phase's
     mla_hf: Any = None
+    # the conv phase: the published LFM2 keys (depth and vocabulary as
+    # given); prompts and new tokens as the gdn phase's
+    conv_hf: Any = None
 
 
 def chip_sizes(n_devices: int) -> SmokeSizes:
@@ -190,8 +200,16 @@ def chip_sizes(n_devices: int) -> SmokeSizes:
         # every width as published; the dense layer and two routed ones, an
         # eighth of the vocabulary slice
         mla_hf = dict(json.load(f), num_hidden_layers=3, vocab_size=5120)
+    with open(os.path.join(_HERE, "benchmark", "configs",
+                           "lfm2-24b-a2b-serve-1chip.json")) as f:
+        # every width and every expert as published; the two dense layers,
+        # an attention layer and a convolution layer behind routers, an
+        # eighth of the vocabulary
+        conv_hf = json.load(f)
+        conv_hf = dict(conv_hf, num_hidden_layers=4, vocab_size=8192,
+                       layer_types=conv_hf["layer_types"][:4])
     return SmokeSizes(
-        gdn_hf=gdn_hf, mla_hf=mla_hf,
+        gdn_hf=gdn_hf, mla_hf=mla_hf, conv_hf=conv_hf,
         moe_config=MixtralConfig.olmoe_1b_7b(num_hidden_layers=2,
                                              dtype=jnp.bfloat16),
         model_config=MistralConfig(dtype=jnp.bfloat16),
@@ -902,6 +920,35 @@ def mla_phase(sizes: SmokeSizes, devices, require_chip: bool,
 
 
 # --------------------------------------------------------------------- #
+# Phase: the gated short convolution and 64-wide heads (one device)
+# --------------------------------------------------------------------- #
+def conv_phase(sizes: SmokeSizes, devices, require_chip: bool,
+               clock: CompileClock) -> Dict[str, Any]:
+    hf = sizes.conv_hf
+    if hf is None or len(devices) > 1:
+        return {"skipped": "no conv_hf in these sizes" if hf is None
+                else "one-device phase", **clock.take()}
+    engine, out, kernels = _interleaved_against_reference(
+        sizes, hf, "lfm2_moe")
+    for name, names in kernels.items():
+        # the one-token rows walk the flat pool row; a program with a tile
+        # segment reads it through the tiled kernel too
+        want = {"_decode_kernel"} | (
+            {"_prefill_kernel"} if name != "decode_step"
+            and int(name[1:]) > sizes.max_seqs else set())
+        if require_chip and not want <= set(names):
+            raise SmokeFailure(f"conv: {name} lacks Mosaic calls "
+                               f"{sorted(want - set(names))}: {names}")
+    pool = engine.state_manager.state_pool
+    if pool.held:
+        raise SmokeFailure(f"conv: {pool.held} state slots still held "
+                           f"after every request finished")
+    return _interleaved_summary(
+        "conv", sizes, out, kernels, devices, clock,
+        layers=hf["num_hidden_layers"], experts_held=hf["num_experts"])
+
+
+# --------------------------------------------------------------------- #
 # Phase: every other kernel
 # --------------------------------------------------------------------- #
 def kernels_phase(_sizes, _devices, _require_chip,
@@ -917,7 +964,8 @@ def kernels_phase(_sizes, _devices, _require_chip,
                            f"cases not ok: {bad or results}")
     return {"cases": len(cases),
             "max_err": {k: v["max_err"] for k, v in cases.items()},
-            # share held -> [blocks, decode walk us, dense XLA read us]
+            # share held -> [blocks, decode walk us, dense XLA read us,
+            # least us: the held bytes at 819 GB/s]
             "decode_read_us": {k: v["us"] for k, v in cases.items()
                                if "us" in v},
             # cell.call -> shape, tiles, live units of units, us, least us
@@ -926,7 +974,8 @@ def kernels_phase(_sizes, _devices, _require_chip,
 
 
 PHASES = {"train": train_phase, "serve": serve_phase, "moe": moe_phase,
-          "gdn": gdn_phase, "mla": mla_phase, "kernels": kernels_phase}
+          "gdn": gdn_phase, "mla": mla_phase, "conv": conv_phase,
+          "kernels": kernels_phase}
 
 
 def run(sizes: Optional[SmokeSizes] = None, require_chip: bool = True,

@@ -307,6 +307,54 @@ def _deepseek_v3_rules() -> List[Rule]:
     ]
 
 
+def _lfm2_moe_rules() -> List[Rule]:
+    # LFM2-MoE (``model_type: lfm2_moe``; LFM2-24B-A2B) -> RaggedLfm2's tree.
+    # The head is tied to the embedding: a checkpoint's ``lm_head.weight``
+    # (safetensors writes tied tensors once, torch.save twice) is skipped.
+    def layer(m, *leaf):
+        return (f"layers_{m.group(1)}", *leaf)
+
+    ffn = {"w1": "gate", "w3": "up", "w2": "down"}
+    return [
+        (r"^model\.embed_tokens\.weight$",
+         lambda m: (("embed_tokens", "embedding"), None)),
+        (r"^model\.embedding_norm\.weight$",
+         lambda m: (("norm", "scale"), None)),
+        (r"^lm_head\.weight$", lambda m: (None, None)),
+        (r"^model\.layers\.(\d+)\.(operator_norm|ffn_norm)\.weight$",
+         lambda m: (layer(m, m.group(2), "scale"), None)),
+        # in_proj's outputs are B | C | x in that order, as RaggedLfm2 reads
+        (r"^model\.layers\.(\d+)\.conv\.(in_proj|out_proj)\.weight$",
+         lambda m: (layer(m, "conv", m.group(2), "kernel"), "t")),
+        # [channels, 1, taps] -> [taps, channels], the last tap on the
+        # current token either way
+        (r"^model\.layers\.(\d+)\.conv\.conv\.weight$",
+         lambda m: (layer(m, "conv", "conv1d", "kernel"),
+                    lambda w, _c: np.asarray(w)[:, 0, :].T)),
+        (r"^model\.layers\.(\d+)\.self_attn\.(q|k|v)_proj\.weight$",
+         lambda m: (layer(m, "self_attn", f"{m.group(2)}_proj", "kernel"),
+                    "t")),
+        (r"^model\.layers\.(\d+)\.self_attn\.out_proj\.weight$",
+         lambda m: (layer(m, "self_attn", "o_proj", "kernel"), "t")),
+        (r"^model\.layers\.(\d+)\.self_attn\.(q|k)_layernorm\.weight$",
+         lambda m: (layer(m, "self_attn", f"{m.group(2)}_norm", "scale"),
+                    None)),
+        (r"^model\.layers\.(\d+)\.feed_forward\.(w1|w2|w3)\.weight$",
+         lambda m: (layer(m, "mlp", f"{ffn[m.group(2)]}_proj", "kernel"),
+                    "t")),
+        (r"^model\.layers\.(\d+)\.feed_forward\.gate\.weight$",
+         lambda m: (layer(m, "mlp", "gate", "wg", "kernel"), "t")),
+        (r"^model\.layers\.(\d+)\.feed_forward\.expert_bias$",
+         lambda m: (layer(m, "mlp", "gate", "e_score_correction_bias"),
+                    None)),
+        (r"^model\.layers\.(\d+)\.feed_forward\.experts\.(\d+)\."
+         r"(w1|w2|w3)\.weight$",
+         lambda m: (layer(m, "mlp", "experts", f"w_{ffn[m.group(3)]}"),
+                    ("stack", int(m.group(2))))),
+        (r".*rotary_emb\.inv_freq$", lambda m: (None, None)),
+    ]
+
+
 def _gpt2_rules() -> List[Rule]:
     # GPT-2 Conv1D weights are already [in, out] — no transpose
     return [
@@ -530,6 +578,7 @@ _ARCH_RULES: Dict[str, Callable[[], List[Rule]]] = {
     "olmoe": _olmoe_rules,
     "qwen3_next": _qwen3_next_rules,
     "deepseek_v3": _deepseek_v3_rules,
+    "lfm2_moe": _lfm2_moe_rules,
     "gpt2": _gpt2_rules,
     "opt": _opt_rules,
     "falcon": _falcon_rules,
@@ -649,6 +698,25 @@ def config_from_hf(model_path: str, dtype: Any = None):
         # refuses each by name)
         return arch, DeepseekV3Config(
             **{k: v for k, v in cfg.items() if k in fields}, dtype=dt)
+    if arch == "lfm2_moe":
+        from deepspeed_tpu.inference.v2.model_implementations. \
+            ragged_lfm2 import Lfm2Config
+
+        rope = cfg.get("rope_parameters") or {}
+        if rope.get("rope_type", "default") != "default" \
+                or cfg.get("rope_scaling") is not None:
+            raise HFLoadError(
+                "lfm2_moe: a scaled rotary embedding is not implemented "
+                "(LFM2-24B-A2B has rope_type default)")
+        fields = {f.name for f in dataclasses.fields(Lfm2Config)} \
+            - {"dtype", "rope_theta"}
+        # (conv_bias, use_expert_bias false: the config refuses each by
+        # name; tie_embedding is the family's key for the tied head)
+        return arch, Lfm2Config(
+            **{k: v for k, v in cfg.items() if k in fields},
+            rope_theta=float(rope.get("rope_theta",
+                                      cfg.get("rope_theta", 1e6))),
+            dtype=dt)
     if arch == "gpt2":
         from deepspeed_tpu.models.gpt2 import GPT2Config
 

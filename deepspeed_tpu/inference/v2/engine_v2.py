@@ -486,8 +486,11 @@ class InferenceEngineV2:
                 # useful tokens of the rows they are padded to
                 span.attrs = {"tokens": self._batch.current_tokens,
                               "bucket": bucket}
-                latent = bool(sm.kv_cache.kv_row)
-                if self._stateful or latent:
+                # a model that states its own pool row: a latent one
+                # (no k / v leaves), or keys and values laid flat
+                row = sm.kv_cache.kv_row or {}
+                latent = bool(row) and "k" not in row
+                if self._stateful or row:
                     # sequences with a chunk in the tile segment here, and
                     # those chunks' (start, tokens)
                     tiled = [(s.seen_tokens, n) for s, n in zip(
@@ -497,21 +500,23 @@ class InferenceEngineV2:
                                       chunk_tokens=sum(n for _, n in tiled))
                 if self._stateful:
                     span.attrs["state_slots"] = sm.state_pool.held
+                if row:
+                    # what the one-token read (the decode walk, the
+                    # absorbed read) must do: the table blocks the batch's
+                    # one-token rows hold up to the position they feed
+                    bs = sm.block_size
+                    span.attrs["row_blocks"] = sum(
+                        s.seen_tokens // bs + 1 for s, n in zip(
+                            self._batch.sequences,
+                            self._batch.chunk_sizes) if n == 1)
                 if latent:
                     # what the expanded read must do: the causal (query,
                     # key) pairs of the chunks, and the context rows to
-                    # expand (each chunk's end position); what the
-                    # absorbed read must: the table blocks the batch's
-                    # one-token rows hold up to the position they feed
-                    bs = sm.block_size
+                    # expand (each chunk's end position)
                     span.attrs.update(
                         attn_pairs=sum(n * (2 * a + n + 1) // 2
                                        for a, n in tiled),
-                        ctx_rows=sum(a + n for a, n in tiled),
-                        row_blocks=sum(
-                            s.seen_tokens // bs + 1 for s, n in zip(
-                                self._batch.sequences,
-                                self._batch.chunk_sizes) if n == 1))
+                        ctx_rows=sum(a + n for a, n in tiled))
         # host↔device alignment: a jax.profiler capture shows this named
         # bracket on the host track lined up with the XLA execution it
         # dispatched
@@ -1330,6 +1335,15 @@ class InferenceEngineV2:
                     "RaggedQwen3Next does not support tensor parallelism "
                     "yet — pass mesh=None")
             model = RaggedQwen3Next(mcfg, block_size)
+        elif arch == "lfm2_moe":
+            from deepspeed_tpu.inference.v2.model_implementations. \
+                ragged_lfm2 import RaggedLfm2
+
+            if mesh is not None and mesh.shape.get("model", 1) > 1:
+                raise ValueError(
+                    "RaggedLfm2 does not support tensor parallelism yet "
+                    "— pass mesh=None")
+            model = RaggedLfm2(mcfg, block_size)
         elif arch == "deepseek_v3":
             from deepspeed_tpu.inference.v2.model_implementations. \
                 ragged_deepseek_v3 import RaggedDeepseekV3

@@ -1,6 +1,7 @@
 """Ragged inference kernels (reference: inference/v2/kernels/ragged_ops/)."""
 
 from deepspeed_tpu.inference.v2.kernels.blocked_flash import (
+    decode_walk_usable,
     paged_attention,
     paged_attention_usable,
     paged_decode_attention,
@@ -14,7 +15,8 @@ from deepspeed_tpu.inference.v2.kernels.latent_flash import (
     latent_prefill_attention,
 )
 
-__all__ = ["latent_decode_attention", "latent_expand",
-           "latent_prefill_attention", "paged_attention", "paged_attention_usable",
+__all__ = ["decode_walk_usable", "latent_decode_attention", "latent_expand",
+           "latent_prefill_attention", "paged_attention",
+           "paged_attention_usable",
            "paged_decode_attention", "paged_prefill_attention",
            "paged_verify_attention"]

@@ -20,8 +20,10 @@ Four kernels, by the shape of the rows they serve (the route is
   set_alignment``), so the grid is (tiles, blocks) with bf16 MXU dots on
   [tile, D] x [block, D];
 * ``_decode_kernel`` — one token a row (a decode step, or the single-token
-  rows of such a forward) at a head size the DMA walk can copy
-  (``D % 128 == 0``), whatever the pool's size: one grid step per row, a
+  rows of such a forward) on a pool the DMA walk can copy
+  (``decode_walk_usable``: ``D % 128 == 0``, or narrower heads in a flat
+  pool row ``[rows, Hkv*D]`` of whole lane tiles, the packed-heads note
+  below), whatever the pool's size: one grid step per row, a
   manual double-buffered DMA walk over the blocks the row's table holds up
   to its position, the next live row's first blocks in flight while this
   row computes;
@@ -212,11 +214,14 @@ def _pv_int8(pg, v_tiles, vs):
 
 
 def _decode_kernel(token_slot, token_pos, tables, q_ref, k_hbm, v_hbm,
-                   *refs, block_size, scale, window, quantized=False):
+                   *refs, block_size, scale, window, quantized=False,
+                   pack=0):
     # quantized mode walks the SAME block schedule over the int8 payload
     # and applies the scales inside the online-softmax update (see the
     # int8 note above) — never a separate dequantized pass, and the HBM
     # read is int8 bytes plus the small pre-gathered scale block.
+    # ``pack`` (heads narrower than a lane tile, see the packed-heads note
+    # below): the same schedule again over flat [bs, Hkv*D] blocks.
     if quantized:
         ks_ref, vs_ref, o_ref, k_buf, v_buf, sems, half_ref = refs
     else:
@@ -274,6 +279,32 @@ def _decode_kernel(token_slot, token_pos, tables, q_ref, k_hbm, v_hbm,
     half0 = half_ref[0]
     after = first_step(t + 1)             # what follows this row's last step
 
+    def advance(i):
+        """Start the copies of the step after ``i`` (this row's next, or
+        the next live row's first), wait for step ``i``'s own; its first
+        table entry and its half of the buffer."""
+        j0 = lo + i * nblk
+        half = jax.lax.rem(half0 + i, 2)
+        last = i + 1 == steps
+        copies(jnp.where(last, after[0], slot),
+               jnp.where(last, after[1], j0 + nblk),
+               jnp.where(last, after[2], hi), 1 - half, lambda c: c.start())
+        copies(slot, j0, hi, half, lambda c: c.wait())
+        return j0, half
+
+    def visible(key, j0):
+        keep = key <= pos - j0 * block_size
+        if window is not None:
+            keep = jnp.logical_and(keep,
+                                   key > pos - window - j0 * block_size)
+        return keep
+
+    if pack:
+        _packed_walk(q_ref, o_ref, k_buf, v_buf, advance, visible, steps,
+                     scale)
+        half_ref[0] = jax.lax.rem(half0 + steps, 2)
+        return
+
     h, d = q_ref.shape[1:]
     hkv = k_buf.shape[3] // d if quantized else k_buf.shape[3]
     g = h // hkv
@@ -292,13 +323,7 @@ def _decode_kernel(token_slot, token_pos, tables, q_ref, k_hbm, v_hbm,
 
     def body(i, carry):
         m_prev, l_prev, acc = carry
-        j0 = lo + i * nblk
-        half = jax.lax.rem(half0 + i, 2)
-        last = i + 1 == steps
-        copies(jnp.where(last, after[0], slot),
-               jnp.where(last, after[1], j0 + nblk),
-               jnp.where(last, after[2], hi), 1 - half, lambda c: c.start())
-        copies(slot, j0, hi, half, lambda c: c.wait())
+        j0, half = advance(i)
         if quantized:
             s = _scores_int8(qg, _head_tiles(k_buf[half, 0], hkv, d),
                              ks_ref[0, j0], scale).reshape(h, block_size)
@@ -307,11 +332,7 @@ def _decode_kernel(token_slot, token_pos, tables, q_ref, k_hbm, v_hbm,
                 q, k_buf.at[half].reshape(cols, d)[...],
                 (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale    # [H, cols]
-        keep = key <= pos - j0 * block_size
-        if window is not None:
-            keep = jnp.logical_and(keep,
-                                   key > pos - window - j0 * block_size)
-        s = jnp.where(keep, s, NEG_INF)
+        s = jnp.where(visible(key, j0), s, NEG_INF)
         m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         p = jnp.exp(s - m_new)            # every row sees a key each step
@@ -335,6 +356,105 @@ def _decode_kernel(token_slot, token_pos, tables, q_ref, k_hbm, v_hbm,
     half_ref[0] = jax.lax.rem(half0 + steps, 2)
     safe_l = jnp.where(l == 0.0, 1.0, l)
     o_ref[0] = (acc / safe_l).astype(o_ref.dtype)
+
+
+# --------------------------------------------------------------------- #
+# Packed heads: the decode walk at a head size under a lane tile (D = 64).
+#
+# A pool whose row is stored FLAT, [rows, Hkv*D] (a model states such a row
+# through ``kv_row``; 8 x 64 = 512 lanes, whole tiles), is walked in that
+# form: a table block is the contiguous [bs, Hkv*D] it is, so a held token
+# moves Hkv*D*2 B a stream and nothing more.  (The [rows, Hkv, 64] pool
+# every other model keeps is no alternative: on the chip XLA lays its 64
+# lanes out transposed, rows minor, and a kernel that wants row blocks pays
+# a relayout copy of the whole pool a call.)  A 128-lane tile of a block
+# holds ``pack = 128 // D`` KV heads side by side.  How the half-tile head
+# is multiplied: the wrapper hands in, per lane tile, the ``pack * g``
+# query heads of its KV heads, each ZERO-PADDED to 128 lanes with its values
+# in its own KV head's lanes (``_pack_queries``), so one dot of [pack*g,
+# 128] with the tile's [keys, 128] gives every head's scores against its own
+# KV head alone: the other head's lanes meet zeros.  PV is one dot with the
+# value tile, [pack*g, 128], of which a head's own D lanes are kept
+# (``_unpack_heads``).  Cost: ``pack`` times the MXU passes the mathematics
+# needs (a pass is half zeros), on a read bound by its bytes; no lane
+# shuffle, no masked product, no relayout in VMEM, and the bytes moved are
+# the held tokens'.
+# --------------------------------------------------------------------- #
+def _packed_walk(q_ref, o_ref, k_buf, v_buf, advance, visible, steps, scale):
+    """The compute of ``_decode_kernel`` on flat blocks: per lane tile its
+    own online softmax over the step's keys."""
+    tiles, heads = q_ref.shape[1:3]       # lane tiles a row, heads a tile
+    nblk, block_size, lanes = k_buf.shape[1:]
+    keys = nblk * block_size
+    key = jax.lax.broadcasted_iota(jnp.int32, (heads, keys), 1)
+
+    def body(i, carry):
+        j0, half = advance(i)
+        keep = visible(key, j0)
+        kk = k_buf.at[half].reshape(keys, lanes)
+        vv = v_buf.at[half].reshape(keys, lanes)
+        out = []
+        for j in range(tiles):
+            m_prev, l_prev, acc = carry[3 * j:3 * j + 3]
+            lane = slice(j * 128, (j + 1) * 128)
+            s = jax.lax.dot_general(
+                q_ref[0, j].astype(k_buf.dtype), kk[:, lane],
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # [heads, keys]
+            s = jnp.where(keep, s, NEG_INF)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)        # every row sees a key each step
+            corr = jnp.exp(m_prev - m_new)
+            out += [m_new, l_prev * corr + jnp.sum(p, axis=1, keepdims=True),
+                    acc * corr + jax.lax.dot_general(
+                        p.astype(v_buf.dtype), vv[:, lane],
+                        (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)]  # [heads, 128]
+        return tuple(out)
+
+    init = (jnp.full((heads, 1), NEG_INF, jnp.float32),
+            jnp.zeros((heads, 1), jnp.float32),
+            jnp.zeros((heads, 128), jnp.float32)) * tiles
+    final = jax.lax.fori_loop(0, steps, body, init)
+    for j in range(tiles):
+        l, acc = final[3 * j + 1:3 * j + 3]
+        o_ref[0, j] = (acc / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+
+def _pack_queries(q, hkv: int, pack: int):
+    """q [T, H, D] -> [T, Hkv/pack, pack*g, pack*D]: the query heads of the
+    ``pack`` KV heads of a lane tile, head ``(half, i)`` in row ``half * g +
+    i`` with its values in lanes ``[half*D, (half+1)*D)`` and zeros in the
+    rest."""
+    t, h, d = q.shape
+    eye = jnp.eye(pack, dtype=q.dtype).reshape(1, 1, pack, 1, pack, 1)
+    return (q.reshape(t, hkv // pack, pack, h // hkv, 1, d) * eye).reshape(
+        t, hkv // pack, pack * (h // hkv), pack * d)
+
+
+def _unpack_heads(o, h: int, pack: int):
+    """[T, Hkv/pack, pack*g, pack*D] -> [T, H, D]: each head's own lanes."""
+    t, tiles, rows, lanes = o.shape
+    o = o.reshape(t, tiles, pack, rows // pack, pack, lanes // pack)
+    return jnp.stack([o[:, :, i, :, i] for i in range(pack)],
+                     axis=2).reshape(t, h, lanes // pack)
+
+
+def pool_kv_heads(k_pool, d: int) -> int:
+    """KV heads of a pool: [rows, Hkv, D], or the flat row [rows, Hkv*D]
+    a model states."""
+    return k_pool.shape[1] // d if k_pool.ndim == 2 else k_pool.shape[1]
+
+
+def decode_walk_usable(d: int, k_pool) -> bool:
+    """Can ``_decode_kernel`` walk this pool?  Blocks whose lanes are whole
+    tiles: heads of 128 lanes and multiples in the [rows, Hkv, D] pool, or
+    narrower heads that divide a tile in a flat float pool of whole
+    tiles."""
+    if d % 128 == 0:
+        return True
+    return (k_pool.ndim == 2 and 128 % d == 0 and k_pool.shape[1] % 128 == 0
+            and k_pool.dtype != jnp.int8)
 
 
 def _walk_step_blocks(block_bytes: int, width: int, quantized: bool) -> int:
@@ -377,8 +497,18 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     scale = 1.0 / (d ** 0.5)
     tables = block_tables.astype(jnp.int32)
     slots = token_slot.astype(jnp.int32)
+    hkv = pool_kv_heads(k_pool, d)
+    # a flat pool row of heads under a lane tile: the packed-heads note
+    pack = 128 // d if k_pool.ndim == 2 and d % 128 else 0
+    if k_pool.ndim == 2 and not pack:     # whole-tile heads: a free view
+        k_pool = k_pool.reshape(-1, hkv, d)
+        v_pool = v_pool.reshape(-1, hkv, d)
+    out_block = (1, h, d)
+    if pack:
+        q = _pack_queries(q, hkv, pack)
+        out_block = (1,) + q.shape[1:]
     nblk = _walk_step_blocks(
-        block_size * k_pool.shape[1] * d * k_pool.dtype.itemsize,
+        block_size * hkv * d * k_pool.dtype.itemsize,
         tables.shape[1], quantized)
     operands, in_specs, scratch = _walk_operands(
         q, k_pool, v_pool, k_scale, v_scale, tables, slots, block_size,
@@ -388,20 +518,22 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         num_scalar_prefetch=3,
         grid=(s_count,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, h, d),
-                               lambda t, slot, pos, tab: (t, 0, 0)),
+        out_specs=pl.BlockSpec(
+            out_block,
+            lambda t, slot, pos, tab: (t,) + (0,) * (len(out_block) - 1)),
         # which half of the double buffer the next row's first step is in
         scratch_shapes=scratch + [pltpu.SMEM((1,), jnp.int32)],
     )
     kernel = functools.partial(_decode_kernel, block_size=block_size,
                                scale=scale, window=window,
-                               quantized=quantized)
-    return pl.pallas_call(
+                               quantized=quantized, pack=pack)
+    out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s_count, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((s_count,) + out_block[1:], q.dtype),
         interpret=bool(interpret),
         **kernel_names(kernel, op_name=False),
     )(slots, token_pos.astype(jnp.int32), tables, *operands)
+    return _unpack_heads(out, h, pack) if pack else out
 
 
 def _walk_operands(q, k_pool, v_pool, k_scale, v_scale, tables, slots,
@@ -413,13 +545,17 @@ def _walk_operands(q, k_pool, v_pool, k_scale, v_scale, tables, slots,
     its DMA semaphores.  bf16 pools walk [bs, Hkv, D] blocks; int8 pools
     the flattened-lane [bs, Hkv*D] view plus each sequence's gathered
     scale blocks (see the int8 note)."""
-    rows, hkv, d = k_pool.shape
-    nb = rows // block_size
+    nb = k_pool.shape[0] // block_size
     quantized = k_scale is not None
-    block = (block_size, hkv * d) if quantized else (block_size, hkv, d)
+    if k_pool.ndim == 2:                  # a flat row: the block as stored
+        block = (block_size, k_pool.shape[1])
+    else:
+        _rows, hkv, d = k_pool.shape
+        block = (block_size, hkv * d) if quantized else (block_size, hkv, d)
     operands = [q, k_pool.reshape(nb, *block), v_pool.reshape(nb, *block)]
     in_specs = [
-        pl.BlockSpec((1,) + q.shape[1:], lambda t, slot, pos, tab: (t, 0, 0)),
+        pl.BlockSpec((1,) + q.shape[1:],
+                     lambda t, slot, pos, tab: (t,) + (0,) * (q.ndim - 1)),
         pl.BlockSpec(memory_space=pl.ANY),
         pl.BlockSpec(memory_space=pl.ANY),
     ]
@@ -699,7 +835,7 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     [T] int32 with -1 on pad rows. Returns [T, H, D] (pad rows 0).
     """
     t_count, h, d = q.shape
-    hkv = k_pool.shape[1]
+    hkv = pool_kv_heads(k_pool, d)
     nb = k_pool.shape[0] // block_size
     s_count, b_per_seq = block_tables.shape
     nt = t_count // tile_q
@@ -974,22 +1110,47 @@ def _dslint_paged_two_segment_case():
     two_segment_case()
 
 
-def two_segment_case(tight_pool: bool = False):
+@pallas_kernel_case(
+    "paged_two_segment_d64",
+    note="the same mixed tick at 64-wide heads (32q/8kv) on a FLAT pool "
+         "row [rows, 512]: the single-token rows take the decode walk in "
+         "its packed-heads mode (two KV heads to a 128-lane tile, the "
+         "queries zero-padded into their half), the chunks the tiled "
+         "kernel's per-head slices at 64-lane offsets")
+def _dslint_paged_two_segment_d64_case():
+    two_segment_case(d=64)
+
+
+@pallas_kernel_case(
+    "paged_decode_dma_d64",
+    note="decode walk over flat [bs, Hkv*D] blocks at D = 64 (8q/2kv): "
+         "one lane tile a block, ragged positions")
+def _dslint_paged_decode_dma_d64_case():
+    bs, kp, vp, tables, slot, pos, q = _dslint_paged_setup(64)
+    flat = lambda p: p.reshape(p.shape[0], -1)
+    paged_decode_attention(q, flat(kp), flat(vp), tables, slot, pos,
+                           block_size=bs, interpret=True)
+
+
+def two_segment_case(tight_pool: bool = False, d: int = 128):
     """One two-segment batch through the kernel route (compiled on the
     chip, interpreted off it) and through the XLA composition: ``(got,
     want, mask of the real rows)``.  Shared with tools/kernel_selftest.py.
     ``tight_pool`` sizes the pool at the table extent instead of over
-    twice it: the single-token rows take the decode walk either way."""
+    twice it: the single-token rows take the decode walk either way.  At a
+    head size under a lane tile (``d`` 64) the pool row is flat, [rows,
+    Hkv*D], as a model that serves such heads states it."""
     import numpy as np
 
     from deepspeed_tpu.inference.v2.model_implementations.ragged_llama \
         import _paged_attention
 
-    bs, S, B, tile, h, hkv, d = 128, 4, 4, 128, 32, 8, 128
+    bs, S, B, tile, h, hkv = 128, 4, 4, 128, 32, 8
     nb = S * B + 1 if tight_pool else 2 * S * B + 2
     rng = np.random.default_rng(21)
+    row = (hkv, d) if d % 128 == 0 else (hkv * d,)
     pool = lambda: jnp.asarray(
-        rng.standard_normal((nb * bs, hkv, d)).astype(np.float32),
+        rng.standard_normal((nb * bs,) + row).astype(np.float32),
         jnp.bfloat16)
     kp, vp = pool(), pool()
     tables = jnp.arange(1, S * B + 1, dtype=jnp.int32).reshape(S, B)

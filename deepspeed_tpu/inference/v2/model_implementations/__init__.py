@@ -1,6 +1,7 @@
 """Inference v2 model implementations (reference:
 inference/v2/model_implementations/ — llama_v2, opt, mistral, mixtral,
-falcon families; qwen3_next has no reference counterpart)."""
+falcon families; qwen3_next, deepseek_v3 and lfm2_moe have no reference
+counterpart)."""
 
 from deepspeed_tpu.inference.v2.model_implementations.ragged_llama import (
     RaggedLlama,
@@ -20,6 +21,10 @@ from deepspeed_tpu.inference.v2.model_implementations.ragged_deepseek_v3 import 
     DeepseekV3Config,
     RaggedDeepseekV3,
 )
+from deepspeed_tpu.inference.v2.model_implementations.ragged_lfm2 import (
+    Lfm2Config,
+    RaggedLfm2,
+)
 from deepspeed_tpu.inference.v2.model_implementations.ragged_qwen3_next import (
     Qwen3NextConfig,
     RaggedQwen3Next,
@@ -30,7 +35,7 @@ from deepspeed_tpu.inference.v2.model_implementations.ragged_qwen3_next import (
 # mistral/ container reuses the llama modules the same way)
 RaggedMistral = RaggedLlama
 
-__all__ = ["DeepseekV3Config", "RaggedDeepseekV3", "Qwen3NextConfig",
-           "RaggedLlama", "RaggedMistral", "RaggedMixtral",
+__all__ = ["DeepseekV3Config", "RaggedDeepseekV3", "Lfm2Config",
+           "Qwen3NextConfig", "RaggedLfm2", "RaggedLlama", "RaggedMistral", "RaggedMixtral",
            "RaggedOPT", "RaggedFalcon", "RaggedQwen3Next",
            "ragged_param_specs", "shard_ragged_params"]

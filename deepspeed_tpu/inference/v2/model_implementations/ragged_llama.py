@@ -124,9 +124,12 @@ def _paged_attention(q, k_pool, v_pool, batch, block_size,
     kernel, grid (tokens, blocks).
 
     ``decode_mode`` (static; engine decode programs set it) asserts
-    T == S with ``token_slot == arange(S)``.  On TPU, at a head size the
-    DMA walk can copy (``D % 128 == 0``), it routes to the manual-DMA
-    decode kernel
+    T == S with ``token_slot == arange(S)``.  On TPU, on a pool the DMA
+    walk can copy (``decode_walk_usable``: heads of whole lane tiles, ``D
+    % 128 == 0``, or narrower heads in a FLAT pool row ``[rows, Hkv*D]``
+    of whole tiles that the model states through ``kv_row``; such a pool
+    arrives here 2-D and goes to the kernels as it is stored), it routes
+    to the manual-DMA decode kernel
     (:func:`deepspeed_tpu.inference.v2.kernels.paged_decode_attention`):
     each row reads exactly the blocks its table holds up to its position,
     so what the read costs follows what the rows hold (their
@@ -135,8 +138,9 @@ def _paged_attention(q, k_pool, v_pool, batch, block_size,
     when it was the cells' route (PERF.md section 7: the benchmark's
     readers match it).
 
-    Off the kernels (CPU; head sizes the walk cannot copy, the d64
-    families) a decode step takes one of two XLA compositions, which are
+    Off the kernels (CPU; pools the walk cannot copy: 64-wide heads in
+    the [rows, Hkv, D] pool) a decode step takes one of two XLA
+    compositions, which are
     also the references the kernels are tested against: the masked dense
     read of the whole pool while the pool is no larger than twice what the
     tables could hold, the gather bounded by the table extent beyond.
@@ -151,9 +155,13 @@ def _paged_attention(q, k_pool, v_pool, batch, block_size,
     if use_kernel is None:
         use_kernel = on_tpu()
     S = batch["block_tables"].shape[0]
+    # a flat pool row (the model's ``kv_row``): the walk and the tiled
+    # kernel read it as stored; everything else its per-head view
+    flat_k, flat_v = k_pool, v_pool
+    k_pool, v_pool = _head_view(k_pool, q), _head_view(v_pool, q)
     if use_kernel:
         from deepspeed_tpu.inference.v2.kernels import (
-            paged_attention, paged_attention_usable,
+            decode_walk_usable, paged_attention, paged_attention_usable,
             paged_decode_attention, paged_prefill_attention,
             paged_verify_attention)
 
@@ -161,10 +169,10 @@ def _paged_attention(q, k_pool, v_pool, batch, block_size,
             w = int(window) if window is not None else None
             meta = (batch["block_tables"], batch["token_slot"],
                     batch["token_pos"])
-            # the manual-DMA walks copy [bs, Hkv, D] pool blocks, whose
-            # lane dim D must be 128-aligned
-            walk = q.shape[-1] % 128 == 0
-            if verify_k and walk:
+            # the manual-DMA walks copy pool blocks whose lanes are whole
+            # tiles: [bs, Hkv, D] at D % 128 == 0, or a flat [bs, Hkv*D]
+            walk = decode_walk_usable(q.shape[-1], flat_k)
+            if verify_k and walk and flat_k is k_pool:
                 # speculative multi-token verify: K query rows per slot
                 # share one block walk (the fused multi-query variant of
                 # the decode kernel).  Smaller head dims fall through to
@@ -178,7 +186,7 @@ def _paged_attention(q, k_pool, v_pool, batch, block_size,
                 if walk:
                     with jax.named_scope("attn/dense_read"):
                         return paged_decode_attention(
-                            q, k_pool, v_pool, *meta, block_size=block_size,
+                            q, flat_k, flat_v, *meta, block_size=block_size,
                             window=w, k_scale=k_scale, v_scale=v_scale)
             elif quantized:
                 # prefill kernels are not scale-aware (prefill is
@@ -188,12 +196,12 @@ def _paged_attention(q, k_pool, v_pool, batch, block_size,
             elif prefill_tile:
                 tables, slot, pos = meta
                 single = _single_row_read(
-                    q[:S], k_pool, v_pool, tables, slot[:S], pos[:S],
+                    q[:S], flat_k, flat_v, tables, slot[:S], pos[:S],
                     block_size, w)
                 if q.shape[0] == S:          # no chunk longer than a token
                     return single
                 return jnp.concatenate([single, paged_prefill_attention(
-                    q[S:], k_pool, v_pool, tables, slot[S:], pos[S:],
+                    q[S:], flat_k, flat_v, tables, slot[S:], pos[S:],
                     block_size=block_size, tile_q=int(prefill_tile),
                     window=w)])
             else:
@@ -207,6 +215,13 @@ def _paged_attention(q, k_pool, v_pool, batch, block_size,
     with jax.named_scope("attn/gather_read"):
         return _gather_read(q, k_pool, v_pool, k_scale, v_scale, batch,
                             block_size, window, decode_mode)
+
+
+def _head_view(pool, q):
+    """[rows, Hkv, D] of a pool: itself, or the per-head view of a flat row
+    [rows, Hkv*D] (a model's ``kv_row``)."""
+    return pool.reshape(pool.shape[0], -1, q.shape[-1]) if pool.ndim == 2 \
+        else pool
 
 
 def _big_pool(k_pool, batch, block_size) -> bool:
@@ -223,14 +238,18 @@ def _single_row_read(q, k_pool, v_pool, tables, slot, pos, block_size,
     pool): the read a decode step takes — the manual-DMA walk over the
     blocks each row holds, in the device scope ``attn/dense_read`` —
     except that the rows' slots are in no order and pad rows (position
-    -1) sit between them, which the walk takes.  At a head size the walk
-    cannot copy (D % 128) these few rows go through the token-grid kernel
-    on a big pool and the dense XLA read on a tight one."""
-    from deepspeed_tpu.inference.v2.kernels import (paged_attention,
+    -1) sit between them, which the walk takes.  On a pool the walk
+    cannot copy (``decode_walk_usable``: narrow heads in the [rows, Hkv, D]
+    pool) these few rows go through the token-grid kernel on a big pool
+    and the dense XLA read on a tight one."""
+    from deepspeed_tpu.inference.v2.kernels import (decode_walk_usable,
+                                                    paged_attention,
                                                     paged_decode_attention)
 
     batch = {"block_tables": tables, "token_slot": slot, "token_pos": pos}
-    walk = q.shape[-1] % 128 == 0
+    walk = decode_walk_usable(q.shape[-1], k_pool)
+    if not walk:                          # a flat row off the walk
+        k_pool, v_pool = _head_view(k_pool, q), _head_view(v_pool, q)
     if not walk and _big_pool(k_pool, batch, block_size):
         return paged_attention(q, k_pool, v_pool, tables, slot, pos,
                                block_size=block_size, window=window)
@@ -479,6 +498,10 @@ def _rope_insert(q, k, v, cos, sin, layer_cache, kv_dest):
         v_scale = layer_cache["v_scale"].at[kv_dest].set(vs)
         return q, k_pool, v_pool, k_scale, v_scale, {
             "k": k_pool, "v": v_pool, "k_scale": k_scale, "v_scale": v_scale}
+    if layer_cache["k"].ndim == 2:
+        # the model states a flat pool row [rows, Hkv*D] (``kv_row``):
+        # a token's heads side by side, written as one row
+        k, v = k.reshape(k.shape[0], -1), v.reshape(v.shape[0], -1)
     k_pool = layer_cache["k"].at[kv_dest].set(
         k.astype(layer_cache["k"].dtype))
     v_pool = layer_cache["v"].at[kv_dest].set(

@@ -54,7 +54,7 @@ from deepspeed_tpu.models.mixtral import MixtralConfig
 
 
 def moe_router(x, wg, k: int, renormalize: bool = True, bias=None,
-               routed_scale: float = 1.0):
+               routed_scale: float = 1.0, norm_eps=None):
     """Router of the dropless MoE: ``x`` [T, H] (normed) x ``wg`` [H, E] in
     float32 -> (topi [T, k] int32, weights [T, k] float32).  Float32
     products as well as sums: on a TPU a float32 matmul at the default
@@ -63,7 +63,9 @@ def moe_router(x, wg, k: int, renormalize: bool = True, bias=None,
     routings on near ties for a float32 one.  Softmax then top-k; with a
     selection ``bias`` [E] (static: the router's parameters carry one) the
     sigmoid router of the DeepSeek-V3 family, its weights times
-    ``routed_scale``."""
+    ``routed_scale``; ``norm_eps`` (static) replaces the constant its
+    renormalisation adds to the sum where a family's published code has
+    another (LFM2: 1e-6)."""
     from deepspeed_tpu.ops.grouped_gemm import (exact_topk_routing,
                                                 sigmoid_bias_topk_routing)
 
@@ -71,14 +73,15 @@ def moe_router(x, wg, k: int, renormalize: bool = True, bias=None,
         logits = jnp.matmul(x.astype(jnp.float32), wg.astype(jnp.float32),
                             precision=jax.lax.Precision.HIGHEST)  # [T, E]
         if bias is not None:
+            kwargs = {} if norm_eps is None else {"norm_eps": norm_eps}
             return sigmoid_bias_topk_routing(logits, bias, k, renormalize,
-                                             routed_scale)
+                                             routed_scale, **kwargs)
         return exact_topk_routing(logits, k, renormalize)
 
 
 def dropless_moe(x, moe_params, k: int, dtype, grouped=None,
                  renormalize: bool = True, expert_start: int = 0,
-                 routed_scale: float = 1.0):
+                 routed_scale: float = 1.0, norm_eps=None):
     """Dropless top-k MoE over a flat token buffer.
 
     x: [T, H]; returns [T, H]. Router math in fp32 (reference TopKGate is
@@ -102,7 +105,8 @@ def dropless_moe(x, moe_params, k: int, dtype, grouped=None,
     where ``shared_expert_gate`` is there too) is added for every token.
     A router whose parameters carry ``e_score_correction_bias`` is the
     sigmoid router with a selection bias, its weights times
-    ``routed_scale`` (static).
+    ``routed_scale`` (static), renormalised with ``norm_eps`` (static; None:
+    the router's own constant).
     """
     from deepspeed_tpu.ops.grouped_gemm import grouped_moe_ffn
 
@@ -111,7 +115,7 @@ def dropless_moe(x, moe_params, k: int, dtype, grouped=None,
     topi, w = moe_router(
         x, wg, k, renormalize,
         bias=moe_params["gate"].get("e_score_correction_bias"),
-        routed_scale=routed_scale)                     # [T, k]
+        routed_scale=routed_scale, norm_eps=norm_eps)  # [T, k]
     e_count = wg.shape[1]
     w_gate = experts["w_gate"].astype(dtype)           # [E, H, F]
     w_up = experts["w_up"].astype(dtype)

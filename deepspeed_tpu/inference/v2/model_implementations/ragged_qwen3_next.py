@@ -157,14 +157,18 @@ def _silu(x):
     return x * jax.nn.sigmoid(x)
 
 
-def _causal_conv(u, w, conv_pool, batch, scratch: int):
+def _causal_conv(u, w, conv_pool, batch, activation=_silu):
     """Depthwise causal convolution over each chunk of a ragged batch, then
-    SiLU.  ``u`` [T, C]: this batch's inputs; ``w`` [K, C], the last tap on
-    the current token; ``conv_pool`` [slots + 1, K - 1, C]: each sequence's
-    last K - 1 inputs.  A row's earlier inputs are the rows before it in its
-    own chunk (chunks are contiguous rows) and, for a chunk's first K - 1
-    rows, the slot's tail (zeros when the chunk starts at position 0).
-    Returns ``(silu(conv) [T, C], new conv_pool)``."""
+    ``activation`` (SiLU for the Gated DeltaNet layers; None: the
+    convolution as it is, LFM2's gated short convolution).  ``u`` [T, C]:
+    this batch's inputs; ``w`` [K, C], the last tap on the current token;
+    ``conv_pool`` [slots + 1, K - 1, C]: each sequence's last K - 1 inputs,
+    its last slot the scratch one that pad rows write.  A row's earlier
+    inputs are the rows before it in its own chunk (chunks are contiguous
+    rows) and, for a chunk's first K - 1 rows, the slot's tail (zeros when
+    the chunk starts at position 0).  Returns ``(activation(conv) [T, C],
+    new conv_pool)``."""
+    scratch = conv_pool.shape[0] - 1
     t_rows, taps = u.shape[0], w.shape[0]
     start, sslot = batch["chunk_start"], batch["state_slot"]
     n = batch["logits_idx"] - start + 1               # [S] chunk lengths
@@ -193,7 +197,9 @@ def _causal_conv(u, w, conv_pool, batch, scratch: int):
     from_tail = jnp.take_along_axis(
         tail, jnp.clip(idx + taps - 1, 0, taps - 2)[:, :, None], axis=1)
     new_tail = jnp.where((idx >= 0)[:, :, None], from_u, from_tail)
-    return _silu(acc).astype(u.dtype), conv_pool.at[sslot].set(
+    if activation is not None:
+        acc = activation(acc)
+    return acc.astype(u.dtype), conv_pool.at[sslot].set(
         new_tail.astype(conv_pool.dtype))
 
 
@@ -305,7 +311,7 @@ class RaggedQwen3Next:
             u, z = qkvz[:, :cfg.conv_dim], qkvz[:, cfg.conv_dim:]
         with jax.named_scope("gdn/conv"):
             u, conv = _causal_conv(u, la["conv1d"]["kernel"],
-                                   layer_cache["conv"], batch, scratch)
+                                   layer_cache["conv"], batch)
         with jax.named_scope("gdn/rule"):
             u32 = u.astype(F32)
 

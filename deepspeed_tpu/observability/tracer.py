@@ -80,8 +80,18 @@ span                     site                        parent    attrs (counters)
                                                                the tile segment
                                                                and their
                                                                ``chunk_tokens``;
-                                                               with a latent
-                                                               row: those two,
+                                                               with a pool row
+                                                               the model states
+                                                               (``kv_row``: a
+                                                               latent row, or
+                                                               LFM2's flat k /
+                                                               v, new in PR 35):
+                                                               those two and
+                                                               ``row_blocks``
+                                                               (table blocks its
+                                                               one-token rows
+                                                               hold); with a
+                                                               latent row also
                                                                ``attn_pairs``
                                                                (causal query-key
                                                                pairs of those
@@ -89,11 +99,7 @@ span                     site                        parent    attrs (counters)
                                                                ``ctx_rows``
                                                                (their end
                                                                positions: rows
-                                                               to expand),
-                                                               ``row_blocks``
-                                                               (table blocks its
-                                                               one-token rows
-                                                               hold)
+                                                               to expand)
 ``engine/ragged_step``   the step's dispatch         prefill   the launch record
                                                                (below):
                                                                ``launch``,

@@ -437,19 +437,21 @@ def exact_topk_routing(logits: jnp.ndarray, k: int,
 
 def sigmoid_bias_topk_routing(logits: jnp.ndarray, bias: jnp.ndarray,
                               k: int, renormalize: bool = True,
-                              scale: float = 1.0):
+                              scale: float = 1.0, norm_eps: float = 1e-20):
     """Dropless router of the DeepSeek-V3 family (``scoring_func:
     sigmoid``, ``topk_method: noaux_tc``, one group): ``s =
     sigmoid(logits)`` over all experts; the experts are the top-k of ``s +
     bias`` (the learned selection bias); their weights are ``s`` at the
-    chosen experts, WITHOUT the bias, divided by their sum (``renormalize``)
-    and multiplied by ``scale`` (``routed_scaling_factor``).  Returns (topi
+    chosen experts, WITHOUT the bias, divided by their sum plus
+    ``norm_eps`` (``renormalize``; the published codes differ in the
+    constant: 1e-20 for DeepSeek-V3, 1e-6 for LFM2) and multiplied by
+    ``scale`` (``routed_scaling_factor``).  Returns (topi
     [T,k] int32, topw [T,k] fp32)."""
     s = jax.nn.sigmoid(logits.astype(jnp.float32))
     _, topi = jax.lax.top_k(s + bias.astype(jnp.float32), k)
     topw = jnp.take_along_axis(s, topi, axis=-1)
     if renormalize:
-        topw = topw / (jnp.sum(topw, axis=-1, keepdims=True) + 1e-20)
+        topw = topw / (jnp.sum(topw, axis=-1, keepdims=True) + norm_eps)
     return topi.astype(jnp.int32), topw * scale
 
 

@@ -174,6 +174,36 @@ def test_smoke_mla_phase_tiny_on_one_cpu_device(monkeypatch):
         _tiny_sizes(), jax.devices()[:1], False, chip_smoke.CompileClock())
 
 
+def test_smoke_conv_phase_tiny_on_one_cpu_device(monkeypatch):
+    """The short-convolution phase's control flow at a tiny LFM2-shaped
+    size: two requests interleaved through the scheduler (the longer one in
+    two chunks, its tails carried in a state slot), each against its own
+    reference forward; every slot given back.  bf16 against float32 at
+    hidden 64 flips routings (as the gdn phase's test says); the float32
+    parity is ``test_ragged_lfm2.py``'s."""
+    monkeypatch.setattr(chip_smoke, "GDN_LOGIT_TOL", 0.5)
+    hf = {"model_type": "lfm2_moe", "vocab_size": 256, "hidden_size": 64,
+          "intermediate_size": 96, "moe_intermediate_size": 32,
+          "num_hidden_layers": 4,
+          "layer_types": ["conv", "conv", "full_attention", "conv"],
+          "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
+          "conv_L_cache": 3, "conv_bias": False, "num_dense_layers": 2,
+          "num_experts": 8, "num_experts_per_tok": 2, "norm_topk_prob": True,
+          "use_expert_bias": True, "routed_scaling_factor": 1,
+          "norm_eps": 1e-5, "max_position_embeddings": 512,
+          "rope_parameters": {"rope_theta": 10000, "rope_type": "default"}}
+    sizes = dataclasses.replace(
+        _tiny_sizes(), token_budget=128, max_seqs=4, block_size=16,
+        conv_hf=hf, gdn_prompt_lens=(150, 40), gdn_new_tokens=(4, 7))
+    out = chip_smoke.conv_phase(sizes, jax.devices()[:1], False,
+                                chip_smoke.CompileClock())
+    assert max(out["logit_gaps"]) <= chip_smoke.GDN_LOGIT_TOL
+    assert min(out["rows_compared"]) >= 4
+    assert "decode_step" in out["kernels"]
+    assert "skipped" in chip_smoke.conv_phase(
+        _tiny_sizes(), jax.devices()[:1], False, chip_smoke.CompileClock())
+
+
 def test_smoke_gates_fail_loudly():
     """The checks that tell a chip run from a CPU or interpreter run."""
     with pytest.raises(chip_smoke.SmokeFailure, match="XLA composition"):

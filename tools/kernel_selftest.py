@@ -21,10 +21,14 @@ import sys
 
 
 #: pool blocks, KV heads, group size, head size of the serving cells'
-#: attention pools (Mistral-7B, OLMoE-1B-7B, Qwen3-Next; block size 128)
+#: attention pools (Mistral-7B, OLMoE-1B-7B, Qwen3-Next, LFM2; block size
+#: 128).  A head size under a lane tile (LFM2's 64) is served from a FLAT
+#: pool row [rows, Hkv*D], the walk in its packed-heads mode; its pool is a
+#: quarter of the cell's 3,072 blocks, which the dense oracle can hold
 DECODE_READ_CELLS = {"mistral7b": (160, 8, 4, 128),
                      "olmoe": (192, 16, 1, 128),
-                     "qwen3next": (512, 2, 8, 256)}
+                     "qwen3next": (512, 2, 8, 256),
+                     "lfm2_d64": (768, 8, 4, 64)}
 
 
 #: tokens a tick, top-k, the router's experts, the experts this share
@@ -68,9 +72,10 @@ def decode_read_case(cell: str, tol: float, layers: int = 16,
     XLA dense read (``_dense_pool_read``) on one cell's pool: 32 rows that
     hold 15%, 50% and 75% of its blocks between them (about three blocks a
     row at 15%, the rest pads).  ``max_err`` over the three; ``us`` = for
-    each share ``[blocks held, walk, dense read]``, microseconds a call:
-    ``layers`` calls a program, ``repeats`` programs dispatched back to
-    back, host clock around them."""
+    each share ``[blocks held, walk, dense read, least]``, microseconds a
+    call: ``layers`` calls a program, ``repeats`` programs dispatched back
+    to back, host clock around them; least = the held blocks' keys and
+    values at 819 GB/s."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -81,9 +86,10 @@ def decode_read_case(cell: str, tol: float, layers: int = 16,
 
     bs, rows_n, width = 128, 32, 36
     nb, hkv, g, d = DECODE_READ_CELLS[cell]
+    row = (hkv, d) if d % 128 == 0 else (hkv * d,)     # flat: narrow heads
     ks = jax.random.split(jax.random.key(30), 3)
-    k_pool = jax.random.normal(ks[0], (nb * bs, hkv, d), jnp.bfloat16)
-    v_pool = jax.random.normal(ks[1], (nb * bs, hkv, d), jnp.bfloat16)
+    k_pool = jax.random.normal(ks[0], (nb * bs,) + row, jnp.bfloat16)
+    v_pool = jax.random.normal(ks[1], (nb * bs,) + row, jnp.bfloat16)
     q = jax.random.normal(ks[2], (rows_n, hkv * g, d), jnp.bfloat16)
     slot = jnp.arange(rows_n, dtype=jnp.int32)
 
@@ -94,8 +100,9 @@ def decode_read_case(cell: str, tol: float, layers: int = 16,
     def dense(q, k_pool, v_pool, tables, pos):
         batch = {"block_tables": tables, "token_slot": slot,
                  "token_pos": pos}
-        return _dense_pool_read(q, k_pool, v_pool, None, None, batch, bs,
-                                None)
+        heads = lambda p: p.reshape(-1, hkv, d)
+        return _dense_pool_read(q, heads(k_pool), heads(v_pool), None, None,
+                                batch, bs, None)
 
     walks, denses = _stacked(walk, layers), _stacked(dense, layers)
     rng = np.random.default_rng(30)
@@ -116,7 +123,9 @@ def decode_read_case(cell: str, tol: float, layers: int = 16,
         t_dense, want = _timed(denses, layers, repeats, *args)
         err = max(err, float(jnp.max(jnp.abs(got - want)[pos >= 0]))
                   / layers)
-        us[str(share)] = [held, round(t_walk, 1), round(t_dense, 1)]
+        least = held * bs * 2 * hkv * d * 2 / 819e9 * 1e6
+        us[str(share)] = [held, round(t_walk, 1), round(t_dense, 1),
+                          round(least, 1)]
     return {"max_err": round(err, 6), "ok": bool(err < tol), "us": us}
 
 
@@ -595,6 +604,14 @@ def run_selftest(tol: float = 3e-2) -> dict:
             lambda: two_segment("paged_two_segment_walk", False))
     guarded("paged_two_segment_tight",
             lambda: two_segment("paged_two_segment_tight", True))
+
+    # the same batch at 64-wide heads (32q/8kv) on a flat pool row: the
+    # walk's packed-heads mode and the tiled kernel's 64-lane slices
+    def two_segment_d64():
+        got, want, real = two_segment_case(d=64)
+        record("paged_two_segment_d64", got[real], want[real])
+
+    guarded("paged_two_segment_d64", two_segment_d64)
 
     # the decode walk against the XLA dense read at the three serving
     # cells' pools and head layouts, 32 rows, with the time of each at
