@@ -720,7 +720,7 @@ def moe_phase(sizes: SmokeSizes, devices, require_chip: bool,
     for name, m in zip(("gmm_fwd_e64_decode", "gmm_fwd_e64_prefill"),
                        sizes.moe_gmm_rows):
         group_sizes = rng.multinomial(m, np.full(e, 1.0 / e))
-        tm, tn = _pick_tiles(m, h, f)
+        tm, tn = _pick_tiles(m, h, f, e)
         lhs = jnp.asarray(rng.standard_normal((m, h)), jnp.bfloat16)
         rhs = jnp.asarray(rng.standard_normal((e, h, f)) * h ** -0.5,
                           jnp.bfloat16)

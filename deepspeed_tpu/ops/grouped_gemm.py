@@ -34,7 +34,25 @@ two backward kernels (dlhs accumulates over n-tiles; drhs is the "tgmm" —
 per-group lhsᵀ@dout accumulated over the group's work units), wired as a
 ``custom_vjp`` so dropless MoE TRAINING differentiates through the kernel.
 
-All accumulation is fp32 in VMEM scratch regardless of input dtype.
+TILES.  Every work unit is a full ``[tile_m, K] x [K, tile_n]`` MXU pass,
+however few of its rows are the group's: a call multiplies ``m + (E - 1)
+* tile_m`` rows at worst, ``m / E + tile_m`` an expert, beside the
+expert's weights that stream in once.  A bf16 weight block has to
+multiply ``_MXU_BOUND_ROWS`` (~240 on a v5e: FLOP/s over bytes/s) rows
+before its pass takes as long as its DMA, so a DECODE tick's rows (a few
+an expert) want the SMALLEST row tile: at 512 rows the padding alone makes
+the MXU, not the weight stream, set the call's time.  The forward and the
+backward budget VMEM for different things and get their tiles from
+different rules: the forward (``_pick_tiles``) holds double-buffered lhs,
+rhs and out blocks and nothing else (its one dot covers the whole K), so
+it affords the widest ``tile_n``; the two backward kernels also hold a
+float32 accumulator (``(tile_m, K)`` for dlhs, ``(K, tile_n)`` for drhs)
+and re-read weights per work unit (dlhs), so ``gmm``'s VJP picks theirs
+itself (``_pick_backward_tiles``) from the shapes it is handed.
+
+The forward accumulates in the MXU's float32 over the whole K in one dot;
+the backward kernels accumulate in fp32 VMEM scratch regardless of input
+dtype.
 """
 
 from __future__ import annotations
@@ -131,6 +149,11 @@ def _gmm_fwd_kernel_call(lhs, rhs, group_sizes, tile_m: int, tile_n: int,
     # an order of magnitude worse at training token counts.
     grid = (n // tile_n, w)
     kernel = functools.partial(_gmm_kernel, tile_m=tile_m)
+    # tiles over the default budget (``_pick_tiles`` hands them out for one
+    # kind of N) bring the scoped limit they need
+    need = _forward_vmem(tile_m, k, tile_n, lhs.dtype.itemsize)
+    limit = {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=need + _VMEM_HEADROOM)} if need > _VMEM_BUDGET else {}
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -148,6 +171,7 @@ def _gmm_fwd_kernel_call(lhs, rhs, group_sizes, tile_m: int, tile_n: int,
         ),
         out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
         interpret=interpret,
+        **limit,
         **kernel_names(kernel),
     )(gids, mtids, rs, re_, lhs, rhs)
     # m-tiles past the last group are never visited (uninitialised) —
@@ -333,8 +357,10 @@ def gmm(lhs: jnp.ndarray, rhs: jnp.ndarray, group_sizes: jnp.ndarray,
     """Grouped matmul: rows of ``lhs`` [M, K] (sorted by group) times
     per-group ``rhs`` [E, K, N]; ``group_sizes`` [E] sums to <= M (rows
     past the last group produce zeros).  M must be divisible by tile_m
-    and N by tile_n on the kernel path.  Differentiable (custom VJP:
-    dlhs kernel + tgmm drhs kernel)."""
+    and N by tile_n on the kernel path; they are the FORWARD's tiles
+    (``_pick_tiles``).  Differentiable (custom VJP: dlhs kernel + tgmm
+    drhs kernel, at the tiles ``_pick_backward_tiles`` gives their
+    larger working sets)."""
     return _gmm_impl(lhs, rhs, group_sizes, tile_m, tile_n, interpret)
 
 
@@ -368,11 +394,17 @@ def _gmm_bwd(tile_m, tile_n, interpret, res, dout):
     lhs, rhs, group_sizes = res
     m, k = lhs.shape
     n = rhs.shape[2]
+    # the forward's tiles say whether the kernels run at all; the backward
+    # kernels get tiles of their own (their accumulators would overflow
+    # the forward's): nothing but (lhs, rhs, group_sizes) is carried over,
+    # each kernel call rebuilds its metadata from ``group_sizes``
     use, interp = _use_kernel(interpret, m, n, tile_m, tile_n)
+    bm, bn = _pick_backward_tiles(m, k, n, lhs.dtype.itemsize)
+    use = use and m % bm == 0 and n % bn == 0
     gs = group_sizes.astype(jnp.int32)
     if use:
-        dlhs = _gmm_dlhs_kernel_call(dout, rhs, gs, tile_m, tile_n, interp)
-        drhs = _gmm_drhs_kernel_call(lhs, dout, gs, tile_m, tile_n, interp)
+        dlhs = _gmm_dlhs_kernel_call(dout, rhs, gs, bm, bn, interp)
+        drhs = _gmm_drhs_kernel_call(lhs, dout, gs, bm, bn, interp)
     else:
         ends = jnp.cumsum(gs)
         starts = ends - gs
@@ -391,29 +423,97 @@ def _gmm_bwd(tile_m, tile_n, interpret, res, dout):
 gmm.defvjp(_gmm_fwd, _gmm_bwd)
 
 
-#: scoped VMEM budget for one gmm's working set (lhs + rhs + out blocks,
-#: double-buffered) — the TPU limit is 16 MiB
+#: scoped VMEM budget for one kernel's working set — the TPU's default
+#: limit is 16 MiB, which leaves the compiler 4 MiB beside the blocks
 _VMEM_BUDGET = 12 * 1024 * 1024
+_VMEM_HEADROOM = 4 * 1024 * 1024
+#: what a forward whose N has no column tile between 128 and itself may
+#: hold under a limit of its own (a v5e core has 128 MiB of VMEM)
+_VMEM_RAISED_BUDGET = 2 * _VMEM_BUDGET
+
+#: rows a bf16 weight block must multiply before its MXU pass takes as long
+#: as its DMA: 197 TFLOP/s over 819 GB/s (v5e; 2 FLOPs a row for each
+#: 2-byte weight).  Under it the weight stream hides the pass; a column
+#: tile under it leaves the row tiles' re-reads exposed the same way.
+_MXU_BOUND_ROWS = 240
 
 
-def _pick_tiles(m_dim: int, k_dim: int, n_dim: int):
-    """Widest (tile_m, tile_n) dividing (m, n) whose double-buffered
-    working set fits the scoped-VMEM budget.  Grid-step overhead
-    dominates grouped GEMM at TPU serving/training sizes, so fewer,
-    fatter steps win until VMEM caps them."""
+def _forward_vmem(tile_m: int, k_dim: int, tile_n: int,
+                  itemsize: int = 2) -> int:
+    """Bytes of ``_gmm_kernel``'s working set: double-buffered lhs, rhs and
+    out blocks.  No accumulator: one dot covers the whole K."""
+    return 2 * itemsize * (tile_m * k_dim + k_dim * tile_n
+                           + tile_m * tile_n)
+
+
+def _pick_tiles(m_dim: int, k_dim: int, n_dim: int,
+                groups: Optional[int] = None, itemsize: int = 2):
+    """(tile_m, tile_n) of the FORWARD kernel for ``[m, K] x [groups, K,
+    n]``, from static shapes alone.
+
+    ``tile_m`` from the rows an expert holds, ``m / groups`` (an upper
+    bound where the matrices are a share of the router's experts): a group
+    costs ``rows + tile_m`` rows of MXU passes, its boundary tile being
+    shared, and the passes hide under the expert's weight stream while
+    that stays under ``_MXU_BOUND_ROWS``.  Only 128, the smallest tile,
+    can stay under it, so 128 it is wherever groups are a few rows to a
+    few hundred (a decode tick's 8 rows an expert under a 512-row tile
+    multiply 520 rows for 8; over the bound every padded row is exposed
+    MXU time, so the least padding still wins) and where ``groups`` is
+    unknown.  A taller tile (the largest of 512, 256 dividing ``m``) is
+    taken only where it is an eighth of the group or less: training's few
+    wide experts with thousands of rows each, MXU-bound on their own rows,
+    where padding is a few percent and the step count matters.
+
+    ``tile_n`` is the widest lane-aligned divisor of ``n`` whose forward
+    working set (``_forward_vmem``: no accumulator) fits ``_VMEM_BUDGET``:
+    it divides the number of grid steps and of re-reads of each row tile.
+    A row tile taller than 128 that leaves a column tile under
+    ``_MXU_BOUND_ROWS`` gives way to the next smaller one; where 128 rows
+    leave one too (Moonlight's N = 1408 = 11 x 128 has no lane-aligned
+    divisor between 128 and itself: eleven walks over the work units, a
+    0.5 MB sliver of weights a step) the whole N is the tile if it fits
+    ``_VMEM_RAISED_BUDGET``, and the call brings the limit that takes
+    (0.34 -> 0.20 ms a Moonlight gate / up call; PERF.md, PR 36).  The
+    backward kernels never see these tiles (``_pick_backward_tiles``)."""
+    rows = m_dim / groups if groups else 0
+    widths = [tn for tn in range(n_dim - n_dim % 128, 0, -128)
+              if n_dim % tn == 0]
+
+    def widest(tm):
+        return next((tn for tn in widths if _forward_vmem(
+            tm, k_dim, tn, itemsize) <= _VMEM_BUDGET), 0)
+
+    for tm in (512, 256):
+        if m_dim % tm == 0 and 8 * tm <= rows \
+                and widest(tm) >= _MXU_BOUND_ROWS:
+            return tm, widest(tm)
+    tn = widest(128)
+    if m_dim % 128 or not tn:
+        return 128, 128
+    if tn < _MXU_BOUND_ROWS and _forward_vmem(
+            128, k_dim, n_dim, itemsize) <= _VMEM_RAISED_BUDGET:
+        tn = n_dim
+    return 128, tn
+
+
+def _pick_backward_tiles(m_dim: int, k_dim: int, n_dim: int,
+                         itemsize: int = 2):
+    """(tile_m, tile_n) both backward kernels run at: the largest
+    ``tile_m`` dividing ``m``, then the widest ``tile_n`` dividing ``n``,
+    whose working set fits ``_VMEM_BUDGET`` WITH the float32 accumulator
+    on top of the three double-buffered blocks, the larger of dlhs's
+    ``(tile_m, K)`` and drhs's ``(K, tile_n)``.  Tall row tiles first: dlhs re-reads a group's
+    weights for every work unit, so its steps want as many rows as a
+    weight block can pay for, and a backward's groups are a training
+    batch's."""
     for tm in (512, 256, 128):
         if m_dim % tm:
             continue
-        # widest n-tile first: it divides the lhs re-read count (n_tiles)
         for tn in (1024, 896, 768, 640, 512, 384, 256, 128):
-            if n_dim % tn:
-                continue
-            # double-buffered bf16 blocks + the LARGER of the two backward
-            # kernels' fp32 accumulators ((tm, K) for dlhs, (K, tn) for
-            # drhs) — the same tiles drive the custom-VJP backward
-            need = (2 * 2 * (tm * k_dim + k_dim * tn + tm * tn)
+            need = (_forward_vmem(tm, k_dim, tn, itemsize)
                     + 4 * max(tm * k_dim, k_dim * tn))
-            if need <= _VMEM_BUDGET:
+            if n_dim % tn == 0 and need <= _VMEM_BUDGET:
                 return tm, tn
     return 128, 128
 
@@ -515,12 +615,13 @@ def grouped_moe_ffn(x: jnp.ndarray, topi: jnp.ndarray, topw: jnp.ndarray,
             xs = jnp.pad(xs, ((0, m_pad - m_rows), (0, 0)))
 
     with jax.named_scope("moe/experts"):
-        tm_g, tn_g = _pick_tiles(m_pad, h, f)
+        size = x.dtype.itemsize
+        tm_g, tn_g = _pick_tiles(m_pad, h, f, e, size)
         gate = gmm(xs, w_gate, group_sizes, tm_g, tn_g, interpret)
         up = gmm(xs, w_up, group_sizes, tm_g, tn_g, interpret)
         hmid = (jax.nn.silu(gate.astype(jnp.float32))
                 * up.astype(jnp.float32)).astype(x.dtype)
-        tm_d, tn_d = _pick_tiles(m_pad, f, h)
+        tm_d, tn_d = _pick_tiles(m_pad, f, h, e, size)
         down = gmm(hmid, w_down, group_sizes, tm_d, tn_d,
                    interpret)                            # [m_pad, H] sorted
     with jax.named_scope("moe/combine"):

@@ -340,3 +340,144 @@ def test_grouped_moe_ffn_differentiable():
     for gi in g:
         assert np.all(np.isfinite(np.asarray(gi)))
     assert float(jnp.abs(g[0]).sum()) > 0
+
+
+# ------------------------------------------------------------------ #
+# The tile rules (PR 36): the forward's tiles from the rows an expert
+# holds and the forward's own working set, the backward's from a working
+# set with its accumulator.
+# ------------------------------------------------------------------ #
+#: name -> (m, hidden, expert width, experts held): the four MoE serving
+#: cells' decode tick and full mixed tick (rows x top-k, padded to 128),
+#: and two training shapes (8 experts, thousands of rows each)
+RULE_SHAPES = {
+    "lfm2_decode": (512, 2048, 1536, 64),
+    "lfm2_T1152": (4608, 2048, 1536, 64),
+    "olmoe_decode": (256, 2048, 1024, 64),
+    "olmoe_T1056": (8448, 2048, 1024, 64),
+    "qwen3next_decode": (384, 2048, 512, 128),
+    "qwen3next_T1056": (10624, 2048, 512, 128),
+    "moonlight_decode": (384, 2048, 1408, 16),
+    "moonlight_T1088": (6528, 2048, 1408, 16),
+    "train_8x4096": (32768, 2048, 1024, 8),
+    "train_8x2048": (16384, 2048, 5632, 8),
+}
+#: the picks ISSUE 36 expects, (gate / up, down), where it names them;
+#: Moonlight's gate / up takes the whole N = 11 x 128 under a raised limit
+EXPECTED_TILES = {
+    "lfm2_decode": ((128, 768), (128, 1024)),
+    "lfm2_T1152": ((128, 768), (128, 1024)),
+    "olmoe_decode": ((128, 1024), (128, 2048)),
+    "olmoe_T1056": ((128, 1024), (128, 2048)),
+    "qwen3next_decode": ((128, 512), (128, 2048)),
+    "qwen3next_T1056": ((128, 512), (128, 2048)),
+    "moonlight_decode": ((128, 1408), (128, 1024)),
+    "moonlight_T1088": ((128, 1408), (128, 1024)),
+}
+
+
+@pytest.mark.parametrize("call", ["gate_up", "down"])
+@pytest.mark.parametrize("name", RULE_SHAPES)
+def test_tile_rules_arithmetic(name, call):
+    gg = grouped_gemm
+    m, h, f, e = RULE_SHAPES[name]
+    k, n = (h, f) if call == "gate_up" else (f, h)
+    tm, tn = gg._pick_tiles(m, k, n, e)
+    assert m % tm == 0 and n % tn == 0 and tn % 128 == 0
+    # the forward holds three double-buffered bf16 blocks, no accumulator
+    need = 2 * 2 * (tm * k + k * tn + tm * tn)
+    assert gg._forward_vmem(tm, k, tn) == need
+    if need > gg._VMEM_BUDGET:
+        # only the whole of an N that has no column tile between 128 and
+        # itself, at 128 rows, and the call then brings its own limit
+        assert (tm, tn) == (128, n) and need <= gg._VMEM_RAISED_BUDGET
+        assert not [d for d in range(256, n, 128) if n % d == 0
+                    and gg._forward_vmem(128, k, d) <= gg._VMEM_BUDGET]
+    if name in EXPECTED_TILES:
+        assert (tm, tn) == EXPECTED_TILES[name][call == "down"]
+    # the backward kernels get their own, each with its accumulator
+    bm, bn = gg._pick_backward_tiles(m, k, n)
+    assert m % bm == 0 and n % bn == 0
+    blocks = 4 * (bm * k + k * bn + bm * bn)
+    assert blocks + 4 * bm * k <= gg._VMEM_BUDGET          # dlhs
+    assert blocks + 4 * k * bn <= gg._VMEM_BUDGET          # drhs
+    rows = m / e
+    if name.startswith("train"):
+        # thousands of rows a group: the tall tiles the parent's rule
+        # gave (the largest dividing m that VMEM lets have a column tile)
+        assert tm >= 256 and 8 * tm <= rows and tn >= gg._MXU_BOUND_ROWS
+    elif rows + 128 <= gg._MXU_BOUND_ROWS:
+        # small groups: the padded passes stay under the weight stream
+        assert rows + tm <= gg._MXU_BOUND_ROWS
+    else:
+        assert tm == 128         # nothing hides them: the least padding
+
+
+def test_pick_tiles_without_the_groups_takes_the_smallest_row_tile():
+    """Three positional arguments keep working (chip_smoke's and the
+    benchmark's one-off callers): with the groups unknown, 128 rows."""
+    assert grouped_gemm._pick_tiles(512, 2048, 1536) == (128, 768)
+    assert grouped_gemm._pick_tiles(32768, 2048, 1024) == (128, 1024)
+    # no lane-aligned column tile, or no 128-row tile: the kernel's
+    # smallest, which ``_use_kernel`` turns into the XLA composition
+    assert grouped_gemm._pick_tiles(512, 64, 96, 4) == (128, 128)
+    assert grouped_gemm._pick_tiles(100, 64, 128, 4) == (128, 128)
+    # float32 blocks are twice the bytes
+    assert grouped_gemm._pick_tiles(512, 2048, 1536, 64, 4) == (128, 512)
+
+
+def test_forward_and_backward_run_at_different_tiles(monkeypatch):
+    """Forward at (128, 256) (64 rows a group), backward at (512, 256):
+    values and both gradients against ``gmm_reference``, and the backward
+    kernels are handed the backward's tiles, not the forward's."""
+    m, k, n, e = 512, 64, 256, 8
+    fwd = grouped_gemm._pick_tiles(m, k, n, e)
+    bwd = grouped_gemm._pick_backward_tiles(m, k, n, 4)
+    assert fwd == (128, 256) and bwd == (512, 256)
+    lhs, rhs, gs = _case(m, k, n, e, [60, 0, 130, 66, 1, 127, 100, 20],
+                         seed=36)
+    seen = {}
+    for fn in ("_gmm_fwd_kernel_call", "_gmm_dlhs_kernel_call",
+               "_gmm_drhs_kernel_call"):
+        def spy(a, b, g, tile_m, tile_n, interp, fn=fn,
+                real=getattr(grouped_gemm, fn)):
+            seen[fn] = (tile_m, tile_n)
+            return real(a, b, g, tile_m, tile_n, interp)
+        monkeypatch.setattr(grouped_gemm, fn, spy)
+
+    def loss(fn):
+        return lambda a, b: jnp.sum(fn(a, b) ** 2)
+
+    got = gmm(lhs, rhs, gs, *fwd, True)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(gmm_reference(lhs, rhs, gs)),
+                               atol=1e-5, rtol=1e-5)
+    assert np.all(np.asarray(got)[504:] == 0)
+    gk = jax.grad(loss(lambda a, b: gmm(a, b, gs, *fwd, True)),
+                  argnums=(0, 1))(lhs, rhs)
+    gr = jax.grad(loss(lambda a, b: gmm_reference(a, b, gs)),
+                  argnums=(0, 1))(lhs, rhs)
+    for g, want in zip(gk, gr):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(want),
+                                   atol=1e-4, rtol=1e-4)
+    assert np.all(np.asarray(gk[1])[1] == 0)        # the empty expert
+    assert seen == {"_gmm_fwd_kernel_call": fwd,
+                    "_gmm_dlhs_kernel_call": bwd,
+                    "_gmm_drhs_kernel_call": bwd}
+
+
+def test_whole_n_under_its_own_limit_runs():
+    """Moonlight's gate / up pick, (128, 1408) at K = 2048, is over the
+    default budget: the forward call brings ``vmem_limit_bytes`` and gives
+    the reference's values (two experts, one of them on a shared tile)."""
+    gg = grouped_gemm
+    m, k, n, e = 256, 2048, 1408, 2
+    assert gg._pick_tiles(m, k, n, e) == (128, n)
+    assert gg._VMEM_BUDGET < gg._forward_vmem(128, k, n) \
+        <= gg._VMEM_RAISED_BUDGET
+    lhs, rhs, gs = _case(m, k, n, e, [150, 90], seed=37, dtype=jnp.bfloat16)
+    got = gmm(lhs, rhs, gs, 128, n, True).astype(jnp.float32)
+    want = gmm_reference(lhs, rhs, gs).astype(jnp.float32)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=0.05, rtol=0.02)
+    assert np.all(np.asarray(got)[240:] == 0)

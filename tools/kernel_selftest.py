@@ -34,10 +34,15 @@ DECODE_READ_CELLS = {"mistral7b": (160, 8, 4, 128),
 #: tokens a tick, top-k, the router's experts, the experts this share
 #: holds, hidden and expert widths: the grouped GEMM's calls in the two
 #: cells that serve a share of the experts (a Qwen3-Next decode tick and
-#: full mixed tick, a Moonlight full mixed tick)
+#: full mixed tick, a Moonlight full mixed tick) and, since PR 36, in the
+#: two that hold every expert (a share of 64 of 64: an LFM2 decode tick
+#: and full mixed tick at 128 slots, an OLMoE decode tick)
 GMM_SHARE_CELLS = {"qwen3next_decode": (32, 10, 512, 128, 2048, 512),
                    "qwen3next_T1056": (1056, 10, 512, 128, 2048, 512),
-                   "moonlight_T1088": (1088, 6, 64, 16, 2048, 1408)}
+                   "moonlight_T1088": (1088, 6, 64, 16, 2048, 1408),
+                   "lfm2_decode": (128, 4, 64, 64, 2048, 1536),
+                   "lfm2_T1152": (1152, 4, 64, 64, 2048, 1536),
+                   "olmoe_decode": (32, 8, 64, 64, 2048, 1024)}
 
 
 def _stacked(read, layers: int):
@@ -157,7 +162,7 @@ def gmm_share_case(tol: float, layers: int = 8, repeats: int = 10) -> dict:
         total, m = int(sizes.sum()), -(-t * k_top // 128) * 128
         gs = jnp.asarray(sizes, jnp.int32)
         for call, (k, n) in (("gate_up", (h, f)), ("down", (f, h))):
-            tm, tn = _pick_tiles(m, k, n)
+            tm, tn = _pick_tiles(m, k, n, held)
             keys = jax.random.split(jax.random.key(len(calls)), layers + 1)
             lhs = jax.random.normal(keys[0], (m, k), jnp.bfloat16)
             # a layer's weights an argument each: a slice of one stacked
