@@ -570,6 +570,41 @@ def _bert_rules() -> List[Rule]:
     ]
 
 
+def _afmoe_rules() -> List[Rule]:
+    # AFMoE (``model_type: afmoe``; Arcee Trinity) -> RaggedAfmoe's tree.
+    # Two ``gate_proj`` a layer: the attention's output gate and the
+    # SwiGLU's; the router is ``mlp.router.gate`` and its selection bias the
+    # buffer ``mlp.expert_bias``; four norms a layer.
+    def layer(m, *leaf):
+        return (f"layers_{m.group(1)}", *leaf)
+
+    return _flat_moe_backbone_rules() + [
+        (r"^model\.layers\.(\d+)\.(pre_mlp_layernorm|post_mlp_layernorm)"
+         r"\.weight$", lambda m: (layer(m, m.group(2), "scale"), None)),
+        (r"^model\.layers\.(\d+)\.self_attn\.gate_proj\.weight$",
+         lambda m: (layer(m, "self_attn", "gate_proj", "kernel"), "t")),
+        (r"^model\.layers\.(\d+)\.self_attn\.(q|k)_norm\.weight$",
+         lambda m: (layer(m, "self_attn", f"{m.group(2)}_norm", "scale"),
+                    None)),
+        (r"^model\.layers\.(\d+)\.mlp\.(gate|up|down)_proj\.weight$",
+         lambda m: (layer(m, "mlp", f"{m.group(2)}_proj", "kernel"), "t")),
+        (r"^model\.layers\.(\d+)\.mlp\.router\.gate\.weight$",
+         lambda m: (layer(m, "mlp", "gate", "wg", "kernel"), "t")),
+        (r"^model\.layers\.(\d+)\.mlp\.expert_bias$",
+         lambda m: (layer(m, "mlp", "gate", "e_score_correction_bias"),
+                    None)),
+        (r"^model\.layers\.(\d+)\.mlp\.experts\.(\d+)\."
+         r"(gate|up|down)_proj\.weight$",
+         lambda m: (layer(m, "mlp", "experts", f"w_{m.group(3)}"),
+                    ("stack", int(m.group(2))))),
+        (r"^model\.layers\.(\d+)\.mlp\.shared_experts\."
+         r"(gate|up|down)_proj\.weight$",
+         lambda m: (layer(m, "mlp", "shared_expert", f"{m.group(2)}_proj",
+                          "kernel"), "t")),
+        (r".*rotary_emb\.inv_freq$", lambda m: (None, None)),
+    ]
+
+
 _ARCH_RULES: Dict[str, Callable[[], List[Rule]]] = {
     "llama": _llama_rules,
     "mistral": _llama_rules,     # same architecture/serialization
@@ -579,6 +614,7 @@ _ARCH_RULES: Dict[str, Callable[[], List[Rule]]] = {
     "qwen3_next": _qwen3_next_rules,
     "deepseek_v3": _deepseek_v3_rules,
     "lfm2_moe": _lfm2_moe_rules,
+    "afmoe": _afmoe_rules,
     "gpt2": _gpt2_rules,
     "opt": _opt_rules,
     "falcon": _falcon_rules,
@@ -717,6 +753,16 @@ def config_from_hf(model_path: str, dtype: Any = None):
             rope_theta=float(rope.get("rope_theta",
                                       cfg.get("rope_theta", 1e6))),
             dtype=dt)
+    if arch == "afmoe":
+        from deepspeed_tpu.inference.v2.model_implementations. \
+            ragged_afmoe import AfmoeConfig
+
+        fields = {f.name for f in dataclasses.fields(AfmoeConfig)} \
+            - {"dtype", "held_experts", "expert_start"}
+        # (n_group / topk_group > 1, rope_scaling, another score_func, a
+        # tied head: the config refuses each by name)
+        return arch, AfmoeConfig(
+            **{k: v for k, v in cfg.items() if k in fields}, dtype=dt)
     if arch == "gpt2":
         from deepspeed_tpu.models.gpt2 import GPT2Config
 

@@ -34,6 +34,7 @@ from deepspeed_tpu.inference.v2.model_implementations.ragged_llama import (
     shard_ragged_params,
 )
 from deepspeed_tpu.inference.v2.ragged import (DSStateManager,
+                                               KVGroupsError,
                                                RaggedBatchWrapper,
                                                RecurrentStateError)
 from deepspeed_tpu.observability.tracer import SpanHandle, open_span
@@ -42,19 +43,28 @@ from deepspeed_tpu.utils.logging import log_dist
 
 
 def _device_decode_batch(tables, pos, tok, block_size: int,
-                         max_blocks: int, state_slot=None):
+                         max_blocks: int, state_slot=None, tables_win=None):
     """Ragged batch dict for a one-token-per-slot decode round, with the
     KV write target derived ON DEVICE from the block tables — the single
     source of the per-step decode metadata contract (shared by the
     scanned ``decode_loop`` body and the per-call ``decode_step``).
     ``state_slot`` ([S], a model with recurrent state): each row's slot of
-    the state pool; every row is its own chunk of one token."""
+    the state pool; every row is its own chunk of one token.
+    ``tables_win`` ([S, B], a model with ``kv_groups``): the window
+    group's tables, and the write target in its pools beside them."""
     S = tables.shape[0]
     if state_slot is not None:
         return {**_device_decode_batch(tables, pos, tok, block_size,
-                                       max_blocks),
+                                       max_blocks, tables_win=tables_win),
                 "state_slot": state_slot,
                 "chunk_start": jnp.arange(S, dtype=jnp.int32)}
+    if tables_win is not None:
+        win = _device_decode_batch(tables_win, pos, tok, block_size,
+                                   max_blocks)
+        return {**_device_decode_batch(tables, pos, tok, block_size,
+                                       max_blocks),
+                "block_tables_win": tables_win,
+                "kv_dest_win": win["kv_dest"]}
     slot = jnp.arange(S, dtype=jnp.int32)
     blk = jnp.take_along_axis(
         tables, jnp.clip(pos // block_size, 0, max_blocks - 1)[:, None],
@@ -109,6 +119,19 @@ def _pack_tables_positions(seqs, max_seqs: int, max_blocks: int):
     return tables, pos
 
 
+def _pack_window_tables(seqs, max_seqs: int, max_blocks: int):
+    """Host-side [S, B] tables of the window group (``kv_groups``): each
+    sequence's live entries, trash below and past them."""
+    from deepspeed_tpu.inference.v2.ragged.blocked_allocator import (
+        BlockedAllocator)
+
+    tables = np.full((max_seqs, max_blocks), BlockedAllocator.TRASH_BLOCK,
+                     np.int32)
+    for i, seq in enumerate(seqs):
+        seq.write_window_row(tables[i])
+    return tables
+
+
 def _named(fn, name: str):
     """``fn`` under the name its jitted program is to carry: the XLA
     module (``jit_decode_step``), the profiler's module line and the head
@@ -156,11 +179,18 @@ class InferenceEngineV2:
                 f"cached positions: {type(model).__name__} keeps recurrent "
                 f"state a skipped position would never reach, and state "
                 f"snapshots at block boundaries are not implemented")
+        # a model whose KV layers are in two groups (window and global
+        # layers) says so: the state manager then keeps two pools behind
+        # two block tables a sequence, every step program is handed both,
+        # and the paths that assume one table refuse
+        kv_groups = getattr(model, "kv_groups", None)
+        self._grouped = kv_groups is not None
         self.state_manager = DSStateManager(
             sm_cfg, kv_cfg, num_layers=model.num_layers,
             num_kv_heads=model.num_kv_heads, head_dim=model.head_dim,
             dtype=getattr(model.config, "dtype", None),
-            state_spec=state_spec, kv_row=getattr(model, "kv_row", None))
+            state_spec=state_spec, kv_row=getattr(model, "kv_row", None),
+            **({"kv_groups": kv_groups} if self._grouped else {}))
         if self.state_manager.kv_cache.quantized:
             if not getattr(model, "supports_quantized_kv", False):
                 raise ValueError(
@@ -189,7 +219,8 @@ class InferenceEngineV2:
             max_blocks=self._max_blocks,
             block_size=kv_cfg.block_size,
             state_scratch=(self.state_manager.state_pool.scratch
-                           if self._stateful else None))
+                           if self._stateful else None),
+            window=self.state_manager.window)
         # Tensor parallelism (reference inference/v2/model_implementations/
         # sharding/): the model is mesh-bound -> place params by the
         # Megatron split rules and the KV pool kv-head-split, so the
@@ -220,6 +251,8 @@ class InferenceEngineV2:
         #: device-resident decode metadata (block tables + positions),
         #: re-uploaded only when the host scheduler changes a table
         self._dev_decode_state: Optional[Dict[str, Any]] = None
+        #: ``sm.win_released`` as of the last span that reported it
+        self._win_reported = 0
         log_dist(
             f"InferenceEngineV2: token_budget={sm_cfg.max_ragged_batch_size} "
             f"max_seqs={sm_cfg.max_ragged_sequence_count} "
@@ -297,6 +330,15 @@ class InferenceEngineV2:
                 self.state_manager.get_sequence(u) is None
                 for u in uids) > self.state_manager.state_pool.free:
             return False            # a new sequence needs a state slot
+        sm = self.state_manager
+        if self._grouped:
+            # (more sequences tracked than one forward has rows; what any
+            # of them holds below its band counts for nothing)
+            sm.release_windows()
+            if sum(sm.window_blocks_needed(sm.get_sequence(u), n)
+                   for u, n in zip(uids, lengths)) \
+                    > sm.win_allocator.free_blocks:
+                return False        # the window group's pool binds
         return blocks <= self.state_manager.free_blocks
 
     def attach_prefix(self, uid: int, tokens: Sequence[int]) -> int:
@@ -308,6 +350,8 @@ class InferenceEngineV2:
         calls this at admission so SplitFuse chunking starts past the
         cached span."""
         seq = self.state_manager.get_or_create_sequence(uid)
+        # (off for a model with kv_groups: the state manager refuses the
+        # prefix cache where it is built, so this is a miss)
         return self.state_manager.attach_prefix(
             seq, [int(t) for t in tokens])
 
@@ -379,10 +423,10 @@ class InferenceEngineV2:
                 unpack_metadata)
 
             S, B = self._batch.max_seqs, self._max_blocks
-            extra = (True,) if self._stateful else ()
+            fields = {"state": self._stateful, "win": self._grouped}
 
             def run(params, cache, packed):
-                batch = unpack_metadata(packed, bucket, S, B, *extra)
+                batch = unpack_metadata(packed, bucket, S, B, **fields)
                 logits, new_cache = self.model(params, cache, batch,
                                                prefill_tile=prefill_tile)
                 with jax.named_scope("sample_argmax"):
@@ -434,6 +478,8 @@ class InferenceEngineV2:
                 f"{self.PREFILL_TILE}")
         scheduled: List[int] = []
         drained: List[bool] = []
+        if self._grouped:           # what fell out of the bands, first
+            sm.release_windows()
         for uid in uids:
             seq = sm.get_sequence(uid)
             if seq is None or not seq.pending:
@@ -500,6 +546,12 @@ class InferenceEngineV2:
                                       chunk_tokens=sum(n for _, n in tiled))
                 if self._stateful:
                     span.attrs["state_slots"] = sm.state_pool.held
+                if self._grouped:
+                    rows = list(zip(self._batch.sequences,
+                                    self._batch.chunk_sizes))
+                    span.attrs.update(self._window_counters(
+                        [s for s, n in rows if n == 1],
+                        [(s.seen_tokens, n) for s, n in rows if n > 1]))
                 if row:
                     # what the one-token read (the decode walk, the
                     # absorbed read) must do: the table blocks the batch's
@@ -606,6 +658,8 @@ class InferenceEngineV2:
                 span.attrs = {"seqs": n}    # live rows of the S it runs
                 if self._stateful:
                     span.attrs["state_slots"] = sm.state_pool.held
+                if self._grouped:
+                    span.attrs.update(self._window_counters(seqs))
         try:
             with open_span(self.tracer, "engine/decode_step") as span:
                 step = self._get_decode_step()
@@ -660,6 +714,11 @@ class InferenceEngineV2:
         max_context = self.config.state_manager.max_context
         seqs = []
         tables_changed = False
+        if self._grouped:
+            # (a release alone uploads nothing: the entries it leaves in
+            # the device's table lie below every later row's band, where
+            # no read goes, and turn to trash at the next upload)
+            sm.release_windows()
         for uid in uids:
             seq = sm.get_sequence(uid)
             if seq is None or seq.pending:
@@ -669,16 +728,20 @@ class InferenceEngineV2:
             if seq.seen_tokens + 1 > max_context:
                 raise RuntimeError(
                     f"decode_step: sequence {uid} would exceed max_context")
-            before = len(seq.blocks)
+            before = len(seq.blocks), len(seq.win_blocks)
             sm.maybe_allocate_kv(seq, 1)
-            tables_changed |= len(seq.blocks) != before
+            tables_changed |= (len(seq.blocks),
+                               len(seq.win_blocks)) != before
             seqs.append(seq)
         from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import (
-            RAGGED_DEBUG, validate_ragged_metadata)
+            RAGGED_DEBUG, validate_ragged_metadata, validate_window_tables)
 
         if RAGGED_DEBUG:
             validate_ragged_metadata(seqs, [np.empty(1)] * len(seqs),
                                      sm.block_size)
+            if self._grouped:
+                validate_window_tables(seqs, [np.empty(1)] * len(seqs),
+                                       sm.block_size, sm.window)
         state = self._dev_decode_state
         key = (tuple(uids), tuple(s.seen_tokens for s in seqs))
         if state is None or tables_changed or state["key"] != key:
@@ -690,6 +753,49 @@ class InferenceEngineV2:
             raise RecurrentStateError(
                 f"{path}: {type(self.model).__name__} keeps per-sequence "
                 f"recurrent state; {why}")
+
+    def _refuse_grouped(self, path: str, why: str) -> None:
+        if self._grouped:
+            raise KVGroupsError(
+                f"{path}: {type(self.model).__name__} keeps window and "
+                f"global KV layers behind two block tables a sequence "
+                f"(kv_groups); {why}")
+
+    def _window_counters(self, single_rows, chunks=()) -> Dict[str, int]:
+        """The window group's counters on a dispatch's span: its pool's
+        blocks and those held now, those released since the span before
+        (``release_window``), and what this dispatch's reads must do in
+        each kind of layer.  Its one-token rows (``single_rows``: their
+        sequences): ``read_blocks``, the table blocks they hold up to the
+        position they feed (every global layer reads them), and
+        ``read_blocks_win``, those of them inside the band, times the
+        window layers.  Its longer chunks (``chunks``: (start, tokens)):
+        ``attn_pairs``, their causal (query, key) pairs (a global layer's),
+        and ``attn_pairs_win``, the pairs inside the band (a window
+        layer's)."""
+        sm = self.state_manager
+        bs, win = sm.block_size, sm.window
+        alloc = sm.win_allocator
+        released, self._win_reported = \
+            sm.win_released - self._win_reported, sm.win_released
+        out = {
+            "win_pool_blocks": alloc.num_blocks - 1,
+            "win_blocks_held": alloc.num_blocks - 1 - alloc.free_blocks,
+            "win_blocks_released": released,
+            "read_blocks": sum(s.seen_tokens // bs + 1 for s in single_rows),
+            "read_blocks_win": len(sm.kv_cache.window_layers) * sum(
+                s.seen_tokens // bs + 1 - sm._window_first(s.seen_tokens)
+                for s in single_rows)}
+        if chunks:
+            out["attn_pairs"] = sum(n * (2 * a + n + 1) // 2
+                                    for a, n in chunks)
+            # a query at t sees min(t + 1, window) keys of the band: the
+            # first ``ramp`` queries of a chunk see all t + 1
+            ramps = [max(0, min(a + n, win - 1) - a) for a, n in chunks]
+            out["attn_pairs_win"] = sum(
+                r * (2 * a + r + 1) // 2 + (n - r) * win
+                for (a, n), r in zip(chunks, ramps))
+        return out
 
     def _recover_donated_cache(self) -> None:
         """A jitted step that donates the KV cache raised after donation
@@ -727,6 +833,9 @@ class InferenceEngineV2:
                             self.state_manager.state_pool.scratch, np.int32)
             slots[:len(seqs)] = [s.state_slot for s in seqs]
             slots = (jnp.asarray(slots),)
+        if self._grouped:       # the window group's tables
+            slots += (jnp.asarray(_pack_window_tables(
+                seqs, self._batch.max_seqs, self._max_blocks)),)
         state = {"tables": jnp.asarray(tables), "pos": jnp.asarray(pos),
                  "slots": slots, "key": key}
         self._dev_decode_state = state
@@ -740,8 +849,14 @@ class InferenceEngineV2:
         B = self._max_blocks
         bs = self.state_manager.block_size
 
+        # what ``_upload_decode_state`` appends: the state slots (a model
+        # with recurrent state), the window group's tables (kv_groups)
+        names = ("state_slot",) * self._stateful \
+            + ("tables_win",) * self._grouped
+
         def run(params, cache, tables, pos, tok, *slots):
-            batch = _device_decode_batch(tables, pos, tok, bs, B, *slots)
+            batch = _device_decode_batch(tables, pos, tok, bs, B,
+                                         **dict(zip(names, slots)))
             logits, new_cache = self.model(params, cache, batch, decode=True)
             with jax.named_scope("sample_argmax"):
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -790,6 +905,9 @@ class InferenceEngineV2:
         self._refuse_stateful(
             "verify_step", "rejected lookahead tokens would have advanced "
             "the state and cannot be rolled back")
+        self._refuse_grouped(
+            "verify_step", "the K-rows-a-sequence verify read and "
+            "commit_verified's block trim know one table")
         if self.state_manager.kv_cache.kv_row:
             raise NotImplementedError(
                 f"verify_step: {type(self.model).__name__} keeps a latent "
@@ -941,6 +1059,9 @@ class InferenceEngineV2:
         self._refuse_stateful(
             "decode_loop", "the scanned program does not carry state slots; "
             "decode_step does")
+        self._refuse_grouped(
+            "decode_loop", "the scanned program carries one table and "
+            "releases nothing between its steps; decode_step does")
         if len(tokens) != len(uids):
             raise ValueError(
                 f"decode_loop: {len(uids)} uids but {len(tokens)} tokens")
@@ -1062,7 +1183,8 @@ class InferenceEngineV2:
                 a.shape, a.dtype, sharding=getattr(a, "sharding", None))
 
         ints = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
-        state = (ints(S),) if self._stateful else ()
+        state = ((ints(S),) if self._stateful else ()) \
+            + ((ints(S, B),) if self._grouped else ())
         if key == ("decode_step",):
             args = (ints(S, B), ints(S), ints(S)) + state
         elif key[0] == "verify_step":
@@ -1073,7 +1195,8 @@ class InferenceEngineV2:
             from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import (
                 packed_length)
 
-            args = (ints(packed_length(key[0], S, B, self._stateful)),)
+            args = (ints(packed_length(key[0], S, B, self._stateful,
+                                       self._grouped)),)
         return self._steps[key].lower(
             jax.tree_util.tree_map(sds, self.params),
             jax.tree_util.tree_map(sds, self.state_manager.kv_cache.cache),
@@ -1142,6 +1265,10 @@ class InferenceEngineV2:
                 "flush_to_host(include_kv=True)", "the handoff payload "
                 "carries KV rows only; without it the sequence is "
                 "recomputed from a zeroed slot")
+            self._refuse_grouped(
+                "flush_to_host(include_kv=True)", "the handoff payload is "
+                "the rows of one table; without it the sequence is "
+                "recomputed")
         out: Dict[int, Dict[str, Any]] = {}
         for uid in uids:
             seq = self.state_manager.get_sequence(uid)
@@ -1185,6 +1312,9 @@ class InferenceEngineV2:
         self._refuse_stateful(
             "resume(kv_state=...)", "a KV payload skips the positions the "
             "state has to be recomputed over; resume without it")
+        self._refuse_grouped(
+            "resume(kv_state=...)", "a KV payload fills one table's "
+            "blocks; resume without it (recompute)")
         seen = int(kv_state["seen_tokens"])
         if not 0 < seen <= len(tokens):
             raise ValueError(
@@ -1282,8 +1412,9 @@ class InferenceEngineV2:
                 quantize_groups: int = 64):
         """Serve a real HuggingFace checkpoint directory (reference: the
         MII/engine_factory path that builds a FastGen engine from a HF
-        snapshot).  Llama/Mistral/Mixtral/OLMoE/Qwen3-Next and DeepSeek-V3
-        family (Moonlight) checkpoints supported; with ``mesh`` (a non-trivial 'model' axis) weights land
+        snapshot).  Llama/Mistral/Mixtral/OLMoE/Qwen3-Next, DeepSeek-V3
+        family (Moonlight), LFM2-MoE and AFMoE (Trinity) checkpoints
+        supported; with ``mesh`` (a non-trivial 'model' axis) weights land
         PRE-SHARDED by the Megatron split rules via
         :func:`shard_ragged_params`'s specs — no full host/device copy.
 
@@ -1344,6 +1475,15 @@ class InferenceEngineV2:
                     "RaggedLfm2 does not support tensor parallelism yet "
                     "— pass mesh=None")
             model = RaggedLfm2(mcfg, block_size)
+        elif arch == "afmoe":
+            from deepspeed_tpu.inference.v2.model_implementations. \
+                ragged_afmoe import RaggedAfmoe
+
+            if mesh is not None and mesh.shape.get("model", 1) > 1:
+                raise ValueError(
+                    "RaggedAfmoe does not support tensor parallelism yet "
+                    "— pass mesh=None")
+            model = RaggedAfmoe(mcfg, block_size)
         elif arch == "deepseek_v3":
             from deepspeed_tpu.inference.v2.model_implementations. \
                 ragged_deepseek_v3 import RaggedDeepseekV3
@@ -1408,7 +1548,7 @@ class InferenceEngineV2:
         live = list(uids)
         nxt = self.put(uids, prompts, greedy=True)
         if eos_token_id is None and max_new_tokens > 1 \
-                and not self._stateful:
+                and not self._stateful and not self._grouped:
             # no early-exit needed -> device-resident decode: one dispatch
             # per decode chunk instead of one per token (grouped by
             # max_seqs — decode_loop batches at most one slot per sequence)

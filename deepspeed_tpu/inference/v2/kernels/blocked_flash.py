@@ -753,6 +753,13 @@ def paged_verify_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
 # (tiles, blocks), not (tokens, blocks): a 512-token prefill at tile 128
 # runs 4xB steps instead of 512xB.
 # ===================================================================== #
+#: scratch and blocks of the tiled kernel the default scoped limit holds
+#: with room for the compiler's own temporaries, and what is added to a
+#: larger need when the limit is raised to it
+_PREFILL_VMEM_BUDGET = 14 << 20
+_PREFILL_VMEM_HEADROOM = 8 << 20
+
+
 def _prefill_kernel(tile_slot, tile_maxpos, tables, q_ref, pos_ref, k_ref,
                     v_ref, o_ref, acc_ref, m_ref, l_ref, *, block_size,
                     num_blocks_per_seq, scale, tile_q, num_heads,
@@ -887,11 +894,19 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         _prefill_kernel, block_size=block_size,
         num_blocks_per_seq=b_per_seq, scale=scale, tile_q=tile_q,
         num_heads=h, num_kv_heads=hkv, window=window)
+    # the per-head accumulators and the double-buffered q / o / k / v
+    # blocks: 11 MB at 32 heads of 128, inside the compiler's default scoped
+    # limit (16 MiB); more heads (48: 16.4 MB) bring the limit they need
+    need = h * tile_q * (d + 256) * 4 + 2 * 2 * q.dtype.itemsize * (
+        tile_q * h * d + block_size * hkv * d)
+    limit = {"compiler_params": pltpu.CompilerParams(
+        vmem_limit_bytes=need + _PREFILL_VMEM_HEADROOM)} \
+        if need > _PREFILL_VMEM_BUDGET else {}
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t_count, h * d), q.dtype),
         interpret=bool(interpret),
-        **kernel_names(kernel, op_name=False),
+        **kernel_names(kernel, op_name=False), **limit,
     )(tile_slot, tile_maxpos, block_tables.astype(jnp.int32), qf, pos8,
       kp, vp)
     return out.reshape(t_count, h, d)

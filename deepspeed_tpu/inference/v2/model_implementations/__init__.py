@@ -1,7 +1,12 @@
 """Inference v2 model implementations (reference:
 inference/v2/model_implementations/ — llama_v2, opt, mistral, mixtral,
-falcon families; qwen3_next, deepseek_v3 and lfm2_moe have no reference
-counterpart)."""
+falcon families; qwen3_next, deepseek_v3, lfm2_moe and afmoe have no
+reference counterpart)."""
+
+from deepspeed_tpu.inference.v2.model_implementations.ragged_afmoe import (
+    AfmoeConfig,
+    RaggedAfmoe,
+)
 
 from deepspeed_tpu.inference.v2.model_implementations.ragged_llama import (
     RaggedLlama,
@@ -35,7 +40,7 @@ from deepspeed_tpu.inference.v2.model_implementations.ragged_qwen3_next import (
 # mistral/ container reuses the llama modules the same way)
 RaggedMistral = RaggedLlama
 
-__all__ = ["DeepseekV3Config", "RaggedDeepseekV3", "Lfm2Config",
-           "Qwen3NextConfig", "RaggedLfm2", "RaggedLlama", "RaggedMistral", "RaggedMixtral",
+__all__ = ["AfmoeConfig", "DeepseekV3Config", "RaggedAfmoe",
+           "RaggedDeepseekV3", "Lfm2Config", "Qwen3NextConfig", "RaggedLfm2", "RaggedLlama", "RaggedMistral", "RaggedMixtral",
            "RaggedOPT", "RaggedFalcon", "RaggedQwen3Next",
            "ragged_param_specs", "shard_ragged_params"]

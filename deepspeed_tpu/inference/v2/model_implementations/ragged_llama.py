@@ -468,18 +468,18 @@ def ragged_attention_block(lp_attn, xa, layer_cache, batch, block_size, cfg,
 
 
 def _rope_insert(q, k, v, cos, sin, layer_cache, kv_dest):
-    """Rotary on q and k, then the paged-KV scatter of this step's k and v
-    (device scope ``attn/rope_insert``).  Returns ``(q, k_pool, v_pool,
-    k_scale, v_scale, new_layer_cache)``; the scales are None on a float
-    pool."""
+    """Rotary on q and k (``cos`` None: a layer without a positional
+    embedding), then the paged-KV scatter of this step's k and v (device
+    scope ``attn/rope_insert``).  Returns ``(q, k_pool, v_pool, k_scale,
+    v_scale, new_layer_cache)``; the scales are None on a float pool."""
     # apply_rotary broadcasts over [T, H, D] with cos/sin [T, 1, D/2]
-    rot = 2 * cos.shape[-1]
+    rot = q.shape[-1] if cos is None else 2 * cos.shape[-1]
     if rot < q.shape[-1]:           # partial rotary: the first dims only
         q = jnp.concatenate([apply_rotary(q[..., :rot], cos, sin),
                              q[..., rot:]], axis=-1)
         k = jnp.concatenate([apply_rotary(k[..., :rot], cos, sin),
                              k[..., rot:]], axis=-1)
-    else:
+    elif cos is not None:
         q = apply_rotary(q, cos, sin)
         k = apply_rotary(k, cos, sin)
     # dtype-polymorphic pool (static branch: the leaf dtype is known at

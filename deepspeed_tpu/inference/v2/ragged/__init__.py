@@ -4,6 +4,7 @@ from deepspeed_tpu.inference.v2.ragged.blocked_allocator import BlockedAllocator
 from deepspeed_tpu.inference.v2.ragged.host_tier import (HostKVTier,
                                                          HostTierStats)
 from deepspeed_tpu.inference.v2.ragged.kv_cache import (BlockedKVCache,
+                                                        KVGroupsError,
                                                         dequantize_kv,
                                                         quantize_kv)
 from deepspeed_tpu.inference.v2.ragged.prefix_cache import (PrefixCacheStats,
@@ -17,7 +18,7 @@ from deepspeed_tpu.inference.v2.ragged.state_pool import (RecurrentStateError,
                                                           StateSlotPool)
 
 __all__ = ["BlockedAllocator", "BlockedKVCache", "DSStateManager",
-           "HostKVTier", "HostTierStats", "PrefixCacheStats",
+           "HostKVTier", "HostTierStats", "KVGroupsError", "PrefixCacheStats",
            "RadixPrefixCache", "RaggedBatchWrapper",
            "RecurrentStateError", "StateSlotPool",
            "DSSequenceDescriptor", "quantize_kv", "dequantize_kv"]

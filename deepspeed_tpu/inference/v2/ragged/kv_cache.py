@@ -26,6 +26,15 @@ attention): instead of ``k``/``v`` per KV head a layer holds the named
 leaves ``[num_blocks * block_size, lanes]`` behind the same allocator and
 block tables.  Every block operation is a ``tree_map`` over pool rows and
 carries such a row unchanged; int8 mode (a scale per KV head) refuses it.
+
+**Two kinds of KV layer** (``window_layers`` / ``window_blocks``, from a
+model's ``kv_groups``): the layers of the window group share a pool of their
+own length, ``window_blocks`` blocks, behind the state manager's second
+allocator and each sequence's second block table; every other KV layer keeps
+``num_blocks``.  ``num_blocks`` stays the global group's.  The block
+operations that move one block id across every layer (``copy_block``,
+``gather_blocks``, ``scatter_blocks``) are refused: an id names different
+rows in the two groups.
 """
 
 from __future__ import annotations
@@ -79,10 +88,19 @@ def dequantize_kv(q: jnp.ndarray, scale: jnp.ndarray,
             * scale[..., None].astype(jnp.float32)).astype(dtype)
 
 
+class KVGroupsError(NotImplementedError):
+    """A path that assumes ONE block table a sequence (prefix-cache attach
+    and register, its copy-on-write fork, the host tier, a KV handoff,
+    speculative verify, the scanned decode loop) was asked of a model whose
+    KV layers are in two groups (``kv_groups``: window and global layers,
+    each with its own pool and table)."""
+
+
 class BlockedKVCache:
     def __init__(self, num_layers: int, num_blocks: int, block_size: int,
                  num_kv_heads: int, head_dim: int, dtype: Any = jnp.bfloat16,
-                 kv_layers=None, kv_row=None):
+                 kv_layers=None, kv_row=None, window_layers=(),
+                 window_blocks: int = 0):
         #: the layers that hold keys and values (all of them, unless the
         #: model says which: its other layers keep state in slots, see
         #: ``state_pool.py``, and their leaves join ``cache`` beside these)
@@ -90,6 +108,10 @@ class BlockedKVCache:
                                else kv_layers)
         self.num_layers = num_layers
         self.num_blocks = num_blocks
+        #: the window group (the module doc): its layers and its pool's
+        #: blocks; none unless the model states ``kv_groups``
+        self.window_layers = tuple(window_layers)
+        self.window_blocks = int(window_blocks)
         self.block_size = block_size
         self.num_kv_heads = num_kv_heads
         self.head_dim = head_dim
@@ -105,9 +127,9 @@ class BlockedKVCache:
                 f"kv_cache.dtype=int8 on a model-stated row {self.kv_row}: "
                 f"quantize_kv keeps one scale per KV head, and a latent "
                 f"row has no head to scale by")
-        flat = num_blocks * block_size
-
-        def layer():
+        def layer(i):
+            flat = block_size * (self.window_blocks
+                                 if i in self.window_layers else num_blocks)
             if self.kv_row:
                 return {name: jnp.zeros((flat, lanes), dtype)
                         for name, lanes in self.kv_row.items()}
@@ -125,7 +147,7 @@ class BlockedKVCache:
             return leaves
 
         self.cache: Dict[str, Dict[str, jax.Array]] = {
-            f"layer_{i}": layer() for i in self.kv_layers
+            f"layer_{i}": layer(i) for i in self.kv_layers
         }
 
     # The engine threads self.cache through the jitted forward and stores the
@@ -144,6 +166,12 @@ class BlockedKVCache:
     def _pools(self) -> Dict[str, Dict[str, jax.Array]]:
         """The KV layers of ``cache``: block operations move pool rows and
         leave any state slots beside them alone."""
+        if self.window_layers:
+            raise KVGroupsError(
+                "a block operation over every KV layer (copy_block, "
+                "gather_blocks, scatter_blocks) names ONE block id, and "
+                "this cache has two pools behind two block tables "
+                "(kv_groups): the id means different rows in each")
         return {f"layer_{i}": self.cache[f"layer_{i}"]
                 for i in self.kv_layers}
 
@@ -190,17 +218,31 @@ class BlockedKVCache:
 
     @property
     def per_token_bytes(self) -> int:
-        """HBM bytes one cached token occupies across every layer — in
-        int8 mode the payload byte per element PLUS the fp32 scale record
-        per (row, head), so occupancy gauges and the roofline decode
-        bytes model never over-report bf16 bytes under quantization."""
+        """HBM bytes one cached token occupies across every layer of the
+        pool ``num_blocks`` counts (all KV layers; with two groups, the
+        global group's) — in int8 mode the payload byte per element PLUS
+        the fp32 scale record per (row, head), so occupancy gauges and the
+        roofline decode bytes model never over-report bf16 bytes under
+        quantization."""
+        return self.layer_token_bytes * (len(self.kv_layers)
+                                         - len(self.window_layers))
+
+    @property
+    def layer_token_bytes(self) -> int:
+        """HBM bytes one cached token occupies in ONE KV layer."""
         itemsize = jnp.dtype(self.dtype).itemsize
         if self.kv_row:
-            return len(self.kv_layers) * sum(self.kv_row.values()) * itemsize
+            return sum(self.kv_row.values()) * itemsize
         per_head = self.head_dim * itemsize
         if self.quantized:
             per_head += 4                       # fp32 scale per (row, head)
-        return 2 * len(self.kv_layers) * self.num_kv_heads * per_head
+        return 2 * self.num_kv_heads * per_head
+
+    @property
+    def window_pool_bytes(self) -> int:
+        """HBM bytes of the window group's pools (0 without one)."""
+        return (len(self.window_layers) * self.window_blocks
+                * self.block_size * self.layer_token_bytes)
 
 
 @partial(jax.jit, static_argnums=(3,), donate_argnums=(0,))

@@ -13,6 +13,36 @@ registered back into the tree as prefill/decode advances
 (:meth:`register_prefix`), and allocation evicts cold cache entries under
 KV pressure — ``free_blocks`` counts evictable warm blocks as free, so the
 scheduler's admission view stays truthful.
+
+**Two kinds of KV layer** (a model's ``kv_groups``: ``{"window": {"layers":
+[...], "window": W}}``; every other KV layer is global).  The global group is
+what the manager always had: ``kv_config.num_blocks`` blocks, ``allocator``,
+``seq.blocks``, ``free_blocks`` (the pool that binds admission).  The window
+group has a pool, an allocator (``win_allocator``) and a table a sequence
+(``seq.win_blocks`` from entry ``seq.win_first``) of its own.  A key at
+position ``j`` is visible to a query at ``t`` iff ``t - W < j <= t``, so once
+a sequence has ``seen_tokens`` positions cached every block wholly below
+``seen_tokens - W + 1`` is dead to it, and :meth:`release_window` returns
+those.  The engine releases every tracked sequence's (:meth:`release_windows`)
+where it builds the next batch, before it allocates for the chunks: a program
+already dispatched carries its own tables and the cache is threaded through
+the programs in order, so a released block is rewritten only
+by a later program.  **The pool's size** comes from what the manager knows,
+with no knob (:attr:`window_pool_blocks`).  Released as of its own
+``seen_tokens`` and then given ``n`` more tokens a sequence holds ``ceil((seen
++ n) / bs) - (seen - W + 1) // bs <= (W - 1 + n) // bs + 2`` blocks, and one
+forward's ``n`` sum to its token budget at most; with ``W - 1 = q x bs + r``
+that is ``max_ragged_sequence_count x (q + 2) + (max_ragged_sequence_count x
+r + budget) // bs`` blocks (+ the trash block) for the tables of as many
+sequences as one forward has rows, whatever ``max_context`` is.  That is what
+a second allocator buys over a fixed ring of blocks a sequence slot, which
+would need each slot's own worst case, the band AND a whole budget's chunk
+(:attr:`window_table_bound`: 41 blocks a sequence where the sum gives 1,095
+for 32 at W = 4,096, a budget of 1,024 and blocks of 128: 34.2 each).
+More sequences than that can be tracked, so admission still
+counts (:meth:`window_blocks_needed`).  The paths that assume one table a
+sequence (the prefix cache, the host tier, a KV handoff) refuse such a model
+(:class:`~deepspeed_tpu.inference.v2.ragged.kv_cache.KVGroupsError`).
 """
 
 from __future__ import annotations
@@ -25,7 +55,8 @@ from deepspeed_tpu.inference.v2.config_v2 import (DSStateManagerConfig,
                                                   KVCacheConfig)
 from deepspeed_tpu.inference.v2.ragged.blocked_allocator import BlockedAllocator
 from deepspeed_tpu.inference.v2.ragged.host_tier import HostKVTier
-from deepspeed_tpu.inference.v2.ragged.kv_cache import BlockedKVCache
+from deepspeed_tpu.inference.v2.ragged.kv_cache import (BlockedKVCache,
+                                                        KVGroupsError)
 from deepspeed_tpu.inference.v2.ragged.prefix_cache import RadixPrefixCache
 from deepspeed_tpu.inference.v2.ragged.sequence_descriptor import (
     DSSequenceDescriptor,
@@ -39,7 +70,7 @@ class DSStateManager:
     def __init__(self, config: DSStateManagerConfig,
                  kv_config: KVCacheConfig,
                  num_layers: int, num_kv_heads: int, head_dim: int,
-                 dtype=None, state_spec=None, kv_row=None):
+                 dtype=None, state_spec=None, kv_row=None, kv_groups=None):
         self.config = config
         self.kv_config = kv_config
         self.block_size = kv_config.block_size
@@ -70,6 +101,26 @@ class DSStateManager:
                 state_spec["leaves"])
             kwargs["kv_layers"] = [i for i in range(num_layers)
                                    if i not in set(state_spec["layers"])]
+        #: the window group (the module doc): its width in tokens, its
+        #: allocator and the blocks it has released so far; None / 0
+        #: without ``kv_groups``
+        self.window: Optional[int] = None
+        self.win_allocator: Optional[BlockedAllocator] = None
+        self.win_released = 0
+        if kv_groups is not None:
+            if getattr(kv_config, "enable_prefix_cache", False):
+                raise KVGroupsError(
+                    "kv_cache.enable_prefix_cache (attach_prefix, "
+                    "register_prefix, the copy-on-write fork, the host "
+                    "tier) shares and copies blocks by ONE id a position: "
+                    "a model with kv_groups keeps two tables a sequence, "
+                    "and a window block another request could attach to "
+                    "is released as its first owner advances")
+            win = kv_groups["window"]
+            self.window = int(win["window"])
+            self.win_allocator = BlockedAllocator(self.window_pool_blocks + 1)
+            kwargs["window_layers"] = win["layers"]
+            kwargs["window_blocks"] = self.win_allocator.num_blocks
         self.kv_cache = BlockedKVCache(num_layers, num_blocks, self.block_size,
                                        num_kv_heads, head_dim, **kwargs)
         if self.state_pool is not None:
@@ -135,6 +186,65 @@ class DSStateManager:
     def blocks_needed(self, seq: DSSequenceDescriptor, new_tokens: int) -> int:
         return seq.tokens_needed_capacity(new_tokens, self.block_size)
 
+    # ------------------------------------------------------------------ #
+    # The window group (a model with ``kv_groups``; the module doc)
+    # ------------------------------------------------------------------ #
+    @property
+    def window_table_bound(self) -> int:
+        """Most blocks a sequence's window table holds at once: those a
+        forward's chunk (at most the token budget) writes and its first
+        query still sees, ``ceil((W + budget) / block_size) + 1``."""
+        return -(-(self.window + self.config.max_ragged_batch_size)
+                 // self.block_size) + 1
+
+    @property
+    def window_pool_blocks(self) -> int:
+        """Most blocks the window tables of ``max_ragged_sequence_count``
+        sequences hold together (the module doc): each sequence's band as
+        of its own ``seen_tokens``, and one forward's tokens."""
+        bs, seqs = self.block_size, self.config.max_ragged_sequence_count
+        q, r = divmod(self.window - 1, bs)
+        return seqs * (q + 2) + (seqs * r + max(
+            self.config.max_ragged_batch_size, seqs)) // bs
+
+    def _window_first(self, seen_tokens: int) -> int:
+        """First table entry a query at ``seen_tokens`` or later can see."""
+        return max(0, seen_tokens - self.window + 1) // self.block_size
+
+    def window_blocks_needed(self, seq: Optional[DSSequenceDescriptor],
+                             new_tokens: int) -> int:
+        """Blocks the window allocator must still give for ``new_tokens``
+        more of ``seq`` (None: a sequence yet to be created) at the most,
+        after what :meth:`release_window` returns of its own: a prompt
+        longer than the budget is fed in chunks with a release between
+        them, so never more than :attr:`window_table_bound` at once."""
+        seen = seq.seen_tokens if seq else 0
+        first = self._window_first(seen)
+        end = -(-(seen + new_tokens) // self.block_size)
+        held = 0
+        if seq is not None:
+            held = len(seq.win_blocks) - min(max(first - seq.win_first, 0),
+                                             len(seq.win_blocks))
+        return max(0, min(end - first, self.window_table_bound) - held)
+
+    def release_window(self, seq: DSSequenceDescriptor) -> int:
+        """Return the window blocks of ``seq`` that no query at
+        ``seq.seen_tokens`` or later can see; how many."""
+        n = min(self._window_first(seq.seen_tokens) - seq.win_first,
+                len(seq.win_blocks))
+        if n <= 0:
+            return 0
+        self.win_allocator.free(seq.win_blocks[:n])
+        del seq.win_blocks[:n]
+        seq.win_first += n
+        self.win_released += n
+        return n
+
+    def release_windows(self) -> int:
+        """:meth:`release_window` for every tracked sequence: what the
+        pool's size counts on before a forward's chunks are allocated."""
+        return sum(self.release_window(s) for s in self._seqs.values())
+
     def _allocate(self, num_blocks: int) -> List[int]:
         """Allocate, evicting cold prefix-cache entries when the free list
         alone cannot cover the request."""
@@ -149,6 +259,11 @@ class DSStateManager:
         need = self.blocks_needed(seq, new_tokens)
         if need:
             seq.blocks.extend(self._allocate(need))
+        if self.window is not None:
+            have = seq.win_first + len(seq.win_blocks)
+            need = -(-(seq.seen_tokens + new_tokens) // self.block_size) - have
+            if need > 0:
+                seq.win_blocks.extend(self.win_allocator.allocate(need))
 
     def flush_sequence(self, uid: int) -> None:
         """reference flush: release a finished sequence's KV blocks.
@@ -159,6 +274,8 @@ class DSStateManager:
             raise ValueError(f"unknown sequence uid {uid}")
         if seq.blocks:
             self.allocator.free(seq.blocks)
+        if seq.win_blocks:
+            self.win_allocator.free(seq.win_blocks)
         if self.state_pool is not None:
             self.state_pool.release(seq.state_slot)
 

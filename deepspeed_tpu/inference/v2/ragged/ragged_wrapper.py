@@ -21,6 +21,13 @@ and, for a model with per-sequence recurrent state (``state_pool.py``)::
                                   (pad -> the scratch slot)
     chunk_start [max_seqs] int32  buffer row of the chunk's first token
 
+and, for a model whose KV layers are in two groups (``kv_groups``)::
+
+    block_tables_win [max_seqs, max_blocks] int32  the window group's
+                           table: trash below the sequence's first live
+                           entry and past its last
+    kv_dest_win      [T] int32  flat index into the window group's pools
+
 Chunks sit back to back in the buffer, or — after ``set_alignment``, which
 the engine calls whenever its token budget is a whole number of prefill
 tiles — in two segments: ``max_seqs`` rows for the chunks of one token,
@@ -110,9 +117,39 @@ def validate_ragged_metadata(seqs: List[DSSequenceDescriptor],
             owned[b] = (seq.uid, shared)
 
 
+def validate_window_tables(seqs: List[DSSequenceDescriptor],
+                           chunks: List[np.ndarray], block_size: int,
+                           window: int) -> None:
+    """The same invariants for the window group's tables (debug mode): a
+    sequence's live entries reach down to the first key its chunk's first
+    query sees and up to the chunk's last position, no block is in two
+    tables or twice in one, none is the trash block."""
+    owned = {}
+    for seq, chunk in zip(seqs, chunks):
+        lo = max(0, seq.seen_tokens - window + 1) // block_size
+        need = seq.seen_tokens + len(chunk)
+        if seq.win_first > lo or \
+                (seq.win_first + len(seq.win_blocks)) * block_size < need:
+            raise RaggedMetadataError(
+                f"sequence {seq.uid}: window table holds entries "
+                f"[{seq.win_first}, {seq.win_first + len(seq.win_blocks)}) "
+                f"but positions [{lo * block_size}, {need}) are live — a "
+                f"read or a KV write would land in a block it does not own")
+        for b in seq.win_blocks:
+            if b == TRASH or b in owned:
+                raise RaggedMetadataError(
+                    f"window block {b} of sequence {seq.uid} is the trash "
+                    f"block or already owned (by {owned.get(b)})")
+            owned[b] = seq.uid
+
+
 class RaggedBatchWrapper:
     def __init__(self, token_budget: int, max_seqs: int, max_blocks: int,
-                 block_size: int, state_scratch: int = None):
+                 block_size: int, state_scratch: int = None,
+                 window: int = None):
+        #: the window group's width in tokens (a model with ``kv_groups``):
+        #: the metadata then carries that group's tables and write targets
+        self.window = window
         #: the scratch slot of the model's recurrent-state pool, or None
         #: for a model without one (no state fields in the metadata then)
         self.state_scratch = state_scratch
@@ -215,6 +252,9 @@ class RaggedBatchWrapper:
         if RAGGED_DEBUG:
             validate_ragged_metadata(self._seqs, self._chunks,
                                      self.block_size)
+            if self.window is not None:
+                validate_window_tables(self._seqs, self._chunks,
+                                       self.block_size, self.window)
         S, B = self.max_seqs, self.max_blocks
         bs = self.block_size
         token_ids = np.zeros((T,), np.int32)
@@ -227,6 +267,9 @@ class RaggedBatchWrapper:
         context_lens = np.zeros((S,), np.int32)
         logits_idx = np.zeros((S,), np.int32)
         n_valid = len(self._seqs)
+        if self.window is not None:
+            tables_win = np.full((S, B), TRASH, np.int32)
+            kv_dest_win = np.full((T,), TRASH * bs, np.int32)
 
         for slot, (seq, chunk, cursor) in enumerate(
                 zip(self._seqs, self._chunks, self._starts)):
@@ -241,6 +284,10 @@ class RaggedBatchWrapper:
                     f"sequence {seq.uid} exceeds max_blocks {B}")
             block_tables[slot, :len(blocks)] = blocks
             kv_dest[cursor:cursor + n] = blocks[pos // bs] * bs + pos % bs
+            if self.window is not None:
+                row = tables_win[slot]
+                seq.write_window_row(row)
+                kv_dest_win[cursor:cursor + n] = row[pos // bs] * bs + pos % bs
             context_lens[slot] = seq.seen_tokens + n
             logits_idx[slot] = cursor + n - 1
 
@@ -255,6 +302,9 @@ class RaggedBatchWrapper:
             meta["chunk_start"] = np.zeros((S,), np.int32)
             meta["state_slot"][:n_valid] = [s.state_slot for s in self._seqs]
             meta["chunk_start"][:n_valid] = self._starts
+        if self.window is not None:
+            meta["block_tables_win"] = tables_win
+            meta["kv_dest_win"] = kv_dest_win
         return meta
 
     @property
@@ -275,38 +325,44 @@ _META_FIELDS = ("token_ids", "token_slot", "token_pos", "kv_dest",
                 "block_tables", "context_lens", "logits_idx")
 #: appended for a model with recurrent state
 _STATE_FIELDS = ("state_slot", "chunk_start")
+#: appended for a model with two groups of KV layers
+_WIN_FIELDS = ("block_tables_win", "kv_dest_win")
 
 
-def _fields(meta_or_flag):
-    return _META_FIELDS + (_STATE_FIELDS if meta_or_flag else ())
+def _fields(state, win=False):
+    return _META_FIELDS + (_STATE_FIELDS if state else ()) \
+        + (_WIN_FIELDS if win else ())
 
 
 def pack_metadata(meta) -> np.ndarray:
     """Flatten the finalize() dict into one int32 vector (host side)."""
     return np.concatenate(
         [np.asarray(meta[k], np.int32).ravel()
-         for k in _fields("state_slot" in meta)])
+         for k in _fields("state_slot" in meta, "kv_dest_win" in meta)])
 
 
 def packed_length(token_capacity: int, max_seqs: int, max_blocks: int,
-                  state: bool = False) -> int:
+                  state: bool = False, win: bool = False) -> int:
     """Length of the packed vector of one batch."""
-    return 4 * token_capacity + max_seqs * max_blocks \
+    return (5 if win else 4) * token_capacity \
+        + (2 if win else 1) * max_seqs * max_blocks \
         + (4 if state else 2) * max_seqs
 
 
 def unpack_metadata(packed, token_capacity: int, max_seqs: int,
-                    max_blocks: int, state: bool = False):
+                    max_blocks: int, state: bool = False,
+                    win: bool = False):
     """Rebuild the batch dict from the packed vector (inside jit)."""
     T, S, B = token_capacity, max_seqs, max_blocks
     sizes = {"token_ids": (T, (T,)), "token_slot": (T, (T,)),
              "token_pos": (T, (T,)), "kv_dest": (T, (T,)),
              "block_tables": (S * B, (S, B)),
              "context_lens": (S, (S,)), "logits_idx": (S, (S,)),
-             "state_slot": (S, (S,)), "chunk_start": (S, (S,))}
+             "state_slot": (S, (S,)), "chunk_start": (S, (S,)),
+             "block_tables_win": (S * B, (S, B)), "kv_dest_win": (T, (T,))}
     out = {}
     o = 0
-    for k in _fields(state):
+    for k in _fields(state, win):
         n, shape = sizes[k]
         out[k] = packed[o:o + n].reshape(shape)
         o += n

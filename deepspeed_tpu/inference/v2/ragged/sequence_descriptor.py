@@ -17,6 +17,12 @@ class DSSequenceDescriptor:
     done: bool = False
     #: slot of the recurrent-state pool (-1: the model keeps none)
     state_slot: int = -1
+    #: the window group's table (a model with ``kv_groups``): the live
+    #: blocks, entries ``[win_first, win_first + len(win_blocks))`` of it;
+    #: the entries below were released (their keys fell out of every future
+    #: query's band) and read as the trash block
+    win_blocks: List[int] = dataclasses.field(default_factory=list)
+    win_first: int = 0
 
     # -- prefix-cache bookkeeping (all zero when caching is off) ------- #
     #: token VALUES whose KV this sequence holds, positions [0, len);
@@ -33,6 +39,12 @@ class DSSequenceDescriptor:
     #: tree registration stopped permanently (content divergence with a
     #: concurrently registered twin, or token values lost to the device)
     register_stopped: bool = False
+
+    def write_window_row(self, row) -> None:
+        """The live window blocks into their entries of ``row`` (one row of
+        a trash-filled [S, B] table)."""
+        row[self.win_first:self.win_first + len(self.win_blocks)] = \
+            self.win_blocks
 
     @property
     def cur_allocated_blocks(self) -> int:

@@ -204,6 +204,17 @@ def kv_occupancy(state_manager) -> Dict[str, float]:
         "observability/kv_sequences_live": float(
             state_manager.n_tracked_sequences),
     }
+    win = getattr(state_manager, "win_allocator", None)
+    if win is not None:
+        # a model with window and global KV layers (kv_groups): the gauges
+        # above are the global group's; the window group's pool beside them
+        out.update({
+            "observability/kv_window_blocks_total": float(win.num_blocks - 1),
+            "observability/kv_window_blocks_live": float(
+                win.num_blocks - 1 - win.free_blocks),
+            "observability/kv_window_pool_bytes": float(
+                kv.window_pool_bytes),
+        })
     pool = getattr(state_manager, "state_pool", None)
     if pool is not None:
         # recurrent state: bytes a SEQUENCE holds whatever its length,

@@ -178,6 +178,14 @@ class ContinuousBatchScheduler:
                     "verify_step, which a model with per-sequence "
                     "recurrent state refuses: rejected lookahead tokens "
                     "cannot be rolled back out of the state")
+            if getattr(engine.state_manager, "window", None) is not None:
+                from deepspeed_tpu.inference.v2.ragged import KVGroupsError
+
+                raise KVGroupsError(
+                    "speculative decoding verifies drafts through "
+                    "verify_step, which a model with window and global KV "
+                    "layers behind two block tables (kv_groups) refuses: "
+                    "the verify read and its block trim know one table")
             if getattr(engine.state_manager.kv_cache, "kv_row", None):
                 raise NotImplementedError(
                     "speculative decoding verifies drafts through "
