@@ -20,6 +20,7 @@ host instead of a vocabulary row.
 
 from __future__ import annotations
 
+import dataclasses
 import logging
 from typing import Any, Dict, List, Optional, Sequence
 
@@ -130,6 +131,31 @@ def _pack_window_tables(seqs, max_seqs: int, max_blocks: int):
     for i, seq in enumerate(seqs):
         seq.write_window_row(tables[i])
     return tables
+
+
+@dataclasses.dataclass
+class PreparedBatch:
+    """One ragged batch built by :meth:`InferenceEngineV2.prepare` and not
+    yet on the device.  Host memory only; :meth:`~InferenceEngineV2.launch`
+    dispatches it, :meth:`~InferenceEngineV2.discard` drops it."""
+
+    #: uids in slot order, and whether this batch drains each one's queue
+    scheduled: List[int]
+    drained: List[bool]
+    #: tokens of each slot's chunk
+    chunk_sizes: List[int]
+    #: rows of the step program (with ``tile`` its ``_get_step`` key)
+    bucket: int
+    tile: Optional[int]
+    #: the packed metadata (``pack_metadata``): what ``launch`` uploads
+    packed: np.ndarray
+    #: uid -> the entry of ``packed`` that waits for a late row's token
+    late: Dict[int, int]
+    #: what ``prepare`` queued and created, and the prefix cache's attach
+    #: counters before it: what ``discard`` takes back
+    queued: Dict[int, int] = dataclasses.field(default_factory=dict)
+    created: List[int] = dataclasses.field(default_factory=list)
+    attach_stats: Optional[Dict[str, int]] = None
 
 
 def _named(fn, name: str):
@@ -384,14 +410,34 @@ class InferenceEngineV2:
         so a caller can pipeline further device work — e.g. sampling —
         before the first host sync; see also :meth:`decode_step` for the
         fully device-resident decode round.
+
+        Each forward is :meth:`prepare` and :meth:`launch` back to back; a
+        caller that has other work for the host between the two (the
+        serving scheduler, while the program before is still running)
+        calls them itself.
         """
+        self._enqueue(uids, tokens)
+        results: Dict[int, Any] = {}
+        while self._has_pending(uids):
+            results.update(self._run_one_batch(uids, sync=sync,
+                                               greedy=greedy))
+        return results
+
+    def _enqueue(self, uids, tokens) -> "tuple[Dict[int, int], List[int]]":
+        """Put ``tokens[i]`` on the pending queue of ``uids[i]`` (a new
+        sequence first skips the prefill of any cached prefix).  Returns
+        ``({uid: tokens queued}, [uids created here])``: what
+        :meth:`discard` takes back."""
         max_context = self.config.state_manager.max_context
+        queued: Dict[int, int] = {}
+        created: List[int] = []
         for uid, toks in zip(uids, tokens):
             if len(toks) == 0:
                 raise ValueError(f"put: empty token list for uid {uid}")
             fresh = self.state_manager.get_sequence(uid) is None
             seq = self.state_manager.get_or_create_sequence(uid)
             if fresh:
+                created.append(uid)
                 # new sequence: skip the prefill of any cached prefix
                 # (sequences pre-created via attach_prefix already did)
                 cached = self.state_manager.attach_prefix(seq, toks)
@@ -403,11 +449,8 @@ class InferenceEngineV2:
                     f"({seq.seen_tokens} seen + {len(seq.pending)} pending "
                     f"+ {len(toks)} new); check can_schedule()/query() first")
             seq.pending.extend(int(t) for t in toks)
-        results: Dict[int, Any] = {}
-        while self._has_pending(uids):
-            results.update(self._run_one_batch(uids, sync=sync,
-                                               greedy=greedy))
-        return results
+            queued[uid] = queued.get(uid, 0) + len(toks)
+        return queued, created
 
     def _get_step(self, bucket: int, prefill_tile: Optional[int] = None):
         """One jitted (model fwd ∘ metadata unpack ∘ argmax) program per
@@ -459,12 +502,45 @@ class InferenceEngineV2:
         tile, budget = self.PREFILL_TILE, self._batch.token_budget
         return tile if budget >= tile and budget % tile == 0 else None
 
-    def _build_batch(self, uids):
-        """One ragged batch under the token budget: SplitFuse chunking, KV
-        allocation, the metadata and its ONE upload.  Returns ``(scheduled
-        uids, drained flags, rows of the step program, prefill tile or
-        None, packed device metadata)``, or None when nothing is
+    def prepare(self, uids: Sequence[int],
+                tokens: Optional[Sequence[Sequence[int]]] = None,
+                late: Sequence[int] = ()) -> Optional["PreparedBatch"]:
+        """Build ONE ragged batch under the token budget and stop short of
+        the device: queue ``tokens[i]`` for ``uids[i]`` (as :meth:`put`
+        does; None: what is pending already), SplitFuse chunking, KV
+        allocation, window release, rows, bucket, positions, block tables,
+        state slots, the packed metadata as a numpy vector.  Nothing is
+        uploaded or dispatched and no sequence advances: ``seen_tokens``
+        moves at :meth:`launch`, and :meth:`discard` takes the queued
+        tokens back.
+
+        ``late`` names the one-token rows whose token does not exist yet
+        (the argmax of a program still running): their chunk is a
+        placeholder, and :meth:`launch` is handed the values.  Everything
+        else a row needs (its position, its KV slot, its block table) is
+        host state the moment the program before was dispatched, so a batch
+        can be prepared while that program runs.
+
+        Returns the :class:`PreparedBatch`, or None when nothing is
         pending."""
+        late = set(late)
+        stats = self.prefix_cache_stats
+        snap = stats.attach_snapshot() \
+            if tokens is not None and stats is not None else None
+        queued, created = self._enqueue(uids, tokens) \
+            if tokens is not None else ({}, [])
+        with open_span(self.tracer, "engine/build_batch") as span:
+            prepared = self._build_batch(uids, late)
+            if prepared is None:
+                return None
+            prepared.queued, prepared.created = queued, created
+            prepared.attach_stats = snap
+            if type(span) is SpanHandle:
+                span.attrs = self._batch_counters(prepared.bucket)
+        return prepared
+
+    def _build_batch(self, uids, late=()) -> Optional["PreparedBatch"]:
+        """The batch of :meth:`prepare` from what is pending."""
         sm = self.state_manager
         self._batch.clear()
         tile = self._prefill_tile()
@@ -509,100 +585,142 @@ class InferenceEngineV2:
         else:
             bucket = min(b for b in self._buckets
                          if b >= self._batch.current_tokens)
-        meta = self._batch.finalize(bucket)
-        packed = jnp.asarray(pack_metadata(meta))  # ONE upload
-        return scheduled, drained, bucket, tile, packed
+        packed = pack_metadata(self._batch.finalize(bucket))
+        sizes, starts = self._batch.chunk_sizes, self._batch.starts
+        rows = {}
+        for slot, uid in enumerate(scheduled):
+            if uid in late:
+                if sizes[slot] != 1:
+                    raise ValueError(
+                        f"prepare: late row {uid} is a chunk of "
+                        f"{sizes[slot]} tokens; only a one-token row can "
+                        f"wait for the program before it")
+                rows[uid] = starts[slot]    # token_ids heads the vector
+        return PreparedBatch(scheduled, drained, sizes, bucket, tile,
+                             packed, rows)
 
-    def _run_one_batch(self, uids, sync: bool = True,
-                       greedy: bool = False) -> Dict[int, Any]:
-        """Build one ragged batch under the token budget (SplitFuse
-        chunking), run the jitted step, and return the logits row (its
-        argmax with ``greedy``) of every slot whose pending queue drained.
-        With ``sync`` the host waits once, for the one output asked for:
-        the span of that wait is ``engine/fetch_logits`` for the logits
-        and ``fetch`` for the token vector, and closes with the step's
-        ``launch``."""
+    def _batch_counters(self, bucket: int) -> Dict[str, int]:
+        """What the ``engine/build_batch`` span closes with: the useful
+        tokens of the rows they are padded to, and what the batch just
+        built asks of each family's kernels."""
         sm = self.state_manager
-        with open_span(self.tracer, "engine/build_batch") as span:
-            built = self._build_batch(uids)
-            if built is None:
-                return {}
-            scheduled, drained, bucket, tile, packed = built
-            if type(span) is SpanHandle:
-                # useful tokens of the rows they are padded to
-                span.attrs = {"tokens": self._batch.current_tokens,
-                              "bucket": bucket}
-                # a model that states its own pool row: a latent one
-                # (no k / v leaves), or keys and values laid flat
-                row = sm.kv_cache.kv_row or {}
-                latent = bool(row) and "k" not in row
-                if self._stateful or row:
-                    # sequences with a chunk in the tile segment here, and
-                    # those chunks' (start, tokens)
-                    tiled = [(s.seen_tokens, n) for s, n in zip(
-                        self._batch.sequences, self._batch.chunk_sizes)
-                        if n > 1]
-                    span.attrs.update(chunk_seqs=len(tiled),
-                                      chunk_tokens=sum(n for _, n in tiled))
-                if self._stateful:
-                    span.attrs["state_slots"] = sm.state_pool.held
-                if self._grouped:
-                    rows = list(zip(self._batch.sequences,
-                                    self._batch.chunk_sizes))
-                    span.attrs.update(self._window_counters(
-                        [s for s, n in rows if n == 1],
-                        [(s.seen_tokens, n) for s, n in rows if n > 1]))
-                if row:
-                    # what the one-token read (the decode walk, the
-                    # absorbed read) must do: the table blocks the batch's
-                    # one-token rows hold up to the position they feed
-                    bs = sm.block_size
-                    span.attrs["row_blocks"] = sum(
-                        s.seen_tokens // bs + 1 for s, n in zip(
-                            self._batch.sequences,
-                            self._batch.chunk_sizes) if n == 1)
-                if latent:
-                    # what the expanded read must do: the causal (query,
-                    # key) pairs of the chunks, and the context rows to
-                    # expand (each chunk's end position)
-                    span.attrs.update(
-                        attn_pairs=sum(n * (2 * a + n + 1) // 2
-                                       for a, n in tiled),
-                        ctx_rows=sum(a + n for a, n in tiled))
+        rows = list(zip(self._batch.sequences, self._batch.chunk_sizes))
+        attrs = {"tokens": self._batch.current_tokens, "bucket": bucket}
+        # a model that states its own pool row: a latent one (no k / v
+        # leaves), or keys and values laid flat
+        row = sm.kv_cache.kv_row or {}
+        latent = bool(row) and "k" not in row
+        # sequences with a chunk in the tile segment here, and those
+        # chunks' (start, tokens)
+        tiled = [(s.seen_tokens, n) for s, n in rows if n > 1]
+        if self._stateful or row:
+            attrs.update(chunk_seqs=len(tiled),
+                         chunk_tokens=sum(n for _, n in tiled))
+        if self._stateful:
+            attrs["state_slots"] = sm.state_pool.held
+        if self._grouped:
+            attrs.update(self._window_counters(
+                [s for s, n in rows if n == 1], tiled))
+        if row:
+            # what the one-token read (the decode walk, the absorbed
+            # read) must do: the table blocks the batch's one-token rows
+            # hold up to the position they feed
+            bs = sm.block_size
+            attrs["row_blocks"] = sum(
+                s.seen_tokens // bs + 1 for s, n in rows if n == 1)
+        if latent:
+            # what the expanded read must do: the causal (query, key)
+            # pairs of the chunks, and the context rows to expand (each
+            # chunk's end position)
+            attrs.update(
+                attn_pairs=sum(n * (2 * a + n + 1) // 2 for a, n in tiled),
+                ctx_rows=sum(a + n for a, n in tiled))
+        return attrs
+
+    def launch(self, prepared: "PreparedBatch",
+               late_tokens: Optional[Dict[int, int]] = None):
+        """Hand a prepared batch to the device: the late rows' tokens
+        into the token buffer, the ONE upload, the dispatch, then what
+        follows a dispatch for every row (``seen_tokens``, the token
+        record, the prefix cache).  Returns ``(logits [max_seqs, vocab],
+        next_tokens int32[max_seqs], launch number)``, the first two on
+        the device and NOT fetched: slot ``i`` is
+        ``prepared.scheduled[i]``, and only a slot whose
+        ``prepared.drained[i]`` is set holds its sequence's last row."""
+        sm = self.state_manager
+        late_tokens = late_tokens or {}
+        if late_tokens.keys() != prepared.late.keys():
+            raise ValueError(
+                f"launch: late rows {sorted(prepared.late)} were prepared, "
+                f"tokens came for {sorted(late_tokens)}")
+        with open_span(self.tracer, "engine/upload"):
+            if late_tokens:
+                prepared.packed[[prepared.late[uid] for uid in late_tokens]] \
+                    = list(late_tokens.values())
+            packed = jnp.asarray(prepared.packed)       # ONE upload
         # host↔device alignment: a jax.profiler capture shows this named
         # bracket on the host track lined up with the XLA execution it
         # dispatched
         with open_span(self.tracer, "engine/ragged_step") as span:
-            step = self._get_step(bucket, tile)
+            step = self._get_step(prepared.bucket, prepared.tile)
             logits, nxt, new_cache = step(self.params, sm.kv_cache.cache,
                                           packed)
             launch = self._launched(span, step)
         sm.kv_cache.update(new_cache)
-
-        rows = nxt if greedy else logits
-        out: Dict[int, Any] = {}
-        host = None
-        for slot, (uid, done) in enumerate(zip(scheduled, drained)):
+        for uid, n in zip(prepared.scheduled, prepared.chunk_sizes):
             seq = sm.get_sequence(uid)
-            n = self._batch.chunk_sizes[slot]
+            if uid in late_tokens:          # in place of the placeholder
+                seq.pending[0] = int(late_tokens[uid])
             sm.record_fed_tokens(seq, seq.pending[:n])
             seq.seen_tokens += n
             del seq.pending[:n]
             sm.register_prefix(seq)
-            if done:
-                if not sync:
-                    out[uid] = rows[slot]          # lazy device row
-                    continue
-                if host is None:
-                    with open_span(self.tracer, "fetch" if greedy else
-                                   "engine/fetch_logits") as span:
-                        if type(span) is SpanHandle:
-                            span.attrs = {"launch": launch}
-                        host = jax.device_get(rows)
-                        host = host.tolist() if greedy else \
-                            np.asarray(host, np.float32)
-                out[uid] = host[slot]
-        return out
+        return logits, nxt, launch
+
+    def discard(self, prepared: "PreparedBatch") -> None:
+        """Drop a batch that was prepared and will not be launched: the
+        tokens :meth:`prepare` queued leave the pending queues (the late
+        rows' placeholders among them) and a sequence it created is
+        flushed, so engine, allocator and prefix-cache statistics stand as
+        if the batch had never been built, but for the KV blocks a
+        sequence that was there before took for its chunk: those stay with
+        it (its next chunk uses them, ``flush`` frees them)."""
+        sm = self.state_manager
+        for uid, n in prepared.queued.items():
+            seq = sm.get_sequence(uid)
+            if seq is not None and n:
+                del seq.pending[len(seq.pending) - n:]
+        if prepared.created:
+            self.flush(prepared.created)
+        if prepared.attach_stats is not None:
+            self.prefix_cache_stats.restore_attach(prepared.attach_stats)
+
+    def _run_one_batch(self, uids, sync: bool = True,
+                       greedy: bool = False) -> Dict[int, Any]:
+        """One forward of :meth:`put`: prepare, launch, and return the
+        logits row (its argmax with ``greedy``) of every slot whose
+        pending queue drained.  With ``sync`` the host waits once, for the
+        one output asked for: the span of that wait is
+        ``engine/fetch_logits`` for the logits and ``fetch`` for the token
+        vector, and closes with the step's ``launch``."""
+        prepared = self.prepare(uids)
+        if prepared is None:
+            return {}
+        logits, nxt, launch = self.launch(prepared)
+        rows = nxt if greedy else logits
+        done = [(slot, uid) for slot, (uid, last) in enumerate(
+            zip(prepared.scheduled, prepared.drained)) if last]
+        if not sync:
+            return {uid: rows[slot] for slot, uid in done}  # lazy rows
+        if not done:
+            return {}
+        with open_span(self.tracer, "fetch" if greedy else
+                       "engine/fetch_logits") as span:
+            if type(span) is SpanHandle:
+                span.attrs = {"launch": launch}
+            host = jax.device_get(rows)
+            host = host.tolist() if greedy else np.asarray(host, np.float32)
+        return {uid: host[slot] for slot, uid in done}
 
     # ------------------------------------------------------------------ #
     # Pipelined per-step decode (the put() scheduling path without the

@@ -315,6 +315,11 @@ class RaggedBatchWrapper:
     def chunk_sizes(self) -> List[int]:
         return [len(c) for c in self._chunks]
 
+    @property
+    def starts(self) -> List[int]:
+        """Buffer row of each chunk's first token."""
+        return list(self._starts)
+
 
 # --------------------------------------------------------------------- #
 # Metadata packing: ONE int32 host->device transfer per forward instead of

@@ -37,23 +37,41 @@ span                     site                        parent    attrs (counters)
 =======================  ==========================  ========  =================
 ``tick``                 ``scheduler.step``          —         ``tick`` (number)
                                                                and, closing,
-                                                               ``kind`` (decode
+                                                               ``kind`` of the
+                                                               program the tick
+                                                               LAUNCHED (decode
                                                                / mixed / prefill
                                                                / verify) and
                                                                ``emitted``
-``pack``                 ``_step_traced``            tick      —
-``prefill``              ``_step_traced``: ``put``   tick      —
-``sample``               after ``put``: the host's   tick      closing:
-                         sampler over fetched                  ``sampled`` (rows
-                         logits, or nothing but                that emitted a
-                         ``advance`` when ``put``              token this tick),
-                         returned the program's                ``device_sampled``
-                         argmax                                (of those, tokens
-                                                               that were the
-                                                               step program's
-                                                               argmax: all when
-                                                               every packed row
-                                                               is greedy, else
+                                                               (tokens the call
+                                                               returns)
+``pack``                 ``_tick_level`` /           tick      —
+                         ``_tick_ahead``: the batch
+                         the tick will launch
+``prefill``              the tick's ragged batch:    tick      around a launch
+                         ``engine.prepare`` (under             of greedy rows,
+                         the program before it,                closing:
+                         when one is in flight),               ``ragged_steps``
+                         the ``fetch`` of that                 (1) and
+                         program, ``engine.launch``            ``ragged_ahead``
+                         on its tokens; or a                   (1: prepared
+                         ``put`` for logits                    under the program
+                                                               before it and
+                                                               launched on its
+                                                               tokens; 0: packed
+                                                               and built with
+                                                               the chip empty)
+``sample``               the tokens the tick hands   tick      closing:
+                         out where it launched a               ``sampled`` (rows
+                         ragged batch: those of the            that emitted a
+                         program it retired (the               token this tick),
+                         step before, or its own               ``device_sampled``
+                         when nothing is due after             (of those, tokens
+                         it); after a ``put`` for              that were a step
+                         logits the host's sampler             program's argmax:
+                                                               all when every
+                                                               packed row is
+                                                               greedy, else
                                                                none)
 ``decode``               ``_fast_decode_tick``: a    tick      closing: ``steps``
                          pure-decode tick's work:              (1), ``ahead``
@@ -67,12 +85,21 @@ span                     site                        parent    attrs (counters)
                                                                that step's rows
                                                                hold: what its
                                                                attention read)
+``retire``               ``_settle``: the wait for   tick (a   —
+                         the program in flight and   decode
+                         its advance, by a tick      tick
+                         that has no ragged batch    after a
+                         to prepare under it (a      mixed
+                         pure-decode tick follows)   one) /
+                         or by a path outside any    ``pack``
+                         tick that frees or moves    / none
+                         a sequence
 ``verify``               ``_speculative_decode_``    tick      —
                          ``tick``
-``engine/build_batch``   ``_run_one_batch``: the     prefill   ``tokens`` fed of
+``engine/build_batch``   ``engine.prepare``: the     prefill   ``tokens`` fed of
                          chunks, their KV slots,               the ``bucket``
-                         the metadata, its upload              padded to; with
-                                                               recurrent state:
+                         the packed metadata on                padded to; with
+                         the host                              recurrent state:
                                                                ``state_slots``
                                                                held,
                                                                ``chunk_seqs``
@@ -100,6 +127,10 @@ span                     site                        parent    attrs (counters)
                                                                (their end
                                                                positions: rows
                                                                to expand)
+``engine/upload``        ``engine.launch``: the      prefill   —
+                         late rows' tokens into
+                         the metadata, its ONE
+                         upload
 ``engine/ragged_step``   the step's dispatch         prefill   the launch record
                                                                (below):
                                                                ``launch``,
@@ -126,19 +157,23 @@ span                     site                        parent    attrs (counters)
                          a run of decode ticks)
 ``engine/verify_step``   the step's dispatch         verify    the launch record
 ``fetch``                ``scheduler._fetch``: the   decode /  ``launch``: the
-                         blocking ``device_get`` of  verify    launch it retires
-                         the step the tick returns:
-                         when that step is ahead,
+                         blocking ``device_get`` of  verify /  launch it retires
+                         the step the tick returns:  prefill /
+                         when that step is ahead,    retire
                          the wait for a program
                          dispatched a tick earlier,
                          what the host's own work
-                         since did not cover.
-                         ``put(greedy=True)``: the   prefill
+                         since did not cover (under
+                         ``prefill``: between the
+                         build of the next ragged
+                         batch and its launch).
+                         ``put(greedy=True)``: the
                          wait for a ragged batch's
                          ``int32[max_seqs]`` argmax
 ``advance``              ``_advance_emitted``, the   decode /  —
                          verify acceptance loop      verify /
-                                                     sample
+                                                     sample /
+                                                     retire
 =======================  ==========================  ========  =================
 
 The launch record.  The engine numbers every step program it dispatches

@@ -33,7 +33,7 @@ def _declare(reg: MetricsRegistry) -> None:
               "deadline_exceeded", "shutdown_failed", "preemptions",
               "handoffs", "preempted_requests", "total_tokens",
               "decode_ticks", "decode_tokens_delivered",
-              "fast_decode_ticks"):
+              "fast_decode_ticks", "ragged_ahead_ticks", "ragged_discards"):
         reg.counter(f"serving/{n}")
     for n in ("preemption_rate", "goodput_tokens_per_s",
               "overall_tokens_per_s", "tokens_per_decode_tick",

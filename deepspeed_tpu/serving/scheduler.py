@@ -34,9 +34,20 @@ position)-keyed host sampler, as every tick once did.
 A greedy pure-decode tick does not wait for its own tokens before it hands
 the device the next step: while the host can tell that the next tick will
 decode exactly the same rows, it dispatches that step on the device-resident
-tokens of this one, then fetches this one's (:class:`_DecodeStep`,
+tokens of this one, then fetches this one's (:class:`_InFlight`,
 ``_fast_decode_tick``).  The device runs one program behind the other while
 the host advances, packs and prepares.
+
+A greedy ragged batch is not built with the chip empty either: with a program
+in flight (a ragged step, or a decode step run ahead) and a ragged batch due
+next, a tick packs and prepares that batch from the view "the program in
+flight has completed" WHILE it runs (``engine.prepare``: everything but the
+token values of the rows it is about to hand a token), then fetches its
+tokens, checks them against those rows' stops, launches the prepared batch
+on them (``engine.launch``) and only then hands the fetched tokens out: the
+advance, the caller's loop and the next tick's packing run under the program
+just launched (``_tick_ahead``).  Only the tokens wait for the device; the
+device waits for the fetch, the token patch, one upload and one dispatch.
 """
 
 from __future__ import annotations
@@ -87,20 +98,29 @@ class TickDeadlineError(RuntimeError):
 
 
 @dataclasses.dataclass
-class _DecodeStep:
-    """A greedy ``decode_step`` whose tokens are still on the device: the
-    scheduler's one piece of state about a program in flight."""
+class _InFlight:
+    """A greedy step program whose tokens are still on the device: the
+    scheduler's one piece of state about a program in flight.  A
+    ``decode_step`` hands every row of ``packed`` a token; a ragged step
+    (``ragged``) those whose feed it completes."""
 
     #: the requests it was packed from, in row order
     packed: List[Request]
-    #: ``int32[max_seqs]`` on the device, row ``i`` = ``packed[i]``'s token
+    #: the requests it hands a token, each with its row of ``nxt``
+    rows: List[Tuple[Request, int]]
+    #: ``int32[max_seqs]`` on the device
     nxt: Any
-    #: 1 when it was dispatched during the tick before the one that returns
-    #: its tokens
+    #: 1 when it was dispatched on the tokens of the program before it, by
+    #: the tick before the one that returns its tokens (a decode step), or
+    #: prepared under that program and launched on its tokens (a ragged
+    #: step)
     ahead: int
     #: the engine's number for this launch (``engine.last_launch`` right
     #: after the dispatch): the ``fetch`` that retires it closes with it
     launch: int
+    #: a ragged step (``engine.launch``): ``Request.fed`` counts its chunks
+    #: since its dispatch; a decode step's token is counted when consumed
+    ragged: bool = False
 
 
 class ContinuousBatchScheduler:
@@ -218,12 +238,18 @@ class ContinuousBatchScheduler:
         #: measures)
         self.fast_decode = fast_decode and hasattr(engine, "decode_step")
         self.fast_ticks = 0
-        #: the decode step dispatched ahead of the tick that will return
-        #: its tokens (None: the host and the device are level).  While it
-        #: is set the engine's positions are one ahead of ``Request.fed``
-        #: for its rows, and every path that frees or moves a sequence
-        #: outside that tick calls ``_settle`` first.
-        self._inflight: Optional[_DecodeStep] = None
+        #: ragged batches prepared under the program before them and
+        #: launched on its tokens, and preparations dropped because a late
+        #: row's token was one of its stop tokens
+        self.ragged_ahead_ticks = 0
+        self.ragged_discards = 0
+        #: the program whose tokens a later tick will return (None: the
+        #: host and the device are level): a decode step dispatched ahead,
+        #: or a ragged step launched by the tick before.  While it is set
+        #: the engine's positions are ahead of what the requests were
+        #: handed, and every path that frees or moves a sequence outside
+        #: the tick that returns it calls ``_settle`` first.
+        self._inflight: Optional[_InFlight] = None
         #: tokens a ``_settle`` outside the owning tick's decode phase
         #: handed out; the next ``step`` returns them before its own
         self._early: List[Tuple[Request, int]] = []
@@ -533,6 +559,73 @@ class ContinuousBatchScheduler:
     def _step_traced(self, tick_h) -> List[Tuple[Request, int]]:
         self._expire_deadlines()
         self._reap_unservable()
+        step, tick = self._inflight, None
+        if step is not None:
+            # a ragged batch due after the program in flight is prepared
+            # under it and launched on its tokens; where that view does
+            # not hold, a ragged step is retired before the tick is packed
+            # the ordinary way (a decode step in flight is the decode
+            # tick's own to return)
+            if self._ragged_due(step):
+                tick = self._tick_ahead(step)
+            if tick is None and step.ragged:
+                self._settle()
+        if tick is None:
+            tick = self._tick_level()
+        if tick is None:
+            return []
+        kind, packed, emitted, t0 = tick
+        if tick_h is not None:
+            # the tick span closes with what it launched and what came out
+            # (with what a retired step handed out on the way)
+            tick_h.attrs["emitted"] = len(emitted) + len(self._early)
+            if kind is not None:    # (None: a discarded batch, and
+                tick_h.attrs["kind"] = kind     # nothing after it)
+        self._tick_s = elapsed = time.monotonic() - t0
+        if self.tick_deadline_s is not None:
+            if elapsed > self.tick_deadline_s:
+                self.tick_deadline_trips += 1
+                self._tick += 1
+                raise TickDeadlineError([r.uid for r in packed],
+                                        elapsed, self.tick_deadline_s)
+        self._tick += 1
+        if self.export_every and self._tick % self.export_every == 0:
+            self._export_metrics()
+        return emitted
+
+    def _begin(self, packed) -> float:
+        """A batch is packed and about to go to the engine; returns the
+        start of what the tick watchdog times."""
+        now = time.monotonic()
+        for req in packed:
+            if req.first_scheduled_time is None:
+                req.first_scheduled_time = now
+        if chaos.armed("poison_request") is not None:
+            # a malformed request deterministically crashes the engine
+            # the moment it is batched into a forward — the crash the
+            # fleet's quarantine layer must attribute and contain
+            for req in packed:
+                chaos.fire("poison_request", key=str(req.uid))
+        # monotonic on purpose: this is a liveness DEADLINE (host-side
+        # control flow), not a device-compute timing bracket — a tick
+        # that stalls on anything (engine, allocator, GIL) should trip
+        t0 = time.monotonic()
+        chaos.fire("tick_stall")
+        return t0
+
+    def _tick_level(self):
+        """A tick that starts with the host and the device level (or with
+        a decode step in flight and no ragged batch due: the decode tick
+        returns it): pack, hand the engine one forward, hand out its
+        tokens.  Returns ``(kind, packed rows, emitted, watchdog start)``,
+        or None when nothing could be packed.
+
+        A ragged batch whose rows are all greedy is prepared and launched
+        back to back.  With another ragged batch due after it, it stays in
+        flight and the tick hands out nothing: the next tick prepares that
+        batch under it and returns its tokens (``_tick_ahead``).  Otherwise
+        its tokens are fetched and handed out here, as a tick always
+        did."""
         uids: List[int] = []
         chunks: List[List[int]] = []
         packed: List[Request] = []
@@ -551,27 +644,15 @@ class ContinuousBatchScheduler:
 
         if not uids:
             self._handle_stall()
-            return []
+            return None
 
-        now = time.monotonic()
-        for req in packed:
-            if req.first_scheduled_time is None:
-                req.first_scheduled_time = now
-        if chaos.armed("poison_request") is not None:
-            # a malformed request deterministically crashes the engine
-            # the moment it is batched into a forward — the crash the
-            # fleet's quarantine layer must attribute and contain
-            for req in packed:
-                chaos.fire("poison_request", key=str(req.uid))
-        # monotonic on purpose: this is a liveness DEADLINE (host-side
-        # control flow), not a device-compute timing bracket — a tick
-        # that stalls on anything (engine, allocator, GIL) should trip
-        t0 = time.monotonic()
-        chaos.fire("tick_stall")
+        t0 = self._begin(packed)
         n_decode = sum(r.state is RequestState.DECODE for r in packed)
         decode_tick = n_decode == len(packed)
         kind = "decode" if decode_tick else "mixed" if n_decode \
             else "prefill"
+        # all-greedy rows: the program's own argmax is the sample
+        greedy = all(r.sampling.greedy for r in packed)
         with step_annotation(self._tick):
             if self.fast_decode and decode_tick:
                 emitted = None
@@ -585,9 +666,22 @@ class ContinuousBatchScheduler:
                     if self._spec_active is not None:
                         self.spec_stats.fallback_ticks += 1
                     emitted = self._fast_decode_tick(uids, chunks, packed)
+            elif greedy and self.fast_decode and self._spec_active is None:
+                with open_span(self.tracer, "prefill") as span:
+                    step = self._launch_ragged(
+                        span, self.engine.prepare(uids, chunks), packed,
+                        chunks, {}, ahead=0)
+                    due = self._ragged_due(step)
+                    if not due:
+                        toks = self._fetch_step(step)
+                if due:
+                    # stays in flight: the next tick prepares the batch
+                    # after it under it, and returns its tokens
+                    return kind, packed, [], t0
+                self._inflight = None
+                with open_span(self.tracer, "sample") as span:
+                    emitted = self._advance_step(step, toks, span)
             else:
-                # all-greedy rows: the program's own argmax is the sample
-                greedy = all(r.sampling.greedy for r in packed)
                 with open_span(self.tracer, "prefill"):
                     out = self.engine.put(uids, chunks, sync=True,
                                           greedy=greedy)
@@ -599,25 +693,116 @@ class ContinuousBatchScheduler:
                         span.attrs = {
                             "sampled": len(emitted),
                             "device_sampled": len(emitted) if greedy else 0}
-        if tick_h is not None:
-            # the tick span closes with what ran and what came out
-            tick_h.attrs.update(kind=kind, emitted=len(emitted))
         if decode_tick:
             # per-tick TPOT accounting divides by tokens DELIVERED (a
             # speculative tick can emit several per request)
             self.metrics.record_decode_tick(len(emitted), len(packed),
                                             time.monotonic() - t0)
-        self._tick_s = elapsed = time.monotonic() - t0
-        if self.tick_deadline_s is not None:
-            if elapsed > self.tick_deadline_s:
-                self.tick_deadline_trips += 1
-                self._tick += 1
-                raise TickDeadlineError([r.uid for r in packed],
-                                        elapsed, self.tick_deadline_s)
-        self._tick += 1
-        if self.export_every and self._tick % self.export_every == 0:
-            self._export_metrics()
-        return emitted
+        return kind, packed, emitted, t0
+
+    # -- a ragged batch under the program before it -------------------- #
+    def _ragged_due(self, step: _InFlight) -> bool:
+        """Can the host tell, before it packs, that the tick after ``step``
+        may run a ragged batch: something waits to be admitted or resumed,
+        or a running request is not among the rows ``step`` hands a token
+        (it is mid-prompt)."""
+        return bool(self._queued or self._preempted
+                    or len(self._running) != len(step.rows))
+
+    def _tick_ahead(self, step: _InFlight):
+        """One tick with ``step`` in flight and a ragged batch due after
+        it: (a) pack and prepare that batch while ``step`` runs, from the
+        view "``step`` has completed": the rows it hands a token decode
+        next, on a token that does not exist yet (late rows), unless that
+        token ends them by length; mid-prompt rows, resumes and the queue
+        are packed as ever, from host tokens; (b) fetch ``step``'s tokens,
+        the tick's one wait; (c) check them against the late rows' stops
+        and launch the batch on them; (d) hand ``step``'s tokens out,
+        under the program just launched.  Returns what ``_tick_level``
+        returns, with ``step``'s tokens as the emitted ones.
+
+        None when the view cannot be packed from, before anything of the
+        engine's has moved (requests admitted on the way stay admitted):
+        the decode set needs a preemption, nothing but decodes came of it
+        after all, a packed row is not greedy, or a path that frees a
+        sequence settled ``step`` under the packing.  (A program is only
+        ever in flight with ``fast_decode`` on and speculation off:
+        ``set_speculative_enabled`` settles first.)
+
+        When the view was wrong (a late row's token is one of its stop
+        tokens) the prepared batch is discarded, nothing of it having
+        reached the device, and after (d) the tick is packed again the
+        ordinary way."""
+        uids: List[int] = []
+        chunks: List[List[int]] = []
+        packed: List[Request] = []
+        with open_span(self.tracer, "pack"):
+            late = self._pack_decodes(uids, chunks, packed, after=step)
+            if late is not None:
+                self._pack_prefills(uids, chunks, packed)
+        if late is None or self._inflight is not step:
+            return None
+        n_decode = sum(r in late or r.state is RequestState.DECODE
+                       for r in packed)
+        if n_decode == len(packed) \
+                or not all(r.sampling.greedy for r in packed):
+            return None
+        kind = "mixed" if n_decode else "prefill"
+        t0 = self._begin(packed)
+        with step_annotation(self._tick):
+            with open_span(self.tracer, "prefill") as span:
+                prepared = self.engine.prepare(
+                    uids, chunks, late=[r.uid for r in late])
+                try:
+                    toks = self._fetch(step.nxt, step.launch)
+                except Exception:
+                    self.engine.discard(prepared)
+                    self._abandon()
+                    raise
+                self._inflight = None
+                tokens = {r.uid: int(toks[slot]) for r, slot in late.items()}
+                wrong = any(r.finish_reason is not None
+                            or r.sampling.is_stop_token(tokens[r.uid])
+                            for r in late)
+                if wrong:
+                    self.engine.discard(prepared)
+                    self.ragged_discards += 1
+                else:
+                    self._launch_ragged(span, prepared, packed, chunks,
+                                        tokens, ahead=1)
+            with open_span(self.tracer, "sample") as span:
+                emitted = self._advance_step(step, toks, span)
+        if wrong:
+            again = self._tick_level()
+            if again is None:
+                return None, packed, emitted, t0
+            kind, packed, more, _ = again
+            emitted += more
+        return kind, packed, emitted, t0
+
+    def _launch_ragged(self, span, prepared, packed, chunks, tokens,
+                       ahead: int) -> _InFlight:
+        """Launch a prepared ragged batch of greedy rows and leave it in
+        flight.  ``span``, the ``prefill`` span around it, closes with
+        ``ragged_steps`` (1) and ``ragged_ahead`` (was the batch prepared
+        under the program before it and launched on its tokens)."""
+        try:
+            _, nxt, launch = self.engine.launch(prepared, tokens)
+        except Exception:
+            self._abandon()
+            raise
+        slot = {uid: i for i, uid in enumerate(prepared.scheduled)}
+        rows = []
+        for req, chunk in zip(packed, chunks):
+            req.fed += len(chunk)
+            if req.uid in tokens or req.remaining_feed == 0:
+                rows.append((req, slot[req.uid]))
+        self.ragged_ahead_ticks += ahead
+        if type(span) is SpanHandle:
+            span.attrs = {"ragged_steps": 1, "ragged_ahead": ahead}
+        self._inflight = _InFlight(packed, rows, nxt, ahead, launch,
+                                   ragged=True)
+        return self._inflight
 
     def _fast_decode_tick(self, uids, chunks, packed) -> List[Tuple[Request,
                                                                     int]]:
@@ -665,7 +850,7 @@ class ContinuousBatchScheduler:
                         self._inflight = self._dispatch_decode(
                             uids, packed, step.nxt, ahead=1)
                 except Exception:
-                    self._abandon(packed)
+                    self._abandon()
                     raise
                 held = self._held_blocks(step.packed) if traced else 0
                 emitted = self._consume(step)
@@ -682,13 +867,14 @@ class ContinuousBatchScheduler:
         return sum(r.fed // bs + 1 for r in packed)
 
     def _dispatch_decode(self, uids, packed, tokens,
-                         ahead: int) -> _DecodeStep:
+                         ahead: int) -> _InFlight:
         """One greedy ``decode_step`` over ``packed``'s rows, fed
         ``tokens`` (host ints, or the ``nxt`` of the step before it)."""
         _, nxt = self.engine.decode_step(uids, tokens, greedy=True)
-        return _DecodeStep(packed, nxt, ahead, self.engine.last_launch)
+        return _InFlight(packed, list(zip(packed, range(len(packed)))), nxt,
+                         ahead, self.engine.last_launch)
 
-    def _same_rows_next_tick(self, step: _DecodeStep, uids, packed) -> bool:
+    def _same_rows_next_tick(self, step: _InFlight, uids, packed) -> bool:
         """Can the host tell, before ``step``'s tokens arrive, that the
         next tick will be a greedy pure-decode tick over exactly its rows
         in the same order?  Read from the host's own state: no speculation
@@ -714,25 +900,41 @@ class ContinuousBatchScheduler:
                 return False
         return self.engine.can_schedule(uids, [1] * len(uids))
 
-    def _consume(self, step: _DecodeStep) -> List[Tuple[Request, int]]:
+    def _consume(self, step: _InFlight) -> List[Tuple[Request, int]]:
         """Fetch ``step``'s tokens and hand each to its request, as the
-        tick that owns it does.  A row whose request ended while the step
-        was in flight (a stop token a tick ago, a failure) is dropped:
-        nothing past the end is emitted or recorded.  The rows that go on
-        tell the engine the values the step dispatched behind this one was
-        fed from the device, for the prefix cache."""
+        tick that owns it does."""
+        return self._advance_step(step, self._fetch_step(step))
+
+    def _fetch_step(self, step: _InFlight) -> np.ndarray:
+        """The wait for ``step``'s tokens; a failed one abandons it."""
         try:
-            toks = self._fetch(step.nxt, step.launch)
+            return self._fetch(step.nxt, step.launch)
         except Exception:
-            self._abandon(step.packed)
+            self._abandon()
             raise
-        rows = [(r, int(t)) for r, t in zip(step.packed, toks)
+
+    def _advance_step(self, step: _InFlight, toks,
+                      span=None) -> List[Tuple[Request, int]]:
+        """Hand the fetched tokens of ``step`` to its rows.  A row whose
+        request ended while the step was in flight (a stop token a tick
+        ago, a failure) is dropped: nothing past the end is emitted or
+        recorded.  The rows that go on tell the engine the values a decode
+        step dispatched behind this one was fed from the device, for the
+        prefix cache (a ragged step launched behind it was fed them from
+        the host).  ``span``, the ``sample`` span of a tick that launched
+        a ragged batch, closes with the rows that emitted, all of them the
+        program's argmax."""
+        rows = [(r, int(toks[i])) for r, i in step.rows
                 if r.finish_reason is None]
-        for req, _ in rows:
-            req.fed += 1
+        if not step.ragged:
+            for req, _ in rows:
+                req.fed += 1
         emitted = self._advance_emitted([r for r, _ in rows],
                                         [t for _, t in rows])
-        if self._inflight is not None:
+        if type(span) is SpanHandle:
+            span.attrs = {"sampled": len(emitted),
+                          "device_sampled": len(emitted)}
+        if self._inflight is not None and not self._inflight.ragged:
             fed = [(r.uid, t) for r, t in rows if r.finish_reason is None]
             if fed:
                 self.engine.record_device_tokens(*zip(*fed))
@@ -742,25 +944,32 @@ class ContinuousBatchScheduler:
 
     def _settle(self) -> None:
         """Bring the host level with the device: fetch and hand out the
-        tokens of the decode step in flight, if there is one.  Every path
-        that frees or moves a sequence outside the decode tick calls this
-        first, so it never meets an engine position the requests have not
-        reached; the next ``step`` returns the tokens."""
+        tokens of the program in flight, if there is one, whatever its
+        kind.  Every path that frees or moves a sequence outside the tick
+        that returns those tokens calls this first, so it never meets an
+        engine position the requests have not reached; the next ``step``
+        returns the tokens.  So does a tick with a ragged step in flight
+        and no ragged batch due after it (a pure-decode tick follows,
+        which needs the tokens on the host), or none it can prepare from
+        the view "the step has completed".  The wait and the advance lie
+        under a ``retire`` span, on the scheduler's own trace wherever
+        the call came from."""
         step, self._inflight = self._inflight, None
         if step is not None:
-            self._early.extend(self._consume(step))
+            with open_span(self.tracer, "retire",
+                           trace_id=self.sched_trace_id, tid=self.trace_tid):
+                self._early.extend(self._consume(step))
 
-    def _abandon(self, packed) -> None:
-        """A decode program failed at its dispatch or at its fetch: what it
-        and the step dispatched behind it left in the pool and in the
+    def _abandon(self) -> None:
+        """A program in flight failed at its dispatch or at its fetch: what
+        it and anything dispatched behind it left in the pool and in the
         engine's positions is not what the requests were handed.  Drop the
-        step in flight, let the engine recover its donated cache, and send
-        the rows back through recompute."""
+        state in flight, let the engine recover its donated cache, and
+        send every running request back through recompute."""
         self._inflight = None
         self.engine._recover_donated_cache()
-        for req in packed:
-            if self._running.get(req.uid) is req:
-                self._preempt(req)
+        for req in list(self._running.values()):
+            self._preempt(req)
 
     def _fetch(self, device_array, launch: int) -> np.ndarray:
         """The tick's one blocking transfer: the host waits here for the
@@ -902,23 +1111,47 @@ class ContinuousBatchScheduler:
         return emitted
 
     # -- packing ------------------------------------------------------- #
-    def _pack_decodes(self, uids, chunks, packed) -> None:
+    def _pack_decodes(self, uids, chunks, packed,
+                      after: Optional[_InFlight] = None):
         """All running decode sequences, one token each; preempt under KV
-        pressure until the set fits."""
+        pressure until the set fits.
+
+        With ``after``, a program in flight, the set is that of the tick
+        after it: the rows it hands a token decode on that token, which
+        does not exist yet (late rows: a placeholder is packed), unless it
+        ends them by length (``max_new_tokens`` or ``max_context``
+        reached with it: what ``_same_rows_next_tick`` tests).  Returns
+        ``{late request: its row of after.nxt}``, or None when that set
+        does not fit: a preemption settles the program in flight first, so
+        it is the ordinary tick's to make."""
+        late: Dict[Request, int] = {}
+        in_step = ()
+        if after is not None:
+            in_step = {r for r, _ in after.rows}
+            for r, slot in after.rows:
+                n = len(r.generated) + 1
+                if r.finish_reason is None \
+                        and n < r.sampling.max_new_tokens \
+                        and len(r.prompt) + n < self.max_context:
+                    late[r] = slot
         decodes = sorted(
-            (r for r in self._running.values() if r.remaining_feed == 1),
+            [r for r in self._running.values()
+             if r.remaining_feed == 1 and r not in in_step] + list(late),
             key=lambda r: r.admitted_at)
         while decodes:
             cand_uids = [r.uid for r in decodes]
             if self.engine.can_schedule(cand_uids, [1] * len(cand_uids)):
                 break
+            if after is not None:
+                return None
             victim = self._pick_victim()
             self._preempt(victim)
             decodes = [r for r in decodes if r.uid != victim.uid]
         for r in decodes:
             uids.append(r.uid)
-            chunks.append([r.history[-1]])
+            chunks.append([0] if r in late else [r.history[-1]])
             packed.append(r)
+        return late
 
     def _pack_prefills(self, uids, chunks, packed) -> None:
         """SplitFuse: fill the remaining budget with prefill chunks —
@@ -1197,6 +1430,8 @@ class ContinuousBatchScheduler:
             _snapshot = self.metrics.snapshot()
         out = {f"serving/{k}": float(v) for k, v in _snapshot.items()}
         out["serving/fast_decode_ticks"] = float(self.fast_ticks)
+        out["serving/ragged_ahead_ticks"] = float(self.ragged_ahead_ticks)
+        out["serving/ragged_discards"] = float(self.ragged_discards)
         if self.speculative is not None:
             out.update((f"serving/spec_{k}", float(v))
                        for k, v in self.spec_stats.as_dict().items())

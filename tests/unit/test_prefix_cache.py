@@ -399,7 +399,9 @@ def test_preempt_resume_with_shared_prefix_parity(params):
     token-for-token exact vs an uncached, unscheduled run."""
     rng = np.random.default_rng(8)
     shared = rng.integers(0, CFG.vocab_size, size=(8,)).tolist()
-    n_req, n_new = 6, 6
+    # (8 new tokens: a scheduler that prepares the next batch under the
+    # one in flight admits a tick later, and 6 no longer fill the pool)
+    n_req, n_new = 6, 8
     prompts = [shared + rng.integers(0, CFG.vocab_size,
                                      size=(int(n),)).tolist()
                for n in rng.integers(2, 8, size=n_req)]
@@ -448,13 +450,18 @@ def test_scheduler_admission_attaches_cached_prefix(params):
     sched.run_until_idle()
 
     calls = []
-    orig = eng.put
+    orig, orig_prepare = eng.put, eng.prepare
 
     def spy(uids, tokens, **how):
         calls.append([len(t) for t in tokens])
         return orig(uids, tokens, **how)
 
-    eng.put = spy
+    def prepare(uids, tokens=None, late=()):
+        if tokens is not None:      # the scheduler's own greedy batch
+            calls.append([len(t) for t in tokens])
+        return orig_prepare(uids, tokens, late)
+
+    eng.put, eng.prepare = spy, prepare
     r2 = sched.submit(prompt, sampling=SamplingParams(max_new_tokens=2))
     sched.run_until_idle()
     assert r2.generated == r1.generated

@@ -231,15 +231,22 @@ def test_scheduler_streaming_and_slo_fields(params):
 # Admission boundaries: exact token budget / max_seqs
 # --------------------------------------------------------------------- #
 def _spy_put(engine):
-    """Record every put()'s chunk lengths."""
+    """Record the chunk lengths of every ragged forward the scheduler
+    asks for: a ``put``, or a ``prepare`` that is handed tokens (``put``
+    itself prepares from what is pending)."""
     calls = []
-    orig = engine.put
+    orig, orig_prepare = engine.put, engine.prepare
 
     def spy(uids, tokens, **how):
         calls.append([len(t) for t in tokens])
         return orig(uids, tokens, **how)
 
-    engine.put = spy
+    def prepare(uids, tokens=None, late=()):
+        if tokens is not None:
+            calls.append([len(t) for t in tokens])
+        return orig_prepare(uids, tokens, late)
+
+    engine.put, engine.prepare = spy, prepare
     return calls
 
 
@@ -711,10 +718,18 @@ def _spy_paths(engine):
     """Record which engine entry point each tick used."""
     paths = []
     orig_put, orig_ds = engine.put, engine.decode_step
+    orig_prepare = engine.prepare
 
     def put(uids, tokens, **how):
         paths.append(("put", [len(t) for t in tokens]))
         return orig_put(uids, tokens, **how)
+
+    def prepare(uids, tokens=None, late=()):
+        if tokens is not None:      # a ragged forward, in two halves
+            paths.append(("put", [len(t) for t in tokens]))
+        return orig_prepare(uids, tokens, late)
+
+    engine.prepare = prepare
 
     def ds(uids, tokens, greedy=False):
         paths.append(("decode_step", len(uids)))
@@ -1120,17 +1135,321 @@ def test_running_ahead_builds_no_new_program(params):
 
 
 # --------------------------------------------------------------------- #
+# The next ragged batch is packed and prepared while the program before it
+# runs, and launched on its tokens (PR 40).  The bar is the same: request by
+# request the streams, finish reasons and states of the fully sequential
+# scheduler (``fast_decode=False``: pack, ``put``, fetch, advance, a tick
+# at a time), and an empty engine afterwards.
+# --------------------------------------------------------------------- #
+def _tick_until(sched, cond, limit=200):
+    """Tick until ``cond()``.  A scheduler that runs ahead then has a
+    ragged step in flight (or the scenario is not testing what it says it
+    is); the sequential one is level, and has handed out as much."""
+    n = 0
+    while not cond():
+        sched.step()
+        n += 1
+        assert n < limit
+    if sched.fast_decode:
+        assert sched._inflight is not None and sched._inflight.ragged
+
+
+def _long(n, seed, lo=40, hi=70):
+    return _ahead_prompts(n, seed=seed, lo=lo, hi=hi)
+
+
+def _staggered(sched, prompts, new, every=2, **kw):
+    """Submit one prompt every ``every`` ticks, then run dry."""
+    reqs, tick = [], 0
+    while len(reqs) < len(prompts) or sched.num_pending:
+        if len(reqs) < len(prompts) and tick % every == 0:
+            n = new[len(reqs)] if isinstance(new, (list, tuple)) else new
+            reqs.append(sched.submit(prompts[len(reqs)], _greedy(n, **kw)))
+        sched.step()
+        tick += 1
+        assert tick < 2000
+    return reqs
+
+
+def _rg_mixed_after_mixed(sched, mk):
+    """Prompts longer than the budget (32) arriving staggered beside rows
+    that decode: every tick runs a ragged batch for a long while."""
+    return _staggered(sched, _long(4, 50), (9, 7, 8, 6))
+
+
+def _rg_mixed_after_decode(sched, mk):
+    """An arrival while a decode step is in flight: its batch is built
+    under that step."""
+    a, = _ahead_prompts(1, seed=51)
+    reqs = [sched.submit(a, _greedy(14))]
+    _steps(sched, 3)
+    before = sched.ragged_ahead_ticks
+    reqs.append(sched.submit(_long(1, 52)[0], _greedy(5)))
+    sched.step()
+    if sched.fast_decode:
+        assert sched.ragged_ahead_ticks == before + 1
+        assert sched._inflight.ragged
+    sched.run_until_idle()
+    return reqs
+
+
+def _rg_stop_token_on_late_row(sched, mk):
+    """A row that decodes beside a long prompt's chunks emits its stop
+    token from a ragged step: the batch prepared under that step holds its
+    next row and is discarded, nothing past the stop is emitted, and the
+    prompt goes on."""
+    a, = _ahead_prompts(1, seed=53)
+    ref = _greedy_reference(sched.engine.params, [a], n_new=8)[0]
+    stop = next(t for i, t in enumerate(ref) if i >= 3 and ref.index(t) == i)
+    ra = sched.submit(a, _greedy(12, stop_token_ids=(stop,)))
+    _steps(sched, 2, in_flight=False)
+    rb = sched.submit(_long(1, 54, lo=150, hi=151)[0], _greedy(4))
+    while ra.finish_reason is None:
+        sched.step()
+    assert ra.finish_reason == "stop" and ra.generated[-1] == stop \
+        and ra.generated.count(stop) == 1
+    assert sched.engine.state_manager.get_sequence(ra.uid) is None
+    if sched.fast_decode:
+        assert sched.ragged_discards >= 1
+    sched.run_until_idle()
+    return [ra, rb]
+
+
+def _rg_ends_inside_the_step(sched, mk):
+    """``max_new_tokens=1`` rows and a row that reaches ``max_context``
+    (64) end with the token of the step in flight: they are left out of
+    the batch prepared under it."""
+    prompts = _long(3, 55, lo=36, hi=50) + \
+        _ahead_prompts(1, seed=56, lo=58, hi=59)
+    reqs = _staggered(sched, prompts, (1, 6, 1, 30), every=1)
+    assert reqs[3].finish_reason == "length" and \
+        len(reqs[3].history) == 64
+    return reqs
+
+
+def _rg_stochastic_joins(sched, mk):
+    """A stochastic request joins under a ragged step in flight: the tick
+    falls back to the logits, and the streams are the sequential ones."""
+    reqs = [sched.submit(p, _greedy(8), uid=81 + i)
+            for i, p in enumerate(_long(2, 57))]
+    _tick_until(sched, lambda: len(reqs[0].generated) >= 1)
+    reqs.append(sched.submit(_ahead_prompts(1, seed=58)[0], SamplingParams(
+        greedy=False, temperature=0.8, top_k=8, seed=3, max_new_tokens=6),
+        uid=91))
+    sched.run_until_idle()
+    return reqs
+
+
+def _rg_preemption_under_kv_pressure(sched, mk):
+    """The decode set no longer fits with a ragged step in flight: that
+    step is settled and the ordinary tick preempts."""
+    reqs = _staggered(sched, _ahead_prompts(6, seed=19, lo=10, hi=22), 10)
+    assert sched.metrics.preemptions >= 1
+    return reqs
+
+
+def _rg_handoff_with_kv(sched, mk, include_kv=True):
+    ra = sched.submit(_ahead_prompts(1, seed=59)[0], _greedy(12))
+    rb = sched.submit(_long(1, 60, lo=120, hi=121)[0], _greedy(5))
+    _tick_until(sched, lambda: len(ra.generated) >= 3)
+    snap, kv = sched.extract_for_handoff(ra.uid, include_kv=include_kv)
+    assert sched._inflight is None and (kv is not None) == include_kv
+    sm = sched.engine.state_manager
+    assert sm.get_sequence(rb.uid).seen_tokens == rb.fed
+    ra2 = sched.resubmit(snap, kv_state=kv)
+    sched.run_until_idle()
+    assert ra.finish_reason == "handoff"
+    return [ra2, rb]
+
+
+def _rg_flush_to_host(sched, mk):
+    return _rg_handoff_with_kv(sched, mk, include_kv=False)
+
+
+def _rg_shutdown_hands_off(sched, mk):
+    reqs = [sched.submit(_ahead_prompts(1, seed=61)[0], _greedy(9)),
+            sched.submit(_long(1, 62, lo=120, hi=121)[0], _greedy(5))]
+    _tick_until(sched, lambda: len(reqs[0].generated) >= 3)
+    drained, snaps = sched.shutdown(0.0, handoff=True)
+    assert not drained and len(snaps) == 2 and sched._inflight is None
+    assert sched.engine.state_manager.n_tracked_sequences == 0
+    other = mk()
+    out = [other.resubmit(s) for s in snaps]
+    other.run_until_idle()
+    assert [r.uid for r in out] == [r.uid for r in reqs]
+    return out
+
+
+def _rg_deadline_expires(sched, mk):
+    """The deadline falls due with the row's next ragged step in flight:
+    its row of that step is dropped, the request keeps what it had."""
+    ra = sched.submit(_ahead_prompts(1, seed=63)[0], _greedy(12),
+                      deadline_s=500.0)
+    rb = sched.submit(_long(1, 64, lo=120, hi=121)[0], _greedy(5))
+    _tick_until(sched, lambda: len(ra.generated) >= 3)
+    had = list(ra.generated)
+    ra.arrival_time -= 1000.0
+    sched.step()
+    assert ra.finish_reason == "deadline" and \
+        ra.state is RequestState.FAILED and ra.generated == had
+    assert sched.engine.state_manager.get_sequence(ra.uid) is None
+    sched.run_until_idle()
+    return [ra, rb]
+
+
+def _rg_prefix_hit_under_a_step(sched, mk):
+    """Requests that share their first two blocks are admitted under a
+    program in flight: they attach what the requests before them cached,
+    the blocks of the step in flight among it."""
+    rng = np.random.default_rng(65)
+    head = rng.integers(0, CFG.vocab_size, size=(16,)).tolist()
+    prompts = [head + rng.integers(0, CFG.vocab_size,
+                                   size=(int(n),)).tolist()
+               for n in (30, 41, 25, 38)]
+    reqs = _staggered(sched, prompts, 6, every=1)
+    assert sched.engine.prefix_cache_stats.hit_tokens >= 16 * 2
+    return reqs
+
+
+def _rg_stateful_model(sched, mk):
+    """State slots (LFM2's convolution tails): a chunk prepared under the
+    program that writes the tail it starts from."""
+    import test_ragged_lfm2 as lfm2
+    prompts = [lfm2._ids(n, seed=70 + i).tolist()
+               for i, n in enumerate((100, 130, 40, 90))]
+    return _staggered(sched, prompts, (6, 5, 7, 4))
+
+
+def _rg_grouped_pool_model(sched, mk):
+    """Two block tables a sequence (Trinity's window layers): window blocks
+    are released while the batch is prepared, under the program that reads
+    the band."""
+    import test_kv_groups as groups
+    prompts = [groups.ids(n, seed=80 + i).tolist()
+               for i, n in enumerate((100, 70, 120, 50))]
+    return _staggered(sched, prompts, (6, 5, 7, 4))
+
+
+def _lfm2_engine():
+    import test_ragged_lfm2 as lfm2
+    return lfm2._engine(lfm2._seeded_params(), max_seqs=8)
+
+
+def _afmoe_engine():
+    import test_kv_groups as groups
+    return groups.engine(groups.params(), budget=32, tile=16, seqs=4)
+
+
+_RAGGED_SCENARIOS = {
+    _rg_mixed_after_mixed: {}, _rg_mixed_after_decode: {},
+    _rg_stop_token_on_late_row: dict(max_context=192),
+    _rg_ends_inside_the_step: dict(max_context=64),
+    _rg_stochastic_joins: {},
+    _rg_preemption_under_kv_pressure: dict(max_context=48, num_blocks=7),
+    _rg_handoff_with_kv: {}, _rg_flush_to_host: {},
+    _rg_shutdown_hands_off: {}, _rg_deadline_expires: {},
+    _rg_prefix_hit_under_a_step: dict(prefix=True),
+    _rg_stateful_model: dict(engine=_lfm2_engine),
+    _rg_grouped_pool_model: dict(engine=_afmoe_engine)}
+
+
+def _ragged_engine(params, engine=None, prefix=False, max_context=128,
+                   num_blocks=None):
+    if engine is not None:
+        return engine()
+    cfg = RaggedInferenceEngineConfig.from_dict({
+        "state_manager": {"max_ragged_batch_size": 32,
+                          "max_ragged_sequence_count": 4,
+                          "max_context": max_context},
+        "kv_cache": {"block_size": 8, "enable_prefix_cache": prefix,
+                     **({"num_blocks": num_blocks} if num_blocks else {})}})
+    return InferenceEngineV2(RaggedLlama(CFG, 8), params, cfg)
+
+
+@pytest.mark.parametrize("scenario", _RAGGED_SCENARIOS,
+                         ids=[f.__name__[4:] for f in _RAGGED_SCENARIOS])
+def test_ragged_batches_prepared_ahead_match_the_sequential_scheduler(
+        params, scenario):
+    how = _RAGGED_SCENARIOS[scenario]
+
+    def run(fast):
+        def mk():
+            return ContinuousBatchScheduler(_ragged_engine(params, **how),
+                                            fast_decode=fast)
+
+        sched = mk()
+        reqs = scenario(sched, mk)
+        sm = sched.engine.state_manager
+        assert sched.num_pending == 0 and sched._inflight is None
+        assert sm.n_tracked_sequences == 0
+        assert sm.free_blocks == sm.allocator.num_blocks - 1
+        if sm.window is not None:
+            assert sm.win_allocator.free_blocks == \
+                sm.win_allocator.num_blocks - 1
+        return [(r.uid, r.generated, r.finish_reason, r.state)
+                for r in reqs], sched.ragged_ahead_ticks
+
+    got, n_ahead = run(True)
+    want, none = run(False)
+    assert got == want
+    assert n_ahead >= 1 and none == 0     # batches were prepared ahead
+
+
+def test_failed_fetch_of_a_ragged_step_in_flight_recovers(params,
+                                                          monkeypatch):
+    """The fetch of a ragged step in flight fails under the batch prepared
+    behind it: the error comes out of the tick that would have returned
+    its tokens, the preparation is dropped with it, nothing of either
+    stays in the engine, and every request recomputes to the stream it
+    would have had."""
+    prompts = _ahead_prompts(1, seed=66) + _long(1, 67, lo=56, hi=57)
+    want = _greedy_reference(params, prompts, n_new=7)
+    eng = _ragged_engine(params)
+    sched = ContinuousBatchScheduler(eng)
+    reqs = [sched.submit(p, _greedy(7)) for p in prompts]
+    _tick_until(sched, lambda: len(reqs[0].generated) >= 1)
+    before = [list(r.generated) for r in reqs]
+    real_fetch, recovered, dropped = sched._fetch, [], []
+    real_recover, real_discard = eng._recover_donated_cache, eng.discard
+
+    def failing(arr, launch):
+        monkeypatch.setattr(sched, "_fetch", real_fetch)
+        raise RuntimeError("device lost")
+
+    monkeypatch.setattr(sched, "_fetch", failing)
+    monkeypatch.setattr(eng, "_recover_donated_cache",
+                        lambda: (recovered.append(1), real_recover()))
+    monkeypatch.setattr(eng, "discard",
+                        lambda b: (dropped.append(1), real_discard(b)))
+    with pytest.raises(RuntimeError, match="device lost"):
+        sched.step()
+    assert recovered == [1] and dropped == [1] and sched._inflight is None
+    sm = eng.state_manager
+    assert sm.n_tracked_sequences == 0
+    assert sm.free_blocks == sm.allocator.num_blocks - 1
+    assert [r.generated for r in reqs] == before      # nothing handed out
+    assert all(r.state is RequestState.PREEMPTED for r in reqs)
+    sched.run_until_idle()
+    assert [r.generated for r in reqs] == want
+    assert sm.n_tracked_sequences == 0
+
+
+# --------------------------------------------------------------------- #
 # What a ``put`` tick fetches follows its rows: the step program's argmax
 # (one int32 a row) when every packed row is greedy, the logits for the
 # host sampler when one is not.  The bar: request by request the tokens of
 # the logits path.
 # --------------------------------------------------------------------- #
 def _spy_greedy(engine, force_logits=False):
-    """Record what every ``put`` was asked for.  With ``force_logits`` a
-    greedy ask is answered as every tick once was: the logits of the same
-    program fetched, ``np.argmax`` on the host."""
+    """Record what every ragged forward was asked for: a ``put``, or the
+    scheduler's own ``prepare`` + ``launch`` (all-greedy rows: the token
+    vector).  With ``force_logits`` a greedy ask is answered as every tick
+    once was: the logits of the same program fetched, ``np.argmax`` on the
+    host."""
     asked = []
-    orig = engine.put
+    orig, orig_prepare, orig_launch = \
+        engine.put, engine.prepare, engine.launch
+    mine = []       # batches the scheduler prepared itself
 
     def put(uids, tokens, sync=True, greedy=False):
         asked.append(greedy)
@@ -1139,7 +1458,20 @@ def _spy_greedy(engine, force_logits=False):
             return {u: int(np.argmax(r)) for u, r in rows.items()}
         return orig(uids, tokens, sync=sync, greedy=greedy)
 
-    engine.put = put
+    def prepare(uids, tokens=None, late=()):
+        out = orig_prepare(uids, tokens, late)
+        if tokens is not None:
+            asked.append(True)
+            mine.append(out)
+        return out
+
+    def launch(prepared, late_tokens=None):
+        logits, nxt, n = orig_launch(prepared, late_tokens)
+        if force_logits and any(prepared is p for p in mine):
+            nxt = np.argmax(np.asarray(logits, np.float32), axis=-1)
+        return logits, nxt, n
+
+    engine.put, engine.prepare, engine.launch = put, prepare, launch
     return asked
 
 
@@ -1241,13 +1573,18 @@ def test_one_stochastic_row_sends_its_ticks_to_the_logits(params):
     noisy_prompt = rng.integers(0, CFG.vocab_size, size=(10,)).tolist()
 
     eng = _engine(params, max_seqs=4)
-    orig, asked = eng.put, []
+    orig, orig_prepare, asked = eng.put, eng.prepare, []
 
     def put(uids, tokens, sync=True, greedy=False):
         asked.append((greedy, 77 in uids))
         return orig(uids, tokens, sync=sync, greedy=greedy)
 
-    eng.put = put
+    def prepare(uids, tokens=None, late=()):
+        if tokens is not None:      # the scheduler's own: a greedy batch
+            asked.append((True, 77 in uids))
+        return orig_prepare(uids, tokens, late)
+
+    eng.put, eng.prepare = put, prepare
     sched = ContinuousBatchScheduler(eng)
     reqs, noisy, tick = [], None, 0
     while len(reqs) < len(prompts) or sched.num_pending:

@@ -115,17 +115,45 @@ def test_every_inner_span_chains_up_to_a_tick(traced):
         names = [c["name"] for c in chain]
         assert names[-1] == "tick", names
         # under the tick's phase, never directly under the tick
-        assert names[-2] in ("prefill", "decode", "verify", "sample"), names
+        assert names[-2] in ("prefill", "decode", "verify", "sample",
+                             "retire"), names
         assert {c["trace_id"] for c in chain} == {r["trace_id"]}
         assert all(a["t0_ns"] >= b["t0_ns"] and a["t1_ns"] <= b["t1_ns"]
                    for a, b in zip(chain, chain[1:]))
+
+
+def _retired(kids, by_id):
+    """``kids`` of a tick without what lies under its ``retire`` span (a
+    decode tick that first retires the ragged step in flight: the tick
+    after a mixed one), and that part: (kids, [fetch, advance] or [])."""
+    if "retire" not in kids:
+        return kids, []
+    (top,), under = kids["retire"], []
+    rest = {}
+    for name, recs in kids.items():
+        if name == "retire":
+            continue
+        mine = [r for r in recs if r["parent"] == top["span_id"]]
+        under += mine
+        rest[name] = [r for r in recs if r not in mine]
+    return rest, sorted(under, key=lambda r: r["t0_ns"])
 
 
 def test_pure_decode_tick_tree(traced):
     tr, _ = traced
     ticks = _of_kind(tr, "decode")
     assert ticks
+    all_by_id = {r["span_id"]: r for r in tr.records()}
+    retiring = 0
     for tick, kids in ticks:
+        # the tick after a mixed one: the ragged step is retired first, its
+        # fetch naming its launch, before anything is packed
+        kids, first = _retired(kids, all_by_id)
+        if first:
+            retiring += 1
+            assert [r["name"] for r in first] == ["fetch", "advance"]
+            assert set(first[0]["attrs"]) == {"launch"}
+            assert first[1]["t1_ns"] <= kids["pack"][0]["t0_ns"]
         # every pure-decode tick: one pack, one decode phase, one fetch and
         # one advance; a dispatch (prep + step) for each program it hands
         # the device: the step it returns unless that was in flight, and
@@ -155,57 +183,71 @@ def test_pure_decode_tick_tree(traced):
         order = [r for pair in zip(preps, steps) for r in pair] + \
             [kids["fetch"][0], kids["advance"][0]]
         assert all(a["t1_ns"] <= b["t0_ns"] for a, b in zip(order, order[1:]))
-        by_id = {r["span_id"]: r for r in tr.records()}
-        assert all(by_id[r["parent"]]["name"] == "decode" for r in order)
+        assert all(all_by_id[r["parent"]]["name"] == "decode" for r in order)
+    assert retiring == 1
 
 
 def test_decode_span_counts_the_steps_ahead(traced):
     """``_drive``: r1 decodes alone (a step, and the next dispatched ahead),
-    r2 arrives while that one is in flight (the tick returns it and
-    dispatches nothing: r2 joins a tick later than it would have), the
-    mixed tick, two rows decode until r2's third token (ahead of its last
-    token: the row still runs; not past it), then r1 alone to its eighth.
-    Every run of decode ticks hands the device one program a tick."""
+    r2 arrives while that one is in flight (the tick prepares the mixed
+    batch under it, returns its token and launches the batch on it), two
+    rows decode until r2's third token (ahead of its last token: the row
+    still runs; not past it), then r1 alone to its eighth.  Every run of
+    decode ticks hands the device one program a tick, but for the step
+    that was in flight when the prompt arrived: a mixed tick returns it."""
     tr, _ = traced
     kinds = [t["attrs"]["kind"] for t, _ in _ticks(tr)]
-    assert kinds == ["prefill", "decode", "decode", "mixed",
+    assert kinds == ["prefill", "decode", "mixed",
                      "decode", "decode", "decode", "decode"]
     ticks = _of_kind(tr, "decode")
     ahead = [kids["decode"][0]["attrs"]["ahead"] for _, kids in ticks]
-    assert ahead == [0, 1, 0, 1, 0, 1]
+    assert ahead == [0, 0, 1, 0, 1]
     dispatched = [len(kids.get("engine/decode_step", []))
                   for _, kids in ticks]
-    assert dispatched == [2, 0, 2, 0, 2, 0]
-    assert sum(dispatched) == len(ticks) == \
+    assert dispatched == [2, 2, 0, 2, 0]
+    assert sum(dispatched) == len(ticks) + 1 == 1 + \
         sum(kids["decode"][0]["attrs"]["steps"] for _, kids in ticks)
     # the table blocks (of 8) the returned step's rows hold at the
-    # positions it fed: r1 at 13, 14 | r1 at 16, 17 beside r2 at 11, 12 |
+    # positions it fed: r1 at 13 | r1 at 16, 17 beside r2 at 11, 12 |
     # r1 at 18, 19
     assert [kids["decode"][0]["attrs"]["read_blocks"] for _, kids in ticks] \
-        == [2, 2, 3 + 2, 3 + 2, 3, 3]
+        == [2, 3 + 2, 3 + 2, 3, 3]
 
 
 def test_mixed_tick_tree(traced):
+    """The mixed tick of ``_drive`` finds a decode step in flight: it
+    builds its batch under that step, waits for it, launches the batch on
+    its token and only then hands that token out."""
     tr, _ = traced
     (tick, kids), = _of_kind(tr, "mixed")
     # every row is greedy: the wait is for the token vector (``fetch``)
     assert sorted(kids) == ["advance", "engine/build_batch",
-                            "engine/ragged_step", "fetch",
+                            "engine/ragged_step", "engine/upload", "fetch",
                             "pack", "prefill", "sample"]
+    assert all(len(v) == 1 for v in kids.values())
     # one decoding token and an 11-token prompt, padded to the 16 bucket
     assert kids["engine/build_batch"][0]["attrs"] == {"tokens": 1 + 11,
                                                       "bucket": 16}
-    # ... the launch record on the dispatch, the launch on its wait, and
-    # on ``sample`` the rows that emitted and how many by the program's
-    # argmax: the decoding row and the prompt that ended here
+    # ... the launch record on the dispatch, the launch on the wait (the
+    # decode step's, the launch before), on ``prefill`` that the batch was
+    # prepared under the program before it, and on ``sample`` the rows
+    # that emitted and how many by the program's argmax: the decoding row
+    # of the step this tick returns
     assert {k for k, v in kids.items() if "attrs" in v[0]} == \
-        {"engine/build_batch", "engine/ragged_step", "fetch", "sample"}
-    assert kids["sample"][0]["attrs"] == {"sampled": 2, "device_sampled": 2}
+        {"engine/build_batch", "engine/ragged_step", "fetch", "prefill",
+         "sample"}
+    assert kids["prefill"][0]["attrs"] == {"ragged_steps": 1,
+                                           "ragged_ahead": 1}
+    assert kids["fetch"][0]["attrs"]["launch"] + 1 == \
+        kids["engine/ragged_step"][0]["attrs"]["launch"]
+    assert kids["sample"][0]["attrs"] == {"sampled": 1, "device_sampled": 1}
     by_id = {r["span_id"]: r for r in tr.records()}
     assert by_id[kids["advance"][0]["parent"]]["name"] == "sample"
-    assert by_id[kids["fetch"][0]["parent"]]["name"] == "prefill"
-    assert by_id[kids["engine/build_batch"][0]["parent"]]["name"] == \
-        "prefill"
+    order = [kids[k][0] for k in (
+        "pack", "engine/build_batch", "fetch", "engine/upload",
+        "engine/ragged_step", "advance")]
+    assert all(a["t1_ns"] <= b["t0_ns"] for a, b in zip(order, order[1:]))
+    assert all(by_id[r["parent"]]["name"] == "prefill" for r in order[1:5])
 
 
 def _mixed_run(params, stochastic):
@@ -240,8 +282,17 @@ def test_put_tick_wait_and_sample_counters(params, stochastic):
         wait, other = ("engine/fetch_logits", "fetch") if logits \
             else ("fetch", "engine/fetch_logits")
         assert other not in kids and len(kids[wait]) == 1
-        assert kids[wait][0]["attrs"] == {
-            "launch": kids["engine/ragged_step"][0]["attrs"]["launch"]}
+        # an all-greedy mixed tick finds the decode step of the tick before
+        # in flight and builds its batch under it: its wait is for that
+        # step, the launch before its own (the stochastic request is
+        # admitted under that step too, but the batch is packed with it on
+        # the host: that tick waits for its own launch)
+        own = kids["engine/ragged_step"][0]["attrs"]["launch"]
+        ahead = tick["attrs"]["kind"] == "mixed" and not stochastic
+        assert kids[wait][0]["attrs"] == {"launch": own - ahead}
+        # (the span around a ``put`` for logits closes with no counter)
+        assert kids["prefill"][0].get("attrs") == (
+            None if logits else {"ragged_steps": 1, "ragged_ahead": ahead})
         emitted = tick["attrs"]["emitted"]
         assert kids["sample"][0]["attrs"] == {
             "sampled": emitted, "device_sampled": 0 if logits else emitted}
@@ -276,7 +327,8 @@ def test_a_greedy_mixed_tick_after_a_logits_put_builds_nothing(params):
     _drive(sched)
     (_, kids), = _of_kind(tr, "mixed")
     assert kids["engine/build_batch"][0]["attrs"]["bucket"] == 16
-    assert kids["sample"][0]["attrs"] == {"sampled": 2, "device_sampled": 2}
+    # (the token of the decode step the tick found in flight)
+    assert kids["sample"][0]["attrs"] == {"sampled": 1, "device_sampled": 1}
     assert eng.step_keys == keys
     assert [eng._steps[k]._cache_size() for k in keys] == sizes == [1, 1, 1]
 
@@ -307,22 +359,26 @@ def test_tick_closing_counters(traced):
     tr, sched = traced
     ticks = _ticks(tr)
     # the second prompt arrives while the step after tick 1 is in flight:
-    # it joins once that step's tick has returned
+    # its batch is built under that step, by the tick that returns it
     assert [t["attrs"]["kind"] for t, _ in ticks[:4]] == \
-        ["prefill", "decode", "decode", "mixed"]
+        ["prefill", "decode", "mixed", "decode"]
     for t, kids in ticks:
         assert set(t["attrs"]) == {"tick", "kind", "emitted"}
         builds = kids.get("engine/build_batch", [])
         if t["attrs"]["kind"] == "decode":
-            # one token a running sequence, and no ragged batch
-            assert 1 <= t["attrs"]["emitted"] <= sched.max_seqs
+            # one token a running sequence (and one more where the tick
+            # first retired the mixed step before it), no ragged batch
+            assert 1 <= t["attrs"]["emitted"] <= \
+                sched.max_seqs * (1 + ("retire" in kids))
             assert not builds
         else:
             assert builds
             for b in builds:
                 assert 0 < b["attrs"]["tokens"] <= b["attrs"]["bucket"]
     assert [t["attrs"]["tick"] for t, _ in ticks] == list(range(len(ticks)))
-    assert [t["attrs"]["emitted"] for t, _ in ticks[:4]] == [1, 1, 1, 2]
+    # ... the mixed tick returns the decode step's one token, and the tick
+    # after it the mixed step's two and its own decode step's two
+    assert [t["attrs"]["emitted"] for t, _ in ticks[:4]] == [1, 1, 1, 2 + 2]
     assert ticks[0][1]["engine/build_batch"][0]["attrs"] == \
         {"tokens": 13, "bucket": 16}
     assert sum(t["attrs"]["emitted"] for t, _ in ticks) == 8 + 3
@@ -433,9 +489,12 @@ def test_every_launch_is_retired_by_the_wait_that_names_it(traced):
     """Each launch is waited for once, by a ``fetch`` (a token vector: every
     row here is greedy) or an ``engine/fetch_logits`` (a ragged batch with
     a stochastic row: none here) that closes with its number;
-    ``_drive`` ends idle, so no launch is left in flight.  A step sent
-    ahead is retired in the NEXT tick, after that tick has (or has not)
-    dispatched its successor; every other launch inside its own tick."""
+    ``_drive`` ends idle, so no launch is left in flight.  A program sent
+    ahead is retired in the NEXT tick: a decode step after that tick has
+    (or has not) dispatched its successor, or, where a ragged batch was
+    due, between that batch's build and its launch; the ragged step so
+    launched before the next tick packs.  Every other launch is retired
+    inside its own tick."""
     tr, _ = traced
     dispatches, waits = _launch_spans(tr)
     assert all(set(w["attrs"]) == {"launch"} for w in waits)
@@ -443,32 +502,48 @@ def test_every_launch_is_retired_by_the_wait_that_names_it(traced):
     assert sorted(retired) == [d["attrs"]["launch"] for d in dispatches]
     assert retired == sorted(retired)       # the device runs them in order
     wait_of = {w["attrs"]["launch"]: w for w in waits}
-    tick_of = {}
+    tick_of, kids_of = {}, {}
     for tick, kids in _ticks(tr):
+        kids_of[tick["attrs"]["tick"]] = kids
         for name in _DISPATCH + _WAIT:
             for r in kids.get(name, []):
                 tick_of[r["span_id"]] = tick["attrs"]["tick"]
     by_launch = {d["attrs"]["launch"]: d for d in dispatches}
-    ahead = 0
+    ahead = []
     for n, d in by_launch.items():
         w = wait_of[n]
         assert d["t1_ns"] <= w["t0_ns"]
         assert w["name"] == "fetch"
         gap = tick_of[w["span_id"]] - tick_of[d["span_id"]]
         assert gap in (0, 1)
-        if gap:
-            ahead += 1
-            # dispatched as the second step of its tick, behind the step
-            # that tick returned ...
-            assert tick_of[by_launch[n - 1]["span_id"]] == \
-                tick_of[d["span_id"]]
-            # ... and retired after whatever the next tick dispatched
-            later = [x for x in dispatches
-                     if tick_of[x["span_id"]] == tick_of[w["span_id"]]]
+        if not gap:
+            continue
+        ahead.append(n)
+        later = [x for x in dispatches
+                 if tick_of[x["span_id"]] == tick_of[w["span_id"]]]
+        if d["name"] == "engine/ragged_step":
+            # the mixed step: retired before the next tick packs anything
+            retire, = kids_of[tick_of[w["span_id"]]]["retire"]
+            assert w["parent"] == retire["span_id"]
+            assert all(w["t1_ns"] <= x["t0_ns"] for x in later)
+            continue
+        # a decode step: dispatched as the second step of its tick, behind
+        # the step that tick returned ...
+        assert tick_of[by_launch[n - 1]["span_id"]] == tick_of[d["span_id"]]
+        ragged = [x for x in later if x["name"] == "engine/ragged_step"]
+        if ragged:
+            # ... and retired between the build and the launch of the
+            # ragged batch that was due after it
+            build, = kids_of[tick_of[w["span_id"]]]["engine/build_batch"]
+            assert build["t1_ns"] <= w["t0_ns"] and \
+                w["t1_ns"] <= ragged[0]["t0_ns"]
+        else:
+            # ... or after whatever the next tick dispatched
             assert all(x["t1_ns"] <= w["t0_ns"] for x in later)
-    # the decode ticks that found their step in flight
-    # (``test_decode_span_counts_the_steps_ahead``: [0, 1, 0, 1, 0, 1])
-    assert ahead == 3
+    # the decode step in flight when the prompt arrived, the mixed step,
+    # and the two decode ticks that found their step in flight
+    # (``test_decode_span_counts_the_steps_ahead``: [0, 0, 1, 0, 1])
+    assert ahead == [3, 4, 6, 8]
 
 
 def test_a_run_of_decode_ticks_retires_behind_its_successor(params):
@@ -667,9 +742,9 @@ def test_untraced_scheduler_still_annotates_while_those_are_on(
     _drive(sched)
     assert len(sched.finished_requests) == 2
     assert {"tick", "ds_tick", "pack", "prefill", "sample", "decode",
-            "engine/build_batch", "engine/ragged_step",
-            "engine/decode_prep", "engine/decode_step", "fetch",
-            "advance"} == set(names)
+            "retire", "engine/build_batch", "engine/upload",
+            "engine/ragged_step", "engine/decode_prep",
+            "engine/decode_step", "fetch", "advance"} == set(names)
 
 
 # --------------------------------------------------------------------- #

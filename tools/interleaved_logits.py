@@ -7,16 +7,16 @@ The benchmark's ``_check_logits`` (``benchmark/runners/serve_ragged.py``)
 feeds ONE sequence: state slots mixed up between sequences, a chunk that
 picks up its neighbour's convolution tail, a slot reused without a reset
 show only when several sequences share batches.  The scheduler hands out
-tokens, not logits, so the engine's ``put`` and ``decode_step`` are wrapped
-to keep the logits of every step program it runs (``decode_step``'s device
-arrays, fetched after the run); the requests sample greedily, and the
-reference is run teacher-forced over each request's prompt plus the tokens
-the engine itself generated.
+tokens, not logits, so the engine's ``launch`` and ``decode_step`` are
+wrapped to keep the device logits of every step program it runs (fetched
+after the run); the requests sample greedily, and the reference is run
+teacher-forced over each request's prompt plus the tokens the engine itself
+generated.
 
-A greedy tick asks ``put`` for tokens (``greedy=True``) and the logits stay
-on the device: the wrapper asks the same program for its logits instead and
-answers with their ``np.argmax``, which is the token the program's own
-argmax gives (first index of the maximum on both sides).
+Every ragged forward is a ``launch`` since PR 40 (``put`` is ``prepare`` +
+``launch``, and a scheduler that runs ahead calls the two itself, with no
+``put`` around them); it returns the logits beside the tokens, unfetched, so
+nothing is asked of the program that the scheduler does not ask.
 ``benchmark/tools/interleaved_check.py`` is this file as of PR 31, written
 against a ``put`` without ``greedy``; it cannot serve a scheduler that
 passes it (PERF.md section 7).
@@ -48,15 +48,18 @@ def serve_and_compare(engine, reference, ref_params, hf: Dict[str, Any],
     sm = engine.state_manager
     seen: List[Any] = []            # (uid, position of the fed token, row)
 
-    real_put, real_step = engine.put, engine.decode_step
+    real_launch, real_step = engine.launch, engine.decode_step
 
-    def put(uids, tokens, sync=True, greedy=False):
-        out = real_put(uids, tokens, sync=sync)
-        for uid, row in out.items():
-            seen.append((uid, sm.get_sequence(uid).seen_tokens - 1, row))
-        if greedy:
-            return {uid: int(np.argmax(row)) for uid, row in out.items()}
-        return out
+    def launch(prepared, late_tokens=None):
+        # every ragged forward goes through here: ``put``'s own, and the
+        # batches a scheduler that runs ahead prepares and launches itself
+        logits, nxt, n = real_launch(prepared, late_tokens)
+        for slot, (uid, last) in enumerate(zip(prepared.scheduled,
+                                               prepared.drained)):
+            if last:
+                seen.append((uid, sm.get_sequence(uid).seen_tokens - 1,
+                             (logits, slot)))
+        return logits, nxt, n
 
     def decode_step(uids, tokens, greedy=False):
         out = real_step(uids, tokens, greedy=greedy)
@@ -66,7 +69,7 @@ def serve_and_compare(engine, reference, ref_params, hf: Dict[str, Any],
                          (logits, i)))
         return out
 
-    engine.put, engine.decode_step = put, decode_step
+    engine.launch, engine.decode_step = launch, decode_step
     try:
         sched = ContinuousBatchScheduler(engine)
         reqs, ticks = [], 0
@@ -80,7 +83,7 @@ def serve_and_compare(engine, reference, ref_params, hf: Dict[str, Any],
                 sched.step()
             ticks += 1
     finally:
-        engine.put, engine.decode_step = real_put, real_step
+        engine.launch, engine.decode_step = real_launch, real_step
 
     rows_by_uid: Dict[int, Dict[int, np.ndarray]] = {}
     fetched: Dict[int, np.ndarray] = {}
