@@ -30,8 +30,8 @@ import numpy as np
 
 from deepspeed_tpu.inference.v2.config_v2 import RaggedInferenceEngineConfig
 from deepspeed_tpu.inference.v2.model_implementations.ragged_llama import (
-    KV_SPEC,
     RaggedLlama,
+    kv_spec,
     shard_ragged_params,
 )
 from deepspeed_tpu.inference.v2.ragged import (DSStateManager,
@@ -255,9 +255,9 @@ class InferenceEngineV2:
             from jax.sharding import NamedSharding
 
             self.params = shard_ragged_params(params, model.mesh)
-            kv_sh = NamedSharding(model.mesh, KV_SPEC)
             self.state_manager.kv_cache.cache = jax.tree.map(
-                lambda x: jax.device_put(x, kv_sh),
+                lambda x: jax.device_put(
+                    x, NamedSharding(model.mesh, kv_spec(x))),
                 self.state_manager.kv_cache.cache)
         # Token-dim buckets of an engine that packs chunks back to back
         # (its budget is no whole number of PREFILL_TILEs; a tiled engine
@@ -606,14 +606,12 @@ class InferenceEngineV2:
         sm = self.state_manager
         rows = list(zip(self._batch.sequences, self._batch.chunk_sizes))
         attrs = {"tokens": self._batch.current_tokens, "bucket": bucket}
-        # a model that states its own pool row: a latent one (no k / v
-        # leaves), or keys and values laid flat
-        row = sm.kv_cache.kv_row or {}
-        latent = bool(row) and "k" not in row
+        # a model that states its own pool row (a latent one: no k / v)
+        latent = bool(sm.kv_cache.kv_row)
         # sequences with a chunk in the tile segment here, and those
         # chunks' (start, tokens)
         tiled = [(s.seen_tokens, n) for s, n in rows if n > 1]
-        if self._stateful or row:
+        if self._stateful or latent:
             attrs.update(chunk_seqs=len(tiled),
                          chunk_tokens=sum(n for _, n in tiled))
         if self._stateful:
@@ -621,13 +619,12 @@ class InferenceEngineV2:
         if self._grouped:
             attrs.update(self._window_counters(
                 [s for s, n in rows if n == 1], tiled))
-        if row:
-            # what the one-token read (the decode walk, the absorbed
-            # read) must do: the table blocks the batch's one-token rows
-            # hold up to the position they feed
-            bs = sm.block_size
-            attrs["row_blocks"] = sum(
-                s.seen_tokens // bs + 1 for s, n in rows if n == 1)
+        # what the one-token read (the decode walk, the absorbed read) must
+        # do: the table blocks the batch's one-token rows hold up to the
+        # position they feed
+        bs = sm.block_size
+        attrs["row_blocks"] = sum(
+            s.seen_tokens // bs + 1 for s, n in rows if n == 1)
         if latent:
             # what the expanded read must do: the causal (query, key)
             # pairs of the chunks, and the context rows to expand (each
@@ -1375,7 +1372,8 @@ class InferenceEngineV2:
 
         ``include_kv=True`` additionally gathers each sequence's actual
         KV rows to the host (``"kv"``: a per-layer ``{"k"/"v"}`` tree of
-        ``[blocks * block_size, Hkv, D]`` arrays in block-table order) so
+        ``[blocks * block_size, ...]`` arrays, rows as the pool stores them,
+        in block-table order) so
         another engine over the same model can :meth:`resume` WITHOUT the
         recompute re-prefill — the disaggregated prefill→decode handoff."""
         if include_kv:

@@ -8,8 +8,16 @@ Pallas TPU kernel using scalar prefetch: the ragged metadata
 THE BLOCK SPEC INDEX MAPS, so each grid step DMAs exactly the KV pool
 block the current token's block table names — no per-token context gather
 is ever materialised (the XLA reference path builds a [T, C, Hkv, D]
-gather; this kernel's live set is one [block_size, Hkv, D] block plus the
+gather; this kernel's live set is one [block_size, Hkv*D] block plus the
 accumulators).
+
+The stored form of a float pool is the one BOTH kernels the serving cells
+run read without a copy: ``[rows, Hkv*D]``, a token's heads side by side
+in whole 128-lane tiles.  ``[blocks, block_size, Hkv*D]`` is then a free
+split of the leading dimension: the tiled kernel's block specs and the
+walk's DMAs address it as it lies.  (``[rows, Hkv, D]`` is not that: on the
+chip its last two dimensions are tiled, and the flattened-lane view the
+tiled kernel wants is a second pool that XLA writes in front of every call.)
 
 Four kernels, by the shape of the rows they serve (the route is
 ``ragged_llama._paged_attention``'s):
@@ -21,9 +29,10 @@ Four kernels, by the shape of the rows they serve (the route is
   [tile, D] x [block, D];
 * ``_decode_kernel`` — one token a row (a decode step, or the single-token
   rows of such a forward) on a pool the DMA walk can copy
-  (``decode_walk_usable``: ``D % 128 == 0``, or narrower heads in a flat
-  pool row ``[rows, Hkv*D]`` of whole lane tiles, the packed-heads note
-  below), whatever the pool's size: one grid step per row, a
+  (``decode_walk_usable``: a float pool in the flat row ``[rows, Hkv*D]``
+  of whole lane tiles that ``BlockedKVCache`` stores, heads of whole tiles
+  or heads that divide one, the note on its arithmetic below; an int8 pool
+  at ``D % 128 == 0``), whatever the pool's size: one grid step per row, a
   manual double-buffered DMA walk over the blocks the row's table holds up
   to its position, the next live row's first blocks in flight while this
   row computes;
@@ -44,6 +53,7 @@ two walks copy no block past a row's position at all.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any
 
 import jax
@@ -51,6 +61,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from deepspeed_tpu.inference.v2.ragged.kv_cache import flat_row
 from deepspeed_tpu.utils.platform import kernel_names, on_tpu
 
 NEG_INF = -1e30
@@ -131,9 +142,10 @@ def paged_attention_usable(q, k_pool, block_size: int) -> bool:
 # The grid-(tokens, blocks) kernel above spends one grid step per
 # (token, table entry) — a skinny [H, D] x [bs, Hkv, D] work item whose
 # fixed grid-step cost dominates at decode (VERDICT r4 weak #3).  Here
-# the KV pool stays in HBM (memory_space=ANY), in the [blocks, bs, Hkv, D]
-# view it is stored in (a free split of its leading dimension: no relayout
-# copy in front of the call), and the kernel runs ONE grid step per row: a
+# the KV pool stays in HBM (memory_space=ANY), in the [blocks, bs, Hkv*D]
+# view of the flat row it is stored in (a free split of its leading
+# dimension: no relayout copy in front of the call), and the kernel runs ONE
+# grid step per row: a
 # fori_loop with a DYNAMIC trip count walks exactly the blocks the row's
 # table holds up to its position — the HBM read volume is the held bytes,
 # not O(pool) (the dense XLA read) or O(S * table-width) (grid version).
@@ -151,16 +163,10 @@ def paged_attention_usable(q, k_pool, block_size: int) -> bool:
 #   half a megabyte a stream and its fixed cost is paid once); entries
 #   past the row's last are neither copied nor waited for, their stale
 #   keys are masked by position.
-# * EVERY KV head of a step in one pair of dots on the pool's dtype with
-#   float32 accumulation: the [blocks, bs, Hkv, D] buffer is read in its
-#   free [blocks*bs*Hkv, D] view (row c = key c // Hkv of KV head c % Hkv),
-#   ``q [H, D]`` is contracted over D against all of it, and the products
-#   of a query head with another KV head are masked together with the
-#   positions.  No per-head slice (a strided sublane gather of packed bf16),
-#   no transpose, no float32 copy of a block; the MXU loads the same
-#   ``bs*Hkv*D / 128^2`` weight tiles a per-head walk would, at M = H rows
-#   instead of M = group size, which is why group size 1 (16 KV heads)
-#   needs no path of its own.  Softmax statistics stay float32; the
+# * The arithmetic of a step (the note below): EVERY KV head in one pair
+#   of dots on the pool's dtype over the whole [keys, Hkv*D] step, with
+#   float32 accumulation.  No per-head sublane gather, no transpose, no
+#   float32 copy of a block.  Softmax statistics stay float32; the
 #   probabilities are cast to the pool dtype for PV, as the dense read does.
 # ===================================================================== #
 # --------------------------------------------------------------------- #
@@ -175,10 +181,11 @@ def paged_attention_usable(q, k_pool, block_size: int) -> bool:
 # per-row scale multiplies the [g, bs] score tile after the QK dot and
 # the probability tile before the PV dot (a lane-wise product on g rows
 # instead of a dequantized [bs, D] tile).  Cost to know: at 8 KV heads
-# the flattened view is not a free reshape of the [rows, Hkv, D] pool in
-# the TPU tiled layout — XLA inserts a relayout copy of the pool in front
-# of the call (PERF.md) — so this form is the one that compiles, not yet
-# the one that is fast.
+# the flattened view is not a free reshape of the [rows, Hkv, D] pool an
+# int8 cache still keeps (its scales are a record a KV head) in the TPU
+# tiled layout — XLA inserts a relayout copy of the pool in front of the
+# call (PERF.md section 7) — so this form is the one that compiles, not yet
+# the one that is fast.  No cell serves an int8 pool.
 # --------------------------------------------------------------------- #
 def _block_scales(scale_pool, block_tables, slots, nb, block_size, hkv):
     """[rows, Hkv] scale pool -> [S, B, Hkv, bs] scales of each
@@ -189,39 +196,39 @@ def _block_scales(scale_pool, block_tables, slots, nb, block_size, hkv):
 
 
 def _head_tiles(block, hkv, d):
-    """[bs, Hkv*D] int8 block -> Hkv fp32 [bs, D] tiles (static,
-    128-aligned lane slices)."""
+    """[bs, Hkv*D] block -> Hkv fp32 [bs, D] tiles (static, 128-aligned
+    lane slices)."""
     return [block[:, i * d:(i + 1) * d].astype(jnp.float32)
             for i in range(hkv)]
 
 
-def _scores_int8(qg, k_tiles, ks, scale):
-    """qg [Hkv, g, D], int8-valued k tiles, ks [Hkv, bs] -> [Hkv, g, bs]
-    scaled scores."""
+def _head_scores(qg, k_tiles, ks, scale):
+    """qg [Hkv, g, D], fp32 k tiles, ks [Hkv, bs] the keys' int8 scales
+    (None: a float pool) -> [Hkv, g, bs] scaled scores."""
     return jnp.stack([
         jax.lax.dot_general(qg[i], kt, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
-        * (ks[i:i + 1, :] * scale) for i, kt in enumerate(k_tiles)])
+        * (scale if ks is None else ks[i:i + 1, :] * scale)
+        for i, kt in enumerate(k_tiles)])
 
 
-def _pv_int8(pg, v_tiles, vs):
-    """pg [Hkv, g, bs], int8-valued v tiles, vs [Hkv, bs] -> [Hkv, g, D]."""
+def _head_pv(pg, v_tiles, vs):
+    """pg [Hkv, g, bs], fp32 v tiles, vs [Hkv, bs] the values' int8 scales
+    (None: a float pool) -> [Hkv, g, D]."""
     return jnp.stack([
-        jax.lax.dot_general(pg[i] * vs[i:i + 1, :], vt,
-                            (((1,), (0,)), ((), ())),
+        jax.lax.dot_general(pg[i] if vs is None else pg[i] * vs[i:i + 1, :],
+                            vt, (((1,), (0,)), ((), ())),
                             preferred_element_type=jnp.float32)
         for i, vt in enumerate(v_tiles)])
 
 
 def _decode_kernel(token_slot, token_pos, tables, q_ref, k_hbm, v_hbm,
-                   *refs, block_size, scale, window, quantized=False,
-                   pack=0):
+                   *refs, block_size, scale, window, quantized=False):
+    # a float pool: the note below on the arithmetic over [bs, Hkv*D] blocks.
     # quantized mode walks the SAME block schedule over the int8 payload
     # and applies the scales inside the online-softmax update (see the
     # int8 note above) — never a separate dequantized pass, and the HBM
     # read is int8 bytes plus the small pre-gathered scale block.
-    # ``pack`` (heads narrower than a lane tile, see the packed-heads note
-    # below): the same schedule again over flat [bs, Hkv*D] blocks.
     if quantized:
         ks_ref, vs_ref, o_ref, k_buf, v_buf, sems, half_ref = refs
     else:
@@ -299,54 +306,34 @@ def _decode_kernel(token_slot, token_pos, tables, q_ref, k_hbm, v_hbm,
                                    key > pos - window - j0 * block_size)
         return keep
 
-    if pack:
-        _packed_walk(q_ref, o_ref, k_buf, v_buf, advance, visible, steps,
-                     scale)
+    if not quantized:
+        # heads under a lane tile arrive packed to tiles, [1, tiles, ...]
+        compute = _packed_walk if q_ref.ndim == 4 else _row_walk
+        compute(q_ref, o_ref, k_buf, v_buf, advance, visible, steps, scale)
         half_ref[0] = jax.lax.rem(half0 + steps, 2)
         return
 
     h, d = q_ref.shape[1:]
-    hkv = k_buf.shape[3] // d if quantized else k_buf.shape[3]
+    hkv = k_buf.shape[3] // d
     g = h // hkv
-    if quantized:
-        qg = q_ref[0].astype(jnp.float32).reshape(hkv, g, d)
-        # every score column is one key of the step's block
-        key = jax.lax.broadcasted_iota(jnp.int32, (h, block_size), 1)
-    else:
-        q = q_ref[0].astype(k_buf.dtype)  # [H, D]
-        cols = nblk * block_size * hkv
-        # column c of the step's [cols, D] view: key c // Hkv of KV head
-        # c % Hkv; a query head sees the columns of its own KV head only
-        col = jax.lax.broadcasted_iota(jnp.int32, (h, cols), 1)
-        head = jax.lax.broadcasted_iota(jnp.int32, (h, cols), 0)
-        key = jnp.where(col % hkv == head // g, col // hkv, 1 << 30)
+    qg = q_ref[0].astype(jnp.float32).reshape(hkv, g, d)
+    # every score column is one key of the step's block
+    key = jax.lax.broadcasted_iota(jnp.int32, (h, block_size), 1)
 
     def body(i, carry):
         m_prev, l_prev, acc = carry
         j0, half = advance(i)
-        if quantized:
-            s = _scores_int8(qg, _head_tiles(k_buf[half, 0], hkv, d),
-                             ks_ref[0, j0], scale).reshape(h, block_size)
-        else:
-            s = jax.lax.dot_general(
-                q, k_buf.at[half].reshape(cols, d)[...],
-                (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale    # [H, cols]
+        s = _head_scores(qg, _head_tiles(k_buf[half, 0], hkv, d),
+                         ks_ref[0, j0], scale).reshape(h, block_size)
         s = jnp.where(visible(key, j0), s, NEG_INF)
         m_cur = jnp.max(s, axis=1, keepdims=True)
         m_new = jnp.maximum(m_prev, m_cur)
         p = jnp.exp(s - m_new)            # every row sees a key each step
         corr = jnp.exp(m_prev - m_new)
         l_new = l_prev * corr + jnp.sum(p, axis=1, keepdims=True)
-        if quantized:
-            out = _pv_int8(p.reshape(hkv, g, block_size),
-                           _head_tiles(v_buf[half, 0], hkv, d),
-                           vs_ref[0, j0]).reshape(h, d)
-        else:
-            out = jax.lax.dot_general(
-                p.astype(v_buf.dtype), v_buf.at[half].reshape(cols, d)[...],
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)            # [H, D]
+        out = _head_pv(p.reshape(hkv, g, block_size),
+                       _head_tiles(v_buf[half, 0], hkv, d),
+                       vs_ref[0, j0]).reshape(h, d)
         return m_new, l_new, acc * corr + out
 
     m0 = jnp.full((h, 1), NEG_INF, jnp.float32)
@@ -359,30 +346,103 @@ def _decode_kernel(token_slot, token_pos, tables, q_ref, k_hbm, v_hbm,
 
 
 # --------------------------------------------------------------------- #
-# Packed heads: the decode walk at a head size under a lane tile (D = 64).
+# The arithmetic of the decode walk on flat [bs, Hkv*D] blocks.
 #
-# A pool whose row is stored FLAT, [rows, Hkv*D] (a model states such a row
-# through ``kv_row``; 8 x 64 = 512 lanes, whole tiles), is walked in that
-# form: a table block is the contiguous [bs, Hkv*D] it is, so a held token
-# moves Hkv*D*2 B a stream and nothing more.  (The [rows, Hkv, 64] pool
-# every other model keeps is no alternative: on the chip XLA lays its 64
-# lanes out transposed, rows minor, and a kernel that wants row blocks pays
-# a relayout copy of the whole pool a call.)  A 128-lane tile of a block
-# holds ``pack = 128 // D`` KV heads side by side.  How the half-tile head
-# is multiplied: the wrapper hands in, per lane tile, the ``pack * g``
-# query heads of its KV heads, each ZERO-PADDED to 128 lanes with its values
-# in its own KV head's lanes (``_pack_queries``), so one dot of [pack*g,
-# 128] with the tile's [keys, 128] gives every head's scores against its own
-# KV head alone: the other head's lanes meet zeros.  PV is one dot with the
-# value tile, [pack*g, 128], of which a head's own D lanes are kept
-# (``_unpack_heads``).  Cost: ``pack`` times the MXU passes the mathematics
-# needs (a pass is half zeros), on a read bound by its bytes; no lane
-# shuffle, no masked product, no relayout in VMEM, and the bytes moved are
-# the held tokens'.
+# A float pool is stored FLAT, [rows, Hkv*D] in whole 128-lane tiles
+# (``BlockedKVCache``; 8 x 128 = 1024 lanes, 8 x 64 = 512), and walked in
+# that form: a table block is the contiguous [bs, Hkv*D] it is, so a held
+# token moves Hkv*D*2 B a stream and nothing more.  A per-head [keys, D]
+# slice of it is cheap (static, whole lane tiles), but a dot a KV head is
+# Hkv skinny dots of a few weight tiles each a step, and their latencies,
+# not their work, then bound the walk (measured: 27-40% over the read's
+# time at 8 and 16 KV heads, PERF.md section 6, PR 41).  So a step is ONE
+# pair of dots over every lane of the block, the queries laid out so that a
+# head meets its own KV head's lanes alone:
+#
+# * heads of whole tiles (D = 128, Qwen3-Next's 256; ``_row_walk``): the
+#   kernel spreads ``q [H, D]`` block-diagonally over the row's lanes, head
+#   ``h`` in the lanes of KV head ``h // g`` and zeros in the rest, once a
+#   row.  ``q_bd [H, Hkv*D]`` against the step's ``[keys, Hkv*D]`` is every
+#   head's scores ``[H, keys]``: the other heads' lanes meet zeros.  The MXU
+#   loads the ``bs*Hkv*D / 128^2`` weight tiles a step holds once, at M = H
+#   rows (group size 1, 16 KV heads, needs no path of its own), and softmax
+#   runs on ``[H, keys]``.  ``p [H, keys]`` against the value block gives
+#   ``[H, Hkv*D]``, accumulated whole; a head's own D lanes are picked out
+#   of it once a row.  No product is masked away but by position.
+# * heads under a tile (D = 64; ``_packed_walk``): the same idea a 128-lane
+#   tile at a time, ``pack = 128 // D`` KV heads side by side.  The wrapper
+#   hands in, per lane tile, the ``pack * g`` query heads of its KV heads,
+#   each ZERO-PADDED to 128 lanes with its values in its own KV head's lanes
+#   (``_pack_queries``), so one dot of [pack*g, 128] with the tile's [keys,
+#   128] gives every head's scores against its own KV head alone.  PV is one
+#   dot with the value tile, [pack*g, 128], of which a head's own D lanes
+#   are kept (``_unpack_heads``).  Cost: ``pack`` times the MXU passes the
+#   mathematics needs (a pass is half zeros), on a read bound by its bytes.
+#
+# Either way: no lane shuffle, no per-head sublane gather, no relayout in
+# VMEM, and the bytes moved are the held tokens'.
 # --------------------------------------------------------------------- #
+def _row_walk(q_ref, o_ref, k_buf, v_buf, advance, visible, steps, scale):
+    """The compute of ``_decode_kernel`` on flat blocks at heads of whole
+    lane tiles: one online softmax over the step's keys for every head, the
+    queries block-diagonal over the row's lanes.  What is done once a row
+    (spreading the queries, picking each head's own lanes out of the
+    accumulator) is done a [H, D] lane block at a time and not at all on a
+    pad row: at a few blocks a row it is what the row costs."""
+    h, d = q_ref.shape[1:]
+    nblk, block_size, lanes = k_buf.shape[1:]
+    keys = nblk * block_size
+    hkv = lanes // d
+    g = h // hkv
+    row = jax.lax.broadcasted_iota(jnp.int32, (h, d), 0)
+
+    def own(i):
+        """[H, D] of KV head ``i``'s lanes: the rows of its query heads."""
+        return jnp.logical_and(row >= i * g, row < (i + 1) * g)
+
+    @pl.when(steps == 0)
+    def _():
+        o_ref[0] = jnp.zeros(o_ref.shape[1:], o_ref.dtype)
+
+    @pl.when(steps > 0)
+    def _():
+        q32 = q_ref[0].astype(jnp.float32)
+        q = jnp.concatenate([jnp.where(own(i), q32, 0.0) for i in range(hkv)],
+                            axis=1).astype(k_buf.dtype)       # [H, lanes]
+        key = jax.lax.broadcasted_iota(jnp.int32, (h, keys), 1)
+
+        def body(i, carry):
+            m_prev, l_prev, acc = carry
+            j0, half = advance(i)
+            s = jax.lax.dot_general(
+                q, k_buf.at[half].reshape(keys, lanes)[...],
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale   # [H, keys]
+            s = jnp.where(visible(key, j0), s, NEG_INF)
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)        # every row sees a key each step
+            corr = jnp.exp(m_prev - m_new)
+            out = jax.lax.dot_general(
+                p.astype(v_buf.dtype),
+                v_buf.at[half].reshape(keys, lanes)[...],
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)           # [H, lanes]
+            return (m_new,
+                    l_prev * corr + jnp.sum(p, axis=1, keepdims=True),
+                    acc * corr + out)
+
+        _m, l, acc = jax.lax.fori_loop(0, steps, body, (
+            jnp.full((h, 1), NEG_INF, jnp.float32),
+            jnp.zeros((h, 1), jnp.float32),
+            jnp.zeros((h, lanes), jnp.float32)))
+        out = sum(jnp.where(own(i), acc[:, i * d:(i + 1) * d], 0.0)
+                  for i in range(hkv))                        # own lanes
+        o_ref[0] = (out / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
+
+
 def _packed_walk(q_ref, o_ref, k_buf, v_buf, advance, visible, steps, scale):
-    """The compute of ``_decode_kernel`` on flat blocks: per lane tile its
-    own online softmax over the step's keys."""
+    """The compute of ``_decode_kernel`` on flat blocks at heads under a
+    lane tile: per lane tile its own online softmax over the step's keys."""
     tiles, heads = q_ref.shape[1:3]       # lane tiles a row, heads a tile
     nblk, block_size, lanes = k_buf.shape[1:]
     keys = nblk * block_size
@@ -441,26 +501,25 @@ def _unpack_heads(o, h: int, pack: int):
 
 
 def pool_kv_heads(k_pool, d: int) -> int:
-    """KV heads of a pool: [rows, Hkv, D], or the flat row [rows, Hkv*D]
-    a model states."""
+    """KV heads of a pool: the flat row [rows, Hkv*D], or [rows, Hkv, D]."""
     return k_pool.shape[1] // d if k_pool.ndim == 2 else k_pool.shape[1]
 
 
 def decode_walk_usable(d: int, k_pool) -> bool:
     """Can ``_decode_kernel`` walk this pool?  Blocks whose lanes are whole
-    tiles: heads of 128 lanes and multiples in the [rows, Hkv, D] pool, or
-    narrower heads that divide a tile in a flat float pool of whole
-    tiles."""
-    if d % 128 == 0:
-        return True
-    return (k_pool.ndim == 2 and 128 % d == 0 and k_pool.shape[1] % 128 == 0
-            and k_pool.dtype != jnp.int8)
+    tiles: a float pool in the flat row [rows, Hkv*D] ``BlockedKVCache``
+    stores (its rule, ``flat_row``: whole tiles a row, heads of whole tiles
+    or heads that divide one; ``_row_walk``, ``_packed_walk``); an int8 pool,
+    [rows, Hkv, D] beside its scales, at heads of whole tiles."""
+    if k_pool.dtype == jnp.int8:
+        return k_pool.ndim == 3 and d % 128 == 0
+    return k_pool.ndim == 2 and flat_row(k_pool.dtype, k_pool.shape[1] // d, d)
 
 
 def _walk_step_blocks(block_bytes: int, width: int, quantized: bool) -> int:
     """Table entries a step of the decode walk copies: as many as bring a
-    stream's step to half a megabyte (one at 8 or more bf16 KV heads of
-    128, four at Qwen3-Next's two heads of 256 — measured on a v5e,
+    stream's step to half a megabyte (two at 8 bf16 KV heads of 128, one
+    at 16, four at Qwen3-Next's two heads of 256 — measured on a v5e,
     PERF.md section 5), never more than a table holds.  The int8 mode's
     per-head tiles and pre-gathered scales are a block's: one."""
     if quantized:
@@ -498,13 +557,16 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     tables = block_tables.astype(jnp.int32)
     slots = token_slot.astype(jnp.int32)
     hkv = pool_kv_heads(k_pool, d)
-    # a flat pool row of heads under a lane tile: the packed-heads note
-    pack = 128 // d if k_pool.ndim == 2 and d % 128 else 0
-    if k_pool.ndim == 2 and not pack:     # whole-tile heads: a free view
-        k_pool = k_pool.reshape(-1, hkv, d)
-        v_pool = v_pool.reshape(-1, hkv, d)
+    if not decode_walk_usable(d, k_pool):
+        raise ValueError(
+            f"paged_decode_attention: a {k_pool.dtype} pool "
+            f"{k_pool.shape} at head size {d} is not one the walk copies: "
+            f"a float pool is walked in the flat row [rows, Hkv*D] of "
+            f"whole 128-lane tiles it is stored in, an int8 pool as "
+            f"[rows, Hkv, D] at D % 128 == 0")
     out_block = (1, h, d)
-    if pack:
+    pack = 0 if quantized or d % 128 == 0 else 128 // d
+    if pack:                              # heads under a lane tile
         q = _pack_queries(q, hkv, pack)
         out_block = (1,) + q.shape[1:]
     nblk = _walk_step_blocks(
@@ -526,7 +588,7 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     )
     kernel = functools.partial(_decode_kernel, block_size=block_size,
                                scale=scale, window=window,
-                               quantized=quantized, pack=pack)
+                               quantized=quantized)
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s_count,) + out_block[1:], q.dtype),
@@ -542,16 +604,13 @@ def _walk_operands(q, k_pool, v_pool, k_scale, v_scale, tables, slots,
     wrappers: the q block per sequence, the KV pools left in HBM for the
     manual block walk, the double-buffered block scratch (``lead`` blocks
     of it: two halves, times the entries of a step in the decode walk) and
-    its DMA semaphores.  bf16 pools walk [bs, Hkv, D] blocks; int8 pools
-    the flattened-lane [bs, Hkv*D] view plus each sequence's gathered
-    scale blocks (see the int8 note)."""
+    its DMA semaphores.  Both walk the flat [bs, Hkv*D] blocks a float
+    pool is stored in; an int8 pool, [rows, Hkv, D], in that view of it (a
+    copy of the pool on the chip, the int8 note) plus each sequence's
+    gathered scale blocks."""
     nb = k_pool.shape[0] // block_size
     quantized = k_scale is not None
-    if k_pool.ndim == 2:                  # a flat row: the block as stored
-        block = (block_size, k_pool.shape[1])
-    else:
-        _rows, hkv, d = k_pool.shape
-        block = (block_size, hkv * d) if quantized else (block_size, hkv, d)
+    block = (block_size, math.prod(k_pool.shape[1:]))
     operands = [q, k_pool.reshape(nb, *block), v_pool.reshape(nb, *block)]
     in_specs = [
         pl.BlockSpec((1,) + q.shape[1:],
@@ -560,6 +619,7 @@ def _walk_operands(q, k_pool, v_pool, k_scale, v_scale, tables, slots,
         pl.BlockSpec(memory_space=pl.ANY),
     ]
     if quantized:
+        hkv = k_scale.shape[1]
         in_specs += [pl.BlockSpec((1, tables.shape[1], hkv, block_size),
                                   lambda t, slot, pos, tab: (t, 0, 0, 0))] * 2
         operands += [_block_scales(sc, tables, slots, nb, block_size, hkv)
@@ -605,7 +665,7 @@ def _verify_kernel(token_slot, token_pos, tables, q_ref, k_hbm, v_hbm,
     qf = q_ref[0].astype(jnp.float32)     # [K*H, D], row k*H+h
     h = qf.shape[0] // k_tokens
     d = qf.shape[1]
-    hkv = k_buf.shape[2] // d if quantized else k_buf.shape[2]
+    hkv = k_buf.shape[2] // d             # flat [bs, Hkv*D] blocks
     g = h // hkv
 
     def dma(buf, hbm, sl, j, which):
@@ -630,24 +690,16 @@ def _verify_kernel(token_slot, token_pos, tables, q_ref, k_hbm, v_hbm,
 
         for buf, hbm, which in streams:
             dma(buf, hbm, sl, j, which).wait()
+        k_tiles = _head_tiles(k_buf[sl], hkv, d)      # Hkv x [bs, D]
+        v_tiles = _head_tiles(v_buf[sl], hkv, d)
+        ks = vs = None
         if quantized:
-            k_tiles = _head_tiles(k_buf[sl], hkv, d)
-            v_tiles = _head_tiles(v_buf[sl], hkv, d)
             ks, vs = ks_ref[0, j], vs_ref[0, j]       # [Hkv, bs]
-        else:
-            k = k_buf[sl].astype(jnp.float32)         # [bs, Hkv, D]
-            v = v_buf[sl].astype(jnp.float32)
         ms, ls, accs = [], [], []
         for kq in range(k_tokens):        # static unroll: K is small
             q = qf[kq * h:(kq + 1) * h]               # [H, D]
             qg = q.reshape(hkv, g, d)
-            if quantized:
-                s = _scores_int8(qg, k_tiles, ks, scale)      # [Hkv,g,bs]
-            else:
-                s = jax.lax.dot_general(
-                    qg, k.transpose(1, 2, 0),
-                    (((2,), (1,)), ((0,), (0,))),
-                    preferred_element_type=jnp.float32) * scale
+            s = _head_scores(qg, k_tiles, ks, scale)  # [Hkv, g, bs]
             key_pos = j * block_size + jax.lax.broadcasted_iota(
                 jnp.int32, (hkv, g, block_size), 2)
             keep = key_pos <= pos0 + kq   # row k's own causal frontier
@@ -663,12 +715,7 @@ def _verify_kernel(token_slot, token_pos, tables, q_ref, k_hbm, v_hbm,
             ls.append(l_prev[kq * h:(kq + 1) * h] * corr
                       + jnp.sum(p, axis=1, keepdims=True))
             pg = p.reshape(hkv, g, block_size)
-            if quantized:
-                out = _pv_int8(pg, v_tiles, vs)       # [Hkv, g, D]
-            else:
-                out = jax.lax.dot_general(
-                    pg, v.transpose(1, 0, 2), (((2,), (1,)), ((0,), (0,))),
-                    preferred_element_type=jnp.float32)
+            out = _head_pv(pg, v_tiles, vs)           # [Hkv, g, D]
             accs.append(acc[kq * h:(kq + 1) * h] * corr
                         + out.reshape(h, d))
             ms.append(m_new)
@@ -704,7 +751,10 @@ def paged_verify_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     position ``token_pos[s * k_tokens] + k``.  token_slot/token_pos are
     the row-level [T] arrays the generic kernels take (each slot's K
     rows share a slot id and carry consecutive positions).  Returns
-    [T, H, D]; pad slots give garbage-but-finite rows.
+    [T, H, D]; pad slots give garbage-but-finite rows.  The pool in the
+    flat row [rows, Hkv*D] it is stored in (an int8 pool [rows, Hkv, D]):
+    the walk copies [bs, Hkv*D] blocks and takes a KV head as a static
+    lane slice of one, so ``D % 128 == 0``.
     """
     t_count, h, d = q.shape
     s_count = t_count // k_tokens
@@ -840,6 +890,9 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
 
     q: [T, H, D] with every [tile_q] stripe single-sequence; token_pos
     [T] int32 with -1 on pad rows. Returns [T, H, D] (pad rows 0).
+    The pool in the flat row [rows, Hkv*D] it is stored in: the kernel's
+    [blocks, bs, Hkv*D] is then a free split of the leading dimension (a
+    [rows, Hkv, D] pool is taken too, through a copy of it on the chip).
     """
     t_count, h, d = q.shape
     hkv = pool_kv_heads(k_pool, d)
@@ -850,7 +903,7 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         interpret = not on_tpu()
 
     # flattened-lane layouts (see _prefill_kernel): q/o [T, H*D], pools
-    # [nb, bs, Hkv*D]
+    # [nb, bs, Hkv*D] (the stored row, its leading dimension split)
     qf = q.reshape(t_count, h * d)
     kp = k_pool.reshape(nb, block_size, hkv * d)
     vp = v_pool.reshape(nb, block_size, hkv * d)
@@ -988,6 +1041,12 @@ def paged_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
 from deepspeed_tpu.analysis.registry import pallas_kernel_case  # noqa: E402
 
 
+def _flat(pool):
+    """[rows, Hkv, D] -> the flat row [rows, Hkv*D] a float pool is stored
+    in."""
+    return pool.reshape(pool.shape[0], -1)
+
+
 def _dslint_paged_setup(d: int):
     import numpy as np
 
@@ -1019,8 +1078,8 @@ def _dslint_paged_grid_case():
          "the double-buffered step scratch is what counts)")
 def _dslint_paged_decode_dma_case():
     bs, kp, vp, tables, slot, pos, q = _dslint_paged_setup(128)
-    paged_decode_attention(q, kp, vp, tables, slot, pos, block_size=bs,
-                           interpret=True)
+    paged_decode_attention(q, _flat(kp), _flat(vp), tables, slot, pos,
+                           block_size=bs, interpret=True)
 
 
 @pallas_kernel_case(
@@ -1117,19 +1176,20 @@ def _dslint_paged_prefill_case():
 @pallas_kernel_case(
     "paged_two_segment",
     note="a mixed tick's batch at Mistral's head counts (32q/8kv, d=128) "
-         "through ragged_llama._paged_attention: 4 single-token rows "
+         "on the flat pool row [rows, 1024] through "
+         "ragged_llama._paged_attention: 4 single-token rows "
          "(slots in no order, two pads at position -1) take the decode "
-         "walk, the tile-aligned chunks behind them (one "
-         "with a sub-tile tail) the tiled prefill kernel")
+         "walk, every KV head in one pair of dots, the tile-aligned chunks "
+         "behind them (one with a sub-tile tail) the tiled prefill kernel")
 def _dslint_paged_two_segment_case():
     two_segment_case()
 
 
 @pallas_kernel_case(
     "paged_two_segment_d64",
-    note="the same mixed tick at 64-wide heads (32q/8kv) on a FLAT pool "
-         "row [rows, 512]: the single-token rows take the decode walk in "
-         "its packed-heads mode (two KV heads to a 128-lane tile, the "
+    note="the same mixed tick at 64-wide heads (32q/8kv), pool row "
+         "[rows, 512]: the single-token rows take the decode walk with "
+         "two KV heads to a 128-lane tile (the "
          "queries zero-padded into their half), the chunks the tiled "
          "kernel's per-head slices at 64-lane offsets")
 def _dslint_paged_two_segment_d64_case():
@@ -1142,8 +1202,7 @@ def _dslint_paged_two_segment_d64_case():
          "one lane tile a block, ragged positions")
 def _dslint_paged_decode_dma_d64_case():
     bs, kp, vp, tables, slot, pos, q = _dslint_paged_setup(64)
-    flat = lambda p: p.reshape(p.shape[0], -1)
-    paged_decode_attention(q, flat(kp), flat(vp), tables, slot, pos,
+    paged_decode_attention(q, _flat(kp), _flat(vp), tables, slot, pos,
                            block_size=bs, interpret=True)
 
 
@@ -1152,9 +1211,8 @@ def two_segment_case(tight_pool: bool = False, d: int = 128):
     chip, interpreted off it) and through the XLA composition: ``(got,
     want, mask of the real rows)``.  Shared with tools/kernel_selftest.py.
     ``tight_pool`` sizes the pool at the table extent instead of over
-    twice it: the single-token rows take the decode walk either way.  At a
-    head size under a lane tile (``d`` 64) the pool row is flat, [rows,
-    Hkv*D], as a model that serves such heads states it."""
+    twice it: the single-token rows take the decode walk either way.  The
+    pool row is flat, [rows, Hkv*D], as ``BlockedKVCache`` stores it."""
     import numpy as np
 
     from deepspeed_tpu.inference.v2.model_implementations.ragged_llama \
@@ -1163,9 +1221,8 @@ def two_segment_case(tight_pool: bool = False, d: int = 128):
     bs, S, B, tile, h, hkv = 128, 4, 4, 128, 32, 8
     nb = S * B + 1 if tight_pool else 2 * S * B + 2
     rng = np.random.default_rng(21)
-    row = (hkv, d) if d % 128 == 0 else (hkv * d,)
     pool = lambda: jnp.asarray(
-        rng.standard_normal((nb * bs,) + row).astype(np.float32),
+        rng.standard_normal((nb * bs, hkv * d)).astype(np.float32),
         jnp.bfloat16)
     kp, vp = pool(), pool()
     tables = jnp.arange(1, S * B + 1, dtype=jnp.int32).reshape(S, B)

@@ -25,6 +25,7 @@ from deepspeed_tpu.inference.v2.model_implementations.ragged_llama import (
     _layer_norm,
     _paged_attention,
     _rotary,
+    insert_kv,
 )
 from deepspeed_tpu.models.falcon import FalconConfig, split_fused_qkv
 from deepspeed_tpu.models.llama import apply_rotary
@@ -82,8 +83,7 @@ class RaggedFalcon:
             q = apply_rotary(q, cos, sin)
             k = apply_rotary(k, cos, sin)
             lc = kv_cache[f"layer_{i}"]
-            k_pool = lc["k"].at[kv_dest].set(k.astype(lc["k"].dtype))
-            v_pool = lc["v"].at[kv_dest].set(v.astype(lc["v"].dtype))
+            k_pool, v_pool = insert_kv(lc, kv_dest, k, v)
             new_cache[f"layer_{i}"] = {"k": k_pool, "v": v_pool}
             out = _paged_attention(q, k_pool, v_pool, batch,
                                    self.block_size,

@@ -19,12 +19,14 @@ pool this model shares:
   boundaries, through decode steps and past pad rows, is
   ``ragged_qwen3_next._causal_conv`` with no activation.
 * **A flat pool row.**  Heads of 64 are half a lane tile: the attention
-  layers state their pool row flat, ``kv_row = {"k": Hkv*D, "v": Hkv*D}``
-  (512 lanes at the published widths, whole tiles), which the decode walk and
-  the tiled prefill kernel read as stored
-  (``kernels/blocked_flash.py``, the packed-heads note).  q and k take an
-  RMSNorm per head (plain weights) before the rotation, through the shared
-  ``ragged_attention_block``.
+  layers' pools are ``[rows, Hkv*D]`` (512 lanes at the published widths,
+  whole tiles), the form ``BlockedKVCache`` stores every float pool of
+  whole-tile rows in (``kv_cache.flat_row``; until PR 41 this model asked
+  for it by a ``kv_row`` of its own), which the decode walk and the tiled
+  prefill kernel read as stored (``kernels/blocked_flash.py``, the note on
+  the walk's arithmetic: two heads of 64 a lane tile).  q and
+  k take an RMSNorm per head (plain weights) before the rotation, through
+  the shared ``ragged_attention_block``.
 * **Every expert held**: the router of the DeepSeek-V3 family
   (``sigmoid_bias_topk_routing``) with the published code's ``1e-6`` in the
   renormalisation (``router_norm_eps``; ``config.json`` does not carry it).
@@ -171,7 +173,8 @@ def param_shapes(cfg: Lfm2Config) -> Dict[str, Any]:
 class RaggedLfm2:
     """Callable ragged forward bound to a :class:`Lfm2Config`."""
 
-    #: a flat pool row has no head to keep an int8 scale by
+    #: the attention reads pass no scales: int8 pools are refused by the
+    #: engine
     supports_quantized_kv = False
 
     def __init__(self, config: Lfm2Config, block_size: int):
@@ -190,13 +193,6 @@ class RaggedLfm2:
     @property
     def head_dim(self):
         return self.config.head_dim
-
-    @property
-    def kv_row(self) -> Dict[str, int]:
-        """The pool row an attention layer keeps a token: keys and values,
-        each with its KV heads side by side."""
-        lanes = self.config.num_key_value_heads * self.config.head_dim
-        return {"k": lanes, "v": lanes}
 
     @property
     def state_spec(self) -> Dict[str, Any]:

@@ -39,6 +39,7 @@ compare the two token-for-token.
 from __future__ import annotations
 
 import functools
+import math
 import re
 from typing import Any, Dict, Optional
 
@@ -76,7 +77,11 @@ def shard_ragged_params(params, mesh: Mesh) -> Any:
         lambda x, s: jax.device_put(x, NamedSharding(mesh, s)), params, specs)
 
 
-KV_SPEC = P(None, "model", None)  # pool [flat, Hkv, D]: kv heads split
+def kv_spec(leaf) -> P:
+    """A pool leaf's spec under TP: the KV heads split, which is a lane
+    range of the flat row [rows, Hkv*D] and the middle dimension of a
+    [rows, Hkv, D] pool (and of an int8 pool's [rows, Hkv] scales)."""
+    return P(None, "model", *(None,) * (leaf.ndim - 2))
 
 
 def _layer_norm(x, p, eps):
@@ -106,9 +111,11 @@ def _paged_attention(q, k_pool, v_pool, batch, block_size,
                      k_scale=None, v_scale=None):
     """Paged attention over the blocked KV pool.
 
-    q: [T, H, D]; k_pool/v_pool: [num_blocks*bs, Hkv, D].
-    Returns [T, H, D]. Under TP the caller passes LOCAL heads — the kernel
-    is oblivious to the mesh. ``window`` = Mistral sliding-window width.
+    q: [T, H, D]; k_pool/v_pool: the pool as ``BlockedKVCache`` stores it,
+    the flat row [num_blocks*bs, Hkv*D] (a float pool of whole-tile rows)
+    or [num_blocks*bs, Hkv, D].  Returns [T, H, D]. Under TP the caller
+    passes LOCAL heads — the kernel is oblivious to the mesh. ``window`` =
+    Mistral sliding-window width.
 
     On TPU a ``put`` forward routes to the Pallas blocked-flash kernels
     (inference/v2/kernels/blocked_flash.py): block tables drive the
@@ -125,11 +132,11 @@ def _paged_attention(q, k_pool, v_pool, batch, block_size,
 
     ``decode_mode`` (static; engine decode programs set it) asserts
     T == S with ``token_slot == arange(S)``.  On TPU, on a pool the DMA
-    walk can copy (``decode_walk_usable``: heads of whole lane tiles, ``D
-    % 128 == 0``, or narrower heads in a FLAT pool row ``[rows, Hkv*D]``
-    of whole tiles that the model states through ``kv_row``; such a pool
-    arrives here 2-D and goes to the kernels as it is stored), it routes
-    to the manual-DMA decode kernel
+    walk can copy (``decode_walk_usable``: a float pool in the flat row
+    ``[rows, Hkv*D]`` of whole lane tiles, at heads of whole tiles or heads
+    that divide one; such a pool arrives here 2-D and goes to the walk and
+    to the tiled kernel as it is stored; an int8 pool at ``D % 128 ==
+    0``), it routes to the manual-DMA decode kernel
     (:func:`deepspeed_tpu.inference.v2.kernels.paged_decode_attention`):
     each row reads exactly the blocks its table holds up to its position,
     so what the read costs follows what the rows hold (their
@@ -138,9 +145,9 @@ def _paged_attention(q, k_pool, v_pool, batch, block_size,
     when it was the cells' route (PERF.md section 7: the benchmark's
     readers match it).
 
-    Off the kernels (CPU; pools the walk cannot copy: 64-wide heads in
-    the [rows, Hkv, D] pool) a decode step takes one of two XLA
-    compositions, which are
+    Off the kernels (CPU; pools the walk cannot copy: a row that is no
+    whole number of lane tiles, kept [rows, Hkv, D]) a decode step takes
+    one of two XLA compositions, on the pool's per-head view, which are
     also the references the kernels are tested against: the masked dense
     read of the whole pool while the pool is no larger than twice what the
     tables could hold, the gather bounded by the table extent beyond.
@@ -155,8 +162,9 @@ def _paged_attention(q, k_pool, v_pool, batch, block_size,
     if use_kernel is None:
         use_kernel = on_tpu()
     S = batch["block_tables"].shape[0]
-    # a flat pool row (the model's ``kv_row``): the walk and the tiled
-    # kernel read it as stored; everything else its per-head view
+    # the flat pool row: the walks (decode, verify) and the tiled kernel
+    # read it as stored; the XLA reads and the token-grid kernel (a budget of
+    # no whole tiles; no cell runs it) its per-head view
     flat_k, flat_v = k_pool, v_pool
     k_pool, v_pool = _head_view(k_pool, q), _head_view(v_pool, q)
     if use_kernel:
@@ -169,17 +177,17 @@ def _paged_attention(q, k_pool, v_pool, batch, block_size,
             w = int(window) if window is not None else None
             meta = (batch["block_tables"], batch["token_slot"],
                     batch["token_pos"])
-            # the manual-DMA walks copy pool blocks whose lanes are whole
-            # tiles: [bs, Hkv, D] at D % 128 == 0, or a flat [bs, Hkv*D]
+            # the manual-DMA walks (decode, verify) copy the pool's flat
+            # [bs, Hkv*D] blocks as they are stored
             walk = decode_walk_usable(q.shape[-1], flat_k)
-            if verify_k and walk and flat_k is k_pool:
+            if verify_k and q.shape[-1] % 128 == 0:
                 # speculative multi-token verify: K query rows per slot
                 # share one block walk (the fused multi-query variant of
                 # the decode kernel).  Smaller head dims fall through to
                 # the generic grid kernel, which handles verify-shaped
                 # metadata unchanged.
                 return paged_verify_attention(
-                    q, k_pool, v_pool, *meta, block_size=block_size,
+                    q, flat_k, flat_v, *meta, block_size=block_size,
                     k_tokens=int(verify_k), window=w, k_scale=k_scale,
                     v_scale=v_scale)
             if decode_mode:
@@ -218,8 +226,9 @@ def _paged_attention(q, k_pool, v_pool, batch, block_size,
 
 
 def _head_view(pool, q):
-    """[rows, Hkv, D] of a pool: itself, or the per-head view of a flat row
-    [rows, Hkv*D] (a model's ``kv_row``)."""
+    """[rows, Hkv, D] of a pool: itself, or the per-head view of the flat
+    row [rows, Hkv*D] (on the chip a copy of the pool: the reads the cells
+    run never take it)."""
     return pool.reshape(pool.shape[0], -1, q.shape[-1]) if pool.ndim == 2 \
         else pool
 
@@ -239,9 +248,9 @@ def _single_row_read(q, k_pool, v_pool, tables, slot, pos, block_size,
     blocks each row holds, in the device scope ``attn/dense_read`` —
     except that the rows' slots are in no order and pad rows (position
     -1) sit between them, which the walk takes.  On a pool the walk
-    cannot copy (``decode_walk_usable``: narrow heads in the [rows, Hkv, D]
-    pool) these few rows go through the token-grid kernel on a big pool
-    and the dense XLA read on a tight one."""
+    cannot copy (``decode_walk_usable``: a row that is no whole number of
+    lane tiles) these few rows go through the token-grid kernel on a big
+    pool and the dense XLA read on a tight one."""
     from deepspeed_tpu.inference.v2.kernels import (decode_walk_usable,
                                                     paged_attention,
                                                     paged_decode_attention)
@@ -268,8 +277,8 @@ def _dense_pool_read(q, k_pool, v_pool, k_scale, v_scale, batch, block_size,
     (device scope ``attn/dense_read``): a decode step's rows, or the
     single-token rows of a two-segment batch, whose slots are in no order
     and whose pad rows carry position -1 (they attend nothing and come out
-    finite).  The CPU's route, the d64 families' on the chip, and the
-    reference the walk is tested against."""
+    finite).  The CPU's route, on the chip that of a row that is no whole
+    number of lane tiles, and the reference the walk is tested against."""
     from deepspeed_tpu.inference.v2.ragged.kv_cache import dequantize_kv
 
     quantized = k_scale is not None
@@ -498,15 +507,32 @@ def _rope_insert(q, k, v, cos, sin, layer_cache, kv_dest):
         v_scale = layer_cache["v_scale"].at[kv_dest].set(vs)
         return q, k_pool, v_pool, k_scale, v_scale, {
             "k": k_pool, "v": v_pool, "k_scale": k_scale, "v_scale": v_scale}
-    if layer_cache["k"].ndim == 2:
-        # the model states a flat pool row [rows, Hkv*D] (``kv_row``):
-        # a token's heads side by side, written as one row
-        k, v = k.reshape(k.shape[0], -1), v.reshape(v.shape[0], -1)
-    k_pool = layer_cache["k"].at[kv_dest].set(
-        k.astype(layer_cache["k"].dtype))
-    v_pool = layer_cache["v"].at[kv_dest].set(
-        v.astype(layer_cache["v"].dtype))
+    k_pool, v_pool = insert_kv(layer_cache, kv_dest, k, v)
     return q, k_pool, v_pool, None, None, {"k": k_pool, "v": v_pool}
+
+
+def insert_kv(layer_cache, kv_dest, k, v):
+    """The paged-KV scatter of ``k`` and ``v`` ``[T, Hkv, D]`` into a float
+    layer's pools; ``(k_pool, v_pool)``.  In the flat row [rows, Hkv*D] a
+    token's heads stand side by side and are written as one row, addressed
+    as (sublane tile, row of the tile) in the pool's ``[rows / 16, 16,
+    Hkv*D]`` view (16 bf16 rows a tile; a free split of the leading
+    dimension).  Why not ``pool.at[kv_dest]``: on a pool of a few MB (a
+    test-sized engine, a TP shard) XLA's TPU scatter takes that form through
+    a sort of the indices, and a step program holding that scatter never
+    returned on the chip once another engine had run in the process (PERF.md
+    section 6, PR 41: calls 6-11); the two-index form takes no sort there,
+    and on a pool of a cell's size XLA folds it back into the same row
+    scatter, to the instruction."""
+    def put(pool, x):
+        if pool.ndim != 2:
+            return pool.at[kv_dest].set(x.astype(pool.dtype))
+        rows, lanes = pool.shape
+        tile = math.gcd(rows, 32 // pool.dtype.itemsize)
+        return pool.reshape(rows // tile, tile, lanes).at[
+            jax.lax.div(kv_dest, tile), jax.lax.rem(kv_dest, tile)].set(
+                x.reshape(-1, lanes).astype(pool.dtype)).reshape(rows, lanes)
+    return put(layer_cache["k"], k), put(layer_cache["v"], v)
 
 
 class RaggedLlama:
@@ -514,8 +540,8 @@ class RaggedLlama:
 
     ``mesh`` with a non-trivial 'model' axis turns on tensor parallelism:
     ``__call__`` becomes a shard_map over that axis (params/KV pool must be
-    placed with :func:`shard_ragged_params` / ``KV_SPEC`` — the engine does
-    this).
+    placed with :func:`shard_ragged_params` / :func:`kv_spec` — the engine
+    does this).
     """
 
     #: the shared ragged_attention_block write path quantizes on insert
@@ -581,7 +607,7 @@ class RaggedLlama:
                                  prefill_tile=prefill_tile, decode=decode,
                                  verify_k=verify_k)
         param_specs = ragged_param_specs(params)
-        cache_specs = jax.tree.map(lambda _x: KV_SPEC, kv_cache)
+        cache_specs = jax.tree.map(kv_spec, kv_cache)
         batch_specs = jax.tree.map(lambda _x: P(), batch)
         fwd = functools.partial(self._forward, ax=self.tp_axis,
                                 prefill_tile=prefill_tile, decode=decode,

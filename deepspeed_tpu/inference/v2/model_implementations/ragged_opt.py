@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from deepspeed_tpu.inference.v2.model_implementations.ragged_llama import (
     _layer_norm,
     _paged_attention,
+    insert_kv,
 )
 from deepspeed_tpu.models.opt import OPT_POSITION_OFFSET, OPTConfig
 
@@ -87,8 +88,7 @@ class RaggedOPT:
             k = _dense(xa, at["k_proj"], dt).reshape(-1, h, d)
             v = _dense(xa, at["v_proj"], dt).reshape(-1, h, d)
             lc = kv_cache[f"layer_{i}"]
-            k_pool = lc["k"].at[kv_dest].set(k.astype(lc["k"].dtype))
-            v_pool = lc["v"].at[kv_dest].set(v.astype(lc["v"].dtype))
+            k_pool, v_pool = insert_kv(lc, kv_dest, k, v)
             new_cache[f"layer_{i}"] = {"k": k_pool, "v": v_pool}
             out = _paged_attention(q, k_pool, v_pool, batch,
                                    self.block_size,

@@ -2,14 +2,23 @@
 ``BlockedKVCache`` over CUDA block pools + the 2.4k-LoC compression
 subsystem's KV quantization, recast TPU-native).
 
-Device layout per layer: ``k/v: [num_blocks * block_size, Hkv, D]`` — a flat
-pool indexed by ``block_id * block_size + offset``. Ragged token writes are
-one scatter; per-sequence reads are one gather through the block table.
-XLA turns both into dynamic-slice/scatter fusions; the Pallas
-paged-attention kernels consume the same layout unchanged.
+Device layout per layer: ``k/v: [num_blocks * block_size, Hkv*D]`` — a pool
+of rows indexed by ``block_id * block_size + offset``, a token's KV heads
+side by side in the lanes of its row.  That is the form BOTH Pallas
+paged-attention kernels the serving cells run read as it lies (``[blocks,
+block_size, Hkv*D]`` is a free split of the leading dimension; the
+``[rows, Hkv, D]`` form is not, on the chip the tiled kernel paid a copy of
+the whole pool a call for it), so it is the ONE stored form of a float pool
+whose row is whole 128-lane tiles and whose heads are whole tiles or divide
+one (:func:`flat_row`: decided from the dtype, ``Hkv`` and ``D``, no knob;
+the one rule the decode walk asks too).  Any other float row (one KV head
+of 64, heads of 96) keeps ``[rows, Hkv, D]`` and the reference reads.  Ragged
+token writes are one scatter; per-sequence reads are one gather through the
+block table.  XLA turns both into dynamic-slice/scatter fusions.
 
 **Quantized mode** (``dtype="int8"``): the pool stores symmetric int8
-payloads with fp32 scale records riding ALONGSIDE in the same tree —
+payloads ``[num_blocks * block_size, Hkv, D]`` with fp32 scale records
+riding ALONGSIDE in the same tree —
 ``k_scale/v_scale: [num_blocks * block_size, Hkv]``, one scale per pool
 row per kv head (quantization group = one head's D-vector, the same
 groupwise absmax/127 rule as ``ops/quantizer``'s symmetric int8 path).
@@ -25,7 +34,10 @@ bit-exact.  Prefill/decode writes quantize on cache insert
 attention): instead of ``k``/``v`` per KV head a layer holds the named
 leaves ``[num_blocks * block_size, lanes]`` behind the same allocator and
 block tables.  Every block operation is a ``tree_map`` over pool rows and
-carries such a row unchanged; int8 mode (a scale per KV head) refuses it.
+carries such a row unchanged; int8 mode (a scale per KV head) refuses it,
+as do ``verify_step`` and speculation (their reads know keys and values).
+No model states a ``k`` / ``v`` row: :func:`flat_row` is the one way to the
+flat pool.
 
 **Two kinds of KV layer** (``window_layers`` / ``window_blocks``, from a
 model's ``kv_groups``): the layers of the window group share a pool of their
@@ -88,6 +100,20 @@ def dequantize_kv(q: jnp.ndarray, scale: jnp.ndarray,
             * scale[..., None].astype(jnp.float32)).astype(dtype)
 
 
+def flat_row(dtype: Any, num_kv_heads: int, head_dim: int) -> bool:
+    """Is a ``k`` / ``v`` pool of this dtype and these heads stored in the
+    flat row ``[rows, Hkv*D]`` (the module doc)?  A float pool whose row is
+    whole 128-lane tiles AND whose heads are whole tiles or divide one: the
+    rows the decode walk reads as they lie (``blocked_flash.
+    decode_walk_usable`` asks this function), so no pool is stored flat for
+    a read that would then copy it back to heads (8 heads of 96: whole
+    tiles a row, but a head starts inside a tile).  An int8 pool keeps its
+    heads apart, beside the scale a head."""
+    return (jnp.dtype(resolve_kv_dtype(dtype)) != jnp.dtype(jnp.int8)
+            and (num_kv_heads * head_dim) % 128 == 0
+            and (head_dim % 128 == 0 or 128 % head_dim == 0))
+
+
 class KVGroupsError(NotImplementedError):
     """A path that assumes ONE block table a sequence (prefix-cache attach
     and register, its copy-on-write fork, the host tier, a KV handoff,
@@ -128,21 +154,22 @@ class BlockedKVCache:
                 f"quantize_kv keeps one scale per KV head, and a latent "
                 f"row has no head to scale by")
         def layer(i):
-            flat = block_size * (self.window_blocks
+            rows = block_size * (self.window_blocks
                                  if i in self.window_layers else num_blocks)
             if self.kv_row:
-                return {name: jnp.zeros((flat, lanes), dtype)
+                return {name: jnp.zeros((rows, lanes), dtype)
                         for name, lanes in self.kv_row.items()}
-            leaves = {
-                "k": jnp.zeros((flat, num_kv_heads, head_dim), dtype),
-                "v": jnp.zeros((flat, num_kv_heads, head_dim), dtype),
-            }
+            row = (num_kv_heads * head_dim,) \
+                if flat_row(dtype, num_kv_heads, head_dim) \
+                else (num_kv_heads, head_dim)
+            leaves = {"k": jnp.zeros((rows,) + row, dtype),
+                      "v": jnp.zeros((rows,) + row, dtype)}
             if self.quantized:
                 # scale 1.0 on never-written rows: dequant of the zero
                 # payload stays zero, same as the unquantized pool
-                leaves["k_scale"] = jnp.ones((flat, num_kv_heads),
+                leaves["k_scale"] = jnp.ones((rows, num_kv_heads),
                                              jnp.float32)
-                leaves["v_scale"] = jnp.ones((flat, num_kv_heads),
+                leaves["v_scale"] = jnp.ones((rows, num_kv_heads),
                                              jnp.float32)
             return leaves
 
@@ -188,7 +215,8 @@ class BlockedKVCache:
 
     def gather_blocks(self, blocks) -> Dict[str, Dict[str, Any]]:
         """Pull the KV rows of ``blocks`` (one sequence's block table) to
-        the host: ``{layer: {"k"/"v": np[len(blocks)*block_size, H, D]}}``.
+        the host: ``{layer: {"k"/"v": np[len(blocks)*block_size, Hkv*D]}}``
+        (each leaf's rows as the pool stores them).
         One device gather + one transfer for the whole tree — the
         disaggregated prefill→decode handoff payload.  Row order follows
         the block table, so position ``p`` lives at row ``p`` regardless

@@ -107,18 +107,14 @@ span                     site                        parent    attrs (counters)
                                                                the tile segment
                                                                and their
                                                                ``chunk_tokens``;
-                                                               with a pool row
-                                                               the model states
-                                                               (``kv_row``: a
-                                                               latent row, or
-                                                               LFM2's flat k /
-                                                               v, new in PR 35):
-                                                               those two and
+                                                               always
                                                                ``row_blocks``
-                                                               (table blocks its
-                                                               one-token rows
-                                                               hold); with a
-                                                               latent row also
+                                                               (table blocks the
+                                                               batch's one-token
+                                                               rows hold); with a
+                                                               latent pool row
+                                                               (``kv_row``) those
+                                                               two and
                                                                ``attn_pairs``
                                                                (causal query-key
                                                                pairs of those

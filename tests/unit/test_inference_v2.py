@@ -332,20 +332,20 @@ def test_v2_tp_hlo_only_rowparallel_allreduce():
     mlp-down), and one all-gather for the vocab-split unembed — nothing
     else (no per-projection resharding)."""
     from deepspeed_tpu.inference.v2.model_implementations.ragged_llama import (
-        KV_SPEC, shard_ragged_params)
+        kv_spec, shard_ragged_params)
+    from deepspeed_tpu.inference.v2.ragged.kv_cache import BlockedKVCache
     from jax.sharding import NamedSharding
 
     params = _tp_params()
     topo = groups.initialize_mesh(model_parallel_size=2)
     model = RaggedLlama(TP_CFG, 8, mesh=topo.mesh)
     params = shard_ragged_params(params, topo.mesh)
-    kv_sh = NamedSharding(topo.mesh, KV_SPEC)
-    cache = {f"layer_{i}": {
-        "k": jax.device_put(jnp.zeros((32, TP_CFG.num_key_value_heads,
-                                       TP_CFG.head_dim), jnp.float32), kv_sh),
-        "v": jax.device_put(jnp.zeros((32, TP_CFG.num_key_value_heads,
-                                       TP_CFG.head_dim), jnp.float32), kv_sh)}
-        for i in range(TP_CFG.num_hidden_layers)}
+    # the pool as the engine places it: the stored row, its KV heads split
+    cache = jax.tree.map(
+        lambda x: jax.device_put(x, NamedSharding(topo.mesh, kv_spec(x))),
+        BlockedKVCache(TP_CFG.num_hidden_layers, 4, 8,
+                       TP_CFG.num_key_value_heads, TP_CFG.head_dim,
+                       jnp.float32).cache)
     meta = {
         "token_ids": jnp.zeros((8,), jnp.int32),
         "token_slot": jnp.zeros((8,), jnp.int32),
@@ -765,10 +765,15 @@ def test_two_segment_attention_routes_match_xla(monkeypatch, d, nb, single):
     from deepspeed_tpu.inference.v2 import kernels
     from deepspeed_tpu.inference.v2.model_implementations import ragged_llama
 
+    from deepspeed_tpu.inference.v2.ragged.kv_cache import flat_row
+
     rng = np.random.default_rng(19)
     bs, hkv, h, tile, S = 8, 2, 4, 16, 4
-    k_pool = jnp.asarray(rng.normal(size=(nb * bs, hkv, d)).astype(np.float32))
-    v_pool = jnp.asarray(rng.normal(size=(nb * bs, hkv, d)).astype(np.float32))
+    # the row as BlockedKVCache stores it: flat where it is whole lane tiles
+    row = (hkv * d,) if flat_row(jnp.float32, hkv, d) else (hkv, d)
+    assert len(row) == (1 if d == 128 else 2)
+    k_pool = jnp.asarray(rng.normal(size=(nb * bs,) + row).astype(np.float32))
+    v_pool = jnp.asarray(rng.normal(size=(nb * bs,) + row).astype(np.float32))
     tables = jnp.asarray([[1, 2, 3, 4], [5, 6, 0, 0], [7, 8, 9, 0],
                           [10, 11, 0, 0]], jnp.int32)
     T = S + 3 * tile

@@ -2,7 +2,8 @@
 (reference: inference/v2/kernels/ragged_ops/blocked_flash — the decode
 hot path).  The kernel is the read of every one-token row at 128-aligned
 head dims (a decode step's rows, the single-token segment of a tiled
-``put`` program), whatever the pool's size; these run it through the
+``put`` program), whatever the pool's size, on the flat pool row
+[rows, Hkv*D] a float pool is stored in; these run it through the
 Pallas interpreter on CPU so the exact kernel code (dynamic walk over the
 held blocks, the DMA schedule that runs from one row into the next,
 pad-row handling, sliding window, several table entries a step) is covered
@@ -22,11 +23,14 @@ from deepspeed_tpu.inference.v2.model_implementations.ragged_llama import (
 BS = 128
 
 
-def _setup(seed, S=4, B=4, hkv=2, d=128, dtype=jnp.float32):
+def _setup(seed, S=4, B=4, hkv=2, d=128, dtype=jnp.float32, flat=True):
+    """``flat``: the pool in the flat row a float pool is stored in, else
+    [rows, Hkv, D] (what an int8 pool is quantized from)."""
     pool_rows = (S * B + 1) * BS
     ks = jax.random.split(jax.random.key(seed), 3)
-    k_pool = jax.random.normal(ks[0], (pool_rows, hkv, d), dtype)
-    v_pool = jax.random.normal(ks[1], (pool_rows, hkv, d), dtype)
+    row = (hkv * d,) if flat else (hkv, d)
+    k_pool = jax.random.normal(ks[0], (pool_rows,) + row, dtype)
+    v_pool = jax.random.normal(ks[1], (pool_rows,) + row, dtype)
     # distinct non-trash blocks per sequence, deliberately NON-contiguous
     rng = np.random.default_rng(seed)
     perm = rng.permutation(S * B) + 1
@@ -105,7 +109,7 @@ def test_paged_decode_and_verify_int8_match_dequantized_reference(hkv,
     from deepspeed_tpu.inference.v2.ragged.kv_cache import (dequantize_kv,
                                                             quantize_kv)
 
-    q, k_pool, v_pool, tables = _setup(3, hkv=hkv)
+    q, k_pool, v_pool, tables = _setup(3, hkv=hkv, flat=False)
     kq, ks = quantize_kv(k_pool)
     vq, vs = quantize_kv(v_pool)
     kd = dequantize_kv(kq, ks, jnp.float32)
@@ -179,8 +183,8 @@ def test_walk_matches_xla_read_on_a_tight_pool(hkv, g, d, case):
     nb = 3 * B
     rng = np.random.default_rng(7)
     ks = jax.random.split(jax.random.key(11), 3)
-    k_pool = jax.random.normal(ks[0], (nb * BS, hkv, d), jnp.bfloat16)
-    v_pool = jax.random.normal(ks[1], (nb * BS, hkv, d), jnp.bfloat16)
+    k_pool = jax.random.normal(ks[0], (nb * BS, hkv * d), jnp.bfloat16)
+    v_pool = jax.random.normal(ks[1], (nb * BS, hkv * d), jnp.bfloat16)
     # a tight pool cannot give every table entry a block of its own: the
     # entries a row can reach (its position's block and those before it)
     # get distinct blocks, the rest name block 0 as a fresh table does
@@ -230,8 +234,8 @@ def test_one_token_rows_lower_to_the_walk_on_a_tight_pool(monkeypatch, mode):
     nb = S * B // 2
     q_rows = S if mode == "decode_step" else S + tile
     args = (jax.ShapeDtypeStruct((q_rows, hkv * g, d), jnp.bfloat16),
-            jax.ShapeDtypeStruct((nb * BS, hkv, d), jnp.bfloat16),
-            jax.ShapeDtypeStruct((nb * BS, hkv, d), jnp.bfloat16),
+            jax.ShapeDtypeStruct((nb * BS, hkv * d), jnp.bfloat16),
+            jax.ShapeDtypeStruct((nb * BS, hkv * d), jnp.bfloat16),
             jax.ShapeDtypeStruct((S, B), jnp.int32),
             jax.ShapeDtypeStruct((q_rows,), jnp.int32),
             jax.ShapeDtypeStruct((q_rows,), jnp.int32))
@@ -252,3 +256,8 @@ def test_one_token_rows_lower_to_the_walk_on_a_tight_pool(monkeypatch, mode):
     assert set(kernels) <= {"_decode_kernel", "_prefill_kernel"}
     assert "attn/dense_read/jit(paged_decode_attention)" in text
     assert "dot_general" not in text and "attn/gather_read" not in text
+    # the pool reaches both kernels as it is stored: split into blocks,
+    # never transposed or re-laid (what the [rows, Hkv, D] form cost)
+    assert "stablehlo.transpose" not in text
+    assert not re.search(r"stablehlo\.reshape.*tensor<%dx%dx%dx" % (
+        nb, BS, hkv), text)

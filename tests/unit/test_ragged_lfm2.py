@@ -417,8 +417,9 @@ def test_every_expert_held_gives_the_uncut_sum():
 
 
 # ------------------------------------------------------------------ #
-# (e) the walks on a flat pool row of narrow heads (interpret mode)
-# against the XLA oracle
+# (e) the walks on the flat pool row (interpret mode) against the XLA
+# oracle: narrow heads packed to a lane tile, and the heads of whole tiles
+# every float pool is stored flat for since PR 41
 # ------------------------------------------------------------------ #
 def _flat_case(h, hkv, d, bs, seed=3):
     s_count, b = 6, 5
@@ -431,11 +432,22 @@ def _flat_case(h, hkv, d, bs, seed=3):
     return f(nb * bs, hkv * d), f(nb * bs, hkv * d), tables
 
 
-@pytest.mark.parametrize("h, hkv, d, bs", [
-    (32, 8, 64, 128), (8, 8, 64, 16), (16, 2, 64, 16), (8, 4, 32, 16)],
+# the whole-tile heads of the serving cells (Mistral, OLMoE, Trinity with
+# and without its window, Qwen3-Next): (h, hkv, d, block size, window)
+WHOLE_TILE = [(32, 8, 128, 16, None), (16, 16, 128, 16, None),
+              (48, 8, 128, 16, None), (48, 8, 128, 16, 40),
+              (16, 2, 256, 16, None)]
+WHOLE_TILE_IDS = ["mistral_32q_8kv_d128", "olmoe_16q_16kv_d128",
+                  "trinity_48q_8kv_d128", "trinity_window",
+                  "qwen3next_16q_2kv_d256"]
+
+
+@pytest.mark.parametrize("h, hkv, d, bs, window", [
+    (32, 8, 64, 128, None), (8, 8, 64, 16, None), (16, 2, 64, 16, None),
+    (8, 4, 32, 16, None)] + WHOLE_TILE,
     ids=["32q_8kv_d64", "multi_head_d64", "multi_query_groups_d64",
-         "four_heads_a_tile_d32"])
-def test_decode_walk_on_a_flat_row_matches_the_oracle(h, hkv, d, bs):
+         "four_heads_a_tile_d32"] + WHOLE_TILE_IDS)
+def test_decode_walk_on_a_flat_row_matches_the_oracle(h, hkv, d, bs, window):
     kp, vp, tables = _flat_case(h, hkv, d, bs)
     b = tables.shape[1]
     # ragged positions: a first token, a block's last row, the next block's
@@ -445,18 +457,26 @@ def test_decode_walk_on_a_flat_row_matches_the_oracle(h, hkv, d, bs):
     q = jnp.asarray(np.random.default_rng(1).standard_normal(
         (6, h, d)), jnp.float32)
     got = blocked_flash.paged_decode_attention(
-        q, kp, vp, tables, slot, pos, block_size=bs, interpret=True)
+        q, kp, vp, tables, slot, pos, block_size=bs, window=window,
+        interpret=True)
     batch = {"block_tables": tables, "token_slot": slot, "token_pos": pos}
     want = ragged_llama._paged_attention(q, kp, vp, batch, bs,
-                                         use_kernel=False, decode_mode=True)
+                                         use_kernel=False, decode_mode=True,
+                                         window=window)
     live = np.asarray(pos) >= 0
     assert np.max(np.abs(np.asarray(got - want))[live]) <= 1e-5
     assert np.all(np.asarray(got)[~live] == 0)
+    if window is not None:              # the window bites on the long rows
+        full = ragged_llama._paged_attention(
+            q, kp, vp, batch, bs, use_kernel=False, decode_mode=True)
+        assert np.max(np.abs(np.asarray(want - full))[live]) > 1e-3
 
 
-@pytest.mark.parametrize("h, hkv, d, bs", [(32, 8, 64, 128), (8, 8, 64, 16)],
-                         ids=["32q_8kv_d64", "multi_head_d64"])
-def test_tiled_prefill_on_a_flat_row_matches_the_oracle(h, hkv, d, bs):
+@pytest.mark.parametrize("h, hkv, d, bs, window", [
+    (32, 8, 64, 128, None), (8, 8, 64, 16, None)] + WHOLE_TILE,
+    ids=["32q_8kv_d64", "multi_head_d64"] + WHOLE_TILE_IDS)
+def test_tiled_prefill_on_a_flat_row_matches_the_oracle(h, hkv, d, bs,
+                                                        window):
     kp, vp, tables = _flat_case(h, hkv, d, bs, seed=6)
     tile = bs
     t_rows = 3 * tile
@@ -468,22 +488,64 @@ def test_tiled_prefill_on_a_flat_row_matches_the_oracle(h, hkv, d, bs):
         (t_rows, h, d)), jnp.float32)
     got = blocked_flash.paged_prefill_attention(
         q, kp, vp, tables, jnp.asarray(slot), jnp.asarray(pos),
-        block_size=bs, tile_q=tile, interpret=True)
+        block_size=bs, tile_q=tile, window=window, interpret=True)
     want = ragged_llama._paged_attention(
         q, kp, vp, {"block_tables": tables, "token_slot": jnp.asarray(slot),
-                    "token_pos": jnp.asarray(pos)}, bs, use_kernel=False)
+                    "token_pos": jnp.asarray(pos)}, bs, use_kernel=False,
+        window=window)
     assert np.max(np.abs(np.asarray(got - want))[pos >= 0]) <= 1e-5
 
 
 def test_walk_rule_is_what_the_kernels_take():
     usable = blocked_flash.decode_walk_usable
     z = lambda *s, dt=jnp.bfloat16: jnp.zeros(s, dt)
-    assert usable(128, z(64, 8, 128)) and usable(256, z(64, 2, 256))
+    # the flat row of whole tiles: heads of whole tiles, heads that divide one
+    assert usable(128, z(64, 1024)) and usable(256, z(64, 512))
     assert usable(64, z(64, 512)) and usable(32, z(64, 128))
-    assert not usable(64, z(64, 8, 64))       # narrow heads, per-head pool
+    # a float pool kept per head (its row is no whole tiles) is not walked,
+    # and the kernel refuses one handed to it in that form
+    assert not usable(64, z(64, 1, 64)) and not usable(128, z(64, 8, 128))
     assert not usable(64, z(64, 192))         # a row that is no whole tiles
     assert not usable(48, z(64, 384))         # a head that divides no tile
+    # int8 keeps [rows, Hkv, D] beside its scales, walked at whole-tile heads
+    assert usable(128, z(64, 8, 128, dt=jnp.int8))
+    assert not usable(64, z(64, 8, 64, dt=jnp.int8))
     assert not usable(64, z(64, 512, dt=jnp.int8))
+    with pytest.raises(ValueError, match="flat row"):
+        blocked_flash.paged_decode_attention(
+            z(2, 8, 128), z(64, 2, 128), z(64, 2, 128),
+            jnp.zeros((2, 4), jnp.int32), jnp.arange(2), jnp.arange(2),
+            block_size=16, interpret=True)
+
+
+@pytest.mark.parametrize("dtype, hkv, d, row", [
+    ("bf16", 8, 128, (1024,)), ("bf16", 16, 128, (2048,)),
+    ("bf16", 2, 256, (512,)), ("bf16", 8, 64, (512,)),
+    ("float32", 2, 64, (128,)), ("bf16", 1, 64, (1, 64)),
+    ("bf16", 3, 96, (3, 96)), ("bf16", 32, 96, (32, 96)),
+    ("bf16", 32, 80, (32, 80)), ("int8", 8, 128, (8, 128))],
+    ids=["mistral", "olmoe", "qwen3next", "lfm2_widths", "two_heads_d64",
+         "one_head_d64", "no_whole_tiles", "whole_tiles_of_d96_heads",
+         "whole_tiles_of_d80_heads", "int8"])
+def test_one_stored_layout_decided_from_dtype_and_shapes(dtype, hkv, d, row):
+    """A float pool whose row is whole 128-lane tiles, at heads the decode
+    walk reads in that row, is stored flat for every model; the rule looks
+    at the dtype, ``Hkv`` and ``D`` alone, and the walk asks the same rule:
+    no pool is stored flat for a read that copies it back to heads."""
+    from deepspeed_tpu.inference.v2.kernels import decode_walk_usable
+    from deepspeed_tpu.inference.v2.ragged.kv_cache import (BlockedKVCache,
+                                                            flat_row)
+
+    kv = BlockedKVCache(2, 3, 16, hkv, d, dtype)
+    assert flat_row(dtype, hkv, d) == (len(row) == 1)
+    if not kv.quantized:
+        assert decode_walk_usable(d, kv.cache["layer_0"]["k"]) \
+            == (len(row) == 1)
+    for leaves in kv.cache.values():
+        assert leaves["k"].shape == leaves["v"].shape == (3 * 16,) + row
+    itemsize = jnp.dtype(kv.dtype).itemsize
+    assert kv.layer_token_bytes == 2 * hkv * (d * itemsize
+                                              + 4 * kv.quantized)
 
 
 def test_one_token_rows_of_the_engine_take_the_walk(monkeypatch):
@@ -519,8 +581,10 @@ def test_bytes_a_token_and_a_sequence_hold():
     assert sm.state_pool.layers == (0, 1, 3, 4, 5)
     assert sm.state_pool.per_sequence_bytes == 5 * 2 * 64 * 2
     cache = sm.kv_cache.cache
+    # 2 heads of 16 are no whole lane tile: the cache's rule (``flat_row``)
+    # keeps heads apart; the published 8 x 64 is stored flat (the next test)
     assert set(cache["layer_2"]) == {"k", "v"} and \
-        cache["layer_2"]["k"].shape == (160 * BLOCK, 2 * 16)
+        cache["layer_2"]["k"].shape == (160 * BLOCK, 2, 16)
     assert set(cache["layer_0"]) == {"conv"} and \
         cache["layer_0"]["conv"].shape == (MAX_SEQS + 1, 2, 64)
 
@@ -541,8 +605,9 @@ def test_bytes_at_the_published_widths():
     pool = StateSlotPool(128, spec["layers"], spec["leaves"])
     assert pool.per_sequence_bytes == 8 * 8192 == \
         family.shapes(hf)["state_bytes_per_seq"]
-    kv = BlockedKVCache(10, 1, 128, 8, 64, kv_row=model.kv_row,
-                        kv_layers=[2, 6])
+    assert not hasattr(model, "kv_row")     # the cache's own rule
+    kv = BlockedKVCache(10, 1, 128, 8, 64, kv_layers=[2, 6])
+    assert kv.cache["layer_2"]["k"].shape == (128, 512)
     assert kv.per_token_bytes == 2 * 2048 == \
         family.shapes(hf)["kv_bytes_per_token"]
 

@@ -225,9 +225,10 @@ def test_mixed_tick_tree(traced):
                             "engine/ragged_step", "engine/upload", "fetch",
                             "pack", "prefill", "sample"]
     assert all(len(v) == 1 for v in kids.values())
-    # one decoding token and an 11-token prompt, padded to the 16 bucket
-    assert kids["engine/build_batch"][0]["attrs"] == {"tokens": 1 + 11,
-                                                      "bucket": 16}
+    # one decoding token and an 11-token prompt, padded to the 16 bucket;
+    # the decoding row holds two table blocks of 8 up to its position
+    assert kids["engine/build_batch"][0]["attrs"] == {
+        "tokens": 1 + 11, "bucket": 16, "row_blocks": 2}
     # ... the launch record on the dispatch, the launch on the wait (the
     # decode step's, the launch before), on ``prefill`` that the batch was
     # prepared under the program before it, and on ``sample`` the rows
@@ -380,7 +381,7 @@ def test_tick_closing_counters(traced):
     # after it the mixed step's two and its own decode step's two
     assert [t["attrs"]["emitted"] for t, _ in ticks[:4]] == [1, 1, 1, 2 + 2]
     assert ticks[0][1]["engine/build_batch"][0]["attrs"] == \
-        {"tokens": 13, "bucket": 16}
+        {"tokens": 13, "bucket": 16, "row_blocks": 0}   # no one-token row
     assert sum(t["attrs"]["emitted"] for t, _ in ticks) == 8 + 3
 
 
@@ -416,9 +417,14 @@ def test_tiled_tick_is_one_forward_and_bucket_is_its_rows(params):
         assert len(kids["engine/ragged_step"]) == 1
         assert len(kids["engine/build_batch"]) == 1
     builds = [kids["engine/build_batch"][0]["attrs"] for _, kids in forwards]
-    assert builds[0] == {"tokens": 6, "bucket": 4 + 16}
-    assert builds[1] == {"tokens": 1 + 33 + 16, "bucket": 4 + 64}
-    assert builds[2] == {"tokens": 1 + 1 + 15, "bucket": 4 + 16}
+    # ``row_blocks``: the table blocks of 8 the one-token rows hold (none in
+    # the first forward; then the decoding prompt of 6 + what it generated,
+    # and beside it the 33-token prompt's last token at position 32)
+    assert builds[0] == {"tokens": 6, "bucket": 4 + 16, "row_blocks": 0}
+    assert builds[1] == {"tokens": 1 + 33 + 16, "bucket": 4 + 64,
+                         "row_blocks": 1}
+    assert builds[2] == {"tokens": 1 + 1 + 15, "bucket": 4 + 16,
+                         "row_blocks": 1 + 33 // 8 + 1}
     assert {(b["bucket"], 16) for b in builds} == \
         {k for k in eng.step_keys if not isinstance(k[0], str)}
     # real tokens never pass the budget, rows never the largest program

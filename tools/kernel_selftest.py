@@ -21,14 +21,17 @@ import sys
 
 
 #: pool blocks, KV heads, group size, head size of the serving cells'
-#: attention pools (Mistral-7B, OLMoE-1B-7B, Qwen3-Next, LFM2; block size
-#: 128).  A head size under a lane tile (LFM2's 64) is served from a FLAT
-#: pool row [rows, Hkv*D], the walk in its packed-heads mode; its pool is a
-#: quarter of the cell's 3,072 blocks, which the dense oracle can hold
+#: attention pools (Mistral-7B, OLMoE-1B-7B, Qwen3-Next, LFM2, Trinity's
+#: global pool; block size 128).  Every one is read in the form
+#: ``BlockedKVCache`` stores it (``_stored_row``): the flat row [rows,
+#: Hkv*D], a KV head one or two lane tiles of it, or half of one at LFM2's
+#: 64.  LFM2's pool is a quarter of the cell's 3,072 blocks, which the
+#: dense oracle can hold
 DECODE_READ_CELLS = {"mistral7b": (160, 8, 4, 128),
                      "olmoe": (192, 16, 1, 128),
                      "qwen3next": (512, 2, 8, 256),
-                     "lfm2_d64": (768, 8, 4, 64)}
+                     "lfm2_d64": (768, 8, 4, 64),
+                     "trinity": (2400, 8, 6, 128)}
 
 
 #: tokens a tick, top-k, the router's experts, the experts this share
@@ -71,14 +74,26 @@ def _timed(run, layers: int, repeats: int, *args):
     return (time.perf_counter() - t0) / repeats / layers * 1e6, out
 
 
+def _stored_row(hkv: int, d: int) -> tuple:
+    """The row of a bf16 ``k`` / ``v`` pool of these heads as the engine's
+    cache stores it: asked of ``BlockedKVCache`` itself."""
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.inference.v2.ragged.kv_cache import BlockedKVCache
+
+    return BlockedKVCache(1, 1, 8, hkv, d, jnp.bfloat16).cache[
+        "layer_0"]["k"].shape[1:]
+
+
 def decode_read_case(cell: str, tol: float, layers: int = 16,
-                     repeats: int = 10) -> dict:
+                     repeats: int = 10, shares=(0.15, 0.5, 0.75)) -> dict:
     """The decode walk (``paged_decode_attention``, compiled) against the
-    XLA dense read (``_dense_pool_read``) on one cell's pool: 32 rows that
-    hold 15%, 50% and 75% of its blocks between them (about three blocks a
-    row at 15%, the rest pads).  ``max_err`` over the three; ``us`` = for
-    each share ``[blocks held, walk, dense read, least]``, microseconds a
-    call: ``layers`` calls a program, ``repeats`` programs dispatched back
+    XLA dense read (``_dense_pool_read``) on one cell's pool, in the form
+    the engine stores it: 32 rows that hold 15%, 50% and 75% (``shares``)
+    of its blocks between them (about three blocks a row at 15%, the rest
+    pads).  ``max_err`` over the three; ``us`` = for each share ``[blocks
+    held, walk, dense read, least]``, microseconds a call: ``layers`` calls
+    a program, ``repeats`` programs dispatched back
     to back, host clock around them; least = the held blocks' keys and
     values at 819 GB/s."""
     import jax
@@ -89,9 +104,12 @@ def decode_read_case(cell: str, tol: float, layers: int = 16,
     from deepspeed_tpu.inference.v2.model_implementations.ragged_llama \
         import _dense_pool_read
 
-    bs, rows_n, width = 128, 32, 36
+    bs, rows_n = 128, 32
     nb, hkv, g, d = DECODE_READ_CELLS[cell]
-    row = (hkv, d) if d % 128 == 0 else (hkv * d,)     # flat: narrow heads
+    # a table as wide as the serving cells' (36 entries), or what the rows
+    # need to hold three quarters of a larger pool
+    width = max(36, -(-3 * nb // (4 * rows_n)) + 1)
+    row = _stored_row(hkv, d)
     ks = jax.random.split(jax.random.key(30), 3)
     k_pool = jax.random.normal(ks[0], (nb * bs,) + row, jnp.bfloat16)
     v_pool = jax.random.normal(ks[1], (nb * bs,) + row, jnp.bfloat16)
@@ -112,7 +130,7 @@ def decode_read_case(cell: str, tol: float, layers: int = 16,
     walks, denses = _stacked(walk, layers), _stacked(dense, layers)
     rng = np.random.default_rng(30)
     err, us = 0.0, {}
-    for share in (0.15, 0.5, 0.75):
+    for share in shares:
         held = int(round(share * (nb - 1)))
         live = min(rows_n, max(1, held // 3))
         per = np.full(live, held // live)
@@ -132,6 +150,61 @@ def decode_read_case(cell: str, tol: float, layers: int = 16,
         us[str(share)] = [held, round(t_walk, 1), round(t_dense, 1),
                           round(least, 1)]
     return {"max_err": round(err, 6), "ok": bool(err < tol), "us": us}
+
+
+def verify_read_case(tol: float, layers: int = 16, repeats: int = 10,
+                     k_tokens: int = 4) -> dict:
+    """The speculative verify read (``paged_verify_attention``, compiled) on
+    a Mistral-shaped pool in the form the engine stores it, 32 slots of
+    ``k_tokens`` rows that hold half the pool between them, against the XLA
+    reads.  ``us`` = ``[blocks held, verify, least]``, microseconds a
+    call, clocked as :func:`decode_read_case` does."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.inference.v2.kernels import paged_verify_attention
+    from deepspeed_tpu.inference.v2.model_implementations.ragged_llama \
+        import _paged_attention
+
+    bs, slots, width = 128, 32, 36
+    nb, hkv, g, d = DECODE_READ_CELLS["mistral7b"]
+    row = _stored_row(hkv, d)
+    ks = jax.random.split(jax.random.key(41), 3)
+    k_pool = jax.random.normal(ks[0], (nb * bs,) + row, jnp.bfloat16)
+    v_pool = jax.random.normal(ks[1], (nb * bs,) + row, jnp.bfloat16)
+    q = jax.random.normal(ks[2], (slots * k_tokens, hkv * g, d), jnp.bfloat16)
+    rng = np.random.default_rng(41)
+    held = (nb - 1) // 2
+    per = np.full(slots, held // slots)
+    per[:held - per.sum()] += 1
+    free = iter(rng.permutation(nb - 1) + 1)
+    tables = np.zeros((slots, width), np.int32)
+    for r, n in enumerate(per):
+        tables[r, :n] = [next(free) for _ in range(n)]
+    # the K rows of a slot end inside the last block it holds
+    pos0 = (per - 1) * bs + rng.integers(0, bs - k_tokens, slots)
+    slot = jnp.repeat(jnp.arange(slots, dtype=jnp.int32), k_tokens)
+    pos = jnp.asarray((pos0[:, None] + np.arange(k_tokens)).reshape(-1),
+                      jnp.int32)
+    tables = jnp.asarray(tables)
+
+    def verify(q, k_pool, v_pool):
+        return paged_verify_attention(q, k_pool, v_pool, tables, slot, pos,
+                                      block_size=bs, k_tokens=k_tokens,
+                                      interpret=False)
+
+    t_verify, _sum = _timed(_stacked(verify, layers), layers, repeats,
+                            q, k_pool, v_pool)
+    batch = {"block_tables": tables, "token_slot": slot, "token_pos": pos}
+    want = jax.jit(lambda q, k, v: _paged_attention(
+        q, k, v, batch, bs, use_kernel=False))(q, k_pool, v_pool)
+    err = float(jnp.max(jnp.abs(
+        jax.jit(verify)(q, k_pool, v_pool).astype(jnp.float32)
+        - want.astype(jnp.float32))))
+    least = held * bs * 2 * hkv * d * 2 / 819e9 * 1e6
+    return {"max_err": round(err, 6), "ok": bool(err < tol), "row": list(row),
+            "us": [int(held), round(t_verify, 1), round(least, 1)]}
 
 
 def gmm_share_case(tol: float, layers: int = 8, repeats: int = 10) -> dict:
@@ -493,9 +566,11 @@ def run_selftest(tol: float = 3e-2) -> dict:
                         block_size=bs, interpret=False), want))
 
     # manual-DMA decode walk over the blocks each row holds (the read of
-    # every one-token row at 128-aligned head dims — its pool-block DMAs
-    # need D % 128 == 0)
+    # every one-token row at 128-aligned head dims), on the flat row
+    # [rows, Hkv*D] a float pool is stored in
     from deepspeed_tpu.inference.v2.kernels import paged_decode_attention
+    from deepspeed_tpu.inference.v2.kernels.blocked_flash import \
+        _flat as flat
 
     ks2 = jax.random.split(jax.random.fold_in(key, 8), 3)
     k_pool2 = jax.random.normal(ks2[0], (pool_rows, 2, 128), jnp.bfloat16)
@@ -505,8 +580,9 @@ def run_selftest(tol: float = 3e-2) -> dict:
                              use_kernel=False)
     guarded("paged_decode_dma", lambda: record(
         "paged_decode_dma",
-        paged_decode_attention(q2, k_pool2, v_pool2, tables, token_slot,
-                               token_pos, block_size=bs, interpret=False),
+        paged_decode_attention(q2, flat(k_pool2), flat(v_pool2), tables,
+                               token_slot, token_pos, block_size=bs,
+                               interpret=False),
         want2))
 
     # speculative multi-token verify: K=4 query rows per slot sharing
@@ -526,8 +602,8 @@ def run_selftest(tol: float = 3e-2) -> dict:
                              use_kernel=False)
     guarded("paged_verify_multiquery", lambda: record(
         "paged_verify_multiquery",
-        paged_verify_attention(qv, k_pool2, v_pool2, tables, vslot, vpos,
-                               block_size=bs, k_tokens=Kv,
+        paged_verify_attention(qv, flat(k_pool2), flat(v_pool2), tables,
+                               vslot, vpos, block_size=bs, k_tokens=Kv,
                                interpret=False), wantv))
 
     # int8 block-quantized decode + verify (kv_cache.dtype="int8"): the
@@ -625,6 +701,11 @@ def run_selftest(tol: float = 3e-2) -> dict:
         guarded("paged_decode_walk_" + cell,
                 lambda c=cell: results.update(
                     {"paged_decode_walk_" + c: decode_read_case(c, tol)}))
+
+    # the verify read (K = 4 rows a slot on one walk) on the Mistral pool
+    # as stored, half of it held, timed
+    guarded("paged_verify_read_mistral7b", lambda: results.update(
+        {"paged_verify_read_mistral7b": verify_read_case(tol)}))
 
     # the latent decode walk (absorbed form, one stream) against its XLA
     # composition at the Moonlight cell's pool, timed at the same shares,
