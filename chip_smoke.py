@@ -513,8 +513,8 @@ def _seeded_bf16_params(cfg, mesh=None, model_cls=None):
     import jax.numpy as jnp
     from jax.sharding import NamedSharding
 
-    from deepspeed_tpu.inference.v2.model_implementations.ragged_llama \
-        import ragged_param_specs
+    from deepspeed_tpu.inference.v2.model_implementations import (
+        ragged_param_specs)
     from deepspeed_tpu.models.llama import LlamaForCausalLM
 
     shapes = jax.eval_shape(
@@ -700,8 +700,9 @@ def moe_phase(sizes: SmokeSizes, devices, require_chip: bool,
 
     from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
                                             RaggedInferenceEngineConfig)
-    from deepspeed_tpu.inference.v2.model_implementations.ragged_mixtral \
-        import RaggedMixtral, moe_router
+    from deepspeed_tpu.inference.v2.model_implementations import (
+        RaggedMixtral)
+    from deepspeed_tpu.inference.v2.modules.moe import moe_router
     from deepspeed_tpu.models.mixtral import MixtralForCausalLM
     from deepspeed_tpu.ops.grouped_gemm import (_pick_tiles, gmm,
                                                 gmm_reference)

@@ -35,6 +35,8 @@ KERNEL_MODULES = (
     "deepspeed_tpu.ops.evoformer_attn",
     "deepspeed_tpu.inference.v2.kernels.blocked_flash",
     "deepspeed_tpu.inference.v2.kernels.latent_flash",
+    # (no ``pallas_call`` site: the cases of the route over blocked_flash)
+    "deepspeed_tpu.inference.v2.modules.attention",
 )
 
 #: default per-call VMEM budget estimate ceiling — v5e VMEM is 16 MiB;

@@ -29,15 +29,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from deepspeed_tpu.inference.v2.config_v2 import RaggedInferenceEngineConfig
-from deepspeed_tpu.inference.v2.model_implementations.ragged_llama import (
-    RaggedLlama,
+from deepspeed_tpu.inference.v2.modules.attention import (
     kv_spec,
     shard_ragged_params,
 )
-from deepspeed_tpu.inference.v2.ragged import (DSStateManager,
-                                               KVGroupsError,
-                                               RaggedBatchWrapper,
-                                               RecurrentStateError)
+from deepspeed_tpu.inference.v2.ragged import (CacheLayoutError,
+                                               DSStateManager,
+                                               RaggedBatchWrapper)
 from deepspeed_tpu.observability.tracer import SpanHandle, open_span
 from deepspeed_tpu.utils.compile_cache import key_cache_on_names
 from deepspeed_tpu.utils.logging import log_dist
@@ -170,7 +168,7 @@ def _named(fn, name: str):
 class InferenceEngineV2:
     """reference engine_v2.py:30."""
 
-    def __init__(self, model: RaggedLlama, params: Any,
+    def __init__(self, model: Any, params: Any,
                  config: Optional[RaggedInferenceEngineConfig] = None):
         self.config = config or RaggedInferenceEngineConfig()
         # the step programs' scopes are read by name from a profile
@@ -193,30 +191,25 @@ class InferenceEngineV2:
                 f"positions past it would silently alias the last row")
         self.model = model
         self.params = params
-        # a model with per-sequence recurrent state (linear-attention
-        # layers) says so: the state manager then keeps a slot pool beside
-        # the KV pool, and the paths that skip or rewind positions refuse
+        # a model states what its cache keeps beside plain k / v pools:
+        # recurrent state in slots (``state_spec``), a pool row of its own
+        # (``kv_row``), two groups of KV layers behind two block tables
+        # (``kv_groups``).  The state manager builds that layout and knows
+        # what it cannot serve (``require``); each extension's operands are
+        # handed to the step programs below.
         state_spec = getattr(model, "state_spec", None)
-        self._stateful = state_spec is not None
-        if self._stateful and getattr(kv_cfg, "enable_prefix_cache", False):
-            raise RecurrentStateError(
-                f"kv_cache.enable_prefix_cache (attach_prefix, its "
-                f"copy-on-write fork, the host tier) skips the prefill of "
-                f"cached positions: {type(model).__name__} keeps recurrent "
-                f"state a skipped position would never reach, and state "
-                f"snapshots at block boundaries are not implemented")
-        # a model whose KV layers are in two groups (window and global
-        # layers) says so: the state manager then keeps two pools behind
-        # two block tables a sequence, every step program is handed both,
-        # and the paths that assume one table refuse
         kv_groups = getattr(model, "kv_groups", None)
+        self._stateful = state_spec is not None
         self._grouped = kv_groups is not None
-        self.state_manager = DSStateManager(
-            sm_cfg, kv_cfg, num_layers=model.num_layers,
-            num_kv_heads=model.num_kv_heads, head_dim=model.head_dim,
-            dtype=getattr(model.config, "dtype", None),
-            state_spec=state_spec, kv_row=getattr(model, "kv_row", None),
-            **({"kv_groups": kv_groups} if self._grouped else {}))
+        try:
+            self.state_manager = DSStateManager(
+                sm_cfg, kv_cfg, num_layers=model.num_layers,
+                num_kv_heads=model.num_kv_heads, head_dim=model.head_dim,
+                dtype=getattr(model.config, "dtype", None),
+                state_spec=state_spec, kv_row=getattr(model, "kv_row", None),
+                **({"kv_groups": kv_groups} if self._grouped else {}))
+        except CacheLayoutError as e:   # of which model, for the message
+            raise CacheLayoutError(f"{type(model).__name__}: {e}") from None
         if self.state_manager.kv_cache.quantized:
             if not getattr(model, "supports_quantized_kv", False):
                 raise ValueError(
@@ -376,8 +369,6 @@ class InferenceEngineV2:
         calls this at admission so SplitFuse chunking starts past the
         cached span."""
         seq = self.state_manager.get_or_create_sequence(uid)
-        # (off for a model with kv_groups: the state manager refuses the
-        # prefix cache where it is built, so this is a miss)
         return self.state_manager.attach_prefix(
             seq, [int(t) for t in tokens])
 
@@ -547,7 +538,7 @@ class InferenceEngineV2:
         if tile:
             self._batch.set_alignment(tile)
         elif self._stateful:
-            raise RecurrentStateError(
+            raise CacheLayoutError(
                 f"a model with recurrent state runs its prompt chunks "
                 f"through whole tiles: max_ragged_batch_size "
                 f"{self._batch.token_budget} is no multiple of "
@@ -863,18 +854,10 @@ class InferenceEngineV2:
             state = self._upload_decode_state(seqs, key)
         return seqs, state
 
-    def _refuse_stateful(self, path: str, why: str) -> None:
-        if self._stateful:
-            raise RecurrentStateError(
-                f"{path}: {type(self.model).__name__} keeps per-sequence "
-                f"recurrent state; {why}")
-
-    def _refuse_grouped(self, path: str, why: str) -> None:
-        if self._grouped:
-            raise KVGroupsError(
-                f"{path}: {type(self.model).__name__} keeps window and "
-                f"global KV layers behind two block tables a sequence "
-                f"(kv_groups); {why}")
+    def _require(self, feature: str, path: str) -> None:
+        """``state_manager.require`` with the model's name in the path."""
+        self.state_manager.require(
+            feature, f"{path} of {type(self.model).__name__}")
 
     def _window_counters(self, single_rows, chunks=()) -> Dict[str, int]:
         """The window group's counters on a dispatch's span: its pool's
@@ -1017,18 +1000,7 @@ class InferenceEngineV2:
         caller fetches K ints per sequence instead of K vocab rows
         (the same asymmetry :meth:`decode_step`'s greedy mode exploits).
         """
-        self._refuse_stateful(
-            "verify_step", "rejected lookahead tokens would have advanced "
-            "the state and cannot be rolled back")
-        self._refuse_grouped(
-            "verify_step", "the K-rows-a-sequence verify read and "
-            "commit_verified's block trim know one table")
-        if self.state_manager.kv_cache.kv_row:
-            raise NotImplementedError(
-                f"verify_step: {type(self.model).__name__} keeps a latent "
-                f"row {self.state_manager.kv_cache.kv_row}; the K-rows-a-"
-                f"sequence verify read exists for per-head keys and values "
-                f"only")
+        self._require("verify", "verify_step")
         sm = self.state_manager
         S, B = self._batch.max_seqs, self._max_blocks
         n = len(uids)
@@ -1171,12 +1143,7 @@ class InferenceEngineV2:
         Internally runs scan chunks drawn from :data:`DECODE_CHUNKS` so the
         set of compiled programs is bounded regardless of ``steps``.
         """
-        self._refuse_stateful(
-            "decode_loop", "the scanned program does not carry state slots; "
-            "decode_step does")
-        self._refuse_grouped(
-            "decode_loop", "the scanned program carries one table and "
-            "releases nothing between its steps; decode_step does")
+        self._require("decode_loop", "decode_loop")
         if len(tokens) != len(uids):
             raise ValueError(
                 f"decode_loop: {len(uids)} uids but {len(tokens)} tokens")
@@ -1377,14 +1344,7 @@ class InferenceEngineV2:
         another engine over the same model can :meth:`resume` WITHOUT the
         recompute re-prefill — the disaggregated prefill→decode handoff."""
         if include_kv:
-            self._refuse_stateful(
-                "flush_to_host(include_kv=True)", "the handoff payload "
-                "carries KV rows only; without it the sequence is "
-                "recomputed from a zeroed slot")
-            self._refuse_grouped(
-                "flush_to_host(include_kv=True)", "the handoff payload is "
-                "the rows of one table; without it the sequence is "
-                "recomputed")
+            self._require("kv_handoff", "flush_to_host(include_kv=True)")
         out: Dict[int, Dict[str, Any]] = {}
         for uid in uids:
             seq = self.state_manager.get_sequence(uid)
@@ -1425,12 +1385,7 @@ class InferenceEngineV2:
                 f"flushed, or the uid was reused")
         if kv_state is None or "kv" not in kv_state:
             return self.put([uid], [tokens], sync=sync)
-        self._refuse_stateful(
-            "resume(kv_state=...)", "a KV payload skips the positions the "
-            "state has to be recomputed over; resume without it")
-        self._refuse_grouped(
-            "resume(kv_state=...)", "a KV payload fills one table's "
-            "blocks; resume without it (recompute)")
+        self._require("kv_handoff", "resume(kv_state=...)")
         seen = int(kv_state["seen_tokens"])
         if not 0 < seen <= len(tokens):
             raise ValueError(
@@ -1528,11 +1483,11 @@ class InferenceEngineV2:
                 quantize_groups: int = 64):
         """Serve a real HuggingFace checkpoint directory (reference: the
         MII/engine_factory path that builds a FastGen engine from a HF
-        snapshot).  Llama/Mistral/Mixtral/OLMoE/Qwen3-Next, DeepSeek-V3
-        family (Moonlight), LFM2-MoE and AFMoE (Trinity) checkpoints
-        supported; with ``mesh`` (a non-trivial 'model' axis) weights land
-        PRE-SHARDED by the Megatron split rules via
-        :func:`shard_ragged_params`'s specs — no full host/device copy.
+        snapshot).  ``model_implementations.HF_MODELS`` names the
+        architectures served and which take a ``mesh`` with a non-trivial
+        'model' axis (the others refuse one): weights then land PRE-SHARDED
+        by the Megatron split rules (``modules/attention.py::
+        shard_ragged_params``'s specs) — no full host/device copy.
 
         ``quantize_bits=8``: weight-only quantized serving (reference
         ★cutlass_ops/mixed_gemm) — projection weights REST as int8
@@ -1542,79 +1497,30 @@ class InferenceEngineV2:
         grouped-dequant composition, which XLA streams efficiently at
         scale (measured 1.71x faster decode at 850M-class on v5e).
         """
-        import jax.numpy as jnp
-
         from deepspeed_tpu.checkpoint.hf_loader import (config_from_hf,
                                                         load_hf_checkpoint)
+        from deepspeed_tpu.inference.v2.model_implementations import (
+            HF_MODELS)
 
         cfg = config or RaggedInferenceEngineConfig()
         arch, mcfg = config_from_hf(model_path,
                                     dtype or jnp.bfloat16)
         block_size = cfg.kv_cache.block_size
-        if arch in ("llama", "mistral", "internlm"):
-            model = RaggedLlama(mcfg, block_size, mesh=mesh)
-        elif arch in ("opt", "falcon"):
-            from deepspeed_tpu.inference.v2.model_implementations import (
-                RaggedFalcon, RaggedOPT)
-
-            if mesh is not None and mesh.shape.get("model", 1) > 1:
-                raise ValueError(
-                    f"Ragged{arch.upper()} does not support tensor "
-                    f"parallelism yet — pass mesh=None")
-            cls_ = RaggedOPT if arch == "opt" else RaggedFalcon
-            model = cls_(mcfg, block_size)
-        elif arch in ("mixtral", "olmoe"):
-            from deepspeed_tpu.inference.v2.model_implementations. \
-                ragged_mixtral import RaggedMixtral
-
-            if mesh is not None and mesh.shape.get("model", 1) > 1:
-                raise ValueError(
-                    "RaggedMixtral does not support tensor parallelism "
-                    "yet — pass mesh=None (weights would silently land "
-                    "unsharded otherwise)")
-            model = RaggedMixtral(mcfg, block_size)
-        elif arch == "qwen3_next":
-            from deepspeed_tpu.inference.v2.model_implementations. \
-                ragged_qwen3_next import RaggedQwen3Next
-
-            if mesh is not None and mesh.shape.get("model", 1) > 1:
-                raise ValueError(
-                    "RaggedQwen3Next does not support tensor parallelism "
-                    "yet — pass mesh=None")
-            model = RaggedQwen3Next(mcfg, block_size)
-        elif arch == "lfm2_moe":
-            from deepspeed_tpu.inference.v2.model_implementations. \
-                ragged_lfm2 import RaggedLfm2
-
-            if mesh is not None and mesh.shape.get("model", 1) > 1:
-                raise ValueError(
-                    "RaggedLfm2 does not support tensor parallelism yet "
-                    "— pass mesh=None")
-            model = RaggedLfm2(mcfg, block_size)
-        elif arch == "afmoe":
-            from deepspeed_tpu.inference.v2.model_implementations. \
-                ragged_afmoe import RaggedAfmoe
-
-            if mesh is not None and mesh.shape.get("model", 1) > 1:
-                raise ValueError(
-                    "RaggedAfmoe does not support tensor parallelism yet "
-                    "— pass mesh=None")
-            model = RaggedAfmoe(mcfg, block_size)
-        elif arch == "deepseek_v3":
-            from deepspeed_tpu.inference.v2.model_implementations. \
-                ragged_deepseek_v3 import RaggedDeepseekV3
-
-            model = RaggedDeepseekV3(
-                mcfg, block_size,
-                mesh=mesh if mesh is not None
-                and mesh.shape.get("model", 1) > 1 else None)
-        else:
+        if arch not in HF_MODELS:
             raise ValueError(
                 f"FastGen has no ragged model for architecture {arch!r}")
+        model_cls, serves_model_axis = HF_MODELS[arch]
+        if mesh is not None and mesh.shape.get("model", 1) <= 1:
+            mesh = None
+        if mesh is not None and not serves_model_axis:
+            raise ValueError(
+                f"{model_cls.__name__} does not support tensor parallelism "
+                f"yet — pass mesh=None (weights would silently land "
+                f"unsharded otherwise)")
+        model = model_cls(mcfg, block_size,
+                          **({"mesh": mesh} if serves_model_axis else {}))
         params = load_hf_checkpoint(
-            model_path, dtype=dtype or jnp.bfloat16,
-            mesh=mesh if (mesh is not None
-                          and getattr(model, "tp", 1) > 1) else None)
+            model_path, dtype=dtype or jnp.bfloat16, mesh=mesh)
         if quantize_bits:
             if arch not in ("llama", "mistral", "internlm"):
                 raise ValueError(
@@ -1664,7 +1570,7 @@ class InferenceEngineV2:
         live = list(uids)
         nxt = self.put(uids, prompts, greedy=True)
         if eos_token_id is None and max_new_tokens > 1 \
-                and not self._stateful and not self._grouped:
+                and "decode_loop" not in self.state_manager.unserved:
             # no early-exit needed -> device-resident decode: one dispatch
             # per decode chunk instead of one per token (grouped by
             # max_seqs — decode_loop batches at most one slot per sequence)

@@ -20,7 +20,7 @@ chip its last two dimensions are tiled, and the flattened-lane view the
 tiled kernel wants is a second pool that XLA writes in front of every call.)
 
 Four kernels, by the shape of the rows they serve (the route is
-``ragged_llama._paged_attention``'s):
+``modules/attention.py::_paged_attention``'s):
 
 * ``_prefill_kernel`` — every ``put`` forward of an engine whose token
   budget is a whole number of tiles, mixed ticks included: the engine packs
@@ -1174,29 +1174,6 @@ def _dslint_paged_prefill_case():
 
 
 @pallas_kernel_case(
-    "paged_two_segment",
-    note="a mixed tick's batch at Mistral's head counts (32q/8kv, d=128) "
-         "on the flat pool row [rows, 1024] through "
-         "ragged_llama._paged_attention: 4 single-token rows "
-         "(slots in no order, two pads at position -1) take the decode "
-         "walk, every KV head in one pair of dots, the tile-aligned chunks "
-         "behind them (one with a sub-tile tail) the tiled prefill kernel")
-def _dslint_paged_two_segment_case():
-    two_segment_case()
-
-
-@pallas_kernel_case(
-    "paged_two_segment_d64",
-    note="the same mixed tick at 64-wide heads (32q/8kv), pool row "
-         "[rows, 512]: the single-token rows take the decode walk with "
-         "two KV heads to a 128-lane tile (the "
-         "queries zero-padded into their half), the chunks the tiled "
-         "kernel's per-head slices at 64-lane offsets")
-def _dslint_paged_two_segment_d64_case():
-    two_segment_case(d=64)
-
-
-@pallas_kernel_case(
     "paged_decode_dma_d64",
     note="decode walk over flat [bs, Hkv*D] blocks at D = 64 (8q/2kv): "
          "one lane tile a block, ragged positions")
@@ -1204,39 +1181,3 @@ def _dslint_paged_decode_dma_d64_case():
     bs, kp, vp, tables, slot, pos, q = _dslint_paged_setup(64)
     paged_decode_attention(q, _flat(kp), _flat(vp), tables, slot, pos,
                            block_size=bs, interpret=True)
-
-
-def two_segment_case(tight_pool: bool = False, d: int = 128):
-    """One two-segment batch through the kernel route (compiled on the
-    chip, interpreted off it) and through the XLA composition: ``(got,
-    want, mask of the real rows)``.  Shared with tools/kernel_selftest.py.
-    ``tight_pool`` sizes the pool at the table extent instead of over
-    twice it: the single-token rows take the decode walk either way.  The
-    pool row is flat, [rows, Hkv*D], as ``BlockedKVCache`` stores it."""
-    import numpy as np
-
-    from deepspeed_tpu.inference.v2.model_implementations.ragged_llama \
-        import _paged_attention
-
-    bs, S, B, tile, h, hkv = 128, 4, 4, 128, 32, 8
-    nb = S * B + 1 if tight_pool else 2 * S * B + 2
-    rng = np.random.default_rng(21)
-    pool = lambda: jnp.asarray(
-        rng.standard_normal((nb * bs, hkv * d)).astype(np.float32),
-        jnp.bfloat16)
-    kp, vp = pool(), pool()
-    tables = jnp.arange(1, S * B + 1, dtype=jnp.int32).reshape(S, B)
-    T = S + 3 * tile
-    slot = np.zeros((T,), np.int32)
-    pos = np.full((T,), -1, np.int32)
-    slot[0:2], pos[0:2] = (2, 0), (317, 200)       # two decodes, two pads
-    slot[S:S + 150], pos[S:S + 150] = 1, np.arange(100, 250)  # tile + tail
-    slot[S + 256:S + 384], pos[S + 256:S + 384] = 3, np.arange(0, 128)
-    q = jnp.asarray(rng.standard_normal((T, h, d)).astype(np.float32),
-                    jnp.bfloat16)
-    batch = {"block_tables": tables, "token_slot": jnp.asarray(slot),
-             "token_pos": jnp.asarray(pos)}
-    got = _paged_attention(q, kp, vp, batch, bs, use_kernel=True,
-                           prefill_tile=tile)
-    want = _paged_attention(q, kp, vp, batch, bs, use_kernel=False)
-    return got, want, pos >= 0

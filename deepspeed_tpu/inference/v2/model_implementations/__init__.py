@@ -1,7 +1,8 @@
 """Inference v2 model implementations (reference:
 inference/v2/model_implementations/ — llama_v2, opt, mistral, mixtral,
 falcon families; qwen3_next, deepseek_v3, lfm2_moe and afmoe have no
-reference counterpart)."""
+reference counterpart).  One file a family (config, parameter shapes,
+class) over the shared layers of ``inference/v2/modules/``."""
 
 from deepspeed_tpu.inference.v2.model_implementations.ragged_afmoe import (
     AfmoeConfig,
@@ -10,8 +11,6 @@ from deepspeed_tpu.inference.v2.model_implementations.ragged_afmoe import (
 
 from deepspeed_tpu.inference.v2.model_implementations.ragged_llama import (
     RaggedLlama,
-    ragged_param_specs,
-    shard_ragged_params,
 )
 from deepspeed_tpu.inference.v2.model_implementations.ragged_falcon import (
     RaggedFalcon,
@@ -35,12 +34,34 @@ from deepspeed_tpu.inference.v2.model_implementations.ragged_qwen3_next import (
     RaggedQwen3Next,
 )
 
+from deepspeed_tpu.inference.v2.modules.attention import (
+    ragged_param_specs,
+    shard_ragged_params,
+)
+
 # Mistral is the Llama architecture + sliding window: serve it with
 # RaggedLlama over a config whose ``sliding_window`` is set (reference
 # mistral/ container reuses the llama modules the same way)
 RaggedMistral = RaggedLlama
 
-__all__ = ["AfmoeConfig", "DeepseekV3Config", "RaggedAfmoe",
+#: what ``InferenceEngineV2.from_hf`` builds: architecture (``hf_loader.
+#: config_from_hf``'s name) -> (class, does its constructor take a ``mesh``
+#: with a 'model' axis; RaggedDeepseekV3 does, to refuse it in its own words)
+HF_MODELS = {
+    "llama": (RaggedLlama, True),
+    "mistral": (RaggedLlama, True),
+    "internlm": (RaggedLlama, True),
+    "opt": (RaggedOPT, False),
+    "falcon": (RaggedFalcon, False),
+    "mixtral": (RaggedMixtral, False),
+    "olmoe": (RaggedMixtral, False),
+    "qwen3_next": (RaggedQwen3Next, False),
+    "deepseek_v3": (RaggedDeepseekV3, True),
+    "lfm2_moe": (RaggedLfm2, False),
+    "afmoe": (RaggedAfmoe, False),
+}
+
+__all__ = ["AfmoeConfig", "DeepseekV3Config", "HF_MODELS", "RaggedAfmoe",
            "RaggedDeepseekV3", "Lfm2Config", "Qwen3NextConfig", "RaggedLfm2", "RaggedLlama", "RaggedMistral", "RaggedMixtral",
            "RaggedOPT", "RaggedFalcon", "RaggedQwen3Next",
            "ragged_param_specs", "shard_ragged_params"]

@@ -59,15 +59,13 @@ from typing import Any, Dict, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference.v2.model_implementations.ragged_llama import (
+from deepspeed_tpu.inference.v2.modules.attention import (
     _paged_attention,
     _rms_norm,
     _rope_insert,
     _rotary,
 )
-from deepspeed_tpu.inference.v2.model_implementations.ragged_mixtral import (
-    dropless_moe,
-)
+from deepspeed_tpu.inference.v2.modules.moe import dropless_moe
 from deepspeed_tpu.ops.quantized_matmul import qmm
 
 F32 = jnp.float32

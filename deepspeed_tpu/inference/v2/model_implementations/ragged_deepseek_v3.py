@@ -61,13 +61,8 @@ import jax.numpy as jnp
 from deepspeed_tpu.inference.v2.kernels.latent_flash import (
     latent_decode_attention, latent_expand, latent_kernels_usable,
     latent_prefill_attention, latent_row_width)
-from deepspeed_tpu.inference.v2.model_implementations.ragged_llama import (
-    _rms_norm,
-    _rotary,
-)
-from deepspeed_tpu.inference.v2.model_implementations.ragged_mixtral import (
-    dropless_moe,
-)
+from deepspeed_tpu.inference.v2.modules.attention import _rms_norm, _rotary
+from deepspeed_tpu.inference.v2.modules.moe import dropless_moe
 from deepspeed_tpu.models.llama import apply_rotary
 from deepspeed_tpu.ops.quantized_matmul import qmm
 from deepspeed_tpu.utils.platform import on_tpu

@@ -17,7 +17,7 @@ pool this model shares:
   (The published cache keeps K columns; the oldest is never read again.)
   The convolution over a ragged batch, its tail carried across chunk
   boundaries, through decode steps and past pad rows, is
-  ``ragged_qwen3_next._causal_conv`` with no activation.
+  ``modules/conv.py::_causal_conv`` with no activation.
 * **A flat pool row.**  Heads of 64 are half a lane tile: the attention
   layers' pools are ``[rows, Hkv*D]`` (512 lanes at the published widths,
   whole tiles), the form ``BlockedKVCache`` stores every float pool of
@@ -54,18 +54,14 @@ from typing import Any, Dict, Optional, Sequence
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference.v2.model_implementations.ragged_llama import (
+from deepspeed_tpu.inference.v2.modules.attention import (
     _rms_norm,
     _rotary,
     ragged_attention_block,
 )
-from deepspeed_tpu.inference.v2.model_implementations.ragged_mixtral import (
-    dropless_moe,
-)
-from deepspeed_tpu.inference.v2.model_implementations.ragged_qwen3_next import (
-    _causal_conv,
-)
-from deepspeed_tpu.inference.v2.ragged.state_pool import RecurrentStateError
+from deepspeed_tpu.inference.v2.modules.conv import _causal_conv
+from deepspeed_tpu.inference.v2.modules.moe import dropless_moe
+from deepspeed_tpu.inference.v2.ragged.kv_cache import CacheLayoutError
 from deepspeed_tpu.ops.quantized_matmul import qmm
 
 F32 = jnp.float32
@@ -213,7 +209,7 @@ class RaggedLfm2:
         cfg = self.config
         dt = cfg.dtype
         if not decode and not prefill_tile:
-            raise RecurrentStateError(
+            raise CacheLayoutError(
                 "RaggedLfm2 runs decode steps and two-segment (tiled) "
                 "batches; a batch packed back to back has no tile a "
                 "sequence's convolution tail could be carried along")

@@ -18,7 +18,7 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
-from deepspeed_tpu.inference.v2.model_implementations.ragged_llama import (
+from deepspeed_tpu.inference.v2.modules.attention import (
     _layer_norm,
     _paged_attention,
     insert_kv,

@@ -4,7 +4,7 @@ from deepspeed_tpu.inference.v2.ragged.blocked_allocator import BlockedAllocator
 from deepspeed_tpu.inference.v2.ragged.host_tier import (HostKVTier,
                                                          HostTierStats)
 from deepspeed_tpu.inference.v2.ragged.kv_cache import (BlockedKVCache,
-                                                        KVGroupsError,
+                                                        CacheLayoutError,
                                                         dequantize_kv,
                                                         quantize_kv)
 from deepspeed_tpu.inference.v2.ragged.prefix_cache import (PrefixCacheStats,
@@ -14,11 +14,10 @@ from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import RaggedBatchWrapper
 from deepspeed_tpu.inference.v2.ragged.sequence_descriptor import (
     DSSequenceDescriptor,
 )
-from deepspeed_tpu.inference.v2.ragged.state_pool import (RecurrentStateError,
-                                                          StateSlotPool)
+from deepspeed_tpu.inference.v2.ragged.state_pool import StateSlotPool
 
-__all__ = ["BlockedAllocator", "BlockedKVCache", "DSStateManager",
-           "HostKVTier", "HostTierStats", "KVGroupsError", "PrefixCacheStats",
-           "RadixPrefixCache", "RaggedBatchWrapper",
-           "RecurrentStateError", "StateSlotPool",
+__all__ = ["BlockedAllocator", "BlockedKVCache", "CacheLayoutError",
+           "DSStateManager", "HostKVTier", "HostTierStats",
+           "PrefixCacheStats", "RadixPrefixCache", "RaggedBatchWrapper",
+           "StateSlotPool",
            "DSSequenceDescriptor", "quantize_kv", "dequantize_kv"]

@@ -34,8 +34,7 @@ bit-exact.  Prefill/decode writes quantize on cache insert
 attention): instead of ``k``/``v`` per KV head a layer holds the named
 leaves ``[num_blocks * block_size, lanes]`` behind the same allocator and
 block tables.  Every block operation is a ``tree_map`` over pool rows and
-carries such a row unchanged; int8 mode (a scale per KV head) refuses it,
-as do ``verify_step`` and speculation (their reads know keys and values).
+carries such a row unchanged; what it cannot serve is :data:`LATENT_ROW`.
 No model states a ``k`` / ``v`` row: :func:`flat_row` is the one way to the
 flat pool.
 
@@ -43,10 +42,10 @@ flat pool.
 model's ``kv_groups``): the layers of the window group share a pool of their
 own length, ``window_blocks`` blocks, behind the state manager's second
 allocator and each sequence's second block table; every other KV layer keeps
-``num_blocks``.  ``num_blocks`` stays the global group's.  The block
-operations that move one block id across every layer (``copy_block``,
-``gather_blocks``, ``scatter_blocks``) are refused: an id names different
-rows in the two groups.
+``num_blocks``.  ``num_blocks`` stays the global group's.  What it cannot
+serve is :data:`WINDOW_GROUP`; behind those, the block operations that move
+one block id across every layer (``copy_block``, ``gather_blocks``,
+``scatter_blocks``) refuse: an id names different rows in the two groups.
 """
 
 from __future__ import annotations
@@ -79,6 +78,11 @@ def resolve_kv_dtype(dtype: Any):
     return dtype
 
 
+def is_int8(dtype: Any) -> bool:
+    """Is ``dtype`` (a config string or a jnp dtype) the quantized pool's?"""
+    return jnp.dtype(resolve_kv_dtype(dtype)) == jnp.dtype(jnp.int8)
+
+
 def quantize_kv(x: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Symmetric int8 quantize per (row, kv-head) group over the head
     vector: ``x [..., Hkv, D] -> (q int8 same shape, scale fp32 [..., Hkv])``
@@ -109,17 +113,51 @@ def flat_row(dtype: Any, num_kv_heads: int, head_dim: int) -> bool:
     a read that would then copy it back to heads (8 heads of 96: whole
     tiles a row, but a head starts inside a tile).  An int8 pool keeps its
     heads apart, beside the scale a head."""
-    return (jnp.dtype(resolve_kv_dtype(dtype)) != jnp.dtype(jnp.int8)
-            and (num_kv_heads * head_dim) % 128 == 0
+    return (not is_int8(dtype) and (num_kv_heads * head_dim) % 128 == 0
             and (head_dim % 128 == 0 or 128 % head_dim == 0))
 
 
-class KVGroupsError(NotImplementedError):
-    """A path that assumes ONE block table a sequence (prefix-cache attach
-    and register, its copy-on-write fork, the host tier, a KV handoff,
-    speculative verify, the scanned decode loop) was asked of a model whose
-    KV layers are in two groups (``kv_groups``: window and global layers,
-    each with its own pool and table)."""
+#: The features a cache layout may be unable to serve.  Each extension a
+#: model states (``state_spec``: ``state_pool.py``; ``kv_row``, ``kv_groups``:
+#: below) says which and why; ``DSStateManager.require`` is the one check.
+FEATURES = (
+    "prefix_cache",     # attach_prefix / register_prefix / the COW fork
+    "host_tier",        # cold blocks spooled to the host, restored on attach
+    "kv_handoff",       # flush_to_host(include_kv=True) / resume(kv_state=)
+    "verify",           # verify_step, the speculative scheduler
+    "decode_loop",      # the scanned greedy decode
+    "int8_kv",          # kv_cache.dtype=int8
+)
+
+
+class CacheLayoutError(NotImplementedError):
+    """A feature was asked of a cache layout that cannot serve it."""
+
+
+#: a model-stated row (``kv_row``): (what the model keeps, feature -> why not)
+LATENT_ROW = ("keeps a latent row a token in place of per-head keys and "
+              "values (kv_row)", {
+    "verify": "the K-rows-a-sequence verify read exists for per-head keys "
+              "and values only",
+    "int8_kv": "quantize_kv keeps one scale per KV head, and a latent row "
+               "has no head to scale by",
+})
+
+#: two kinds of KV layer (``kv_groups``): each path assumes ONE table a sequence
+WINDOW_GROUP = ("keeps window and global KV layers behind two block tables "
+                "a sequence (kv_groups)", {
+    "prefix_cache": "attach, register and the copy-on-write fork share and "
+                    "copy blocks by ONE id a position, and a window block is "
+                    "released as its first owner advances",
+    "host_tier": "a block is spooled and restored by ONE id, which names "
+                 "different rows in the two groups",
+    "kv_handoff": "the handoff payload is the rows of one table; without it "
+                  "the sequence is recomputed",
+    "verify": "the K-rows-a-sequence verify read and commit_verified's "
+              "block trim know one table",
+    "decode_loop": "the scanned program carries one table and releases "
+                   "nothing between its steps; decode_step does",
+})
 
 
 class BlockedKVCache:
@@ -144,15 +182,10 @@ class BlockedKVCache:
         dtype = resolve_kv_dtype(dtype)
         self.dtype = dtype
         #: int8 pools carry per-row/per-head fp32 scale records in-tree
-        self.quantized = jnp.dtype(dtype) == jnp.dtype(jnp.int8)
+        self.quantized = is_int8(dtype)
         #: leaf name -> lanes of the row a layer keeps per token, for a
         #: model that states one (see the module doc); None: k and v
         self.kv_row = dict(kv_row) if kv_row else None
-        if self.kv_row and self.quantized:
-            raise NotImplementedError(
-                f"kv_cache.dtype=int8 on a model-stated row {self.kv_row}: "
-                f"quantize_kv keeps one scale per KV head, and a latent "
-                f"row has no head to scale by")
         def layer(i):
             rows = block_size * (self.window_blocks
                                  if i in self.window_layers else num_blocks)
@@ -194,7 +227,7 @@ class BlockedKVCache:
         """The KV layers of ``cache``: block operations move pool rows and
         leave any state slots beside them alone."""
         if self.window_layers:
-            raise KVGroupsError(
+            raise CacheLayoutError(
                 "a block operation over every KV layer (copy_block, "
                 "gather_blocks, scatter_blocks) names ONE block id, and "
                 "this cache has two pools behind two block tables "

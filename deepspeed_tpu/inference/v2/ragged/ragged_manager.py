@@ -40,9 +40,12 @@ would need each slot's own worst case, the band AND a whole budget's chunk
 (:attr:`window_table_bound`: 41 blocks a sequence where the sum gives 1,095
 for 32 at W = 4,096, a budget of 1,024 and blocks of 128: 34.2 each).
 More sequences than that can be tracked, so admission still
-counts (:meth:`window_blocks_needed`).  The paths that assume one table a
-sequence (the prefix cache, the host tier, a KV handoff) refuse such a model
-(:class:`~deepspeed_tpu.inference.v2.ragged.kv_cache.KVGroupsError`).
+counts (:meth:`window_blocks_needed`).
+
+**What a layout cannot serve** is said beside each extension's definition
+(``state_pool.STATE_SLOTS``, ``kv_cache.LATENT_ROW`` / ``WINDOW_GROUP``),
+merged into :attr:`DSStateManager.unserved` and checked in ONE place,
+:meth:`DSStateManager.require`, by every path behind a feature.
 """
 
 from __future__ import annotations
@@ -55,13 +58,18 @@ from deepspeed_tpu.inference.v2.config_v2 import (DSStateManagerConfig,
                                                   KVCacheConfig)
 from deepspeed_tpu.inference.v2.ragged.blocked_allocator import BlockedAllocator
 from deepspeed_tpu.inference.v2.ragged.host_tier import HostKVTier
-from deepspeed_tpu.inference.v2.ragged.kv_cache import (BlockedKVCache,
-                                                        KVGroupsError)
+from deepspeed_tpu.inference.v2.ragged.kv_cache import (FEATURES,
+                                                        LATENT_ROW,
+                                                        WINDOW_GROUP,
+                                                        BlockedKVCache,
+                                                        CacheLayoutError,
+                                                        is_int8)
 from deepspeed_tpu.inference.v2.ragged.prefix_cache import RadixPrefixCache
 from deepspeed_tpu.inference.v2.ragged.sequence_descriptor import (
     DSSequenceDescriptor,
 )
-from deepspeed_tpu.inference.v2.ragged.state_pool import StateSlotPool
+from deepspeed_tpu.inference.v2.ragged.state_pool import (STATE_SLOTS,
+                                                          StateSlotPool)
 
 
 class DSStateManager:
@@ -81,6 +89,15 @@ class DSStateManager:
             per_seq = -(-config.max_context // self.block_size)
             num_blocks = config.max_ragged_sequence_count * per_seq + 1
         self.allocator = BlockedAllocator(num_blocks)
+        #: feature -> why this layout cannot serve it (the module doc)
+        self.unserved: Dict[str, str] = {}
+        for keeps, cannot in [table for stated, table in (
+                (state_spec is not None, STATE_SLOTS), (kv_row, LATENT_ROW),
+                (kv_groups is not None, WINDOW_GROUP)) if stated]:
+            for feature, why in cannot.items():
+                prior = self.unserved.get(feature)
+                self.unserved[feature] = (f"{prior} and " if prior else "") \
+                    + f"{keeps}; {why}"
         kwargs = {}
         # precedence: explicit kv_cache.dtype string > legacy cache_dtype
         # > the model's compute dtype
@@ -88,6 +105,13 @@ class DSStateManager:
             kwargs["dtype"] = kv_config.dtype
         elif dtype is not None or kv_config.cache_dtype is not None:
             kwargs["dtype"] = kv_config.cache_dtype or dtype
+        if "dtype" in kwargs and is_int8(kwargs["dtype"]):
+            self.require("int8_kv", "kv_cache.dtype=int8")
+        if getattr(kv_config, "host_tier", False):
+            self.require("host_tier", "kv_cache.host_tier (behind "
+                         "kv_cache.enable_prefix_cache)")
+        if getattr(kv_config, "enable_prefix_cache", False):
+            self.require("prefix_cache", "kv_cache.enable_prefix_cache")
         #: slots of per-sequence recurrent state, for a model whose
         #: ``state_spec`` names layers that keep such state instead of keys
         #: and values (``{"layers": [...], "leaves": {name: (shape,
@@ -108,14 +132,6 @@ class DSStateManager:
         self.win_allocator: Optional[BlockedAllocator] = None
         self.win_released = 0
         if kv_groups is not None:
-            if getattr(kv_config, "enable_prefix_cache", False):
-                raise KVGroupsError(
-                    "kv_cache.enable_prefix_cache (attach_prefix, "
-                    "register_prefix, the copy-on-write fork, the host "
-                    "tier) shares and copies blocks by ONE id a position: "
-                    "a model with kv_groups keeps two tables a sequence, "
-                    "and a window block another request could attach to "
-                    "is released as its first owner advances")
             win = kv_groups["window"]
             self.window = int(win["window"])
             self.win_allocator = BlockedAllocator(self.window_pool_blocks + 1)
@@ -149,6 +165,14 @@ class DSStateManager:
             self.host_tier = HostKVTier(max_bytes=tier_bytes)
             self.prefix_cache.spool_fn = self._spool_nodes
         self._seqs: Dict[int, DSSequenceDescriptor] = {}
+
+    def require(self, feature: str, path: str) -> None:
+        """Raise :class:`CacheLayoutError` (``path``, the extension, why)
+        when this layout cannot serve ``feature`` (``kv_cache.FEATURES``)."""
+        assert feature in FEATURES, feature
+        if feature in self.unserved:
+            raise CacheLayoutError(
+                f"{path}: the model {self.unserved[feature]}")
 
     # ------------------------------------------------------------------ #
     # Sequence tracking (reference get_or_create_sequence / flush)

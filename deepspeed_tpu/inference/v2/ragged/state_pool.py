@@ -23,11 +23,22 @@ import jax.numpy as jnp
 import numpy as np
 
 
-class RecurrentStateError(NotImplementedError):
-    """A path that skips or rewinds positions (prefix-cache attach and its
-    copy-on-write fork, the host tier, a KV handoff, speculative verify, the
-    scanned decode loop) was asked of a model with per-sequence recurrent
-    state, which cannot follow without a snapshot of that state."""
+#: what a cache with state slots cannot serve (``kv_cache.FEATURES``) and why:
+#: each skips or rewinds positions, which the state cannot follow
+STATE_SLOTS = ("keeps per-sequence recurrent state (state_spec)", {
+    "prefix_cache": "attach_prefix and its copy-on-write fork skip the "
+                    "prefill of cached positions, which the state would "
+                    "never reach (no snapshots at block boundaries)",
+    "host_tier": "a restored block skips the prefill of its positions as a "
+                 "prefix-cache hit does, with no state snapshot beside it",
+    "kv_handoff": "the payload carries KV rows only and skips the positions "
+                  "the state has to be recomputed over; without it the "
+                  "sequence is recomputed from a zeroed slot",
+    "verify": "rejected lookahead tokens would have advanced the state and "
+              "cannot be rolled back",
+    "decode_loop": "the scanned program does not carry state slots; "
+                   "decode_step does",
+})
 
 
 class StateSlotPool:

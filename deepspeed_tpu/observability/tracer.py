@@ -189,6 +189,11 @@ two, not by order or by a clock.
 (``request/*`` spans and instants carry each request's own ``trace_id``
 and are opened with :meth:`Tracer.start` / :meth:`Tracer.instant`.)
 
+Device scopes (``jax.named_scope``) are opened where the layer is written:
+``attn/*`` in ``inference/v2/modules/attention.py``, ``moe/router`` and
+``moe/shared`` in ``modules/moe.py``, the other ``moe/*`` in ``ops/
+grouped_gemm.py``, a family's own in ``model_implementations/ragged_*.py``.
+
 Host↔device alignment, one rule: a span opened with :meth:`Tracer.span`
 is ALSO entered as a ``jax.profiler.TraceAnnotation`` of the same name
 while :func:`enable_device_annotations` (or ``DS_DEVICE_TRACE``) is on,

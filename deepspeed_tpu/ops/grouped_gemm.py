@@ -525,7 +525,7 @@ def exact_topk_routing(logits: jnp.ndarray, k: int,
     semantics, the default) or as the softmax gave them (HF
     ``norm_topk_prob: false``, OLMoE).  The single source of truth shared
     by the training gate (moe/sharded_moe.py), the ragged inference path
-    (ragged_mixtral.py), and benchmarks.  Returns (topi [T,k] int32,
+    (modules/moe.py), and benchmarks.  Returns (topi [T,k] int32,
     topw [T,k] fp32)."""
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     topw, topi = jax.lax.top_k(probs, k)

@@ -189,28 +189,9 @@ class ContinuousBatchScheduler:
                 raise ValueError(
                     "speculative decoding needs an engine with "
                     "verify_step/commit_verified (InferenceEngineV2)")
-            if getattr(engine, "_stateful", False):
-                from deepspeed_tpu.inference.v2.ragged import (
-                    RecurrentStateError)
-
-                raise RecurrentStateError(
-                    "speculative decoding verifies drafts through "
-                    "verify_step, which a model with per-sequence "
-                    "recurrent state refuses: rejected lookahead tokens "
-                    "cannot be rolled back out of the state")
-            if getattr(engine.state_manager, "window", None) is not None:
-                from deepspeed_tpu.inference.v2.ragged import KVGroupsError
-
-                raise KVGroupsError(
-                    "speculative decoding verifies drafts through "
-                    "verify_step, which a model with window and global KV "
-                    "layers behind two block tables (kv_groups) refuses: "
-                    "the verify read and its block trim know one table")
-            if getattr(engine.state_manager.kv_cache, "kv_row", None):
-                raise NotImplementedError(
-                    "speculative decoding verifies drafts through "
-                    "verify_step, which reads per-head keys and values: "
-                    "a model that keeps a latent row refuses it")
+            engine.state_manager.require(
+                "verify", "speculative decoding (drafts are verified "
+                "through verify_step)")
             if not fast_decode:
                 raise ValueError(
                     "speculative decoding runs on the fast decode tick — "

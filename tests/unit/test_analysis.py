@@ -69,8 +69,13 @@ def test_all_pallas_sites_registered_and_validated(dslint_repo):
     for mod in registry.KERNEL_MODULES:
         importlib.import_module(mod)
     sites = list(_iter_pallas_sites(str(REPO / "deepspeed_tpu")))
-    # the 7 kernel files and (at least) the historical 18 call sites
-    assert len({s[0] for s in sites}) == len(registry.KERNEL_MODULES)
+    # every file with a site is listed, and (at least) the historical 18
+    # call sites; the one listed file without a site holds the cases of
+    # the route over blocked_flash's kernels, beside that route (PR 42)
+    listed = {m.replace(".", "/") + ".py" for m in registry.KERNEL_MODULES}
+    assert listed - {s[0] for s in sites} == {
+        "deepspeed_tpu/inference/v2/modules/attention.py"}
+    assert {s[0] for s in sites} <= listed
     assert len(sites) >= 18
     _rc, report = dslint_repo
     assert not [f for f in report["new"] + report["baselined"]

@@ -1,0 +1,120 @@
+"""The one table of what a cache layout cannot serve (PR 42): each extension
+a model states (``state_spec``, ``kv_row``, ``kv_groups``) lists, where it is
+defined, the features it cannot serve and why; ``DSStateManager`` merges
+them and ``require(feature, path)`` is the one check.  This walks the table:
+every (extension, feature) entry refuses at every path behind the feature,
+with one exception class, naming the path, the extension and the entry's
+reason.  The model is a stub (no forward runs: every check is made before a
+program is built)."""
+
+import re
+import types
+
+import jax.numpy as jnp
+import pytest
+
+from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
+                                        RaggedInferenceEngineConfig)
+from deepspeed_tpu.inference.v2.ragged import CacheLayoutError
+from deepspeed_tpu.inference.v2.ragged.kv_cache import (FEATURES, LATENT_ROW,
+                                                        WINDOW_GROUP)
+from deepspeed_tpu.inference.v2.ragged.state_pool import STATE_SLOTS
+from deepspeed_tpu.serving import ContinuousBatchScheduler, SpeculativeConfig
+
+#: extension -> (its table, what a model states to ask for it)
+EXTENSIONS = {
+    "state_spec": (STATE_SLOTS, {"state_spec": {
+        "layers": [0], "leaves": {"conv": ((2, 8), jnp.float32)}}}),
+    "kv_row": (LATENT_ROW, {"kv_row": {"ckv": 128}}),
+    "kv_groups": (WINDOW_GROUP, {"kv_groups": {
+        "window": {"layers": [0], "window": 16}}}),
+}
+
+
+class _Model:
+    """What the engine reads of a model before it builds a program."""
+    config = types.SimpleNamespace(dtype=jnp.float32)
+    num_layers, num_kv_heads, head_dim, tp = 2, 2, 16, 1
+
+    def __init__(self, **stated):
+        self.__dict__.update(stated)
+
+
+def _engine(stated, **kv):
+    return InferenceEngineV2(
+        _Model(**stated), {}, RaggedInferenceEngineConfig.from_dict({
+            "state_manager": {"max_ragged_batch_size": 128,
+                              "max_ragged_sequence_count": 2,
+                              "max_context": 64},
+            "kv_cache": {"block_size": 8, "num_blocks": 17, **kv}}))
+
+
+#: feature -> the paths behind it: (what the message names, the call)
+PATHS = {
+    "prefix_cache": [("kv_cache.enable_prefix_cache", lambda stated: _engine(
+        stated, enable_prefix_cache=True))],
+    "host_tier": [("kv_cache.host_tier", lambda stated: _engine(
+        stated, enable_prefix_cache=True, host_tier=True,
+        host_tier_bytes=1 << 20))],
+    "kv_handoff": [
+        ("flush_to_host(include_kv=True)", lambda stated: _engine(
+            stated).flush_to_host([1], include_kv=True)),
+        ("resume(kv_state=...)", lambda stated: _engine(stated).resume(
+            9, list(range(8)), kv_state={"seen_tokens": 8, "kv": {}}))],
+    "verify": [
+        ("verify_step", lambda stated: _engine(stated).verify_step(
+            [1], [[3, 4]])),
+        ("speculative decoding", lambda stated: ContinuousBatchScheduler(
+            _engine(stated), speculative=SpeculativeConfig()))],
+    "decode_loop": [("decode_loop", lambda stated: _engine(
+        stated).decode_loop([1], [3], 4))],
+    "int8_kv": [("kv_cache.dtype=int8", lambda stated: _engine(
+        stated, dtype="int8"))],
+}
+
+ENTRIES = [(ext, feature) for ext, (table, _) in EXTENSIONS.items()
+           for feature in table[1]]
+
+
+def test_the_tables_name_features_and_every_feature_has_a_path():
+    for _keeps, cannot in (STATE_SLOTS, LATENT_ROW, WINDOW_GROUP):
+        assert set(cannot) <= set(FEATURES)
+    assert set(PATHS) == set(FEATURES)
+
+
+@pytest.mark.parametrize("extension,feature", ENTRIES,
+                         ids=[f"{e}-{f}" for e, f in ENTRIES])
+def test_each_entry_refuses_at_its_paths_with_its_reason(extension, feature):
+    (keeps, cannot), stated = EXTENSIONS[extension]
+    for path, call in PATHS[feature]:
+        with pytest.raises(CacheLayoutError,
+                           match=re.escape(cannot[feature])) as err:
+            call(stated)
+        message = str(err.value)
+        assert path in message and keeps in message
+        assert f"({extension})" in message
+        # the refusals the engine makes or passes on name the model
+        assert "_Model" in message or path == "speculative decoding"
+
+
+@pytest.mark.parametrize("extension", EXTENSIONS)
+def test_what_an_extension_does_not_list_is_served(extension):
+    (_keeps, cannot), stated = EXTENSIONS[extension]
+    sm = _engine(stated).state_manager
+    assert set(sm.unserved) == set(cannot)
+    for feature in set(FEATURES) - set(cannot):
+        sm.require(feature, "a path")
+
+
+def test_plain_pools_serve_every_feature_and_two_extensions_merge():
+    sm = _engine({}).state_manager
+    assert sm.unserved == {}
+    for feature in FEATURES:
+        sm.require(feature, "a path")
+    with pytest.raises(AssertionError):
+        sm.require("no_such_feature", "a path")
+    both = {**EXTENSIONS["state_spec"][1], **EXTENSIONS["kv_groups"][1]}
+    with pytest.raises(CacheLayoutError) as err:
+        _engine(both).verify_step([1], [[3, 4]])
+    assert STATE_SLOTS[1]["verify"] in str(err.value)
+    assert WINDOW_GROUP[1]["verify"] in str(err.value)

@@ -17,8 +17,8 @@ import jax.numpy as jnp
 from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
                                         RaggedInferenceEngineConfig)
 from deepspeed_tpu.inference.v2.model_implementations import (RaggedLlama,
-                                                              RaggedMixtral,
-                                                              ragged_llama)
+                                                              RaggedMixtral)
+from deepspeed_tpu.inference.v2.modules import attention
 from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
 from deepspeed_tpu.models.mixtral import MixtralConfig, MixtralForCausalLM
 from deepspeed_tpu.parallel import groups
@@ -93,7 +93,7 @@ def test_put_and_decode_step_on_the_flat_pool_match_the_xla_reads(
     assert d == 128
     want = _put_then_decode(_engine(name, weights[name]), _prompts(name))
 
-    monkeypatch.setattr(ragged_llama, "on_tpu", lambda: True)
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
     pools = {"paged_decode_attention": [], "paged_prefill_attention": []}
     for kernel in pools:
         def spy(q, k_pool, *a, _f=getattr(kernels, kernel), _n=kernel, **kw):
@@ -179,7 +179,7 @@ def test_verify_step_on_the_flat_pool_matches_sequential_decode(
     on the chip's route the verify kernel takes the pool's per-head view."""
     name = "mistral"
     if route == "kernels":
-        monkeypatch.setattr(ragged_llama, "on_tpu", lambda: True)
+        monkeypatch.setattr(attention, "on_tpu", lambda: True)
     prompt = _prompts(name)[0]
     eng = _engine(name, weights[name])
     toks = [int(np.argmax(eng.put([0], [prompt])[0]))]

@@ -241,7 +241,7 @@ def test_gmm_nondivisible_falls_back():
 
 def test_grouped_moe_ffn_matches_dense_dropless():
     """grouped_moe_ffn == the dense all-experts dropless composition
-    (ragged_mixtral.dropless_moe's math) for identical routing."""
+    (modules/moe.py::dropless_moe's math) for identical routing."""
     rng = np.random.default_rng(7)
     t, h, f, e, k = 64, 64, 128, 4, 2
     x = jnp.asarray(rng.standard_normal((t, h)) * 0.1, jnp.float32)

@@ -159,8 +159,8 @@ def test_mixtral_ragged_engine_matches_hf(tmp_path):
 
     from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
                                             RaggedInferenceEngineConfig)
-    from deepspeed_tpu.inference.v2.model_implementations.ragged_mixtral \
-        import RaggedMixtral
+    from deepspeed_tpu.inference.v2.model_implementations import (
+        RaggedMixtral)
 
     eng_cfg = RaggedInferenceEngineConfig.from_dict({
         "state_manager": {"max_ragged_batch_size": 16,

@@ -216,7 +216,7 @@ def test_paged_attention_kernel_matches_xla_reference():
     import numpy as np
 
     from deepspeed_tpu.inference.v2.kernels import paged_attention
-    from deepspeed_tpu.inference.v2.model_implementations.ragged_llama import (
+    from deepspeed_tpu.inference.v2.modules.attention import (
         _paged_attention)
 
     rng = np.random.default_rng(7)
@@ -244,7 +244,7 @@ def test_ragged_engine_with_kernel_path():
     (interpret mode on CPU): outputs must match the XLA-path engine."""
     import numpy as np
 
-    import deepspeed_tpu.inference.v2.model_implementations.ragged_llama as rl
+    import deepspeed_tpu.inference.v2.modules.attention as rl
 
     orig = rl._paged_attention
 
@@ -331,7 +331,7 @@ def test_v2_tp_hlo_only_rowparallel_allreduce():
     one psum for the vocab-split embedding + 2 per layer (attn-out,
     mlp-down), and one all-gather for the vocab-split unembed — nothing
     else (no per-projection resharding)."""
-    from deepspeed_tpu.inference.v2.model_implementations.ragged_llama import (
+    from deepspeed_tpu.inference.v2.modules.attention import (
         kv_spec, shard_ragged_params)
     from deepspeed_tpu.inference.v2.ragged.kv_cache import BlockedKVCache
     from jax.sharding import NamedSharding
@@ -369,7 +369,7 @@ def test_v2_tp_hlo_only_rowparallel_allreduce():
 # ------------------------------------------------------------------ #
 def test_paged_attention_kernel_window_matches_xla():
     from deepspeed_tpu.inference.v2.kernels import paged_attention
-    from deepspeed_tpu.inference.v2.model_implementations.ragged_llama import (
+    from deepspeed_tpu.inference.v2.modules.attention import (
         _paged_attention)
 
     rng = np.random.default_rng(9)
@@ -691,7 +691,7 @@ def test_v2_serialize_roundtrip(tmp_path):
 # ------------------------------------------------------------------ #
 def test_tiled_prefill_kernel_matches_xla():
     from deepspeed_tpu.inference.v2.kernels import paged_prefill_attention
-    from deepspeed_tpu.inference.v2.model_implementations.ragged_llama import (
+    from deepspeed_tpu.inference.v2.modules.attention import (
         _paged_attention)
 
     rng = np.random.default_rng(15)
@@ -726,7 +726,7 @@ def test_tiled_prefill_kernel_matches_xla():
 
 def test_tiled_prefill_kernel_window_matches_xla():
     from deepspeed_tpu.inference.v2.kernels import paged_prefill_attention
-    from deepspeed_tpu.inference.v2.model_implementations.ragged_llama import (
+    from deepspeed_tpu.inference.v2.modules.attention import (
         _paged_attention)
 
     rng = np.random.default_rng(16)
@@ -763,7 +763,7 @@ def test_two_segment_attention_routes_match_xla(monkeypatch, d, nb, single):
     step of that pool takes, the tiles the tiled kernel; every real row
     equals the XLA gather composition."""
     from deepspeed_tpu.inference.v2 import kernels
-    from deepspeed_tpu.inference.v2.model_implementations import ragged_llama
+    from deepspeed_tpu.inference.v2.modules import attention
 
     from deepspeed_tpu.inference.v2.ragged.kv_cache import flat_row
 
@@ -791,16 +791,16 @@ def test_two_segment_attention_routes_match_xla(monkeypatch, d, nb, single):
     for mod, name in ((kernels, "paged_decode_attention"),
                       (kernels, "paged_attention"),
                       (kernels, "paged_prefill_attention"),
-                      (ragged_llama, "_dense_pool_read")):
+                      (attention, "_dense_pool_read")):
         def spy(*a, _f=getattr(mod, name), _n=name, **kw):
             ran.append(_n)
             return _f(*a, **kw)
         monkeypatch.setattr(mod, name, spy)
     for window in (None, 12):
-        ref = ragged_llama._paged_attention(q, k_pool, v_pool, batch, bs,
+        ref = attention._paged_attention(q, k_pool, v_pool, batch, bs,
                                             use_kernel=False, window=window)
         del ran[:]
-        got = ragged_llama._paged_attention(q, k_pool, v_pool, batch, bs,
+        got = attention._paged_attention(q, k_pool, v_pool, batch, bs,
                                             use_kernel=True, window=window,
                                             prefill_tile=tile)
         assert ran == [single, "paged_prefill_attention"]
@@ -812,12 +812,12 @@ def test_two_segment_attention_routes_match_xla(monkeypatch, d, nb, single):
     # a batch of single-token chunks only: no tiled segment at all
     head = {k: v[:S] if k != "block_tables" else v for k, v in batch.items()}
     del ran[:]
-    got = ragged_llama._paged_attention(q[:S], k_pool, v_pool, head, bs,
+    got = attention._paged_attention(q[:S], k_pool, v_pool, head, bs,
                                         use_kernel=True, prefill_tile=tile)
     assert ran == [single]
     np.testing.assert_allclose(
         np.asarray(got)[:2],
-        np.asarray(ragged_llama._paged_attention(
+        np.asarray(attention._paged_attention(
             q[:S], k_pool, v_pool, head, bs, use_kernel=False))[:2],
         rtol=2e-5, atol=2e-5)
 
@@ -829,11 +829,11 @@ def _tiled_scheduler(monkeypatch, params, cfg=CFG, token_budget=64,
     route ``_paged_attention`` takes on a TPU), with the calls of the two
     ``put`` kernels counted as they are traced."""
     from deepspeed_tpu.inference.v2 import kernels
-    from deepspeed_tpu.inference.v2.model_implementations import ragged_llama
+    from deepspeed_tpu.inference.v2.modules import attention
     from deepspeed_tpu.observability import Tracer
     from deepspeed_tpu.serving import ContinuousBatchScheduler
 
-    monkeypatch.setattr(ragged_llama, "on_tpu", lambda: True)
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
     calls = {"paged_prefill_attention": 0, "paged_attention": 0}
     for name in calls:
         def counted(*a, _f=getattr(kernels, name), _n=name, **kw):

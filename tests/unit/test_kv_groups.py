@@ -38,7 +38,7 @@ from deepspeed_tpu.inference.v2 import (                      # noqa: E402
 from deepspeed_tpu.inference.v2.config_v2 import (            # noqa: E402
     DSStateManagerConfig, KVCacheConfig)
 from deepspeed_tpu.inference.v2.ragged import (               # noqa: E402
-    BlockedKVCache, DSStateManager, KVGroupsError, RaggedBatchWrapper)
+    BlockedKVCache, DSStateManager, CacheLayoutError, RaggedBatchWrapper)
 from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import (  # noqa: E402
     RaggedMetadataError, pack_metadata, packed_length, unpack_metadata)
 from deepspeed_tpu.observability.tracer import Tracer         # noqa: E402
@@ -135,7 +135,7 @@ def test_block_operations_over_every_layer_refuse():
                         window_layers=(0, 1, 3, 4), window_blocks=4)
     for call in (lambda: kv.copy_block(1, 2), lambda: kv.gather_blocks([1]),
                  lambda: kv.scatter_blocks([1], {})):
-        with pytest.raises(KVGroupsError, match="two pools"):
+        with pytest.raises(CacheLayoutError, match="two pools"):
             call()
 
 
@@ -405,12 +405,12 @@ def test_paths_that_know_one_table_refuse_by_name(path):
     p = params()
     if path in ("prefix_cache", "host_tier"):
         kv = {"enable_prefix_cache": True, "host_tier": path == "host_tier"}
-        with pytest.raises(KVGroupsError, match="enable_prefix_cache"):
+        with pytest.raises(CacheLayoutError, match="enable_prefix_cache"):
             engine(p, **kv)
         return
     eng = engine(p)
     if path == "speculative":
-        with pytest.raises(KVGroupsError, match="kv_groups"):
+        with pytest.raises(CacheLayoutError, match="kv_groups"):
             ContinuousBatchScheduler(eng, speculative=SpeculativeConfig())
         return
     eng.put([1], [ids(20).tolist()])
@@ -421,7 +421,7 @@ def test_paths_that_know_one_table_refuse_by_name(path):
         "resume_kv": lambda: eng.resume(
             9, list(range(8)), kv_state={"seen_tokens": 8, "kv": {}}),
     }[path]
-    with pytest.raises(KVGroupsError, match=path.split("_kv")[0]) as err:
+    with pytest.raises(CacheLayoutError, match=path.split("_kv")[0]) as err:
         call()
     assert "RaggedAfmoe" in str(err.value) or "_SeededBias" in str(err.value)
     assert eng.state_manager.get_sequence(1).seen_tokens == 20

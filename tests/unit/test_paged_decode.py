@@ -17,7 +17,7 @@ import jax.numpy as jnp
 
 from deepspeed_tpu.inference.v2.kernels.blocked_flash import (
     paged_decode_attention)
-from deepspeed_tpu.inference.v2.model_implementations.ragged_llama import (
+from deepspeed_tpu.inference.v2.modules.attention import (
     _paged_attention)
 
 BS = 128

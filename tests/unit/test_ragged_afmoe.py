@@ -39,9 +39,9 @@ from benchmark.reference import afmoe as reference            # noqa: E402
 from benchmark.tools.calls import pr39_faults                 # noqa: E402
 from deepspeed_tpu.inference.v2.kernels import blocked_flash  # noqa: E402
 from deepspeed_tpu.inference.v2.model_implementations import (  # noqa: E402
-    AfmoeConfig, RaggedAfmoe, ragged_afmoe, ragged_llama)
-from deepspeed_tpu.inference.v2.model_implementations.ragged_mixtral \
-    import dropless_moe                                       # noqa: E402
+    AfmoeConfig, RaggedAfmoe, ragged_afmoe)
+from deepspeed_tpu.inference.v2.modules import attention      # noqa: E402
+from deepspeed_tpu.inference.v2.modules.moe import dropless_moe  # noqa: E402
 from test_kv_groups import BS, HF, WINDOW, engine, ids, params  # noqa: E402
 
 # float32 engine against the float32 reference, largest |difference| over
@@ -184,8 +184,7 @@ def _moe_params(p, layer=1):
 
 
 def test_router_bias_selects_and_does_not_weigh():
-    from deepspeed_tpu.inference.v2.model_implementations.ragged_mixtral \
-        import moe_router
+    from deepspeed_tpu.inference.v2.modules.moe import moe_router
 
     mlp = _moe_params(params())
     x = jax.random.normal(jax.random.key(1), (50, 64), jnp.float32)
@@ -308,7 +307,7 @@ def test_one_token_rows_of_both_kinds_take_the_walk(monkeypatch):
           "layer_types": ["sliding_attention", "full_attention"],
           "hidden_size": 32, "intermediate_size": 64,
           "moe_intermediate_size": 32}
-    monkeypatch.setattr(ragged_llama, "on_tpu", lambda: True)
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
     import deepspeed_tpu.inference.v2.kernels as kernels
 
     calls = {"paged_decode_attention": [], "paged_prefill_attention": []}

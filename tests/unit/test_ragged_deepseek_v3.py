@@ -33,8 +33,7 @@ from deepspeed_tpu.inference.v2 import (                     # noqa: E402
 from deepspeed_tpu.inference.v2.kernels import latent_flash as lf  # noqa: E402
 from deepspeed_tpu.inference.v2.model_implementations import (  # noqa: E402
     ragged_deepseek_v3 as rd)
-from deepspeed_tpu.inference.v2.model_implementations.ragged_mixtral \
-    import dropless_moe                                      # noqa: E402
+from deepspeed_tpu.inference.v2.modules.moe import dropless_moe  # noqa: E402
 from deepspeed_tpu.observability.tracer import Tracer        # noqa: E402
 from deepspeed_tpu.ops.grouped_gemm import sigmoid_bias_topk_routing  # noqa: E402
 from deepspeed_tpu.serving import (ContinuousBatchScheduler,  # noqa: E402

@@ -32,10 +32,11 @@ from deepspeed_tpu.inference.v2 import (                     # noqa: E402
     InferenceEngineV2, RaggedInferenceEngineConfig)
 from deepspeed_tpu.inference.v2.kernels import blocked_flash  # noqa: E402
 from deepspeed_tpu.inference.v2.model_implementations import (  # noqa: E402
-    ragged_lfm2 as rl, ragged_llama, ragged_qwen3_next as rq)
-from deepspeed_tpu.inference.v2.model_implementations.ragged_mixtral \
-    import dropless_moe, moe_router                          # noqa: E402
-from deepspeed_tpu.inference.v2.ragged import RecurrentStateError  # noqa: E402
+    ragged_lfm2 as rl)
+from deepspeed_tpu.inference.v2.modules import attention, conv  # noqa: E402
+from deepspeed_tpu.inference.v2.modules.moe import (         # noqa: E402
+    dropless_moe, moe_router)
+from deepspeed_tpu.inference.v2.ragged import CacheLayoutError  # noqa: E402
 from deepspeed_tpu.observability.tracer import Tracer        # noqa: E402
 from deepspeed_tpu.serving import (ContinuousBatchScheduler,  # noqa: E402
                                    SamplingParams)
@@ -221,7 +222,7 @@ def test_a_seeded_fault_fails_the_tolerance(fault, monkeypatch):
     zeroed at every chunk boundary, SiLU left after the taps, B and C
     exchanged, the selection bias dropped."""
     params, ids = _params(), _ids(66 + 6)
-    real_conv = rq._causal_conv
+    real_conv = conv._causal_conv
     if fault == "tail_zeroed":
         monkeypatch.setattr(rl, "_causal_conv", lambda u, w, pool, batch,
                             activation=None: real_conv(
@@ -338,13 +339,13 @@ def test_causal_conv_against_a_padded_convolution(activation):
         "token_pos": jnp.asarray(
             [9, -1, -1] + [0, 1, 2, 3, 4, -1, -1, -1]
             + [4, 5, -1, -1, -1, -1, -1, -1], jnp.int32)}
-    act = rq._silu if activation else None
-    got, new_pool = rq._causal_conv(u, w, pool, batch, activation=act)
+    act = conv._silu if activation else None
+    got, new_pool = conv._causal_conv(u, w, pool, batch, activation=act)
 
     def padded(rows, tail):
         seq = jnp.concatenate([tail, u[rows]])
         out = sum(w[j] * seq[j:j + len(rows)] for j in range(taps))
-        return (rq._silu(out) if activation else out), seq[-(taps - 1):]
+        return (conv._silu(out) if activation else out), seq[-(taps - 1):]
 
     zero = jnp.zeros((taps - 1, ch))
     for rows, slot, tail in ((np.array([0]), 2, pool[2]),
@@ -460,14 +461,14 @@ def test_decode_walk_on_a_flat_row_matches_the_oracle(h, hkv, d, bs, window):
         q, kp, vp, tables, slot, pos, block_size=bs, window=window,
         interpret=True)
     batch = {"block_tables": tables, "token_slot": slot, "token_pos": pos}
-    want = ragged_llama._paged_attention(q, kp, vp, batch, bs,
+    want = attention._paged_attention(q, kp, vp, batch, bs,
                                          use_kernel=False, decode_mode=True,
                                          window=window)
     live = np.asarray(pos) >= 0
     assert np.max(np.abs(np.asarray(got - want))[live]) <= 1e-5
     assert np.all(np.asarray(got)[~live] == 0)
     if window is not None:              # the window bites on the long rows
-        full = ragged_llama._paged_attention(
+        full = attention._paged_attention(
             q, kp, vp, batch, bs, use_kernel=False, decode_mode=True)
         assert np.max(np.abs(np.asarray(want - full))[live]) > 1e-3
 
@@ -489,7 +490,7 @@ def test_tiled_prefill_on_a_flat_row_matches_the_oracle(h, hkv, d, bs,
     got = blocked_flash.paged_prefill_attention(
         q, kp, vp, tables, jnp.asarray(slot), jnp.asarray(pos),
         block_size=bs, tile_q=tile, window=window, interpret=True)
-    want = ragged_llama._paged_attention(
+    want = attention._paged_attention(
         q, kp, vp, {"block_tables": tables, "token_slot": jnp.asarray(slot),
                     "token_pos": jnp.asarray(pos)}, bs, use_kernel=False,
         window=window)
@@ -554,7 +555,7 @@ def test_one_token_rows_of_the_engine_take_the_walk(monkeypatch):
     ``_decode_kernel`` (counted), and the logits are the reference's."""
     calls = []
     real = blocked_flash.paged_decode_attention
-    monkeypatch.setattr(ragged_llama, "on_tpu", lambda: True)
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
     import deepspeed_tpu.inference.v2.kernels as kernels
     monkeypatch.setattr(
         kernels, "paged_decode_attention",
@@ -618,13 +619,13 @@ def test_bytes_at_the_published_widths():
 def test_paths_that_cannot_carry_a_tail_refuse_by_name(path):
     params = _params()
     if path == "prefix_cache":
-        with pytest.raises(RecurrentStateError, match="RaggedLfm2"):
+        with pytest.raises(CacheLayoutError, match="RaggedLfm2"):
             _engine(params, enable_prefix_cache=True)
         return
     eng = _engine(params)
     if path == "untiled_budget":
         eng.PREFILL_TILE = 48           # 80 is no whole number of tiles
-        with pytest.raises(RecurrentStateError, match="whole tiles"):
+        with pytest.raises(CacheLayoutError, match="whole tiles"):
             eng.put([1], [[1, 2, 3]])
         return
     eng.put([1], [_ids(20).tolist()])
@@ -635,7 +636,7 @@ def test_paths_that_cannot_carry_a_tail_refuse_by_name(path):
         "resume_kv": lambda: eng.resume(
             9, list(range(8)), kv_state={"seen_tokens": 8, "kv": {}}),
     }[path]
-    with pytest.raises(RecurrentStateError, match=path.split("_kv")[0]):
+    with pytest.raises(CacheLayoutError, match=path.split("_kv")[0]):
         call()
     assert eng.state_manager.get_sequence(1).seen_tokens == 20
 

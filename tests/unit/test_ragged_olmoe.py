@@ -30,8 +30,9 @@ from benchmark.families import olmoe as family          # noqa: E402
 from benchmark.reference import olmoe as reference      # noqa: E402
 from deepspeed_tpu.inference.v2 import (                 # noqa: E402
     InferenceEngineV2, RaggedInferenceEngineConfig)
-from deepspeed_tpu.inference.v2.model_implementations.ragged_mixtral \
-    import RaggedMixtral, dropless_moe                  # noqa: E402
+from deepspeed_tpu.inference.v2.model_implementations import (  # noqa: E402
+    RaggedMixtral)
+from deepspeed_tpu.inference.v2.modules.moe import dropless_moe  # noqa: E402
 from deepspeed_tpu.models.mixtral import (               # noqa: E402
     MixtralConfig, MixtralForCausalLM)
 from deepspeed_tpu.ops import grouped_gemm              # noqa: E402

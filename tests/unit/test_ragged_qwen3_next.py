@@ -33,9 +33,8 @@ from deepspeed_tpu.inference.v2 import (                     # noqa: E402
     InferenceEngineV2, RaggedInferenceEngineConfig)
 from deepspeed_tpu.inference.v2.model_implementations import (  # noqa: E402
     ragged_qwen3_next as rq)
-from deepspeed_tpu.inference.v2.model_implementations.ragged_mixtral \
-    import dropless_moe                                      # noqa: E402
-from deepspeed_tpu.inference.v2.ragged import RecurrentStateError  # noqa: E402
+from deepspeed_tpu.inference.v2.modules.moe import dropless_moe  # noqa: E402
+from deepspeed_tpu.inference.v2.ragged import CacheLayoutError  # noqa: E402
 from deepspeed_tpu.ops import gated_delta_rule as gdr        # noqa: E402
 from deepspeed_tpu.serving import (ContinuousBatchScheduler,  # noqa: E402
                                    RequestState, SamplingParams)
@@ -391,19 +390,19 @@ def test_paths_that_cannot_carry_state_refuse_by_name(path):
             kv.update(host_tier=True, host_tier_bytes=1 << 20)
         # attach_prefix, its copy-on-write fork and the host tier all hang
         # off the prefix cache: refused when the engine is built
-        with pytest.raises(RecurrentStateError, match="enable_prefix_cache"):
+        with pytest.raises(CacheLayoutError, match="enable_prefix_cache"):
             _engine(params, **kv)
         return
     eng = _engine(params)
     if path == "speculative":
         from deepspeed_tpu.serving import SpeculativeConfig
 
-        with pytest.raises(RecurrentStateError, match="verify_step"):
+        with pytest.raises(CacheLayoutError, match="verify_step"):
             ContinuousBatchScheduler(eng, speculative=SpeculativeConfig())
         return
     if path == "untiled_budget":
         eng.PREFILL_TILE = 48           # 64 is no whole number of tiles
-        with pytest.raises(RecurrentStateError, match="whole tiles"):
+        with pytest.raises(CacheLayoutError, match="whole tiles"):
             eng.put([1], [[1, 2, 3]])
         return
     eng.put([1], [_ids(20).tolist()])
@@ -414,7 +413,7 @@ def test_paths_that_cannot_carry_state_refuse_by_name(path):
         "resume_kv": lambda: eng.resume(
             9, list(range(8)), kv_state={"seen_tokens": 8, "kv": {}}),
     }[path]
-    with pytest.raises(RecurrentStateError, match=path.split("_kv")[0]):
+    with pytest.raises(CacheLayoutError, match=path.split("_kv")[0]):
         call()
     # the sequence is as it was: recompute paths still work
     assert eng.state_manager.get_sequence(1).seen_tokens == 20

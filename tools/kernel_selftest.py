@@ -101,8 +101,8 @@ def decode_read_case(cell: str, tol: float, layers: int = 16,
     import numpy as np
 
     from deepspeed_tpu.inference.v2.kernels import paged_decode_attention
-    from deepspeed_tpu.inference.v2.model_implementations.ragged_llama \
-        import _dense_pool_read
+    from deepspeed_tpu.inference.v2.modules.attention import (
+        _dense_pool_read)
 
     bs, rows_n = 128, 32
     nb, hkv, g, d = DECODE_READ_CELLS[cell]
@@ -164,8 +164,8 @@ def verify_read_case(tol: float, layers: int = 16, repeats: int = 10,
     import numpy as np
 
     from deepspeed_tpu.inference.v2.kernels import paged_verify_attention
-    from deepspeed_tpu.inference.v2.model_implementations.ragged_llama \
-        import _paged_attention
+    from deepspeed_tpu.inference.v2.modules.attention import (
+        _paged_attention)
 
     bs, slots, width = 128, 32, 36
     nb, hkv, g, d = DECODE_READ_CELLS["mistral7b"]
@@ -544,8 +544,8 @@ def run_selftest(tol: float = 3e-2) -> dict:
     # ---- paged decode + tiled prefill kernels ---- #
     from deepspeed_tpu.inference.v2.kernels import (
         paged_attention, paged_prefill_attention)
-    from deepspeed_tpu.inference.v2.model_implementations.ragged_llama \
-        import _paged_attention
+    from deepspeed_tpu.inference.v2.modules.attention import (
+        _paged_attention)
 
     bs, S, B = 128, 4, 4
     pool_rows = (S * B + 1) * bs
@@ -674,7 +674,7 @@ def run_selftest(tol: float = 3e-2) -> dict:
     # in no order, pads at position -1) by the decode walk, on a pool
     # larger than the tables could hold and on one smaller, tile-aligned
     # chunks by the tiled kernel
-    from deepspeed_tpu.inference.v2.kernels.blocked_flash import (
+    from deepspeed_tpu.inference.v2.modules.attention import (
         two_segment_case)
 
     def two_segment(name, tight_pool):

@@ -1,29 +1,31 @@
-"""PR 41: does a program still copy a pool?  Compiles every serving cell's
+"""Is a program the parent's?  Compiles every serving configuration's
 ``decode_step`` and two-segment ``put`` programs (the one-token program, one
 tile and the full budget) at REAL size for a described v5e from a machine
-with no chip (the form of ``benchmark/tools/calls/pr39_aot.py``, every
-family), and for each program writes
+with no chip (the form of ``benchmark/tools/aot.py``, every family), and for
+each program writes
 
-* ``pool_relayouts``: the ``copy`` / ``reshape`` / ``transpose`` instructions
-  of the COMPILED text whose result has a K/V pool's element count and dtype
-  (fused computations included; a ``bitcast`` is free and not counted): each
-  is a second pool written in front of a kernel;
-* ``temp_gb``: the temporaries of XLA's memory analysis;
 * ``jaxpr_sha`` / ``compiled_sha``: the program's jaxpr (Pallas kernel
   bodies included, source locations stripped) and its compiled text (the
   tables of files, functions and stack frames at its head, each
   instruction's source metadata and the serialized body of each Mosaic call
   cut: all carry paths and line numbers), to say "the parent's to the
-  letter" of a cell the change bypasses.
+  letter" of a program a change moved, or of a cell it bypasses;
+* ``pool_relayouts``: the ``copy`` / ``reshape`` / ``transpose`` instructions
+  of the COMPILED text whose result has a K/V pool's element count and dtype
+  (fused computations included; a ``bitcast`` is free and not counted): each
+  is a second pool written in front of a kernel;
+* ``temp_gb``: the temporaries of XLA's memory analysis.
 
-    JAX_PLATFORMS=cpu python3 tools/chip_calls/pr41_program_text.py <checkout> <out.json> [dump=<dir>] [config ...]
+    JAX_PLATFORMS=cpu python3 tools/program_text.py <checkout> <out.json> [dump=<dir>] [config ...]
 
 ``dump=<dir>`` also writes each program's two hashed texts there, to ``diff``
 where a hash differs.
 
-Run it on ``git archive`` of the parent and on the change; ``pr41_results/``
-holds both and ``PERF.md`` section 6 the table.  No chip, no value, no
-time."""
+Run it on ``git archive`` of the parent and on the change (``<checkout>`` is
+what it imports ``deepspeed_tpu`` and ``benchmark`` from) and compare the two
+files.  No chip, no value, no time.  (PRs 27, 29, 30, 40 and 41 each kept a
+copy of this under ``tools/chip_calls/``; their ``*_results/`` stay
+there.)"""
 import hashlib
 import json
 import os
