@@ -605,6 +605,27 @@ def _afmoe_rules() -> List[Rule]:
     ]
 
 
+def _ouro_rules() -> List[Rule]:
+    # Ouro (``model_type: ouro``; a looped stack) -> RaggedOuro's tree: the
+    # Llama names, flat, with a second norm after each branch and the exit
+    # gate beside the final norm.
+    def layer(m, *leaf):
+        return (f"layers_{m.group(1)}", *leaf)
+
+    return _flat_moe_backbone_rules() + [
+        (r"^model\.layers\.(\d+)\.(input_layernorm_2|"
+         r"post_attention_layernorm_2)\.weight$",
+         lambda m: (layer(m, m.group(2), "scale"), None)),
+        (r"^model\.layers\.(\d+)\.mlp\.(gate|up|down)_proj\.weight$",
+         lambda m: (layer(m, "mlp", f"{m.group(2)}_proj", "kernel"), "t")),
+        (r"^model\.early_exit_gate\.weight$",
+         lambda m: (("early_exit_gate", "kernel"), "t")),
+        (r"^model\.early_exit_gate\.bias$",
+         lambda m: (("early_exit_gate", "bias"), None)),
+        (r".*rotary_emb\.inv_freq$", lambda m: (None, None)),
+    ]
+
+
 _ARCH_RULES: Dict[str, Callable[[], List[Rule]]] = {
     "llama": _llama_rules,
     "mistral": _llama_rules,     # same architecture/serialization
@@ -615,6 +636,7 @@ _ARCH_RULES: Dict[str, Callable[[], List[Rule]]] = {
     "deepseek_v3": _deepseek_v3_rules,
     "lfm2_moe": _lfm2_moe_rules,
     "afmoe": _afmoe_rules,
+    "ouro": _ouro_rules,
     "gpt2": _gpt2_rules,
     "opt": _opt_rules,
     "falcon": _falcon_rules,
@@ -762,6 +784,15 @@ def config_from_hf(model_path: str, dtype: Any = None):
         # (n_group / topk_group > 1, rope_scaling, another score_func, a
         # tied head: the config refuses each by name)
         return arch, AfmoeConfig(
+            **{k: v for k, v in cfg.items() if k in fields}, dtype=dt)
+    if arch == "ouro":
+        from deepspeed_tpu.inference.v2.model_implementations. \
+            ragged_ouro import OuroConfig
+
+        fields = {f.name for f in dataclasses.fields(OuroConfig)} - {"dtype"}
+        # (early_exit_threshold < 1, rope_scaling, a sliding window, a tied
+        # head: the config refuses each by name)
+        return arch, OuroConfig(
             **{k: v for k, v in cfg.items() if k in fields}, dtype=dt)
     if arch == "gpt2":
         from deepspeed_tpu.models.gpt2 import GPT2Config

@@ -194,20 +194,25 @@ class InferenceEngineV2:
         # a model states what its cache keeps beside plain k / v pools:
         # recurrent state in slots (``state_spec``), a pool row of its own
         # (``kv_row``), two groups of KV layers behind two block tables
-        # (``kv_groups``).  The state manager builds that layout and knows
+        # (``kv_groups``), a cache per pass of a stack of layers that runs
+        # several times a token (``kv_passes``).  The state manager builds
+        # that layout and knows
         # what it cannot serve (``require``); each extension's operands are
         # handed to the step programs below.
         state_spec = getattr(model, "state_spec", None)
         kv_groups = getattr(model, "kv_groups", None)
         self._stateful = state_spec is not None
         self._grouped = kv_groups is not None
+        #: caches a layer keeps (``kv_passes``): on every dispatch span
+        self._passes = int(getattr(model, "kv_passes", 1))
         try:
             self.state_manager = DSStateManager(
                 sm_cfg, kv_cfg, num_layers=model.num_layers,
                 num_kv_heads=model.num_kv_heads, head_dim=model.head_dim,
                 dtype=getattr(model.config, "dtype", None),
                 state_spec=state_spec, kv_row=getattr(model, "kv_row", None),
-                **({"kv_groups": kv_groups} if self._grouped else {}))
+                **({"kv_groups": kv_groups} if self._grouped else {}),
+                **({"kv_passes": self._passes} if self._passes > 1 else {}))
         except CacheLayoutError as e:   # of which model, for the message
             raise CacheLayoutError(f"{type(model).__name__}: {e}") from None
         if self.state_manager.kv_cache.quantized:
@@ -276,22 +281,44 @@ class InferenceEngineV2:
             f"InferenceEngineV2: token_budget={sm_cfg.max_ragged_batch_size} "
             f"max_seqs={sm_cfg.max_ragged_sequence_count} "
             f"kv_blocks={self.state_manager.allocator.num_blocks} "
-            f"block_size={kv_cfg.block_size}", ranks=[0])
+            f"block_size={kv_cfg.block_size}" + (
+                f" passes={self._passes} cache_layers={self._cache_layers}"
+                if self._passes > 1 else ""), ranks=[0])
 
     def attach_tracer(self, tracer) -> None:
         """Record this engine's spans on ``tracer`` (None detaches)."""
         self.tracer = tracer
 
-    def _launched(self, span, step) -> int:
+    def _launched(self, span, step, rows=()) -> int:
         """Count the step program just dispatched; a live dispatch span
         closes with the launch record: that number and the jitted
         program's name (the ``jit(<program>)`` that heads the ``op_name``
-        of its device operations)."""
+        of its device operations).  A looped stack (``kv_passes``) adds
+        its trips, its caches and what ``rows`` ([(positions cached, tokens
+        fed)] a sequence) ask of each of them (``loop_seqs``,
+        ``loop_tokens``, the context tokens read ``loop_ctx_tokens`` and the
+        causal (query, key) pairs ``loop_attn_pairs``; names no other
+        span's counter has: readers sum a counter over a tick's spans)."""
         self.last_launch += 1
         if type(span) is SpanHandle:
             span.attrs = {"launch": self.last_launch,
                           "program": step.__name__}
+            if self._passes > 1:
+                span.attrs.update(
+                    passes=self._passes, cache_layers=self._cache_layers,
+                    loop_seqs=len(rows),
+                    loop_tokens=sum(n for _, n in rows),
+                    loop_ctx_tokens=sum(a + n for a, n in rows),
+                    loop_attn_pairs=sum(n * (2 * a + n + 1) // 2
+                                        for a, n in rows))
         return self.last_launch
+
+    @property
+    def _cache_layers(self) -> int:
+        """K/V caches the step programs read and write: one a (KV layer,
+        pass)."""
+        kv = self.state_manager.kv_cache
+        return kv.passes * len(kv.kv_layers)
 
     # ------------------------------------------------------------------ #
     # Scheduling predicates (reference can_schedule:181 / query:153)
@@ -653,7 +680,10 @@ class InferenceEngineV2:
             step = self._get_step(prepared.bucket, prepared.tile)
             logits, nxt, new_cache = step(self.params, sm.kv_cache.cache,
                                           packed)
-            launch = self._launched(span, step)
+            launch = self._launched(span, step, [
+                (sm.get_sequence(uid).seen_tokens, n) for uid, n in zip(
+                    prepared.scheduled, prepared.chunk_sizes)]
+                if self._passes > 1 else ())
         sm.kv_cache.update(new_cache)
         for uid, n in zip(prepared.scheduled, prepared.chunk_sizes):
             seq = sm.get_sequence(uid)
@@ -772,7 +802,9 @@ class InferenceEngineV2:
                 logits, nxt, new_cache, new_pos = step(
                     self.params, sm.kv_cache.cache, state["tables"],
                     state["pos"], tok, *state["slots"])
-                self._launched(span, step)
+                self._launched(span, step,
+                               [(seq.seen_tokens, 1) for seq in seqs]
+                               if self._passes > 1 else ())
         except Exception:
             self._recover_donated_cache()
             raise
@@ -1400,7 +1432,7 @@ class InferenceEngineV2:
         try:
             seq.blocks = sm._allocate(n_blocks)
             payload = kv_state["kv"]
-            need_rows = n_blocks * sm.block_size
+            need_rows = n_blocks * sm.kv_cache.block_rows
             payload = jax.tree_util.tree_map(
                 lambda h: np.asarray(h)[:need_rows], payload)
             sm.kv_cache.scatter_blocks(seq.blocks, payload)
@@ -1484,8 +1516,10 @@ class InferenceEngineV2:
         """Serve a real HuggingFace checkpoint directory (reference: the
         MII/engine_factory path that builds a FastGen engine from a HF
         snapshot).  ``model_implementations.HF_MODELS`` names the
-        architectures served and which take a ``mesh`` with a non-trivial
-        'model' axis (the others refuse one): weights then land PRE-SHARDED
+        architectures served (llama, mistral, internlm, opt, falcon, mixtral,
+        olmoe, qwen3_next, deepseek_v3, lfm2_moe, afmoe, ouro) and which take
+        a ``mesh`` with a non-trivial 'model' axis (the others refuse one):
+        weights then land PRE-SHARDED
         by the Megatron split rules (``modules/attention.py::
         shard_ragged_params``'s specs) — no full host/device copy.
 

@@ -1,6 +1,6 @@
 """Inference v2 model implementations (reference:
 inference/v2/model_implementations/ — llama_v2, opt, mistral, mixtral,
-falcon families; qwen3_next, deepseek_v3, lfm2_moe and afmoe have no
+falcon families; qwen3_next, deepseek_v3, lfm2_moe, afmoe and ouro have no
 reference counterpart).  One file a family (config, parameter shapes,
 class) over the shared layers of ``inference/v2/modules/``."""
 
@@ -28,6 +28,10 @@ from deepspeed_tpu.inference.v2.model_implementations.ragged_deepseek_v3 import 
 from deepspeed_tpu.inference.v2.model_implementations.ragged_lfm2 import (
     Lfm2Config,
     RaggedLfm2,
+)
+from deepspeed_tpu.inference.v2.model_implementations.ragged_ouro import (
+    OuroConfig,
+    RaggedOuro,
 )
 from deepspeed_tpu.inference.v2.model_implementations.ragged_qwen3_next import (
     Qwen3NextConfig,
@@ -59,9 +63,10 @@ HF_MODELS = {
     "deepseek_v3": (RaggedDeepseekV3, True),
     "lfm2_moe": (RaggedLfm2, False),
     "afmoe": (RaggedAfmoe, False),
+    "ouro": (RaggedOuro, False),
 }
 
 __all__ = ["AfmoeConfig", "DeepseekV3Config", "HF_MODELS", "RaggedAfmoe",
-           "RaggedDeepseekV3", "Lfm2Config", "Qwen3NextConfig", "RaggedLfm2", "RaggedLlama", "RaggedMistral", "RaggedMixtral",
-           "RaggedOPT", "RaggedFalcon", "RaggedQwen3Next",
+           "RaggedDeepseekV3", "Lfm2Config", "OuroConfig", "Qwen3NextConfig", "RaggedLfm2", "RaggedLlama", "RaggedMistral", "RaggedMixtral",
+           "RaggedOPT", "RaggedFalcon", "RaggedOuro", "RaggedQwen3Next",
            "ragged_param_specs", "shard_ragged_params"]

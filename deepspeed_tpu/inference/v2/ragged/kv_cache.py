@@ -46,6 +46,21 @@ allocator and each sequence's second block table; every other KV layer keeps
 serve is :data:`WINDOW_GROUP`; behind those, the block operations that move
 one block id across every layer (``copy_block``, ``gather_blocks``,
 ``scatter_blocks``) refuse: an id names different rows in the two groups.
+
+**A cache per (layer, pass)** (``passes``, from a model's ``kv_passes``: a
+stack of layers run several times a token, each pass with keys and values of
+its own).  The pools keep their names and their form and grow ``passes``
+times as long: ``[passes * num_blocks * block_size, ...]``, pass ``t`` in the
+rows from ``t * num_blocks * block_size``.  One allocator and one block table
+a sequence serve every pass, because a block id names the same offset in
+each: pass ``t`` of a step program reads through ``block_tables + t *
+num_blocks`` and writes at ``kv_dest + t * num_blocks * block_size``, and
+both paged kernels read the pool as it lies.  A block is then ``passes *
+block_size`` rows a layer (:attr:`BlockedKVCache.block_rows`), and every
+block operation moves them all, pass after pass within a block, so a payload
+still splits by block.  What it cannot serve is :data:`PASS_CACHES`; a
+window group beside it is refused (no model states both: a pass's offset
+would differ between the two pools).
 """
 
 from __future__ import annotations
@@ -160,11 +175,17 @@ WINDOW_GROUP = ("keeps window and global KV layers behind two block tables "
 })
 
 
+#: one cache per (layer, pass) (``kv_passes``): every feature is served, the
+#: block operations move a block's rows in every pass (the module doc)
+PASS_CACHES = ("keeps one cache per (layer, pass) behind one block table a "
+               "sequence (kv_passes)", {})
+
+
 class BlockedKVCache:
     def __init__(self, num_layers: int, num_blocks: int, block_size: int,
                  num_kv_heads: int, head_dim: int, dtype: Any = jnp.bfloat16,
                  kv_layers=None, kv_row=None, window_layers=(),
-                 window_blocks: int = 0):
+                 window_blocks: int = 0, passes: int = 1):
         #: the layers that hold keys and values (all of them, unless the
         #: model says which: its other layers keep state in slots, see
         #: ``state_pool.py``, and their leaves join ``cache`` beside these)
@@ -176,6 +197,8 @@ class BlockedKVCache:
         #: blocks; none unless the model states ``kv_groups``
         self.window_layers = tuple(window_layers)
         self.window_blocks = int(window_blocks)
+        #: caches a layer keeps, one a pass of the stack (the module doc)
+        self.passes = int(passes)
         self.block_size = block_size
         self.num_kv_heads = num_kv_heads
         self.head_dim = head_dim
@@ -187,8 +210,8 @@ class BlockedKVCache:
         #: model that states one (see the module doc); None: k and v
         self.kv_row = dict(kv_row) if kv_row else None
         def layer(i):
-            rows = block_size * (self.window_blocks
-                                 if i in self.window_layers else num_blocks)
+            rows = block_size * (self.window_blocks if i in self.window_layers
+                                 else self.passes * num_blocks)
             if self.kv_row:
                 return {name: jnp.zeros((rows, lanes), dtype)
                         for name, lanes in self.kv_row.items()}
@@ -221,7 +244,8 @@ class BlockedKVCache:
         geometry — src/dst are traced scalars, so forking different blocks
         never recompiles; the old cache is donated (in-place on device)."""
         self._update_pools(_copy_block(
-            self._pools(), jnp.int32(src), jnp.int32(dst), self.block_size))
+            self._pools(), jnp.int32(src), jnp.int32(dst), self.block_size,
+            self._pass_rows))
 
     def _pools(self) -> Dict[str, Dict[str, jax.Array]]:
         """The KV layers of ``cache``: block operations move pool rows and
@@ -238,22 +262,38 @@ class BlockedKVCache:
     def _update_pools(self, pools) -> None:
         self.cache = {**self.cache, **pools}
 
+    @property
+    def block_rows(self) -> int:
+        """Rows of a layer's pool one block names: ``block_size`` in every
+        pass; what a block is in a :meth:`gather_blocks` payload."""
+        return self.passes * self.block_size
+
+    @property
+    def _pass_rows(self) -> tuple:
+        """First pool row of each pass."""
+        return tuple(t * self.num_blocks * self.block_size
+                     for t in range(self.passes))
+
     def _block_rows(self, blocks) -> "jax.Array":
-        """Flat pool row indices covering ``blocks`` in table order."""
+        """Flat pool row indices covering ``blocks`` in table order, each
+        block's rows in pass 0, then in pass 1, ..."""
         import numpy as np
 
-        base = np.asarray(blocks, np.int32)[:, None] * self.block_size
+        base = np.asarray(blocks, np.int32)[:, None, None] * self.block_size \
+            + np.asarray(self._pass_rows, np.int32)[None, :, None]
         return jnp.asarray(
             (base + np.arange(self.block_size, dtype=np.int32)).ravel())
 
     def gather_blocks(self, blocks) -> Dict[str, Dict[str, Any]]:
         """Pull the KV rows of ``blocks`` (one sequence's block table) to
-        the host: ``{layer: {"k"/"v": np[len(blocks)*block_size, Hkv*D]}}``
+        the host: ``{layer: {"k"/"v": np[len(blocks)*block_rows, Hkv*D]}}``
         (each leaf's rows as the pool stores them).
         One device gather + one transfer for the whole tree — the
         disaggregated prefill→decode handoff payload.  Row order follows
         the block table, so position ``p`` lives at row ``p`` regardless
-        of which physical blocks held it."""
+        of which physical blocks held it (with ``passes`` caches a layer,
+        block ``i`` of the table is rows ``[i, i + 1) * block_rows``, pass
+        ``t`` of it from ``t * block_size``)."""
         rows = self._block_rows(blocks)
         return jax.device_get(
             jax.tree_util.tree_map(lambda a: a[rows], self._pools()))
@@ -285,12 +325,13 @@ class BlockedKVCache:
         the fp32 scale record per (row, head), so occupancy gauges and the
         roofline decode bytes model never over-report bf16 bytes under
         quantization."""
-        return self.layer_token_bytes * (len(self.kv_layers)
-                                         - len(self.window_layers))
+        return self.passes * self.layer_token_bytes * (
+            len(self.kv_layers) - len(self.window_layers))
 
     @property
     def layer_token_bytes(self) -> int:
-        """HBM bytes one cached token occupies in ONE KV layer."""
+        """HBM bytes one cached token occupies in ONE cache of one KV
+        layer (a layer keeps ``passes`` of them)."""
         itemsize = jnp.dtype(self.dtype).itemsize
         if self.kv_row:
             return sum(self.kv_row.values()) * itemsize
@@ -306,12 +347,14 @@ class BlockedKVCache:
                 * self.block_size * self.layer_token_bytes)
 
 
-@partial(jax.jit, static_argnums=(3,), donate_argnums=(0,))
-def _copy_block(cache, src, dst, block_size: int):
+@partial(jax.jit, static_argnums=(3, 4), donate_argnums=(0,))
+def _copy_block(cache, src, dst, block_size: int, pass_rows=(0,)):
     def one(arr):
-        rows = jax.lax.dynamic_slice_in_dim(arr, src * block_size,
-                                            block_size, axis=0)
-        return jax.lax.dynamic_update_slice_in_dim(arr, rows,
-                                                   dst * block_size, axis=0)
+        for first in pass_rows:     # the block's rows in each pass
+            rows = jax.lax.dynamic_slice_in_dim(
+                arr, first + src * block_size, block_size, axis=0)
+            arr = jax.lax.dynamic_update_slice_in_dim(
+                arr, rows, first + dst * block_size, axis=0)
+        return arr
 
     return jax.tree_util.tree_map(one, cache)

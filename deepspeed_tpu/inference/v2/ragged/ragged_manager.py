@@ -42,8 +42,16 @@ for 32 at W = 4,096, a budget of 1,024 and blocks of 128: 34.2 each).
 More sequences than that can be tracked, so admission still
 counts (:meth:`window_blocks_needed`).
 
+**A cache per (layer, pass)** (a model's ``kv_passes``: its stack of layers
+runs that many times a token, each pass over keys and values of its own).
+Nothing of the bookkeeping here changes: one allocator, one table a sequence,
+and a block id that names the same offset in every pass's part of a pool
+(``kv_cache.py``, the module doc); a block is ``passes`` times the bytes, which
+``per_token_bytes`` and the occupancy gauges count.
+
 **What a layout cannot serve** is said beside each extension's definition
-(``state_pool.STATE_SLOTS``, ``kv_cache.LATENT_ROW`` / ``WINDOW_GROUP``),
+(``state_pool.STATE_SLOTS``, ``kv_cache.LATENT_ROW`` / ``WINDOW_GROUP`` /
+``PASS_CACHES``),
 merged into :attr:`DSStateManager.unserved` and checked in ONE place,
 :meth:`DSStateManager.require`, by every path behind a feature.
 """
@@ -60,6 +68,7 @@ from deepspeed_tpu.inference.v2.ragged.blocked_allocator import BlockedAllocator
 from deepspeed_tpu.inference.v2.ragged.host_tier import HostKVTier
 from deepspeed_tpu.inference.v2.ragged.kv_cache import (FEATURES,
                                                         LATENT_ROW,
+                                                        PASS_CACHES,
                                                         WINDOW_GROUP,
                                                         BlockedKVCache,
                                                         CacheLayoutError,
@@ -78,7 +87,8 @@ class DSStateManager:
     def __init__(self, config: DSStateManagerConfig,
                  kv_config: KVCacheConfig,
                  num_layers: int, num_kv_heads: int, head_dim: int,
-                 dtype=None, state_spec=None, kv_row=None, kv_groups=None):
+                 dtype=None, state_spec=None, kv_row=None, kv_groups=None,
+                 kv_passes: int = 1):
         self.config = config
         self.kv_config = kv_config
         self.block_size = kv_config.block_size
@@ -93,7 +103,8 @@ class DSStateManager:
         self.unserved: Dict[str, str] = {}
         for keeps, cannot in [table for stated, table in (
                 (state_spec is not None, STATE_SLOTS), (kv_row, LATENT_ROW),
-                (kv_groups is not None, WINDOW_GROUP)) if stated]:
+                (kv_groups is not None, WINDOW_GROUP),
+                (kv_passes > 1, PASS_CACHES)) if stated]:
             for feature, why in cannot.items():
                 prior = self.unserved.get(feature)
                 self.unserved[feature] = (f"{prior} and " if prior else "") \
@@ -119,6 +130,12 @@ class DSStateManager:
         self.state_pool: Optional[StateSlotPool] = None
         if kv_row:          # the model states its pool row (latent attention)
             kwargs["kv_row"] = kv_row
+        if kv_passes > 1:   # one cache per (layer, pass): the module doc
+            if kv_groups is not None:
+                raise CacheLayoutError(
+                    "kv_passes beside kv_groups: a pass's offset would "
+                    "differ between the window pool and the global one")
+            kwargs["passes"] = kv_passes
         if state_spec is not None:
             self.state_pool = StateSlotPool(
                 config.max_ragged_sequence_count, state_spec["layers"],
@@ -424,7 +441,7 @@ class DSStateManager:
 
         cache = self.prefix_cache
         tier = self.host_tier
-        bs = self.block_size
+        bs = self.kv_cache.block_rows       # a block's rows in a payload
         # keys read the parent chains BEFORE anything else — evict()
         # guarantees they are intact at hook time
         keys = [cache.node_tokens(n) for n in nodes]

@@ -193,6 +193,17 @@ Device scopes (``jax.named_scope``) are opened where the layer is written:
 ``attn/*`` in ``inference/v2/modules/attention.py``, ``moe/router`` and
 ``moe/shared`` in ``modules/moe.py``, the other ``moe/*`` in ``ops/
 grouped_gemm.py``, a family's own in ``model_implementations/ragged_*.py``.
+A stack of layers that runs several times a token (``ragged_ouro.py``) is
+ONE loop in the step program: its layers keep the names above under one
+enclosing scope, ``loop/layers_<i>/attn/qkv`` ... ``loop/layers_<i>/mlp``,
+whatever the pass, and the norm between the passes is ``pass_norm``, so a
+reader that searches ``/attn/dense_read/`` finds every pass's and one that
+searches ``/loop/`` tells the looped body from ``embed`` and ``lm_head``.
+Such an engine's dispatch spans (``engine/ragged_step``,
+``engine/decode_step``) close with ``passes`` and ``cache_layers`` beside
+``launch`` / ``program``, and with what the launch asks of every cache
+layer: ``loop_seqs``, ``loop_tokens``, ``loop_ctx_tokens``,
+``loop_attn_pairs``.
 
 Host↔device alignment, one rule: a span opened with :meth:`Tracer.span`
 is ALSO entered as a ``jax.profiler.TraceAnnotation`` of the same name

@@ -1,5 +1,5 @@
 """The one table of what a cache layout cannot serve (PR 42): each extension
-a model states (``state_spec``, ``kv_row``, ``kv_groups``) lists, where it is
+a model states (``state_spec``, ``kv_row``, ``kv_groups``, ``kv_passes``) lists, where it is
 defined, the features it cannot serve and why; ``DSStateManager`` merges
 them and ``require(feature, path)`` is the one check.  This walks the table:
 every (extension, feature) entry refuses at every path behind the feature,
@@ -17,6 +17,7 @@ from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
                                         RaggedInferenceEngineConfig)
 from deepspeed_tpu.inference.v2.ragged import CacheLayoutError
 from deepspeed_tpu.inference.v2.ragged.kv_cache import (FEATURES, LATENT_ROW,
+                                                        PASS_CACHES,
                                                         WINDOW_GROUP)
 from deepspeed_tpu.inference.v2.ragged.state_pool import STATE_SLOTS
 from deepspeed_tpu.serving import ContinuousBatchScheduler, SpeculativeConfig
@@ -28,6 +29,9 @@ EXTENSIONS = {
     "kv_row": (LATENT_ROW, {"kv_row": {"ckv": 128}}),
     "kv_groups": (WINDOW_GROUP, {"kv_groups": {
         "window": {"layers": [0], "window": 16}}}),
+    # (a cache per (layer, pass): every block operation moves a block's rows
+    # in every pass, so its table refuses nothing)
+    "kv_passes": (PASS_CACHES, {"kv_passes": 3}),
 }
 
 
@@ -77,7 +81,8 @@ ENTRIES = [(ext, feature) for ext, (table, _) in EXTENSIONS.items()
 
 
 def test_the_tables_name_features_and_every_feature_has_a_path():
-    for _keeps, cannot in (STATE_SLOTS, LATENT_ROW, WINDOW_GROUP):
+    for _keeps, cannot in (STATE_SLOTS, LATENT_ROW, WINDOW_GROUP,
+                           PASS_CACHES):
         assert set(cannot) <= set(FEATURES)
     assert set(PATHS) == set(FEATURES)
 

@@ -107,7 +107,8 @@ for entry in bench["configs"]:
                 "max_context": sv["max_context"]},
             "kv_cache": {"block_size": bs, "num_blocks": 4}}))
     kv = eng.state_manager.kv_cache
-    rows = int(sv["kv_pool_blocks"]) * bs
+    # (a layer keeps ``kv.passes`` caches in one pool: kv_cache.py)
+    rows = getattr(kv, "passes", 1) * int(sv["kv_pool_blocks"]) * bs
     pooled = {f"layer_{i}" for i in kv.kv_layers}
     window = {f"layer_{i}" for i in kv.window_layers}
     # the global group's pools at the cell's size; the window group's as the
