@@ -968,7 +968,12 @@ def kernels_phase(_sizes, _devices, _require_chip,
             # share held -> [blocks, decode walk us, dense XLA read us,
             # least us: the held bytes at 819 GB/s]
             "decode_read_us": {k: v["us"] for k, v in cases.items()
-                               if "us" in v},
+                               if "us" in v
+                               and not k.startswith("paged_prefill_")},
+            # cell -> first position of the chunk -> [tiled chunk read us,
+            # least us: the visible pairs' dots at 197 TFLOP/s]
+            "prefill_us": {k: v["us"] for k, v in cases.items()
+                           if "us" in v and k.startswith("paged_prefill_")},
             # cell.call -> shape, tiles, live units of units, us, least us
             "gmm_share": cases.get("gmm_share", {}).get("calls"),
             **clock.take()}

@@ -637,6 +637,10 @@ class InferenceEngineV2:
         if self._grouped:
             attrs.update(self._window_counters(
                 [s for s, n in rows if n == 1], tiled))
+        tile = self._prefill_tile()
+        if tile and tiled and not latent and not sm.kv_cache.quantized:
+            attrs.update(self._chunk_step_counters(
+                tiled, (bucket - self._batch.max_seqs) // tile, tile))
         # what the one-token read (the decode walk, the absorbed read) must
         # do: the table blocks the batch's one-token rows hold up to the
         # position they feed
@@ -925,6 +929,39 @@ class InferenceEngineV2:
             out["attn_pairs_win"] = sum(
                 r * (2 * a + r + 1) // 2 + (n - r) * win
                 for (a, n), r in zip(chunks, ramps))
+        return out
+
+    def _chunk_step_counters(self, chunks, tiles: int,
+                             tile: int) -> Dict[str, int]:
+        """How the tiled chunk read's grid fits what this batch's chunks
+        (``chunks``: (start, tokens), in ``tiles`` tiles of the bucket) can
+        see, on the ``engine/build_batch`` span: ``chunk_key_steps``, the key
+        steps the grid of ``paged_prefill_attention`` runs, summed over
+        tiles, attention layers and passes, and ``chunk_live_key_steps``,
+        those of them that hold a visible key.  A model with window layers
+        adds the window layers' share of each (``..._win``).  Host
+        arithmetic on the kernel's own rule (``prefill_key_steps``)."""
+        from deepspeed_tpu.inference.v2.kernels import prefill_key_steps
+
+        sm, cfg = self.state_manager, self.model.config
+        cache = sm.kv_cache
+        shape = dict(group=cfg.num_attention_heads // self.model.num_kv_heads,
+                     block_size=sm.block_size, entries=self._max_blocks,
+                     tile_q=tile)
+        layers = {getattr(cfg, "sliding_window", None):
+                  len(cache.kv_layers) * self._passes}
+        if self._grouped:
+            layers = {None: len(cache.kv_layers) - len(cache.window_layers),
+                      sm.window: len(cache.window_layers)}
+        out = dict.fromkeys(("chunk_key_steps", "chunk_live_key_steps"), 0)
+        for window, count in layers.items():
+            steps, live = prefill_key_steps(chunks, tiles, window=window,
+                                            **shape)
+            out["chunk_key_steps"] += count * steps
+            out["chunk_live_key_steps"] += count * live
+            if self._grouped and window is not None:
+                out.update(chunk_key_steps_win=count * steps,
+                           chunk_live_key_steps_win=count * live)
         return out
 
     def _recover_donated_cache(self) -> None:

@@ -7,6 +7,7 @@ from deepspeed_tpu.inference.v2.kernels.blocked_flash import (
     paged_decode_attention,
     paged_prefill_attention,
     paged_verify_attention,
+    prefill_key_steps,
 )
 
 from deepspeed_tpu.inference.v2.kernels.latent_flash import (
@@ -19,4 +20,4 @@ __all__ = ["decode_walk_usable", "latent_decode_attention", "latent_expand",
            "latent_prefill_attention", "paged_attention",
            "paged_attention_usable",
            "paged_decode_attention", "paged_prefill_attention",
-           "paged_verify_attention"]
+           "paged_verify_attention", "prefill_key_steps"]

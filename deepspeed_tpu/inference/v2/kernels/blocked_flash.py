@@ -25,8 +25,10 @@ Four kernels, by the shape of the rows they serve (the route is
 * ``_prefill_kernel`` — every ``put`` forward of an engine whose token
   budget is a whole number of tiles, mixed ticks included: the engine packs
   each chunk longer than one token tile-aligned (``RaggedBatchWrapper.
-  set_alignment``), so the grid is (tiles, blocks) with bf16 MXU dots on
-  [tile, D] x [block, D];
+  set_alignment``), so the grid is (tiles, key steps): a step meets a run
+  of table entries counted from the tile's first live block, a KV head's
+  whole group of query heads in one pair of MXU dots and one online-softmax
+  update (the note above the kernel);
 * ``_decode_kernel`` — one token a row (a decode step, or the single-token
   rows of such a forward) on a pool the DMA walk can copy
   (``decode_walk_usable``: a float pool in the flat row ``[rows, Hkv*D]``
@@ -799,81 +801,231 @@ def paged_verify_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
 # TILE-ALIGNED in the tiled segment of the token buffer (single-token
 # chunks — the decodes of a mixed tick — sit in their own rows in front of
 # it and never reach this kernel), so every [tile_q]-row stripe belongs to
-# one sequence (pad rows carry position -1 and mask to zero) — the grid is
-# (tiles, blocks), not (tokens, blocks): a 512-token prefill at tile 128
-# runs 4xB steps instead of 512xB.
+# one sequence (pad rows carry position -1 and mask to zero).
+#
+# The grid is (tiles, key steps).  A key step is a RUN of ``kb`` consecutive
+# table entries (``_prefill_step_blocks``; the blocks lie anywhere in the
+# pool, so a step has ``kb`` block specs a stream, each with its own index
+# map), counted from the tile's FIRST LIVE block, which rides in SMEM beside
+# the tile's other scalars: block 0 on a layer that sees every key, the
+# block of the lowest key inside the band on a window layer.  The key axis is
+# therefore as long as a tile's band can be, not as the table: on a window
+# layer ``ceil(band / kb)`` steps with ``band = ceil((window + tile_q) /
+# block_size) + 1`` blocks (``_prefill_extent``) whatever ``max_context``
+# is; without a window ``ceil(entries / kb)``, and the steps past a tile's
+# last block name the blocks of its last live step again, so the pipeline
+# moves nothing for them.
+#
+# A live step takes the KV heads one after the other (a ``fori_loop`` over the
+# row's lane tiles: the body is compiled once, not once a head).  The query
+# heads of a KV head are stacked to ``[g * tile_q, D]`` once a tile, so a step
+# reads that head's ``[kb * bs, D]`` keys and values ONCE for its whole group
+# (the MXU streams ``g * tile_q`` rows a weight tile, not ``tile_q``) and
+# makes ONE online-softmax update (row max, correction, ``l``, ``m``, the
+# accumulator's rescale) for all ``kb * bs`` keys.  That update crosses the
+# lanes once a row, for the maximum; everything else of it is lane-wise (the
+# note on ``w`` in the kernel): crossed for the sum, the correction and the
+# rescale too, the cross-lane unit and neither the MXU nor the VPU bounds the
+# step (measured: 3.5 us a (KV head, step) whatever ``kb``, against 1.5).  The
+# mask is built only on steps that hold an edge of the band (the causal
+# diagonal, the window's lower edge, a pad row, a table entry past the tile's
+# last); the steps inside it run the bare update.  Statistics and accumulator
+# are float32, the dots take the pool's dtype into float32, the scale
+# multiplies the float32 scores (on ``q`` it would cost the bfloat16 queries
+# half their precision for 2-4% of the kernel), ``p`` is cast to the pool's
+# dtype for PV.
 # ===================================================================== #
 #: scratch and blocks of the tiled kernel the default scoped limit holds
 #: with room for the compiler's own temporaries, and what is added to a
 #: larger need when the limit is raised to it
 _PREFILL_VMEM_BUDGET = 14 << 20
 _PREFILL_VMEM_HEADROOM = 8 << 20
+#: keys a step of the tiled kernel meets where the shapes allow it
+_PREFILL_STEP_KEYS = 512
+#: bytes of ONE float32 score tile ``[g * tile_q, keys]`` a step may hold
+#: (the compiler keeps about three of that size: scores, probabilities and
+#: their cast)
+_PREFILL_SCORE_BYTES = 2 << 20
 
 
-def _prefill_kernel(tile_slot, tile_maxpos, tables, q_ref, pos_ref, k_ref,
-                    v_ref, o_ref, acc_ref, m_ref, l_ref, *, block_size,
-                    num_blocks_per_seq, scale, tile_q, num_heads,
-                    num_kv_heads, window):
+def _prefill_extent(entries: int, window: Any, tile_q: int,
+                    block_size: int) -> int:
+    """Table entries one tile's rows can see: the table's, or on a window
+    layer the band's, the ``window + tile_q - 1`` keys wherever they start
+    in a block."""
+    if window is None:
+        return entries
+    return min(entries, -(-(window + tile_q) // block_size) + 1)
+
+
+def _prefill_step_blocks(group: int, block_size: int, entries: int,
+                         window: Any, tile_q: int) -> int:
+    """Table entries a key step of the tiled kernel meets (``kb``), from the
+    shapes alone: ``_PREFILL_STEP_KEYS`` keys a step, fewer where one
+    float32 score tile of a KV head's whole group ``[group * tile_q, keys]``
+    would pass ``_PREFILL_SCORE_BYTES``, never more than the table (or a
+    window layer's band) holds.  Four at every serving cell's shapes (block
+    128, groups of 1 to 8, tile 128): a window layer's band of 34 blocks is
+    nine steps, the last with two entries past it.  Measured on a v5e at
+    Trinity's window layer (48 / 8 heads of 128; PERF.md section 6, PR 44),
+    microseconds a call by ``kb``: 2: 1,058, 3: 1,377, 4: **869**, 6: 1,002,
+    8: 986, 12: 920, 16: 1,168 (a step's fixed work no longer sets the time,
+    a longer step only puts more entries past the band's end)."""
+    keys = min(_PREFILL_STEP_KEYS,
+               _PREFILL_SCORE_BYTES // (4 * group * tile_q))
+    return max(1, min(keys // block_size,
+                      _prefill_extent(entries, window, tile_q, block_size)))
+
+
+def _prefill_key_steps(entries: int, window: Any, tile_q: int,
+                       block_size: int, kb: int) -> int:
+    """The static length of the tiled kernel's key axis."""
+    return -(-_prefill_extent(entries, window, tile_q, block_size) // kb)
+
+
+def prefill_key_steps(chunks, tiles: int, *, group: int, block_size: int,
+                      entries: int, window: Any, tile_q: int) -> tuple:
+    """(key steps the grid of ONE ``paged_prefill_attention`` call runs,
+    those of them that hold a visible key) for a tiled segment of ``tiles``
+    tiles that holds ``chunks`` ((start, tokens) each, tile-aligned): host
+    arithmetic on the same rule the call takes its step from, for the
+    ``engine/build_batch`` span."""
+    kb = _prefill_step_blocks(group, block_size, entries, window, tile_q)
+    live = 0
+    for start, tokens in chunks:
+        for lo in range(start, start + tokens, tile_q):
+            maxpos = min(lo + tile_q, start + tokens) - 1
+            last = maxpos // block_size
+            first, seen = 0, 0
+            if window is not None:
+                first = min(max(
+                    (maxpos - tile_q - window + 1) // block_size, 0), last)
+                seen = max(lo - window + 1, 0) // block_size
+            live += (last - first) // kb - (seen - first) // kb + 1
+    return tiles * _prefill_key_steps(entries, window, tile_q, block_size,
+                                      kb), live
+
+
+def _prefill_kernel(tile_slot, tile_first, tile_steps, tile_minpos,
+                    tile_maxpos, tables, q_ref, pos_ref, *refs, block_size,
+                    scale, tile_q, num_heads, num_kv_heads, window, kb):
+    k_refs, v_refs = refs[:kb], refs[kb:2 * kb]
+    o_ref, qs_ref, acc_ref, m_ref, l_ref = refs[2 * kb:]
     t = pl.program_id(0)
     j = pl.program_id(1)
     g = num_heads // num_kv_heads
+    d = q_ref.shape[1] // num_heads
+    rows, keys = g * tile_q, kb * block_size
+    # the KV heads a loop step takes: one of whole lane tiles, the heads
+    # side by side in one tile (D = 64: two), or, on a row that is no whole
+    # tiles (the CPU's small shapes), every head of the row
+    lanes = num_kv_heads * d
+    if d % 128 == 0:
+        unit = d
+    elif lanes % 128 == 0 and 128 % d == 0:
+        unit = 128
+    else:
+        unit = lanes
+    pack = unit // d
 
     @pl.when(j == 0)
     def _():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        # the query heads of a KV head stacked on the rows
+        for h in range(num_heads):
+            qs_ref[h // g, (h % g) * tile_q:(h % g + 1) * tile_q, :] = \
+                q_ref[:, h * d:(h + 1) * d]
 
-    maxpos = tile_maxpos[t]
-    run = jnp.logical_and(j * block_size <= maxpos, maxpos >= 0)
+    k0 = (tile_first[t] + j * kb) * block_size        # the step's first key
+    live = j < tile_steps[t]
+    # every row of the tile sees every key of the step: no pad row (their
+    # position is -1), the step's last key at or under the lowest position,
+    # its first inside the highest position's window
+    inside = k0 + keys - 1 <= tile_minpos[t]
     if window is not None:
-        # the whole tile is below the window band for this block -> skip
-        run = jnp.logical_and(
-            run, (j + 1) * block_size - 1 > maxpos - tile_q - window)
+        inside = jnp.logical_and(inside, k0 > tile_maxpos[t] - window)
 
-    @pl.when(run)
-    def _():
-        pos = pos_ref[:, :1]                          # [tile_q, 1] (-1 pads)
-        key_pos = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (tile_q, block_size), 1)
-        keep = key_pos <= pos
-        if window is not None:
-            keep = jnp.logical_and(keep, key_pos > pos - window)
-        for h in range(num_heads):
-            # flattened-lane per-head slices (static offsets): a 4D
-            # [:, h, :] access needs a 2D<->3D vector reshape that
-            # Mosaic's infer-vector-layout rejects at some (tile, d)
-            # combos ("unsupported shape cast")
-            d = q_ref.shape[1] // num_heads
-            q = q_ref[:, h * d:(h + 1) * d]           # [tile_q, d]
-            kb = k_ref[0][:, (h // g) * d:(h // g + 1) * d]   # [bs, d]
-            vb = v_ref[0][:, (h // g) * d:(h // g + 1) * d]
-            s = jax.lax.dot_general(
-                q, kb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale
-            s = jnp.where(keep, s, NEG_INF)
-            m_prev = m_ref[h, :, :1]
-            m_cur = jnp.max(s, axis=1, keepdims=True)
-            m_new = jnp.maximum(m_prev, m_cur)
-            p = jnp.exp(s - m_new)
-            p = jnp.where(keep, p, 0.0)  # all-masked rows: exp(0) == 1
-            corr = jnp.exp(m_prev - m_new)
-            l_ref[h] = jnp.broadcast_to(
-                l_ref[h, :, :1] * corr + jnp.sum(p, axis=1, keepdims=True),
-                l_ref[h].shape)
-            acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
-                p.astype(vb.dtype), vb, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_ref[h] = jnp.broadcast_to(m_new, m_ref[h].shape)
+    # the statistics of a row, ``w`` lanes wide.  128 (a step's keys are whole
+    # lane tiles): ``m`` stands in every lane and ``l`` is kept as 128 partial
+    # sums, a lane each, so a step folds its keys' lane tiles into one with
+    # plain vector operations and crosses the lanes ONCE, for the row's
+    # maximum (the cross-lane unit, not the MXU or the VPU, bounds a step
+    # that crosses them for the sum, the correction and the rescale too:
+    # PERF.md section 6, PR 44); ``l`` is summed over its lanes once a tile.
+    # 1 (the CPU's small blocks): the plain column.
+    w = m_ref.shape[-1]
 
-    @pl.when(j == num_blocks_per_seq - 1)
+    def fold(x, op, over_row):
+        """[rows, keys] -> [rows, w]: ``op`` over the keys' lane tiles (w
+        1: ``over_row``, the same reduction over the whole row)."""
+        if w == 1:
+            return over_row(x, axis=1, keepdims=True)
+        return functools.reduce(
+            op, [x[:, c * w:(c + 1) * w] for c in range(keys // w)])
+
+    def spread(x, n):
+        """[rows, w] -> [rows, n]: the row's value in every lane."""
+        if w == 1 or n == w:
+            return x
+        return x[:, :n] if n < w else jnp.tile(x, (1, n // w))
+
+    def step(masked):
+        if masked:
+            pos = jnp.concatenate([pos_ref[:, :1]] * g, axis=0)   # [rows, 1]
+            key_pos = k0 + jax.lax.broadcasted_iota(
+                jnp.int32, (rows, keys), 1)
+            keep = key_pos <= pos
+            if window is not None:
+                keep = jnp.logical_and(keep, key_pos > pos - window)
+
+        def heads(i):
+            lane = slice(None) if unit == lanes else pl.ds(
+                pl.multiple_of(i * unit, unit), unit)
+            ku = jnp.concatenate([r[0, :, lane] for r in k_refs], axis=0)
+            vu = jnp.concatenate([r[0, :, lane] for r in v_refs], axis=0)
+            for r in range(pack):
+                head = i * pack + r
+                kh = ku[:, r * d:(r + 1) * d] if pack > 1 else ku
+                vh = vu[:, r * d:(r + 1) * d] if pack > 1 else vu
+                s = jax.lax.dot_general(
+                    qs_ref[head], kh, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                if masked:
+                    s = jnp.where(keep, s, NEG_INF)
+                m_prev = m_ref[head]                          # [rows, w]
+                m_new = jnp.maximum(m_prev, jnp.max(
+                    fold(s, jnp.maximum, jnp.max), axis=1, keepdims=True))
+                p = jnp.exp(s - spread(m_new, keys))
+                if masked:
+                    p = jnp.where(keep, p, 0.0)  # all-masked rows: exp(0)
+                corr = jnp.exp(m_prev - m_new)
+                l_ref[head] = l_ref[head] * corr + fold(p, jnp.add, jnp.sum)
+                acc_ref[head] = acc_ref[head] * spread(corr, d) \
+                    + jax.lax.dot_general(
+                        p.astype(vh.dtype), vh, (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+                m_ref[head] = m_new
+
+        if unit == lanes:
+            heads(0)
+        else:
+            jax.lax.fori_loop(0, lanes // unit,
+                              lambda i, c: (heads(i), c)[1], 0)
+
+    pl.when(jnp.logical_and(live, inside))(lambda: step(False))
+    pl.when(jnp.logical_and(live, jnp.logical_not(inside)))(
+        lambda: step(True))
+
+    @pl.when(j == pl.num_programs(1) - 1)
     def _():
-        d = q_ref.shape[1] // num_heads
         for h in range(num_heads):
-            l = l_ref[h, :, :1]
-            safe_l = jnp.where(l == 0.0, 1.0, l)
-            o_ref[:, h * d:(h + 1) * d] = (acc_ref[h]
-                                           / safe_l).astype(o_ref.dtype)
+            own = slice((h % g) * tile_q, (h % g + 1) * tile_q)
+            l = jnp.sum(l_ref[h // g, own, :], axis=1, keepdims=True)
+            o_ref[:, h * d:(h + 1) * d] = (
+                acc_ref[h // g, own, :]
+                / jnp.where(l == 0.0, 1.0, l)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -893,6 +1045,11 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     The pool in the flat row [rows, Hkv*D] it is stored in: the kernel's
     [blocks, bs, Hkv*D] is then a free split of the leading dimension (a
     [rows, Hkv, D] pool is taken too, through a copy of it on the chip).
+
+    The grid is (tiles, key steps): a step meets ``kb`` consecutive table
+    entries (``_prefill_step_blocks``) counted from the tile's first live
+    block, so a window layer runs the steps its band needs and no more,
+    whatever the table's width (the note above ``_prefill_kernel``).
     """
     t_count, h, d = q.shape
     hkv = pool_kv_heads(k_pool, d)
@@ -908,50 +1065,69 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     kp = k_pool.reshape(nb, block_size, hkv * d)
     vp = v_pool.reshape(nb, block_size, hkv * d)
     scale = 1.0 / (d ** 0.5)
+    kb = _prefill_step_blocks(h // hkv, block_size, b_per_seq, window,
+                              tile_q)
+    steps = _prefill_key_steps(b_per_seq, window, tile_q, block_size, kb)
 
-    # per-tile metadata (XLA-land, cheap): the stripe's slot + max position
+    # per-tile metadata (XLA-land, cheap): the stripe's slot, its highest
+    # and lowest position (-1: a pad row), the first and last table entry
+    # its rows see and the key steps between them (none on a tile of pads)
+    pos = token_pos.reshape(nt, tile_q).astype(jnp.int32)
     tile_slot = token_slot.reshape(nt, tile_q)[:, 0].astype(jnp.int32)
-    tile_maxpos = token_pos.reshape(nt, tile_q).max(axis=1).astype(jnp.int32)
+    tile_maxpos, tile_minpos = pos.max(axis=1), pos.min(axis=1)
+    last = jnp.maximum(tile_maxpos, 0) // block_size
+    tile_first = jnp.zeros_like(last)
+    if window is not None:
+        tile_first = jnp.clip(
+            (tile_maxpos - tile_q - window + 1) // block_size, 0, last)
+    tile_steps = jnp.where(tile_maxpos >= 0, (last - tile_first) // kb + 1,
+                           0)
     pos8 = jnp.broadcast_to(token_pos.astype(jnp.int32)[:, None],
                             (t_count, 8))
 
-    def _kv_index(t, j, slot, maxpos, tab):
-        jj = jnp.minimum(j, jnp.maximum(maxpos[t], 0) // block_size)
-        if window is not None:
-            lo = jnp.maximum(
-                (maxpos[t] - tile_q - window + 1) // block_size, 0)
-            jj = jnp.maximum(jj, jnp.minimum(
-                lo, jnp.maximum(maxpos[t], 0) // block_size))
-        return (tab[slot[t], jj], 0, 0)
+    # lanes of a row's softmax statistics (the note in the kernel)
+    stat_lanes = 128 if (kb * block_size) % 128 == 0 and (
+        d <= 128 or d % 128 == 0) else 1
 
+    def kv_spec(u):
+        def index(t, j, slot, first, n, lo, hi, tab):
+            # a step past the tile's last live one names that one's blocks
+            # again; an entry past the tile's last block names that block
+            # (its keys are masked by position)
+            jj = jnp.minimum(j, jnp.maximum(n[t] - 1, 0))
+            return (tab[slot[t], jnp.minimum(
+                first[t] + jj * kb + u,
+                jnp.maximum(hi[t], 0) // block_size)], 0, 0)
+        return pl.BlockSpec((1, block_size, hkv * d), index)
+
+    tile = pl.BlockSpec((tile_q, h * d), lambda t, j, *_: (t, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(nt, b_per_seq),
-        in_specs=[
-            pl.BlockSpec((tile_q, h * d),
-                         lambda t, j, slot, maxpos, tab: (t, 0)),
-            pl.BlockSpec((tile_q, 8),
-                         lambda t, j, slot, maxpos, tab: (t, 0)),
-            pl.BlockSpec((1, block_size, hkv * d), _kv_index),
-            pl.BlockSpec((1, block_size, hkv * d), _kv_index),
-        ],
-        out_specs=pl.BlockSpec((tile_q, h * d),
-                               lambda t, j, slot, maxpos, tab: (t, 0)),
+        num_scalar_prefetch=6,
+        grid=(nt, steps),
+        in_specs=[tile, pl.BlockSpec((tile_q, 8), lambda t, j, *_: (t, 0))]
+        + [kv_spec(u) for u in range(kb)] * 2,
+        out_specs=tile,
         scratch_shapes=[
-            pltpu.VMEM((h, tile_q, d), jnp.float32),
-            pltpu.VMEM((h, tile_q, 128), jnp.float32),
-            pltpu.VMEM((h, tile_q, 128), jnp.float32),
+            pltpu.VMEM((hkv, h // hkv * tile_q, d), q.dtype),
+            pltpu.VMEM((hkv, h // hkv * tile_q, d), jnp.float32),
+            pltpu.VMEM((hkv, h // hkv * tile_q, stat_lanes), jnp.float32),
+            pltpu.VMEM((hkv, h // hkv * tile_q, stat_lanes), jnp.float32),
         ],
     )
     kernel = functools.partial(
-        _prefill_kernel, block_size=block_size,
-        num_blocks_per_seq=b_per_seq, scale=scale, tile_q=tile_q,
-        num_heads=h, num_kv_heads=hkv, window=window)
-    # the per-head accumulators and the double-buffered q / o / k / v
-    # blocks: 11 MB at 32 heads of 128, inside the compiler's default scoped
-    # limit (16 MiB); more heads (48: 16.4 MB) bring the limit they need
-    need = h * tile_q * (d + 256) * 4 + 2 * 2 * q.dtype.itemsize * (
-        tile_q * h * d + block_size * hkv * d)
+        _prefill_kernel, block_size=block_size, scale=scale, tile_q=tile_q,
+        num_heads=h, num_kv_heads=hkv, window=window, kb=kb)
+    # what the call holds: the double-buffered q / o blocks and the kb k / v
+    # blocks a stream, the stacked queries, the float32 accumulator and
+    # statistics of every head, and a KV head's score tiles (about three of
+    # them live at once).  13 MB at LFM2's 32 heads of 64, inside the
+    # compiler's default scoped limit (16 MiB); 19 MB at Mistral's 32 heads
+    # of 128 and 26 MB at Trinity's 48 bring the limit they need
+    size = q.dtype.itemsize
+    need = (2 * 2 * size * tile_q * h * d
+            + 2 * 2 * size * kb * block_size * hkv * d
+            + h * tile_q * (d * (size + 4) + 2 * 128 * 4)
+            + 3 * 4 * (h // hkv) * tile_q * kb * block_size)
     limit = {"compiler_params": pltpu.CompilerParams(
         vmem_limit_bytes=need + _PREFILL_VMEM_HEADROOM)} \
         if need > _PREFILL_VMEM_BUDGET else {}
@@ -960,8 +1136,8 @@ def paged_prefill_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((t_count, h * d), q.dtype),
         interpret=bool(interpret),
         **kernel_names(kernel, op_name=False), **limit,
-    )(tile_slot, tile_maxpos, block_tables.astype(jnp.int32), qf, pos8,
-      kp, vp)
+    )(tile_slot, tile_first, tile_steps, tile_minpos, tile_maxpos,
+      block_tables.astype(jnp.int32), qf, pos8, *[kp] * kb, *[vp] * kb)
     return out.reshape(t_count, h, d)
 
 
@@ -1158,7 +1334,9 @@ def _dslint_paged_verify_int8_case():
 
 @pallas_kernel_case("paged_prefill",
                     note="tile-aligned prefill at the shipped 125M "
-                         "serving geometry (6q/2kv heads, d=64)")
+                         "serving geometry (6q/2kv heads, d=64): a key step "
+                         "of the table's four entries, a block spec an entry "
+                         "and stream")
 def _dslint_paged_prefill_case():
     import numpy as np
 

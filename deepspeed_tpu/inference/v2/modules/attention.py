@@ -98,8 +98,12 @@ def _paged_attention(q, k_pool, v_pool, batch, block_size,
     the two-segment layout, ``RaggedBatchWrapper.set_alignment``) says the
     first S rows (S = the block table's height) are single-token rows and
     the rest whole tiles: the tiles go to the TILED kernel — grid (tiles,
-    blocks), bf16 MXU dots, the reference's atom_builder work-unit shape
-    — and the single-token rows to the read a decode step takes
+    key steps): a step is a run of table entries counted from the tile's
+    first live block (the band alone on a window layer, whatever the
+    table's width), a KV head's group of query heads stacked into one pair
+    of MXU dots and one online-softmax update; the reference's
+    atom_builder work-unit shape — and the single-token rows to the read a
+    decode step takes
     (:func:`_single_row_read`).  Without it (a token budget that is
     no whole number of tiles) the whole buffer goes to the token-grid
     kernel, grid (tokens, blocks).

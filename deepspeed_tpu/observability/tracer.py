@@ -122,7 +122,22 @@ span                     site                        parent    attrs (counters)
                                                                ``ctx_rows``
                                                                (their end
                                                                positions: rows
-                                                               to expand)
+                                                               to expand); a
+                                                               tiled engine with
+                                                               k / v pools:
+                                                               ``chunk_key_steps``
+                                                               (key steps the
+                                                               tiled read's grid
+                                                               runs, over tiles,
+                                                               layers, passes)
+                                                               and
+                                                               ``chunk_live_key_steps``
+                                                               (those that hold
+                                                               a visible key);
+                                                               with window
+                                                               layers their
+                                                               share of both,
+                                                               ``..._win``
 ``engine/upload``        ``engine.launch``: the      prefill   —
                          late rows' tokens into
                          the metadata, its ONE

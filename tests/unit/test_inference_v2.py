@@ -751,6 +751,158 @@ def test_tiled_prefill_kernel_window_matches_xla():
                                rtol=2e-5, atol=2e-5)
 
 
+# the edges of the tiled kernel's grid: key steps of four table entries
+# (block 128: the rule's own choice) counted from a tile's first live block.
+# name: (heads, KV heads, head size, table entries, window, tiles), a tile
+# (slot, first position, tokens) or None, a tile of pads
+_TILED_EDGES = {
+    # the band starts in the third entry of a step and ends in the third of
+    # the next; the last tile is short
+    "band_mid_step": (4, 2, 16, 16, 700,
+                      [(0, 1000, 128), (0, 1128, 128), (0, 1256, 44)]),
+    # positions under the window (every key visible) beside a tile far past it
+    "ramp_and_far": (4, 2, 16, 16, 300,
+                     [(0, 0, 128), (0, 128, 128), (1, 1500, 128)]),
+    # a step wholly inside the band (no mask) between two on its edges
+    "window_inside": (4, 2, 16, 16, 1100, [(0, 1400, 128)]),
+    "short_table": (4, 2, 16, 3, None, [(0, 100, 128), (0, 228, 72)]),
+    "short_table_window": (4, 2, 16, 3, 150, [(0, 100, 128), (0, 228, 72)]),
+    # fifteen live blocks beside one: three of the second tile's four steps
+    # do nothing
+    "two_lengths": (4, 2, 16, 16, None, [(0, 1800, 128), (1, 0, 40)]),
+    "pad_tile_between": (4, 2, 16, 8, None,
+                         [(0, 300, 128), None, (1, 0, 100)]),
+    "pad_tile_between_window": (4, 2, 16, 8, 200,
+                                [(0, 300, 128), None, (1, 600, 100)]),
+    "group_1": (2, 2, 16, 8, None, [(0, 200, 128), (0, 328, 128)]),
+    # two KV heads a lane tile, two tiles a row: the loop over lane tiles
+    "group_4_d64": (16, 4, 64, 8, 400, [(0, 500, 128), (0, 628, 128)]),
+    "group_1_d64": (4, 4, 64, 8, None, [(1, 0, 128), (0, 640, 128)]),
+    "group_6": (12, 2, 16, 8, None, [(0, 130, 128), (1, 0, 7)]),
+    # a KV head a lane tile: the loop over KV heads, six query heads each
+    "group_6_d128": (12, 2, 128, 8, 300, [(0, 600, 128)]),
+}
+
+
+@pytest.mark.parametrize("name", list(_TILED_EDGES))
+def test_tiled_prefill_kernel_edges_match_xla(name):
+    from deepspeed_tpu.inference.v2.kernels import (blocked_flash,
+                                                    paged_prefill_attention)
+    from deepspeed_tpu.inference.v2.modules.attention import (
+        _paged_attention)
+
+    h, hkv, d, entries, window, tiles = _TILED_EDGES[name]
+    bs = tile = 128
+    assert blocked_flash._prefill_step_blocks(
+        h // hkv, bs, entries, window, tile) == min(4, entries)
+    rng = np.random.default_rng(44)
+    nb = 2 * entries + 1
+    pool = lambda: jnp.asarray(
+        rng.normal(size=(nb * bs, hkv, d)).astype(np.float32))
+    k_pool, v_pool = pool(), pool()
+    # two sequences' blocks interleaved in the pool, block 0 the trash block
+    tables = jnp.asarray(
+        1 + rng.permutation(nb - 1).reshape(2, entries), jnp.int32)
+    slot = np.zeros((len(tiles) * tile,), np.int32)
+    pos = np.full((len(tiles) * tile,), -1, np.int32)
+    for i, chunk in enumerate(tiles):
+        if chunk is not None:
+            s, start, n = chunk
+            slot[i * tile:(i + 1) * tile] = s
+            pos[i * tile:i * tile + n] = np.arange(start, start + n)
+    q = jnp.asarray(rng.normal(size=(len(pos), h, d)).astype(np.float32))
+    batch = {"block_tables": tables, "token_slot": jnp.asarray(slot),
+             "token_pos": jnp.asarray(pos)}
+    ref = _paged_attention(q, k_pool, v_pool, batch, bs, use_kernel=False,
+                           window=window)
+    got = paged_prefill_attention(
+        q, k_pool, v_pool, tables, jnp.asarray(slot), jnp.asarray(pos),
+        block_size=bs, tile_q=tile, window=window)
+    np.testing.assert_allclose(np.asarray(got)[pos >= 0],
+                               np.asarray(ref)[pos >= 0],
+                               rtol=2e-5, atol=2e-5)
+    assert np.all(np.asarray(got)[pos < 0] == 0)  # pads: exact zeros
+    # the host's count of the grid: no step outside the table or the band,
+    # every tile with a row holds a live step, a tile of pads none
+    chunks = [(start, n) for c in tiles if c for _, start, n in [c]]
+    steps, live = blocked_flash.prefill_key_steps(
+        chunks, len(tiles), group=h // hkv, block_size=bs, entries=entries,
+        window=window, tile_q=tile)
+    assert len(chunks) <= live <= steps <= len(tiles) * -(-entries // 4)
+
+
+# cell: (group, table entries, window) -> (entries a step, key steps a tile,
+# live steps of a 1,024-token chunk from position 6,144, or from the last
+# position the table holds one)
+@pytest.mark.parametrize("cell, shape, want", [
+    ("trinity_window", (6, 200, 4096), (4, 9, 8 * 9)),
+    ("trinity_global", (6, 200, None), (4, 50, sum(
+        (6144 + 128 * i + 127) // 128 // 4 + 1 for i in range(8)))),
+    ("mistral7b", (4, 32, 4096), (4, 8, sum(
+        (3072 + 128 * i + 127) // 128 // 4 + 1 for i in range(8)))),
+    ("lfm2", (4, 70, None), (4, 18, None)),
+    ("qwen3next", (8, 36, None), (4, 9, None)),
+    ("olmoe", (1, 32, None), (4, 8, None)),
+    ("ouro", (1, 8, None), (4, 2, 4 * 1 + 4 * 2)),
+])
+def test_tiled_prefill_grid_at_the_cells_shapes(cell, shape, want):
+    from deepspeed_tpu.inference.v2.kernels import blocked_flash
+
+    group, entries, window = shape
+    kb, steps, live = want
+    assert blocked_flash._prefill_step_blocks(
+        group, 128, entries, window, 128) == kb
+    assert blocked_flash._prefill_key_steps(
+        entries, window, 128, 128, kb) == steps
+    start = min(6144, entries * 128 - 1024)
+    got = blocked_flash.prefill_key_steps(
+        [(start, 1024)], 8, group=group, block_size=128, entries=entries,
+        window=window, tile_q=128)
+    assert got[0] == 8 * steps and got[1] <= got[0]
+    if live is not None:
+        assert got[1] == live
+
+
+@pytest.mark.parametrize("cell, h, hkv, d, entries, window", [
+    ("trinity_window", 48, 8, 128, 200, 4096),
+    ("trinity_global", 48, 8, 128, 200, None),
+    ("mistral7b", 32, 8, 128, 32, 4096),
+    ("lfm2", 32, 8, 64, 70, None),
+    ("qwen3next", 16, 2, 256, 36, None),
+    ("ouro", 16, 16, 128, 8, None),
+])
+def test_tiled_prefill_lowers_for_the_tpu_at_the_cells_shapes(
+        monkeypatch, cell, h, hkv, d, entries, window):
+    """The tiled kernel at a cell's real widths lowers for the TPU (no chip,
+    no compile: the Mosaic module is built from the kernel's jaxpr) under the
+    names the benchmark's readers match."""
+    import re
+
+    from deepspeed_tpu.inference.v2.kernels import blocked_flash
+
+    monkeypatch.setattr(blocked_flash, "on_tpu", lambda: True)
+    rows, bs, nb = 1024, 128, entries + 8
+    args = (jax.ShapeDtypeStruct((rows, h, d), jnp.bfloat16),
+            jax.ShapeDtypeStruct((nb * bs, hkv * d), jnp.bfloat16),
+            jax.ShapeDtypeStruct((nb * bs, hkv * d), jnp.bfloat16),
+            jax.ShapeDtypeStruct((32, entries), jnp.int32),
+            jax.ShapeDtypeStruct((rows,), jnp.int32),
+            jax.ShapeDtypeStruct((rows,), jnp.int32))
+
+    def read(q, kp, vp, tables, slot, pos):
+        return blocked_flash.paged_prefill_attention(
+            q, kp, vp, tables, slot, pos, block_size=bs, tile_q=128,
+            window=window)
+
+    text = jax.jit(read).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text(debug_info=True)
+    assert re.findall(r'@tpu_custom_call\(.*?kernel_name = "([^"]+)"',
+                      text) == ["_prefill_kernel"]
+    assert "jit(paged_prefill_attention)" in text
+    # the pool is split into blocks as it lies: no copy in front of the call
+    assert "stablehlo.transpose" not in text
+
+
 @pytest.mark.parametrize("d,nb,single", [
     (128, 40, "paged_decode_attention"),      # D % 128 == 0: the walk,
     (128, 12, "paged_decode_attention"),      # whatever the pool's size

@@ -348,6 +348,27 @@ def test_admission_counts_the_window_pool_when_it_binds():
     assert sm.free_blocks > 150
 
 
+def test_tiled_batches_count_the_chunk_reads_key_steps():
+    """A tiled engine's ``engine/build_batch`` says how the tiled read's
+    grid fits the chunks: key steps over tiles and layers, the live ones,
+    and the four window layers' share of both.  At blocks of 16 the rule's
+    step holds the whole table (16 entries) and the whole band ((40 + 16) /
+    16 + 1 = 5 entries): one step a tile and layer, every one live."""
+    trc = Tracer()
+    eng = engine(params(), tile=16)
+    sched = ContinuousBatchScheduler(eng, tracer=trc)
+    sched.submit(ids(70).tolist(), _greedy(2))
+    sched.run_until_idle()
+    builds = [r["attrs"] for r in trc.records()
+              if r["name"] == "engine/build_batch" and r["attrs"]["tokens"] > 1]
+    assert [b["tokens"] for b in builds] == [32, 32, 6]
+    assert [b["bucket"] - 4 for b in builds] == [32, 32, 16]
+    for b, tiles in zip(builds, (2, 2, 1)):
+        assert b["chunk_key_steps"] == b["chunk_live_key_steps"] == 5 * tiles
+        assert b["chunk_key_steps_win"] == b["chunk_live_key_steps_win"] \
+            == 4 * tiles
+
+
 def test_counters_match_a_hand_count():
     trc = Tracer()
     eng = engine(params())

@@ -420,11 +420,22 @@ def test_tiled_tick_is_one_forward_and_bucket_is_its_rows(params):
     # ``row_blocks``: the table blocks of 8 the one-token rows hold (none in
     # the first forward; then the decoding prompt of 6 + what it generated,
     # and beside it the 33-token prompt's last token at position 32)
-    assert builds[0] == {"tokens": 6, "bucket": 4 + 16, "row_blocks": 0}
+    # ``chunk_key_steps``: the key steps the tiled read's grid runs, over
+    # the bucket's tiles and the layers: the table's 8 entries are one step
+    # (the rule's four blocks of 128 are 64 of 8), so every tile with a
+    # chunk holds one live step (``chunk_live_key_steps``)
+    layers = CFG.num_hidden_layers
+
+    def grid(tiles, live):
+        return {"chunk_key_steps": layers * tiles,
+                "chunk_live_key_steps": layers * live}
+
+    assert builds[0] == {"tokens": 6, "bucket": 4 + 16, "row_blocks": 0,
+                         **grid(1, 1)}
     assert builds[1] == {"tokens": 1 + 33 + 16, "bucket": 4 + 64,
-                         "row_blocks": 1}
+                         "row_blocks": 1, **grid(4, 4)}
     assert builds[2] == {"tokens": 1 + 1 + 15, "bucket": 4 + 16,
-                         "row_blocks": 1 + 33 // 8 + 1}
+                         "row_blocks": 1 + 33 // 8 + 1, **grid(1, 1)}
     assert {(b["bucket"], 16) for b in builds} == \
         {k for k in eng.step_keys if not isinstance(k[0], str)}
     # real tokens never pass the budget, rows never the largest program

@@ -48,6 +48,24 @@ GMM_SHARE_CELLS = {"qwen3next_decode": (32, 10, 512, 128, 2048, 512),
                    "olmoe_decode": (32, 8, 64, 64, 2048, 1024)}
 
 
+#: heads, KV heads, head size, table entries, window, first positions of
+#: the 1,024-row chunk: the tiled chunk read's calls in the serving cells
+#: (Trinity's window and global layers at two context lengths, Mistral-7B's
+#: last chunk of a full context, LFM2's 64-wide heads, Qwen3-Next's 256)
+PREFILL_CELLS = {"trinity_swa": (48, 8, 128, 200, 4096, (6144, 24000)),
+                 "trinity_full": (48, 8, 128, 200, None, (6144, 24000)),
+                 "mistral7b": (32, 8, 128, 32, 4096, (3072,)),
+                 "lfm2_d64": (32, 8, 64, 70, None, (4096,)),
+                 "qwen3next": (16, 2, 256, 36, None, (3072,))}
+
+#: the tiled chunk read against the XLA read, max |difference| over max
+#: |value|.  Second readings (my chip run, PR 44): the kernel as it stands
+#: reads under 0.01 at every shape; the same read with a bfloat16 softmax
+#: (scores, exponentials and their sum in bfloat16) reads over 0.03: each
+#: case records both, and the case fails if the second is not over the limit
+PREFILL_TOL = 0.02
+
+
 def _stacked(read, layers: int):
     """``layers`` reads with a query of their own each, summed (the pools
     are arguments: a closed-over pool would be compiled in as a constant)."""
@@ -150,6 +168,121 @@ def decode_read_case(cell: str, tol: float, layers: int = 16,
         us[str(share)] = [held, round(t_walk, 1), round(t_dense, 1),
                           round(least, 1)]
     return {"max_err": round(err, 6), "ok": bool(err < tol), "us": us}
+
+
+def _chunk_read_reference(q, k_ctx, v_ctx, pos, window, low: bool):
+    """The XLA read of one sequence's chunk: q [T, H, D] at positions
+    ``pos`` over its context ``k_ctx`` / ``v_ctx`` [C, Hkv, D], a KV head at
+    a time (its scores are [g, T, C] float32).  Operands as the kernel takes
+    them (the pool's dtype into float32 dots, float32 softmax, ``p`` in the
+    pool's dtype for PV); ``low``: the softmax in bfloat16 instead, the
+    lower precision the case's limit must refuse."""
+    import jax
+    import jax.numpy as jnp
+
+    t, h, d = q.shape
+    hkv = k_ctx.shape[1]
+    key = jnp.arange(k_ctx.shape[0])[None, None, :]
+    keep = key <= pos[None, :, None]
+    if window is not None:
+        keep = jnp.logical_and(keep, key > pos[None, :, None] - window)
+
+    def one(args):
+        qg, k, v = args                       # [g, T, D], [C, D], [C, D]
+        s = jnp.einsum("gtd,cd->gtc", qg, k,
+                       preferred_element_type=jnp.float32) / d ** 0.5
+        s = jnp.where(keep, s, -1e30)
+        if low:
+            p = jax.nn.softmax(s.astype(jnp.bfloat16), axis=-1)
+        else:
+            p = jax.nn.softmax(s, axis=-1)
+        return jnp.einsum("gtc,cd->gtd", p.astype(v.dtype), v,
+                          preferred_element_type=jnp.float32)
+
+    out = jax.lax.map(one, (
+        q.reshape(t, hkv, h // hkv, d).transpose(1, 2, 0, 3),
+        k_ctx.transpose(1, 0, 2), v_ctx.transpose(1, 0, 2)))
+    return out.transpose(2, 0, 1, 3).reshape(t, h, d)
+
+
+def prefill_chunk_case(h: int, hkv: int, d: int, entries: int, window,
+                       starts, rows: int = 1024, layers: int = 4,
+                       repeats: int = 5, check: bool = True) -> dict:
+    """The tiled chunk read (``paged_prefill_attention``, compiled) on a
+    flat pool row at one cell's shapes: a chunk of ``rows`` tokens of one
+    sequence from each position of ``starts``, its blocks scattered over the
+    pool behind a table of ``entries`` entries.  ``max_err`` = the largest
+    max |got - want| / max |want| against the XLA read, ``low_err`` = what
+    that read with a bfloat16 softmax gives (``PREFILL_TOL`` lies between);
+    ``us`` = for each start ``[the call, the least]``, microseconds: the
+    least is the visible (query, key) pairs' two dots at 197 TFLOP/s;
+    ``steps`` = for each start ``[key steps of the grid, live ones]`` where
+    the kernel's rule gives them."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from deepspeed_tpu.inference.v2.kernels import (blocked_flash,
+                                                    paged_prefill_attention)
+
+    bs, tile = 128, 128
+    nb = entries + 40                          # blocks of the pool
+    ks = jax.random.split(jax.random.key(44), 3)
+    k_pool = jax.random.normal(ks[0], (nb * bs, hkv * d), jnp.bfloat16)
+    v_pool = jax.random.normal(ks[1], (nb * bs, hkv * d), jnp.bfloat16)
+    # queries four times a unit normal: a peaked softmax, where the precision
+    # of the statistics shows in the output and the rounding of the output
+    # itself (bfloat16 on both sides) does not hide it
+    q = 4 * jax.random.normal(ks[2], (rows, h, d), jnp.bfloat16)
+    rng = np.random.default_rng(44)
+    table = rng.permutation(nb - 1)[:entries].astype(np.int32) + 1
+    tables = np.zeros((32, entries), np.int32)
+    tables[5] = table
+    tables, slot = jnp.asarray(tables), jnp.full((rows,), 5, jnp.int32)
+
+    def read(q, k_pool, v_pool, pos):
+        return paged_prefill_attention(
+            q, k_pool, v_pool, tables, slot, pos, block_size=bs,
+            tile_q=tile, window=window, interpret=False)
+
+    @jax.jit
+    def errors(q, k_pool, v_pool, pos):
+        ctx = (jnp.asarray(table)[:, None] * bs
+               + jnp.arange(bs)[None, :]).reshape(-1)
+        k_ctx = k_pool[ctx].reshape(-1, hkv, d)
+        v_ctx = v_pool[ctx].reshape(-1, hkv, d)
+        want = _chunk_read_reference(q, k_ctx, v_ctx, pos, window, False)
+        low = _chunk_read_reference(q, k_ctx, v_ctx, pos, window, True)
+        got = read(q, k_pool, v_pool, pos).astype(jnp.float32)
+        top = jnp.max(jnp.abs(want))
+        return (jnp.max(jnp.abs(got - want)) / top,
+                jnp.max(jnp.abs(low - want)) / top)
+
+    stacked = _stacked(read, layers)
+    # (none in a tree from before the rule: PR 44's bench timed its parent)
+    steps_of = getattr(blocked_flash, "prefill_key_steps", None)
+    out = {"max_err": 0.0, "low_err": float("inf"), "us": {}, "steps": {}}
+    for start in starts:
+        pos = jnp.arange(start, start + rows, dtype=jnp.int32)
+        t_call, _sum = _timed(stacked, layers, repeats, q, k_pool, v_pool,
+                              pos)
+        seen = np.minimum(np.arange(start, start + rows) + 1,
+                          window or start + rows)
+        least = 4.0 * h * d * float(seen.sum()) / 197e12 * 1e6
+        out["us"][str(start)] = [round(t_call, 1), round(least, 1)]
+        if steps_of is not None:
+            out["steps"][str(start)] = list(steps_of(
+                [(start, rows)], rows // tile, group=h // hkv,
+                block_size=bs, entries=entries, window=window, tile_q=tile))
+        if check:
+            err, low = (float(e) for e in errors(q, k_pool, v_pool, pos))
+            out["max_err"] = max(out["max_err"], err)
+            out["low_err"] = min(out["low_err"], low)
+    if check:
+        out["ok"] = bool(out["max_err"] < PREFILL_TOL < out["low_err"])
+        out["max_err"] = round(out["max_err"], 6)
+        out["low_err"] = round(out["low_err"], 6)
+    return out
 
 
 def verify_read_case(tol: float, layers: int = 16, repeats: int = 10,
@@ -693,6 +826,14 @@ def run_selftest(tol: float = 3e-2) -> dict:
         record("paged_two_segment_d64", got[real], want[real])
 
     guarded("paged_two_segment_d64", two_segment_d64)
+
+    # the tiled chunk read at the serving cells' shapes against the XLA
+    # read, held to a relative limit a bfloat16 softmax fails, with the
+    # time of a call beside the least its visible pairs need
+    for cell, shape in PREFILL_CELLS.items():
+        guarded("paged_prefill_" + cell,
+                lambda c=cell, a=shape: results.update(
+                    {"paged_prefill_" + c: prefill_chunk_case(*a)}))
 
     # the decode walk against the XLA dense read at the three serving
     # cells' pools and head layouts, 32 rows, with the time of each at
