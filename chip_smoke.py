@@ -952,6 +952,10 @@ def conv_phase(sizes: SmokeSizes, devices, require_chip: bool,
 # --------------------------------------------------------------------- #
 # Phase: every other kernel
 # --------------------------------------------------------------------- #
+#: the self-test's cases that time a chunk read (``prefill_us``)
+_CHUNK_READS = ("paged_prefill_", "latent_prefill_")
+
+
 def kernels_phase(_sizes, _devices, _require_chip,
                   clock: CompileClock) -> Dict[str, Any]:
     sys.path.insert(0, os.path.join(_HERE, "tools"))
@@ -969,11 +973,11 @@ def kernels_phase(_sizes, _devices, _require_chip,
             # least us: the held bytes at 819 GB/s]
             "decode_read_us": {k: v["us"] for k, v in cases.items()
                                if "us" in v
-                               and not k.startswith("paged_prefill_")},
+                               and not k.startswith(_CHUNK_READS)},
             # cell -> first position of the chunk -> [tiled chunk read us,
             # least us: the visible pairs' dots at 197 TFLOP/s]
             "prefill_us": {k: v["us"] for k, v in cases.items()
-                           if "us" in v and k.startswith("paged_prefill_")},
+                           if "us" in v and k.startswith(_CHUNK_READS)},
             # cell.call -> shape, tiles, live units of units, us, least us
             "gmm_share": cases.get("gmm_share", {}).get("calls"),
             **clock.take()}

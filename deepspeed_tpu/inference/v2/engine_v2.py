@@ -638,8 +638,10 @@ class InferenceEngineV2:
             attrs.update(self._window_counters(
                 [s for s, n in rows if n == 1], tiled))
         tile = self._prefill_tile()
-        if tile and tiled and not latent and not sm.kv_cache.quantized:
-            attrs.update(self._chunk_step_counters(
+        if tile and tiled and not sm.kv_cache.quantized:
+            count = self._latent_step_counters if latent \
+                else self._chunk_step_counters
+            attrs.update(count(
                 tiled, (bucket - self._batch.max_seqs) // tile, tile))
         # what the one-token read (the decode walk, the absorbed read) must
         # do: the table blocks the batch's one-token rows hold up to the
@@ -963,6 +965,24 @@ class InferenceEngineV2:
                 out.update(chunk_key_steps_win=count * steps,
                            chunk_live_key_steps_win=count * live)
         return out
+
+    def _latent_step_counters(self, chunks, tiles: int,
+                              tile: int) -> Dict[str, int]:
+        """``_chunk_step_counters`` for an engine with a latent pool row:
+        ``latent_key_steps``, the key steps the grid of
+        ``latent_prefill_attention`` runs, summed over tiles and layers, and
+        ``latent_live_key_steps``, those of them that hold a visible key
+        (``latent_prefill_key_steps``, the kernel's own rule)."""
+        from deepspeed_tpu.inference.v2.kernels import \
+            latent_prefill_key_steps
+
+        sm = self.state_manager
+        steps, live = latent_prefill_key_steps(
+            chunks, tiles, block_size=sm.block_size,
+            entries=self._max_blocks, tile_q=tile)
+        layers = len(sm.kv_cache.kv_layers) * self._passes
+        return {"latent_key_steps": layers * steps,
+                "latent_live_key_steps": layers * live}
 
     def _recover_donated_cache(self) -> None:
         """A jitted step that donates the KV cache raised after donation

@@ -14,10 +14,12 @@ from deepspeed_tpu.inference.v2.kernels.latent_flash import (
     latent_decode_attention,
     latent_expand,
     latent_prefill_attention,
+    latent_prefill_key_steps,
 )
 
 __all__ = ["decode_walk_usable", "latent_decode_attention", "latent_expand",
-           "latent_prefill_attention", "paged_attention",
+           "latent_prefill_attention", "latent_prefill_key_steps",
+           "paged_attention",
            "paged_attention_usable",
            "paged_decode_attention", "paged_prefill_attention",
            "paged_verify_attention", "prefill_key_steps"]

@@ -138,6 +138,13 @@ span                     site                        parent    attrs (counters)
                                                                layers their
                                                                share of both,
                                                                ``..._win``
+                                                               (a latent row:
+                                                               ``latent_key_steps``
+                                                               and
+                                                               ``latent_live_key_steps``,
+                                                               the expanded
+                                                               read's grid over
+                                                               tiles and layers)
 ``engine/upload``        ``engine.launch``: the      prefill   —
                          late rows' tokens into
                          the metadata, its ONE

@@ -11,6 +11,7 @@ too), a selection bias of N(0, 0.3^2) on a router of N(0, 4/H), a share of 4
 of 8 experts from id 2, two shared experts, a leading dense layer.
 """
 
+import functools
 import os
 import sys
 
@@ -525,19 +526,24 @@ def test_what_a_latent_row_cannot_do_refuses_by_name(path):
 # ------------------------------------------------------------------ #
 # (f) spans, counters and device scopes
 # ------------------------------------------------------------------ #
-def test_counters_on_build_batch_match_a_hand_count():
+def _serve_a_prompt_and_a_join():
+    """100 tokens: chunks of 64 and 36 at a 64-token budget; then a join of
+    40 tokens beside the first one's decode.  (engine, the spans)."""
     tracer = Tracer()
     eng = _engine(_params())
     sched = ContinuousBatchScheduler(eng, tracer=tracer)
-    # 100 tokens: chunks of 64 and 36 at a 64-token budget; then a join of
-    # 40 tokens beside the first one's decode
     first = sched.submit(_ids(100).tolist(), _greedy(8))
     for _ in range(3):
         sched.step()
     sched.submit(_ids(40, seed=4).tolist(), _greedy(3))
     sched.run_until_idle()
     assert len(first.generated) == 8
-    spans = [r for r in tracer.records() if r.get("ph") == "X"]
+    return eng, lambda: [r for r in tracer.records() if r.get("ph") == "X"]
+
+
+def test_counters_on_build_batch_match_a_hand_count():
+    _eng, spans = _serve_a_prompt_and_a_join()
+    spans = spans()
     built = [r["attrs"] for r in spans if r["name"] == "engine/build_batch"]
     chunks = [a for a in built if a["chunk_seqs"]]
     assert [(a["chunk_tokens"], a["attn_pairs"], a["ctx_rows"])
@@ -554,6 +560,33 @@ def test_counters_on_build_batch_match_a_hand_count():
                for a in built if not a["chunk_seqs"])
     dec = [r["attrs"] for r in spans if r["name"] == "decode"]
     assert dec and all(a["read_blocks"] >= 1 for a in dec)
+
+
+def test_key_step_counters_on_build_batch_match_a_hand_count():
+    """``latent_key_steps`` / ``latent_live_key_steps`` on a latent engine's
+    ``engine/build_batch`` span: the grid of the expanded read over tiles
+    and layers, and its steps that hold a visible key; neither on a batch of
+    one-token rows."""
+    eng, spans = _serve_a_prompt_and_a_join()
+    eng.put([99], [[7]])                  # a ragged batch of one-token rows
+    built = [r["attrs"] for r in spans() if r["name"] == "engine/build_batch"]
+    # a table of 512 // 16 = 32 entries is ONE step of 512 keys: each of the
+    # bucket's four tiles runs a step a layer, a tile that holds a chunk's
+    # rows a live one
+    layers = HF["num_hidden_layers"]
+    assert [(a["bucket"], a["latent_key_steps"], a["latent_live_key_steps"])
+            for a in built if a["chunk_seqs"]] == [
+        (MAX_SEQS + 64, layers * 4, layers * 4),      # 64 tokens: 4 tiles
+        (MAX_SEQS + 64, layers * 4, layers * 3),      # 36 tokens: 3 tiles
+        (MAX_SEQS + 64, layers * 4, layers * 3)]      # 40 tokens: 3 tiles
+    for a in built:
+        if a["chunk_seqs"]:
+            assert a["latent_key_steps"] >= a["latent_live_key_steps"] > 0
+            assert "chunk_key_steps" not in a         # the k / v pools' own
+        else:
+            assert "latent_key_steps" not in a \
+                and "latent_live_key_steps" not in a
+    assert not all(a["chunk_seqs"] for a in built)
 
 
 def test_device_scopes_of_both_kinds_of_layer():
@@ -646,6 +679,114 @@ def test_expand_and_prefill_kernels_over_tiles_of_three_sequences():
     real = np.asarray(pos) >= 0
     assert np.max(np.abs(np.asarray(got - want)[real])) <= 2e-5
     assert not np.asarray(got)[~real].any()
+
+
+# the expanded read's key step (PR 45): ``kb`` table entries a grid step,
+# lane-wise statistics, the mask on edge steps only, never-written blocks.
+# (block_size, table width, tile_q, chunks as (first row, slot, start,
+# tokens)); ``kb`` = 512 keys where the table holds them.
+_STEP_CASES = {
+    # kb 32 of 40 entries (no multiple): a chunk from inside block 31 whose
+    # context crosses the step at key 512; its second tile (516-531) is
+    # wholly inside on step 0 and on the diagonal on step 1, its third ends
+    # in pad rows; a second chunk that lives in the first step alone
+    "two_steps_of_small_blocks": (16, 40, 16, (
+        (0, 3, 500, 40), (48, 1, 7, 30), (96, 4, 0, 16))),
+    # the same table under a tile of two blocks
+    "tile_of_two_blocks": (16, 40, 32, (
+        (0, 2, 470, 90), (96, 5, 20, 33))),
+    # blocks of a lane tile: four lane tiles of statistics a step, kb 4 of 6
+    # entries; tile 1 (528-655) inside on step 0, diagonal on step 1; the
+    # short chunk's step holds three entries nobody wrote
+    "lane_tiles": (128, 6, 128, (
+        (0, 1, 400, 300), (384, 0, 0, 100))),
+    # a table under a lane tile of keys: the plain-column statistics
+    "column_statistics": (16, 5, 16, (
+        (0, 2, 30, 40), (48, 0, 0, 9))),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _step_case(name):
+    bs, width, tile, chunks = _STEP_CASES[name]
+    rng = np.random.default_rng(45)
+    h, rank, nope, rope, vd, w, s_count = 2, 128, 128, 64, 128, 256, 6
+    f32 = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
+    pool = f32((s_count * width + 1) * bs, w).at[:, rank + rope:].set(0)
+    w_kvb = f32(rank, h * (nope + vd)) * rank ** -0.5
+    tables = jnp.asarray((rng.permutation(s_count * width) + 1).reshape(
+        s_count, width), jnp.int32)
+    t_rows = -(-max(r + n for r, _s, _a, n in chunks) // tile) * tile + tile
+    slot = np.zeros((t_rows,), np.int32)
+    pos = np.full((t_rows,), -1, np.int32)
+    for row, seq, start, n in chunks:
+        slot[row:row + n], pos[row:row + n] = seq, np.arange(start,
+                                                             start + n)
+    slot, pos = jnp.asarray(slot), jnp.asarray(pos)
+    q_nope, q_pe = f32(t_rows, h, nope), f32(t_rows, h, rope)
+    scale = (nope + rope) ** -0.5
+    kv, plan = lf.latent_expand(pool, w_kvb, tables, slot, pos,
+                                block_size=bs, tile_q=tile, rank=rank,
+                                interpret=True)
+    q_cat = jnp.concatenate(
+        [q_nope, q_pe, jnp.zeros((t_rows, h, 128 - rope))], -1)
+    read = functools.partial(
+        lf.latent_prefill_attention, q_cat, plan=plan, token_pos=pos,
+        block_size=bs, tile_q=tile, nope=nope, v_dim=vd, scale=scale,
+        interpret=True)
+    want = rd.expanded_read_xla(q_nope, q_pe, pool, w_kvb, tables, slot,
+                                pos, bs, rank, scale)
+    return kv, plan, read, want, np.asarray(pos) >= 0
+
+
+@pytest.mark.parametrize("name", _STEP_CASES)
+def test_expanded_read_key_steps_match_the_composition(name):
+    bs, width, tile, chunks = _STEP_CASES[name]
+    kv, _plan, read, want, real = _step_case(name)
+    kb = lf._latent_step_blocks(bs, width, tile)
+    assert kb == min(512 // bs, width)
+    # the case is what its comment says: a context past one step, a table
+    # that is no multiple of the step (or one step of a lane tile's part)
+    if width > kb:
+        assert width % kb and max(a + n for _r, _s, a, n in chunks) \
+            > kb * bs
+    got = read(expanded=kv)
+    assert np.max(np.abs(np.asarray(got - want)[real])) <= 2e-5
+    assert not np.asarray(got)[~real].any()          # pad rows: zeros
+
+
+@pytest.mark.parametrize("name", _STEP_CASES)
+def test_never_written_blocks_do_not_reach_the_output(name):
+    """``latent_expand`` writes a chunk's blocks up to its last position; a
+    key step reads whole runs of ``kb`` entries.  Every block nobody wrote,
+    set to NaN: the same finite answer."""
+    bs, width, _tile, _chunks = _STEP_CASES[name]
+    kv, plan, read, want, real = _step_case(name)
+    blocks = np.asarray(plan[3])
+    unwritten = (np.arange(width)[None, :] >= blocks[:, None]).reshape(-1)
+    assert unwritten.sum() > unwritten.size // 2
+    poisoned = jnp.where(jnp.asarray(unwritten)[:, None, None], jnp.nan, kv)
+    got = np.asarray(read(expanded=poisoned))
+    assert np.isfinite(got).all()
+    assert np.max(np.abs((got - np.asarray(want))[real])) <= 2e-5
+    assert np.array_equal(got, np.asarray(read(expanded=kv)))
+
+
+def test_latent_prefill_key_steps_equal_a_hand_count():
+    # the cell's shape: a 1,024-token chunk from 3,072 over 60 entries of
+    # 128, tile 128: four entries a step, 15 steps a tile; tile i ends at
+    # 3,072 + 128 (i + 1) - 1, so tiles 0-3 see 7 steps and 4-7 see 8
+    assert lf.latent_prefill_key_steps(
+        [(3072, 1024)], 8, block_size=128, entries=60, tile_q=128) \
+        == (8 * 15, 4 * 7 + 4 * 8)
+    # two chunks and a pad tile; the last tile of the first holds 44 rows
+    assert lf.latent_prefill_key_steps(
+        [(400, 300), (0, 100)], 5, block_size=128, entries=6, tile_q=128) \
+        == (5 * 2, (2 + 2 + 2) + 1)
+    # a table of one step: every tile with a chunk is one live step
+    assert lf.latent_prefill_key_steps(
+        [(50, 40), (0, 16)], 6, block_size=16, entries=8, tile_q=16) \
+        == (6, 3 + 1)
 
 
 # ------------------------------------------------------------------ #
