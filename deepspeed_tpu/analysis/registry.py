@@ -29,6 +29,7 @@ KERNEL_MODULES = (
     "deepspeed_tpu.ops.flash_attention",
     "deepspeed_tpu.ops.grouped_gemm",
     "deepspeed_tpu.ops.gated_delta_rule",
+    "deepspeed_tpu.ops.selective_scan",
     "deepspeed_tpu.ops.quantized_matmul",
     "deepspeed_tpu.ops.quantizer",
     "deepspeed_tpu.ops.block_sparse_attention",

@@ -626,6 +626,49 @@ def _ouro_rules() -> List[Rule]:
     ]
 
 
+def _jamba_rules() -> List[Rule]:
+    # Jamba (``model_type: jamba``, the dense sibling: ``num_experts`` 1) ->
+    # RaggedJamba's tree.  The head is tied to the embedding (a checkpoint's
+    # ``lm_head.weight`` is skipped); ``A_log`` is stored [Di, N] and read
+    # [N, Di], the layout of the state it decays.
+    def layer(m, *leaf):
+        return (f"layers_{m.group(1)}", *leaf)
+
+    return [
+        (r"^model\.embed_tokens\.weight$",
+         lambda m: (("embed_tokens", "embedding"), None)),
+        (r"^model\.final_layernorm\.weight$",
+         lambda m: (("final_layernorm", "scale"), None)),
+        (r"^lm_head\.weight$", lambda m: (None, None)),
+        (r"^model\.layers\.(\d+)\.(input_layernorm|pre_ff_layernorm)"
+         r"\.weight$", lambda m: (layer(m, m.group(2), "scale"), None)),
+        (r"^model\.layers\.(\d+)\.mamba\.(in_proj|x_proj|out_proj)\.weight$",
+         lambda m: (layer(m, "mamba", m.group(2), "kernel"), "t")),
+        (r"^model\.layers\.(\d+)\.mamba\.dt_proj\.(weight|bias)$",
+         _dense(lambda m: (*layer(m, "mamba", "dt_proj"), m.group(2)))),
+        # [channels, 1, taps] -> [taps, channels], the last tap on the
+        # current token either way
+        (r"^model\.layers\.(\d+)\.mamba\.conv1d\.weight$",
+         lambda m: (layer(m, "mamba", "conv1d", "kernel"),
+                    lambda w, _c: np.asarray(w)[:, 0, :].T)),
+        (r"^model\.layers\.(\d+)\.mamba\.conv1d\.bias$",
+         lambda m: (layer(m, "mamba", "conv1d", "bias"), None)),
+        (r"^model\.layers\.(\d+)\.mamba\.A_log$",
+         lambda m: (layer(m, "mamba", "A_log"), "t")),
+        (r"^model\.layers\.(\d+)\.mamba\.D$",
+         lambda m: (layer(m, "mamba", "D"), None)),
+        (r"^model\.layers\.(\d+)\.mamba\.(dt|b|c)_layernorm\.weight$",
+         lambda m: (layer(m, "mamba", f"{m.group(2)}_layernorm", "scale"),
+                    None)),
+        (r"^model\.layers\.(\d+)\.self_attn\.(q|k|v|o)_proj\.weight$",
+         lambda m: (layer(m, "self_attn", f"{m.group(2)}_proj", "kernel"),
+                    "t")),
+        (r"^model\.layers\.(\d+)\.feed_forward\.(gate|up|down)_proj"
+         r"\.weight$",
+         lambda m: (layer(m, "mlp", f"{m.group(2)}_proj", "kernel"), "t")),
+    ]
+
+
 _ARCH_RULES: Dict[str, Callable[[], List[Rule]]] = {
     "llama": _llama_rules,
     "mistral": _llama_rules,     # same architecture/serialization
@@ -637,6 +680,7 @@ _ARCH_RULES: Dict[str, Callable[[], List[Rule]]] = {
     "lfm2_moe": _lfm2_moe_rules,
     "afmoe": _afmoe_rules,
     "ouro": _ouro_rules,
+    "jamba": _jamba_rules,
     "gpt2": _gpt2_rules,
     "opt": _opt_rules,
     "falcon": _falcon_rules,
@@ -793,6 +837,21 @@ def config_from_hf(model_path: str, dtype: Any = None):
         # (early_exit_threshold < 1, rope_scaling, a sliding window, a tied
         # head: the config refuses each by name)
         return arch, OuroConfig(
+            **{k: v for k, v in cfg.items() if k in fields}, dtype=dt)
+    if arch == "jamba":
+        from deepspeed_tpu.inference.v2.model_implementations. \
+            ragged_jamba import JambaConfig
+
+        if int(cfg.get("num_experts", 1)) > 1:
+            raise HFLoadError(
+                f"jamba: num_experts={cfg['num_experts']}: routed experts "
+                f"beside state-space layers are not implemented (the dense "
+                f"sibling, num_experts 1, is: AI21-Jamba2-3B)")
+        fields = {f.name for f in dataclasses.fields(JambaConfig)} \
+            - {"dtype"}
+        # (mamba_proj_bias, a sliding window: the config refuses each by
+        # name)
+        return arch, JambaConfig(
             **{k: v for k, v in cfg.items() if k in fields}, dtype=dt)
     if arch == "gpt2":
         from deepspeed_tpu.models.gpt2 import GPT2Config

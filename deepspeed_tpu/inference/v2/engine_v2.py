@@ -283,7 +283,11 @@ class InferenceEngineV2:
             f"kv_blocks={self.state_manager.allocator.num_blocks} "
             f"block_size={kv_cfg.block_size}" + (
                 f" passes={self._passes} cache_layers={self._cache_layers}"
-                if self._passes > 1 else ""), ranks=[0])
+                if self._passes > 1 else "") + (
+                " state_slots={0.num_slots} state_bytes_per_seq="
+                "{0.per_sequence_bytes} state_bytes={0.total_bytes}".format(
+                    self.state_manager.state_pool)
+                if self._stateful else ""), ranks=[0])
 
     def attach_tracer(self, tracer) -> None:
         """Record this engine's spans on ``tracer`` (None detaches)."""
@@ -617,6 +621,13 @@ class InferenceEngineV2:
         return PreparedBatch(scheduled, drained, sizes, bucket, tile,
                              packed, rows)
 
+    def _state_counters(self) -> Dict[str, int]:
+        """What a dispatch span says of the slot pool: slots held, and the
+        bytes they and the whole pool (scratch slot included) take."""
+        pool = self.state_manager.state_pool
+        return {"state_slots": pool.held, "state_bytes": pool.held_bytes,
+                "state_bytes_total": pool.total_bytes}
+
     def _batch_counters(self, bucket: int) -> Dict[str, int]:
         """What the ``engine/build_batch`` span closes with: the useful
         tokens of the rows they are padded to, and what the batch just
@@ -633,7 +644,7 @@ class InferenceEngineV2:
             attrs.update(chunk_seqs=len(tiled),
                          chunk_tokens=sum(n for _, n in tiled))
         if self._stateful:
-            attrs["state_slots"] = sm.state_pool.held
+            attrs.update(self._state_counters())
         if self._grouped:
             attrs.update(self._window_counters(
                 [s for s, n in rows if n == 1], tiled))
@@ -799,7 +810,7 @@ class InferenceEngineV2:
             if type(span) is SpanHandle:
                 span.attrs = {"seqs": n}    # live rows of the S it runs
                 if self._stateful:
-                    span.attrs["state_slots"] = sm.state_pool.held
+                    span.attrs.update(self._state_counters())
                 if self._grouped:
                     span.attrs.update(self._window_counters(seqs))
         try:
@@ -1574,7 +1585,8 @@ class InferenceEngineV2:
         MII/engine_factory path that builds a FastGen engine from a HF
         snapshot).  ``model_implementations.HF_MODELS`` names the
         architectures served (llama, mistral, internlm, opt, falcon, mixtral,
-        olmoe, qwen3_next, deepseek_v3, lfm2_moe, afmoe, ouro) and which take
+        olmoe, qwen3_next, deepseek_v3, lfm2_moe, afmoe, ouro, jamba) and which
+        take
         a ``mesh`` with a non-trivial 'model' axis (the others refuse one):
         weights then land PRE-SHARDED
         by the Megatron split rules (``modules/attention.py::

@@ -1,7 +1,7 @@
 """Inference v2 model implementations (reference:
 inference/v2/model_implementations/ — llama_v2, opt, mistral, mixtral,
-falcon families; qwen3_next, deepseek_v3, lfm2_moe, afmoe and ouro have no
-reference counterpart).  One file a family (config, parameter shapes,
+falcon families; qwen3_next, deepseek_v3, lfm2_moe, afmoe, ouro and jamba have
+no reference counterpart).  One file a family (config, parameter shapes,
 class) over the shared layers of ``inference/v2/modules/``."""
 
 from deepspeed_tpu.inference.v2.model_implementations.ragged_afmoe import (
@@ -24,6 +24,10 @@ from deepspeed_tpu.inference.v2.model_implementations.ragged_opt import (
 from deepspeed_tpu.inference.v2.model_implementations.ragged_deepseek_v3 import (
     DeepseekV3Config,
     RaggedDeepseekV3,
+)
+from deepspeed_tpu.inference.v2.model_implementations.ragged_jamba import (
+    JambaConfig,
+    RaggedJamba,
 )
 from deepspeed_tpu.inference.v2.model_implementations.ragged_lfm2 import (
     Lfm2Config,
@@ -64,9 +68,10 @@ HF_MODELS = {
     "lfm2_moe": (RaggedLfm2, False),
     "afmoe": (RaggedAfmoe, False),
     "ouro": (RaggedOuro, False),
+    "jamba": (RaggedJamba, False),
 }
 
 __all__ = ["AfmoeConfig", "DeepseekV3Config", "HF_MODELS", "RaggedAfmoe",
-           "RaggedDeepseekV3", "Lfm2Config", "OuroConfig", "Qwen3NextConfig", "RaggedLfm2", "RaggedLlama", "RaggedMistral", "RaggedMixtral",
+           "RaggedDeepseekV3", "JambaConfig", "RaggedJamba", "Lfm2Config", "OuroConfig", "Qwen3NextConfig", "RaggedLfm2", "RaggedLlama", "RaggedMistral", "RaggedMixtral",
            "RaggedOPT", "RaggedFalcon", "RaggedOuro", "RaggedQwen3Next",
            "ragged_param_specs", "shard_ragged_params"]

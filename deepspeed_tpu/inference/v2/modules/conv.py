@@ -1,6 +1,7 @@
 """The depthwise causal convolution of the families that keep a
 convolution's tail per sequence in a state slot (``ragged/state_pool.py``):
-Qwen3-Next's Gated DeltaNet layers, LFM2's gated short convolution."""
+Qwen3-Next's Gated DeltaNet layers, LFM2's gated short convolution, Jamba's
+Mamba layers (the one with a bias)."""
 
 import jax
 import jax.numpy as jnp
@@ -12,9 +13,10 @@ def _silu(x):
     return x * jax.nn.sigmoid(x)
 
 
-def _causal_conv(u, w, conv_pool, batch, activation=_silu):
-    """Depthwise causal convolution over each chunk of a ragged batch, then
-    ``activation`` (SiLU for the Gated DeltaNet layers; None: the
+def _causal_conv(u, w, conv_pool, batch, activation=_silu, bias=None):
+    """Depthwise causal convolution over each chunk of a ragged batch, plus
+    ``bias`` [C] where the layer has one (a Mamba layer's), then
+    ``activation`` (SiLU for the Gated DeltaNet and Mamba layers; None: the
     convolution as it is, LFM2's gated short convolution).  ``u`` [T, C]:
     this batch's inputs; ``w`` [K, C], the last tap on the current token;
     ``conv_pool`` [slots + 1, K - 1, C]: each sequence's last K - 1 inputs,
@@ -52,6 +54,8 @@ def _causal_conv(u, w, conv_pool, batch, activation=_silu):
     from_tail = jnp.take_along_axis(
         tail, jnp.clip(idx + taps - 1, 0, taps - 2)[:, :, None], axis=1)
     new_tail = jnp.where((idx >= 0)[:, :, None], from_u, from_tail)
+    if bias is not None:
+        acc = acc + bias.astype(F32)
     if activation is not None:
         acc = activation(acc)
     return acc.astype(u.dtype), conv_pool.at[sslot].set(

@@ -1,8 +1,10 @@
 """Per-sequence recurrent state beside the paged KV pool.
 
-A linear-attention (Gated DeltaNet) layer keeps no keys and values: it keeps,
-for every live sequence, a fixed-size state (a recurrent matrix per head and
-the tail of its causal convolution).  The state manager holds those in SLOTS:
+A linear-attention (Gated DeltaNet) or state-space (Mamba) layer keeps no
+keys and values: it keeps, for every live sequence, a fixed-size state (a
+recurrent matrix per head or a scan state per channel, and the tail of its
+causal convolution; leaves of any shape and dtype, e.g. a float32 ``[16,
+5120]`` beside a bf16 ``[3, 5120]``).  The state manager holds those in SLOTS:
 ``num_slots`` of them, one taken when a sequence is created and released when
 it is flushed, plus one scratch slot (index ``num_slots``) that pad rows of a
 step program read and write so that they touch no live sequence.
@@ -86,3 +88,13 @@ class StateSlotPool:
         return len(self.layers) * sum(
             int(np.prod(shape)) * jnp.dtype(dtype).itemsize
             for shape, dtype in self.leaves.values())
+
+    @property
+    def held_bytes(self) -> int:
+        """What the live sequences' slots hold."""
+        return self.held * self.per_sequence_bytes
+
+    @property
+    def total_bytes(self) -> int:
+        """The device arrays: every slot and the scratch one."""
+        return (self.num_slots + 1) * self.per_sequence_bytes

@@ -222,10 +222,8 @@ def kv_occupancy(state_manager) -> Dict[str, float]:
         out.update({
             "observability/state_slots_total": float(pool.num_slots),
             "observability/state_slots_held": float(pool.held),
-            "observability/state_pool_bytes": float(
-                (pool.num_slots + 1) * pool.per_sequence_bytes),
-            "observability/state_live_bytes": float(
-                pool.held * pool.per_sequence_bytes),
+            "observability/state_pool_bytes": float(pool.total_bytes),
+            "observability/state_live_bytes": float(pool.held_bytes),
         })
     tier = getattr(state_manager, "host_tier", None)
     if tier is not None:

@@ -164,7 +164,10 @@ span                     site                        parent    attrs (counters)
                                                                with recurrent
                                                                state:
                                                                ``state_slots``
-                                                               held
+                                                               held and their
+                                                               ``state_bytes``
+                                                               of the pool's
+                                                               ``state_bytes_total``
 ``engine/decode_step``   the step's dispatch: of     decode    the launch record
                          the step the tick returns
                          unless that is in flight,
@@ -226,6 +229,14 @@ Such an engine's dispatch spans (``engine/ragged_step``,
 ``launch`` / ``program``, and with what the launch asks of every cache
 layer: ``loop_seqs``, ``loop_tokens``, ``loop_ctx_tokens``,
 ``loop_attn_pairs``.
+A state-space (Mamba) layer (``ragged_jamba.py``) is ``layers_<i>/mamba/
+in_proj`` (norm and ``W_in``), ``mamba/conv``, ``mamba/x_proj`` (``W_x``,
+the inner norms, ``W_dt``, softplus), ``mamba/scan`` (the Mosaic kernels
+``_ssm_step_kernel`` / ``_ssm_chunk_kernel`` of ``ops/selective_scan.py``,
+or their XLA compositions) and ``mamba/out``.  An engine with state slots
+closes ``engine/decode_prep`` and ``engine/build_batch`` with
+``state_bytes`` (what the held slots take) and ``state_bytes_total`` (the
+pool's device arrays) beside ``state_slots``.
 
 Host↔device alignment, one rule: a span opened with :meth:`Tracer.span`
 is ALSO entered as a ``jax.profiler.TraceAnnotation`` of the same name

@@ -123,3 +123,25 @@ def test_plain_pools_serve_every_feature_and_two_extensions_merge():
         _engine(both).verify_step([1], [[3, 4]])
     assert STATE_SLOTS[1]["verify"] in str(err.value)
     assert WINDOW_GROUP[1]["verify"] in str(err.value)
+
+
+@pytest.mark.parametrize("feature", sorted(STATE_SLOTS[1]))
+def test_a_family_with_two_state_leaves_is_refused_what_the_table_says(
+        feature):
+    """``RaggedJamba``'s own ``state_spec`` (26 layers of a float32 scan
+    state beside a bf16 convolution tail at the published sizes; here at a
+    small one) walks the state-slot table like the stub's one leaf."""
+    from deepspeed_tpu.inference.v2.model_implementations.ragged_jamba \
+        import JambaConfig, RaggedJamba
+
+    spec = RaggedJamba(JambaConfig(
+        vocab_size=64, hidden_size=32, intermediate_size=48,
+        num_hidden_layers=2, num_attention_heads=2, attn_layer_period=2,
+        attn_layer_offset=1, mamba_d_state=4, mamba_dt_rank=4), 8).state_spec
+    assert spec["layers"] == [0] and set(spec["leaves"]) == {"ssm", "conv"}
+    assert spec["leaves"]["ssm"][1] != spec["leaves"]["conv"][1]
+    for path, call in PATHS[feature]:
+        with pytest.raises(CacheLayoutError, match=re.escape(
+                STATE_SLOTS[1][feature])) as err:
+            call({"state_spec": spec})
+        assert path in str(err.value) and "(state_spec)" in str(err.value)

@@ -497,3 +497,138 @@ def test_bert_hidden_states_match_hf(tmp_path):
     np.testing.assert_allclose(np.asarray(pooled),
                                out.pooler_output.numpy(),
                                atol=ATOL, rtol=1e-3)
+
+
+# ------------------------------------------------------------------ #
+# Jamba (dense): no transformers class is needed, the checkpoint is a
+# synthetic state dict under the published tensor names
+# ------------------------------------------------------------------ #
+JAMBA = {"model_type": "jamba", "vocab_size": 128, "hidden_size": 32,
+         "intermediate_size": 48, "num_hidden_layers": 3,
+         "num_attention_heads": 2, "num_key_value_heads": 1,
+         "attn_layer_period": 3, "attn_layer_offset": 1, "mamba_expand": 2,
+         "mamba_d_state": 4, "mamba_d_conv": 4, "mamba_dt_rank": 6,
+         "mamba_conv_bias": True, "mamba_proj_bias": False,
+         "num_experts": 1, "num_experts_per_tok": 1, "rms_norm_eps": 1e-6,
+         "max_position_embeddings": 256, "tie_word_embeddings": True,
+         "sliding_window": None}
+
+
+def test_jamba_rules_on_a_synthetic_state_dict(tmp_path):
+    """Tensors named and laid out as the published checkpoint has them
+    ([out, in] matrices, ``conv1d.weight`` [channels, 1, taps] with its
+    bias, ``A_log`` [channels, states], ``D``, the three inner norms,
+    ``feed_forward.*``, a tied ``lm_head.weight`` beside the embedding)
+    load into ``RaggedJamba``'s tree, and the engine built from the
+    directory serves the plain reference's logits, the reference fed the
+    same tensors by their published meaning."""
+    import json
+    import os
+    import sys
+
+    from safetensors.numpy import save_file
+
+    repo = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    if repo not in sys.path:
+        sys.path.insert(0, repo)
+    from benchmark.reference import jamba as reference
+    from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
+                                            RaggedInferenceEngineConfig)
+    from deepspeed_tpu.inference.v2.model_implementations import \
+        ragged_jamba as rj
+
+    rng = np.random.default_rng(8)
+    h, f, di, n, r = 32, 48, 64, 4, 6
+    g = lambda *s: rng.standard_normal(s).astype(np.float32)
+    norm = lambda w: rng.uniform(0.5, 1.5, w).astype(np.float32)
+    sd = {"model.embed_tokens.weight": g(128, h),
+          "model.final_layernorm.weight": norm(h)}
+    sd["lm_head.weight"] = sd["model.embed_tokens.weight"]
+    ref_layers = []
+    for i in range(3):
+        pre = f"model.layers.{i}."
+        sd[pre + "input_layernorm.weight"] = norm(h)
+        sd[pre + "pre_ff_layernorm.weight"] = norm(h)
+        for name, shape in (("gate", (f, h)), ("up", (f, h)),
+                            ("down", (h, f))):
+            sd[pre + f"feed_forward.{name}_proj.weight"] = \
+                g(*shape) * shape[1] ** -0.5
+        lp = {"ln1": sd[pre + "input_layernorm.weight"],
+              "ln2": sd[pre + "pre_ff_layernorm.weight"],
+              **{k: sd[pre + f"feed_forward.{k}_proj.weight"].T
+                 for k in ("gate", "up", "down")}}
+        if i == 1:
+            for name, rows in (("q", 32), ("k", 16), ("v", 16)):
+                sd[pre + f"self_attn.{name}_proj.weight"] = \
+                    g(rows, h) * h ** -0.5
+            sd[pre + "self_attn.o_proj.weight"] = g(h, 32) * 32 ** -0.5
+            lp.update({f"w{k}": sd[pre + f"self_attn.{k}_proj.weight"].T
+                       for k in "qkvo"})
+        else:
+            m = pre + "mamba."
+            sd[m + "in_proj.weight"] = g(2 * di, h) * h ** -0.5
+            sd[m + "conv1d.weight"] = g(di, 1, 4) * 0.5
+            sd[m + "conv1d.bias"] = g(di)
+            sd[m + "x_proj.weight"] = g(r + 2 * n, di) * di ** -0.5
+            sd[m + "dt_proj.weight"] = g(di, r) * r ** -0.5
+            sd[m + "dt_proj.bias"] = -3.0 + 0.5 * g(di)
+            sd[m + "A_log"] = np.log(rng.uniform(1, 4, (di, n))).astype(
+                np.float32)
+            sd[m + "D"] = g(di)
+            sd[m + "out_proj.weight"] = g(h, di) * di ** -0.5
+            for k, w in (("dt", r), ("b", n), ("c", n)):
+                sd[m + f"{k}_layernorm.weight"] = norm(w)
+            lp.update(
+                w_in=sd[m + "in_proj.weight"].T,
+                taps=sd[m + "conv1d.weight"][:, 0, :].T,
+                conv_bias=sd[m + "conv1d.bias"],
+                w_x=sd[m + "x_proj.weight"].T,
+                w_dt=sd[m + "dt_proj.weight"].T, b_dt=sd[m + "dt_proj.bias"],
+                A_log=sd[m + "A_log"], D=sd[m + "D"],
+                g_dt=sd[m + "dt_layernorm.weight"],
+                g_b=sd[m + "b_layernorm.weight"],
+                g_c=sd[m + "c_layernorm.weight"],
+                w_out=sd[m + "out_proj.weight"].T)
+        ref_layers.append(lp)
+    save_file(sd, str(tmp_path / "model.safetensors"))
+    with open(tmp_path / "config.json", "w") as fh:
+        json.dump(JAMBA, fh)
+
+    arch, cfg = config_from_hf(str(tmp_path), jnp.float32)
+    assert arch == "jamba" and type(cfg) is rj.JambaConfig
+    params = load_hf_checkpoint(str(tmp_path), dtype=jnp.float32)
+    assert jax.tree.map(lambda a: a.shape, params) == jax.tree.map(
+        lambda a: a.shape, rj.param_shapes(cfg))
+    assert "lm_head" not in params                  # tied: the embedding
+    assert params["layers_0"]["mamba"]["A_log"].shape == (n, di)
+
+    eng = InferenceEngineV2.from_hf(
+        str(tmp_path), RaggedInferenceEngineConfig.from_dict({
+            "state_manager": {"max_ragged_batch_size": 128,
+                              "max_ragged_sequence_count": 2,
+                              "max_context": 256},
+            "kv_cache": {"block_size": 8, "num_blocks": 40}}),
+        dtype=jnp.float32)
+    assert type(eng.model) is rj.RaggedJamba
+    ids = rng.integers(0, 128, size=(150 + 3,))
+    got = [np.asarray(eng.put([1], [ids[:150].tolist()])[1], np.float32)]
+    for t in ids[150:]:
+        got.append(np.asarray(jax.device_get(
+            eng.decode_step([1], [int(t)])), np.float32)[0])
+    ref = jax.tree.map(jnp.asarray, {
+        "embed": sd["model.embed_tokens.weight"], "layers": ref_layers,
+        "norm": sd["model.final_layernorm.weight"]})
+    want = reference.logits_at(ref, ids, JAMBA, rows=list(range(149, 153)))
+    assert np.max(np.abs(np.stack(got) - want)) / np.max(np.abs(want)) < 1e-4
+
+
+def test_jamba_with_routed_experts_is_refused_by_name(tmp_path):
+    import json
+
+    from deepspeed_tpu.checkpoint.hf_loader import HFLoadError
+
+    with open(tmp_path / "config.json", "w") as fh:
+        json.dump({**JAMBA, "num_experts": 16, "num_experts_per_tok": 2}, fh)
+    with pytest.raises(HFLoadError, match="num_experts=16"):
+        config_from_hf(str(tmp_path))

@@ -318,8 +318,10 @@ def test_preemption_by_recompute_gives_the_same_tokens(served):
 # (c) _causal_conv with and without the activation against a padded jnp
 # convolution; pad rows write only the scratch slot
 # ------------------------------------------------------------------ #
-@pytest.mark.parametrize("activation", [None, "silu"])
-def test_causal_conv_against_a_padded_convolution(activation):
+@pytest.mark.parametrize("activation, bias", [
+    (None, False), ("silu", False), ("silu", True), (None, True)],
+    ids=["plain", "silu", "silu_bias", "bias"])
+def test_causal_conv_against_a_padded_convolution(activation, bias):
     rng = np.random.default_rng(5)
     taps, ch, slots = 3, 8, 4
     f = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
@@ -340,11 +342,17 @@ def test_causal_conv_against_a_padded_convolution(activation):
             [9, -1, -1] + [0, 1, 2, 3, 4, -1, -1, -1]
             + [4, 5, -1, -1, -1, -1, -1, -1], jnp.int32)}
     act = conv._silu if activation else None
-    got, new_pool = conv._causal_conv(u, w, pool, batch, activation=act)
+    # a Mamba layer's convolution adds a bias a channel before the
+    # activation; the tail keeps the inputs, so it never sees the bias
+    b_c = f(ch) if bias else None
+    got, new_pool = conv._causal_conv(u, w, pool, batch, activation=act,
+                                      bias=b_c)
 
     def padded(rows, tail):
         seq = jnp.concatenate([tail, u[rows]])
         out = sum(w[j] * seq[j:j + len(rows)] for j in range(taps))
+        if bias:
+            out = out + b_c
         return (conv._silu(out) if activation else out), seq[-(taps - 1):]
 
     zero = jnp.zeros((taps - 1, ch))
@@ -356,6 +364,26 @@ def test_causal_conv_against_a_padded_convolution(activation):
         assert np.allclose(new_pool[slot], want_tail, atol=1e-6)
     # the slot no row names is bitwise as it was; pads wrote the scratch
     assert np.array_equal(new_pool[1], pool[1])
+
+
+def test_causal_conv_without_a_bias_traces_the_program_it_traced():
+    """``bias=None`` (LFM2's and Qwen3-Next's calls) adds nothing to the
+    program: the jaxpr is the one of a call that never names the argument,
+    and a bias is one more ``add`` over the rows."""
+    f = lambda *s: jnp.zeros(s, jnp.float32)
+    batch = {"chunk_start": jnp.zeros((2,), jnp.int32),
+             "state_slot": jnp.zeros((2,), jnp.int32),
+             "logits_idx": jnp.zeros((2,), jnp.int32),
+             "token_slot": jnp.zeros((16,), jnp.int32),
+             "token_pos": jnp.zeros((16,), jnp.int32)}
+    args = (f(16, 8), f(3, 8), f(3, 2, 8), batch)
+    plain = str(jax.make_jaxpr(lambda *a: conv._causal_conv(*a))(*args))
+    named = str(jax.make_jaxpr(
+        lambda *a: conv._causal_conv(*a, bias=None))(*args))
+    biased = str(jax.make_jaxpr(
+        lambda *a: conv._causal_conv(*a, bias=f(8)))(*args))
+    assert plain == named
+    assert biased.count(" add ") == plain.count(" add ") + 1
 
 
 def test_pad_rows_and_padded_tails_change_no_other_slot():

@@ -844,6 +844,8 @@ _KERNEL_ENTRIES = [
     ("gmm_drhs", "_gmm_drhs_kernel"),
     ("gdn_step", "_gdn_step_kernel"),
     ("gdn_chunk", "_gdn_chunk_kernel"),
+    ("ssm_step", "_ssm_step_kernel"),
+    ("ssm_chunk", "_ssm_chunk_kernel"),
     ("quantized_matmul", "_qmm_kernel"),
     ("quantizer_int8", "_quantize_kernel"),
     ("block_sparse_attention", "_fwd_kernel"),
@@ -882,7 +884,7 @@ def test_every_pallas_call_site_is_covered():
         src = (root.parent / (mod.replace(".", "/") + ".py")).read_text()
         sites += len(re.findall(r"pl\.pallas_call\(", src))
         named += len(re.findall(r"\*\*kernel_names\(", src))
-    assert sites == named == 27
+    assert sites == named == 29
 
 
 def test_paged_wrappers_keep_their_instruction_names():

@@ -628,6 +628,79 @@ def latent_prefill_cell_case(starts=(3072,), rows: int = 1024,
     return out
 
 
+def ssm_case(which: str, layers: int = 26, repeats: int = 10) -> dict:
+    """The selective scan at the Jamba2-3B cell's shapes (N 16, Di 5120, a
+    pool of 257 slots) against its XLA composition, with microseconds a
+    call beside the least time the chip could take for what the recurrence
+    requires (bytes over the HBM peak: every live slot read and written
+    once, the rows once).  ``which``: ``step`` (256 rows, 40 of them pad
+    rows on the scratch slot, 3 reset) or ``chunk`` (1,024 rows in 8 tiles
+    of 128: three sequences of 3 + 3 + 1 tiles, the last 40 rows short,
+    and a pad tile)."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops import selective_scan as ss
+
+    n, di, slots = 16, 5120, 256
+    rows = 256 if which == "step" else 1024
+    ks = jax.random.split(jax.random.fold_in(jax.random.key(0), 31), 7)
+    pool = jax.random.normal(ks[0], (slots + 1, n, di))
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (rows, di)) - 4.0)
+    x = jax.random.normal(ks[2], (rows, di))
+    b, c = (jax.random.normal(k, (rows, n)) for k in ks[3:5])
+    a = -jnp.exp(jax.random.uniform(ks[5], (n, di), minval=0.0, maxval=2.8))
+    if which == "step":
+        live = 216
+        slot = jnp.where(jnp.arange(rows) < live,
+                         jax.random.permutation(ks[6], slots)[:rows], slots)
+        reset = jnp.arange(rows) % 70 == 5
+        args = (slot.astype(jnp.int32), reset)
+        kernel = lambda p, *r: ss.ssm_step(p, *r, interpret=False)
+        oracle = ss.ssm_step_reference
+        moved = live * (2 * n * di * 4 + (3 * di + 2 * n) * 4)
+        keep = lambda y, p: (y[:live], p[:slots])
+    else:
+        real = (jnp.arange(rows) < 856)[:, None]
+        dt = jnp.where(real, dt, 0.0)
+        args = (jnp.asarray([9, 9, 9, 200, 200, 200, 4, slots], jnp.int32),
+                jnp.asarray([1, 0, 0, 0, 0, 0, 1, 0], bool), 128)
+        kernel = lambda p, *r: ss.ssm_chunk(p, *r, interpret=False)
+        oracle = ss.ssm_chunk_reference
+        moved = 3 * 2 * n * di * 4 + 856 * (3 * di + 2 * n) * 4
+        keep = lambda y, p: (y[:856], p[:slots])
+    ops = (pool, dt, dt * x, b, c, a) + args
+    got, want = keep(*kernel(*ops)), keep(*oracle(*ops))
+    scale = max(float(jnp.max(jnp.abs(w))) for w in want)
+    err = max(float(jnp.max(jnp.abs(g - w))) for g, w in zip(got, want))
+
+    # a pool a layer, as the model has (one pool read 26 times over would
+    # measure whatever the chip keeps of 84 MB between calls), donated so
+    # that the update is in place as in the step programs
+    def stacked(pools, *rest):
+        y, out = 0.0, []
+        for p in pools:
+            o, p = kernel(p, *rest)
+            y, out = y + o, out + [p]
+        return y, out
+
+    run = jax.jit(stacked, donate_argnums=0,
+                  static_argnums=(8,) if which == "chunk" else ())
+    pools = [pool + 0.0 for _ in range(layers)]
+    y, pools = run(pools, *ops[1:])
+    y.block_until_ready()
+    t0 = time.perf_counter()
+    for _ in range(repeats):
+        y, pools = run(pools, *ops[1:])
+    y.block_until_ready()
+    us = (time.perf_counter() - t0) / repeats / layers * 1e6
+    return {"max_err": round(err / scale, 7), "ok": bool(err / scale < 1e-4),
+            "us_per_call": round(us, 1),
+            "least_us": round(moved / 819e9 * 1e6, 1)}
+
+
 def run_selftest(tol: float = 3e-2) -> dict:
     """Returns {kernel_name: {"max_err": float, "ok": bool}} plus an
     overall "ok". Skips (with a note) off-TPU."""
@@ -1060,6 +1133,12 @@ def run_selftest(tol: float = 3e-2) -> dict:
 
     guarded("gdn_step", gdn_step_case)
     guarded("gdn_chunk", gdn_chunk_case)
+
+    # ---- selective scan (Mamba): both kernels at the Jamba2-3B cell's
+    # shapes against their XLA compositions, float32 throughout ---- #
+    for which in ("step", "chunk"):
+        guarded("ssm_" + which, lambda w=which: results.update(
+            {"ssm_" + w: ssm_case(w)}))
 
     # ---- int8-resident quantized matmul ---- #
     from deepspeed_tpu.ops.quantized_matmul import (
