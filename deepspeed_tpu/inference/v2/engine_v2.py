@@ -275,6 +275,9 @@ class InferenceEngineV2:
         #: device-resident decode metadata (block tables + positions),
         #: re-uploaded only when the host scheduler changes a table
         self._dev_decode_state: Optional[Dict[str, Any]] = None
+        #: the jitted gather behind ``decode_step(rows=...)``
+        #: (``_build_token_gather``)
+        self._gather_tokens = None
         #: ``sm.win_released`` as of the last span that reported it
         self._win_reported = 0
         log_dist(
@@ -770,7 +773,8 @@ class InferenceEngineV2:
     # exists to hide.
     # ------------------------------------------------------------------ #
     def decode_step(self, uids: Sequence[int], tokens,
-                    greedy: bool = False):
+                    greedy: bool = False,
+                    rows: Optional[Sequence[int]] = None):
         """One continuous-batching decode step with device-resident token
         feedback.
 
@@ -779,11 +783,19 @@ class InferenceEngineV2:
         the greedy tokens the previous call returned, which never leave
         the device.  The caller keeps that array: the serving scheduler
         holds the ``next_tokens`` of the step in flight, feeds it to the
-        next step over the same rows before it has fetched it, and reports
-        the values through :meth:`record_device_tokens` once it has, which
-        is what the prefix cache registers decoded blocks from.  Every
-        ``uids[i]`` must be live with no pending prompt tokens (run
-        :meth:`put` first).
+        next step before it has fetched it, and reports the values through
+        :meth:`record_device_tokens` once it has, which is what the prefix
+        cache registers decoded blocks from.  Every ``uids[i]`` must be
+        live with no pending prompt tokens (run :meth:`put` first).
+
+        ``rows`` (with a device ``tokens`` only): the step that produced
+        ``tokens`` ran over more rows than go on, and ``uids[i]``'s token
+        is at ``tokens[rows[i]]`` (the scheduler's rows that go on: those
+        the step before neither ended by length nor lost meanwhile).  The
+        engine gathers them into this step's row order on the device, one
+        fixed-shape program that is built with the decode step program and
+        is none of ``step_keys``; the caller never touches the array.  None:
+        ``uids[i]``'s token is ``tokens[i]``.
 
         Returns logits ``[max_seqs, vocab]`` as a device array WITHOUT
         host synchronisation; rows ``>= len(uids)`` are padding.  With
@@ -807,6 +819,9 @@ class InferenceEngineV2:
         with open_span(self.tracer, "engine/decode_prep") as span:
             seqs, state = self._prepare_decode(uids)
             tok = self._as_token_array(tokens, n, S)
+            if rows is not None:    # (pad rows read row 0: never used)
+                tok = self._gather_tokens(
+                    tok, self._as_token_array(rows, n, S))
             if type(span) is SpanHandle:
                 span.attrs = {"seqs": n}    # live rows of the S it runs
                 if self._stateful:
@@ -826,6 +841,8 @@ class InferenceEngineV2:
             self._recover_donated_cache()
             raise
         sm.kv_cache.update(new_cache)
+        if self._gather_tokens is None:
+            self._build_token_gather(nxt)
         host_toks = (None if isinstance(tokens, jax.Array)
                      else [int(t) for t in tokens])
         for i, seq in enumerate(seqs):
@@ -1063,6 +1080,17 @@ class InferenceEngineV2:
         runner = jax.jit(_named(run, "decode_step"), donate_argnums=(1, 3))
         self._steps[key] = runner
         return runner
+
+    def _build_token_gather(self, nxt: jax.Array) -> None:
+        """``decode_step``'s reorder of a step's tokens into the rows of the
+        step after it (``rows``): built right after the first execution of
+        the step program and compiled on that execution's own ``nxt``, so
+        that the placement it is compiled for is the one it will meet and
+        no later tick builds a program.  No step program: not in
+        ``_steps``."""
+        self._gather_tokens = jax.jit(_named(
+            lambda tok, rows: tok[rows], "gather_tokens"))
+        self._gather_tokens(nxt, self._as_token_array((), 0, nxt.shape[0]))
 
     # ------------------------------------------------------------------ #
     # Speculative decoding: multi-token verify (ROADMAP item 1).  One

@@ -173,9 +173,12 @@ span                     site                        parent    attrs (counters)
                          unless that is in flight,
                          and of the step after it
                          when the next tick decodes
-                         the same rows (so 0, 1 or
-                         2 a tick, one a tick over
-                         a run of decode ticks)
+                         the rows that go on (all
+                         of them, or those the
+                         returned step does not end
+                         by length: so 0, 1 or 2 a
+                         tick, one a tick over a
+                         run of decode ticks)
 ``engine/verify_step``   the step's dispatch         verify    the launch record
 ``fetch``                ``scheduler._fetch``: the   decode /  ``launch``: the
                          blocking ``device_get`` of  verify /  launch it retires

@@ -32,11 +32,15 @@ stochastic row and the tick fetches the logits for the (seed, uid,
 position)-keyed host sampler, as every tick once did.
 
 A greedy pure-decode tick does not wait for its own tokens before it hands
-the device the next step: while the host can tell that the next tick will
-decode exactly the same rows, it dispatches that step on the device-resident
+the device the next step: while the host can tell that the next tick will be
+a pure-decode tick too, it dispatches that step on the device-resident
 tokens of this one, then fetches this one's (:class:`_InFlight`,
-``_fast_decode_tick``).  The device runs one program behind the other while
-the host advances, packs and prepares.
+``_fast_decode_tick``).  The step goes out over THE ROWS THAT GO ON: the rows
+of this step whose request has not ended and does not end by length with the
+token this step hands it (``_rows_going_on``; the engine gathers their tokens
+into their new rows).  So the tick in which a request ends leaves a program
+in flight like any other.  The device runs one program behind the other
+while the host advances, packs and prepares.
 
 A greedy ragged batch is not built with the chip empty either: with a program
 in flight (a ragged step, or a decode step run ahead) and a ragged batch due
@@ -102,7 +106,11 @@ class _InFlight:
     """A greedy step program whose tokens are still on the device: the
     scheduler's one piece of state about a program in flight.  A
     ``decode_step`` hands every row of ``packed`` a token; a ragged step
-    (``ragged``) those whose feed it completes."""
+    (``ragged``) those whose feed it completes.  Of those rows, the ones
+    that go on (``_rows_going_on``: not ended, and not ended by length
+    with that token) are the rows of whatever is sent behind it: the next
+    decode step, whose ``packed`` / ``rows`` are they in their new order,
+    or the late rows of the ragged batch prepared under it."""
 
     #: the requests it was packed from, in row order
     packed: List[Request]
@@ -796,10 +804,14 @@ class ContinuousBatchScheduler:
         The greedy tick keeps its tokens on the device until it has given
         the device its next program: the step whose tokens this tick
         returns is the one in flight (dispatched during the tick before)
-        or is dispatched now; then, if the next tick will decode the same
-        rows (``_same_rows_next_tick``), the step after it is dispatched
-        on this step's device-resident ``nxt``; only then are this step's
-        tokens fetched and handed out (``_consume``).  The ``decode`` span
+        or is dispatched now; then, if the next tick will be a pure-decode
+        tick too, the step after it is dispatched over the rows that go on
+        (``_next_decode_rows``: all of this step's, or those its token does
+        not end by length) on this step's device-resident ``nxt``; only
+        then are this step's tokens fetched and handed out (``_consume``).
+        So the tick in which a row ends by length leaves a program in
+        flight all the same, and the arrival that follows the finish is
+        packed and built under it (``_tick_ahead``).  The ``decode`` span
         closes with ``steps`` (1), ``ahead`` (was the returned step
         dispatched a tick ago) and ``read_blocks`` (the table blocks that
         step's rows hold up to the positions it fed: what its attention
@@ -827,9 +839,14 @@ class ContinuousBatchScheduler:
                     if step is None:
                         step = self._dispatch_decode(
                             uids, packed, [c[0] for c in chunks], ahead=0)
-                    if self._same_rows_next_tick(step, uids, packed):
+                    on = self._next_decode_rows(step, packed)
+                    if on:
+                        rows = [row for _, row in on]
+                        if rows == list(range(len(on))):
+                            rows = None     # each stays where it stood
                         self._inflight = self._dispatch_decode(
-                            uids, packed, step.nxt, ahead=1)
+                            [r.uid for r, _ in on], [r for r, _ in on],
+                            step.nxt, ahead=1, rows=rows)
                 except Exception:
                     self._abandon()
                     raise
@@ -847,39 +864,64 @@ class ContinuousBatchScheduler:
         bs = self.engine.state_manager.block_size
         return sum(r.fed // bs + 1 for r in packed)
 
-    def _dispatch_decode(self, uids, packed, tokens,
-                         ahead: int) -> _InFlight:
+    def _dispatch_decode(self, uids, packed, tokens, ahead: int,
+                         rows: Optional[List[int]] = None) -> _InFlight:
         """One greedy ``decode_step`` over ``packed``'s rows, fed
-        ``tokens`` (host ints, or the ``nxt`` of the step before it)."""
-        _, nxt = self.engine.decode_step(uids, tokens, greedy=True)
+        ``tokens``: host ints, or the ``nxt`` of the step before it, where
+        ``rows[i]`` is the row of it that holds ``packed[i]``'s token (None:
+        row ``i``; the engine does the gather)."""
+        _, nxt = self.engine.decode_step(uids, tokens, greedy=True,
+                                         rows=rows)
         return _InFlight(packed, list(zip(packed, range(len(packed)))), nxt,
                          ahead, self.engine.last_launch)
 
-    def _same_rows_next_tick(self, step: _InFlight, uids, packed) -> bool:
-        """Can the host tell, before ``step``'s tokens arrive, that the
-        next tick will be a greedy pure-decode tick over exactly its rows
-        in the same order?  Read from the host's own state: no speculation
-        (its verify pass packs the tick), nothing waiting to join (queue,
-        preempted, a running request mid-prefill), no row that stopped
-        while ``step`` was in flight, none that reaches ``max_new_tokens``
-        or ``max_context`` with ``step``'s token or whose deadline falls
-        due within a tick, and KV room for every row's next position
-        without a preemption.  A stop token cannot be told in advance: its
-        row of the step dispatched ahead is dropped (``_consume``)."""
-        if (self._spec_active is not None or self._queued
-                or self._preempted or len(packed) != len(step.packed)
-                or len(self._running) != len(packed)):
-            return False
-        due = time.monotonic() + self._tick_s
-        for r in packed:
+    def _rows_going_on(self, step: _InFlight) -> List[Tuple[Request, int]]:
+        """The rows of ``step``, a program in flight, that decode next on
+        the token it hands them, each with its row of ``step.nxt``: every
+        row it hands a token whose request has not ended while it was in
+        flight (a stop token a tick ago, a failure) and does not end by
+        length with that token (``max_new_tokens`` or ``max_context``
+        reached with it).  All the host can tell before the token exists: a
+        stop token it cannot, and that row is dropped when the step sent
+        ahead over it is consumed (``_advance_step``) or the batch prepared
+        on it discarded (``_tick_ahead``).  The one rule of both ways of
+        running ahead: the decode step dispatched behind a decode step
+        (``_next_decode_rows``) and the late rows of a ragged batch prepared
+        under a program (``_pack_decodes``)."""
+        on = []
+        for r, row in step.rows:
             n = len(r.generated) + 1
-            if n >= r.sampling.max_new_tokens \
-                    or len(r.prompt) + n >= self.max_context:
-                return False
-            if r.deadline_s is not None \
-                    and due - r.arrival_time > r.deadline_s:
-                return False
-        return self.engine.can_schedule(uids, [1] * len(uids))
+            if r.finish_reason is None \
+                    and n < r.sampling.max_new_tokens \
+                    and len(r.prompt) + n < self.max_context:
+                on.append((r, row))
+        return on
+
+    def _next_decode_rows(self, step: _InFlight,
+                          packed) -> List[Tuple[Request, int]]:
+        """The rows to dispatch the decode step after ``step`` over, before
+        ``step``'s tokens arrive: its rows that go on (``_rows_going_on``),
+        when the host can tell that the next tick will be a greedy
+        pure-decode tick over exactly those; else none.  Read from the
+        host's own state: no speculation (its verify pass packs the tick),
+        nothing waiting to join (queue, preempted, a running request that
+        is not among ``packed``, the live rows of ``step``: it is
+        mid-prefill), no row that goes on whose deadline falls due within
+        a tick, and KV room for each one's next position without a
+        preemption (reckoned with the blocks of the rows that end still
+        held: they are freed when ``step`` is consumed)."""
+        if (self._spec_active is not None or self._queued
+                or self._preempted or len(self._running) != len(packed)):
+            return []
+        on = self._rows_going_on(step)
+        due = time.monotonic() + self._tick_s
+        if any(r.deadline_s is not None
+               and due - r.arrival_time > r.deadline_s for r, _ in on):
+            return []
+        if on and self.engine.can_schedule([r.uid for r, _ in on],
+                                           [1] * len(on)):
+            return on
+        return []
 
     def _consume(self, step: _InFlight) -> List[Tuple[Request, int]]:
         """Fetch ``step``'s tokens and hand each to its request, as the
@@ -1100,8 +1142,7 @@ class ContinuousBatchScheduler:
         With ``after``, a program in flight, the set is that of the tick
         after it: the rows it hands a token decode on that token, which
         does not exist yet (late rows: a placeholder is packed), unless it
-        ends them by length (``max_new_tokens`` or ``max_context``
-        reached with it: what ``_same_rows_next_tick`` tests).  Returns
+        ends them by length (``_rows_going_on``).  Returns
         ``{late request: its row of after.nxt}``, or None when that set
         does not fit: a preemption settles the program in flight first, so
         it is the ordinary tick's to make."""
@@ -1109,12 +1150,7 @@ class ContinuousBatchScheduler:
         in_step = ()
         if after is not None:
             in_step = {r for r, _ in after.rows}
-            for r, slot in after.rows:
-                n = len(r.generated) + 1
-                if r.finish_reason is None \
-                        and n < r.sampling.max_new_tokens \
-                        and len(r.prompt) + n < self.max_context:
-                    late[r] = slot
+            late = dict(self._rows_going_on(after))
         decodes = sorted(
             [r for r in self._running.values()
              if r.remaining_feed == 1 and r not in in_step] + list(late),

@@ -245,6 +245,43 @@ def test_interleaved_sequences_equal_their_solo_runs(solo_runs):
     assert eng.occupancy()["observability/state_slots_held"] == 0.0
 
 
+def test_rows_that_go_on_keep_their_state_slots(solo_runs):
+    """Three requests decode and end by length one after the other.  The
+    tick that returns a row's last token sends the next decode step ahead
+    over the rows that go on, in a new order: each row's state slot goes
+    with it (the slots the device holds for that step are its uids' own,
+    the ones the step before held at the rows the tokens are gathered
+    from), and every request ends with the tokens of its solo run."""
+    params, prompts, want = solo_runs
+    eng = _engine(params)
+    sched = ContinuousBatchScheduler(eng)
+    real, gathered = eng.decode_step, []
+
+    def slots():
+        return np.asarray(eng._dev_decode_state["slots"][0])
+
+    def decode_step(uids, tokens, greedy=False, rows=None):
+        before = slots() if rows is not None else None
+        out = real(uids, tokens, greedy=greedy, rows=rows)
+        if rows is not None:
+            mine = [eng.state_manager.get_sequence(u).state_slot
+                    for u in uids]
+            assert slots()[:len(uids)].tolist() == mine == \
+                before[list(rows)].tolist()
+            gathered.append((list(uids), list(rows)))
+        return out
+
+    eng.decode_step = decode_step
+    picked = (4, 3, 1)                      # 6, 12 and 9 new tokens
+    reqs = [sched.submit(prompts[i], _greedy(NEW[i])) for i in picked]
+    sched.run_until_idle()
+    assert [list(r.generated) for r in reqs] == [want[i] for i in picked]
+    # the first row ended first: the two behind it moved up (the row that
+    # is left when the next one ends stands where it stood: no gather)
+    assert gathered == [([reqs[1].uid, reqs[2].uid], [1, 2])]
+    assert eng.state_manager.state_pool.held == 0
+
+
 def test_interleaved_logits_match_each_reference(solo_runs):
     from interleaved_logits import serve_and_compare
 

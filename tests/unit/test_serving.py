@@ -731,9 +731,9 @@ def _spy_paths(engine):
 
     engine.prepare = prepare
 
-    def ds(uids, tokens, greedy=False):
+    def ds(uids, tokens, greedy=False, rows=None):
         paths.append(("decode_step", len(uids)))
-        return orig_ds(uids, tokens, greedy=greedy)
+        return orig_ds(uids, tokens, greedy=greedy, rows=rows)
 
     engine.put, engine.decode_step = put, ds
     return paths
@@ -988,18 +988,169 @@ def _sc_deadline_expires(sched, mk):
     return [ra, rb]
 
 
+# -- the tick in which a row ends by length sends the next step ahead over
+# -- the rows that go on (PR 47) ---------------------------------------- #
+def _to_a_finish(sched, first):
+    """Tick until ``first`` has ended by length.  A scheduler that runs
+    ahead did not stop for it: the decode step after the one that ended it
+    is in flight over the rows that go on (or the scenario is not testing
+    what it says it is)."""
+    while first.finish_reason is None:
+        sched.step()
+    assert first.finish_reason == "length"
+    if sched.fast_decode:
+        step = sched._inflight
+        assert step is not None and not step.ragged and step.ahead == 1
+        assert step.packed and first not in step.packed
+        assert [row for _, row in step.rows] == list(range(len(step.packed)))
+
+
+def _survivor_rows(sched, seed=42):
+    """Three rows decoding, the first of them just ended by length."""
+    reqs = [sched.submit(p, _greedy(n))
+            for p, n in zip(_ahead_prompts(3, seed=seed), (4, 12, 12))]
+    _to_a_finish(sched, reqs[0])
+    return reqs
+
+
+def _sc_survivors_go_ahead(sched, mk):
+    """Nothing arrives: in each tick in which a row ends, a device-fed
+    ``decode_step`` goes out over fewer uids than the step before it, told
+    where in that step's tokens each of them stands."""
+    reqs = [sched.submit(p, _greedy(n))
+            for p, n in zip(_ahead_prompts(4, seed=43), (3, 5, 5, 9))]
+    calls = sched.engine.decode_step.calls
+    _to_a_finish(sched, reqs[0])
+    if sched.fast_decode:
+        (before, _), (uids, rows) = calls[-2:]
+        assert before == [r.uid for r in reqs] and uids == before[1:]
+        assert rows == [1, 2, 3]
+    _to_a_finish(sched, reqs[1])
+    if sched.fast_decode:                  # two rows ended in one tick
+        assert calls[-1] == ([reqs[3].uid], [2])
+    sched.run_until_idle()
+    if sched.fast_decode:       # the others: all rows, no gather asked for
+        assert sum(rows is not None for _, rows in calls) == 2
+    return reqs
+
+
+def _sc_survivors_meet_stop_tokens(sched, mk):
+    """Two stop tokens around the step sent ahead over the survivors: one
+    arrives in the tick of the finish (its row of that step is dropped, and
+    the step after goes out over the rows left, by a gather again), one
+    with that step itself."""
+    prompts = _ahead_prompts(4, seed=44)
+    ref = _greedy_reference(sched.engine.params, prompts, n_new=8)
+    stops = [next(t for i, t in enumerate(r) if i >= at and r.index(t) == i)
+             for r, at in ((ref[1], 3), (ref[2], 4))]
+    at = [ref[1].index(stops[0]), ref[2].index(stops[1])]
+    reqs = [sched.submit(prompts[0], _greedy(at[0] + 1)),
+            sched.submit(prompts[1], _greedy(12, stop_token_ids=stops[:1])),
+            sched.submit(prompts[2], _greedy(12, stop_token_ids=stops[1:])),
+            sched.submit(prompts[3], _greedy(10))]
+    calls = sched.engine.decode_step.calls
+    _to_a_finish(sched, reqs[0])
+    assert reqs[1].finish_reason == "stop"      # in that very tick
+    if sched.fast_decode:
+        assert reqs[1] in sched._inflight.packed
+    while reqs[2].finish_reason is None:
+        sched.step()
+    assert [r.finish_reason for r in reqs] == ["length", "stop", "stop", None]
+    assert [r.generated[-1] for r in reqs[1:3]] == stops
+    if sched.fast_decode:
+        assert [u for u, rows in calls if rows is not None][:2] == \
+            [[r.uid for r in reqs[1:]], [r.uid for r in reqs[2:]]]
+    sched.run_until_idle()
+    return reqs
+
+
+def _sc_survivors_to_max_context(sched, mk):
+    # max_context 32: the 20-token prompt ends by context while the third
+    # row goes on, after the first ended by its budget
+    prompts = _ahead_prompts(1, seed=45) + \
+        _ahead_prompts(1, seed=46, lo=20, hi=21) + _ahead_prompts(1, seed=47)
+    reqs = [sched.submit(p, _greedy(n))
+            for p, n in zip(prompts, (4, 64, 18))]
+    _to_a_finish(sched, reqs[0])
+    _to_a_finish(sched, reqs[1])
+    assert len(reqs[1].history) == 32
+    sched.run_until_idle()
+    return reqs
+
+
+def _sc_survivors_deadline(sched, mk):
+    reqs = _survivor_rows(sched)
+    had = list(reqs[1].generated)
+    reqs[1].deadline_s = 500.0
+    reqs[1].arrival_time -= 1000.0
+    sched.step()
+    assert reqs[1].finish_reason == "deadline" and \
+        reqs[1].state is RequestState.FAILED
+    # the token of the step in flight over it was dropped, not handed out
+    assert reqs[1].generated == had
+    assert sched.engine.state_manager.get_sequence(reqs[1].uid) is None
+    sched.run_until_idle()
+    return reqs
+
+
+def _sc_survivors_stochastic_arrival(sched, mk):
+    """A stochastic request arrives after the finish: no batch can be
+    prepared on device tokens for it, the step in flight is returned as a
+    decode tick and the arrival joins at level."""
+    reqs = _survivor_rows(sched, seed=48)
+    reqs.append(sched.submit(_ahead_prompts(1, seed=49)[0], SamplingParams(
+        greedy=False, temperature=0.8, top_k=8, seed=3, max_new_tokens=6),
+        uid=73))
+    before = sched.ragged_ahead_ticks
+    sched.run_until_idle()
+    assert sched.ragged_ahead_ticks == before
+    return reqs
+
+
+def _sc_survivors_under_kv_pressure(sched, mk):
+    """Six usable blocks, staggered budgets and an arrival every other
+    tick: rows end while others go on, arrivals are prepared under the
+    survivors' step, and the decode set outgrows the pool."""
+    prompts = _ahead_prompts(6, seed=19, lo=6, hi=16)
+    new = (5, 8, 6, 9, 7, 8)
+    reqs, tick = [], 0
+    while len(reqs) < len(prompts) or sched.num_pending:
+        if len(reqs) < len(prompts) and tick % 2 == 0:
+            reqs.append(sched.submit(prompts[len(reqs)],
+                                     _greedy(new[len(reqs)])))
+        sched.step()
+        tick += 1
+        assert tick < 2000
+    assert sched.metrics.preemptions >= 1
+    if sched.fast_decode:
+        assert any(rows is not None
+                   for _, rows in sched.engine.decode_step.calls)
+    return reqs
+
+
+def _sc_survivors_shutdown_drains(sched, mk):
+    reqs = _survivor_rows(sched, seed=50)
+    assert sched.shutdown(30.0) is True
+    assert sched.num_pending == 0 and sched._inflight is None
+    return reqs
+
+
 _AHEAD_SCENARIOS = [
     _sc_length_staggered, _sc_stop_token_in_flight, _sc_max_context,
     _sc_arrival_mid_run, _sc_stochastic_joins,
     _sc_preemption_under_kv_pressure, _sc_handoff_with_kv,
     _sc_flush_to_host, _sc_shutdown_drains, _sc_shutdown_hands_off,
-    _sc_deadline_expires]
+    _sc_deadline_expires, _sc_survivors_go_ahead,
+    _sc_survivors_meet_stop_tokens, _sc_survivors_to_max_context,
+    _sc_survivors_deadline, _sc_survivors_stochastic_arrival,
+    _sc_survivors_under_kv_pressure, _sc_survivors_shutdown_drains]
 
 
 @pytest.mark.parametrize("scenario", _AHEAD_SCENARIOS,
                          ids=[f.__name__[4:] for f in _AHEAD_SCENARIOS])
 def test_running_ahead_matches_sequential_decode(params, scenario):
-    tight = scenario is _sc_preemption_under_kv_pressure
+    tight = scenario in (_sc_preemption_under_kv_pressure,
+                         _sc_survivors_under_kv_pressure)
 
     def run(fast):
         def mk():
@@ -1024,31 +1175,40 @@ def test_running_ahead_matches_sequential_decode(params, scenario):
 
 
 def _spy_device_steps(engine):
-    """The ``decode_step`` calls fed a device array: the steps dispatched
-    ahead."""
+    """The ``decode_step`` calls fed a device array, the steps dispatched
+    ahead: ``(uids, rows)`` of each (also as ``engine.decode_step.calls``)."""
     calls, orig = [], engine.decode_step
 
-    def ds(uids, tokens, greedy=False):
+    def ds(uids, tokens, greedy=False, rows=None):
         if isinstance(tokens, jax.Array):
-            calls.append(list(uids))
-        return orig(uids, tokens, greedy=greedy)
+            calls.append((list(uids), rows))
+        else:
+            assert rows is None
+        return orig(uids, tokens, greedy=greedy, rows=rows)
 
+    ds.calls = calls
     engine.decode_step = ds
     return calls
 
 
-def test_failed_fetch_of_a_step_ahead_recovers(params, monkeypatch):
-    """The fetch of a step that was dispatched ahead fails: the error
-    comes out of the tick that owns the step, nothing of it or of the step
-    behind it stays in the engine, and the rows recompute to the streams
-    they would have had."""
+@pytest.mark.parametrize("new", [(9, 9), (3, 9)],
+                         ids=["same_rows", "survivors"])
+def test_failed_fetch_of_a_step_ahead_recovers(params, monkeypatch, new):
+    """The fetch of a step that was dispatched ahead fails (over the same
+    rows as the step before it, or over the row that goes on after the
+    other ended by length): the error comes out of the tick that owns the
+    step, nothing of it or of the step behind it stays in the engine, and
+    the rows recompute to the streams they would have had."""
     prompts = _ahead_prompts(2, seed=40)
-    want = _greedy_reference(params, prompts, n_new=9)
+    want = [w[:n] for w, n in
+            zip(_greedy_reference(params, prompts, n_new=9), new)]
     eng = _engine(params)
     sched = ContinuousBatchScheduler(eng)
-    reqs = [sched.submit(p, _greedy(9)) for p in prompts]
+    reqs = [sched.submit(p, _greedy(n)) for p, n in zip(prompts, new)]
     _steps(sched, 3)
     before = [list(r.generated) for r in reqs]
+    live = [r for r in reqs if r.finish_reason is None]
+    assert len(live) == (1 if new[0] == 3 else 2)
     real_fetch, recovered = sched._fetch, []
     real_recover = eng._recover_donated_cache
 
@@ -1059,7 +1219,7 @@ def test_failed_fetch_of_a_step_ahead_recovers(params, monkeypatch):
     monkeypatch.setattr(sched, "_fetch", failing)
     monkeypatch.setattr(eng, "_recover_donated_cache",
                         lambda: (recovered.append(1), real_recover()))
-    assert sched._inflight.ahead == 1
+    assert sched._inflight.ahead == 1 and sched._inflight.packed == live
     with pytest.raises(RuntimeError, match="device lost"):
         sched.step()
     assert recovered == [1] and sched._inflight is None
@@ -1067,7 +1227,7 @@ def test_failed_fetch_of_a_step_ahead_recovers(params, monkeypatch):
     assert sm.n_tracked_sequences == 0 and eng._dev_decode_state is None
     assert sm.free_blocks == sm.allocator.num_blocks - 1
     assert [r.generated for r in reqs] == before      # nothing handed out
-    assert all(r.state is RequestState.PREEMPTED for r in reqs)
+    assert all(r.state is RequestState.PREEMPTED for r in live)
     sched.run_until_idle()
     assert [r.generated for r in reqs] == want
     assert sm.n_tracked_sequences == 0
@@ -1330,6 +1490,38 @@ def _rg_grouped_pool_model(sched, mk):
     return _staggered(sched, prompts, (6, 5, 7, 4))
 
 
+def _rg_closed_loop(sched, mk):
+    """Three callers, each submitting its next request the moment its last
+    one is done, as the benchmark's closed loop does: every arrival follows
+    a finish by length, finds a program in flight (the decode step that was
+    sent ahead over the rows that go on, or the ragged step launched by
+    the tick that handed the last token out) and its batch is prepared
+    under that program."""
+    rng = np.random.default_rng(68)
+    budgets = [[3, 4, 6, 3], [5, 3, 4, 5], [18]]
+    busy, reqs, arrivals = [None] * len(budgets), [], 0
+    start = sched.ragged_ahead_ticks
+    while any(busy) or any(budgets):
+        for c, r in enumerate(busy):
+            if r is not None and r.finish_reason is not None:
+                busy[c] = None
+            if busy[c] is None and budgets[c]:
+                if len(reqs) >= len(budgets):       # it follows a finish
+                    arrivals += 1
+                    assert sched._inflight is not None or \
+                        not sched.fast_decode
+                busy[c] = sched.submit(
+                    rng.integers(0, CFG.vocab_size,
+                                 size=(int(rng.integers(5, 20)),)).tolist(),
+                    _greedy(budgets[c].pop(0)))
+                reqs.append(busy[c])
+        sched.step()
+    assert arrivals == 6
+    if sched.fast_decode:
+        assert sched.ragged_ahead_ticks - start == arrivals
+    return reqs
+
+
 def _lfm2_engine():
     import test_ragged_lfm2 as lfm2
     return lfm2._engine(lfm2._seeded_params(), max_seqs=8)
@@ -1349,6 +1541,7 @@ _RAGGED_SCENARIOS = {
     _rg_handoff_with_kv: {}, _rg_flush_to_host: {},
     _rg_shutdown_hands_off: {}, _rg_deadline_expires: {},
     _rg_prefix_hit_under_a_step: dict(prefix=True),
+    _rg_closed_loop: {},
     _rg_stateful_model: dict(engine=_lfm2_engine),
     _rg_grouped_pool_model: dict(engine=_afmoe_engine)}
 

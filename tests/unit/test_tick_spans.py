@@ -169,8 +169,10 @@ def test_pure_decode_tick_tree(traced):
         counters = kids["decode"][0]["attrs"]
         assert set(counters) == {"ahead", "steps", "read_blocks"} \
             and counters["steps"] == 1
-        # a step in flight is not dispatched again; one that is not, is
-        assert (len(steps) >= 1) == (counters["ahead"] == 0)
+        # a step in flight is not dispatched again; one that is not, is;
+        # beside it, at most the step after it
+        assert (counters["ahead"] == 0) <= len(steps) <= \
+            2 - counters["ahead"]
         # a counter is recorded once, where something reads it: the live
         # rows of each step on its engine/decode_prep (``gmm_roofline_pct``
         # of the benchmark reads it), the mechanism's on the decode span
@@ -191,20 +193,21 @@ def test_decode_span_counts_the_steps_ahead(traced):
     """``_drive``: r1 decodes alone (a step, and the next dispatched ahead),
     r2 arrives while that one is in flight (the tick prepares the mixed
     batch under it, returns its token and launches the batch on it), two
-    rows decode until r2's third token (ahead of its last token: the row
-    still runs; not past it), then r1 alone to its eighth.  Every run of
-    decode ticks hands the device one program a tick, but for the step
-    that was in flight when the prompt arrived: a mixed tick returns it."""
+    rows decode until r2's third token (the tick that returns it sends the
+    next step ahead over r1 alone: r2 ends by length with it), then r1
+    alone to its eighth, which no step follows.  Every run of decode ticks
+    hands the device one program a tick, but for the step that was in
+    flight when the prompt arrived: a mixed tick returns it."""
     tr, _ = traced
     kinds = [t["attrs"]["kind"] for t, _ in _ticks(tr)]
     assert kinds == ["prefill", "decode", "mixed",
                      "decode", "decode", "decode", "decode"]
     ticks = _of_kind(tr, "decode")
     ahead = [kids["decode"][0]["attrs"]["ahead"] for _, kids in ticks]
-    assert ahead == [0, 0, 1, 0, 1]
+    assert ahead == [0, 0, 1, 1, 1]
     dispatched = [len(kids.get("engine/decode_step", []))
                   for _, kids in ticks]
-    assert dispatched == [2, 2, 0, 2, 0]
+    assert dispatched == [2, 2, 1, 1, 0]
     assert sum(dispatched) == len(ticks) + 1 == 1 + \
         sum(kids["decode"][0]["attrs"]["steps"] for _, kids in ticks)
     # the table blocks (of 8) the returned step's rows hold at the
@@ -544,9 +547,11 @@ def test_every_launch_is_retired_by_the_wait_that_names_it(traced):
             assert w["parent"] == retire["span_id"]
             assert all(w["t1_ns"] <= x["t0_ns"] for x in later)
             continue
-        # a decode step: dispatched as the second step of its tick, behind
-        # the step that tick returned ...
-        assert tick_of[by_launch[n - 1]["span_id"]] == tick_of[d["span_id"]]
+        # a decode step: dispatched by the tick that returned the step
+        # before it, before that tick waited for that one ...
+        before = wait_of[n - 1]
+        assert tick_of[before["span_id"]] == tick_of[d["span_id"]] and \
+            d["t1_ns"] <= before["t0_ns"]
         ragged = [x for x in later if x["name"] == "engine/ragged_step"]
         if ragged:
             # ... and retired between the build and the launch of the
@@ -558,9 +563,9 @@ def test_every_launch_is_retired_by_the_wait_that_names_it(traced):
             # ... or after whatever the next tick dispatched
             assert all(x["t1_ns"] <= w["t0_ns"] for x in later)
     # the decode step in flight when the prompt arrived, the mixed step,
-    # and the two decode ticks that found their step in flight
-    # (``test_decode_span_counts_the_steps_ahead``: [0, 0, 1, 0, 1])
-    assert ahead == [3, 4, 6, 8]
+    # and the three decode ticks that found their step in flight
+    # (``test_decode_span_counts_the_steps_ahead``: [0, 0, 1, 1, 1])
+    assert ahead == [3, 4, 6, 7, 8]
 
 
 def test_a_run_of_decode_ticks_retires_behind_its_successor(params):
@@ -579,6 +584,65 @@ def test_a_run_of_decode_ticks_retires_behind_its_successor(params):
     # back | ... | 8 out, 7 back | 8 back: the eighth token's step is not
     # followed (the row reaches max_new_tokens with it)
     assert len(dispatches) == 8 and behind == [2, 3, 4, 5, 6, 7]
+
+
+def test_a_finish_by_length_leaves_the_next_step_in_flight(params):
+    """Two callers in a closed loop, one of them with short answers.  The
+    decode tick that returns a row's last token closes its ``decode`` span
+    with the step after it dispatched over the row that goes on, before it
+    waited; the tick after it, with the caller's next request in the queue,
+    is a mixed tick whose ``prefill`` span closes with ``ragged_ahead`` 1;
+    and from the first such tick to the last nothing is compiled (the
+    programs, the tokens' gather among them, are those of the ladder)."""
+    from deepspeed_tpu.analysis.trace_guard import compile_count
+
+    sched = _sched(params)
+    sched.submit(_prompt(9), SamplingParams(greedy=True, max_new_tokens=3))
+    sched.run_until_idle()                  # the ladder: T16, decode_step
+    tr = Tracer()
+    sched.tracer = tr
+    sched.engine.attach_tracer(tr)
+
+    def ask(n, seed, new):
+        return sched.submit(_prompt(n, seed),
+                            SamplingParams(greedy=True, max_new_tokens=new))
+
+    short, long_ = ask(7, 1, 4), ask(6, 2, 40)
+    finishes, compiled = [], None
+    for seed in range(3, 7):
+        while len(short.generated) < short.sampling.max_new_tokens - 1:
+            sched.step()
+        compiled = compile_count() if compiled is None else compiled
+        sched.step()
+        assert short.finish_reason == "length"
+        finishes.append(sched._tick - 1)
+        assert sched._inflight.packed == [long_]
+        short = ask(8, seed, 3)
+        sched.step()
+    assert compile_count() == compiled
+    sched.run_until_idle()
+    ticks = {t["attrs"]["tick"]: (t, kids) for t, kids in _ticks(tr)}
+    for n in finishes:
+        tick, kids = ticks[n]
+        assert tick["attrs"]["kind"] == "decode"
+        assert kids["decode"][0]["attrs"]["steps"] == 1
+        # the one dispatch of the tick: over the row that goes on, and out
+        # before the wait for the step the tick returns (two rows)
+        (prep,), (step,) = kids["engine/decode_prep"], \
+            kids["engine/decode_step"]
+        assert prep["attrs"]["seqs"] == 1
+        assert step["t1_ns"] <= kids["fetch"][0]["t0_ns"]
+        assert tick["attrs"]["emitted"] == 2
+        tick, kids = ticks[n + 1]
+        assert tick["attrs"]["kind"] == "mixed"
+        assert kids["prefill"][0]["attrs"] == {"ragged_steps": 1,
+                                               "ragged_ahead": 1}
+        # the step over the survivor is retired between the batch's build
+        # and its launch
+        assert kids["engine/build_batch"][0]["t1_ns"] <= \
+            kids["fetch"][0]["t0_ns"] <= kids["fetch"][0]["t1_ns"] <= \
+            kids["engine/ragged_step"][0]["t0_ns"]
+    assert sched.ragged_ahead_ticks == len(finishes) == 4
 
 
 def test_a_tracer_attached_later_continues_the_count(params):

@@ -61,8 +61,8 @@ def serve_and_compare(engine, reference, ref_params, hf: Dict[str, Any],
                              (logits, slot)))
         return logits, nxt, n
 
-    def decode_step(uids, tokens, greedy=False):
-        out = real_step(uids, tokens, greedy=greedy)
+    def decode_step(uids, tokens, greedy=False, rows=None):
+        out = real_step(uids, tokens, greedy=greedy, rows=rows)
         logits = out[0] if greedy else out
         for i, uid in enumerate(uids):
             seen.append((uid, sm.get_sequence(uid).seen_tokens - 1,
