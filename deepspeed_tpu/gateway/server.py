@@ -30,8 +30,9 @@ duplicating it:
 * **tracing** — the ``trace_id`` is minted AT THE EDGE and returned as
   the ``X-Trace-Id`` response header; the gateway opens a
   ``http/request`` span under it on the fleet's tracer (tid
-  ``gateway``), and the scheduler's ``request/submit`` /
-  ``request/prefill`` / ``request/decode`` spans continue the same id —
+  ``gateway``), and the scheduler's ``request/submit`` instant and its
+  ``request/queued`` / ``request/prefill`` / ``request/decode`` spans
+  continue the same id —
   one Perfetto timeline from HTTP accept to the emitting tick.
 * **exactly-once streaming** — tokens cross from the fleet's
   synchronous ``on_token`` callbacks into the SSE writer through a

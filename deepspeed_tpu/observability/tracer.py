@@ -45,9 +45,25 @@ span                     site                        parent    attrs (counters)
                                                                ``emitted``
                                                                (tokens the call
                                                                returns)
-``pack``                 ``_tick_level`` /           tick      —
-                         ``_tick_ahead``: the batch
-                         the tick will launch
+``pack``                 ``_tick_level`` /           tick      closing:
+                         ``_tick_ahead``: the batch            ``queued``
+                         the tick will launch                  (requests still
+                                                               waiting to be
+                                                               admitted or
+                                                               resumed once it
+                                                               is packed) and,
+                                                               when there are
+                                                               any and the
+                                                               prefills were
+                                                               packed,
+                                                               ``held_by``: the
+                                                               first rule of
+                                                               ``_pack_prefills``
+                                                               that held one
+                                                               back (``budget``
+                                                               / ``rows`` /
+                                                               ``slots`` /
+                                                               ``kv``)
 ``prefill``              the tick's ragged batch:    tick      around a launch
                          ``engine.prepare`` (under             of greedy rows,
                          the program before it,                closing:
@@ -214,8 +230,57 @@ device runs launches in order, so that wait retires every earlier one too
 dispatch on the host is joined to its execution on the device by these
 two, not by order or by a clock.
 
-(``request/*`` spans and instants carry each request's own ``trace_id``
-and are opened with :meth:`Tracer.start` / :meth:`Tracer.instant`.)
+A request's own spans.  One phase is open a live request, from ``submit``
+to its end, under the request's own ``trace_id`` (minted at the first
+submit and carried across replicas), opened with :meth:`Tracer.start`
+(no parent: they overlap the ticks) by ``scheduler._open_req_span``, which
+closes the phase before:
+
+=====================  ==========================  =======================
+span / instant         site                        attrs (counters)
+=====================  ==========================  =======================
+``request/submit``     ``submit``, beside the      ``uid``,
+(instant)              open of ``request/queued``  ``prompt_tokens``
+``request/queued``     ``submit`` -> ``_admit``;   —
+                       opened again by
+                       ``_preempt`` (the request
+                       waits to be resumed)
+``request/prefill``    ``_admit`` -> the first     closing: ``chunks``
+                       token handed out            (step programs that
+                       (``_advance_emitted``       carried a chunk of its
+                       opens ``request/decode``,   prompt), ``first_launch``
+                       or ``_finish`` when that    and ``last_launch`` (the
+                       token ends it), a           launch record's numbers
+                       preemption, a failure       of the first and the
+                                                   last of them),
+                                                   ``behind_launch`` (the
+                                                   launch in flight when
+                                                   the first chunk was
+                                                   packed, whose tokens
+                                                   that batch was launched
+                                                   on; 0: the host and the
+                                                   device were level);
+                                                   none of the four when no
+                                                   chunk was launched;
+                                                   ``outcome`` / ``reason``
+                                                   unless the first token
+                                                   closed it and the
+                                                   request goes on
+``request/decode``     the first token -> the      closing: ``tokens``
+                       end                         (handed out in the
+                                                   phase), ``outcome``
+                                                   (finished / preempted /
+                                                   failed / handoff /
+                                                   ``replica_death:..``),
+                                                   ``reason`` (length /
+                                                   stop / deadline / ..)
+``request/handoff``    ``_detach``                 ``kv`` (did the device
+(instant)                                          KV travel with it)
+=====================  ==========================  =======================
+
+``first_launch`` .. ``last_launch`` join a request to the launch record
+above and, through it, to the device; a request's holds in the queue are
+the ``pack`` spans its ``request/queued`` span covers.
 
 Device scopes (``jax.named_scope``) are opened where the layer is written:
 ``attn/*`` in ``inference/v2/modules/attention.py``, ``moe/router`` and

@@ -54,6 +54,33 @@ def _pct(values: List[float], q: float) -> float:
     return float(np.percentile(np.asarray(values, np.float64), q))
 
 
+class _Window:
+    """One per-request (or per-tick) series over the last ``window_s``
+    seconds of its own activity.  Entries older than that leave when a new
+    one arrives: a server holds what a window of traffic records, not an
+    entry a request for its whole life, and a series that has gone quiet
+    keeps its last window for the percentiles."""
+
+    __slots__ = ("window_s", "_items")
+
+    def __init__(self, window_s: float):
+        self.window_s = window_s
+        self._items: Deque[Tuple[float, float]] = deque()
+
+    def append(self, value: float, now: float) -> None:
+        items = self._items
+        items.append((now, float(value)))
+        cutoff = now - self.window_s
+        while items[0][0] < cutoff:
+            items.popleft()
+
+    def __len__(self) -> int:
+        return len(self._items)
+
+    def values(self) -> List[float]:
+        return [v for _, v in self._items]
+
+
 class ServingMetrics:
     """Aggregates request lifecycles into SLO telemetry.
 
@@ -77,9 +104,11 @@ class ServingMetrics:
         self.preempted_requests = 0      # ever preempted (incl. in-flight)
         self._terminal_preempted = 0     # preempted AND reached a terminal state
         self.total_tokens = 0            # tokens of FINISHED requests only
-        self.ttft_s: List[float] = []
-        self.tpot_s: List[float] = []
-        self.queue_wait_s: List[float] = []
+        #: per-request series of the last ``window_s`` seconds (p50 / p95
+        #: in ``snapshot``)
+        self.ttft_s = _Window(window_s)
+        self.tpot_s = _Window(window_s)
+        self.queue_wait_s = _Window(window_s)
         #: (emit time, 1) per goodput-counted token, for the rolling rate
         self._token_times: Deque[float] = deque()
         # -- decode-tick accounting ------------------------------------ #
@@ -92,12 +121,11 @@ class ServingMetrics:
         self.decode_ticks = 0
         self.decode_tick_tokens = 0
         self.decode_tick_requests = 0
-        self._decode_tick_time_s = 0.0
         #: request-seconds: Σ elapsed * batched-requests — dividing by
         #: tokens delivered gives the mean inter-token latency a REQUEST
         #: experiences (batch-independent, acceptance-aware)
         self._decode_req_seconds = 0.0
-        self.decode_tick_s: List[float] = []
+        self.decode_tick_s = _Window(window_s)
 
     # ------------------------------------------------------------------ #
     # Lifecycle hooks
@@ -119,17 +147,17 @@ class ServingMetrics:
         self.handoffs += 1
 
     def record_decode_tick(self, tokens: int, requests: int,
-                           elapsed_s: float) -> None:
+                           elapsed_s: float, now: float) -> None:
         """One pure-decode scheduler tick batched ``requests`` requests
-        and delivered ``tokens`` tokens in ``elapsed_s`` seconds.
+        and delivered ``tokens`` tokens in the ``elapsed_s`` seconds up to
+        ``now`` (``time.monotonic``).
         ``tokens == requests`` on a plain decode tick; speculative
         acceptance delivers more."""
         self.decode_ticks += 1
         self.decode_tick_tokens += int(tokens)
         self.decode_tick_requests += int(requests)
-        self._decode_tick_time_s += float(elapsed_s)
         self._decode_req_seconds += float(elapsed_s) * int(requests)
-        self.decode_tick_s.append(float(elapsed_s))
+        self.decode_tick_s.append(elapsed_s, now)
 
     def tpot_delivered_s(self) -> float:
         """Per-request inter-token latency, dividing by tokens DELIVERED
@@ -155,11 +183,11 @@ class ServingMetrics:
         self.finished += 1
         self.total_tokens += len(req.generated)
         if req.ttft is not None:
-            self.ttft_s.append(req.ttft)
+            self.ttft_s.append(req.ttft, now)
         if req.tpot is not None:
-            self.tpot_s.append(req.tpot)
+            self.tpot_s.append(req.tpot, now)
         if req.queue_wait is not None:
-            self.queue_wait_s.append(req.queue_wait)
+            self.queue_wait_s.append(req.queue_wait, now)
         # goodput counts a finished request's tokens at completion time
         self._token_times.extend([now] * len(req.generated))
         self._trim(now)
@@ -223,6 +251,7 @@ class ServingMetrics:
                            ("queue_wait_s", self.queue_wait_s),
                            # old one-token-per-tick view, as a ticks series
                            ("decode_tick_s", self.decode_tick_s)):
+            vals = vals.values()
             if vals:
                 out[f"p50_{name}"] = _pct(vals, 50)
                 out[f"p95_{name}"] = _pct(vals, 95)

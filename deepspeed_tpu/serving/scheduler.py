@@ -160,8 +160,9 @@ class ContinuousBatchScheduler:
             engine.attach_tracer(tracer)
         #: the tick timeline's own trace (request traces are per-request)
         self.sched_trace_id = mint_trace_id()
-        #: uid -> open request-phase SpanHandle
-        self._req_spans: Dict[int, object] = {}
+        #: uid -> (open request-phase SpanHandle, tokens the request had
+        #: been handed when it opened); empty while nothing is traced
+        self._req_spans: Dict[int, Tuple[SpanHandle, int]] = {}
         #: unified metrics registry (observability.registry): when given,
         #: this scheduler's serving/* snapshot registers as a provider
         #: under the STABLE ``registry_key`` — a respawned scheduler
@@ -417,8 +418,8 @@ class ContinuousBatchScheduler:
             tr.instant("request/submit", trace_id=request.trace_id,
                        tid=self.trace_tid,
                        attrs={"uid": request.uid,
-                              "prompt_tokens": len(request.prompt),
-                              "resumed": len(request.generated)})
+                              "prompt_tokens": len(request.prompt)})
+            self._open_req_span(request, "queued")
         return request
 
     def _is_tracked_uid(self, uid: int) -> bool:
@@ -460,27 +461,54 @@ class ContinuousBatchScheduler:
     # Request-phase spans (one open phase per live request)
     # ------------------------------------------------------------------ #
     def _open_req_span(self, req: Request, phase: str) -> None:
+        """Close the request's open phase and open ``request/<phase>``:
+        ``queued`` (submit, or a preemption, to admission), ``prefill``
+        (admission to the first token handed out) or ``decode`` (from
+        there to the end).  Kept with the tokens the request had been
+        handed when the phase opened: a ``decode`` span closes with the
+        ``tokens`` of the phase."""
         tr = self.tracer
         if tr is None or not tr.enabled:
             return
-        self._close_req_span(req.uid)
-        self._req_spans[req.uid] = tr.start(
-            f"request/{phase}", trace_id=req.trace_id, tid=self.trace_tid,
-            attrs={"uid": req.uid, "fed": req.fed,
-                   "generated": len(req.generated)})
+        self._close_req_span(req)
+        self._req_spans[req.uid] = (
+            tr.start(f"request/{phase}", trace_id=req.trace_id,
+                     tid=self.trace_tid), len(req.generated))
 
-    def _close_req_span(self, uid: int, **attrs) -> None:
-        h = self._req_spans.pop(uid, None)
+    def _close_req_span(self, req: Request, **attrs) -> None:
+        h, had = self._req_spans.pop(req.uid, (None, 0))
         if h is not None and self.tracer is not None:
+            if h.name == "request/decode":
+                attrs["tokens"] = len(req.generated) - had
             self.tracer.finish(h, attrs=attrs or None)
+
+    def _trace_chunks(self, packed, launch: int, behind: int) -> None:
+        """Traced ticks only: launch ``launch`` carries a chunk of every
+        packed request whose open phase is ``request/prefill``.  That
+        span closes with ``chunks`` (such step programs), the engine's
+        numbers of the first and the last of them (``first_launch``,
+        ``last_launch``) and ``behind_launch``: the launch that was in
+        flight when the first chunk was packed (0: the host and the device
+        were level)."""
+        for req in packed:
+            h, _ = self._req_spans.get(req.uid, (None, 0))
+            if h is None or h.name != "request/prefill":
+                continue
+            if h.attrs is None:
+                h.attrs = {"chunks": 1, "first_launch": launch,
+                           "last_launch": launch, "behind_launch": behind}
+            else:
+                h.attrs["chunks"] += 1
+                h.attrs["last_launch"] = launch
 
     def abort_request_spans(self, outcome: str) -> None:
         """Close every open request-phase span.  The fleet calls this on
         a replica death so the dead incarnation's spans export closed
         and tagged with the outcome instead of dangling — the request's
         NEXT incarnation opens fresh spans under the same trace_id."""
-        for uid in list(self._req_spans):
-            self._close_req_span(uid, outcome=outcome)
+        for req in [*self._queued, *self._running.values(),
+                    *self._preempted]:
+            self._close_req_span(req, outcome=outcome)
 
     # ------------------------------------------------------------------ #
     # State inspection
@@ -602,6 +630,16 @@ class ContinuousBatchScheduler:
         chaos.fire("tick_stall")
         return t0
 
+    def _left_waiting(self, held_by: Optional[str]) -> Dict[str, Any]:
+        """What a traced ``pack`` span closes with: ``queued``, the
+        requests still waiting to be admitted or resumed once the batch is
+        packed, and, when there are any and ``_pack_prefills`` ran,
+        ``held_by``: its first rule that held a request back."""
+        waiting = len(self._queued) + len(self._preempted)
+        if waiting and held_by is not None:
+            return {"queued": waiting, "held_by": held_by}
+        return {"queued": waiting}
+
     def _tick_level(self):
         """A tick that starts with the host and the device level (or with
         a decode step in flight and no ragged batch due: the decode tick
@@ -619,10 +657,11 @@ class ContinuousBatchScheduler:
         chunks: List[List[int]] = []
         packed: List[Request] = []
 
-        with open_span(self.tracer, "pack"):
+        with open_span(self.tracer, "pack") as span:
+            held = None
             if self._inflight is None:
                 self._pack_decodes(uids, chunks, packed)
-                self._pack_prefills(uids, chunks, packed)
+                held = self._pack_prefills(uids, chunks, packed)
             else:
                 # this tick returns the tokens of the step in flight: its
                 # rows were packed a tick ago, and whatever arrived since
@@ -630,6 +669,8 @@ class ContinuousBatchScheduler:
                 packed = [r for r in self._inflight.packed
                           if r.finish_reason is None]
                 uids = [r.uid for r in packed]
+            if type(span) is SpanHandle:
+                span.attrs = self._left_waiting(held)
 
         if not uids:
             self._handle_stall()
@@ -659,7 +700,7 @@ class ContinuousBatchScheduler:
                 with open_span(self.tracer, "prefill") as span:
                     step = self._launch_ragged(
                         span, self.engine.prepare(uids, chunks), packed,
-                        chunks, {}, ahead=0)
+                        chunks, {}, behind=0)
                     due = self._ragged_due(step)
                     if not due:
                         toks = self._fetch_step(step)
@@ -671,11 +712,14 @@ class ContinuousBatchScheduler:
                 with open_span(self.tracer, "sample") as span:
                     emitted = self._advance_step(step, toks, span)
             else:
-                with open_span(self.tracer, "prefill"):
+                with open_span(self.tracer, "prefill") as span:
                     out = self.engine.put(uids, chunks, sync=True,
                                           greedy=greedy)
                     for req, chunk in zip(packed, chunks):
                         req.fed += len(chunk)
+                    if type(span) is SpanHandle:
+                        self._trace_chunks(packed, self.engine.last_launch,
+                                           behind=0)
                 with open_span(self.tracer, "sample") as span:
                     emitted = self._sample_and_advance(packed, out, greedy)
                     if type(span) is SpanHandle:
@@ -685,8 +729,9 @@ class ContinuousBatchScheduler:
         if decode_tick:
             # per-tick TPOT accounting divides by tokens DELIVERED (a
             # speculative tick can emit several per request)
+            now = time.monotonic()
             self.metrics.record_decode_tick(len(emitted), len(packed),
-                                            time.monotonic() - t0)
+                                            now - t0, now)
         return kind, packed, emitted, t0
 
     # -- a ragged batch under the program before it -------------------- #
@@ -725,10 +770,13 @@ class ContinuousBatchScheduler:
         uids: List[int] = []
         chunks: List[List[int]] = []
         packed: List[Request] = []
-        with open_span(self.tracer, "pack"):
+        with open_span(self.tracer, "pack") as span:
             late = self._pack_decodes(uids, chunks, packed, after=step)
+            held = None
             if late is not None:
-                self._pack_prefills(uids, chunks, packed)
+                held = self._pack_prefills(uids, chunks, packed)
+            if type(span) is SpanHandle:
+                span.attrs = self._left_waiting(held)
         if late is None or self._inflight is not step:
             return None
         n_decode = sum(r in late or r.state is RequestState.DECODE
@@ -758,7 +806,7 @@ class ContinuousBatchScheduler:
                     self.ragged_discards += 1
                 else:
                     self._launch_ragged(span, prepared, packed, chunks,
-                                        tokens, ahead=1)
+                                        tokens, behind=step.launch)
             with open_span(self.tracer, "sample") as span:
                 emitted = self._advance_step(step, toks, span)
         if wrong:
@@ -770,11 +818,13 @@ class ContinuousBatchScheduler:
         return kind, packed, emitted, t0
 
     def _launch_ragged(self, span, prepared, packed, chunks, tokens,
-                       ahead: int) -> _InFlight:
+                       behind: int) -> _InFlight:
         """Launch a prepared ragged batch of greedy rows and leave it in
-        flight.  ``span``, the ``prefill`` span around it, closes with
-        ``ragged_steps`` (1) and ``ragged_ahead`` (was the batch prepared
-        under the program before it and launched on its tokens)."""
+        flight.  ``behind`` is the launch it was prepared under and whose
+        tokens it is launched on (0: the chip was empty).  ``span``, the
+        ``prefill`` span around it, closes with ``ragged_steps`` (1) and
+        ``ragged_ahead`` (was there such a launch)."""
+        ahead = int(behind > 0)
         try:
             _, nxt, launch = self.engine.launch(prepared, tokens)
         except Exception:
@@ -789,6 +839,9 @@ class ContinuousBatchScheduler:
         self.ragged_ahead_ticks += ahead
         if type(span) is SpanHandle:
             span.attrs = {"ragged_steps": 1, "ragged_ahead": ahead}
+            # (a late row is fed the token its prompt's last chunk made)
+            self._trace_chunks([r for r in packed if r.uid not in tokens],
+                               launch, behind)
         self._inflight = _InFlight(packed, rows, nxt, ahead, launch,
                                    ragged=True)
         return self._inflight
@@ -1170,10 +1223,16 @@ class ContinuousBatchScheduler:
             packed.append(r)
         return late
 
-    def _pack_prefills(self, uids, chunks, packed) -> None:
+    def _pack_prefills(self, uids, chunks, packed) -> Optional[str]:
         """SplitFuse: fill the remaining budget with prefill chunks —
         running mid-prefill first, then preempted resumes, then new
-        admissions (priority, then FIFO)."""
+        admissions (priority, then FIFO).  Returns the first rule that
+        held a request back from admission, for the ``pack`` span:
+        ``budget`` (the tick's tokens are spent), ``rows`` (the batch has
+        ``max_seqs`` rows), ``slots`` (the running set is full) or ``kv``
+        (no chunk of it fits the pool beside the packed set); None when
+        everything waiting was admitted."""
+        held = None
         budget_left = self.token_budget - sum(len(c) for c in chunks)
         mid = sorted((r for r in self._running.values()
                       if r.remaining_feed > 1 and r not in packed),
@@ -1184,16 +1243,19 @@ class ContinuousBatchScheduler:
                        key=lambda r: (-r.priority, r.arrival_time))
         for req in itertools.chain(mid, resumes, fresh):
             if budget_left <= 0 or len(uids) >= self.max_seqs:
+                held = held or ("budget" if budget_left <= 0 else "rows")
                 break
             admitting = req.state in (RequestState.QUEUED,
                                       RequestState.PREEMPTED)
             if admitting and len(self._running) + 1 > self.max_seqs:
+                held = held or "slots"
                 continue   # running set must stay one-forward-sized
             want = min(req.remaining_feed, budget_left,
                        self.max_context - req.fed)
             chunk = self._max_feasible_chunk(uids, chunks, req.uid, want)
             if chunk <= 0:
                 if admitting:
+                    held = held or "kv"
                     break  # KV full: later (lower-priority) queue entries
                            # can't fit either — don't starve order
                 continue
@@ -1224,12 +1286,14 @@ class ContinuousBatchScheduler:
                             if snap is not None:
                                 stats.restore_attach(snap)
                             self._preempt(req)
+                            held = held or "kv"
                             break
             hist = req.history
             uids.append(req.uid)
             chunks.append(hist[req.fed:req.fed + chunk])
             packed.append(req)
             budget_left -= chunk
+        return held
 
     def _max_feasible_chunk(self, uids, chunks, uid: int, want: int) -> int:
         """Largest chunk <= want that ``can_schedule`` accepts alongside
@@ -1272,7 +1336,8 @@ class ContinuousBatchScheduler:
         del self._running[req.uid]
         req.fed = 0
         req.preemptions += 1
-        self._close_req_span(req.uid, outcome="preempted")
+        self._close_req_span(req, outcome="preempted")
+        self._open_req_span(req, "queued")      # it waits again
         req.transition(RequestState.PREEMPTED)
         self._preempted.append(req)
         self._parked_backlog += self._work(req)
@@ -1296,7 +1361,7 @@ class ContinuousBatchScheduler:
         if req in self._preempted:
             self._preempted.remove(req)
             self._parked_backlog -= self._work(req)
-        self._close_req_span(req.uid, outcome="failed", reason=reason)
+        self._close_req_span(req, outcome="failed", reason=reason)
         req.transition(RequestState.FAILED)
         self._drop_request_state(req.uid)
         self._finished.append(req)
@@ -1336,12 +1401,12 @@ class ContinuousBatchScheduler:
                 self._parked_backlog -= self._work(req)
             if req.generated:
                 req.finish_reason = "length"
-                self._close_req_span(req.uid, outcome="finished",
+                self._close_req_span(req, outcome="finished",
                                      reason="length")
                 req.transition(RequestState.FINISHED)
             else:
                 req.finish_reason = "kv_capacity"
-                self._close_req_span(req.uid, outcome="failed",
+                self._close_req_span(req, outcome="failed",
                                      reason="kv_capacity")
                 req.transition(RequestState.FAILED)
             self._drop_request_state(req.uid)
@@ -1428,7 +1493,7 @@ class ContinuousBatchScheduler:
         self.engine.flush([req.uid])
         del self._running[req.uid]
         req.finish_reason = reason
-        self._close_req_span(req.uid, outcome="finished", reason=reason)
+        self._close_req_span(req, outcome="finished", reason=reason)
         req.transition(RequestState.FINISHED)
         self._drop_request_state(req.uid)
         self._finished.append(req)
@@ -1601,14 +1666,12 @@ class ContinuousBatchScheduler:
         self._drop_request_state(req.uid)
         snap = req.snapshot(fed_tokens=fed)
         req.finish_reason = "handoff"
-        self._close_req_span(req.uid, outcome="handoff",
-                             fed_tokens=fed)
+        self._close_req_span(req, outcome="handoff")
         tr = self.tracer
         if tr is not None and tr.enabled:
             tr.instant("request/handoff", trace_id=req.trace_id,
                        tid=self.trace_tid,
-                       attrs={"uid": req.uid, "fed_tokens": fed,
-                              "kv": kv_state is not None})
+                       attrs={"kv": kv_state is not None})
         req.transition(RequestState.HANDED_OFF)
         self.metrics.record_handoff(req)
         return snap, kv_state

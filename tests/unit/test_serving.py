@@ -661,6 +661,42 @@ def test_serving_metrics_export_wallclock_csv(params, tmp_path):
     assert float(rows[-1][1]) == 2.0
 
 
+def test_serving_metrics_series_hold_one_window(monkeypatch):
+    """``ttft_s``, ``tpot_s``, ``queue_wait_s`` and ``decode_tick_s`` keep
+    the last ``window_s`` seconds of their own activity, not an entry a
+    request (a decode tick) for the life of the server; the snapshot's
+    names are what they were, and a series that has gone quiet keeps its
+    last window."""
+    from deepspeed_tpu.serving import metrics as metrics_mod
+    from deepspeed_tpu.serving.request import Request, RequestState
+
+    clock = [100.0]
+    monkeypatch.setattr(metrics_mod.time, "monotonic", lambda: clock[0])
+    m = metrics_mod.ServingMetrics(window_s=10.0)
+    for i in range(50):                 # a request and a tick a second
+        clock[0] = 100.0 + i
+        req = Request(uid=i + 1, prompt=[1, 2], arrival_time=clock[0] - 1.0)
+        req.first_scheduled_time = clock[0] - 0.9
+        req.emit(7, clock[0] - 0.5)
+        req.emit(8, clock[0] - 0.5 + 0.01 * (i + 1))
+        req.state = RequestState.FINISHED
+        m.record_finish(req)
+        m.record_decode_tick(1, 1, 0.001 * (i + 1), clock[0])
+    for series in (m.ttft_s, m.tpot_s, m.queue_wait_s, m.decode_tick_s):
+        assert len(series) == 11        # now - 10 s .. now, both ends
+    snap = m.snapshot()
+    assert snap["finished"] == 50 and snap["decode_ticks"] == 50
+    # the percentiles are those of the window: requests 40 .. 50
+    assert snap["p50_tpot_s"] == pytest.approx(0.45)
+    assert snap["p50_decode_tick_s"] == pytest.approx(0.045)
+    assert snap["p50_ttft_s"] == pytest.approx(0.5)
+    assert snap["p50_queue_wait_s"] == pytest.approx(0.1)
+    clock[0] += 3600.0                  # an idle hour: the last window stays
+    assert m.snapshot()["p50_tpot_s"] == pytest.approx(0.45)
+    m.record_decode_tick(1, 1, 0.5, clock[0])
+    assert m.decode_tick_s.values() == [0.5]
+
+
 def test_monitor_int_steps_unchanged(tmp_path):
     import csv
 

@@ -43,12 +43,12 @@ def params():
         jax.random.key(0), np.zeros((1, 4), np.int32))["params"]
 
 
-def _sched(params, tracer=None, **kw):
+def _sched(params, tracer=None, budget=32, seqs=4, blocks=17, **kw):
     cfg = RaggedInferenceEngineConfig.from_dict({
-        "state_manager": {"max_ragged_batch_size": 32,
-                          "max_ragged_sequence_count": 4,
+        "state_manager": {"max_ragged_batch_size": budget,
+                          "max_ragged_sequence_count": seqs,
                           "max_context": 48},
-        "kv_cache": {"block_size": 8, "num_blocks": 17}})
+        "kv_cache": {"block_size": 8, "num_blocks": blocks}})
     return ContinuousBatchScheduler(
         InferenceEngineV2(RaggedLlama(CFG, 8), params, cfg), tracer=tracer,
         **kw)
@@ -175,10 +175,13 @@ def test_pure_decode_tick_tree(traced):
             2 - counters["ahead"]
         # a counter is recorded once, where something reads it: the live
         # rows of each step on its engine/decode_prep (``gmm_roofline_pct``
-        # of the benchmark reads it), the mechanism's on the decode span
+        # of the benchmark reads it), the mechanism's on the decode span,
+        # what the tick left waiting on ``pack`` (nobody, in ``_drive``)
         assert {k for k, v in kids.items() if "attrs" in v[0]} == \
-            {"decode", "fetch"} | ({"engine/decode_prep",
-                                   "engine/decode_step"} if preps else set())
+            {"decode", "fetch", "pack"} | (
+                {"engine/decode_prep", "engine/decode_step"} if preps
+                else set())
+        assert kids["pack"][0]["attrs"] == {"queued": 0}
         for prep in preps:
             assert set(prep["attrs"]) == {"seqs"} and \
                 1 <= prep["attrs"]["seqs"] <= 4
@@ -238,8 +241,9 @@ def test_mixed_tick_tree(traced):
     # that emitted and how many by the program's argmax: the decoding row
     # of the step this tick returns
     assert {k for k, v in kids.items() if "attrs" in v[0]} == \
-        {"engine/build_batch", "engine/ragged_step", "fetch", "prefill",
-         "sample"}
+        {"engine/build_batch", "engine/ragged_step", "fetch", "pack",
+         "prefill", "sample"}
+    assert kids["pack"][0]["attrs"] == {"queued": 0}
     assert kids["prefill"][0]["attrs"] == {"ragged_steps": 1,
                                            "ragged_ahead": 1}
     assert kids["fetch"][0]["attrs"]["launch"] + 1 == \
@@ -708,6 +712,242 @@ def test_verify_dispatch_carries_the_launch_record(params):
         list(range(1, len(dispatches) + 1))
 
 
+
+# --------------------------------------------------------------------- #
+# a request's own spans: queued -> prefill -> decode, joined to launches
+# --------------------------------------------------------------------- #
+def _spy_launches(engine):
+    """[(launch number, {uid: tokens fed}, late uids)] of every ragged
+    step the engine launches (``put`` launches through it too)."""
+    seen, real = [], engine.launch
+
+    def launch(prepared, late_tokens=None):
+        out = real(prepared, late_tokens)
+        seen.append((out[2], dict(zip(prepared.scheduled,
+                                      prepared.chunk_sizes)),
+                     set(prepared.late)))
+        return out
+
+    engine.launch = launch
+    return seen
+
+
+def _greedy(sched):
+    req = sched.submit(_prompt(13), SamplingParams(greedy=True,
+                                                   max_new_tokens=4))
+    sched.run_until_idle()
+    return req, {}
+
+
+def _stochastic(sched):
+    req = sched.submit(_prompt(13), SamplingParams(
+        greedy=False, temperature=0.7, seed=5, max_new_tokens=4))
+    sched.run_until_idle()
+    return req, {}
+
+
+def _three_chunks(sched):
+    req = sched.submit(_prompt(43), SamplingParams(greedy=True,
+                                                   max_new_tokens=3))
+    sched.run_until_idle()
+    return req, {"chunks": 3}
+
+
+def _behind_a_step_sent_ahead(sched):
+    """Two callers; the short one ends by length, the tick that returns
+    its last token sends the next decode step ahead over the other, and
+    the caller's next request arrives behind that step."""
+    short = sched.submit(_prompt(7, 1), SamplingParams(greedy=True,
+                                                       max_new_tokens=4))
+    sched.submit(_prompt(6, 2), SamplingParams(greedy=True,
+                                               max_new_tokens=12))
+    while short.finish_reason is None:
+        sched.step()
+    ahead = sched._inflight.launch
+    assert ahead and not sched._inflight.ragged
+    req = sched.submit(_prompt(8, 3), SamplingParams(greedy=True,
+                                                     max_new_tokens=3))
+    sched.run_until_idle()
+    return req, {"behind_launch": ahead}
+
+
+def _preempted_before_its_first_token(sched):
+    req = sched.submit(_prompt(43), SamplingParams(greedy=True,
+                                                   max_new_tokens=3))
+    sched.step()                        # the first of three chunks
+    sched._preempt(req)
+    sched.run_until_idle()
+    return req, {"chunks": 3, "preempted_after": 1}
+
+
+@pytest.mark.parametrize("scenario, budget", [
+    (_greedy, 32), (_stochastic, 32), (_three_chunks, 16),
+    (_behind_a_step_sent_ahead, 32), (_preempted_before_its_first_token, 16)],
+    ids=["greedy", "stochastic", "three_chunks", "behind_a_step_sent_ahead",
+         "preempted_before_its_first_token"])
+def test_request_span_chain(params, scenario, budget):
+    """One open phase a live request from submit to finish, under its own
+    ``trace_id``: ``request/queued`` (submit -> admission), ``request/
+    prefill`` (-> the first token handed out; closes with the step programs
+    that carried its prompt) and ``request/decode`` (-> the end; closes
+    with the tokens of the phase).  The phases join end to end, and they
+    span ``first_token_time - arrival_time``."""
+    tr = Tracer()
+    sched = _sched(params, tracer=tr, budget=budget)
+    launches = _spy_launches(sched.engine)
+    req, want = scenario(sched)
+    mine = sorted((r for r in tr.records() if r["trace_id"] == req.trace_id),
+                  key=lambda r: r["t0_ns"])
+    assert all(r.get("parent") is None for r in mine)
+    submit, spans = mine[0], mine[1:]
+    assert submit["name"] == "request/submit" and submit["ph"] == "i"
+    assert submit["attrs"] == {"uid": req.uid,
+                               "prompt_tokens": len(req.prompt)}
+    names = [r["name"] for r in spans]
+    again = ["request/queued", "request/prefill"] \
+        if "preempted_after" in want else []
+    assert names == again + ["request/queued", "request/prefill",
+                             "request/decode"]
+    # end to end: a phase opens where the one before it closed
+    assert submit["t0_ns"] <= spans[0]["t0_ns"]
+    for a, b in zip(spans, spans[1:]):
+        assert 0 <= b["t0_ns"] - a["t1_ns"] < 1_000_000
+    # ... from the submit to the first token, as the request itself
+    # stamped them (within a millisecond here: the request reads the clock
+    # when it is made and once a tick before the tokens are handed out)
+    queued, prefill, decode = spans[-3:]
+    own = (req.first_token_time - req.arrival_time) * 1e9
+    assert abs((decode["t0_ns"] - spans[0]["t0_ns"]) - own) < 2_000_000
+    assert all("attrs" not in r for r in spans
+               if r["name"] == "request/queued")
+    # the prompt's chunks, from the engine's own launches: those that fed
+    # this uid from the host until the prompt was in (after a preemption:
+    # from the start again)
+    feeds = [(number, sizes[req.uid]) for number, sizes, late in launches
+             if req.uid in sizes and req.uid not in late]
+    lost = want.get("preempted_after", 0)
+    if lost:
+        assert spans[1]["attrs"] == {
+            "outcome": "preempted", "chunks": lost,
+            "first_launch": feeds[0][0], "last_launch": feeds[lost - 1][0],
+            "behind_launch": 0}
+    last_run, fed = [], 0
+    for number, n in feeds[lost:]:
+        if fed < len(req.prompt):
+            last_run.append(number)
+            fed += n
+    a = prefill["attrs"]
+    assert set(a) == {"chunks", "first_launch", "last_launch",
+                      "behind_launch"}
+    assert a["chunks"] == len(last_run) == want.get("chunks", 1)
+    assert (a["first_launch"], a["last_launch"]) == \
+        (last_run[0], last_run[-1])
+    assert a["first_launch"] <= a["last_launch"]
+    assert a["behind_launch"] == want.get("behind_launch", 0)
+    # every chunk's launch is a ragged step of the record, and the last
+    # is retired by a wait that names it before the first token goes out
+    dispatches, waits = _launch_spans(tr)
+    ragged = {d["attrs"]["launch"] for d in dispatches
+              if d["name"] == "engine/ragged_step"}
+    assert set(last_run) <= ragged
+    wait, = [w for w in waits if w["attrs"]["launch"] == a["last_launch"]]
+    assert prefill["t0_ns"] <= wait["t1_ns"] <= decode["t0_ns"]
+    if a["behind_launch"]:
+        step, = [d for d in dispatches
+                 if d["attrs"]["launch"] == a["behind_launch"]]
+        assert step["name"] == "engine/decode_step"
+        assert step["t1_ns"] <= prefill["t0_ns"]
+    assert decode["attrs"] == {"tokens": len(req.generated) - 1,
+                               "outcome": "finished", "reason": "length"}
+    assert not sched._req_spans and not tr.open_spans()
+
+
+def _held(tr):
+    """The closing counters of every ``pack`` span that left someone
+    waiting, oldest first."""
+    return [r["attrs"] for r in tr.records() if r["name"] == "pack"
+            and r["attrs"]["queued"]]
+
+
+def _hold_budget(params, tr):
+    """Two prompts of a whole budget each, submitted together: the first
+    spends the tick's tokens."""
+    sched = _sched(params, tracer=tr, budget=16)
+    for seed in (0, 1):
+        req = sched.submit(_prompt(16, seed), SamplingParams(
+            greedy=True, max_new_tokens=2))
+    return sched, req
+
+
+def _two_decoding(params, tr, second_new, **kw):
+    sched = _sched(params, tracer=tr, seqs=2, **kw)
+    first = sched.submit(_prompt(9), SamplingParams(greedy=True,
+                                                    max_new_tokens=12))
+    second = sched.submit(_prompt(7, 1), SamplingParams(
+        greedy=True, max_new_tokens=second_new))
+    return sched, first, second
+
+
+def _hold_rows(params, tr):
+    """Both rows of a two-row engine decode and go on: the batch is full
+    of them."""
+    sched, _, second = _two_decoding(params, tr, 12)
+    while len(second.generated) < 2:
+        sched.step()
+    return sched, sched.submit(_prompt(8, 2), SamplingParams(
+        greedy=True, max_new_tokens=2))
+
+
+def _hold_slots(params, tr):
+    """The second row ends by length with the token in flight, so the
+    batch packed under that step has a row to spare; but its request still
+    holds its slot of the running set."""
+    sched, _, second = _two_decoding(params, tr, 4)
+    while len(second.generated) < 3:
+        sched.step()
+    assert sched._inflight is not None and second in sched._inflight.packed
+    return sched, sched.submit(_prompt(8, 2), SamplingParams(
+        greedy=True, max_new_tokens=2))
+
+
+def _hold_kv(params, tr):
+    """A pool of four usable blocks, all held by the first request."""
+    sched = _sched(params, tracer=tr, blocks=5)
+    first = sched.submit(_prompt(26), SamplingParams(greedy=True,
+                                                     max_new_tokens=5))
+    while not first.generated:
+        sched.step()
+    return sched, sched.submit(_prompt(9, 1), SamplingParams(
+        greedy=True, max_new_tokens=2))
+
+
+@pytest.mark.parametrize("make, rule", [
+    (_hold_budget, "budget"), (_hold_rows, "rows"), (_hold_slots, "slots"),
+    (_hold_kv, "kv")], ids=["budget", "rows", "slots", "kv"])
+def test_pack_names_the_rule_that_held_a_request_back(params, make, rule):
+    """``pack`` closes with ``queued``, the requests it left waiting, and
+    ``held_by``, the first rule of ``_pack_prefills`` that held one back;
+    a tick that leaves nobody waiting, or packs nothing new (it returns
+    the step in flight), records ``queued`` alone."""
+    tr = Tracer()
+    sched, waiting = make(params, tr)
+    before = len(_held(tr))
+    sched.step()
+    held = _held(tr)[before:]
+    assert held and held[0] == {"queued": 1, "held_by": rule}
+    sched.run_until_idle()
+    assert all(r.finish_reason == "length" for r in sched.finished_requests)
+    packs = [r["attrs"] for r in tr.records() if r["name"] == "pack"]
+    assert packs[-1] == {"queued": 0}
+    assert {a.get("held_by", rule) for a in packs if a["queued"]} == {rule}
+    # a request's holds are the packs its ``request/queued`` span covers
+    q = next(r for r in tr.records() if r["name"] == "request/queued"
+             and r["trace_id"] == waiting.trace_id)
+    covered = [r for r in tr.records() if r["name"] == "pack"
+               and q["t0_ns"] <= r["t0_ns"] and r["t1_ns"] <= q["t1_ns"]]
+    assert len(covered) == len(_held(tr)) >= 1
+
+
 # --------------------------------------------------------------------- #
 # off: nothing recorded, nothing built
 # --------------------------------------------------------------------- #
@@ -733,6 +973,34 @@ def test_untraced_tick_builds_no_span(params, monkeypatch, make):
     if tr is not None:
         assert len(tr) == 0 and not tr.open_spans()
         assert tr.span("x") is tracer_mod._NULL_CM
+
+
+@pytest.mark.parametrize("make", [lambda: None,
+                                  lambda: Tracer(enabled=False)],
+                         ids=["no_tracer", "disabled_tracer"])
+def test_untraced_request_path_keeps_nothing(params, make):
+    """With no tracer, or a disabled one, a request that waits behind the
+    budget, takes three chunks and is preempted on the way leaves nothing
+    behind: no open phase, no record, and no attribute on the ``Request``
+    beside its declared fields."""
+    import dataclasses
+
+    from deepspeed_tpu.serving.request import Request
+
+    tr = make()
+    sched = _sched(params, tracer=tr, budget=16)
+    reqs = [sched.submit(_prompt(43, seed), SamplingParams(
+        greedy=True, max_new_tokens=3)) for seed in (0, 1)]
+    sched.step()
+    assert not sched._req_spans
+    sched._preempt(reqs[0])
+    sched.run_until_idle()
+    assert [r.finish_reason for r in reqs] == ["length"] * 2
+    assert reqs[0].preemptions == 1 and not sched._req_spans
+    fields = {f.name for f in dataclasses.fields(Request)}
+    assert all(set(vars(r)) == fields for r in reqs)
+    if tr is not None:
+        assert len(tr) == 0 and not tr.open_spans()
 
 
 def test_attach_tracer_reaches_the_engine(params):

@@ -279,13 +279,13 @@ def run_traced_sample(out_dir: str, n_requests: int = 4,
     assert "serving_finished" in prom, prom[:400]
     assert not registry.unknown_names, registry.unknown_names
 
-    # every request's spans connect: submit -> prefill -> decode under
-    # one trace_id, parents resolving
+    # every request's spans connect: submit -> queued -> prefill -> decode
+    # under one trace_id, parents resolving
     for r in reqs:
         mine = [e for e in events
                 if (e.get("args") or {}).get("trace_id") == r.trace_id]
         names = {e["name"] for e in mine}
-        assert {"request/submit", "request/prefill",
+        assert {"request/submit", "request/queued", "request/prefill",
                 "request/decode"} <= names, (r.uid, names)
 
     summary = trace_summary(events)
