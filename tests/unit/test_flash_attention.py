@@ -226,6 +226,210 @@ def test_flash_window_requires_causal():
 
 
 # ===================================================================== #
+# A causal tile's live part (PR 49): the kernels walk the sub-blocks of a
+# tile that the band leaves alive, and mask only those an edge crosses
+# ===================================================================== #
+from deepspeed_tpu.ops import flash_attention as fa  # noqa: E402
+
+#: sq, sk, h, hkv, d, window, (block_q, block_k) or None for the defaults:
+#: the shapes where the live range bites
+LIVE_PART_CASES = {
+    "s1k_d64": (1024, 1024, 1, 1, 64, None, None),
+    "s1k_d128": (1024, 1024, 1, 1, 128, None, None),
+    "end_aligned_512_of_1024": (512, 1024, 1, 1, 64, None, None),
+    "end_aligned_off_the_sub_block": (384, 1024, 1, 1, 64, None, None),
+    "window_under_a_sub_block": (1024, 1024, 1, 1, 64, 100, None),
+    "window_of_s": (1024, 1024, 1, 1, 64, 1024, None),
+    "window_across_two_key_tiles": (1024, 1024, 1, 1, 64, 700, (512, 512)),
+    "window_under_a_tile_of_128": (512, 512, 2, 2, 64, 200, (128, 128)),
+    "gqa_4_to_1": (1024, 1024, 4, 1, 64, None, None),
+    "two_key_tiles_s2k": (2048, 2048, 1, 1, 64, None, None),
+    "more_displacements_than_bodies": (1024, 1024, 1, 1, 64, None,
+                                       (128, 1024)),
+}
+
+
+@pytest.mark.parametrize("case", LIVE_PART_CASES)
+def test_live_part_forward_and_grads_match_xla(case):
+    sq, sk, h, hkv, d, window, blocks = LIVE_PART_CASES[case]
+    bq, bk = blocks or (None, None)
+    q, k, v = _make(b=1, sq=sq, sk=sk, h=h, hkv=hkv, d=d, seed=49)
+
+    def flash(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=window,
+                               block_q=bq, block_k=bk, interpret=True)
+
+    def ref(q, k, v):
+        return _xla_attention(q, k, v, causal=True, mask=None, scale=None,
+                              window=window)
+
+    np.testing.assert_allclose(np.asarray(flash(q, k, v)),
+                               np.asarray(ref(q, k, v)), atol=2e-5)
+    loss = lambda fn: lambda *a: jnp.sum(fn(*a) ** 2)
+    gf = jax.grad(loss(flash), argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(loss(ref), argnums=(0, 1, 2))(q, k, v)
+    for name, a, b in zip("qkv", gf, gr):
+        scale = float(jnp.abs(b).max()) + 1e-9
+        np.testing.assert_allclose(np.asarray(a) / scale,
+                                   np.asarray(b) / scale,
+                                   atol=1e-4, err_msg=f"d{name}")
+
+
+def _classes(rows, cols, offset, window):
+    """(any visible, all visible) of the rectangle rows x cols (ranges)."""
+    u = np.asarray(rows)[:, None] + offset - np.asarray(cols)[None, :]
+    keep = (u >= 0) & (u < (window or 1 << 30))
+    return keep.any(), keep.all()
+
+
+def _taken(steps, sub):
+    """{sub-block: masked} of a group's products, each sub-block once."""
+    seen = {}
+    for first, width, masked in steps:
+        assert width > 0 and first % sub == 0 and width % sub == 0
+        for j in range(first // sub, (first + width) // sub):
+            assert j not in seen
+            seen[j] = masked
+    return seen
+
+
+@pytest.mark.parametrize("window", [None, 1, 37, 128, 300, 4096])
+@pytest.mark.parametrize("offset", [0, 96, 512])
+def test_a_group_takes_the_live_sub_blocks_and_masks_the_crossed(offset,
+                                                                 window):
+    """``_key_steps`` / ``_row_steps`` against the mask itself, a
+    sub-block at a time: taken where any element is visible, bare where
+    every one is; at sub-blocks of two sizes and tiles off the origin."""
+    for sub, n, group in ((128, 4, 128), (64, 8, 192)):
+        for first in (0, 128, 384, 640, 1024):
+            for tile0 in (0, 512, 1024):
+                u0 = first + offset - tile0
+                keys = _taken(fa._key_steps(u0, 0, group, n * sub, sub,
+                                            window), sub)
+                rows = _taken(fa._row_steps(-u0, 0, group, n * sub, sub,
+                                            window), sub)
+                for j in range(n):
+                    span = range(tile0 + j * sub, tile0 + (j + 1) * sub)
+                    mine = range(first, first + group)
+                    some, every = _classes(mine, span, offset, window)
+                    assert (j in keys) == some, (first, tile0, j)
+                    assert not some or keys[j] == (not every)
+                    # the mirror: ``mine`` as keys under the rows ``span``
+                    some, every = _classes(span, mine, -offset, window)
+                    assert (j in rows) == some, (first, tile0, j)
+                    assert not some or rows[j] == (not every)
+
+
+@pytest.mark.parametrize("grid,want", [
+    ((2, 1, 512, 1024, 0, None), (0, 512)),              # GPT-2 at 8 x 1024
+    ((1, 1, 1024, 1024, 0, None), (0,)),                 # ... a q-tile of 1024
+    ((4, 4, 1024, 1024, 0, 4096), (0,)),                 # Mistral at 4096
+    ((8, 4, 512, 1024, 0, 1024), (0, 512, 1024, 1536)),  # 4 + 4 + 3 + 3 pairs
+    ((1, 1, 512, 1024, 512, None), (512,)),              # 512 rows of 1024
+    ((2, 2, 128, 128, 0, None), (0,)),
+], ids=["s1k_512", "s1k", "s4k_window_of_s", "s4k_window_1k", "end_aligned",
+        "128"])
+def test_the_crossed_displacements_of_a_grid(grid, want):
+    assert fa._tile_bodies(*grid) == want
+
+
+@pytest.mark.parametrize("window", [None, 300, 1500])
+@pytest.mark.parametrize("offset", [0, 512, 1024])
+def test_a_step_outside_the_band_names_a_tile_inside_it(offset, window):
+    """The index maps hold the k-tile (forward, dQ) or the q-tile (dK/dV)
+    of a grid step that does no work at the nearest one that does: the
+    pipeline moves nothing for it."""
+    bq, bk, nq, nk = 256, 512, 8, 4 + offset // 512
+    band = dict(causal=True, block_q=bq, block_k=bk, causal_offset=offset,
+                window=window)
+    live = np.array([[bool(fa._run_predicate(iq, ik, bq, bk, True, offset,
+                                             window))
+                      for ik in range(nk)] for iq in range(nq)])
+    for iq in range(nq):
+        inside = np.flatnonzero(live[iq])
+        for ik in range(nk):
+            held = int(fa._held_k_tile(iq, ik, nk=nk, **band))
+            assert held == np.clip(ik, inside[0], inside[-1]), (iq, ik)
+    for ik in range(nk):
+        inside = np.flatnonzero(live[:, ik])
+        for iq in range(nq):
+            held = int(fa._held_q_tile(ik, iq, nq=nq, **band))
+            if len(inside):
+                assert held == np.clip(iq, inside[0], inside[-1]), (ik, iq)
+
+
+def test_causal_work_counts_what_the_kernels_execute():
+    run, live = fa.causal_work(1024, 1024)
+    assert live == 1024 * 1025 // 2 and run / live <= 1.50
+    run, live = fa.causal_work(4096, 4096, window=4096)
+    assert live == 4096 * 4097 // 2 and run / live <= 1.13
+    assert fa.causal_work(1024, 2048, causal=False) == (1024 * 2048,) * 2
+    # the mask's own count at an end-aligned shape under a window
+    run, live = fa.causal_work(384, 1024, window=200)
+    u = np.arange(384)[:, None] + 640 - np.arange(1024)[None, :]
+    assert live == int(((u >= 0) & (u < 200)).sum())
+    assert live <= run <= 384 * 1024
+    # dK/dV takes rows under a group of keys: the same sub-blocks
+    sub_q, sub_k = fa._sub_blocks(512, 1024)
+    by_rows = sum(sub_k * height
+                  for q0 in range(0, 4096, 512)
+                  for k0 in range(0, 4096, 1024)
+                  for c in range(0, 1024, sub_k)
+                  for _, height, _ in fa._row_steps(
+                      q0 - k0, c, sub_k, 512, sub_q, 4096))
+    assert by_rows == fa.causal_work(4096, 4096, window=4096,
+                                     block_q=512)[0]
+    # more crossed displacements than bodies: the rest run whole
+    run, live = fa.causal_work(1024, 1024, block_q=128, block_k=1024)
+    assert len(fa._tile_bodies(8, 1, 128, 1024, 0, None)) == 8 \
+        > fa.MAX_TILE_BODIES == 6
+    assert run == 128 * (2 * 256 + 2 * 512 + 2 * 768 + 2 * 1024)
+
+
+def test_flash_attention_records_its_work_once_a_trace():
+    from deepspeed_tpu.observability.registry import MetricsRegistry
+
+    reg = MetricsRegistry.default()
+    before = reg.snapshot()
+    q, k, v = _make(b=2, sq=256, sk=256, h=4, hkv=2)
+    f = jax.jit(lambda q, k, v: flash_attention(q, k, v, causal=True,
+                                                interpret=True))
+    f(q, k, v)
+    f(q, k, v)                                  # the traced program again
+    after = reg.snapshot()
+    run, live = fa.causal_work(256, 256)
+    assert after["flash/score_elems_run"] \
+        - before["flash/score_elems_run"] == 2 * 4 * run
+    assert after["flash/score_elems_live"] \
+        - before["flash/score_elems_live"] == 2 * 4 * live
+    assert not reg.unknown_names, reg.unknown_names
+
+
+@pytest.mark.parametrize("b,s,h,hkv,d,window", [
+    (8, 1024, 20, 20, 64, None), (1, 4096, 16, 4, 128, 4096)],
+    ids=["gpt2large_d64_s1k", "mistral7b_d128_s4k"])
+def test_the_training_cells_shapes_lower_for_the_tpu(b, s, h, hkv, d, window):
+    """The three kernels at the two training cells' shapes, lowered for the
+    TPU from here (no chip): the walk's loops, its dynamic slices and its
+    lane-wise statistics pass the Mosaic lowering, under the kernels'
+    accepted names."""
+    q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16)
+    k = jax.ShapeDtypeStruct((b, s, hkv, d), jnp.bfloat16)
+
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(
+            q, k, v, causal=True, window=window,
+            interpret=False).astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(q, k, k).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert text.count("tpu_custom_call") == 3
+    fwd = "_fwd_kernel_onepass" if s <= 1024 else "_fwd_kernel"
+    for name in (fwd, "_bwd_dq_kernel", "_bwd_dkv_kernel"):
+        assert f'kernel_name = "{name}"' in text, name
+
+
+# ===================================================================== #
 # Folded ([B, S, H*D]) layout-native kernels
 # ===================================================================== #
 from deepspeed_tpu.ops.attention import (folded_attention,  # noqa: E402

@@ -74,6 +74,14 @@ PREFILL_TOL = 0.02
 LATENT_PREFILL_TOL = 0.01
 
 
+#: batch, heads, KV heads, tokens, head size, window: the bshd flash
+#: kernels' calls in the two training cells (GPT-2 Large at 8 x 1024; one
+#: tensor-parallel shard of Mistral-7B, 16 of its 32 heads, at 1 x 4096 with
+#: the configuration's window, which never bites there)
+FLASH_TRAIN_CELLS = {"gpt2large_d64_s1k": (8, 20, 20, 1024, 64, None),
+                     "mistral7b_d128_s4k": (1, 16, 4, 4096, 128, 4096)}
+
+
 def _stacked(read, layers: int):
     """``layers`` reads with a query of their own each, summed (the pools
     are arguments: a closed-over pool would be compiled in as a constant)."""
@@ -701,6 +709,89 @@ def ssm_case(which: str, layers: int = 26, repeats: int = 10) -> dict:
             "least_us": round(moved / 819e9 * 1e6, 1)}
 
 
+def flash_train_case(b: int, h: int, hkv: int, s: int, d: int, window,
+                     layers: int = 4, repeats: int = 10,
+                     tol: float = 3e-2) -> dict:
+    """The three bshd flash kernels (``ops/flash_attention.py``, compiled)
+    alone at one training cell's shapes.  ``us`` = microseconds a call of
+    the forward, of dQ and of dK/dV (``_bwd`` with the other kernel's
+    results unused, so XLA drops its call; the ``delta`` row sums, an XLA
+    fusion over ``do`` and ``o``, run in both); ``work`` = score elements a
+    kernel executes over those the mask leaves alive (``causal_work``;
+    None in a tree from before it); ``peak_pct`` = the FLOPs
+    ``benchmark/lib/costs.py`` counts for the forward, and for all three,
+    over their time at the chip's bf16 peak; ``max_err`` / ``grad_err``
+    against the XLA route."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark.lib import costs
+    from deepspeed_tpu.ops import flash_attention as fa
+    from deepspeed_tpu.ops.attention import _xla_attention
+
+    ks = jax.random.split(jax.random.key(49), 4)
+    q, do = (jax.random.normal(k_, (b, h, s, d), jnp.bfloat16)
+             for k_ in ks[:2])
+    k, v = (jax.random.normal(k_, (b, hkv, s, d), jnp.bfloat16)
+            for k_ in ks[2:])
+    scale = d ** -0.5
+    # (a tree from before PR 49 has one q-tile for every call)
+    bq = fa._pick_block(s, getattr(fa, "CAUSAL_BLOCK_Q", fa.DEFAULT_BLOCK_Q))
+    bk = fa._pick_block(s, fa.DEFAULT_BLOCK_K)
+    tile = dict(causal=True, block_q=bq, block_k=bk, interpret=False,
+                window=window)
+    qs = (q.astype(jnp.float32) * scale).astype(q.dtype)
+    o, lse = jax.jit(lambda *a: fa._fwd(*a, **tile))(qs, k, v)
+
+    def stacked(call):
+        """``layers`` calls on inputs of their own each, their results
+        summed to one number a result (nothing for XLA to merge or drop)."""
+        def run(x, *rest):
+            return sum(jnp.sum(r.astype(jnp.float32))
+                       for i in range(layers)
+                       for r in call(x + jnp.asarray(0.01 * i, x.dtype),
+                                     *rest))
+        return jax.jit(run)
+
+    bwd = lambda do_, *res: fa._bwd(res, (do_,), scale=scale, **tile)
+    runs = {"fwd": (stacked(lambda q_, k_, v_: fa._fwd(q_, k_, v_, **tile)[:1]),
+                    (qs, k, v)),
+            "dq": (stacked(lambda *a: bwd(*a)[:1]), (do, qs, k, v, o, lse)),
+            "dkv": (stacked(lambda *a: bwd(*a)[1:]), (do, qs, k, v, o, lse))}
+    us = {name: round(_timed(run, layers, repeats, *args)[0], 1)
+          for name, (run, args) in runs.items()}
+
+    fwd_flops = b * s * costs.attention_fwd_flops_per_token(
+        {"q_heads": h, "head_dim": d}, s)
+    peak = 197e12
+    work = getattr(fa, "causal_work", None)
+    if work is not None:
+        run_, live = work(s, s, window=window)
+        work = round(run_ / live, 4)
+
+    to_bshd = lambda x: x.transpose(0, 2, 1, 3)
+    flash = lambda a, b_, c: fa.flash_attention(
+        a, b_, c, causal=True, window=window, interpret=False)
+    xla = lambda a, b_, c: _xla_attention(
+        a, b_, c, causal=True, mask=None, scale=None, window=window)
+    loss = lambda fn: lambda *a: jnp.sum(fn(*a).astype(jnp.float32) ** 2)
+    args = tuple(to_bshd(x)[:1] for x in (q, k, v))    # one sequence
+    err = lambda got, want: float(jnp.max(jnp.abs(
+        got.astype(jnp.float32) - want.astype(jnp.float32))))
+    max_err = err(jax.jit(flash)(*args), jax.jit(xla)(*args))
+    grad_err = max(err(g_, w_) for g_, w_ in zip(
+        jax.jit(jax.grad(loss(flash), argnums=(0, 1, 2)))(*args),
+        jax.jit(jax.grad(loss(xla), argnums=(0, 1, 2)))(*args)))
+    return {"us": us, "work": work, "blocks": [bq, bk],
+            "peak_pct": {
+                "fwd": round(100 * fwd_flops / peak / (us["fwd"] * 1e-6), 2),
+                "all": round(100 * 3 * fwd_flops / peak
+                             / (sum(us.values()) * 1e-6), 2)},
+            "max_err": round(max_err, 6), "grad_err": round(grad_err, 6),
+            # the gradients sum over up to ``s`` rows of bf16 products
+            "ok": bool(max_err < tol and grad_err < 10 * tol * s / 512)}
+
+
 def run_selftest(tol: float = 3e-2) -> dict:
     """Returns {kernel_name: {"max_err": float, "ok": bool}} plus an
     overall "ok". Skips (with a note) off-TPU."""
@@ -773,6 +864,11 @@ def run_selftest(tol: float = 3e-2) -> dict:
         guarded(name,
                 lambda n=name, i=idx, a=(h, hkv, d, win): flash_case(
                     n, i, *a))
+
+    # ---- the bshd kernels alone at the two training cells' shapes ---- #
+    for name, shape in FLASH_TRAIN_CELLS.items():
+        guarded("flash_train_" + name, lambda n=name, a=shape: results.update(
+            {"flash_train_" + n: flash_train_case(*a)}))
 
     # ---- folded-layout flash ([B,S,H*D] lane layout, no transposes):
     # the honest-geometry 12x64 MHA shape plus GQA at both head dims ---- #
@@ -1209,6 +1305,11 @@ if __name__ == "__main__":
     from deepspeed_tpu.utils.compile_cache import enable_compile_cache
 
     enable_compile_cache()
-    out = run_selftest()
+    if sys.argv[1:] == ["flash_train"]:     # the training kernels alone
+        out = {name: flash_train_case(*shape)
+               for name, shape in FLASH_TRAIN_CELLS.items()}
+        out["ok"] = all(v["ok"] for v in out.values())
+    else:
+        out = run_selftest()
     print(json.dumps(out, indent=2))
     sys.exit(0 if out.get("ok") else 1)
