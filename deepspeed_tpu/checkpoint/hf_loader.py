@@ -244,7 +244,8 @@ def _qwen3_next_rules() -> List[Rule]:
     ]
 
 
-def _deepseek_v3_rope_rows(head_width):
+def _deepseek_v3_rope_rows(head_width, first: bool = False,
+                           flag: str = "rope_interleave"):
     """The published DeepSeek-V3 family stores the rotary dims of ``q_proj``
     (each head's last ``qk_rope_head_dim`` outputs) and of
     ``kv_a_proj_with_mqa`` (its last ``qk_rope_head_dim`` outputs)
@@ -252,13 +253,18 @@ def _deepseek_v3_rope_rows(head_width):
     rotate-half layout (x0, x1, ..., y0, y1, ...) before every rotation;
     ``RaggedDeepseekV3`` rotates in that layout, so the regrouping is done
     once, here.  ``head_width(cfg)`` is the width of one block of outputs
-    whose LAST rope dims are the rotary ones.  HF weight [out, in] ->
-    kernel [in, out]."""
+    whose LAST rope dims are the rotary ones (``first``: whose FIRST are, an
+    indexer head of ``glm_moe_dsa``).  A configuration that sets ``flag``
+    false stores them in the rotate-half layout already: a plain
+    transpose.  HF weight [out, in] -> kernel [in, out]."""
     def tf(w, cfg):
         w = np.asarray(w)
+        if not cfg.get(flag, True):
+            return w.T
         rope, width = int(cfg["qk_rope_head_dim"]), head_width(cfg)
+        at = 0 if first else width - rope
         order = np.arange(width)
-        order[width - rope:] = width - rope + np.concatenate(
+        order[at:at + rope] = at + np.concatenate(
             [np.arange(0, rope, 2), np.arange(1, rope, 2)])
         blocks = w.reshape(-1, width, w.shape[-1])[:, order]
         return blocks.reshape(w.shape).T
@@ -305,6 +311,51 @@ def _deepseek_v3_rules() -> List[Rule]:
                           "kernel"), "t")),
         (r".*rotary_emb\.inv_freq$", lambda m: (None, None)),
     ]
+
+
+def _glm_moe_dsa_rules() -> List[Rule]:
+    # GLM-5 (``model_type: glm_moe_dsa``) -> RaggedDeepseekV3's tree with a
+    # low-rank query and an indexer: the DeepSeek-V3 rules but for the query
+    # path, plus the indexer's four leaves.  Both rope layouts
+    # (``rope_interleave``: q_b_proj and kv_a_proj_with_mqa, the rotary dims
+    # LAST; ``indexer_rope_interleave``: indexer.wq_b and indexer.wk, the
+    # rotary dims FIRST) are de-interleaved once, here.  No GLM-5 checkpoint
+    # is in the repository: the names are the published ones, tested on a
+    # synthetic state dict.
+    def layer(m, *leaf):
+        return (f"layers_{m.group(1)}", *leaf)
+
+    attn = r"^model\.layers\.(\d+)\.self_attn\."
+    q_rows = _deepseek_v3_rope_rows(
+        lambda c: int(c["qk_nope_head_dim"]) + int(c["qk_rope_head_dim"]))
+    idx_rows = _deepseek_v3_rope_rows(
+        lambda c: int(c["index_head_dim"]), first=True,
+        flag="indexer_rope_interleave")
+    return [
+        # the multi-token-prediction layer's own leaves (the layer itself,
+        # index num_hidden_layers, is dropped by load_hf_checkpoint)
+        (r"^model\.layers\.\d+\.(enorm|hnorm|eh_proj|shared_head)\..*$",
+         lambda m: (None, None)),
+        (attn + r"q_a_proj\.weight$",
+         lambda m: (layer(m, "self_attn", "q_a_proj", "kernel"), "t")),
+        (attn + r"q_a_layernorm\.weight$",
+         lambda m: (layer(m, "self_attn", "q_a_layernorm", "scale"), None)),
+        (attn + r"q_b_proj\.weight$",
+         lambda m: (layer(m, "self_attn", "q_b_proj", "kernel"), q_rows)),
+        (attn + r"indexer\.wq_b\.weight$",
+         lambda m: (layer(m, "self_attn", "indexer", "wq_b", "kernel"),
+                    idx_rows)),
+        (attn + r"indexer\.wk\.weight$",
+         lambda m: (layer(m, "self_attn", "indexer", "wk", "kernel"),
+                    idx_rows)),
+        (attn + r"indexer\.k_norm\.(weight|bias)$",
+         lambda m: (layer(m, "self_attn", "indexer", "k_norm",
+                          "scale" if m.group(2) == "weight" else "bias"),
+                    None)),
+        (attn + r"indexer\.weights_proj\.weight$",
+         lambda m: (layer(m, "self_attn", "indexer", "weights_proj",
+                          "kernel"), "t")),
+    ] + [r for r in _deepseek_v3_rules() if r"q_proj\." not in r[0]]
 
 
 def _lfm2_moe_rules() -> List[Rule]:
@@ -677,6 +728,7 @@ _ARCH_RULES: Dict[str, Callable[[], List[Rule]]] = {
     "olmoe": _olmoe_rules,
     "qwen3_next": _qwen3_next_rules,
     "deepseek_v3": _deepseek_v3_rules,
+    "glm_moe_dsa": _glm_moe_dsa_rules,
     "lfm2_moe": _lfm2_moe_rules,
     "afmoe": _afmoe_rules,
     "ouro": _ouro_rules,
@@ -800,6 +852,29 @@ def config_from_hf(model_path: str, dtype: Any = None):
         # refuses each by name)
         return arch, DeepseekV3Config(
             **{k: v for k, v in cfg.items() if k in fields}, dtype=dt)
+    if arch == "glm_moe_dsa":
+        from deepspeed_tpu.inference.v2.model_implementations. \
+            ragged_deepseek_v3 import DeepseekV3Config
+
+        rope = cfg.get("rope_parameters") or {}
+        if rope.get("rope_type", "default") != "default" \
+                or cfg.get("rope_scaling") is not None \
+                or cfg.get("attention_bias") \
+                or cfg.get("tie_word_embeddings"):
+            raise HFLoadError(
+                "glm_moe_dsa: a scaled rotary embedding, attention_bias "
+                "and a tied head are not implemented (GLM-5 sets none of "
+                "them)")
+        fields = {f.name for f in dataclasses.fields(DeepseekV3Config)} \
+            - {"dtype", "rope_theta"}
+        # (num_nextn_predict_layers: the multi-token-prediction layer is
+        # not on the path to the main head's logits; load_hf_checkpoint
+        # drops it)
+        return arch, DeepseekV3Config(
+            **{k: v for k, v in cfg.items() if k in fields},
+            rope_theta=float(rope.get("rope_theta",
+                                      cfg.get("rope_theta", 1e6))),
+            dtype=dt)
     if arch == "lfm2_moe":
         from deepspeed_tpu.inference.v2.model_implementations. \
             ragged_lfm2 import Lfm2Config
@@ -1089,6 +1164,11 @@ def load_hf_checkpoint(model_path: str, architecture: Optional[str] = None,
             + (f" (+{len(unmapped) - 8} more)" if len(unmapped) > 8 else ""))
     for path in list(stacks):
         flush_stack(path)
+    if arch == "glm_moe_dsa" and file_cfg.get("num_nextn_predict_layers"):
+        # the multi-token-prediction layers follow the model's own
+        for i in range(int(file_cfg["num_nextn_predict_layers"])):
+            tree.pop(f"layers_{int(file_cfg['num_hidden_layers']) + i}",
+                     None)
     return tree
 
 

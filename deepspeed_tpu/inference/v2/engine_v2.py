@@ -205,6 +205,9 @@ class InferenceEngineV2:
         self._grouped = kv_groups is not None
         #: caches a layer keeps (``kv_passes``): on every dispatch span
         self._passes = int(getattr(model, "kv_passes", 1))
+        #: cached positions a query row of the model reads (its learned
+        #: sparse-attention indexer's top-k); None: every one
+        self.index_topk: Optional[int] = getattr(model, "index_topk", None)
         try:
             self.state_manager = DSStateManager(
                 sm_cfg, kv_cfg, num_layers=model.num_layers,
@@ -652,7 +655,10 @@ class InferenceEngineV2:
             attrs.update(self._window_counters(
                 [s for s, n in rows if n == 1], tiled))
         tile = self._prefill_tile()
-        if tile and tiled and not sm.kv_cache.quantized:
+        if self.index_topk is not None:
+            attrs.update(self.index_counters(
+                [s.seen_tokens for s, n in rows if n == 1], tiled))
+        elif tile and tiled and not sm.kv_cache.quantized:
             count = self._latent_step_counters if latent \
                 else self._chunk_step_counters
             attrs.update(count(
@@ -992,6 +998,28 @@ class InferenceEngineV2:
             if self._grouped and window is not None:
                 out.update(chunk_key_steps_win=count * steps,
                            chunk_live_key_steps_win=count * live)
+        return out
+
+    def index_counters(self, single_rows, chunks=()) -> Dict[str, int]:
+        """What a learned sparse-attention indexer (the model's
+        ``index_topk``) must do for one dispatch, a layer: its one-token
+        rows (``single_rows``: the position each feeds) score ``idx_keys``
+        cached positions (``p + 1`` each) and read ``sel_keys`` of them
+        (``min(p + 1, index_topk)``); the rows of its longer chunks
+        (``chunks``: (start, tokens)) ``idx_pairs`` and ``sel_pairs``, the
+        same two sums.  Host arithmetic on the lengths alone (the serving
+        scheduler counts a consumed decode step's rows by it too)."""
+        k = self.index_topk
+        out = {"idx_keys": sum(p + 1 for p in single_rows),
+               "sel_keys": sum(min(p + 1, k) for p in single_rows)}
+        if chunks:
+            out["idx_pairs"] = sum(n * (2 * a + n + 1) // 2
+                                   for a, n in chunks)
+            # the first max(0, k - a) rows of a chunk see all p + 1
+            ramps = [max(0, min(a + n, k) - a) for a, n in chunks]
+            out["sel_pairs"] = sum(
+                r * (2 * a + r + 1) // 2 + (n - r) * k
+                for (a, n), r in zip(chunks, ramps))
         return out
 
     def _latent_step_counters(self, chunks, tiles: int,
@@ -1613,7 +1641,8 @@ class InferenceEngineV2:
         MII/engine_factory path that builds a FastGen engine from a HF
         snapshot).  ``model_implementations.HF_MODELS`` names the
         architectures served (llama, mistral, internlm, opt, falcon, mixtral,
-        olmoe, qwen3_next, deepseek_v3, lfm2_moe, afmoe, ouro, jamba) and which
+        olmoe, qwen3_next, deepseek_v3, glm_moe_dsa, lfm2_moe, afmoe, ouro,
+        jamba) and which
         take
         a ``mesh`` with a non-trivial 'model' axis (the others refuse one):
         weights then land PRE-SHARDED

@@ -1,6 +1,6 @@
 """Inference v2 model implementations (reference:
 inference/v2/model_implementations/ — llama_v2, opt, mistral, mixtral,
-falcon families; qwen3_next, deepseek_v3, lfm2_moe, afmoe, ouro and jamba have
+falcon families; qwen3_next, deepseek_v3 (and glm_moe_dsa through it), lfm2_moe, afmoe, ouro and jamba have
 no reference counterpart).  One file a family (config, parameter shapes,
 class) over the shared layers of ``inference/v2/modules/``."""
 
@@ -65,6 +65,7 @@ HF_MODELS = {
     "olmoe": (RaggedMixtral, False),
     "qwen3_next": (RaggedQwen3Next, False),
     "deepseek_v3": (RaggedDeepseekV3, True),
+    "glm_moe_dsa": (RaggedDeepseekV3, True),
     "lfm2_moe": (RaggedLfm2, False),
     "afmoe": (RaggedAfmoe, False),
     "ouro": (RaggedOuro, False),

@@ -1,7 +1,9 @@
 """Ragged DeepSeek-V3-family forward for the FastGen engine (``model_type:
-deepseek_v3``; Moonlight-16B-A3B is the configuration served): multi-head
-latent attention (MLA), a sigmoid router with a learned selection bias over
-routed experts beside ungated shared experts, and leading dense layers.
+deepseek_v3``, Moonlight-16B-A3B, and ``glm_moe_dsa``, GLM-5, are the
+configurations served): multi-head latent attention (MLA), dense or behind a
+learned sparse-attention indexer, a sigmoid router with a learned selection
+bias over routed experts beside ungated shared experts, and leading dense
+layers.
 
 What is new beside :class:`RaggedLlama` / :class:`RaggedMixtral`:
 
@@ -40,13 +42,30 @@ What is new beside :class:`RaggedLlama` / :class:`RaggedMixtral`:
   all ``n_routed_experts``, the layer holds ``held_experts`` from
   ``expert_start``.
 
+* **A low-rank query** (``q_lora_rank``): ``q = RMSNorm(x W_qa) W_qb``;
+  None: ``q_proj`` is one matrix.
+* **A learned sparse-attention indexer** (``index_topk``; DeepSeek Sparse
+  Attention, ``kernels/sparse_latent.py``): a token keeps a SECOND pool row,
+  its indexer key (``kv_row`` then states two leaves, ``ckv`` and
+  ``idx_k``), every query row scores all of its sequence's cached
+  positions against that leaf, ``sum_j w_j relu(qI_j . kI_s)``, and reads
+  the exact top ``index_topk`` latent rows alone: the tile segment through
+  a mask over blocks its rows share, one-token rows token by token through
+  the table; both absorbed.  The indexer's queries come from the query
+  latent, so it needs ``q_lora_rank``.  ``index_topk`` None: the dense
+  read, every program as it was.
+
 The rotary dims are in the rotate-half layout (the published checkpoint
 stores them interleaved; ``checkpoint/hf_loader.py`` de-interleaves).
-Device scopes under ``layers_<i>``: ``attn/q_proj`` (norm and ``W_q``),
+Device scopes under ``layers_<i>``: ``attn/q_proj`` (norm and ``W_q``, or
+``W_qa``, its norm and ``W_qb``),
 ``attn/kv_latent`` (``W_kva``, the latent norm, rotary, the insert),
 ``attn/latent_read`` (one-token rows: absorb into q, the walk, ``W_uv``),
 ``attn/expand`` and ``attn/prefill_read`` (the tile segment),
-``attn/out_proj``; ``mlp`` on a dense layer; ``moe/router``,
+``attn/out_proj``; with an indexer ``attn/index_k`` (``W_Ik``, LayerNorm,
+rotary, the insert into ``idx_k``), ``attn/index_score`` (``W_Iq``, ``W_Iw``,
+the scores), ``attn/index_topk`` and ``attn/sparse_read`` (both segments) in
+place of the three reads; ``mlp`` on a dense layer; ``moe/router``,
 ``moe/dispatch``, ``moe/experts``, ``moe/combine``, ``moe/shared``.
 """
 
@@ -61,7 +80,11 @@ import jax.numpy as jnp
 from deepspeed_tpu.inference.v2.kernels.latent_flash import (
     latent_decode_attention, latent_expand, latent_kernels_usable,
     latent_prefill_attention, latent_row_width)
-from deepspeed_tpu.inference.v2.modules.attention import _rms_norm, _rotary
+from deepspeed_tpu.inference.v2.kernels.sparse_latent import (
+    gathered_latent_read, index_scores, masked_latent_read, select_threshold,
+    select_topk, sort_key)
+from deepspeed_tpu.inference.v2.modules.attention import (_layer_norm,
+                                                          _rms_norm, _rotary)
 from deepspeed_tpu.inference.v2.modules.moe import dropless_moe
 from deepspeed_tpu.models.llama import apply_rotary
 from deepspeed_tpu.ops.quantized_matmul import qmm
@@ -97,9 +120,18 @@ class DeepseekV3Config:
     topk_method: str = "noaux_tc"
     rope_theta: float = 50000.0
     rms_norm_eps: float = 1e-5
-    #: the latent norm's eps: the published modelling code builds it with
-    #: its default, which ``config.json`` does not carry
+    #: the latent norms' eps (``kv_a_layernorm``, ``q_a_layernorm``): the
+    #: published modelling code builds them with its default, which
+    #: ``config.json`` does not carry
     latent_norm_eps: float = 1e-6
+    #: the sparse-attention indexer (``glm_moe_dsa``): its heads, their
+    #: width, and how many cached positions a query row reads; ``index_topk``
+    #: None = no indexer, the dense latent read
+    index_n_heads: int = 32
+    index_head_dim: int = 128
+    index_topk: Optional[int] = None
+    #: eps of the indexer key's LayerNorm (``k_norm``)
+    index_norm_eps: float = 1e-6
     max_position_embeddings: int = 8192
     #: the experts this program holds: ``[expert_start, expert_start +
     #: held_experts)`` of the router's; None = all of them
@@ -119,10 +151,17 @@ class DeepseekV3Config:
                 f"scoring_func={self.scoring_func!r}, topk_method="
                 f"{self.topk_method!r}: only the sigmoid score with a "
                 f"selection bias (noaux_tc) is implemented")
-        if self.q_lora_rank is not None:
+        if self.index_topk is not None and self.q_lora_rank is None:
             raise NotImplementedError(
-                f"q_lora_rank={self.q_lora_rank}: a low-rank query "
-                f"projection is not implemented (q_proj is one matrix)")
+                f"index_topk={self.index_topk} without q_lora_rank: the "
+                f"indexer's queries are made from the query latent")
+        if self.index_topk is not None and (
+                self.index_head_dim % 128
+                or self.qk_rope_head_dim > self.index_head_dim):
+            raise NotImplementedError(
+                f"index_head_dim={self.index_head_dim}: the indexer key is "
+                f"a pool row of whole 128-lane tiles whose first "
+                f"qk_rope_head_dim ({self.qk_rope_head_dim}) values rotate")
 
     def is_moe(self, i: int) -> bool:
         return i >= self.first_k_dense_replace \
@@ -135,13 +174,24 @@ class DeepseekV3Config:
 
 def param_shapes(cfg: DeepseekV3Config) -> Dict[str, Any]:
     """The parameter tree :class:`RaggedDeepseekV3` reads, as shapes (every
-    matrix stored [in, out]; ``kv_b_proj`` columns per head ``k_nope | v``)."""
+    matrix stored [in, out]; ``kv_b_proj`` columns per head ``k_nope | v``;
+    an indexer head's rotated dims come first)."""
     dt, h, hq = cfg.dtype, cfg.hidden_size, cfg.num_attention_heads
     qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
     e = cfg.held_experts or cfg.n_routed_experts
     f = cfg.moe_intermediate_size
     sds = lambda *shape: jax.ShapeDtypeStruct(shape, dt)
     kern = lambda i, o: {"kernel": sds(i, o)}
+    qr = cfg.q_lora_rank
+    q_path = {"q_proj": kern(h, hq * qk)} if qr is None else {
+        "q_a_proj": kern(h, qr), "q_a_layernorm": {"scale": sds(qr)},
+        "q_b_proj": kern(qr, hq * qk)}
+    if cfg.index_topk is not None:
+        hi, di = cfg.index_n_heads, cfg.index_head_dim
+        q_path["indexer"] = {
+            "wq_b": kern(qr, hi * di), "wk": kern(h, di),
+            "k_norm": {"scale": sds(di), "bias": sds(di)},
+            "weights_proj": kern(h, hi)}
     swiglu = lambda width: {"gate_proj": kern(h, width),
                             "up_proj": kern(h, width),
                             "down_proj": kern(width, h)}
@@ -157,7 +207,7 @@ def param_shapes(cfg: DeepseekV3Config) -> Dict[str, Any]:
             "input_layernorm": {"scale": sds(h)},
             "post_attention_layernorm": {"scale": sds(h)},
             "self_attn": {
-                "q_proj": kern(h, hq * qk),
+                **q_path,
                 "kv_a_proj_with_mqa": kern(
                     h, cfg.kv_lora_rank + cfg.qk_rope_head_dim),
                 "kv_a_layernorm": {"scale": sds(cfg.kv_lora_rank)},
@@ -260,8 +310,18 @@ class RaggedDeepseekV3:
     @property
     def kv_row(self) -> Dict[str, int]:
         """What the paged pool keeps per token and layer for this model,
-        instead of per-head keys and values: leaf name -> lanes."""
-        return {"ckv": self.config.row_width}
+        instead of per-head keys and values: leaf name -> lanes.  With an
+        indexer a second, narrower leaf: its key."""
+        row = {"ckv": self.config.row_width}
+        if self.index_topk is not None:
+            row["idx_k"] = self.config.index_head_dim
+        return row
+
+    @property
+    def index_topk(self) -> Optional[int]:
+        """Cached positions a query row reads (None: all of them); what the
+        engine counts ``idx_*`` / ``sel_*`` by."""
+        return self.config.index_topk
 
     # ------------------------------------------------------------------ #
     def __call__(self, params: Dict[str, Any], cache: Dict[str, Any],
@@ -313,22 +373,25 @@ class RaggedDeepseekV3:
     def _mla(self, lp, x, layer_cache, batch, cos, sin, prefill_tile,
              decode):
         """One latent-attention mixer over the flat token buffer.  Returns
-        ``(out [T, hidden], {"ckv": pool})``."""
+        ``(out [T, hidden], the layer's pool leaves)``."""
         cfg, att, dt = self.config, lp["self_attn"], self.config.dtype
         h, rank = cfg.num_attention_heads, cfg.kv_lora_rank
         nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, \
             cfg.v_head_dim
         width = cfg.row_width
-        bs = self.block_size
-        scale = float((nope + rope) ** -0.5)
-        tables, slot, pos = batch["block_tables"], batch["token_slot"], \
-            batch["token_pos"]
-        t_rows, s_rows = x.shape[0], tables.shape[0]
+        t_rows = x.shape[0]
         with jax.named_scope("attn/q_proj"):
             xa = _rms_norm(x, lp["input_layernorm"]["scale"],
                            cfg.rms_norm_eps)
-            q = qmm(xa, att["q_proj"]["kernel"], dt).reshape(
-                t_rows, h, nope + rope)
+            if cfg.q_lora_rank is None:
+                cq = None
+                q = qmm(xa, att["q_proj"]["kernel"], dt)
+            else:
+                cq = _rms_norm(qmm(xa, att["q_a_proj"]["kernel"], dt),
+                               att["q_a_layernorm"]["scale"],
+                               cfg.latent_norm_eps)
+                q = qmm(cq, att["q_b_proj"]["kernel"], dt)
+            q = q.reshape(t_rows, h, nope + rope)
             q_nope = q[..., :nope]
         with jax.named_scope("attn/kv_latent"):
             kva = qmm(xa, att["kv_a_proj_with_mqa"]["kernel"], dt)
@@ -341,6 +404,33 @@ class RaggedDeepseekV3:
                 [c, k_pe, jnp.zeros((t_rows, width - rank - rope), dt)], -1)
             pool = layer_cache["ckv"].at[batch["kv_dest"]].set(
                 row.astype(layer_cache["ckv"].dtype))
+        new_cache = {"ckv": pool}
+        if cfg.index_topk is None:
+            out = self._latent_read(att, q_nope, q_pe, pool, batch,
+                                    prefill_tile, decode)
+        else:
+            out, new_cache["idx_k"] = self._sparse_read(
+                att, xa, cq, q_nope, q_pe, pool, layer_cache["idx_k"], batch,
+                cos, sin, prefill_tile, decode)
+        with jax.named_scope("attn/out_proj"):
+            out = qmm(out.reshape(t_rows, h * vd), att["o_proj"]["kernel"],
+                      dt)
+        return out, new_cache
+
+    def _latent_read(self, att, q_nope, q_pe, pool, batch, prefill_tile,
+                     decode):
+        """The read of EVERY cached row up to a row's position (no indexer):
+        ``out [T, H, v_head_dim]``.  One-token rows absorbed, the tile
+        segment expanded."""
+        cfg, dt, bs = self.config, self.config.dtype, self.block_size
+        h, rank = cfg.num_attention_heads, cfg.kv_lora_rank
+        nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, \
+            cfg.v_head_dim
+        width = cfg.row_width
+        scale = float((nope + rope) ** -0.5)
+        tables, slot, pos = batch["block_tables"], batch["token_slot"], \
+            batch["token_pos"]
+        t_rows, s_rows = q_nope.shape[0], tables.shape[0]
         w_kvb = att["kv_b_proj"]["kernel"].astype(dt)
         kernels = self.interpret
         if kernels is None:
@@ -401,7 +491,88 @@ class RaggedDeepseekV3:
                     [out, expanded(slice(s_rows, t_rows))])
         else:
             out = expanded(slice(0, t_rows))
-        with jax.named_scope("attn/out_proj"):
-            out = qmm(out.reshape(t_rows, h * vd), att["o_proj"]["kernel"],
-                      dt)
-        return out, {"ckv": pool}
+        return out
+
+    def _sparse_read(self, att, xa, cq, q_nope, q_pe, pool, idx_pool, batch,
+                     cos, sin, prefill_tile, decode):
+        """The indexer and the read of what it selects, for every row of
+        the buffer: ``(out [T, H, v_head_dim], the idx_k pool)``.  Rows are
+        taken in groups that share a block table: the one-token rows one
+        each (a gather of the selected rows), the tile segment a tile each
+        (a mask over the blocks its rows share); a batch packed back to
+        back (no tiles) is one-token groups throughout."""
+        cfg, dt, bs = self.config, self.config.dtype, self.block_size
+        ix = att["indexer"]
+        h, rank = cfg.num_attention_heads, cfg.kv_lora_rank
+        nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, \
+            cfg.v_head_dim
+        hi, di, topk = cfg.index_n_heads, cfg.index_head_dim, cfg.index_topk
+        width = cfg.row_width
+        scale = float((nope + rope) ** -0.5)
+        tables, slot, pos = batch["block_tables"], batch["token_slot"], \
+            batch["token_pos"]
+        t_rows, s_rows = xa.shape[0], tables.shape[0]
+
+        def rot(v):                     # an indexer head: rope dims first
+            return jnp.concatenate(
+                [apply_rotary(v[..., :rope], cos, sin), v[..., rope:]], -1)
+
+        with jax.named_scope("attn/index_k"):
+            k_idx = _layer_norm(qmm(xa, ix["wk"]["kernel"], dt),
+                                ix["k_norm"], cfg.index_norm_eps)
+            idx_pool = idx_pool.at[batch["kv_dest"]].set(
+                rot(k_idx[:, None])[:, 0].astype(idx_pool.dtype))
+        with jax.named_scope("attn/index_score"):
+            q_idx = rot(qmm(cq, ix["wq_b"]["kernel"], dt).reshape(
+                t_rows, hi, di))
+            w_idx = qmm(xa, ix["weights_proj"]["kernel"], dt).astype(F32) \
+                * float(hi ** -0.5 * di ** -0.5)
+        w3 = att["kv_b_proj"]["kernel"].astype(dt).reshape(
+            rank, h, nope + vd)
+        with jax.named_scope("attn/sparse_read"):   # absorb W_uk into q
+            q_lat = jnp.einsum("thd,chd->thc", q_nope, w3[..., :nope],
+                               preferred_element_type=F32).astype(dt)
+            q_cat = jnp.concatenate(
+                [q_lat, q_pe,
+                 jnp.zeros((t_rows, h, width - rank - rope), dt)], -1)
+
+        def read(rows, r):
+            """Rows ``rows`` of the buffer in groups of ``r``."""
+            n = (rows.stop - rows.start) // r
+            grp = lambda a: a[rows].reshape((n, r) + a.shape[1:])
+            g_pos = grp(pos)
+            g_tab = tables[grp(slot)[:, 0]]
+            with jax.named_scope("attn/index_score"):
+                scores = index_scores(grp(q_idx), grp(w_idx), idx_pool,
+                                      g_tab, g_pos, block_size=bs)
+            c = scores.shape[-1]
+            if r == 1:
+                with jax.named_scope("attn/index_topk"):
+                    sel = select_topk(scores[:, 0], topk)
+                with jax.named_scope("attn/sparse_read"):
+                    o_lat = gathered_latent_read(
+                        grp(q_cat)[:, 0], pool, g_tab, g_pos[:, 0], sel,
+                        block_size=bs, rank=rank, scale=scale)
+            else:
+                with jax.named_scope("attn/index_topk"):
+                    key = sort_key(scores)
+                    thr, cut = select_threshold(key.reshape(n * r, c), topk,
+                                                live=jnp.max(g_pos) + 1)
+                with jax.named_scope("attn/sparse_read"):
+                    o_lat = masked_latent_read(
+                        grp(q_cat), pool, g_tab, g_pos, key,
+                        thr.reshape(n, r), cut.reshape(n, r),
+                        block_size=bs, rank=rank, scale=scale)
+            with jax.named_scope("attn/sparse_read"):
+                return jnp.einsum(
+                    "thc,chd->thd",
+                    o_lat.reshape(n * r, h, rank).astype(dt), w3[..., nope:],
+                    preferred_element_type=F32).astype(dt)
+
+        if decode or not prefill_tile:
+            return read(slice(0, t_rows), 1), idx_pool
+        out = read(slice(0, s_rows), 1)
+        if t_rows > s_rows:                 # the tile segment
+            out = jnp.concatenate(
+                [out, read(slice(s_rows, t_rows), int(prefill_tile))])
+        return out, idx_pool

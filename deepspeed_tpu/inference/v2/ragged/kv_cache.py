@@ -31,10 +31,12 @@ bit-exact.  Prefill/decode writes quantize on cache insert
 (``kernels/blocked_flash.py``), never as a separate materialized pass.
 
 **A model-stated row** (``kv_row``, e.g. ``{"ckv": 640}`` for latent
-attention): instead of ``k``/``v`` per KV head a layer holds the named
-leaves ``[num_blocks * block_size, lanes]`` behind the same allocator and
-block tables.  Every block operation is a ``tree_map`` over pool rows and
-carries such a row unchanged; what it cannot serve is :data:`LATENT_ROW`.
+attention, ``{"ckv": 640, "idx_k": 128}`` where a sparse-attention indexer
+keeps a second, narrower row a token for its keys): instead of ``k``/``v``
+per KV head a layer holds the named leaves ``[num_blocks * block_size,
+lanes]``, one or several, behind the same allocator and block tables.  Every
+block operation is a ``tree_map`` over pool rows and carries every such leaf
+unchanged; what it cannot serve is :data:`LATENT_ROW`.
 No model states a ``k`` / ``v`` row: :func:`flat_row` is the one way to the
 flat pool.
 

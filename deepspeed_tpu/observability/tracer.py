@@ -100,7 +100,11 @@ span                     site                        parent    attrs (counters)
                                                                (table blocks
                                                                that step's rows
                                                                hold: what its
-                                                               attention read)
+                                                               attention read);
+                                                               under an indexer
+                                                               ``idx_keys`` and
+                                                               ``sel_keys`` of
+                                                               that step's rows
 ``retire``               ``_settle``: the wait for   tick (a   —
                          the program in flight and   decode
                          its advance, by a tick      tick
@@ -161,6 +165,24 @@ span                     site                        parent    attrs (counters)
                                                                the expanded
                                                                read's grid over
                                                                tiles and layers)
+                                                               ; under a sparse-
+                                                               attention indexer
+                                                               (the model's
+                                                               ``index_topk``)
+                                                               in their place,
+                                                               a layer:
+                                                               ``idx_keys`` /
+                                                               ``sel_keys``
+                                                               (positions the
+                                                               one-token rows
+                                                               score, ``p + 1``,
+                                                               and read,
+                                                               ``min(p + 1, k)``)
+                                                               and ``idx_pairs``
+                                                               / ``sel_pairs``
+                                                               (the same sums
+                                                               over the chunks'
+                                                               rows)
 ``engine/upload``        ``engine.launch``: the      prefill   —
                          late rows' tokens into
                          the metadata, its ONE

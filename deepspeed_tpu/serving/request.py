@@ -103,7 +103,10 @@ class Request:
     #: wall-clock budget from arrival; past it the scheduler fails the
     #: request with reason "deadline" at the next tick (None = no SLO)
     deadline_s: Optional[float] = None
-    arrival_time: float = dataclasses.field(default_factory=time.monotonic)
+    # (through the module's ``time`` at call time, so that a test can put
+    # its own clock there)
+    arrival_time: float = dataclasses.field(
+        default_factory=lambda: time.monotonic())
     #: called as ``on_token(request, token)`` for every emitted token
     #: (streaming hook).  A raising callback is disabled and logged, not
     #: propagated — one client's broken stream handler must not corrupt

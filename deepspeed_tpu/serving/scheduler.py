@@ -868,7 +868,10 @@ class ContinuousBatchScheduler:
         closes with ``steps`` (1), ``ahead`` (was the returned step
         dispatched a tick ago) and ``read_blocks`` (the table blocks that
         step's rows hold up to the positions it fed: what its attention
-        read, from the host's own lengths)."""
+        read, from the host's own lengths), and under a model with a sparse
+        -attention indexer ``idx_keys`` / ``sel_keys`` (the positions that
+        step's rows scored, ``p + 1`` each, and read, ``min(p + 1,
+        index_topk)``)."""
         self.fast_ticks += 1
         with open_span(self.tracer, "decode") as span:
             traced = type(span) is SpanHandle
@@ -878,6 +881,7 @@ class ContinuousBatchScheduler:
                     self._fetch(logits, self.engine.last_launch),
                     np.float32)[:len(uids)]
                 held = self._held_blocks(packed) if traced else 0
+                fed = [r.fed for r in packed] if traced else ()
                 for req in packed:
                     req.fed += 1
                 tokens_out = sample_batch(
@@ -904,11 +908,15 @@ class ContinuousBatchScheduler:
                     self._abandon()
                     raise
                 held = self._held_blocks(step.packed) if traced else 0
+                fed = [r.fed for r in step.packed] if traced else ()
                 emitted = self._consume(step)
                 ahead = step.ahead
             if traced:
                 span.attrs = {"ahead": ahead, "steps": 1,
                               "read_blocks": held}
+                if getattr(self.engine, "index_topk", None) is not None:
+                    # what the consumed step's indexer scored and read
+                    span.attrs.update(self.engine.index_counters(fed))
         return emitted
 
     def _held_blocks(self, packed) -> int:

@@ -145,3 +145,54 @@ def test_a_family_with_two_state_leaves_is_refused_what_the_table_says(
                 STATE_SLOTS[1][feature])) as err:
             call({"state_spec": spec})
         assert path in str(err.value) and "(state_spec)" in str(err.value)
+
+
+# ------------------------------------------------------------------ #
+# a model-stated row of TWO leaves (a latent row and its indexer key,
+# ``glm_moe_dsa``): the latent row's entry, and every block operation
+# carries both
+# ------------------------------------------------------------------ #
+TWO_LEAVES = {"kv_row": {"ckv": 128, "idx_k": 128}}
+
+
+@pytest.mark.parametrize("feature", sorted(LATENT_ROW[1]))
+def test_a_row_of_two_leaves_refuses_what_a_latent_row_does(feature):
+    for path, call in PATHS[feature]:
+        with pytest.raises(CacheLayoutError) as err:
+            call(TWO_LEAVES)
+        assert LATENT_ROW[1][feature] in str(err.value), path
+
+
+def test_a_row_of_two_leaves_goes_through_every_block_operation():
+    import numpy as np
+
+    eng = _engine(TWO_LEAVES, enable_prefix_cache=True, host_tier=True,
+                  host_tier_bytes=1 << 20)
+    sm = eng.state_manager
+    assert set(sm.unserved) == set(LATENT_ROW[1])
+    for feature in set(FEATURES) - set(LATENT_ROW[1]):
+        sm.require(feature, "a path")
+    kv = sm.kv_cache
+    assert kv.per_token_bytes == 2 * (128 + 128) * 4
+    assert {k: v.shape for k, v in kv.cache["layer_1"].items()} == {
+        "ckv": (17 * 8, 128), "idx_k": (17 * 8, 128)}
+    # rows of block 3 written by hand in both leaves of both layers
+    mark = {name: jnp.arange(8 * 128, dtype=jnp.float32).reshape(8, 128)
+            + 1000.0 * i for i, name in enumerate(("ckv", "idx_k"))}
+    kv.update({layer: {name: pool.at[24:32].set(mark[name])
+                       for name, pool in leaves.items()}
+               for layer, leaves in kv.cache.items()})
+    kv.copy_block(3, 9)
+    payload = kv.gather_blocks([9, 3])
+    for layer in ("layer_0", "layer_1"):
+        for name in ("ckv", "idx_k"):
+            got = np.asarray(payload[layer][name])
+            assert got.shape == (16, 128)
+            assert (got[:8] == np.asarray(mark[name])).all()
+            assert (got[8:] == np.asarray(mark[name])).all()
+    kv.scatter_blocks([5, 6], payload)
+    assert (np.asarray(kv.cache["layer_1"]["idx_k"][40:48])
+            == np.asarray(mark["idx_k"])).all()
+    with pytest.raises(ValueError):         # a payload of one leaf
+        kv.scatter_blocks([5, 6], {layer: {"ckv": leaves["ckv"]}
+                                   for layer, leaves in payload.items()})

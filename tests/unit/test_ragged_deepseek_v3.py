@@ -322,7 +322,7 @@ def test_router_selects_by_score_plus_bias_and_weighs_by_score():
 
 @pytest.mark.parametrize("over, match", [
     ({"n_group": 2}, "n_group=2"), ({"topk_group": 2}, "topk_group=2"),
-    ({"q_lora_rank": 16}, "q_lora_rank=16"),
+    ({"topk_method": "greedy"}, "topk_method='greedy'"),
     ({"scoring_func": "softmax"}, "scoring_func='softmax'")])
 def test_what_the_router_does_not_compute_is_refused_by_name(over, match):
     with pytest.raises(NotImplementedError, match=match):
@@ -330,6 +330,49 @@ def test_what_the_router_does_not_compute_is_refused_by_name(over, match):
     if "n_group" in over or "topk_group" in over:
         with pytest.raises(ValueError, match="n_group"):
             reference.logits_at({}, _ids(4), dict(HF, **over), rows=[0])
+
+
+def test_a_low_rank_query_and_an_indexer_are_read_from_published_keys():
+    """``q_lora_rank`` and the three ``index_*`` keys change the parameter
+    tree and the pool row of the configurations that set them; Moonlight's
+    (``q_lora_rank`` None, no ``index_topk``) keeps its tree, its one-leaf
+    row and its scopes."""
+    plain = rd.RaggedDeepseekV3(_config(jnp.float32), BLOCK)
+    assert plain.kv_row == {"ckv": 128} and plain.index_topk is None
+    att = rd.param_shapes(plain.config)["layers_1"]["self_attn"]
+    assert set(att) == {"q_proj", "kv_a_proj_with_mqa", "kv_a_layernorm",
+                        "kv_b_proj", "o_proj"}
+    low = rd.DeepseekV3Config(**{**vars(plain.config), "q_lora_rank": 48})
+    att = rd.param_shapes(low)["layers_1"]["self_attn"]
+    assert "q_proj" not in att and att["q_a_proj"]["kernel"].shape == (64, 48)
+    assert att["q_a_layernorm"]["scale"].shape == (48,)
+    assert att["q_b_proj"]["kernel"].shape == (48, 4 * 24)
+    assert "indexer" not in att
+    assert rd.RaggedDeepseekV3(low, BLOCK).kv_row == {"ckv": 128}
+    dsa = rd.DeepseekV3Config(**{**vars(low), "index_topk": 24,
+                                 "index_n_heads": 4})
+    ix = rd.param_shapes(dsa)["layers_0"]["self_attn"]["indexer"]
+    assert {k: tuple(v[n].shape for n in sorted(v)) for k, v in ix.items()} \
+        == {"wq_b": ((48, 512),), "wk": ((64, 128),),
+            "k_norm": ((128,), (128,)), "weights_proj": ((64, 4),)}
+    model = rd.RaggedDeepseekV3(dsa, BLOCK)
+    assert model.kv_row == {"ckv": 128, "idx_k": 128}
+    assert model.index_topk == 24
+    with pytest.raises(NotImplementedError, match="query latent"):
+        rd.DeepseekV3Config(**{**vars(plain.config), "index_topk": 24})
+
+
+def test_moonlights_programs_keep_their_reads():
+    """No indexer, no ``attn/index_*`` or ``attn/sparse_read`` scope: the
+    dense latent reads, as before."""
+    eng = _engine(_params(), max_seqs=4)
+    eng.put([1], [_ids(70).tolist()])
+    eng.decode_step([1], [3])
+    for key in eng.step_keys:
+        text = eng.lower_step(key).as_text(debug_info=True)
+        assert "attn/index_" not in text and "attn/sparse_read" not in text
+        assert "attn/latent_read" in text or "attn/prefill_read" in text
+    assert eng.index_topk is None
 
 
 # ------------------------------------------------------------------ #

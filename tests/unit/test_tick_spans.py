@@ -780,12 +780,46 @@ def _preempted_before_its_first_token(sched):
     return req, {"chunks": 3, "preempted_after": 1}
 
 
+class _StepClock:
+    """``time`` for the modules that stamp a request: every read of the
+    clock is a microsecond after the one before it, so the gap between two
+    stamps counts the clock reads between them and not what else the
+    machine was doing (six xdist workers on a loaded host stretched a
+    gap between two adjacent stamps past a millisecond: PR 49's last run)."""
+
+    def __init__(self):
+        self.ns = 1_000_000_000
+
+    def monotonic_ns(self):
+        self.ns += 1_000
+        return self.ns
+
+    def monotonic(self):
+        return self.monotonic_ns() / 1e9
+
+    def __getattr__(self, name):
+        import time
+
+        return getattr(time, name)
+
+
+@pytest.fixture
+def step_clock(monkeypatch):
+    from deepspeed_tpu.serving import request as request_mod
+    from deepspeed_tpu.serving import scheduler as scheduler_mod
+
+    clock = _StepClock()
+    for mod in (tracer_mod, request_mod, scheduler_mod):
+        monkeypatch.setattr(mod, "time", clock)
+    return clock
+
+
 @pytest.mark.parametrize("scenario, budget", [
     (_greedy, 32), (_stochastic, 32), (_three_chunks, 16),
     (_behind_a_step_sent_ahead, 32), (_preempted_before_its_first_token, 16)],
     ids=["greedy", "stochastic", "three_chunks", "behind_a_step_sent_ahead",
          "preempted_before_its_first_token"])
-def test_request_span_chain(params, scenario, budget):
+def test_request_span_chain(params, scenario, budget, step_clock):
     """One open phase a live request from submit to finish, under its own
     ``trace_id``: ``request/queued`` (submit -> admission), ``request/
     prefill`` (-> the first token handed out; closes with the step programs
@@ -813,8 +847,10 @@ def test_request_span_chain(params, scenario, budget):
     for a, b in zip(spans, spans[1:]):
         assert 0 <= b["t0_ns"] - a["t1_ns"] < 1_000_000
     # ... from the submit to the first token, as the request itself
-    # stamped them (within a millisecond here: the request reads the clock
-    # when it is made and once a tick before the tokens are handed out)
+    # stamped them (on the injected clock: under a thousand clock reads
+    # between two adjacent stamps, two thousand between the request's own
+    # and the spans'; the request reads the clock when it is made and once
+    # a tick before the tokens are handed out)
     queued, prefill, decode = spans[-3:]
     own = (req.first_token_time - req.arrival_time) * 1e9
     assert abs((decode["t0_ns"] - spans[0]["t0_ns"]) - own) < 2_000_000
