@@ -36,6 +36,7 @@ KERNEL_MODULES = (
     "deepspeed_tpu.ops.evoformer_attn",
     "deepspeed_tpu.inference.v2.kernels.blocked_flash",
     "deepspeed_tpu.inference.v2.kernels.latent_flash",
+    "deepspeed_tpu.inference.v2.kernels.sparse_latent",
     # (no ``pallas_call`` site: the cases of the route over blocked_flash)
     "deepspeed_tpu.inference.v2.modules.attention",
 )

@@ -658,6 +658,9 @@ class InferenceEngineV2:
         if self.index_topk is not None:
             attrs.update(self.index_counters(
                 [s.seen_tokens for s, n in rows if n == 1], tiled))
+            if tile and tiled:
+                attrs.update(self._sparse_step_counters(
+                    tiled, (bucket - self._batch.max_seqs) // tile, tile))
         elif tile and tiled and not sm.kv_cache.quantized:
             count = self._latent_step_counters if latent \
                 else self._chunk_step_counters
@@ -1039,6 +1042,21 @@ class InferenceEngineV2:
         layers = len(sm.kv_cache.kv_layers) * self._passes
         return {"latent_key_steps": layers * steps,
                 "latent_live_key_steps": layers * live}
+
+    def _sparse_step_counters(self, chunks, tiles: int,
+                              tile: int) -> Dict[str, int]:
+        """What the tile rows' sparse read (``sparse_tile_read``) walks for
+        one dispatch, a layer: ``sparse_key_steps``, the key steps its
+        tiles' tables hold, and ``sparse_live_key_steps``, those of them at
+        or before a tile's last position, the ones that do work
+        (``sparse_tile_key_steps``, the kernel's own rule)."""
+        from deepspeed_tpu.inference.v2.kernels.sparse_latent import \
+            sparse_tile_key_steps
+
+        steps, live = sparse_tile_key_steps(
+            chunks, tiles, block_size=self.state_manager.block_size,
+            entries=self._max_blocks, tile_q=tile)
+        return {"sparse_key_steps": steps, "sparse_live_key_steps": live}
 
     def _recover_donated_cache(self) -> None:
         """A jitted step that donates the KV cache raised after donation

@@ -72,6 +72,7 @@ place of the three reads; ``mlp`` on a dense layer; ``moe/router``,
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, Optional
 
 import jax
@@ -82,7 +83,7 @@ from deepspeed_tpu.inference.v2.kernels.latent_flash import (
     latent_prefill_attention, latent_row_width)
 from deepspeed_tpu.inference.v2.kernels.sparse_latent import (
     gathered_latent_read, index_scores, masked_latent_read, select_threshold,
-    select_topk, sort_key)
+    select_topk, sort_key, sparse_tile_read, sparse_tile_read_usable)
 from deepspeed_tpu.inference.v2.modules.attention import (_layer_norm,
                                                           _rms_norm, _rotary)
 from deepspeed_tpu.inference.v2.modules.moe import dropless_moe
@@ -536,6 +537,14 @@ class RaggedDeepseekV3:
                 [q_lat, q_pe,
                  jnp.zeros((t_rows, h, width - rank - rope), dt)], -1)
 
+        # the tile rows' read: the Mosaic kernel on a TPU (at widths it can
+        # tile; interpreted where a test asks), else the XLA composition
+        tile_read = masked_latent_read
+        if self.interpret or (self.interpret is None and on_tpu()
+                              and sparse_tile_read_usable(rank, width, bs)):
+            tile_read = functools.partial(sparse_tile_read,
+                                          interpret=bool(self.interpret))
+
         def read(rows, r):
             """Rows ``rows`` of the buffer in groups of ``r``."""
             n = (rows.stop - rows.start) // r
@@ -559,7 +568,7 @@ class RaggedDeepseekV3:
                     thr, cut = select_threshold(key.reshape(n * r, c), topk,
                                                 live=jnp.max(g_pos) + 1)
                 with jax.named_scope("attn/sparse_read"):
-                    o_lat = masked_latent_read(
+                    o_lat = tile_read(
                         grp(q_cat), pool, g_tab, g_pos, key,
                         thr.reshape(n, r), cut.reshape(n, r),
                         block_size=bs, rank=rank, scale=scale)

@@ -106,8 +106,11 @@ def _ref_params(params):
 
 
 def _engine(params, act=jnp.float32, hf=HF, blocks=120, max_context=512,
-            max_seqs=MAX_SEQS, budget=BUDGET, tile=TILE, **kv):
+            max_seqs=MAX_SEQS, budget=BUDGET, tile=TILE, interpret=None,
+            **kv):
     model = rd.RaggedDeepseekV3(_config(act, hf), BLOCK)
+    # True: the tile rows' read through its Mosaic kernel, interpreted
+    model.interpret = interpret
     eng = InferenceEngineV2(
         model, jax.tree.map(lambda a: a.astype(act), params),
         RaggedInferenceEngineConfig.from_dict({
@@ -152,15 +155,26 @@ def _gap(got, want) -> float:
 # (a) engine against reference: chunks that cross index_topk, then decode
 # through both leaves
 # ------------------------------------------------------------------ #
-@pytest.mark.parametrize("n_prompt, tile, budget", [
-    (20, TILE, BUDGET),         # under index_topk: every row reads all
-    (140, TILE, BUDGET),        # three chunks, the masked read over tiles
-    (140, 128, 60),             # no tiles: rows packed back to back
+@pytest.fixture
+def short_key_steps(monkeypatch):
+    """The kernel's key step at 64 positions: a context of 140 is three."""
+    monkeypatch.setattr(sl, "_STEP_KEYS", 64)
+    sl.sparse_tile_read.clear_cache()       # (traced at another step)
+    yield
+    sl.sparse_tile_read.clear_cache()
+
+
+@pytest.mark.parametrize("n_prompt, tile, budget, interpret", [
+    (20, TILE, BUDGET, None),   # under index_topk: every row reads all
+    (140, TILE, BUDGET, None),  # three chunks, the masked read over tiles
+    (140, 128, 60, None),       # no tiles: rows packed back to back
+    (140, TILE, BUDGET, True),  # the tiles' read through the kernel
 ])
-def test_f32_engine_matches_reference(n_prompt, tile, budget):
+def test_f32_engine_matches_reference(n_prompt, tile, budget, interpret,
+                                      short_key_steps):
     params = _params()
     ids = _ids(n_prompt + 6)
-    eng = _engine(params, tile=tile, budget=budget)
+    eng = _engine(params, tile=tile, budget=budget, interpret=interpret)
     assert (eng._prefill_tile() is None) == (tile == 128)
     assert _gap(_serve(eng, ids, n_prompt),
                 _want(params, ids, n_prompt)) <= F32_TOL
@@ -311,10 +325,95 @@ def test_index_scores_walk_a_table_and_stop_at_the_position():
 
 
 # ------------------------------------------------------------------ #
+# (b') the tile rows' read as a Mosaic kernel (interpret mode) against the
+# XLA composition on the same operands
+# ------------------------------------------------------------------ #
+def _tile_operands(dtype, last, scores_of, entries=20, seed=0, rows=TILE,
+                   heads=4, width=128, rank=32):
+    """A ``masked_latent_read`` call of one tile a sequence: tile ``t``'s
+    rows end at position ``last[t]`` (``-1``: a pad tile; rows before
+    position 0 are pad rows), its index scores ``scores_of(rng, shape)``,
+    its table ``entries`` blocks of a shuffled pool."""
+    rng = np.random.default_rng(seed)
+    g, c = len(last), entries * BLOCK
+    pool = jnp.asarray(rng.standard_normal(((g * entries + 1) * BLOCK,
+                                            width)), dtype)
+    tables = jnp.asarray(rng.permutation(np.arange(1, g * entries + 1))
+                         .reshape(g, entries), jnp.int32)
+    pos = np.asarray(last)[:, None] - np.arange(rows)[::-1][None, :]
+    pos = np.where((pos >= 0) & (np.asarray(last)[:, None] >= 0), pos, -1)
+    scores = np.where(np.arange(c)[None, None] <= pos[..., None],
+                      scores_of(rng, (g, rows, c)).astype(np.float32),
+                      -np.inf)
+    key = sl.sort_key(jnp.asarray(scores))
+    thr, cut = sl.select_threshold(key.reshape(g * rows, c), TOPK)
+    q = jnp.asarray(0.3 * rng.standard_normal((g, rows, heads, width)),
+                    dtype)
+    return (q, pool, tables, jnp.asarray(pos, jnp.int32), key,
+            thr.reshape(g, rows), cut.reshape(g, rows))
+
+
+_NORMAL = lambda rng, shape: rng.standard_normal(shape)
+#: six values over a context: every row's 24th largest score is a tie
+_TIES = lambda rng, shape: rng.integers(0, 6, shape)
+
+
+@pytest.mark.parametrize("dtype, tol", [(jnp.float32, 1e-5),
+                                        (jnp.bfloat16, 1e-2)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", ["context_0", "ties", "short_and_long",
+                                  "pads"])
+def test_the_tile_read_kernel_is_the_masked_read(case, dtype, tol,
+                                                 short_key_steps):
+    """(a) a chunk at context 0: its first rows hold fewer than
+    ``index_topk`` positions and select them all; (b) a context of five key
+    steps whose scores tie on every threshold, so ``cut`` decides; (c) the
+    end of a long sequence beside the start of a short one: each tile
+    stops at its own last position; (d) a pad tile between two live ones, a
+    tile with pad rows, and tables whose entries past the context name
+    block 0, the allocator's trash block."""
+    last, scores_of, trash = {
+        "context_0": ([15, 31, 47], _NORMAL, False),
+        "ties": ([300, 316], _TIES, False),
+        "short_and_long": ([309, 15, 31, 200], _NORMAL, False),
+        "pads": ([150, -1, 9, -1], _TIES, True)}[case]
+    args = list(_tile_operands(dtype, last, scores_of))
+    if trash:       # table entries past each tile's context: block 0
+        live = np.asarray(last)[:, None] // BLOCK
+        args[2] = jnp.where(np.arange(20)[None] <= live, args[2], 0)
+        args[1] = args[1].at[:BLOCK].set(7.0)
+    kw = dict(block_size=BLOCK, rank=32, scale=0.2)
+    want = np.asarray(sl.masked_latent_read(*args, **kw))
+    got = np.asarray(sl.sparse_tile_read(*args, interpret=True, **kw))
+    assert got.shape == want.shape and got.dtype == args[0].dtype
+    got = got.astype(np.float32)
+    assert np.isfinite(got).all()
+    assert np.max(np.abs(got - want)) <= tol * np.max(np.abs(want))
+    # a pad row reads nothing
+    assert (got[np.asarray(args[3]) < 0] == 0).all()
+    if case == "ties":      # and ``cut`` matters: without it another sum
+        args[6] = jnp.full_like(args[6], 1 << 20)
+        other = np.asarray(sl.masked_latent_read(*args, **kw))
+        assert np.max(np.abs(other - want)) > 1e-2 * np.max(np.abs(want))
+
+
+def test_the_tile_read_counters_are_the_kernels_rule(short_key_steps):
+    """``sparse_tile_key_steps`` counts what the kernel's walk does: steps
+    of 64 positions here, a tile up to its own last position."""
+    # a chunk of 40 from 100 (tiles 100-115, 116-131, 132-139) beside one of
+    # 16 from 0, in a segment of 6 tiles over tables of 32 blocks
+    steps, live = sl.sparse_tile_key_steps(
+        [(100, 40), (0, 16)], 6, block_size=BLOCK, entries=32, tile_q=TILE)
+    assert (steps, live) == (6 * 8, 2 + 3 + 3 + 1)
+
+
+# ------------------------------------------------------------------ #
 # (c) faults: what the check must see (glm_dsa_faults.py, beside this file;
 # benchmark/tools/calls/pr50_faults.py applies the same on the chip at the
 # published widths)
 # ------------------------------------------------------------------ #
+@pytest.mark.parametrize("interpret", [None, True],
+                         ids=["composition", "kernel-interpreted"])
 @pytest.mark.parametrize("name, seen", [
     ("indexer_dropped", True), ("recent_topk", True),
     ("k_off_by_block", True), ("indexer_rope_missing", True),
@@ -322,11 +421,12 @@ def test_index_scores_walk_a_table_and_stop_at_the_position():
     # a positive factor on a row's scores changes no order
     ("w_scale_missing", False),
 ])
-def test_a_fault_in_the_indexer_moves_the_logits(name, seen):
+def test_a_fault_in_the_indexer_moves_the_logits(name, seen, interpret,
+                                                 short_key_steps):
     params = _params()
     ids = _ids(146)
     with fault(name, block=8):
-        got = _serve(_engine(params), ids, 140)
+        got = _serve(_engine(params, interpret=interpret), ids, 140)
     gap = _gap(got, _want(params, ids, 140))
     assert (gap > BF16_TOL) if seen else (gap <= F32_TOL), gap
 
@@ -465,9 +565,10 @@ def test_what_two_leaves_cannot_do_refuses_by_name(path):
 # ------------------------------------------------------------------ #
 # (e) counters and device scopes
 # ------------------------------------------------------------------ #
-def test_the_four_counters_match_a_hand_count():
+def test_the_four_counters_match_a_hand_count(short_key_steps):
     """100 tokens: chunks of 64 and 36 at a 64-token budget; then a join of
-    40 tokens beside the first one's decode."""
+    40 tokens beside the first one's decode; then two prompts at once, the
+    end of the first beside the start of the second in one batch."""
     tracer = Tracer()
     eng = _engine(_params())
     sched = ContinuousBatchScheduler(eng, tracer=tracer)
@@ -491,6 +592,13 @@ def test_the_four_counters_match_a_hand_count():
         # prompt's 100, the decode step consumed and the one sent ahead)
         (103, k, tri(0, 40), tri(0, k) + (40 - k) * k)]
     assert all("latent_key_steps" not in a for a in built)
+    # the tile rows' walk, in steps of 64 positions over tables of 32
+    # blocks (8 steps a tile): a tile walks to its own last position
+    assert [(a["bucket"], a["sparse_key_steps"],
+             a["sparse_live_key_steps"]) for a in built] == [
+        (MAX_SEQS + 64, 4 * 8, 1 + 1 + 1 + 1),      # rows end at 15 .. 63
+        (MAX_SEQS + 64, 4 * 8, 2 + 2 + 2),          # at 79, 95, 99
+        (MAX_SEQS + 64, 4 * 8, 1 + 1 + 1)]          # at 15, 31, 39
     dec = [r["attrs"] for r in spans if r["name"] == "decode"]
     # every decoding row is past index_topk: each reads 24 of its p + 1
     assert dec and all(a["sel_keys"] in (k, 2 * k)
@@ -499,6 +607,20 @@ def test_the_four_counters_match_a_hand_count():
     assert (dec[0]["idx_keys"], dec[0]["sel_keys"]) == (101, k)
     # rows of both requests in one step: each scored p + 1, read 24
     assert any(a["sel_keys"] == 2 * k for a in dec)
+    # two chunks in one batch: 70 tokens and 20; the second batch holds the
+    # first prompt's last 6 (one tile, rows end at 69: two steps) beside
+    # the second's 20 (tiles that end at 15 and 19: one step each)
+    sched.submit(_ids(70, seed=5).tolist(), _greedy(2))
+    sched.submit(_ids(20, seed=6).tolist(), _greedy(2))
+    sched.run_until_idle()
+    built = [r["attrs"] for r in tracer.records()
+             if r.get("ph") == "X" and r["name"] == "engine/build_batch"
+             and r["attrs"].get("chunk_seqs")][3:]
+    assert [(a["chunk_seqs"], a["chunk_tokens"], a["sparse_key_steps"],
+             a["sparse_live_key_steps"]) for a in built] == [
+        (1, 64, 4 * 8, 4), (2, 26, 4 * 8, 2 + 1 + 1)]
+    assert all(a["sparse_live_key_steps"] < a["sparse_key_steps"]
+               for a in built)
 
 
 def test_device_scopes_of_the_indexer_and_the_sparse_read():
@@ -519,6 +641,17 @@ def test_device_scopes_of_the_indexer_and_the_sparse_read():
                   "attn/sparse_read"):
         assert f"layers_1/{scope}" in text, scope
     assert "attn/prefill_read" not in text and "attn/expand" not in text
+    assert "_sparse_tile_read_kernel" not in text       # the composition
+    # through the kernel: the jitted wrapper is lowered once and called
+    # under the read's scope in every layer, the ``pallas_call`` inside it
+    # by its own name (the compiled op's name is the two joined)
+    eng = _engine(_params(), max_seqs=4, interpret=True)
+    eng._get_step(4 + TILE, TILE)       # built, not run: one tile
+    text = eng.lower_step((4 + TILE, TILE)).as_text(debug_info=True)
+    for i in range(HF["num_hidden_layers"]):
+        assert (f'/layers_{i}/attn/sparse_read/jit(sparse_tile_read)"'
+                in text), i
+    assert 'loc("_sparse_tile_read_kernel/pallas_call"' in text
 
 
 # ------------------------------------------------------------------ #

@@ -1207,6 +1207,7 @@ _KERNEL_ENTRIES = [
     ("latent_decode_dma", "_latent_decode_kernel"),
     ("latent_expand_prefill", "_latent_expand_kernel"),
     ("latent_expand_prefill", "_latent_prefill_kernel"),
+    ("sparse_tile_read", "_sparse_tile_read_kernel"),
     ("gmm_fwd", "_gmm_kernel"),
     ("gmm_dlhs", "_gmm_dlhs_kernel"),
     ("gmm_drhs", "_gmm_drhs_kernel"),
@@ -1252,7 +1253,7 @@ def test_every_pallas_call_site_is_covered():
         src = (root.parent / (mod.replace(".", "/") + ".py")).read_text()
         sites += len(re.findall(r"pl\.pallas_call\(", src))
         named += len(re.findall(r"\*\*kernel_names\(", src))
-    assert sites == named == 29
+    assert sites == named == 30
 
 
 def test_paged_wrappers_keep_their_instruction_names():
