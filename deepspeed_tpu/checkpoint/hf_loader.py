@@ -358,6 +358,61 @@ def _glm_moe_dsa_rules() -> List[Rule]:
     ] + [r for r in _deepseek_v3_rules() if r"q_proj\." not in r[0]]
 
 
+def _longcat_flash_rules() -> List[Rule]:
+    # LongCat-Flash (``model_type: longcat_flash``; LongCat-Flash-Omni's
+    # language model) -> RaggedLongcatFlash's tree: a published layer keeps
+    # its two sub-blocks as module lists (``self_attn.{0,1}``,
+    # ``mlps.{0,1}``, ``input_layernorm.{0,1}``,
+    # ``post_attention_layernorm.{0,1}``: ``sub_0`` / ``sub_1`` here) and
+    # one routed branch (``mlp.router.classifier`` over the 512 experts then
+    # the 256 zero-compute outputs, its ``e_score_correction_bias``, and
+    # ``mlp.experts.<n>`` for the experts alone: a zero-compute expert has
+    # no tensor).  The rope dims of ``q_b_proj`` and ``kv_a_proj_with_mqa``
+    # are de-interleaved once, here.  No LongCat-Flash checkpoint is in the
+    # repository: the names are the published ones, tested on a synthetic
+    # state dict.
+    def sub(m, *leaf):
+        return (f"layers_{m.group(1)}", f"sub_{m.group(2)}", *leaf)
+
+    def branch(m, *leaf):
+        return (f"layers_{m.group(1)}", "mlp", *leaf)
+
+    layer = r"^model\.layers\.(\d+)\."
+    attn = layer + r"self_attn\.([01])\."
+    q_rows = _deepseek_v3_rope_rows(
+        lambda c: int(c["qk_nope_head_dim"]) + int(c["qk_rope_head_dim"]))
+    kva_rows = _deepseek_v3_rope_rows(
+        lambda c: int(c["kv_lora_rank"]) + int(c["qk_rope_head_dim"]))
+    return [r for r in _flat_moe_backbone_rules()
+            if r"layers" not in r[0]] + [
+        (attn + r"(q_a|kv_b|o)_proj\.weight$",
+         lambda m: (sub(m, "self_attn", f"{m.group(3)}_proj", "kernel"),
+                    "t")),
+        (attn + r"(q_a|kv_a)_layernorm\.weight$",
+         lambda m: (sub(m, "self_attn", f"{m.group(3)}_layernorm", "scale"),
+                    None)),
+        (attn + r"q_b_proj\.weight$",
+         lambda m: (sub(m, "self_attn", "q_b_proj", "kernel"), q_rows)),
+        (attn + r"kv_a_proj_with_mqa\.weight$",
+         lambda m: (sub(m, "self_attn", "kv_a_proj_with_mqa", "kernel"),
+                    kva_rows)),
+        (layer + r"mlps\.([01])\.(gate|up|down)_proj\.weight$",
+         lambda m: (sub(m, "mlp", f"{m.group(3)}_proj", "kernel"), "t")),
+        (layer + r"(input_layernorm|post_attention_layernorm)\.([01])"
+         r"\.weight$",
+         lambda m: ((f"layers_{m.group(1)}", f"sub_{m.group(3)}",
+                     m.group(2), "scale"), None)),
+        (layer + r"mlp\.router\.classifier\.weight$",
+         lambda m: (branch(m, "gate", "wg", "kernel"), "t")),
+        (layer + r"mlp\.router\.e_score_correction_bias$",
+         lambda m: (branch(m, "gate", "e_score_correction_bias"), None)),
+        (layer + r"mlp\.experts\.(\d+)\.(gate|up|down)_proj\.weight$",
+         lambda m: (branch(m, "experts", f"w_{m.group(3)}"),
+                    ("stack", int(m.group(2))))),
+        (r".*rotary_emb\.inv_freq$", lambda m: (None, None)),
+    ]
+
+
 def _lfm2_moe_rules() -> List[Rule]:
     # LFM2-MoE (``model_type: lfm2_moe``; LFM2-24B-A2B) -> RaggedLfm2's tree.
     # The head is tied to the embedding: a checkpoint's ``lm_head.weight``
@@ -729,6 +784,7 @@ _ARCH_RULES: Dict[str, Callable[[], List[Rule]]] = {
     "qwen3_next": _qwen3_next_rules,
     "deepseek_v3": _deepseek_v3_rules,
     "glm_moe_dsa": _glm_moe_dsa_rules,
+    "longcat_flash": _longcat_flash_rules,
     "lfm2_moe": _lfm2_moe_rules,
     "afmoe": _afmoe_rules,
     "ouro": _ouro_rules,
@@ -875,6 +931,23 @@ def config_from_hf(model_path: str, dtype: Any = None):
             rope_theta=float(rope.get("rope_theta",
                                       cfg.get("rope_theta", 1e6))),
             dtype=dt)
+    if arch == "longcat_flash":
+        from deepspeed_tpu.inference.v2.model_implementations. \
+            ragged_longcat_flash import LongcatFlashConfig
+
+        if cfg.get("rope_scaling") is not None or cfg.get("attention_bias") \
+                or cfg.get("tie_word_embeddings"):
+            raise HFLoadError(
+                "longcat_flash: rope_scaling, attention_bias and a tied "
+                "head are not implemented (LongCat-Flash sets none of "
+                "them)")
+        fields = {f.name for f in dataclasses.fields(LongcatFlashConfig)} \
+            - {"dtype"}
+        # (zero_expert_type other than identity: the config refuses it by
+        # name; the multi-token-prediction head has no key here and its
+        # tensors, if a checkpoint carries them, are not mapped)
+        return arch, LongcatFlashConfig(
+            **{k: v for k, v in cfg.items() if k in fields}, dtype=dt)
     if arch == "lfm2_moe":
         from deepspeed_tpu.inference.v2.model_implementations. \
             ragged_lfm2 import Lfm2Config

@@ -208,6 +208,14 @@ class InferenceEngineV2:
         #: cached positions a query row of the model reads (its learned
         #: sparse-attention indexer's top-k); None: every one
         self.index_topk: Optional[int] = getattr(model, "index_topk", None)
+        #: counters the model decides on the device (how its router's slots
+        #: fell: ``RaggedLongcatFlash``), by name: its forward returns them
+        #: as one int32 vector beside the logits, and every greedy step
+        #: program of such a model returns ``next_tokens`` with that
+        #: vector BEHIND its ``max_seqs`` tokens, so they cross to the host
+        #: in the transfer that brings the tokens (``counters_of``).  ():
+        #: ``next_tokens`` is int32[max_seqs], every program as it was
+        self.step_counters = tuple(getattr(model, "step_counters", ()))
         try:
             self.state_manager = DSStateManager(
                 sm_cfg, kv_cfg, num_layers=model.num_layers,
@@ -498,10 +506,12 @@ class InferenceEngineV2:
 
             def run(params, cache, packed):
                 batch = unpack_metadata(packed, bucket, S, B, **fields)
-                logits, new_cache = self.model(params, cache, batch,
-                                               prefill_tile=prefill_tile)
+                logits, new_cache, *counts = self.model(
+                    params, cache, batch, prefill_tile=prefill_tile)
                 with jax.named_scope("sample_argmax"):
                     nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                    if counts:          # (``step_counters``)
+                        nxt = jnp.concatenate([nxt, counts[0]])
                 return logits, nxt, new_cache
 
             step = jax.jit(_named(run, f"ragged_step_T{bucket}" + (
@@ -763,10 +773,11 @@ class InferenceEngineV2:
             return {}
         with open_span(self.tracer, "fetch" if greedy else
                        "engine/fetch_logits") as span:
-            if type(span) is SpanHandle:
-                span.attrs = {"launch": launch}
             host = jax.device_get(rows)
             host = host.tolist() if greedy else np.asarray(host, np.float32)
+            if type(span) is SpanHandle:
+                span.attrs = {"launch": launch,
+                              **(self.counters_of(host) if greedy else {})}
         return {uid: host[slot] for slot, uid in done}
 
     # ------------------------------------------------------------------ #
@@ -827,10 +838,12 @@ class InferenceEngineV2:
             raise ValueError(f"decode_step: {n} sequences exceed max_seqs {S}")
         with open_span(self.tracer, "engine/decode_prep") as span:
             seqs, state = self._prepare_decode(uids)
-            tok = self._as_token_array(tokens, n, S)
+            # (a model with ``step_counters``: as wide as ``next_tokens``)
+            width = S + len(self.step_counters)
+            tok = self._as_token_array(tokens, n, width)
             if rows is not None:    # (pad rows read row 0: never used)
                 tok = self._gather_tokens(
-                    tok, self._as_token_array(rows, n, S))
+                    tok, self._as_token_array(rows, n, width))
             if type(span) is SpanHandle:
                 span.attrs = {"seqs": n}    # live rows of the S it runs
                 if self._stateful:
@@ -870,6 +883,14 @@ class InferenceEngineV2:
         if greedy:
             return logits, nxt
         return logits
+
+    def counters_of(self, next_tokens) -> Dict[str, int]:
+        """What the model counted on the device in the step whose fetched
+        ``next_tokens`` vector this is (``step_counters``: name -> value);
+        {} for a model that states none."""
+        names = self.step_counters
+        return {name: int(v) for name, v in zip(
+            names, next_tokens[len(next_tokens) - len(names):])}
 
     def record_device_tokens(self, uids: Sequence[int],
                              tokens: Sequence[int]) -> None:
@@ -1115,12 +1136,19 @@ class InferenceEngineV2:
         names = ("state_slot",) * self._stateful \
             + ("tables_win",) * self._grouped
 
+        S = self._batch.max_seqs
+
         def run(params, cache, tables, pos, tok, *slots):
+            if self.step_counters:  # fed a step's tokens, counters behind
+                tok = tok[:S]
             batch = _device_decode_batch(tables, pos, tok, bs, B,
                                          **dict(zip(names, slots)))
-            logits, new_cache = self.model(params, cache, batch, decode=True)
+            logits, new_cache, *counts = self.model(params, cache, batch,
+                                                    decode=True)
             with jax.named_scope("sample_argmax"):
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                if counts:              # (``step_counters``)
+                    nxt = jnp.concatenate([nxt, counts[0]])
             return logits, nxt, new_cache, pos + 1
 
         runner = jax.jit(_named(run, "decode_step"), donate_argnums=(1, 3))
@@ -1396,7 +1424,7 @@ class InferenceEngineV2:
             def body(carry, _):
                 kv, tok, pos = carry
                 batch = _device_decode_batch(tables, pos, tok, bs, B)
-                logits, kv = self.model(params, kv, batch, decode=True)
+                logits, kv, *_ = self.model(params, kv, batch, decode=True)
                 nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 return (kv, nxt, pos + 1), nxt
 
@@ -1442,7 +1470,8 @@ class InferenceEngineV2:
         state = ((ints(S),) if self._stateful else ()) \
             + ((ints(S, B),) if self._grouped else ())
         if key == ("decode_step",):
-            args = (ints(S, B), ints(S), ints(S)) + state
+            args = (ints(S, B), ints(S),
+                    ints(S + len(self.step_counters))) + state
         elif key[0] == "verify_step":
             args = (ints(S * B + S + S * key[1]),)
         elif key[0] == "decode_loop":
@@ -1659,7 +1688,8 @@ class InferenceEngineV2:
         MII/engine_factory path that builds a FastGen engine from a HF
         snapshot).  ``model_implementations.HF_MODELS`` names the
         architectures served (llama, mistral, internlm, opt, falcon, mixtral,
-        olmoe, qwen3_next, deepseek_v3, glm_moe_dsa, lfm2_moe, afmoe, ouro,
+        olmoe, qwen3_next, deepseek_v3, glm_moe_dsa, longcat_flash, lfm2_moe,
+        afmoe, ouro,
         jamba) and which
         take
         a ``mesh`` with a non-trivial 'model' axis (the others refuse one):

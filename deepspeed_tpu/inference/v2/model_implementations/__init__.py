@@ -1,6 +1,6 @@
 """Inference v2 model implementations (reference:
 inference/v2/model_implementations/ — llama_v2, opt, mistral, mixtral,
-falcon families; qwen3_next, deepseek_v3 (and glm_moe_dsa through it), lfm2_moe, afmoe, ouro and jamba have
+falcon families; qwen3_next, deepseek_v3 (and glm_moe_dsa through it), longcat_flash, lfm2_moe, afmoe, ouro and jamba have
 no reference counterpart).  One file a family (config, parameter shapes,
 class) over the shared layers of ``inference/v2/modules/``."""
 
@@ -32,6 +32,10 @@ from deepspeed_tpu.inference.v2.model_implementations.ragged_jamba import (
 from deepspeed_tpu.inference.v2.model_implementations.ragged_lfm2 import (
     Lfm2Config,
     RaggedLfm2,
+)
+from deepspeed_tpu.inference.v2.model_implementations.ragged_longcat_flash import (
+    LongcatFlashConfig,
+    RaggedLongcatFlash,
 )
 from deepspeed_tpu.inference.v2.model_implementations.ragged_ouro import (
     OuroConfig,
@@ -66,6 +70,7 @@ HF_MODELS = {
     "qwen3_next": (RaggedQwen3Next, False),
     "deepseek_v3": (RaggedDeepseekV3, True),
     "glm_moe_dsa": (RaggedDeepseekV3, True),
+    "longcat_flash": (RaggedLongcatFlash, True),
     "lfm2_moe": (RaggedLfm2, False),
     "afmoe": (RaggedAfmoe, False),
     "ouro": (RaggedOuro, False),
@@ -73,6 +78,6 @@ HF_MODELS = {
 }
 
 __all__ = ["AfmoeConfig", "DeepseekV3Config", "HF_MODELS", "RaggedAfmoe",
-           "RaggedDeepseekV3", "JambaConfig", "RaggedJamba", "Lfm2Config", "OuroConfig", "Qwen3NextConfig", "RaggedLfm2", "RaggedLlama", "RaggedMistral", "RaggedMixtral",
+           "RaggedDeepseekV3", "JambaConfig", "RaggedJamba", "Lfm2Config", "LongcatFlashConfig", "RaggedLongcatFlash", "OuroConfig", "Qwen3NextConfig", "RaggedLfm2", "RaggedLlama", "RaggedMistral", "RaggedMixtral",
            "RaggedOPT", "RaggedFalcon", "RaggedOuro", "RaggedQwen3Next",
            "ragged_param_specs", "shard_ragged_params"]

@@ -44,6 +44,10 @@ What is new beside :class:`RaggedLlama` / :class:`RaggedMixtral`:
 
 * **A low-rank query** (``q_lora_rank``): ``q = RMSNorm(x W_qa) W_qb``;
   None: ``q_proj`` is one matrix.
+* **Scaled latents** (``q_scale``, ``kv_scale``; LongCat-Flash, whose
+  double block ``ragged_longcat_flash.py`` builds around ``_mla``): the
+  query times one constant, the normalised latent times another BEFORE it
+  is cached, so the row, ``W_kvb`` and the kernels are used as they are.
 * **A learned sparse-attention indexer** (``index_topk``; DeepSeek Sparse
   Attention, ``kernels/sparse_latent.py``): a token keeps a SECOND pool row,
   its indexer key (``kv_row`` then states two leaves, ``ckv`` and
@@ -125,6 +129,11 @@ class DeepseekV3Config:
     #: published modelling code builds them with its default, which
     #: ``config.json`` does not carry
     latent_norm_eps: float = 1e-6
+    #: what the query (after ``q_b_proj``) and the normalised latent are
+    #: multiplied by (LongCat-Flash's ``mla_scale_q_lora`` /
+    #: ``mla_scale_kv_lora``); 1: nothing is
+    q_scale: float = 1.0
+    kv_scale: float = 1.0
     #: the sparse-attention indexer (``glm_moe_dsa``): its heads, their
     #: width, and how many cached positions a query row reads; ``index_topk``
     #: None = no indexer, the dense latent read
@@ -392,12 +401,16 @@ class RaggedDeepseekV3:
                                att["q_a_layernorm"]["scale"],
                                cfg.latent_norm_eps)
                 q = qmm(cq, att["q_b_proj"]["kernel"], dt)
+            if cfg.q_scale != 1.0:
+                q = (q.astype(F32) * cfg.q_scale).astype(dt)
             q = q.reshape(t_rows, h, nope + rope)
             q_nope = q[..., :nope]
         with jax.named_scope("attn/kv_latent"):
             kva = qmm(xa, att["kv_a_proj_with_mqa"]["kernel"], dt)
             c = _rms_norm(kva[:, :rank], att["kv_a_layernorm"]["scale"],
                           cfg.latent_norm_eps)
+            if cfg.kv_scale != 1.0:     # the row holds the SCALED latent
+                c = (c.astype(F32) * cfg.kv_scale).astype(dt)
             # ONE rotated key a token, shared by every head
             k_pe = apply_rotary(kva[:, None, rank:], cos, sin)[:, 0]
             q_pe = apply_rotary(q[..., nope:], cos, sin)

@@ -7,9 +7,10 @@ by expert: ★moe_scatter), ``moe/experts`` (three calls of the grouped GEMM
 ``ops/grouped_gemm.py::_gmm_kernel`` around the SwiGLU product, each expert
 over its own rows, so FLOPs scale with ``k x T``, not ``E x T``: ★moe_gemm;
 the XLA composition ``gmm_reference`` off the TPU), ``moe/combine`` (unsort
-and weighted sum: ★moe_gather), ``moe/shared``.  ``grouped=False`` is the
-dense all-experts parity oracle of the tests and ``chip_smoke.py`` (``E / k``
-times the FLOPs), never served.  Dropless gating makes MoE ragged-safe: no
+and weighted sum: ★moe_gather), ``moe/shared``, and for a router with
+zero-compute experts ``moe/zero`` (``zero_expert_moe``).  ``grouped=False``
+is the dense all-experts parity oracle of the tests and ``chip_smoke.py``
+(``E / k`` times the FLOPs), never served.  Dropless gating makes MoE ragged-safe: no
 capacity buckets, so pad lanes cannot perturb real tokens' routing."""
 
 import jax
@@ -17,7 +18,7 @@ import jax.numpy as jnp
 
 
 def moe_router(x, wg, k: int, renormalize: bool = True, bias=None,
-               routed_scale: float = 1.0, norm_eps=None):
+               routed_scale: float = 1.0, norm_eps=None, scoring=None):
     """Router of the dropless MoE: ``x`` [T, H] (normed) x ``wg`` [H, E] in
     float32 -> (topi [T, k] int32, weights [T, k] float32).  Float32
     products as well as sums: on a TPU a float32 matmul at the default
@@ -28,13 +29,18 @@ def moe_router(x, wg, k: int, renormalize: bool = True, bias=None,
     sigmoid router of the DeepSeek-V3 family, its weights times
     ``routed_scale``; ``norm_eps`` (static) replaces the constant its
     renormalisation adds to the sum where a family's published code has
-    another (LFM2: 1e-6)."""
+    another (LFM2: 1e-6).  ``scoring="softmax"`` (static) with a ``bias``
+    is the LongCat-Flash router: softmax scores, the bias in the selection
+    only, weights times ``routed_scale`` and never renormalised."""
     from deepspeed_tpu.ops.grouped_gemm import (exact_topk_routing,
-                                                sigmoid_bias_topk_routing)
+                                                sigmoid_bias_topk_routing,
+                                                softmax_bias_topk_routing)
 
     with jax.named_scope("moe/router"):
         logits = jnp.matmul(x.astype(jnp.float32), wg.astype(jnp.float32),
                             precision=jax.lax.Precision.HIGHEST)  # [T, E]
+        if bias is not None and scoring == "softmax":
+            return softmax_bias_topk_routing(logits, bias, k, routed_scale)
         if bias is not None:
             kwargs = {} if norm_eps is None else {"norm_eps": norm_eps}
             return sigmoid_bias_topk_routing(logits, bias, k, renormalize,
@@ -102,6 +108,50 @@ def dropless_moe(x, moe_params, k: int, dtype, grouped=None,
         jnp.einsum("tm,emf->etf", xe, w_up)            # [E, T, F]
     out = jnp.einsum("etf,efm->etm", h, w_down)        # [E, T, H]
     return jnp.einsum("te,etm->tm", comb.astype(dtype), out)
+
+
+def zero_expert_moe(x, moe_params, k: int, dtype, zero_experts: int,
+                    expert_start: int = 0, routed_scale: float = 1.0,
+                    real=None):
+    """The routed branch of a router whose LAST ``zero_experts`` outputs
+    are zero-compute experts (LongCat-Flash, ``zero_expert_type:
+    identity``): softmax over every output, the top-k of ``score + bias``,
+    weights the unbiased scores times ``routed_scale``.  A chosen expert
+    adds ``w E(x)`` through the grouped GEMMs (only the held ones
+    ``[expert_start, expert_start + held)``, as ``dropless_moe``; the zero
+    outputs lie past every expert and leave before the sort like an expert
+    held elsewhere); a chosen zero output costs nothing and adds ``w x``:
+    device scope ``moe/zero``, the sum of a token's zero slots' weights and
+    one multiply-add a value.  x: [T, H]; returns ``(out [T, H], counts)``:
+    ``counts`` is None, or with ``real`` ([T] bool: the rows that are no
+    padding) int32[3], over the real rows: routed slots (``k`` a row),
+    slots that chose a zero output, slots whose expert is held here."""
+    from deepspeed_tpu.ops.grouped_gemm import grouped_moe_ffn
+
+    gate, experts = moe_params["gate"], moe_params["experts"]
+    wg = gate["wg"]["kernel"]                           # [H, E + Z]
+    n_experts = wg.shape[1] - zero_experts
+    topi, w = moe_router(x, wg, k, bias=gate["e_score_correction_bias"],
+                         routed_scale=routed_scale, scoring="softmax")
+    held = experts["w_gate"].shape[0]
+    out = grouped_moe_ffn(
+        x.astype(dtype), topi, w.astype(dtype),
+        experts["w_gate"].astype(dtype), experts["w_up"].astype(dtype),
+        experts["w_down"].astype(dtype), expert_start=int(expert_start))
+    with jax.named_scope("moe/zero"):
+        zero = topi >= n_experts
+        w_zero = jnp.sum(jnp.where(zero, w, 0.0), axis=-1, keepdims=True)
+        out = (out.astype(jnp.float32)
+               + w_zero * x.astype(jnp.float32)).astype(dtype)
+    counts = None
+    if real is not None:
+        with jax.named_scope("moe/router"):
+            here = (topi >= expert_start) & (topi < expert_start + held)
+            rows = real[:, None]
+            counts = jnp.stack([
+                k * jnp.sum(real), jnp.sum(zero & rows),
+                jnp.sum(here & rows)]).astype(jnp.int32)
+    return out, counts
 
 
 def _shared_expert(x, moe_params, dtype):

@@ -219,16 +219,27 @@ span                     site                        parent    attrs (counters)
                          run of decode ticks)
 ``engine/verify_step``   the step's dispatch         verify    the launch record
 ``fetch``                ``scheduler._fetch``: the   decode /  ``launch``: the
-                         blocking ``device_get`` of  verify /  launch it retires
-                         the step the tick returns:  prefill /
-                         when that step is ahead,    retire
-                         the wait for a program
-                         dispatched a tick earlier,
-                         what the host's own work
-                         since did not cover (under
-                         ``prefill``: between the
-                         build of the next ragged
-                         batch and its launch).
+                         blocking ``device_get`` of  verify /  launch it retires;
+                         the step the tick returns:  prefill / a model with
+                         when that step is ahead,    retire    ``step_counters``
+                         the wait for a program                (its router
+                         dispatched a tick earlier,            decides them on
+                         what the host's own work              the device, they
+                         since did not cover (under            cross behind the
+                         ``prefill``: between the              tokens):
+                         build of the next ragged              ``moe_slots``
+                         batch and its launch).                (real rows x
+                                                               top-k x routed
+                                                               layers of that
+                                                               launch),
+                                                               ``moe_zero_slots``
+                                                               (those that chose
+                                                               a zero-compute
+                                                               expert),
+                                                               ``moe_held_rows``
+                                                               (those whose
+                                                               expert is held
+                                                               here)
                          ``put(greedy=True)``: the
                          wait for a ragged batch's
                          ``int32[max_seqs]`` argmax

@@ -555,6 +555,21 @@ def sigmoid_bias_topk_routing(logits: jnp.ndarray, bias: jnp.ndarray,
     return topi.astype(jnp.int32), topw * scale
 
 
+def softmax_bias_topk_routing(logits: jnp.ndarray, bias: jnp.ndarray,
+                              k: int, scale: float = 1.0):
+    """Dropless router of the LongCat-Flash family: ``s = softmax(logits)``
+    over EVERY output of the router (its experts and its zero-compute
+    experts alike); the chosen outputs are the top-k of ``s + bias`` (the
+    learned selection bias; ties to the lowest index); their weights are
+    ``s`` at the chosen outputs, WITHOUT the bias and without
+    renormalisation, times ``scale`` (``routed_scaling_factor``).  Returns
+    (topi [T,k] int32, topw [T,k] fp32)."""
+    s = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    _, topi = jax.lax.top_k(s + bias.astype(jnp.float32), k)
+    topw = jnp.take_along_axis(s, topi, axis=-1)
+    return topi.astype(jnp.int32), topw * scale
+
+
 # --------------------------------------------------------------------- #
 # Dropless MoE FFN on top of gmm: sort-by-expert (★moe_scatter), three
 # grouped GEMMs (SwiGLU), unsort+combine (★moe_gather).

@@ -1059,13 +1059,22 @@ class ContinuousBatchScheduler:
         """The tick's one blocking transfer: the host waits here for the
         step program to finish and its tokens (or logits) to arrive.  The
         span closes with ``launch``, the engine's number of the launch
-        whose result this is."""
+        whose result this is, and, where the array is a step's
+        ``next_tokens`` (a vector; logits and a verify step's candidates
+        are matrices) of a model with ``step_counters``, with what the
+        model counted on the device in that step, which came behind the
+        tokens (``engine.counters_of``: e.g. ``moe_slots`` /
+        ``moe_zero_slots`` / ``moe_held_rows``)."""
         import jax
 
         with open_span(self.tracer, "fetch") as span:
+            out = np.asarray(jax.device_get(device_array))
             if type(span) is SpanHandle:
                 span.attrs = {"launch": launch}
-            return np.asarray(jax.device_get(device_array))
+                if out.ndim == 1 and getattr(self.engine, "step_counters",
+                                             ()):
+                    span.attrs.update(self.engine.counters_of(out))
+            return out
 
     # -- speculative decode -------------------------------------------- #
     def _speculative_decode_tick(self, uids, chunks, packed

@@ -196,3 +196,54 @@ def test_a_row_of_two_leaves_goes_through_every_block_operation():
     with pytest.raises(ValueError):         # a payload of one leaf
         kv.scatter_blocks([5, 6], {layer: {"ckv": leaves["ckv"]}
                                    for layer, leaves in payload.items()})
+
+
+# ------------------------------------------------------------------ #
+# a family whose published layer is TWO attention sub-layers
+# (``longcat_flash``): it states the latent row and twice its layers, and
+# is refused what a latent row is
+# ------------------------------------------------------------------ #
+def _double_block_model():
+    from deepspeed_tpu.inference.v2.model_implementations. \
+        ragged_longcat_flash import LongcatFlashConfig, RaggedLongcatFlash
+
+    return RaggedLongcatFlash(LongcatFlashConfig(
+        vocab_size=64, hidden_size=32, ffn_hidden_size=48,
+        expert_ffn_hidden_size=16, num_layers=3, num_attention_heads=2,
+        kv_lora_rank=16, q_lora_rank=24, qk_nope_head_dim=8,
+        qk_rope_head_dim=8, v_head_dim=8, n_routed_experts=4,
+        zero_expert_num=2, moe_topk=2, dtype=jnp.float32), 8)
+
+
+def test_a_double_block_family_keeps_a_cache_layer_an_attention_sub_layer():
+    model = _double_block_model()
+    assert model.num_layers == 6 and model.kv_row == {"ckv": 128}
+    assert (model.num_kv_heads, model.head_dim) == (1, 128)
+    eng = InferenceEngineV2(model, {}, _engine({}).config)
+    kv = eng.state_manager.kv_cache
+    assert sorted(kv.cache) == [f"layer_{i}" for i in range(6)]
+    assert kv.per_token_bytes == 6 * 128 * 4
+    assert set(eng.state_manager.unserved) == set(LATENT_ROW[1])
+    assert eng.step_counters == ("moe_slots", "moe_zero_slots",
+                                 "moe_held_rows")
+
+
+@pytest.mark.parametrize("feature", sorted(LATENT_ROW[1]))
+def test_a_double_block_family_is_refused_what_a_latent_row_is(feature):
+    model = _double_block_model()
+    config = _engine({}).config
+    if feature == "int8_kv":
+        config = RaggedInferenceEngineConfig.from_dict({
+            "state_manager": {"max_ragged_batch_size": 128,
+                              "max_ragged_sequence_count": 2,
+                              "max_context": 64},
+            "kv_cache": {"block_size": 8, "num_blocks": 17,
+                         "dtype": "int8"}})
+        call = lambda: InferenceEngineV2(model, {}, config)
+    else:
+        call = lambda: InferenceEngineV2(model, {}, config).verify_step(
+            [1], [[3, 4]])
+    with pytest.raises(CacheLayoutError,
+                       match=re.escape(LATENT_ROW[1][feature])) as err:
+        call()
+    assert "RaggedLongcatFlash" in str(err.value)
