@@ -21,7 +21,8 @@ the kernel enumerates a fixed worst-case list of work units — one per
 (work→group, work→m-tile, group start/end rows) is computed in XLA from
 ``group_sizes`` and scalar-prefetched into SMEM, where it DRIVES THE
 BLOCK-SPEC INDEX MAPS: each work unit DMAs exactly the lhs m-tile and the
-rhs slice of ITS group.  Rows of a shared boundary tile are masked by the
+rhs slice of ITS group (the forward copies that slice itself: THE WEIGHT
+RING, below).  Rows of a shared boundary tile are masked by the
 group's row range, so every output row is written by exactly one work
 unit.  The list is as long as the worst case; the units past the ones a
 call needs (``num_work``: most of the list when the matrices are a share
@@ -34,18 +35,37 @@ two backward kernels (dlhs accumulates over n-tiles; drhs is the "tgmm" —
 per-group lhsᵀ@dout accumulated over the group's work units), wired as a
 ``custom_vjp`` so dropless MoE TRAINING differentiates through the kernel.
 
+THE WEIGHT RING (forward only).  A call's time is its experts' weights
+streaming in once, if nothing stops the stream.  ``pallas_call``'s grid
+pipeline fetches the blocks of step ``i + 1`` while step ``i`` runs, ONE
+step ahead: a step whose successor is the same expert (the expert's rows
+cross a row tile) moves a row tile under its pass, the stream idles, and
+the next expert's block is then waited for whole: ``sum max(pass, next
+DMA)``, a third over the stream at 1.5 units an expert.  So the forward
+leaves ``rhs`` in HBM and keeps a ring of ``slots`` ``[K, tile_n]`` VMEM
+blocks it fills itself (``_gmm_kernel``): at the first unit of a block it
+starts the block ``slots - 1`` places ahead in the call's walk (the live
+groups in rising order, n-tile after n-tile: ``make_block_metadata``) into
+the slot the block before has just left, then waits for its own; every
+other unit only multiplies.  The next block is in flight under EVERY step
+of the current one, across n-tiles too: ``max(sum DMA, sum pass)``.  Where
+an expert is one unit (a decode tick) the ring is the double buffer the
+pipeline was.  lhs and out stay on the grid pipeline; the backward kernels
+are as they were.
+
 TILES.  Every work unit is a full ``[tile_m, K] x [K, tile_n]`` MXU pass,
 however few of its rows are the group's: a call multiplies ``m + (E - 1)
 * tile_m`` rows at worst, ``m / E + tile_m`` an expert, beside the
 expert's weights that stream in once.  A bf16 weight block has to
 multiply ``_MXU_BOUND_ROWS`` (~240 on a v5e: FLOP/s over bytes/s) rows
-before its pass takes as long as its DMA, so a DECODE tick's rows (a few
+before its passes take as long as its DMA, so a DECODE tick's rows (a few
 an expert) want the SMALLEST row tile: at 512 rows the padding alone makes
 the MXU, not the weight stream, set the call's time.  The forward and the
 backward budget VMEM for different things and get their tiles from
-different rules: the forward (``_pick_tiles``) holds double-buffered lhs,
-rhs and out blocks and nothing else (its one dot covers the whole K), so
-it affords the widest ``tile_n``; the two backward kernels also hold a
+different rules: the forward (``_pick_tiles``) holds double-buffered lhs
+and out blocks and the weight ring and nothing else (its one dot covers
+the whole K), so it affords the widest ``tile_n``; the two backward
+kernels also hold a
 float32 accumulator (``(tile_m, K)`` for dlhs, ``(K, tile_n)`` for drhs)
 and re-read weights per work unit (dlhs), so ``gmm``'s VJP picks theirs
 itself (``_pick_backward_tiles``) from the shapes it is handed.
@@ -106,41 +126,108 @@ def make_group_metadata(group_sizes: jnp.ndarray, m: int, tile_m: int):
     return group_ids, m_tile_ids, w_row_start, w_row_end, num_work
 
 
+def make_block_metadata(group_sizes: jnp.ndarray):
+    """What the forward's weight ring walks.  A BLOCK is one group's
+    ``[K, tile_n]`` slice of ``rhs``; within an n-tile the live units visit
+    the groups that hold rows in rising order, each once, and every n-tile
+    repeats that walk, so three small arrays name every block of a call:
+    ``block_of [E]`` the place of a group's block in the walk, ``next_live
+    [E]`` the group of the block after it (after the last one: the first
+    again, which is how the kernel knows that the walk wrapped into the
+    next n-tile: ``next_live[g] <= g``), ``num_blocks [1]``."""
+    e = group_sizes.shape[0]
+    held = (group_sizes > 0).astype(jnp.int32)
+    seen = jnp.cumsum(held)                  # live groups up to and with g
+    # the b-th live group: as many groups as have seen <= b stand before it
+    block_groups = jnp.sum(
+        seen[None, :] <= jnp.arange(e, dtype=jnp.int32)[:, None], axis=1)
+    num_blocks = seen[-1]
+    after = jnp.where(seen < num_blocks, seen, 0)    # walk place of the next
+    next_live = jnp.minimum(block_groups[after], e - 1).astype(jnp.int32)
+    return ((seen - held).astype(jnp.int32), next_live,
+            num_blocks.astype(jnp.int32)[None])
+
+
 # --------------------------------------------------------------------- #
 # Forward kernel: out[M, N]
 # --------------------------------------------------------------------- #
-def _gmm_kernel(group_ids, m_tile_ids, row_start, row_end, lhs_ref,
-                rhs_ref, out_ref, *, tile_m: int):
+def _gmm_kernel(group_ids, m_tile_ids, row_start, row_end, block_of,
+                next_live, num_blocks, lhs_ref, rhs_hbm, out_ref, ring, sems,
+                *, tile_m: int, tile_n: int, n_tiles: int):
+    j = pl.program_id(0)
     w = pl.program_id(1)
     mt = m_tile_ids[w]
+    slots = ring.shape[0]
+
+    def copy(g_, j_, slot_):
+        src = rhs_hbm.at[g_] if n_tiles == 1 else rhs_hbm.at[
+            g_, :, pl.ds(pl.multiple_of(j_ * tile_n, 128), tile_n)]
+        return pltpu.make_async_copy(src, ring.at[slot_], sems.at[slot_])
 
     # first work unit visiting this m-tile initialises the output block
     @pl.when(jnp.logical_or(w == 0, m_tile_ids[w - 1] != mt))
     def _():
         out_ref[:] = jnp.zeros_like(out_ref)
 
-    # a unit past ``num_work`` holds no rows: no MXU pass, no store (its
-    # blocks are the last live unit's, so nothing was moved for it either)
+    # a unit past ``num_work`` holds no rows: no MXU pass, no store, and it
+    # starts and waits for no block
     @pl.when(row_end[w] > row_start[w])
     def _():
+        g = group_ids[w]
+        # this unit's block among all the call walks, n-tile after n-tile,
+        # and the ring slot that holds it
+        place = j * num_blocks[0] + block_of[g]
+        slot = jax.lax.rem(place, slots)
+
+        def start_ahead(hops: int):
+            """Start the block ``hops`` places after this one, if the call
+            holds one there."""
+            g_, j_ = g, j
+            for _ in range(hops):
+                nxt = next_live[g_]
+                j_ = j_ + (nxt <= g_).astype(jnp.int32)  # wrapped: next j
+                g_ = nxt
+
+            @pl.when(j_ < n_tiles)
+            def _():
+                copy(g_, j_, jax.lax.rem(place + hops, slots)).start()
+
+        # THE WEIGHT RING (module docstring): at the FIRST unit of a block
+        # the block ``slots - 1`` places ahead is started into the slot the
+        # block before this one has just left (the grid is sequential, both
+        # axes "arbitrary": its last reader is done), then this block is
+        # waited for; every other unit only multiplies.
+        @pl.when(jnp.logical_or(
+            w == 0, group_ids[jnp.maximum(w - 1, 0)] != g))
+        def _():
+            @pl.when(jnp.logical_and(j == 0, w == 0))
+            def _():
+                for hops in range(slots - 1):    # the call's first blocks
+                    start_ahead(hops)
+            start_ahead(slots - 1)
+            copy(0, 0, slot).wait()    # a wait reads the shape alone
+
         rows = mt * tile_m + jax.lax.broadcasted_iota(
             jnp.int32, (tile_m, 1), 0)
         keep = (rows >= row_start[w]) & (rows < row_end[w])
         partial = jax.lax.dot_general(
-            lhs_ref[:], rhs_ref[0], (((1,), (0,)), ((), ())),
+            lhs_ref[:], ring[slot], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         out_ref[:] = jnp.where(keep, partial.astype(out_ref.dtype),
                                out_ref[:])
 
 
 @functools.partial(jax.jit, static_argnames=("tile_m", "tile_n",
-                                             "interpret"))
+                                             "interpret", "slots"))
 def _gmm_fwd_kernel_call(lhs, rhs, group_sizes, tile_m: int, tile_n: int,
-                         interpret: bool):
+                         interpret: bool, slots: Optional[int] = None):
     m, k = lhs.shape
     e, _, n = rhs.shape
     gids, mtids, rs, re_, _ = make_group_metadata(group_sizes, m, tile_m)
     w = gids.shape[0]
+    size = lhs.dtype.itemsize
+    if slots is None:
+        slots = _ring_slots(tile_m, k, tile_n, size)
     # n-major grid: within one n-tile the work units of a group are
     # consecutive, so each group's rhs slice is DMAed ONCE per n-tile
     # (total rhs traffic = E*K*N); the lhs m-tiles are re-read per
@@ -148,32 +235,34 @@ def _gmm_fwd_kernel_call(lhs, rhs, group_sizes, tile_m: int, tile_n: int,
     # order re-reads each group's FULL rhs per work unit — W*K*N bytes,
     # an order of magnitude worse at training token counts.
     grid = (n // tile_n, w)
-    kernel = functools.partial(_gmm_kernel, tile_m=tile_m)
+    kernel = functools.partial(_gmm_kernel, tile_m=tile_m, tile_n=tile_n,
+                               n_tiles=n // tile_n)
     # tiles over the default budget (``_pick_tiles`` hands them out for one
     # kind of N) bring the scoped limit they need
-    need = _forward_vmem(tile_m, k, tile_n, lhs.dtype.itemsize)
-    limit = {"compiler_params": pltpu.CompilerParams(
-        vmem_limit_bytes=need + _VMEM_HEADROOM)} if need > _VMEM_BUDGET else {}
+    need = _forward_vmem(tile_m, k, tile_n, size, slots)
+    limit = {"vmem_limit_bytes": need + _VMEM_HEADROOM} \
+        if need > _VMEM_BUDGET else {}
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=7,
             grid=grid,
             in_specs=[
-                pl.BlockSpec((tile_m, k),
-                             lambda j, w, g, mt, rs, re: (mt[w], 0)),
-                pl.BlockSpec((1, k, tile_n),
-                             lambda j, w, g, mt, rs, re: (g[w], 0, j)),
+                pl.BlockSpec((tile_m, k), lambda j, w, g, mt, *_: (mt[w], 0)),
+                # the weights stay in HBM: the kernel's ring reads them
+                pl.BlockSpec(memory_space=pl.ANY),
             ],
             out_specs=pl.BlockSpec(
-                (tile_m, tile_n),
-                lambda j, w, g, mt, rs, re: (mt[w], j)),
+                (tile_m, tile_n), lambda j, w, g, mt, *_: (mt[w], j)),
+            scratch_shapes=[pltpu.VMEM((slots, k, tile_n), rhs.dtype),
+                            pltpu.SemaphoreType.DMA((slots,))],
         ),
         out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
         interpret=interpret,
-        **limit,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"), **limit),
         **kernel_names(kernel),
-    )(gids, mtids, rs, re_, lhs, rhs)
+    )(gids, mtids, rs, re_, *make_block_metadata(group_sizes), lhs, rhs)
     # m-tiles past the last group are never visited (uninitialised) —
     # the contract is zeros there
     total = jnp.sum(group_sizes)
@@ -431,19 +520,38 @@ _VMEM_HEADROOM = 4 * 1024 * 1024
 #: hold under a limit of its own (a v5e core has 128 MiB of VMEM)
 _VMEM_RAISED_BUDGET = 2 * _VMEM_BUDGET
 
-#: rows a bf16 weight block must multiply before its MXU pass takes as long
-#: as its DMA: 197 TFLOP/s over 819 GB/s (v5e; 2 FLOPs a row for each
-#: 2-byte weight).  Under it the weight stream hides the pass; a column
-#: tile under it leaves the row tiles' re-reads exposed the same way.
+#: rows a bf16 weight block must multiply before its MXU passes take as
+#: long as its DMA: 197 TFLOP/s over 819 GB/s (v5e; 2 FLOPs a row for each
+#: 2-byte weight).  Under it the weight stream is the call's time IF the
+#: next block streams in under all of this block's passes: the weight ring
+#: sees to that (PR 53).  On the grid pipeline's one-step prefetch a pass
+#: hid only under the DMA of the step right after it, and every pass of a
+#: step that reused its block stood outside the stream whatever the rows.
+#: A column tile under it leaves the row tiles' re-reads exposed.
 _MXU_BOUND_ROWS = 240
 
 
 def _forward_vmem(tile_m: int, k_dim: int, tile_n: int,
-                  itemsize: int = 2) -> int:
-    """Bytes of ``_gmm_kernel``'s working set: double-buffered lhs, rhs and
-    out blocks.  No accumulator: one dot covers the whole K."""
-    return 2 * itemsize * (tile_m * k_dim + k_dim * tile_n
-                           + tile_m * tile_n)
+                  itemsize: int = 2, slots: int = 2) -> int:
+    """Bytes of ``_gmm_kernel``'s working set: double-buffered lhs and out
+    blocks and the weight ring's ``slots`` blocks (two are what the grid
+    pipeline's double buffer held).  No accumulator: one dot covers the
+    whole K."""
+    return itemsize * (2 * tile_m * (k_dim + tile_n)
+                       + slots * k_dim * tile_n)
+
+
+def _ring_slots(tile_m: int, k_dim: int, tile_n: int,
+                itemsize: int = 2) -> int:
+    """Slots of the forward's weight ring: a third (the ring then runs two
+    blocks ahead, and one expert of several units no longer stalls the
+    stream behind it: -1.3 to -3.4% on the LFM2 ``T1152`` calls) where the
+    working set with it still fits ``_VMEM_BUDGET``.  A call that is over
+    the default budget at two (Moonlight's whole-N gate / up) stays at two:
+    a third slot of 5.5 MiB under a limit raised further measured 3.7%
+    SLOWER (my chip runs, PR 53, calls 1-2; PERF.md section 6)."""
+    return 3 if _forward_vmem(tile_m, k_dim, tile_n, itemsize,
+                              3) <= _VMEM_BUDGET else 2
 
 
 def _pick_tiles(m_dim: int, k_dim: int, n_dim: int,
@@ -454,8 +562,9 @@ def _pick_tiles(m_dim: int, k_dim: int, n_dim: int,
     ``tile_m`` from the rows an expert holds, ``m / groups`` (an upper
     bound where the matrices are a share of the router's experts): a group
     costs ``rows + tile_m`` rows of MXU passes, its boundary tile being
-    shared, and the passes hide under the expert's weight stream while
-    that stays under ``_MXU_BOUND_ROWS``.  Only 128, the smallest tile,
+    shared, and the passes hide under the NEXT expert's weight stream (the
+    kernel's ring has it in flight under all of them) while that stays
+    under ``_MXU_BOUND_ROWS``.  Only 128, the smallest tile,
     can stay under it, so 128 it is wherever groups are a few rows to a
     few hundred (a decode tick's 8 rows an expert under a 512-row tile
     multiply 520 rows for 8; over the bound every padded row is exposed
@@ -466,8 +575,12 @@ def _pick_tiles(m_dim: int, k_dim: int, n_dim: int,
     where padding is a few percent and the step count matters.
 
     ``tile_n`` is the widest lane-aligned divisor of ``n`` whose forward
-    working set (``_forward_vmem``: no accumulator) fits ``_VMEM_BUDGET``:
-    it divides the number of grid steps and of re-reads of each row tile.
+    working set (``_forward_vmem`` at a ring of two slots, the footprint
+    of the grid pipeline's double buffer: no accumulator) fits
+    ``_VMEM_BUDGET``: it divides the number of grid steps and of re-reads
+    of each row tile.  The ring takes a third slot afterwards, where
+    ``_VMEM_BUDGET`` holds it (``_ring_slots``): the tiles never narrow
+    for it.
     A row tile taller than 128 that leaves a column tile under
     ``_MXU_BOUND_ROWS`` gives way to the next smaller one; where 128 rows
     leave one too (Moonlight's N = 1408 = 11 x 128 has no lane-aligned
@@ -673,7 +786,14 @@ def _dslint_gmm_inputs():
 
 @pallas_kernel_case("gmm_fwd",
                     note="grouped expert GEMM forward, selftest sizes "
-                         "with an empty group")
+                         "with an empty group, two n-tiles; seven "
+                         "scalar-prefetch arrays (the work units' four, "
+                         "the weight ring's walk: block_of, next_live, "
+                         "num_blocks); the expert weights stay in HBM "
+                         "(memory_space=ANY) and a ring of three "
+                         "[K, tile_n] blocks with a DMA semaphore a slot "
+                         "is the kernel's scratch, counted by the VMEM "
+                         "rule beside the double-buffered lhs / out")
 def _dslint_gmm_fwd():
     lhs, rhs, sizes = _dslint_gmm_inputs()
     gmm(lhs, rhs, sizes, 128, 128, True)

@@ -15,14 +15,17 @@ import jax
 import jax.numpy as jnp
 
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from deepspeed_tpu.ops import grouped_gemm
 from deepspeed_tpu.ops.grouped_gemm import (
     gmm,
     gmm_reference,
     grouped_moe_ffn,
+    make_block_metadata,
     make_group_metadata,
 )
+from gmm_grid_pipeline import grid_pipeline_gmm
 
 TM = TN = 128
 
@@ -149,7 +152,7 @@ def _parent_gmm_kernel(group_ids, m_tile_ids, row_start, row_end, lhs_ref,
                        rhs_ref, out_ref, *, tile_m: int):
     """``_gmm_kernel`` as it was before PR 32, kept as the oracle: every
     unit multiplies, a unit with an empty row range stores back what was
-    there."""
+    there (and, as until PR 53, the weights come on the grid pipeline)."""
     w = pl.program_id(1)
     mt = m_tile_ids[w]
     rows = mt * tile_m + jax.lax.broadcasted_iota(
@@ -169,8 +172,7 @@ def _parent_gmm_kernel(group_ids, m_tile_ids, row_start, row_end, lhs_ref,
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
 @pytest.mark.parametrize("name", SHARE_CASES)
-def test_gmm_on_a_share_is_the_parents_kernel_bit_for_bit(
-        name, dtype, monkeypatch):
+def test_gmm_on_a_share_is_the_parents_kernel_bit_for_bit(name, dtype):
     m, sizes = _share_sizes(name)
     lhs, rhs, gs = _case(m, 64, 256, len(sizes), sizes, seed=32,
                          dtype=dtype)
@@ -179,12 +181,95 @@ def test_gmm_on_a_share_is_the_parents_kernel_bit_for_bit(
     tol = 1e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(got, want, atol=tol, rtol=tol)
     assert np.all(got[int(sizes.sum()):] == 0)
-    # the unjitted call, so that the patched body is the one traced
-    monkeypatch.setattr(grouped_gemm, "_gmm_kernel", _parent_gmm_kernel)
-    parent = grouped_gemm._gmm_fwd_kernel_call.__wrapped__(
-        lhs, rhs, gs, TM, TN, True)
+    parent = grid_pipeline_gmm(lhs, rhs, gs, TM, TN, True,
+                               kernel=_parent_gmm_kernel)
     np.testing.assert_array_equal(
         got, np.asarray(parent.astype(jnp.float32)))
+
+
+# ------------------------------------------------------------------ #
+# The weight ring (PR 53): the expert weights stay in HBM and the kernel
+# fills ``slots`` VMEM slots itself, the block ``slots - 1`` places ahead
+# started at a block's first unit, where the grid pipeline fetched one
+# step ahead.  Same dot, same mask, same stores: the parent's bits.
+# ------------------------------------------------------------------ #
+#: name -> (m, n, group sizes) at 128 x 128 tiles, K = 64
+RING_CASES = {
+    # one expert over four row tiles, its neighbours inside its edge tiles
+    "three_tiles_an_expert": (640, 256, [30, 420, 100, 90]),
+    # empty experts between the live ones, at both ends too
+    "empties_between": (512, 256, [0, 130, 0, 0, 200, 0, 182, 0]),
+    # a share: 177 of 512 rows, the list's last five units hold nothing
+    "dead_units_at_the_end": (512, 256, [100, 0, 70, 7, 0, 0]),
+    # three n-tiles: the ring runs ahead across two n-tile boundaries
+    "three_n_tiles": (384, 384, [140, 0, 128, 116]),
+    # one block an n-tile: every block ahead is the next n-tile's
+    "one_live_expert": (384, 384, [0, 0, 300, 0]),
+    "one_live_expert_one_n_tile": (256, 128, [0, 5, 0]),
+    # as many blocks as slots, and one fewer
+    "two_blocks": (256, 256, [128, 128]),
+    "nothing_routed_here": (256, 256, [0, 0, 0]),
+}
+
+
+@pytest.mark.parametrize("slots", [2, 3])
+@pytest.mark.parametrize("name", RING_CASES)
+def test_ring_is_the_grid_pipelines_kernel_bit_for_bit(name, slots):
+    m, n, sizes = RING_CASES[name]
+    lhs, rhs, gs = _case(m, 64, n, len(sizes), sizes, seed=53,
+                         dtype=jnp.bfloat16)
+    got = grouped_gemm._gmm_fwd_kernel_call(lhs, rhs, gs, TM, TN, True,
+                                            slots).astype(jnp.float32)
+    want = gmm_reference(lhs, rhs, gs).astype(jnp.float32)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-2, rtol=2e-2)
+    assert np.all(np.asarray(got)[sum(sizes):] == 0)
+    parent = grid_pipeline_gmm(lhs, rhs, gs, TM, TN, True)
+    np.testing.assert_array_equal(
+        np.asarray(got), np.asarray(parent.astype(jnp.float32)))
+
+
+@pytest.mark.parametrize("slots", [2, 3])
+@pytest.mark.parametrize("name", ["three_tiles_an_expert",
+                                  "dead_units_at_the_end", "three_n_tiles",
+                                  "one_live_expert"])
+def test_ring_waits_for_every_block_it_reads(name, slots):
+    """The TPU interpreter with copies that land when they are WAITED for,
+    in buffers that start as NaN, with its race detector on: a block read
+    before its wait, a slot started while its last reader could still run
+    or a wait on a slot nothing was started into would show as NaN, as a
+    race or as a hang.  (``interpret=True`` copies at the start.)"""
+    from jax._src.pallas.mosaic.interpret import interpret_pallas_call
+
+    m, n, sizes = RING_CASES[name]
+    lhs, rhs, gs = _case(m, 64, n, len(sizes), sizes, seed=54)
+    params = pltpu.InterpretParams(dma_execution_mode="on_wait",
+                                   uninitialized_memory="nan",
+                                   detect_races=True)
+    got = grouped_gemm._gmm_fwd_kernel_call(lhs, rhs, gs, TM, TN, params,
+                                            slots)
+    np.testing.assert_allclose(np.asarray(got),
+                               np.asarray(gmm_reference(lhs, rhs, gs)),
+                               atol=1e-5, rtol=1e-5)
+    assert not interpret_pallas_call.races.races_found
+
+
+@pytest.mark.parametrize("name", RING_CASES)
+def test_block_metadata_names_the_walk(name):
+    """``block_of`` / ``next_live`` / ``num_blocks`` against the walk
+    itself: the live groups in rising order, the one after the last being
+    the first again (the kernel reads ``next_live[g] <= g`` as "the next
+    n-tile")."""
+    _, _, sizes = RING_CASES[name]
+    block_of, next_live, num_blocks = map(
+        np.asarray, make_block_metadata(jnp.asarray(sizes, jnp.int32)))
+    walk = [g for g, s in enumerate(sizes) if s]
+    assert num_blocks.shape == (1,) and int(num_blocks[0]) == len(walk)
+    for place, g in enumerate(walk):
+        assert block_of[g] == place
+        assert next_live[g] == walk[(place + 1) % len(walk)]
+        assert (next_live[g] <= g) == (place == len(walk) - 1)
+    assert np.all((next_live >= 0) & (next_live < len(sizes)))
 
 
 @pytest.mark.parametrize("name", ["quarter", "one_live"])
@@ -374,6 +459,15 @@ EXPECTED_TILES = {
     "moonlight_decode": ((128, 1408), (128, 1024)),
     "moonlight_T1088": ((128, 1408), (128, 1024)),
 }
+#: the ring's slots at those picks (gate / up, down): OLMoE's 4 MiB blocks
+#: leave no room for a third under 12 MiB, and Moonlight's whole-N call,
+#: over the default budget at two, stays at two
+EXPECTED_SLOTS = {
+    "lfm2_decode": (3, 3), "lfm2_T1152": (3, 3),
+    "olmoe_decode": (2, 2), "olmoe_T1056": (2, 2),
+    "qwen3next_decode": (3, 3), "qwen3next_T1056": (3, 3),
+    "moonlight_decode": (2, 3), "moonlight_T1088": (2, 3),
+}
 
 
 @pytest.mark.parametrize("call", ["gate_up", "down"])
@@ -384,9 +478,17 @@ def test_tile_rules_arithmetic(name, call):
     k, n = (h, f) if call == "gate_up" else (f, h)
     tm, tn = gg._pick_tiles(m, k, n, e)
     assert m % tm == 0 and n % tn == 0 and tn % 128 == 0
-    # the forward holds three double-buffered bf16 blocks, no accumulator
+    # the forward holds double-buffered bf16 lhs and out blocks and the
+    # weight ring; at two slots (what the tiles are picked under) that is
+    # the grid pipeline's three double-buffered blocks; no accumulator
     need = 2 * 2 * (tm * k + k * tn + tm * tn)
     assert gg._forward_vmem(tm, k, tn) == need
+    assert gg._forward_vmem(tm, k, tn, 2, 3) == need + 2 * k * tn
+    # a third slot only inside the default budget
+    slots = gg._ring_slots(tm, k, tn)
+    assert slots == (3 if need + 2 * k * tn <= gg._VMEM_BUDGET else 2)
+    if name in EXPECTED_SLOTS:
+        assert slots == EXPECTED_SLOTS[name][call == "down"]
     if need > gg._VMEM_BUDGET:
         # only the whole of an N that has no column tile between 128 and
         # itself, at 128 rows, and the call then brings its own limit
@@ -475,6 +577,8 @@ def test_whole_n_under_its_own_limit_runs():
     assert gg._pick_tiles(m, k, n, e) == (128, n)
     assert gg._VMEM_BUDGET < gg._forward_vmem(128, k, n) \
         <= gg._VMEM_RAISED_BUDGET
+    # over the default budget at two slots: the ring takes no third
+    assert gg._ring_slots(128, k, n) == 2
     lhs, rhs, gs = _case(m, k, n, e, [150, 90], seed=37, dtype=jnp.bfloat16)
     got = gmm(lhs, rhs, gs, 128, n, True).astype(jnp.float32)
     want = gmm_reference(lhs, rhs, gs).astype(jnp.float32)

@@ -356,6 +356,39 @@ def verify_read_case(tol: float, layers: int = 16, repeats: int = 10,
             "us": [int(held), round(t_verify, 1), round(least, 1)]}
 
 
+def gmm_call_model(sizes, m: int, k: int, n: int, tile_m: int, tile_n: int,
+                   step_us: float = 0.35, itemsize: int = 2):
+    """What one forward ``gmm`` call should cost on a v5e, from the group
+    sizes and the tiles alone: ``(reuse_units, [grid_pipeline_us,
+    ring_us])``.  A live unit is an MXU pass of ``tile_m x K x tile_n`` at
+    197 TFLOP/s; a unit that begins a block needs the block's ``K x
+    tile_n`` weights at 819 GB/s.  ``reuse_units``: the live units of one
+    walk whose block is the unit's before them.  With the weights on the
+    grid pipeline (one STEP ahead: the form before PR 53) a block's DMA
+    has only the pass of the step before it to hide under, ``sum max(pass,
+    next DMA)``; with the kernel's weight ring the next block is in flight
+    under every step of this one, ``max(sum DMA, sum pass)``.  Both plus
+    ``step_us`` a grid step, dead units too (PERF.md section 6, PR 53)."""
+    import numpy as np
+
+    sizes = np.asarray(sizes)
+    ends = np.cumsum(sizes)
+    tiles = [(e_ - 1) // tile_m - (e_ - s_) // tile_m + 1
+             for s_, e_ in zip(sizes, ends) if s_]
+    live, blocks = int(sum(tiles)), len(tiles)
+    walks = n // tile_n
+    dma = k * tile_n * itemsize / 819e9 * 1e6
+    mxu = 2 * tile_m * k * tile_n / 197e12 * 1e6
+    steps = walks * (m // tile_m + len(sizes) - 1) * step_us
+    # the step before a block hides its DMA under one pass (the call's
+    # first block under nothing: its pass is the last one's, unpaired)
+    pipeline = walks * (blocks * max(dma, mxu) + (live - blocks) * mxu) \
+        + (mxu if blocks else 0.0)
+    ring = walks * max(blocks * dma, live * mxu) + (dma if blocks else 0.0)
+    return live - blocks, [round(pipeline + steps, 1),
+                           round(ring + steps, 1)]
+
+
 def gmm_share_case(tol: float, layers: int = 8, repeats: int = 10) -> dict:
     """The grouped GEMM (``gmm``, compiled) where the matrices are a share
     of the router's experts: for each entry of ``GMM_SHARE_CELLS``, seeded
@@ -364,11 +397,13 @@ def gmm_share_case(tol: float, layers: int = 8, repeats: int = 10) -> dict:
     gate / up and the down call at the tiles ``_pick_tiles`` gives them,
     against ``gmm_reference`` on the row tiles that hold rows (the others
     must be zero).  ``calls`` = for each cell and call ``{shape [m, k, n],
-    tiles, live_units of units, us a call, least_us}``: ``layers`` calls a
-    program with a layer's weights each (an argument each), ``repeats``
-    programs back to back, host clock around them; least = the touched
-    experts' weight bytes at 819 GB/s or the routed rows' FLOPs at 197
-    TFLOP/s, the larger."""
+    tiles, live_units of units, reuse_units, us a call, least_us,
+    model_us}``: ``layers`` calls a program with a layer's weights each (an
+    argument each), ``repeats`` programs back to back, host clock around
+    them; least = the touched experts' weight bytes at 819 GB/s or the
+    routed rows' FLOPs at 197 TFLOP/s, the larger; ``reuse_units`` and
+    ``model_us`` = :func:`gmm_call_model` (units are of ONE walk over the
+    list; a call makes ``n / tile_n`` walks)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -411,10 +446,13 @@ def gmm_share_case(tol: float, layers: int = 8, repeats: int = 10) -> dict:
             nw = make_group_metadata(gs, m, tm)[4]
             least = max(int((sizes > 0).sum()) * k * n * 2 / 819e9,
                         2 * total * k * n / 197e12)
+            reuse, model = gmm_call_model(sizes, m, k, n, tm, tn)
             calls[f"{cell}.{call}"] = {
                 "shape": [m, k, n], "tiles": [tm, tn],
                 "live_units": int(nw), "units": m // tm + held - 1,
-                "us": round(us, 1), "least_us": round(least * 1e6, 1)}
+                "reuse_units": reuse,
+                "us": round(us, 1), "least_us": round(least * 1e6, 1),
+                "model_us": model}
     return {"max_err": round(err, 6), "ok": bool(err < tol),
             "calls": calls}
 
