@@ -18,10 +18,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.models.llama import cross_entropy_loss
-from deepspeed_tpu.ops.attention import (dot_product_attention,
-                                         folded_attention,
-                                         paired_attention,
-                                         resolve_attention_layout)
+from deepspeed_tpu.ops.attention import dot_product_attention
 
 
 @dataclasses.dataclass
@@ -39,13 +36,6 @@ class GPT2Config:
     intermediate_size: Any = None
     dtype: Any = jnp.bfloat16
     remat: bool = False
-    # "paired" | "folded" | "bshd" | None (None -> the process default set
-    # from the DeepSpeed config's top-level `attention_layout` key).
-    # "folded" keeps attention in the c_attn GEMM's [B,S,H*D] layout — no
-    # BSHD<->BHSD transposes around the flash kernel; "paired" adds
-    # in-kernel head pairing so d=64 heads run full-lane MXU dots
-    # (falls back to folded/bshd where pairing does not apply).
-    attention_layout: Any = None
 
     @property
     def head_dim(self) -> int:
@@ -97,18 +87,10 @@ class GPT2Block(nn.Module):
             y = ln("ln_1")(x)
             qkv = dense(3 * cfg.hidden_size, "c_attn")(y)
             q, k, v = jnp.split(qkv, 3, axis=-1)
-            layout = resolve_attention_layout(cfg.attention_layout)
-            if layout in ("folded", "paired"):
-                # consume the c_attn GEMM output directly ([B,S,H*D] end to
-                # end); ineligible geometries fall back inside
-                attn_fn = paired_attention if layout == "paired" \
-                    else folded_attention
-                out = attn_fn(q, k, v, num_heads=h, causal=True)
-            else:
-                reshape = lambda t: t.reshape(*t.shape[:2], h, d)
-                out = dot_product_attention(reshape(q), reshape(k),
-                                            reshape(v), causal=True)
-                out = out.reshape(*x.shape[:2], cfg.hidden_size)
+            reshape = lambda t: t.reshape(*t.shape[:2], h, d)
+            out = dot_product_attention(reshape(q), reshape(k), reshape(v),
+                                        causal=True)
+            out = out.reshape(*x.shape[:2], cfg.hidden_size)
             out = dense(cfg.hidden_size, "attn_out")(out)
             if cfg.resid_pdrop > 0:
                 out = nn.Dropout(cfg.resid_pdrop)(

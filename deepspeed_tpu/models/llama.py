@@ -24,10 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from deepspeed_tpu.ops.attention import (dot_product_attention,
-                                         folded_attention,
-                                         paired_attention,
-                                         resolve_attention_layout)
+from deepspeed_tpu.ops.attention import dot_product_attention
 
 
 @dataclasses.dataclass
@@ -55,15 +52,6 @@ class LlamaConfig:
     # Mistral-style sliding-window attention: each token attends to at
     # most the previous `sliding_window` positions (None = full causal).
     sliding_window: Any = None
-    # "paired" | "folded" | "bshd" | None (None -> the process default set
-    # from the DeepSpeed config's top-level `attention_layout` key).
-    # "folded" keeps the training attention path in the projection GEMMs'
-    # [B,S,H*D] lane layout — no BSHD<->BHSD transposes around the flash
-    # kernel (the 13.8 ms layout tax of the 86 ms honest-geometry step,
-    # PERFLOG r5); "paired" adds in-kernel head pairing so d<128 heads
-    # run full-lane MXU dots (ineligible geometries fall back to
-    # folded/bshd per call).
-    attention_layout: Any = None
     # OLMo-2 / OLMoE: RMSNorm over the WHOLE q and k projections (all heads
     # at once), before the head split and the rotary embedding.
     qk_norm: bool = False
@@ -181,27 +169,6 @@ class LlamaAttention(nn.Module):
         q = apply_rotary(q, cos, sin)
         k = apply_rotary(k, cos, sin)
         attn = attention_fn or dot_product_attention
-
-        # SWA window only bites once the query range exceeds it
-        window = (cfg.sliding_window
-                  if cfg.sliding_window is not None and
-                  x.shape[1] > cfg.sliding_window else None)
-
-        layout = resolve_attention_layout(cfg.attention_layout)
-        if (cache is None and attention_fn is None and
-                layout in ("folded", "paired")):
-            # layout-native training path: [B,S,H,D] here is a free
-            # reshape of the projection output, so folding back costs
-            # nothing — the kernel consumes [B,S,H*D] directly and no
-            # transpose appears in forward or backward
-            layout_fn = paired_attention if layout == "paired" \
-                else folded_attention
-            out = layout_fn(
-                q.reshape(*x.shape[:2], h * d),
-                k.reshape(*x.shape[:2], hkv * d),
-                v.reshape(*x.shape[:2], hkv * d),
-                num_heads=h, num_kv_heads=hkv, causal=True, window=window)
-            return dense(cfg.hidden_size, "o_proj")(out), None
 
         def prefill_attn(q_, k_, v_):
             # Mistral SWA: the window is a first-class kernel argument

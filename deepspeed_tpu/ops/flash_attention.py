@@ -24,15 +24,17 @@ Layout: [batch, seq, heads, head_dim] at the boundary (matching
 ``interpret=True`` (automatic off-TPU) runs the same kernels through the
 Pallas interpreter so CPU tests exercise identical code.
 
-``flash_attention_folded`` is the layout-native variant: q/k/v stay in the
-head-folded [B, S, H*D] lane layout the QKV projection GEMM emits, so the
-BSHD<->BHSD transposes (13.8 ms of the 86 ms honest-geometry step,
-PERFLOG round 5) disappear. Per-head access is expressed as static lane
--block slices in the BlockSpec index maps — the grid stays per-(head
-group), preserving Mosaic's cross-grid-step pipelining (NOT the rejected
-in-kernel ``fori`` designs, PERFLOG items 1-4). For head dims below the
-128-lane tile (d=64) one grid step covers a lane-aligned *group* of
-heads (a pair for MHA d=64) and a short static unroll walks the group.
+``flash_attention_folded`` is the family for heads narrower than the
+128-lane tile (``ops.attention`` picks it from the head size): q/k/v stay
+in the head-folded [B, S, H*D] lane layout the QKV projection GEMM emits,
+so the BSHD<->BHSD transposes disappear (8.6 ms of the GPT-2-Large cell's
+153 ms step on a v5e, for 8.0 ms more in kernels that still compute whole
+tiles: +1.03% tokens/s in every round, PERF.md section 6, PR 54). Per-head
+access is expressed as static lane-block slices in the BlockSpec index
+maps — the grid stays per-(head group), preserving Mosaic's
+cross-grid-step pipelining (NOT the rejected in-kernel ``fori`` designs,
+PERFLOG items 1-4): one grid step covers a lane-aligned *group* of heads
+(a pair for MHA d=64) and a short static unroll walks the group.
 """
 
 from __future__ import annotations
@@ -59,8 +61,8 @@ NEG_INF = -1e30
 # 789 / 634 / 591, 1,092 / 985 / 916 and 1,699 / 1,398 / 1,291 at d64 once
 # a crossed tile does its live part only (a grid step's fixed ~0.35 us, and
 # nothing of a larger tile is dead work any more).  A call that is not
-# causal, and the folded and paired families, keep the 512 rows they were
-# measured with (the [512, 1024] fp32 score tile, 2 MB, is the largest
+# causal, and the folded family, keep the 512 rows they were measured
+# with (the [512, 1024] fp32 score tile, 2 MB, is the largest
 # block any body multiplies: ``_SCORE_ELEMS``).
 DEFAULT_BLOCK_Q = 512
 CAUSAL_BLOCK_Q = 1024
@@ -888,10 +890,16 @@ def flash_attention(q, k, v, *, causal: bool = True,
 # ``hb`` heads (hb*d lanes) per grid step keeps every DMA window 128-lane
 # aligned. The grid is per head-GROUP (hb heads), so Mosaic still
 # software-pipelines DMA/MXU/VPU across grid steps; inside a step a short
-# STATIC python unroll (hb <= 8, typically 1-2) walks the group with
+# STATIC python unroll (hb <= 4, typically 2) walks the group with
 # static lane slices. lse/delta stay head-major [B, H, S, 8] (tiny).
 
-_FOLDED_MAX_HEADS_PER_BLOCK = 8  # VMEM guard: hb fp32 [bq, bk] score tiles
+# VMEM guard, from the TPU compiler itself (every kernel of the family
+# compiled for a described v5e at the default 512 x 1024 tiles, 1,024 and
+# 4,096 tokens: tools/chip_calls/pr54_results/aot_route.txt): groups of 2
+# and 4 heads on 128 or 256 lanes fit; 6 or 8 heads a group (hb fp32
+# [bq, bk] score tiles) and 4 heads of 96 lanes (384) run out of VMEM.
+_FOLDED_MAX_HEADS_PER_BLOCK = 4
+_FOLDED_MAX_LANES_PER_BLOCK = 256
 
 
 def folded_heads_per_block(num_heads: int, num_kv_heads: int,
@@ -899,46 +907,23 @@ def folded_heads_per_block(num_heads: int, num_kv_heads: int,
     """Query heads per grid step for the folded layout, or None when the
     geometry has no lane-aligned grouping.
 
-    d % 128 == 0: singleton blocks — every per-head lane window is
-    already 128-aligned. Otherwise a group of ``m = 128/gcd(d,128)``
-    heads spans whole lane tiles; the group is widened to ``m * g`` so
-    the KV heads it touches also form whole tiles (g = GQA group size).
+    The family serves heads narrower than a 128-lane tile (at 128 lanes
+    the [B, H, S, D] kernels measured 3.3% more tokens/s: PERF.md
+    section 6, PR 54): a group of ``m = 128/gcd(d,128)`` heads spans whole lane
+    tiles; the group is widened to ``m * g`` so the KV heads it touches
+    also form whole tiles (g = GQA group size).
     """
     d, h, hkv = head_dim, num_heads, num_kv_heads
-    if d % 8 != 0 or h % hkv != 0:
+    if d % 8 != 0 or d >= 128 or h % hkv != 0:
         return None
-    if d % 128 == 0:
-        return 1
     import math
 
     m = 128 // math.gcd(d, 128)
     hb = m * (h // hkv)
-    if hb > _FOLDED_MAX_HEADS_PER_BLOCK or h % hb != 0:
+    if hb > _FOLDED_MAX_HEADS_PER_BLOCK or \
+            hb * d > _FOLDED_MAX_LANES_PER_BLOCK or h % hb != 0:
         return None
     return hb
-
-
-def flash_attention_folded_usable(q, k, v, num_heads, num_kv_heads,
-                                  causal, mask) -> bool:
-    """Folded-kernel eligibility for the auto path (mirrors
-    :func:`flash_attention_usable`)."""
-    if mask is not None:
-        return False
-    if q.ndim != 3 or q.shape[-1] % num_heads or \
-            k.shape[-1] % num_kv_heads:
-        return False
-    d = q.shape[-1] // num_heads
-    if k.shape[-1] // num_kv_heads != d:
-        return False
-    if folded_heads_per_block(num_heads, num_kv_heads, d) is None:
-        return False
-    sq, sk = q.shape[1], k.shape[1]
-    if sq % _pick_block(sq, DEFAULT_BLOCK_Q) or \
-            sk % _pick_block(sk, DEFAULT_BLOCK_K):
-        return False
-    if sq * sk < 128 * 128:
-        return False
-    return on_tpu()
 
 
 def _fwd_kernel_folded_onepass(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
@@ -1384,598 +1369,6 @@ def flash_attention_folded(q, k, v, *, num_heads: int,
 
 
 # ===================================================================== #
-# Head-paired variant: lane-FULL tiles for d < 128 (the honest GPT-2
-# d=64 geometry).  The folded kernels above keep the [B,S,H*D] layout
-# but still issue PER-HEAD dots whose minor dim is d — at d=64 every
-# q/k/v operand tile occupies half the 128 MXU lanes, which is exactly
-# the "half-lane ceiling" row the roofline lane-utilisation model named
-# (PR 13, ROADMAP item 2).  Here ``m = 128 // d`` heads are packed into
-# ONE [block, 128] lane tile per dot:
-#
-# * q heads are adjacent in the folded layout, so a head *pair* (m=2 at
-#   d=64) is a single static 128-lane slice — no repack;
-# * each sub-head's score dot contracts the FULL 128 lanes with the
-#   other sub-heads' lanes zeroed in one operand (q for scores, k for
-#   dq, v for dp/PV) — mathematically per-head-exact, structurally a
-#   full [*, 128] MXU pass;
-# * per-pair softmax stays independent via a lane-BLOCKED running
-#   max/sum: both d64 online-softmax states ride side by side in one
-#   [block_q, 128] VMEM tile (lanes [t*d, (t+1)*d) hold sub-head t's
-#   broadcast state), so the correction/normalisation passes are single
-#   full-lane VPU ops;
-# * GQA pairing groups heads sharing a KV head first (head j reads KV
-#   head j // g), so a pair's K/V lanes are loaded once per grid step
-#   and reused by every pair in the step.
-# ===================================================================== #
-
-_PAIRED_MAX_HEADS_PER_BLOCK = 8  # VMEM guard, same bound as the folded path
-
-
-def paired_heads_per_block(num_heads: int, num_kv_heads: int,
-                           head_dim: int) -> Optional[int]:
-    """Query heads per grid step for the head-PAIRED layout, or None
-    when pairing does not apply.
-
-    Pairing needs ``d < 128`` with ``128 % d == 0`` (``m = 128/d`` heads
-    fill one lane tile exactly) and a head count divisible by the group
-    ``hb = m * g`` (g = GQA group size) so every grid step's KV lanes
-    are whole 128-lane tiles too.  ``d >= 128`` heads are already
-    lane-full — the folded kernels are the right path; odd head counts
-    have no pad rule and fall back likewise.
-    """
-    d, h, hkv = head_dim, num_heads, num_kv_heads
-    if d % 8 != 0 or d >= 128 or 128 % d != 0 or h % hkv != 0:
-        return None
-    g = h // hkv
-    m = 128 // d
-    hb = m * g
-    if hb > _PAIRED_MAX_HEADS_PER_BLOCK or h % hb != 0:
-        return None
-    return hb
-
-
-def flash_attention_paired_usable(q, k, v, num_heads, num_kv_heads,
-                                  causal, mask) -> bool:
-    """Paired-kernel eligibility for the auto path (mirrors
-    :func:`flash_attention_folded_usable`)."""
-    if mask is not None:
-        return False
-    if q.ndim != 3 or q.shape[-1] % num_heads or \
-            k.shape[-1] % num_kv_heads:
-        return False
-    d = q.shape[-1] // num_heads
-    if k.shape[-1] // num_kv_heads != d:
-        return False
-    if paired_heads_per_block(num_heads, num_kv_heads, d) is None:
-        return False
-    sq, sk = q.shape[1], k.shape[1]
-    if sq % _pick_block(sq, DEFAULT_BLOCK_Q) or \
-            sk % _pick_block(sk, DEFAULT_BLOCK_K):
-        return False
-    if sq * sk < 128 * 128:
-        return False
-    return on_tpu()
-
-
-def _lane_iota(rows: int):
-    """[rows, 128] lane-index tile for the sub-head masks."""
-    return jax.lax.broadcasted_iota(jnp.int32, (rows, 128), 1)
-
-
-def _kv_pair_tile(kv_ref, p, m, g, d):
-    """The [block, 128] K or V lane tile pair ``p``'s sub-heads read:
-    sub-head t (query head ``p*m + t`` of this grid step) reads KV head
-    ``(p*m + t) // g`` of the step's m-KV-head block.  When the slices
-    are the identity layout (g == 1) this is the block itself; GQA
-    pairs duplicate their shared KV head's d lanes across the tile, so
-    the HBM load still happens once per grid step."""
-    parts = [kv_ref[0, :, (((p * m + t) // g) * d):
-                    (((p * m + t) // g) + 1) * d] for t in range(m)]
-    return jnp.concatenate(parts, axis=-1)
-
-
-def _fwd_kernel_paired_onepass(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
-                               causal, block_q, block_k, causal_offset,
-                               window, hb, g, d):
-    """Single-k-block paired forward: plain softmax per sub-head, all
-    dots full-lane (see _fwd_kernel_onepass)."""
-    iq = pl.program_id(2)
-    m = 128 // d
-    n_pairs = hb // m
-    if causal:
-        keep = _causal_keep(iq, 0, block_q, block_k, causal_offset, window)
-    lane_q = _lane_iota(block_q)
-    lane_k = _lane_iota(block_k)
-    outs, lses = [], []
-    for p in range(n_pairs):                 # static unroll over the pairs
-        q_pair = q_ref[0, :, p * 128:(p + 1) * 128]       # [bq, 128] bf16
-        kb = _kv_pair_tile(k_ref, p, m, g, d)             # [bk, 128]
-        vb = _kv_pair_tile(v_ref, p, m, g, d)
-        out_pair = jnp.zeros((block_q, 128), jnp.float32)
-        for t in range(m):                   # sub-heads of this pair
-            sel_q = jnp.logical_and(lane_q >= t * d, lane_q < (t + 1) * d)
-            sel_k = jnp.logical_and(lane_k >= t * d, lane_k < (t + 1) * d)
-            qt = jnp.where(sel_q, q_pair, 0)              # other head zeroed
-            s = jax.lax.dot_general(
-                qt, kb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)       # [bq, bk] f32
-            if causal:
-                s = jnp.where(keep, s, NEG_INF)
-            mx = jnp.max(s, axis=1, keepdims=True)        # [bq, 1]
-            pt = jnp.exp(s - mx)
-            l = jnp.sum(pt, axis=1, keepdims=True)
-            safe_l = jnp.where(l == 0.0, 1.0, l)
-            vt = jnp.where(sel_k, vb, 0)     # PV lands only in lanes t
-            out_pair = out_pair + jax.lax.dot_general(
-                (pt / safe_l).astype(vb.dtype), vt, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            lses.append(jnp.broadcast_to(mx + jnp.log(safe_l),
-                                         (block_q, 8)))
-        outs.append(out_pair.astype(o_ref.dtype))
-    o_ref[0] = jnp.concatenate(outs, axis=-1)
-    lse_ref[0] = jnp.stack(lses)
-
-
-def _fwd_kernel_paired(q_ref, k_ref, v_ref, o_ref, lse_ref,
-                       acc_ref, m_ref, l_ref, *, causal, block_q, block_k,
-                       num_k_blocks, causal_offset, window, hb, g, d):
-    iq = pl.program_id(2)
-    ik = pl.program_id(3)
-    m = 128 // d
-    n_pairs = hb // m
-
-    @pl.when(ik == 0)
-    def _():
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-        m_ref[:] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[:] = jnp.zeros_like(l_ref)
-
-    run = _run_predicate(iq, ik, block_q, block_k, causal, causal_offset,
-                         window)
-
-    @pl.when(run)
-    def _():
-        if causal:
-            keep = _causal_keep(iq, ik, block_q, block_k, causal_offset,
-                                window)
-        lane_q = _lane_iota(block_q)
-        lane_k = _lane_iota(block_k)
-        for p in range(n_pairs):
-            q_pair = q_ref[0, :, p * 128:(p + 1) * 128]
-            kb = _kv_pair_tile(k_ref, p, m, g, d)
-            vb = _kv_pair_tile(v_ref, p, m, g, d)
-            m_lane = m_ref[p]                              # [bq, 128]
-            l_lane = l_ref[p]
-            pv = jnp.zeros((block_q, 128), jnp.float32)
-            corr_lane = jnp.ones((block_q, 128), jnp.float32)
-            for t in range(m):
-                sel_q = jnp.logical_and(lane_q >= t * d,
-                                        lane_q < (t + 1) * d)
-                sel_k = jnp.logical_and(lane_k >= t * d,
-                                        lane_k < (t + 1) * d)
-                qt = jnp.where(sel_q, q_pair, 0)
-                s = jax.lax.dot_general(
-                    qt, kb, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-                if causal:
-                    s = jnp.where(keep, s, NEG_INF)
-                # sub-head t's running state lives (broadcast) in lanes
-                # [t*d, (t+1)*d) of the pair's m/l tiles
-                m_prev = m_lane[:, t * d:t * d + 1]        # [bq, 1]
-                m_new = jnp.maximum(m_prev, jnp.max(s, axis=1,
-                                                    keepdims=True))
-                pt = jnp.exp(s - m_new)
-                corr = jnp.exp(m_prev - m_new)
-                l_new = l_lane[:, t * d:t * d + 1] * corr + \
-                    jnp.sum(pt, axis=1, keepdims=True)
-                vt = jnp.where(sel_k, vb, 0)
-                pv = pv + jax.lax.dot_general(
-                    pt.astype(vb.dtype), vt, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)
-                corr_lane = jnp.where(sel_q, corr, corr_lane)
-                m_lane = jnp.where(sel_q, m_new, m_lane)
-                l_lane = jnp.where(sel_q, l_new, l_lane)
-            acc_ref[p] = acc_ref[p] * corr_lane + pv
-            m_ref[p] = m_lane
-            l_ref[p] = l_lane
-
-    @pl.when(ik == num_k_blocks - 1)
-    def _():
-        outs, lses = [], []
-        for p in range(n_pairs):
-            l_lane = l_ref[p]
-            safe_l = jnp.where(l_lane == 0.0, 1.0, l_lane)
-            outs.append((acc_ref[p] / safe_l).astype(o_ref.dtype))
-            for t in range(m):
-                lses.append(jnp.broadcast_to(
-                    m_ref[p][:, t * d:t * d + 1]
-                    + jnp.log(safe_l[:, t * d:t * d + 1]), (block_q, 8)))
-        o_ref[0] = jnp.concatenate(outs, axis=-1)
-        lse_ref[0] = jnp.stack(lses)
-
-
-def _fwd_paired(q, k, v, *, h, hkv, causal, block_q, block_k, interpret,
-                window=None):
-    """q (PRE-SCALED): [B, Sq, H*D]; k/v: [B, Sk, Hkv*D]
-    -> (o: [B, Sq, H*D], lse: [B, H, Sq, 8])."""
-    b, sq, _ = q.shape
-    sk = k.shape[1]
-    d = q.shape[-1] // h
-    g = h // hkv
-    hb = paired_heads_per_block(h, hkv, d)
-    m = 128 // d
-    n_pairs = hb // m
-    nq = sq // block_q
-    nk = sk // block_k
-
-    # the step's KV block is its m KV heads — one 128-lane chunk,
-    # block-indexed directly by the head-group coordinate
-    if nk == 1:
-        kernel = functools.partial(
-            _fwd_kernel_paired_onepass, causal=causal, block_q=block_q,
-            block_k=block_k, causal_offset=sk - sq, window=window,
-            hb=hb, g=g, d=d)
-        grid = (b, h // hb, nq)
-        idx_q = lambda b_, hp, iq: (b_, iq, hp)
-        idx_kv = lambda b_, hp, iq: (b_, 0, hp)
-        idx_l = lambda b_, hp, iq: (b_, hp, iq, 0)
-        scratch = []
-    else:
-        kernel = functools.partial(
-            _fwd_kernel_paired, causal=causal, block_q=block_q,
-            block_k=block_k, num_k_blocks=nk, causal_offset=sk - sq,
-            window=window, hb=hb, g=g, d=d)
-        grid = (b, h // hb, nq, nk)
-        idx_q = lambda b_, hp, iq, ik: (b_, iq, hp)
-        idx_kv = lambda b_, hp, iq, ik: (b_, ik, hp)
-        idx_l = lambda b_, hp, iq, ik: (b_, hp, iq, 0)
-        scratch = [
-            pltpu.VMEM((n_pairs, block_q, 128), jnp.float32),
-            pltpu.VMEM((n_pairs, block_q, 128), jnp.float32),
-            pltpu.VMEM((n_pairs, block_q, 128), jnp.float32),
-        ]
-    return pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, hb * d), idx_q),
-            pl.BlockSpec((1, block_k, 128), idx_kv),
-            pl.BlockSpec((1, block_k, 128), idx_kv),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, hb * d), idx_q),
-            pl.BlockSpec((1, hb, block_q, 8), idx_l),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, sq, h * d), q.dtype),
-            jax.ShapeDtypeStruct((b, h, sq, 8), jnp.float32),
-        ],
-        scratch_shapes=scratch,
-        interpret=interpret,
-        **kernel_names(kernel),
-    )(q, k, v)
-
-
-def _bwd_dq_kernel_paired(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                          dq_ref, dq_acc, *, scale, causal, block_q,
-                          block_k, num_k_blocks, causal_offset, window,
-                          hb, g, d):
-    iq = pl.program_id(2)
-    ik = pl.program_id(3)
-    m = 128 // d
-    n_pairs = hb // m
-
-    @pl.when(ik == 0)
-    def _():
-        dq_acc[:] = jnp.zeros_like(dq_acc)
-
-    run = _run_predicate(iq, ik, block_q, block_k, causal, causal_offset,
-                         window)
-
-    @pl.when(run)
-    def _():
-        if causal:
-            keep = _causal_keep(iq, ik, block_q, block_k, causal_offset,
-                                window)
-        lane_q = _lane_iota(block_q)
-        lane_k = _lane_iota(block_k)
-        for p in range(n_pairs):
-            q_pair = q_ref[0, :, p * 128:(p + 1) * 128]
-            do_pair = do_ref[0, :, p * 128:(p + 1) * 128]
-            kb = _kv_pair_tile(k_ref, p, m, g, d)
-            vb = _kv_pair_tile(v_ref, p, m, g, d)
-            dq_pair = jnp.zeros((block_q, 128), jnp.float32)
-            for t in range(m):
-                j = p * m + t
-                sel_q = jnp.logical_and(lane_q >= t * d,
-                                        lane_q < (t + 1) * d)
-                sel_k = jnp.logical_and(lane_k >= t * d,
-                                        lane_k < (t + 1) * d)
-                qt = jnp.where(sel_q, q_pair, 0)
-                kt = jnp.where(sel_k, kb, 0)
-                vt = jnp.where(sel_k, vb, 0)
-                lse = lse_ref[0, j][:, :1]
-                delta = delta_ref[0, j][:, :1]
-                s = jax.lax.dot_general(qt, kb, (((1,), (1,)), ((), ())),
-                                        preferred_element_type=jnp.float32)
-                if causal:
-                    s = jnp.where(keep, s, NEG_INF)
-                pt = jnp.exp(s - lse)                     # [bq, bk]
-                # dp: do_pair's off-head lanes meet vt's zeros, so the
-                # full-lane contraction is do_t · v_t exactly
-                dp = jax.lax.dot_general(do_pair, vt, (((1,), (1,)), ((), ())),
-                                         preferred_element_type=jnp.float32)
-                ds = (pt * (dp - delta)).astype(kb.dtype)
-                dq_pair = dq_pair + jax.lax.dot_general(
-                    ds, kt, (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)   # lands in lanes t
-            dq_acc[p] = dq_acc[p] + dq_pair
-
-    @pl.when(ik == num_k_blocks - 1)
-    def _():
-        dq_ref[0] = jnp.concatenate(
-            [(dq_acc[p] * scale).astype(dq_ref.dtype)
-             for p in range(n_pairs)], axis=-1)
-
-
-def _bwd_dkv_kernel_paired(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                           dk_ref, dv_ref, dk_acc, dv_acc, *, causal,
-                           block_q, block_k, num_q_blocks, causal_offset,
-                           window, hb, g, d):
-    ik = pl.program_id(2)
-    iq = pl.program_id(3)
-    m = 128 // d
-    n_pairs = hb // m
-
-    @pl.when(iq == 0)
-    def _():
-        dk_acc[:] = jnp.zeros_like(dk_acc)
-        dv_acc[:] = jnp.zeros_like(dv_acc)
-
-    run = _run_predicate(iq, ik, block_q, block_k, causal, causal_offset,
-                         window)
-
-    @pl.when(run)
-    def _():
-        if causal:
-            keep = _causal_keep(iq, ik, block_q, block_k, causal_offset,
-                                window)
-        lane_q = _lane_iota(block_q)
-        lane_k = _lane_iota(block_k)
-        for p in range(n_pairs):
-            q_pair = q_ref[0, :, p * 128:(p + 1) * 128]
-            do_pair = do_ref[0, :, p * 128:(p + 1) * 128]
-            kb = _kv_pair_tile(k_ref, p, m, g, d)
-            vb = _kv_pair_tile(v_ref, p, m, g, d)
-            dk_pair = jnp.zeros((block_k, 128), jnp.float32)
-            dv_pair = jnp.zeros((block_k, 128), jnp.float32)
-            for t in range(m):
-                j = p * m + t
-                sel_q = jnp.logical_and(lane_q >= t * d,
-                                        lane_q < (t + 1) * d)
-                sel_k = jnp.logical_and(lane_k >= t * d,
-                                        lane_k < (t + 1) * d)
-                qt = jnp.where(sel_q, q_pair, 0)
-                dot = jnp.where(sel_q, do_pair, 0)
-                vt = jnp.where(sel_k, vb, 0)
-                lse = lse_ref[0, j][:, :1]
-                delta = delta_ref[0, j][:, :1]
-                s = jax.lax.dot_general(qt, kb, (((1,), (1,)), ((), ())),
-                                        preferred_element_type=jnp.float32)
-                if causal:
-                    s = jnp.where(keep, s, NEG_INF)
-                pt = jnp.exp(s - lse)                     # [bq, bk]
-                pb = pt.astype(do_pair.dtype)
-                dv_pair = dv_pair + jax.lax.dot_general(
-                    pb, dot, (((0,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)   # [bk, 128] lanes t
-                dp = jax.lax.dot_general(do_pair, vt, (((1,), (1,)), ((), ())),
-                                         preferred_element_type=jnp.float32)
-                ds = (pt * (dp - delta)).astype(q_pair.dtype)
-                dk_pair = dk_pair + jax.lax.dot_general(
-                    ds, qt, (((0,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32)   # [bk, 128] lanes t
-            dk_acc[p] = dk_acc[p] + dk_pair
-            dv_acc[p] = dv_acc[p] + dv_pair
-
-    @pl.when(iq == num_q_blocks - 1)
-    def _():
-        dk_ref[0] = jnp.concatenate(
-            [dk_acc[p].astype(dk_ref.dtype) for p in range(n_pairs)],
-            axis=-1)
-        dv_ref[0] = jnp.concatenate(
-            [dv_acc[p].astype(dv_ref.dtype) for p in range(n_pairs)],
-            axis=-1)
-
-
-def _bwd_paired(res, grads, *, h, hkv, scale, causal, block_q, block_k,
-                interpret, window=None):
-    q, k, v, o, lse = res  # q is the PRE-SCALED folded query
-    do = grads[0]
-    b, sq, _ = q.shape
-    sk = k.shape[1]
-    d = q.shape[-1] // h
-    g = h // hkv
-    hb = paired_heads_per_block(h, hkv, d)
-    m = 128 // d
-    n_pairs = hb // m
-    nq = sq // block_q
-    nk = sk // block_k
-
-    # delta_i = rowsum(dO_i * O_i), head-major like lse (see _bwd_folded)
-    delta = (do.astype(jnp.float32) * o.astype(jnp.float32)) \
-        .reshape(b, sq, h, d).sum(axis=-1).transpose(0, 2, 1)
-    delta = jnp.broadcast_to(delta[..., None], (b, h, sq, 8))
-
-    dq = pl.pallas_call(
-        functools.partial(_bwd_dq_kernel_paired, scale=scale, causal=causal,
-                          block_q=block_q, block_k=block_k, num_k_blocks=nk,
-                          causal_offset=sk - sq, window=window,
-                          hb=hb, g=g, d=d),
-        grid=(b, h // hb, nq, nk),
-        in_specs=[
-            pl.BlockSpec((1, block_q, hb * d),
-                         lambda b_, hp, iq, ik: (b_, iq, hp)),
-            pl.BlockSpec((1, block_k, 128),
-                         lambda b_, hp, iq, ik: (b_, ik, hp)),
-            pl.BlockSpec((1, block_k, 128),
-                         lambda b_, hp, iq, ik: (b_, ik, hp)),
-            pl.BlockSpec((1, block_q, hb * d),
-                         lambda b_, hp, iq, ik: (b_, iq, hp)),
-            pl.BlockSpec((1, hb, block_q, 8),
-                         lambda b_, hp, iq, ik: (b_, hp, iq, 0)),
-            pl.BlockSpec((1, hb, block_q, 8),
-                         lambda b_, hp, iq, ik: (b_, hp, iq, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, hb * d),
-                               lambda b_, hp, iq, ik: (b_, iq, hp)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch_shapes=[pltpu.VMEM((n_pairs, block_q, 128), jnp.float32)],
-        interpret=interpret,
-        **kernel_names(_bwd_dq_kernel_paired),
-    )(q, k, v, do, lse, delta)
-
-    # dK/dV per q-head (folded [B, Sk, H*D]), then sum each GQA group
-    dk_h, dv_h = pl.pallas_call(
-        functools.partial(_bwd_dkv_kernel_paired, causal=causal,
-                          block_q=block_q, block_k=block_k, num_q_blocks=nq,
-                          causal_offset=sk - sq, window=window,
-                          hb=hb, g=g, d=d),
-        grid=(b, h // hb, nk, nq),
-        in_specs=[
-            pl.BlockSpec((1, block_q, hb * d),
-                         lambda b_, hp, ik, iq: (b_, iq, hp)),
-            pl.BlockSpec((1, block_k, 128),
-                         lambda b_, hp, ik, iq: (b_, ik, hp)),
-            pl.BlockSpec((1, block_k, 128),
-                         lambda b_, hp, ik, iq: (b_, ik, hp)),
-            pl.BlockSpec((1, block_q, hb * d),
-                         lambda b_, hp, ik, iq: (b_, iq, hp)),
-            pl.BlockSpec((1, hb, block_q, 8),
-                         lambda b_, hp, ik, iq: (b_, hp, iq, 0)),
-            pl.BlockSpec((1, hb, block_q, 8),
-                         lambda b_, hp, ik, iq: (b_, hp, iq, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, hb * d),
-                         lambda b_, hp, ik, iq: (b_, ik, hp)),
-            pl.BlockSpec((1, block_k, hb * d),
-                         lambda b_, hp, ik, iq: (b_, ik, hp)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b, sk, h * d), k.dtype),
-            jax.ShapeDtypeStruct((b, sk, h * d), v.dtype),
-        ],
-        scratch_shapes=[pltpu.VMEM((n_pairs, block_k, 128), jnp.float32),
-                        pltpu.VMEM((n_pairs, block_k, 128), jnp.float32)],
-        interpret=interpret,
-        **kernel_names(_bwd_dkv_kernel_paired),
-    )(q, k, v, do, lse, delta)
-
-    if g > 1:
-        dk = dk_h.reshape(b, sk, hkv, g, d).sum(axis=3).reshape(b, sk, -1)
-        dv = dv_h.reshape(b, sk, hkv, g, d).sum(axis=3).reshape(b, sk, -1)
-    else:
-        dk, dv = dk_h, dv_h
-    return dq, dk.astype(k.dtype), dv.astype(v.dtype)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(3, 11)))
-def _flash_paired(q, k, v, h, hkv, scale, causal, block_q, block_k,
-                  interpret, window):
-    qs = (q.astype(jnp.float32) * scale).astype(q.dtype)
-    o, _ = _fwd_paired(qs, k, v, h=h, hkv=hkv, causal=causal,
-                       block_q=block_q, block_k=block_k,
-                       interpret=interpret, window=window)
-    return o
-
-
-def _flash_paired_fwd(q, k, v, h, hkv, scale, causal, block_q, block_k,
-                      interpret, window):
-    qs = (q.astype(jnp.float32) * scale).astype(q.dtype)
-    o, lse = _fwd_paired(qs, k, v, h=h, hkv=hkv, causal=causal,
-                         block_q=block_q, block_k=block_k,
-                         interpret=interpret, window=window)
-    return o, (qs, k, v, o, lse)
-
-
-def _flash_paired_bwd(h, hkv, scale, causal, block_q, block_k, interpret,
-                      window, res, g):
-    return _bwd_paired(res, (g,), h=h, hkv=hkv, scale=scale, causal=causal,
-                       block_q=block_q, block_k=block_k,
-                       interpret=interpret, window=window)
-
-
-_flash_paired.defvjp(_flash_paired_fwd, _flash_paired_bwd)
-
-
-def flash_attention_paired(q, k, v, *, num_heads: int,
-                           num_kv_heads: Optional[int] = None,
-                           causal: bool = True,
-                           mask: Optional[jax.Array] = None,
-                           scale: Optional[float] = None,
-                           window: Optional[int] = None,
-                           block_q: Optional[int] = None,
-                           block_k: Optional[int] = None,
-                           interpret: Optional[bool] = None):
-    """Head-paired flash attention for sub-lane-tile head dims.
-    q: [B,Sq,H*D]; k/v: [B,Sk,Hkv*D]; returns [B,Sq,H*D].
-
-    Semantics (causal / sliding ``window`` / GQA / ``scale``) match
-    :func:`flash_attention` exactly; the layout matches
-    :func:`flash_attention_folded` — only the in-kernel tiling differs:
-    every MXU dot is a full-128-lane pass even at d=64.
-    """
-    if mask is not None:
-        raise NotImplementedError(
-            "flash_attention_paired supports causal/full (+sliding window) "
-            "only; use ops.attention.dot_product_attention for custom masks")
-    if window is not None and not causal:
-        raise ValueError("sliding window requires causal attention")
-    if window is not None and window <= 0:
-        raise ValueError(f"window must be positive, got {window}")
-    hkv = num_kv_heads if num_kv_heads is not None else num_heads
-    if q.ndim != 3 or k.ndim != 3 or v.ndim != 3:
-        raise ValueError("paired layout expects rank-3 [B, S, H*D] inputs")
-    b, sq, hd = q.shape
-    _, sk, kvd = k.shape
-    if num_heads % hkv:
-        raise ValueError(f"GQA needs H % Hkv == 0, got {num_heads} % {hkv}")
-    if hd % num_heads or kvd % hkv:
-        raise ValueError(
-            f"paired widths ({hd}, {kvd}) must be divisible by their head "
-            f"counts ({num_heads}, {hkv})")
-    d = hd // num_heads
-    if kvd // hkv != d:
-        raise ValueError(
-            f"q head_dim {d} != kv head_dim {kvd // hkv}")
-    if paired_heads_per_block(num_heads, hkv, d) is None:
-        raise ValueError(
-            f"no lane-full head pairing for H={num_heads} Hkv={hkv} "
-            f"d={d}; use flash_attention_folded (d >= 128) or the "
-            f"[B,S,H,D] flash_attention path")
-    scale = scale if scale is not None else 1.0 / (d ** 0.5)
-    block_q = block_q or _pick_block(sq, DEFAULT_BLOCK_Q)
-    block_k = block_k or _pick_block(sk, DEFAULT_BLOCK_K)
-    if sq % block_q or sk % block_k:
-        raise ValueError(
-            f"seq lengths ({sq},{sk}) must divide blocks ({block_q},{block_k})")
-    if interpret is None:
-        interpret = not on_tpu()
-
-    def kernel(q, k, v, head_shards):
-        return _flash_paired(
-            q, k, v, int(num_heads) // head_shards, int(hkv) // head_shards,
-            float(scale), bool(causal), int(block_q), int(block_k),
-            bool(interpret), int(window) if window is not None else None)
-
-    return run_partitioned(
-        kernel, q, k, v, None if interpret else mesh_partition(
-            b, num_heads, hkv, lambda n: paired_heads_per_block(
-                num_heads // n, hkv // n, d) is not None))
-
-
-# ===================================================================== #
 # dslint contract-checker registration (see analysis/pallas_lint.py):
 # the kernel_selftest parameter grid, invoked under the checker's
 # capture context — no kernel body runs, nothing compiles.
@@ -2012,11 +1405,11 @@ def _dslint_flash_cases():
 
 @pallas_kernel_case(
     "flash_attention_folded",
-    note="folded [B,S,H*D] lane layout incl. the d=64 head-group lane "
-         "slicing (hb>1) and hb==1 (d=128) BlockSpecs")
+    note="folded [B,S,H*D] lane layout: the d=64 head-group lane "
+         "slicing (pairs, a GQA group of 2), d=32 quads, SWA")
 def _dslint_flash_folded_cases():
     for h, hkv, d, win in ((12, 12, 64, None), (8, 4, 64, None),
-                           (8, 2, 128, None), (4, 4, 64, 256)):
+                           (4, 4, 32, None), (4, 4, 64, 256)):
         q, k, v = _dslint_qkv(h, hkv, d)
         b, s = q.shape[:2]
         flash_attention_folded(
@@ -2032,30 +1425,4 @@ def _dslint_flash_folded_cases():
     o, lse = _fwd_folded(qf, kf, vf, h=h, hkv=hkv, causal=True,
                          block_q=bq, block_k=bk, interpret=True)
     _bwd_folded((qf, kf, vf, o, lse), (o,), h=h, hkv=hkv, scale=0.125,
-                causal=True, block_q=bq, block_k=bk, interpret=True)
-
-
-@pallas_kernel_case(
-    "flash_attention_paired",
-    note="head-paired lane-FULL tiles for d < 128: honest 12-head/d64 "
-         "MHA, GQA sharing KV loads per pair, d=32 quad-pack, SWA; "
-         "multi-k fwd + both backward kernels at 128x128 blocks")
-def _dslint_flash_paired_cases():
-    for h, hkv, d, win in ((12, 12, 64, None), (8, 4, 64, None),
-                           (4, 4, 32, None), (4, 4, 64, 256)):
-        q, k, v = _dslint_qkv(h, hkv, d)
-        b, s = q.shape[:2]
-        flash_attention_paired(
-            q.reshape(b, s, h * d), k.reshape(b, s, hkv * d),
-            v.reshape(b, s, hkv * d), num_heads=h, num_kv_heads=hkv,
-            causal=True, window=win, interpret=True)
-    h, hkv, d, bq, bk = 4, 2, 64, 128, 128
-    q, k, v = _dslint_qkv(h, hkv, d)
-    b, s = q.shape[:2]
-    qf = q.reshape(b, s, h * d)
-    kf = k.reshape(b, s, hkv * d)
-    vf = v.reshape(b, s, hkv * d)
-    o, lse = _fwd_paired(qf, kf, vf, h=h, hkv=hkv, causal=True,
-                         block_q=bq, block_k=bk, interpret=True)
-    _bwd_paired((qf, kf, vf, o, lse), (o,), h=h, hkv=hkv, scale=0.125,
                 causal=True, block_q=bq, block_k=bk, interpret=True)

@@ -308,24 +308,6 @@ class DeepSpeedConfig:
         self.memory_breakdown = pd.get("memory_breakdown", False)
         self.dataloader_drop_last = pd.get("dataloader_drop_last", False)
         self.seed = pd.get("seed", 1234)
-        # "folded" keeps attention in the QKV GEMM's [B,S,H*D] lane layout
-        # (layout-native Pallas flash, no BSHD<->BHSD transposes);
-        # "paired" additionally packs 128/D heads per lane-full MXU tile
-        # (the d=64 full-lane path, falling back to folded/bshd where
-        # pairing does not apply); "bshd" is the historical [B,S,H,D]
-        # boundary. Applied by the engine via
-        # ops.attention.set_default_attention_layout; models whose own
-        # config pins attention_layout override this.
-        from deepspeed_tpu.ops.attention import ATTENTION_LAYOUTS
-
-        self.attention_layout = pd.get("attention_layout", "bshd")
-        # only an EXPLICIT key may overwrite the process default at engine
-        # init — a second engine with no opinion must not stomp the first's
-        self.attention_layout_explicit = "attention_layout" in pd
-        if self.attention_layout not in ATTENTION_LAYOUTS:
-            raise ValueError(
-                f"attention_layout must be one of {ATTENTION_LAYOUTS}, got "
-                f"{self.attention_layout!r}")
 
         self.fp16 = FP16Config.from_dict(pd.get("fp16"))
         self.bf16 = BF16Config.from_dict(pd.get("bf16", pd.get("bfloat16")))

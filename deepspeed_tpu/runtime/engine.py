@@ -171,19 +171,6 @@ class DeepSpeedEngine:
         self.module = model
         self._init_fn, self._apply_fn = _as_model_fns(model, loss_fn)
 
-        # attention layout (must land before the train step is traced so
-        # models that consult the process default pick it up). Only an
-        # explicit config key writes the process-wide default — engines
-        # without one inherit whatever is in force, so co-resident engines
-        # (train+eval, actor+critic) don't silently flip each other's
-        # layout; models needing a guaranteed layout pin it in their own
-        # config's attention_layout.
-        if self.config.attention_layout_explicit:
-            from deepspeed_tpu.ops.attention import (
-                set_default_attention_layout)
-
-            set_default_attention_layout(self.config.attention_layout)
-
         # precision ---------------------------------------------------------
         self.compute_dtype = self.config.precision_dtype
         self.fp16_enabled = self.config.fp16.enabled

@@ -149,14 +149,6 @@ def test_checker_flags_folded_d64_lane_slice():
     # ...and the shipped folded grouping (hb=2 -> 128-lane blocks) passes
     from deepspeed_tpu.ops import flash_attention as fa
     assert fa.folded_heads_per_block(12, 12, 64) == 2
-    # the head-PAIRED kernels take the same full-lane grouping one step
-    # further: every BlockSpec lane window AND every in-kernel MXU dot
-    # is 128 lanes — pairing exists precisely so no d64 slice is ever
-    # the half-lane spelling this checker flags
-    assert fa.paired_heads_per_block(12, 12, 64) == 2
-    assert fa.paired_heads_per_block(4, 4, 32) == 4   # quad-pack
-    assert fa.paired_heads_per_block(8, 2, 128) is None  # d128: use folded
-    assert fa.paired_heads_per_block(3, 3, 64) is None   # odd heads
 
 
 def test_checker_flags_uncovered_tile():

@@ -23,29 +23,46 @@ def _make(b=2, sq=256, sk=256, h=4, hkv=4, d=64, dtype=jnp.float32, seed=0):
     return q, k, v
 
 
+#: (q heads, kv heads, head size).  The first three are this file's own; the
+#: rest are what the folded and paired families' tests held until PR 54
+#: took the families out: GPT-2's 12 x 64, GQA groups of 2 and 3 at 64
+#: lanes, 32-lane heads, and 128-lane heads with and without a group.
+GEOMS = [(4, 4, 64), (4, 2, 64), (4, 1, 64), (12, 12, 64), (8, 4, 64),
+         (6, 2, 64), (4, 4, 32), (4, 4, 128), (4, 2, 128)]
+#: None: the defaults (one tile at 256 x 256, the one-pass forward);
+#: (64, 128): four q-tiles by two k-tiles, the forward with its scratch
+BLOCKS = [None, (64, 128)]
+_ids = lambda g: "-".join(map(str, g)) if g else "default"
+
+
+@pytest.mark.parametrize("blocks", BLOCKS, ids=_ids)
 @pytest.mark.parametrize("causal", [True, False])
-@pytest.mark.parametrize("hkv", [4, 2, 1])
-def test_flash_forward_matches_xla(causal, hkv):
-    q, k, v = _make(hkv=hkv)
+@pytest.mark.parametrize("geom", GEOMS, ids=_ids)
+def test_flash_forward_matches_xla(geom, causal, blocks):
+    h, hkv, d = geom
+    bq, bk = blocks or (None, None)
+    q, k, v = _make(h=h, hkv=hkv, d=d)
     ref = _xla_attention(q, k, v, causal=causal, mask=None, scale=None)
-    out = flash_attention(q, k, v, causal=causal, interpret=True)
+    out = flash_attention(q, k, v, causal=causal, block_q=bq, block_k=bk,
+                          interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
+def _grads(fn, q, k, v):
+    return jax.grad(lambda *a: jnp.sum(fn(*a).astype(jnp.float32) ** 2),
+                    argnums=(0, 1, 2))(q, k, v)
+
+
 @pytest.mark.parametrize("causal", [True, False])
-def test_flash_grads_match_xla(causal):
-    q, k, v = _make(h=4, hkv=2)
-
-    def loss_f(q, k, v):
-        return jnp.sum(flash_attention(q, k, v, causal=causal,
-                                       interpret=True) ** 2)
-
-    def loss_r(q, k, v):
-        return jnp.sum(_xla_attention(q, k, v, causal=causal, mask=None,
-                                      scale=None) ** 2)
-
-    gf = jax.grad(loss_f, argnums=(0, 1, 2))(q, k, v)
-    gr = jax.grad(loss_r, argnums=(0, 1, 2))(q, k, v)
+@pytest.mark.parametrize("geom", GEOMS[1:2] + GEOMS[3:], ids=_ids)
+def test_flash_grads_match_xla(geom, causal):
+    """dQ over two k-tiles and dK/dV over four q-tiles, group-summed."""
+    h, hkv, d = geom
+    q, k, v = _make(h=h, hkv=hkv, d=d)
+    gf = _grads(lambda *a: flash_attention(
+        *a, causal=causal, block_q=64, block_k=128, interpret=True), q, k, v)
+    gr = _grads(lambda *a: _xla_attention(
+        *a, causal=causal, mask=None, scale=None), q, k, v)
     for name, a, b in zip("qkv", gf, gr):
         scale = float(jnp.abs(b).max()) + 1e-9
         np.testing.assert_allclose(np.asarray(a) / scale,
@@ -83,13 +100,23 @@ def test_flash_rectangular_causal_end_aligned():
     np.testing.assert_allclose(np.asarray(gf), np.asarray(gr), atol=1e-3)
 
 
-def test_flash_bf16():
-    q, k, v = _make(dtype=jnp.bfloat16)
-    ref = _xla_attention(q, k, v, causal=True, mask=None, scale=None)
-    out = flash_attention(q, k, v, causal=True, interpret=True)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32), atol=3e-2)
+@pytest.mark.parametrize("geom", [(4, 4, 64), (12, 12, 64), (4, 2, 64),
+                                  (4, 2, 128)], ids=_ids)
+def test_flash_bf16(geom):
+    """Inside the on-chip self-test's tolerances (forward 3e-2, gradients
+    ten times that) through the interpreter too."""
+    h, hkv, d = geom
+    q, k, v = _make(h=h, hkv=hkv, d=d, dtype=jnp.bfloat16)
+    flash = lambda *a: flash_attention(*a, causal=True, interpret=True)
+    xla = lambda *a: _xla_attention(*a, causal=True, mask=None, scale=None)
+    out = flash(q, k, v)
     assert out.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(xla(q, k, v), np.float32),
+                               atol=3e-2)
+    for a, b in zip(_grads(flash, q, k, v), _grads(xla, q, k, v)):
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32), atol=3e-1)
 
 
 def test_flash_custom_scale():
@@ -126,7 +153,9 @@ def test_dot_product_attention_pallas_switch():
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
 
 
-def test_flash_partitioned_over_mesh_matches_whole():
+@pytest.mark.parametrize("geom", [(4, 2, 64), (8, 4, 64), (4, 4, 32)],
+                         ids=_ids)
+def test_flash_partitioned_over_mesh_matches_whole(geom):
     """A compiled Mosaic kernel cannot be split by GSPMD, so on a
     multi-device mesh the entry runs it per shard (batch over the
     data-parallel axes, heads over 'model').  Same values and gradients
@@ -136,10 +165,11 @@ def test_flash_partitioned_over_mesh_matches_whole():
                                                    run_partitioned)
     from deepspeed_tpu.parallel import groups
 
-    q, k, v = _make(b=4, h=4, hkv=2)
+    h, hkv, d = geom
+    q, k, v = _make(b=4, h=h, hkv=hkv, d=d)
     assert mesh_partition(4, 4, 2) is None          # no topology yet
     groups.initialize_mesh(model_parallel_size=2)   # data=4 x model=2
-    part = mesh_partition(4, 4, 2)
+    part = mesh_partition(4, h, hkv)
     assert part[1:] == (("dout", "data", "expert"), ("model",), 2)
     # a batch or head count that does not divide stays whole
     assert mesh_partition(3, 4, 2)[1] is None
@@ -342,8 +372,9 @@ def test_a_step_outside_the_band_names_a_tile_inside_it(offset, window):
     bq, bk, nq, nk = 256, 512, 8, 4 + offset // 512
     band = dict(causal=True, block_q=bq, block_k=bk, causal_offset=offset,
                 window=window)
-    live = np.array([[bool(fa._run_predicate(iq, ik, bq, bk, True, offset,
-                                             window))
+    live = np.array([[_classes(range(iq * bq, (iq + 1) * bq),
+                               range(ik * bk, (ik + 1) * bk), offset,
+                               window)[0]
                       for ik in range(nk)] for iq in range(nq)])
     for iq in range(nq):
         inside = np.flatnonzero(live[iq])
@@ -386,58 +417,72 @@ def test_causal_work_counts_what_the_kernels_execute():
     assert run == 128 * (2 * 256 + 2 * 512 + 2 * 768 + 2 * 1024)
 
 
-def test_flash_attention_records_its_work_once_a_trace():
+@pytest.mark.parametrize("geom", GEOMS[1:2] + GEOMS[3:], ids=_ids)
+def test_flash_attention_records_its_work_once_a_trace(geom):
+    """``causal_work`` x batch x QUERY heads at every geometry (a KV head
+    is multiplied once a query head of its group), and its ``run`` against
+    the mask itself: the sub-blocks that hold a visible pair."""
     from deepspeed_tpu.observability.registry import MetricsRegistry
 
+    h, hkv, d = geom
     reg = MetricsRegistry.default()
     before = reg.snapshot()
-    q, k, v = _make(b=2, sq=256, sk=256, h=4, hkv=2)
-    f = jax.jit(lambda q, k, v: flash_attention(q, k, v, causal=True,
-                                                interpret=True))
+    q, k, v = _make(b=2, sq=256, sk=256, h=h, hkv=hkv, d=d)
+    f = jax.jit(lambda q, k, v: flash_attention(
+        q, k, v, causal=True, block_q=128, block_k=128, interpret=True))
     f(q, k, v)
     f(q, k, v)                                  # the traced program again
     after = reg.snapshot()
-    run, live = fa.causal_work(256, 256)
+    run, live = fa.causal_work(256, 256, block_q=128, block_k=128)
+    sub_q, sub_k = fa._sub_blocks(128, 128)
+    assert run == sub_q * sub_k * sum(
+        _classes(range(r, r + sub_q), range(c, c + sub_k), 0, None)[0]
+        for r in range(0, 256, sub_q) for c in range(0, 256, sub_k))
+    assert live == 256 * 257 // 2 < run < 256 * 256
     assert after["flash/score_elems_run"] \
-        - before["flash/score_elems_run"] == 2 * 4 * run
+        - before["flash/score_elems_run"] == 2 * h * run
     assert after["flash/score_elems_live"] \
-        - before["flash/score_elems_live"] == 2 * 4 * live
+        - before["flash/score_elems_live"] == 2 * h * live
     assert not reg.unknown_names, reg.unknown_names
 
 
 @pytest.mark.parametrize("b,s,h,hkv,d,window", [
-    (8, 1024, 20, 20, 64, None), (1, 4096, 16, 4, 128, 4096)],
-    ids=["gpt2large_d64_s1k", "mistral7b_d128_s4k"])
-def test_the_training_cells_shapes_lower_for_the_tpu(b, s, h, hkv, d, window):
-    """The three kernels at the two training cells' shapes, lowered for the
-    TPU from here (no chip): the walk's loops, its dynamic slices and its
-    lane-wise statistics pass the Mosaic lowering, under the kernels'
-    accepted names."""
+    (8, 1024, 20, 20, 64, None), (1, 4096, 16, 4, 128, 4096),
+    (2, 512, 8, 4, 64, None), (2, 2048, 6, 2, 64, 256),
+    (2, 512, 4, 4, 32, None)],
+    ids=["gpt2large_d64_s1k", "mistral7b_d128_s4k", "gqa_8_4_d64",
+         "gqa_6_2_d64_s2k_window", "d32"])
+def test_the_training_cells_shapes_lower_for_the_tpu(monkeypatch, b, s, h,
+                                                     hkv, d, window):
+    """The three kernels the one route picks at the two training cells'
+    shapes (and at GQA groups and 32-lane heads), lowered for the TPU
+    from here (no chip): heads under a lane tile take the folded family,
+    128-lane heads the [B, H, S, D] kernels whose walk's loops, dynamic
+    slices and lane-wise statistics pass the Mosaic lowering; each under
+    the kernels' accepted names."""
+    monkeypatch.setattr(fa, "on_tpu", lambda: True)
     q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16)
     k = jax.ShapeDtypeStruct((b, s, hkv, d), jnp.bfloat16)
 
     def loss(q, k, v):
-        return jnp.sum(flash_attention(
-            q, k, v, causal=True, window=window,
-            interpret=False).astype(jnp.float32) ** 2)
+        return jnp.sum(dot_product_attention(
+            q, k, v, causal=True, window=window).astype(jnp.float32) ** 2)
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).trace(q, k, k).lower(
         lowering_platforms=("tpu",)).as_text()
     assert text.count("tpu_custom_call") == 3
-    fwd = "_fwd_kernel_onepass" if s <= 1024 else "_fwd_kernel"
-    for name in (fwd, "_bwd_dq_kernel", "_bwd_dkv_kernel"):
+    family = "_folded" if fa.folded_heads_per_block(h, hkv, d) else ""
+    fwd = "_onepass" if s <= 1024 else ""
+    for name in (f"_fwd_kernel{family}{fwd}", f"_bwd_dq_kernel{family}",
+                 f"_bwd_dkv_kernel{family}"):
         assert f'kernel_name = "{name}"' in text, name
 
 
 # ===================================================================== #
 # Folded ([B, S, H*D]) layout-native kernels
 # ===================================================================== #
-from deepspeed_tpu.ops.attention import (folded_attention,  # noqa: E402
-                                         get_default_attention_layout,
-                                         set_default_attention_layout)
 from deepspeed_tpu.ops.flash_attention import (  # noqa: E402
-    flash_attention_folded, flash_attention_folded_usable,
-    folded_heads_per_block)
+    flash_attention_folded, folded_heads_per_block)
 
 
 def _make_folded(b=2, sq=256, sk=256, h=4, hkv=4, d=64, dtype=jnp.float32,
@@ -449,10 +494,16 @@ def _make_folded(b=2, sq=256, sk=256, h=4, hkv=4, d=64, dtype=jnp.float32,
     return (fold(q), fold(k), fold(v)), (q, k, v)
 
 
-# d=64 exercises the head-group (hb>1) kernels, d=128 the singleton-head
-# blocks; the explicit small blocks force the multi-k-block online-softmax
-# kernel where the defaults would select the one-pass variant.
-FOLDED_GEOMS = [(4, 4, 64), (4, 2, 64), (4, 4, 128), (4, 2, 128)]
+# Heads under a lane tile, in groups of whole tiles: pairs at 64 lanes
+# (GPT-2's 12 heads among them), a GQA group of 2 that widens the pair
+# to four heads, four 32-lane heads a tile (the geometries the paired
+# family's tests held until PR 54; its GQA group of 3, six heads a
+# group, is past what the chip's VMEM holds and is a case of GEOMS
+# alone); the explicit small blocks force the multi-k-block
+# online-softmax kernel where the defaults would select the one-pass
+# variant.
+FOLDED_GEOMS = [(4, 4, 64), (4, 2, 64), (12, 12, 64), (8, 4, 64),
+                (4, 4, 32)]
 
 
 @pytest.mark.parametrize("causal", [True, False])
@@ -492,7 +543,7 @@ def test_folded_grads_match_xla(h, hkv, d):
                                    atol=1e-4, err_msg=f"d{name}")
 
 
-@pytest.mark.parametrize("h,hkv,d", [(4, 4, 64), (4, 2, 128)])
+@pytest.mark.parametrize("h,hkv,d", [(4, 4, 64), (12, 12, 64), (4, 2, 64)])
 def test_folded_bf16_within_selftest_tolerances(h, hkv, d):
     """The acceptance tolerances of the on-chip selftest (fwd 2e-2, grad
     2.5e-1 at bf16) hold through the interpreter too."""
@@ -556,7 +607,14 @@ def test_folded_rectangular_causal_end_aligned():
 def test_folded_heads_per_block_grouping():
     assert folded_heads_per_block(12, 12, 64) == 2   # MHA d64: lane pair
     assert folded_heads_per_block(4, 2, 64) == 4     # GQA g=2 d64
-    assert folded_heads_per_block(8, 2, 128) == 1    # d128: singleton
+    assert folded_heads_per_block(4, 4, 32) == 4     # four heads a tile
+    assert folded_heads_per_block(8, 2, 128) is None  # d128: the other family
+    # past the chip's VMEM at the default tiles: six or eight heads a
+    # group, or more than 256 lanes (four heads of 96)
+    assert folded_heads_per_block(6, 2, 64) is None
+    assert folded_heads_per_block(8, 2, 64) is None
+    assert folded_heads_per_block(8, 8, 16) is None
+    assert folded_heads_per_block(16, 16, 96) is None
     assert folded_heads_per_block(3, 3, 64) is None  # 3 heads: no pair
     assert folded_heads_per_block(4, 4, 48) is None  # 48 lanes: no tile
 
@@ -580,85 +638,132 @@ def test_folded_validation_errors():
                                num_heads=4, interpret=True)
 
 
-def test_folded_usable_gate():
-    (qf, kf, vf), _ = _make_folded()
-    # CPU platform: not usable (auto path keeps the fallback)
-    assert not flash_attention_folded_usable(qf, kf, vf, 4, 4, True, None)
-    # mask always falls back
-    assert not flash_attention_folded_usable(qf, kf, vf, 4, 4, True,
-                                             jnp.ones((1,), bool))
-    # no lane-aligned grouping falls back
-    (q3, k3, v3), _ = _make_folded(h=3, hkv=3, d=64)
-    assert not flash_attention_folded_usable(q3, k3, v3, 3, 3, True, None)
-
-
-def test_folded_attention_pallas_switch_and_fallback():
-    """implementation='pallas' runs the folded kernel (interpret off-TPU);
-    the auto path off-TPU falls back through the free reshape and still
-    matches — both against the XLA reference."""
-    (qf, kf, vf), (q, k, v) = _make_folded(h=4, hkv=2, d=64)
-    ref = _xla_attention(q, k, v, causal=True, mask=None, scale=None)
-    out_kernel = folded_attention(qf, kf, vf, num_heads=4, num_kv_heads=2,
-                                  causal=True, implementation="pallas")
-    np.testing.assert_allclose(np.asarray(out_kernel).reshape(ref.shape),
-                               np.asarray(ref), atol=2e-5)
-    out_auto = folded_attention(qf, kf, vf, num_heads=4, num_kv_heads=2,
-                                causal=True)
-    np.testing.assert_allclose(np.asarray(out_auto).reshape(ref.shape),
-                               np.asarray(ref), atol=2e-5)
-
-
 # ===================================================================== #
-# attention_layout config plumbing
+# The one route (PR 54): the models hand ``dot_product_attention`` their
+# [B, S, H, D] heads and the gate picks the flash kernels or XLA
 # ===================================================================== #
 @pytest.fixture
-def _restore_layout():
-    prev = get_default_attention_layout()
-    yield
-    set_default_attention_layout(prev)
+def chip_route(monkeypatch):
+    """``flash_attention_usable`` as a TPU answers it (its shape rules as
+    they are, ``on_tpu()`` true inside it alone, so the kernels still run
+    in interpret mode), and the two families' entries spied on; returns
+    the list of what each call took: False, "folded" or "bshd"."""
+    usable, took = fa.flash_attention_usable, []
+
+    def gate(*args):
+        with monkeypatch.context() as m:
+            m.setattr(fa, "on_tpu", lambda: True)
+            ok = usable(*args)
+        if not ok:
+            took.append(False)
+        return ok
+
+    def spy(name, fn):
+        def entry(*args, **kw):
+            took.append(name)
+            return fn(*args, **kw)
+        return entry
+
+    monkeypatch.setattr(fa, "flash_attention_usable", gate)
+    monkeypatch.setattr(fa, "flash_attention_folded",
+                        spy("folded", fa.flash_attention_folded))
+    monkeypatch.setattr(fa, "flash_attention",
+                        spy("bshd", fa.flash_attention))
+    return took
 
 
-def test_attention_layout_config_parse(_restore_layout):
+@pytest.mark.parametrize("geom,want", [
+    ((12, 12, 64), "folded"), ((4, 2, 64), "folded"), ((4, 4, 32), "folded"),
+    ((3, 3, 64), "bshd"),               # three heads make no whole tile
+    ((6, 2, 64), "bshd"),               # six heads a group: past the VMEM
+    ((4, 4, 128), "bshd"), ((4, 2, 128), "bshd")], ids=_ids)
+def test_the_head_size_picks_the_family(chip_route, geom, want):
+    """Heads under a 128-lane tile that group to whole tiles go to the
+    folded kernels (the chip's A/B of PR 54), everything else the gate
+    admits to the [B, H, S, D] kernels; same values either way."""
+    h, hkv, d = geom
+    q, k, v = _make(h=h, hkv=hkv, d=d)
+    out = dot_product_attention(q, k, v, causal=True)
+    assert chip_route == [want]
+    ref = _xla_attention(q, k, v, causal=True, mask=None, scale=None)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-5)
+
+
+@pytest.mark.parametrize("why,shape,mask", [
+    ("head_size_no_multiple_of_8", dict(d=20), False),
+    ("rows_no_whole_block", dict(sq=250, sk=250), False),
+    ("under_a_block_of_128", dict(sq=64, sk=64), False),
+    ("a_mask_of_the_callers", dict(), True),
+])
+def test_a_geometry_the_gate_refuses_takes_the_xla_route(chip_route, why,
+                                                         shape, mask):
+    q, k, v = _make(**shape)
+    mask = jnp.tril(jnp.ones(q.shape[1:2] * 2, bool))[None, None] \
+        if mask else None
+    out = dot_product_attention(q, k, v, causal=True, mask=mask)
+    assert chip_route == [False]
+    ref = _xla_attention(q, k, v, causal=True, mask=mask, scale=None)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+
+@pytest.mark.parametrize("model_name,want", [("gpt2", "folded"),
+                                             ("llama", "bshd")])
+def test_a_models_training_forward_takes_the_one_route(chip_route,
+                                                       model_name, want):
+    """GPT-2 at 4 heads of 64 and Llama at GQA 4-on-2 heads of 128, 128
+    tokens: the loss and its gradients through the family the head size
+    picks against the XLA composition; neither config has a field that
+    picks the route."""
+    if model_name == "gpt2":
+        from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHeadModel
+        model = GPT2LMHeadModel(GPT2Config.tiny(
+            dtype=jnp.float32, hidden_size=256))
+    else:
+        from deepspeed_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+        model = LlamaForCausalLM(LlamaConfig.tiny(
+            dtype=jnp.float32, hidden_size=512))
+    ids = (np.arange(2 * 128, dtype=np.int32).reshape(2, 128) * 7) % 250
+    params = model.init(jax.random.key(0), ids)
+    chip_route.clear()
+    loss = lambda p: model.apply(p, ids, labels=ids)
+    got, grads = jax.value_and_grad(loss)(params)
+    assert chip_route == [want] * model.config.num_hidden_layers
+    with pytest.MonkeyPatch.context() as m:     # the XLA route
+        m.setattr(fa, "flash_attention_usable", lambda *a: False)
+        want_loss, want_grads = jax.value_and_grad(loss)(params)
+    np.testing.assert_allclose(float(got), float(want_loss), rtol=1e-5)
+    for a, b in zip(jax.tree.leaves(grads), jax.tree.leaves(want_grads)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4)
+
+
+def test_flash_steady_state_recompile_and_sync_free(trace_guard):
+    """A warmed jitted train-style step over the kernels (forward and the
+    custom_vjp's two backward kernels) builds no new executable and makes
+    no host sync when it is called again."""
+    q, k, v = _make(h=4, hkv=2, d=64)
+
+    @jax.jit
+    def step(q, k, v):
+        return jax.value_and_grad(lambda *a: jnp.sum(flash_attention(
+            *a, causal=True, block_q=64, block_k=128, interpret=True) ** 2),
+            argnums=(0, 1, 2))(q, k, v)
+
+    step(q, k, v)[0].block_until_ready()
+    with trace_guard(max_compiles=0, max_host_syncs=0):
+        for _ in range(3):
+            out = step(q, k, v)
+    jax.block_until_ready(out)
+
+
+def test_a_config_that_still_names_attention_layout_parses():
+    """The key PR 54 took out is to the config what any key it does not
+    know is: kept in the dict it was given, read by nothing, refused for no
+    value."""
     from deepspeed_tpu.runtime.config import DeepSpeedConfig
 
     base = {"train_micro_batch_size_per_gpu": 1}
-    assert DeepSpeedConfig(base).attention_layout == "bshd"
-    assert DeepSpeedConfig({**base, "attention_layout": "folded"}) \
-        .attention_layout == "folded"
-    with pytest.raises(ValueError, match="attention_layout"):
-        DeepSpeedConfig({**base, "attention_layout": "bhsd"})
-    with pytest.raises(ValueError, match="attention_layout"):
-        set_default_attention_layout("nope")
-    set_default_attention_layout("folded")
-    assert get_default_attention_layout() == "folded"
-
-
-@pytest.mark.parametrize("model_name", ["gpt2", "llama"])
-def test_attention_layout_selects_and_falls_back(model_name, _restore_layout):
-    """A model with attention_layout='folded' routes through
-    folded_attention (off-TPU: the reshape fallback) and must match the
-    bshd path exactly; None defers to the process default."""
-    import flax.linen as nn  # noqa: F401 — model import sanity
-
-    if model_name == "gpt2":
-        from deepspeed_tpu.models.gpt2 import GPT2Config, GPT2LMHeadModel
-        make = lambda layout: GPT2LMHeadModel(
-            GPT2Config.tiny(dtype=jnp.float32, attention_layout=layout))
-    else:
-        from deepspeed_tpu.models.llama import (LlamaConfig,
-                                                LlamaForCausalLM)
-        make = lambda layout: LlamaForCausalLM(
-            LlamaConfig.tiny(dtype=jnp.float32, attention_layout=layout))
-
-    ids = np.arange(32, dtype=np.int32).reshape(1, 32) % 250
-    params = make("bshd").init(jax.random.key(0), ids)
-    ref = make("bshd").apply(params, ids)
-    out_folded = make("folded").apply(params, ids)
-    np.testing.assert_allclose(np.asarray(out_folded), np.asarray(ref),
-                               atol=1e-5, rtol=1e-5)
-    # None defers to the process-wide default (what the engine sets from
-    # the DeepSpeed config's attention_layout key)
-    set_default_attention_layout("folded")
-    out_default = make(None).apply(params, ids)
-    np.testing.assert_allclose(np.asarray(out_default), np.asarray(ref),
-                               atol=1e-5, rtol=1e-5)
+    for extra in ({"attention_layout": "folded"}, {"attention_layout": 7},
+                  {"a_key_nobody_knows": "x"}):
+        cfg = DeepSpeedConfig({**base, **extra})
+        assert vars(cfg).keys() == vars(DeepSpeedConfig(base)).keys()
+        assert cfg._param_dict == {**base, **extra}

@@ -1195,9 +1195,6 @@ _KERNEL_ENTRIES = [
     ("flash_attention_folded", "_fwd_kernel_folded"),
     ("flash_attention_folded", "_bwd_dq_kernel_folded"),
     ("flash_attention_folded", "_bwd_dkv_kernel_folded"),
-    ("flash_attention_paired", "_fwd_kernel_paired"),
-    ("flash_attention_paired", "_bwd_dq_kernel_paired"),
-    ("flash_attention_paired", "_bwd_dkv_kernel_paired"),
     ("paged_attention_grid", "_kernel"),
     ("paged_prefill", "_prefill_kernel"),
     ("paged_decode_dma", "_decode_kernel"),
@@ -1253,7 +1250,7 @@ def test_every_pallas_call_site_is_covered():
         src = (root.parent / (mod.replace(".", "/") + ".py")).read_text()
         sites += len(re.findall(r"pl\.pallas_call\(", src))
         named += len(re.findall(r"\*\*kernel_names\(", src))
-    assert sites == named == 30
+    assert sites == named == 27
 
 
 def test_paged_wrappers_keep_their_instruction_names():
