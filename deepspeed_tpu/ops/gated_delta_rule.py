@@ -19,9 +19,26 @@ segment of a ragged batch (``RaggedBatchWrapper.set_alignment``):
   sequence, tiles of one sequence follow each other in position order.  The
   recurrence is computed in its chunked (WY) form, ``chunk`` tokens at a
   time: inside a chunk the ``chunk x chunk`` unit-lower-triangular system is
-  inverted by block doubling (``_tri_inverse``: exact block forward
-  substitution, log2(chunk) levels of two matmuls) and the state enters
-  through three matmuls a chunk.
+  inverted by block doubling (exact block forward substitution, ``-T22 a21
+  T11`` level by level) and the state enters through three matmuls a chunk.
+
+  The kernel does a grid step's ``hb`` heads x ``tile // chunk`` chunks in
+  two phases, every product a 3-D ``dot_general`` over a batch of
+  chunk-heads: a float32 product is six bf16 passes of its left operand's
+  rows through the MXU, and passes of DIFFERENT chunk-heads issued one
+  after another keep all of the chip's MXUs fed where one chunk-head's own
+  chain of products does not (chip, PR 55: 1.85 x a call at the same
+  products).  **Phase A**, state-free, over all chunk-heads of the step:
+  ``[k beta; q] k^T`` (one product), the inverse ``T``.  **Phase B**, the
+  chunks of the tile in order, the ``hb`` heads of a chunk as the batch:
+  ``[k beta exp G; q exp G] S`` (one product), ``v_new = T (v beta - .)``,
+  ``o = . + attn v_new``, ``S = S exp(G_last) + (k exp(G_last - G))^T
+  v_new``.  The inverse multiplies what is live only
+  (:func:`_tri_inverse_live`): the 16-row diagonal blocks side by side as a
+  ``[16, chunk]`` left operand (four levels of two 16-row products, the
+  last of them the merge 16 -> 32), then the merge 32 -> 64 on the lower
+  half's rows; 192 rows pushed a chunk where :func:`_tri_inverse`, which
+  the composition keeps, pushes 768.
 
 Each has a Mosaic kernel (the TPU path; ``interpret=True`` in tests) and an
 XLA composition of the same mathematics (``*_reference``: the path off the
@@ -48,11 +65,6 @@ _HI = jax.lax.Precision.HIGHEST
 
 #: tokens of one chunk of the WY form (the published implementation's)
 CHUNK = 64
-
-
-def _dot(a, b, dims=(((1,), (0,)), ((), ()))):
-    return jax.lax.dot_general(a, b, dims, precision=_HI,
-                               preferred_element_type=F32)
 
 
 def _tri_inverse(a):
@@ -214,6 +226,76 @@ def _gdn_step_call(pool, q, k, v, g, beta, slots, reset, hb: int,
 # --------------------------------------------------------------------- #
 # Mosaic kernel (a): the chunked rule over the tile segment
 # --------------------------------------------------------------------- #
+def _mm(a, b, lhs: int = -1, rhs: int = -2):
+    """``a @ b`` over the last two axes at float32 passes, leading axes
+    batch; ``rhs=-1`` is ``a @ b^T`` and ``lhs=-2`` is ``a^T @ b``."""
+    n = a.ndim
+    batch = tuple(range(n - 2))
+    return jax.lax.dot_general(a, b, (((n + lhs,), (n + rhs,)),
+                                      (batch, batch)), precision=_HI,
+                               preferred_element_type=F32)
+
+
+#: rows of the diagonal blocks :func:`_tri_inverse_live` inverts side by side
+_DIAG = 16
+
+
+def _tri_inverse_live(a):
+    """``(I + a)^-1`` as :func:`_tri_inverse` forms it (block doubling,
+    ``-T22 a21 T11``), multiplying only what is live.  ``a [..., C, C]``
+    strictly lower, C a power of two.
+
+    While the blocks being merged have at most ``_DIAG`` rows the inverse's
+    diagonal blocks of ``_DIAG`` rows are kept SIDE BY SIDE, ``s [..., 16,
+    C]`` with ``s[r, 16 b + c] = T[16 b + r, 16 b + c]``: ``(s m) F``, with
+    ``m`` the level's ``a21`` blocks and ``F`` the block-diagonal form of
+    ``s`` (``s`` stacked and masked), is ``T22 a21 T11`` of every pair of
+    blocks from two products of 16 rows where the full form multiplies 64.
+    Below 16 rows that stays inside ``s`` (``s - (s m) F``); the first
+    level needs no product (``I - m``); the level that merges 16-row blocks
+    lands below the diagonal blocks of the full form.  From 32 rows up a
+    level changes the odd blocks' rows only: those rows alone go through
+    ``(T_odd m) T``."""
+    c = a.shape[-1]
+    d = min(_DIAG, c)
+    i = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+
+    def low(b):                 # where a level of size b has its a21 blocks
+        return ((i // (2 * b)) == (j // (2 * b))) & ((i // b) % 2 == 1) \
+            & ((j // b) % 2 == 0)
+
+    def stacked(s):             # [.., d, C] -> [.., C, C], s in every slab
+        return jnp.concatenate([s] * (c // d), axis=-2)
+
+    def pairs(s, f, b):         # T22 a21 T11 of the level, side by side
+        return _mm(_mm(s, jnp.where(low(b), a, 0.0)), f)
+
+    # level 1, (I - m), folded to the side-by-side form: each slab of d rows
+    # is live in its own d columns only
+    t = (i == j).astype(F32) - jnp.where(low(1), a, 0.0)
+    s = sum(t[..., u:u + d, :] for u in range(0, c, d))
+    diag = (i // d) == (j // d)
+    b = 2
+    while b < d:
+        s = s - pairs(s, jnp.where(diag, stacked(s), 0.0), b)
+        b *= 2
+    t = jnp.where(diag, stacked(s), 0.0)
+    if b < c:
+        t = t - jnp.where(low(b), stacked(pairs(s, t, b)), 0.0)
+        b *= 2
+    while b < c:
+        odd = [t[..., u:u + b, :] for u in range(b, c, 2 * b)]
+        y = _mm(_mm(jnp.concatenate(odd, axis=-2),
+                    jnp.where(low(b), a, 0.0)), t)
+        rows = []
+        for n, u in enumerate(range(0, c, 2 * b)):
+            rows += [t[..., u:u + b, :], odd[n] - y[..., n * b:(n + 1) * b, :]]
+        t = jnp.concatenate(rows, axis=-2)
+        b *= 2
+    return t
+
+
 def _gdn_chunk_kernel(slot_ref, reset_ref, q_ref, k_ref, v_ref, c_ref, d_ref,
                       s_in_ref, o_ref, s_out_ref, *, hb: int, tile: int,
                       chunk: int):
@@ -221,7 +303,15 @@ def _gdn_chunk_kernel(slot_ref, reset_ref, q_ref, k_ref, v_ref, c_ref, d_ref,
     sequence follow each other and map to the same block of the pool, so
     Pallas neither fetches the slot again nor writes it back between them
     — the state is carried in the output block, read from the pool at a
-    sequence's first tile and written once when the slot changes."""
+    sequence's first tile and written once when the slot changes.
+
+    A grid step holds ``hb x (tile // chunk)`` chunk-heads.  Every product
+    is a 3-D ``dot_general`` over a batch of them, so that the MXU passes
+    of one chunk-head follow those of the next and not its own earlier
+    ones.  Phase A, over all chunk-heads of the step (chunk-major): ``[k
+    beta; q] k^T`` in one product and the inverse ``T``.  Phase B, chunk
+    by chunk over the ``hb`` heads: ``[k beta exp G; q exp G] S`` in one
+    product, ``v_new = T (v beta - .)``, ``o``, and the state's update."""
     t = pl.program_id(1)
     first = jnp.logical_or(t == 0,
                            slot_ref[jnp.maximum(t - 1, 0)] != slot_ref[t])
@@ -231,35 +321,35 @@ def _gdn_chunk_kernel(slot_ref, reset_ref, q_ref, k_ref, v_ref, c_ref, d_ref,
         keep = jnp.where(reset_ref[t] != 0, 0.0, 1.0).astype(F32)
         s_out_ref[...] = s_in_ref[...] * keep
 
+    dk, dv = k_ref.shape[-1], v_ref.shape[-1]
     i = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     j = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    nt = (((1,), (1,)), ((), ()))          # a @ b^T
-    tn = (((0,), (0,)), ((), ()))          # a^T @ b
+    spans = [pl.ds(c * chunk, chunk) for c in range(tile // chunk)]
 
-    def head(hd, carry):
-        st = s_out_ref[0, hd]                             # [dk, dv]
-        for c in range(tile // chunk):
-            rows = pl.ds(c * chunk, chunk)
-            qc, kc, vc = q_ref[hd, rows, :], k_ref[hd, rows, :], \
-                v_ref[hd, rows, :]
-            cols = c_ref[hd, rows, :]                     # [C, 8]
-            beta, eg, kd = cols[:, 0:1], cols[:, 1:2], cols[:, 2:3]
-            decay = d_ref[hd, rows, :]                    # [C, C] lower
-            kb = kc * beta
-            tm = _tri_inverse(jnp.where(i > j, _dot(kb, kc, nt) * decay, 0.0))
-            v_new = _dot(tm, vc * beta) - _dot(_dot(tm, kb * eg), st)
-            o_ref[hd, rows, :] = _dot(qc * eg, st) \
-                + _dot(_dot(qc, kc, nt) * decay, v_new)
-            # exp(G_last) as a [1, dv] row (Mosaic does not broadcast a
-            # [1, 1] value both ways at once): the smallest of exp(G), as
-            # G falls along the chunk
-            last = jnp.min(jnp.broadcast_to(eg, (chunk, st.shape[1])),
-                           axis=0, keepdims=True)
-            st = st * last + _dot(kc * kd, v_new, tn)
-        s_out_ref[0, hd] = st
-        return carry
+    def held(ref):              # [hb, tile, w] -> [chunks * hb, C, w]
+        return jnp.concatenate([ref[:, rows, :] for rows in spans], axis=0)
 
-    jax.lax.fori_loop(0, hb, head, 0)
+    k, decay = held(k_ref), held(d_ref)     # decay [., C, C], lower
+    qk = _mm(jnp.concatenate([k * held(c_ref)[..., 0:1], held(q_ref)],
+                             axis=1), k, rhs=-1)
+    tm = _tri_inverse_live(jnp.where(i > j, qk[:, :chunk] * decay, 0.0))
+    attn = qk[:, chunk:] * decay
+
+    for c, rows in enumerate(spans):
+        heads = slice(c * hb, (c + 1) * hb)
+        st = s_out_ref[0]                                 # [hb, dk, dv]
+        k, cols = k_ref[:, rows, :], c_ref[:, rows, :]    # cols [hb, C, 4]
+        beta, eg, kd = cols[..., 0:1], cols[..., 1:2], cols[..., 2:3]
+        u = _mm(jnp.concatenate([k * (beta * eg), q_ref[:, rows, :] * eg],
+                                axis=1), st)              # [hb, 2C, dv]
+        v_new = _mm(tm[heads], v_ref[:, rows, :] * beta - u[:, :chunk])
+        o_ref[:, rows, :] = u[:, chunk:] + _mm(attn[heads], v_new)
+        # exp(G_last), the same in every row of the chunk's column: eight
+        # rows of it across the lanes, stacked to the state's rows (Mosaic
+        # does not broadcast a [1, 1] value both ways at once)
+        last = jnp.concatenate([jnp.broadcast_to(
+            cols[:, 0:8, 3:4], (hb, 8, dv))] * (dk // 8), axis=1)
+        s_out_ref[0] = st * last + _mm(k * kd, v_new, lhs=-2)
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "chunk", "hb",
@@ -270,17 +360,17 @@ def _gdn_chunk_call(pool, q, k, v, g, beta, tile_slot, tile_reset,
     dv = v.shape[-1]
     n, nt = t_rows // chunk, t_rows // tile
     # per-row scalars and the decay mask are elementwise work XLA fuses;
-    # the kernel gets matrices only
-    gc = jnp.cumsum(g.reshape(n, chunk, h), axis=1)           # [n, C, H]
+    # the kernel gets matrices only.  Head-major from the start: the small
+    # [T, H] arrays are transposed, not the lane-padded columns
+    hc = lambda x: jnp.swapaxes(x, 0, 1).reshape(h, n, chunk)
+    gc = jnp.cumsum(hc(g), axis=-1)                           # [H, n, C]
     eg = jnp.exp(gc)
-    kd = jnp.exp(gc[:, -1:, :] - gc)
-    cols = jnp.stack([beta.reshape(n, chunk, h), eg, kd]
-                     + [jnp.zeros_like(eg)] * 5, axis=-1)     # [n, C, H, 8]
-    cols = jnp.moveaxis(cols, 2, 0).reshape(h, t_rows, 8)
-    gh = jnp.moveaxis(gc, 2, 0)                               # [H, n, C]
+    cols = jnp.stack([hc(beta), eg, jnp.exp(gc[..., -1:] - gc),
+                      jnp.broadcast_to(eg[..., -1:], eg.shape)],
+                     axis=-1).reshape(h, t_rows, 4)
     i = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     j = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    decay = jnp.exp(jnp.where(i >= j, gh[..., :, None] - gh[..., None, :],
+    decay = jnp.exp(jnp.where(i >= j, gc[..., :, None] - gc[..., None, :],
                               -jnp.inf)).reshape(h, t_rows, chunk)
     hm = lambda x: jnp.swapaxes(x, 0, 1)                      # head-major
     kernel = functools.partial(_gdn_chunk_kernel, hb=hb, tile=tile,
@@ -296,7 +386,7 @@ def _gdn_chunk_call(pool, q, k, v, g, beta, tile_slot, tile_reset,
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2, grid=(h // hb, nt),
-            in_specs=[rows(dk), rows(dk), rows(dv), rows(8), rows(chunk),
+            in_specs=[rows(dk), rows(dk), rows(dv), rows(4), rows(chunk),
                       pool_spec],
             out_specs=[rows(dv), pool_spec]),
         out_shape=[jax.ShapeDtypeStruct((h, t_rows, dv), F32),
@@ -376,7 +466,8 @@ def _dslint_gdn_step():
 
 @pallas_kernel_case(
     "gdn_chunk", allow=("pallas-uncovered-tile",),
-    note="chunked gated delta rule over the tile segment; the state is "
+    note="chunked gated delta rule over the tile segment, two phases a grid "
+         "step (no scratch: phase A's results are values); the state is "
          "carried in the output block across a sequence's tiles")
 def _dslint_gdn_chunk():
     gdn_chunk(*_dslint_gdn_inputs(512), jnp.asarray([2, 2, 0, 4]),

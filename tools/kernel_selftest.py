@@ -747,6 +747,74 @@ def ssm_case(which: str, layers: int = 26, repeats: int = 10) -> dict:
             "least_us": round(moved / 819e9 * 1e6, 1)}
 
 
+def gdn_chunk_cell_case(call=None, layers: int = 6, repeats: int = 10,
+                        check: bool = True) -> dict:
+    """The chunked delta rule at the Qwen3-Next cell's shape (1,024 rows in
+    8 tiles of 128, 32 value heads, 128 x 128 states, a pool of 33 slots:
+    three sequences of 3 + 3 + 1 tiles, the last 40 rows short, and a pad
+    tile on the scratch slot) against its XLA composition, with
+    microseconds a call (six pools, one a layer as the model has, donated;
+    the call's XLA prework included) beside the least time the chip could
+    take for what the RECURRENCE requires (``benchmark/lib/costs_gdn.py``'s
+    counts: 7 dk dv + 2 dv FLOPs a token and head over the bf16 peak, or
+    each sequence's state read and written once and the rows once over the
+    HBM peak) and that least time's share of the call, per cent.  ``call``
+    (default ``gdn_chunk``) lets a chip script time another form of the
+    kernel on the same inputs."""
+    import time
+
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops import gated_delta_rule as gdr
+
+    rows, h, d, slots, tile, live, seqs = 1024, 32, 128, 32, 128, 856, 3
+    call = call or (lambda *a: gdr.gdn_chunk(*a, interpret=False))
+    ks = jax.random.split(jax.random.fold_in(jax.random.key(0), 23), 6)
+    unit = lambda y: y / jnp.linalg.norm(y, axis=-1, keepdims=True)
+    real = (jnp.arange(rows) < live)[:, None]
+    ops = (unit(jax.random.normal(ks[0], (rows, h, d))) * d ** -0.5,
+           unit(jax.random.normal(ks[1], (rows, h, d)) + 0.3),
+           jax.random.normal(ks[2], (rows, h, d)),
+           jnp.where(real, -0.05 * jnp.abs(
+               jax.random.normal(ks[3], (rows, h))), 0.0),
+           jnp.where(real, jax.nn.sigmoid(
+               jax.random.normal(ks[4], (rows, h))), 0.0),
+           jnp.asarray([9, 9, 9, 20, 20, 20, 4, slots], jnp.int32),
+           jnp.asarray([1, 0, 0, 0, 0, 0, 1, 0], bool))
+    pool = jax.random.normal(ks[5], (slots + 1, h, d, d))
+    out = {}
+    if check:
+        keep = lambda o, p: (o[:live], p[:slots])
+        got = keep(*call(pool, *ops, tile))
+        want = keep(*gdr.gdn_chunk_reference(pool, *ops, tile))
+        scale = max(float(jnp.max(jnp.abs(w))) for w in want)
+        err = max(float(jnp.max(jnp.abs(g - w))) for g, w in zip(got, want))
+        out = {"max_err": round(err / scale, 7), "ok": bool(err / scale < 1e-3)}
+
+    def stacked(pools, *rest):
+        y, new = 0.0, []
+        for p in pools:
+            o, p = call(p, *rest, tile)
+            y, new = y + o, new + [p]
+        return y, new
+
+    run = jax.jit(stacked, donate_argnums=0)
+    pools = [pool + 0.0 for _ in range(layers)]
+    y, pools = run(pools, *ops)
+    y.block_until_ready()
+    t0 = time.perf_counter()
+    for _ in range(repeats):
+        y, pools = run(pools, *ops)
+    y.block_until_ready()
+    us = (time.perf_counter() - t0) / repeats / layers * 1e6
+    flops = live * h * (7 * d * d + 2 * d)
+    moved = (seqs * 2 * h * d * d + live * h * (4 * d + 2)) * 4
+    least = max(flops / 197e12, moved / 819e9) * 1e6
+    return {**out, "us_per_call": round(us, 1), "least_us": round(least, 1),
+            "least_share_pct": round(100 * least / us, 2)}
+
+
 def flash_train_case(b: int, h: int, hkv: int, s: int, d: int, window,
                      layers: int = 4, repeats: int = 10,
                      tol: float = 3e-2) -> dict:
@@ -1221,6 +1289,10 @@ def run_selftest(tol: float = 3e-2) -> dict:
 
     guarded("gdn_step", gdn_step_case)
     guarded("gdn_chunk", gdn_chunk_case)
+    # the chunked rule again at the cell's 1,024 rows and 33 slots, with
+    # microseconds a call beside the recurrence's least time
+    guarded("gdn_chunk_cell", lambda: results.update(
+        {"gdn_chunk_cell": gdn_chunk_cell_case()}))
 
     # ---- selective scan (Mamba): both kernels at the Jamba2-3B cell's
     # shapes against their XLA compositions, float32 throughout ---- #
