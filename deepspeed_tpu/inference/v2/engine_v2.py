@@ -203,8 +203,14 @@ class InferenceEngineV2:
         kv_groups = getattr(model, "kv_groups", None)
         self._stateful = state_spec is not None
         self._grouped = kv_groups is not None
+        #: state slots AND layers with keys and values: every dispatch span
+        #: closes with what the launch asks of both (``hyb_*``)
+        self._hybrid = self._stateful and \
+            len(state_spec["layers"]) < model.num_layers
         #: caches a layer keeps (``kv_passes``): on every dispatch span
         self._passes = int(getattr(model, "kv_passes", 1))
+        #: a dispatch span counts what its launch's rows ask for
+        self._counts_rows = self._passes > 1 or self._hybrid
         #: cached positions a query row of the model reads (its learned
         #: sparse-attention indexer's top-k); None: every one
         self.index_topk: Optional[int] = getattr(model, "index_topk", None)
@@ -316,11 +322,25 @@ class InferenceEngineV2:
         fed)] a sequence) ask of each of them (``loop_seqs``,
         ``loop_tokens``, the context tokens read ``loop_ctx_tokens`` and the
         causal (query, key) pairs ``loop_attn_pairs``; names no other
-        span's counter has: readers sum a counter over a tick's spans)."""
+        span's counter has: readers sum a counter over a tick's spans).
+        A model with state slots beside KV layers adds what the launch
+        asks of both: ``hyb_seqs`` (its live one-token rows),
+        ``hyb_tokens`` (tokens fed), ``hyb_ctx_tokens`` (cached tokens
+        those one-token rows read, their own included), ``hyb_attn_pairs``
+        (causal (query, key) pairs of its chunk rows) and
+        ``hyb_state_seqs`` (sequences whose state it reads and writes)."""
         self.last_launch += 1
         if type(span) is SpanHandle:
             span.attrs = {"launch": self.last_launch,
                           "program": step.__name__}
+            if self._hybrid:
+                span.attrs.update(
+                    hyb_seqs=sum(n == 1 for _, n in rows),
+                    hyb_tokens=sum(n for _, n in rows),
+                    hyb_ctx_tokens=sum(a + 1 for a, n in rows if n == 1),
+                    hyb_attn_pairs=sum(n * (2 * a + n + 1) // 2
+                                       for a, n in rows if n > 1),
+                    hyb_state_seqs=len(rows))
             if self._passes > 1:
                 span.attrs.update(
                     passes=self._passes, cache_layers=self._cache_layers,
@@ -722,7 +742,7 @@ class InferenceEngineV2:
             launch = self._launched(span, step, [
                 (sm.get_sequence(uid).seen_tokens, n) for uid, n in zip(
                     prepared.scheduled, prepared.chunk_sizes)]
-                if self._passes > 1 else ())
+                if self._counts_rows else ())
         sm.kv_cache.update(new_cache)
         for uid, n in zip(prepared.scheduled, prepared.chunk_sizes):
             seq = sm.get_sequence(uid)
@@ -858,7 +878,7 @@ class InferenceEngineV2:
                     state["pos"], tok, *state["slots"])
                 self._launched(span, step,
                                [(seq.seen_tokens, 1) for seq in seqs]
-                               if self._passes > 1 else ())
+                               if self._counts_rows else ())
         except Exception:
             self._recover_donated_cache()
             raise

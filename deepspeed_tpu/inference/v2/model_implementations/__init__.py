@@ -1,6 +1,6 @@
 """Inference v2 model implementations (reference:
 inference/v2/model_implementations/ — llama_v2, opt, mistral, mixtral,
-falcon families; qwen3_next, deepseek_v3 (and glm_moe_dsa through it), longcat_flash, lfm2_moe, afmoe, ouro and jamba have
+falcon families; qwen3_next, deepseek_v3 (and glm_moe_dsa through it), longcat_flash, lfm2_moe, afmoe, ouro, jamba and olmo_hybrid have
 no reference counterpart).  One file a family (config, parameter shapes,
 class) over the shared layers of ``inference/v2/modules/``."""
 
@@ -36,6 +36,10 @@ from deepspeed_tpu.inference.v2.model_implementations.ragged_lfm2 import (
 from deepspeed_tpu.inference.v2.model_implementations.ragged_longcat_flash import (
     LongcatFlashConfig,
     RaggedLongcatFlash,
+)
+from deepspeed_tpu.inference.v2.model_implementations.ragged_olmo_hybrid import (
+    OlmoHybridConfig,
+    RaggedOlmoHybrid,
 )
 from deepspeed_tpu.inference.v2.model_implementations.ragged_ouro import (
     OuroConfig,
@@ -78,6 +82,6 @@ HF_MODELS = {
 }
 
 __all__ = ["AfmoeConfig", "DeepseekV3Config", "HF_MODELS", "RaggedAfmoe",
-           "RaggedDeepseekV3", "JambaConfig", "RaggedJamba", "Lfm2Config", "LongcatFlashConfig", "RaggedLongcatFlash", "OuroConfig", "Qwen3NextConfig", "RaggedLfm2", "RaggedLlama", "RaggedMistral", "RaggedMixtral",
+           "RaggedDeepseekV3", "JambaConfig", "RaggedJamba", "Lfm2Config", "LongcatFlashConfig", "RaggedLongcatFlash", "OlmoHybridConfig", "RaggedOlmoHybrid", "OuroConfig", "Qwen3NextConfig", "RaggedLfm2", "RaggedLlama", "RaggedMistral", "RaggedMixtral",
            "RaggedOPT", "RaggedFalcon", "RaggedOuro", "RaggedQwen3Next",
            "ragged_param_specs", "shard_ragged_params"]

@@ -34,9 +34,10 @@ produce; ``checkpoint/hf_loader.py`` regroups the published interleaving):
 ``in_proj_qkvz`` columns are ``q | k | v | z`` (all heads of q, then of k,
 ...), ``in_proj_ba`` columns ``b | a``, ``conv1d/kernel`` is ``[taps,
 channels]`` over ``q | k | v`` with the LAST tap on the current token.
-Device scopes under ``layers_<i>``: ``gdn/in_proj`` (norm and both
-projections), ``gdn/conv``, ``gdn/rule``, ``gdn/out`` (gated norm and
-``out_proj``); ``attn/*`` as RaggedLlama; ``moe/router``, ``moe/dispatch``,
+The mixer is ``modules/gdn.py::gdn_mixer`` (Olmo-Hybrid's too); the norm
+before it is this file's.  Device scopes under ``layers_<i>``:
+``gdn/in_proj`` (norm and both projections), ``gdn/conv``, ``gdn/rule``,
+``gdn/out`` (gated norm and ``out_proj``); ``attn/*`` as RaggedLlama; ``moe/router``, ``moe/dispatch``,
 ``moe/experts``, ``moe/combine``, ``moe/shared``.
 """
 
@@ -53,13 +54,14 @@ from deepspeed_tpu.inference.v2.modules.attention import (
     _rotary,
     ragged_attention_block,
 )
-from deepspeed_tpu.inference.v2.modules.conv import _causal_conv, _silu
+from deepspeed_tpu.inference.v2.modules.gdn import (
+    gdn_conv_dim,
+    gdn_mixer,
+    gdn_param_shapes,
+    gdn_state_leaves,
+)
 from deepspeed_tpu.inference.v2.modules.moe import dropless_moe
 from deepspeed_tpu.inference.v2.ragged.kv_cache import CacheLayoutError
-from deepspeed_tpu.ops.gated_delta_rule import gdn_chunk, gdn_step
-from deepspeed_tpu.ops.quantized_matmul import qmm
-
-F32 = jnp.float32
 
 
 @dataclasses.dataclass
@@ -102,16 +104,13 @@ class Qwen3NextConfig:
 
     @property
     def conv_dim(self) -> int:
-        return 2 * self.linear_num_key_heads * self.linear_key_head_dim \
-            + self.linear_num_value_heads * self.linear_value_head_dim
+        return gdn_conv_dim(self)
 
 
 def param_shapes(cfg: Qwen3NextConfig) -> Dict[str, Any]:
     """The parameter tree :class:`RaggedQwen3Next` reads, as shapes (every
     matrix stored [in, out])."""
     dt, h = cfg.dtype, cfg.hidden_size
-    hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
-    dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
     hq, hkv, d = cfg.num_attention_heads, cfg.num_key_value_heads, \
         cfg.head_dim
     e = cfg.held_experts or cfg.num_experts
@@ -124,14 +123,8 @@ def param_shapes(cfg: Qwen3NextConfig) -> Dict[str, Any]:
             "q_proj": kern(h, 2 * hq * d), "k_proj": kern(h, hkv * d),
             "v_proj": kern(h, hkv * d), "o_proj": kern(hq * d, h),
             "q_norm": {"scale": sds(d)}, "k_norm": {"scale": sds(d)}}} \
-            if cfg.is_attention(i) else {"linear_attn": {
-                "in_proj_qkvz": kern(h, cfg.conv_dim + hv * dv),
-                "in_proj_ba": kern(h, 2 * hv),
-                "conv1d": {"kernel": sds(cfg.linear_conv_kernel_dim,
-                                         cfg.conv_dim)},
-                "A_log": sds(hv), "dt_bias": sds(hv),
-                "norm": {"scale": sds(dv)},
-                "out_proj": kern(hv * dv, h)}}
+            if cfg.is_attention(i) else {
+                "linear_attn": gdn_param_shapes(cfg, sds)}
         return {
             "input_layernorm": {"scale": sds(h)},
             "post_attention_layernorm": {"scale": sds(h)},
@@ -186,12 +179,7 @@ class RaggedQwen3Next:
         return {
             "layers": [i for i in range(cfg.num_hidden_layers)
                        if not cfg.is_attention(i)],
-            "leaves": {
-                "state": ((cfg.linear_num_value_heads,
-                           cfg.linear_key_head_dim,
-                           cfg.linear_value_head_dim), F32),
-                "conv": ((cfg.linear_conv_kernel_dim - 1, cfg.conv_dim),
-                         cfg.dtype)}}
+            "leaves": gdn_state_leaves(cfg)}
 
     def __call__(self, params: Dict[str, Any], cache: Dict[str, Any],
                  batch: Dict[str, jax.Array], prefill_tile=None,
@@ -225,8 +213,12 @@ class RaggedQwen3Next:
                         self.block_size, cfg, h, hkv, d, cos, sin,
                         prefill_tile=prefill_tile, decode_mode=decode)
                 else:
-                    out, new_cache[f"layer_{i}"] = self._gdn(
-                        lp, x, cache[f"layer_{i}"], batch, prefill_tile)
+                    with jax.named_scope("gdn/in_proj"):
+                        xn = _rms_norm_1p(x, lp["input_layernorm"]["scale"],
+                                          cfg.rms_norm_eps)
+                    out, new_cache[f"layer_{i}"] = gdn_mixer(
+                        lp["linear_attn"], xn, cache[f"layer_{i}"], batch,
+                        prefill_tile, cfg, interpret=self.interpret)
                 x = x + out
                 with jax.named_scope("moe/router"):
                     xm = _rms_norm_1p(
@@ -241,67 +233,3 @@ class RaggedQwen3Next:
             x = x[batch["logits_idx"]]
             logits = x @ params["lm_head"]["kernel"].astype(dt)
         return logits, new_cache
-
-    def _gdn(self, lp, x, layer_cache, batch, prefill_tile):
-        """One Gated DeltaNet mixer over the flat token buffer.  Returns
-        ``(out [T, hidden], {"state", "conv"})``."""
-        cfg, la, dt = self.config, lp["linear_attn"], self.config.dtype
-        hk, hv = cfg.linear_num_key_heads, cfg.linear_num_value_heads
-        dk, dv = cfg.linear_key_head_dim, cfg.linear_value_head_dim
-        pool = layer_cache["state"]
-        scratch = pool.shape[0] - 1
-        pos, sslot = batch["token_pos"], batch["state_slot"]
-        t_rows, s_rows = x.shape[0], sslot.shape[0]
-        with jax.named_scope("gdn/in_proj"):
-            xn = _rms_norm_1p(x, lp["input_layernorm"]["scale"],
-                              cfg.rms_norm_eps)
-            qkvz = qmm(xn, la["in_proj_qkvz"]["kernel"], dt)
-            ba = qmm(xn, la["in_proj_ba"]["kernel"], dt)
-            u, z = qkvz[:, :cfg.conv_dim], qkvz[:, cfg.conv_dim:]
-        with jax.named_scope("gdn/conv"):
-            u, conv = _causal_conv(u, la["conv1d"]["kernel"],
-                                   layer_cache["conv"], batch)
-        with jax.named_scope("gdn/rule"):
-            u32 = u.astype(F32)
-
-            def unit(y):            # L2 norm per head, as published
-                return y * jax.lax.rsqrt(
-                    jnp.sum(y * y, axis=-1, keepdims=True) + 1e-6)
-
-            q = unit(u32[:, :hk * dk].reshape(t_rows, hk, dk)) * dk ** -0.5
-            k = unit(u32[:, hk * dk:2 * hk * dk].reshape(t_rows, hk, dk))
-            v = u32[:, 2 * hk * dk:].reshape(t_rows, hv, dv)
-            # each key head serves hv // hk value heads
-            q = jnp.repeat(q, hv // hk, axis=1)
-            k = jnp.repeat(k, hv // hk, axis=1)
-            real = (pos >= 0)[:, None]
-            beta = jnp.where(real, jax.nn.sigmoid(ba[:, :hv].astype(F32)), 0.)
-            g = jnp.where(real, -jnp.exp(la["A_log"].astype(F32))
-                          * jax.nn.softplus(ba[:, hv:].astype(F32)
-                                            + la["dt_bias"].astype(F32)), 0.)
-            rows = slice(0, s_rows)             # one token a row
-            row_slot = jnp.where(pos[rows] >= 0,
-                                 sslot[batch["token_slot"][rows]], scratch)
-            o, pool = gdn_step(pool, q[rows], k[rows], v[rows], g[rows],
-                               beta[rows], row_slot, pos[rows] == 0,
-                               interpret=self.interpret)
-            if t_rows > s_rows:                 # the tile segment
-                rows = slice(s_rows, t_rows)
-                first = slice(s_rows, t_rows, int(prefill_tile))
-                tile_slot = jnp.where(pos[first] >= 0,
-                                      sslot[batch["token_slot"][first]],
-                                      scratch)
-                o2, pool = gdn_chunk(pool, q[rows], k[rows], v[rows],
-                                     g[rows], beta[rows], tile_slot,
-                                     pos[first] == 0, int(prefill_tile),
-                                     interpret=self.interpret)
-                o = jnp.concatenate([o, o2])
-        with jax.named_scope("gdn/out"):
-            # RMSNorm per head with a plain weight, gated by silu(z)
-            o = o * jax.lax.rsqrt(jnp.mean(o * o, axis=-1, keepdims=True)
-                                  + cfg.rms_norm_eps)
-            o = o * la["norm"]["scale"].astype(F32) \
-                * _silu(z.astype(F32).reshape(t_rows, hv, dv))
-            out = qmm(o.astype(dt).reshape(t_rows, hv * dv),
-                      la["out_proj"]["kernel"], dt)
-        return out, {"state": pool, "conv": conv}

@@ -43,6 +43,23 @@ STATE_SLOTS = ("keeps per-sequence recurrent state (state_spec)", {
 })
 
 
+#: lanes of the chip's tiles: an array's minor dimension is stored in
+#: whole tiles of this many values
+LANES = 128
+
+
+def slot_bytes(shape: Sequence[int], dtype) -> int:
+    """HBM bytes ONE slot of a leaf holds: its values with the minor
+    dimension rounded up to whole lane tiles, as the chip stores it (a
+    float32 ``[30, 96, 192]`` state holds 256 lanes a row: a third more
+    than its 2,211,840 B; every leaf whose rows are whole tiles counts as
+    its values).  The few rows a pool's slot axis is padded by are not in
+    it."""
+    *rows, lanes = shape
+    return int(np.prod(rows, dtype=np.int64)) * -(-lanes // LANES) * LANES \
+        * jnp.dtype(dtype).itemsize
+
+
 class StateSlotPool:
     def __init__(self, num_slots: int, layers: Sequence[int],
                  leaves: Dict[str, Tuple[Tuple[int, ...], Any]]):
@@ -84,10 +101,10 @@ class StateSlotPool:
     @property
     def per_sequence_bytes(self) -> int:
         """HBM bytes one live sequence holds across every stateful layer,
-        whatever its length (the KV pool's ``per_token_bytes`` is apart)."""
+        whatever its length (the KV pool's ``per_token_bytes`` is apart):
+        what the chip holds, lane padding included (:func:`slot_bytes`)."""
         return len(self.layers) * sum(
-            int(np.prod(shape)) * jnp.dtype(dtype).itemsize
-            for shape, dtype in self.leaves.values())
+            slot_bytes(shape, dtype) for shape, dtype in self.leaves.values())
 
     @property
     def held_bytes(self) -> int:

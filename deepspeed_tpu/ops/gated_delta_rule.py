@@ -4,7 +4,10 @@ layer over a pool of per-sequence state slots.
 Per value head the layer keeps a matrix ``S [dk, dv]`` (float32) for every
 live sequence.  One token ``t`` with query ``q_t`` and key ``k_t`` (both
 L2-normalised, ``q`` scaled), value ``v_t``, log-decay ``g_t <= 0`` and
-write strength ``beta_t`` in (0, 1) does::
+write strength ``beta_t`` in (0, 2) does (``sigmoid(b)`` in (0, 1) as
+Qwen3-Next has it; ``2 sigmoid(b)`` where a model allows negative
+eigenvalues, Olmo-Hybrid's ``linear_allow_neg_eigval``: along ``k`` the
+state's transition ``I - beta k k^T`` then reaches -1)::
 
     S *= exp(g_t);  d = (v_t - S^T k_t) * beta_t;  S += k_t d^T;  o_t = S^T q_t
 
@@ -46,6 +49,22 @@ TPU and the parity oracle, as ``gmm_reference`` is for the grouped GEMM).
 Pad rows carry ``g = 0`` and ``beta = 0`` (the caller masks them), which
 leaves a state exactly as it was; ``reset`` zeroes a slot before its first
 token (a sequence whose first position is 0).  Everything is float32.
+
+**Shapes the kernels have run at on a chip** (v5e): 32 heads of ``dk = dv =
+128`` (Qwen3-Next, since PR 29; PR 55's chunk kernel) and 30 heads of ``dk =
+96``, ``dv = 192`` (Olmo-Hybrid, PR 56), both through the one ``gdn_step``
+and the one ``gdn_chunk``.  A head group (``_head_block``) is the largest
+divisor of H up to 8 (step) or 4 (chunk): 8 and 4 of 32 heads, 6 and 3 of
+30; the step kernel's row blocks hold a group as whole trailing axes of 4-D
+arrays, so a group need be no multiple of 8.  With ``beta`` up to 2 the
+chunk form's unit-lower system has off-diagonal entries up to 2 in
+magnitude; block doubling holds a float64 solve to rounding there
+(``tests/unit/test_gdn_chunk_kernel.py``).  A ``dv`` of 192 is one and a
+half lane tiles: the chip stores each float32 state row as 256 lanes, a
+third more bytes in HBM and in every decode step's read and write than the
+mathematics has (PERF.md, PR 56: two heads side by side on the lanes,
+``[H / 2, dk, 2 dv]``, read 885 us a 128-row call against 1,179; the
+chunk kernel on that layout is the open half).
 """
 
 from __future__ import annotations
@@ -178,13 +197,13 @@ def _gdn_step_kernel(slot_ref, reset_ref, qt_ref, kt_ref, v_ref, a_ref,
         s0 = s_in_ref[0, j] * keep                        # [dk, dv]
         qc = qt_ref[0, 0, :, j:j + 1]                     # [dk, 1]
         kc = kt_ref[0, 0, :, j:j + 1]
-        a = a_ref[0, j:j + 1, :]                          # [1, dv]
+        a = a_ref[0, 0, j:j + 1, :]                       # [1, dv]
         ks = jnp.sum(kc * s0, axis=0, keepdims=True)      # [1, dv]
         qs = jnp.sum(qc * s0, axis=0, keepdims=True)
         qk = jnp.sum(qc * kc, axis=0, keepdims=True)      # [1, 1]
-        d = b_ref[0, j:j + 1, :] * (v_ref[0, j:j + 1, :] - a * ks)
+        d = b_ref[0, 0, j:j + 1, :] * (v_ref[0, 0, j:j + 1, :] - a * ks)
         s_out_ref[0, j] = a * s0 + kc * d
-        o_ref[0, j:j + 1, :] = a * qs + qk * d
+        o_ref[0, 0, j:j + 1, :] = a * qs + qk * d
 
 
 @functools.partial(jax.jit, static_argnames=("hb", "interpret"))
@@ -197,11 +216,16 @@ def _gdn_step_call(pool, q, k, v, g, beta, slots, reset, hb: int,
     def cols(x):                    # [S, H, dk] -> [S, hg, dk, hb]
         return jnp.swapaxes(x.reshape(s, hg, hb, dk), 2, 3)
 
-    a_row = jnp.broadcast_to(jnp.exp(g)[..., None], (s, h, dv))
-    b_row = jnp.broadcast_to(beta[..., None], (s, h, dv))
+    def rows(x):                    # [S, H] or [S, H, dv] -> [S, hg, hb, dv]
+        if x.ndim == 2:
+            x = jnp.broadcast_to(x[..., None], (s, h, dv))
+        return x.reshape(s, hg, hb, dv)
+
     kernel = functools.partial(_gdn_step_kernel, hb=hb)
+    # a head group's rows and columns are whole trailing axes of their
+    # arrays, so ``hb`` is any divisor of H (30 heads: no multiple of 8)
     col_spec = pl.BlockSpec((1, 1, dk, hb), lambda i, j, sl, rs: (i, j, 0, 0))
-    row_spec = pl.BlockSpec((1, hb, dv), lambda i, j, sl, rs: (i, j, 0))
+    row_spec = pl.BlockSpec((1, 1, hb, dv), lambda i, j, sl, rs: (i, j, 0, 0))
     pool_spec = pl.BlockSpec((1, hb, dk, dv),
                              lambda i, j, sl, rs: (sl[i], j, 0, 0))
     o, pool = pl.pallas_call(
@@ -211,7 +235,7 @@ def _gdn_step_call(pool, q, k, v, g, beta, slots, reset, hb: int,
             in_specs=[col_spec, col_spec, row_spec, row_spec, row_spec,
                       pool_spec],
             out_specs=[row_spec, pool_spec]),
-        out_shape=[jax.ShapeDtypeStruct((s, h, dv), F32),
+        out_shape=[jax.ShapeDtypeStruct((s, hg, hb, dv), F32),
                    jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
         # the pool is updated in place: operand 7 (after the two scalar
         # vectors and five row operands) is output 1
@@ -219,8 +243,8 @@ def _gdn_step_call(pool, q, k, v, g, beta, slots, reset, hb: int,
         interpret=interpret,
         **kernel_names(kernel),
     )(slots.astype(jnp.int32), reset.astype(jnp.int32), cols(q), cols(k),
-      v, a_row, b_row, pool)
-    return o, pool
+      rows(v), rows(jnp.exp(g)), rows(beta), pool)
+    return o.reshape(s, h, dv), pool
 
 
 # --------------------------------------------------------------------- #
@@ -403,7 +427,9 @@ def _gdn_chunk_call(pool, q, k, v, g, beta, tile_slot, tile_reset,
 # Public entries
 # --------------------------------------------------------------------- #
 def _head_block(h: int, want: int) -> int:
-    return want if h % want == 0 else h
+    """Heads a grid step holds: the largest divisor of ``h`` up to
+    ``want`` (32 heads: 8 and 4; 30 heads: 6 and 3)."""
+    return max(b for b in range(1, want + 1) if h % b == 0)
 
 
 def _kernel_mode(interpret: Optional[bool]):
@@ -445,14 +471,15 @@ def gdn_chunk(pool, q, k, v, g, beta, tile_slot, tile_reset, tile: int,
 from deepspeed_tpu.analysis.registry import pallas_kernel_case  # noqa: E402
 
 
-def _dslint_gdn_inputs(rows: int):
+def _dslint_gdn_inputs(rows: int, h: int = 8, dk: int = 128, dv: int = 128,
+                       beta_scale: float = 1.0):
     import numpy as np
 
     rng = np.random.default_rng(2)
     f = lambda *shape: jnp.asarray(rng.standard_normal(shape), F32)
-    return (f(5, 8, 128, 128), f(rows, 8, 128), f(rows, 8, 128),
-            f(rows, 8, 128), -jnp.abs(f(rows, 8)),
-            jax.nn.sigmoid(f(rows, 8)))
+    return (f(5, h, dk, dv), f(rows, h, dk), f(rows, h, dk),
+            f(rows, h, dv), -jnp.abs(f(rows, h)),
+            beta_scale * jax.nn.sigmoid(f(rows, h)))
 
 
 @pallas_kernel_case(
@@ -472,3 +499,24 @@ def _dslint_gdn_step():
 def _dslint_gdn_chunk():
     gdn_chunk(*_dslint_gdn_inputs(512), jnp.asarray([2, 2, 0, 4]),
               jnp.asarray([True, False, False, False]), 128, interpret=True)
+
+
+@pallas_kernel_case(
+    "gdn_step_h6_96x192", allow=("pallas-uncovered-tile",),
+    note="the decode update at Olmo-Hybrid's shape class: a head group of "
+         "6 (no multiple of 8) as whole trailing axes of 4-D row blocks, 96 "
+         "keys x 192 values (one and a half lane tiles), beta in (0, 2)")
+def _dslint_gdn_step_olmo():
+    gdn_step(*_dslint_gdn_inputs(8, 6, 96, 192, 2.0),
+             jnp.asarray([1, 0, 4, 3, 4, 4, 2, 4]), jnp.zeros((8,), bool),
+             interpret=True)
+
+
+@pallas_kernel_case(
+    "gdn_chunk_h6_96x192", allow=("pallas-uncovered-tile",),
+    note="the chunked rule at Olmo-Hybrid's shape class: head groups of 3, "
+         "a contraction over 96 keys, states of 96 x 192, beta in (0, 2)")
+def _dslint_gdn_chunk_olmo():
+    gdn_chunk(*_dslint_gdn_inputs(256, 6, 96, 192, 2.0),
+              jnp.asarray([2, 0]), jnp.asarray([True, False]), 128,
+              interpret=True)

@@ -135,7 +135,10 @@ def test_head_blocks_and_tiles(tile, h, hb):
                                         tile), real, slice(0, 5))
 
 
-def test_public_entry_takes_every_head_when_the_block_does_not_divide():
+def test_public_entry_takes_a_divisor_of_the_heads_when_the_block_does_not_divide():
+    assert [gdr._head_block(*a) for a in ((32, 8), (32, 4), (30, 8), (30, 4),
+                                          (6, 4), (7, 4))] == \
+        [8, 4, 6, 3, 3, 1]
     tile, rows = 64, 128
     pool, q, k, v, g, beta = _inputs(rows, 6, seed=9)
     args = (pool, q, k, v, g, beta, jnp.asarray([2, 2], jnp.int32),
@@ -201,6 +204,121 @@ def test_live_inverse_multiplies_a_quarter_of_the_rows(monkeypatch):
     assert rows == {"live": 8 * 16 + 2 * 32, "full": 0}
     gdr._tri_inverse(a)
     assert rows["full"] == 12 * 64
+
+
+# ------------------------------------------------------------------ #
+# (g) Olmo-Hybrid's shape class: heads that are no multiple of 4 or 8,
+# dk != dv, neither a multiple of 128, write strengths in (0, 2)
+# ------------------------------------------------------------------ #
+OLMO_SHAPES = [(30, 96, 192), (6, 24, 48)]
+
+
+def _strong(beta, rng, share=0.3):
+    """Write strengths in (0, 2) with ``share`` of them above 1.9."""
+    beta = 2.0 * np.asarray(beta)
+    high = rng.random(beta.shape) < share
+    return jnp.asarray(np.where(high, rng.uniform(1.9, 2.0, beta.shape),
+                                beta), jnp.float32)
+
+
+@pytest.mark.parametrize("h,dk,dv", OLMO_SHAPES, ids=["published", "small"])
+def test_step_kernel_at_olmo_hybrids_shape_class(h, dk, dv):
+    """One token a row at write strengths up to 2: the kernel against the
+    composition, and every slot no row names (the scratch slot too, which
+    the pad row writes ``g = 0``, ``beta = 0`` to) bit-equal before and
+    after."""
+    rows, slots = 5, 6
+    pool, q, k, v, g, beta = _inputs(rows, h, dk, dv, slots=slots, seed=h)
+    real = np.asarray([1, 1, 1, 1, 0], bool)
+    beta = _strong(beta, np.random.default_rng(h))
+    g, beta = _masked(g, beta, real)
+    where = (jnp.asarray([4, 0, 2, 5, slots], jnp.int32),
+             jnp.asarray([0, 1, 0, 0, 0], bool))
+    got = gdr.gdn_step(pool, q, k, v, g, beta, *where, interpret=True)
+    _agree(got, gdr.gdn_step_reference(pool, q, k, v, g, beta, *where), real)
+    before, after = np.asarray(pool), np.asarray(got[1])
+    assert np.array_equal(before[[1, 3, slots]], after[[1, 3, slots]])
+    assert not np.array_equal(before[4], after[4])
+
+
+@pytest.mark.parametrize("h,dk,dv", OLMO_SHAPES, ids=["published", "small"])
+def test_chunk_kernel_at_olmo_hybrids_shape_class(h, dk, dv):
+    """The tile segment at write strengths up to 2 over 64-row chunks: a
+    sequence over two tiles, one from a reset, a pad tile on the scratch
+    slot; untouched slots and the scratch slot bit-equal before and
+    after."""
+    tile, slots = 64, 5
+    slot, reset = [3, 3, 1, slots], [0, 0, 1, 0]
+    rows = tile * len(slot)
+    pool, q, k, v, g, beta = _inputs(rows, h, dk, dv, slots=slots, seed=dk)
+    real = np.ones(rows, bool)
+    real[3 * tile - 9:] = False         # a short tile, then the pad tile
+    beta = _strong(beta, np.random.default_rng(dk))
+    g, beta = _masked(g, beta, real)
+    args = (pool, q, k, v, g, beta, jnp.asarray(slot, jnp.int32),
+            jnp.asarray(reset, bool), tile)
+    got = gdr.gdn_chunk(*args, interpret=True)
+    _agree(got, gdr.gdn_chunk_reference(*args), real, slice(0, slots))
+    before, after = np.asarray(pool), np.asarray(got[1])
+    assert np.array_equal(before[[0, 2, 4, slots]], after[[0, 2, 4, slots]])
+    # and token by token, what the chunked form has to equal
+    o, p = [], pool
+    for t in range(3 * tile - 9):
+        first = t % tile == 0 and bool(reset[t // tile])
+        ot, p = gdr.gdn_step_reference(
+            p, q[t:t + 1], k[t:t + 1], v[t:t + 1], g[t:t + 1],
+            beta[t:t + 1], jnp.asarray([slot[t // tile]], jnp.int32),
+            jnp.asarray([first]))
+        o.append(ot)
+    _agree((got[0][:3 * tile - 9], got[1]), (jnp.concatenate(o), p),
+           slots=slice(0, slots))
+
+
+@pytest.mark.parametrize("share", [0.0, 0.3, 1.0],
+                         ids=["to_2", "a_third_above_1.9", "all_above_1.9"])
+def test_live_inverse_at_write_strengths_up_to_two(share):
+    """``beta`` in (0, 2): the unit-lower system's off-diagonal entries
+    reach 2 in magnitude and the inverse's are larger.  Block doubling is
+    exact in exact arithmetic; in float32 it holds a float64 solve to
+    rounding over a 64-row chunk, at the worst share of strong writes."""
+    rng = np.random.default_rng(56)
+    c = 64
+    k = rng.standard_normal((5, c, 96)) + 0.5
+    k /= np.linalg.norm(k, axis=-1, keepdims=True)
+    beta = np.asarray(_strong(
+        1 / (1 + np.exp(-rng.standard_normal((5, c, 1)))), rng, share))
+    gc = np.cumsum(-0.002 * np.abs(rng.standard_normal((5, c))), -1)
+    decay = np.exp(gc[..., :, None] - gc[..., None, :])
+    a = np.tril(beta * (k @ np.swapaxes(k, -1, -2)) * decay, -1)
+    want = np.stack([np.linalg.solve(np.eye(c) + m, np.eye(c)) for m in a])
+    got = np.asarray(gdr._tri_inverse_live(jnp.asarray(a, jnp.float32)))
+    scale = np.max(np.abs(want))
+    assert np.max(np.abs(got - want)) <= 2e-6 * max(scale, 1.0), scale
+
+
+def test_olmo_hybrids_calls_lower_for_the_tpu_under_the_kernels_names():
+    """128 one-token rows and 1,024 tile rows at 30 heads of 96 x 192 over
+    129 slots: one ``pallas_call`` each, under the names the per-layer
+    readers match, the pool aliased in and out."""
+    s = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+    ints = lambda n, dt=jnp.int32: jax.ShapeDtypeStruct((n,), dt)
+    step = jax.jit(lambda *a: gdr._gdn_step_call(
+        *a, hb=gdr._head_block(30, 8), interpret=False)).trace(
+        s(129, 30, 96, 192), s(128, 30, 96), s(128, 30, 96),
+        s(128, 30, 192), s(128, 30), s(128, 30), ints(128),
+        ints(128, jnp.bool_)).lower(lowering_platforms=("tpu",)).as_text()
+    chunk = jax.jit(lambda *a: gdr._gdn_chunk_call(
+        *a, tile=128, chunk=64, hb=gdr._head_block(30, 4),
+        interpret=False)).trace(
+        s(129, 30, 96, 192), s(1024, 30, 96), s(1024, 30, 96),
+        s(1024, 30, 192), s(1024, 30), s(1024, 30), ints(8),
+        ints(8, jnp.bool_)).lower(lowering_platforms=("tpu",)).as_text()
+    for text, name in ((step, "_gdn_step_kernel"),
+                       (chunk, "_gdn_chunk_kernel")):
+        assert text.count("tpu_custom_call") == 1
+        assert f'kernel_name = "{name}"' in text
+        assert "output_operand_alias<output_tuple_indices = [1], " \
+            "operand_index = 7" in text
 
 
 def test_the_cells_call_lowers_for_the_tpu_under_the_kernels_name():

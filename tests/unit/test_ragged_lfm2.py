@@ -607,8 +607,10 @@ def test_bytes_a_token_and_a_sequence_hold():
     assert sm.kv_cache.kv_layers == (2,)
     assert sm.kv_cache.per_token_bytes == 1 * 2 * 2 * 16 * 2
     # the five convolution layers' tails alone: 2 rows x 64 channels x 2 B
+    # as the chip holds them, a whole lane tile a row (``slot_bytes``; the
+    # published 2,048 channels are whole tiles: the next test)
     assert sm.state_pool.layers == (0, 1, 3, 4, 5)
-    assert sm.state_pool.per_sequence_bytes == 5 * 2 * 64 * 2
+    assert sm.state_pool.per_sequence_bytes == 5 * 2 * 128 * 2
     cache = sm.kv_cache.cache
     # 2 heads of 16 are no whole lane tile: the cache's rule (``flat_row``)
     # keeps heads apart; the published 8 x 64 is stored flat (the next test)
