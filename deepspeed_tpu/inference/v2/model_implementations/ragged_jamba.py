@@ -31,9 +31,11 @@ call signature and slot pool this model shares:
   softplus and ``dt * x`` (scope ``mamba/x_proj``), ``+ D x`` and ``*
   silu(z)`` (``mamba/out``).
 * **The convolution has a bias** (``mamba_conv_bias``):
-  ``modules/conv.py::_causal_conv(..., bias=)`` for the tile segment's
-  chunks; the one-token rows, 256 of them in a decode step, read and write
-  their slots' tails by one-hot matmuls (``_conv``).
+  ``modules/conv.py::_causal_conv(..., bias=)``, the function the two
+  other stateful families call: the one-token rows, 256 of them in a
+  decode step, read and write their slots' tails by one-hot matmuls there
+  (the form this family met first, its own ``_conv`` until PR 57), the
+  tile segment goes through the chunk form an entry a tile.
 * **Attention without positions**: ``ragged_attention_block`` with ``cos =
   sin = None`` (Trinity's global layers do the same), 20 query heads on ONE
   KV head of 128: the pool row is one lane tile, stored flat.
@@ -198,9 +200,7 @@ class RaggedJamba:
         """The per-sequence state the engine's slot pool holds: each Mamba
         layer's scan state (float32, the channels on the lanes) and the
         tail of its convolution (its ``K - 1`` rows of ``Di`` back to
-        back in ONE row a slot: a ``[slots, K - 1, Di]`` pool is a tile of
-        three sublanes a slot, which XLA re-tiles whole around every
-        gather and scatter)."""
+        back in ONE row a slot, the layout of ``modules/conv.py``)."""
         cfg = self.config
         return {
             "layers": [i for i in range(cfg.num_hidden_layers)
@@ -262,86 +262,6 @@ class RaggedJamba:
                 logits = x @ params["lm_head"]["kernel"].astype(dt)
         return logits, new_cache
 
-    def _conv(self, cv, u, pool, batch, prefill_tile):
-        """The causal convolution with its bias under SiLU over a
-        two-segment batch, the tails in a flat pool ``[slots + 1, (K - 1)
-        Di]``.  Returns ``(x [T, Di], new pool)``.
-
-        The one-token rows (a decode step: every row) are their own
-        chunks: a row's ``K - 1`` earlier inputs are its slot's tail, so
-        the segment is elementwise but for reading and writing ``S`` slots
-        of a pool in another order.  Both are one-hot matmuls here (exact:
-        one term a row, float32 accumulation), a gather and a scatter of
-        ``S`` rows being ``S`` serial updates on the chip:
-        ``_causal_conv``'s own scatter of the tails' contributions and
-        gathers of the new tails, sized for a handful of chunks, took half
-        of a 256-row decode tick.  The tile segment holds few chunks (one
-        a tile at the most): it goes through ``_causal_conv`` as it is,
-        on a batch of one entry a tile (a chunk's entry is its first
-        tile's; the other tiles' entries are empty) and a pool of those
-        entries' tails."""
-        cfg = self.config
-        taps, di, dt = cfg.mamba_d_conv, cfg.d_inner, u.dtype
-        pos, sslot, start = batch["token_pos"], batch["state_slot"], \
-            batch["chunk_start"]
-        t_rows, s_rows, slots = u.shape[0], sslot.shape[0], pool.shape[0]
-        scratch = slots - 1
-        exact = jax.lax.Precision.HIGHEST if pool.dtype == F32 else None
-        w32 = cv["kernel"].astype(F32)
-        bias = cv.get("bias")
-
-        def read(hot):          # [R, slots] one-hot -> the slots' tails
-            return jnp.dot(hot.astype(pool.dtype), pool, precision=exact,
-                           preferred_element_type=F32)
-
-        def write(hot, tails):  # what the slots named by ``hot`` now hold
-            return jnp.dot(hot.T.astype(pool.dtype),
-                           tails.astype(pool.dtype), precision=exact,
-                           preferred_element_type=F32)
-
-        lanes = jnp.arange(slots, dtype=jnp.int32)[None, :]
-        rows = slice(0, s_rows)
-        row_slot = jnp.where(pos[rows] >= 0,
-                             sslot[batch["token_slot"][rows]], scratch)
-        hot = row_slot[:, None] == lanes
-        # (the tails stay rows of (K - 1) Di lanes: a tap is a lane slice)
-        tail = read(hot) * jnp.where(pos[rows] == 0, 0.0, 1.0)[:, None]
-        u32 = u[rows].astype(F32)
-        acc = u32 * w32[taps - 1] + sum(
-            w32[j] * tail[:, j * di:(j + 1) * di] for j in range(taps - 1))
-        if bias is not None:
-            acc = acc + bias.astype(F32)
-        x = _silu(acc).astype(dt)
-        hit = jnp.any(hot, axis=0)
-        new = write(hot, jnp.concatenate([tail[:, di:], u32], axis=1))
-        if t_rows > s_rows:
-            tile = int(prefill_tile)
-            nt = (t_rows - s_rows) // tile
-            first = s_rows + jnp.arange(nt, dtype=jnp.int32) * tile
-            slot_b = batch["token_slot"][first]     # each tile's batch slot
-            real = pos[first] >= 0
-            # tiles before this one in its chunk; a chunk's first tile is
-            # its entry, every other tile's entry is empty (scratch, n = 0)
-            back = jnp.where(real, (first - start[slot_b]) // tile, 0)
-            head = real & (back == 0)
-            ar = jnp.arange(nt, dtype=jnp.int32)
-            n = jnp.where(head, batch["logits_idx"][slot_b]
-                          - start[slot_b] + 1, 0)
-            hot = jnp.where(head, sslot[slot_b], scratch)[:, None] == lanes
-            local = jnp.concatenate([
-                read(hot).astype(pool.dtype).reshape(nt, taps - 1, di),
-                jnp.zeros((1, taps - 1, di), pool.dtype)])
-            x2, local = _causal_conv(u[s_rows:], cv["kernel"], local, {
-                "chunk_start": ar * tile,
-                "state_slot": jnp.where(head, ar, nt),
-                "logits_idx": ar * tile + n - 1,
-                "token_slot": jnp.repeat(ar - back, tile),
-                "token_pos": pos[s_rows:]}, bias=bias)
-            x = jnp.concatenate([x, x2])
-            hit = hit | jnp.any(hot, axis=0)
-            new = new + write(hot, local[:nt].reshape(nt, -1))
-        return x, jnp.where(hit[:, None], new.astype(pool.dtype), pool)
-
     def _mamba(self, lp, x, layer_cache, batch, prefill_tile):
         """One Mamba mixer over the flat token buffer.  Returns ``(out [T,
         hidden], {"ssm", "conv"})``."""
@@ -357,8 +277,10 @@ class RaggedJamba:
             u, z = jnp.split(qmm(xn, mb["in_proj"]["kernel"], dt), 2,
                              axis=-1)
         with jax.named_scope("mamba/conv"):
-            u, conv = self._conv(mb["conv1d"], u, layer_cache["conv"],
-                                 batch, prefill_tile)
+            u, conv = _causal_conv(u, mb["conv1d"]["kernel"],
+                                   layer_cache["conv"], batch,
+                                   bias=mb["conv1d"].get("bias"),
+                                   prefill_tile=prefill_tile)
         with jax.named_scope("mamba/x_proj"):
             dbc = qmm(u, mb["x_proj"]["kernel"], dt)
             dt_r = _rms_norm(dbc[:, :r], mb["dt_layernorm"]["scale"], eps)

@@ -13,11 +13,13 @@ pool this model shares:
   over the hidden channels, the last tap on the current token, zeros before
   position 0, NO activation; ``y = (C * c) W_out``.  Per sequence a layer
   keeps the last ``K - 1`` rows of ``g`` in a slot of the state manager's
-  pool (``ragged/state_pool.py``): ``state_spec`` has the one leaf ``conv``.
+  pool (``ragged/state_pool.py``): ``state_spec`` has the one leaf ``conv``,
+  the ``K - 1`` rows flat in one row ``[(K - 1) hidden]``.
   (The published cache keeps K columns; the oldest is never read again.)
   The convolution over a ragged batch, its tail carried across chunk
   boundaries, through decode steps and past pad rows, is
-  ``modules/conv.py::_causal_conv`` with no activation.
+  ``modules/conv.py::_causal_conv`` with no activation (one-token rows
+  through its one-hot form, the tile segment through its chunk form).
 * **A flat pool row.**  Heads of 64 are half a lane tile: the attention
   layers' pools are ``[rows, Hkv*D]`` (512 lanes at the published widths,
   whole tiles), the form ``BlockedKVCache`` stores every float pool of
@@ -193,12 +195,13 @@ class RaggedLfm2:
     @property
     def state_spec(self) -> Dict[str, Any]:
         """The per-sequence state the engine's slot pool holds: the tail of
-        each convolution layer, nothing else."""
+        each convolution layer (flat, ``modules/conv.py``), nothing
+        else."""
         cfg = self.config
         return {
             "layers": [i for i in range(cfg.num_hidden_layers)
                        if not cfg.is_attention(i)],
-            "leaves": {"conv": ((cfg.conv_L_cache - 1, cfg.hidden_size),
+            "leaves": {"conv": (((cfg.conv_L_cache - 1) * cfg.hidden_size,),
                                 cfg.dtype)}}
 
     def __call__(self, params: Dict[str, Any], cache: Dict[str, Any],
@@ -225,7 +228,7 @@ class RaggedLfm2:
             with jax.named_scope(f"layers_{i}"):
                 if "conv" in lp:
                     out, new_cache[f"layer_{i}"] = self._conv(
-                        lp, x, cache[f"layer_{i}"], batch)
+                        lp, x, cache[f"layer_{i}"], batch, prefill_tile)
                 else:
                     with jax.named_scope("attn/qkv"):
                         xa = _rms_norm(x, lp["operator_norm"]["scale"],
@@ -263,7 +266,7 @@ class RaggedLfm2:
                 logits = x @ params["lm_head"]["kernel"].astype(dt)
         return logits, new_cache
 
-    def _conv(self, lp, x, layer_cache, batch):
+    def _conv(self, lp, x, layer_cache, batch, prefill_tile):
         """One gated short convolution over the flat token buffer.  Returns
         ``(out [T, hidden], {"conv": pool})``."""
         cfg, cv, dt = self.config, lp["conv"], self.config.dtype
@@ -274,7 +277,8 @@ class RaggedLfm2:
         with jax.named_scope("conv/mix"):
             mixed, pool = _causal_conv(b * u, cv["conv1d"]["kernel"],
                                        layer_cache["conv"], batch,
-                                       activation=None)
+                                       activation=None,
+                                       prefill_tile=prefill_tile)
             mixed = c * mixed
         with jax.named_scope("conv/out_proj"):
             out = qmm(mixed, cv["out_proj"]["kernel"], dt)

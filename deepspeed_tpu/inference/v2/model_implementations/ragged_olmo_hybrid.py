@@ -18,8 +18,9 @@ this model shares:
   ``ops/gated_delta_rule.py``.  Per sequence and layer: a float32 ``[30,
   96, 192]`` state (2,211,840 B as the mathematics counts it; the chip
   tiles a float32 array ``(8, 128)``, so the pool holds 256 lanes a row:
-  ``StateSlotPool.per_sequence_bytes`` counts those) and a bf16 ``[3,
-  11520]`` convolution tail.
+  ``StateSlotPool.per_sequence_bytes`` counts those) and a bf16
+  convolution tail of 3 x 11,520 inputs, flat in one row ``[34560]``
+  (``modules/conv.py``).
 * **Multi-head attention without positions**: 30 query = 30 KV heads of
   128, RMSNorm over the WHOLE ``q`` and ``k`` projections before the head
   split (``ragged_attention_block``'s rule for a ``q_norm`` as long as the

@@ -8,7 +8,8 @@ What is new beside :class:`RaggedLlama` / :class:`RaggedMixtral`:
 * **Two kinds of per-sequence state.**  An attention layer keeps keys and
   values in the paged pool, as everywhere.  A Gated DeltaNet layer keeps,
   per sequence, a recurrent matrix per value head (float32) and the last
-  ``conv_kernel - 1`` inputs of its causal convolution, in a SLOT of the
+  ``conv_kernel - 1`` inputs of its causal convolution (flat in one row,
+  ``modules/conv.py``), in a SLOT of the
   state manager's pool (``ragged/state_pool.py``; ``state_spec`` below is
   how the engine learns of it).  The cache tree handed to the step program
   has ``{k, v}`` for attention layers and ``{state, conv}`` for the others.

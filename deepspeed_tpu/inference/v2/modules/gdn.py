@@ -11,6 +11,11 @@ Layout of the projections (what a checkpoint loader has to produce):
 ``in_proj_qkvz`` columns are ``q | k | v | z`` (all heads of q, then of k,
 ...), ``in_proj_ba`` columns ``b | a``, ``conv1d/kernel`` is ``[taps,
 channels]`` over ``q | k | v`` with the LAST tap on the current token.
+The convolution is ``modules/conv.py::_causal_conv`` (the function LFM2's
+and Jamba's layers call): the one-token rows read and write their slots'
+tails by one-hot matmuls, the tile segment takes the chunk form an entry a
+tile, and the slot's ``conv`` leaf is the tail flat in one row, ``[(taps -
+1) channels]``.
 Device scopes, under the caller's ``layers_<i>``: ``gdn/in_proj`` (both
 projections), ``gdn/conv``, ``gdn/rule``, ``gdn/out`` (gated norm and
 ``out_proj``)."""
@@ -36,11 +41,11 @@ def gdn_conv_dim(cfg) -> int:
 def gdn_state_leaves(cfg) -> Dict[str, Any]:
     """What one sequence keeps in a slot for ONE such layer
     (``state_spec``'s leaves): the float32 matrices and the convolution's
-    last ``taps - 1`` inputs."""
+    last ``taps - 1`` inputs, flat in one row (``modules/conv.py``)."""
     return {
         "state": ((cfg.linear_num_value_heads, cfg.linear_key_head_dim,
                    cfg.linear_value_head_dim), F32),
-        "conv": ((cfg.linear_conv_kernel_dim - 1, gdn_conv_dim(cfg)),
+        "conv": (((cfg.linear_conv_kernel_dim - 1) * gdn_conv_dim(cfg),),
                  cfg.dtype)}
 
 
@@ -80,7 +85,8 @@ def gdn_mixer(la, xn, layer_cache, batch, prefill_tile, cfg,
         u, z = qkvz[:, :conv_dim], qkvz[:, conv_dim:]
     with jax.named_scope("gdn/conv"):
         u, conv = _causal_conv(u, la["conv1d"]["kernel"],
-                               layer_cache["conv"], batch)
+                               layer_cache["conv"], batch,
+                               prefill_tile=prefill_tile)
     with jax.named_scope("gdn/rule"):
         u32 = u.astype(F32)
 

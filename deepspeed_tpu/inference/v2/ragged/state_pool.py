@@ -4,7 +4,7 @@ A linear-attention (Gated DeltaNet) or state-space (Mamba) layer keeps no
 keys and values: it keeps, for every live sequence, a fixed-size state (a
 recurrent matrix per head or a scan state per channel, and the tail of its
 causal convolution; leaves of any shape and dtype, e.g. a float32 ``[16,
-5120]`` beside a bf16 ``[3, 5120]``).  The state manager holds those in SLOTS:
+5120]`` beside a bf16 ``[3 x 5120]``).  The state manager holds those in SLOTS:
 ``num_slots`` of them, one taken when a sequence is created and released when
 it is flushed, plus one scratch slot (index ``num_slots``) that pad rows of a
 step program read and write so that they touch no live sequence.

@@ -225,11 +225,12 @@ def test_a_seeded_fault_fails_the_tolerance(fault, monkeypatch):
     real_conv = conv._causal_conv
     if fault == "tail_zeroed":
         monkeypatch.setattr(rl, "_causal_conv", lambda u, w, pool, batch,
-                            activation=None: real_conv(
-            u, w, jnp.zeros_like(pool), batch, activation))
+                            **kw: real_conv(
+            u, w, jnp.zeros_like(pool), batch, **kw))
     elif fault == "silu_left":
         monkeypatch.setattr(rl, "_causal_conv", lambda u, w, pool, batch,
-                            activation=None: real_conv(u, w, pool, batch))
+                            activation=None, **kw: real_conv(
+            u, w, pool, batch, **kw))
     elif fault == "b_c_swapped":
         params = jax.tree.map(lambda a: a, params)
         for i in range(HF["num_hidden_layers"]):
@@ -315,77 +316,9 @@ def test_preemption_by_recompute_gives_the_same_tokens(served):
 
 
 # ------------------------------------------------------------------ #
-# (c) _causal_conv with and without the activation against a padded jnp
-# convolution; pad rows write only the scratch slot
+# (c) pad rows write no slot (``_causal_conv`` itself against a plain
+# convolution: ``test_causal_conv.py``)
 # ------------------------------------------------------------------ #
-@pytest.mark.parametrize("activation, bias", [
-    (None, False), ("silu", False), ("silu", True), (None, True)],
-    ids=["plain", "silu", "silu_bias", "bias"])
-def test_causal_conv_against_a_padded_convolution(activation, bias):
-    rng = np.random.default_rng(5)
-    taps, ch, slots = 3, 8, 4
-    f = lambda *s: jnp.asarray(rng.standard_normal(s), jnp.float32)
-    w, pool = f(taps, ch), f(slots + 1, taps - 1, ch)
-    # batch slots 0..2: a decode row of slot 2 (position 9), a pad row, a
-    # fresh chunk of 5 rows in slot 0, a continued chunk of 1 row... as one
-    # two-segment batch: rows [dec, pad, pad | chunk A x5, pad x3 | chunk B
-    # x2 (continues at position 4), pad x6]
-    t_rows = 3 + 8 + 8
-    u = f(t_rows, ch)
-    batch = {
-        "chunk_start": jnp.asarray([0, 3, 11], jnp.int32),
-        "state_slot": jnp.asarray([2, 0, 3], jnp.int32),
-        "logits_idx": jnp.asarray([0, 7, 12], jnp.int32),
-        "token_slot": jnp.asarray([0] + [0, 0] + [1] * 8 + [2] * 8,
-                                  jnp.int32),
-        "token_pos": jnp.asarray(
-            [9, -1, -1] + [0, 1, 2, 3, 4, -1, -1, -1]
-            + [4, 5, -1, -1, -1, -1, -1, -1], jnp.int32)}
-    act = conv._silu if activation else None
-    # a Mamba layer's convolution adds a bias a channel before the
-    # activation; the tail keeps the inputs, so it never sees the bias
-    b_c = f(ch) if bias else None
-    got, new_pool = conv._causal_conv(u, w, pool, batch, activation=act,
-                                      bias=b_c)
-
-    def padded(rows, tail):
-        seq = jnp.concatenate([tail, u[rows]])
-        out = sum(w[j] * seq[j:j + len(rows)] for j in range(taps))
-        if bias:
-            out = out + b_c
-        return (conv._silu(out) if activation else out), seq[-(taps - 1):]
-
-    zero = jnp.zeros((taps - 1, ch))
-    for rows, slot, tail in ((np.array([0]), 2, pool[2]),
-                             (np.arange(3, 8), 0, zero),
-                             (np.array([11, 12]), 3, pool[3])):
-        want, want_tail = padded(rows, tail)
-        assert np.allclose(got[rows], want, atol=1e-6)
-        assert np.allclose(new_pool[slot], want_tail, atol=1e-6)
-    # the slot no row names is bitwise as it was; pads wrote the scratch
-    assert np.array_equal(new_pool[1], pool[1])
-
-
-def test_causal_conv_without_a_bias_traces_the_program_it_traced():
-    """``bias=None`` (LFM2's and Qwen3-Next's calls) adds nothing to the
-    program: the jaxpr is the one of a call that never names the argument,
-    and a bias is one more ``add`` over the rows."""
-    f = lambda *s: jnp.zeros(s, jnp.float32)
-    batch = {"chunk_start": jnp.zeros((2,), jnp.int32),
-             "state_slot": jnp.zeros((2,), jnp.int32),
-             "logits_idx": jnp.zeros((2,), jnp.int32),
-             "token_slot": jnp.zeros((16,), jnp.int32),
-             "token_pos": jnp.zeros((16,), jnp.int32)}
-    args = (f(16, 8), f(3, 8), f(3, 2, 8), batch)
-    plain = str(jax.make_jaxpr(lambda *a: conv._causal_conv(*a))(*args))
-    named = str(jax.make_jaxpr(
-        lambda *a: conv._causal_conv(*a, bias=None))(*args))
-    biased = str(jax.make_jaxpr(
-        lambda *a: conv._causal_conv(*a, bias=f(8)))(*args))
-    assert plain == named
-    assert biased.count(" add ") == plain.count(" add ") + 1
-
-
 def test_pad_rows_and_padded_tails_change_no_other_slot():
     params = _params()
     eng = _engine(params, max_seqs=4)
@@ -606,18 +539,18 @@ def test_bytes_a_token_and_a_sequence_hold():
     # the attention layer alone: 1 layer x (k + v) x 2 heads x 16 x 2 B
     assert sm.kv_cache.kv_layers == (2,)
     assert sm.kv_cache.per_token_bytes == 1 * 2 * 2 * 16 * 2
-    # the five convolution layers' tails alone: 2 rows x 64 channels x 2 B
-    # as the chip holds them, a whole lane tile a row (``slot_bytes``; the
-    # published 2,048 channels are whole tiles: the next test)
+    # the five convolution layers' tails alone: 2 rows x 64 channels x 2 B,
+    # flat in one row of a whole lane tile (``slot_bytes``; the published
+    # 2 x 2,048 channels are whole tiles too: the next test)
     assert sm.state_pool.layers == (0, 1, 3, 4, 5)
-    assert sm.state_pool.per_sequence_bytes == 5 * 2 * 128 * 2
+    assert sm.state_pool.per_sequence_bytes == 5 * 2 * 64 * 2
     cache = sm.kv_cache.cache
     # 2 heads of 16 are no whole lane tile: the cache's rule (``flat_row``)
     # keeps heads apart; the published 8 x 64 is stored flat (the next test)
     assert set(cache["layer_2"]) == {"k", "v"} and \
         cache["layer_2"]["k"].shape == (160 * BLOCK, 2, 16)
     assert set(cache["layer_0"]) == {"conv"} and \
-        cache["layer_0"]["conv"].shape == (MAX_SEQS + 1, 2, 64)
+        cache["layer_0"]["conv"].shape == (MAX_SEQS + 1, 2 * 64)
 
 
 def test_bytes_at_the_published_widths():

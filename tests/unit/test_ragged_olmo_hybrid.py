@@ -313,7 +313,7 @@ def test_everything_is_read_from_published_keys():
     spec = model.state_spec
     assert spec["layers"] == [0, 1, 2, 4, 5, 6]
     assert spec["leaves"]["state"][0] == (6, 24, 48)
-    assert spec["leaves"]["conv"][0] == (3, 2 * 6 * 24 + 6 * 48)
+    assert spec["leaves"]["conv"][0] == (3 * (2 * 6 * 24 + 6 * 48),)
     # the default pattern is the published one
     assert ro.OlmoHybridConfig(num_hidden_layers=8).layer_types == \
         tuple(HF["layer_types"])
