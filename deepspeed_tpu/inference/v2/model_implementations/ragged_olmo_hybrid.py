@@ -15,11 +15,12 @@ this model shares:
   values, neither a multiple of 8 heads nor of 128 lanes; write strengths
   ``beta = 2 sigmoid(b)`` in (0, 2) (``linear_allow_neg_eigval``).  Both go
   through the one ``gdn_step`` / ``gdn_chunk`` of
-  ``ops/gated_delta_rule.py``.  Per sequence and layer: a float32 ``[30,
-  96, 192]`` state (2,211,840 B as the mathematics counts it; the chip
-  tiles a float32 array ``(8, 128)``, so the pool holds 256 lanes a row:
-  ``StateSlotPool.per_sequence_bytes`` counts those) and a bf16
-  convolution tail of 3 x 11,520 inputs, flat in one row ``[34560]``
+  ``ops/gated_delta_rule.py``.  Per sequence and layer: a float32 state
+  of 30 x 96 x 192 values, 2,211,840 B, stored as 15 head PAIRS ``[15,
+  96, 384]`` (``gated_delta_rule.state_leaf_shape``: 192 lanes are one
+  and a half of the chip's 128-lane tiles, two heads side by side are
+  three whole ones, so ``StateSlotPool.per_sequence_bytes`` is the
+  mathematics' count) and a bf16 convolution tail of 3 x 11,520 inputs, flat in one row ``[34560]``
   (``modules/conv.py``).
 * **Multi-head attention without positions**: 30 query = 30 KV heads of
   128, RMSNorm over the WHOLE ``q`` and ``k`` projections before the head

@@ -26,7 +26,11 @@ import jax
 import jax.numpy as jnp
 
 from deepspeed_tpu.inference.v2.modules.conv import _causal_conv, _silu
-from deepspeed_tpu.ops.gated_delta_rule import gdn_chunk, gdn_step
+from deepspeed_tpu.ops.gated_delta_rule import (
+    gdn_chunk,
+    gdn_step,
+    state_leaf_shape,
+)
 from deepspeed_tpu.ops.quantized_matmul import qmm
 
 F32 = jnp.float32
@@ -40,11 +44,16 @@ def gdn_conv_dim(cfg) -> int:
 
 def gdn_state_leaves(cfg) -> Dict[str, Any]:
     """What one sequence keeps in a slot for ONE such layer
-    (``state_spec``'s leaves): the float32 matrices and the convolution's
-    last ``taps - 1`` inputs, flat in one row (``modules/conv.py``)."""
+    (``state_spec``'s leaves): the float32 matrices, in the layout the
+    rule's kernels keep them in (``ops/gated_delta_rule.py::
+    state_leaf_shape``: head pairs side by side on the lanes where one
+    head's values fill no whole lane tile and two heads' do), and the
+    convolution's last ``taps - 1`` inputs, flat in one row
+    (``modules/conv.py``)."""
     return {
-        "state": ((cfg.linear_num_value_heads, cfg.linear_key_head_dim,
-                   cfg.linear_value_head_dim), F32),
+        "state": (state_leaf_shape(cfg.linear_num_value_heads,
+                                   cfg.linear_key_head_dim,
+                                   cfg.linear_value_head_dim), F32),
         "conv": (((cfg.linear_conv_kernel_dim - 1) * gdn_conv_dim(cfg),),
                  cfg.dtype)}
 

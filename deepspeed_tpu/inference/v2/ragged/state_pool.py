@@ -50,11 +50,14 @@ LANES = 128
 
 def slot_bytes(shape: Sequence[int], dtype) -> int:
     """HBM bytes ONE slot of a leaf holds: its values with the minor
-    dimension rounded up to whole lane tiles, as the chip stores it (a
-    float32 ``[30, 96, 192]`` state holds 256 lanes a row: a third more
-    than its 2,211,840 B; every leaf whose rows are whole tiles counts as
-    its values).  The few rows a pool's slot axis is padded by are not in
-    it."""
+    dimension rounded up to whole lane tiles, as the chip stores it.  Every
+    leaf whose rows are whole tiles counts as its values, which is every
+    leaf the models declare at their published widths since PR 58 (a
+    float32 ``[30, 96, 192]`` delta-rule state held 256 lanes a row, a
+    third more than its 2,211,840 B, until
+    ``ops/gated_delta_rule.py::state_leaf_shape`` stored it as ``[15, 96,
+    384]``); a tiny test width still pads.  The few rows a pool's slot axis
+    is padded by are not in it."""
     *rows, lanes = shape
     return int(np.prod(rows, dtype=np.int64)) * -(-lanes // LANES) * LANES \
         * jnp.dtype(dtype).itemsize

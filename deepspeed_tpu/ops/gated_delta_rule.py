@@ -11,10 +11,20 @@ state's transition ``I - beta k k^T`` then reaches -1)::
 
     S *= exp(g_t);  d = (v_t - S^T k_t) * beta_t;  S += k_t d^T;  o_t = S^T q_t
 
-The states live in a slot pool ``[slots + 1, H, dk, dv]`` owned by the
-serving engine's state manager (``inference/v2/ragged/state_pool.py``); the
-last slot is scratch, where pad rows write.  Two entry points, one for each
-segment of a ragged batch (``RaggedBatchWrapper.set_alignment``):
+The states live in a slot pool owned by the serving engine's state manager
+(``inference/v2/ragged/state_pool.py``); the last slot is scratch, where pad
+rows write.  **The pool's layout is one rule, by the widths alone**
+(:func:`state_leaf_shape`; the model's ``state`` leaf asks it): where one
+head's values do not fill whole 128-lane tiles and two heads' do (``dv %
+128 != 0``, ``2 dv % 128 == 0``, H even: Olmo-Hybrid's 30 x 96 x 192), a
+sequence's matrices are stored as head PAIRS, ``[slots + 1, H / 2, dk, 2
+dv]``, lanes below ``dv`` the even head; otherwise (``dv = 128``:
+Qwen3-Next; tiny test widths such as 48) ``[slots + 1, H, dk, dv]``.  Both
+entry points recognise the layout from ``pool.shape`` against the rows'
+(:func:`_paired`) and hand it to the SAME kernel as a static argument; the
+compositions unpair on the way in and pair on the way out.  Two entry
+points, one for each segment of a ragged batch
+(``RaggedBatchWrapper.set_alignment``):
 
 * :func:`gdn_step` — rows of one token each (a decode step, the
   single-token segment): one read and one write of each row's slot.
@@ -59,12 +69,31 @@ divisor of H up to 8 (step) or 4 (chunk): 8 and 4 of 32 heads, 6 and 3 of
 arrays, so a group need be no multiple of 8.  With ``beta`` up to 2 the
 chunk form's unit-lower system has off-diagonal entries up to 2 in
 magnitude; block doubling holds a float64 solve to rounding there
-(``tests/unit/test_gdn_chunk_kernel.py``).  A ``dv`` of 192 is one and a
-half lane tiles: the chip stores each float32 state row as 256 lanes, a
-third more bytes in HBM and in every decode step's read and write than the
-mathematics has (PERF.md, PR 56: two heads side by side on the lanes,
-``[H / 2, dk, 2 dv]``, read 885 us a 128-row call against 1,179; the
-chunk kernel on that layout is the open half).
+(``tests/unit/test_gdn_chunk_kernel.py``).
+
+**Why pairs.**  A ``dv`` of 192 is one and a half lane tiles: stored ``[30,
+96, 192]`` the chip held each float32 state row as 256 lanes, a third more
+bytes in HBM and in every decode step's read and write than the
+mathematics has (that is history since PR 58; the step kernel read 1,179 us
+a 128-row call so, 59.6% of what its bytes need).  Two heads side by side
+are three whole tiles.  The step kernel on a pair is the same VPU update,
+a pair's ``q`` / ``k`` column chosen by lane half.  The chunk kernel on a
+pair moves nothing across a tile boundary either: see
+:func:`_gdn_chunk_kernel`.  The paired form adds exact zeros inside float32
+products and sums them in another order, nothing else.  One thing it does
+change, as ``modules/conv.py``'s one-hot form did for the tails.  An
+output lane of a product sums its own lane's column of the RIGHT operand,
+so a non-finite value in a head's state or values (the right operands: the
+state, ``v beta - .``, ``v_new``) stays in that head's lanes as before,
+and the half of ``rows x state`` a head does not want is dropped by a
+select, not a product.  But the LEFT operands of the block-diagonal
+products hold a pair side by side (``T``, the intra-chunk attention, ``k
+exp(G_last - G)``; inside the joint inverse, the two heads' diagonal
+blocks), and a NON-FINITE value there meets the other head's zero block
+(``inf x 0``) and reaches the pair's other head, where in the natural
+layout it stays in its head.  Those operands are functions of a head's
+keys, write strengths and decays, a layer's own finite inputs; it is
+never another pair or another sequence.
 """
 
 from __future__ import annotations
@@ -84,6 +113,49 @@ _HI = jax.lax.Precision.HIGHEST
 
 #: tokens of one chunk of the WY form (the published implementation's)
 CHUNK = 64
+
+#: values of one lane tile: an array's minor dimension is stored in whole
+#: tiles of this many
+_LANES = 128
+
+
+def state_leaf_shape(h: int, dk: int, dv: int):
+    """THE rule of the state's layout: what one sequence's matrices of one
+    layer are stored as.  Where one head's values do not fill whole lane
+    tiles and two heads' do (30 x 96 x 192 -> ``(15, 96, 384)``), head
+    PAIRS ``(h / 2, dk, 2 dv)``, lanes below ``dv`` the even head;
+    otherwise ``(h, dk, dv)`` (``dv = 128``; widths no pair fills either,
+    ``dv = 48``; an odd head count)."""
+    if dv % _LANES and not (2 * dv) % _LANES and not h % 2:
+        return (h // 2, dk, 2 * dv)
+    return (h, dk, dv)
+
+
+def _paired(pool, q, v) -> bool:
+    """Whether ``pool`` holds head pairs, from its shape against the rows'
+    (``q [., H, dk]``, ``v [., H, dv]``)."""
+    h, dk, dv = q.shape[1], q.shape[2], v.shape[-1]
+    if pool.shape[1:] == (h, dk, dv):
+        return False
+    if pool.shape[1:] != (h // 2, dk, 2 * dv) or h % 2:
+        raise ValueError(
+            f"a state pool {pool.shape} is neither [slots, {h}, {dk}, {dv}] "
+            f"nor its pairs [slots, {h // 2}, {dk}, {2 * dv}]")
+    return True
+
+
+def _pairs(pool):
+    """``[N, H, dk, dv] -> [N, H / 2, dk, 2 dv]``."""
+    n, h, dk, dv = pool.shape
+    return jnp.moveaxis(pool.reshape(n, h // 2, 2, dk, dv), 2, 3).reshape(
+        n, h // 2, dk, 2 * dv)
+
+
+def _unpairs(pool):
+    """``[N, H / 2, dk, 2 dv] -> [N, H, dk, dv]``."""
+    n, hp, dk, dv2 = pool.shape
+    return jnp.moveaxis(pool.reshape(n, hp, dk, 2, dv2 // 2), 3, 2).reshape(
+        n, 2 * hp, dk, dv2 // 2)
 
 
 def _tri_inverse(a):
@@ -113,7 +185,13 @@ def _tri_inverse(a):
 def gdn_step_reference(pool, q, k, v, g, beta, slots, reset):
     """One token a row.  pool [N, H, dk, dv]; q, k [S, H, dk]; v [S, H, dv];
     g, beta [S, H]; slots [S] int32; reset [S] bool.  Returns
-    ``(o [S, H, dv], new pool)``."""
+    ``(o [S, H, dv], new pool)``.  A pool of head pairs
+    (:func:`state_leaf_shape`) is unpaired on the way in and paired on the
+    way out: the mathematics knows one layout."""
+    if _paired(pool, q, v):
+        o, pool = gdn_step_reference(_unpairs(pool), q, k, v, g, beta,
+                                     slots, reset)
+        return o, _pairs(pool)
     s0 = pool[slots] * jnp.where(reset, 0.0, 1.0)[:, None, None, None]
     s1 = s0 * jnp.exp(g)[..., None, None]
     ks = jnp.einsum("shk,shkv->shv", k, s1, precision=_HI)
@@ -151,7 +229,12 @@ def gdn_chunk_reference(pool, q, k, v, g, beta, tile_slot, tile_reset,
     """The tile segment.  q, k [T, H, dk]; v [T, H, dv]; g, beta [T, H];
     tile_slot [T // tile] int32; tile_reset [T // tile] bool.  Returns
     ``(o [T, H, dv], new pool)``.  Every chunk reads its slot and writes it
-    back, so the carry from tile to tile goes through the pool."""
+    back, so the carry from tile to tile goes through the pool.  A pool of
+    head pairs: as :func:`gdn_step_reference`."""
+    if _paired(pool, q, v):
+        o, pool = gdn_chunk_reference(_unpairs(pool), q, k, v, g, beta,
+                                      tile_slot, tile_reset, tile, chunk)
+        return o, _pairs(pool)
     t_rows, h, dk = q.shape
     dv = v.shape[-1]
     chunk = min(chunk, tile)
@@ -186,17 +269,33 @@ def gdn_chunk_reference(pool, q, k, v, g, beta, tile_slot, tile_reset,
 # Mosaic kernel (b): the decode update, one token a row
 # --------------------------------------------------------------------- #
 def _gdn_step_kernel(slot_ref, reset_ref, qt_ref, kt_ref, v_ref, a_ref,
-                     b_ref, s_in_ref, o_ref, s_out_ref, *, hb: int):
+                     b_ref, s_in_ref, o_ref, s_out_ref, *, hb: int,
+                     pair: bool = False):
     """Grid (rows, head groups).  All on the VPU: with ``k`` as a column
     and ``v``, ``d``, ``o`` as rows, ``S^T k`` is a sublane reduction and
     ``k d^T`` a broadcast product, so no float32 pass goes through the
-    MXU.  ``o = S_new^T q = a S0^T q + (q . k) d``."""
+    MXU.  ``o = S_new^T q = a S0^T q + (q . k) d``.
+
+    ``pair``: the group's ``hb`` entries are head PAIRS, rows and state
+    ``2 dv`` lanes wide, and a pair's ``q`` / ``k`` column is the even
+    head's below lane ``dv`` and the odd head's from there on."""
     s = pl.program_id(0)
     keep = jnp.where(reset_ref[s] != 0, 0.0, 1.0).astype(F32)
+    if pair:
+        lanes = s_in_ref.shape[-1]
+        even = jax.lax.broadcasted_iota(jnp.int32, (1, lanes), 1) \
+            < lanes // 2
+
+    def col(ref, j):                # [dk, 1], or [dk, 2 dv] by lane half
+        if pair:
+            return jnp.where(even, ref[0, 0, :, 2 * j:2 * j + 1],
+                             ref[0, 0, :, 2 * j + 1:2 * j + 2])
+        return ref[0, 0, :, j:j + 1]
+
     for j in range(hb):
         s0 = s_in_ref[0, j] * keep                        # [dk, dv]
-        qc = qt_ref[0, 0, :, j:j + 1]                     # [dk, 1]
-        kc = kt_ref[0, 0, :, j:j + 1]
+        qc = col(qt_ref, j)                               # [dk, 1]
+        kc = col(kt_ref, j)
         a = a_ref[0, 0, j:j + 1, :]                       # [1, dv]
         ks = jnp.sum(kc * s0, axis=0, keepdims=True)      # [1, dv]
         qs = jnp.sum(qc * s0, axis=0, keepdims=True)
@@ -209,24 +308,30 @@ def _gdn_step_kernel(slot_ref, reset_ref, qt_ref, kt_ref, v_ref, a_ref,
 @functools.partial(jax.jit, static_argnames=("hb", "interpret"))
 def _gdn_step_call(pool, q, k, v, g, beta, slots, reset, hb: int,
                    interpret: bool):
+    """``hb``: entries of the pool's head axis a grid step holds (heads,
+    or pairs of a paired pool)."""
     s, h, dk = q.shape
     dv = v.shape[-1]
-    hg = h // hb
+    pair = _paired(pool, q, v)
+    hg = pool.shape[1] // hb
+    per = 2 if pair else 1          # heads an entry of the group holds
 
     def cols(x):                    # [S, H, dk] -> [S, hg, dk, hb]
-        return jnp.swapaxes(x.reshape(s, hg, hb, dk), 2, 3)
+        return jnp.swapaxes(x.reshape(s, hg, per * hb, dk), 2, 3)
 
     def rows(x):                    # [S, H] or [S, H, dv] -> [S, hg, hb, dv]
         if x.ndim == 2:
             x = jnp.broadcast_to(x[..., None], (s, h, dv))
-        return x.reshape(s, hg, hb, dv)
+        return x.reshape(s, hg, hb, per * dv)
 
-    kernel = functools.partial(_gdn_step_kernel, hb=hb)
+    kernel = functools.partial(_gdn_step_kernel, hb=hb, pair=pair)
     # a head group's rows and columns are whole trailing axes of their
     # arrays, so ``hb`` is any divisor of H (30 heads: no multiple of 8)
-    col_spec = pl.BlockSpec((1, 1, dk, hb), lambda i, j, sl, rs: (i, j, 0, 0))
-    row_spec = pl.BlockSpec((1, 1, hb, dv), lambda i, j, sl, rs: (i, j, 0, 0))
-    pool_spec = pl.BlockSpec((1, hb, dk, dv),
+    col_spec = pl.BlockSpec((1, 1, dk, per * hb),
+                            lambda i, j, sl, rs: (i, j, 0, 0))
+    row_spec = pl.BlockSpec((1, 1, hb, per * dv),
+                            lambda i, j, sl, rs: (i, j, 0, 0))
+    pool_spec = pl.BlockSpec((1, hb) + pool.shape[2:],
                              lambda i, j, sl, rs: (sl[i], j, 0, 0))
     o, pool = pl.pallas_call(
         kernel,
@@ -235,7 +340,7 @@ def _gdn_step_call(pool, q, k, v, g, beta, slots, reset, hb: int,
             in_specs=[col_spec, col_spec, row_spec, row_spec, row_spec,
                       pool_spec],
             out_specs=[row_spec, pool_spec]),
-        out_shape=[jax.ShapeDtypeStruct((s, hg, hb, dv), F32),
+        out_shape=[jax.ShapeDtypeStruct((s, hg, hb, per * dv), F32),
                    jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
         # the pool is updated in place: operand 7 (after the two scalar
         # vectors and five row operands) is output 1
@@ -264,10 +369,12 @@ def _mm(a, b, lhs: int = -1, rhs: int = -2):
 _DIAG = 16
 
 
-def _tri_inverse_live(a):
+def _tri_inverse_live(a, live: Optional[int] = None):
     """``(I + a)^-1`` as :func:`_tri_inverse` forms it (block doubling,
     ``-T22 a21 T11``), multiplying only what is live.  ``a [..., C, C]``
-    strictly lower, C a power of two.
+    strictly lower, C a power of two.  ``live``: ``a`` is block diagonal in
+    blocks of that many rows (several systems in one array), so the levels
+    that would merge them, whose ``a21`` is zero, are not made.
 
     While the blocks being merged have at most ``_DIAG`` rows the inverse's
     diagonal blocks of ``_DIAG`` rows are kept SIDE BY SIDE, ``s [..., 16,
@@ -305,10 +412,11 @@ def _tri_inverse_live(a):
         s = s - pairs(s, jnp.where(diag, stacked(s), 0.0), b)
         b *= 2
     t = jnp.where(diag, stacked(s), 0.0)
-    if b < c:
+    top = c if live is None else live
+    if b < top:
         t = t - jnp.where(low(b), stacked(pairs(s, t, b)), 0.0)
         b *= 2
-    while b < c:
+    while b < top:
         odd = [t[..., u:u + b, :] for u in range(b, c, 2 * b)]
         y = _mm(_mm(jnp.concatenate(odd, axis=-2),
                     jnp.where(low(b), a, 0.0)), t)
@@ -322,7 +430,7 @@ def _tri_inverse_live(a):
 
 def _gdn_chunk_kernel(slot_ref, reset_ref, q_ref, k_ref, v_ref, c_ref, d_ref,
                       s_in_ref, o_ref, s_out_ref, *, hb: int, tile: int,
-                      chunk: int):
+                      chunk: int, pair: bool = False):
     """Grid (head groups, tiles), tiles innermost: the tiles of one
     sequence follow each other and map to the same block of the pool, so
     Pallas neither fetches the slot again nor writes it back between them
@@ -335,7 +443,23 @@ def _gdn_chunk_kernel(slot_ref, reset_ref, q_ref, k_ref, v_ref, c_ref, d_ref,
     ones.  Phase A, over all chunk-heads of the step (chunk-major): ``[k
     beta; q] k^T`` in one product and the inverse ``T``.  Phase B, chunk
     by chunk over the ``hb`` heads: ``[k beta exp G; q exp G] S`` in one
-    product, ``v_new = T (v beta - .)``, ``o``, and the state's update."""
+    product, ``v_new = T (v beta - .)``, ``o``, and the state's update.
+
+    ``pair``: the group's ``hb`` entries are head PAIRS and nothing leaves
+    its lane tile.  What has ``dv`` in it (``v``, ``o``, the state) holds a
+    pair side by side on the lanes, ``2 dv`` wide; so do the ``chunk``-wide
+    matrices (the decays, ``T``, the intra-chunk attention: two 64-wide
+    heads fill the 128 lanes one wasted half of).  What is ``dk`` wide
+    (``q``, ``k``, the per-row scalars; refs ``[hb, 2, tile, .]``) holds
+    the pair's rows one head UNDER the other (:func:`stack`).  A product
+    with such rows on the left gives both heads' rows against both heads'
+    lanes, and each head keeps its own half (:func:`halves`); a product
+    that contracts over a chunk's rows takes the side-by-side matrix on
+    the left and the pair's values as a block-diagonal right operand
+    (:func:`diag2`: ``[T_0 | T_1] [[x_0, 0], [0, x_1]] = [T_0 x_0 | T_1
+    x_1]``), the zeros exact.  The two heads' systems are inverted as ONE
+    block-diagonal ``[2 chunk, 2 chunk]`` system, its last level skipped
+    (:func:`_tri_inverse_live`, ``live``), and folded side by side."""
     t = pl.program_id(1)
     first = jnp.logical_or(t == 0,
                            slot_ref[jnp.maximum(t - 1, 0)] != slot_ref[t])
@@ -345,34 +469,67 @@ def _gdn_chunk_kernel(slot_ref, reset_ref, q_ref, k_ref, v_ref, c_ref, d_ref,
         keep = jnp.where(reset_ref[t] != 0, 0.0, 1.0).astype(F32)
         s_out_ref[...] = s_in_ref[...] * keep
 
-    dk, dv = k_ref.shape[-1], v_ref.shape[-1]
-    i = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
-    j = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    dk, dv = k_ref.shape[-1], v_ref.shape[-1]       # dv: the state's lanes
+    live = 2 * chunk if pair else chunk             # rows a stack holds
+    i = jax.lax.broadcasted_iota(jnp.int32, (live, live), 0)
+    j = jax.lax.broadcasted_iota(jnp.int32, (live, live), 1)
     spans = [pl.ds(c * chunk, chunk) for c in range(tile // chunk)]
 
-    def held(ref):              # [hb, tile, w] -> [chunks * hb, C, w]
-        return jnp.concatenate([ref[:, rows, :] for rows in spans], axis=0)
+    def stack(ref, rows):       # a chunk's rows of the step's hb entries
+        if len(ref.shape) == 4:     # [hb, 2, tile, w] -> [hb, 2 C, w]
+            return jnp.concatenate([ref[:, 0, rows, :], ref[:, 1, rows, :]],
+                                   axis=1)
+        return ref[:, rows, :]
 
-    k, decay = held(k_ref), held(d_ref)     # decay [., C, C], lower
+    def even(shape):            # the even head's lanes
+        return jax.lax.broadcasted_iota(jnp.int32, shape, len(shape) - 1) \
+            < shape[-1] // 2
+
+    def halves(x):              # [., 2 C, w] -> [., C, w], each head's own
+        if not pair:
+            return x
+        top, low = x[:, :chunk], x[:, chunk:]
+        return jnp.where(even(top.shape), top, low)
+
+    def diag2(x):               # [., C, w] -> [., 2 C, w], block diagonal
+        if not pair:
+            return x
+        mine = even(x.shape)
+        return jnp.concatenate([jnp.where(mine, x, 0.0),
+                                jnp.where(mine, 0.0, x)], axis=1)
+
+    def held(ref):              # [hb, tile, w] -> [chunks * hb, C, w]
+        return jnp.concatenate([stack(ref, rows) for rows in spans], axis=0)
+
+    k, decay = held(k_ref), held(d_ref)     # decay [., C, live], lower
     qk = _mm(jnp.concatenate([k * held(c_ref)[..., 0:1], held(q_ref)],
                              axis=1), k, rhs=-1)
-    tm = _tri_inverse_live(jnp.where(i > j, qk[:, :chunk] * decay, 0.0))
-    attn = qk[:, chunk:] * decay
+    tm = _tri_inverse_live(jnp.where(i > j, qk[:, :live] * diag2(decay), 0.0),
+                           live=chunk)
+    if pair:                    # [[T_0, 0], [0, T_1]] -> [T_0 | T_1]
+        tm = tm[:, :chunk] + tm[:, chunk:]
+    attn = halves(qk[:, live:]) * decay
 
     for c, rows in enumerate(spans):
         heads = slice(c * hb, (c + 1) * hb)
         st = s_out_ref[0]                                 # [hb, dk, dv]
-        k, cols = k_ref[:, rows, :], c_ref[:, rows, :]    # cols [hb, C, 4]
+        k, cols = stack(k_ref, rows), stack(c_ref, rows)  # cols [hb, C, 4]
         beta, eg, kd = cols[..., 0:1], cols[..., 1:2], cols[..., 2:3]
-        u = _mm(jnp.concatenate([k * (beta * eg), q_ref[:, rows, :] * eg],
+        u = _mm(jnp.concatenate([k * (beta * eg), stack(q_ref, rows) * eg],
                                 axis=1), st)              # [hb, 2C, dv]
-        v_new = _mm(tm[heads], v_ref[:, rows, :] * beta - u[:, :chunk])
-        o_ref[:, rows, :] = u[:, chunk:] + _mm(attn[heads], v_new)
+        if pair:                # each head's write strength on its lanes
+            beta = halves(jnp.broadcast_to(beta, (hb, live, dv)))
+        v_new = diag2(_mm(tm[heads], diag2(v_ref[:, rows, :] * beta
+                                           - halves(u[:, :live]))))
+        o_ref[:, rows, :] = halves(u[:, live:]) + _mm(attn[heads], v_new)
         # exp(G_last), the same in every row of the chunk's column: eight
         # rows of it across the lanes, stacked to the state's rows (Mosaic
         # does not broadcast a [1, 1] value both ways at once)
-        last = jnp.concatenate([jnp.broadcast_to(
-            cols[:, 0:8, 3:4], (hb, 8, dv))] * (dk // 8), axis=1)
+        last = jnp.broadcast_to(cols[:, 0:8, 3:4], (hb, 8, dv))
+        if pair:
+            last = jnp.where(even(last.shape), last, jnp.broadcast_to(
+                cols[:, chunk:chunk + 8, 3:4], (hb, 8, dv)))
+        last = jnp.concatenate([last] * (dk // 8), axis=1)
         s_out_ref[0] = st * last + _mm(k * kd, v_new, lhs=-2)
 
 
@@ -380,8 +537,10 @@ def _gdn_chunk_kernel(slot_ref, reset_ref, q_ref, k_ref, v_ref, c_ref, d_ref,
                                              "interpret"))
 def _gdn_chunk_call(pool, q, k, v, g, beta, tile_slot, tile_reset,
                     tile: int, chunk: int, hb: int, interpret: bool):
+    """``hb``: as :func:`_gdn_step_call` takes it."""
     t_rows, h, dk = q.shape
     dv = v.shape[-1]
+    pair = _paired(pool, q, v)
     n, nt = t_rows // chunk, t_rows // tile
     # per-row scalars and the decay mask are elementwise work XLA fuses;
     # the kernel gets matrices only.  Head-major from the start: the small
@@ -398,37 +557,56 @@ def _gdn_chunk_call(pool, q, k, v, g, beta, tile_slot, tile_reset,
                               -jnp.inf)).reshape(h, t_rows, chunk)
     hm = lambda x: jnp.swapaxes(x, 0, 1)                      # head-major
     kernel = functools.partial(_gdn_chunk_kernel, hb=hb, tile=tile,
-                               chunk=chunk)
+                               chunk=chunk, pair=pair)
 
     def rows(width):
         return pl.BlockSpec((hb, tile, width),
                             lambda hg, t, sl, rs: (hg, t, 0))
 
-    pool_spec = pl.BlockSpec((1, hb, dk, dv),
+    def under(width):               # what is dk wide: q, k, the scalars
+        if not pair:
+            return rows(width)
+        return pl.BlockSpec((hb, 2, tile, width),
+                            lambda hg, t, sl, rs: (hg, 0, t, 0))
+
+    tile_slot, tile_reset = tile_slot.astype(jnp.int32), \
+        tile_reset.astype(jnp.int32)
+    q, k, v = hm(q), hm(k), hm(v)
+    if pair:                        # see the kernel's docstring
+        q, k, cols = (x.reshape((h // 2, 2) + x.shape[1:])
+                      for x in (q, k, cols))
+        side = lambda x: jnp.moveaxis(
+            x.reshape((h // 2, 2) + x.shape[1:]), 1, 2).reshape(
+            h // 2, t_rows, 2 * x.shape[-1])
+        v, decay = side(v), side(decay)
+    pool_spec = pl.BlockSpec((1, hb) + pool.shape[2:],
                              lambda hg, t, sl, rs: (sl[t], hg, 0, 0))
     o, pool = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(h // hb, nt),
-            in_specs=[rows(dk), rows(dk), rows(dv), rows(4), rows(chunk),
-                      pool_spec],
-            out_specs=[rows(dv), pool_spec]),
-        out_shape=[jax.ShapeDtypeStruct((h, t_rows, dv), F32),
+            num_scalar_prefetch=2, grid=(pool.shape[1] // hb, nt),
+            in_specs=[under(dk), under(dk), rows(v.shape[-1]), under(4),
+                      rows(decay.shape[-1]), pool_spec],
+            out_specs=[rows(v.shape[-1]), pool_spec]),
+        out_shape=[jax.ShapeDtypeStruct(v.shape, F32),
                    jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
         input_output_aliases={7: 1},
         interpret=interpret,
         **kernel_names(kernel),
-    )(tile_slot.astype(jnp.int32), tile_reset.astype(jnp.int32), hm(q),
-      hm(k), hm(v), cols, decay, pool)
-    return hm(o), pool
+    )(tile_slot, tile_reset, q, k, v, cols, decay, pool)
+    o = hm(o)
+    if pair:                        # [T, H / 2, 2 dv]: the heads in order
+        o = o.reshape(t_rows, h, dv)
+    return o, pool
 
 
 # --------------------------------------------------------------------- #
 # Public entries
 # --------------------------------------------------------------------- #
 def _head_block(h: int, want: int) -> int:
-    """Heads a grid step holds: the largest divisor of ``h`` up to
-    ``want`` (32 heads: 8 and 4; 30 heads: 6 and 3)."""
+    """Entries of the pool's head axis a grid step holds: the largest
+    divisor of ``h`` up to ``want`` (32 heads: 8 and 4; 15 pairs: 5 and 3;
+    30 heads unpaired: 6 and 3)."""
     return max(b for b in range(1, want + 1) if h % b == 0)
 
 
@@ -442,12 +620,14 @@ def _kernel_mode(interpret: Optional[bool]):
 
 def gdn_step(pool, q, k, v, g, beta, slots, reset,
              interpret: Optional[bool] = None):
-    """One token a row: see :func:`gdn_step_reference` for the shapes."""
+    """One token a row: see :func:`gdn_step_reference` for the shapes.
+    ``pool`` as :func:`state_leaf_shape` gives it: either layout is
+    recognised from its shape against the rows'."""
     use, interp = _kernel_mode(interpret)
     if not use:
         return gdn_step_reference(pool, q, k, v, g, beta, slots, reset)
     return _gdn_step_call(pool, q, k, v, g, beta, slots, reset,
-                          _head_block(q.shape[1], 8), interp)
+                          _head_block(pool.shape[1], 8), interp)
 
 
 def gdn_chunk(pool, q, k, v, g, beta, tile_slot, tile_reset, tile: int,
@@ -459,7 +639,7 @@ def gdn_chunk(pool, q, k, v, g, beta, tile_slot, tile_reset, tile: int,
                                    tile_reset, tile)
     return _gdn_chunk_call(pool, q, k, v, g, beta, tile_slot, tile_reset,
                            tile, min(CHUNK, tile),
-                           _head_block(q.shape[1], 4), interp)
+                           _head_block(pool.shape[1], 4), interp)
 
 
 # --------------------------------------------------------------------- #
@@ -501,22 +681,31 @@ def _dslint_gdn_chunk():
               jnp.asarray([True, False, False, False]), 128, interpret=True)
 
 
+def _dslint_gdn_olmo_inputs(rows: int):
+    """Olmo-Hybrid's shape class, the pool stored as the layout rule says:
+    6 heads of 96 x 192 as three PAIRS ``[3, 96, 384]``."""
+    pool, *rest = _dslint_gdn_inputs(rows, 6, 96, 192, 2.0)
+    return (_pairs(pool), *rest)
+
+
 @pallas_kernel_case(
     "gdn_step_h6_96x192", allow=("pallas-uncovered-tile",),
-    note="the decode update at Olmo-Hybrid's shape class: a head group of "
-         "6 (no multiple of 8) as whole trailing axes of 4-D row blocks, 96 "
-         "keys x 192 values (one and a half lane tiles), beta in (0, 2)")
+    note="the decode update at Olmo-Hybrid's shape class on the pool of "
+         "head pairs: a group of 3 pairs (no multiple of 8) as whole "
+         "trailing axes of 4-D row blocks, 96 keys x 2 x 192 values (three "
+         "whole lane tiles), q and k columns by lane half, beta in (0, 2)")
 def _dslint_gdn_step_olmo():
-    gdn_step(*_dslint_gdn_inputs(8, 6, 96, 192, 2.0),
+    gdn_step(*_dslint_gdn_olmo_inputs(8),
              jnp.asarray([1, 0, 4, 3, 4, 4, 2, 4]), jnp.zeros((8,), bool),
              interpret=True)
 
 
 @pallas_kernel_case(
     "gdn_chunk_h6_96x192", allow=("pallas-uncovered-tile",),
-    note="the chunked rule at Olmo-Hybrid's shape class: head groups of 3, "
-         "a contraction over 96 keys, states of 96 x 192, beta in (0, 2)")
+    note="the chunked rule at Olmo-Hybrid's shape class on the pool of "
+         "head pairs: a group of 3 pairs, q / k / scalars as [3, 2, tile, "
+         ".] blocks (a pair's heads one under the other), states of 96 x "
+         "384, beta in (0, 2)")
 def _dslint_gdn_chunk_olmo():
-    gdn_chunk(*_dslint_gdn_inputs(256, 6, 96, 192, 2.0),
-              jnp.asarray([2, 0]), jnp.asarray([True, False]), 128,
-              interpret=True)
+    gdn_chunk(*_dslint_gdn_olmo_inputs(256), jnp.asarray([2, 0]),
+              jnp.asarray([True, False]), 128, interpret=True)

@@ -18,9 +18,10 @@ by name, so the three-pass form ISSUE 56 names cannot be built on the
 chip).
 
 A non-zero value in a padded lane or a dead head, which ISSUE 56 lists, has
-no place to stand: the state is stored ``[30, 96, 192]``, with no dead head
-and no lane the program can address beyond the 192 (the chip's own tiling
-pads the rows in HBM, out of any program's reach).
+no place to stand: the state is stored as head pairs ``[15, 96, 384]``
+(since PR 58; ``[30, 96, 192]`` before), with no dead head and no padded
+lane at all.  Every fault here is blind to that layout: the rules are
+patched at their public entries, which take and return the pool as stored.
 """
 
 import contextlib

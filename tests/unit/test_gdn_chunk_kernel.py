@@ -211,6 +211,26 @@ def test_live_inverse_multiplies_a_quarter_of_the_rows(monkeypatch):
 # dk != dv, neither a multiple of 128, write strengths in (0, 2)
 # ------------------------------------------------------------------ #
 OLMO_SHAPES = [(30, 96, 192), (6, 24, 48)]
+#: ... and the widths the layout rule stores as head PAIRS (``[H / 2, dk,
+#: 2 dv]``, two heads side by side on the lanes): the published one and a
+#: small one of the same class
+PAIR_SHAPES = [(30, 96, 192), (6, 24, 64)]
+#: (h, dk, dv, the pool the kernel is handed)
+LAYOUT_CASES = [pytest.param(*shape, layout, id=f"{name}-{layout}")
+                for shape, name, layout in (
+                    (OLMO_SHAPES[0], "published", "natural"),
+                    (OLMO_SHAPES[1], "small", "natural"),
+                    (PAIR_SHAPES[0], "published", "pairs"),
+                    (PAIR_SHAPES[1], "small", "pairs"))]
+
+
+def _laid(pool, layout):
+    return gdr._pairs(pool) if layout == "pairs" else pool
+
+
+def _natural(got, layout):
+    """A kernel's ``(o, pool)`` with the pool as the mathematics has it."""
+    return (got[0], gdr._unpairs(got[1])) if layout == "pairs" else got
 
 
 def _strong(beta, rng, share=0.3):
@@ -221,10 +241,40 @@ def _strong(beta, rng, share=0.3):
                                 beta), jnp.float32)
 
 
-@pytest.mark.parametrize("h,dk,dv", OLMO_SHAPES, ids=["published", "small"])
-def test_step_kernel_at_olmo_hybrids_shape_class(h, dk, dv):
-    """One token a row at write strengths up to 2: the kernel against the
-    composition, and every slot no row names (the scratch slot too, which
+@pytest.mark.parametrize("h,dk,dv,want", [
+    (30, 96, 192, (15, 96, 384)),       # Olmo-Hybrid: three whole tiles
+    (6, 24, 64, (3, 24, 128)),          # the small pair class
+    (32, 128, 128, (32, 128, 128)),     # Qwen3-Next: whole tiles already
+    (6, 24, 48, (6, 24, 48)),           # two heads fill no tile either
+    (15, 96, 192, (15, 96, 192)),       # an odd head count
+    (30, 96, 320, (15, 96, 640)),       # 2.5 tiles: a pair is 5, whole
+])
+def test_the_layout_rule(h, dk, dv, want):
+    """ONE function says what a state is stored as, from the widths alone;
+    a paired pool is recognised by its shape against the rows', and a pool
+    that is neither layout is refused by name."""
+    assert gdr.state_leaf_shape(h, dk, dv) == want
+    f = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+    q, v = f(4, h, dk), f(4, h, dv)
+    assert gdr._paired(f(9, *want), q, v) == (want != (h, dk, dv))
+    assert not gdr._paired(f(9, h, dk, dv), q, v)
+    with pytest.raises(ValueError, match="neither"):
+        gdr._paired(f(9, h, dk, dv + 128), q, v)
+    pool = jnp.arange(2 * h * dk * dv, dtype=jnp.float32).reshape(
+        2, h, dk, dv)
+    if h % 2 == 0:
+        pairs = gdr._pairs(pool)
+        assert np.array_equal(gdr._unpairs(pairs), pool)
+        # lanes below dv are the even head
+        assert np.array_equal(pairs[:, 1, :, :dv], pool[:, 2])
+        assert np.array_equal(pairs[:, 1, :, dv:], pool[:, 3])
+
+
+@pytest.mark.parametrize("h,dk,dv,layout", LAYOUT_CASES)
+def test_step_kernel_at_olmo_hybrids_shape_class(h, dk, dv, layout):
+    """One token a row at write strengths up to 2: the kernel (on the
+    PAIRED pool where the layout says so) against the composition on the
+    natural pool, and every slot no row names (the scratch slot too, which
     the pad row writes ``g = 0``, ``beta = 0`` to) bit-equal before and
     after."""
     rows, slots = 5, 6
@@ -234,19 +284,27 @@ def test_step_kernel_at_olmo_hybrids_shape_class(h, dk, dv):
     g, beta = _masked(g, beta, real)
     where = (jnp.asarray([4, 0, 2, 5, slots], jnp.int32),
              jnp.asarray([0, 1, 0, 0, 0], bool))
-    got = gdr.gdn_step(pool, q, k, v, g, beta, *where, interpret=True)
+    got = _natural(gdr.gdn_step(_laid(pool, layout), q, k, v, g, beta,
+                                *where, interpret=True), layout)
     _agree(got, gdr.gdn_step_reference(pool, q, k, v, g, beta, *where), real)
     before, after = np.asarray(pool), np.asarray(got[1])
     assert np.array_equal(before[[1, 3, slots]], after[[1, 3, slots]])
     assert not np.array_equal(before[4], after[4])
+    # the composition takes the paired pool too, and answers in pairs
+    if layout == "pairs":
+        ref = gdr.gdn_step_reference(gdr._pairs(pool), q, k, v, g, beta,
+                                     *where)
+        assert ref[1].shape == gdr._pairs(pool).shape
+        _agree(_natural(ref, layout), got, real)
 
 
-@pytest.mark.parametrize("h,dk,dv", OLMO_SHAPES, ids=["published", "small"])
-def test_chunk_kernel_at_olmo_hybrids_shape_class(h, dk, dv):
+@pytest.mark.parametrize("h,dk,dv,layout", LAYOUT_CASES)
+def test_chunk_kernel_at_olmo_hybrids_shape_class(h, dk, dv, layout):
     """The tile segment at write strengths up to 2 over 64-row chunks: a
     sequence over two tiles, one from a reset, a pad tile on the scratch
     slot; untouched slots and the scratch slot bit-equal before and
-    after."""
+    after.  The kernel on the PAIRED pool where the layout says so, the
+    composition on the natural one."""
     tile, slots = 64, 5
     slot, reset = [3, 3, 1, slots], [0, 0, 1, 0]
     rows = tile * len(slot)
@@ -255,12 +313,17 @@ def test_chunk_kernel_at_olmo_hybrids_shape_class(h, dk, dv):
     real[3 * tile - 9:] = False         # a short tile, then the pad tile
     beta = _strong(beta, np.random.default_rng(dk))
     g, beta = _masked(g, beta, real)
-    args = (pool, q, k, v, g, beta, jnp.asarray(slot, jnp.int32),
+    args = (q, k, v, g, beta, jnp.asarray(slot, jnp.int32),
             jnp.asarray(reset, bool), tile)
-    got = gdr.gdn_chunk(*args, interpret=True)
-    _agree(got, gdr.gdn_chunk_reference(*args), real, slice(0, slots))
+    got = _natural(gdr.gdn_chunk(_laid(pool, layout), *args, interpret=True),
+                   layout)
+    _agree(got, gdr.gdn_chunk_reference(pool, *args), real, slice(0, slots))
     before, after = np.asarray(pool), np.asarray(got[1])
     assert np.array_equal(before[[0, 2, 4, slots]], after[[0, 2, 4, slots]])
+    if layout == "pairs":
+        ref = gdr.gdn_chunk_reference(gdr._pairs(pool), *args)
+        assert ref[1].shape == gdr._pairs(pool).shape
+        _agree(_natural(ref, layout), got, real, slice(0, slots))
     # and token by token, what the chunked form has to equal
     o, p = [], pool
     for t in range(3 * tile - 9):
@@ -272,6 +335,51 @@ def test_chunk_kernel_at_olmo_hybrids_shape_class(h, dk, dv):
         o.append(ot)
     _agree((got[0][:3 * tile - 9], got[1]), (jnp.concatenate(o), p),
            slots=slice(0, slots))
+
+
+@pytest.mark.parametrize("tile", [16, 32, 128])
+def test_chunk_kernel_on_pairs_at_other_tiles(tile):
+    """The paired form with tiles under a chunk (the two heads' systems one
+    ``[2 tile, 2 tile]`` block-diagonal system) and two chunks a tile."""
+    h, dk, dv = PAIR_SHAPES[1]
+    slot, reset = [4, 0, 0], [0, 1, 0]
+    rows = tile * len(slot)
+    pool, q, k, v, g, beta = _inputs(rows, h, dk, dv, seed=tile)
+    real = np.arange(rows) < rows - tile // 2 - 3
+    g, beta = _masked(g, _strong(beta, np.random.default_rng(tile)), real)
+    args = (q, k, v, g, beta, jnp.asarray(slot, jnp.int32),
+            jnp.asarray(reset, bool), tile)
+    got = _natural(gdr.gdn_chunk(gdr._pairs(pool), *args, interpret=True),
+                   "pairs")
+    _agree(got, gdr.gdn_chunk_reference(pool, *args), real, slice(0, 5))
+
+
+@pytest.mark.parametrize("kernel", ["step", "chunk"])
+def test_the_odd_head_reading_the_even_heads_key_would_be_seen(kernel,
+                                                               monkeypatch):
+    """The fault the paired form can have and the natural one cannot: a
+    lane half that takes its pair's OTHER head's column.  Here every odd
+    head is given its even neighbour's ``k``: what a kernel that ignores
+    the lane half computes.  Far outside the limit of the cases above."""
+    h, dk, dv = PAIR_SHAPES[1]
+    tile = 64
+    rows = 5 if kernel == "step" else tile
+    pool, q, k, v, g, beta = _inputs(rows, h, dk, dv, seed=7)
+    beta = _strong(beta, np.random.default_rng(7))
+    where = (jnp.asarray([4, 0, 2, 5, 1], jnp.int32),
+             jnp.zeros(5, bool)) if kernel == "step" else \
+        (jnp.asarray([2], jnp.int32), jnp.zeros(1, bool), tile)
+    entry, ref = (gdr.gdn_step, gdr.gdn_step_reference) \
+        if kernel == "step" else (gdr.gdn_chunk, gdr.gdn_chunk_reference)
+    want = ref(pool, q, k, v, g, beta, *where)
+    wrong_k = jnp.repeat(k[:, 0::2], 2, axis=1)
+    got = _natural(entry(gdr._pairs(pool), q, wrong_k, v, g, beta, *where,
+                         interpret=True), "pairs")
+    odd = np.max(np.abs(np.asarray(got[0] - want[0])[:, 1::2]))
+    assert odd > 1e-2 * np.max(np.abs(np.asarray(want[0])))
+    # the even heads, whose key is their own, are untouched by it
+    _agree((got[0][:, 0::2], got[1][:, 0::2]),
+           (want[0][:, 0::2], want[1][:, 0::2]))
 
 
 @pytest.mark.parametrize("share", [0.0, 0.3, 1.0],
@@ -296,21 +404,26 @@ def test_live_inverse_at_write_strengths_up_to_two(share):
     assert np.max(np.abs(got - want)) <= 2e-6 * max(scale, 1.0), scale
 
 
-def test_olmo_hybrids_calls_lower_for_the_tpu_under_the_kernels_names():
+@pytest.mark.parametrize("pool,hb", [((129, 15, 96, 384), (5, 3)),
+                                     ((129, 30, 96, 192), (6, 3))],
+                         ids=["pairs", "natural"])
+def test_olmo_hybrids_calls_lower_for_the_tpu_under_the_kernels_names(pool,
+                                                                      hb):
     """128 one-token rows and 1,024 tile rows at 30 heads of 96 x 192 over
-    129 slots: one ``pallas_call`` each, under the names the per-layer
+    129 slots, on the pool of head pairs the layout rule gives (and on the
+    natural one): one ``pallas_call`` each, under the names the per-layer
     readers match, the pool aliased in and out."""
+    assert (gdr._head_block(pool[1], 8), gdr._head_block(pool[1], 4)) == hb
     s = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
     ints = lambda n, dt=jnp.int32: jax.ShapeDtypeStruct((n,), dt)
     step = jax.jit(lambda *a: gdr._gdn_step_call(
-        *a, hb=gdr._head_block(30, 8), interpret=False)).trace(
-        s(129, 30, 96, 192), s(128, 30, 96), s(128, 30, 96),
+        *a, hb=hb[0], interpret=False)).trace(
+        s(*pool), s(128, 30, 96), s(128, 30, 96),
         s(128, 30, 192), s(128, 30), s(128, 30), ints(128),
         ints(128, jnp.bool_)).lower(lowering_platforms=("tpu",)).as_text()
     chunk = jax.jit(lambda *a: gdr._gdn_chunk_call(
-        *a, tile=128, chunk=64, hb=gdr._head_block(30, 4),
-        interpret=False)).trace(
-        s(129, 30, 96, 192), s(1024, 30, 96), s(1024, 30, 96),
+        *a, tile=128, chunk=64, hb=hb[1], interpret=False)).trace(
+        s(*pool), s(1024, 30, 96), s(1024, 30, 96),
         s(1024, 30, 192), s(1024, 30), s(1024, 30), ints(8),
         ints(8, jnp.bool_)).lower(lowering_platforms=("tpu",)).as_text()
     for text, name in ((step, "_gdn_step_kernel"),

@@ -55,6 +55,11 @@ HF = {"model_type": "olmo_hybrid", "vocab_size": 512, "hidden_size": 96,
       "rope_parameters": {"rope_theta": None},
       "max_position_embeddings": 512, "tie_word_embeddings": False,
       "attention_bias": False}
+#: the same model at a value width the layout rule stores as head PAIRS
+#: (``ops/gated_delta_rule.py::state_leaf_shape``: 64 lanes fill no tile,
+#: two heads' 128 do); at 48 the state stays ``[6, 24, 48]``
+HF_PAIRS = {**HF, "linear_value_head_dim": 64}
+WIDTHS = {"natural": HF, "pairs": HF_PAIRS}
 MAX_SEQS, BUDGET, TILE, BLOCK = 4, 64, 16, 8
 
 # float32 engine against the float32 reference, largest |difference| over
@@ -134,6 +139,10 @@ def _ids(n, seed=3):
                                                 size=(n,))
 
 
+def _state_leaf(eng):
+    return eng.state_manager.kv_cache.cache["layer_0"]["state"].shape[1:]
+
+
 def _serve(eng, ids, n_prompt, uid=7):
     got = [np.asarray(eng.put([uid], [ids[:n_prompt].tolist()])[uid],
                       np.float32)]
@@ -157,14 +166,20 @@ def _gap(got, want) -> float:
 # ------------------------------------------------------------------ #
 # (a) one prompt in 1, 2 and 5 chunks, then 6 decode steps, both pools
 # ------------------------------------------------------------------ #
-@pytest.mark.parametrize("n_prompt, interpret", [
-    (40, None), (100, None), (270 - 6, None), (100, True)],
-    ids=["1_chunk", "2_chunks", "5_chunks", "2_chunks_kernels_interpreted"])
-def test_f32_engine_matches_reference(n_prompt, interpret):
-    params, ids = _params(), _ids(n_prompt + 6)
-    eng = _engine(params, interpret=interpret, blocks=40, max_context=288)
+@pytest.mark.parametrize("n_prompt, interpret, width", [
+    (40, None, "natural"), (100, None, "natural"), (270 - 6, None, "natural"),
+    (100, True, "natural"), (100, None, "pairs"), (100, True, "pairs")],
+    ids=["1_chunk", "2_chunks", "5_chunks", "2_chunks_kernels_interpreted",
+         "2_chunks_pairs", "2_chunks_pairs_kernels_interpreted"])
+def test_f32_engine_matches_reference(n_prompt, interpret, width):
+    hf = WIDTHS[width]
+    params, ids = _params(hf), _ids(n_prompt + 6)
+    eng = _engine(params, hf=hf, interpret=interpret, blocks=40,
+                  max_context=288)
+    assert _state_leaf(eng) == {"natural": (6, 24, 48),
+                                "pairs": (3, 24, 128)}[width]
     assert _gap(_serve(eng, ids, n_prompt),
-                _want(params, ids, n_prompt)) <= F32_TOL
+                _want(params, ids, n_prompt, hf)) <= F32_TOL
     assert eng.state_manager.state_pool.held == 0
     assert eng.state_manager.free_blocks == 40 - 1      # (the trash block)
 
@@ -177,27 +192,32 @@ def test_bf16_engine_is_the_same_model():
     assert _gap(got, _want(rounded, ids, 100)) <= BF16_TOL
 
 
+@pytest.mark.parametrize("width", sorted(WIDTHS))
 @pytest.mark.parametrize("name", [f for f in FAULTS
                                   if f not in ("state_bf16",
                                                "products_bf16")])
-def test_a_fault_fails_the_tolerance(name):
+def test_a_fault_fails_the_tolerance(name, width):
     """Each fault of the state and of the mathematics, at float32, against
-    the unchanged reference: far over the limit."""
-    params, ids = _params(), _ids(100 + 6)
+    the unchanged reference: far over the limit, on either layout of the
+    state."""
+    hf = WIDTHS[width]
+    params, ids = _params(hf), _ids(100 + 6)
     with fault(name):
-        gap = _gap(_serve(_engine(params), ids, 100),
-                   _want(params, ids, 100))
+        gap = _gap(_serve(_engine(params, hf=hf), ids, 100),
+                   _want(params, ids, 100, hf))
     assert gap > 30 * F32_TOL, gap
 
 
-def test_a_bf16_state_is_seen_at_float32():
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+def test_a_bf16_state_is_seen_at_float32(width):
     """Small beside a fault of the mathematics, and still over the float32
     limit (``products_bf16`` is the chip's: the CPU multiplies float32 at
     full precision whatever ``precision`` says)."""
-    params, ids = _params(), _ids(100 + 6)
+    hf = WIDTHS[width]
+    params, ids = _params(hf), _ids(100 + 6)
     with fault("state_bf16"):
-        gap = _gap(_serve(_engine(params), ids, 100),
-                   _want(params, ids, 100))
+        gap = _gap(_serve(_engine(params, hf=hf), ids, 100),
+                   _want(params, ids, 100, hf))
     assert gap > 3 * F32_TOL, gap
 
 
@@ -273,9 +293,10 @@ def test_admission_runs_out_of_whichever_pool_is_short(solo_runs):
             assert max(s for s, _ in held) == MAX_SEQS
 
 
-def test_pad_rows_and_padded_tails_change_no_other_slot():
-    params = _params()
-    eng = _engine(params)
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+def test_pad_rows_and_padded_tails_change_no_other_slot(width):
+    params = _params(WIDTHS[width])
+    eng = _engine(params, hf=WIDTHS[width])
 
     def slots():
         pool = eng.state_manager.state_pool
@@ -312,8 +333,10 @@ def test_everything_is_read_from_published_keys():
     model = ro.RaggedOlmoHybrid(cfg, BLOCK)
     spec = model.state_spec
     assert spec["layers"] == [0, 1, 2, 4, 5, 6]
-    assert spec["leaves"]["state"][0] == (6, 24, 48)
+    assert spec["leaves"]["state"][0] == (6, 24, 48)    # the rule leaves it
     assert spec["leaves"]["conv"][0] == (3 * (2 * 6 * 24 + 6 * 48),)
+    pairs = ro.RaggedOlmoHybrid(family.program_config(HF_PAIRS), BLOCK)
+    assert pairs.state_spec["leaves"]["state"][0] == (3, 24, 128)
     # the default pattern is the published one
     assert ro.OlmoHybridConfig(num_hidden_layers=8).layer_types == \
         tuple(HF["layer_types"])
@@ -322,9 +345,10 @@ def test_everything_is_read_from_published_keys():
 def test_bytes_at_the_published_widths():
     """The cell's configuration (shapes only, nothing allocated): a token
     holds 30,720 B of keys and values in the two attention layers; a
-    sequence's state is 13,685,760 B as the mathematics counts it and
-    18,109,440 B as the chip holds it (192 lanes stored as 256), which is
-    what the pool's gauge says."""
+    sequence's state is 13,685,760 B as the mathematics counts it and,
+    stored as head pairs (``[15, 96, 384]``: three whole lane tiles), as
+    the chip holds it and the pool's gauge says; ``[30, 96, 192]`` would
+    hold 18,109,440 B (192 lanes stored as 256), as it did before PR 58."""
     import json
 
     from deepspeed_tpu.inference.v2.ragged.kv_cache import BlockedKVCache
@@ -340,9 +364,11 @@ def test_bytes_at_the_published_widths():
     shapes = family.shapes(hf)
     assert shapes["state_bytes_per_seq"] == 6 * (2_211_840 + 69_120) \
         == 13_685_760
+    assert spec["leaves"]["state"][0] == (15, 96, 384)
     assert slot_bytes((30, 96, 192), jnp.float32) == 2_949_120
-    assert pool.per_sequence_bytes == 6 * (2_949_120 + 69_120) == 18_109_440
-    assert pool.total_bytes == 129 * 18_109_440
+    assert slot_bytes((15, 96, 384), jnp.float32) == 2_211_840
+    assert pool.per_sequence_bytes == shapes["state_bytes_per_seq"]
+    assert pool.total_bytes == 129 * 13_685_760
     kv = BlockedKVCache(10, 1, 128, 30, 128, kv_layers=[3, 7])
     assert kv.cache["layer_3"]["k"].shape == (128, 30 * 128)    # flat
     assert kv.per_token_bytes == 30_720 == shapes["kv_bytes_per_token"]

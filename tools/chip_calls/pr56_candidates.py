@@ -11,9 +11,11 @@ is exact (a dead head has ``g = 0``, ``beta = 0``; a zero key row or value lane 
 * ``dead32-hb<n>``: two dead heads, ``[32, 96, 192]`` (6.7% more bytes on top of the lanes' third);
 * ``dk128-hb<n>``: keys zero-padded to 128, ``[30, 128, 192]`` (a third more rows on top of the lanes' third);
 * ``dv256-hb<n>``: values zero-padded to 256, ``[30, 96, 256]``: what the chip holds anyway, said out loud;
-* ``pairs-hb<n>`` (step only): two heads side by side on the lanes, ``[15, 96, 384]``, three whole lane tiles
-  and no padding; the kernel of this file (``_pair_step_kernel``: the update of ``_gdn_step_kernel`` with ``k``
-  and ``q`` chosen by lane half) is a sketch to time the layout with, not the program's.
+* ``pairs-hb<n>``: two heads side by side on the lanes, ``[15, 96, 384]``, three whole lane tiles and no
+  padding.  Since PR 58 this is the PROGRAM's layout (``gdr.state_leaf_shape``) and both kernels take it by the
+  pool's shape, so the form goes through ``_gdn_step_call`` / ``_gdn_chunk_call`` like every other (``hb``
+  counts pairs); the sketch PR 56 timed it with (``_pair_step_kernel``, step only) is gone, the kernel it
+  sketched being ``_gdn_step_kernel``'s ``pair`` form.
 
 ``step`` forms print microseconds a call at 128 rows (six donated pools, one a layer as the model has) beside
 the least time for the mathematics' bytes (``benchmark/lib/costs_gdn.py``: each slot read and written once);
@@ -37,73 +39,11 @@ sys.path.insert(0, _ROOT)
 
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
-from jax.experimental import pallas as pl  # noqa: E402
-from jax.experimental.pallas import tpu as pltpu  # noqa: E402
 
 from deepspeed_tpu.ops import gated_delta_rule as gdr  # noqa: E402
 
 F32 = jnp.float32
 H, DK, DV = 30, 96, 192
-
-
-def _pair_step_kernel(slot_ref, reset_ref, qt_ref, kt_ref, v_ref, a_ref, b_ref, s_in_ref, o_ref, s_out_ref, *,
-                      hb: int, dv: int):
-    """``_gdn_step_kernel`` on a pool of head PAIRS ``[dk, 2 dv]``: lanes below ``dv`` are the even head."""
-    s = pl.program_id(0)
-    keep = jnp.where(reset_ref[s] != 0, 0.0, 1.0).astype(F32)
-    even = jax.lax.broadcasted_iota(jnp.int32, (1, 2 * dv), 1) < dv
-    for j in range(hb):
-        s0 = s_in_ref[0, j] * keep                                  # [dk, 2 dv]
-        qc = jnp.where(even, qt_ref[0, 0, :, 2 * j:2 * j + 1], qt_ref[0, 0, :, 2 * j + 1:2 * j + 2])
-        kc = jnp.where(even, kt_ref[0, 0, :, 2 * j:2 * j + 1], kt_ref[0, 0, :, 2 * j + 1:2 * j + 2])
-        a = a_ref[0, 0, j:j + 1, :]
-        ks = jnp.sum(kc * s0, axis=0, keepdims=True)
-        qs = jnp.sum(qc * s0, axis=0, keepdims=True)
-        qk = jnp.sum(qc * kc, axis=0, keepdims=True)
-        d = b_ref[0, 0, j:j + 1, :] * (v_ref[0, 0, j:j + 1, :] - a * ks)
-        s_out_ref[0, j] = a * s0 + kc * d
-        o_ref[0, 0, j:j + 1, :] = a * qs + qk * d
-
-
-@functools.partial(jax.jit, static_argnames=("hb", "interpret"))
-def _pair_step_call(pool, q, k, v, g, beta, slots, reset, hb: int, interpret: bool):
-    """pool ``[N, H / 2, dk, 2 dv]``; the rows as ``gdn_step`` takes them."""
-    s, h, dk = q.shape
-    dv = v.shape[-1]
-    hp = h // 2
-    hg = hp // hb
-    cols = lambda x: jnp.swapaxes(x.reshape(s, hg, 2 * hb, dk), 2, 3)
-
-    def rows(x):
-        if x.ndim == 2:
-            x = jnp.broadcast_to(x[..., None], (s, h, dv))
-        return x.reshape(s, hg, hb, 2 * dv)
-
-    kernel = functools.partial(_pair_step_kernel, hb=hb, dv=dv)
-    col_spec = pl.BlockSpec((1, 1, dk, 2 * hb), lambda i, j, sl, rs: (i, j, 0, 0))
-    row_spec = pl.BlockSpec((1, 1, hb, 2 * dv), lambda i, j, sl, rs: (i, j, 0, 0))
-    pool_spec = pl.BlockSpec((1, hb, dk, 2 * dv), lambda i, j, sl, rs: (sl[i], j, 0, 0))
-    o, pool = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2, grid=(s, hg),
-            in_specs=[col_spec, col_spec, row_spec, row_spec, row_spec, pool_spec],
-            out_specs=[row_spec, pool_spec]),
-        out_shape=[jax.ShapeDtypeStruct((s, hg, hb, 2 * dv), F32), jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
-        input_output_aliases={7: 1}, interpret=interpret,
-    )(slots.astype(jnp.int32), reset.astype(jnp.int32), cols(q), cols(k), rows(v), rows(jnp.exp(g)),
-      rows(beta), pool)
-    return o.reshape(s, h, dv), pool
-
-
-def _pairs(pool):               # [N, H, dk, dv] -> [N, H / 2, dk, 2 dv]
-    n, h, dk, dv = pool.shape
-    return jnp.moveaxis(pool.reshape(n, h // 2, 2, dk, dv), 2, 3).reshape(n, h // 2, dk, 2 * dv)
-
-
-def _unpairs(pool, dv):
-    n, hp, dk, _ = pool.shape
-    return jnp.moveaxis(pool.reshape(n, hp, dk, 2, dv), 3, 2).reshape(n, 2 * hp, dk, dv)
 
 
 def _padded(layout, pool, q, k, v, g, beta):
@@ -123,11 +63,10 @@ def form(name):
     layout, hb = rest.rsplit("-hb", 1)
     hb = int(hb)
     if layout == "pairs":
-        assert kern == "step"
         prep = lambda pool, q, k, v, g, beta: (
-            (_pairs(pool), q, k, v, g, beta), lambda o, p: (o, _unpairs(p, v.shape[-1])))
-        return kern, prep, lambda interpret: lambda *a: _pair_step_call(*a, hb=hb, interpret=interpret)
-    prep = functools.partial(_padded, layout)
+            (gdr._pairs(pool), q, k, v, g, beta), lambda o, p: (o, gdr._unpairs(p)))
+    else:
+        prep = functools.partial(_padded, layout)
     if kern == "step":
         return kern, prep, lambda interpret: lambda *a: gdr._gdn_step_call(*a, hb=hb, interpret=interpret)
     return kern, prep, lambda interpret: lambda *a, tile: gdr._gdn_chunk_call(
@@ -157,7 +96,7 @@ def rehearse(names):
         kern, prep, call = form(name)
         hb = int(name.rsplit("-hb", 1)[1])
         h = 2 * 3 * hb if "dead32" not in name else 30
-        pool, rows, where = inputs(kern, h, 24, 48, 8, 8 if kern == "step" else 256, 32)
+        pool, rows, where = inputs(kern, h, 24, 64 if "pairs" in name else 48, 8, 8 if kern == "step" else 256, 32)
         if "dk128" in name or "dv256" in name or "dead32" in name:
             pool, rows, where = inputs(kern, 30, DK, DV, 8, 8 if kern == "step" else 256, 32)
         ops, cut = prep(pool, *rows)

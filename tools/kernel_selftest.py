@@ -747,6 +747,33 @@ def ssm_case(which: str, layers: int = 26, repeats: int = 10) -> dict:
             "least_us": round(moved / 819e9 * 1e6, 1)}
 
 
+def _us_a_layer_call(call, pool, ops, layers: int, repeats: int) -> float:
+    """Microseconds a call of ``call(pool, *ops) -> (o, pool)`` by the
+    host's clock: ``layers`` copies of ``pool`` donated to one jitted
+    function that calls it on each (one a layer, as a model has), warmed
+    once, ``repeats`` times."""
+    import time
+
+    import jax
+
+    def stacked(pools, *rest):
+        y, new = 0.0, []
+        for p in pools:
+            o, p = call(p, *rest)
+            y, new = y + o, new + [p]
+        return y, new
+
+    run = jax.jit(stacked, donate_argnums=0)
+    pools = [pool + 0.0 for _ in range(layers)]
+    y, pools = run(pools, *ops)
+    y.block_until_ready()
+    t0 = time.perf_counter()
+    for _ in range(repeats):
+        y, pools = run(pools, *ops)
+    y.block_until_ready()
+    return (time.perf_counter() - t0) / repeats / layers * 1e6
+
+
 def gdn_chunk_cell_case(call=None, layers: int = 6, repeats: int = 10,
                         check: bool = True) -> dict:
     """The chunked delta rule at the Qwen3-Next cell's shape (1,024 rows in
@@ -761,8 +788,6 @@ def gdn_chunk_cell_case(call=None, layers: int = 6, repeats: int = 10,
     HBM peak) and that least time's share of the call, per cent.  ``call``
     (default ``gdn_chunk``) lets a chip script time another form of the
     kernel on the same inputs."""
-    import time
-
     import jax
     import jax.numpy as jnp
 
@@ -792,26 +817,75 @@ def gdn_chunk_cell_case(call=None, layers: int = 6, repeats: int = 10,
         err = max(float(jnp.max(jnp.abs(g - w))) for g, w in zip(got, want))
         out = {"max_err": round(err / scale, 7), "ok": bool(err / scale < 1e-3)}
 
-    def stacked(pools, *rest):
-        y, new = 0.0, []
-        for p in pools:
-            o, p = call(p, *rest, tile)
-            y, new = y + o, new + [p]
-        return y, new
-
-    run = jax.jit(stacked, donate_argnums=0)
-    pools = [pool + 0.0 for _ in range(layers)]
-    y, pools = run(pools, *ops)
-    y.block_until_ready()
-    t0 = time.perf_counter()
-    for _ in range(repeats):
-        y, pools = run(pools, *ops)
-    y.block_until_ready()
-    us = (time.perf_counter() - t0) / repeats / layers * 1e6
+    us = _us_a_layer_call(lambda p, *a: call(p, *a, tile), pool, ops, layers,
+                          repeats)
     flops = live * h * (7 * d * d + 2 * d)
     moved = (seqs * 2 * h * d * d + live * h * (4 * d + 2)) * 4
     least = max(flops / 197e12, moved / 819e9) * 1e6
     return {**out, "us_per_call": round(us, 1), "least_us": round(least, 1),
+            "least_share_pct": round(100 * least / us, 2)}
+
+
+def gdn_olmo_cell_case(kernel: str, natural: bool = False, layers: int = 6,
+                       repeats: int = 10, slots: int = 128) -> dict:
+    """The delta rule's ``kernel`` (``"step"``: 128 one-token rows;
+    ``"chunk"``: 1,024 rows in 8 tiles of 128, three sequences of 3 + 3 + 1
+    tiles and a pad tile on the scratch slot) at the Olmo-Hybrid cell's
+    shape, 30 heads of 96 keys x 192 values with write strengths up to 2
+    over a pool of 129 slots STORED AS THE LAYOUT RULE SAYS (head pairs,
+    ``[15, 96, 384]``: ``gdr.state_leaf_shape``; ``natural``: ``[30, 96,
+    192]`` through the same kernels, 256 lanes a row in HBM), against the
+    composition on the natural pool, with microseconds a call (six pools
+    donated, the call's XLA prework included) beside the least time the
+    chip could take for the mathematics' bytes and operations
+    (``benchmark/lib/costs_gdn.py``'s counts: 702 us for the step) and that
+    least time's share of the call, per cent."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops import gated_delta_rule as gdr
+
+    h, dk, dv, tile = 30, 96, 192, 128
+    rows = slots if kernel == "step" else 1024
+    ks = jax.random.split(jax.random.fold_in(jax.random.key(0), 58), 6)
+    unit = lambda y: y / jnp.linalg.norm(y, axis=-1, keepdims=True)
+    pool = jax.random.normal(ks[5], (slots + 1, h, dk, dv))
+    if kernel == "step":
+        live, seqs = rows, rows
+        where = (jax.random.permutation(ks[5], slots)[:rows].astype(
+            jnp.int32), jnp.arange(rows) % 7 == 0)
+        call = lambda p, *a: gdr.gdn_step(p, *a, interpret=False)
+        ref = gdr.gdn_step_reference
+    else:
+        live, seqs = 7 * tile - 40, 3
+        where = (jnp.asarray([9, 9, 9, 20, 20, 20, 4, slots], jnp.int32),
+                 jnp.asarray([1, 0, 0, 0, 0, 0, 1, 0], bool))
+        call = lambda p, *a: gdr.gdn_chunk(p, *a, tile, interpret=False)
+        ref = lambda p, *a: gdr.gdn_chunk_reference(p, *a, tile)
+    real = (jnp.arange(rows) < live)[:, None]
+    ops = (unit(jax.random.normal(ks[0], (rows, h, dk))) * dk ** -0.5,
+           unit(jax.random.normal(ks[1], (rows, h, dk)) + 0.3),
+           jax.random.normal(ks[2], (rows, h, dv)),
+           jnp.where(real, -0.05 * jnp.abs(
+               jax.random.normal(ks[3], (rows, h))), 0.0),
+           jnp.where(real, 2.0 * jax.nn.sigmoid(
+               jax.random.normal(ks[4], (rows, h))), 0.0)) + where
+    paired = not natural and gdr.state_leaf_shape(h, dk, dv) != (h, dk, dv)
+    stored = gdr._pairs(pool) if paired else pool
+    o, new = call(stored, *ops)
+    got = (o[:live], (gdr._unpairs(new) if paired else new)[:slots])
+    o, new = ref(pool, *ops)
+    want = (o[:live], new[:slots])
+    scale = max(float(jnp.max(jnp.abs(w))) for w in want)
+    err = max(float(jnp.max(jnp.abs(g - w))) for g, w in zip(got, want))
+
+    us = _us_a_layer_call(call, stored, ops, layers, repeats)
+    flops = live * h * (7 * dk * dv + 2 * dv)
+    moved = (seqs * 2 * h * dk * dv + live * h * (2 * dk + 2 * dv + 2)) * 4
+    least = max(flops / 197e12, moved / 819e9) * 1e6
+    return {"max_err": round(err / scale, 7), "ok": bool(err / scale < 1e-3),
+            "pool": list(stored.shape), "us_per_call": round(us, 1),
+            "least_us": round(least, 1),
             "least_share_pct": round(100 * least / us, 2)}
 
 
@@ -1293,6 +1367,12 @@ def run_selftest(tol: float = 3e-2) -> dict:
     # microseconds a call beside the recurrence's least time
     guarded("gdn_chunk_cell", lambda: results.update(
         {"gdn_chunk_cell": gdn_chunk_cell_case()}))
+    # both kernels at the Olmo-Hybrid cell's shape on the pool of head
+    # pairs the layout rule gives, each with microseconds a call beside
+    # the least its bytes (step: 702 us) or operations need
+    for which in ("step", "chunk"):
+        guarded(f"gdn_{which}_olmo_pairs", lambda w=which: results.update(
+            {f"gdn_{w}_olmo_pairs": gdn_olmo_cell_case(w)}))
 
     # ---- selective scan (Mamba): both kernels at the Jamba2-3B cell's
     # shapes against their XLA compositions, float32 throughout ---- #
@@ -1372,6 +1452,11 @@ if __name__ == "__main__":
     if sys.argv[1:] == ["flash_train"]:     # the training kernels alone
         out = {name: flash_train_case(*shape)
                for name, shape in FLASH_TRAIN_CELLS.items()}
+        out["ok"] = all(v["ok"] for v in out.values())
+    elif sys.argv[1:] == ["gdn_olmo"]:      # the delta rule, both layouts
+        out = {f"gdn_{w}_olmo_{'natural' if n else 'pairs'}":
+               gdn_olmo_cell_case(w, natural=n)
+               for w in ("step", "chunk") for n in (False, True)}
         out["ok"] = all(v["ok"] for v in out.values())
     else:
         out = run_selftest()
