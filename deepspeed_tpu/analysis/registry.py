@@ -30,6 +30,7 @@ KERNEL_MODULES = (
     "deepspeed_tpu.ops.grouped_gemm",
     "deepspeed_tpu.ops.gated_delta_rule",
     "deepspeed_tpu.ops.selective_scan",
+    "deepspeed_tpu.ops.ssd",
     "deepspeed_tpu.ops.quantized_matmul",
     "deepspeed_tpu.ops.quantizer",
     "deepspeed_tpu.ops.block_sparse_attention",

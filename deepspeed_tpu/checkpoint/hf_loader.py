@@ -95,7 +95,9 @@ def iter_checkpoint_tensors(model_path: str):
 # Architecture name maps.  Each rule: (regex, target builder) where the
 # builder receives the match and returns (path_tuple, transform) —
 # transform "t" = transpose, None = as-is, ("stack", axis_index) = stack
-# into the leading expert axis at position axis_index.
+# into the leading expert axis at position axis_index, a callable
+# ``(tensor, config) -> array``; a LIST of (path, callable) in the path's
+# place fills several leaves from one tensor.
 # --------------------------------------------------------------------- #
 Rule = Tuple[str, Callable[[re.Match], Tuple[Tuple[str, ...], Any]]]
 
@@ -775,6 +777,71 @@ def _jamba_rules() -> List[Rule]:
     ]
 
 
+def _granitemoehybrid_rules() -> List[Rule]:
+    # Granite-4.0-H (``model_type: granitemoehybrid``) ->
+    # RaggedGraniteMoeHybrid's tree.  The head is tied to the embedding (a
+    # checkpoint's ``lm_head.weight`` is skipped); ``mamba.in_proj``'s
+    # columns are ``z | xBC | dt`` as published; the experts' and the shared
+    # expert's ``input_linear`` stack the gate's rows over the up
+    # projection's (``chunk(2)`` of the output: the FIRST half is
+    # activated), so ONE tensor fills two leaves.
+    def layer(m, *leaf):
+        return (f"layers_{m.group(1)}", *leaf)
+
+    def half(which: int):       # [..., 2 F, H] -> [..., H, F]
+        def tf(w, _c):
+            w = np.asarray(w)
+            f = w.shape[-2] // 2
+            return np.swapaxes(w[..., which * f:(which + 1) * f, :], -1, -2)
+        return tf
+
+    return [
+        (r"^model\.embed_tokens\.weight$",
+         lambda m: (("embed_tokens", "embedding"), None)),
+        (r"^model\.norm\.weight$", lambda m: (("norm", "scale"), None)),
+        (r"^lm_head\.weight$", lambda m: (None, None)),
+        (r"^model\.layers\.(\d+)\.(input_layernorm|post_attention_layernorm)"
+         r"\.weight$", lambda m: (layer(m, m.group(2), "scale"), None)),
+        (r"^model\.layers\.(\d+)\.mamba\.(in_proj|out_proj)\.weight$",
+         lambda m: (layer(m, "mamba", m.group(2), "kernel"), "t")),
+        # [channels, 1, taps] -> [taps, channels], the last tap on the
+        # current token either way
+        (r"^model\.layers\.(\d+)\.mamba\.conv1d\.weight$",
+         lambda m: (layer(m, "mamba", "conv1d", "kernel"),
+                    lambda w, _c: np.asarray(w)[:, 0, :].T)),
+        (r"^model\.layers\.(\d+)\.mamba\.conv1d\.bias$",
+         lambda m: (layer(m, "mamba", "conv1d", "bias"), None)),
+        (r"^model\.layers\.(\d+)\.mamba\.(dt_bias|A_log|D)$",
+         lambda m: (layer(m, "mamba", m.group(2)), None)),
+        (r"^model\.layers\.(\d+)\.mamba\.norm\.weight$",
+         lambda m: (layer(m, "mamba", "norm", "scale"), None)),
+        (r"^model\.layers\.(\d+)\.self_attn\.(q|k|v|o)_proj\.weight$",
+         lambda m: (layer(m, "self_attn", f"{m.group(2)}_proj", "kernel"),
+                    "t")),
+        # [E, 2 x 768, 4096] -> w_gate, w_up [E, 4096, 768]
+        (r"^model\.layers\.(\d+)\.block_sparse_moe\.input_linear\.weight$",
+         lambda m: ([(layer(m, "block_sparse_moe", "experts", "w_gate"),
+                      half(0)),
+                     (layer(m, "block_sparse_moe", "experts", "w_up"),
+                      half(1))], None)),
+        # [E, 4096, 768] -> w_down [E, 768, 4096]
+        (r"^model\.layers\.(\d+)\.block_sparse_moe\.output_linear\.weight$",
+         lambda m: (layer(m, "block_sparse_moe", "experts", "w_down"),
+                    lambda w, _c: np.swapaxes(np.asarray(w), -1, -2))),
+        (r"^model\.layers\.(\d+)\.block_sparse_moe\.router\.layer\.weight$",
+         lambda m: (layer(m, "block_sparse_moe", "gate", "wg", "kernel"),
+                    "t")),
+        (r"^model\.layers\.(\d+)\.shared_mlp\.input_linear\.weight$",
+         lambda m: ([(layer(m, "block_sparse_moe", "shared_expert",
+                            "gate_proj", "kernel"), half(0)),
+                     (layer(m, "block_sparse_moe", "shared_expert",
+                            "up_proj", "kernel"), half(1))], None)),
+        (r"^model\.layers\.(\d+)\.shared_mlp\.output_linear\.weight$",
+         lambda m: (layer(m, "block_sparse_moe", "shared_expert",
+                          "down_proj", "kernel"), "t")),
+    ]
+
+
 _ARCH_RULES: Dict[str, Callable[[], List[Rule]]] = {
     "llama": _llama_rules,
     "mistral": _llama_rules,     # same architecture/serialization
@@ -789,6 +856,7 @@ _ARCH_RULES: Dict[str, Callable[[], List[Rule]]] = {
     "afmoe": _afmoe_rules,
     "ouro": _ouro_rules,
     "jamba": _jamba_rules,
+    "granitemoehybrid": _granitemoehybrid_rules,
     "gpt2": _gpt2_rules,
     "opt": _opt_rules,
     "falcon": _falcon_rules,
@@ -1000,6 +1068,16 @@ def config_from_hf(model_path: str, dtype: Any = None):
         # (mamba_proj_bias, a sliding window: the config refuses each by
         # name)
         return arch, JambaConfig(
+            **{k: v for k, v in cfg.items() if k in fields}, dtype=dt)
+    if arch == "granitemoehybrid":
+        from deepspeed_tpu.inference.v2.model_implementations. \
+            ragged_granite_moe_hybrid import GraniteMoeHybridConfig
+
+        fields = {f.name for f in dataclasses.fields(GraniteMoeHybridConfig)
+                  } - {"dtype", "held_experts", "expert_start"}
+        # (more than one group, a rotary variant, a bias in the
+        # projections, an untied head: the config refuses each by name)
+        return arch, GraniteMoeHybridConfig(
             **{k: v for k, v in cfg.items() if k in fields}, dtype=dt)
     if arch == "gpt2":
         from deepspeed_tpu.models.gpt2 import GPT2Config
@@ -1219,6 +1297,10 @@ def load_hf_checkpoint(model_path: str, architecture: Optional[str] = None,
                 continue
             path, tf = fn(m)
             if path is None:            # deliberately skipped tensor
+                break
+            if isinstance(path, list):  # one tensor, several leaves
+                for leaf, part in path:
+                    place(leaf, part(tensor, file_cfg))
                 break
             if isinstance(tf, tuple) and tf[0] == "stack":
                 stacks.setdefault(path, {})[tf[1]] = np.asarray(tensor).T

@@ -1,6 +1,6 @@
 """Inference v2 model implementations (reference:
 inference/v2/model_implementations/ — llama_v2, opt, mistral, mixtral,
-falcon families; qwen3_next, deepseek_v3 (and glm_moe_dsa through it), longcat_flash, lfm2_moe, afmoe, ouro, jamba and olmo_hybrid have
+falcon families; qwen3_next, deepseek_v3 (and glm_moe_dsa through it), longcat_flash, lfm2_moe, afmoe, ouro, jamba, olmo_hybrid and granite_moe_hybrid have
 no reference counterpart).  One file a family (config, parameter shapes,
 class) over the shared layers of ``inference/v2/modules/``."""
 
@@ -24,6 +24,10 @@ from deepspeed_tpu.inference.v2.model_implementations.ragged_opt import (
 from deepspeed_tpu.inference.v2.model_implementations.ragged_deepseek_v3 import (
     DeepseekV3Config,
     RaggedDeepseekV3,
+)
+from deepspeed_tpu.inference.v2.model_implementations.ragged_granite_moe_hybrid import (
+    GraniteMoeHybridConfig,
+    RaggedGraniteMoeHybrid,
 )
 from deepspeed_tpu.inference.v2.model_implementations.ragged_jamba import (
     JambaConfig,
@@ -79,9 +83,11 @@ HF_MODELS = {
     "afmoe": (RaggedAfmoe, False),
     "ouro": (RaggedOuro, False),
     "jamba": (RaggedJamba, False),
+    "granitemoehybrid": (RaggedGraniteMoeHybrid, True),
 }
 
-__all__ = ["AfmoeConfig", "DeepseekV3Config", "HF_MODELS", "RaggedAfmoe",
+__all__ = ["AfmoeConfig", "DeepseekV3Config", "GraniteMoeHybridConfig",
+           "HF_MODELS", "RaggedAfmoe", "RaggedGraniteMoeHybrid",
            "RaggedDeepseekV3", "JambaConfig", "RaggedJamba", "Lfm2Config", "LongcatFlashConfig", "RaggedLongcatFlash", "OlmoHybridConfig", "RaggedOlmoHybrid", "OuroConfig", "Qwen3NextConfig", "RaggedLfm2", "RaggedLlama", "RaggedMistral", "RaggedMixtral",
            "RaggedOPT", "RaggedFalcon", "RaggedOuro", "RaggedQwen3Next",
            "ragged_param_specs", "shard_ragged_params"]

@@ -406,7 +406,11 @@ def ragged_attention_block(lp_attn, xa, layer_cache, batch, block_size, cfg,
     narrower than half a head rotate only the head's first dims (partial
     rotary); ``cfg.attn_output_gate``: ``q_proj`` emits, per head, the
     query and a gate, and the attention output is multiplied by the gate's
-    sigmoid before ``o_proj``."""
+    sigmoid before ``o_proj``; ``cfg.query_scale`` (Granite's
+    ``attention_multiplier x sqrt(d)``): ``q`` times it right after the
+    projection, so the ``1/sqrt(d)`` of every read below is that model's
+    own scale.  A config without the attribute keeps its program to the
+    letter."""
     dt = cfg.dtype
     kv_dest = batch["kv_dest"]
     # OLMoE / OLMo-2 (static: the layer's own parameters say so): RMSNorm
@@ -427,6 +431,8 @@ def ragged_attention_block(lp_attn, xa, layer_cache, batch, block_size, cfg,
             q, gate = jnp.split(q.reshape(-1, h, 2 * d), 2, axis=-1)
         if qk_norm and not headwise:
             q = _rms_norm(q, lp_attn["q_norm"]["scale"], cfg.rms_norm_eps)
+        if getattr(cfg, "query_scale", None) is not None:
+            q = (q.astype(jnp.float32) * cfg.query_scale).astype(q.dtype)
         q = q.reshape(-1, h, d)
         k = qmm(xa, lp_attn["k_proj"]["kernel"], dt)
         if qk_norm and not headwise:

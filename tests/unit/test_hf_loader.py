@@ -632,3 +632,149 @@ def test_jamba_with_routed_experts_is_refused_by_name(tmp_path):
         json.dump({**JAMBA, "num_experts": 16, "num_experts_per_tok": 2}, fh)
     with pytest.raises(HFLoadError, match="num_experts=16"):
         config_from_hf(str(tmp_path))
+
+
+# ------------------------------------------------------------------ #
+# Granite-4.0-H: a synthetic state dict under the published tensor names
+# ------------------------------------------------------------------ #
+GRANITE = {"model_type": "granitemoehybrid", "vocab_size": 128,
+           "hidden_size": 32, "intermediate_size": 16,
+           "shared_intermediate_size": 24, "num_hidden_layers": 3,
+           "layer_types": ["mamba", "attention", "mamba"],
+           "num_attention_heads": 2, "num_key_value_heads": 1,
+           "mamba_n_heads": 4, "mamba_d_head": 16, "mamba_d_state": 8,
+           "mamba_n_groups": 1, "mamba_d_conv": 4, "mamba_expand": 2,
+           "mamba_conv_bias": True, "mamba_proj_bias": False,
+           "mamba_chunk_size": 256, "attention_bias": False,
+           "position_embedding_type": "nope", "num_local_experts": 6,
+           "num_experts_per_tok": 2, "embedding_multiplier": 12,
+           "residual_multiplier": 0.22, "attention_multiplier": 0.0078125,
+           "logits_scaling": 16, "rms_norm_eps": 1e-5,
+           "max_position_embeddings": 256, "tie_word_embeddings": True,
+           "hidden_act": "silu", "normalization_function": "rmsnorm",
+           "rope_theta": 10000, "rope_scaling": None}
+
+
+def test_granitemoehybrid_rules_on_a_synthetic_state_dict(tmp_path):
+    """Tensors named and laid out as the published checkpoint has them
+    ([out, in] matrices, ``mamba.in_proj`` rows ``z | xBC | dt``,
+    ``conv1d.weight`` [channels, 1, taps] with its bias, ``dt_bias`` /
+    ``A_log`` / ``D`` a head, the gated norm, ``block_sparse_moe.
+    input_linear`` [E, 2 F, H] with the gate's rows first, ``output_linear``
+    [E, H, F], ``router.layer``, ``shared_mlp``, a tied ``lm_head.weight``)
+    load into ``RaggedGraniteMoeHybrid``'s tree, and the engine built from
+    the directory serves the plain reference's logits, the reference fed
+    the same tensors by their published meaning."""
+    import json
+    import os
+    import sys
+
+    from safetensors.numpy import save_file
+
+    repo = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    if repo not in sys.path:
+        sys.path.insert(0, repo)
+    from benchmark.reference import granite_moe_hybrid as reference
+    from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
+                                            RaggedInferenceEngineConfig)
+    from deepspeed_tpu.inference.v2.model_implementations import \
+        ragged_granite_moe_hybrid as rg
+
+    rng = np.random.default_rng(9)
+    h, f, fs, di, n, hm, e = 32, 16, 24, 64, 8, 4, 6
+    g = lambda *s: rng.standard_normal(s).astype(np.float32)
+    norm = lambda w: rng.uniform(0.5, 1.5, w).astype(np.float32)
+    sd = {"model.embed_tokens.weight": 0.1 * g(128, h),
+          "model.norm.weight": norm(h)}
+    sd["lm_head.weight"] = sd["model.embed_tokens.weight"]
+    ref_layers = []
+    for i in range(3):
+        pre = f"model.layers.{i}."
+        sd[pre + "input_layernorm.weight"] = norm(h)
+        sd[pre + "post_attention_layernorm.weight"] = norm(h)
+        moe = pre + "block_sparse_moe."
+        sd[moe + "input_linear.weight"] = g(e, 2 * f, h) * h ** -0.5
+        sd[moe + "output_linear.weight"] = g(e, h, f) * f ** -0.5
+        sd[moe + "router.layer.weight"] = g(e, h) * h ** -0.5
+        sd[pre + "shared_mlp.input_linear.weight"] = g(2 * fs, h) * h ** -0.5
+        sd[pre + "shared_mlp.output_linear.weight"] = g(h, fs) * fs ** -0.5
+        w_in, s_in = sd[moe + "input_linear.weight"], \
+            sd[pre + "shared_mlp.input_linear.weight"]
+        lp = {"ln1": sd[pre + "input_layernorm.weight"],
+              "ln2": sd[pre + "post_attention_layernorm.weight"],
+              "router": sd[moe + "router.layer.weight"].T,
+              "w_gate": w_in[:, :f].transpose(0, 2, 1),
+              "w_up": w_in[:, f:].transpose(0, 2, 1),
+              "w_down": sd[moe + "output_linear.weight"].transpose(0, 2, 1),
+              "s_gate": s_in[:fs].T, "s_up": s_in[fs:].T,
+              "s_down": sd[pre + "shared_mlp.output_linear.weight"].T}
+        if i == 1:
+            for name, rows in (("q", 32), ("k", 16), ("v", 16)):
+                sd[pre + f"self_attn.{name}_proj.weight"] = \
+                    (6.0 if name in "qk" else 1.0) * g(rows, h) * h ** -0.5
+            sd[pre + "self_attn.o_proj.weight"] = g(h, 32) * 32 ** -0.5
+            lp.update({f"w{k}": sd[pre + f"self_attn.{k}_proj.weight"].T
+                       for k in "qkvo"})
+        else:
+            m = pre + "mamba."
+            sd[m + "in_proj.weight"] = g(2 * di + 2 * n + hm, h) * h ** -0.5
+            sd[m + "conv1d.weight"] = g(di + 2 * n, 1, 4) * 0.5
+            sd[m + "conv1d.bias"] = g(di + 2 * n)
+            sd[m + "dt_bias"] = -3.0 + 0.5 * g(hm)
+            sd[m + "A_log"] = np.log(rng.uniform(0.25, 4, (hm,))).astype(
+                np.float32)
+            sd[m + "D"] = g(hm)
+            sd[m + "norm.weight"] = norm(di)
+            sd[m + "out_proj.weight"] = g(h, di) * di ** -0.5
+            lp.update(
+                w_in=sd[m + "in_proj.weight"].T,
+                taps=sd[m + "conv1d.weight"][:, 0, :].T,
+                conv_bias=sd[m + "conv1d.bias"], dt_bias=sd[m + "dt_bias"],
+                A_log=sd[m + "A_log"], D=sd[m + "D"],
+                gnorm=sd[m + "norm.weight"],
+                w_out=sd[m + "out_proj.weight"].T)
+        ref_layers.append(lp)
+    save_file(sd, str(tmp_path / "model.safetensors"))
+    with open(tmp_path / "config.json", "w") as fh:
+        json.dump(GRANITE, fh)
+
+    arch, cfg = config_from_hf(str(tmp_path), jnp.float32)
+    assert arch == "granitemoehybrid" \
+        and type(cfg) is rg.GraniteMoeHybridConfig
+    assert cfg.layer_types == ("mamba", "attention", "mamba")
+    assert (cfg.held_experts, cfg.num_local_experts) == (None, 6)
+    params = load_hf_checkpoint(str(tmp_path), dtype=jnp.float32)
+    assert jax.tree.map(lambda a: a.shape, params) == jax.tree.map(
+        lambda a: a.shape, rg.param_shapes(cfg))
+    assert "lm_head" not in params                  # tied: the embedding
+    assert params["layers_0"]["block_sparse_moe"]["experts"][
+        "w_gate"].shape == (e, h, f)
+
+    eng = InferenceEngineV2.from_hf(
+        str(tmp_path), RaggedInferenceEngineConfig.from_dict({
+            "state_manager": {"max_ragged_batch_size": 128,
+                              "max_ragged_sequence_count": 2,
+                              "max_context": 256},
+            "kv_cache": {"block_size": 8, "num_blocks": 40}}),
+        dtype=jnp.float32)
+    assert type(eng.model) is rg.RaggedGraniteMoeHybrid
+    ids = rng.integers(0, 128, size=(150 + 3,))
+    got = [np.asarray(eng.put([1], [ids[:150].tolist()])[1], np.float32)]
+    for t in ids[150:]:
+        got.append(np.asarray(jax.device_get(
+            eng.decode_step([1], [int(t)])), np.float32)[0])
+    ref = jax.tree.map(jnp.asarray, {
+        "embed": sd["model.embed_tokens.weight"], "layers": ref_layers,
+        "norm": sd["model.norm.weight"]})
+    want = reference.logits_at(ref, ids, GRANITE, rows=list(range(149, 153)))
+    assert np.max(np.abs(np.stack(got) - want)) / np.max(np.abs(want)) < 1e-4
+
+
+def test_granitemoehybrid_with_groups_is_refused_by_name(tmp_path):
+    import json
+
+    with open(tmp_path / "config.json", "w") as fh:
+        json.dump({**GRANITE, "mamba_n_groups": 4}, fh)
+    with pytest.raises(NotImplementedError, match="mamba_n_groups=4"):
+        config_from_hf(str(tmp_path))

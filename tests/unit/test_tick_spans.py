@@ -1212,6 +1212,8 @@ _KERNEL_ENTRIES = [
     ("gdn_chunk", "_gdn_chunk_kernel"),
     ("ssm_step", "_ssm_step_kernel"),
     ("ssm_chunk", "_ssm_chunk_kernel"),
+    ("ssd_step", "_ssd_step_kernel"),
+    ("ssd_chunk", "_ssd_chunk_kernel"),
     ("quantized_matmul", "_qmm_kernel"),
     ("quantizer_int8", "_quantize_kernel"),
     ("block_sparse_attention", "_fwd_kernel"),
@@ -1250,7 +1252,7 @@ def test_every_pallas_call_site_is_covered():
         src = (root.parent / (mod.replace(".", "/") + ".py")).read_text()
         sites += len(re.findall(r"pl\.pallas_call\(", src))
         named += len(re.findall(r"\*\*kernel_names\(", src))
-    assert sites == named == 27
+    assert sites == named == 29
 
 
 def test_paged_wrappers_keep_their_instruction_names():

@@ -747,6 +747,65 @@ def ssm_case(which: str, layers: int = 26, repeats: int = 10) -> dict:
             "least_us": round(moved / 819e9 * 1e6, 1)}
 
 
+def ssd_case(which: str, layers: int = 9, repeats: int = 10,
+             block: int = 0) -> dict:
+    """Mamba-2's recurrence at the Granite-4.0-H cell's shapes (128 heads
+    of 64, N 128, a pool of 129 slots) against its XLA composition, with
+    microseconds a call beside the least time the chip could take for what
+    the recurrence requires (bytes over the HBM peak: every live slot read
+    and written once, the rows once).  ``which``: ``step`` (128 rows, 20 of
+    them pad rows on the scratch slot, 2 reset) or ``chunk`` (1,024 rows in
+    8 tiles of 128: three sequences of 3 + 3 + 1 tiles, the last 40 rows
+    short, and a pad tile).  ``block``: channels a grid step (0: the
+    kernel's own)."""
+    import jax
+    import jax.numpy as jnp
+
+    from deepspeed_tpu.ops import ssd
+
+    h, p, n, slots = 128, 64, 128, 128
+    di = h * p
+    rows = 128 if which == "step" else 1024
+    ks = jax.random.split(jax.random.fold_in(jax.random.key(0), 59), 7)
+    pool = jax.random.normal(ks[0], (slots + 1, n, di))
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (rows, h)) - 4.0)
+    x = jax.random.normal(ks[2], (rows, di))
+    b, c = (jax.random.normal(k, (rows, n)) for k in ks[3:5])
+    a = -jnp.exp(jax.random.uniform(ks[5], (h,), minval=-2.0, maxval=2.8))
+    if which == "step":
+        live = 108
+        slot = jnp.where(jnp.arange(rows) < live,
+                         jax.random.permutation(ks[6], slots)[:rows], slots)
+        dt = jnp.where((jnp.arange(rows) < live)[:, None], dt, 0.0)
+        args = (slot.astype(jnp.int32), jnp.arange(rows) % 70 == 5)
+        cb = block or ssd.STEP_BLOCK
+        kernel = lambda pl_, *r: ssd._ssd_step_call(pl_, *r, cb, False)
+        oracle = ssd.ssd_step_reference
+        moved = live * (2 * n * di * 4 + (3 * di + 2 * n) * 4)
+        keep = lambda y, pl_: (y[:live], pl_[:slots])
+    else:
+        dt = jnp.where((jnp.arange(rows) < 856)[:, None], dt, 0.0)
+        args = (jnp.asarray([9, 9, 9, 100, 100, 100, 4, slots], jnp.int32),
+                jnp.asarray([1, 0, 0, 0, 0, 0, 1, 0], bool))
+        cb = block or ssd.CHUNK_BLOCK
+        kernel = lambda pl_, *r: ssd._ssd_chunk_call(pl_, *r, 128, cb, False)
+        oracle = lambda *r: ssd.ssd_chunk_reference(*r, 128)
+        moved = 3 * 2 * n * di * 4 + 856 * (3 * di + 2 * n) * 4
+        keep = lambda y, pl_: (y[:856], pl_[:slots])
+    ops = (pool, dt * a, ssd.head_lanes(dt, di) * x, b, c) + args
+    got, want = keep(*kernel(*ops)), keep(*oracle(*ops))
+    scale = max(float(jnp.max(jnp.abs(w))) for w in want)
+    err = max(float(jnp.max(jnp.abs(g - w))) for g, w in zip(got, want))
+    us = _us_a_layer_call(kernel, pool, ops[1:], layers, repeats)
+    # the step is the composition's own arithmetic; the matmul form sums a
+    # chunk in another order at float32 passes (1.2e-4 of the largest
+    # value on the chip, PR 59)
+    tol = 1e-5 if which == "step" else 1e-3
+    return {"max_err": round(err / scale, 7), "ok": bool(err / scale < tol),
+            "block": cb, "us_per_call": round(us, 1),
+            "least_us": round(moved / 819e9 * 1e6, 1)}
+
+
 def _us_a_layer_call(call, pool, ops, layers: int, repeats: int) -> float:
     """Microseconds a call of ``call(pool, *ops) -> (o, pool)`` by the
     host's clock: ``layers`` copies of ``pool`` donated to one jitted
@@ -1379,6 +1438,8 @@ def run_selftest(tol: float = 3e-2) -> dict:
     for which in ("step", "chunk"):
         guarded("ssm_" + which, lambda w=which: results.update(
             {"ssm_" + w: ssm_case(w)}))
+        guarded("ssd_" + which, lambda w=which: results.update(
+            {"ssd_" + w: ssd_case(w)}))
 
     # ---- int8-resident quantized matmul ---- #
     from deepspeed_tpu.ops.quantized_matmul import (
@@ -1457,6 +1518,17 @@ if __name__ == "__main__":
         out = {f"gdn_{w}_olmo_{'natural' if n else 'pairs'}":
                gdn_olmo_cell_case(w, natural=n)
                for w in ("step", "chunk") for n in (False, True)}
+        out["ok"] = all(v["ok"] for v in out.values())
+    elif sys.argv[1:2] == ["ssd"]:          # Mamba-2, blocks as given
+        out = {}
+        for w, blocks in (("step", sys.argv[2:3] or ["0"]),
+                          ("chunk", sys.argv[3:4] or ["0"])):
+            for b in map(int, blocks[0].split(",")):
+                try:
+                    out[f"ssd_{w}_b{b}"] = ssd_case(w, block=b)
+                except Exception as e:      # a block the compiler refuses
+                    out[f"ssd_{w}_b{b}"] = {"ok": False,
+                                            "error": repr(e)[:300]}
         out["ok"] = all(v["ok"] for v in out.values())
     else:
         out = run_selftest()
