@@ -758,10 +758,32 @@ def grouped_moe_ffn(x: jnp.ndarray, topi: jnp.ndarray, topw: jnp.ndarray,
         # token's k weighted rows.  A scatter-add over the token index
         # serialises on the TPU: ten layers at H = 2048, k = 8 on a v5e
         # took 6.58 ms against 1.98 at 1056 tokens, 2.38 against 1.07 at
-        # 544, 0.22 against 0.23 at 32 (PERF.md, PR 25, chip call 4)
-        back = down[dest].astype(jnp.float32).reshape(t, k, h)
-        return jnp.sum(back * topw.astype(jnp.float32)[..., None],
-                       axis=1).astype(x.dtype)
+        # 544, 0.22 against 0.23 at 32 (PERF.md, PR 25, chip call 4).
+        # The gather is CHOICE-major, ``[k, T, H]``: slab j holds every
+        # token's j-th choice with T and H where ``down`` has them, and the
+        # k terms are written out so that the widening to float32 stays
+        # inside the one fusion that reads the slabs once in ``down``'s
+        # dtype and writes ``[T, H]`` once.  Token-major (``[T, k, H]`` and
+        # a sum over axis 1) puts k on the sublanes; at a k that is no whole
+        # tile XLA then writes a float32 relayout of all T x k rows in front
+        # of the reduce (compiled for a v5e, temporaries / XLA's own cycles
+        # before -> now: 396.5 MB / 1,402,990 + the relayout -> 94.5 MB /
+        # 290,879 at (T, k, H) = (1152, 10, 4096); 138.5 MB / 687,414 + the
+        # relayout -> 0 / 141,163 at (1056, 10, 2048); 0 / 14,532 -> 0 /
+        # 11,325 at (32, 8, 2048); on the chip, a layer's combine alone:
+        # 1,311 -> 412 us, 522 -> 160, 5.5 -> 4.8; k gathers of [T, H]
+        # read 409 / 131 / 10.2, slower than before at every decode shape;
+        # PERF.md, PR 60, chip call 1).  A held-out slot's
+        # ``dest`` is ``m_rows``: its gather is clamped to ``down``'s last
+        # row, a real row or one of the rows past the last group, which the
+        # kernel writes as zeros, and its weight is zero: nothing that is
+        # not a number enters through it
+        rows = down[dest.reshape(t, k).T]                # [k, T, H]
+        w = topw.astype(jnp.float32)
+        acc = rows[0].astype(jnp.float32) * w[:, 0, None]
+        for j in range(1, k):
+            acc = acc + rows[j].astype(jnp.float32) * w[:, j, None]
+        return acc.astype(x.dtype)
 
 
 # --------------------------------------------------------------------- #

@@ -428,6 +428,101 @@ def test_grouped_moe_ffn_differentiable():
 
 
 # ------------------------------------------------------------------ #
+# The combine (PR 60): the k choices gathered choice-major and summed as k
+# written-out float32 terms, against the dense composition of every held
+# expert over every token.
+# ------------------------------------------------------------------ #
+def _combine_case(t, k, share, dtype, seed):
+    """Inputs of one combine case: 16 held experts; behind ``share`` they
+    are ids [8, 24) of a router 32 wide, and tokens 0-2 choose outside
+    the share alone."""
+    rng = np.random.default_rng(seed)
+    h, f, e = 64, 128, 16
+    start, wide = (8, 32) if share else (None, e)
+    x = jnp.asarray(rng.standard_normal((t, h)) * 0.5, dtype)
+    ws = [jnp.asarray(rng.standard_normal(s) * 0.1, dtype)
+          for s in ((e, h, f), (e, h, f), (e, f, h))]
+    logits = rng.standard_normal((t, wide))
+    if share:
+        logits[:3, 8:24] -= 100.0
+    topw, topi = jax.lax.top_k(jax.nn.softmax(jnp.asarray(
+        logits, jnp.float32), -1), k)
+    return x, topi.astype(jnp.int32), topw, ws, start
+
+
+def _dense_moe(x, topi, topw, wg, wu, wd, start):
+    """Every held expert over every token in float32, each token's k
+    weights on its experts' columns; a choice outside the share has no
+    column (``one_hot`` of an id out of range is a zero row)."""
+    e = wg.shape[0]
+    ids = topi - (start or 0)
+    comb = jnp.sum(jax.nn.one_hot(ids, e) * topw[..., None], axis=1)
+    x, wg, wu, wd = (a.astype(jnp.float32) for a in (x, wg, wu, wd))
+    hmid = jax.nn.silu(jnp.einsum("th,ehf->etf", x, wg)) * \
+        jnp.einsum("th,ehf->etf", x, wu)
+    return jnp.einsum("etf,efh,te->th", hmid, wd, comb)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("share", [False, True], ids=["whole", "share"])
+@pytest.mark.parametrize("t", [32, 40, 132])
+@pytest.mark.parametrize("k", [4, 6, 8, 10, 12])
+def test_combine_sums_k_slabs(k, t, share, dtype, monkeypatch):
+    """``grouped_moe_ffn`` and its gradients against the dense composition
+    at the cells' top-k (4 LFM2 / Trinity, 6 Moonlight, 8 OLMoE / GLM-5, 10
+    Qwen3-Next / Granite, 12 LongCat), at token counts of whole bf16 row
+    tiles (32), of whole float32 tiles only (40) and of neither (132 = 1056
+    / 8), on all of a router's experts and on a share of them.  The down
+    projection's rows past the last group, which the kernel leaves zero and
+    a held-out choice's clamped gather may land on, are set to 1e30 here:
+    a held-out choice has to weigh exactly nothing, so the result stays
+    the reference's and a token with no expert in the share reads 0."""
+    x, topi, topw, ws, start = _combine_case(t, k, share, dtype,
+                                             seed=100 * k + t)
+    f = ws[0].shape[2]
+    real_gmm = grouped_gemm.gmm
+
+    def poisoned_gmm(lhs, rhs, group_sizes, *a):
+        out = real_gmm(lhs, rhs, group_sizes, *a)
+        if rhs.shape[1] != f:                 # gate / up: left as they are
+            return out
+        past = jnp.arange(out.shape[0])[:, None] >= jnp.sum(group_sizes)
+        return jnp.where(past, jnp.asarray(1e30, out.dtype), out)
+
+    monkeypatch.setattr(grouped_gemm, "gmm", poisoned_gmm)
+
+    def ours(x, topw, wg, wu, wd):
+        return grouped_moe_ffn(x, topi, topw, wg, wu, wd, interpret=True,
+                               expert_start=start).astype(jnp.float32)
+
+    def dense(x, topw, wg, wu, wd):
+        return _dense_moe(x, topi, topw, wg, wu, wd, start)
+
+    # float32: the file's tolerance; bf16: the three GEMMs' outputs and
+    # the SwiGLU product are each rounded to 8 bits on the way
+    tol = dict(atol=1e-4, rtol=1e-4) if dtype == jnp.float32 \
+        else dict(atol=3e-2, rtol=3e-2)
+    got, want = ours(x, topw, *ws), dense(x, topw, *ws)
+    assert got.shape == (t, x.shape[1])
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), **tol)
+    if share:
+        assert np.all(np.asarray(got[:3]) == 0.0)
+
+    probe = jnp.asarray(np.random.default_rng(k + t).standard_normal(
+        got.shape), jnp.float32)
+    g_ours = jax.grad(lambda *a: jnp.sum(ours(*a) * probe),
+                      argnums=(0, 1, 2, 3, 4))(x, topw, *ws)
+    g_dense = jax.grad(lambda *a: jnp.sum(dense(*a) * probe),
+                       argnums=(0, 1, 2, 3, 4))(x, topw, *ws)
+    for name, a, b in zip(("x", "topw", "w_gate", "w_up", "w_down"),
+                          g_ours, g_dense):
+        a, b = np.asarray(a, np.float32), np.asarray(b, np.float32)
+        scale = max(float(np.abs(b).max()), 1.0)
+        np.testing.assert_allclose(a / scale, b / scale, err_msg=name, **tol)
+
+
+# ------------------------------------------------------------------ #
 # The tile rules (PR 36): the forward's tiles from the rows an expert
 # holds and the forward's own working set, the backward's from a working
 # set with its accumulator.

@@ -14,6 +14,14 @@ each program writes
   of the COMPILED text whose result has a K/V pool's element count and dtype
   (fused computations included; a ``bitcast`` is free and not counted): each
   is a second pool written in front of a kernel;
+* ``moe_relayouts``: the instructions of the compiled text that write
+  ``T x k x H`` elements once more, for the program's T rows and the
+  configuration's top-k and hidden size: a ``reshape`` or ``convert`` that is
+  an instruction of its own, and a ``copy`` wherever it stands (inside a fused
+  computation a ``reshape`` or ``convert`` is the fusion's own arithmetic:
+  the dispatch's gather ends in one).  Each is the routed rows written again
+  between the grouped GEMM and the combine's sum (0 where the configuration
+  routes to no more than one expert);
 * ``temp_gb``: the temporaries of XLA's memory analysis.
 
     JAX_PLATFORMS=cpu python3 tools/program_text.py <checkout> <out.json> [dump=<dir>] [config ...]
@@ -52,20 +60,41 @@ from deepspeed_tpu.inference.v2 import (                    # noqa: E402
 from deepspeed_tpu.inference.v2.ragged.ragged_wrapper import (  # noqa: E402
     packed_length)
 
-RELAYOUT = re.compile(
-    r"= (\w+)\[([0-9,]+)\]\S* (copy|reshape|transpose)\(")
+WRITE = re.compile(
+    r"= (\w+)\[([0-9,]+)\]\S* (copy|reshape|transpose|convert)\(")
+
+
+def _writes(text: str):
+    """``(op, dtype, shape, elements, fused)`` of every ``copy`` /
+    ``reshape`` / ``transpose`` / ``convert`` of ``text``; ``fused``: the
+    instruction stands inside a fused computation."""
+    fused = False
+    for line in text.splitlines():
+        if line.startswith(("%fused_computation", "fused_computation")):
+            fused = True
+        elif line.startswith("}"):
+            fused = False
+        m = WRITE.search(line)
+        if m:
+            yield (m.group(3), m.group(1), m.group(2), int(np.prod(
+                [int(n) for n in m.group(2).split(",")])), fused)
 
 
 def relayouts(text: str, pools: set) -> list:
     """The instructions of ``text`` that write an array as large as a pool
     in another layout: ``(op, dtype[shape])`` each."""
-    found = []
-    for line in text.splitlines():
-        m = RELAYOUT.search(line)
-        if m and (m.group(1), int(np.prod(
-                [int(n) for n in m.group(2).split(",")]))) in pools:
-            found.append(f"{m.group(3)} {m.group(1)}[{m.group(2)}]")
-    return found
+    return [f"{op} {dtype}[{shape}]"
+            for op, dtype, shape, n, _ in _writes(text)
+            if op != "convert" and (dtype, n) in pools]
+
+
+def moe_relayouts(text: str, elements: int) -> list:
+    """The instructions of ``text`` that write ``elements`` values once more
+    in another layout or dtype: ``(op, dtype[shape])`` each."""
+    return [f"{op} {dtype}[{shape}]"
+            for op, dtype, shape, n, fused in _writes(text)
+            if n == elements and op != "transpose"
+            and (op == "copy" or not fused)]
 
 
 def without_sources(text: str) -> str:
@@ -125,21 +154,24 @@ for entry in bench["configs"]:
              for name in pooled for l in jax.tree.leaves(cache[name])}
     S = int(sv["max_ragged_sequence_count"])
     B = -(-int(sv["max_context"]) // bs)
+    # a token's routed rows: its top-k choices x the hidden size
+    topk = int(cfg.get("num_experts_per_tok", cfg.get("moe_topk", 1)))
+    routed = topk * int(cfg["hidden_size"]) if topk > 1 else 0
     grouped = getattr(eng, "_grouped", False)
     ints = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one)
     extra = ((ints(S),) if eng._stateful else ()) \
         + ((ints(S, B),) if grouped else ())
-    progs = {"decode_step": (eng._get_decode_step(),
+    progs = {"decode_step": (eng._get_decode_step(), S,
                              (ints(S, B), ints(S), ints(S)) + extra)}
     for tokens in (0, 128, int(sv["token_budget"])):
         fn = eng._get_step(S + tokens, eng.PREFILL_TILE)
-        progs[fn.__name__] = (fn, (ints(packed_length(
+        progs[fn.__name__] = (fn, S + tokens, (ints(packed_length(
             S + tokens, S, B, eng._stateful,
             **({"win": True} if grouped else {}))),))
     real_devices = jax.devices
     jax.devices = lambda *a, **k: list(topo.devices)[:1]   # route as the chip
     try:
-        for pname, (fn, args) in progs.items():
+        for pname, (fn, rows_in, args) in progs.items():
             t0 = time.time()
             traced = fn.trace(params, cache, *args)
             jaxpr = re.sub(r" at [^ \n]*\.py:\d+", "", str(traced.jaxpr))
@@ -147,6 +179,8 @@ for entry in bench["configs"]:
             compiled = traced.lower().compile()
             text = compiled.as_text()
             found = relayouts(text, pools)
+            routed_found = moe_relayouts(text, rows_in * routed) \
+                if routed else []
             plain = without_sources(text)
             for kind, txt in (("jaxpr", jaxpr), ("compiled", plain)) \
                     if dump else ():
@@ -159,6 +193,8 @@ for entry in bench["configs"]:
                 "pools": sorted(f"{d}x{n}" for d, n in pools),
                 "pool_relayouts": len(found),
                 "relayouts": sorted(set(found)),
+                "moe_relayouts": len(routed_found),
+                "moe_relayout_ops": sorted(set(routed_found)),
                 "temp_gb": round(
                     compiled.memory_analysis().temp_size_in_bytes / 1e9, 3),
                 "jaxpr_sha": sha(jaxpr), "compiled_sha": sha(plain),
