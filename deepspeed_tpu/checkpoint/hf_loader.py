@@ -360,6 +360,65 @@ def _glm_moe_dsa_rules() -> List[Rule]:
     ] + [r for r in _deepseek_v3_rules() if r"q_proj\." not in r[0]]
 
 
+def _dots3_note_rules() -> List[Rule]:
+    # dots3-note (``model_type: dots3_note``; dots3-note-prev's language
+    # model) -> RaggedDots3Note's tree: GLM-5's names (a low-rank query, the
+    # indexer on full layers) plus ``self_attn.gate_proj`` (the gate a head),
+    # with the rope rows of ``q_b_proj`` and ``kv_a_proj_with_mqa``
+    # de-interleaved at each layer's OWN widths (``layer_types[i]``: the
+    # ``swa_*`` keys on a sliding layer).  The vision tower, the audio
+    # encoder and multi-token-prediction layers are NOT served: their
+    # tensors are refused by name, not skipped.  No checkpoint is in the
+    # repository: the names are the DeepSeek-V3 family's, tested on a seeded
+    # tree.
+    def layer(m, *leaf):
+        return (f"layers_{m.group(1)}", *leaf)
+
+    def own(i: int, head_width):
+        """``_deepseek_v3_rope_rows`` at layer ``i``'s kind's widths."""
+        def tf(w, cfg):
+            pre = "swa_" if cfg["layer_types"][i] == "sliding_attention" \
+                else ""
+            view = {**cfg, **{k: cfg[pre + k] for k in (
+                "qk_nope_head_dim", "qk_rope_head_dim", "kv_lora_rank")}}
+            return _deepseek_v3_rope_rows(head_width)(w, view)
+        return tf
+
+    def refuse(what):
+        def build(m):
+            raise HFLoadError(
+                f"dots3_note: tensor {m.group(0)!r} belongs to {what}, "
+                f"which RaggedDots3Note does not serve (the language model "
+                f"alone is): drop it from the checkpoint or load with a "
+                f"model that has it")
+        return build
+
+    attn = r"^model\.layers\.(\d+)\.self_attn\."
+    q_width = lambda c: int(c["qk_nope_head_dim"]) \
+        + int(c["qk_rope_head_dim"])
+    kva_width = lambda c: int(c["kv_lora_rank"]) + int(c["qk_rope_head_dim"])
+    taken = (r"q_b_proj\.", r"kv_a_proj_with_mqa\.", r"q_proj\.")
+    return [
+        (r"^(model\.)?(visual|vision_tower|vision_model|vision_encoder)\..*$",
+         refuse("the vision tower")),
+        (r"^(model\.)?(audio_tower|audio_encoder|audio_model|audio)\..*$",
+         refuse("the audio encoder")),
+        (r"^(model\.)?(mtp|mtp_layers|nextn)\..*$",
+         refuse("multi-token prediction")),
+        (r"^model\.layers\.\d+\.(enorm|hnorm|eh_proj|shared_head)\..*$",
+         refuse("multi-token prediction")),
+        (attn + r"q_b_proj\.weight$",
+         lambda m: (layer(m, "self_attn", "q_b_proj", "kernel"),
+                    own(int(m.group(1)), q_width))),
+        (attn + r"kv_a_proj_with_mqa\.weight$",
+         lambda m: (layer(m, "self_attn", "kv_a_proj_with_mqa", "kernel"),
+                    own(int(m.group(1)), kva_width))),
+        (attn + r"gate_proj\.weight$",
+         lambda m: (layer(m, "self_attn", "gate_proj", "kernel"), "t")),
+    ] + [r for r in _glm_moe_dsa_rules()
+         if not any(t in r[0] for t in taken) and "enorm" not in r[0]]
+
+
 def _longcat_flash_rules() -> List[Rule]:
     # LongCat-Flash (``model_type: longcat_flash``; LongCat-Flash-Omni's
     # language model) -> RaggedLongcatFlash's tree: a published layer keeps
@@ -852,6 +911,7 @@ _ARCH_RULES: Dict[str, Callable[[], List[Rule]]] = {
     "deepseek_v3": _deepseek_v3_rules,
     "glm_moe_dsa": _glm_moe_dsa_rules,
     "longcat_flash": _longcat_flash_rules,
+    "dots3_note": _dots3_note_rules,
     "lfm2_moe": _lfm2_moe_rules,
     "afmoe": _afmoe_rules,
     "ouro": _ouro_rules,
@@ -999,6 +1059,25 @@ def config_from_hf(model_path: str, dtype: Any = None):
             rope_theta=float(rope.get("rope_theta",
                                       cfg.get("rope_theta", 1e6))),
             dtype=dt)
+    if arch == "dots3_note":
+        from deepspeed_tpu.inference.v2.model_implementations. \
+            ragged_dots3_note import Dots3NoteConfig
+
+        if cfg.get("rope_scaling") is not None or cfg.get("attention_bias") \
+                or cfg.get("tie_word_embeddings"):
+            raise HFLoadError(
+                "dots3_note: rope_scaling, attention_bias and a tied head "
+                "are not implemented (dots3-note-prev sets none of them)")
+        fields = {f.name for f in dataclasses.fields(Dots3NoteConfig)} \
+            - {"dtype"}
+        # (the swa_* keys, layer_types, sliding_window_size, both gate
+        # types and apply_mla_qkv_lora_rescale are fields under their
+        # published names; a gate type other than headwise and group-limited
+        # routing are refused by the config, by name; the towers and
+        # multi-token prediction have no key here and their tensors are
+        # refused by load_hf_checkpoint)
+        return arch, Dots3NoteConfig(
+            **{k: v for k, v in cfg.items() if k in fields}, dtype=dt)
     if arch == "longcat_flash":
         from deepspeed_tpu.inference.v2.model_implementations. \
             ragged_longcat_flash import LongcatFlashConfig
@@ -1324,6 +1403,15 @@ def load_hf_checkpoint(model_path: str, architecture: Optional[str] = None,
         for i in range(int(file_cfg["num_nextn_predict_layers"])):
             tree.pop(f"layers_{int(file_cfg['num_hidden_layers']) + i}",
                      None)
+    if arch == "dots3_note" and "num_hidden_layers" in file_cfg:
+        extra = sorted(k for k in tree if k.startswith("layers_")
+                       and int(k[7:]) >= int(file_cfg["num_hidden_layers"]))
+        if extra:
+            raise HFLoadError(
+                f"dots3_note: the checkpoint holds {extra} past "
+                f"num_hidden_layers={file_cfg['num_hidden_layers']} "
+                f"(multi-token-prediction layers), which RaggedDots3Note "
+                f"does not serve")
     return tree
 
 

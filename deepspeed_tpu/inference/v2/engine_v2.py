@@ -985,8 +985,8 @@ class InferenceEngineV2:
         ``read_blocks_win``, those of them inside the band, times the
         window layers.  Its longer chunks (``chunks``: (start, tokens)):
         ``attn_pairs``, their causal (query, key) pairs (a global layer's),
-        and ``attn_pairs_win``, the pairs inside the band (a window
-        layer's)."""
+        ``attn_pairs_win``, the pairs inside the band (a window layer's)
+        and ``ctx_rows_win``, the cached rows inside it."""
         sm = self.state_manager
         bs, win = sm.block_size, sm.window
         alloc = sm.win_allocator
@@ -999,7 +999,11 @@ class InferenceEngineV2:
             "read_blocks": sum(s.seen_tokens // bs + 1 for s in single_rows),
             "read_blocks_win": len(sm.kv_cache.window_layers) * sum(
                 s.seen_tokens // bs + 1 - sm._window_first(s.seen_tokens)
-                for s in single_rows)}
+                for s in single_rows),
+            # the KEYS of those blocks a row sees, ``min(t + 1, window)``
+            # each: what a band's read needs at the row's real width
+            "read_keys_win": len(sm.kv_cache.window_layers) * sum(
+                min(s.seen_tokens + 1, win) for s in single_rows)}
         if chunks:
             out["attn_pairs"] = sum(n * (2 * a + n + 1) // 2
                                     for a, n in chunks)
@@ -1009,6 +1013,11 @@ class InferenceEngineV2:
             out["attn_pairs_win"] = sum(
                 r * (2 * a + r + 1) // 2 + (n - r) * win
                 for (a, n), r in zip(chunks, ramps))
+            # the rows a chunk's queries see at all: its own and the
+            # ``window - 1`` before its first (what an expansion of the
+            # band alone expands)
+            out["ctx_rows_win"] = sum(min(a, win - 1) + n
+                                      for a, n in chunks)
         return out
 
     def _chunk_step_counters(self, chunks, tiles: int,

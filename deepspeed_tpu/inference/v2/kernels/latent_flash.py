@@ -59,12 +59,22 @@ def latent_row_width(kv_lora_rank: int, rope_dim: int) -> int:
     return -(-(kv_lora_rank + rope_dim) // LANES) * LANES
 
 
+def latent_walk_usable(kv_lora_rank: int, block_size: int) -> bool:
+    """Can the absorbed walk serve this row?  It meets the row as it lies
+    (every head against the whole padded row, the value its first
+    ``kv_lora_rank`` lanes), so the row's width decides and a head's
+    ``nope`` does not (192 + 64 at rank 1,024 walks)."""
+    return kv_lora_rank % LANES == 0 and block_size % 16 == 0
+
+
 def latent_kernels_usable(kv_lora_rank: int, nope: int, v_dim: int,
                           block_size: int) -> bool:
-    """Can the Mosaic kernels serve this geometry?  (Otherwise the model
-    takes its XLA compositions, as it does off the TPU.)"""
-    return (kv_lora_rank % LANES == 0 and nope % LANES == 0
-            and v_dim % LANES == 0 and block_size % 16 == 0)
+    """Can the Mosaic kernels serve this geometry, the expanded pair
+    included (they slice a head's ``k_nope | v`` and ``q_nope | q_pe`` at
+    lane tiles)?  (Otherwise the model takes its XLA compositions, as it
+    does off the TPU.)"""
+    return (latent_walk_usable(kv_lora_rank, block_size)
+            and nope % LANES == 0 and v_dim % LANES == 0)
 
 
 # ===================================================================== #
@@ -72,24 +82,31 @@ def latent_kernels_usable(kv_lora_rank: int, nope: int, v_dim: int,
 # ===================================================================== #
 def _latent_decode_kernel(token_slot, token_pos, tables, q_ref, pool_hbm,
                           o_ref, buf, sems, half_ref, *, block_size, scale,
-                          value_dim):
+                          value_dim, window=None):
     t = pl.program_id(0)
     rows = pl.num_programs(0)
     width = tables.shape[1]
     nblk = buf.shape[1]                   # table entries a step
 
     def span(r):
-        return token_pos[r] // block_size + 1     # 0 on a pad row (-1)
+        """[lo, hi): the table entries row ``r`` reads (empty on a pad row,
+        position -1); a window layer's start at the block of ``pos - window
+        + 1``, the band's first."""
+        lo = 0
+        if window is not None:
+            lo = jnp.maximum(0, (token_pos[r] - window + 1) // block_size)
+        return lo, token_pos[r] // block_size + 1
 
     def first_step(r):
-        """(slot, hi) of the first live row at or after ``r``; hi = 0 when
-        there is none."""
+        """(slot, lo, hi) of the first live row at or after ``r``; an empty
+        span when there is none."""
         r = jax.lax.while_loop(
             lambda r: jnp.logical_and(
                 r < rows, token_pos[jnp.minimum(r, rows - 1)] < 0),
             lambda r: r + 1, r)
         live = jnp.minimum(r, rows - 1)
-        return token_slot[live], jnp.where(r < rows, span(live), 0)
+        lo, hi = span(live)
+        return token_slot[live], lo, jnp.where(r < rows, hi, 0)
 
     def copies(slot, j0, hi, half, act):
         for u in range(nblk):
@@ -106,13 +123,12 @@ def _latent_decode_kernel(token_slot, token_pos, tables, q_ref, pool_hbm,
             # entries of a step past the row's last are never copied: what
             # stands there is masked, so it must be finite
             buf[...] = jnp.zeros_like(buf)
-        slot0, hi0 = first_step(0)
-        copies(slot0, 0, hi0, 0, lambda c: c.start())
+        copies(*first_step(0), 0, lambda c: c.start())
 
     pos = token_pos[t]
     slot = token_slot[t]
-    hi = span(t)
-    steps = (hi + nblk - 1) // nblk       # 0 on a pad row
+    lo, hi = span(t)
+    steps = (hi - lo + nblk - 1) // nblk  # 0 on a pad row
     half0 = half_ref[0]
     after = first_step(t + 1)
 
@@ -123,18 +139,22 @@ def _latent_decode_kernel(token_slot, token_pos, tables, q_ref, pool_hbm,
 
     def body(i, carry):
         m_prev, l_prev, acc = carry
-        j0 = i * nblk
+        j0 = lo + i * nblk
         half = jax.lax.rem(half0 + i, 2)
         last = i + 1 == steps
         copies(jnp.where(last, after[0], slot),
-               jnp.where(last, 0, j0 + nblk),
-               jnp.where(last, after[1], hi), 1 - half, lambda c: c.start())
+               jnp.where(last, after[1], j0 + nblk),
+               jnp.where(last, after[2], hi), 1 - half, lambda c: c.start())
         copies(slot, j0, hi, half, lambda c: c.wait())
         block = buf.at[half].reshape(cols, buf.shape[-1])[...]
         s = jax.lax.dot_general(
             q, block, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale        # [H, cols]
-        s = jnp.where(key <= pos - j0 * block_size, s, NEG_INF)
+        keep = key <= pos - j0 * block_size
+        if window is not None:
+            keep = jnp.logical_and(
+                keep, key > pos - window - j0 * block_size)
+        s = jnp.where(keep, s, NEG_INF)
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         p = jnp.exp(s - m_new)            # every row sees a key each step
         corr = jnp.exp(m_prev - m_new)
@@ -156,17 +176,22 @@ def _latent_decode_kernel(token_slot, token_pos, tables, q_ref, pool_hbm,
 
 
 @functools.partial(jax.jit, static_argnames=("block_size", "value_dim",
-                                             "scale", "interpret"))
+                                             "scale", "window", "interpret"))
 def latent_decode_attention(q: jnp.ndarray, pool: jnp.ndarray,
                             block_tables: jnp.ndarray,
                             token_slot: jnp.ndarray, token_pos: jnp.ndarray,
                             *, block_size: int, value_dim: int, scale: float,
+                            window: Any = None,
                             interpret: Any = None) -> jnp.ndarray:
     """One-token-a-row absorbed attention: q [T, H, W] (``q_lat | q_pe |
     0``, as wide as a pool row), row ``t`` the token of slot
     ``token_slot[t]`` at ``token_pos[t]`` (slots in any order, a pad row at
-    position -1); pool [rows, W].  Returns ``sum p c`` [T, H, value_dim]
-    (pad rows give zeros); the caller applies ``W_uv``."""
+    position -1); pool [rows, W].  ``window`` (a window layer's latent
+    rows): row ``t`` sees the keys ``j`` with ``pos - window < j <= pos``
+    and the walk starts at the block of ``pos - window + 1``, so the table
+    entries below it (released, the trash block) are never read.  Returns
+    ``sum p c`` [T, H, value_dim] (pad rows give zeros); the caller applies
+    ``W_uv``."""
     t_count, h, w = q.shape
     if interpret is None:
         interpret = not on_tpu()
@@ -185,7 +210,8 @@ def latent_decode_attention(q: jnp.ndarray, pool: jnp.ndarray,
                         pltpu.SMEM((1,), jnp.int32)],
     )
     kernel = functools.partial(_latent_decode_kernel, block_size=block_size,
-                               scale=scale, value_dim=value_dim)
+                               scale=scale, value_dim=value_dim,
+                               window=window)
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t_count, h, value_dim), q.dtype),

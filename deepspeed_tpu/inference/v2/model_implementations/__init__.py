@@ -1,6 +1,6 @@
 """Inference v2 model implementations (reference:
 inference/v2/model_implementations/ — llama_v2, opt, mistral, mixtral,
-falcon families; qwen3_next, deepseek_v3 (and glm_moe_dsa through it), longcat_flash, lfm2_moe, afmoe, ouro, jamba, olmo_hybrid and granite_moe_hybrid have
+falcon families; qwen3_next, deepseek_v3 (and glm_moe_dsa through it), longcat_flash, dots3_note, lfm2_moe, afmoe, ouro, jamba, olmo_hybrid and granite_moe_hybrid have
 no reference counterpart).  One file a family (config, parameter shapes,
 class) over the shared layers of ``inference/v2/modules/``."""
 
@@ -24,6 +24,10 @@ from deepspeed_tpu.inference.v2.model_implementations.ragged_opt import (
 from deepspeed_tpu.inference.v2.model_implementations.ragged_deepseek_v3 import (
     DeepseekV3Config,
     RaggedDeepseekV3,
+)
+from deepspeed_tpu.inference.v2.model_implementations.ragged_dots3_note import (
+    Dots3NoteConfig,
+    RaggedDots3Note,
 )
 from deepspeed_tpu.inference.v2.model_implementations.ragged_granite_moe_hybrid import (
     GraniteMoeHybridConfig,
@@ -79,6 +83,7 @@ HF_MODELS = {
     "deepseek_v3": (RaggedDeepseekV3, True),
     "glm_moe_dsa": (RaggedDeepseekV3, True),
     "longcat_flash": (RaggedLongcatFlash, True),
+    "dots3_note": (RaggedDots3Note, True),
     "lfm2_moe": (RaggedLfm2, False),
     "afmoe": (RaggedAfmoe, False),
     "ouro": (RaggedOuro, False),
@@ -86,7 +91,8 @@ HF_MODELS = {
     "granitemoehybrid": (RaggedGraniteMoeHybrid, True),
 }
 
-__all__ = ["AfmoeConfig", "DeepseekV3Config", "GraniteMoeHybridConfig",
+__all__ = ["AfmoeConfig", "DeepseekV3Config", "Dots3NoteConfig",
+           "RaggedDots3Note", "GraniteMoeHybridConfig",
            "HF_MODELS", "RaggedAfmoe", "RaggedGraniteMoeHybrid",
            "RaggedDeepseekV3", "JambaConfig", "RaggedJamba", "Lfm2Config", "LongcatFlashConfig", "RaggedLongcatFlash", "OlmoHybridConfig", "RaggedOlmoHybrid", "OuroConfig", "Qwen3NextConfig", "RaggedLfm2", "RaggedLlama", "RaggedMistral", "RaggedMixtral",
            "RaggedOPT", "RaggedFalcon", "RaggedOuro", "RaggedQwen3Next",

@@ -66,7 +66,8 @@ Device scopes under ``layers_<i>``: ``attn/q_proj`` (norm and ``W_q``, or
 ``attn/kv_latent`` (``W_kva``, the latent norm, rotary, the insert),
 ``attn/latent_read`` (one-token rows: absorb into q, the walk, ``W_uv``),
 ``attn/expand`` and ``attn/prefill_read`` (the tile segment),
-``attn/out_proj``; with an indexer ``attn/index_k`` (``W_Ik``, LayerNorm,
+``attn/gate`` (a layer whose ``self_attn`` holds ``gate_proj``: one sigmoid
+scalar a head on the read's output), ``attn/out_proj``; with an indexer ``attn/index_k`` (``W_Ik``, LayerNorm,
 rotary, the insert into ``idx_k``), ``attn/index_score`` (``W_Iq``, ``W_Iw``,
 the scores), ``attn/index_topk`` and ``attn/sparse_read`` (both segments) in
 place of the three reads; ``mlp`` on a dense layer; ``moe/router``,
@@ -352,33 +353,38 @@ class RaggedDeepseekV3:
                 out, new_cache[f"layer_{i}"] = self._mla(
                     lp, x, cache[f"layer_{i}"], batch, cos, sin,
                     prefill_tile, decode)
-                x = x + out
-                mlp = lp["mlp"]
-                if "gate" in mlp:       # a router: routed + shared experts
-                    with jax.named_scope("moe/router"):
-                        xm = _rms_norm(
-                            x, lp["post_attention_layernorm"]["scale"],
-                            cfg.rms_norm_eps)
-                    x = x + dropless_moe(
-                        xm, mlp, cfg.num_experts_per_tok, dt,
-                        renormalize=cfg.norm_topk_prob,
-                        expert_start=cfg.expert_start,
-                        routed_scale=cfg.routed_scaling_factor)
-                else:
-                    with jax.named_scope("mlp"):
-                        xm = _rms_norm(
-                            x, lp["post_attention_layernorm"]["scale"],
-                            cfg.rms_norm_eps)
-                        x = x + qmm(
-                            jax.nn.silu(qmm(xm, mlp["gate_proj"]["kernel"],
-                                            dt))
-                            * qmm(xm, mlp["up_proj"]["kernel"], dt),
-                            mlp["down_proj"]["kernel"], dt)
+                x = self._ffn(lp, x + out)
+        return self._head(params, x, batch), new_cache
+
+    def _ffn(self, lp, x):
+        """``x`` plus the layer's FFN branch: routed experts beside the
+        shared ones where its ``mlp`` holds a router, else a dense
+        SwiGLU."""
+        cfg, dt, mlp = self.config, self.config.dtype, lp["mlp"]
+        if "gate" in mlp:       # a router: routed + shared experts
+            with jax.named_scope("moe/router"):
+                xm = _rms_norm(x, lp["post_attention_layernorm"]["scale"],
+                               cfg.rms_norm_eps)
+            return x + dropless_moe(
+                xm, mlp, cfg.num_experts_per_tok, dt,
+                renormalize=cfg.norm_topk_prob,
+                expert_start=cfg.expert_start,
+                routed_scale=cfg.routed_scaling_factor)
+        with jax.named_scope("mlp"):
+            xm = _rms_norm(x, lp["post_attention_layernorm"]["scale"],
+                           cfg.rms_norm_eps)
+            return x + qmm(
+                jax.nn.silu(qmm(xm, mlp["gate_proj"]["kernel"], dt))
+                * qmm(xm, mlp["up_proj"]["kernel"], dt),
+                mlp["down_proj"]["kernel"], dt)
+
+    def _head(self, params, x, batch):
+        """The final norm and the head over the rows ``logits_idx`` names."""
+        cfg = self.config
         with jax.named_scope("lm_head"):
             x = _rms_norm(x, params["norm"]["scale"], cfg.rms_norm_eps)
             x = x[batch["logits_idx"]]
-            logits = x @ params["lm_head"]["kernel"].astype(dt)
-        return logits, new_cache
+            return x @ params["lm_head"]["kernel"].astype(cfg.dtype)
 
     def _mla(self, lp, x, layer_cache, batch, cos, sin, prefill_tile,
              decode):
@@ -426,10 +432,21 @@ class RaggedDeepseekV3:
             out, new_cache["idx_k"] = self._sparse_read(
                 att, xa, cq, q_nope, q_pe, pool, layer_cache["idx_k"], batch,
                 cos, sin, prefill_tile, decode)
+        if "gate_proj" in att:      # a gate a head (dots3_note)
+            out = self._head_gate(att, xa, out)
         with jax.named_scope("attn/out_proj"):
             out = qmm(out.reshape(t_rows, h * vd), att["o_proj"]["kernel"],
                       dt)
         return out, new_cache
+
+    def _head_gate(self, att, xa, out):
+        """``out [T, H, v_head_dim]`` times one sigmoid scalar a head, ``g =
+        sigmoid(x_n W_g)`` from the layer's normed input."""
+        dt = self.config.dtype
+        with jax.named_scope("attn/gate"):
+            g = jax.nn.sigmoid(
+                qmm(xa, att["gate_proj"]["kernel"], dt).astype(F32))
+            return (out.astype(F32) * g[:, :, None]).astype(dt)
 
     def _latent_read(self, att, q_nope, q_pe, pool, batch, prefill_tile,
                      decode):

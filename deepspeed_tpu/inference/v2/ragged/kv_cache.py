@@ -44,7 +44,13 @@ flat pool.
 model's ``kv_groups``): the layers of the window group share a pool of their
 own length, ``window_blocks`` blocks, behind the state manager's second
 allocator and each sequence's second block table; every other KV layer keeps
-``num_blocks``.  ``num_blocks`` stays the global group's.  What it cannot
+``num_blocks``.  ``num_blocks`` stays the global group's.  **A row a group**
+(``window_row``, from ``kv_groups["window"]["row"]``): the window layers keep
+the leaves their group states (``{"ckv": 1152}``: a window layer whose cache
+row is a latent of its own rank) and every other KV layer ``kv_row``
+(``{"ckv": 640, "idx_k": 128}``), so one cache holds rows of two widths, each
+in its own pool; bytes are counted by group (:attr:`BlockedKVCache.
+window_layer_token_bytes`).  What two groups cannot
 serve is :data:`WINDOW_GROUP`; behind those, the block operations that move
 one block id across every layer (``copy_block``, ``gather_blocks``,
 ``scatter_blocks``) refuse: an id names different rows in the two groups.
@@ -187,7 +193,7 @@ class BlockedKVCache:
     def __init__(self, num_layers: int, num_blocks: int, block_size: int,
                  num_kv_heads: int, head_dim: int, dtype: Any = jnp.bfloat16,
                  kv_layers=None, kv_row=None, window_layers=(),
-                 window_blocks: int = 0, passes: int = 1):
+                 window_blocks: int = 0, passes: int = 1, window_row=None):
         #: the layers that hold keys and values (all of them, unless the
         #: model says which: its other layers keep state in slots, see
         #: ``state_pool.py``, and their leaves join ``cache`` beside these)
@@ -211,12 +217,18 @@ class BlockedKVCache:
         #: leaf name -> lanes of the row a layer keeps per token, for a
         #: model that states one (see the module doc); None: k and v
         self.kv_row = dict(kv_row) if kv_row else None
+        #: the window group's own row (the module doc); None: the row (or
+        #: the k / v heads) of every other KV layer
+        self.window_row = dict(window_row) if window_row else None
+
         def layer(i):
-            rows = block_size * (self.window_blocks if i in self.window_layers
+            windowed = i in self.window_layers
+            rows = block_size * (self.window_blocks if windowed
                                  else self.passes * num_blocks)
-            if self.kv_row:
+            row = (self.window_row if windowed else None) or self.kv_row
+            if row:
                 return {name: jnp.zeros((rows, lanes), dtype)
-                        for name, lanes in self.kv_row.items()}
+                        for name, lanes in row.items()}
             row = (num_kv_heads * head_dim,) \
                 if flat_row(dtype, num_kv_heads, head_dim) \
                 else (num_kv_heads, head_dim)
@@ -333,7 +345,8 @@ class BlockedKVCache:
     @property
     def layer_token_bytes(self) -> int:
         """HBM bytes one cached token occupies in ONE cache of one KV
-        layer (a layer keeps ``passes`` of them)."""
+        layer (a layer keeps ``passes`` of them); with two groups, of a
+        global layer."""
         itemsize = jnp.dtype(self.dtype).itemsize
         if self.kv_row:
             return sum(self.kv_row.values()) * itemsize
@@ -343,10 +356,25 @@ class BlockedKVCache:
         return 2 * self.num_kv_heads * per_head
 
     @property
+    def window_layer_token_bytes(self) -> int:
+        """HBM bytes one cached token occupies in one WINDOW layer: its
+        group's own row where it states one, else a global layer's."""
+        if self.window_row:
+            return sum(self.window_row.values()) \
+                * jnp.dtype(self.dtype).itemsize
+        return self.layer_token_bytes
+
+    @property
+    def window_token_bytes(self) -> int:
+        """HBM bytes one cached token occupies across the window group's
+        layers (0 without one): what :attr:`per_token_bytes` is to the
+        global group."""
+        return len(self.window_layers) * self.window_layer_token_bytes
+
+    @property
     def window_pool_bytes(self) -> int:
         """HBM bytes of the window group's pools (0 without one)."""
-        return (len(self.window_layers) * self.window_blocks
-                * self.block_size * self.layer_token_bytes)
+        return self.window_blocks * self.block_size * self.window_token_bytes
 
 
 @partial(jax.jit, static_argnums=(3, 4), donate_argnums=(0,))

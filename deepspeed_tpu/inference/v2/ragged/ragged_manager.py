@@ -15,7 +15,9 @@ KV pressure — ``free_blocks`` counts evictable warm blocks as free, so the
 scheduler's admission view stays truthful.
 
 **Two kinds of KV layer** (a model's ``kv_groups``: ``{"window": {"layers":
-[...], "window": W}}``; every other KV layer is global).  The global group is
+[...], "window": W}}``; every other KV layer is global; with ``"row":
+{leaf: lanes}`` the window layers keep that row and the global ones the
+model's ``kv_row``: a row a group, ``kv_cache.py``).  The global group is
 what the manager always had: ``kv_config.num_blocks`` blocks, ``allocator``,
 ``seq.blocks``, ``free_blocks`` (the pool that binds admission).  The window
 group has a pool, an allocator (``win_allocator``) and a table a sequence
@@ -101,8 +103,10 @@ class DSStateManager:
         self.allocator = BlockedAllocator(num_blocks)
         #: feature -> why this layout cannot serve it (the module doc)
         self.unserved: Dict[str, str] = {}
+        win_row = (kv_groups or {}).get("window", {}).get("row")
         for keeps, cannot in [table for stated, table in (
-                (state_spec is not None, STATE_SLOTS), (kv_row, LATENT_ROW),
+                (state_spec is not None, STATE_SLOTS),
+                (kv_row or win_row, LATENT_ROW),
                 (kv_groups is not None, WINDOW_GROUP),
                 (kv_passes > 1, PASS_CACHES)) if stated]:
             for feature, why in cannot.items():
@@ -154,6 +158,8 @@ class DSStateManager:
             self.win_allocator = BlockedAllocator(self.window_pool_blocks + 1)
             kwargs["window_layers"] = win["layers"]
             kwargs["window_blocks"] = self.win_allocator.num_blocks
+            if win_row:     # the group's own row: a row a group
+                kwargs["window_row"] = win_row
         self.kv_cache = BlockedKVCache(num_layers, num_blocks, self.block_size,
                                        num_kv_heads, head_dim, **kwargs)
         if self.state_pool is not None:

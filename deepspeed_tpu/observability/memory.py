@@ -208,12 +208,16 @@ def kv_occupancy(state_manager) -> Dict[str, float]:
     if win is not None:
         # a model with window and global KV layers (kv_groups): the gauges
         # above are the global group's; the window group's pool beside them
+        win_live = win.num_blocks - 1 - win.free_blocks
         out.update({
             "observability/kv_window_blocks_total": float(win.num_blocks - 1),
-            "observability/kv_window_blocks_live": float(
-                win.num_blocks - 1 - win.free_blocks),
+            "observability/kv_window_blocks_live": float(win_live),
             "observability/kv_window_pool_bytes": float(
                 kv.window_pool_bytes),
+            # at the window group's OWN row (a row a group): what its live
+            # blocks hold, as kv_live_bytes is the global group's
+            "observability/kv_window_live_bytes": float(
+                win_live * kv.block_size * kv.window_token_bytes),
         })
     pool = getattr(state_manager, "state_pool", None)
     if pool is not None:

@@ -157,7 +157,20 @@ span                     site                        parent    attrs (counters)
                                                                with window
                                                                layers their
                                                                share of both,
-                                                               ``..._win``
+                                                               ``..._win``;
+                                                               two groups
+                                                               (``kv_groups``):
+                                                               ``read_blocks_win``
+                                                               / ``read_keys_win``
+                                                               (in-band blocks /
+                                                               keys of one-token
+                                                               rows x window
+                                                               layers),
+                                                               ``attn_pairs_win``
+                                                               / ``ctx_rows_win``
+                                                               (banded pairs /
+                                                               band rows of the
+                                                               chunks, a layer)
                                                                (a latent row:
                                                                ``latent_key_steps``
                                                                and

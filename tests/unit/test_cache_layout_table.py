@@ -247,3 +247,54 @@ def test_a_double_block_family_is_refused_what_a_latent_row_is(feature):
                        match=re.escape(LATENT_ROW[1][feature])) as err:
         call()
     assert "RaggedLongcatFlash" in str(err.value)
+
+
+# ------------------------------------------------------------------ #
+# A model that states BOTH a row of its own and two groups, the window
+# group with a row of ITS own (``dots3_note``): every entry of both tables,
+# from the one place
+# ------------------------------------------------------------------ #
+BOTH_ROWS = {"kv_row": {"ckv": 128, "idx_k": 128},
+             "kv_groups": {"window": {"layers": [0], "window": 16,
+                                      "row": {"ckv": 256}}}}
+BOTH_ENTRIES = sorted(set(LATENT_ROW[1]) | set(WINDOW_GROUP[1]))
+
+
+@pytest.mark.parametrize("feature", BOTH_ENTRIES)
+def test_a_model_with_a_row_a_group_is_refused_what_both_tables_say(feature):
+    reasons = [table[1][feature] for table in (LATENT_ROW, WINDOW_GROUP)
+               if feature in table[1]]
+    for path, call in PATHS[feature]:
+        with pytest.raises(CacheLayoutError) as err:
+            call(BOTH_ROWS)
+        message = str(err.value)
+        assert path in message
+        for why in reasons:         # both reasons in the one message
+            assert why in message
+
+
+def test_a_window_groups_own_row_alone_is_a_latent_row_too():
+    """A group that states a row makes the cache a latent one even where
+    the global layers keep k and v: what a latent row cannot serve is
+    refused, and the two kinds of pool have their own leaves."""
+    stated = {"kv_groups": {"window": {"layers": [0], "window": 16,
+                                       "row": {"ckv": 256}}}}
+    sm = _engine(stated).state_manager
+    assert set(sm.unserved) == set(LATENT_ROW[1]) | set(WINDOW_GROUP[1])
+    assert set(sm.kv_cache.cache["layer_0"]) == {"ckv"}
+    assert set(sm.kv_cache.cache["layer_1"]) == {"k", "v"}
+    assert sm.kv_cache.cache["layer_0"]["ckv"].shape[1] == 256
+
+
+def test_a_row_a_group_builds_two_pools_of_two_widths():
+    sm = _engine(BOTH_ROWS).state_manager
+    kv = sm.kv_cache
+    assert {n: a.shape for n, a in kv.cache["layer_0"].items()} \
+        == {"ckv": ((sm.window_pool_blocks + 1) * 8, 256)}
+    assert {n: a.shape for n, a in kv.cache["layer_1"].items()} \
+        == {"ckv": (17 * 8, 128), "idx_k": (17 * 8, 128)}
+    assert kv.per_token_bytes == 256 * 4            # the global layer
+    assert kv.window_token_bytes == 256 * 4         # the window layer's own
+    assert kv.window_layer_token_bytes == 1024
+    with pytest.raises(CacheLayoutError, match="two pools"):
+        kv.copy_block(1, 2)

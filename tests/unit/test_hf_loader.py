@@ -778,3 +778,184 @@ def test_granitemoehybrid_with_groups_is_refused_by_name(tmp_path):
         json.dump({**GRANITE, "mamba_n_groups": 4}, fh)
     with pytest.raises(NotImplementedError, match="mamba_n_groups=4"):
         config_from_hf(str(tmp_path))
+
+
+DOTS3 = {"model_type": "dots3_note", "vocab_size": 128, "hidden_size": 32,
+         "intermediate_size": 48, "moe_intermediate_size": 16,
+         "num_hidden_layers": 3,
+         "layer_types": ["full_attention", "sliding_attention",
+                         "full_attention"],
+         "num_attention_heads": 4, "num_key_value_heads": 4,
+         "kv_lora_rank": 16, "q_lora_rank": 24, "qk_nope_head_dim": 8,
+         "qk_rope_head_dim": 8, "v_head_dim": 8, "rope_theta": 80000000,
+         "index_n_heads": 2, "index_head_dim": 128, "index_topk": 12,
+         "attention_gate_type": "headwise",
+         "swa_num_attention_heads": 2, "swa_num_key_value_heads": 2,
+         "swa_kv_lora_rank": 32, "swa_q_lora_rank": 20,
+         "swa_qk_nope_head_dim": 12, "swa_qk_rope_head_dim": 4,
+         "swa_v_head_dim": 8, "swa_rope_theta": 50000,
+         "swa_attention_gate_type": "headwise", "sliding_window_size": 21,
+         "apply_mla_qkv_lora_rescale": True, "n_routed_experts": 4,
+         "n_shared_experts": 1, "num_experts_per_tok": 2,
+         "first_k_dense_replace": 1, "moe_layer_freq": 1,
+         "norm_topk_prob": True, "routed_scaling_factor": 1,
+         "scoring_func": "sigmoid", "topk_method": "noaux_tc",
+         "attention_bias": False, "hidden_act": "silu",
+         "rms_norm_eps": 1e-5, "rope_scaling": None,
+         "max_position_embeddings": 256, "tie_word_embeddings": False}
+
+
+def _dots3_state_dict(cfg, params):
+    """``params`` (the program's tree) under the published names and
+    layouts: [out, in] matrices, the rope rows of ``q_b_proj`` (a head's
+    LAST dims), ``kv_a_proj_with_mqa`` (its last) and the indexer's two
+    (a head's FIRST) interleaved, experts one tensor each."""
+    def interleave(kernel, width, rope, first=False):
+        w = np.asarray(kernel).T                        # [out, in]
+        at = 0 if first else width - rope
+        order = np.arange(width)
+        order[at:at + rope] = at + np.concatenate(
+            [np.arange(0, rope, 2), np.arange(1, rope, 2)])
+        blocks = w.reshape(-1, width, w.shape[-1])
+        out = np.empty_like(blocks)
+        out[:, order] = blocks
+        return out.reshape(w.shape)
+
+    t = lambda leaf: np.asarray(leaf["kernel"]).T
+    sd = {"model.embed_tokens.weight":
+          np.asarray(params["embed_tokens"]["embedding"]),
+          "model.norm.weight": np.asarray(params["norm"]["scale"]),
+          "lm_head.weight": t(params["lm_head"])}
+    for i, kind in enumerate(cfg["layer_types"]):
+        lp, pre = params[f"layers_{i}"], f"model.layers.{i}."
+        sw = "swa_" if kind == "sliding_attention" else ""
+        nope, rope, rank = (cfg[sw + k] for k in (
+            "qk_nope_head_dim", "qk_rope_head_dim", "kv_lora_rank"))
+        att, a = lp["self_attn"], pre + "self_attn."
+        sd[pre + "input_layernorm.weight"] = np.asarray(
+            lp["input_layernorm"]["scale"])
+        sd[pre + "post_attention_layernorm.weight"] = np.asarray(
+            lp["post_attention_layernorm"]["scale"])
+        sd[a + "q_a_proj.weight"] = t(att["q_a_proj"])
+        sd[a + "q_a_layernorm.weight"] = np.asarray(
+            att["q_a_layernorm"]["scale"])
+        sd[a + "q_b_proj.weight"] = interleave(
+            att["q_b_proj"]["kernel"], nope + rope, rope)
+        sd[a + "kv_a_proj_with_mqa.weight"] = interleave(
+            att["kv_a_proj_with_mqa"]["kernel"], rank + rope, rope)
+        sd[a + "kv_a_layernorm.weight"] = np.asarray(
+            att["kv_a_layernorm"]["scale"])
+        for name in ("kv_b_proj", "o_proj", "gate_proj"):
+            sd[a + name + ".weight"] = t(att[name])
+        if "indexer" in att:
+            ix, full_rope = att["indexer"], cfg["qk_rope_head_dim"]
+            for name in ("wq_b", "wk"):
+                sd[a + f"indexer.{name}.weight"] = interleave(
+                    ix[name]["kernel"], cfg["index_head_dim"], full_rope,
+                    first=True)
+            sd[a + "indexer.k_norm.weight"] = np.asarray(
+                ix["k_norm"]["scale"])
+            sd[a + "indexer.k_norm.bias"] = np.asarray(ix["k_norm"]["bias"])
+            sd[a + "indexer.weights_proj.weight"] = t(ix["weights_proj"])
+        mlp, m = lp["mlp"], pre + "mlp."
+        if "gate" not in mlp:
+            for name in ("gate", "up", "down"):
+                sd[m + f"{name}_proj.weight"] = t(mlp[f"{name}_proj"])
+            continue
+        sd[m + "gate.weight"] = t(mlp["gate"]["wg"])
+        sd[m + "gate.e_score_correction_bias"] = np.asarray(
+            mlp["gate"]["e_score_correction_bias"])
+        for name in ("gate", "up", "down"):
+            sd[m + f"shared_experts.{name}_proj.weight"] = t(
+                mlp["shared_expert"][f"{name}_proj"])
+            for e in range(cfg["n_routed_experts"]):
+                sd[m + f"experts.{e}.{name}_proj.weight"] = np.asarray(
+                    mlp["experts"][f"w_{name}"][e]).T
+    return {k: np.ascontiguousarray(v, np.float32) for k, v in sd.items()}
+
+
+def _dots3_checkpoint(tmp_path, extra=None):
+    import json
+
+    from safetensors.numpy import save_file
+
+    from deepspeed_tpu.inference.v2.model_implementations import \
+        ragged_dots3_note as rd
+
+    _arch_cfg = rd.Dots3NoteConfig(
+        **{k: v for k, v in DOTS3.items()
+           if k in rd.Dots3NoteConfig.__dataclass_fields__},
+        dtype=jnp.float32)
+    rng = np.random.default_rng(13)
+    params = jax.tree.map(
+        lambda l: jnp.asarray(rng.standard_normal(l.shape), jnp.float32),
+        rd.param_shapes(_arch_cfg))
+    sd = _dots3_state_dict(DOTS3, params)
+    sd.update(extra or {})
+    save_file(sd, str(tmp_path / "model.safetensors"))
+    with open(tmp_path / "config.json", "w") as fh:
+        json.dump(DOTS3, fh)
+    return params
+
+
+def test_dots3_note_rules_on_a_seeded_tree(tmp_path):
+    """A seeded program tree written out under the published names and
+    layouts (each layer's rope rows interleaved at its OWN kind's widths)
+    loads back into the same tree, value for value, and the engine built
+    from the directory is a ``RaggedDots3Note`` with both pools."""
+    from deepspeed_tpu.inference.v2 import (InferenceEngineV2,
+                                            RaggedInferenceEngineConfig)
+    from deepspeed_tpu.inference.v2.model_implementations import \
+        ragged_dots3_note as rd
+
+    params = _dots3_checkpoint(tmp_path)
+    arch, cfg = config_from_hf(str(tmp_path), jnp.float32)
+    assert arch == "dots3_note" and type(cfg) is rd.Dots3NoteConfig
+    assert cfg.layer_types == DOTS3["layer_types"]
+    assert (cfg.swa_kv_lora_rank, cfg.sliding_window_size,
+            cfg.swa_rope_theta, cfg.rope_theta) == (32, 21, 50000, 80000000)
+    assert cfg.apply_mla_qkv_lora_rescale and cfg.held_experts is None
+    assert (cfg.attention_gate_type, cfg.swa_attention_gate_type) \
+        == ("headwise", "headwise")
+    loaded = load_hf_checkpoint(str(tmp_path), dtype=jnp.float32)
+    assert jax.tree.structure(loaded) == jax.tree.structure(params)
+    same = jax.tree.map(lambda a, b: bool(jnp.array_equal(a, b)), loaded,
+                        params)
+    assert all(jax.tree.leaves(same)), same
+    eng = InferenceEngineV2.from_hf(
+        str(tmp_path), RaggedInferenceEngineConfig.from_dict({
+            "state_manager": {"max_ragged_batch_size": 64,
+                              "max_ragged_sequence_count": 2,
+                              "max_context": 128},
+            "kv_cache": {"block_size": 8, "num_blocks": 40}}),
+        dtype=jnp.float32)
+    assert type(eng.model) is rd.RaggedDots3Note
+    kv = eng.state_manager.kv_cache
+    assert kv.window_layers == (1,) and kv.window_row == {"ckv": 128}
+    logits = eng.put([1], [list(range(40))])[1]
+    assert np.isfinite(np.asarray(logits)).all()
+
+
+@pytest.mark.parametrize("name, what", [
+    ("visual.blocks.0.attn.qkv.weight", "the vision tower"),
+    ("model.vision_tower.patch_embed.proj.weight", "the vision tower"),
+    ("audio_tower.conv1.weight", "the audio encoder"),
+    ("model.layers.3.eh_proj.weight", "multi-token prediction"),
+    ("mtp.layers.0.input_layernorm.weight", "multi-token prediction"),
+    ("model.layers.3.input_layernorm.weight", "past num_hidden_layers"),
+])
+def test_dots3_note_towers_and_mtp_are_refused_by_name(tmp_path, name, what):
+    from deepspeed_tpu.checkpoint.hf_loader import HFLoadError
+
+    _dots3_checkpoint(tmp_path, {name: np.zeros((32,), np.float32)})
+    with pytest.raises(HFLoadError, match=what):
+        load_hf_checkpoint(str(tmp_path), dtype=jnp.float32)
+
+
+def test_dots3_note_gate_types_are_refused_by_name(tmp_path):
+    import json
+
+    with open(tmp_path / "config.json", "w") as fh:
+        json.dump({**DOTS3, "swa_attention_gate_type": "elementwise"}, fh)
+    with pytest.raises(NotImplementedError, match="swa_attention_gate_type"):
+        config_from_hf(str(tmp_path))

@@ -473,3 +473,98 @@ def test_occupancy_gauges_tell_the_groups_apart():
     assert occ["observability/kv_window_blocks_live"] == 3
     assert occ["observability/kv_window_pool_bytes"] == \
         eng.state_manager.kv_cache.window_pool_bytes
+
+
+# ------------------------------------------------------------------ #
+# (g) a row a group: the window group's pool at its OWN row width
+# ------------------------------------------------------------------ #
+ROW_GROUPS = {"window": {"layers": [1, 2, 3], "window": 513,
+                         "row": {"ckv": 1152}}}
+
+
+def _row_manager(seqs=48, budget=1024, blocks=8, groups=ROW_GROUPS,
+                 block_size=128, **kw):
+    return DSStateManager(
+        DSStateManagerConfig(max_ragged_batch_size=budget,
+                             max_ragged_sequence_count=seqs,
+                             max_context=50176),
+        KVCacheConfig(block_size=block_size, num_blocks=blocks),
+        num_layers=5, num_kv_heads=1, head_dim=640, dtype=jnp.bfloat16,
+        kv_row={"ckv": 640, "idx_k": 128}, kv_groups=groups, **kw)
+
+
+@pytest.mark.parametrize("window, seqs, budget, want", [
+    (513, 48, 1024, 48 * 6 + 8),        # W - 1 = 4 x 128 + 0
+    (513, 16, 1024, 16 * 6 + 8),
+    (514, 48, 1024, 48 * 6 + (48 + 1024) // 128),   # r = 1
+    (512, 48, 1024, 48 * 5 + (48 * 127 + 1024) // 128),
+    (4096, 32, 1024, 1095),             # Trinity's, as before
+])
+def test_window_pool_blocks_by_hand(window, seqs, budget, want):
+    sm = _row_manager(seqs=seqs, budget=budget, groups={"window": {
+        "layers": [1], "window": window, "row": {"ckv": 1152}}})
+    assert sm.window_pool_blocks == want
+    assert sm.win_allocator.num_blocks == want + 1
+
+
+def test_each_group_counts_bytes_at_its_own_row():
+    sm = _row_manager()
+    kv = sm.kv_cache
+    assert kv.cache["layer_1"]["ckv"].shape == (297 * 128, 1152)
+    assert kv.cache["layer_0"]["ckv"].shape == (8 * 128, 640)
+    assert kv.cache["layer_4"]["idx_k"].shape == (8 * 128, 128)
+    assert "idx_k" not in kv.cache["layer_2"]
+    assert kv.layer_token_bytes == 768 * 2
+    assert kv.window_layer_token_bytes == 1152 * 2
+    assert kv.per_token_bytes == 2 * 1536
+    assert kv.window_token_bytes == 3 * 2304
+    assert kv.window_pool_bytes == 297 * 128 * 6912 == 262_766_592
+
+
+def test_the_gauges_count_each_group_at_its_own_row():
+    from deepspeed_tpu.observability.memory import kv_occupancy
+
+    sm = _row_manager(blocks=16)
+    seq = sm.get_or_create_sequence(1)
+    sm.maybe_allocate_kv(seq, 1024)
+    seq.seen_tokens = 1024
+    occ = kv_occupancy(sm)
+    assert occ["observability/kv_blocks_live"] == 8
+    assert occ["observability/kv_live_bytes"] == 8 * 128 * 3072
+    assert occ["observability/kv_window_blocks_live"] == 8
+    assert occ["observability/kv_window_live_bytes"] == 8 * 128 * 6912
+    assert sm.release_window(seq) == (1024 - 513 + 1) // 128 == 4
+    occ = kv_occupancy(sm)
+    assert occ["observability/kv_window_blocks_live"] == 4
+    assert occ["observability/kv_window_live_bytes"] == 4 * 128 * 6912
+    assert occ["observability/kv_live_bytes"] == 8 * 128 * 3072
+
+
+def test_a_group_without_a_row_keeps_the_models_row():
+    """``kv_groups`` beside ``kv_row`` with no row of the group's own: the
+    window layers keep the model's row (and Trinity's k / v pools, above,
+    read as before)."""
+    sm = _row_manager(groups={"window": {"layers": [1], "window": 513}})
+    kv = sm.kv_cache
+    assert set(kv.cache["layer_1"]) == {"ckv", "idx_k"}
+    assert kv.window_layer_token_bytes == kv.layer_token_bytes == 1536
+    plain = _manager()
+    assert plain.kv_cache.window_row is None
+    assert plain.kv_cache.window_layer_token_bytes \
+        == plain.kv_cache.layer_token_bytes == 2 * 2 * 16 * 4
+
+
+def test_admission_counts_the_window_tables_bound_at_w513():
+    sm = _row_manager(seqs=2, blocks=512)
+    assert sm.window_table_bound == 14
+    assert sm.window_blocks_needed(None, 49152) == 14
+    assert sm.window_blocks_needed(None, 1024) == 8
+    seq = sm.get_or_create_sequence(7)
+    sm.maybe_allocate_kv(seq, 1024)
+    seq.seen_tokens = 1024
+    # the next chunk of 1,024 ends at entry 16 and its band starts at 4:
+    # 12 blocks, of which the 4 that outlive the release are held
+    assert sm.window_blocks_needed(seq, 1024) == 16 - 4 - 4
+    sm.release_window(seq)
+    assert (seq.win_first, len(seq.win_blocks)) == (4, 4)
+    assert sm.window_blocks_needed(seq, 1) == 1
