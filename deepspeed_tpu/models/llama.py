@@ -5,9 +5,16 @@ inference/v2/model_implementations/llama_v2).
 TPU-first design notes:
 * bf16 compute, fp32 RMSNorm accumulations, einsum-heavy so every FLOP lands
   on the MXU;
-* tensor parallel = Megatron-style column/row sharding expressed purely as
-  ``partition_rules`` (PartitionSpec over the 'model' mesh axis) — no code
-  change between 1 and N-way TP;
+* tensor parallel = Megatron-style column/row sharding stated as
+  ``partition_rules`` (PartitionSpec over the 'model' mesh axis).  One-token
+  decode, short sequences and every one-chip run are those rules under
+  GSPMD and nothing else; a whole-sequence forward on a mesh whose 'model'
+  axis is larger than 1, with chunks of at least 512 tokens a rank, takes
+  the rules' collectives as ring steps under the GEMMs
+  (:mod:`deepspeed_tpu.parallel.tensor_overlap`: ``LlamaModel`` asks its
+  ``plan``; the residual stream, its adds and its norms are then
+  token-sharded over 'model' between a row-parallel GEMM and the next
+  column-parallel one).  Same parameters, same rules, same numbers;
 * sequence parallel (Ulysses) = optional all-to-all head<->seq re-partition
   around attention via :mod:`deepspeed_tpu.sequence` when the mesh has a
   'seq' axis;
@@ -25,6 +32,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from deepspeed_tpu.ops.attention import dot_product_attention
+from deepspeed_tpu.parallel import tensor_overlap
 
 
 @dataclasses.dataclass
@@ -135,19 +143,49 @@ def init_kv_cache(config: LlamaConfig, batch: int, max_len: int):
     }
 
 
+class _DenseKernel(nn.Module):
+    """The ``kernel`` of a bias-free ``nn.Dense`` of the same name, in the
+    compute dtype and without the product: what a ring site multiplies by.
+    Only ever applied (``LlamaModel`` plans no ring while it initialises),
+    so ``nn.Dense`` stays the one place that creates the parameter."""
+    features: int
+    dtype: Any
+
+    @nn.compact
+    def __call__(self, in_features: int):
+        return self.param("kernel", nn.linear.default_kernel_init,
+                          (in_features, self.features),
+                          jnp.float32).astype(self.dtype)
+
+
+def _kernel(cfg: LlamaConfig, features: int, name: str, fan_in: int):
+    return _DenseKernel(features, cfg.dtype, name=name)(fan_in)
+
+
 class LlamaAttention(nn.Module):
+    """``ring`` (a ``tensor_overlap.Ring``, from ``LlamaBlock``): ``x``
+    arrives token-sharded over 'model' and so does the result; q/k/v are
+    the ring gather's products and ``o_proj`` the ring scatter's."""
     config: LlamaConfig
+    ring: Any = None
 
     @nn.compact
     def __call__(self, x, positions, attention_fn=None, cache=None,
                  cache_index=None):
         cfg = self.config
+        ring = self.ring
         h, hkv, d = (cfg.num_attention_heads, cfg.num_key_value_heads,
                      cfg.head_dim)
         dense = lambda feats, name: nn.Dense(
             feats, use_bias=False, dtype=cfg.dtype,
             param_dtype=jnp.float32, name=name)
-        if cfg.fused_qkv:
+        if ring:
+            q, k, v = tensor_overlap.gather_column_parallel(
+                ring, x.astype(cfg.dtype),
+                {name: _kernel(cfg, nh * d, name, x.shape[-1]) for name, nh
+                 in (("q_proj", h), ("k_proj", hkv), ("v_proj", hkv))}
+            ).values()
+        elif cfg.fused_qkv:
             # one wide matmul (fused qkv_gemm) then split
             qkv = dense((h + 2 * hkv) * d, "qkv_proj")(x)
             q, k, v = jnp.split(qkv, [h * d, (h + hkv) * d], axis=-1)
@@ -207,18 +245,33 @@ class LlamaAttention(nn.Module):
                                    cfg.sliding_window)
                 out = attn(q, ck, cv, causal=False, mask=mask)
         out = out.reshape(*x.shape[:2], h * d)
+        if ring:
+            return tensor_overlap.row_parallel_scatter(
+                ring, out, _kernel(cfg, cfg.hidden_size, "o_proj", h * d),
+                name="o_proj"), new_cache
         return dense(cfg.hidden_size, "o_proj")(out), new_cache
 
 
 class LlamaMLP(nn.Module):
+    """``ring``: as :class:`LlamaAttention`; gate / up are a ring gather's
+    products and ``down_proj`` a ring scatter's (``gated_mlp``)."""
     config: LlamaConfig
+    ring: Any = None
 
     @nn.compact
     def __call__(self, x):
         cfg = self.config
+        ring = self.ring
         dense = lambda feats, name: nn.Dense(
             feats, use_bias=False, dtype=cfg.dtype,
             param_dtype=jnp.float32, name=name)
+        if ring:
+            inter, hidden = cfg.intermediate_size, cfg.hidden_size
+            return tensor_overlap.gated_mlp(
+                ring, x.astype(cfg.dtype),
+                _kernel(cfg, inter, "gate_proj", hidden),
+                _kernel(cfg, inter, "up_proj", hidden),
+                _kernel(cfg, hidden, "down_proj", inter), nn.silu)
         if cfg.fused_gate_up:
             # one wide matmul (fused mlp_gemm) then split
             gu = dense(2 * cfg.intermediate_size, "gate_up_proj")(x)
@@ -230,17 +283,21 @@ class LlamaMLP(nn.Module):
 
 
 class LlamaBlock(nn.Module):
+    """``ring``: ``x`` is token-sharded over 'model' through the block (a
+    rank holds ``[B, T/n, H]``): both norms and both residual adds run on
+    a rank's chunk."""
     config: LlamaConfig
+    ring: Any = None
 
     @nn.compact
     def __call__(self, x, positions, attention_fn=None, cache=None,
                  cache_index=None):
         cfg = self.config
-        a, new_cache = LlamaAttention(cfg, name="self_attn")(
+        a, new_cache = LlamaAttention(cfg, self.ring, name="self_attn")(
             RMSNorm(cfg.rms_norm_eps, name="input_layernorm")(x),
             positions, attention_fn, cache, cache_index)
         x = x + a
-        m = LlamaMLP(cfg, name="mlp")(
+        m = LlamaMLP(cfg, self.ring, name="mlp")(
             RMSNorm(cfg.rms_norm_eps, name="post_attention_layernorm")(x))
         return x + m, new_cache
 
@@ -261,6 +318,15 @@ class LlamaModel(nn.Module):
                          dtype=cfg.dtype, param_dtype=jnp.float32,
                          name="embed_tokens")
         x = embed(input_ids)
+        # four GEMM sites a layer: q/k/v, o_proj, gate/up, down_proj
+        ring = None if self.is_initializing() or cfg.fused_qkv or \
+            cfg.fused_gate_up else tensor_overlap.plan(
+                s, sites=4 * cfg.num_hidden_layers, cache=cache,
+                features=(cfg.num_attention_heads, cfg.num_key_value_heads,
+                          cfg.intermediate_size))
+        if ring:
+            # the embedding's all-reduce becomes a reduce-scatter
+            x = ring.shard_tokens(x)
         block = LlamaBlock
         if cfg.remat and cache is None:
             block = nn.remat(
@@ -270,11 +336,14 @@ class LlamaModel(nn.Module):
         for i in range(cfg.num_hidden_layers):
             name = f"layers_{i}"
             layer_cache = cache[name] if cache is not None else None
-            x, c = block(cfg, name=name)(x, positions, self.attention_fn,
-                                         layer_cache, cache_index)
+            x, c = block(cfg, ring, name=name)(
+                x, positions, self.attention_fn, layer_cache, cache_index)
             if cache is not None:
                 new_cache[name] = c
         x = RMSNorm(cfg.rms_norm_eps, name="norm")(x)
+        if ring:
+            # the head is vocab-parallel: it takes the tokens whole
+            x = ring.gather_tokens(x)
         if tie_logits:
             x = embed.attend(x.astype(cfg.dtype))
         return (x, new_cache) if cache is not None else x

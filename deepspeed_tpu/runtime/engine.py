@@ -46,7 +46,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from deepspeed_tpu.accelerator import get_accelerator
-from deepspeed_tpu.parallel import groups
+from deepspeed_tpu.parallel import groups, tensor_overlap
 from deepspeed_tpu.parallel.topology import GROUP_ALIASES, MeshTopology
 from deepspeed_tpu.runtime.config import DeepSpeedConfig
 from deepspeed_tpu.runtime.lr_schedules import LRScheduler, get_lr_schedule_fn
@@ -169,7 +169,22 @@ class DeepSpeedEngine:
 
         self.loss_fn = loss_fn
         self.module = model
-        self._init_fn, self._apply_fn = _as_model_fns(model, loss_fn)
+        self._init_fn, apply_fn = _as_model_fns(model, loss_fn)
+        # what parallel/tensor_overlap.py's rule decided when the model
+        # was last traced on a tensor-parallel mesh:
+        # {"ring": sites, "steps": n, "fallbacks": {why: sites}}
+        self.tp_overlap_sites = None
+
+        def counted_apply(*args, **kwargs):
+            with tensor_overlap.recording() as sites:
+                out = apply_fn(*args, **kwargs)
+            if (sites["ring"] or sites["fallbacks"]) and \
+                    sites != self.tp_overlap_sites:
+                self.tp_overlap_sites = sites
+                log_dist(tensor_overlap.describe(sites), ranks=[0])
+            return out
+
+        self._apply_fn = counted_apply
 
         # precision ---------------------------------------------------------
         self.compute_dtype = self.config.precision_dtype

@@ -1,6 +1,7 @@
 """ZeRO sharding-policy tests (reference: tests/unit/runtime/zero/)."""
 
 import functools
+import re
 
 import jax
 import numpy as np
@@ -168,14 +169,14 @@ def _step(engine, ids):
     return float(jax.device_get(loss))
 
 
-def _lowered_step(dp, tp, stage, zero_keys=None):
+def _lowered_step(dp, tp, stage, zero_keys=None, seq=32):
     engine, model_cfg = _llama_engine(dp, tp, stage, zero_keys=zero_keys)
-    _step(engine, _token_batches(1, dp, model_cfg.vocab_size)[0])
+    _step(engine, _token_batches(1, dp, model_cfg.vocab_size, seq=seq)[0])
     return engine, engine.lower_train_step()
 
 
-def _compiled_step(dp, tp, stage):
-    engine, lowered = _lowered_step(dp, tp, stage)
+def _compiled_step(dp, tp, stage, seq=32):
+    engine, lowered = _lowered_step(dp, tp, stage, seq=seq)
     return engine, lowered.compile().as_text()
 
 
@@ -206,6 +207,60 @@ def test_stage3_gathers_weights_not_activations(dp, tp):
     assert len(gathers) <= len(jax.tree.leaves(engine.state["params"]))
 
 
+def test_stage3_with_the_tp_ring_moves_activations_by_permute_alone(
+        monkeypatch):
+    """At a sequence long enough for ``parallel/tensor_overlap.py`` to
+    engage (512 rows a rank) the compiled (2, 2) step holds no all-reduce
+    of a ``[batch, seq, hidden]`` activation under a layer's scope: its
+    layers move activations by ``collective-permute`` under ``tp/ring``.
+    ZeRO's half is untouched: the weights are still gathered under
+    ``zero/gather`` exactly as in the step without the ring, and the
+    weight gradients summed over 'data' are the same arrays."""
+    from deepspeed_tpu.parallel import tensor_overlap
+
+    def weight_reductions(found, vocab=256):
+        """Sizes of the layers' weight-shaped arrays the step sums over
+        'data'.  A set: the CPU compiler combines several arrays into one
+        all-reduce (the step compiled for a v5e holds the parent's
+        weight-gradient collectives one for one:
+        ``tools/chip_calls/pr62_tp_ring.py --aot``)."""
+        return {int(np.prod(shape))
+                for c in found for _dtype, shape in c.results
+                if c.kind in ("all-reduce", "reduce-scatter")
+                and len(shape) == 2 and vocab not in shape}
+
+    def weight_gathers(found):
+        return sorted(c.results for c in found if c.kind == "all-gather"
+                      and "zero/gather" in c.scope)
+
+    engine, hlo = _compiled_step(2, 2, stage=3, seq=1024)
+    assert engine.tp_overlap_sites == {"ring": 8, "steps": 2,
+                                       "fallbacks": {}}
+    found = collectives(hlo)
+    in_layers = [c for c in found if "/layers_" in c.scope]
+    assert [c for c in in_layers
+            if c.kind in ("all-reduce", "all-to-all", "reduce-scatter")
+            and c.max_rank >= 3] == []
+    # read off the lines: on the CPU every ``ppermute`` of the step shares
+    # one channel id, and ``collectives`` lists a channel once
+    permutes = [line for line in hlo.splitlines()
+                if re.search(r"= f32\[[0-9,]*\]\S* collective-permute"
+                             r"(-start)?\(", line) and "/layers_" in line]
+    assert len(permutes) >= 8
+    assert all(tensor_overlap.RING_SCOPE + "/ppermute" in line
+               for line in permutes)
+    gathers = [c for c in in_layers if c.kind == "all-gather"]
+    assert all(c.max_rank <= 2 and "zero/gather" in c.scope
+               for c in gathers if _is_float(c))
+    monkeypatch.setattr(tensor_overlap, "plan", lambda *a, **k: None)
+    plain_engine, plain = _compiled_step(2, 2, stage=3, seq=1024)
+    assert plain_engine.tp_overlap_sites is None
+    assert [c for c in collectives(plain) if "/layers_" in c.scope
+            and c.kind == "all-reduce" and c.max_rank >= 3]
+    assert weight_gathers(found) == weight_gathers(collectives(plain))
+    assert weight_reductions(found) == weight_reductions(collectives(plain))
+
+
 @pytest.mark.parametrize("dp,tp,stage", [(2, 2, 1), (1, 1, 3), (1, 1, 1)])
 def test_gather_on_use_adds_nothing_where_nothing_is_sharded(dp, tp, stage):
     """Below stage 3, and on one device, the step holds no instruction of
@@ -216,12 +271,13 @@ def test_gather_on_use_adds_nothing_where_nothing_is_sharded(dp, tp, stage):
         assert collectives(hlo) == []
 
 
-@pytest.mark.parametrize("dp,tp,gas,threshold", [
-    (2, 2, 1, 0), (4, 2, 1, 0), (4, 1, 1, 0),
-    (2, 2, 2, 0),        # gas 2: the micro + apply programs, not the fused
-    (2, 2, 1, 100),      # the norms (64 elements) stay unsharded
+@pytest.mark.parametrize("dp,tp,gas,threshold,seq", [
+    (2, 2, 1, 0, 32), (4, 2, 1, 0, 32), (4, 1, 1, 0, 32),
+    (2, 2, 2, 0, 32),    # gas 2: the micro + apply programs, not the fused
+    (2, 2, 1, 100, 32),  # the norms (64 elements) stay unsharded
+    (2, 2, 1, 0, 1024),  # 512 rows a rank: TP's collectives as ring steps
 ])
-def test_stage3_on_mesh_matches_single_device(dp, tp, gas, threshold):
+def test_stage3_on_mesh_matches_single_device(dp, tp, gas, threshold, seq):
     """Three optimizer steps from one seed: losses and master weights of
     stage 3 on the mesh agree with stage 0 on one device, to the tolerance
     of test_engine.py::test_zero_stages_agree.  SGD, because its update is
@@ -236,8 +292,11 @@ def test_stage3_on_mesh_matches_single_device(dp, tp, gas, threshold):
             d, t, stage, gas=gas, threshold=threshold if stage else 0,
             micro=batch // d, dtype="fp32", optimizer=sgd)
         losses = [_step(engine, ids) for ids in
-                  _token_batches(steps * gas, batch, model_cfg.vocab_size)]
+                  _token_batches(steps * gas, batch, model_cfg.vocab_size,
+                                 seq=seq)]
         assert engine.global_steps == steps
+        ring_sites = (engine.tp_overlap_sites or {}).get("ring", 0)
+        assert ring_sites == (8 if stage == 3 and seq >= 1024 else 0)
         runs.append((losses, jax.device_get(engine.state["master"])))
     if threshold:
         specs = jax.tree.leaves(
