@@ -251,26 +251,25 @@ def describe_device(require_chip: bool = True) -> Dict[str, Any]:
 
 class CompileClock:
     """Seconds JAX spent building executables (compiling, or loading them
-    from the persistent cache), from its own monitoring events."""
-
-    _EVENT = "/jax/core/compile/backend_compile_duration"
+    from the persistent cache) and how many, since the last ``take``: the
+    totals of the program's one ``jax.monitoring`` listener
+    (``deepspeed_tpu.observability.tracer.build_totals``), subtracted."""
 
     def __init__(self):
-        import jax.monitoring
+        self._taken = self._totals()
 
-        self.seconds = 0.0
-        self.programs = 0
-        jax.monitoring.register_event_duration_secs_listener(self._on_event)
+    @staticmethod
+    def _totals():
+        from deepspeed_tpu.observability.tracer import build_totals
 
-    def _on_event(self, event: str, duration: float, **_kw) -> None:
-        if event == self._EVENT:
-            self.seconds += duration
-            self.programs += 1
+        totals = build_totals()
+        return totals["backend_seconds"], totals["programs"]
 
     def take(self) -> Dict[str, Any]:
-        out = {"compile_s": round(self.seconds, 2),
-               "programs_built": self.programs}
-        self.seconds, self.programs = 0.0, 0
+        now = self._totals()
+        out = {"compile_s": round(now[0] - self._taken[0], 2),
+               "programs_built": now[1] - self._taken[1]}
+        self._taken = now
         return out
 
 

@@ -6,7 +6,10 @@ parallelism over a named device mesh, fused Pallas kernels for the hot ops.
 
 from __future__ import annotations
 
+import time as _time
 from typing import Any, Callable, Optional, Tuple
+
+_IMPORT_OPEN_NS = _time.monotonic_ns()      # ``setup/import`` opens here
 
 from deepspeed_tpu.accelerator import get_accelerator
 from deepspeed_tpu import comm
@@ -167,3 +170,13 @@ def argparse_suppress():
 
 def init_distributed(**kwargs):
     return comm.init_distributed(**kwargs)
+
+
+# ``setup/import`` closes here (the imports above, ``jax`` among them when
+# the caller had not imported it), and the program's one ``jax.monitoring``
+# listener goes in: from now on every executable JAX builds in this process
+# leaves a ``setup/build_program`` record (observability/tracer.py, "Once a
+# process")
+from deepspeed_tpu.observability import tracer as _tracer  # noqa: E402
+
+_tracer.process_began(_IMPORT_OPEN_NS)

@@ -4,8 +4,10 @@ Static lint can't see a shape that quietly varies step to step; this
 guard proves at runtime that a warmed-up region is **steady-state**:
 
 * **recompiles** — counted via ``jax.monitoring``'s backend-compile
-  event, so ANY new executable built inside the guarded region (a jit
-  cache miss, a new eager-op shape) trips it;
+  event (by the program's one listener, ``observability/tracer.py``:
+  which program a region built is the ``setup/build_program`` record it
+  left on ``process_tracer()``), so ANY new executable built inside the
+  guarded region (a jit cache miss, a new eager-op shape) trips it;
 * **explicit host syncs** — ``jax.device_get`` / ``jax.block_until_ready``
   calls are counted (patched for the guard's scope), catching the
   "fetch a flag every step" class on every backend;
@@ -26,7 +28,6 @@ The pytest fixture lives in ``tests/conftest.py`` (``trace_guard``).
 from __future__ import annotations
 
 import contextlib
-import threading
 from typing import Optional
 
 __all__ = ["TraceGuard", "TraceGuardError", "compile_count"]
@@ -36,39 +37,20 @@ class TraceGuardError(AssertionError):
     """A guarded region recompiled or synced more than allowed."""
 
 
-_lock = threading.Lock()
-_counts = {"backend_compile": 0, "jaxpr_trace": 0}
-_listener_installed = False
+def _counts() -> tuple:
+    """(backend compiles, jaxpr traces) so far, from the program's one
+    ``jax.monitoring`` listener (``observability/tracer.py``: the same
+    events it folds into ``setup/build_program`` records)."""
+    from deepspeed_tpu.observability.tracer import build_totals
 
-_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
-_JAXPR_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
-
-
-def _on_event(event: str, duration: float, **_kw) -> None:
-    if event == _BACKEND_COMPILE_EVENT:
-        with _lock:
-            _counts["backend_compile"] += 1
-    elif event == _JAXPR_TRACE_EVENT:
-        with _lock:
-            _counts["jaxpr_trace"] += 1
-
-
-def _install_listener() -> None:
-    global _listener_installed
-    if _listener_installed:
-        return
-    import jax.monitoring
-
-    jax.monitoring.register_event_duration_secs_listener(_on_event)
-    _listener_installed = True
+    totals = build_totals()
+    return totals["programs"], totals["traces"]
 
 
 def compile_count() -> int:
-    """Process-wide backend compiles observed since the guard module
-    first armed (monotonic; snapshot-and-diff around regions)."""
-    _install_listener()
-    with _lock:
-        return _counts["backend_compile"]
+    """Process-wide backend compiles observed since ``deepspeed_tpu`` was
+    imported (monotonic; snapshot-and-diff around regions)."""
+    return _counts()[0]
 
 
 class TraceGuard:
@@ -139,7 +121,6 @@ class TraceGuard:
     def __enter__(self) -> "TraceGuard":
         import jax
 
-        _install_listener()
         self._stack = contextlib.ExitStack()
         if self.d2h is not None:
             self._stack.enter_context(
@@ -151,18 +132,15 @@ class TraceGuard:
             self._stack.enter_context(
                 jax.transfer_guard_device_to_device(self.d2d))
         self._patch_syncs()
-        with _lock:
-            self._c0 = _counts["backend_compile"]
-            self._t0 = _counts["jaxpr_trace"]
+        self._c0, self._t0 = _counts()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         self._unpatch_syncs()
         assert self._stack is not None
         self._stack.close()
-        with _lock:
-            self.compiles = _counts["backend_compile"] - self._c0
-            self.retraces = _counts["jaxpr_trace"] - self._t0
+        compiles, retraces = _counts()
+        self.compiles, self.retraces = compiles - self._c0, retraces - self._t0
         if exc_type is not None:
             return False
         problems = []

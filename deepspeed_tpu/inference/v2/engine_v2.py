@@ -36,7 +36,9 @@ from deepspeed_tpu.inference.v2.modules.attention import (
 from deepspeed_tpu.inference.v2.ragged import (CacheLayoutError,
                                                DSStateManager,
                                                RaggedBatchWrapper)
-from deepspeed_tpu.observability.tracer import SpanHandle, open_span
+from deepspeed_tpu.observability.tracer import (SpanHandle,
+                                                build_telemetry_from_here,
+                                                open_span, setup_span)
 from deepspeed_tpu.utils.compile_cache import key_cache_on_names
 from deepspeed_tpu.utils.logging import log_dist
 
@@ -168,6 +170,7 @@ def _named(fn, name: str):
 class InferenceEngineV2:
     """reference engine_v2.py:30."""
 
+    @setup_span("setup/engine_init")
     def __init__(self, model: Any, params: Any,
                  config: Optional[RaggedInferenceEngineConfig] = None):
         self.config = config or RaggedInferenceEngineConfig()
@@ -181,6 +184,9 @@ class InferenceEngineV2:
         #: from 1): what its dispatch span and the wait that retires it
         #: are named by
         self.last_launch = 0
+        #: the ``observability/program*`` counters and this engine's
+        #: ``time_to_first_launch_s`` (``occupancy``)
+        self._build_telemetry = build_telemetry_from_here()
         sm_cfg = self.config.state_manager
         kv_cfg = self.config.kv_cache
         max_pos = getattr(model, "max_positions", None)
@@ -1481,6 +1487,9 @@ class InferenceEngineV2:
         # weights only: kv_occupancy already carries the pool bytes —
         # the same quantity must not scrape under two names
         out.update(hbm_footprint(self.params))
+        # what the process built, and how long this engine took to be ready
+        out.update(self._build_telemetry(
+            step.__name__ for step in self._steps.values()))
         return out
 
     def lower_step(self, key: tuple):

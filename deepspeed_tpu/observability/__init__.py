@@ -31,6 +31,19 @@ and the off-path cost (one attribute test, the shared null context) are
 in :mod:`~deepspeed_tpu.observability.tracer`.  ``annotate()`` /
 ``step_annotation()`` remain for callers that have no tracer.
 
+What happens once a process is on a tracer of its own,
+``process_tracer()`` (made on first use, always on): ``setup/import`` (the
+package's import, closing with ``process_start_ns``), ``setup/engine_init``
+and ``setup/init_parameters`` (the engines' constructors and the sharded
+parameter init), and ONE ``setup/build_program`` record an executable JAX
+builds anywhere in the process, under the program's own name with its
+``trace_s`` / ``lower_s`` / ``backend_s`` and what the persistent cache said
+(``cache``: hit / miss / none), folded from ``jax.monitoring``'s events by
+the program's one listener.  One record a build: every tracer reads one
+clock, so the tick that recompiled is the span of the scheduler's tracer
+whose interval holds the record's end, and ``merge_events(process_tracer().
+export_events(), tracer.export_events())`` is one timeline.
+
 Every request carries a ``trace_id`` minted at submit; spans from every
 replica incarnation it touches (kill→replay, rolling restarts,
 disaggregated prefill→decode handoff) share that id, so the exported
@@ -56,7 +69,8 @@ from deepspeed_tpu.observability.tracer import (Tracer, annotate,
                                                 enable_device_annotations,
                                                 load_chrome_trace,
                                                 merge_events, mint_trace_id,
-                                                open_span, step_annotation,
+                                                open_span, process_tracer,
+                                                step_annotation,
                                                 write_chrome_trace)
 
 __all__ = ["FlightRecorder", "MemoryLedger", "MetricSpec", "MetricsRegistry",
@@ -64,5 +78,6 @@ __all__ = ["FlightRecorder", "MemoryLedger", "MetricSpec", "MetricsRegistry",
            "capture_memory_analysis", "default_registry",
            "enable_device_annotations", "kv_occupancy", "list_postmortems",
            "load_chrome_trace", "load_postmortem", "make_occupancy_provider",
-           "merge_events", "mint_trace_id", "open_span", "step_annotation",
+           "merge_events", "mint_trace_id", "open_span", "process_tracer",
+           "step_annotation",
            "tenant_occupancy", "write_chrome_trace", "write_postmortem"]

@@ -22,6 +22,21 @@ def _declare(reg: MetricsRegistry) -> None:
                 help="total span/instant records ever written")
     reg.gauge("observability/spans_open",
               help="currently open (unfinished) spans")
+    # what the process built (the ``setup/build_program`` records of the
+    # process tracer, summed by the one jax.monitoring listener) and how
+    # long an engine took to be ready; exported by the engines' providers
+    reg.counter("observability/programs_built",
+                help="executables JAX built in this process (compiled or "
+                     "read from the persistent cache)")
+    reg.counter("observability/program_build_seconds", unit="s",
+                help="seconds of tracing, lowering and backend compile "
+                     "(or cache read) over those builds")
+    reg.counter("observability/program_cache_misses",
+                help="builds the persistent compile cache was asked for "
+                     "and did not hold")
+    reg.gauge("observability/time_to_first_launch_s", unit="s",
+              help="the package's import -> the engine's first step "
+                   "program built and launched")
     # compile-time HBM ledger gauges + static residency arithmetic
     reg.gauge("observability/hbm_*", unit="bytes",
               help="HLO memory ledger / static HBM residency gauges")

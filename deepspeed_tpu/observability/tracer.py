@@ -269,7 +269,11 @@ three dispatch spans closes with ``launch`` (that number) and ``program``
 ``ragged_step_T1088_tiled``, ``verify_step_K4``, letter for letter the
 ``jit(<program>)`` that heads the ``op_name`` of its device operations and,
 as ``jit_<program>``, names its executions on a profile's "XLA Modules"
-line).  The wait that retires a launch, ``fetch`` or
+line).  The first dispatch record with a ``program`` is the launch that
+loads that executable onto the chip and, unless something built it ahead,
+builds it (the build's ``setup/build_program`` record then closes inside
+the span's interval: "Once a process", below); every later launch of the
+program only runs it.  The wait that retires a launch, ``fetch`` or
 ``engine/fetch_logits``, closes with the ``launch`` it blocked on; the
 device runs launches in order, so that wait retires every earlier one too
 (a prefill chunk that drains no sequence is fetched by nobody).  A
@@ -328,6 +332,89 @@ span / instant         site                        attrs (counters)
 above and, through it, to the device; a request's holds in the queue are
 the ``pack`` spans its ``request/queued`` span covers.
 
+Once a process.  What happens once, before the first tick or training
+step, is on a tracer of its own, :func:`process_tracer` (made on first use,
+always enabled, the same records and exports; written by whichever thread
+builds, so it appends under a lock and nests a thread's spans under that
+thread's own), opened with :func:`open_span` like every other span:
+
+=======================  ==========================  =======================
+span                     site                        attrs (counters)
+=======================  ==========================  =======================
+``setup/import``         ``deepspeed_tpu/``          ``process_start_ns``
+                         ``__init__.py``, top to     (when the process
+                         bottom (two clock reads;    began, on this clock:
+                         ``jax``'s import is inside  Linux, from
+                         it when the caller had not  ``/proc/self/stat``;
+                         imported it); kept beside   elsewhere the span's
+                         the ring too                own open): where a
+                         (``import_span``)           reader anchors
+                                                     ``setup_s``
+``setup/engine_init``    ``InferenceEngineV2``       none: its length is
+                         ``.__init__``,              the metric
+                         ``DeepSpeedEngine``         (``setup_engine_``
+                         ``.__init__``               ``init_s``)
+``setup/init_``          ``DeepSpeedEngine.``        none (the same
+``parameters``           ``initialize_parameters``   metric)
+``setup/build_program``  one a build: every          ``program`` (the jitted
+                         executable JAX builds in    function's
+                         the process, the eager      ``__name__``, letter
+                         operations' and the         for letter a dispatch
+                         harness's own among them    span's ``program``),
+                         (reported when the build    ``trace_s``,
+                         is over)                    ``lower_s``,
+                                                     ``backend_s`` (the
+                                                     phases it went
+                                                     through), ``cache``
+                                                     (``hit``: the
+                                                     persistent cache held
+                                                     the executable, then
+                                                     ``retrieval_s`` too;
+                                                     ``miss``: asked, then
+                                                     compiled, written or
+                                                     not; ``none``: no
+                                                     cache directory),
+                                                     ``seq`` (the build's
+                                                     ordinal in the
+                                                     process)
+=======================  ==========================  =======================
+
+Who reads them: ``benchmark/readers/setup_build_s.py`` (the spans' lengths:
+``setup_engine_init_s``; ``trace_s + lower_s``: ``setup_trace_lower_s``;
+``backend_s`` by ``cache``: ``setup_cache_read_s``, ``setup_compile_s``,
+``setup_programs_compiled``; ``program``, ``retrieval_s`` and
+``process_start_ns`` in its commentary and its anchor) and the operator's
+view below (``seq`` and ``program``: ``time_to_first_launch_s``).  What an
+engine holds on the device is ``observability/hbm_*`` and
+``observability/kv_pool_bytes`` (``occupancy()``), not an attr here.
+
+The build records are folded from ``jax.monitoring``'s events by the
+program's ONE listener (:func:`install_build_listener`, at the bottom of
+``deepspeed_tpu/__init__.py``; ``analysis/trace_guard.py`` and
+``chip_smoke.py`` read their counts from it, :func:`build_totals`): each
+phase's event fires when the phase ENDS, so a record's end is
+the backend event's instant and its start the first phase's end less its
+seconds.  The trace event names the function bare, lowering and the backend
+name ``jit(<function>)``; a jitted function called inside another is traced
+inside the outer's trace (those seconds are within the outer's ``trace_s``,
+not added to it); the cache's events arrive between the lowering and the
+backend event of the same build.  A phase JAX's in-memory caches answer
+leaves no attr, and a lowering that is never compiled (``.lower()`` for the
+text) no record.  A build's parent is the ``setup/*`` span its thread
+holds open on the process tracer (``setup/engine_init``,
+``setup/init_parameters``).  There is ONE record a build: every tracer
+reads ``time.monotonic_ns``, so the span of a scheduler's tracer that
+caused a build (inside the warm-up ladder, or inside the window against the
+contract) is the innermost one whose interval holds the record's
+``t1_ns``: ``tick`` -> ``prefill`` -> ``engine/ragged_step`` (``decode`` ->
+``engine/decode_step``); the benchmark's reader names it so, and
+``merge_events`` puts both tracers on one timeline (``tools/obs_dump.py``).
+The operator's view of the same records: ``observability/
+programs_built``, ``program_build_seconds``, ``program_cache_misses`` and
+``time_to_first_launch_s`` (:func:`build_telemetry_from_here`, in the engines'
+registry providers).  Cost: the listener runs only when JAX builds
+something; nothing on the path of a tick or a training step reads it.
+
 Device scopes (``jax.named_scope``) are opened where the layer is written:
 ``attn/*`` in ``inference/v2/modules/attention.py``, ``moe/router`` and
 ``moe/shared`` in ``modules/moe.py``, the other ``moe/*`` in ``ops/
@@ -371,11 +458,13 @@ annotations are on).
 from __future__ import annotations
 
 import contextlib
+import functools
 import itertools
 import json
 import os
+import threading
 import time
-from typing import Any, Dict, Iterable, List, Optional, Sequence
+from typing import Any, Callable, Dict, Iterable, List, Optional, Sequence
 
 
 def mint_trace_id() -> str:
@@ -693,6 +782,278 @@ class Tracer:
                 ev["s"] = "t"              # instant scope: thread
             out.append(ev)
         return out
+
+
+# --------------------------------------------------------------------- #
+# Once a process: the process tracer, the ``setup/*`` spans, one record
+# an executable JAX builds
+# --------------------------------------------------------------------- #
+class _ProcessTracer(Tracer):
+    """The :class:`Tracer` of what happens once a process: the same
+    records and exports.  A scheduler's tracer has one writer; this one is
+    written by whichever thread builds something (an engine made on a
+    worker thread, a checkpoint thread's first copy), so a record is
+    appended under a lock and the innermost open span, the default parent,
+    is each thread's own."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._open_here = threading.local()
+        #: the ``setup/import`` record, kept beside the ring as well: a
+        #: process that builds more than the ring holds still knows when
+        #: it began
+        self.import_span: Optional[dict] = None
+        super().__init__(tid="process")
+
+    @property
+    def _current(self) -> Optional[SpanHandle]:
+        return getattr(self._open_here, "span", None)
+
+    @_current.setter
+    def _current(self, h: Optional[SpanHandle]) -> None:
+        self._open_here.span = h
+
+    def _append(self, rec: dict) -> None:
+        with self._lock:
+            super()._append(rec)
+
+    def records(self, tail: Optional[int] = None) -> List[dict]:
+        with self._lock:
+            return super().records(tail)
+
+    def record(self, name: str, t0_ns: int, t1_ns: int,
+               attrs: dict) -> dict:
+        """A span that is over when it is reported, its two ends read by
+        whoever reports it (JAX says how long a phase of a build took when
+        the phase ends), under the span this thread holds open."""
+        outer = self._current
+        rec = {"name": name, "ph": "X", "tid": self.default_tid,
+               "trace_id": _PROCESS_TRACE_ID,
+               "span_id": self._mint_span_id(),
+               "parent": outer.span_id if outer is not None else None,
+               "t0_ns": t0_ns, "t1_ns": t1_ns, "attrs": attrs}
+        self._append(rec)
+        return rec
+
+
+_PROCESS_TRACER: Optional[_ProcessTracer] = None
+_PROCESS_TRACE_ID = mint_trace_id()
+
+
+def process_tracer() -> _ProcessTracer:
+    """The tracer of what happens once a process: the ``setup/*`` spans
+    and one ``setup/build_program`` record an executable (the third table
+    of the module doc).  Made on first use, always enabled."""
+    global _PROCESS_TRACER
+    if _PROCESS_TRACER is None:
+        _PROCESS_TRACER = _ProcessTracer()
+    return _PROCESS_TRACER
+
+
+def setup_span(name: str):
+    """Decorator: the function runs under the span ``name`` on the
+    process tracer (``open_span``: a profiler annotation too while those
+    are on)."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def under_span(*args, **kw):
+            with open_span(process_tracer(), name,
+                           trace_id=_PROCESS_TRACE_ID):
+                return fn(*args, **kw)
+        return under_span
+    return deco
+
+
+def _process_start_ns(default: int) -> int:
+    """The instant this process began, on ``time.monotonic_ns``: its age
+    by the kernel's account (Linux: ``starttime`` of ``/proc/self/stat``,
+    clock ticks after boot, against ``CLOCK_BOOTTIME``) taken from now;
+    elsewhere ``default``."""
+    try:
+        with open("/proc/self/stat") as f:
+            after_comm = f.read().rsplit(")", 1)[1].split()
+        age_s = time.clock_gettime(time.CLOCK_BOOTTIME) \
+            - int(after_comm[19]) / os.sysconf("SC_CLK_TCK")
+        return min(default, time.monotonic_ns() - int(age_s * 1e9))
+    except (OSError, ValueError, IndexError, AttributeError):
+        return default
+
+
+def process_began(import_open_ns: int) -> None:
+    """Bottom of ``deepspeed_tpu/__init__.py``: close ``setup/import``
+    (opened at its top, two clock reads) with ``process_start_ns`` and
+    install the build listener."""
+    tr = process_tracer()
+    if tr.import_span is not None:
+        return
+    tr.import_span = tr.record(
+        "setup/import", import_open_ns, time.monotonic_ns(),
+        {"process_start_ns": _process_start_ns(import_open_ns)})
+    install_build_listener()
+
+
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_BACKEND_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_ASKED_EVENT = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_CACHE_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+
+class _BuildState(threading.local):
+    """One thread's build in progress, between JAX's events."""
+
+    def __init__(self):
+        #: bare name -> (seconds, end): traces no lowering has claimed
+        self.traces: Dict[str, tuple] = {}
+        #: the program lowered last and not yet compiled
+        self.lowered: Optional[dict] = None
+        #: what the persistent cache said to the compile under way
+        self.cache: Dict[str, Any] = {}
+
+
+_BUILD = _BuildState()
+_BUILD_LOCK = threading.Lock()
+#: process-wide, monotone: executables built, functions traced, seconds of
+#: all three phases and of the backend alone, builds the persistent cache
+#: was asked for and lacked
+_BUILD_TOTALS = {"programs": 0, "traces": 0, "seconds": 0.0,
+                 "backend_seconds": 0.0, "misses": 0}
+_LISTENING = False
+
+
+def _bare(fun_name: Optional[str]) -> str:
+    """``jit(decode_step)`` / ``pmap(f)`` -> ``decode_step`` / ``f``: the
+    trace event names the function bare, lowering and the backend name
+    the module."""
+    name = fun_name or "?"
+    if name.endswith(")") and "(" in name:
+        return name[name.index("(") + 1:-1]
+    return name
+
+
+def _on_build_seconds(event: str, seconds: float, **kw) -> None:
+    """jax.monitoring's duration events, folded into one record a build.
+    Each fires at the END of its phase: end = now, start = end - seconds.
+    A jitted function called inside another is traced inside the outer's
+    trace (its seconds are within the outer's and are not added); only
+    the function that is lowered next becomes a program."""
+    st = _BUILD
+    if event == _TRACE_EVENT:
+        with _BUILD_LOCK:
+            _BUILD_TOTALS["traces"] += 1
+        st.traces[kw.get("fun_name") or "?"] = (seconds,
+                                                time.monotonic_ns())
+    elif event == _LOWER_EVENT:
+        now = time.monotonic_ns()
+        program = _bare(kw.get("fun_name"))
+        low = st.lowered = {"program": program, "lower_s": seconds,
+                            "t0_ns": now - int(seconds * 1e9)}
+        traced = st.traces.pop(program, None)
+        if traced is not None:
+            low["trace_s"] = traced[0]
+            low["t0_ns"] = traced[1] - int(traced[0] * 1e9)
+        st.traces.clear()
+        st.cache = {}
+    elif event == _BACKEND_EVENT:
+        _close_build(st, _bare(kw.get("fun_name")), seconds)
+    elif event == _CACHE_RETRIEVAL_EVENT:
+        st.cache["retrieval_s"] = seconds
+
+
+def _on_build_event(event: str, **_kw) -> None:
+    if event == _CACHE_ASKED_EVENT:
+        # JAX asks its cache layer with or without a directory behind it
+        import jax
+
+        if jax.config.jax_compilation_cache_dir:
+            _BUILD.cache["cache"] = "miss"  # asked; a hit says so next
+    elif event == _CACHE_HIT_EVENT:
+        _BUILD.cache["cache"] = "hit"
+
+
+def _close_build(st: _BuildState, program: str, backend_s: float) -> None:
+    """The backend event closes the record, on the process tracer."""
+    now = time.monotonic_ns()
+    low, cache = st.lowered, st.cache
+    st.lowered, st.cache = None, {}
+    if low is None or low["program"] != program:    # compiled ahead of
+        low = {"t0_ns": now - int(backend_s * 1e9)}  # time: ``.compile()``
+    attrs = {"program": program}
+    for k in ("trace_s", "lower_s"):
+        if k in low:
+            attrs[k] = low[k]
+    attrs["backend_s"] = backend_s
+    attrs["cache"] = cache.get("cache", "none")
+    if attrs["cache"] == "hit" and "retrieval_s" in cache:
+        attrs["retrieval_s"] = cache["retrieval_s"]
+    with _BUILD_LOCK:
+        totals = _BUILD_TOTALS
+        totals["programs"] += 1
+        totals["backend_seconds"] += backend_s
+        totals["seconds"] += backend_s + low.get("trace_s", 0.0) \
+            + low.get("lower_s", 0.0)
+        totals["misses"] += attrs["cache"] == "miss"
+        attrs["seq"] = totals["programs"]
+    process_tracer().record("setup/build_program", low["t0_ns"], now, attrs)
+
+
+def install_build_listener() -> None:
+    """The program's ONE ``jax.monitoring`` listener (its two
+    registrations: durations and plain events), once a process."""
+    global _LISTENING
+    if _LISTENING:
+        return
+    import jax.monitoring
+
+    jax.monitoring.register_event_duration_secs_listener(_on_build_seconds)
+    jax.monitoring.register_event_listener(_on_build_event)
+    _LISTENING = True
+
+
+def build_totals() -> Dict[str, float]:
+    """``programs`` built, functions traced (``traces``), ``seconds`` of
+    trace + lowering + backend, ``backend_seconds`` of the backend alone
+    (compile, or the persistent cache's read) and persistent-cache
+    ``misses`` since the listener was installed; monotone (snapshot and
+    subtract)."""
+    install_build_listener()
+    with _BUILD_LOCK:
+        return dict(_BUILD_TOTALS)
+
+
+def build_telemetry_from_here() -> Callable[[Iterable[str]], Dict[str, float]]:
+    """For an engine's constructor.  Returns ``telemetry(programs)``: the
+    ``observability/program*`` counters of this process and, once one of
+    the engine's step programs (named ``programs``) has been built after
+    this call, ``observability/time_to_first_launch_s``: ``setup/import``'s
+    open to the end of that build, the instant the engine's first dispatch
+    reached the device (looked up in the process tracer's ring until it is
+    found, then kept).  Host-side reads only."""
+    after_seq = build_totals()["programs"]
+    found: List[float] = []
+
+    def telemetry(programs: Iterable[str]) -> Dict[str, float]:
+        totals = build_totals()
+        out = {"observability/programs_built": float(totals["programs"]),
+               "observability/program_build_seconds": totals["seconds"],
+               "observability/program_cache_misses": float(totals["misses"])}
+        tr = process_tracer()
+        if not found and tr.import_span is not None \
+                and totals["programs"] > after_seq:
+            names = set(programs)
+            for r in tr.records():
+                a = r.get("attrs") or {}
+                if r["name"] == "setup/build_program" \
+                        and a["seq"] > after_seq and a["program"] in names:
+                    found.append(
+                        (r["t1_ns"] - tr.import_span["t0_ns"]) / 1e9)
+                    break
+        if found:
+            out["observability/time_to_first_launch_s"] = found[0]
+        return out
+
+    return telemetry
 
 
 # --------------------------------------------------------------------- #

@@ -51,6 +51,8 @@ from deepspeed_tpu.parallel.topology import GROUP_ALIASES, MeshTopology
 from deepspeed_tpu.runtime.config import DeepSpeedConfig
 from deepspeed_tpu.runtime.lr_schedules import LRScheduler, get_lr_schedule_fn
 from deepspeed_tpu.runtime.zero import ZeroShardings
+from deepspeed_tpu.observability.tracer import (build_telemetry_from_here,
+                                                setup_span)
 from deepspeed_tpu.ops.optimizers import OptimizerDef, get_optimizer
 from deepspeed_tpu.utils.compile_cache import key_cache_on_names
 from deepspeed_tpu.utils.logging import log_dist, logger
@@ -141,6 +143,7 @@ def _as_model_fns(model, loss_fn) -> Tuple[Callable, Callable]:
 class DeepSpeedEngine:
     """Training engine (reference runtime/engine.py:175)."""
 
+    @setup_span("setup/engine_init")
     def __init__(self,
                  model: Any,
                  config: Any = None,
@@ -155,6 +158,9 @@ class DeepSpeedEngine:
         self.accelerator = get_accelerator()
         # the fused step's scopes are read by name from a profile
         key_cache_on_names()
+        #: the ``observability/program*`` counters and this engine's
+        #: ``time_to_first_launch_s`` (``register_observability``)
+        self._build_telemetry = build_telemetry_from_here()
         cfg = config if config is not None else config_params
         self.config = (cfg if isinstance(cfg, DeepSpeedConfig)
                        else DeepSpeedConfig(cfg or {}))
@@ -542,6 +548,7 @@ class DeepSpeedEngine:
             self._offload_transfer(to_host=True)
         self._param_offload_transfer(to_host=True)
 
+    @setup_span("setup/init_parameters")
     def initialize_parameters(self, *sample_args, seed: Optional[int] = None):
         """Construct params directly sharded (the reference's ``zero.Init``
         construction-time partitioning, partition_parameters.py:734 — here a
@@ -1496,6 +1503,12 @@ class DeepSpeedEngine:
                         tree_bytes(self.state[name])
             if self._offload_stats is not None:
                 out.update(self._offload_stats.snapshot())
+            # what the process built, and how long the engine took to
+            # launch its first step program
+            out.update(self._build_telemetry(
+                getattr(f, "__name__", None) for f in (
+                    self._jit_micro, self._jit_fused, self._jit_train_batch)
+                if f is not None))
             return out
 
         registry.register_provider(key, provider)

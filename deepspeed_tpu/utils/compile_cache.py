@@ -17,6 +17,11 @@ the chip, PERF.md section 6, PR 23).  The span and scope names are what the
 engines publish to an operator and to the benchmark's readers, so both
 engines call :func:`key_cache_on_names` when they are built: a change of
 names then compiles once more instead of reporting another program's.
+Where such a recompile shows: the build's ``setup/build_program`` record on
+``observability.tracer.process_tracer()`` reads ``cache: miss`` under the
+program's own name (and the benchmark's ``setup_programs_compiled`` counts a
+step program in a warm run), so a ``setup_s`` that differs between a parent
+and its change names the programs a moved line invalidated.
 """
 
 from __future__ import annotations

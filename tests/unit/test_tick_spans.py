@@ -1003,12 +1003,36 @@ def test_untraced_tick_builds_no_span(params, monkeypatch, make):
     sched = _sched(params, tracer=tr)
     assert sched.engine.tracer is tr
     _drive(sched)
-    assert built == []
+    # (the engine's constructor, once: on the process tracer)
+    assert built == ["setup/engine_init"]
     # launches are still counted: one integer
     assert sched.engine.last_launch == 8
     if tr is not None:
         assert len(tr) == 0 and not tr.open_spans()
         assert tr.span("x") is tracer_mod._NULL_CM
+    # once the programs are built, a tick leaves nothing on the process
+    # tracer either and JAX reports nothing for the build listener to hear
+    # (PR 64): a listener of the test's own, beside the program's, counts
+    from jax._src import monitoring
+
+    heard = []
+    on_seconds = lambda event, *a, **kw: heard.append(event)
+    on_event = lambda event, **kw: heard.append(event)
+    monitoring.register_event_duration_secs_listener(on_seconds)
+    monitoring.register_event_listener(on_event)
+    try:
+        assert tracer_mod._on_build_seconds in \
+            monitoring.get_event_duration_listeners()
+        assert tracer_mod._on_build_event in monitoring.get_event_listeners()
+        written = tracer_mod.process_tracer()._n
+        totals = tracer_mod.build_totals()
+        _drive(sched)
+    finally:
+        monitoring.unregister_event_duration_listener(on_seconds)
+        monitoring.unregister_event_listener(on_event)
+    assert sched.engine.last_launch == 16
+    assert heard == [] and tracer_mod.build_totals() == totals
+    assert tracer_mod.process_tracer()._n == written
 
 
 @pytest.mark.parametrize("make", [lambda: None,
@@ -1126,7 +1150,8 @@ def test_untraced_scheduler_still_annotates_while_those_are_on(
     sched = _sched(params)
     _drive(sched)
     assert len(sched.finished_requests) == 2
-    assert {"tick", "ds_tick", "pack", "prefill", "sample", "decode",
+    assert {"setup/engine_init",
+            "tick", "ds_tick", "pack", "prefill", "sample", "decode",
             "retire", "engine/build_batch", "engine/upload",
             "engine/ragged_step", "engine/decode_prep",
             "engine/decode_step", "fetch", "advance"} == set(names)

@@ -236,6 +236,7 @@ def run_traced_sample(out_dir: str, n_requests: int = 4,
     from deepspeed_tpu.inference.v2.model_implementations import RaggedLlama
     from deepspeed_tpu.models import LlamaConfig, LlamaForCausalLM
     from deepspeed_tpu.observability import (MetricsRegistry, Tracer,
+                                             merge_events, process_tracer,
                                              write_chrome_trace)
     from deepspeed_tpu.serving import (ContinuousBatchScheduler,
                                        SamplingParams)
@@ -264,7 +265,10 @@ def run_traced_sample(out_dir: str, n_requests: int = 4,
         [(r.uid, r.state.value) for r in reqs]
 
     os.makedirs(out_dir, exist_ok=True)
-    events = tracer.export_events()
+    # one timeline: what the process did once (setup/import, the engine's
+    # constructor, one setup/build_program a program built) and the ticks
+    events = merge_events(process_tracer().export_events(),
+                          tracer.export_events())
     trace_path = os.path.join(out_dir, "trace.json")
     write_chrome_trace(trace_path, events)
     problems = validate_trace(events)
